@@ -21,6 +21,8 @@ from flexflow_tpu import (AdamOptimizer, FFConfig, LossType, MetricsType,
 
 
 def parse_config(argv=None) -> FFConfig:
+    from flexflow_tpu.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     cfg = FFConfig()
     rest = cfg.parse_args(argv if argv is not None else sys.argv[1:])
     cfg._rest = rest

@@ -368,7 +368,9 @@ class CollectiveInferencePass:
         differ. The same priced-vs-executed closure FFL207 gave the
         '_ovl' dimension."""
         from flexflow_tpu.ffconst import OperatorType
-        from flexflow_tpu.ops.pallas_kernels import (BLK_Q, pallas_mode)
+        from flexflow_tpu.ops.pallas_kernels import (
+            BLK_Q, MAX_FLASH_HEAD_DIM, MAX_FLASH_SEQ, flash_shape_legal,
+            pallas_mode)
         from flexflow_tpu.search.unity import kernel_choice_of
 
         out: List[Diagnostic] = []
@@ -405,12 +407,14 @@ class CollectiveInferencePass:
                     training = getattr(ctx.ff.executor, "comp_mode",
                                        CompMode.TRAINING) \
                         == CompMode.TRAINING
-                if seq % BLK_Q or op.head_dim % 8:
+                if not flash_shape_legal(seq, op.head_dim):
                     out.append(error(
                         "FFL208",
                         f"'_k:flash' is illegal at this shape (seq={seq}"
-                        f" % {BLK_Q} != 0 or head_dim={op.head_dim} % 8"
-                        f" != 0) — the priced kernel cannot execute",
+                        f" must divide by {BLK_Q} and stay <= "
+                        f"{MAX_FLASH_SEQ}; head_dim={op.head_dim} must "
+                        f"divide by 8 and stay <= {MAX_FLASH_HEAD_DIM})"
+                        f" — the priced kernel cannot execute",
                         op=op.name,
                         hint="re-search (the flash gate rejects this "
                              "shape) or drop the stale strategy file"))

@@ -93,11 +93,7 @@ def initialize_from_config(cfg) -> None:
 def is_initialized() -> bool:
     import jax
 
-    try:
-        from jax._src import distributed as _d
-        return _d.global_state.client is not None
-    except Exception:
-        return jax.process_count() > 1
+    return jax.distributed.is_initialized()
 
 
 def process_count() -> int:

@@ -39,6 +39,8 @@ def main(argv=None) -> int:
         return 2
     rest.remove(script)
     _config = cfg
+    from flexflow_tpu.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     # multi-host launch (--nodes N > 1, one driver process per host):
     # rendezvous through the JAX distributed runtime before the script
     # builds any mesh, so jax.devices() spans all hosts

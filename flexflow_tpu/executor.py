@@ -34,6 +34,18 @@ from flexflow_tpu.ops.base import Op, OpContext
 COMPUTE_PARAMS_KEY = "__compute_params__"
 
 
+def settled_spec(spec: P) -> P:
+    """``spec`` as jit reports it on an output: without trailing Nones.
+    ``P('data', None)`` and ``P('data')`` place an array identically but do
+    not compare equal, so a parameter placed with the first comes back
+    from the train step with the second, and jit compiles the whole step
+    again for what it takes to be a new input type."""
+    entries = list(spec)
+    while entries and entries[-1] is None:
+        entries.pop()
+    return P(*entries)
+
+
 class OpNode:
     """One materialized operator + where its inputs come from.
 
@@ -402,13 +414,11 @@ class GraphExecutor:
         data-sharded master layout the optimizer state follows (zeros_like
         inherits it, so sharded params get sharded m/v for free)."""
         def spec_for(op_name, pname, arr):
-            node = self._by_name[op_name]
-            spec = node.param_specs.get(pname, P())
             if master:
-                w = self.wus_spec(op_name, pname, tuple(arr.shape))
-                if w is not None:
-                    spec = w
-            return NamedSharding(self.mesh, spec)
+                spec = self.master_spec(op_name, pname, tuple(arr.shape))
+            else:
+                spec = self._by_name[op_name].param_specs.get(pname, P())
+            return NamedSharding(self.mesh, settled_spec(spec))
 
         return {
             op_name: {
@@ -416,6 +426,16 @@ class GraphExecutor:
             }
             for op_name, sub in params.items()
         }
+
+    def master_spec(self, op_name: str, pname: str,
+                    shape: Tuple[int, ...]) -> P:
+        """Spec the f32 master copy and the optimizer moments of one
+        leaf live on: its WUS spec where WUS shards it, else the
+        strategy's (compute) spec."""
+        w = self.wus_spec(op_name, pname, shape)
+        if w is not None:
+            return w
+        return self._by_name[op_name].param_specs.get(pname, P())
 
     # ---- forward graph traversal ------------------------------------------
     def _output_layout(self, guid: int, idx: int) -> str:
@@ -588,7 +608,8 @@ class GraphExecutor:
             return self.optimizer.update(grads, opt_state, params)
         from flexflow_tpu.ops.fused_update import fused_optimizer_update
         return fused_optimizer_update(self.optimizer, grads, opt_state,
-                                      params, fused)
+                                      params, fused, mesh=self.mesh,
+                                      spec_of=self.master_spec)
 
     def _train_step_fn(self):
         """The raw (unjitted) train-step function, for composition into

@@ -69,6 +69,18 @@ CHIP_SPECS: Dict[str, Dict[str, float]] = {
 }
 
 
+# ``jax.Device.device_kind`` (lower-cased, matched whole) -> CHIP_SPECS key.
+DEVICE_KIND_TO_CHIP: Dict[str, str] = {
+    "tpu v4": "tpu-v4",
+    "tpu v5 lite": "tpu-v5e",
+    "tpu v5e": "tpu-v5e",
+    "tpu v5": "tpu-v5p",
+    "tpu v5p": "tpu-v5p",
+    "tpu v6 lite": "tpu-v6e",
+    "tpu v6e": "tpu-v6e",
+}
+
+
 def _factor_torus(n: int, dims: int) -> Tuple[int, ...]:
     """Near-equal `dims`-way factorization of a slice's chip count into
     torus extents, largest first (e.g. 32 chips, 3-D -> (4, 4, 2) — the
@@ -476,7 +488,10 @@ def detect_machine_spec(num_devices: Optional[int] = None,
     time). ``slices > 1`` splits the detected chips into that many
     DCN-connected slices (``FFConfig --slices``): chips_per_slice =
     n // slices, with the per-generation default ICI torus factored
-    per SLICE rather than over the flat device count. On a real chip,
+    per SLICE rather than over the flat device count. The synthetic
+    "cpu-sim" chip is chosen only on the cpu platform; any other platform
+    must report a ``device_kind`` in ``DEVICE_KIND_TO_CHIP`` or this
+    raises. On a real chip,
     measured per-collective calibration from CALIBRATION.json engages
     automatically (platform-gated like search/profile's op corrections;
     FFS_NO_DRIFT_CORRECTIONS opts out) — CPU runs never pick up chip
@@ -491,19 +506,20 @@ def detect_machine_spec(num_devices: Optional[int] = None,
     if s > 1 and n % s != 0:
         raise ValueError(
             f"--slices {s} does not divide the {n} visible devices")
-    kind = devs[0].device_kind.lower() if devs else "cpu"
-    if "v5 lite" in kind or "v5e" in kind:
-        chip = "tpu-v5e"
-    elif "v5p" in kind or "v5" in kind:
-        chip = "tpu-v5p"
-    elif "v4" in kind:
-        chip = "tpu-v4"
-    elif "v6" in kind:
-        chip = "tpu-v6e"
-    else:
-        chip = "cpu-sim"
-    spec = MachineSpec(chip=chip, chips_per_slice=n // s, num_slices=s)
     platform = devs[0].platform if devs else "cpu"
+    if platform == "cpu":
+        chip = "cpu-sim"
+    else:
+        kind = devs[0].device_kind
+        chip = DEVICE_KIND_TO_CHIP.get(kind.lower())
+        if chip is None:
+            # never a default: a synthetic peak would silently decide
+            # dtype and layout and turn up in every utilization
+            raise ValueError(
+                f"no chip spec for device_kind {kind!r} on platform "
+                f"{platform!r}: add it to DEVICE_KIND_TO_CHIP / CHIP_SPECS "
+                f"in flexflow_tpu/machine.py or pass compile(machine_spec=)")
+    spec = MachineSpec(chip=chip, chips_per_slice=n // s, num_slices=s)
     if platform != "cpu" and not os.environ.get("FFS_NO_DRIFT_CORRECTIONS"):
         corr = load_collective_corrections(platform)
         if corr:

@@ -15,6 +15,7 @@ than Legion task launches. ``fit/eval`` mirror the Python frontend's loop
 from __future__ import annotations
 
 import time
+import warnings
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -554,6 +555,7 @@ class FFModel:
         self.machine_spec = machine_spec or detect_machine_spec(
             n_dev, slices=getattr(cfg, "slices", 1))
         self.search_info = None
+        self.search_seconds = None  # wall time of the native search, if run
         # search-objective provenance: "step_time" (TRAINING search),
         # "latency" (INFERENCE search), None (no search ran) — recorded
         # in exported strategy files and checkpoint manifests
@@ -572,6 +574,12 @@ class FFModel:
             dp = n_dev // mp
             while dp > 1 and batch0 % dp != 0:
                 dp //= 2
+            if dp * mp < n_dev:
+                warnings.warn(
+                    f"compile(): batch size {batch0} does not divide over "
+                    f"the {n_dev // mp}-way data axis of {n_dev} devices; "
+                    f"running on {dp * mp} devices and leaving "
+                    f"{n_dev - dp * mp} idle", RuntimeWarning, stacklevel=3)
             axes = {"data": dp}
             if mp > 1:
                 axes["model"] = mp
@@ -629,9 +637,11 @@ class FFModel:
                     from flexflow_tpu.search.profile import microbenchmark
                     measured = microbenchmark(
                         nodes, cache_file=cfg.measured_cache_file)
+                t_search = time.perf_counter()
                 mesh_axes, self.strategy, self.search_info = _unity.graph_optimize(
                     nodes, self.machine_spec, cfg, n_dev, batch=batch0,
                     measured=measured, final_ref=final_ref)
+                self.search_seconds = time.perf_counter() - t_search
                 self.search_objective = self.search_info.get("objective")
                 self.mesh = make_mesh(_math.prod(mesh_axes.values()), mesh_axes)
                 # the substitution engine may have rewritten the graph —
@@ -830,13 +840,13 @@ class FFModel:
                 # cross-attention) the availability-based auto pick
                 # must survive: eval/serve forwards may legally run
                 # flash even though the TRAINING search never priced it.
-                from flexflow_tpu.ops.pallas_kernels import BLK_Q
+                from flexflow_tpu.ops.pallas_kernels import (
+                    flash_shape_legal)
                 try:
                     b, s, e = op.input_shapes[0]
                     sk = (op.input_shapes[1][1]
                           if len(op.input_shapes) > 1 else s)
-                    return (sk == s and s % BLK_Q == 0
-                            and op.head_dim % 8 == 0
+                    return (sk == s and flash_shape_legal(s, op.head_dim)
                             and not (comp_mode == CompMode.TRAINING
                                      and op.dropout > 0))
                 except Exception:
@@ -964,6 +974,17 @@ class FFModel:
         # optimizer state is ever allocated
         self.opt_state = (None if comp_mode == CompMode.INFERENCE
                           else self.optimizer.init(self.params))
+        # every leaf starts where the train step will leave it, committed
+        # to the mesh. An uncommitted scalar (Adam's step count, a
+        # BatchNorm statistic) comes back from the first step as a
+        # mesh-replicated array; jit takes that for a new input type and
+        # answers with a second trace and a second full compile of the
+        # step (34 s for the BERT-proxy on a v5e).
+        replicated = NamedSharding(self.mesh, P())
+        self.state, self.opt_state = jax.tree.map(
+            lambda a: a if isinstance(a.sharding, NamedSharding)
+            else jax.device_put(a, replicated),
+            (self.state, self.opt_state))
         self._iter = 0
         self._seq_execs: Dict[int, Any] = {}  # seq-length bucket executors
         self._declared_seq_cache = -1  # lazily derived (-1 = not yet)

@@ -165,20 +165,34 @@ def locate_profile_traces(profile_dir: str) -> List[str]:
     return sorted(glob.glob(os.path.join(sessions[-1], "*.trace.json*")))
 
 
+# the thread of a TPU's ``/device:`` process that carries one span per
+# executed HLO op. Its siblings are roll-ups of the same time — "XLA
+# Modules" (one span per program run) and "Steps" (step groupings that
+# include the gaps between programs) — and counting them would report
+# the whole step as device compute.
+DEVICE_OP_THREAD = "XLA Ops"
+
+
 def extract_device_events(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
     """Device op spans from a profiler Chrome trace.
 
     An event is a device op when its args carry ``hlo_op``/``hlo_module``
-    (the CPU thunk executor stamps these) or when it sits under a
-    ``/device:`` process (real TPU lanes). Python-tracer frames
-    (``$``-prefixed) and runtime bookkeeping spans carry neither and are
-    dropped. Returns rows ``{name, ts, dur, bucket, kind}`` (µs)."""
+    (the CPU thunk executor stamps these) or when it sits on the
+    ``XLA Ops`` thread of a ``/device:`` process (real TPU lanes).
+    Python-tracer frames (``$``-prefixed) and runtime bookkeeping spans
+    carry neither and are dropped. Returns rows ``{name, ts, dur,
+    bucket, kind}`` (µs)."""
     device_pids = set()
+    op_lanes = set()
     for e in trace.get("traceEvents", []):
-        if (e.get("ph") == "M" and e.get("name") == "process_name"
-                and str((e.get("args") or {}).get("name", ""))
-                .startswith("/device:")):
+        if e.get("ph") != "M":
+            continue
+        label = str((e.get("args") or {}).get("name", ""))
+        if e.get("name") == "process_name" and label.startswith("/device:"):
             device_pids.add(e.get("pid"))
+        elif e.get("name") == "thread_name" and label == DEVICE_OP_THREAD:
+            op_lanes.add((e.get("pid"), e.get("tid")))
+    op_lanes = {lane for lane in op_lanes if lane[0] in device_pids}
     out: List[Dict[str, Any]] = []
     for e in trace.get("traceEvents", []):
         if e.get("ph") != "X":
@@ -186,7 +200,7 @@ def extract_device_events(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
         name = e.get("name") or ""
         args = e.get("args") or {}
         if not (args.get("hlo_op") or args.get("hlo_module")
-                or e.get("pid") in device_pids):
+                or (e.get("pid"), e.get("tid")) in op_lanes):
             continue
         if name.startswith("$"):
             continue

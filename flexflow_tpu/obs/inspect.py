@@ -87,6 +87,13 @@ def collective_census(hlo_text: str, min_bytes: float = 0.0
     return out
 
 
+def pallas_kernel_count(hlo_text: str) -> int:
+    """Pallas (Mosaic) kernels in an optimized TPU module: its custom-call
+    instructions that target ``tpu_custom_call``. The bare name also turns
+    up in op metadata, so the whole attribute is matched."""
+    return hlo_text.count('custom_call_target="tpu_custom_call"')
+
+
 def census_totals(census: Dict[str, Dict[str, float]]) -> Dict[str, float]:
     return dict(
         count=sum(e["count"] for e in census.values()),

@@ -11,7 +11,9 @@ ROADMAP names as the BERT proxy's remaining gap). The searched
 * **Pallas path** (TPU, or CPU under ``FLEXFLOW_TPU_PALLAS=interpret``):
   a single elementwise kernel reads p/g/m/v once from HBM and writes
   p'/m'/v' once — one launch, the minimal (2 + 2·state-copies) HBM
-  round trips the native ``update_triad_time`` prices.
+  round trips the native ``update_triad_time`` prices. On a mesh of
+  several devices it runs per shard under ``shard_map`` over the leaf's
+  own (WUS) sharding.
 * **XLA fallback** (Pallas unavailable or shape not lane-aligned):
   ``lax.optimization_barrier`` fences the leaf's inputs so XLA forms
   one fused loop over the update instead of interleaving it with
@@ -75,18 +77,15 @@ def _pallas_rows(size: int):
     return None
 
 
-def fused_adam_leaf(p, g, m, v, alpha_t, *, beta1, beta2, eps, wd):
-    """One leaf's fused Adam update -> (p', m', v')."""
-    from flexflow_tpu.ops.pallas_kernels import pallas_mode
-
-    mode = pallas_mode()
+def _adam_leaf_local(alpha2, p, g, m, v, *, mode, beta1, beta2, eps, wd):
+    """The fused update of one device's block of a leaf."""
     geom = _pallas_rows(int(p.size)) if mode != "off" else None
     if geom is None:
         # XLA-fused fallback: the barrier fences the four inputs into
         # one region boundary; identity on values
         p, g, m, v = jax.lax.optimization_barrier((p, g, m, v))
-        return _adam_math(p, g, m, v, alpha_t, beta1=beta1, beta2=beta2,
-                          eps=eps, wd=wd)
+        return _adam_math(p, g, m, v, alpha2[0, 0], beta1=beta1,
+                          beta2=beta2, eps=eps, wd=wd)
     from jax.experimental import pallas as pl
 
     rows, blk = geom
@@ -95,7 +94,6 @@ def fused_adam_leaf(p, g, m, v, alpha_t, *, beta1, beta2, eps, wd):
     kern = functools.partial(_adam_kernel, beta1=beta1, beta2=beta2,
                              eps=eps, wd=wd)
     row_spec = pl.BlockSpec((blk, 128), lambda i: (i, 0))
-    alpha2 = jnp.asarray(alpha_t, jnp.float32).reshape(1, 1)
     pn, mn, vn = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((rows, 128), p.dtype),
@@ -110,6 +108,35 @@ def fused_adam_leaf(p, g, m, v, alpha_t, *, beta1, beta2, eps, wd):
     return pn.reshape(shp), mn.reshape(shp), vn.reshape(shp)
 
 
+def fused_adam_leaf(p, g, m, v, alpha_t, *, beta1, beta2, eps, wd,
+                    mesh=None, spec=None):
+    """One leaf's fused Adam update -> (p', m', v').
+
+    ``mesh`` / ``spec``: the sharding the leaf's master copy and moments
+    live on (the WUS spec where WUS shards it). On a mesh of more than
+    one device the kernel runs per shard under ``shard_map``: to the
+    SPMD partitioner a bare ``pallas_call`` is a custom call it cannot
+    split ("Mosaic kernels cannot be automatically partitioned"). The
+    update is elementwise, so each device updates the block it owns and
+    no collective is needed; a leaf the spec leaves replicated is
+    updated redundantly on every device, as the triad does."""
+    from flexflow_tpu.ops.pallas_kernels import pallas_mode
+
+    mode = pallas_mode()
+    local = functools.partial(_adam_leaf_local, mode=mode, beta1=beta1,
+                              beta2=beta2, eps=eps, wd=wd)
+    alpha2 = jnp.asarray(alpha_t, jnp.float32).reshape(1, 1)
+    if mode == "off" or mesh is None or mesh.size == 1:
+        return local(alpha2, p, g, m, v)
+    from jax.sharding import PartitionSpec as P
+
+    spec = spec if spec is not None else P()
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(), spec, spec, spec, spec),
+                         out_specs=(spec, spec, spec),
+                         check_vma=False)(alpha2, p, g, m, v)
+
+
 def _sgd_math(opt, p, g, v):
     """The reference SGDOptimizer.update step (momentum form)."""
     g = g + opt.weight_decay * p
@@ -118,11 +145,13 @@ def _sgd_math(opt, p, g, v):
     return p - opt.lr * upd, v_new
 
 
-def fused_optimizer_update(opt, grads, state, params,
-                           fused_ops: Set[str]) -> Tuple[Dict, Dict]:
+def fused_optimizer_update(opt, grads, state, params, fused_ops: Set[str],
+                           mesh=None, spec_of=None) -> Tuple[Dict, Dict]:
     """``optimizer.update`` with the ``fused_ops`` subtrees routed
     through the fused one-dispatch region; value-identical to the
-    reference update (same math, same order) by construction."""
+    reference update (same math, same order) by construction.
+    ``spec_of(op name, param name, shape)`` names each leaf's master
+    sharding on ``mesh`` (see ``fused_adam_leaf``)."""
     from flexflow_tpu.optimizers import AdamOptimizer, SGDOptimizer
 
     rest_names = [k for k in params if k not in fused_ops]
@@ -159,7 +188,9 @@ def fused_optimizer_update(opt, grads, state, params,
                     p, grads[op_name][pn], state["m"][op_name][pn],
                     state["v"][op_name][pn], alpha_t, beta1=opt.beta1,
                     beta2=opt.beta2, eps=opt.epsilon,
-                    wd=opt.weight_decay)
+                    wd=opt.weight_decay, mesh=mesh,
+                    spec=(spec_of(op_name, pn, tuple(p.shape))
+                          if spec_of is not None else None))
             new_p[op_name] = sp
             new_m[op_name] = sm
             new_v[op_name] = sv

@@ -93,11 +93,10 @@ def expert_parallel_ffn(x, dispatch, combine, w_h, b_h, w_o, b_o,
     tok4 = P(tok_axes, None, None, None)
     wspec3 = P(expert_axis, None, None)
     wspec2 = P(expert_axis, None)
-    from flexflow_tpu.utils.shard_map_compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(tok2, tok4, tok4, wspec3, wspec2, wspec3, wspec2),
-        out_specs=tok2, check_rep=False,
+        out_specs=tok2, check_vma=False,
     )(x, dispatch, combine, w_h, b_h, w_o, b_o)
 
 

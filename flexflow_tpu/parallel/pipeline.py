@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from flexflow_tpu.utils.shard_map_compat import shard_map
 
 SCHEDULES = ("gpipe", "circular")
 
@@ -290,10 +289,10 @@ def pipeline_spmd(stage_fn, stacked_params, x, mesh, *, num_microbatches,
     # slice of every microbatch)
     x_spec = P(axis if qsharded else None, data_axis) if data_axis \
         else (P(axis) if qsharded else P())
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: pipe_spec, stacked_params), x_spec),
-        out_specs=x_spec, check_rep=False)
+        out_specs=x_spec, check_vma=False)
     mb = x.shape[0] // M
     xs = x.reshape((M, mb) + x.shape[1:])
     return fn(stacked_params, xs).reshape(x.shape)

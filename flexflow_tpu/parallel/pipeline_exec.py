@@ -41,7 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from flexflow_tpu.executor import COMPUTE_PARAMS_KEY, GraphExecutor
+from flexflow_tpu.executor import (COMPUTE_PARAMS_KEY, GraphExecutor,
+                                   settled_spec)
 from flexflow_tpu.ops.base import OpContext
 
 BODY_KEY = "__pipe_body__"
@@ -142,21 +143,22 @@ class PipelineGraphExecutor(GraphExecutor):
                  else list(range(self.pb.num_blocks)))
 
         def _init(rng):
-            p: Dict[str, Any] = {}
-            for node in self._head + self._tail:
+            # one key per node in graph order, exactly as GraphExecutor
+            # draws them: a seed gives the same weights whichever
+            # executor the strategy lands on, so searched-vs-data-parallel
+            # loss curves compare
+            by_node = []
+            for node in self.nodes:
                 rng, sub = jax.random.split(rng)
-                ps = node.op.init_params(sub)
-                if ps:
-                    p[node.op.name] = ps
-            per_block: List[Dict] = []
-            for blk in self.pb.blocks:
-                bp = {}
-                for j, ni in enumerate(blk):
-                    rng, sub = jax.random.split(rng)
-                    ps = self.nodes[ni].op.init_params(sub)
-                    if ps:
-                        bp[f"op{j}"] = ps
-                per_block.append(bp)
+                by_node.append(node.op.init_params(sub))
+            p: Dict[str, Any] = {}
+            for i in list(self.pb.head) + list(self.pb.tail):
+                if by_node[i]:
+                    p[self.nodes[i].op.name] = by_node[i]
+            per_block: List[Dict] = [
+                {f"op{j}": by_node[ni] for j, ni in enumerate(blk)
+                 if by_node[ni]}
+                for blk in self.pb.blocks]
             p[BODY_KEY] = jax.tree.map(
                 lambda *ws: jnp.stack([ws[b] for b in order]), *per_block)
             return p
@@ -260,25 +262,22 @@ class PipelineGraphExecutor(GraphExecutor):
         return jax.tree_util.tree_map_with_path(leaf, tree)
 
     def param_shardings(self, params, master: bool = False):
-        by_name = {n.op.name: n for n in self.nodes}
-
         def head_tail(op_name, sub):
             out = {}
             for pn, arr in sub.items():
-                spec = by_name[op_name].param_specs.get(pn, P())
                 if master:
-                    w = self.wus_spec(op_name, pn,
-                                      tuple(getattr(arr, "shape", ())))
-                    if w is not None:
-                        spec = w
-                out[pn] = NamedSharding(self.mesh, spec)
+                    spec = self.master_spec(
+                        op_name, pn, tuple(getattr(arr, "shape", ())))
+                else:
+                    spec = self._by_name[op_name].param_specs.get(pn, P())
+                out[pn] = NamedSharding(self.mesh, settled_spec(spec))
             return out
 
         def body_leaf(w):
             spec = self._body_wus_spec(w.shape) if master else None
             if spec is None:
                 spec = self._body_compute_spec(w.shape)
-            return NamedSharding(self.mesh, spec)
+            return NamedSharding(self.mesh, settled_spec(spec))
 
         out = {}
         for op_name, sub in params.items():

@@ -64,10 +64,9 @@ def _ring_attention_local(q, k, v, axis_name: str, causal: bool):
     When the Pallas flash kernel is available for the local block shape,
     each Q-block x KV-block partial runs inside it — the S_loc x S_loc
     score tile lives in VMEM only, in BOTH forward and backward (the
-    K-blocked backward kernel covers shard lengths up to
-    MAX_BWD_BLOCKED_SEQ; only beyond that does the backward fall back to
-    the HBM-materializing einsum recompute). Fixes VERDICT r3 Weak #7:
-    the einsum inner body materialized per-shard scores in HBM, quadratic
+    K-blocked backward kernel covers every shard length the gate admits,
+    up to MAX_FLASH_SEQ; longer shards take the einsum body below). The
+    einsum inner body materializes per-shard scores in HBM, quadratic
     in the shard length at exactly the long contexts ring attention
     exists for. The merge accumulates (o_normalized, lse) blockwise:
         lse' = logaddexp(lse, lse_blk)
@@ -181,6 +180,5 @@ def ring_attention(q, k, v, mesh: Mesh, seq_axis: str = "seq",
                           causal=causal)
     # axes not named in the specs replicate, which is the intended layout
     # for dp x sp attention
-    from flexflow_tpu.utils.shard_map_compat import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

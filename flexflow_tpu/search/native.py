@@ -2,9 +2,11 @@
 
 Analog of the reference's in-process C++ search invoked through a Legion
 task boundary (GRAPH_OPTIMIZE_TASK_ID, src/runtime/model.cc:2825): here the
-boundary is a JSON string through a C ABI. The library is built from
-native/ by `make`; if the .so is missing we attempt a one-shot build with
-the system compiler (g++ is part of the supported toolchain).
+boundary is a JSON string through a C ABI. The library is git-ignored and
+built from native/ by `make`, which the loader runs before every first
+load — a no-op when the library is newer than its sources — so a library
+older than ffs_*.hpp/.cpp is never used: a stale one prices a different
+lattice than the Python side replays.
 """
 
 from __future__ import annotations
@@ -23,85 +25,48 @@ _lib = None
 _load_error: Optional[str] = None
 
 
-def _build(clean: bool = False) -> bool:
-    backup = None
+def _make() -> Optional[str]:
+    """Bring libffsearch.so up to date with its sources; the error text
+    when that fails, else None."""
     try:
-        if clean and os.path.exists(_LIB_PATH):
-            # move (not delete) the current library aside: the rebuild
-            # gets a fresh inode (glibc dlopen caches by path+inode), and
-            # a failed rebuild restores the working .so instead of
-            # destroying it
-            backup = _LIB_PATH + ".stale"
-            os.replace(_LIB_PATH, backup)
         r = subprocess.run(["make", "-C", _NATIVE_DIR], capture_output=True,
-                           timeout=120)
-        ok = r.returncode == 0 and os.path.exists(_LIB_PATH)
-        if ok and backup is not None:
-            os.remove(backup)
-            backup = None
-        return ok
-    except Exception:
-        return False
-    finally:
-        # restore the known-good library on ANY failed build — including
-        # a killed compiler leaving a truncated .so behind
-        if backup is not None:
-            os.replace(backup, _LIB_PATH)
-
-
-# exports the load-bearing paths need (search + simulator); a library
-# missing one of these is unusable
-_CORE_SYMBOLS = ("ffs_optimize", "ffs_simulate", "ffs_free", "ffs_version")
-# newer audit/tooling exports: their absence marks a stale build worth
-# one rebuild attempt, but never disables the core search
-_OPTIONAL_SYMBOLS = ("ffs_list_rules", "ffs_match_rules")
+                           text=True, timeout=300)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"`make -C native` did not run: {e!r}"
+    if r.returncode != 0:
+        return f"`make -C native` failed:\n{r.stderr[-2000:]}"
+    return None
 
 
 def get_lib():
-    """Load (building if necessary) the native library; None if unavailable."""
+    """Build (when stale) and load the native library; None if unavailable."""
     global _lib, _load_error
     if _lib is not None:
         return _lib
     if _load_error is not None:
         return None
-    if not os.path.exists(_LIB_PATH) and not _build():
-        _load_error = "libffsearch.so missing and build failed"
+    _load_error = _make()
+    if _load_error is not None:
         return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
-        if not all(hasattr(lib, s)
-                   for s in _CORE_SYMBOLS + _OPTIONAL_SYMBOLS):
-            # stale prebuilt library from an older checkout: one rebuild
-            # attempt; on failure keep whatever the current library CAN
-            # do (a failed rebuild restores the old .so — _build)
-            if _build(clean=True):
-                lib = ctypes.CDLL(_LIB_PATH)
-        missing_core = [s for s in _CORE_SYMBOLS if not hasattr(lib, s)]
-        if missing_core:
-            _load_error = (f"libffsearch.so missing core exports "
-                           f"{missing_core} — run `make -C native`")
-            return None
-        for fn in ("ffs_optimize", "ffs_simulate") + tuple(
-                s for s in _OPTIONAL_SYMBOLS if hasattr(lib, s)):
-            getattr(lib, fn).argtypes = [ctypes.c_char_p]
-            getattr(lib, fn).restype = ctypes.c_void_p
-        lib.ffs_free.argtypes = [ctypes.c_void_p]
-        lib.ffs_version.restype = ctypes.c_char_p
-        _lib = lib
-        return _lib
     except OSError as e:  # pragma: no cover
         _load_error = str(e)
         return None
+    for fn in ("ffs_optimize", "ffs_simulate", "ffs_list_rules",
+               "ffs_match_rules"):
+        getattr(lib, fn).argtypes = [ctypes.c_char_p]
+        getattr(lib, fn).restype = ctypes.c_void_p
+    lib.ffs_free.argtypes = [ctypes.c_void_p]
+    lib.ffs_version.restype = ctypes.c_char_p
+    _lib = lib
+    return _lib
 
 
 def _call(fn_name: str, request: Dict[str, Any]) -> Dict[str, Any]:
     lib = get_lib()
     if lib is None:
         raise RuntimeError(f"ffsearch native library unavailable: {_load_error}")
-    if not hasattr(lib, fn_name):
-        raise RuntimeError(
-            f"libffsearch.so has no '{fn_name}' export (stale build and "
-            f"rebuild unavailable) — run `make -C native`")
     fn = getattr(lib, fn_name)
     ptr = fn(json.dumps(request).encode())
     try:

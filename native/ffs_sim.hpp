@@ -343,7 +343,7 @@ class TaskgraphSimulator {
     res.iteration_time = makespan;
     if (measured_) {
       // fixed per-step dispatch/runtime cost measured on the live device
-      // (program launch + host runtime; large on tunneled devices)
+      // (program launch + host runtime)
       auto it = measured_->find("__step_overhead__");
       if (it != measured_->end()) res.iteration_time += it->second;
     }

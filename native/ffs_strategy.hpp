@@ -193,6 +193,11 @@ inline std::string kernel_gate(const Node& n, const std::string& impl,
         return "not_self_attention";
     if (seq % 128) return "seq_not_divisible_by_flash_tile_128";
     if (head_dim % 8) return "head_dim_not_lane_aligned_8";
+    // upper bounds of the kernels' VMEM budget — MAX_FLASH_SEQ /
+    // MAX_FLASH_HEAD_DIM in flexflow_tpu/ops/pallas_kernels.py; past
+    // them the executor runs einsum, so pricing flash would misrank
+    if (seq > 16384) return "seq_exceeds_flash_vmem_budget_16384";
+    if (head_dim > 128) return "head_dim_exceeds_flash_vmem_budget_128";
     // attention-prob dropout has no flash lowering (the kernel never
     // materializes the probabilities to drop) — training forwards take
     // the einsum path, so pricing flash would be a priced-vs-executed

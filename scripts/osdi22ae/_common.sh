@@ -2,7 +2,8 @@
 # Shared runner for the artifact-evaluation-style benchmarks: each model is
 # run twice — Unity-searched strategy vs --only-data-parallel — and prints
 # THROUGHPUT samples/s (protocol of the reference's scripts/osdi22ae/*.sh).
-# FF_TPU_DEVICES=N limits visible devices (analog of -ll:gpu N).
+# The two runs are two processes, one after the other: a chip belongs to
+# one process at a time.
 set -e
 REPO="$(cd "$(dirname "${BASH_SOURCE[0]}")/../.." && pwd)"
 run_pair() {

@@ -132,6 +132,8 @@ def main():
 
     from flexflow_tpu import __version__
     from flexflow_tpu.machine import detect_machine_spec
+    from flexflow_tpu.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from flexflow_tpu.obs.roofline import (finish_aggregates,
                                            format_markdown, roofline_report)
 

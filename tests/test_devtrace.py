@@ -197,6 +197,43 @@ class TestFixtureAttribution:
         assert coll["reduce-scatter"]["per_step_s"] == pytest.approx(50e-6)
 
 
+class TestRealTpuTrace:
+    """A trace a TPU v5e wrote (two BERT-proxy train steps, chip_smoke.py,
+    PR 21), thinned to every 25th op span. Its ``/device:TPU:0`` process
+    has three threads: ``XLA Ops`` and two roll-ups of the same time,
+    ``XLA Modules`` and ``Steps``."""
+
+    TPU_FIXTURE = os.path.join(os.path.dirname(FIXTURE),
+                               "devtrace_tpu_v5e.trace.json.gz")
+
+    @pytest.fixture(scope="class")
+    def trace(self):
+        return load_chrome_trace(self.TPU_FIXTURE)
+
+    def test_only_the_op_lane_counts(self, trace):
+        lanes = {(e["pid"], e["tid"]): e["args"]["name"]
+                 for e in trace["traceEvents"]
+                 if e.get("ph") == "M" and e.get("name") == "thread_name"}
+        spans = [e for e in trace["traceEvents"] if e.get("ph") == "X"]
+        on = lambda lane: [e for e in spans
+                           if lanes.get((e["pid"], e["tid"])) == lane]
+        assert on("Steps") and on("XLA Modules")
+        events = extract_device_events(trace)
+        assert len(events) == len(on("XLA Ops")) == 446
+        assert not any(ev["name"].startswith("jit_") for ev in events)
+
+    def test_device_time_is_the_ops_not_the_step(self, trace):
+        rep = attribution_report([self.TPU_FIXTURE])
+        assert rep["steps"] == 2
+        module_s = max(e["dur"] for e in trace["traceEvents"]
+                       if e.get("ph") == "X"
+                       and e["name"].startswith("jit_train_step")) / 1e6
+        for row in rep["per_step"]:
+            # with the roll-up lanes counted this was the whole window
+            assert 0 < row["compute_s"] <= module_s < row["wall_s"]
+            assert row["idle_s"] > 0
+
+
 class TestRegistryReservoir:
     def test_percentiles_bounded_memory(self):
         from flexflow_tpu.obs.registry import (RESERVOIR_SIZE,

@@ -107,15 +107,24 @@ class TestNativeEnumeration:
         assert i_t == "triad" and i_u == "fused"
         assert t_u < t_t  # one round trip + two launches cheaper
 
-    def test_illegal_flash_rejected_with_named_reason(self):
+    @pytest.mark.parametrize("seq,reason", [
+        (64, "seq_not_divisible_by_flash_tile_128"),
+        # one Q block past the kernels' VMEM budget (MAX_FLASH_SEQ)
+        (16384 + 128, "seq_exceeds_flash_vmem_budget_16384"),
+    ])
+    def test_illegal_flash_rejected_with_named_reason(self, seq, reason):
+        from flexflow_tpu.ops.pallas_kernels import flash_shape_legal
         native = _native()
-        resp = native.native_optimize(_req(_attn_linear_nodes(seq=64)))
+        resp = native.native_optimize(_req(_attn_linear_nodes(seq=seq)))
         ops = {o["name"]: o for o in resp["search_trace"]["ops"]}
         rej = {r["impl"]: r["reason"]
                for r in ops["attn"].get("kernel_rejections") or []}
-        assert rej.get("flash") == "seq_not_divisible_by_flash_tile_128"
+        assert rej.get("flash") == reason
         assert not any("_k:flash" in c["choice"]
                        for c in ops["attn"]["candidates"])
+        # the Python gate refuses what the native gate refuses
+        assert not flash_shape_legal(seq, 16)
+        assert flash_shape_legal(16384, 16)
 
     def test_dropout_attention_rejects_flash(self):
         """Attention-prob dropout has no flash lowering: the training
@@ -291,11 +300,17 @@ class TestExecutorParity:
         return [np.asarray(l) for l in jax.tree_util.tree_leaves(
             (ff.params, ff.opt_state))]
 
-    @pytest.mark.parametrize("opt", ["adam", "sgd", "sgd_momentum"])
-    def test_fused_update_bitwise_on_8way_mesh(self, opt):
+    @pytest.mark.parametrize("opt", ["adam", "adam_pallas", "sgd",
+                                     "sgd_momentum"])
+    def test_fused_update_bitwise_on_8way_mesh(self, opt, monkeypatch):
         """The `_k:fused` one-dispatch update is bit-for-bit with the
-        reference triad over a 3-step seeded run on the 8-way mesh."""
+        reference triad over a 3-step seeded run on the 8-way mesh.
+        `adam_pallas` takes the Pallas kernel (interpret mode), which on
+        a mesh runs per WUS shard under shard_map."""
+        if opt == "adam_pallas":
+            monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
         mk = {"adam": lambda: AdamOptimizer(alpha=1e-2),
+              "adam_pallas": lambda: AdamOptimizer(alpha=1e-2),
               "sgd": lambda: SGDOptimizer(lr=0.01),
               "sgd_momentum": lambda: SGDOptimizer(lr=0.01, momentum=0.9)}
         ref = self._train(_plain_mlp(mk[opt]()))

@@ -232,3 +232,28 @@ def test_duplicate_layer_names_do_not_collide():
     ff.compile(loss_type=LossType.MEAN_SQUARED_ERROR_AVG_REDUCE)
     names = ff.get_layer_names()
     assert len(set(names)) == 2
+
+
+@pytest.mark.parametrize("family", ["adam_mlp", "batchnorm_cnn"])
+def test_train_step_compiles_once(family):
+    """State that starts off the mesh (Adam's step count, BatchNorm's
+    running statistics) comes back from the first step on it; jit took
+    that for a new input type and compiled the whole step a second time."""
+    rs = np.random.RandomState(0)
+    ff = FFModel(FFConfig(batch_size=8))
+    if family == "adam_mlp":
+        x = rs.randn(8, 4).astype(np.float32)
+        y = rs.randn(8, 1).astype(np.float32)
+        t = ff.dense(ff.create_tensor((8, 4)), 1)
+        ff.compile(AdamOptimizer(alpha=0.01),
+                   LossType.MEAN_SQUARED_ERROR_AVG_REDUCE, [])
+    else:
+        x = rs.randn(8, 3, 8, 8).astype(np.float32)
+        y = rs.randint(0, 4, 8).astype(np.int32)
+        t = ff.conv2d(ff.create_tensor((8, 3, 8, 8)), 4, 3, 3, 1, 1, 1, 1)
+        t = ff.dense(ff.flat(ff.batch_norm(t)), 4)
+        ff.compile(SGDOptimizer(lr=0.05),
+                   LossType.SPARSE_CATEGORICAL_CROSSENTROPY, [])
+    for _ in range(3):
+        ff.fit(x, y, epochs=1, verbose=False)
+    assert ff.executor._jit_train._cache_size() == 1

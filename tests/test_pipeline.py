@@ -539,19 +539,8 @@ def _pipe_variant(tag):
                LossType.MEAN_SQUARED_ERROR_AVG_REDUCE, [],
                mesh=make_mesh(int(np.prod(list(mesh_axes.values()))),
                               mesh_axes))
-    if tag == "single":
-        # snapshot the pristine init weights BEFORE training: the pipe
-        # variants start from these (their executor consumes the init
-        # rng in a different order, so trajectories would not compare)
-        _parity_cache["__init_weights__"] = {
-            lname: {pname: ff.get_parameter(lname, pname)
-                    for pname in sub}
-            for lname, sub in ff.params.items()}
-    else:
-        _pipe_variant("single")
-        for lname, sub in _parity_cache["__init_weights__"].items():
-            for pname, w in sub.items():
-                ff.set_parameter(lname, w, pname)
+    # no weight copying between variants: every executor draws the init
+    # keys in graph order, so one seed gives all of them the same weights
     rs = np.random.RandomState(0)
     x = rs.randn(16, 8, 32).astype(np.float32)
     y = rs.randn(16, 8, 1).astype(np.float32)

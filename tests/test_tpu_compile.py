@@ -1,0 +1,291 @@
+"""Deviceless compiles for a described TPU v5e 2x2: what the chip's
+compiler would refuse is refused here, at no chip time.
+
+The TPU compiler ships with the installation and compiles for a topology
+that is described, not attached (`jax.experimental.topologies`). Nothing
+runs, so these tests say nothing about results or times — they catch
+what interpret-mode Pallas on the CPU cannot: a kernel over the VMEM
+budget, a kernel the SPMD partitioner cannot split, a step that does not
+fit HBM. Code that asks the live backend which platform it is on
+(`pallas_mode`) still sees the CPU, so the tests steer it to its TPU
+branch themselves.
+
+Tier-1 keeps the kernels of the main path at real widths plus one whole
+train step at depth 2; the full-depth steps of every `chip_smoke.py` arm
+(30-90 s each) are `-m slow` and are the rehearsal to run before a
+four-chip call.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from flexflow_tpu import AdamOptimizer, FFConfig, LossType, MetricsType
+from flexflow_tpu.machine import MachineSpec, make_mesh
+from flexflow_tpu.models import TransformerConfig, create_transformer
+from flexflow_tpu.obs.inspect import pallas_kernel_count
+from flexflow_tpu.ops import pallas_kernels as pk
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compilation_cache():
+    """A deviceless executable can be written to the persistent cache
+    but not read back without a chip; keep the cache out of the way."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Take the kernels' TPU branch although the live backend is CPU."""
+    monkeypatch.delenv("FLEXFLOW_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(pk, "pallas_mode", lambda: "tpu")
+
+
+def described_mesh(topo, axes):
+    n = int(np.prod(list(axes.values())))
+    devs = np.array(topo.devices[:n]).reshape(tuple(axes.values()))
+    return Mesh(devs, tuple(axes))
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash_grads(q, k, v):
+    def loss(q, k, v):
+        return pk._flash(q, k, v, False, False).astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _flash_lse_grads(q, k, v):
+    def loss(q, k, v):
+        o, lse = pk.flash_attention_lse(q, k, v, False, False)
+        return o.sum() + lse.sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+class TestFlashKernels:
+    def test_bert_shape_fwd_bwd(self, topo):
+        q = jax.ShapeDtypeStruct((128, 512, 64), jnp.bfloat16,
+                                 sharding=SingleDeviceSharding(
+                                     topo.devices[0]))
+        assert pallas_kernel_count(_compile(_flash_grads, q, q, q)) == 2
+
+    @pytest.mark.parametrize("grads,dtype", [
+        # the ring variant (f32 output, lse gradient) needs the most VMEM
+        (_flash_lse_grads, jnp.bfloat16),
+        pytest.param(_flash_grads, jnp.bfloat16, marks=pytest.mark.slow),
+        pytest.param(_flash_grads, jnp.float32, marks=pytest.mark.slow),
+        pytest.param(_flash_lse_grads, jnp.float32,
+                     marks=pytest.mark.slow),
+    ])
+    def test_longest_admitted_shape_compiles(self, topo, grads, dtype):
+        """Forward and K-blocked backward at the gate's upper bounds."""
+        q = jax.ShapeDtypeStruct(
+            (1, pk.MAX_FLASH_SEQ, pk.MAX_FLASH_HEAD_DIM), dtype,
+            sharding=SingleDeviceSharding(topo.devices[0]))
+        assert pallas_kernel_count(_compile(grads, q, q, q)) == 2
+
+    def test_lowering_ignores_the_call_site_once_the_cache_is_configured(
+            self, topo):
+        """The persistent cache keys on the kernel's serialized MLIR; with
+        Python tracebacks in its locations, the same step lowered from two
+        lines never hits."""
+        from flexflow_tpu.utils.compile_cache import configure_compile_cache
+        names = ("jax_compilation_cache_dir",
+                 "jax_include_full_tracebacks_in_locations")
+        prev = {n: getattr(jax.config, n) for n in names}
+        q = jax.ShapeDtypeStruct((16, 512, 64), jnp.bfloat16,
+                                 sharding=SingleDeviceSharding(
+                                     topo.devices[0]))
+        try:
+            configure_compile_cache()
+            here = jax.jit(_flash_grads).lower(q, q, q).as_text()
+            jax.clear_caches()
+            there = jax.jit(_flash_grads).lower(q, q, q).as_text()
+        finally:
+            for n, v in prev.items():
+                jax.config.update(n, v)
+        assert here == there
+
+    def test_gate_refuses_one_past_each_bound(self, on_tpu):
+        ok = pk.flash_attention_available
+        assert ok(pk.MAX_FLASH_SEQ, pk.MAX_FLASH_HEAD_DIM)
+        assert not ok(pk.MAX_FLASH_SEQ + pk.BLK_Q, 64)
+        assert not ok(512, pk.MAX_FLASH_HEAD_DIM + 8)
+
+    def test_ring_attention_4way(self, topo, on_tpu):
+        from flexflow_tpu.parallel.ring_attention import ring_attention
+        mesh = described_mesh(topo, {"seq": 4})
+        q = jax.ShapeDtypeStruct(
+            (2, 4, 2048, 64), jnp.bfloat16,
+            sharding=NamedSharding(mesh, P(None, None, "seq", None)))
+
+        def grads(q, k, v):
+            def loss(q, k, v):
+                o = ring_attention(q, k, v, mesh, seq_axis="seq",
+                                   batch_axis=None)
+                return o.astype(jnp.float32).sum()
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        hlo = _compile(grads, q, q, q)
+        assert pallas_kernel_count(hlo) >= 2
+        assert "collective-permute" in hlo
+
+
+class TestFusedAdam:
+    KW = dict(beta1=0.9, beta2=0.999, eps=1e-8, wd=1e-4)
+
+    def _args(self, sharding):
+        f32 = jax.ShapeDtypeStruct((1024, 4096), jnp.float32,
+                                   sharding=sharding)
+        bf16 = jax.ShapeDtypeStruct((1024, 4096), jnp.bfloat16,
+                                    sharding=sharding)
+        return f32, bf16, bf16, bf16  # p, g, m, v (bf16 Adam state)
+
+    def test_leaf_alone(self, topo, on_tpu):
+        from flexflow_tpu.ops.fused_update import fused_adam_leaf
+        fn = lambda p, g, m, v: fused_adam_leaf(p, g, m, v,
+                                                jnp.float32(1e-4), **self.KW)
+        hlo = _compile(fn, *self._args(SingleDeviceSharding(topo.devices[0])))
+        assert pallas_kernel_count(hlo) == 1
+
+    def test_leaf_under_wus_spec_on_four_devices(self, topo, on_tpu):
+        from flexflow_tpu.ops.fused_update import fused_adam_leaf
+        mesh = described_mesh(topo, {"data": 4})
+        spec = P("data", None)
+        fn = lambda p, g, m, v: fused_adam_leaf(
+            p, g, m, v, jnp.float32(1e-4), mesh=mesh, spec=spec, **self.KW)
+        hlo = _compile(fn, *self._args(NamedSharding(mesh, spec)))
+        assert pallas_kernel_count(hlo) == 1
+        assert "all-gather" not in hlo and "all-reduce" not in hlo
+
+
+# ---------------------------------------------------------------------------
+# whole train steps
+
+
+def build_bert(num_layers, batch, mesh_axes=None, chips=4, seq_parallel=None,
+               **cfg_kw):
+    """BERT-proxy at full width through the normal entry points, placed
+    on virtual CPU devices: `compile()` builds its mesh from
+    `jax.devices()` and puts parameters there. The machine is described
+    to the search as v5e so strategy, dtype and layout are the chip's."""
+    tc = TransformerConfig(num_layers=num_layers, batch_size=batch,
+                           seq_parallel=seq_parallel)
+    cfg = FFConfig(batch_size=batch, workers_per_node=chips, **cfg_kw)
+    ff = create_transformer(tc, cfg)
+    mesh = (make_mesh(int(np.prod(list(mesh_axes.values()))), mesh_axes)
+            if mesh_axes else None)
+    ff.compile(AdamOptimizer(alpha=1e-4, state_dtype=jnp.bfloat16),
+               LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
+               [MetricsType.MEAN_SQUARED_ERROR], mesh=mesh,
+               machine_spec=MachineSpec("tpu-v5e", chips_per_slice=chips))
+    return ff
+
+
+def compile_step_for(ff, topo):
+    """Compile `ff`'s train step for the described chips: the executor's
+    mesh is swapped for the same axes over `topo`'s devices and every
+    argument becomes a shape carrying its live spec on that mesh."""
+    ex = ff.executor
+    axes = dict(zip(ex.mesh.axis_names, ex.mesh.devices.shape))
+    mesh = described_mesh(topo, axes)
+
+    def abstract(a):
+        spec = getattr(a.sharding, "spec", P())  # scalars sit on one device
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    params, opt_state, state = jax.tree.map(
+        abstract, (ff.params, ff.opt_state, ff.state))
+    x = ff.input_tensors[0].shape
+    inputs = {ex.input_names[0]: jax.ShapeDtypeStruct(
+        x, ex.compute_dtype,
+        sharding=NamedSharding(mesh, ex.batch_sharding().spec))}
+    labels = jax.ShapeDtypeStruct(
+        x[:-1] + (1,), jnp.float32,
+        sharding=NamedSharding(mesh, ex.label_sharding().spec))
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh, P()))
+    live, ex.mesh = ex.mesh, mesh
+    try:
+        return jax.jit(ex._train_step_fn(), donate_argnums=(0, 1, 2)).lower(
+            params, opt_state, state, inputs, labels, rng).compile()
+    finally:
+        ex.mesh = live
+
+
+def _choices(ff):
+    return [getattr(s, "choice", None) or "" for s in ff.strategy.values()]
+
+
+def test_wus_step_with_fused_update_compiles_for_four_chips(topo, on_tpu):
+    """The strategy the search picks for four chips: {data:4}, WUS with
+    overlap, flash attention and the fused optimizer update. Before the
+    fused update ran under shard_map this raised "Mosaic kernels cannot
+    be automatically partitioned"."""
+    ff = build_bert(2, 32, search_budget=30, enable_parameter_parallel=True)
+    assert dict(zip(ff.mesh.axis_names, ff.mesh.devices.shape)) == {"data": 4}
+    assert ff.wus_enabled and ff.executor.fused_update_ops
+    flash = sum("_k:flash" in c for c in _choices(ff))
+    assert flash >= 1
+    hlo = compile_step_for(ff, topo).as_text()
+    # a forward and a backward per flash op, plus at least one update
+    # kernel of the fused ops
+    assert pallas_kernel_count(hlo) > 2 * flash
+
+
+# arm -> fewest kernels its step holds: a flash forward and backward per
+# layer; the searched four-chip step adds fused updates; the pipeline
+# (searched at batch 8) runs one block per tick inside a loop; the ring's
+# 256-row shards are below MIN_SEQ_FOR_FLASH and take the einsum body
+ARM_KERNELS = {"one_chip": 24, "one_chip_searched": 24, "dp": 24,
+               "searched": 25, "searched_b8": 2, "hybrid": 24, "ring": 0}
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("arm", list(ARM_KERNELS))
+def test_full_depth_step_of_each_chip_smoke_arm(topo, on_tpu, arm):
+    build = {
+        "one_chip": lambda: build_bert(12, 8, chips=1),
+        "one_chip_searched": lambda: build_bert(12, 8, chips=1,
+                                                search_budget=30),
+        "dp": lambda: build_bert(12, 32, only_data_parallel=True),
+        "searched": lambda: build_bert(12, 32, search_budget=30,
+                                       enable_parameter_parallel=True),
+        "searched_b8": lambda: build_bert(12, 8, search_budget=30,
+                                          enable_parameter_parallel=True),
+        "hybrid": lambda: build_bert(12, 32, {"data": 2, "model": 2},
+                                     enable_parameter_parallel=True),
+        "ring": lambda: build_bert(12, 32, {"data": 2, "seq": 2},
+                                   seq_parallel="seq"),
+    }
+    ff = build[arm]()
+    compiled = compile_step_for(ff, topo)
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
+    assert pallas_kernel_count(compiled.as_text()) >= ARM_KERNELS[arm]

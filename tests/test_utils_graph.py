@@ -52,7 +52,10 @@ class TestGraphAlgorithms:
 
 
 class TestDriver:
-    def test_launcher_parses_flags_and_runs_script(self, tmp_path, capsys):
+    def test_launcher_parses_flags_and_runs_script(self, tmp_path, capsys,
+                                                   monkeypatch):
+        # a cache placed from outside: the launcher then sets none in code
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         script = tmp_path / "prog.py"
         script.write_text(
             "import sys\n"
