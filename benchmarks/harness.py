@@ -1,5 +1,6 @@
 """One run of one cell: set-up, the output check's system side, the
-measured window, the traced extras, the reference, the result line.
+measured window, the traced extras (`--trace 1`) or the traced tail
+(`--trace 2`), the reference, the result line.
 
 `run.py` is the only entry on the chip. `rehearsal` (a dict of size
 overrides) exists for the tests under tests/chipbench, which run a cell
@@ -19,9 +20,10 @@ import sys
 import time
 
 from benchmarks import manifest as mf
+from benchmarks.session_reduce import FENCED, OUT_DIR, SESSION
 
 ROOT = mf.ROOT
-OUT_DIR = "chipbench_out"     # git-ignored; traces and reductions
+DISCARD = "session_discard"   # beside SESSION and FENCED under a cell's output
 
 
 def emit(**kw):
@@ -282,10 +284,96 @@ def program_dispatch_ms(ff, xs, y, out_dir):
 
 
 # ---------------------------------------------------------------------------
+# the traced tail (only with --trace 2, after the window has closed)
+
+TAIL_SECONDS = 3.0   # of part-A traffic under the session; at least 2 epochs
+
+
+def traced_tail(ff, xs, y, s, out_dir):
+    """What `--trace 2` adds once every end-to-end number is taken, through
+    the program's own control (`flexflow_tpu.obs.start_trace/stop_trace`):
+    a session that is started and stopped at once and thrown away, as the
+    form of a `--trace 2` run asks, so that the profiler's first start in
+    the process falls into no number (0.3 s; PERF.md section 6 has the tail
+    without it); (a) a session with the profiler over about TAIL_SECONDS of
+    back-to-back part-A epochs, nothing fenced; (b) a session without the
+    profiler over one epoch of part-B steps, each fenced here, whose
+    `dispatch` spans are `executor.dispatch_ms`. The spans stay under `out_dir` for the readers
+    (`session_reduce.find`); the profile is reduced here and deleted.
+    Returns (devices, part A's session, dispatch milliseconds, a record for
+    the `trace` line)."""
+    import jax
+
+    from benchmarks import session_reduce as sr
+    from benchmarks import trace_reduce as tr
+    from flexflow_tpu import obs
+
+    t_tail = time.perf_counter()
+    dirs = {k: os.path.join(out_dir, k) for k in (SESSION, FENCED, DISCARD)}
+    obs.start_trace(dirs[DISCARD], device=True)
+    obs.stop_trace()
+    shutil.rmtree(dirs[DISCARD], ignore_errors=True)
+    first_start_s = time.perf_counter() - t_tail
+
+    t0 = time.perf_counter()
+    obs.start_trace(dirs[SESSION], device=True)
+    start_s = time.perf_counter() - t0
+    try:
+        epochs, t0 = 0, time.perf_counter()
+        while epochs < 2 or time.perf_counter() - t0 < TAIL_SECONDS:
+            ff.fit(xs, y, epochs=1, verbose=False)
+            epochs += 1
+        jax.block_until_ready(ff.params)
+        traced_s = time.perf_counter() - t0
+    finally:
+        t0 = time.perf_counter()
+        paths = obs.stop_trace()
+        stop_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    devices, xplane_bytes = [], 0
+    if paths["xplane"]:
+        xplane_bytes = os.path.getsize(paths["xplane"])
+        devices = tr.load_xplane(paths["xplane"])
+    for d in glob.glob(os.path.join(dirs[SESSION], "*.jaxprof")):
+        shutil.rmtree(d, ignore_errors=True)
+    session = sr.load(dirs[SESSION])
+    reduce_s = time.perf_counter() - t0
+
+    batch = s["batch"]
+    obs.start_trace(dirs[FENCED], device=False)
+    try:
+        for k in range(s["steps_per_epoch"]):
+            sl = slice(k * batch, (k + 1) * batch)
+            ff.fit([x[sl] for x in xs], y[sl], epochs=1, verbose=False)
+            jax.block_until_ready(ff.params)
+    finally:
+        obs.stop_trace()
+    dispatch_ms = sr.durations_ms(sr.load(dirs[FENCED]), "dispatch")
+
+    # per traced step, the device program's start less its dispatch span's
+    leads = [v for d in devices
+             for v in sr.dispatch_leads_s(d, session.spans) or ()]
+    record = dict(
+        tail_s=time.perf_counter() - t_tail, first_start_s=first_start_s,
+        start_s=start_s, traced_s=traced_s, traced_epochs=epochs, stop_s=stop_s,
+        reduce_s=reduce_s, xplane_bytes=xplane_bytes, spans=len(session.spans),
+        dispatch_lead_us_min=1e6 * min(leads) if leads else None,
+        dispatch_lead_us_max=1e6 * max(leads) if leads else None,
+        **{k: session.header.get(k) for k in (
+            "clock_shift_us", "clock_tie_spread_us", "clock_tie_markers",
+            "compile_phases", "set_parameter_s")})
+    return devices, session, dispatch_ms, record
+
+
+# ---------------------------------------------------------------------------
 
 
 def run_cell(name, seed, seconds, trace, *, t_start, root=ROOT,
              rehearsal=None):
+    """`trace`: 0 the window alone; 1 the window, then the traced extras,
+    and a line of per-layer metrics only; 2 exactly what 0 does until the
+    window has closed, then the traced tail, and a line with both kinds."""
+    trace = int(trace)
     manifest = mf.load_manifest(root)
     cell, config, traffic = mf.find_cell(manifest, name, root)
     chips = cell["chips"]
@@ -311,7 +399,8 @@ def run_cell(name, seed, seconds, trace, *, t_start, root=ROOT,
     events = CompileEvents()
     kind = devs[0].device_kind
     peaks = mf.peaks_for(kind, root) if on_tpu else None
-    emit(phase="start", cell=name, seed=seed, seconds=seconds, trace=trace,
+    emit(phase="start", cell=name, seed=seed, seconds=seconds,
+         trace=trace if trace == 2 else bool(trace),
          jax=jax.__version__, jaxlib=jaxlib.__version__,
          devices=[str(d) for d in devs], device_kind=kind, chips=chips,
          cache_dir=cache_dir, rehearsal=rehearsal is not None)
@@ -425,14 +514,29 @@ def run_cell(name, seed, seconds, trace, *, t_start, root=ROOT,
                     chips=chips, batch=batch, sizes=s,
                     train_flops_per_sample=family.train_flops_per_sample(s),
                     peaks=peaks, dispatch_ms=[])
-    devices = []
+    devices, session, run_peak = [], None, peak
     if trace:
         from benchmarks import trace_reduce as tr
         out_dir = os.path.join(root, OUT_DIR, name)
         os.makedirs(out_dir, exist_ok=True)
+        # the readers of the session's metrics look here on their own: what
+        # an earlier run left must not be read as this run's
+        for k in (SESSION, FENCED, DISCARD):
+            shutil.rmtree(os.path.join(out_dir, k), ignore_errors=True)
+    if trace == 1:
         devices = profiled_epoch(ff, xs, y, out_dir)
         emit(phase="trace", **tr.describe(devices))
         counters["dispatch_ms"] = program_dispatch_ms(ff, xs, y, out_dir)
+    elif trace == 2:
+        devices, session, counters["dispatch_ms"], record = traced_tail(
+            ff, xs, y, s, out_dir)
+        # the line's `device.memory_peak_bytes` is the whole run's, tail
+        # included, as the form of a `--trace 2` line asks; every value
+        # under `metrics` stays the window's own
+        memory_tail = memory_reading(chips)
+        emit(phase="trace", **tr.describe(devices), **record,
+             memory=memory_tail)
+        run_peak = max(peak, memory_tail["peak_bytes"])
 
     # ---- the reference, with the device to itself ----
     release(ff)
@@ -454,14 +558,14 @@ def run_cell(name, seed, seconds, trace, *, t_start, root=ROOT,
     values = {"throughput": throughput, "step_ms_p95": step_p95,
               "peak_hbm_gb": peak / 1e9, "setup_s": setup_s}
     device = {"platform": devs[0].platform, "kind": kind, "count": len(devs),
-              "memory_peak_bytes": peak}
+              "memory_peak_bytes": run_peak}
     result = {"correct": bool(correct), "attempted": epochs + len(step_ms),
               "failed": 0, "metrics": {}, "device": device}
-    if not trace:
+    if trace != 1:
         for m in mf.metrics_of(manifest, "end_to_end", name):
             result["metrics"][m["name"]] = {"value": values[m["name"]],
                                             "unit": m["unit"]}
-    else:
+    if trace:
         ctx = dict(devices=devices, counters=counters, cell=cell,
                    config=config, traffic=traffic, family=family)
         for m in mf.metrics_of(manifest, "per_layer", name):
@@ -472,9 +576,18 @@ def run_cell(name, seed, seconds, trace, *, t_start, root=ROOT,
         bw = tr.mean_over_devices(devices, tr.busy_and_window)
         if bw is not None:
             device["busy_s"], device["window_s"] = bw
-        result["breakdown"] = {
-            "device_ops": tr.top_device_ops(devices),
-            "idle_gaps": tr.top_idle_gaps(devices)}
+        result["breakdown"] = {"device_ops": tr.top_device_ops(devices)}
+        if session is None:
+            result["breakdown"]["idle_gaps"] = tr.top_idle_gaps(devices)
+        else:
+            # the tail's gaps carry the name of the program span that was
+            # open on the host, on the session's shared clock
+            from benchmarks import session_reduce as sr
+            shares = sr.idle_shares_pct(devices, session.spans) or {}
+            result["breakdown"].update(
+                idle_gaps=sr.labelled_idle_gaps(devices, session.spans),
+                idle_shares_pct=sorted(shares.items(),
+                                       key=lambda kv: -kv[1]))
         reduction = os.path.join(root, OUT_DIR, name, f"trace_{seed}.json")
         with open(reduction, "w") as f:
             json.dump(dict(result=result, dispatch_ms=counters["dispatch_ms"]),

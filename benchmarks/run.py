@@ -1,13 +1,16 @@
 #!/usr/bin/env python3
 """The benchmark's command: one run of one cell on the chip.
 
-    python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 Every line of standard output is one JSON object; the last is the result
 (`correct`, `attempted`, `failed`, `metrics`, `device`, and with
-`--trace 1` `breakdown`). It refuses to run without a TPU, with fewer
-devices than the cell's chips, or with FLEXFLOW_TPU_PALLAS set: there is
-no CPU fallback. One process; nothing outlives it.
+`--trace 1` or `2` `breakdown`). `--trace 2` is a `--trace 0` run that,
+once its window has closed, traces a few seconds more and prints the
+end-to-end and the per-layer metrics in one line. It refuses to run
+without a TPU, with fewer devices than the cell's chips, or with
+FLEXFLOW_TPU_PALLAS set: there is no CPU fallback. One process; nothing
+outlives it.
 """
 
 import time
@@ -27,7 +30,7 @@ def main():
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     args = ap.parse_args()
     if "FLEXFLOW_TPU_PALLAS" in os.environ:
         raise SystemExit("benchmark: unset FLEXFLOW_TPU_PALLAS; the kernels "
@@ -36,7 +39,7 @@ def main():
         raise SystemExit("benchmark: the program (flexflow_tpu/) is not in "
                          "this checkout")
     from benchmarks.harness import run_cell
-    run_cell(args.workload, args.seed, args.seconds, bool(args.trace),
+    run_cell(args.workload, args.seed, args.seconds, args.trace,
              t_start=T_START)
 
 

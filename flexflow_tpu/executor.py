@@ -627,9 +627,13 @@ class GraphExecutor:
                 values, new_state, aux = self.run_graph(p, state, inputs, ctx,
                                                         nodes=train_nodes)
                 logits = values[self.final_ref]
-                loss = self._loss_value(logits, labels)
-                for a in aux:
-                    loss = loss + a
+                # named for the device trace: an op's `op_name` holds
+                # `loss/` here and `optimizer_update/` below, beside the
+                # `jvp`/`transpose` that tell forward from backward
+                with jax.named_scope("loss"):
+                    loss = self._loss_value(logits, labels)
+                    for a in aux:
+                        loss = loss + a
                 return loss, (logits, new_state)
 
             (loss, (logits, new_state)), grads = jax.value_and_grad(
@@ -641,17 +645,19 @@ class GraphExecutor:
             # reduce-scatter: each chip receives only the gradient shard
             # whose master-param/moment shard it owns.
             grads = self._wus_shard(grads)
-            new_params, new_opt_state = self._optimizer_update(
-                grads, opt_state, params
-            )
-            new_params = self._wus_shard(new_params)
-            if self.use_master_copy:
-                # next step's bf16 working copy, fused into the update loop
-                # (one extra bf16 write instead of a separate cast pass;
-                # under WUS the compute-spec constraint is the all-gather
-                # that rebuilds the replicated copy from the shards)
-                new_state[COMPUTE_PARAMS_KEY] = self._constrain_compute(
-                    jax.tree.map(self._cast_leaf, new_params))
+            with jax.named_scope("optimizer_update"):
+                new_params, new_opt_state = self._optimizer_update(
+                    grads, opt_state, params
+                )
+                new_params = self._wus_shard(new_params)
+                if self.use_master_copy:
+                    # next step's bf16 working copy, fused into the update
+                    # loop (one extra bf16 write instead of a separate cast
+                    # pass; under WUS the compute-spec constraint is the
+                    # all-gather that rebuilds the replicated copy from the
+                    # shards)
+                    new_state[COMPUTE_PARAMS_KEY] = self._constrain_compute(
+                        jax.tree.map(self._cast_leaf, new_params))
             metric_vals = self.metrics.compute(logits, labels)
             return new_params, new_opt_state, new_state, loss, metric_vals
 

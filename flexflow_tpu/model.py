@@ -60,6 +60,9 @@ class FFModel:
         self._rng = jax.random.PRNGKey(self.config.seed)
         self._iter = 0
         self._metrics_acc = PerfMetrics()
+        # host seconds: compile()'s phases, and set_parameter since then
+        self.compile_phases: Optional[Dict[str, float]] = None
+        self.set_parameter_s = 0.0
         # parity loop state (forward/backward/update protocol)
         self._current_batch = None
         self._pending = None
@@ -508,6 +511,7 @@ class FFModel:
         ERROR-severity diagnostic. None defers to ``FFConfig.lint``
         (the ``--lint`` flag); the report lands in ``self.lint_report``.
         """
+        t_compile = time.perf_counter()
         cfg = self.config
         cfg.computation_mode = comp_mode
         self.optimizer = optimizer or SGDOptimizer(
@@ -949,6 +953,7 @@ class FFModel:
                 nodes, input_names, final_ref, self.mesh, loss_type,
                 self.metrics, self.optimizer, **exec_kwargs)
         self.executor.comp_mode = comp_mode
+        t_built = time.perf_counter()
         # --- fflint static verification (flexflow_tpu/analysis) ----------
         # runs BEFORE parameter allocation so an illegal strategy fails
         # fast instead of deep inside jit
@@ -968,6 +973,7 @@ class FFModel:
                     f"fflint: {len(self.lint_report.errors)} error-"
                     f"severity diagnostic(s) — see report above "
                     f"(compile with lint='warn' to proceed anyway)")
+        t_linted = time.perf_counter()
         self._rng, sub = jax.random.split(self._rng)
         self.params, self.state = self.executor.init_params_and_state(sub)
         # INFERENCE (ffconst.h:46 CompMode): forward-only executable — no
@@ -980,11 +986,24 @@ class FFModel:
         # mesh-replicated array; jit takes that for a new input type and
         # answers with a second trace and a second full compile of the
         # step (34 s for the BERT-proxy on a v5e).
+        t_init = time.perf_counter()
         replicated = NamedSharding(self.mesh, P())
         self.state, self.opt_state = jax.tree.map(
             lambda a: a if isinstance(a.sharding, NamedSharding)
             else jax.device_put(a, replicated),
             (self.state, self.opt_state))
+        # host seconds of this call by phase (nothing is fenced: a phase
+        # holds its programs' compile and dispatch, not the device's run).
+        # `executor_build_s` is everything up to the built executor less
+        # the native search: materialization, strategy, layout, executor
+        search_s = self.search_seconds or 0.0
+        self.compile_phases = dict(
+            search_s=search_s,
+            executor_build_s=t_built - t_compile - search_s,
+            lint_s=t_linted - t_built,
+            param_init_s=t_init - t_linted,
+            state_placement_s=time.perf_counter() - t_init)
+        self.set_parameter_s = 0.0
         self._iter = 0
         self._seq_execs: Dict[int, Any] = {}  # seq-length bucket executors
         self._declared_seq_cache = -1  # lazily derived (-1 = not yet)
@@ -1032,12 +1051,16 @@ class FFModel:
 
     # ======================= train / eval loops ============================
     def _make_tracer(self, trace_dir, run_name: str):
-        """Tracer for one fit/evaluate call: the explicit ``trace_dir``
-        argument wins over ``Config --trace-dir``; both unset returns the
-        shared no-op (flexflow_tpu/obs — zero overhead path)."""
-        from flexflow_tpu.obs import make_tracer, model_context
-        tracer = make_tracer(trace_dir or self.config.trace_dir,
-                             run_name=run_name)
+        """Tracer for one fit/evaluate call. An open trace session
+        (``obs.start_trace``) wins: the call records into the session's
+        tracer and makes none of its own. Otherwise the explicit
+        ``trace_dir`` argument wins over ``Config --trace-dir``; both
+        unset returns the shared no-op (flexflow_tpu/obs — zero overhead
+        path)."""
+        from flexflow_tpu.obs import (make_tracer, model_context,
+                                      session_tracer)
+        tracer = session_tracer() or make_tracer(
+            trace_dir or self.config.trace_dir, run_name=run_name)
         if tracer.active:
             tracer.set_meta(**model_context(self))
         return tracer
@@ -1045,9 +1068,12 @@ class FFModel:
     def _make_capture(self, tracer, profile_steps):
         """Windowed jax.profiler device-trace capture (obs/devtrace):
         the explicit ``profile_steps`` argument wins over ``Config
-        --profile-steps``; both unset (or no active tracer) returns the
-        shared no-op capture."""
-        from flexflow_tpu.obs import make_capture
+        --profile-steps``; both unset (or no active tracer, or a tracer
+        that belongs to an open session, which runs the profiler itself)
+        returns the shared no-op capture."""
+        from flexflow_tpu.obs import NULL_CAPTURE, make_capture
+        if tracer.active and not tracer.fence:
+            return NULL_CAPTURE
         return make_capture(tracer,
                             profile_steps or self.config.profile_steps)
 
@@ -1067,8 +1093,11 @@ class FFModel:
         counters: the summary/drift reports need a fresh lower+compile
         of the step (AOT inspection cannot reuse the executor's cached
         executable), which is minutes of XLA on TPU and — after an OOM
-        — likely to fail again; the trace alone is the diagnosis."""
-        if not tracer.active:
+        — likely to fail again; the trace alone is the diagnosis.
+
+        A session's tracer (``obs.start_trace``) is not finalized here:
+        ``obs.stop_trace`` writes it, and writes nothing else."""
+        if not tracer.fence:
             return
         import os
         import sys
@@ -1211,15 +1240,18 @@ class FFModel:
         accumulation (one host sync per epoch), ELAPSED TIME / THROUGHPUT
         report. ``next_batch(epoch, b)`` -> (inputs dict, labels).
 
-        With an active tracer each step is a span with dispatch /
-        device_wait phases (device_wait fences the step on the loss — an
-        observer effect tracing accepts so per-step times mean device
-        time, not async dispatch time) plus whatever phases the
+        With an active tracer each step is a span with rng_split /
+        dispatch / metric_accumulate phases plus whatever phases the
         ``next_batch`` closure records (fit: sibling data_load /
         device_put spans — disjoint, so phase totals sum to step time
         instead of double-booking H2D under data_load), and each epoch
         ends with a metrics_sync span (the one host fetch of the
-        accumulated metrics).
+        accumulated metrics). A per-call tracer (``fit(trace_dir=...)``,
+        ``tracer.fence``) also ends every step in a device_wait phase
+        that fences it on the loss — an observer effect that form
+        accepts so per-step times mean device time, not async dispatch
+        time. A session's tracer (``obs.start_trace``) fences nothing:
+        the traced loop is the untraced one.
 
         ``ckpt_mgr`` (a flexflow_tpu.ckpt.CheckpointManager) saves every
         ``checkpoint_every`` iterations (blocking only for the local
@@ -1245,6 +1277,7 @@ class FFModel:
         devtrace = devtrace or NULL_CAPTURE
         train_step = self.executor.make_train_step()
         self._refresh_compute_params()
+        tracer.setup_done()
         start = time.time()
         loss = None
         executed = 0
@@ -1272,16 +1305,19 @@ class FFModel:
                 # reservoir observes (ISSUE 8 satellite: the 17 s p99)
                 with devtrace.step(step_idx), tracer.step():
                     inputs, labels = next_batch(epoch, b)
-                    self._rng, sub = jax.random.split(self._rng)
+                    with tracer.phase("rng_split"):
+                        self._rng, sub = jax.random.split(self._rng)
                     with tracer.phase("dispatch"):
                         (self.params, self.opt_state, self.state, loss,
                          mvals) = train_step(
                             self.params, self.opt_state, self.state,
                             inputs, labels, sub)
                     self._iter += 1
-                    mtotals = mvals if mtotals is None else jax.tree.map(
-                        jnp.add, mtotals, mvals)
-                    if tracer.active or devtrace.active:
+                    with tracer.phase("metric_accumulate"):
+                        mtotals = (mvals if mtotals is None
+                                   else jax.tree.map(jnp.add, mtotals,
+                                                     mvals))
+                    if tracer.fence:
                         with tracer.phase("device_wait"):
                             jax.block_until_ready(loss)
                 executed += 1
@@ -1334,6 +1370,7 @@ class FFModel:
             # final save + durability barrier + goodput gauge: the run
             # must not be reported done while a commit is still in flight
             ckpt_mgr.finalize(elapsed_s=elapsed, steps=executed)
+        tracer.annotate(steps=executed)
         # throughput counts only the samples this run actually processed
         # (a resume skips the checkpoint-covered step slots in ~0 time)
         thr = bs * executed / elapsed
@@ -1392,7 +1429,10 @@ class FFModel:
             with tracer.phase("data_load"):
                 xs_np = [xx[sl] for xx in xs]
                 y_np = y[sl]
-            with tracer.phase("device_put"):
+            with tracer.phase("device_put") as span:
+                if span is not None:   # None on the no-op tracer
+                    span.args = dict(bytes=sum(a.nbytes for a in xs_np)
+                                     + y_np.nbytes)
                 return (self._stage_inputs(xs_np),
                         self._shard_batch(y_np))
 
@@ -1400,19 +1440,23 @@ class FFModel:
         # preemption) — or at resume, against a missing/corrupt
         # checkpoint — still flushes its trace: that trace is the
         # diagnosis
-        run_name = tracer.run_name if tracer.active else "fit"
-        health = self._make_health(tracer, devtrace, run_name=run_name)
+        run_name = tracer.run_name if tracer.fence else "fit"
+        health = None
         try:
-            if health is not None:
-                health.install()
-            ckpt_mgr, start_step = self._make_checkpointer(
-                checkpoint_dir, checkpoint_every, resume,
-                run_name=run_name,
-                heartbeat=health.heartbeat if health is not None else None)
-            out = self._run_epochs(next_batch, num_batches, bs, epochs,
-                                   verbose, tracer=tracer,
-                                   devtrace=devtrace, ckpt_mgr=ckpt_mgr,
-                                   start_step=start_step, health=health)
+            with tracer.call("fit", epochs=epochs):
+                health = self._make_health(tracer, devtrace,
+                                           run_name=run_name)
+                if health is not None:
+                    health.install()
+                ckpt_mgr, start_step = self._make_checkpointer(
+                    checkpoint_dir, checkpoint_every, resume,
+                    run_name=run_name,
+                    heartbeat=(health.heartbeat if health is not None
+                               else None))
+                out = self._run_epochs(next_batch, num_batches, bs, epochs,
+                                       verbose, tracer=tracer,
+                                       devtrace=devtrace, ckpt_mgr=ckpt_mgr,
+                                       start_step=start_step, health=health)
         except BaseException:
             self._finalize_trace(tracer, success=False, devtrace=devtrace)
             raise
@@ -1449,28 +1493,32 @@ class FFModel:
                                     batch=int(self._iter % nb),
                                     num_batches=int(nb)))
 
-        run_name = tracer.run_name if tracer.active else "fit"
-        health = self._make_health(tracer, devtrace, run_name=run_name)
+        run_name = tracer.run_name if tracer.fence else "fit"
+        health = None
         try:
-            if health is not None:
-                health.install()
-            ckpt_mgr, start_step = self._make_checkpointer(
-                checkpoint_dir, checkpoint_every, resume,
-                run_name=run_name,
-                heartbeat=health.heartbeat if health is not None else None,
-                state_provider=cursor)
-            # the staged loader advances positional state — a resumed
-            # run repositions it once (seek) at the first post-resume
-            # slot, paying zero fetches for the covered ones
-            out = self._run_epochs(next_batch, loaders.num_batches, bs,
-                                   epochs, verbose,
-                                   on_epoch_start=loaders.reset,
-                                   tracer=tracer, devtrace=devtrace,
-                                   ckpt_mgr=ckpt_mgr,
-                                   start_step=start_step,
-                                   on_resume=lambda s: loaders.seek(
-                                       s % loaders.num_batches),
-                                   health=health)
+            with tracer.call("fit", epochs=epochs):
+                health = self._make_health(tracer, devtrace,
+                                           run_name=run_name)
+                if health is not None:
+                    health.install()
+                ckpt_mgr, start_step = self._make_checkpointer(
+                    checkpoint_dir, checkpoint_every, resume,
+                    run_name=run_name,
+                    heartbeat=(health.heartbeat if health is not None
+                               else None),
+                    state_provider=cursor)
+                # the staged loader advances positional state — a resumed
+                # run repositions it once (seek) at the first post-resume
+                # slot, paying zero fetches for the covered ones
+                out = self._run_epochs(next_batch, loaders.num_batches, bs,
+                                       epochs, verbose,
+                                       on_epoch_start=loaders.reset,
+                                       tracer=tracer, devtrace=devtrace,
+                                       ckpt_mgr=ckpt_mgr,
+                                       start_step=start_step,
+                                       on_resume=lambda s: loaders.seek(
+                                           s % loaders.num_batches),
+                                       health=health)
         except BaseException:
             self._finalize_trace(tracer, success=False, devtrace=devtrace)
             raise
@@ -1507,22 +1555,24 @@ class FFModel:
         acc = PerfMetrics()
         loss_sum, batches = 0.0, 0
         try:
-            for b in range(n // bs):
-                with tracer.step():
-                    sl = slice(b * bs, (b + 1) * bs)
-                    with tracer.phase("device_put"):
-                        inputs = self._stage_inputs([xx[sl] for xx in xs])
-                        labels = self._shard_batch(y[sl])
-                    with tracer.phase("dispatch"):
-                        loss, logits, mvals = eval_step(
-                            self.params, self.state, inputs, labels)
-                    with tracer.phase("metrics_sync"):
-                        loss_sum += float(loss)
-                        batches += 1
-                        acc.update({k: v for k, v in mvals.items()},
-                                   bs_report)
+            with tracer.call("evaluate"):
+                for b in range(n // bs):
+                    with tracer.step():
+                        sl = slice(b * bs, (b + 1) * bs)
+                        with tracer.phase("device_put"):
+                            inputs = self._stage_inputs(
+                                [xx[sl] for xx in xs])
+                            labels = self._shard_batch(y[sl])
+                        with tracer.phase("dispatch"):
+                            loss, logits, mvals = eval_step(
+                                self.params, self.state, inputs, labels)
+                        with tracer.phase("metrics_sync"):
+                            loss_sum += float(loss)
+                            batches += 1
+                            acc.update({k: v for k, v in mvals.items()},
+                                       bs_report)
         finally:
-            if tracer.active:
+            if tracer.fence:   # a session's tracer is written by stop_trace
                 try:
                     tracer.export()
                 except Exception as e:
@@ -1782,6 +1832,15 @@ class FFModel:
 
     def set_parameter(self, layer_name: str, value: np.ndarray,
                       param_name: str = "kernel") -> None:
+        t0 = time.perf_counter()
+        try:
+            self._set_parameter(layer_name, value, param_name)
+        finally:
+            # host seconds spent here since compile(), beside
+            # `compile_phases` in every trace header (obs.model_context)
+            self.set_parameter_s += time.perf_counter() - t0
+
+    def _set_parameter(self, layer_name, value, param_name) -> None:
         ref = self._body_ref(layer_name)
         if ref is not None:
             from flexflow_tpu.parallel.pipeline_exec import BODY_KEY

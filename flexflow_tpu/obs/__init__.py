@@ -15,9 +15,12 @@ per-kind calibration. Cf. "A Learned Performance Model for TPUs" /
 SCALE-Sim (PAPERS.md): a calibrated performance model is only as good
 as its feedback loop.
 
-Everything is inert unless a trace dir is set: ``make_tracer(None)``
-returns the shared ``NULL_TRACER`` whose methods are no-ops, so the
-training hot path pays nothing when observability is off.
+Everything is inert unless a trace dir is set or a session is open
+(``start_trace`` / ``stop_trace``, obs/session.py: one tracer for the
+whole process, unfenced, optionally with ``jax.profiler`` on the same
+clock): ``make_tracer(None)`` returns the shared ``NULL_TRACER`` whose
+methods are no-ops, so the training hot path pays nothing when
+observability is off.
 """
 
 from flexflow_tpu.obs.artifacts import artifact_header, write_artifact
@@ -38,6 +41,11 @@ from flexflow_tpu.obs.inspect import (
     model_context,
 )
 from flexflow_tpu.obs.registry import CounterRegistry, get_registry
+from flexflow_tpu.obs.session import (
+    session_tracer,
+    start_trace,
+    stop_trace,
+)
 from flexflow_tpu.obs.simtrace import (
     corpus_rows,
     sim_lane_events,
@@ -80,6 +88,9 @@ __all__ = [
     "sim_lane_events",
     "simtrace_report",
     "write_simtrace",
+    "session_tracer",
+    "start_trace",
+    "stop_trace",
     "class_aggregates",
     "finish_aggregates",
     "format_markdown",

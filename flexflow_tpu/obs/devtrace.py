@@ -59,11 +59,11 @@ _KIND_RE = re.compile(
 
 # Perfetto lane tids for device events injected into the StepTracer
 # trace (tid 0 is the host train_loop): one lane per bucket, shared by
-# all local devices — the union semantics below treat them as one
-# device-time resource per host.
-TID_COMPUTE, TID_COMMS, TID_HOST = 64, 65, 66
+# all local devices (the accounting below reduces each device on its
+# own; the lanes only draw them together).
+TID_COMPUTE, TID_COMMS, TID_HOST, TID_ASYNC = 64, 65, 66, 67
 LANE_THREADS = {TID_COMPUTE: "device:compute", TID_COMMS: "device:comms",
-                TID_HOST: "device:host"}
+                TID_HOST: "device:host", TID_ASYNC: "device:async"}
 
 
 def parse_profile_steps(spec: Optional[str]) -> Optional[Tuple[int, int]]:
@@ -165,12 +165,15 @@ def locate_profile_traces(profile_dir: str) -> List[str]:
     return sorted(glob.glob(os.path.join(sessions[-1], "*.trace.json*")))
 
 
-# the thread of a TPU's ``/device:`` process that carries one span per
-# executed HLO op. Its siblings are roll-ups of the same time — "XLA
-# Modules" (one span per program run) and "Steps" (step groupings that
-# include the gaps between programs) — and counting them would report
-# the whole step as device compute.
+# the threads of a TPU's ``/device:`` process that carry device work:
+# "XLA Ops" has one span per executed HLO op, "Async XLA Ops" the
+# asynchronous ops from start to done (copies, slices, collectives),
+# which overlap the first. Their siblings are roll-ups of the same time
+# — "XLA Modules" (one span per program run) and "Steps" (step
+# groupings that include the gaps between programs) — and counting them
+# would report the whole step as device compute.
 DEVICE_OP_THREAD = "XLA Ops"
+DEVICE_ASYNC_THREAD = "Async XLA Ops"
 
 
 def extract_device_events(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
@@ -178,36 +181,44 @@ def extract_device_events(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
 
     An event is a device op when its args carry ``hlo_op``/``hlo_module``
     (the CPU thunk executor stamps these) or when it sits on the
-    ``XLA Ops`` thread of a ``/device:`` process (real TPU lanes).
-    Python-tracer frames (``$``-prefixed) and runtime bookkeeping spans
-    carry neither and are dropped. Returns rows ``{name, ts, dur,
-    bucket, kind}`` (µs)."""
+    ``XLA Ops`` or ``Async XLA Ops`` thread of a ``/device:`` process
+    (real TPU lanes). Python-tracer frames (``$``-prefixed) and runtime
+    bookkeeping spans carry neither and are dropped. Returns rows
+    ``{name, ts, dur, bucket, kind, device}`` (µs). ``device`` is the
+    ``/device:`` process the span ran on (None for CPU thunk spans, which
+    all share the host process); an asynchronous span that is not a
+    collective gets the bucket ``"async"``: it keeps the device busy but
+    is neither compute nor communication."""
     device_pids = set()
-    op_lanes = set()
+    lanes: Dict[Any, str] = {}
     for e in trace.get("traceEvents", []):
         if e.get("ph") != "M":
             continue
         label = str((e.get("args") or {}).get("name", ""))
         if e.get("name") == "process_name" and label.startswith("/device:"):
             device_pids.add(e.get("pid"))
-        elif e.get("name") == "thread_name" and label == DEVICE_OP_THREAD:
-            op_lanes.add((e.get("pid"), e.get("tid")))
-    op_lanes = {lane for lane in op_lanes if lane[0] in device_pids}
+        elif e.get("name") == "thread_name" and label in (
+                DEVICE_OP_THREAD, DEVICE_ASYNC_THREAD):
+            lanes[(e.get("pid"), e.get("tid"))] = label
     out: List[Dict[str, Any]] = []
     for e in trace.get("traceEvents", []):
         if e.get("ph") != "X":
             continue
         name = e.get("name") or ""
         args = e.get("args") or {}
-        if not (args.get("hlo_op") or args.get("hlo_module")
-                or (e.get("pid"), e.get("tid")) in op_lanes):
+        pid = e.get("pid")
+        lane = lanes.get((pid, e.get("tid"))) if pid in device_pids else None
+        if not (args.get("hlo_op") or args.get("hlo_module") or lane):
             continue
         if name.startswith("$"):
             continue
         bucket, kind = classify_hlo_op(name)
+        if lane == DEVICE_ASYNC_THREAD and bucket != "collective":
+            bucket = "async"
         out.append(dict(name=name, ts=float(e.get("ts", 0.0)),
                         dur=float(e.get("dur", 0.0)),
-                        bucket=bucket, kind=kind))
+                        bucket=bucket, kind=kind,
+                        device=pid if lane else None))
     return out
 
 
@@ -234,62 +245,90 @@ def extract_step_windows(trace: Dict[str, Any],
     return out
 
 
+def attribute_window(device_events: List[Dict[str, Any]],
+                     t0: float, t1: float) -> Dict[str, Any]:
+    """Interval accounting of ONE device's spans inside ``[t0, t1]`` (µs):
+    ``compute_s`` is the time during which the device computes,
+    ``overlapped_comms_s`` is collective time hidden under that compute,
+    ``exposed_comms_s = comms_s - overlapped_comms_s`` is what the step
+    waits on, ``busy_s`` the union of every span (asynchronous copies
+    included). Times in seconds."""
+    by_bucket: Dict[str, List[Tuple[float, float]]] = {}
+    kind_iv: Dict[str, List[Tuple[float, float]]] = {}
+    kind_count: Dict[str, int] = {}
+    for ev in device_events:
+        s = max(ev["ts"], t0)
+        e = min(ev["ts"] + ev["dur"], t1)
+        if e <= s:
+            continue
+        by_bucket.setdefault(ev["bucket"], []).append((s, e))
+        if ev["bucket"] == "collective":
+            kind_iv.setdefault(ev["kind"], []).append((s, e))
+            kind_count[ev["kind"]] = kind_count.get(ev["kind"], 0) + 1
+    compute_u = merge_intervals(by_bucket.get("compute", []))
+    comms_u = merge_intervals(by_bucket.get("collective", []))
+    compute_s = interval_total(compute_u) / 1e6
+    comms_s = interval_total(comms_u) / 1e6
+    overlapped_s = intersect_total(comms_u, compute_u) / 1e6
+    busy_s = interval_total(merge_intervals(
+        [iv for ivs in by_bucket.values() for iv in ivs])) / 1e6
+    wall_s = (t1 - t0) / 1e6
+    return dict(
+        wall_s=wall_s,
+        compute_s=compute_s,
+        comms_s=comms_s,
+        overlapped_comms_s=overlapped_s,
+        exposed_comms_s=comms_s - overlapped_s,
+        host_s=interval_total(
+            merge_intervals(by_bucket.get("host", []))) / 1e6,
+        busy_s=busy_s,
+        idle_s=max(wall_s - busy_s, 0.0),
+        # per-kind hidden/exposed split (ISSUE 9): a kind's hidden
+        # seconds are its intervals under the compute union — the
+        # measured counterpart of the simulator's per-choice hidden
+        # term, so the merged report can show WHERE overlap lands
+        per_kind={k: _kind_entry(v, kind_count[k], compute_u)
+                  for k, v in kind_iv.items()},
+    )
+
+
+def _mean_rows(rows: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """Key-wise mean of per-device rows (nested ``per_kind`` included; a
+    kind a device did not run counts as zero there)."""
+    n = len(rows)
+    out: Dict[str, Any] = {}
+    for key in rows[0]:
+        if key == "per_kind":
+            kinds = sorted({k for r in rows for k in r["per_kind"]})
+            zero = dict(time_s=0.0, count=0, overlapped_s=0.0,
+                        exposed_s=0.0)
+            out[key] = {k: _mean_rows([r["per_kind"].get(k, zero)
+                                       for r in rows]) for k in kinds}
+        else:
+            mean = sum(r[key] for r in rows) / n
+            out[key] = int(mean) if key == "count" and mean == int(mean) \
+                else mean
+    return out
+
+
 def attribute_steps(device_events: List[Dict[str, Any]],
                     step_windows: Dict[int, Tuple[float, float]]
                     ) -> List[Dict[str, Any]]:
-    """Per-step interval accounting over the device spans.
-
-    All local devices share one timeline per bucket (union semantics):
-    ``compute_s`` is wall time during which ANY device computes,
-    ``overlapped_comms_s`` is collective time hidden under that compute,
-    and ``exposed_comms_s = comms_s - overlapped_comms_s`` is what the
-    step waits on. Times in seconds."""
+    """Per-step interval accounting over the device spans: every device
+    is reduced on its own timeline (``attribute_window``), then the
+    devices are averaged — as ``benchmarks/trace_reduce.py`` does. A
+    union over devices would hide one chip's idle time under another's
+    work. CPU thunk spans carry no device and form one group."""
+    by_device: Dict[Any, List[Dict[str, Any]]] = {}
+    for ev in device_events:
+        by_device.setdefault(ev.get("device"), []).append(ev)
     rows: List[Dict[str, Any]] = []
     for step in sorted(step_windows):
         t0, t1 = step_windows[step]
-        compute_iv: List[Tuple[float, float]] = []
-        comms_iv: List[Tuple[float, float]] = []
-        host_iv: List[Tuple[float, float]] = []
-        kind_iv: Dict[str, List[Tuple[float, float]]] = {}
-        kind_count: Dict[str, int] = {}
-        for ev in device_events:
-            s = max(ev["ts"], t0)
-            e = min(ev["ts"] + ev["dur"], t1)
-            if e <= s:
-                continue
-            if ev["bucket"] == "collective":
-                comms_iv.append((s, e))
-                kind_iv.setdefault(ev["kind"], []).append((s, e))
-                kind_count[ev["kind"]] = kind_count.get(ev["kind"], 0) + 1
-            elif ev["bucket"] == "host":
-                host_iv.append((s, e))
-            else:
-                compute_iv.append((s, e))
-        compute_u = merge_intervals(compute_iv)
-        comms_u = merge_intervals(comms_iv)
-        compute_s = interval_total(compute_u) / 1e6
-        comms_s = interval_total(comms_u) / 1e6
-        overlapped_s = intersect_total(comms_u, compute_u) / 1e6
-        host_s = interval_total(merge_intervals(host_iv)) / 1e6
-        busy_s = interval_total(
-            merge_intervals(compute_iv + comms_iv + host_iv)) / 1e6
-        wall_s = (t1 - t0) / 1e6
-        rows.append(dict(
-            step=step,
-            wall_s=wall_s,
-            compute_s=compute_s,
-            comms_s=comms_s,
-            overlapped_comms_s=overlapped_s,
-            exposed_comms_s=comms_s - overlapped_s,
-            host_s=host_s,
-            idle_s=max(wall_s - busy_s, 0.0),
-            # per-kind hidden/exposed split (ISSUE 9): a kind's hidden
-            # seconds are its intervals under the compute union — the
-            # measured counterpart of the simulator's per-choice hidden
-            # term, so the merged report can show WHERE overlap lands
-            per_kind={k: _kind_entry(v, kind_count[k], compute_u)
-                      for k, v in kind_iv.items()},
-        ))
+        per_device = [attribute_window(evs, t0, t1)
+                      for evs in by_device.values()] or [
+                          attribute_window([], t0, t1)]
+        rows.append(dict(step=step, **_mean_rows(per_device)))
     return rows
 
 
@@ -307,7 +346,8 @@ def aggregate_attribution(per_step: List[Dict[str, Any]]) -> Dict[str, Any]:
     — the measured half of the measured-vs-priced drift join)."""
     n = len(per_step)
     totals = dict(compute_s=0.0, comms_s=0.0, overlapped_comms_s=0.0,
-                  exposed_comms_s=0.0, host_s=0.0, idle_s=0.0, wall_s=0.0)
+                  exposed_comms_s=0.0, host_s=0.0, busy_s=0.0, idle_s=0.0,
+                  wall_s=0.0)
     coll: Dict[str, Dict[str, float]] = {}
     for row in per_step:
         for k in totals:
@@ -382,7 +422,7 @@ class _CaptureStep:
     perf_counter bracket of every annotated step for the clock
     correlation the Perfetto lane merge needs."""
 
-    __slots__ = ("cap", "idx", "_ann", "_t0")
+    __slots__ = ("cap", "idx", "_ann", "_bracket")
 
     def __init__(self, cap, idx):
         self.cap = cap
@@ -398,21 +438,21 @@ class _CaptureStep:
                 import jax
                 self._ann = jax.profiler.StepTraceAnnotation(
                     STEP_ANNOTATION, step_num=self.idx)
+                p0 = time.perf_counter()
                 self._ann.__enter__()
+                self._bracket = (p0, time.perf_counter())
             except Exception:
                 self._ann = None
-        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
         cap = self.cap
         if self._ann is not None:
             try:
                 self._ann.__exit__(*exc)
             except Exception:
                 pass
-            cap.host_steps[self.idx] = (self._t0, t1)
+            cap.host_steps[self.idx] = self._bracket
         if cap.state == "capturing" and self.idx + 1 >= cap.window[1]:
             cap._stop()
         return False
@@ -452,8 +492,8 @@ class DeviceTraceCapture:
 
     def _start(self) -> None:
         try:
-            import jax
-            jax.profiler.start_trace(self.profile_dir)
+            from flexflow_tpu.obs.session import start_profiler
+            start_profiler(self.profile_dir)
             self.state = "capturing"
         except Exception as e:
             import sys
@@ -463,8 +503,8 @@ class DeviceTraceCapture:
 
     def _stop(self) -> None:
         try:
-            import jax
-            jax.profiler.stop_trace()
+            from flexflow_tpu.obs.session import stop_profiler
+            stop_profiler()
             self.state = "done"
             self.trace_paths = locate_profile_traces(self.profile_dir)
             if not self.trace_paths:
@@ -479,18 +519,19 @@ class DeviceTraceCapture:
 
     # ---- post-run ----------------------------------------------------------
     def _clock_shift_us(self, step_windows) -> float:
-        """Profiler-timebase -> tracer-timeline shift, averaged over
-        every step seen by both clocks (the host perf_counter bracket
-        recorded around each annotation vs the annotation's own span in
-        the profile)."""
+        """Profiler-timebase -> tracer-timeline shift: the session's
+        clock tie (obs/session.clock_shift_us), with each step's
+        annotation as a marker — the host perf_counter bracket recorded
+        around its start vs the start the profile gives it."""
+        from flexflow_tpu.obs.session import clock_shift_us
         origin = getattr(self.tracer, "_origin", None)
-        if origin is None:
+        seen = [idx for idx in self.host_steps if idx in step_windows]
+        if origin is None or not seen:
             return 0.0
-        shifts = [
-            (t0 - origin) * 1e6 - step_windows[idx][0]
-            for idx, (t0, _) in self.host_steps.items()
-            if idx in step_windows]
-        return sum(shifts) / len(shifts) if shifts else 0.0
+        shift, _ = clock_shift_us(
+            origin, [self.host_steps[idx] for idx in seen],
+            [step_windows[idx][0] for idx in seen])
+        return shift
 
     def finalize(self, ff, tracer) -> Optional[Dict[str, Any]]:
         """Parse + attribute, emit the artifact, merge Perfetto lanes.
@@ -512,27 +553,19 @@ class DeviceTraceCapture:
             device_events=len(events),
             **aggregate_attribution(per_step),
         )
-        # registry: exposed-comms / compute distributions survive into
-        # the counters snapshot (bounded reservoir, registry.observe)
+        # registry: the exposed-comms distribution survives into the
+        # counters snapshot (bounded reservoir, registry.observe)
         from flexflow_tpu.obs.registry import get_registry
         reg = get_registry()
-        run = tracer.run_name
         for row in per_step:
-            reg.observe(f"{run}/devtrace_compute_s", row["compute_s"])
-            reg.observe(f"{run}/devtrace_exposed_comms_s",
+            reg.observe(f"{tracer.run_name}/devtrace_exposed_comms_s",
                         row["exposed_comms_s"])
-        tot = report["totals"]
-        if tot["wall_s"] > 0:
-            reg.gauge(f"{run}/devtrace_exposed_comms_frac",
-                      tot["exposed_comms_s"] / tot["wall_s"])
-            reg.gauge(f"{run}/devtrace_compute_frac",
-                      tot["compute_s"] / tot["wall_s"])
         # Perfetto lanes: device spans + per-step attribution counters,
         # rebased from the profiler timebase onto the tracer timeline
         shift = self._clock_shift_us(windows)
         lane_events: List[Dict[str, Any]] = []
         tid_of = {"compute": TID_COMPUTE, "collective": TID_COMMS,
-                  "host": TID_HOST}
+                  "host": TID_HOST, "async": TID_ASYNC}
         for ev in events:
             ce = dict(name=ev["name"], ph="X", tid=tid_of[ev["bucket"]],
                       ts=round(ev["ts"] + shift, 3),

@@ -300,6 +300,10 @@ def model_context(ff) -> Dict[str, Any]:
         mesh_axes=dict(zip(ff.mesh.axis_names, ff.mesh.devices.shape)),
         batch_size=(ff.input_tensors[0].shape[0]
                     if ff.input_tensors else None),
+        # host seconds of FFModel.compile by phase, and of every
+        # set_parameter call since (model.py records both, always)
+        compile_phases=getattr(ff, "compile_phases", None),
+        set_parameter_s=getattr(ff, "set_parameter_s", None),
     )
 
 

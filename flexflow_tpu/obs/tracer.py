@@ -1,8 +1,16 @@
 """Step tracer: per-step wall time + phase spans, Perfetto-viewable.
 
-Records a span tree per training step — data_load (host slicing),
-device_put (host->device staging), step (jitted dispatch), metrics_sync
-(the host fetch that fences the device) — and exports two artifacts:
+Records a span tree per ``fit`` call — the call itself, its set-up, and
+per training step data_load (host slicing), device_put (host->device
+staging), rng_split, dispatch (the jitted step's call),
+metric_accumulate, then metrics_sync (the epoch's host fetch of the
+loss). Every span carries an ``id``, its ``parent``'s id and the id of
+the ``fit``/``evaluate`` call it belongs to (``call``), so a reader
+rebuilds the tree from the flat stream. A tracer lives either for one
+``fit(trace_dir=...)`` call (``fence=True``: each step ends in a
+``device_wait``) or for a process-wide session (``obs.start_trace``:
+nothing is fenced, the traced program is the untraced one). It exports
+two artifacts:
 
 - ``<run>_hostNN.trace.json``: Chrome-trace/Perfetto ``traceEvents``
   JSON (load in ui.perfetto.dev or chrome://tracing). One ``pid`` per
@@ -36,10 +44,20 @@ class NullTracer:
     """Inert tracer: the no-trace_dir fast path."""
 
     active = False
+    fence = False
     _NULL = contextlib.nullcontext()
 
     def step(self):
         return self._NULL
+
+    def call(self, name, **args):
+        return self._NULL
+
+    def setup_done(self):
+        pass
+
+    def annotate(self, **args):
+        pass
 
     def phase(self, name, **args):
         return self._NULL
@@ -86,7 +104,10 @@ def _clock_pair(samples: int = 5):
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "args", "t0")
+    """One open span. ``args`` may be filled in while the span is open
+    (``device_put`` learns the bytes it staged inside the span)."""
+
+    __slots__ = ("tracer", "name", "args", "t0", "id", "parent")
 
     def __init__(self, tracer, name, args):
         self.tracer = tracer
@@ -94,12 +115,19 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        tr = self.tracer
+        self.id = tr._next_id
+        tr._next_id += 1
+        self.parent = tr._open[-1].id if tr._open else None
+        tr._open.append(self)
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        self.tracer._record(self.name, self.t0, t1, self.args)
+        tr = self.tracer
+        tr._open.pop()
+        tr._record(self, t1)
         return False
 
 
@@ -119,6 +147,23 @@ class _StepSpan(_Span):
         return r
 
 
+class _CallSpan(_Span):
+    """A whole ``fit`` / ``evaluate`` call: the spans recorded inside it
+    carry its id as ``call``."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        _Span.__enter__(self)
+        self.tracer._call = self
+        return self
+
+    def __exit__(self, *exc):
+        r = _Span.__exit__(self, *exc)
+        self.tracer._call = None
+        return r
+
+
 class StepTracer:
     """Records phase spans and exports Chrome-trace JSON + JSONL."""
 
@@ -131,7 +176,8 @@ class StepTracer:
     MAX_EVENTS = 500_000
 
     def __init__(self, trace_dir: str, host_id: Optional[int] = None,
-                 run_name: str = "fit", max_events: Optional[int] = None):
+                 run_name: str = "fit", max_events: Optional[int] = None,
+                 fence: bool = True):
         if host_id is None:
             try:
                 import jax
@@ -141,6 +187,11 @@ class StepTracer:
         self.trace_dir = trace_dir
         self.host_id = int(host_id)
         self.run_name = run_name
+        # fence=True: the fit loop ends every step in a device_wait, so
+        # a step span is device-inclusive (the fit(trace_dir=...) form).
+        # A session's tracer does not fence: its spans are the host's
+        # own time in the program the untraced run executes.
+        self.fence = fence
         self.run_seq = next(_RUN_SEQ)
         self.max_events = (self.MAX_EVENTS if max_events is None
                            else max_events)
@@ -155,21 +206,25 @@ class StepTracer:
         self._clock_pair_spread_us = pair_spread * 1e6
         self._step_index = -1
         self._in_step = False
+        self._next_id = 0
+        self._open: List[_Span] = []   # the loop thread's open spans
+        self._call: Optional[_CallSpan] = None
         os.makedirs(trace_dir, exist_ok=True)
 
     # ---- recording --------------------------------------------------------
-    def _record(self, name: str, t0: float, t1: float,
-                args: Optional[Dict[str, Any]]) -> None:
+    def _record(self, span: _Span, t1: float) -> None:
         if len(self._events) >= self.max_events:
             self._dropped += 1
             return
-        ev = dict(name=name,
-                  ts=(t0 - self._origin) * 1e6,
-                  dur=(t1 - t0) * 1e6)
-        if self._in_step or name == "step":
+        ev = dict(name=span.name,
+                  ts=(span.t0 - self._origin) * 1e6,
+                  dur=(t1 - span.t0) * 1e6,
+                  id=span.id, parent=span.parent,
+                  call=self._call.id if self._call else None)
+        if self._in_step or span.name == "step":
             ev["step"] = self._step_index
-        if args:
-            ev["args"] = args
+        if span.args:
+            ev["args"] = span.args
         self._events.append(ev)
 
     def step(self):
@@ -178,9 +233,32 @@ class StepTracer:
         return _StepSpan(self, "step", None)
 
     def phase(self, name: str, **args):
-        """Span for one phase (data_load / device_put / step_dispatch /
-        metrics_sync / ...) — nests under the current step span."""
+        """Span for one phase (data_load / device_put / dispatch /
+        metrics_sync / ...) — nests under whatever span is open."""
         return _Span(self, name, args or None)
+
+    def call(self, name: str, **args):
+        """Span for one whole ``fit`` / ``evaluate`` call."""
+        return _CallSpan(self, name, args or None)
+
+    def setup_done(self) -> None:
+        """Close the open call's set-up stretch: a ``<call>_setup`` span
+        from the call's entry to now (the fit loop says when its first
+        batch is about to be fetched)."""
+        call = self._call
+        if call is None:
+            return
+        done = _Span(self, call.name + "_setup", None)
+        done.id, self._next_id = self._next_id, self._next_id + 1
+        done.parent, done.t0 = call.id, call.t0
+        self._record(done, time.perf_counter())
+
+    def annotate(self, **args) -> None:
+        """Add args to the open call's span (the fit loop knows how many
+        steps it ran only at its end)."""
+        call = self._call
+        if call is not None:
+            call.args = dict(call.args or {}, **args)
 
     def instant(self, name: str, **args) -> None:
         if len(self._events) >= self.max_events:

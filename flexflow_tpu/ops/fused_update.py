@@ -88,6 +88,8 @@ def _adam_leaf_local(alpha2, p, g, m, v, *, mode, beta1, beta2, eps, wd):
                           beta2=beta2, eps=eps, wd=wd)
     from jax.experimental import pallas as pl
 
+    from flexflow_tpu.ops.pallas_kernels import KERNEL_NAME_PREFIX
+
     rows, blk = geom
     shp = p.shape
     view = lambda x: x.reshape(rows, 128)
@@ -96,6 +98,7 @@ def _adam_leaf_local(alpha2, p, g, m, v, *, mode, beta1, beta2, eps, wd):
     row_spec = pl.BlockSpec((blk, 128), lambda i: (i, 0))
     pn, mn, vn = pl.pallas_call(
         kern,
+        name=KERNEL_NAME_PREFIX + "fused_adam",
         out_shape=(jax.ShapeDtypeStruct((rows, 128), p.dtype),
                    jax.ShapeDtypeStruct((rows, 128), m.dtype),
                    jax.ShapeDtypeStruct((rows, 128), v.dtype)),

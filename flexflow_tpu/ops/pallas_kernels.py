@@ -44,6 +44,14 @@ BLK_Q = 128  # rows of Q per grid step (MXU-aligned)
 # (tests/test_tpu_compile.py), and refuses head_dim 256 at S = 16384.
 _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 
+# Every kernel's ``pallas_call`` carries a ``name=``: XLA names the custom
+# call's HLO instruction after it, and the profiler names a device event
+# by its instruction, so a chip trace shows `tpu_custom_call_flash_fwd.3`
+# where it showed `tpu_custom_call.3`. The names keep the prefix an
+# unnamed kernel gets: reductions of a trace find kernels by it
+# (benchmarks/trace_reduce.KERNEL_PREFIX).
+KERNEL_NAME_PREFIX = "tpu_custom_call_"
+
 
 def _fwd_blk(s: int) -> int:
     """Q-block rows for the forward kernel. 128 everywhere: a same-chip
@@ -89,6 +97,7 @@ def _flash_fwd(q, k, v, causal: bool, interpret: bool, out_dtype=None):
                              blk_q=blk)
     return pl.pallas_call(
         kern,
+        name=KERNEL_NAME_PREFIX + "flash_fwd",
         # lse is (bh, 1, s): TPU requires the last two block dims be
         # (8,128)-aligned or span the array — a middle singleton satisfies
         # that while keeping one row per (batch*head)
@@ -174,6 +183,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool,
     row_spec = pl.BlockSpec((1, 1, s), lambda b: (b, 0, 0))
     return pl.pallas_call(
         kern,
+        name=KERNEL_NAME_PREFIX + "flash_bwd",
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
@@ -248,6 +258,7 @@ def _flash_bwd_blocked(q, k, v, o, lse, do, causal: bool, interpret: bool,
     row_spec = pl.BlockSpec((1, 1, s), lambda b, j: (b, 0, 0))
     dq, dk, dv = pl.pallas_call(
         kern,
+        name=KERNEL_NAME_PREFIX + "flash_bwd_blocked",
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), jnp.float32),  # dq acc
                    jax.ShapeDtypeStruct((bh, s, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), v.dtype)),

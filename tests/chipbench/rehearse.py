@@ -4,7 +4,7 @@ Not reachable from `benchmarks/run.py`. Nothing it prints is a device
 number: the platform in every line is `cpu`.
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
-        python tests/chipbench/rehearse.py <cell> [trace]
+        python tests/chipbench/rehearse.py <cell> [0|1|2]
 """
 
 import os
@@ -26,6 +26,14 @@ TINY = {
 }
 
 
+def send_output_to(monkeypatch, directory):
+    """Point the harness and the readers of the session's metrics at
+    `directory` instead of the checkout's `chipbench_out/`."""
+    from benchmarks import harness, session_reduce
+    for module in (harness, session_reduce):
+        monkeypatch.setattr(module, "OUT_DIR", str(directory))
+
+
 def rehearse(cell, trace, seed=7, seconds=0.5, root=ROOT):
     from benchmarks import manifest as mf
     from benchmarks.harness import run_cell
@@ -35,4 +43,4 @@ def rehearse(cell, trace, seed=7, seconds=0.5, root=ROOT):
 
 
 if __name__ == "__main__":
-    rehearse(sys.argv[1], len(sys.argv) > 2 and sys.argv[2] == "trace")
+    rehearse(sys.argv[1], int(sys.argv[2]) if len(sys.argv) > 2 else 0)

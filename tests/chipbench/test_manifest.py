@@ -21,7 +21,10 @@ def manifest():
 
 def test_keys_and_command(manifest):
     assert set(manifest) == {"command", "paths", "run_seconds", "configs",
-                             "workloads", "end_to_end", "per_layer"}
+                             "workloads", "end_to_end", "per_layer",
+                             "trace_in_run"}
+    # per-layer metrics are read in the run that measures (`--trace 2`)
+    assert manifest["trace_in_run"] is True
     assert manifest["command"] == ["python3", "benchmarks/run.py"]
     assert manifest["paths"] == ["benchmarks", "tests/chipbench"]
     assert 1 <= manifest["run_seconds"] <= 51

@@ -234,6 +234,74 @@ class TestRealTpuTrace:
             assert row["idle_s"] > 0
 
 
+    def test_busy_time_agrees_with_the_benchmarks_reduction(self, trace):
+        """One device: `attribute_window` (per device, asynchronous line
+        counted) and `benchmarks/trace_reduce.py` give the same busy
+        time over the same window."""
+        import sys
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        if root not in sys.path:
+            sys.path.insert(0, root)
+        from benchmarks import trace_reduce as tr
+        from flexflow_tpu.obs.devtrace import attribute_window
+        (dev,) = tr.load_chrome(self.TPU_FIXTURE)
+        busy_s, window_s = tr.busy_and_window(dev)
+        t0, t1 = tr.window(dev)
+        events = extract_device_events(trace)
+        assert {ev["device"] for ev in events} == {
+            e["pid"] for e in trace["traceEvents"]
+            if e.get("name") == "process_name"
+            and e["args"]["name"].startswith("/device:")}
+        row = attribute_window(events, t0 * 1e6, t1 * 1e6)
+        assert row["wall_s"] == pytest.approx(window_s, rel=1e-9)
+        assert row["busy_s"] == pytest.approx(busy_s, rel=1e-9)
+        assert row["idle_s"] == pytest.approx(window_s - busy_s, rel=1e-9)
+
+
+def _two_device_trace():
+    """Device 1 computes in the first half of a 100 us step, device 2 in
+    the second half and copies asynchronously for 10 us of its first."""
+    meta = []
+    for pid in (1, 2):
+        meta += [dict(ph="M", name="process_name", pid=pid,
+                      args=dict(name=f"/device:TPU:{pid - 1}")),
+                 dict(ph="M", name="thread_name", pid=pid, tid=1,
+                      args=dict(name="XLA Ops")),
+                 dict(ph="M", name="thread_name", pid=pid, tid=2,
+                      args=dict(name="Async XLA Ops")),
+                 dict(ph="M", name="thread_name", pid=pid, tid=3,
+                      args=dict(name="XLA Modules"))]
+    spans = [dict(ph="X", name="fusion.1", pid=1, tid=1, ts=0.0, dur=50.0),
+             dict(ph="X", name="fusion.1", pid=2, tid=1, ts=50.0, dur=50.0),
+             dict(ph="X", name="copy-start.1", pid=2, tid=2, ts=20.0,
+                  dur=10.0),
+             dict(ph="X", name="all-gather-start.1", pid=1, tid=2, ts=40.0,
+                  dur=20.0),
+             dict(ph="X", name="jit_train_step(1)", pid=1, tid=3, ts=0.0,
+                  dur=100.0)]
+    return dict(traceEvents=meta + spans)
+
+
+class TestPerDeviceReduction:
+    def test_devices_are_reduced_apart_then_averaged(self):
+        events = extract_device_events(_two_device_trace())
+        assert {(e["device"], e["bucket"]) for e in events} == {
+            (1, "compute"), (2, "compute"), (2, "async"),
+            (1, "collective")}
+        (row,) = attribute_steps(events, {0: (0.0, 100.0)})
+        # a union over the devices would call the step fully busy
+        assert row["compute_s"] == pytest.approx(50e-6)
+        assert row["busy_s"] == pytest.approx((60e-6 + 60e-6) / 2)
+        assert row["idle_s"] == pytest.approx(40e-6)
+        # device 1's all-gather: 10 us under its compute, 10 us exposed;
+        # device 2 ran none, so the mean halves both
+        assert row["comms_s"] == pytest.approx(10e-6)
+        assert row["exposed_comms_s"] == pytest.approx(5e-6)
+        assert row["per_kind"]["all-gather"]["exposed_s"] \
+            == pytest.approx(5e-6)
+        assert row["per_kind"]["all-gather"]["count"] == 0.5
+
+
 class TestRegistryReservoir:
     def test_percentiles_bounded_memory(self):
         from flexflow_tpu.obs.registry import (RESERVOIR_SIZE,

@@ -92,7 +92,11 @@ class TestFlashKernels:
         q = jax.ShapeDtypeStruct((128, 512, 64), jnp.bfloat16,
                                  sharding=SingleDeviceSharding(
                                      topo.devices[0]))
-        assert pallas_kernel_count(_compile(_flash_grads, q, q, q)) == 2
+        hlo = _compile(_flash_grads, q, q, q)
+        assert pallas_kernel_count(hlo) == 2
+        # the kernels' names, in the custom calls' `op_name`
+        assert "tpu_custom_call_flash_fwd" in hlo
+        assert "tpu_custom_call_flash_bwd" in hlo
 
     @pytest.mark.parametrize("grads,dtype", [
         # the ring variant (f32 output, lse gradient) needs the most VMEM
@@ -172,6 +176,7 @@ class TestFusedAdam:
                                                 jnp.float32(1e-4), **self.KW)
         hlo = _compile(fn, *self._args(SingleDeviceSharding(topo.devices[0])))
         assert pallas_kernel_count(hlo) == 1
+        assert "tpu_custom_call_fused_adam" in hlo
 
     def test_leaf_under_wus_spec_on_four_devices(self, topo, on_tpu):
         from flexflow_tpu.ops.fused_update import fused_adam_leaf
@@ -257,6 +262,8 @@ def test_wus_step_with_fused_update_compiles_for_four_chips(topo, on_tpu):
     # a forward and a backward per flash op, plus at least one update
     # kernel of the fused ops
     assert pallas_kernel_count(hlo) > 2 * flash
+    # the scopes that tell the optimizer and the loss from the layers
+    assert "/optimizer_update/" in hlo and "/jvp(loss)/" in hlo
 
 
 # arm -> fewest kernels its step holds: a flash forward and backward per

@@ -1,0 +1,291 @@
+"""The process-wide trace session (flexflow_tpu/obs/session.py).
+
+`obs.start_trace` / `obs.stop_trace` around several `fit` calls: one
+artifact, spans with ids and parents on one timeline, nothing fenced,
+nothing compiled at the end; no session, no tracer; the clock tie on a
+CPU profiler trace; `compile_phases` in the header.
+"""
+
+import glob
+import json
+import os
+import time
+
+import pytest
+
+import jax
+
+from flexflow_tpu import obs
+from flexflow_tpu.obs import session as obs_session
+from flexflow_tpu.obs import tracer as obs_tracer
+
+from test_observability import build_mlp, make_blobs
+
+STEPS = 4   # 128 samples / batch 32
+
+
+@pytest.fixture(scope="module")
+def model():
+    x, y = make_blobs()
+    ff = build_mlp()
+    ff.fit(x, y, epochs=1, verbose=False)   # compiles the step
+    ff.evaluate(x, y)
+    return ff, x, y
+
+
+@pytest.fixture
+def no_open_session():
+    yield
+    if obs.session_tracer() is not None:
+        obs.stop_trace()
+
+
+def read_events(path):
+    with open(path) as f:
+        rows = [json.loads(line) for line in f]
+    return rows[0], rows[1:]
+
+
+@pytest.fixture(scope="module")
+def session_run(model, tmp_path_factory):
+    """Two `fit` calls and an `evaluate` inside one session, no profiler."""
+    ff, x, y = model
+    td = str(tmp_path_factory.mktemp("session"))
+    before = obs.get_registry().to_dict()["counters"]
+    obs.start_trace(td, device=False)
+    ff.fit(x, y, epochs=1, verbose=False)
+    time.sleep(0.01)   # a stretch between the calls
+    ff.fit(x, y, epochs=2, verbose=False, trace_dir=os.path.join(td, "own"))
+    ff.evaluate(x, y)
+    paths = obs.stop_trace()
+    after = obs.get_registry().to_dict()["counters"]
+    header, events = read_events(paths["events"])
+    return dict(td=td, paths=paths, header=header, events=events,
+                before=before, after=after)
+
+
+class TestSessionArtifact:
+    def test_one_artifact_for_all_calls(self, session_run):
+        td, paths = session_run["td"], session_run["paths"]
+        assert paths["xplane"] is None
+        assert sorted(os.listdir(td)) == sorted(
+            os.path.basename(paths[k])
+            for k in ("trace", "events", "counters"))
+        # no report of the per-call form: no recompile, no replay, no drift
+        assert not glob.glob(os.path.join(td, "**", "*.summary.json"),
+                             recursive=True)
+        assert session_run["header"]["run_name"] == "session"
+
+    def test_fit_spans_ids_and_parents(self, session_run):
+        events = session_run["events"]
+        by_id = {e["id"]: e for e in events}
+        assert len(by_id) == len(events)
+        fits = [e for e in events if e["name"] == "fit"]
+        assert len(fits) == 2
+        for f in fits:
+            assert f["parent"] is None and f["call"] == f["id"]
+        assert fits[0]["args"] == dict(epochs=1, steps=STEPS)
+        assert fits[1]["args"] == dict(epochs=2, steps=2 * STEPS)
+        parents = {"fit_setup": "fit", "step": "fit", "metrics_sync": "fit",
+                   "data_load": "step", "device_put": "step",
+                   "rng_split": "step", "dispatch": "step",
+                   "metric_accumulate": "step"}
+        train = [e for e in events if e["call"] in {f["id"] for f in fits}]
+        assert {e["name"] for e in train} == set(parents) | {"fit"}
+        for e in train:
+            if e["name"] == "fit":
+                continue
+            parent = by_id[e["parent"]]
+            assert parent["name"] == parents[e["name"]], e
+            assert parent["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1e-3
+        steps = [e for e in train if e["name"] == "step"]
+        assert [e["step"] for e in sorted(steps, key=lambda e: e["ts"])] \
+            == list(range(3 * STEPS))
+        put = next(e for e in train if e["name"] == "device_put")
+        assert put["args"] == dict(bytes=32 * (8 + 1) * 4)
+
+    def test_stretch_between_calls_is_on_the_timeline(self, session_run):
+        fits = sorted((e for e in session_run["events"]
+                       if e["name"] == "fit"), key=lambda e: e["ts"])
+        gap_us = fits[1]["ts"] - (fits[0]["ts"] + fits[0]["dur"])
+        assert 10e3 <= gap_us < 1e6   # the 10 ms slept between the calls
+        setup = [e for e in session_run["events"]
+                 if e["name"] == "fit_setup"]
+        assert [s["ts"] for s in setup] == [f["ts"] for f in fits]
+
+    def test_evaluate_records_into_the_session(self, session_run):
+        events = session_run["events"]
+        (ev,) = [e for e in events if e["name"] == "evaluate"]
+        inside = [e for e in events if e["call"] == ev["id"] and e is not ev]
+        assert {e["name"] for e in inside} == {"step", "device_put",
+                                               "dispatch", "metrics_sync"}
+
+    def test_trace_dir_inside_a_session_is_the_sessions(self, session_run):
+        assert not os.path.exists(os.path.join(session_run["td"], "own"))
+
+    def test_header_holds_compile_phases(self, session_run):
+        header = session_run["header"]
+        assert set(header["compile_phases"]) == {
+            "search_s", "executor_build_s", "lint_s", "param_init_s",
+            "state_placement_s"}
+        assert header["compile_phases"]["param_init_s"] > 0
+        assert header["set_parameter_s"] == 0.0
+        assert "clock_shift_us" not in header   # no profiler, no tie
+
+    def test_counters_snapshot_is_the_registrys(self, session_run):
+        """The session writes the process registry as it stands and adds
+        no counter of its own: a call's totals are its span's args."""
+        assert set(session_run["after"]) == set(session_run["before"])
+        snapshot = json.load(open(session_run["paths"]["counters"]))
+        assert snapshot["counters"] == session_run["after"]
+        assert "executor.train_step_jits" in snapshot["counters"]
+
+
+class CountingFence:
+    def __init__(self, monkeypatch):
+        self.calls = 0
+        self.real = jax.block_until_ready
+        monkeypatch.setattr(jax, "block_until_ready", self)
+
+    def __call__(self, x):
+        self.calls += 1
+        return self.real(x)
+
+
+class TestNoFence:
+    def test_a_session_records_no_fence(self, model, tmp_path, monkeypatch,
+                                        no_open_session):
+        ff, x, y = model
+        fence = CountingFence(monkeypatch)
+        obs.start_trace(str(tmp_path), device=False)
+        ff.fit(x, y, epochs=2, verbose=False)
+        _, events = read_events(obs.stop_trace()["events"])
+        names = [e["name"] for e in events]
+        assert names.count("dispatch") == 2 * STEPS
+        assert "device_wait" not in names
+        assert fence.calls == 0
+
+    def test_the_per_call_form_still_fences_every_step(
+            self, model, tmp_path, monkeypatch):
+        ff, x, y = model
+        fence = CountingFence(monkeypatch)
+        ff.fit(x, y, epochs=1, verbose=False, trace_dir=str(tmp_path))
+        (path,) = glob.glob(str(tmp_path / "fit_*.events.jsonl"))
+        _, events = read_events(path)
+        names = [e["name"] for e in events]
+        assert names.count("device_wait") == names.count("dispatch") == STEPS
+        assert fence.calls >= STEPS
+        assert glob.glob(str(tmp_path / "fit_*.summary.json"))
+
+
+class Compiles:
+    """Counts what JAX lowers and compiles from now on."""
+
+    def __init__(self):
+        self.n = 0
+        self.on = True
+        jax.monitoring.register_event_duration_secs_listener(self._seen)
+
+    def _seen(self, event, duration, **_):
+        if self.on and ("compile" in event or "lower" in event
+                        or "trace" in event):
+            self.n += 1
+
+
+def test_stop_trace_neither_lowers_nor_compiles(model, tmp_path,
+                                                no_open_session):
+    ff, x, y = model
+    obs.start_trace(str(tmp_path), device=False)
+    ff.fit(x, y, epochs=1, verbose=False)
+    jits = obs.get_registry().get("executor.train_step_jits")
+    seen = Compiles()
+    try:
+        obs.stop_trace()
+    finally:
+        seen.on = False
+    assert seen.n == 0
+    assert obs.get_registry().get("executor.train_step_jits") == jits
+    seen.on = True   # the control: the listener does see a compile
+    jax.jit(lambda a: a + 1)(1.0)
+    seen.on = False
+    assert seen.n > 0
+
+
+def test_no_session_no_tracer(model, monkeypatch):
+    ff, x, y = model
+
+    def refuse(*a, **kw):
+        raise AssertionError("an untraced fit made a tracer")
+
+    monkeypatch.setattr(obs_tracer.StepTracer, "__init__", refuse)
+    assert obs.session_tracer() is None
+    assert ff._make_tracer(None, "fit") is obs.NULL_TRACER
+    ff.fit(x, y, epochs=1, verbose=False)
+    ff.evaluate(x, y)
+
+
+def test_one_session_at_a_time(tmp_path, no_open_session):
+    with pytest.raises(RuntimeError, match="no trace session"):
+        obs.stop_trace()
+    obs.start_trace(str(tmp_path), device=False)
+    with pytest.raises(RuntimeError, match="already open"):
+        obs.start_trace(str(tmp_path), device=False)
+    obs.stop_trace()
+    assert obs.session_tracer() is None
+
+
+class TestClockTie:
+    def test_shift_from_bracketed_markers(self):
+        origin = 100.0
+        # three host events at 1, 2 and 3 ms after the origin, which the
+        # profiler stamped 250 us earlier on its own clock
+        brackets = [(origin + t - 1e-6, origin + t + 1e-6)
+                    for t in (1e-3, 2e-3, 3e-3)]
+        stamped = [750.0, 1750.5, 2749.5]
+        shift, spread = obs_session.clock_shift_us(origin, brackets, stamped)
+        assert shift == pytest.approx(250.0, abs=1e-6)
+        assert spread == pytest.approx(1.0, abs=1e-6)
+        assert obs_session.clock_shift_us(origin, [], []) == (None, None)
+
+    def test_tie_on_a_cpu_profiler_trace(self, model, tmp_path,
+                                         no_open_session):
+        """A marker of the test's own, bracketed by `perf_counter` like the
+        session's, lands where the header's shift says it should."""
+        ff, x, y = model
+        session = obs.start_trace(str(tmp_path), device=True)
+        ff.fit(x, y, epochs=1, verbose=False)
+        p0, p1 = obs_session.tie_marker("test_probe_mark")
+        ff.fit(x, y, epochs=1, verbose=False)
+        paths = obs.stop_trace()
+        header, events = read_events(paths["events"])
+        assert header["clock_tie_markers"] == 2 * obs_session.TIE_MARKERS
+        assert header["xplane"] == os.path.relpath(paths["xplane"],
+                                                   str(tmp_path))
+        # the ten markers agree with one another, and with the probe
+        assert 0 <= header["clock_tie_spread_us"] < 500
+        (stamped,) = obs_session.annotation_starts_us(paths["xplane"],
+                                                      "test_probe_mark")
+        host_us = ((p0 + p1) / 2 - session.tracer._origin) * 1e6
+        assert stamped + header["clock_shift_us"] == pytest.approx(
+            host_us, abs=500)
+        # the probe lies between the two fit spans on the tracer's timeline
+        fits = sorted((e for e in events if e["name"] == "fit"),
+                      key=lambda e: e["ts"])
+        assert fits[0]["ts"] + fits[0]["dur"] <= host_us <= fits[1]["ts"]
+
+
+def test_compile_phases_and_set_parameter_seconds():
+    ff = build_mlp()
+    phases = ff.compile_phases
+    assert all(v >= 0 for v in phases.values())
+    assert phases["search_s"] == 0.0   # no search budget
+    assert ff.set_parameter_s == 0.0
+    name = ff.get_layer_names()[0]
+    ff.set_parameter(name, ff.get_parameter(name))
+    first = ff.set_parameter_s
+    assert first > 0
+    ff.set_parameter(name, ff.get_parameter(name))
+    assert ff.set_parameter_s > first
+    assert obs.model_context(ff)["set_parameter_s"] == ff.set_parameter_s
+    assert obs.model_context(ff)["compile_phases"] == phases
