@@ -38,7 +38,9 @@ class SingleDataLoader:
         # labels stage on the loss-boundary layout (data-sharded), inputs
         # on the executor's batch layout (pipe-sharded under the
         # pipeline's sharded microbatch queue) — same contract as
-        # model._shard_batch, or the two staging paths would diverge
+        # model._shard_batch, which stages the host-resident batches
+        # below. What differs from `fit`: a loader never casts (both its
+        # paths hand the step the dataset's own dtype)
         sharding = (ffmodel.executor.batch_sharding()
                     if input_name is not None
                     else ffmodel.executor.label_sharding())
@@ -112,9 +114,11 @@ class SingleDataLoader:
                 self.data[start:start + self._local_bs], self._sharding,
                 global_rows=self.batch_size)
         if isinstance(self.data, np.ndarray):
-            # single transfer straight onto the batch sharding
-            return jax.device_put(self.data[start:start + self.batch_size],
-                                  self._sharding)
+            # the model's own staging: raw bytes straight onto the batch
+            # sharding, shaped on the device
+            return self.ff._shard_batch(
+                self.data[start:start + self.batch_size],
+                inputs=self.input_name is not None)
         return jax.lax.dynamic_slice_in_dim(self.data, start, self.batch_size,
                                             axis=0)
 

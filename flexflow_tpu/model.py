@@ -43,6 +43,16 @@ from flexflow_tpu.optimizers import Optimizer, SGDOptimizer
 from flexflow_tpu.tensor import Tensor
 
 
+# About the bytes of a staged batch's raw form that one host-to-device
+# transfer carries (`FFModel._shard_batch`). Small pieces keep a batch's
+# copy from being held up by those of the batches `fit` stages right
+# behind it: Inception's 275 MB batch in pieces of 16/8/4/2 MiB left a
+# v5e waiting 45/31/23/34 ms at the start of an epoch, 67-143 ms whole
+# (PERF.md section 6, PR 26). Under 4 MiB the host thread's enqueues
+# (0.24 ms each) take longer than the wire.
+_RAW_PIECE_BYTES = 4 << 20
+
+
 class FFModel:
     def __init__(self, config: Optional[FFConfig] = None):
         self.config = config or FFConfig()
@@ -1006,29 +1016,106 @@ class FFModel:
         self.set_parameter_s = 0.0
         self._iter = 0
         self._seq_execs: Dict[int, Any] = {}  # seq-length bucket executors
+        self._unpackers: Dict[Tuple, Any] = {}  # staging programs, by shape
         self._declared_seq_cache = -1  # lazily derived (-1 = not yet)
 
     # ======================= data staging ==================================
+    @staticmethod
+    def _stages_raw(arr) -> bool:
+        """Whether `_shard_batch` hands `arr` to the runtime as its raw
+        bytes: every host array of a single-controller run. A device
+        array has no host-side conversion to spare, and multi-controller
+        staging assembles per-process rows from host arrays."""
+        return jax.process_count() == 1 and not isinstance(arr, jax.Array)
+
+    def _unpacker(self, shape: Tuple[int, ...], raw_dtype, dtype, sharding):
+        """How a host batch of `shape` and `raw_dtype` is handed over and
+        the jitted program that makes of it the array of `dtype` on
+        `sharding`: (shards, bounds, unpack), one per key, compiled by the
+        first call that stages the shape. Every shard's rows are one run
+        of the batch's row-major elements; `bounds` cuts a run into the
+        pieces the host hands over one by one."""
+        key = (shape, np.dtype(raw_dtype), np.dtype(dtype), sharding)
+        plan = self._unpackers.get(key)
+        if plan is None:
+            shard_shape = sharding.shard_shape(shape)
+            rows = shard_shape[0] if shape else 1
+            shards = shape[0] // rows if shape else 1
+            row = int(np.prod(shape[1:]))
+            # whole rows to a piece: the device then shapes each piece on
+            # its own (1.6 against 3.4 ms for Inception's batch on a v5e)
+            per = max(1, round(_RAW_PIECE_BYTES / max(
+                1, row * np.dtype(raw_dtype).itemsize)))
+            bounds = [min(r, rows) * row
+                      for r in range(0, rows + per, per)]
+            k = len(bounds) - 1
+            # a batch layout shards dim 0 only: as 1-D, on the same axes
+            flat_spec = P(*sharding.spec[:1])
+
+            def unpack_batch(*pieces):
+                def local(*mine):   # a device's stretches, in order
+                    return (jnp.concatenate(mine).reshape(shard_shape)
+                            .astype(dtype))
+                return jax.shard_map(
+                    local, mesh=sharding.mesh, in_specs=(flat_spec,) * k,
+                    out_specs=sharding.spec)(*pieces)
+
+            # one piece can be donated (JAX matches it to the output by
+            # size); several cannot, and are freed as the call returns
+            plan = self._unpackers[key] = (
+                NamedSharding(sharding.mesh, flat_spec), shards, bounds,
+                jax.jit(unpack_batch, out_shardings=sharding,
+                        donate_argnums=(0,) if k == 1 else ()))
+        return plan
+
     def _shard_batch(self, arr: np.ndarray, cast: bool = False,
                      inputs: bool = False) -> jax.Array:
-        arr = jnp.asarray(arr)
-        if cast and jnp.issubdtype(arr.dtype, jnp.floating):
-            # activations flow in the compute dtype end-to-end (bf16 on
-            # TPU): ops emit outputs in their input dtype, so casting once
-            # at the graph boundary halves every activation's HBM traffic.
-            # Labels are staged without cast (loss math is f32).
-            arr = arr.astype(self.executor.compute_dtype)
         # inputs stage on the executor's batch layout (pipe-sharded under
         # the pipeline's sharded microbatch queue); labels stay on the
         # data-sharded loss layout
         sharding = (self.executor.batch_sharding() if inputs
                     else self.executor.label_sharding())
-        if jax.process_count() > 1:
-            # multi-controller SPMD: `arr` is the rows THIS host feeds;
-            # assemble the global batch from per-process shards
-            from flexflow_tpu import distributed as _dist
-            return _dist.stage_local_batch(np.asarray(arr), sharding)
-        return jax.device_put(arr, sharding)
+        if not self._stages_raw(arr):
+            arr = jnp.asarray(arr)
+            if cast and jnp.issubdtype(arr.dtype, jnp.floating):
+                arr = arr.astype(self.executor.compute_dtype)
+            if jax.process_count() > 1:
+                # multi-controller SPMD: `arr` is the rows THIS host feeds;
+                # assemble the global batch from per-process shards
+                from flexflow_tpu import distributed as _dist
+                return _dist.stage_local_batch(np.asarray(arr), sharding)
+            return jax.device_put(arr, sharding)
+        # The host hands over the batch's row-major bytes as 1-D views
+        # (no copy of a contiguous slice), straight onto the target
+        # devices. A 1-D array's device layout IS its byte order, so the
+        # runtime's host threads copy where a shaped array has them tile,
+        # pad and transpose (six threads x 25 ms for a float32
+        # [256, 3, 299, 299] on a v5e host, 120 thread-ms against 34); in
+        # pieces, because one piece is copied by one thread before its
+        # DMA starts (24 ms, then the wire's 20). Shape, cast and tiled
+        # layout are made on the device, at HBM speed.
+        arr = np.asarray(arr)
+        raw_dtype = jax.dtypes.canonicalize_dtype(arr.dtype)
+        flat = np.ascontiguousarray(arr, dtype=raw_dtype).reshape(-1)
+        dtype = raw_dtype
+        if cast and jnp.issubdtype(dtype, jnp.floating):
+            # activations flow in the compute dtype end-to-end (bf16 on
+            # TPU): ops emit outputs in their input dtype, so casting once
+            # at the graph boundary halves every activation's HBM traffic.
+            # Labels are staged without cast (loss math is f32).
+            dtype = self.executor.compute_dtype
+        flat_sharding, shards, bounds, unpack = self._unpacker(
+            arr.shape, raw_dtype, dtype, sharding)
+        run = bounds[-1]
+
+        def piece(lo, hi):
+            def of_shard(index):    # the slice of the piece a device holds
+                at = (index[0].start or 0) // (hi - lo) * run
+                return flat[at + lo:at + hi]
+            return jax.make_array_from_callback(
+                (shards * (hi - lo),), flat_sharding, of_shard)
+
+        return unpack(*(piece(lo, hi) for lo, hi in zip(bounds, bounds[1:])))
 
     def _local_batch_size(self, global_bs: int) -> int:
         """Rows of a `global_bs` batch this process feeds (== global_bs
@@ -1431,8 +1518,12 @@ class FFModel:
                 y_np = y[sl]
             with tracer.phase("device_put") as span:
                 if span is not None:   # None on the no-op tracer
-                    span.args = dict(bytes=sum(a.nbytes for a in xs_np)
-                                     + y_np.nbytes)
+                    staged = xs_np + [y_np]
+                    span.args = dict(
+                        bytes=sum(a.nbytes for a in staged),
+                        # of them, handed to the runtime unconverted
+                        raw_bytes=sum(a.nbytes for a in staged
+                                      if self._stages_raw(a)))
                 return (self._stage_inputs(xs_np),
                         self._shard_batch(y_np))
 

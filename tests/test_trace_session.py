@@ -103,7 +103,19 @@ class TestSessionArtifact:
         assert [e["step"] for e in sorted(steps, key=lambda e: e["ts"])] \
             == list(range(3 * STEPS))
         put = next(e for e in train if e["name"] == "device_put")
-        assert put["args"] == dict(bytes=32 * (8 + 1) * 4)
+        # every array of the step was a host array, handed over raw
+        assert put["args"] == dict(bytes=32 * (8 + 1) * 4,
+                                   raw_bytes=32 * (8 + 1) * 4)
+
+    def test_raw_bytes_leave_out_what_was_on_the_device(self, model, tmp_path,
+                                                        no_open_session):
+        ff, x, y = model
+        obs.start_trace(str(tmp_path), device=False)
+        ff.fit(jax.numpy.asarray(x), y, epochs=1, verbose=False)
+        _, events = read_events(obs.stop_trace()["events"])
+        puts = [e["args"] for e in events if e["name"] == "device_put"]
+        assert puts == [dict(bytes=32 * (8 + 1) * 4,
+                             raw_bytes=32 * 1 * 4)] * STEPS
 
     def test_stretch_between_calls_is_on_the_timeline(self, session_run):
         fits = sorted((e for e in session_run["events"]
