@@ -32,6 +32,8 @@ from flexflow_tpu.ops.base import Op, OpContext
 # copy under the master-weight mixed-precision regime (never collides with
 # op names, which come from Layer naming)
 COMPUTE_PARAMS_KEY = "__compute_params__"
+# beside a counter that is a mean over ops and steps: how many were added
+COUNT_SUFFIX = "#n"
 
 
 def settled_spec(spec: P) -> P:
@@ -397,7 +399,7 @@ class GraphExecutor:
             # cached: repeated refreshes (per-weight import loops) must not
             # retrace a fresh jit each call
             self._cast_jit = jax.jit(
-                lambda p: jax.tree.map(self._cast_leaf, p))
+                lambda p: self._cast_tree(p))
         out = self._cast_jit(params)
         if self.weight_update_sharding:
             out = jax.device_put(out, self.param_shardings(out))
@@ -407,6 +409,28 @@ class GraphExecutor:
         if jnp.issubdtype(x.dtype, jnp.floating):
             return x.astype(self.compute_dtype)
         return x
+
+    def _cast_tree(self, params):
+        """The compute copy of a params tree: floating leaves in the
+        compute dtype, but for the leaves an op states it needs whole
+        (``Op.full_precision_params``: a router's bias and weights, a
+        scan's decay rates — small, and rounding them moves every token
+        alike), which stay float32."""
+        keep = self._full_precision_leaves
+        if not keep:
+            return jax.tree.map(self._cast_leaf, params)
+
+        def cast(path, x):
+            # a leaf's last two keys are (op name, parameter name)
+            at = tuple(getattr(k, "key", None) for k in path[-2:])
+            return x if at in keep else self._cast_leaf(x)
+
+        return jax.tree_util.tree_map_with_path(cast, params)
+
+    @property
+    def _full_precision_leaves(self):
+        return {(n.op.name, p) for n in self.nodes
+                for p in getattr(n.op, "full_precision_params", ())}
 
     def param_shardings(self, params, master: bool = False):
         """NamedShardings tree for a params-shaped tree: the compute
@@ -446,8 +470,14 @@ class GraphExecutor:
         return ols[idx] if ols and idx < len(ols) else "NCHW"
 
     def run_graph(self, params, state, inputs: Dict[str, jax.Array],
-                  ctx: OpContext, nodes=None):
+                  ctx: OpContext, nodes=None, counters=None):
         """Evaluate ops in topo order; returns (values, new_state, aux_losses).
+
+        ``counters`` (a dict, filled in place) collects what ops count
+        during forward (``op._counters``: name -> ("sum" | "mean", value),
+        e.g. the expert layers' routing counts): sums add up over ops,
+        means keep a count beside them under ``name + COUNT_SUFFIX``. The
+        train step returns them with the metrics.
 
         aux_losses collects regularizer terms ops emit during forward (e.g.
         the MoE load-balance loss the reference computes inside Aggregate's
@@ -460,7 +490,7 @@ class GraphExecutor:
         aux_losses: List[jax.Array] = []
         self._run_nodes(nodes if nodes is not None else self.nodes,
                         params, state, inputs, values,
-                        new_state, aux_losses, ctx)
+                        new_state, aux_losses, ctx, counters=counters)
         # the designated output leaves in the boundary layout whatever the
         # execution layout of its producer was
         if self._output_layout(*self.final_ref) == "NHWC":
@@ -470,7 +500,7 @@ class GraphExecutor:
         return values, new_state, aux_losses
 
     def _run_nodes(self, nodes, params, state, inputs, values, new_state,
-                   aux_losses, ctx: OpContext):
+                   aux_losses, ctx: OpContext, counters=None):
         """Evaluate the given nodes in order, reading/writing the shared
         ``values`` dict (lets the pipeline executor run head/tail subsets
         around the shard_map'd body).
@@ -551,6 +581,14 @@ class GraphExecutor:
             if getattr(op, "_aux_loss", None) is not None:
                 aux_losses.append(op._aux_loss)
                 op._aux_loss = None
+            if getattr(op, "_counters", None) is not None:
+                if counters is not None:
+                    for cname, (kind, v) in op._counters.items():
+                        counters[cname] = counters.get(cname, 0.0) + v
+                        if kind == "mean":
+                            n = cname + COUNT_SUFFIX
+                            counters[n] = counters.get(n, 0.0) + 1.0
+                op._counters = None
             out_layouts = getattr(node, "output_layouts", None)
             for i, o in enumerate(outs):
                 spec = node.output_specs[i]
@@ -603,7 +641,11 @@ class GraphExecutor:
         region (ops/fused_update.py, bit-compatible with the reference
         triad); the rest take ``optimizer.update`` unchanged. No fused
         choices = exactly the pre-kernel-search call."""
-        fused = {n for n in self.fused_update_ops if n in params}
+        # sorted: the fused regions are traced in this order, and a set's
+        # order follows the process's hash seed, so that two processes
+        # would lower two different programs and miss each other's
+        # entries in the persistent compile cache
+        fused = sorted(n for n in self.fused_update_ops if n in params)
         if not fused:
             return self.optimizer.update(grads, opt_state, params)
         from flexflow_tpu.ops.fused_update import fused_optimizer_update
@@ -624,8 +666,10 @@ class GraphExecutor:
                 ctx = OpContext(training=True, rng=rng,
                                 compute_dtype=self.compute_dtype,
                                 mesh=self.mesh)
-                values, new_state, aux = self.run_graph(p, state, inputs, ctx,
-                                                        nodes=train_nodes)
+                counters = {}
+                values, new_state, aux = self.run_graph(
+                    p, state, inputs, ctx, nodes=train_nodes,
+                    counters=counters)
                 logits = values[self.final_ref]
                 # named for the device trace: an op's `op_name` holds
                 # `loss/` here and `optimizer_update/` below, beside the
@@ -634,9 +678,9 @@ class GraphExecutor:
                     loss = self._loss_value(logits, labels)
                     for a in aux:
                         loss = loss + a
-                return loss, (logits, new_state)
+                return loss, (logits, new_state, counters)
 
-            (loss, (logits, new_state)), grads = jax.value_and_grad(
+            (loss, (logits, new_state, counters)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(cparams)
             # gradient sync over the data axes is inserted by GSPMD here
@@ -657,8 +701,11 @@ class GraphExecutor:
                     # all-gather that rebuilds the replicated copy from the
                     # shards)
                     new_state[COMPUTE_PARAMS_KEY] = self._constrain_compute(
-                        jax.tree.map(self._cast_leaf, new_params))
+                        self._cast_tree(new_params))
             metric_vals = self.metrics.compute(logits, labels)
+            # what the ops counted leaves the step with the metrics and is
+            # read with them, once an epoch
+            metric_vals.update(counters)
             return new_params, new_opt_state, new_state, loss, metric_vals
 
         return train_step
@@ -673,6 +720,11 @@ class GraphExecutor:
                                       donate_argnums=(0, 1, 2))
             get_registry().inc("executor.train_step_jits")
             get_registry().gauge("executor.num_ops", len(self.nodes))
+            for gauge, kind in (("executor.ssm_ops", OperatorType.SSM_MIXER),
+                                ("executor.expert_ops",
+                                 OperatorType.MOE_LAYER)):
+                get_registry().gauge(gauge, sum(
+                    n.op.op_type == kind for n in self.nodes))
         return self._jit_train
 
     def make_multi_step(self, num_iters: int, stacked: bool = False):

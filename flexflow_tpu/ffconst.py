@@ -172,6 +172,11 @@ class OperatorType(enum.Enum):
     METRICS = enum.auto()
     OPTIMIZER = enum.auto()
     ALLREDUCE = enum.auto()
+    # appended (PR 27), so that every earlier member keeps its value:
+    # the Mamba-2 mixer (ops/ssm.py) and the dropless mixture-of-experts
+    # layer over the experts held here (ops/experts.py)
+    SSM_MIXER = enum.auto()
+    MOE_LAYER = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

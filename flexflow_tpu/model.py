@@ -201,9 +201,13 @@ class FFModel:
                             rope: bool = False, rope_theta: float = 10000.0,
                             kernel_initializer=None,
                             seq_parallel: Optional[str] = None,
+                            head_dim: int = 0,
                             name: Optional[str] = None) -> Tensor:
         """``seq_parallel='seq'`` runs the attention core as ring attention
-        over that mesh axis (context parallelism for long sequences)."""
+        over that mesh axis (context parallelism for long sequences).
+        ``head_dim`` is the width of a head where it is not
+        ``embed_dim // num_heads`` (a few wide heads on a model width they
+        do not divide; the softmax scale is ``head_dim ** -0.5``)."""
         layer = self._add_layer(OperatorType.MULTIHEAD_ATTENTION,
                                 [query, key, value], dict(
             embed_dim=embed_dim, num_heads=num_heads, kdim=kdim or embed_dim,
@@ -211,7 +215,44 @@ class FFModel:
             qkv_bias=qkv_bias, causal=causal,
             num_kv_heads=num_kv_heads or num_heads, rope=rope,
             rope_theta=rope_theta,
-            kernel_initializer=kernel_initializer, seq_parallel=seq_parallel), name)
+            kernel_initializer=kernel_initializer, seq_parallel=seq_parallel,
+            **({"head_dim": head_dim} if head_dim else {})), name)
+        return self._finish(layer)
+
+    def ssm_mixer(self, input: Tensor, num_heads: int, head_dim: int,
+                  state_size: int, n_groups: int = 1, conv_kernel: int = 4,
+                  chunk_size: int = 128, eps: float = 1e-5,
+                  time_step_min: float = 1e-3, time_step_max: float = 1e-1,
+                  time_step_floor: float = 1e-4, kernel_initializer=None,
+                  name: Optional[str] = None) -> Tensor:
+        """Mamba-2 mixer over [B, S, E] (ops/ssm.py): input projection,
+        causal depthwise convolution, the chunked state-space scan, the
+        gated group RMSNorm, output projection."""
+        layer = self._add_layer(OperatorType.SSM_MIXER, [input], dict(
+            num_heads=num_heads, head_dim=head_dim, state_size=state_size,
+            n_groups=n_groups, conv_kernel=conv_kernel,
+            chunk_size=chunk_size, eps=eps, time_step_min=time_step_min,
+            time_step_max=time_step_max, time_step_floor=time_step_floor,
+            kernel_initializer=kernel_initializer), name)
+        return self._finish(layer)
+
+    def moe_layer(self, input: Tensor, n_experts: int, k: int,
+                  hidden_size: int, shared_width: int = 0,
+                  experts_held: int = 0, expert_offset: int = 0,
+                  routed_scaling: float = 1.0, norm_topk: bool = True,
+                  slot_slack: float = 0.5, kernel_initializer=None,
+                  name: Optional[str] = None) -> Tensor:
+        """Dropless mixture-of-experts layer over [B, S, D] with sigmoid
+        top-k routing over all ``n_experts``, computing the part of the
+        ``experts_held`` experts from ``expert_offset`` (all of them by
+        default) and a shared expert (ops/experts.py ``MoELayer``)."""
+        layer = self._add_layer(OperatorType.MOE_LAYER, [input], dict(
+            n_experts=n_experts, k=k, hidden_size=hidden_size,
+            shared_width=shared_width,
+            experts_held=experts_held or n_experts,
+            expert_offset=expert_offset, routed_scaling=routed_scaling,
+            norm_topk=norm_topk, slot_slack=slot_slack,
+            kernel_initializer=kernel_initializer), name)
         return self._finish(layer)
 
     # ---- elementwise -------------------------------------------------------
@@ -1443,9 +1484,15 @@ class FFModel:
                     # a resumed run's partial epoch accumulated only the
                     # EXECUTED steps' totals — average over those, not
                     # the full grid
-                    self._metrics_acc.update(dict(mtotals or {}),
-                                             bs * epoch_executed)
+                    totals = dict(mtotals or {})
+                    # what the ops counted (executor counters, named
+                    # "<layer>/<what>") came with the metrics: one fetch
+                    counted = {k: totals.pop(k) for k in list(totals)
+                               if "/" in k}
+                    self._metrics_acc.update(totals, bs * epoch_executed)
                     self._last_loss = float(loss)
+                    if counted:
+                        self._publish_op_counters(counted)
             if verbose and epoch_executed:
                 # fully-skipped epochs (inside the restored checkpoint)
                 # have nothing to report
@@ -1464,6 +1511,21 @@ class FFModel:
         if verbose:
             print(f"ELAPSED TIME = {elapsed:.4f}s, THROUGHPUT = {thr:.2f} samples/s")
         return thr
+
+    def _publish_op_counters(self, counted):
+        """An epoch's op counters to the registry's gauges and to
+        ``self.op_counters``: sums as they are, means over the ops and
+        steps that were added up."""
+        from flexflow_tpu.executor import COUNT_SUFFIX
+        from flexflow_tpu.obs.registry import get_registry
+        host = {k: float(v) for k, v in jax.device_get(counted).items()}
+        self.op_counters = {}
+        for k, v in host.items():
+            if k.endswith(COUNT_SUFFIX):
+                continue
+            n = host.get(k + COUNT_SUFFIX)
+            self.op_counters[k] = v / n if n else v
+            get_registry().gauge(k, self.op_counters[k])
 
     def fit(self, x=None, y=None, batch_size: Optional[int] = None,
             epochs: Optional[int] = None, verbose: bool = True,

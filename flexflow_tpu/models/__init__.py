@@ -17,6 +17,7 @@ from flexflow_tpu.models.xdl import create_xdl, XDLConfig
 from flexflow_tpu.models.moe_model import create_moe, create_moe_encoder, MoEConfig
 from flexflow_tpu.models.llama import (create_llama, import_hf_weights,
                                        LlamaModelConfig)
+from flexflow_tpu.models.decoder import create_decoder, DecoderConfig
 
 __all__ = [
     "create_transformer",
@@ -39,4 +40,5 @@ __all__ = [
     "create_moe_encoder",
     "MoEConfig",
     "create_llama", "import_hf_weights", "LlamaModelConfig",
+    "create_decoder", "DecoderConfig",
 ]

@@ -1,0 +1,147 @@
+"""One decoder language-model builder, driven by a layer pattern.
+
+A model is an embedding, a stack of blocks and a head; the pattern names
+each block by one letter, and the sizes of each kind come from keys that
+mirror the public ``config.json`` files (``nemotron_h``'s names where two
+families differ). New scope vs the reference, whose zoo has one file a
+family.
+
+    M   x + ssm_mixer(rms_norm(x))        Mamba-2 mixer (ops/ssm.py)
+    E   x + moe_layer(rms_norm(x))        sigmoid top-k experts over the
+                                          experts held, a shared expert
+    *   x + attention(rms_norm(x))        causal GQA, no rotary embedding
+    -   x + mlp(rms_norm(x))              down(relu(up(x))^2), no gate
+    L   the Llama block: rotary GQA attention, then a SwiGLU MLP, each
+        behind its own norm and residual (models/llama.py builds on it)
+
+After the last block ``rms_norm`` and the head, ``logits = x W_head``
+(untied); train with ``SPARSE_CATEGORICAL_CROSSENTROPY`` on labels
+``[B, S]``.
+
+A chip's share of a layer is a configuration like any other: fewer heads
+(``mamba_num_heads`` with ``n_groups``, ``num_attention_heads`` with
+``num_key_value_heads``), a slice of the vocabulary (``vocab_size``), and
+``experts_held`` of the ``n_routed_experts`` from ``expert_offset``; the
+widths (``hidden_size``, the head sizes, the expert widths, the router's
+``n_routed_experts`` outputs) stay the model's.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+from flexflow_tpu.config import FFConfig
+from flexflow_tpu.ffconst import ActiMode, DataType
+from flexflow_tpu.model import FFModel
+
+
+@dataclasses.dataclass
+class DecoderConfig:
+    # defaults are a test-size model
+    hybrid_override_pattern: str = "ME*"
+    vocab_size: int = 256
+    hidden_size: int = 64
+    layer_norm_epsilon: float = 1e-5
+    # attention (`*`, `L`)
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    head_dim: int = 0                       # 0: hidden_size // heads
+    rope_theta: float = 10000.0             # `L` only
+    # Mamba-2 mixer (`M`)
+    mamba_num_heads: int = 4
+    mamba_head_dim: int = 16
+    n_groups: int = 1
+    ssm_state_size: int = 16
+    conv_kernel: int = 4
+    chunk_size: int = 8
+    time_step_min: float = 1e-3
+    time_step_max: float = 1e-1
+    time_step_floor: float = 1e-4
+    # experts (`E`)
+    n_routed_experts: int = 8
+    num_experts_per_tok: int = 2
+    moe_intermediate_size: int = 32
+    moe_shared_expert_intermediate_size: int = 64
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    experts_held: int = 0                   # 0: all of them
+    expert_offset: int = 0
+    slot_slack: float = 0.5
+    # dense MLP (`-`, `L`)
+    intermediate_size: int = 128
+    batch_size: int = 2
+    seq_length: int = 16
+    seq_parallel: Optional[str] = None      # 'seq': ring attention
+
+
+def _attention(ff, h, cfg, name, rope):
+    return ff.multihead_attention(
+        h, h, h, cfg.hidden_size, cfg.num_attention_heads, bias=False,
+        causal=True, num_kv_heads=cfg.num_key_value_heads, rope=rope,
+        rope_theta=cfg.rope_theta, seq_parallel=cfg.seq_parallel,
+        head_dim=cfg.head_dim, name=name)
+
+
+def _llama_block(ff, t, i, cfg):
+    h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"l{i}_input_ln")
+    t = ff.add(t, _attention(ff, h, cfg, f"l{i}_attn", rope=True),
+               name=f"l{i}_res1")
+    # SwiGLU MLP: down(silu(gate(x)) * up(x))
+    h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"l{i}_post_ln")
+    gate = ff.dense(h, cfg.intermediate_size, use_bias=False,
+                    name=f"l{i}_gate_proj")
+    up = ff.dense(h, cfg.intermediate_size, use_bias=False,
+                  name=f"l{i}_up_proj")
+    silu = ff.multiply(gate, ff.sigmoid(gate, name=f"l{i}_sig"),
+                       name=f"l{i}_silu")
+    h = ff.multiply(silu, up, name=f"l{i}_swiglu")
+    h = ff.dense(h, cfg.hidden_size, use_bias=False, name=f"l{i}_down_proj")
+    return ff.add(t, h, name=f"l{i}_res2")
+
+
+def _mixer(ff, h, letter, i, cfg):
+    name = f"b{i}_mixer"
+    if letter == "M":
+        return ff.ssm_mixer(
+            h, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size,
+            n_groups=cfg.n_groups, conv_kernel=cfg.conv_kernel,
+            chunk_size=cfg.chunk_size, eps=cfg.layer_norm_epsilon,
+            time_step_min=cfg.time_step_min,
+            time_step_max=cfg.time_step_max,
+            time_step_floor=cfg.time_step_floor, name=name)
+    if letter == "E":
+        return ff.moe_layer(
+            h, cfg.n_routed_experts, cfg.num_experts_per_tok,
+            cfg.moe_intermediate_size,
+            shared_width=cfg.moe_shared_expert_intermediate_size,
+            experts_held=cfg.experts_held, expert_offset=cfg.expert_offset,
+            routed_scaling=cfg.routed_scaling_factor,
+            norm_topk=cfg.norm_topk_prob, slot_slack=cfg.slot_slack,
+            name=name)
+    if letter == "*":
+        return _attention(ff, h, cfg, name, rope=False)
+    if letter == "-":
+        up = ff.dense(h, cfg.intermediate_size, use_bias=False,
+                      activation=ActiMode.AC_MODE_RELU, name=f"b{i}_up")
+        return ff.dense(ff.multiply(up, up, name=f"b{i}_sq"),
+                        cfg.hidden_size, use_bias=False, name=name)
+    raise ValueError(f"decoder pattern: unknown block letter {letter!r} "
+                     f"(known: M E * - L)")
+
+
+def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
+    ff = FFModel(ff_config or FFConfig(batch_size=cfg.batch_size))
+    ids = ff.create_tensor((cfg.batch_size, cfg.seq_length),
+                           dtype=DataType.INT32, name="input_ids")
+    t = ff.embedding(ids, cfg.vocab_size, cfg.hidden_size,
+                     name="embed_tokens")
+    for i, letter in enumerate(cfg.hybrid_override_pattern):
+        if letter == "L":
+            t = _llama_block(ff, t, i, cfg)
+            continue
+        h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"b{i}_norm")
+        t = ff.add(t, _mixer(ff, h, letter, i, cfg), name=f"b{i}_res")
+    t = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name="final_ln")
+    t = ff.dense(t, cfg.vocab_size, use_bias=False, name="lm_head")
+    return ff

@@ -37,39 +37,18 @@ class LlamaModelConfig:
 
 
 def create_llama(cfg: LlamaModelConfig, ff_config: FFConfig = None) -> FFModel:
-    ff = FFModel(ff_config or FFConfig(batch_size=cfg.batch_size))
-    from flexflow_tpu.ffconst import DataType
+    """The pattern ``L`` repeated: models/decoder.py builds it."""
+    from flexflow_tpu.models.decoder import DecoderConfig, create_decoder
 
-    ids = ff.create_tensor((cfg.batch_size, cfg.seq_length),
-                           dtype=DataType.INT32, name="input_ids")
-    t = ff.embedding(ids, cfg.vocab_size, cfg.hidden_size,
-                     name="embed_tokens")
-    for i in range(cfg.num_hidden_layers):
-        # attention sublayer (pre-norm, causal, RoPE, GQA)
-        h = ff.rms_norm(t, eps=cfg.rms_norm_eps, name=f"l{i}_input_ln")
-        a = ff.multihead_attention(
-            h, h, h, cfg.hidden_size, cfg.num_attention_heads,
-            bias=False, causal=True,
-            num_kv_heads=cfg.num_key_value_heads,
-            rope=True, rope_theta=cfg.rope_theta,
-            seq_parallel=cfg.seq_parallel,
-            name=f"l{i}_attn")
-        t = ff.add(t, a, name=f"l{i}_res1")
-        # SwiGLU MLP: down(silu(gate(x)) * up(x))
-        h = ff.rms_norm(t, eps=cfg.rms_norm_eps, name=f"l{i}_post_ln")
-        gate = ff.dense(h, cfg.intermediate_size, use_bias=False,
-                        name=f"l{i}_gate_proj")
-        up = ff.dense(h, cfg.intermediate_size, use_bias=False,
-                      name=f"l{i}_up_proj")
-        silu = ff.multiply(gate, ff.sigmoid(gate, name=f"l{i}_sig"),
-                           name=f"l{i}_silu")
-        h = ff.multiply(silu, up, name=f"l{i}_swiglu")
-        h = ff.dense(h, cfg.hidden_size, use_bias=False,
-                     name=f"l{i}_down_proj")
-        t = ff.add(t, h, name=f"l{i}_res2")
-    t = ff.rms_norm(t, eps=cfg.rms_norm_eps, name="final_ln")
-    t = ff.dense(t, cfg.vocab_size, use_bias=False, name="lm_head")
-    return ff
+    return create_decoder(DecoderConfig(
+        hybrid_override_pattern="L" * cfg.num_hidden_layers,
+        vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
+        intermediate_size=cfg.intermediate_size,
+        num_attention_heads=cfg.num_attention_heads,
+        num_key_value_heads=cfg.num_key_value_heads,
+        layer_norm_epsilon=cfg.rms_norm_eps, rope_theta=cfg.rope_theta,
+        batch_size=cfg.batch_size, seq_length=cfg.seq_length,
+        seq_parallel=cfg.seq_parallel), ff_config)
 
 
 def import_hf_weights(ff: FFModel, hf_model) -> int:

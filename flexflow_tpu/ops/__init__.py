@@ -18,6 +18,7 @@ import flexflow_tpu.ops.embedding  # noqa: F401
 import flexflow_tpu.ops.reduce  # noqa: F401
 import flexflow_tpu.ops.moe  # noqa: F401
 import flexflow_tpu.ops.experts  # noqa: F401
+import flexflow_tpu.ops.ssm  # noqa: F401
 import flexflow_tpu.ops.parallel_ops  # noqa: F401
 
 __all__ = ["Op", "OpRegistry", "register_op"]

@@ -80,7 +80,10 @@ class MultiHeadAttention(Op):
         self.num_heads = p["num_heads"]
         self.kdim = p.get("kdim") or self.embed_dim
         self.vdim = p.get("vdim") or self.embed_dim
-        self.head_dim = self.embed_dim // self.num_heads
+        # a head's width is a property of its own where the model gives
+        # one (a few wide heads on a width they do not divide); the
+        # default is the split of the model width
+        self.head_dim = p.get("head_dim") or self.embed_dim // self.num_heads
         self.dropout = p.get("dropout", 0.0)
         self.causal = p.get("causal", False)
         self.use_bias = p.get("bias", True)

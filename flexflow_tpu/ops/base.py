@@ -56,8 +56,27 @@ class OpContext:
         return sub
 
 
+def scoped(name: str, fn: Callable) -> Callable:
+    """`fn` as a nested jit called `name`, for the device trace: every
+    instruction it lowers to carries `jit(<name>)` in its `op_name`,
+    forward (`jvp(jit(name))`) and backward (`transpose(jvp(jit(name)))`).
+    A `jax.named_scope` alone is not enough on the TPU: in a large step
+    the compiled program's metadata keeps the scope only for instructions
+    that come from a nested call (read on the v5e, PR 27: `dot_general`
+    where the same step compiled for a described chip had
+    `jit(train_step)/jvp(ssm_mixer)/ssd_scan/dot_general`). XLA inlines
+    the call, so nothing changes in what runs."""
+    def call(*args):
+        return fn(*args)
+
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call)
+
+
 class Op:
     op_type: OperatorType = OperatorType.NOOP
+    # parameter names the executor keeps in float32 in the compute copy
+    full_precision_params: Tuple[str, ...] = ()
 
     def __init__(self, layer: Layer, input_shapes: Sequence[Tuple[int, ...]]):
         self.layer = layer

@@ -24,9 +24,11 @@ import jax.numpy as jnp
 
 from flexflow_tpu.ffconst import OperatorType
 from flexflow_tpu.initializers import DefaultWeightInitializer
-from flexflow_tpu.ops.base import DimRole, Op, OpContext, register_op
-from flexflow_tpu.ops.moe import (expert_capacity, load_balance_loss,
-                                  make_dispatch_tensors)
+from flexflow_tpu.ops.base import (DimRole, Op, OpContext, register_op,
+                                   scoped)
+from flexflow_tpu.ops.moe import (expert_capacity, grouped_matmul,
+                                  load_balance_loss, make_dispatch_tensors,
+                                  route_held_experts, route_scores)
 
 
 @register_op(OperatorType.EXPERTS)
@@ -103,3 +105,190 @@ class Experts(Op):
         e, h = self.n_experts, self.hidden_size
         d = self.input_shapes[0][-1]
         return e * (d * h + h + h * d + d)
+
+
+def squared_relu(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+@register_op(OperatorType.MOE_LAYER)
+class MoELayer(Op):
+    """A mixture-of-experts feed-forward layer over the experts HELD here:
+    input [B, S, D] -> [B, S, D].
+
+        s = sigmoid(x W_r)            float32, over all `n_experts`
+        top-k of s + b; w_j = s_j / (sum of the k + 1e-20) * routed_scaling
+        expert_j(x) = relu(x U_j)^2 D_j        (no gate, no bias)
+        out = sum over the chosen j that are held of w_j expert_j(x)
+              + shared(x)             (the same form, `shared_width` wide)
+
+    The layer is told which experts it holds (`experts_held` of them from
+    `expert_offset`); it routes over all `n_experts` and computes its own
+    experts' part. A (token, slot) pair routed to an expert that is not
+    held contributes nothing: the chip that holds it adds that part. On
+    one chip the layer runs without an exchange, and nothing here stands
+    in for one.
+
+    Routing is dropless for the held experts: the pairs are sorted by
+    expert into ONE buffer whose rows are the expected number of held
+    pairs, tokens * k * held / n_experts, times (1 + `slot_slack`), and a
+    grouped matrix product runs over the buffer. There is no per-expert
+    capacity. Pairs beyond the buffer are counted (`moe/overflow_slots`,
+    with `moe/slots_held` and `moe/load_max_over_mean` beside it: they
+    leave the step with the metrics and are read once an epoch).
+
+    Weights: w_router [D, n_experts], e_bias [n_experts] (b, the
+    score-correction bias: it enters the choice only, so its gradient is
+    exactly zero and no optimizer moves it; models that balance their
+    experts without an auxiliary loss adjust it outside the gradient),
+    w_up [held, D, F], w_down [held, F, D], and with a shared expert ws_up
+    [D, Fs], ws_down [Fs, D]. The router's two leaves stay float32 in the
+    compute copy: a bias rounded to bfloat16 moves the choice of every
+    token alike.
+    """
+
+    full_precision_params = ("w_router", "e_bias")
+
+    def __init__(self, layer, input_shapes):
+        p = layer.properties
+        self.n_experts = p["n_experts"]
+        self.experts_held = p.get("experts_held") or self.n_experts
+        self.expert_offset = p.get("expert_offset", 0)
+        self.k = p["k"]
+        self.hidden_size = p["hidden_size"]
+        self.shared_width = p.get("shared_width", 0)
+        self.routed_scaling = p.get("routed_scaling", 1.0)
+        self.norm_topk = p.get("norm_topk", True)
+        self.slot_slack = p.get("slot_slack", 0.5)
+        if self.expert_offset + self.experts_held > self.n_experts:
+            raise ValueError(
+                f"moe_layer '{layer.name}': experts {self.expert_offset}.."
+                f"{self.expert_offset + self.experts_held} held of "
+                f"{self.n_experts}")
+        self.kernel_init = (p.get("kernel_initializer")
+                            or DefaultWeightInitializer())
+        self._counters = None
+        super().__init__(layer, input_shapes)
+
+    def compute_output_shapes(self):
+        return [tuple(self.input_shapes[0])]
+
+    @property
+    def tokens(self):
+        b, s, _ = self.input_shapes[0]
+        return b * s
+
+    @property
+    def buffer_rows(self):
+        """Rows of the one local buffer: every pair if all experts are
+        held, else the expected held pairs with the slack, in 128s."""
+        pairs = self.tokens * self.k
+        if self.experts_held == self.n_experts:
+            rows = pairs
+        else:
+            rows = min(pairs, int(pairs * self.experts_held / self.n_experts
+                                  * (1.0 + self.slot_slack)) + 1)
+        return -(-rows // 128) * 128
+
+    def init_params(self, rng):
+        d = self.input_shapes[0][-1]
+        e, f = self.experts_held, self.hidden_size
+        ks = jax.random.split(rng, 5)
+        params = {
+            "w_router": self.kernel_init(ks[0], (d, self.n_experts)),
+            "e_bias": jnp.zeros((self.n_experts,)),
+            "w_up": self.kernel_init(ks[1], (e, d, f)),
+            "w_down": self.kernel_init(ks[2], (e, f, d)),
+        }
+        if self.shared_width:
+            params["ws_up"] = self.kernel_init(ks[3], (d, self.shared_width))
+            params["ws_down"] = self.kernel_init(ks[4],
+                                                 (self.shared_width, d))
+        return params
+
+    def forward(self, params, inputs, ctx: OpContext):
+        (x,) = inputs
+        cd = ctx.compute_dtype
+        b, s, d = x.shape
+        rows = self.buffer_rows
+
+        def route(params, xt):
+            logits = jnp.dot(xt.astype(jnp.float32),
+                             params["w_router"].astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            weights, experts = route_scores(
+                jax.nn.sigmoid(logits), params["e_bias"].astype(jnp.float32),
+                self.k, self.norm_topk, self.routed_scaling)
+            r = route_held_experts(experts, self.experts_held,
+                                   self.expert_offset, rows)
+            token = r["slot"] // self.k
+            w_row = jnp.where(r["valid"], weights.reshape(-1)[r["slot"]],
+                              0.0)
+            return xt[token].astype(cd), token, w_row, r
+
+        def experts_held(params, x_buf, group_sizes):
+            h = grouped_matmul(x_buf, params["w_up"].astype(cd), group_sizes)
+            h = squared_relu(h.astype(jnp.float32)).astype(cd)
+            return grouped_matmul(h, params["w_down"].astype(cd),
+                                  group_sizes)
+
+        def shared(params, xt):
+            hs = jnp.dot(xt.astype(cd), params["ws_up"].astype(cd),
+                         preferred_element_type=jnp.float32)
+            return jnp.dot(squared_relu(hs).astype(cd),
+                           params["ws_down"].astype(cd),
+                           preferred_element_type=jnp.float32)
+
+        def layer(params, x):
+            xt = x.reshape(b * s, d)
+            x_buf, token, w_row, r = scoped("moe_route", route)(params, xt)
+            o = scoped("moe_grouped_matmul", experts_held)(
+                params, x_buf, r["group_sizes"])
+            # rows past the groups are zero, and their weight is
+            o = o.astype(jnp.float32) * w_row[:, None]
+            y = jnp.zeros((b * s, d), jnp.float32).at[token].add(o)
+            if self.shared_width:
+                y = y + scoped("moe_shared", shared)(params, xt)
+            return y.reshape(b, s, d).astype(x.dtype), r["load"], \
+                r["overflow"]
+
+        y, load, overflow = scoped("moe_layer", layer)(params, x)
+        load = load.astype(jnp.float32)
+        self._counters = {
+            "moe/slots_held": ("sum", jnp.sum(load)),
+            "moe/overflow_slots": ("sum", overflow.astype(jnp.float32)),
+            "moe/load_max_over_mean": (
+                "mean", jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0)),
+        }
+        return [y]
+
+    def output_dim_roles(self):
+        # routing sorts the tokens of the whole batch into one buffer: the
+        # sequence dim is not independently shardable
+        return [(DimRole.SAMPLE, DimRole.OTHER, DimRole.CHANNEL)]
+
+    def flops(self):
+        """Forward FLOPs of the work done here: the router over all
+        experts, the expected held pairs through two matrices, the shared
+        expert."""
+        d = self.input_shapes[0][-1]
+        t = self.tokens
+        pairs = t * self.k * self.experts_held / self.n_experts
+        return int(2 * t * d * self.n_experts
+                   + 4 * pairs * d * self.hidden_size
+                   + 4 * t * d * self.shared_width)
+
+    def interior_bytes(self):
+        """Kept for the backward pass besides the output: the buffer's
+        rows in and between the two products, the shared expert's hidden
+        activations, the scores."""
+        d = self.input_shapes[0][-1]
+        return (self.buffer_rows * (d + 2 * self.hidden_size)
+                + self.tokens * 2 * self.shared_width
+                ) * self.dtype.size + 4 * self.tokens * self.n_experts
+
+    def params_elems(self):
+        d = self.input_shapes[0][-1]
+        return ((d + 1) * self.n_experts
+                + 2 * self.experts_held * d * self.hidden_size
+                + 2 * d * self.shared_width)

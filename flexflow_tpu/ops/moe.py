@@ -1,4 +1,5 @@
-"""Mixture-of-Experts ops: GroupBy, Aggregate, AggregateSpec, Cache.
+"""Mixture-of-Experts ops: GroupBy, Aggregate, AggregateSpec, Cache, and
+the routing and grouped product of the dropless layer (ops/experts.py).
 
 Analogs of src/ops/{group_by,aggregate,aggregate_spec,cache}.cc/.cu.
 TPU re-design: the reference scatters tokens into per-expert CUDA buffers
@@ -7,10 +8,21 @@ is expressed GShard-style — one-hot dispatch/combine tensors with a fixed
 per-expert capacity (capacity factor `alpha`, same knob as the reference's
 Group_by alpha) — lowered to einsums on the MXU, and to all_to_all over the
 'expert' mesh axis when experts are sharded (see parallel/expert.py).
+
+That one-hot form stays for the ops with the upstream signature
+(GROUP_BY / AGGREGATE / AGGREGATE_SPEC) and for the toy `Experts` op whose
+exchange across an 'expert' axis is built on it. The layer a real
+mixture-of-experts model uses (`MoELayer`) does not go through it: its
+tensor would have tokens x k x experts x capacity elements and drops what
+overflows a per-expert capacity. `route_held_experts` and
+`grouped_matmul` below are its routing and its product: slots sorted by
+expert into one buffer, no per-expert capacity, every dropped slot
+counted.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import List
 
@@ -19,6 +31,131 @@ import jax.numpy as jnp
 
 from flexflow_tpu.ffconst import OperatorType
 from flexflow_tpu.ops.base import DimRole, Op, OpContext, register_op
+
+
+def route_scores(scores, bias, k: int, norm_topk: bool, scaling: float):
+    """scores [T, E] float32, bias [E] -> (weights [T, k] float32, experts
+    [T, k] int32): the k largest of scores + bias a token (the bias takes
+    part in the choice only), their weights s_j / (sum of the k + 1e-20)
+    if `norm_topk`, times `scaling`."""
+    _, idx = jax.lax.top_k(scores + bias, k)
+    top = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk:
+        top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    return top * scaling, idx.astype(jnp.int32)
+
+
+def route_held_experts(experts, held: int, offset: int, rows: int):
+    """Sort the (token, slot) pairs whose expert is one of the `held`
+    experts [offset, offset + held) into one buffer of `rows` rows, by
+    expert; pairs routed elsewhere contribute nothing here.
+
+    experts [T, k] int32 -> dict of
+      slot        [rows] int32  flat index t * k + j of the pair in a row
+      valid       [rows] bool   the row holds a pair of a held expert
+      group_sizes [held] int32  rows of each held expert, in order, cut so
+                                that their sum is at most `rows`
+      load        [held] int32  pairs of each held expert before the cut
+      overflow    []     int32  held pairs that found no row (0 unless the
+                                buffer is too small): counted, never
+                                silently dropped
+    """
+    flat = experts.reshape(-1) - offset
+    here = (flat >= 0) & (flat < held)
+    key = jnp.where(here, flat, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    # a buffer rounded up past the number of pairs: the rest is not valid
+    order = jnp.pad(order, (0, max(0, rows - order.shape[0])))
+    load = jnp.sum(jax.nn.one_hot(key, held + 1, dtype=jnp.int32),
+                   axis=0)[:held]
+    ends = jnp.minimum(jnp.cumsum(load), rows)
+    group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    n_rows = ends[-1]
+    return dict(slot=order[:rows],
+                valid=jnp.arange(rows, dtype=jnp.int32) < n_rows,
+                group_sizes=group_sizes, load=load,
+                overflow=jnp.sum(load) - n_rows)
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    """Tile sizes of the megablox kernels for an [m, k] x [g, k, n]
+    product (and, with the roles of k and n as the caller gives them, of
+    its two backward products): the widest row tile up to 512 that
+    divides m; a dimension up to 1024 whole, a longer one in its largest
+    divisor that is a multiple of 128, or in 1024s with a ragged last
+    tile, which the kernels mask. At these sizes the operand, accumulator
+    and output tiles stay under the compiler's 16 MiB of scoped VMEM."""
+    def tile(dim):
+        if dim <= 1024:
+            return dim
+        return next((t for t in range(1024, 127, -128) if dim % t == 0),
+                    1024)
+
+    tm = next(t for t in (512, 256, 128) if m % t == 0)
+    return tm, tile(k), tile(n)
+
+
+def _megablox():
+    # the package's `gmm` name is its differentiable wrapper with one
+    # tiling for all three products; the kernels are in the module
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _megablox_gmm(lhs, rhs, group_sizes, interpret):
+    backend = _megablox()
+    m, k = lhs.shape
+    out = backend.gmm(lhs, rhs, group_sizes, lhs.dtype,
+                      _gmm_tiling(m, k, rhs.shape[2]), interpret=interpret)
+    return _zero_past(out, group_sizes)
+
+
+def _zero_past(rows, group_sizes):
+    """The kernels leave the rows past the groups' sum unwritten."""
+    covered = jnp.arange(rows.shape[0]) < jnp.sum(group_sizes)
+    return jnp.where(covered[:, None], rows, 0)
+
+
+def _megablox_fwd(lhs, rhs, group_sizes, interpret):
+    return _megablox_gmm(lhs, rhs, group_sizes, interpret), (
+        lhs, rhs, group_sizes)
+
+
+def _megablox_bwd(interpret, res, grad):
+    backend = _megablox()
+    lhs, rhs, group_sizes = res
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    # d lhs = grad [m, n] x rhs^T: contracts n; d rhs[g] = lhs_g^T grad_g
+    d_lhs = _zero_past(
+        backend.gmm(grad, rhs, group_sizes, lhs.dtype, _gmm_tiling(m, n, k),
+                    transpose_rhs=True, interpret=interpret), group_sizes)
+    d_rhs = backend.tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
+                         _gmm_tiling(m, k, n), num_actual_groups=rhs.shape[0],
+                         interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_megablox_gmm.defvjp(_megablox_fwd, _megablox_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """lhs [m, k] rows sorted by group, rhs [g, k, n], group_sizes [g]
+    (sum <= m) -> [m, n]: rows of group i times rhs[i]; rows past the
+    groups' sum are zero, and so is their gradient. On the TPU (and
+    under FLEXFLOW_TPU_PALLAS=interpret) the Pallas megablox kernels that
+    ship with JAX, with tiles sized here and row tiles of at least 128
+    (m must be a multiple of 128); elsewhere `lax.ragged_dot`."""
+    from flexflow_tpu.ops.pallas_kernels import pallas_mode
+    mode = pallas_mode()
+    if mode != "off" and lhs.shape[0] % 128 == 0:
+        return _megablox_gmm(lhs, rhs, group_sizes, mode == "interpret")
+    return _zero_past(
+        jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                           preferred_element_type=jnp.float32
+                           ).astype(lhs.dtype), group_sizes)
 
 
 def expert_capacity(batch: int, k: int, n_experts: int, alpha: float) -> int:

@@ -357,7 +357,10 @@ class PipelineGraphExecutor(GraphExecutor):
         return GraphExecutor.batch_sharding(self)
 
     # ---- graph traversal (head -> pipeline -> tail) -----------------------
-    def run_graph(self, params, state, inputs, ctx: OpContext, nodes=None):
+    def run_graph(self, params, state, inputs, ctx: OpContext, nodes=None,
+                  counters=None):
+        # `counters` collects from the head and the tail; what an op inside
+        # the stage body counts stays inside the shard_map and is not read
         # `nodes` (the base executor's Conv+BN-folded inference list) is
         # ignored: pipeline bodies are transformer blocks — nothing folds —
         # and the head/tail partition is fixed at construction
@@ -367,7 +370,7 @@ class PipelineGraphExecutor(GraphExecutor):
         new_state: Dict[str, Any] = {}
         aux: List = []
         self._run_nodes(self._head, params, state, inputs, values,
-                        new_state, aux, ctx)
+                        new_state, aux, ctx, counters=counters)
         if self.pb.body_in[0] == "input":
             x = inputs[self.pb.body_in[1]]
         else:
@@ -390,5 +393,5 @@ class PipelineGraphExecutor(GraphExecutor):
                 y, NamedSharding(self.mesh, spec))
         values[(self.pb.body_out[1], self.pb.body_out[2])] = y
         self._run_nodes(self._tail, params, state, inputs, values,
-                        new_state, aux, ctx)
+                        new_state, aux, ctx, counters=counters)
         return values, new_state, aux

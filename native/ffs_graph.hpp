@@ -69,6 +69,9 @@ struct Node {
     int64_t b = param_bytes();
     for (size_t i = 0; i < input_shapes.size(); ++i) b += input_bytes(i);
     for (size_t i = 0; i < output_shapes.size(); ++i) b += output_bytes(i);
+    // intermediates an op with a wide interior writes between its own
+    // stages (the op states them; 0 for every other op)
+    b += static_cast<int64_t>(attrs.get("interior_bytes").as_double(0.0));
     return b;
   }
 };

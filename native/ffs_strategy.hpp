@@ -187,7 +187,9 @@ inline std::string kernel_gate(const Node& n, const std::string& impl,
     const Shape& os = n.output_shapes.empty() ? Shape{} : n.output_shapes[0];
     if (os.size() < 3 || heads <= 0) return "no_attention_geometry";
     int64_t seq = os[1];
-    int64_t head_dim = os.back() / heads;
+    // a head's width is the op's own where it states one (attention.py
+    // `head_dim`), else the split of the model width
+    int64_t head_dim = n.attrs.get("head_dim").as_int(os.back() / heads);
     for (const Shape& is : n.input_shapes)
       if ((int64_t)is.size() < 2 || is[1] != seq)
         return "not_self_attention";
@@ -263,6 +265,9 @@ inline std::string remat_gate(const Node& n, const Choice& c,
   if (n.type == "EXPERTS" || n.type == "AGGREGATE" || n.type == "GROUP_BY" ||
       n.type == "TOPK" || n.type == "CACHE")
     return "stateful_interior";
+  // the dropless layer's routing counts leave the step beside its output
+  // (executor counters): a checkpointed interior would strand them
+  if (n.type == "MOE_LAYER") return "counter_side_channel";
   // the recompute re-runs the forward's collectives too; the pricing
   // charges compute only, so choices whose forward moves bytes (psum /
   // ring / gather / weight-gather) do not spawn twins — this also keeps
@@ -274,7 +279,7 @@ inline std::string remat_gate(const Node& n, const Choice& c,
   // interior (what the checkpoint frees) must exceed the boundary (what
   // it keeps): output bytes + impl-aware extras vs the UNIQUE input
   // tensors (self-attention's q=k=v count once)
-  double interior = 0;
+  double interior = n.attrs.get("interior_bytes").as_double(0.0);
   for (size_t i = 0; i < n.output_shapes.size(); ++i)
     interior += (double)n.output_bytes((int)i);
   if (n.type == "MULTIHEAD_ATTENTION" && c.kernel != "flash" &&
@@ -1315,6 +1320,12 @@ inline double node_act_bytes(const Node& n, const Choice& c,
     int k = i < c.out.size() ? shards_of(c.out[i], mesh) : 1;
     mem += (double)n.output_bytes(i) / k;
   }
+  // what an op with a wide interior keeps for its backward pass besides
+  // its outputs (the scan's projections and chunk states, the experts'
+  // buffer): the op states it, and it shards as the first output does
+  double interior = n.attrs.get("interior_bytes").as_double(0.0);
+  if (interior > 0 && !c.out.empty())
+    mem += interior / shards_of(c.out[0], mesh);
   return mem;
 }
 

@@ -1,0 +1,405 @@
+"""The hybrid Mamba-2 / experts / attention decoder against its plain
+reference, at a small size on the CPU (same letters, tiny widths): forward
+logits, loss and every gradient leaf; the chunked scan against the
+stepwise recurrence; the grouped product against a loop; and the share
+tests that tie a chip's share of a layer to the uncut layer."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks import harness as hs  # noqa: E402
+from benchmarks.references import nemotron_h as ref  # noqa: E402
+from flexflow_tpu.ffconst import OperatorType  # noqa: E402
+from flexflow_tpu.layer import Layer  # noqa: E402
+from flexflow_tpu.ops import moe, ssm  # noqa: E402
+from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
+
+family = hs.load_by_path("families", "nemotron_h")
+
+TINY = dict(
+    hybrid_override_pattern="MEMEM*EME", num_hidden_layers=9, vocab_size=64,
+    hidden_size=32, layer_norm_epsilon=1e-5, num_attention_heads=2,
+    num_key_value_heads=1, head_dim=8, mamba_num_heads=2, mamba_head_dim=8,
+    n_groups=1, ssm_state_size=16, conv_kernel=4, chunk_size=8,
+    time_step_min=1e-3, time_step_max=1e-1, time_step_floor=1e-4,
+    n_routed_experts=4, n_routed_experts_published=16, expert_offset=4,
+    num_experts_per_tok=3, moe_intermediate_size=24,
+    moe_shared_expert_intermediate_size=48, routed_scaling_factor=2.5,
+    norm_topk_prob=True, slot_slack=3.0, initializer_range=0.2,
+    embedding_std=1.0, seq=29,
+    batch=2, steps_per_epoch=1)
+CONFIG = dict(search_budget=2, adam=dict(
+    alpha=1e-3, beta1=0.9, beta2=0.999, epsilon=1e-8, weight_decay=0.0,
+    state_dtype="float32"))
+HIGHEST = jax.default_matmul_precision("highest")
+
+
+@pytest.fixture(scope="module")
+def model():
+    ff = family.build(CONFIG, TINY, 1, 3)
+    weights = jax.device_get(family.make_weights(TINY, 3))
+    family.install_weights(ff, weights)
+    (ids,), labels = family.make_data(TINY, 3)
+    return ff, weights, ids, labels
+
+
+def reference_loss(w, ids, labels):
+    logits = ref.forward(w, ids, **family.reference_kw(TINY))
+    return jnp.sum(ref.sample_losses(logits, labels)) / labels.size
+
+
+def test_searched_like_any_other_graph(model):
+    ff = model[0]
+    assert ff.search_seconds is not None and ff.strategy
+    types = {n.op.op_type for n in ff.executor.nodes}
+    assert {OperatorType.SSM_MIXER, OperatorType.MOE_LAYER,
+            OperatorType.MULTIHEAD_ATTENTION} <= types
+
+
+def test_forward_logits_and_loss_match_the_reference(model):
+    ff, weights, ids, labels = model
+    got = np.asarray(ff.predict([ids]))
+    with HIGHEST:
+        want = np.asarray(ref.forward(weights, jnp.asarray(ids),
+                                      **family.reference_kw(TINY)))
+        want_loss = float(reference_loss(weights, ids, labels))
+    assert got.shape == (TINY["batch"], TINY["seq"], TINY["vocab_size"])
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    ff.fit([ids], labels, epochs=1, verbose=False)
+    assert float(ff._last_loss) == pytest.approx(want_loss, rel=1e-5)
+    # the routing counts left the step with the metrics
+    assert ff.op_counters["moe/overflow_slots"] == 0
+    assert ff.op_counters["moe/slots_held"] > 0
+    assert ff.op_counters["moe/load_max_over_mean"] >= 1.0
+
+
+def test_every_gradient_leaf_matches_the_reference(model):
+    ff, weights, ids, labels = model
+    ex = ff.executor
+    inputs = ff._stage_inputs([ids])
+    lab = ff._shard_batch(labels)
+
+    def program_loss(p):
+        ctx = OpContext(training=True, rng=jax.random.PRNGKey(0),
+                        compute_dtype=ex.compute_dtype, mesh=ex.mesh)
+        values, _, _ = ex.run_graph(p, {}, inputs, ctx)
+        return ex._loss_value(values[ex.final_ref], lab)
+
+    params = {k: {p: jnp.asarray(v) for p, v in leaves.items()}
+              for k, leaves in weights.items()}
+    with HIGHEST:
+        got = jax.jit(jax.grad(program_loss))(params)
+        want = jax.jit(jax.grad(reference_loss))(params, jnp.asarray(ids),
+                                                 jnp.asarray(labels))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        scale = float(jnp.max(jnp.abs(w))) or 1.0
+        np.testing.assert_allclose(np.asarray(g) / scale,
+                                   np.asarray(w) / scale, atol=2e-4,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+# ---------------------------------------------------------------------------
+# the chunked scan
+
+
+def scan_inputs(length, b=2, h=4, p=8, g=2, n=16, seed=0):
+    rs = np.random.RandomState(seed)
+    x = jnp.asarray(rs.randn(b, length, h, p), jnp.float32)
+    dt = jax.nn.softplus(jnp.asarray(rs.randn(b, length, h), jnp.float32))
+    a = -jnp.exp(jnp.asarray(rs.randn(h), jnp.float32))
+    bm = jnp.asarray(rs.randn(b, length, g, n), jnp.float32)
+    cm = jnp.asarray(rs.randn(b, length, g, n), jnp.float32)
+    return x, dt, a, bm, cm
+
+
+@pytest.mark.parametrize("length", [24, 29, 8, 5])
+def test_chunked_scan_matches_the_stepwise_recurrence(length):
+    """Three whole chunks, a length the chunk does not divide, exactly one
+    chunk, and less than one; forward and every gradient."""
+    args = scan_inputs(length)
+    weight = jnp.asarray(np.random.RandomState(1).randn(
+        *args[0].shape), jnp.float32)
+
+    def chunked(*a):
+        return jnp.sum(ssm.ssd_chunked(*a, chunk=8) * weight)
+
+    def stepwise(*a):
+        return jnp.sum(ssm.ssd_stepwise(*a) * weight)
+
+    with HIGHEST:
+        np.testing.assert_allclose(ssm.ssd_chunked(*args, chunk=8),
+                                   ssm.ssd_stepwise(*args), rtol=1e-4,
+                                   atol=1e-4)
+        got = jax.grad(chunked, argnums=range(5))(*args)
+        want = jax.grad(stepwise, argnums=range(5))(*args)
+    for g, w in zip(got, want):
+        assert np.all(np.isfinite(g))
+        np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# the grouped product and the routing
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+def test_grouped_matmul_matches_a_loop(mode, monkeypatch):
+    """`lax.ragged_dot` (any backend) and the megablox kernels (the TPU's
+    path, here interpreted): values of the rows the groups cover, and both
+    gradients; the sizes leave rows past the groups' sum."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+    rs = np.random.RandomState(0)
+    m, k, n, g = 256, 40, 24, 3
+    lhs = jnp.asarray(rs.randn(m, k), jnp.float32)
+    rhs = jnp.asarray(rs.randn(g, k, n), jnp.float32)
+    sizes = jnp.asarray([70, 0, 130], jnp.int32)
+    rows = int(sizes.sum())
+    group = np.repeat(np.arange(g), np.asarray(sizes))
+
+    def loop(lhs, rhs):
+        return jnp.einsum("mk,mkn->mn", lhs[:rows], rhs[group])
+
+    def grouped(lhs, rhs):
+        return moe.grouped_matmul(lhs, rhs, sizes)[:rows]
+
+    with HIGHEST:
+        np.testing.assert_allclose(grouped(lhs, rhs), loop(lhs, rhs),
+                                   rtol=1e-4, atol=1e-4)
+        got = jax.grad(lambda a, b: jnp.sum(grouped(a, b) ** 2),
+                       argnums=(0, 1))(lhs, rhs)
+        want = jax.grad(lambda a, b: jnp.sum(loop(a, b) ** 2),
+                        argnums=(0, 1))(lhs, rhs)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-3)
+
+
+def test_routing_sorts_held_pairs_and_counts_what_does_not_fit():
+    experts = jnp.asarray([[5, 0, 9], [4, 5, 1], [7, 6, 5], [2, 3, 8]],
+                          jnp.int32)
+    r = moe.route_held_experts(experts, held=4, offset=4, rows=8)
+    assert r["load"].tolist() == [1, 3, 1, 1]
+    assert r["group_sizes"].tolist() == [1, 3, 1, 1]
+    assert int(r["overflow"]) == 0 and int(r["valid"].sum()) == 6
+    flat = np.asarray(experts).reshape(-1)
+    assert flat[np.asarray(r["slot"])[:6]].tolist() == [4, 5, 5, 5, 6, 7]
+    # a buffer of 4 rows holds 4 of the 6 pairs and counts the other 2
+    small = moe.route_held_experts(experts, held=4, offset=4, rows=4)
+    assert small["group_sizes"].tolist() == [1, 3, 0, 0]
+    assert int(small["overflow"]) == 2
+
+
+# ---------------------------------------------------------------------------
+# the share tests: a chip's share of a layer, summed over the chips,
+# is the uncut layer
+
+
+def make_op(kind, props, shape):
+    layer = Layer(kind, "op", [])
+    layer.properties.update(props)
+    return OpRegistry.create(layer, [shape])
+
+
+def run_op(op, params, x):
+    ctx = OpContext(training=False, compute_dtype=jnp.float32)
+    with HIGHEST:
+        return np.asarray(op.forward(params, [x], ctx)[0])
+
+
+@pytest.fixture(scope="module")
+def hidden():
+    return jnp.asarray(np.random.RandomState(5).randn(2, 24, 32), jnp.float32)
+
+
+def test_sixteen_expert_shares_add_up_to_the_uncut_layer(hidden):
+    """16 chips with one expert each, the shared expert counted once,
+    against the reference's uncut layer (all 16 experts held)."""
+    kw = dict(n_experts=16, k=3, hidden_size=24, shared_width=48,
+              routed_scaling=2.5, slot_slack=15.0)
+    full = make_op(OperatorType.MOE_LAYER, kw, hidden.shape)
+    params = full.init_params(jax.random.PRNGKey(1))
+    with HIGHEST:
+        want = np.asarray(ref.experts(hidden, params, k=3, scaling=2.5,
+                                      offset=0, operand="f32"))
+        shared = np.asarray(ref.relu2_mlp(hidden, params["ws_up"],
+                                          params["ws_down"], "f32"))
+    np.testing.assert_allclose(run_op(full, params, hidden), want,
+                               rtol=1e-4, atol=1e-4)
+    total = np.zeros_like(want)
+    for chip in range(16):
+        op = make_op(OperatorType.MOE_LAYER,
+                     dict(kw, experts_held=1, expert_offset=chip),
+                     hidden.shape)
+        share = dict(params, w_up=params["w_up"][chip:chip + 1],
+                     w_down=params["w_down"][chip:chip + 1])
+        total += run_op(op, share, hidden) - shared
+        assert float(op._counters["moe/overflow_slots"][1]) == 0
+    np.testing.assert_allclose(total + shared, want, rtol=1e-4, atol=1e-4)
+
+
+def test_eight_head_shares_of_a_mamba_mixer_add_up(hidden):
+    """8 chips with one head and one group each: the convolution is
+    depthwise, dt, A, D are per head, a head reads its group's B and C,
+    the gated norm's group is the share, W_out is linear."""
+    h, p, g, n = 8, 4, 8, 8
+    kw = dict(num_heads=h, head_dim=p, n_groups=g, state_size=n,
+              chunk_size=8)
+    full = make_op(OperatorType.SSM_MIXER, kw, hidden.shape)
+    params = full.init_params(jax.random.PRNGKey(2))
+    with HIGHEST:
+        want = np.asarray(ref.mamba2(
+            hidden, params, heads=h, head_dim=p, groups=g, state=n,
+            eps=1e-5, operand="f32"))
+    np.testing.assert_allclose(run_op(full, params, hidden), want,
+                               rtol=1e-4, atol=1e-4)
+    d_inner, gn = h * p, g * n
+    cols = np.arange(2 * d_inner + 2 * gn + h)
+    z, xs, bs, cs, dts = np.split(cols, np.cumsum(
+        [d_inner, d_inner, gn, gn]))
+    total = np.zeros_like(want)
+    for chip in range(8):
+        head = slice(chip * p, (chip + 1) * p)
+        grp = slice(chip * n, (chip + 1) * n)
+        pick = np.concatenate([z[head], xs[head], bs[grp], cs[grp],
+                               dts[chip:chip + 1]])
+        conv = pick[p:-1] - d_inner
+        share = dict(
+            w_in=params["w_in"][:, pick], conv_w=params["conv_w"][:, conv],
+            conv_b=params["conv_b"][conv],
+            dt_bias=params["dt_bias"][chip:chip + 1],
+            a_log=params["a_log"][chip:chip + 1], d=params["d"][chip:chip + 1],
+            norm_scale=params["norm_scale"][head],
+            w_out=params["w_out"][head])
+        op = make_op(OperatorType.SSM_MIXER,
+                     dict(kw, num_heads=1, n_groups=1), hidden.shape)
+        total += run_op(op, share, hidden)
+    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-4)
+
+
+def test_eight_head_shares_of_attention_add_up(hidden):
+    """8 chips with 2 of 16 query heads and 1 of 8 key/value heads each,
+    heads of 8 on a model width of 32 that they do not divide."""
+    kw = dict(embed_dim=32, num_heads=16, num_kv_heads=8, head_dim=8,
+              bias=False, causal=True)
+    full = make_op(OperatorType.MULTIHEAD_ATTENTION, kw, hidden.shape)
+    assert full.head_dim == 8
+    params = full.init_params(jax.random.PRNGKey(3))
+    with HIGHEST:
+        want = np.asarray(ref.attention(hidden, params, "f32"))
+    np.testing.assert_allclose(run_op(full, params, hidden), want,
+                               rtol=1e-4, atol=1e-4)
+    total = np.zeros_like(want)
+    for chip in range(8):
+        q = slice(2 * chip, 2 * chip + 2)
+        share = dict(wq=params["wq"][q], wo=params["wo"][q],
+                     wk=params["wk"][chip:chip + 1],
+                     wv=params["wv"][chip:chip + 1])
+        op = make_op(OperatorType.MULTIHEAD_ATTENTION,
+                     dict(kw, num_heads=2, num_kv_heads=1), hidden.shape)
+        total += run_op(op, share, hidden)
+    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-4)
+
+
+def test_a_sliced_vocabulary_gives_the_slice_of_the_logits(model):
+    """Rows 0-15 of the head and of the embedding: the logits over the
+    slice are the slice of the full head's logits (ids drawn from it)."""
+    _, weights, ids, _ = model
+    ids = jnp.asarray(ids) % 16
+    kw = family.reference_kw(TINY)
+    sliced = dict(weights,
+                  embed_tokens={"kernel": weights["embed_tokens"]["kernel"][:16]},
+                  lm_head={"kernel": weights["lm_head"]["kernel"][:, :16]})
+    with HIGHEST:
+        full = ref.forward(weights, ids, **kw)
+        part = ref.forward(sliced, ids, **kw)
+    np.testing.assert_allclose(part, full[..., :16], rtol=1e-5, atol=1e-6)
+
+
+def test_attention_head_dim_defaults_to_the_split_of_the_width(hidden):
+    op = make_op(OperatorType.MULTIHEAD_ATTENTION,
+                 dict(embed_dim=32, num_heads=4), hidden.shape)
+    assert op.head_dim == 8 and "head_dim" not in op.layer.properties
+
+
+# ---------------------------------------------------------------------------
+# the search sees the new ops like any other
+
+
+def test_search_prices_and_places_the_new_ops(model):
+    """The serialized graph states the ops' FLOPs, parameters, roles and
+    interior; the native search offers replicated, batch-parallel and,
+    for the scan, `_r` twins, and refuses `_r` for the expert layer
+    (its counters leave the step beside its output)."""
+    from flexflow_tpu.search import native
+    from flexflow_tpu.search.unity import serialize_graph
+    if not native.available():
+        pytest.skip("native search unavailable")
+    ff = model[0]
+    nodes = serialize_graph(ff.executor.nodes)
+    by_type = {}
+    for n in nodes:
+        by_type.setdefault(n["type"], n)
+    scan, experts, attn = (by_type["SSM_MIXER"], by_type["MOE_LAYER"],
+                           by_type["MULTIHEAD_ATTENTION"])
+    assert scan["roles"] == [["sample", "other", "channel"]]
+    assert scan["flops"] > 0 and scan["attrs"]["interior_bytes"] > 0
+    assert set(scan["params"]) == {"w_in", "conv_w", "conv_b", "dt_bias",
+                                   "a_log", "d", "norm_scale", "w_out"}
+    assert experts["attrs"]["n_experts"] == 16
+    assert experts["params"]["w_up"][0] == 4       # the experts held
+    assert attn["attrs"]["head_dim"] == 8          # not 32 // 2
+    machine = {"num_devices": 4, "flops": 197e12, "hbm_bw": 0.82e12,
+               "hbm_cap": 16e9, "ici_bw": 45e9, "ici_latency": 1e-6,
+               "dcn_bw": 25e9, "dcn_latency": 1e-5, "num_slices": 1,
+               "comm_bytes_factor": 0.5}
+    resp = native.native_optimize(dict(
+        nodes=nodes, machine=machine, measured={},
+        config=dict(budget=2, training=True, enable_substitution=False,
+                    enable_parameter_parallel=True, batch=TINY["batch"],
+                    emit_search_trace=True)))
+    ops = {o["name"]: o for o in resp["search_trace"]["ops"]}
+    scan_choices = {c["choice"] for c in ops["b0_mixer"]["candidates"]}
+    assert {"rep", "dp"} <= {c.split("_")[0] for c in scan_choices}
+    assert any(c.endswith("_r") for c in scan_choices), scan_choices
+    moe_choices = {c["choice"] for c in ops["b1_mixer"]["candidates"]}
+    assert not any(c.endswith("_r") for c in moe_choices)
+    assert "counter_side_channel" in [
+        r["reason"] for r in ops["b1_mixer"].get("remat_rejections") or []]
+
+
+def test_router_and_scan_rates_stay_float32_in_the_compute_copy(model):
+    ff = model[0]
+    ex = ff.executor
+    keep = ex._full_precision_leaves
+    assert ("b1_mixer", "e_bias") in keep and ("b0_mixer", "a_log") in keep
+    ex.compute_dtype, was = jnp.bfloat16, ex.compute_dtype
+    try:
+        copy = ex._cast_tree(ff.params)
+    finally:
+        ex.compute_dtype = was
+    assert copy["b1_mixer"]["e_bias"].dtype == jnp.float32
+    assert copy["b1_mixer"]["w_router"].dtype == jnp.float32
+    assert copy["b1_mixer"]["w_up"].dtype == jnp.bfloat16
+    assert copy["b0_mixer"]["dt_bias"].dtype == jnp.float32
+    assert copy["b0_mixer"]["w_in"].dtype == jnp.bfloat16
+    assert copy["lm_head"]["kernel"].dtype == jnp.bfloat16
+
+
+def test_scopes_reach_the_compiled_steps_op_names(model):
+    """The device trace's readers find the new ops by these names."""
+    ff = model[0]
+    scopes = family.scopes_of_compiled_step(ff, family.observed_sizes(ff))
+    names = " ".join(scopes.values())
+    for scope in ("jit(ssm_mixer)", "jit(ssd_scan)", "jit(moe_layer)",
+                  "jit(moe_route)", "jit(moe_grouped_matmul)",
+                  "jit(moe_shared)"):
+        assert scope in names, scope

@@ -160,6 +160,50 @@ class TestFlashKernels:
         assert "collective-permute" in hlo
 
 
+class TestHybridDecoderKernels:
+    """The new ops of the pattern-driven decoder at the widths of the
+    `nemotron3_nano_30b_a3b` cell (one chip's share: 8 Mamba heads, 8
+    held experts, 8,192 tokens), forward and backward."""
+
+    def test_grouped_matmul_at_the_cells_widths(self, topo, on_tpu):
+        from flexflow_tpu.ops.moe import grouped_matmul
+        one = SingleDeviceSharding(topo.devices[0])
+        rows = jax.ShapeDtypeStruct((4736, 2688), jnp.bfloat16, sharding=one)
+        up = jax.ShapeDtypeStruct((8, 2688, 1856), jnp.bfloat16,
+                                  sharding=one)
+        down = jax.ShapeDtypeStruct((8, 1856, 2688), jnp.bfloat16,
+                                    sharding=one)
+        sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
+
+        def loss(x, up, down, sizes):
+            h = jnp.square(jax.nn.relu(grouped_matmul(x, up, sizes)))
+            return grouped_matmul(h, down, sizes).astype(jnp.float32).sum()
+
+        hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), rows, up,
+                       down, sizes)
+        # two products forward, two for the rows, two for the weights
+        assert pallas_kernel_count(hlo) == 6
+
+    def test_chunked_scan_at_the_cells_widths(self, topo):
+        from flexflow_tpu.ops.ssm import ssd_chunked
+        one = SingleDeviceSharding(topo.devices[0])
+        x = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16,
+                                 sharding=one)
+        dt = jax.ShapeDtypeStruct((1, 8192, 8), jnp.float32, sharding=one)
+        a = jax.ShapeDtypeStruct((8,), jnp.float32, sharding=one)
+        bc = jax.ShapeDtypeStruct((1, 8192, 1, 128), jnp.bfloat16,
+                                  sharding=one)
+
+        def loss(x, dt, a, bm, cm):
+            return ssd_chunked(x, dt, a, bm, cm, 128, jnp.bfloat16).sum()
+
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            x, dt, a, bc, bc).compile()
+        # the per-chunk decay matrices, float32: 8 heads x 64 chunks of
+        # 128 x 128, a few copies live at once
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 class TestFusedAdam:
     KW = dict(beta1=0.9, beta2=0.999, eps=1e-8, wd=1e-4)
 
