@@ -3,19 +3,23 @@
 Analog of the reference's hand-written CUDA kernels (src/ops/kernels/*.cu)
 — but only where needed: XLA already fuses elementwise chains into matmuls,
 so the win is in attention, where materializing the [B,H,S,S] score tensor
-in HBM is the bottleneck. ``flash_attention`` streams K/V through VMEM per
-Q block with the standard online-softmax accumulation, keeping scores
-on-chip.
+in HBM is the bottleneck. ``flash_attention`` keeps a head's scores in
+VMEM: forward and backward recompute them from Q and K, and only the
+per-row logsumexp goes to HBM beside the output.
 
-Forward is the Pallas kernel (it also emits the per-row logsumexp).
-Backward: for sequences whose full S x S score tile fits VMEM
-(S <= MAX_BWD_SEQ) a fused Pallas backward kernel recomputes P from the
-saved LSE and produces dQ/dK/dV without ever materializing scores in HBM
-— slope-measured 1.87x over the XLA einsum fwd+bwd at the bench shape
-(b8 h16 s512 d64; 601us vs 1124us). Longer sequences take the K-blocked
-backward kernel, up to MAX_FLASH_SEQ — the one upper bound the gate
-(``flash_attention_available``), the backward dispatch and the native
-``kernel_gate`` share; past it attention runs the einsum path.
+Four kernels, told apart in a trace by name. Up to MAX_BWD_SEQ a grid
+step holds the whole S x S tile of several heads (`flash_fwd_whole`,
+`flash_bwd`: dQ/dK/dV from P recomputed out of the saved LSE); longer
+sequences take the Q-blocked forward (`flash_fwd`) and the K-blocked
+backward (`flash_bwd_blocked`), up to MAX_FLASH_SEQ — the one upper bound
+the gate (``flash_attention_available``), the backward dispatch and the
+native ``kernel_gate`` share; past it attention runs the einsum path.
+Every MXU product takes its operands in the dtype the caller stored and
+accumulates in float32 (``_dot``); softmax statistics, ``exp``, scale
+and mask are float32. Tile sizes follow from the shape
+(``_heads_per_step``, ``_kv_block``); the timings quoted beside them are
+the kernels alone on a v5e (PR 28), and PERF.md has what they gave
+through the benchmark's full step.
 
 CPU fallback: the same kernels run under ``interpret=True`` when
 FLEXFLOW_TPU_PALLAS=interpret (used by the deviceless tests); otherwise
@@ -32,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLK_Q = 128  # rows of Q per grid step (MXU-aligned)
+BLK_Q = 128  # rows of Q per grid step of the Q-blocked forward
 
 # Mosaic's default scoped-VMEM budget on v5e is 16 MiB, which the
 # kernels below outgrow long before the chip's 128 MiB of VMEM is used
@@ -52,28 +56,103 @@ _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 # (benchmarks/trace_reduce.KERNEL_PREFIX).
 KERNEL_NAME_PREFIX = "tpu_custom_call_"
 
+# Longest sequence whose whole S x S f32 score tile of a head is one grid
+# step's work, forward (`flash_fwd_whole`) and backward (`flash_bwd`)
+# alike. Re-measured in PR 28 (v5e, kernel alone, bf16, head_dim 64): at
+# S = 1024 the whole-tile backward takes 652 us for 128 heads against 745
+# for the K-blocked one, the whole-tile forward 408 against 700 for the
+# Q-blocked one; past it the tile (4 bytes x S^2, and three more like it)
+# outgrows the VMEM budget.
+MAX_BWD_SEQ = 1024
+# Upper bounds of the flash path, forward and K-blocked backward alike.
+# What binds is the VMEM budget above, not the chip's physical VMEM: the
+# blocked backward holds the Q/dO/dQ panels ([S, D], lanes padded to 128)
+# plus [BLK, S] f32 score tiles, and the forward holds the K/V panels
+# plus a [BLK_Q, S] tile. Mirrored by the native kernel_gate
+# (native/ffs_strategy.hpp) so the search never prices a length the
+# compiler refuses.
+MAX_FLASH_SEQ = 16384
+MAX_FLASH_HEAD_DIM = 128
 
-def _fwd_blk(s: int) -> int:
-    """Q-block rows for the forward kernel. 128 everywhere: a same-chip
-    A/B through the FULL bert train step measured 228.1 samples/s at 128
-    vs 222.3 at 256 (r5) — an isolated-kernel microbench had suggested
-    256, but in the fused step the larger block loses (and a 256-block
-    forward feeding the single-block backward triggers a pathological
-    relayout in standalone use). Keep the block parameterized so the
-    experiment stays one-line."""
-    return BLK_Q
+_NT = (((1,), (1,)), ((), ()))  # a[m, c] . b[n, c] -> [m, n]
+_NN = (((1,), (0,)), ((), ()))  # a[m, c] . b[c, n] -> [m, n]
+
+
+def _dot(a, b, dims):
+    """One MXU product: operands in the dtype they have, f32 accumulator.
+    q, k, v, dO come as the caller stored them; the kernels round `P` and
+    `dS` to that dtype for the product they enter and for nothing else
+    (as the einsum path rounds `probs`), so bf16 storage means bf16
+    products and f32 storage f32 products."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _heads_per_step(bh: int, s: int) -> int:
+    """Heads a grid step of the whole-tile kernels works through: the
+    largest of 8, 4, 2, 1 that keeps S^2 x heads within 2^21 score
+    elements and divides ``bh``. A step costs about 0.35 us before it
+    computes. v5e, bf16,
+    head_dim 64, forward / backward us for the same work with every
+    head of a step unrolled (PR 28): S = 512: 618 / 864 at 1, 462 / 752
+    at 4, 449 / 737 at 8; S = 1024: 422 / 681 at 1, 408 / 652 at 2;
+    S = 256: 499 / 735 at 4, 438 / 704 at 8, 422 / 705 at 16."""
+    heads = 8
+    while heads > 1 and (heads * s * s > 1 << 21 or bh % heads):
+        heads //= 2
+    return heads
+
+
+def _for_heads(heads: int, unroll: int, body) -> None:
+    """``body(h)`` for every head of a grid step: a loop whose iteration
+    works through ``unroll`` heads, so that the scheduler can fill one
+    head's MXU waits with the next one's VPU passes without the kernel
+    growing with ``heads`` (both powers of two). v5e, S = 512, 8 heads a
+    step (PR 28): the forward takes 584 us at one head an iteration, 509
+    at 2, 474 at 4, 449 all 8 unrolled; the backward 777, 734, 737, 737.
+    Twelve layers'
+    kernels lower and compile (deviceless, cold) in 0.7 + 1.2 s at
+    forward 4 / backward 2 as at the parent, in 1.5 + 3.7 s unrolled."""
+    unroll = min(unroll, heads)
+
+    def step(i, carry):
+        for u in range(unroll):
+            body(i * unroll + u)
+        return carry
+
+    jax.lax.fori_loop(0, heads // unroll, step, None)
+
+
+def _kv_block(s: int) -> int:
+    """K/V rows a grid step of the K-blocked backward takes: the largest
+    of 512, 256, 128 that divides S and keeps the [BLK, S] tile within
+    2^22 elements. v5e, bf16, causal, head_dim 128 (PR 28): S = 8192:
+    2221 us at 128, 1923 at 512; S = 16384: 4343 at 128, 3923 at 256,
+    4414 at 512."""
+    blk = 512
+    while blk > BLK_Q and (blk * s > 1 << 22 or s % blk):
+        blk //= 2
+    return blk
+
+
+def _mask_causal(st, k0):
+    """-inf where the query may not see the key, in a [k, q] tile whose
+    first row is key ``k0`` and first column query 0."""
+    kk = k0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+    qq = jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+    return jnp.where(kk <= qq, st, -jnp.inf)
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
                       scale: float, blk_q: int):
     """One (batch*head, q-block) grid cell: q [1,BLK_Q,D] against the full
     K/V [1,S,D] resident in VMEM; scores never touch HBM. Also emits the
-    per-row logsumexp so the fused backward can recompute P exactly."""
-    q = q_ref[0].astype(jnp.float32)  # [BLK_Q, D]
-    k = k_ref[0].astype(jnp.float32)  # [S, D]
-    v = v_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    per-row logsumexp so the fused backward can recompute P exactly.
+    The forward of sequences past MAX_BWD_SEQ."""
+    q = q_ref[0]  # [BLK_Q, D]
+    k = k_ref[0]  # [S, D]
+    v = v_ref[0]
+    s = _dot(q, k, _NT) * scale
     if causal:
         blk = pl.program_id(1)
         rows = blk * blk_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -82,27 +161,68 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    o = _dot(p.astype(v.dtype), v, _NN)
     o_ref[0] = (o / l).astype(o_ref.dtype)
     lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
 
 
+def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                            causal: bool, scale: float, heads: int):
+    """``heads`` (batch*head)s a grid cell, each with its whole sequence:
+    the forward up to MAX_BWD_SEQ. The score tile is held as [k, q]: the
+    softmax's max and sum then run down the sublanes (vreg against vreg)
+    and come out as the [1, S] rows the logsumexp is stored as, where the
+    [q, k] tile needs two cross-lane reductions a row and a relayout; and
+    O^T = V^T P^T streams D rows through the MXU against the tile, which
+    a head_dim of 64 fills where P V fills half its width."""
+    def head(h):
+        q, k, v = q_ref[h], k_ref[h], v_ref[h]      # [S, D]
+        st = _dot(k, q, _NT) * scale                 # [k, q]
+        if causal:
+            st = _mask_causal(st, 0)
+        m = jnp.max(st, axis=0, keepdims=True)       # [1, S]
+        pt = jnp.exp(st - m)
+        l = jnp.sum(pt, axis=0, keepdims=True)
+        ot = _dot(v.T, pt.astype(v.dtype), _NN)      # [D, q]
+        o_ref[h] = (ot / l).T.astype(o_ref.dtype)
+        lse_ref[h] = m + jnp.log(l)
+
+    _for_heads(heads, 4, head)
+
+
 def _flash_fwd(q, k, v, causal: bool, interpret: bool, out_dtype=None):
-    """q,k,v: [BH, S, D] with S % BLK_Q == 0 -> (o, lse[BH, S])."""
+    """q,k,v: [BH, S, D] with S % BLK_Q == 0 -> (o, lse[BH, 1, S])."""
     bh, s, d = q.shape
     scale = 1.0 / float(d) ** 0.5
-    blk = _fwd_blk(s)
-    kern = functools.partial(_flash_fwd_kernel, causal=causal, scale=scale,
-                             blk_q=blk)
+    # lse is (bh, 1, s): TPU requires the last two block dims be
+    # (8,128)-aligned or span the array — a middle singleton satisfies
+    # that while keeping one row per (batch*head)
+    out_shape = (jax.ShapeDtypeStruct((bh, s, d), out_dtype or q.dtype),
+                 jax.ShapeDtypeStruct((bh, 1, s), jnp.float32))
+    if s <= MAX_BWD_SEQ:
+        heads = _heads_per_step(bh, s)
+        seq_spec = pl.BlockSpec((heads, s, d), lambda b: (b, 0, 0))
+        return pl.pallas_call(
+            functools.partial(_flash_fwd_whole_kernel, causal=causal,
+                              scale=scale, heads=heads),
+            name=KERNEL_NAME_PREFIX + "flash_fwd_whole",
+            out_shape=out_shape,
+            grid=(bh // heads,),
+            in_specs=[seq_spec, seq_spec, seq_spec],
+            out_specs=(seq_spec,
+                       pl.BlockSpec((heads, 1, s), lambda b: (b, 0, 0))),
+            interpret=interpret,
+            compiler_params=_FLASH_COMPILER_PARAMS,
+        )(q, k, v)
+    # Q blocks of 128 rows: at S = 8192 and 16384 blocks of 256 take the
+    # same time within 2% (v5e, kernel alone, PR 28); not re-measured
+    # through a full step, where an older round had 128 ahead
+    blk = BLK_Q
     return pl.pallas_call(
-        kern,
+        functools.partial(_flash_fwd_kernel, causal=causal, scale=scale,
+                          blk_q=blk),
         name=KERNEL_NAME_PREFIX + "flash_fwd",
-        # lse is (bh, 1, s): TPU requires the last two block dims be
-        # (8,128)-aligned or span the array — a middle singleton satisfies
-        # that while keeping one row per (batch*head)
-        out_shape=(jax.ShapeDtypeStruct((bh, s, d), out_dtype or q.dtype),
-                   jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)),
+        out_shape=out_shape,
         grid=(bh, s // blk),
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda b, i: (b, i, 0)),
@@ -116,84 +236,51 @@ def _flash_fwd(q, k, v, causal: bool, interpret: bool, out_dtype=None):
     )(q, k, v)
 
 
-# Longest sequence whose full S x S f32 score tile (plus q/k/v/do/dq/dk/dv
-# panels) fits the VMEM budget in the single-block backward kernel.
-MAX_BWD_SEQ = 1024
-# Upper bounds of the flash path, forward and K-blocked backward alike.
-# What binds is the VMEM budget above, not the chip's physical VMEM: the
-# blocked backward holds the Q/dO/dQ panels ([S, D], lanes padded to 128)
-# plus [S, BLK_Q] f32 score tiles, and the forward holds the K/V panels
-# plus a [BLK_Q, S] tile — at S = 16384 and D <= 128 the f32 case needs
-# between 48 and 64 MiB (deviceless compile, v5e). Mirrored by the native
-# kernel_gate (native/ffs_strategy.hpp) so the search never prices a
-# length the compiler refuses.
-MAX_FLASH_SEQ = 16384
-MAX_FLASH_HEAD_DIM = 128
+def _flash_bwd_tile(q, k, v, do, lse, delta, glse, scale: float, k0):
+    """FlashAttention-2 backward of one [k, q] tile: k, v [Bk, D] from key
+    ``k0`` on (None: not causal) against q, dO [Bq, D] and the [1, Bq]
+    rows lse, delta = rowsum(dO * O) and g_lse, the upstream gradient on
+    the logsumexp output (zero when only o is consumed; nonzero under
+    ring attention's streaming merge, whose weights are functions of
+    each block's lse). Recompute P from the saved lse, then dV = P^T dO,
+    dS = P * (dO V^T - delta + g_lse), dQ = dS K * scale,
+    dK = dS^T Q * scale; returns float32 dQ [Bq, D], dK, dV [Bk, D].
+
+    With the tile as [k, q] the three products that contract over the
+    sequence stream the D rows of dO^T, Q^T, K^T against it and come out
+    as [D, S]: none transposes the tile, a head_dim of 64 fills the MXU,
+    and the row statistics broadcast down the sublanes as they are
+    stored. v5e, bf16, S = 512, head_dim 64, 512 heads, one a step (PR
+    28): 864 us this way; 989 with the tile as [q, k] and dK, dV
+    contracting over its rows; 980 as [k, q] with [S, D] results, and
+    980 still with the element-wise work taken out of that one."""
+    st = _dot(k, q, _NT) * scale                     # [k, q]
+    if k0 is not None:
+        st = _mask_causal(st, k0)
+    pt = jnp.exp(st - lse)                           # exact softmax probs
+    dpt = _dot(v, do, _NT)
+    dst = (pt * (dpt - (delta - glse))).astype(q.dtype)
+    dvt = _dot(do.T, pt.astype(do.dtype), _NT)       # [D, k]
+    dkt = _dot(q.T, dst, _NT)                        # [D, k]
+    dqt = _dot(k.T, dst, _NN)                        # [D, q]
+    return (dqt * scale).T, (dkt * scale).T, dvt.T
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       glse_ref, dq_ref, dk_ref, dv_ref, *, causal: bool,
-                      scale: float):
-    """FlashAttention-2 backward, one (batch*head) per grid cell with the
-    whole sequence in VMEM (gated by MAX_BWD_SEQ): recompute P from Q,K and
-    the saved LSE, then dV = P^T dO; dS = P * (dO V^T - delta + g_lse);
-    dQ = dS K * scale; dK = dS^T Q * scale. Scores/probabilities never
-    touch HBM — the reason XLA's einsum backward loses at these shapes.
-    ``g_lse`` is the upstream gradient on the logsumexp output (zero when
-    only o is consumed; nonzero under ring attention's streaming merge,
-    where the merge weights are functions of each block's lse)."""
-    q = q_ref[0].astype(jnp.float32)   # [S, D]
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                 # [S]
-    delta = delta_ref[0, 0]             # [S] rowsum(dO * O)
-    glse = glse_ref[0, 0]               # [S] upstream d/d lse
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if causal:
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols <= rows, s, -jnp.inf)
-    p = jnp.exp(s - lse[:, None])       # exact softmax probs
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None] + glse[:, None])
-    dq_ref[0] = (jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-                 * scale).astype(dq_ref.dtype)
-    dk_ref[0] = (jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-                 * scale).astype(dk_ref.dtype)
-    dv_ref[0] = jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32
-                                    ).astype(dv_ref.dtype)
+                      scale: float, heads: int):
+    """``heads`` (batch*head)s a grid cell with the whole sequence in VMEM
+    (gated by MAX_BWD_SEQ). Scores/probabilities never touch HBM — the
+    reason XLA's einsum backward loses at these shapes."""
+    def head(h):
+        dq, dk, dv = _flash_bwd_tile(
+            q_ref[h], k_ref[h], v_ref[h], do_ref[h], lse_ref[h],
+            delta_ref[h], glse_ref[h], scale, 0 if causal else None)
+        dq_ref[h] = dq.astype(dq_ref.dtype)
+        dk_ref[h] = dk.astype(dk_ref.dtype)
+        dv_ref[h] = dv.astype(dv_ref.dtype)
 
-
-def _flash_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool,
-               glse=None):
-    bh, s, d = q.shape
-    scale = 1.0 / float(d) ** 0.5
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[:, None, :]
-    if glse is None:
-        glse = jnp.zeros((bh, 1, s), jnp.float32)
-    kern = functools.partial(_flash_bwd_kernel, causal=causal, scale=scale)
-    seq_spec = pl.BlockSpec((1, s, d), lambda b: (b, 0, 0))
-    row_spec = pl.BlockSpec((1, 1, s), lambda b: (b, 0, 0))
-    return pl.pallas_call(
-        kern,
-        name=KERNEL_NAME_PREFIX + "flash_bwd",
-        out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
-        grid=(bh,),
-        in_specs=[seq_spec, seq_spec, seq_spec, seq_spec, row_spec,
-                  row_spec, row_spec],
-        out_specs=(seq_spec, seq_spec, seq_spec),
-        interpret=interpret,
-        compiler_params=_FLASH_COMPILER_PARAMS,
-    )(q, k, v, do, lse, delta, glse)
+    _for_heads(heads, 2, head)
 
 
 def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -201,37 +288,16 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                               *, causal: bool, scale: float, blk: int):
     """FA2 backward for sequences past MAX_BWD_SEQ: grid cell = one
     (batch*head, K-block). The full Q/dO panels are resident; the
-    [S, BLK] score tile for this K-block is recomputed in VMEM; dK/dV
+    [BLK, S] score tile for this K-block is recomputed in VMEM; dK/dV
     write their block, and dQ accumulates in-place across the K-block
     grid dimension (same output block revisited -> Pallas keeps it in
     VMEM between consecutive steps)."""
     j = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)    # [S, D]
-    k = k_ref[0].astype(jnp.float32)    # [BLK, D]
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)  # [S, D]
-    lse = lse_ref[0, 0]                 # [S]
-    delta = delta_ref[0, 0]
-    glse = glse_ref[0, 0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if causal:
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = j * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols <= rows, s, -jnp.inf)
-    p = jnp.exp(s - lse[:, None])       # [S, BLK]
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None] + glse[:, None])
-    dk_ref[0] = (jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-                 * scale).astype(dk_ref.dtype)
-    dv_ref[0] = jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32
-                                    ).astype(dv_ref.dtype)
-    dq_blk = (jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-              * scale)
+    dq_blk, dk, dv = _flash_bwd_tile(
+        q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
+        glse_ref[0], scale, j * blk if causal else None)
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
 
     @pl.when(j == 0)
     def _init():
@@ -242,22 +308,46 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_ref[0] += dq_blk
 
 
-def _flash_bwd_blocked(q, k, v, o, lse, do, causal: bool, interpret: bool,
-                       glse=None):
+def _flash_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool,
+               glse=None):
+    """dq, dk, dv from the saved (o, lse[BH, 1, S]): one whole-tile step
+    for several heads up to MAX_BWD_SEQ, K-blocked past it — scores stay
+    in VMEM tiles at every length the gate admits
+    (flash_attention_available caps S at MAX_FLASH_SEQ)."""
     bh, s, d = q.shape
     scale = 1.0 / float(d) ** 0.5
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]
+    # the ring's merge hands a float32 dO (its o is float32): as an MXU
+    # operand it takes the stored dtype, like P and dS
+    do = do.astype(q.dtype)
     if glse is None:
         glse = jnp.zeros((bh, 1, s), jnp.float32)
-    blk = BLK_Q
-    kern = functools.partial(_flash_bwd_blocked_kernel, causal=causal,
-                             scale=scale, blk=blk)
+    if s <= MAX_BWD_SEQ:
+        heads = _heads_per_step(bh, s)
+        seq_spec = pl.BlockSpec((heads, s, d), lambda b: (b, 0, 0))
+        row_spec = pl.BlockSpec((heads, 1, s), lambda b: (b, 0, 0))
+        return pl.pallas_call(
+            functools.partial(_flash_bwd_kernel, causal=causal,
+                              scale=scale, heads=heads),
+            name=KERNEL_NAME_PREFIX + "flash_bwd",
+            out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+                       jax.ShapeDtypeStruct((bh, s, d), k.dtype),
+                       jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
+            grid=(bh // heads,),
+            in_specs=[seq_spec, seq_spec, seq_spec, seq_spec, row_spec,
+                      row_spec, row_spec],
+            out_specs=(seq_spec, seq_spec, seq_spec),
+            interpret=interpret,
+            compiler_params=_FLASH_COMPILER_PARAMS,
+        )(q, k, v, do, lse, delta, glse)
+    blk = _kv_block(s)
     seq_spec = pl.BlockSpec((1, s, d), lambda b, j: (b, 0, 0))
     kblk_spec = pl.BlockSpec((1, blk, d), lambda b, j: (b, j, 0))
     row_spec = pl.BlockSpec((1, 1, s), lambda b, j: (b, 0, 0))
     dq, dk, dv = pl.pallas_call(
-        kern,
+        functools.partial(_flash_bwd_blocked_kernel, causal=causal,
+                          scale=scale, blk=blk),
         name=KERNEL_NAME_PREFIX + "flash_bwd_blocked",
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), jnp.float32),  # dq acc
                    jax.ShapeDtypeStruct((bh, s, d), k.dtype),
@@ -304,11 +394,7 @@ def _flash_vjp_fwd(q, k, v, causal, interpret):
 
 def _flash_vjp_bwd(causal, interpret, res, g):
     q, k, v, o, lse = res
-    if q.shape[1] <= MAX_BWD_SEQ:
-        return _flash_bwd(q, k, v, o, lse, g, causal, interpret)
-    # K-blocked kernel: scores stay in VMEM tiles at every length the
-    # gate admits (flash_attention_available caps S at MAX_FLASH_SEQ)
-    return _flash_bwd_blocked(q, k, v, o, lse, g, causal, interpret)
+    return _flash_bwd(q, k, v, o, lse, g, causal, interpret)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -335,11 +421,7 @@ def _flash_lse_vjp_bwd(causal, interpret, res, gs):
     q, k, v, o, lse = res
     g_o, g_lse = gs
     glse = g_lse[:, None, :].astype(jnp.float32)
-    if q.shape[1] <= MAX_BWD_SEQ:
-        return _flash_bwd(q, k, v, o, lse, g_o, causal, interpret,
-                          glse=glse)
-    return _flash_bwd_blocked(q, k, v, o, lse, g_o, causal, interpret,
-                              glse=glse)
+    return _flash_bwd(q, k, v, o, lse, g_o, causal, interpret, glse=glse)
 
 
 flash_attention_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
@@ -353,10 +435,12 @@ def pallas_mode() -> str:
     return "tpu" if jax.default_backend() == "tpu" else "off"
 
 
-# Slope-measured on v5e (b=8 h=16 d=64, per-call dispatch cancelled by
-# the two-length slope): flash fwd 261us vs XLA 375us at S=512, and with
-# the fused Pallas backward fwd+bwd 601us vs 1124us — flash wins from
-# S=512 up (and XLA OOMs at S=8192 where flash still runs).
+# From S = 512 up the search may choose flash on hardware. The bound is
+# from a chip round older than the benchmark (flash forward+backward
+# 601 us against the einsum path's 1124 us at b8 h16 s512 d64, and the
+# einsum path out of memory at S = 8192) and was not re-measured in PR
+# 28, whose kernels take 54% of the time of that round's at S = 512;
+# whether shorter sequences would gain now is open.
 MIN_SEQ_FOR_FLASH = 512
 
 

@@ -113,6 +113,26 @@ class TestFlashKernels:
             sharding=SingleDeviceSharding(topo.devices[0]))
         assert pallas_kernel_count(_compile(grads, q, q, q)) == 2
 
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("bh,seq,head_dim", [
+        # whole-tile kernels: 8 heads a step, then 2 at their longest
+        (16, 512, 128), (16, pk.MAX_BWD_SEQ, 128),
+        # K-blocked backward at its widest block, narrow and wide heads
+        (4, 2 * pk.MAX_BWD_SEQ, 64), (4, 8192, 128),
+    ])
+    def test_tiles_derived_from_shape_and_dtype_compile(self, topo, bh, seq,
+                                                        head_dim, dtype):
+        """Heads a step and K/V rows a block follow from (S, D): each
+        choice at its largest footprint, under the same VMEM budget."""
+        q = jax.ShapeDtypeStruct((bh, seq, head_dim), dtype,
+                                 sharding=SingleDeviceSharding(
+                                     topo.devices[0]))
+        hlo = _compile(_flash_lse_grads, q, q, q)
+        assert pallas_kernel_count(hlo) == 2
+        whole = seq <= pk.MAX_BWD_SEQ
+        assert ("tpu_custom_call_flash_fwd_whole" in hlo) == whole
+        assert ("tpu_custom_call_flash_bwd_blocked" in hlo) != whole
+
     def test_lowering_ignores_the_call_site_once_the_cache_is_configured(
             self, topo):
         """The persistent cache keys on the kernel's serialized MLIR; with
