@@ -1088,11 +1088,11 @@ def searched_vs_dp_ratio(on_cpu):
         # op — the per-workload kernel_choices record for strategies
         # that actually SEARCH (the CPU proxy workloads run heuristic
         # single-device strategies and record none)
-        from flexflow_tpu.search.unity import kernel_choice_of
+        from flexflow_tpu.parallel.choice import Choice
         by_guid = {n.op.guid: n.op.name for n in ff.executor.nodes}
         kchoices = {}
         for guid, oj in (searched.get("ops") or {}).items():
-            impl = kernel_choice_of(oj.get("choice"))
+            impl = Choice.parse(oj.get("choice")).kernel
             if impl is not None:
                 kchoices[by_guid.get(int(guid), guid)] = impl
         out = {

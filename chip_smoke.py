@@ -269,7 +269,7 @@ def run_one_chip(cache_dir):
     check(ff.search_info is not None, "the search did not run")
     check_placement(ff, x, 1)
     choices = collections.Counter(
-        getattr(s, "choice", None) for s in ff.strategy.values())
+        s.choice for s in ff.strategy.values())
     emit(phase="search", mesh=mesh_axes_of(ff),
          search_s=ff.search_seconds, ff_compile_s=compile_s,
          choices=choices, executor=type(ff.executor).__name__)
@@ -351,10 +351,11 @@ def run_arm(name, batch, baseline=None, **build_kw):
     x, y = make_data(tc)
     check_placement(ff, x, 4)
     choices = collections.Counter(
-        getattr(s, "choice", None) for s in ff.strategy.values())
+        s.choice for s in ff.strategy.values())
     hlo = compiled_train_step(ff).as_text()
     fused = sorted(getattr(ff.executor, "fused_update_ops", ()))
-    executed = {c for c in choices if c and "_k:fused" in c}
+    executed = {s.choice for s in ff.strategy.values()
+                if s.parsed.kernel == "fused"}
     check(bool(fused) == bool(executed),
           f"search chose {executed} but the executor fuses {fused}")
     losses, secs = train(ff, x, y)

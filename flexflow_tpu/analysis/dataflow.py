@@ -558,7 +558,7 @@ def _adapt_donor(node, donor, donor_st, di: int):
     ``model``-sharded dim 2 shards each constituent's [B,S,H] dim 2
     identically); param specs transfer by name (``kernel`` → the
     constituent kernel sees the same row/col split)."""
-    import types
+    from flexflow_tpu.parallel.strategy import OpStrategy
     dshape = (donor.op.output_shapes[di]
               if di < len(donor.op.output_shapes) else ())
     dspec = _norm(donor_st.output_specs[di]
@@ -573,10 +573,9 @@ def _adapt_donor(node, donor, donor_st, di: int):
                                   or dshape[d] % oshape[d] == 0):
                 ent[d] = dspec[d]
         specs.append(tuple(ent))
-    return types.SimpleNamespace(
-        output_specs=specs,
-        param_specs=dict(getattr(donor_st, "param_specs", None) or {}),
-        choice=getattr(donor_st, "choice", None))
+    return OpStrategy(output_specs=specs,
+                      param_specs=dict(donor_st.param_specs or {}),
+                      choice=donor_st.choice)
 
 
 def _project_strategy(pre_nodes, post_strategy, post_nodes=None,

@@ -58,6 +58,7 @@ from flexflow_tpu.analysis.dataflow import (edge_reshard_table,
 from flexflow_tpu.analysis.diagnostics import (Diagnostic, error, info,
                                                warning)
 from flexflow_tpu.ffconst import CompMode, OperatorType
+from flexflow_tpu.parallel.choice import Choice
 
 # which priced kinds cover an inferred/emitted kind — the shared
 # definition (XLA AR decomposition, reshard covering permute/a2a) lives
@@ -166,10 +167,9 @@ def infer_strategy_collectives(ctx, edge_table=None,
             # different rows per device, so their grads all-reduce over
             # the data axes. A fully replicated op ("rep" choice)
             # computes identical grads on every device and needs no sync.
-            st_choice = getattr(ctx.strategy.get(op.guid), "choice",
-                                None) or ""
+            st = ctx.strategy.get(op.guid)
             stage_div = pp if op.guid in body_guids else 1
-            if wus_on or "_wus" in st_choice:
+            if wus_on or (st is not None and st.parsed.wus):
                 # weight-update sharding: the sync is a reduce-scatter
                 # (XLA's AR-decomposition half — stays in the allreduce
                 # bucket) plus the all-gather rebuilding the next step's
@@ -336,10 +336,10 @@ class CollectiveInferencePass:
         out: List[Diagnostic] = []
         for oj in ops:
             chosen_name = oj.get("chosen") or ""
-            if "_ovl" in chosen_name:
+            if Choice.parse(chosen_name).ovl:
                 continue
             cands = oj.get("candidates") or []
-            if not any("_ovl" in (c.get("choice") or "") for c in cands):
+            if not any(Choice.parse(c.get("choice")).ovl for c in cands):
                 continue  # no twin enumerated — nothing was rejected
             chosen = next((c for c in cands if c.get("chosen")), None)
             terms = (chosen or {}).get("terms") or {}
@@ -371,14 +371,12 @@ class CollectiveInferencePass:
         from flexflow_tpu.ops.pallas_kernels import (
             BLK_Q, MAX_FLASH_HEAD_DIM, MAX_FLASH_SEQ, flash_shape_legal,
             pallas_mode)
-        from flexflow_tpu.search.unity import kernel_choice_of
 
         out: List[Diagnostic] = []
         fusable = None
         for node in ctx.nodes:
-            ch = getattr(ctx.strategy.get(node.op.guid), "choice",
-                         None) or ""
-            impl = kernel_choice_of(ch)
+            st = ctx.strategy.get(node.op.guid)
+            impl = st.parsed.kernel if st is not None else None
             if impl is None:
                 continue
             op = node.op

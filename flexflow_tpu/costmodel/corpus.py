@@ -62,14 +62,13 @@ def row_impl(row: Dict[str, Any]) -> Optional[str]:
     impl = row.get("impl")
     if impl:
         return str(impl)
-    from flexflow_tpu.search.unity import kernel_choice_of
-    ch = row.get("choice") or ""
-    k = kernel_choice_of(ch)
-    if k is not None:
-        return k
+    from flexflow_tpu.parallel.choice import Choice
+    ch = Choice.parse(row.get("choice"))
+    if ch.kernel is not None:
+        return ch.kernel
     t = row.get("type")
     if t == "MULTIHEAD_ATTENTION":
-        return "ring" if "_ring" in ch else "einsum"
+        return "ring" if ch.ring else "einsum"
     if t == "CONV2D":
         return "conv"
     return None

@@ -26,6 +26,7 @@ from flexflow_tpu.losses import get_loss_fn
 from flexflow_tpu.metrics import Metrics
 from flexflow_tpu.obs.registry import get_registry
 from flexflow_tpu.ops.base import Op, OpContext
+from flexflow_tpu.parallel.choice import ExecPlan
 
 
 # pseudo-entry in the op-state dict holding the bf16 parameter working
@@ -81,12 +82,7 @@ class GraphExecutor:
         data_axes: Tuple[str, ...] = ("data",),
         final_is_softmax: bool = False,
         fold_conv_bn: bool = True,
-        weight_update_sharding: bool = False,
-        wus_ops: Optional[set] = None,
-        overlap_grad_sync: bool = False,
-        overlap_bucket_bytes: int = 4 << 20,
-        kernel_choices: Optional[Dict[str, str]] = None,
-        remat_ops: Optional[set] = None,
+        plan: ExecPlan = ExecPlan(),
     ):
         self.nodes = nodes
         self.by_guid = {n.guid: n for n in nodes}
@@ -113,50 +109,45 @@ class GraphExecutor:
         # flat-buffer layout — is the lever.
         self.use_master_copy = compute_dtype != jnp.float32
         self.fold_conv_bn = fold_conv_bn
-        # weight-update sharding (WUS): the data-axis gradient sync runs
-        # as a reduce-scatter onto a per-param shard spec, the f32 master
-        # copy + optimizer moments live sharded over the data axes, and
-        # the next step's bf16 compute params are all-gathered inside the
-        # same optimizer fusion (preserving the one-extra-bf16-write
-        # property). Per-chip optimizer HBM then scales with params/chip
-        # instead of total params. Only meaningful with a data degree > 1.
-        self.weight_update_sharding = bool(
-            weight_update_sharding and self._data_degree() > 1)
-        # per-op WUS granularity: when the search picked "_wus" choices
-        # per op, only those ops' params/state shard — the rest keep the
-        # plain all-reduce sync, closing the priced-vs-emitted gap on
-        # mixed strategies. None = every eligible op (forced/heuristic).
-        self.wus_ops = set(wus_ops) if wus_ops is not None else None
-        # comms-compute overlap: the WUS gradient sync issues as
-        # size-targeted bucketed async reduce-scatters in reverse-
-        # backward order (each bucket's collective depends only on its
-        # own grads plus the previous bucket's issue, so XLA's async
-        # collective scheduler hides it under the remaining backward
-        # compute), and the next step's bf16 param all-gathers chain in
-        # forward order under the optimizer fusion tail. Identity on
-        # values — bit-for-bit parity with the synchronous sync.
-        self.grad_overlap = bool(overlap_grad_sync
-                                 and self.weight_update_sharding)
-        self.overlap_bucket_bytes = max(1, int(overlap_bucket_bytes))
-        # per-op searched kernel implementations (ISSUE 15): {op name ->
-        # impl}. "fused" routes the op's optimizer update through the
-        # one-dispatch fused region (ops/fused_update.py, bit-compatible
-        # with the triad); "conv_bn_fused" executes the Conv2D and its
-        # BatchNorm consumer as one fused train-time region
-        # (layout.TrainFusedConvBN); attention impls ("flash"/"einsum")
-        # live on the op itself (MultiHeadAttention.kernel_impl, set by
-        # apply_strategy). None = no searched kernel dimension — every
-        # op keeps its availability-based default, bit-identical to
-        # pre-kernel-search execution.
-        self.kernel_choices = dict(kernel_choices) if kernel_choices else None
-        # per-op searched rematerialization (ISSUE 20): names of ops whose
-        # '_r' choice won — their forward runs under jax.checkpoint, so
-        # backward keeps only the op's boundary (inputs + params) and
-        # recomputes the interior. The native gate (ffs_strategy.hpp
-        # remat_gate) only spawns '_r' twins for stateless, collective-free
-        # ops, so the plain-forward branch below is the only wrap point.
-        # None/empty = no remat, bit-identical to pre-remat execution.
-        self.remat_ops = set(remat_ops) if remat_ops else None
+        # The plan's fields (parallel/choice.py says when each engages)
+        # are this executor's state from here on; tests and scripts set
+        # them. How each is run:
+        # WUS: the data-axis gradient sync is a reduce-scatter onto a
+        # per-param shard spec, the f32 master copy + optimizer moments
+        # live sharded over the data axes, and the next step's bf16
+        # compute params are all-gathered inside the same optimizer
+        # fusion (preserving the one-extra-bf16-write property). Per-chip
+        # optimizer HBM then scales with params/chip. With ``wus_ops``
+        # only those ops' params/state shard; the rest keep the plain
+        # all-reduce, closing the priced-vs-emitted gap on mixed strategies.
+        self.weight_update_sharding = plan.wus
+        self.wus_ops = (set(plan.wus_ops) if plan.wus_ops is not None
+                        else None)
+        # overlap: the WUS gradient sync issues as size-targeted bucketed
+        # async reduce-scatters in reverse-backward order (each bucket's
+        # collective depends only on its own grads plus the previous
+        # bucket's issue, so XLA's async collective scheduler hides it
+        # under the remaining backward compute), and the next step's bf16
+        # param all-gathers chain in forward order under the optimizer
+        # fusion tail. Identity on values: bit-for-bit with the
+        # synchronous sync.
+        self.grad_overlap = plan.overlap
+        self.overlap_bucket_bytes = plan.bucket_bytes
+        # kernels, {op name -> impl}: "fused" routes the op's optimizer
+        # update through the one-dispatch fused region
+        # (ops/fused_update.py, bit-compatible with the triad);
+        # "conv_bn_fused" runs the Conv2D and its BatchNorm consumer as
+        # one train-time region (layout.TrainFusedConvBN); attention
+        # impls live on the op itself (MultiHeadAttention.kernel_impl).
+        self.kernel_choices = (dict(plan.kernel_choices)
+                               if plan.kernel_choices else None)
+        # remat: these ops' forward runs under jax.checkpoint, so backward
+        # keeps only the op's boundary (inputs + params) and recomputes
+        # the interior. The native gate (ffs_strategy.hpp remat_gate)
+        # only spawns '_r' twins for stateless, collective-free ops, so
+        # the plain-forward branch of the step is the only wrap point.
+        self.remat_ops = set(plan.remat_ops) if plan.remat_ops else None
+        self.body_remat = plan.body_remat  # PipelineGraphExecutor's
         self.fused_update_ops = {
             n for n, impl in (self.kernel_choices or {}).items()
             if impl == "fused"}
@@ -164,6 +155,19 @@ class GraphExecutor:
         self._jit_train = None
         self._jit_eval = None
         self._jit_fwd = {}  # keyed by training flag
+
+    @property
+    def plan(self) -> ExecPlan:
+        """What this executor runs now, as the record it was built from."""
+        return ExecPlan(
+            wus=self.weight_update_sharding,
+            wus_ops=(frozenset(self.wus_ops) if self.wus_ops is not None
+                     else None),
+            overlap=self.grad_overlap,
+            bucket_bytes=self.overlap_bucket_bytes,
+            kernel_choices=self.kernel_choices,
+            remat_ops=frozenset(self.remat_ops) if self.remat_ops else None,
+            body_remat=self.body_remat)
 
     # ---- weight-update sharding (WUS) -------------------------------------
     def _data_degree(self) -> int:

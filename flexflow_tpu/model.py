@@ -14,6 +14,7 @@ than Legion task launches. ``fit/eval`` mirror the Python frontend's loop
 
 from __future__ import annotations
 
+import dataclasses
 import time
 import warnings
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -617,6 +618,7 @@ class FFModel:
         self.search_objective = None
 
         import math as _math
+        from flexflow_tpu.parallel.choice import DATA_AXES, plan_execution
         from flexflow_tpu.parallel.strategy import (
             data_parallel_strategy, apply_strategy, tensor_parallel_overrides)
         from flexflow_tpu.search import unity as _unity
@@ -792,168 +794,19 @@ class FFModel:
         # WUS/optimizer-state sharding, and the bucketed-RS gradient sync
         # all extend across it (the cross-slice sync is the slow DCN leg
         # the '_ovl' pricing hides under backward compute)
-        data_axes = tuple(a for a in self.mesh.axis_names
-                          if a in ("slice", "data", "replica"))
+        data_axes = tuple(a for a in self.mesh.axis_names if a in DATA_AXES)
         axes_now = dict(zip(self.mesh.axis_names, self.mesh.devices.shape))
-        # weight-update sharding (WUS): reduce-scatter gradient sync +
-        # data-sharded master params / optimizer moments + fused all-gather
-        # of the next step's compute params (flexflow_tpu/executor.py).
-        # 'auto' defers to the native DP's per-mesh verdict when the
-        # strategy was searched (WUS is a priced choice dimension — the
-        # '_wus' choice suffix); heuristic strategies engage it at data
-        # degree >= 4, where the optimizer-state HBM win dominates.
-        import math as _math2
-        data_deg = _math2.prod(axes_now.get(a, 1) for a in data_axes) or 1
-        wus_mode = getattr(cfg, "weight_update_sharding", "auto")
-        if wus_mode not in ("auto", "on", "off"):
-            raise ValueError(f"weight_update_sharding expects auto|on|off, "
-                             f"got {wus_mode!r}")
-        searched = isinstance(self.search_info, dict)
-        searched_wus = searched and any(
-            "_wus" in (getattr(st, "choice", None) or "")
-            for st in (self.strategy or {}).values())
-        if comp_mode == CompMode.INFERENCE or wus_mode == "off":
-            wus = False
-        elif wus_mode == "on":
-            wus = data_deg > 1
-        else:
-            wus = searched_wus if searched else data_deg >= 4
-        self.wus_enabled = wus
-        # per-op WUS granularity: a searched strategy picks '_wus' per
-        # op; under 'auto' the executor honors each op's choice instead
-        # of applying WUS globally — the ops the DP left on plain
-        # all-reduce keep it, closing the priced-vs-emitted gap on mixed
-        # strategies. Forced 'on' (and heuristic strategies) stay global.
-        wus_ops = None
-        if wus and wus_mode == "auto" and searched and searched_wus:
-            wus_ops = {
-                n.op.name for n in nodes
-                if "_wus" in (getattr((self.strategy or {}).get(n.op.guid),
-                                      "choice", None) or "")}
-        # comms-compute overlap (ISSUE 9): bucketed async grad reduce-
-        # scatter + prefetched compute-param all-gathers. 'auto' follows
-        # the search: overlap engages when the DP picked '_ovl' choice
-        # twins (with the searched bucket size), or whenever WUS engages
-        # on heuristic strategies (4 MB default); explicit N forces
-        # N-MB buckets; '0'/'off' disables.
-        ovl_raw = str(getattr(cfg, "overlap_bucket_mb", "auto")).lower()
-        searched_ovl = searched and any(
-            "_ovl" in (getattr(st, "choice", None) or "")
-            for st in (self.strategy or {}).values())
-        searched_bucket = ((self.search_info or {}).get("overlap") or {}).get(
-            "bucket_mb") if searched else None
-        if ovl_raw in ("0", "off"):
-            overlap, bucket_mb = False, 4.0
-        elif ovl_raw == "auto":
-            overlap = searched_ovl if searched else wus
-            bucket_mb = float(searched_bucket or 4.0)
-        else:
-            bucket_mb = float(int(ovl_raw))
-            overlap = bucket_mb > 0
-        self.overlap_enabled = bool(overlap and wus)
-        # kernel-implementation choices (ISSUE 15): the search prices
-        # "_k:<impl>" twins per op; the executor honors each op's chosen
-        # lowering through the same per-op plumbing as wus_ops. When the
-        # kernel dimension ran, attention ops whose choice kept the
-        # DEFAULT impl are pinned to it ("einsum") so the executor's
-        # availability-based auto-pick cannot silently run a kernel the
-        # DP priced AND rejected (the priced-vs-executed gap FFL209
-        # watches). Off/not-searched leaves every op on auto — the
-        # pre-kernel-search behavior, bit-identical.
-        import os as _os
-        from flexflow_tpu.search.unity import kernel_choice_of
-        kernel_on = ((searched or any(
-                         "_k:" in (getattr(st, "choice", None) or "")
-                         for st in (self.strategy or {}).values()))
-                     and str(getattr(cfg, "kernel_search", "auto")).lower()
-                     != "off"
-                     and not _os.environ.get("FFS_NO_KERNEL_SEARCH"))
-        # pipe-mesh winners never enumerated the kernel dimension (the
-        # native search gates "_k:" twins off pp>1 meshes) — pinning
-        # attention to einsum there would disable the availability-based
-        # flash auto-pick the DP never priced an alternative to
-        if axes_now.get("pipe", 1) > 1:
-            kernel_on = False
-        kernel_choices: Optional[Dict[str, str]] = None
-        if kernel_on:
-            kernel_choices = {}
-            for n in nodes:
-                ch = getattr((self.strategy or {}).get(n.op.guid),
-                             "choice", None) or ""
-                impl = kernel_choice_of(ch)
-                if impl is not None:
-                    kernel_choices[n.op.name] = impl
-                elif n.op.op_type == OperatorType.MULTIHEAD_ATTENTION:
-                    kernel_choices[n.op.name] = ("ring" if "_ring" in ch
-                                                 else "einsum")
-            def _flash_was_enumerable(op):
-                # mirror the native flash gate (ffs_strategy.hpp
-                # kernel_gate): the "einsum" pin below asserts "the DP
-                # priced flash AND rejected it" — which only holds when
-                # a twin could exist for this op. Where the gate
-                # excluded flash (dropout, tile divisibility,
-                # cross-attention) the availability-based auto pick
-                # must survive: eval/serve forwards may legally run
-                # flash even though the TRAINING search never priced it.
-                from flexflow_tpu.ops.pallas_kernels import (
-                    flash_shape_legal)
-                try:
-                    b, s, e = op.input_shapes[0]
-                    sk = (op.input_shapes[1][1]
-                          if len(op.input_shapes) > 1 else s)
-                    return (sk == s and flash_shape_legal(s, op.head_dim)
-                            and not (comp_mode == CompMode.TRAINING
-                                     and op.dropout > 0))
-                except Exception:
-                    return False
-
-            for n in nodes:
-                impl = kernel_choices.get(n.op.name)
-                if not hasattr(n.op, "seq_parallel"):
-                    continue
-                if impl == "flash":
-                    n.op.kernel_impl = impl
-                elif impl == "einsum" and _flash_was_enumerable(n.op):
-                    n.op.kernel_impl = impl
-                n.op._kernel_fallback = None  # fresh compile, fresh record
-        else:
-            # the off switch promises availability-based defaults
-            # bit-identical to pre-kernel-search execution: clear any
-            # kernel_impl apply_strategy pinned from an imported "_k:"
-            # strategy under FFS_NO_KERNEL_SEARCH / --kernel-search off
-            # (and any stale fallback record with it — FFL209 must not
-            # keep firing for a fallback that can no longer occur)
-            for n in nodes:
-                if getattr(n.op, "kernel_impl", None) is not None:
-                    n.op.kernel_impl = None
-                if getattr(n.op, "_kernel_fallback", None) is not None:
-                    n.op._kernel_fallback = None
-        self.kernel_choices = kernel_choices
-        # rematerialization (ISSUE 20): on flat meshes the search prices
-        # per-op '_r' twins — ops whose twin won run under jax.checkpoint
-        # (executor remat_ops); pipe meshes never enumerate '_r' twins and
-        # instead carry a block-level 'remat' bit in the searched pipeline
-        # object (body_remat below). The off switch (--remat-search off /
-        # FFS_NO_REMAT) forces both off — bit-identical to pre-remat
-        # execution.
-        from flexflow_tpu.search.unity import executed_remat_ops
-        remat_on = (str(getattr(cfg, "remat_search", "auto")).lower() != "off"
-                    and not _os.environ.get("FFS_NO_REMAT"))
-        remat_ops: Optional[set] = None
-        if remat_on and axes_now.get("pipe", 1) == 1:
-            remat_ops = executed_remat_ops(nodes, self.strategy) or None
-        self.remat_ops = remat_ops
+        # what the executor runs of the searched choice dimensions (WUS,
+        # overlap, kernel impls, remat), every switch applied
+        plan = plan_execution(nodes, self.strategy, axes_now,
+                              self.search_info, cfg, comp_mode)
+        self.wus_enabled = plan.wus
+        self.overlap_enabled = plan.overlap
+        self.kernel_choices = plan.kernel_choices
+        self.remat_ops = set(plan.remat_ops) if plan.remat_ops else None
         exec_kwargs = dict(compute_dtype=compute_dtype, data_axes=data_axes,
                            final_is_softmax=self._final_is_softmax,
-                           fold_conv_bn=cfg.fold_conv_bn,
-                           weight_update_sharding=wus,
-                           wus_ops=wus_ops,
-                           overlap_grad_sync=overlap,
-                           # MB (1e6), matching the native bucket sweep's
-                           # wire-byte unit (ffs_strategy.hpp kOvlBucketMB)
-                           overlap_bucket_bytes=int(bucket_mb * 1e6),
-                           kernel_choices=kernel_choices,
-                           remat_ops=remat_ops)
+                           fold_conv_bn=cfg.fold_conv_bn, plan=plan)
         # conv-family execution layout (flexflow_tpu/layout.py): NCHW stays
         # the API/PCG boundary, but on TPU the conv family computes
         # channels-last with boundary transposes hoisted to chain edges.
@@ -996,7 +849,6 @@ class FFModel:
                 microbatches=microbatches,
                 schedule=schedule,
                 shard_queue=getattr(cfg, "pipeline_shard_queue", True),
-                body_remat=bool(remat_on and pinfo.get("remat")),
                 **exec_kwargs)
         else:
             self.layout_info = propagate_layouts(nodes, **self._layout_args)
@@ -1921,11 +1773,11 @@ class FFModel:
                            data_axes=full.data_axes,
                            final_is_softmax=self._final_is_softmax,
                            fold_conv_bn=full.fold_conv_bn,
-                           weight_update_sharding=full.weight_update_sharding,
-                           wus_ops=full.wus_ops,
-                           overlap_grad_sync=full.grad_overlap,
-                           overlap_bucket_bytes=full.overlap_bucket_bytes,
-                           kernel_choices=full.kernel_choices)
+                           # a shorter bucket holds fewer activations than
+                           # the length the search fitted: it does not pay
+                           # the recompute
+                           plan=dataclasses.replace(full.plan,
+                                                    remat_ops=None))
         ex.comp_mode = full.comp_mode
         self._seq_execs[bucket] = ex
         return ex

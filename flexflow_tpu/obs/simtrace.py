@@ -120,27 +120,6 @@ def per_op_predicted(tasks: List[Dict[str, Any]]
     return out
 
 
-def _row_impl(ff, op, choice: Optional[str]) -> Optional[str]:
-    """Kernel impl of one corpus row: the ``_k:`` choice suffix when the
-    search picked one, else the executor's recorded kernel choice, else
-    (attention only) the impl ``forward`` dispatches on this platform.
-    None for ops with no registered kernel alternatives."""
-    from flexflow_tpu.search.unity import kernel_choice_of
-    k = kernel_choice_of(choice)
-    if k is not None:
-        return k
-    kc = getattr(ff.executor, "kernel_choices", None) or {}
-    if op.name in kc:
-        return kc[op.name]
-    if hasattr(op, "selected_impl"):
-        try:
-            mesh_axes = dict(zip(ff.mesh.axis_names, ff.mesh.devices.shape))
-            return op.selected_impl(mesh_axes, training=True)
-        except Exception:
-            return None
-    return None
-
-
 def corpus_rows(ff, resp: Dict[str, Any],
                 measured: Optional[Dict[str, float]] = None
                 ) -> List[Dict[str, Any]]:
@@ -161,6 +140,10 @@ def corpus_rows(ff, resp: Dict[str, Any],
     sources = resp.get("cost_sources") or {}
     mesh_axes = dict(zip(ff.mesh.axis_names,
                          (int(d) for d in ff.mesh.devices.shape)))
+    from flexflow_tpu.search.unity import executed_kernel_choices
+    impls = executed_kernel_choices(
+        ff.executor.nodes, ff.strategy, mesh_axes, training=True,
+        recorded=ff.executor.kernel_choices)
     rows: List[Dict[str, Any]] = []
     for idx, node in enumerate(ff.executor.nodes):
         op = node.op
@@ -178,19 +161,16 @@ def corpus_rows(ff, resp: Dict[str, Any],
             io_bytes += float(math.prod(s)) * dts
         for s in op.output_shapes:
             io_bytes += float(math.prod(s)) * dts
-        choice = getattr(st, "choice", None)
         rows.append(dict(
             schema=CORPUS_SCHEMA_VERSION,
             guid=op.guid,
             name=op.name,
             type=op.op_type.name,
             out_shape=list(op.output_shapes[0]) if op.output_shapes else [],
-            choice=choice,
-            # which kernel implementation executed the op (the searched
-            # "_k:" dimension, ISSUE 15): the executor's recorded choice
-            # wins; attention ops without one report the impl forward
-            # actually dispatches (ring/flash/einsum)
-            impl=_row_impl(ff, op, choice),
+            choice=st.choice if st is not None else None,
+            # which kernel implementation executed the op; None for ops
+            # with no registered kernel alternatives
+            impl=impls.get(op.name),
             # priced terms are PER-CHIP SHARDED schedule durations;
             # measured fwd/bwd are WHOLE-OP unsharded profile seconds —
             # work_div is the strategy's split so consumers can compare

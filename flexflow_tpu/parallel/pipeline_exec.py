@@ -51,16 +51,15 @@ BODY_KEY = "__pipe_body__"
 class PipelineGraphExecutor(GraphExecutor):
     def __init__(self, *args, pipe_blocks=None, microbatches: int = 0,
                  pipe_axis: str = "pipe", schedule: str = "auto",
-                 shard_queue: bool = True, body_remat: bool = False,
-                 **kwargs):
+                 shard_queue: bool = True, **kwargs):
+        # ``self.body_remat`` (the plan's, set by the base): the searched
+        # pipeline's block-level 'remat' bit (ISSUE 20). Each block body
+        # runs under jax.checkpoint, so a stage keeps only block BOUNDARY
+        # activations per in-flight microbatch and recomputes block
+        # interiors in backward — the HBM term ffs_sim.hpp prices as
+        # k*block_out/dp + one transient interior. False = bit-identical
+        # to pre-remat execution.
         super().__init__(*args, **kwargs)
-        # block-level rematerialization (ISSUE 20): the searched pipeline
-        # 'remat' bit. Each block body runs under jax.checkpoint, so a
-        # stage keeps only block BOUNDARY activations per in-flight
-        # microbatch and recomputes block interiors in backward — the HBM
-        # term ffs_sim.hpp prices as k*block_out/dp + one transient
-        # interior. False = bit-identical to pre-remat execution.
-        self.body_remat = bool(body_remat)
         if pipe_blocks is None:
             raise ValueError("PipelineGraphExecutor needs detected blocks")
         self.pb = pipe_blocks

@@ -21,12 +21,20 @@ from jax.sharding import PartitionSpec as P
 
 from flexflow_tpu.ffconst import OperatorType
 from flexflow_tpu.ops.base import DimRole
+from flexflow_tpu.parallel.choice import Choice
 
 
 @dataclasses.dataclass
 class OpStrategy:
     output_specs: List[Optional[P]]
     param_specs: Dict[str, P] = dataclasses.field(default_factory=dict)
+    # the search's name for its pick (None on heuristic strategies): the
+    # form strategy files and reports hold; code reads ``parsed``
+    choice: Optional[str] = None
+
+    @property
+    def parsed(self) -> Choice:
+        return Choice.parse(self.choice)
 
 
 Strategy = Dict[int, OpStrategy]
@@ -126,33 +134,20 @@ def apply_strategy(nodes, strategy: Strategy, mesh) -> None:
         if st is not None:
             node.output_specs = list(st.output_specs)
             node.param_specs = dict(st.param_specs)
-            # a searched "ring" choice switches the attention op onto the
-            # ring-attention execution path over the mesh's 'seq' axis (the
+            # the searched choice switches the op's execution path (the
             # analog of a substitution rewrite changing the op's task
-            # implementation); "head" choices record the head-sharded axis
-            # so ring attention keeps heads distributed under shard_map
-            # ("_wus" may trail any choice name — weight-update sharding
-            # composes with every base choice, so match by substring)
-            choice = getattr(st, "choice", None) or ""
-            # a searched "_k:<impl>" kernel suffix records WHICH KERNEL
-            # runs the op (ISSUE 15): attention ops carry it as
-            # kernel_impl (forward honors it — "flash" forces the Pallas
-            # kernel where available, "einsum" pins the reference path);
-            # "fused"/"conv_bn_fused" are executor-level choices routed
-            # via GraphExecutor.kernel_choices
-            if "_k:" in choice and hasattr(node.op, "seq_parallel"):
-                from flexflow_tpu.search.unity import kernel_choice_of
-                impl = kernel_choice_of(choice)
-                if impl in ("flash", "einsum"):
-                    # model.compile clears this again when the kernel
-                    # dimension is switched off (--kernel-search off /
-                    # FFS_NO_KERNEL_SEARCH): the off switch promises
-                    # availability-based defaults
-                    node.op.kernel_impl = impl
+            # implementation): "flash"/"einsum" say which kernel runs an
+            # attention op (plan_execution clears it again when the kernel
+            # dimension is off; "fused"/"conv_bn_fused" are the
+            # executor's), a ring choice runs it over the mesh's 'seq'
+            # axis, a head choice keeps heads distributed under shard_map
+            choice = st.parsed
             if hasattr(node.op, "seq_parallel"):
-                if "_ring" in choice and axis_sizes.get("seq", 1) > 1:
+                if choice.kernel in ("flash", "einsum"):
+                    node.op.kernel_impl = choice.kernel
+                if choice.ring and axis_sizes.get("seq", 1) > 1:
                     node.op.seq_parallel = "seq"
-                if "head" in choice and axis_sizes.get("model", 1) > 1:
+                if choice.head and axis_sizes.get("model", 1) > 1:
                     node.op.head_parallel = "model"
                 # record the batch-dim sharding (may be a tuple under the
                 # sample2 'data+model' 2-D partition) so the flash-attention
@@ -162,8 +157,7 @@ def apply_strategy(nodes, strategy: Strategy, mesh) -> None:
                 if spec0:
                     entries = list(spec0)
                     node.op.batch_parallel = entries[0] if entries else None
-            if (hasattr(node.op, "expert_parallel")
-                    and "_ep" in choice
+            if (hasattr(node.op, "expert_parallel") and choice.expert
                     and axis_sizes.get("expert", 1) > 1):
                 node.op.expert_parallel = "expert"
         op = node.op

@@ -95,51 +95,23 @@ def _node_attrs(op) -> Dict[str, Any]:
     return attrs
 
 
-def kernel_choice_of(choice: Optional[str]) -> Optional[str]:
-    """Kernel impl a choice name selects (the ``_k:<impl>`` suffix of
-    the suffix lattice, ISSUE 15), or None for the default lowering.
-    The trailing ``_r`` remat suffix (canonical order
-    ``base[_wus][_ovl][_k:impl][_r]``) is not part of the impl name."""
-    if not choice or "_k:" not in choice:
-        return None
-    impl = choice.split("_k:", 1)[1]
-    if impl.endswith("_r"):
-        impl = impl[:-2]
-    return impl or None
-
-
-def remat_choice_of(choice: Optional[str]) -> bool:
-    """Whether a choice name selects the rematerialized ("_r") twin —
-    the executor then routes the op through jax.checkpoint (ISSUE 20)."""
-    return bool(choice) and choice.endswith("_r")
-
-
-def executed_remat_ops(nodes, strategy) -> set:
-    """{op name} whose searched choice carries the ``_r`` remat suffix —
-    the per-op checkpoint policy the executor applies (the
-    ``wus_ops``/``kernel_choices`` per-op pattern)."""
-    out = set()
-    for node in nodes:
-        st = (strategy or {}).get(node.op.guid)
-        if remat_choice_of(getattr(st, "choice", None)):
-            out.add(node.op.name)
-    return out
-
-
 def executed_kernel_choices(nodes, strategy, mesh_axes,
-                            training: bool = False) -> Dict[str, str]:
-    """{op name -> kernel impl} a node list will EXECUTE: explicit
-    ``_k:`` suffixes from the strategy win; attention ops without one
-    report their static dispatch (``selected_impl`` — ring/flash/einsum
-    on this platform at these shapes). The ONE extraction the serve
-    bucket reports and the bench provenance column share, so the
-    recorded impls cannot drift between surfaces."""
+                            training: bool = False,
+                            recorded=None) -> Dict[str, str]:
+    """{op name -> kernel impl} a node list will EXECUTE: the searched
+    kernel of the op's choice wins, then the executor's ``recorded``
+    kernel choices; attention ops with neither report their static
+    dispatch (``selected_impl`` — ring/flash/einsum on this platform at
+    these shapes). The ONE extraction the serve bucket reports, the
+    bench provenance column and the corpus rows share, so the recorded
+    impls cannot drift between surfaces."""
     out: Dict[str, str] = {}
     for node in nodes:
         st = (strategy or {}).get(node.op.guid)
-        impl = kernel_choice_of(getattr(st, "choice", None))
-        if impl is not None:
-            out[node.op.name] = impl
+        if st is not None and st.parsed.kernel is not None:
+            out[node.op.name] = st.parsed.kernel
+        elif node.op.name in (recorded or {}):
+            out[node.op.name] = recorded[node.op.name]
         elif hasattr(node.op, "selected_impl"):
             try:
                 out[node.op.name] = node.op.selected_impl(
@@ -289,9 +261,8 @@ def decode_strategy(resp: Dict[str, Any], nodes) -> Tuple[Dict[str, int], Strate
             if owned and pname not in owned:
                 continue
             params[pname] = _entries_to_spec([_entry(e) for e in entries])
-        st = OpStrategy(output_specs=outs, param_specs=params)
-        st.choice = oj.get("choice")
-        strategy[node.op.guid] = st
+        strategy[node.op.guid] = OpStrategy(
+            output_specs=outs, param_specs=params, choice=oj.get("choice"))
     return mesh_axes, strategy
 
 
@@ -601,7 +572,7 @@ def strategy_json(mesh_axes: Dict[str, int], strategy: Strategy,
         if name is None:
             continue
         ops[name] = dict(
-            choice=getattr(st, "choice", None),
+            choice=st.choice,
             outputs=[list(s) if s is not None else None for s in st.output_specs],
             params={k: list(v) for k, v in st.param_specs.items()},
         )
@@ -636,7 +607,6 @@ def import_strategy_file(path: str, nodes) -> Tuple[Dict[str, int], Strategy]:
             for e in oj["outputs"]
         ]
         params = {k: P(*v) for k, v in oj.get("params", {}).items()}
-        st = OpStrategy(output_specs=outs, param_specs=params)
-        st.choice = oj.get("choice")
-        strategy[node.op.guid] = st
+        strategy[node.op.guid] = OpStrategy(
+            output_specs=outs, param_specs=params, choice=oj.get("choice"))
     return mesh_axes, strategy

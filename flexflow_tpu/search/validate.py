@@ -135,6 +135,7 @@ def simulate_strategy(ff, learned: Any = "auto") -> Dict[str, Any]:
     searched ones); False forces pure analytic pricing (the
     analytic-vs-learned accuracy comparison's control arm); an explicit
     native-table dict uses that table."""
+    from flexflow_tpu.parallel.choice import Choice
     from flexflow_tpu.search.native import native_simulate
     from flexflow_tpu.search.unity import machine_to_json, serialize_graph
 
@@ -148,42 +149,18 @@ def simulate_strategy(ff, learned: Any = "auto") -> Dict[str, Any]:
         learned = None
 
     nodes = ff.executor.nodes
-    wus_on = bool(getattr(ff.executor, "weight_update_sharding", False))
-    wus_ops = getattr(ff.executor, "wus_ops", None)
-    ovl_on = bool(getattr(ff.executor, "grad_overlap", False))
+    # replay what the executor EXECUTES, not what the DP picked: the
+    # plan sets each choice's suffixes to the runtime state. The native
+    # side falls back along the suffix lattice when an op spawns no
+    # matching twin.
+    plan = ff.executor.plan
     assignment = {}
     for node in nodes:
         st = (ff.strategy or {}).get(node.op.guid)
-        choice = getattr(st, "choice", None)
-        if choice is None:
-            choice = _infer_choice(node, st)
-        # replay what the executor EXECUTES, not what the DP picked: the
-        # executor honors per-op "_wus" choices when the search supplied
-        # them (wus_ops) and applies WUS globally otherwise, the
-        # bucketed-async overlap structuring ("_ovl") is an executor
-        # property, and the "_k:<impl>" kernel suffix survives exactly
-        # when the executor's kernel_choices will run that impl — so the
-        # suffixes are normalized to the runtime state (canonical order
-        # base[_wus][_ovl][_k:impl]). The native side falls back along
-        # the suffix lattice when an op spawns no matching twin.
-        base = choice
-        ksfx = ""
-        if "_k:" in base:
-            base, _, kimpl = base.partition("_k:")
-            ksfx = "_k:" + kimpl
-        for sfx in ("_ovl", "_wus"):
-            base = base.replace(sfx, "")
-        choice = base
-        op_wus = (wus_on and node.op.params_elems()
-                  and (wus_ops is None or node.op.name in wus_ops))
-        if op_wus:
-            choice += "_wus"
-            if ovl_on:
-                choice += "_ovl"
-        kc = getattr(ff.executor, "kernel_choices", None) or {}
-        if ksfx and kc.get(node.op.name) == ksfx[3:]:
-            choice += ksfx
-        assignment[str(node.op.guid)] = choice
+        searched = (st.parsed if st is not None and st.choice is not None
+                    else Choice.parse(_infer_choice(node, st)))
+        assignment[str(node.op.guid)] = str(
+            plan.executed_choice(node, searched))
     axes = dict(zip(ff.mesh.axis_names, ff.mesh.devices.shape))
     req = dict(
         nodes=serialize_graph(nodes,

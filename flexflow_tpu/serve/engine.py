@@ -170,7 +170,7 @@ class ServingEngine:
 
     @staticmethod
     def _signature(strategy):
-        return {g: (getattr(s, "choice", None),
+        return {g: (s.choice,
                     tuple(tuple(sp) if sp is not None else None
                           for sp in s.output_specs),
                     tuple(sorted((k, tuple(v))
@@ -179,6 +179,7 @@ class ServingEngine:
 
     def _build_bucket(self, bucket: int, budget: int) -> BucketExecutor:
         from flexflow_tpu.executor import GraphExecutor
+        from flexflow_tpu.parallel.choice import ExecPlan
         from flexflow_tpu.parallel.strategy import apply_strategy
 
         ff = self.ff
@@ -224,20 +225,23 @@ class ServingEngine:
         propagate_layouts(nodes, **getattr(
             ff, "_layout_args", dict(mode="nchw", on_tpu=False)))
         full = ff.executor
-        axes_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+        axes_now = dict(zip(mesh.axis_names, mesh.devices.shape))
         # only data axes whose degree divides the bucket stage the batch
         # sharded; a bucket below the data degree stages replicated
         data_axes = tuple(
             a for a in mesh.axis_names if a in ("data", "replica")
-            and axes_sizes.get(a, 1) > 1 and bucket % axes_sizes[a] == 0)
+            and axes_now.get(a, 1) > 1 and bucket % axes_now[a] == 0)
         ex = GraphExecutor(
             nodes, input_names, final_ref, mesh, ff.loss_type, ff.metrics,
             full.optimizer, compute_dtype=full.compute_dtype,
             data_axes=data_axes,
             final_is_softmax=ff._final_is_softmax,
-            fold_conv_bn=full.fold_conv_bn)
+            fold_conv_bn=full.fold_conv_bn,
+            # a forward-only bucket runs no gradient sync, no update and
+            # no backward: none of the plan's dimensions engages, and its
+            # attention impls are on the ops (apply_strategy above)
+            plan=ExecPlan())
         ex.comp_mode = CompMode.INFERENCE
-        axes_now = dict(zip(mesh.axis_names, mesh.devices.shape))
         # record the kernel each op will RUN in this bucket: explicit
         # "_k:" searched choices, plus attention ops' static dispatch
         # (apply_strategy already pinned kernel_impl from the choice) —

@@ -162,10 +162,12 @@ class TestCorpus:
         assert stats["skipped"] >= 1
 
     def test_roofline_rows_ingest(self):
-        """The committed repo-root roofline reports are corpus rows too
-        (the conv-class coverage channel)."""
+        """A committed ``scripts/roofline.py`` report (Inception-v3,
+        NCHW, a CPU run) is corpus rows too (the conv-class coverage
+        channel)."""
         from flexflow_tpu.costmodel import load_trace_dir
-        rows, stats = load_trace_dir(REPO)
+        rows, stats = load_trace_dir(
+            os.path.join(REPO, "tests", "fixtures", "roofline"))
         assert stats["roofline_files"] >= 1
         assert any(r["type"] == "CONV2D" for r in rows)
         assert all(r["measured"]["source"] == "measured" for r in rows)

@@ -451,11 +451,11 @@ class TestDecodeAndReplay:
         assert "cost_sources" in resp
 
     def test_kernel_choice_of(self):
-        from flexflow_tpu.search.unity import kernel_choice_of
-        assert kernel_choice_of("dp_wus_ovl_k:fused") == "fused"
-        assert kernel_choice_of("dp_head_k:flash") == "flash"
-        assert kernel_choice_of("dp_wus") is None
-        assert kernel_choice_of(None) is None
+        from flexflow_tpu.parallel.choice import Choice
+        assert Choice.parse("dp_wus_ovl_k:fused").kernel == "fused"
+        assert Choice.parse("dp_head_k:flash").kernel == "flash"
+        assert Choice.parse("dp_wus").kernel is None
+        assert Choice.parse(None).kernel is None
 
 
 class TestCorpusImpl:

@@ -237,21 +237,28 @@ class TestFlagPlumbing:
             FFConfig().parse_args(["--remat-search", "sometimes"])
 
     def test_suffix_helpers(self):
-        from flexflow_tpu.search.unity import (kernel_choice_of,
-                                               remat_choice_of)
-        assert remat_choice_of("dp_r")
-        assert remat_choice_of("dp_wus_ovl_k:fused_r")
-        assert not remat_choice_of("dp")
-        assert not remat_choice_of(None)
+        from flexflow_tpu.parallel.choice import Choice
+        assert Choice.parse("dp_r").remat
+        assert Choice.parse("dp_wus_ovl_k:fused_r").remat
+        assert not Choice.parse("dp").remat
+        assert not Choice.parse(None).remat
         # the kernel extractor must not swallow the trailing remat suffix
-        assert kernel_choice_of("dp_k:flash_r") == "flash"
-        assert kernel_choice_of("dp_wus_k:fused_r") == "fused"
-        assert kernel_choice_of("dp_r") is None
+        assert Choice.parse("dp_k:flash_r").kernel == "flash"
+        assert Choice.parse("dp_wus_k:fused_r").kernel == "fused"
+        assert Choice.parse("dp_r").kernel is None
 
     def test_executed_remat_ops(self):
-        from flexflow_tpu.search.unity import executed_remat_ops
+        from flexflow_tpu.ffconst import CompMode
+        from flexflow_tpu.parallel.choice import plan_execution
+        from flexflow_tpu.parallel.strategy import OpStrategy
+
+        def executed_remat_ops(nodes, strategy):
+            return plan_execution(nodes, strategy, {}, None, FFConfig(),
+                                  CompMode.TRAINING).remat_ops
 
         class _Op:
+            op_type = None
+
             def __init__(self, guid, name):
                 self.guid, self.name = guid, name
 
@@ -259,14 +266,13 @@ class TestFlagPlumbing:
             def __init__(self, guid, name):
                 self.op = _Op(guid, name)
 
-        class _St:
-            def __init__(self, choice):
-                self.choice = choice
+        def _St(choice):
+            return OpStrategy(output_specs=[], choice=choice)
 
         nodes = [_Node(1, "a"), _Node(2, "b"), _Node(3, "c")]
         strategy = {1: _St("dp_r"), 2: _St("dp"), 3: _St("dp_k:fused_r")}
         assert executed_remat_ops(nodes, strategy) == {"a", "c"}
-        assert executed_remat_ops(nodes, None) == set()
+        assert executed_remat_ops(nodes, None) is None
 
     def test_env_opt_out_forces_remat_off(self, monkeypatch):
         monkeypatch.setenv("FFS_NO_REMAT", "1")
