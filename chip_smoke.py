@@ -180,7 +180,8 @@ def check_kernels():
     from flexflow_tpu.ops.attention import scaled_dot_product_attention
     from flexflow_tpu.ops.fused_update import _adam_math, fused_adam_leaf
     from flexflow_tpu.ops.pallas_kernels import (
-        MAX_BWD_SEQ, MAX_FLASH_HEAD_DIM, MAX_FLASH_SEQ, flash_attention)
+        MAX_BWD_SEQ, MAX_FLASH_HEAD_DIM, MAX_FLASH_SEQ, flash_attention,
+        merge_heads, split_heads)
 
     def rel_err(got, want):
         """Per leaf: largest difference over largest reference value."""
@@ -202,8 +203,10 @@ def check_kernels():
         seq = shape[2]
         q, k, v = (jax.random.normal(key, shape, jnp.bfloat16)
                    for key in jax.random.split(jax.random.PRNGKey(SEED), 3))
-        flash = value_and_grads(
-            lambda q, k, v: flash_attention(q, k, v, causal=True))(q, k, v)
+        # the kernels take [B, S, H*D]; this check holds [B, H, S, D]
+        flash = value_and_grads(lambda q, k, v: split_heads(
+            flash_attention(merge_heads(q), merge_heads(k), merge_heads(v),
+                            q.shape[1], causal=True), q.shape[1]))(q, k, v)
         einsum = value_and_grads(
             lambda q, k, v: scaled_dot_product_attention(
                 q, k, v, causal=True, compute_dtype=jnp.bfloat16))(q, k, v)

@@ -405,13 +405,16 @@ class CollectiveInferencePass:
                     training = getattr(ctx.ff.executor, "comp_mode",
                                        CompMode.TRAINING) \
                         == CompMode.TRAINING
-                if not flash_shape_legal(seq, op.head_dim):
+                if not flash_shape_legal(seq, op.head_dim,
+                                         op.num_heads):
                     out.append(error(
                         "FFL208",
                         f"'_k:flash' is illegal at this shape (seq={seq}"
                         f" must divide by {BLK_Q} and stay <= "
                         f"{MAX_FLASH_SEQ}; head_dim={op.head_dim} must "
-                        f"divide by 8 and stay <= {MAX_FLASH_HEAD_DIM})"
+                        f"divide by 8 and stay <= {MAX_FLASH_HEAD_DIM}; "
+                        f"the {op.num_heads} heads must tile the lanes "
+                        f"in blocks of 128 or as one whole row)"
                         f" — the priced kernel cannot execute",
                         op=op.name,
                         hint="re-search (the flash gate rejects this "
@@ -431,7 +434,8 @@ class CollectiveInferencePass:
                 else:
                     from flexflow_tpu.ops.pallas_kernels import (
                         flash_attention_available)
-                    if not flash_attention_available(seq, op.head_dim):
+                    if not flash_attention_available(
+                            seq, op.head_dim, op.num_heads):
                         out.append(info(
                             "FFL209",
                             f"'_k:flash' was priced but this platform "

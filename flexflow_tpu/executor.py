@@ -623,6 +623,14 @@ class GraphExecutor:
             return -jnp.mean(jnp.take_along_axis(logp, lab[:, None], axis=-1))
         return fn(logits, labels)
 
+    def flash_lane_dense_ops(self) -> int:
+        """Attention ops whose forward, as last traced, called the flash
+        kernels with q, k, v as [B, S, heads*head_dim] (ops/attention.py
+        sets the flag): the gauge `executor.flash_lane_dense_ops`, and
+        `flash_lane_dense_ops` in every trace header."""
+        return sum(bool(getattr(n.op, "_flash_lane_dense", False))
+                   for n in self.nodes)
+
     def _training_nodes(self):
         """Node list the TRAIN step runs: (Conv2D, BatchNorm) pairs whose
         searched kernel choice is ``_k:conv_bn_fused`` execute as one
@@ -687,6 +695,10 @@ class GraphExecutor:
             (loss, (logits, new_state, counters)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(cparams)
+            # the ops' forwards have run (this is trace time): how many
+            # attention ops handed the flash kernels [B, S, H*D] operands
+            get_registry().gauge("executor.flash_lane_dense_ops",
+                                 self.flash_lane_dense_ops())
             # gradient sync over the data axes is inserted by GSPMD here
             # (in bf16 under the master-weight regime — half the bytes).
             # Under WUS the shard constraint turns that all-reduce into a

@@ -1378,6 +1378,10 @@ class FFModel:
             n = host.get(k + COUNT_SUFFIX)
             self.op_counters[k] = v / n if n else v
             get_registry().gauge(k, self.op_counters[k])
+        # beside what the ops counted on the device, what the trace of
+        # their forwards recorded on the host
+        self.op_counters["executor.flash_lane_dense_ops"] = float(
+            self.executor.flash_lane_dense_ops())
 
     def fit(self, x=None, y=None, batch_size: Optional[int] = None,
             epochs: Optional[int] = None, verbose: bool = True,

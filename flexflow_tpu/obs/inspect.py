@@ -304,6 +304,10 @@ def model_context(ff) -> Dict[str, Any]:
         # set_parameter call since (model.py records both, always)
         compile_phases=getattr(ff, "compile_phases", None),
         set_parameter_s=getattr(ff, "set_parameter_s", None),
+        # attention ops whose traced forward took the flash kernels in
+        # their [B, S, heads*head_dim] operand form (0 until a step or a
+        # forward has been traced)
+        flash_lane_dense_ops=ff.executor.flash_lane_dense_ops(),
     )
 
 

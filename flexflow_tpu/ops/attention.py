@@ -1,12 +1,16 @@
 """MultiHeadAttention.
 
 Analog of src/ops/attention.cc/.cu (cuDNN cudnnMultiHeadAttnForward,
-attention.cu:35). TPU design: the four projections are MXU matmuls with an
-explicit head dimension — weights are stored [num_heads, ...] so the head
-dim is a first-class shardable axis (attribute parallelism,
-substitution.cc:1764-1770 create_partition_attention_combine). The scaled
-dot-product core is jnp.einsum, which XLA fuses; a Pallas flash-attention
-kernel (ops/pallas_kernels.py) is used for long sequences when enabled.
+attention.cu:35). TPU design: the four projections are MXU matmuls.
+Weights are stored [num_heads, ...] so the head dim is a first-class
+shardable axis (attribute parallelism, substitution.cc:1764-1770
+create_partition_attention_combine); the products themselves run as
+plain 2-D matmuls over the [E, heads*head_dim] view of those weights, so
+q, k, v and o are [B, S, heads*head_dim] from the projections to the
+output projection (PR 30): the form the Pallas flash-attention kernels
+(ops/pallas_kernels.py) take, with no layout copy between a projection
+and a kernel. The einsum core and ring attention, which want
+[B, H, S, D], convert at their own boundary; XLA fuses that.
 """
 
 from __future__ import annotations
@@ -20,18 +24,22 @@ from flexflow_tpu.initializers import DefaultWeightInitializer
 from flexflow_tpu.ops.base import DimRole, Op, OpContext, register_op
 
 
-def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0):
-    """Apply RoPE to [B, H, S, D] (HF Llama rotate-half convention):
-    positions offset..offset+S-1, inv_freq = theta^(-2i/D).
-    ``position_offset`` (static or traced scalar) is the absolute
-    position of the first row — the incremental-decode path rotates the
-    new token at its true position, not at 0."""
-    b, h, s, d = x.shape
+def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
+                     seq_axis: int = 2):
+    """Apply RoPE to [B, H, S, D], or with ``seq_axis=1`` to [B, S, H, D]
+    (HF Llama rotate-half convention): positions offset..offset+S-1,
+    inv_freq = theta^(-2i/D). ``position_offset`` (static or traced
+    scalar) is the absolute position of the first row — the
+    incremental-decode path rotates the new token at its true position,
+    not at 0."""
+    s, d = x.shape[seq_axis], x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     pos = position_offset + jnp.arange(s, dtype=jnp.float32)
     angles = pos[:, None] * inv_freq[None, :]
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)  # [S, D]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
+    if seq_axis == 1:
+        cos, sin = cos[:, None, :], sin[:, None, :]        # [S, 1, D]
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)
     return (x.astype(jnp.float32) * cos + rotated.astype(jnp.float32) * sin
@@ -71,7 +79,8 @@ class MultiHeadAttention(Op):
 
     Weight layout keeps an explicit head axis: wq/wk/wv [H, E, D],
     wo [H, D, E] — the head axis is the attribute-parallel dim the search
-    may shard on the model mesh axis (reference attention.cc:214).
+    may shard on the model mesh axis (reference attention.cc:214). The
+    forward multiplies through their [E, H*D] / [H*D, E] views.
     """
 
     def __init__(self, layer, input_shapes):
@@ -118,6 +127,9 @@ class MultiHeadAttention(Op):
         # fflint FFL209 surfaces the priced-vs-executed gap.
         self.kernel_impl = p.get("kernel_impl", None)
         self._kernel_fallback = None
+        # set when a forward hands the flash kernels [B, S, H*D]
+        # operands (counted by `executor.flash_lane_dense_ops`)
+        self._flash_lane_dense = False
         # batch-dim sharding (str or tuple of mesh axes under the sample2
         # 'data+model' 2-D partition), recorded by apply_strategy
         self.batch_parallel = p.get("batch_parallel", None)
@@ -149,26 +161,42 @@ class MultiHeadAttention(Op):
                 params["bv"] = jnp.zeros((hk, d))
         return params
 
+    def _project(self, x, w, bias, cd):
+        """x [B, S, E] through w [H, E, D] (and bias [H, D]) to
+        [B, S, H*D] in float32: one plain 2-D product over the [E, H*D]
+        view of the weight, whose result has the heads side by side along
+        the lanes. Spelled as an einsum onto [B, H, S, D] (or
+        [B, S, H, D]) XLA writes the product sequence-minor and pays a
+        whole transposing copy of it before a kernel can take it."""
+        h, e, d = w.shape
+        w2 = w.astype(cd).transpose(1, 0, 2).reshape(e, h * d)
+        y = jnp.dot(x.astype(cd), w2, preferred_element_type=jnp.float32)
+        if bias is not None:
+            y = y + bias.reshape(h * d)
+        return y
+
     def forward(self, params, inputs, ctx: OpContext):
+        from flexflow_tpu.ops.pallas_kernels import merge_heads, split_heads
+
         query, key, value = (inputs + inputs[:1] * 2)[:3] if len(inputs) == 1 else inputs
         cd = ctx.compute_dtype
-        q = jnp.einsum("bse,hed->bhsd", query.astype(cd), params["wq"].astype(cd),
-                       preferred_element_type=jnp.float32)
-        k = jnp.einsum("bse,hed->bhsd", key.astype(cd), params["wk"].astype(cd),
-                       preferred_element_type=jnp.float32)
-        v = jnp.einsum("bse,hed->bhsd", value.astype(cd), params["wv"].astype(cd),
-                       preferred_element_type=jnp.float32)
-        if self.qkv_bias and "bq" in params:
-            q = q + params["bq"][None, :, None, :]
-            k = k + params["bk"][None, :, None, :]
-            v = v + params["bv"][None, :, None, :]
+        h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
+        biased = self.qkv_bias and "bq" in params
+        # q, k, v and o stay [B, S, heads*head_dim] from the projections
+        # to the output projection: what the products write, what the
+        # flash kernels take, and no minor dimension under 128 lanes
+        q = self._project(query, params["wq"], params["bq"] if biased else None, cd)
+        k = self._project(key, params["wk"], params["bk"] if biased else None, cd)
+        v = self._project(value, params["wv"], params["bv"] if biased else None, cd)
+        b, sq, sk = q.shape[0], q.shape[1], k.shape[1]
         if self.rope:
-            q = rotary_embedding(q, theta=self.rope_theta)
-            k = rotary_embedding(k, theta=self.rope_theta)
-        if self.num_kv_heads != self.num_heads:
-            rep = self.num_heads // self.num_kv_heads
-            k = jnp.repeat(k, rep, axis=1)
-            v = jnp.repeat(v, rep, axis=1)
+            q = rotary_embedding(q.reshape(b, sq, h, d), theta=self.rope_theta,
+                                 seq_axis=1).reshape(b, sq, h * d)
+            k = rotary_embedding(k.reshape(b, sk, hk, d), theta=self.rope_theta,
+                                 seq_axis=1).reshape(b, sk, hk * d)
+        if hk != h:
+            k, v = (jnp.repeat(x.reshape(b, sk, hk, d), h // hk, axis=2
+                               ).reshape(b, sk, h * d) for x in (k, v))
         rng = ctx.next_rng() if (self.dropout > 0 and ctx.training) else None
         dropout_rate = self.dropout if ctx.training else 0.0
         # the attention core consumes q/k/v in the compute dtype (the
@@ -176,11 +204,16 @@ class MultiHeadAttention(Op):
         # path below is f32 regardless, and bf16 kernel I/O halves the
         # flash kernel's HBM traffic
         q, k, v = q.astype(cd), k.astype(cd), v.astype(cd)
+
+        def heads_first(core):
+            """A core that wants [B, H, S, D], at its own boundary."""
+            return merge_heads(core(split_heads(q, h), split_heads(k, h),
+                                    split_heads(v, h)))
+
         seq_axis = self.seq_parallel
         mesh_axes = (dict(zip(ctx.mesh.axis_names, ctx.mesh.devices.shape))
                      if ctx.mesh is not None else {})
-        if (seq_axis and mesh_axes.get(seq_axis, 1) > 1
-                and q.shape[2] == k.shape[2]):
+        if seq_axis and mesh_axes.get(seq_axis, 1) > 1 and sq == sk:
             if dropout_rate > 0.0 and not getattr(self, "_warned_dropout", False):
                 import warnings
 
@@ -194,23 +227,25 @@ class MultiHeadAttention(Op):
             # ring, scores never leave the shard
             from flexflow_tpu.parallel.ring_attention import ring_attention
 
-            o = ring_attention(q, k, v, ctx.mesh, seq_axis=seq_axis,
-                               head_axis=self.head_parallel,
-                               causal=self.causal)
+            o = heads_first(lambda q, k, v: ring_attention(
+                q, k, v, ctx.mesh, seq_axis=seq_axis,
+                head_axis=self.head_parallel, causal=self.causal))
         elif (self.kernel_impl != "einsum"
-              and dropout_rate == 0.0 and q.shape[2] == k.shape[2]):
+              and dropout_rate == 0.0 and sq == sk):
             from flexflow_tpu.ops.pallas_kernels import (
                 flash_attention, flash_attention_available,
-                flash_attention_sharded)
+                flash_attention_sharded, flash_shape_legal)
 
-            available = flash_attention_available(q.shape[2], q.shape[3])
+            available = flash_attention_available(sq, d, h)
             if self.kernel_impl == "flash" and not available:
                 # the search chose flash but this platform/shape cannot
                 # run it: record the silent fallback for fflint FFL209
                 self._kernel_fallback = (
-                    f"flash unavailable at runtime (seq={q.shape[2]}, "
-                    f"head_dim={q.shape[3]}) — einsum executed instead")
+                    f"flash unavailable at runtime (seq={sq}, "
+                    f"head_dim={d}, heads={h}) — einsum executed instead")
             if available:
+                # for `executor.flash_lane_dense_ops`
+                self._flash_lane_dense = True
                 if any(s > 1 for s in mesh_axes.values()):
                     # non-trivial mesh: the raw pallas_call would be an
                     # unpartitionable custom call under GSPMD — run it
@@ -221,26 +256,28 @@ class MultiHeadAttention(Op):
                     bp = bp if isinstance(bp, tuple) else (bp,)
                     bp = tuple(a for a in bp if mesh_axes.get(a, 1) > 1)
                     bsz = int(np.prod([mesh_axes[a] for a in bp])) if bp else 1
-                    batch_axis = (bp if bp and q.shape[0] % bsz == 0
-                                  else None)
+                    batch_axis = (bp if bp and b % bsz == 0 else None)
                     if batch_axis is not None and len(batch_axis) == 1:
                         batch_axis = batch_axis[0]
                     hp = self.head_parallel
                     in_batch = batch_axis if isinstance(batch_axis, tuple) \
                         else (batch_axis,)
+                    # a shard's heads still have to tile the lanes
                     head_axis = (hp if hp and hp not in in_batch
                                  and mesh_axes.get(hp, 1) > 1
-                                 and q.shape[1] % mesh_axes[hp] == 0
+                                 and h % mesh_axes[hp] == 0
+                                 and flash_shape_legal(
+                                     sq, d, h // mesh_axes[hp])
                                  else None)
                     o = flash_attention_sharded(
-                        q, k, v, ctx.mesh, batch_axis=batch_axis,
+                        q, k, v, h, ctx.mesh, batch_axis=batch_axis,
                         head_axis=head_axis, causal=self.causal)
                 else:
-                    o = flash_attention(q, k, v, causal=self.causal)
+                    o = flash_attention(q, k, v, h, causal=self.causal)
             else:
-                o = scaled_dot_product_attention(
+                o = heads_first(lambda q, k, v: scaled_dot_product_attention(
                     q, k, v, causal=self.causal, dropout_rate=0.0,
-                    rng=None, compute_dtype=cd)
+                    rng=None, compute_dtype=cd))
         else:
             if self.kernel_impl == "flash" and self._kernel_fallback is None:
                 # forced flash but this forward cannot take the flash
@@ -249,14 +286,13 @@ class MultiHeadAttention(Op):
                 # fflint FFL209 surfaces the priced-vs-executed gap
                 self._kernel_fallback = (
                     f"flash has no lowering for this forward "
-                    f"(dropout_rate={dropout_rate}, Sq={q.shape[2]}, "
-                    f"Sk={k.shape[2]}) — einsum executed instead")
-            o = scaled_dot_product_attention(
+                    f"(dropout_rate={dropout_rate}, Sq={sq}, "
+                    f"Sk={sk}) — einsum executed instead")
+            o = heads_first(lambda q, k, v: scaled_dot_product_attention(
                 q, k, v, causal=self.causal, dropout_rate=dropout_rate,
-                rng=rng, compute_dtype=cd,
-            )
-        y = jnp.einsum("bhsd,hde->bse", o.astype(cd), params["wo"].astype(cd),
-                       preferred_element_type=jnp.float32)
+                rng=rng, compute_dtype=cd))
+        y = jnp.dot(o.astype(cd), params["wo"].astype(cd).reshape(h * d, -1),
+                    preferred_element_type=jnp.float32)
         if self.use_bias:
             y = y + params["bo"]
         return [y.astype(query.dtype)]
@@ -278,7 +314,8 @@ class MultiHeadAttention(Op):
             return "einsum"
         b, s, e = self.input_shapes[0]
         sk = self.input_shapes[1][1] if len(self.input_shapes) > 1 else s
-        if s == sk and flash_attention_available(s, self.head_dim):
+        if s == sk and flash_attention_available(s, self.head_dim,
+                                                 self.num_heads):
             return "flash"
         return "einsum"
 

@@ -14,12 +14,25 @@ sequences take the Q-blocked forward (`flash_fwd`) and the K-blocked
 backward (`flash_bwd_blocked`), up to MAX_FLASH_SEQ — the one upper bound
 the gate (``flash_attention_available``), the backward dispatch and the
 native ``kernel_gate`` share; past it attention runs the einsum path.
+
+One operand form (PR 30): q, k, v, o and their gradients are
+[B, S, H*D], the heads side by side along the lanes, which is what the
+projections' 2-D products write and the output projection reads. A
+block is S (or a block of) rows by one column block of 128 lanes: one
+head of 128 or two of 64 (``_heads_per_block``), picked by the
+BlockSpec's last index. So no XLA pass changes a layout between a
+projection and a kernel, and no array the kernels touch pads a 64-wide
+minor dimension to 128 lanes in HBM. Inside, a block's K^T, V^T, O^T,
+dK^T ... are [128, S] tiles whose heads are sublane ranges; where one
+head's lanes are wanted out of a [rows, 128] operand the others are
+zeroed (``_only_head``) and the MXU contracts all 128.
+
 Every MXU product takes its operands in the dtype the caller stored and
 accumulates in float32 (``_dot``); softmax statistics, ``exp``, scale
 and mask are float32. Tile sizes follow from the shape
-(``_heads_per_step``, ``_kv_block``); the timings quoted beside them are
-the kernels alone on a v5e (PR 28), and PERF.md has what they gave
-through the benchmark's full step.
+(``_heads_per_block``, ``_rows_per_step``, ``_kv_block``); the timings
+quoted beside them are the kernels alone on a v5e (PR 28), and PERF.md
+has what they gave through the benchmark's full step.
 
 CPU fallback: the same kernels run under ``interpret=True`` when
 FLEXFLOW_TPU_PALLAS=interpret (used by the deviceless tests); otherwise
@@ -37,10 +50,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 BLK_Q = 128  # rows of Q per grid step of the Q-blocked forward
+LANES = 128  # a vreg's and an HBM tile's minor extent
 
 # Mosaic's default scoped-VMEM budget on v5e is 16 MiB, which the
 # kernels below outgrow long before the chip's 128 MiB of VMEM is used
-# (they keep whole [S, D] panels resident): at 16 MiB the compiler
+# (they keep whole [S, 128] panels resident): at 16 MiB the compiler
 # refuses the bf16 K-blocked backward from S = 8192 and the forward from
 # S = 16384. Every flash pallas_call asks for 96 MiB instead; with that
 # the deviceless v5e compile accepts forward and both backwards for
@@ -66,9 +80,10 @@ KERNEL_NAME_PREFIX = "tpu_custom_call_"
 MAX_BWD_SEQ = 1024
 # Upper bounds of the flash path, forward and K-blocked backward alike.
 # What binds is the VMEM budget above, not the chip's physical VMEM: the
-# blocked backward holds the Q/dO/dQ panels ([S, D], lanes padded to 128)
-# plus [BLK, S] f32 score tiles, and the forward holds the K/V panels
-# plus a [BLK_Q, S] tile. Mirrored by the native kernel_gate
+# blocked backward holds the Q/dO/dQ panels of a column block ([S, 128]:
+# one head of 128 or two of 64 side by side, every lane a value) plus
+# [BLK, S] f32 score tiles, and the forward holds the K/V panels plus a
+# [BLK_Q, S] tile. Mirrored by the native kernel_gate
 # (native/ffs_strategy.hpp) so the search never prices a length the
 # compiler refuses.
 MAX_FLASH_SEQ = 16384
@@ -88,39 +103,60 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _heads_per_step(bh: int, s: int) -> int:
-    """Heads a grid step of the whole-tile kernels works through: the
-    largest of 8, 4, 2, 1 that keeps S^2 x heads within 2^21 score
-    elements and divides ``bh``. A step costs about 0.35 us before it
-    computes. v5e, bf16,
-    head_dim 64, forward / backward us for the same work with every
-    head of a step unrolled (PR 28): S = 512: 618 / 864 at 1, 462 / 752
-    at 4, 449 / 737 at 8; S = 1024: 422 / 681 at 1, 408 / 652 at 2;
-    S = 256: 499 / 735 at 4, 438 / 704 at 8, 422 / 705 at 16."""
-    heads = 8
-    while heads > 1 and (heads * s * s > 1 << 21 or bh % heads):
-        heads //= 2
-    return heads
+def split_heads(x, num_heads: int):
+    """[B, S, H*D], the kernels' form, as [B, H, S, D]."""
+    b, s, hd = x.shape
+    return x.reshape(b, s, num_heads, hd // num_heads).transpose(0, 2, 1, 3)
 
 
-def _for_heads(heads: int, unroll: int, body) -> None:
-    """``body(h)`` for every head of a grid step: a loop whose iteration
-    works through ``unroll`` heads, so that the scheduler can fill one
-    head's MXU waits with the next one's VPU passes without the kernel
-    growing with ``heads`` (both powers of two). v5e, S = 512, 8 heads a
-    step (PR 28): the forward takes 584 us at one head an iteration, 509
-    at 2, 474 at 4, 449 all 8 unrolled; the backward 777, 734, 737, 737.
-    Twelve layers'
-    kernels lower and compile (deviceless, cold) in 0.7 + 1.2 s at
-    forward 4 / backward 2 as at the parent, in 1.5 + 3.7 s unrolled."""
-    unroll = min(unroll, heads)
+def merge_heads(x):
+    """[B, H, S, D] as the [B, S, H*D] the kernels take."""
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _heads_per_block(num_heads: int, head_dim: int) -> int:
+    """Heads that sit side by side in one column block of a [B, S, H*D]
+    operand: as many as fill 128 lanes (two at head_dim 64, one at 128),
+    and all of them where the whole row is narrower than that."""
+    return min(num_heads, max(1, LANES // head_dim))
+
+
+def _rows_per_step(batch: int, heads_per_block: int, s: int) -> int:
+    """Batch rows a grid step of the whole-tile kernels works through,
+    each with the heads of its column block: the most that keeps the
+    step at 8 heads and S^2 x heads within 2^21 score elements, and
+    divides ``batch``. A step costs about 0.35 us before it computes.
+    v5e, bf16, head_dim 64, forward / backward us for the same work at
+    the [B*H, S, 64] operand form of PR 28, every head of a step
+    unrolled: S = 512: 618 / 864 at 1 head a step, 462 / 752 at 4,
+    449 / 737 at 8; S = 1024: 422 / 681 at 1, 408 / 652 at 2; S = 256:
+    499 / 735 at 4, 438 / 704 at 8, 422 / 705 at 16."""
+    rows = max(1, 8 // heads_per_block)
+    while rows > 1 and (rows * heads_per_block * s * s > 1 << 21
+                        or batch % rows):
+        rows //= 2
+    return rows
+
+
+def _for_rows(rows: int, unroll: int, body) -> None:
+    """``body(b)`` for every batch row of a grid step: a loop whose
+    iteration works through ``unroll`` rows, so that the scheduler can
+    fill one head's MXU waits with the next one's VPU passes without the
+    kernel growing with the step (both powers of two). v5e, S = 512, 8
+    heads a step (PR 28): the forward takes 584 us at one head an
+    iteration, 509 at 2, 474 at 4, 449 all 8 unrolled; the backward 777,
+    734, 737, 737. Twelve layers' kernels lower and compile (deviceless,
+    cold) in 0.7 + 1.2 s at forward 4 / backward 2, in 1.5 + 3.7 s
+    unrolled."""
+    unroll = min(unroll, rows)
 
     def step(i, carry):
         for u in range(unroll):
             body(i * unroll + u)
         return carry
 
-    jax.lax.fori_loop(0, heads // unroll, step, None)
+    jax.lax.fori_loop(0, rows // unroll, step, None)
 
 
 def _kv_block(s: int) -> int:
@@ -143,74 +179,123 @@ def _mask_causal(st, k0):
     return jnp.where(kk <= qq, st, -jnp.inf)
 
 
+def _only_head(x, h: int, head_dim: int):
+    """``x`` [rows, W] with every lane but head ``h``'s zeroed (itself
+    where the block is one head). A product that contracts over all W
+    lanes of a column block, or writes all of them, is then head ``h``'s
+    alone; the MXU contracts 128 deep and writes 128 wide whether 64 of
+    them are zero or not, and no operand is sliced at half a vreg."""
+    if x.shape[-1] == head_dim:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    mine = (lane >= h * head_dim) & (lane < (h + 1) * head_dim)
+    return jnp.where(mine, x, jnp.zeros_like(x))
+
+
+def _dot_head(a, b, h: int, head_dim: int):
+    """a[m, W] . b[n, W]^T over head ``h``'s lanes of a column block."""
+    return _dot(_only_head(a, h, head_dim), b, _NT)
+
+
+def _stack_heads(parts):
+    """Per-head [D, S] results as the [W, S] tile of their column block:
+    heads are sublane ranges there, so this moves nothing across lanes."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
-                      scale: float, blk_q: int):
-    """One (batch*head, q-block) grid cell: q [1,BLK_Q,D] against the full
-    K/V [1,S,D] resident in VMEM; scores never touch HBM. Also emits the
-    per-row logsumexp so the fused backward can recompute P exactly.
-    The forward of sequences past MAX_BWD_SEQ."""
-    q = q_ref[0]  # [BLK_Q, D]
-    k = k_ref[0]  # [S, D]
+                      scale: float, blk_q: int, head_dim: int):
+    """One (batch row, column block, q-block) grid cell: q [1,BLK_Q,W]
+    against the full K/V [1,S,W] resident in VMEM; scores never touch
+    HBM. Also emits the per-row logsumexp so the fused backward can
+    recompute P exactly. The forward of sequences past MAX_BWD_SEQ."""
+    q = q_ref[0]  # [BLK_Q, W]
+    k = k_ref[0]  # [S, W]
     v = v_ref[0]
-    s = _dot(q, k, _NT) * scale
     if causal:
-        blk = pl.program_id(1)
-        rows = blk * blk_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols <= rows, s, -jnp.inf)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    o = _dot(p.astype(v.dtype), v, _NN)
-    o_ref[0] = (o / l).astype(o_ref.dtype)
-    lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
+        tile = (q.shape[0], k.shape[0])
+        rows = pl.program_id(2) * blk_q + jax.lax.broadcasted_iota(
+            jnp.int32, tile, 0)
+        visible = jax.lax.broadcasted_iota(jnp.int32, tile, 1) <= rows
+    o = None
+    for h in range(q.shape[-1] // head_dim):
+        s = _dot_head(q, k, h, head_dim) * scale
+        if causal:
+            s = jnp.where(visible, s, -jnp.inf)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        # P against every head of the block, the other heads' lanes
+        # dropped from the [BLK_Q, W] result: cheaper than masking V
+        oh = _only_head(_dot(p.astype(v.dtype), v, _NN) / l, h, head_dim)
+        o = oh if o is None else o + oh
+        lse_ref[0, h, 0] = (m + jnp.log(l))[:, 0]
+    o_ref[0] = o.astype(o_ref.dtype)
 
 
 def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                            causal: bool, scale: float, heads: int):
-    """``heads`` (batch*head)s a grid cell, each with its whole sequence:
-    the forward up to MAX_BWD_SEQ. The score tile is held as [k, q]: the
-    softmax's max and sum then run down the sublanes (vreg against vreg)
-    and come out as the [1, S] rows the logsumexp is stored as, where the
-    [q, k] tile needs two cross-lane reductions a row and a relayout; and
-    O^T = V^T P^T streams D rows through the MXU against the tile, which
-    a head_dim of 64 fills where P V fills half its width."""
-    def head(h):
-        q, k, v = q_ref[h], k_ref[h], v_ref[h]      # [S, D]
-        st = _dot(k, q, _NT) * scale                 # [k, q]
-        if causal:
-            st = _mask_causal(st, 0)
-        m = jnp.max(st, axis=0, keepdims=True)       # [1, S]
-        pt = jnp.exp(st - m)
-        l = jnp.sum(pt, axis=0, keepdims=True)
-        ot = _dot(v.T, pt.astype(v.dtype), _NN)      # [D, q]
-        o_ref[h] = (ot / l).T.astype(o_ref.dtype)
-        lse_ref[h] = m + jnp.log(l)
+                            causal: bool, scale: float, rows: int,
+                            head_dim: int):
+    """``rows`` batch rows a grid cell, each with the heads of one column
+    block and their whole sequence: the forward up to MAX_BWD_SEQ. The
+    score tile is held as [k, q]: the softmax's max and sum then run down
+    the sublanes (vreg against vreg) and come out as the [1, S] rows the
+    logsumexp is stored as, where the [q, k] tile needs two cross-lane
+    reductions a row and a relayout; and O^T = V^T P^T streams D rows
+    through the MXU against the tile, which a head_dim of 64 fills where
+    P V fills half its width. V^T and O^T are [W, S] tiles of the whole
+    block: one transpose each serves every head of it."""
+    heads = q_ref.shape[-1] // head_dim
 
-    _for_heads(heads, 4, head)
+    def row(b):
+        q, k, v = q_ref[b], k_ref[b], v_ref[b]      # [S, W]
+        vt = v.T                                     # [W, S]
+        ots = []
+        for h in range(heads):
+            st = _dot_head(k, q, h, head_dim) * scale   # [k, q]
+            if causal:
+                st = _mask_causal(st, 0)
+            m = jnp.max(st, axis=0, keepdims=True)   # [1, S]
+            pt = jnp.exp(st - m)
+            l = jnp.sum(pt, axis=0, keepdims=True)
+            ot = _dot(vt[h * head_dim:(h + 1) * head_dim],
+                      pt.astype(v.dtype), _NN)       # [D, q]
+            ots.append(ot / l)
+            lse_ref[b, h] = m + jnp.log(l)
+        o_ref[b] = _stack_heads(ots).T.astype(o_ref.dtype)
+
+    _for_rows(rows, max(1, 4 // heads), row)
 
 
-def _flash_fwd(q, k, v, causal: bool, interpret: bool, out_dtype=None):
-    """q,k,v: [BH, S, D] with S % BLK_Q == 0 -> (o, lse[BH, 1, S])."""
-    bh, s, d = q.shape
+def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
+               out_dtype=None):
+    """q, k, v: [B, S, H*D] with S % BLK_Q == 0 -> (o [B, S, H*D],
+    lse [B, H, 1, S]). A block is S (or BLK_Q) rows by one column block
+    of the operand, picked by the BlockSpec's last index: in HBM's
+    (8, 128) tiles that is a run of whole tiles, no lane of it padding."""
+    b, s, hd = q.shape
+    d = hd // num_heads
+    hpb = _heads_per_block(num_heads, d)
+    w = hpb * d
     scale = 1.0 / float(d) ** 0.5
-    # lse is (bh, 1, s): TPU requires the last two block dims be
-    # (8,128)-aligned or span the array — a middle singleton satisfies
-    # that while keeping one row per (batch*head)
-    out_shape = (jax.ShapeDtypeStruct((bh, s, d), out_dtype or q.dtype),
-                 jax.ShapeDtypeStruct((bh, 1, s), jnp.float32))
+    # lse is (b, h, 1, s): TPU requires the last two block dims be
+    # (8,128)-aligned or span the array — a singleton before the
+    # sequence satisfies that while keeping one row per head
+    out_shape = (jax.ShapeDtypeStruct((b, s, hd), out_dtype or q.dtype),
+                 jax.ShapeDtypeStruct((b, num_heads, 1, s), jnp.float32))
     if s <= MAX_BWD_SEQ:
-        heads = _heads_per_step(bh, s)
-        seq_spec = pl.BlockSpec((heads, s, d), lambda b: (b, 0, 0))
+        rows = _rows_per_step(b, hpb, s)
+        seq_spec = pl.BlockSpec((rows, s, w), lambda i, j: (i, 0, j))
         return pl.pallas_call(
             functools.partial(_flash_fwd_whole_kernel, causal=causal,
-                              scale=scale, heads=heads),
+                              scale=scale, rows=rows, head_dim=d),
             name=KERNEL_NAME_PREFIX + "flash_fwd_whole",
             out_shape=out_shape,
-            grid=(bh // heads,),
+            grid=(b // rows, num_heads // hpb),
             in_specs=[seq_spec, seq_spec, seq_spec],
             out_specs=(seq_spec,
-                       pl.BlockSpec((heads, 1, s), lambda b: (b, 0, 0))),
+                       pl.BlockSpec((rows, hpb, 1, s),
+                                    lambda i, j: (i, j, 0, 0))),
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
         )(q, k, v)
@@ -220,82 +305,102 @@ def _flash_fwd(q, k, v, causal: bool, interpret: bool, out_dtype=None):
     blk = BLK_Q
     return pl.pallas_call(
         functools.partial(_flash_fwd_kernel, causal=causal, scale=scale,
-                          blk_q=blk),
+                          blk_q=blk, head_dim=d),
         name=KERNEL_NAME_PREFIX + "flash_fwd",
         out_shape=out_shape,
-        grid=(bh, s // blk),
+        grid=(b, num_heads // hpb, s // blk),
         in_specs=[
-            pl.BlockSpec((1, blk, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, blk, w), lambda b, j, i: (b, i, j)),
+            pl.BlockSpec((1, s, w), lambda b, j, i: (b, 0, j)),
+            pl.BlockSpec((1, s, w), lambda b, j, i: (b, 0, j)),
         ],
-        out_specs=(pl.BlockSpec((1, blk, d), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, 1, blk), lambda b, i: (b, 0, i))),
+        out_specs=(pl.BlockSpec((1, blk, w), lambda b, j, i: (b, i, j)),
+                   pl.BlockSpec((1, hpb, 1, blk),
+                                lambda b, j, i: (b, j, 0, i))),
         interpret=interpret,
         compiler_params=_FLASH_COMPILER_PARAMS,
     )(q, k, v)
 
 
-def _flash_bwd_tile(q, k, v, do, lse, delta, glse, scale: float, k0):
-    """FlashAttention-2 backward of one [k, q] tile: k, v [Bk, D] from key
-    ``k0`` on (None: not causal) against q, dO [Bq, D] and the [1, Bq]
-    rows lse, delta = rowsum(dO * O) and g_lse, the upstream gradient on
-    the logsumexp output (zero when only o is consumed; nonzero under
-    ring attention's streaming merge, whose weights are functions of
-    each block's lse). Recompute P from the saved lse, then dV = P^T dO,
-    dS = P * (dO V^T - delta + g_lse), dQ = dS K * scale,
-    dK = dS^T Q * scale; returns float32 dQ [Bq, D], dK, dV [Bk, D].
+def _flash_bwd_tile(q, k, v, o, do, lse, glse, scale: float, k0,
+                    head_dim: int):
+    """FlashAttention-2 backward of the [k, q] tiles of one column
+    block: k, v [Bk, W] from key ``k0`` on (None: not causal) against
+    q, O, dO [Bq, W] and, a head, the [1, Bq] rows lse and g_lse, the
+    upstream gradient on the logsumexp output (zero when only o is
+    consumed; nonzero under ring attention's streaming merge, whose
+    weights are functions of each block's lse). Recompute P from the
+    saved lse, then dV = P^T dO, dS = P * (dO V^T - delta + g_lse) with
+    delta = rowsum(dO * O), dQ = dS K * scale, dK = dS^T Q * scale;
+    returns float32 dQ [Bq, W], dK, dV [Bk, W].
+
+    delta is formed here, from the O^T and dO^T tiles, as sums down a
+    head's sublanes: outside, XLA writes the [.., S]-minor rows by
+    transposing the whole float32 product dO * O first (a 67 MB copy a
+    layer at bert_ae's sizes, deviceless compile, PR 30).
 
     With the tile as [k, q] the three products that contract over the
     sequence stream the D rows of dO^T, Q^T, K^T against it and come out
     as [D, S]: none transposes the tile, a head_dim of 64 fills the MXU,
     and the row statistics broadcast down the sublanes as they are
-    stored. v5e, bf16, S = 512, head_dim 64, 512 heads, one a step (PR
-    28): 864 us this way; 989 with the tile as [q, k] and dK, dV
-    contracting over its rows; 980 as [k, q] with [S, D] results, and
-    980 still with the element-wise work taken out of that one."""
-    st = _dot(k, q, _NT) * scale                     # [k, q]
-    if k0 is not None:
-        st = _mask_causal(st, k0)
-    pt = jnp.exp(st - lse)                           # exact softmax probs
-    dpt = _dot(v, do, _NT)
-    dst = (pt * (dpt - (delta - glse))).astype(q.dtype)
-    dvt = _dot(do.T, pt.astype(do.dtype), _NT)       # [D, k]
-    dkt = _dot(q.T, dst, _NT)                        # [D, k]
-    dqt = _dot(k.T, dst, _NN)                        # [D, q]
-    return (dqt * scale).T, (dkt * scale).T, dvt.T
+    stored. Q^T, K^T, O^T, dO^T and the results are [W, S] tiles of the
+    whole block, whose heads are sublane ranges: seven transposes a
+    block whatever the heads in it. v5e, bf16, S = 512, head_dim 64, 512
+    heads, one a step at the [B*H, S, 64] form (PR 28): 864 us this way;
+    989 with the tile as [q, k] and dK, dV contracting over its rows;
+    980 as [k, q] with [S, D] results, and 980 still with the
+    element-wise work taken out of that one."""
+    qt, kt, dot = q.T, k.T, do.T                     # [W, S]
+    dot_ot = dot.astype(jnp.float32) * o.T.astype(jnp.float32)
+    dqt, dkt, dvt = [], [], []
+    for h in range(q.shape[-1] // head_dim):
+        mine = slice(h * head_dim, (h + 1) * head_dim)
+        st = _dot_head(k, q, h, head_dim) * scale        # [k, q]
+        if k0 is not None:
+            st = _mask_causal(st, k0)
+        pt = jnp.exp(st - lse[h])                    # exact softmax probs
+        dpt = _dot_head(v, do, h, head_dim)
+        delta = jnp.sum(dot_ot[mine], axis=0, keepdims=True)   # [1, q]
+        dst = (pt * (dpt - (delta - glse[h]))).astype(q.dtype)
+        dvt.append(_dot(dot[mine], pt.astype(do.dtype), _NT))  # [D, k]
+        dkt.append(_dot(qt[mine], dst, _NT))                   # [D, k]
+        dqt.append(_dot(kt[mine], dst, _NN))                   # [D, q]
+    return ((_stack_heads(dqt) * scale).T, (_stack_heads(dkt) * scale).T,
+            _stack_heads(dvt).T)
 
 
-def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       glse_ref, dq_ref, dk_ref, dv_ref, *, causal: bool,
-                      scale: float, heads: int):
-    """``heads`` (batch*head)s a grid cell with the whole sequence in VMEM
-    (gated by MAX_BWD_SEQ). Scores/probabilities never touch HBM — the
-    reason XLA's einsum backward loses at these shapes."""
-    def head(h):
+                      scale: float, rows: int, head_dim: int):
+    """``rows`` batch rows a grid cell, each with the heads of one column
+    block and the whole sequence in VMEM (gated by MAX_BWD_SEQ).
+    Scores/probabilities never touch HBM — the reason XLA's einsum
+    backward loses at these shapes."""
+    def row(b):
         dq, dk, dv = _flash_bwd_tile(
-            q_ref[h], k_ref[h], v_ref[h], do_ref[h], lse_ref[h],
-            delta_ref[h], glse_ref[h], scale, 0 if causal else None)
-        dq_ref[h] = dq.astype(dq_ref.dtype)
-        dk_ref[h] = dk.astype(dk_ref.dtype)
-        dv_ref[h] = dv.astype(dv_ref.dtype)
+            q_ref[b], k_ref[b], v_ref[b], o_ref[b], do_ref[b], lse_ref[b],
+            glse_ref[b], scale, 0 if causal else None, head_dim)
+        dq_ref[b] = dq.astype(dq_ref.dtype)
+        dk_ref[b] = dk.astype(dk_ref.dtype)
+        dv_ref[b] = dv.astype(dv_ref.dtype)
 
-    _for_heads(heads, 2, head)
+    _for_rows(rows, max(1, 2 // (q_ref.shape[-1] // head_dim)), row)
 
 
-def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                              delta_ref, glse_ref, dq_ref, dk_ref, dv_ref,
-                              *, causal: bool, scale: float, blk: int):
+def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                              glse_ref, dq_ref, dk_ref, dv_ref, *,
+                              causal: bool, scale: float, blk: int,
+                              head_dim: int):
     """FA2 backward for sequences past MAX_BWD_SEQ: grid cell = one
-    (batch*head, K-block). The full Q/dO panels are resident; the
-    [BLK, S] score tile for this K-block is recomputed in VMEM; dK/dV
-    write their block, and dQ accumulates in-place across the K-block
-    grid dimension (same output block revisited -> Pallas keeps it in
-    VMEM between consecutive steps)."""
-    j = pl.program_id(1)
+    (batch row, column block, K-block). The full Q/O/dO panels are
+    resident; the [BLK, S] score tile for this K-block is recomputed in
+    VMEM; dK/dV write their block, and dQ accumulates in-place across
+    the K-block grid dimension (same output block revisited -> Pallas
+    keeps it in VMEM between consecutive steps)."""
+    j = pl.program_id(2)
     dq_blk, dk, dv = _flash_bwd_tile(
-        q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
-        glse_ref[0], scale, j * blk if causal else None)
+        q_ref[0], k_ref[0], v_ref[0], o_ref[0], do_ref[0], lse_ref[0],
+        glse_ref[0], scale, j * blk if causal else None, head_dim)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -308,57 +413,58 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_ref[0] += dq_blk
 
 
-def _flash_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool,
-               glse=None):
-    """dq, dk, dv from the saved (o, lse[BH, 1, S]): one whole-tile step
-    for several heads up to MAX_BWD_SEQ, K-blocked past it — scores stay
-    in VMEM tiles at every length the gate admits
+def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
+               interpret: bool, glse=None):
+    """dq, dk, dv [B, S, H*D] from the saved (o, lse[B, H, 1, S]): one
+    whole-tile step for several heads up to MAX_BWD_SEQ, K-blocked past
+    it — scores stay in VMEM tiles at every length the gate admits
     (flash_attention_available caps S at MAX_FLASH_SEQ)."""
-    bh, s, d = q.shape
+    b, s, hd = q.shape
+    d = hd // num_heads
+    hpb = _heads_per_block(num_heads, d)
+    w = hpb * d
     scale = 1.0 / float(d) ** 0.5
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[:, None, :]
     # the ring's merge hands a float32 dO (its o is float32): as an MXU
     # operand it takes the stored dtype, like P and dS
     do = do.astype(q.dtype)
     if glse is None:
-        glse = jnp.zeros((bh, 1, s), jnp.float32)
+        glse = jnp.zeros((b, num_heads, 1, s), jnp.float32)
     if s <= MAX_BWD_SEQ:
-        heads = _heads_per_step(bh, s)
-        seq_spec = pl.BlockSpec((heads, s, d), lambda b: (b, 0, 0))
-        row_spec = pl.BlockSpec((heads, 1, s), lambda b: (b, 0, 0))
+        rows = _rows_per_step(b, hpb, s)
+        seq_spec = pl.BlockSpec((rows, s, w), lambda i, j: (i, 0, j))
+        row_spec = pl.BlockSpec((rows, hpb, 1, s), lambda i, j: (i, j, 0, 0))
         return pl.pallas_call(
             functools.partial(_flash_bwd_kernel, causal=causal,
-                              scale=scale, heads=heads),
+                              scale=scale, rows=rows, head_dim=d),
             name=KERNEL_NAME_PREFIX + "flash_bwd",
-            out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-                       jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                       jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
-            grid=(bh // heads,),
-            in_specs=[seq_spec, seq_spec, seq_spec, seq_spec, row_spec,
+            out_shape=(jax.ShapeDtypeStruct((b, s, hd), q.dtype),
+                       jax.ShapeDtypeStruct((b, s, hd), k.dtype),
+                       jax.ShapeDtypeStruct((b, s, hd), v.dtype)),
+            grid=(b // rows, num_heads // hpb),
+            in_specs=[seq_spec, seq_spec, seq_spec, seq_spec, seq_spec,
                       row_spec, row_spec],
             out_specs=(seq_spec, seq_spec, seq_spec),
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
-        )(q, k, v, do, lse, delta, glse)
+        )(q, k, v, o, do, lse, glse)
     blk = _kv_block(s)
-    seq_spec = pl.BlockSpec((1, s, d), lambda b, j: (b, 0, 0))
-    kblk_spec = pl.BlockSpec((1, blk, d), lambda b, j: (b, j, 0))
-    row_spec = pl.BlockSpec((1, 1, s), lambda b, j: (b, 0, 0))
+    seq_spec = pl.BlockSpec((1, s, w), lambda b, c, j: (b, 0, c))
+    kblk_spec = pl.BlockSpec((1, blk, w), lambda b, c, j: (b, j, c))
+    row_spec = pl.BlockSpec((1, hpb, 1, s), lambda b, c, j: (b, c, 0, 0))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_blocked_kernel, causal=causal,
-                          scale=scale, blk=blk),
+                          scale=scale, blk=blk, head_dim=d),
         name=KERNEL_NAME_PREFIX + "flash_bwd_blocked",
-        out_shape=(jax.ShapeDtypeStruct((bh, s, d), jnp.float32),  # dq acc
-                   jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
-        grid=(bh, s // blk),
-        in_specs=[seq_spec, kblk_spec, kblk_spec, seq_spec, row_spec,
+        out_shape=(jax.ShapeDtypeStruct((b, s, hd), jnp.float32),  # dq acc
+                   jax.ShapeDtypeStruct((b, s, hd), k.dtype),
+                   jax.ShapeDtypeStruct((b, s, hd), v.dtype)),
+        grid=(b, num_heads // hpb, s // blk),
+        in_specs=[seq_spec, kblk_spec, kblk_spec, seq_spec, seq_spec,
                   row_spec, row_spec],
         out_specs=(seq_spec, kblk_spec, kblk_spec),
         interpret=interpret,
         compiler_params=_FLASH_COMPILER_PARAMS,
-    )(q, k, v, do, lse, delta, glse)
+    )(q, k, v, o, do, lse, glse)
     return dq.astype(q.dtype), dk, dv
 
 
@@ -382,46 +488,50 @@ def _xla_attention_lse(q, k, v, causal: bool):
     return o, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal, interpret):
-    return _flash_fwd(q, k, v, causal, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, num_heads, causal, interpret):
+    return _flash_fwd(q, k, v, num_heads, causal, interpret)[0]
 
 
-def _flash_vjp_fwd(q, k, v, causal, interpret):
-    o, lse = _flash_fwd(q, k, v, causal, interpret)
+def _flash_vjp_fwd(q, k, v, num_heads, causal, interpret):
+    o, lse = _flash_fwd(q, k, v, num_heads, causal, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, interpret, res, g):
+def _flash_vjp_bwd(num_heads, causal, interpret, res, g):
     q, k, v, o, lse = res
-    return _flash_bwd(q, k, v, o, lse, g, causal, interpret)
+    return _flash_bwd(q, k, v, o, lse, g, num_heads, causal, interpret)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def flash_attention_lse(q, k, v, causal, interpret):
-    """Flash attention returning (o, lse[BH, S]) — the streaming-merge
-    primitive ring attention accumulates per K/V block. Differentiable:
-    the backward kernel carries the upstream lse gradient (the merge
-    weights are functions of lse). q,k,v: [BH, S, D]. ``o`` is emitted in
-    f32: the ring merge accumulates in f32, and rounding each block's
-    normalized output to bf16 first would compound per-block error."""
-    o, lse = _flash_fwd(q, k, v, causal, interpret, out_dtype=jnp.float32)
-    return o, lse[:, 0, :]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention_lse(q, k, v, num_heads, causal, interpret):
+    """Flash attention returning (o [B, S, H*D], lse [B, H, S]) — the
+    streaming-merge primitive ring attention accumulates per K/V block.
+    Differentiable: the backward kernel carries the upstream lse
+    gradient (the merge weights are functions of lse). q, k, v:
+    [B, S, H*D]. ``o`` is emitted in f32: the ring merge accumulates in
+    f32, and rounding each block's normalized output to bf16 first would
+    compound per-block error."""
+    o, lse = _flash_fwd(q, k, v, num_heads, causal, interpret,
+                        out_dtype=jnp.float32)
+    return o, lse[:, :, 0, :]
 
 
-def _flash_lse_vjp_fwd(q, k, v, causal, interpret):
-    o, lse = _flash_fwd(q, k, v, causal, interpret, out_dtype=jnp.float32)
-    return (o, lse[:, 0, :]), (q, k, v, o, lse)
+def _flash_lse_vjp_fwd(q, k, v, num_heads, causal, interpret):
+    o, lse = _flash_fwd(q, k, v, num_heads, causal, interpret,
+                        out_dtype=jnp.float32)
+    return (o, lse[:, :, 0, :]), (q, k, v, o, lse)
 
 
-def _flash_lse_vjp_bwd(causal, interpret, res, gs):
+def _flash_lse_vjp_bwd(num_heads, causal, interpret, res, gs):
     q, k, v, o, lse = res
     g_o, g_lse = gs
-    glse = g_lse[:, None, :].astype(jnp.float32)
-    return _flash_bwd(q, k, v, o, lse, g_o, causal, interpret, glse=glse)
+    glse = g_lse[:, :, None, :].astype(jnp.float32)
+    return _flash_bwd(q, k, v, o, lse, g_o, num_heads, causal, interpret,
+                      glse=glse)
 
 
 flash_attention_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
@@ -444,45 +554,61 @@ def pallas_mode() -> str:
 MIN_SEQ_FOR_FLASH = 512
 
 
-def flash_shape_legal(seq_len: int, head_dim: int) -> bool:
+def flash_shape_legal(seq_len: int, head_dim: int, num_heads: int) -> bool:
     """The shape half of the flash gate, platform aside: Q-block tile
-    divisibility, lane-aligned head dim, and the VMEM-budget upper
-    bounds past which the compiler refuses the kernels. The native
-    ``kernel_gate`` (native/ffs_strategy.hpp) admits the same shapes."""
+    divisibility, sublane-aligned head dim, the VMEM-budget upper bounds
+    past which the compiler refuses the kernels, and heads that tile the
+    [B, S, H*D] operands' lanes in column blocks. The rule for the last
+    is the BlockSpec's own: a block's lanes are a multiple of 128 or the
+    whole row, so the heads of a block (``_heads_per_block``) have to
+    divide the heads and fill 128 lanes exactly, unless one block is the
+    whole row (H*D <= 128, or a single head). 16 heads of 64 and 4 of
+    128 pass; 3 heads of 64 or 4 of 96 are refused and run the einsum
+    path. The native ``kernel_gate`` (native/ffs_strategy.hpp) admits
+    the same shapes."""
+    if num_heads <= 0:
+        return False
+    hpb = _heads_per_block(num_heads, head_dim)
     return (seq_len % BLK_Q == 0 and head_dim % 8 == 0
-            and seq_len <= MAX_FLASH_SEQ and head_dim <= MAX_FLASH_HEAD_DIM)
+            and seq_len <= MAX_FLASH_SEQ and head_dim <= MAX_FLASH_HEAD_DIM
+            and num_heads % hpb == 0
+            and ((hpb * head_dim) % LANES == 0 or hpb == num_heads))
 
 
-def flash_attention_available(seq_len: int, head_dim: int) -> bool:
+def flash_attention_available(seq_len: int, head_dim: int,
+                              num_heads: int) -> bool:
     mode = pallas_mode()
-    if mode == "off" or not flash_shape_legal(seq_len, head_dim):
+    if mode == "off" or not flash_shape_legal(seq_len, head_dim, num_heads):
         return False
     # interpret mode (tests) exercises any legal shape; on hardware only
     # take over where the kernel beats XLA
     return mode == "interpret" or seq_len >= MIN_SEQ_FOR_FLASH
 
 
-def flash_attention(q, k, v, causal: bool = False):
-    """q,k,v: [B, H, S, D] → [B, H, S, D]. Caller checks
-    flash_attention_available first; self-attention only (Sq == Sk)."""
-    b, h, s, d = q.shape
-    interpret = pallas_mode() == "interpret"
-    fold = lambda x: x.reshape(b * h, x.shape[2], d)
-    o = _flash(fold(q), fold(k), fold(v), causal, interpret)
-    return o.reshape(b, h, s, d)
+def flash_attention(q, k, v, num_heads: int, causal: bool = False):
+    """q, k, v: [B, S, H*D] -> [B, S, H*D], the heads side by side along
+    the lanes as the projections' plain 2-D products leave them, so that
+    no layout change sits between a projection and a kernel and no
+    operand's minor dimension is narrower than a vreg. Caller checks
+    flash_attention_available first; self-attention only (Sq == Sk).
+    Who holds [B, H, S, D] converts with ``merge_heads`` /
+    ``split_heads`` at its own boundary."""
+    return _flash(q, k, v, num_heads, causal, pallas_mode() == "interpret")
 
 
-def flash_attention_sharded(q, k, v, mesh, batch_axis=None, head_axis=None,
-                            causal: bool = False):
+def flash_attention_sharded(q, k, v, num_heads: int, mesh, batch_axis=None,
+                            head_axis=None, causal: bool = False):
     """Flash attention inside a GSPMD-sharded jit: a bare ``pallas_call``
     is an unpartitionable custom call to the partitioner, so wrap it in
     ``shard_map`` over the mesh axes the batch/head dims are sharded on —
-    each device runs the kernel on its local [B/dp, H/mp, S, D] block
-    (scores never cross shards; no collectives needed). Axes not named
-    stay replicated, which GSPMD enforces on entry."""
+    each device runs the kernel on its local [B/dp, S, (H/mp)*D] block
+    (scores never cross shards; no collectives needed; the caller keeps
+    a head axis only where the local heads still tile the lanes). Axes
+    not named stay replicated, which GSPMD enforces on entry."""
     from jax.sharding import PartitionSpec as P
 
-    spec = P(batch_axis, head_axis, None, None)
-    fn = functools.partial(flash_attention, causal=causal)
+    spec = P(batch_axis, None, head_axis)
+    local = num_heads // (mesh.shape[head_axis] if head_axis else 1)
+    fn = functools.partial(flash_attention, num_heads=local, causal=causal)
     return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)

@@ -106,7 +106,7 @@ def _flash_was_enumerable(op, comp_mode) -> bool:
     try:
         _, s, _ = op.input_shapes[0]
         sk = op.input_shapes[1][1] if len(op.input_shapes) > 1 else s
-        return (sk == s and flash_shape_legal(s, op.head_dim)
+        return (sk == s and flash_shape_legal(s, op.head_dim, op.num_heads)
                 and not (comp_mode == CompMode.TRAINING and op.dropout > 0))
     except Exception:
         return False

@@ -74,21 +74,22 @@ def _ring_attention_local(q, k, v, axis_name: str, causal: bool):
     """
     from flexflow_tpu.ops.pallas_kernels import (flash_attention_available,
                                                  flash_attention_lse,
-                                                 pallas_mode)
+                                                 merge_heads, pallas_mode,
+                                                 split_heads)
 
     n = jax.lax.psum(1, axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
     b, h, sq, d = q.shape
-    if flash_attention_available(sq, d) and sq == k.shape[2]:
+    if flash_attention_available(sq, d, h) and sq == k.shape[2]:
         interpret = pallas_mode() == "interpret"
-        fold = lambda x: x.reshape(b * h, x.shape[2], x.shape[3])
 
         def _run(q_, k_, v_, blk_causal):
-            o, lse = flash_attention_lse(fold(q_), fold(k_), fold(v_),
-                                         blk_causal, interpret)
-            return (o.astype(jnp.float32).reshape(b, h, sq, d),
-                    lse.reshape(b, h, sq))
+            # the kernels take [B, S, H*D]; the ring holds [B, H, S, D]
+            o, lse = flash_attention_lse(merge_heads(q_), merge_heads(k_),
+                                         merge_heads(v_), h, blk_causal,
+                                         interpret)
+            return split_heads(o.astype(jnp.float32), h), lse
 
         def block(k_cur, v_cur, kv_idx):
             if not causal:

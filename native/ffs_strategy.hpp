@@ -176,8 +176,9 @@ inline const char* kernel_default_impl(const Node& n, const Choice& c) {
 
 // Structural legality of a kernel alternative on `n`: "" = legal, else a
 // named rejection reason recorded in the search trace (the flash gate
-// mirrors ops/pallas_kernels.flash_attention_available — Q-block tile
-// divisibility and lane-aligned head dim; conv_bn_fused mirrors the
+// mirrors ops/pallas_kernels.flash_shape_legal — Q-block tile
+// divisibility, sublane-aligned head dim and heads that tile the lanes
+// of the kernels' [B, S, H*D] operands; conv_bn_fused mirrors the
 // layout.py fold eligibility shipped as the `bn_fusable` node attr).
 inline std::string kernel_gate(const Node& n, const std::string& impl,
                                bool training = true) {
@@ -200,6 +201,15 @@ inline std::string kernel_gate(const Node& n, const std::string& impl,
     // them the executor runs einsum, so pricing flash would misrank
     if (seq > 16384) return "seq_exceeds_flash_vmem_budget_16384";
     if (head_dim > 128) return "head_dim_exceeds_flash_vmem_budget_128";
+    // the kernels take [B, S, H*D] operands in column blocks of the heads
+    // that fill 128 lanes (pallas_kernels._heads_per_block): those have
+    // to divide the heads and fill the lanes exactly, unless one block
+    // is the whole row
+    int64_t per_block = std::min<int64_t>(
+        heads, std::max<int64_t>(1, 128 / std::max<int64_t>(head_dim, 1)));
+    if (heads % per_block ||
+        ((per_block * head_dim) % 128 && per_block != heads))
+      return "heads_do_not_tile_128_lanes";
     // attention-prob dropout has no flash lowering (the kernel never
     // materializes the probabilities to drop) — training forwards take
     // the einsum path, so pricing flash would be a priced-vs-executed

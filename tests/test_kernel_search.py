@@ -38,22 +38,24 @@ _MACHINE = {"num_devices": 8, "flops": 197e12, "hbm_bw": 0.82e12,
             "comm_bytes_factor": 0.5}
 
 
-def _attn_linear_nodes(seq=512):
-    """One self-attention (flash-legal at seq=512, 128|seq) + one
-    Linear — the minimal graph every kernel dimension shows up on."""
+def _attn_linear_nodes(seq=512, heads=8, head_dim=16):
+    """One self-attention (flash-legal at seq=512, 128|seq; 8 heads of
+    16 are one column block of 128 lanes) + one Linear — the minimal
+    graph every kernel dimension shows up on."""
+    e = heads * head_dim
     return [
         dict(guid=1, type="MULTIHEAD_ATTENTION", name="attn",
              inputs=[[-1, 0], [-1, 0], [-1, 0]],
-             input_shapes=[[8, seq, 128]] * 3,
-             output_shapes=[[8, seq, 128]],
+             input_shapes=[[8, seq, e]] * 3,
+             output_shapes=[[8, seq, e]],
              roles=[["sample", "seq", "channel"]],
-             params={"wq": [8, 128, 16], "wk": [8, 128, 16],
-                     "wv": [8, 128, 16], "wo": [8, 16, 128]},
-             flops=1e9, dtype_size=4, attrs={"num_heads": 8}),
+             params={"wq": [heads, e, head_dim], "wk": [heads, e, head_dim],
+                     "wv": [heads, e, head_dim], "wo": [heads, head_dim, e]},
+             flops=1e9, dtype_size=4, attrs={"num_heads": heads}),
         dict(guid=2, type="LINEAR", name="fc", inputs=[[1, 0]],
-             input_shapes=[[8, seq, 128]], output_shapes=[[8, seq, 128]],
+             input_shapes=[[8, seq, e]], output_shapes=[[8, seq, e]],
              roles=[["sample", "seq", "channel"]],
-             params={"kernel": [128, 128], "bias": [128]},
+             params={"kernel": [e, e], "bias": [e]},
              flops=1e9, dtype_size=4, attrs={}),
     ]
 
@@ -123,8 +125,32 @@ class TestNativeEnumeration:
         assert not any("_k:flash" in c["choice"]
                        for c in ops["attn"]["candidates"])
         # the Python gate refuses what the native gate refuses
-        assert not flash_shape_legal(seq, 16)
-        assert flash_shape_legal(16384, 16)
+        assert not flash_shape_legal(seq, 16, 8)
+        assert flash_shape_legal(16384, 16, 8)
+
+    @pytest.mark.parametrize("heads,head_dim,legal", [
+        # both benchmark shapes; a whole row as one block
+        (16, 64, True), (4, 128, True), (1, 64, True), (4, 8, True),
+        # an odd head out; lanes the heads of a block do not fill
+        (3, 64, False), (4, 96, False), (24, 8, False),
+    ])
+    def test_heads_that_do_not_tile_the_lanes_are_rejected(self, heads,
+                                                           head_dim, legal):
+        """The kernels take [B, S, H*D] in column blocks of 128 lanes:
+        the native gate and `flash_shape_legal` admit the same set."""
+        from flexflow_tpu.ops.pallas_kernels import flash_shape_legal
+        native = _native()
+        resp = native.native_optimize(_req(_attn_linear_nodes(
+            heads=heads, head_dim=head_dim)))
+        ops = {o["name"]: o for o in resp["search_trace"]["ops"]}
+        rej = {r["impl"]: r["reason"]
+               for r in ops["attn"].get("kernel_rejections") or []}
+        twins = any("_k:flash" in c["choice"]
+                    for c in ops["attn"]["candidates"])
+        assert flash_shape_legal(512, head_dim, heads) == legal
+        assert twins == legal
+        assert rej.get("flash") == (
+            None if legal else "heads_do_not_tile_128_lanes")
 
     def test_dropout_attention_rejects_flash(self):
         """Attention-prob dropout has no flash lowering: the training

@@ -23,6 +23,17 @@ def qkv(b=4, h=2, s=32, d=8, seed=0):
     return mk(), mk(), mk()
 
 
+def flash_heads_first(q, k, v, causal, run=None):
+    """The kernels take [B, S, H*D]; these tests hold [B, H, S, D] like
+    the einsum core they compare with, and convert at the boundary."""
+    from flexflow_tpu.ops.pallas_kernels import (flash_attention,
+                                                 merge_heads, split_heads)
+    h = q.shape[1]
+    run = run or flash_attention
+    return split_heads(run(merge_heads(q), merge_heads(k), merge_heads(v),
+                           h, causal=causal), h)
+
+
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_dense_attention(self, causal):
@@ -69,22 +80,19 @@ class TestFlashAttention:
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_dense(self, causal):
-        from flexflow_tpu.ops.pallas_kernels import (flash_attention,
-                                                     flash_attention_available)
+        from flexflow_tpu.ops.pallas_kernels import flash_attention_available
 
-        assert flash_attention_available(256, 8)
+        assert flash_attention_available(256, 8, 2)
         q, k, v = qkv(b=2, h=2, s=256, d=8, seed=1)
         want = scaled_dot_product_attention(q, k, v, causal=causal)
-        got = flash_attention(q, k, v, causal=causal)
+        got = flash_heads_first(q, k, v, causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-5)
 
     def test_backward_matches_dense(self):
-        from flexflow_tpu.ops.pallas_kernels import flash_attention
-
         q, k, v = qkv(b=1, h=2, s=128, d=8, seed=2)
         g1 = jax.grad(lambda q: jnp.sum(
-            flash_attention(q, k, v, causal=True) ** 2))(q)
+            flash_heads_first(q, k, v, True) ** 2))(q)
         g2 = jax.grad(lambda q: jnp.sum(
             scaled_dot_product_attention(q, k, v, causal=True) ** 2))(q)
         np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
@@ -93,7 +101,7 @@ class TestFlashAttention:
     def test_unavailable_for_ragged_seq(self):
         from flexflow_tpu.ops.pallas_kernels import flash_attention_available
 
-        assert not flash_attention_available(100, 8)  # S % 128 != 0
+        assert not flash_attention_available(100, 8, 2)  # S % 128 != 0
 
     def test_sharded_flash_on_dp_mp_mesh(self):
         # round-1 advisor finding: a bare pallas_call inside a GSPMD jit is
@@ -104,9 +112,13 @@ class TestFlashAttention:
         mesh = make_mesh(8, {"data": 2, "model": 4})
         q, k, v = qkv(b=2, h=4, s=128, d=8, seed=3)
         want = scaled_dot_product_attention(q, k, v, causal=True)
-        got = jax.jit(lambda q, k, v: flash_attention_sharded(
-            q, k, v, mesh, batch_axis="data", head_axis="model",
-            causal=True))(q, k, v)
+        # [2, 128, 4*8] with the heads' lanes over 'model': a shard holds
+        # one head, its whole row one column block
+        sharded = lambda q, k, v, h, causal: flash_attention_sharded(
+            q, k, v, h, mesh, batch_axis="data", head_axis="model",
+            causal=causal)
+        got = jax.jit(lambda q, k, v: flash_heads_first(
+            q, k, v, True, run=sharded))(q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-5)
 
@@ -226,18 +238,22 @@ class TestRingFlashInner:
         """flash_attention_lse's lse output and its gradient path."""
         from flexflow_tpu.ops.pallas_kernels import flash_attention_lse
 
+        # [B, S, H*D] with two heads of 8: o comes back in that form,
+        # lse as [B, H, S]
         rs = np.random.RandomState(0)
-        q = jnp.asarray(rs.randn(2, 128, 8).astype(np.float32))
-        k = jnp.asarray(rs.randn(2, 128, 8).astype(np.float32))
-        v = jnp.asarray(rs.randn(2, 128, 8).astype(np.float32))
+        q = jnp.asarray(rs.randn(1, 128, 16).astype(np.float32))
+        k = jnp.asarray(rs.randn(1, 128, 16).astype(np.float32))
+        v = jnp.asarray(rs.randn(1, 128, 16).astype(np.float32))
 
         def ref(q, k, v):
-            s = jnp.einsum("bqd,bkd->bqk", q, k) / jnp.sqrt(jnp.float32(8))
+            q, k, v = (x.reshape(1, 128, 2, 8) for x in (q, k, v))
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
+                jnp.float32(8))
             lse = jax.scipy.special.logsumexp(s, axis=-1)
-            o = jnp.einsum("bqk,bkd->bqd", jnp.exp(s - lse[..., None]), v)
-            return o, lse
+            o = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v)
+            return o.reshape(1, 128, 16), lse
 
-        o, lse = flash_attention_lse(q, k, v, False, True)
+        o, lse = flash_attention_lse(q, k, v, 2, False, True)
         o_r, lse_r = ref(q, k, v)
         np.testing.assert_allclose(np.asarray(o), np.asarray(o_r),
                                    rtol=1e-4, atol=1e-5)
@@ -245,8 +261,9 @@ class TestRingFlashInner:
                                    rtol=1e-5, atol=1e-5)
         # gradient including the lse output (the ring-merge dependency)
         f = lambda q, k, v: (
-            jnp.sum(flash_attention_lse(q, k, v, False, True)[0] ** 2)
-            + jnp.sum(jnp.sin(flash_attention_lse(q, k, v, False, True)[1])))
+            jnp.sum(flash_attention_lse(q, k, v, 2, False, True)[0] ** 2)
+            + jnp.sum(jnp.sin(
+                flash_attention_lse(q, k, v, 2, False, True)[1])))
         fr = lambda q, k, v: (jnp.sum(ref(q, k, v)[0] ** 2)
                               + jnp.sum(jnp.sin(ref(q, k, v)[1])))
         g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
@@ -267,7 +284,7 @@ class TestRingFlashInner:
         q = jnp.asarray(rs.randn(1, s, 8).astype(np.float32))
         k = jnp.asarray(rs.randn(1, s, 8).astype(np.float32))
         v = jnp.asarray(rs.randn(1, s, 8).astype(np.float32))
-        f = lambda q, k, v: jnp.sum(_flash(q, k, v, causal, True) ** 2)
+        f = lambda q, k, v: jnp.sum(_flash(q, k, v, 1, causal, True) ** 2)
         fr = lambda q, k, v: jnp.sum(_xla_attention(q, k, v, causal) ** 2)
         g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(fr, argnums=(0, 1, 2))(q, k, v)

@@ -17,6 +17,7 @@ four-chip call.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -74,26 +75,62 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _flash_grads(q, k, v):
-    def loss(q, k, v):
-        return pk._flash(q, k, v, False, False).astype(jnp.float32).sum()
-    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+def _flash_grads(heads):
+    """Gradients through the kernels of q, k, v [B, S, heads * D]."""
+    def grads(q, k, v):
+        def loss(q, k, v):
+            return pk._flash(q, k, v, heads, False, False).astype(
+                jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return grads
 
 
-def _flash_lse_grads(q, k, v):
-    def loss(q, k, v):
-        o, lse = pk.flash_attention_lse(q, k, v, False, False)
-        return o.sum() + lse.sum()
-    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+def _flash_lse_grads(heads):
+    def grads(q, k, v):
+        def loss(q, k, v):
+            o, lse = pk.flash_attention_lse(q, k, v, heads, False, False)
+            return o.sum() + lse.sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return grads
+
+
+_BYTES = {"bf16": 2, "f32": 4}
+_ARRAY = re.compile(r"(bf16|f32)\[([\d,]+)\]\{([\d,]+)")
+
+
+def layout_faults(hlo, big, weights=()):
+    """What the [B, S, H*D] operand form exists to remove from a compiled
+    step: `copy` instructions whose result holds ``big`` bytes or more (a
+    whole q, k, v or o changing layout; a result shaped like one of
+    ``weights`` is a parameter's copy and none of this), and operands or
+    results of a flash kernel whose minor dimension is narrower than the
+    128 lanes it is padded to in HBM."""
+    faults = []
+    weights = {",".join(map(str, w)) for w in weights}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (bf16|f32)\[([\d,]+)\]"
+                     r"\S* copy\(", line)
+        if m and m.group(3) not in weights and (
+                int(np.prod([int(d) for d in m.group(3).split(",")]))
+                * _BYTES[m.group(2)] >= big):
+            faults.append(f"copy {m.group(1)} {m.group(2)}[{m.group(3)}]")
+        if ('custom_call_target="tpu_custom_call"' in line
+                and "flash_" in line.split("metadata=")[-1][:200]):
+            for dt, dims, layout in _ARRAY.findall(line.split("metadata=")[0]):
+                dims = [int(d) for d in dims.split(",")]
+                if dims[int(layout.split(",")[0])] < pk.LANES:
+                    faults.append(f"flash operand {dt}{dims}{{{layout}}}")
+    return faults
 
 
 class TestFlashKernels:
     def test_bert_shape_fwd_bwd(self, topo):
-        q = jax.ShapeDtypeStruct((128, 512, 64), jnp.bfloat16,
+        q = jax.ShapeDtypeStruct((8, 512, 16 * 64), jnp.bfloat16,
                                  sharding=SingleDeviceSharding(
                                      topo.devices[0]))
-        hlo = _compile(_flash_grads, q, q, q)
+        hlo = _compile(_flash_grads(16), q, q, q)
         assert pallas_kernel_count(hlo) == 2
+        assert layout_faults(hlo, q.size * 2) == []
         # the kernels' names, in the custom calls' `op_name`
         assert "tpu_custom_call_flash_fwd" in hlo
         assert "tpu_custom_call_flash_bwd" in hlo
@@ -111,23 +148,27 @@ class TestFlashKernels:
         q = jax.ShapeDtypeStruct(
             (1, pk.MAX_FLASH_SEQ, pk.MAX_FLASH_HEAD_DIM), dtype,
             sharding=SingleDeviceSharding(topo.devices[0]))
-        assert pallas_kernel_count(_compile(grads, q, q, q)) == 2
+        assert pallas_kernel_count(_compile(grads(1), q, q, q)) == 2
 
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-    @pytest.mark.parametrize("bh,seq,head_dim", [
-        # whole-tile kernels: 8 heads a step, then 2 at their longest
-        (16, 512, 128), (16, pk.MAX_BWD_SEQ, 128),
-        # K-blocked backward at its widest block, narrow and wide heads
-        (4, 2 * pk.MAX_BWD_SEQ, 64), (4, 8192, 128),
+    @pytest.mark.parametrize("batch,heads,seq,head_dim", [
+        # whole-tile kernels: 8 heads a step, then 2 at their longest,
+        # one head a column block and two
+        (16, 1, 512, 128), (16, 1, pk.MAX_BWD_SEQ, 128),
+        (4, 4, 512, 64), (2, 2, pk.MAX_BWD_SEQ, 64),
+        # K-blocked backward at its widest block, two heads of 64 a
+        # column block and the nemotron cell's four of 128
+        (2, 2, 2 * pk.MAX_BWD_SEQ, 64), (1, 4, 8192, 128),
     ])
-    def test_tiles_derived_from_shape_and_dtype_compile(self, topo, bh, seq,
-                                                        head_dim, dtype):
-        """Heads a step and K/V rows a block follow from (S, D): each
-        choice at its largest footprint, under the same VMEM budget."""
-        q = jax.ShapeDtypeStruct((bh, seq, head_dim), dtype,
+    def test_tiles_derived_from_shape_and_dtype_compile(
+            self, topo, batch, heads, seq, head_dim, dtype):
+        """Heads a column block, batch rows a step and K/V rows a block
+        follow from (S, H, D): each choice at its largest footprint,
+        under the same VMEM budget."""
+        q = jax.ShapeDtypeStruct((batch, seq, heads * head_dim), dtype,
                                  sharding=SingleDeviceSharding(
                                      topo.devices[0]))
-        hlo = _compile(_flash_lse_grads, q, q, q)
+        hlo = _compile(_flash_lse_grads(heads), q, q, q)
         assert pallas_kernel_count(hlo) == 2
         whole = seq <= pk.MAX_BWD_SEQ
         assert ("tpu_custom_call_flash_fwd_whole" in hlo) == whole
@@ -142,14 +183,14 @@ class TestFlashKernels:
         names = ("jax_compilation_cache_dir",
                  "jax_include_full_tracebacks_in_locations")
         prev = {n: getattr(jax.config, n) for n in names}
-        q = jax.ShapeDtypeStruct((16, 512, 64), jnp.bfloat16,
+        q = jax.ShapeDtypeStruct((1, 512, 16 * 64), jnp.bfloat16,
                                  sharding=SingleDeviceSharding(
                                      topo.devices[0]))
         try:
             configure_compile_cache()
-            here = jax.jit(_flash_grads).lower(q, q, q).as_text()
+            here = jax.jit(_flash_grads(16)).lower(q, q, q).as_text()
             jax.clear_caches()
-            there = jax.jit(_flash_grads).lower(q, q, q).as_text()
+            there = jax.jit(_flash_grads(16)).lower(q, q, q).as_text()
         finally:
             for n, v in prev.items():
                 jax.config.update(n, v)
@@ -157,9 +198,12 @@ class TestFlashKernels:
 
     def test_gate_refuses_one_past_each_bound(self, on_tpu):
         ok = pk.flash_attention_available
-        assert ok(pk.MAX_FLASH_SEQ, pk.MAX_FLASH_HEAD_DIM)
-        assert not ok(pk.MAX_FLASH_SEQ + pk.BLK_Q, 64)
-        assert not ok(512, pk.MAX_FLASH_HEAD_DIM + 8)
+        assert ok(pk.MAX_FLASH_SEQ, pk.MAX_FLASH_HEAD_DIM, 1)
+        assert not ok(pk.MAX_FLASH_SEQ + pk.BLK_Q, 64, 2)
+        assert not ok(512, pk.MAX_FLASH_HEAD_DIM + 8, 1)
+        # both cells' shapes, and heads that do not tile the lanes
+        assert ok(512, 64, 16) and ok(8192, 128, 4)
+        assert not ok(512, 64, 3) and not ok(512, 96, 4)
 
     def test_ring_attention_4way(self, topo, on_tpu):
         from flexflow_tpu.parallel.ring_attention import ring_attention
@@ -328,6 +372,39 @@ def test_wus_step_with_fused_update_compiles_for_four_chips(topo, on_tpu):
     assert pallas_kernel_count(hlo) > 2 * flash
     # the scopes that tell the optimizer and the loss from the layers
     assert "/optimizer_update/" in hlo and "/jvp(loss)/" in hlo
+    # on each chip q, k, v, o reach the kernels as the projections wrote
+    # them: no copy of a [8, 512, 1024] array, no 64-wide minor dimension
+    # (the FFN kernels are as large there, and are copied as parameters)
+    weights = {p.shape for p in jax.tree.leaves(ff.params)}
+    assert layout_faults(hlo, 8 * 512 * 1024 * 2, weights) == []
+
+
+def test_one_chip_step_keeps_qkvo_lane_dense(topo, on_tpu):
+    """The searched one-chip step of the `bert_ae` cell at depth 2 and
+    its batch of 32: two named kernels a layer, and between the q/k/v
+    projections and the output projection no XLA pass over a
+    q/k/v/o-sized array. With q, k, v as [b, heads, s, 64] this step
+    held seven `copy` instructions of a bf16[32,16,512,64] a layer
+    (7.9 ms of a 119.7 ms step on the chip, PR 28) and every kernel
+    operand padded 64 lanes to 128."""
+    ff = build_bert(2, 32, chips=1, search_budget=30)
+    assert sum("_k:flash" in c for c in _choices(ff)) == 2
+    hlo = compile_step_for(ff, topo).as_text()
+    kernels = [re.search(r"tpu_custom_call_(\w+)", line.split(
+        "metadata=")[-1]).group(1) for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(kernels) == ["flash_bwd"] * 2 + ["flash_fwd_whole"] * 2
+    assert layout_faults(hlo, 32 * 512 * 1024 * 2) == []
+    # the guard sees the form it guards against
+    assert layout_faults(
+        "  %copy.1 = bf16[32,16,512,64]{3,2,1,0:T(8,128)(2,1)} copy(%x)\n"
+        '  %k = bf16[512,512,64]{2,1,0} custom-call(%a), custom_call_target='
+        '"tpu_custom_call", operand_layout_constraints={bf16[512,512,64]'
+        '{2,1,0}}, metadata={op_name="jvp(tpu_custom_call_flash_fwd_whole)"}',
+        32 * 512 * 1024 * 2) == [
+            "copy copy.1 bf16[32,16,512,64]",
+            "flash operand bf16[512, 512, 64]{2,1,0}",
+            "flash operand bf16[512, 512, 64]{2,1,0}"]
 
 
 # arm -> fewest kernels its step holds: a flash forward and backward per

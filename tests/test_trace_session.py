@@ -301,3 +301,47 @@ def test_compile_phases_and_set_parameter_seconds():
     assert ff.set_parameter_s > first
     assert obs.model_context(ff)["set_parameter_s"] == ff.set_parameter_s
     assert obs.model_context(ff)["compile_phases"] == phases
+
+
+@pytest.mark.parametrize("flash_layers,dropout_layers", [(2, 1), (1, 0),
+                                                         (0, 1)])
+def test_flash_lane_dense_ops_counts_the_models_flash_ops(
+        flash_layers, dropout_layers, tmp_path, monkeypatch, no_open_session):
+    """`executor.flash_lane_dense_ops`: attention ops whose forward
+    called the flash kernels with [B, S, heads*head_dim] operands. It is
+    in the registry's snapshot and in the trace header of a session, and
+    equals the number of the model's ops that run flash in training
+    (an op with attention-probability dropout runs the einsum core)."""
+    import numpy as np
+    from flexflow_tpu import (FFConfig, FFModel, LossType, MetricsType,
+                              SGDOptimizer)
+    from flexflow_tpu.ffconst import OperatorType
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    b, s, e = 2, 128, 32
+    ff = FFModel(FFConfig(batch_size=b))
+    t = ff.create_tensor((b, s, e))
+    for i in range(flash_layers + dropout_layers):
+        t = ff.multihead_attention(
+            t, t, t, e, 4, dropout=0.0 if i < flash_layers else 0.1,
+            name=f"attn{i}")
+    ff.dense(t, 1)
+    ff.compile(SGDOptimizer(lr=0.01), LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
+               [MetricsType.MEAN_SQUARED_ERROR])
+    assert obs.model_context(ff)["flash_lane_dense_ops"] == 0  # not traced
+    rs = np.random.RandomState(0)
+    x = rs.randn(2 * b, s, e).astype(np.float32)
+    y = rs.randn(2 * b, s, 1).astype(np.float32)
+    ff.fit(x, y, epochs=1, verbose=False)   # traces and compiles the step
+    obs.start_trace(str(tmp_path), device=False)
+    ff.fit(x, y, epochs=1, verbose=False)
+    paths = obs.stop_trace()
+    attention = [n.op for n in ff.executor.nodes
+                 if n.op.op_type == OperatorType.MULTIHEAD_ATTENTION]
+    flash = sum(op.selected_impl(training=True) == "flash"
+                for op in attention)
+    assert flash == flash_layers
+    header, _ = read_events(paths["events"])
+    assert header["flash_lane_dense_ops"] == flash
+    gauges = json.load(open(paths["counters"]))["gauges"]
+    assert gauges["executor.flash_lane_dense_ops"] == flash
