@@ -631,6 +631,22 @@ class GraphExecutor:
         return sum(bool(getattr(n.op, "_flash_lane_dense", False))
                    for n in self.nodes)
 
+    def attention_gauges(self) -> Dict[str, int]:
+        """What the attention ops' forwards, as last traced, recorded on
+        the host (PR 31): the ops whose window hides something at their
+        sequence length, and the [Q block, K chunk] tiles a head's flash
+        forward works through against those of the whole square, added
+        up over the ops that ran flash (`kv_blocks`; 0 / 0 until one has
+        been traced). Published as gauges when the train step is traced,
+        in every trace header and in `FFModel.op_counters`."""
+        blocks = [n.op._kv_blocks for n in self.nodes
+                  if getattr(n.op, "_kv_blocks", None)]
+        return {
+            "executor.window_attention_ops": sum(
+                bool(getattr(n.op, "windowed", False)) for n in self.nodes),
+            "attention/kv_blocks_visited": sum(b[0] for b in blocks),
+            "attention/kv_blocks_total": sum(b[1] for b in blocks)}
+
     def _training_nodes(self):
         """Node list the TRAIN step runs: (Conv2D, BatchNorm) pairs whose
         searched kernel choice is ``_k:conv_bn_fused`` execute as one
@@ -699,6 +715,8 @@ class GraphExecutor:
             # attention ops handed the flash kernels [B, S, H*D] operands
             get_registry().gauge("executor.flash_lane_dense_ops",
                                  self.flash_lane_dense_ops())
+            for gauge, value in self.attention_gauges().items():
+                get_registry().gauge(gauge, value)
             # gradient sync over the data axes is inserted by GSPMD here
             # (in bf16 under the master-weight regime — half the bytes).
             # Under WUS the shard constraint turns that all-reduce into a

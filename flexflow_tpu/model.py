@@ -202,13 +202,17 @@ class FFModel:
                             rope: bool = False, rope_theta: float = 10000.0,
                             kernel_initializer=None,
                             seq_parallel: Optional[str] = None,
-                            head_dim: int = 0,
+                            head_dim: int = 0, window: int = 0,
                             name: Optional[str] = None) -> Tensor:
         """``seq_parallel='seq'`` runs the attention core as ring attention
         over that mesh axis (context parallelism for long sequences).
         ``head_dim`` is the width of a head where it is not
         ``embed_dim // num_heads`` (a few wide heads on a model width they
-        do not divide; the softmax scale is ``head_dim ** -0.5``)."""
+        do not divide; the softmax scale is ``head_dim ** -0.5``).
+        ``window`` (with ``causal``): a query sees its last ``window``
+        keys, itself among them, and no key at distance ``window`` or
+        more; the blocked flash kernels skip the blocks that leaves
+        empty."""
         layer = self._add_layer(OperatorType.MULTIHEAD_ATTENTION,
                                 [query, key, value], dict(
             embed_dim=embed_dim, num_heads=num_heads, kdim=kdim or embed_dim,
@@ -217,7 +221,8 @@ class FFModel:
             num_kv_heads=num_kv_heads or num_heads, rope=rope,
             rope_theta=rope_theta,
             kernel_initializer=kernel_initializer, seq_parallel=seq_parallel,
-            **({"head_dim": head_dim} if head_dim else {})), name)
+            **({"head_dim": head_dim} if head_dim else {}),
+            **({"window": window} if window else {})), name)
         return self._finish(layer)
 
     def ssm_mixer(self, input: Tensor, num_heads: int, head_dim: int,
@@ -242,18 +247,28 @@ class FFModel:
                   experts_held: int = 0, expert_offset: int = 0,
                   routed_scaling: float = 1.0, norm_topk: bool = True,
                   slot_slack: float = 0.5, kernel_initializer=None,
+                  scoring: str = "sigmoid", gated: bool = False,
+                  router_input: Optional[Tensor] = None,
                   name: Optional[str] = None) -> Tensor:
-        """Dropless mixture-of-experts layer over [B, S, D] with sigmoid
-        top-k routing over all ``n_experts``, computing the part of the
+        """Dropless mixture-of-experts layer over [B, S, D] with top-k
+        routing over all ``n_experts``, computing the part of the
         ``experts_held`` experts from ``expert_offset`` (all of them by
-        default) and a shared expert (ops/experts.py ``MoELayer``)."""
-        layer = self._add_layer(OperatorType.MOE_LAYER, [input], dict(
-            n_experts=n_experts, k=k, hidden_size=hidden_size,
-            shared_width=shared_width,
-            experts_held=experts_held or n_experts,
-            expert_offset=expert_offset, routed_scaling=routed_scaling,
-            norm_topk=norm_topk, slot_slack=slot_slack,
-            kernel_initializer=kernel_initializer), name)
+        default) and a shared expert (ops/experts.py ``MoELayer``).
+        ``scoring``: "sigmoid" scores with a score-correction bias, or
+        "softmax" over the chosen logits; ``gated``: experts of three
+        matrices, down(relu(gate(x)) * up(x)); ``router_input``: a second
+        tensor of the input's shape that the router reads instead."""
+        extra = {k_: v for k_, v in (("scoring", scoring), ("gated", gated))
+                 if v not in ("sigmoid", False)}
+        layer = self._add_layer(
+            OperatorType.MOE_LAYER,
+            [input] + ([router_input] if router_input is not None else []),
+            dict(n_experts=n_experts, k=k, hidden_size=hidden_size,
+                 shared_width=shared_width,
+                 experts_held=experts_held or n_experts,
+                 expert_offset=expert_offset, routed_scaling=routed_scaling,
+                 norm_topk=norm_topk, slot_slack=slot_slack,
+                 kernel_initializer=kernel_initializer, **extra), name)
         return self._finish(layer)
 
     # ---- elementwise -------------------------------------------------------
@@ -1382,6 +1397,8 @@ class FFModel:
         # their forwards recorded on the host
         self.op_counters["executor.flash_lane_dense_ops"] = float(
             self.executor.flash_lane_dense_ops())
+        self.op_counters.update(
+            (k, float(v)) for k, v in self.executor.attention_gauges().items())
 
     def fit(self, x=None, y=None, batch_size: Optional[int] = None,
             epochs: Optional[int] = None, verbose: bool = True,

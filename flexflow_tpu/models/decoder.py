@@ -13,6 +13,13 @@ family.
     -   x + mlp(rms_norm(x))              down(relu(up(x))^2), no gate
     L   the Llama block: rotary GQA attention, then a SwiGLU MLP, each
         behind its own norm and residual (models/llama.py builds on it)
+    G   attention, then experts, each behind its own norm and residual:
+        full causal GQA with NO position embedding; the experts' router
+        reads the PRE-attention norm (so the choice is known while
+        attention runs), softmax over the chosen logits, gated ReLU
+        experts down(relu(gate(x)) * up(x)), no shared expert
+    W   the same with a sliding window of ``sliding_window_size`` keys
+        and rotary embedding (``rope_theta``) over the whole head
 
 After the last block ``rms_norm`` and the head, ``logits = x W_head``
 (untied); train with ``SPARSE_CATEGORICAL_CROSSENTROPY`` on labels
@@ -47,7 +54,8 @@ class DecoderConfig:
     num_attention_heads: int = 4
     num_key_value_heads: int = 2
     head_dim: int = 0                       # 0: hidden_size // heads
-    rope_theta: float = 10000.0             # `L` only
+    rope_theta: float = 10000.0             # `L`, `W`
+    sliding_window_size: int = 0            # `W`
     # Mamba-2 mixer (`M`)
     mamba_num_heads: int = 4
     mamba_head_dim: int = 16
@@ -68,6 +76,7 @@ class DecoderConfig:
     experts_held: int = 0                   # 0: all of them
     expert_offset: int = 0
     slot_slack: float = 0.5
+    moe_ffn_hidden_size: int = 32           # expert width of `G`, `W`
     # dense MLP (`-`, `L`)
     intermediate_size: int = 128
     batch_size: int = 2
@@ -75,12 +84,31 @@ class DecoderConfig:
     seq_parallel: Optional[str] = None      # 'seq': ring attention
 
 
-def _attention(ff, h, cfg, name, rope):
+def _attention(ff, h, cfg, name, rope, window=0):
     return ff.multihead_attention(
         h, h, h, cfg.hidden_size, cfg.num_attention_heads, bias=False,
         causal=True, num_kv_heads=cfg.num_key_value_heads, rope=rope,
         rope_theta=cfg.rope_theta, seq_parallel=cfg.seq_parallel,
-        head_dim=cfg.head_dim, name=name)
+        head_dim=cfg.head_dim, window=window, name=name)
+
+
+def _attention_experts_block(ff, t, i, cfg, windowed):
+    """`G` / `W`: x' = x + attention(h), x'' = x' + experts(norm(x'))
+    with the experts chosen from h = norm(x), the attention's input."""
+    eps = cfg.layer_norm_epsilon
+    h = ff.rms_norm(t, eps=eps, name=f"b{i}_norm")
+    window = cfg.sliding_window_size if windowed else 0
+    t = ff.add(t, _attention(ff, h, cfg, f"b{i}_attn", rope=windowed,
+                             window=window), name=f"b{i}_res1")
+    g = ff.rms_norm(t, eps=eps, name=f"b{i}_post_norm")
+    m = ff.moe_layer(
+        g, cfg.n_routed_experts, cfg.num_experts_per_tok,
+        cfg.moe_ffn_hidden_size, experts_held=cfg.experts_held,
+        expert_offset=cfg.expert_offset,
+        routed_scaling=cfg.routed_scaling_factor,
+        norm_topk=cfg.norm_topk_prob, slot_slack=cfg.slot_slack,
+        scoring="softmax", gated=True, router_input=h, name=f"b{i}_mixer")
+    return ff.add(t, m, name=f"b{i}_res2")
 
 
 def _llama_block(ff, t, i, cfg):
@@ -127,7 +155,7 @@ def _mixer(ff, h, letter, i, cfg):
         return ff.dense(ff.multiply(up, up, name=f"b{i}_sq"),
                         cfg.hidden_size, use_bias=False, name=name)
     raise ValueError(f"decoder pattern: unknown block letter {letter!r} "
-                     f"(known: M E * - L)")
+                     f"(known: M E * - L G W)")
 
 
 def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
@@ -139,6 +167,9 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
     for i, letter in enumerate(cfg.hybrid_override_pattern):
         if letter == "L":
             t = _llama_block(ff, t, i, cfg)
+            continue
+        if letter in "GW":
+            t = _attention_experts_block(ff, t, i, cfg, letter == "W")
             continue
         h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"b{i}_norm")
         t = ff.add(t, _mixer(ff, h, letter, i, cfg), name=f"b{i}_res")

@@ -308,6 +308,10 @@ def model_context(ff) -> Dict[str, Any]:
         # their [B, S, heads*head_dim] operand form (0 until a step or a
         # forward has been traced)
         flash_lane_dense_ops=ff.executor.flash_lane_dense_ops(),
+        # windowed attention ops, and the flash forwards' K blocks visited
+        # against the whole square's (`executor.attention_gauges`)
+        **{k.split(".")[-1].replace("/", "_"): v
+           for k, v in ff.executor.attention_gauges().items()},
     )
 
 

@@ -15,13 +15,16 @@ and a kernel. The einsum core and ring attention, which want
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from flexflow_tpu.ffconst import OperatorType
 from flexflow_tpu.initializers import DefaultWeightInitializer
-from flexflow_tpu.ops.base import DimRole, Op, OpContext, register_op
+from flexflow_tpu.ops.base import (DimRole, Op, OpContext, register_op,
+                                   scoped)
 
 
 def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
@@ -47,8 +50,11 @@ def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
 
 
 def scaled_dot_product_attention(q, k, v, *, causal=False, dropout_rate=0.0,
-                                 rng=None, compute_dtype=jnp.float32):
-    """q,k,v: [B, H, S, D] -> [B, H, S, D]. Softmax in f32 for stability."""
+                                 rng=None, compute_dtype=jnp.float32,
+                                 window=0):
+    """q,k,v: [B, H, S, D] -> [B, H, S, D]. Softmax in f32 for stability.
+    ``window``: under ``causal``, a query sees only its last ``window``
+    keys (the rule is the flash kernels': ``pallas_kernels.visible``)."""
     d = q.shape[-1]
     scores = jnp.einsum(
         "bhqd,bhkd->bhqk",
@@ -57,8 +63,11 @@ def scaled_dot_product_attention(q, k, v, *, causal=False, dropout_rate=0.0,
         preferred_element_type=jnp.float32,
     ) / jnp.sqrt(jnp.float32(d))
     if causal:
+        from flexflow_tpu.ops.pallas_kernels import visible
+
         s_q, s_k = scores.shape[-2], scores.shape[-1]
-        mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+        mask = visible(jnp.arange(s_q)[:, None] + (s_k - s_q),
+                       jnp.arange(s_k)[None, :], window)
         scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1)
     if dropout_rate > 0.0 and rng is not None:
@@ -95,6 +104,13 @@ class MultiHeadAttention(Op):
         self.head_dim = p.get("head_dim") or self.embed_dim // self.num_heads
         self.dropout = p.get("dropout", 0.0)
         self.causal = p.get("causal", False)
+        # sliding window (causal only): a query sees its last `window`
+        # keys, itself among them; 0 is none, and one that covers the
+        # whole sequence is none either
+        self.window = p.get("window", 0) or 0
+        if self.window and not self.causal:
+            raise ValueError(f"attention '{layer.name}': a sliding window "
+                             f"needs causal attention")
         self.use_bias = p.get("bias", True)
         # grouped-query attention (Llama-family): kv heads may be fewer
         # than query heads; kv repeat to H before the core
@@ -130,6 +146,9 @@ class MultiHeadAttention(Op):
         # set when a forward hands the flash kernels [B, S, H*D]
         # operands (counted by `executor.flash_lane_dense_ops`)
         self._flash_lane_dense = False
+        # (visited, total) K blocks of the flash forward as traced, a
+        # head (`attention/kv_blocks_*`); None until a forward ran flash
+        self._kv_blocks = None
         # batch-dim sharding (str or tuple of mesh axes under the sample2
         # 'data+model' 2-D partition), recorded by apply_strategy
         self.batch_parallel = p.get("batch_parallel", None)
@@ -175,7 +194,32 @@ class MultiHeadAttention(Op):
             y = y + bias.reshape(h * d)
         return y
 
+    @property
+    def windowed(self) -> bool:
+        """The window hides something at this sequence length."""
+        return 0 < self.window < self.input_shapes[0][1]
+
     def forward(self, params, inputs, ctx: OpContext):
+        # the op's rng is split off here: inside the nested call below it
+        # would leave a tracer of that call in `ctx`
+        rng = ctx.next_rng() if (self.dropout > 0 and ctx.training) else None
+        if not self.causal:
+            return self._forward(params, inputs, ctx, rng, None)
+        # the ops under the causal visibility rule carry a scope for the
+        # device trace: `attention_window` where the window hides
+        # something, else `attention_full`; in it `flash_window` /
+        # `flash_full` around the kernel calls. XLA names an instruction
+        # after the first scope inside the nested call it came from, so a
+        # causal op's kernel events read `flash_window.N` / `flash_full.N`
+        # (as the grouped products' read `gmm.N`); the kernel's own name
+        # stays in `op_name`. Non-causal ops run unscoped, as they did.
+        kind = "window" if self.windowed else "full"
+        return scoped("attention_" + kind,
+                      lambda params, inputs: self._forward(
+                          params, inputs, ctx, rng, "flash_" + kind))(
+                              params, inputs)
+
+    def _forward(self, params, inputs, ctx: OpContext, rng, flash_scope):
         from flexflow_tpu.ops.pallas_kernels import merge_heads, split_heads
 
         query, key, value = (inputs + inputs[:1] * 2)[:3] if len(inputs) == 1 else inputs
@@ -197,7 +241,6 @@ class MultiHeadAttention(Op):
         if hk != h:
             k, v = (jnp.repeat(x.reshape(b, sk, hk, d), h // hk, axis=2
                                ).reshape(b, sk, h * d) for x in (k, v))
-        rng = ctx.next_rng() if (self.dropout > 0 and ctx.training) else None
         dropout_rate = self.dropout if ctx.training else 0.0
         # the attention core consumes q/k/v in the compute dtype (the
         # projections accumulate in f32): softmax/accumulation inside every
@@ -214,6 +257,11 @@ class MultiHeadAttention(Op):
         mesh_axes = (dict(zip(ctx.mesh.axis_names, ctx.mesh.devices.shape))
                      if ctx.mesh is not None else {})
         if seq_axis and mesh_axes.get(seq_axis, 1) > 1 and sq == sk:
+            if self.windowed:
+                raise NotImplementedError(
+                    f"attention '{self.name}': ring attention has no "
+                    f"sliding window (each block of the ring would need "
+                    f"its own offset into it)")
             if dropout_rate > 0.0 and not getattr(self, "_warned_dropout", False):
                 import warnings
 
@@ -234,7 +282,7 @@ class MultiHeadAttention(Op):
               and dropout_rate == 0.0 and sq == sk):
             from flexflow_tpu.ops.pallas_kernels import (
                 flash_attention, flash_attention_available,
-                flash_attention_sharded, flash_shape_legal)
+                flash_attention_sharded, flash_shape_legal, kv_blocks)
 
             available = flash_attention_available(sq, d, h)
             if self.kernel_impl == "flash" and not available:
@@ -246,6 +294,15 @@ class MultiHeadAttention(Op):
             if available:
                 # for `executor.flash_lane_dense_ops`
                 self._flash_lane_dense = True
+                self._kv_blocks = kv_blocks(sq, self.causal, self.window)
+
+                def flash(kernel, **where):
+                    call = functools.partial(
+                        kernel, num_heads=h, causal=self.causal,
+                        window=self.window, **where)
+                    return (scoped(flash_scope, call) if flash_scope
+                            else call)(q, k, v)
+
                 if any(s > 1 for s in mesh_axes.values()):
                     # non-trivial mesh: the raw pallas_call would be an
                     # unpartitionable custom call under GSPMD — run it
@@ -269,15 +326,14 @@ class MultiHeadAttention(Op):
                                  and flash_shape_legal(
                                      sq, d, h // mesh_axes[hp])
                                  else None)
-                    o = flash_attention_sharded(
-                        q, k, v, h, ctx.mesh, batch_axis=batch_axis,
-                        head_axis=head_axis, causal=self.causal)
+                    o = flash(flash_attention_sharded, mesh=ctx.mesh,
+                              batch_axis=batch_axis, head_axis=head_axis)
                 else:
-                    o = flash_attention(q, k, v, h, causal=self.causal)
+                    o = flash(flash_attention)
             else:
                 o = heads_first(lambda q, k, v: scaled_dot_product_attention(
                     q, k, v, causal=self.causal, dropout_rate=0.0,
-                    rng=None, compute_dtype=cd))
+                    rng=None, compute_dtype=cd, window=self.window))
         else:
             if self.kernel_impl == "flash" and self._kernel_fallback is None:
                 # forced flash but this forward cannot take the flash
@@ -290,7 +346,7 @@ class MultiHeadAttention(Op):
                     f"Sk={sk}) — einsum executed instead")
             o = heads_first(lambda q, k, v: scaled_dot_product_attention(
                 q, k, v, causal=self.causal, dropout_rate=dropout_rate,
-                rng=rng, compute_dtype=cd))
+                rng=rng, compute_dtype=cd, window=self.window))
         y = jnp.dot(o.astype(cd), params["wo"].astype(cd).reshape(h * d, -1),
                     preferred_element_type=jnp.float32)
         if self.use_bias:
@@ -343,6 +399,12 @@ class MultiHeadAttention(Op):
                 f"attention '{self.name}': KV-cache incremental decode "
                 f"requires causal attention (bidirectional rows depend "
                 f"on future positions)")
+        if self.window:
+            raise NotImplementedError(
+                f"attention '{self.name}': KV-cache incremental decode has "
+                f"no sliding window (window {self.window}): the cached "
+                f"path would attend over the whole prefix and drift from "
+                f"the training forward")
         query, key, value = (inputs + inputs[:1] * 2)[:3] \
             if len(inputs) == 1 else inputs
         cd = ctx.compute_dtype
@@ -424,7 +486,11 @@ class MultiHeadAttention(Op):
         hk = self.num_kv_heads  # GQA: k/v projections use the kv heads
         proj = (2 * b * h * d * (sq * e + sq * e)
                 + 2 * b * hk * d * (sk * self.kdim + sk * self.vdim))
-        core = 2 * b * h * sq * sk * d * 2
+        # under a window a query meets at most `window` keys: S x W
+        # products, not S^2 (a plain causal layer is priced at the whole
+        # square, as it always was)
+        core = 2 * b * h * sq * (min(sk, self.window) if self.window
+                                 else sk) * d * 2
         return proj + core
 
     def params_elems(self):

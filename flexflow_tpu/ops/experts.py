@@ -122,6 +122,14 @@ class MoELayer(Op):
         out = sum over the chosen j that are held of w_j expert_j(x)
               + shared(x)             (the same form, `shared_width` wide)
 
+    Three things a model may state otherwise (PR 31), each a property:
+    `scoring` "softmax": the k largest LOGITS x W_r are chosen and w is
+    the softmax over them (no bias leaf); `gated`: expert_j(x) =
+    (relu(x G_j) * (x U_j)) D_j with a third leaf `w_gate`; a second
+    input [B, S, D] that the ROUTER reads in place of x (a model that
+    routes from the pre-attention norm, so that the experts' choice is
+    known while attention runs): the experts still transform the first.
+
     The layer is told which experts it holds (`experts_held` of them from
     `expert_offset`); it routes over all `n_experts` and computes its own
     experts' part. A (token, slot) pair routed to an expert that is not
@@ -138,13 +146,14 @@ class MoELayer(Op):
     leave the step with the metrics and are read once an epoch).
 
     Weights: w_router [D, n_experts], e_bias [n_experts] (b, the
-    score-correction bias: it enters the choice only, so its gradient is
-    exactly zero and no optimizer moves it; models that balance their
-    experts without an auxiliary loss adjust it outside the gradient),
-    w_up [held, D, F], w_down [held, F, D], and with a shared expert ws_up
-    [D, Fs], ws_down [Fs, D]. The router's two leaves stay float32 in the
-    compute copy: a bias rounded to bfloat16 moves the choice of every
-    token alike.
+    score-correction bias, sigmoid scoring only: it enters the choice
+    only, so its gradient is exactly zero and no optimizer moves it;
+    models that balance their experts without an auxiliary loss adjust it
+    outside the gradient), w_up [held, D, F], w_down [held, F, D], with
+    `gated` w_gate [held, D, F], and with a shared expert ws_up [D, Fs],
+    ws_down [Fs, D]. The router's leaves stay float32 in the compute
+    copy: a bias rounded to bfloat16 moves the choice of every token
+    alike.
     """
 
     full_precision_params = ("w_router", "e_bias")
@@ -160,6 +169,11 @@ class MoELayer(Op):
         self.routed_scaling = p.get("routed_scaling", 1.0)
         self.norm_topk = p.get("norm_topk", True)
         self.slot_slack = p.get("slot_slack", 0.5)
+        self.scoring = p.get("scoring", "sigmoid")
+        self.gated = p.get("gated", False)
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"moe_layer '{layer.name}': unknown scoring "
+                             f"{self.scoring!r}")
         if self.expert_offset + self.experts_held > self.n_experts:
             raise ValueError(
                 f"moe_layer '{layer.name}': experts {self.expert_offset}.."
@@ -172,6 +186,11 @@ class MoELayer(Op):
 
     def compute_output_shapes(self):
         return [tuple(self.input_shapes[0])]
+
+    @property
+    def matrices(self) -> int:
+        """Matrices an expert has: up and down, and with `gated` a gate."""
+        return 3 if self.gated else 2
 
     @property
     def tokens(self):
@@ -193,13 +212,16 @@ class MoELayer(Op):
     def init_params(self, rng):
         d = self.input_shapes[0][-1]
         e, f = self.experts_held, self.hidden_size
-        ks = jax.random.split(rng, 5)
+        ks = jax.random.split(rng, 6)
         params = {
             "w_router": self.kernel_init(ks[0], (d, self.n_experts)),
-            "e_bias": jnp.zeros((self.n_experts,)),
             "w_up": self.kernel_init(ks[1], (e, d, f)),
             "w_down": self.kernel_init(ks[2], (e, f, d)),
         }
+        if self.scoring == "sigmoid":
+            params["e_bias"] = jnp.zeros((self.n_experts,))
+        if self.gated:
+            params["w_gate"] = self.kernel_init(ks[5], (e, d, f))
         if self.shared_width:
             params["ws_up"] = self.kernel_init(ks[3], (d, self.shared_width))
             params["ws_down"] = self.kernel_init(ks[4],
@@ -207,18 +229,23 @@ class MoELayer(Op):
         return params
 
     def forward(self, params, inputs, ctx: OpContext):
-        (x,) = inputs
+        x, x_router = inputs[0], inputs[-1]   # one input: the same
         cd = ctx.compute_dtype
         b, s, d = x.shape
         rows = self.buffer_rows
 
-        def route(params, xt):
-            logits = jnp.dot(xt.astype(jnp.float32),
+        def route(params, xt, xr):
+            logits = jnp.dot(xr.astype(jnp.float32),
                              params["w_router"].astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
+            if self.scoring == "sigmoid":
+                logits = jax.nn.sigmoid(logits)
+                bias = params["e_bias"].astype(jnp.float32)
+            else:
+                bias = None
             weights, experts = route_scores(
-                jax.nn.sigmoid(logits), params["e_bias"].astype(jnp.float32),
-                self.k, self.norm_topk, self.routed_scaling)
+                logits, bias, self.k, self.norm_topk, self.routed_scaling,
+                self.scoring)
             r = route_held_experts(experts, self.experts_held,
                                    self.expert_offset, rows)
             token = r["slot"] // self.k
@@ -228,7 +255,13 @@ class MoELayer(Op):
 
         def experts_held(params, x_buf, group_sizes):
             h = grouped_matmul(x_buf, params["w_up"].astype(cd), group_sizes)
-            h = squared_relu(h.astype(jnp.float32)).astype(cd)
+            if self.gated:
+                g = grouped_matmul(x_buf, params["w_gate"].astype(cd),
+                                   group_sizes)
+                h = (jax.nn.relu(g.astype(jnp.float32))
+                     * h.astype(jnp.float32)).astype(cd)
+            else:
+                h = squared_relu(h.astype(jnp.float32)).astype(cd)
             return grouped_matmul(h, params["w_down"].astype(cd),
                                   group_sizes)
 
@@ -239,9 +272,10 @@ class MoELayer(Op):
                            params["ws_down"].astype(cd),
                            preferred_element_type=jnp.float32)
 
-        def layer(params, x):
+        def layer(params, x, x_router):
             xt = x.reshape(b * s, d)
-            x_buf, token, w_row, r = scoped("moe_route", route)(params, xt)
+            x_buf, token, w_row, r = scoped("moe_route", route)(
+                params, xt, x_router.reshape(b * s, d))
             o = scoped("moe_grouped_matmul", experts_held)(
                 params, x_buf, r["group_sizes"])
             # rows past the groups are zero, and their weight is
@@ -252,7 +286,7 @@ class MoELayer(Op):
             return y.reshape(b, s, d).astype(x.dtype), r["load"], \
                 r["overflow"]
 
-        y, load, overflow = scoped("moe_layer", layer)(params, x)
+        y, load, overflow = scoped("moe_layer", layer)(params, x, x_router)
         load = load.astype(jnp.float32)
         self._counters = {
             "moe/slots_held": ("sum", jnp.sum(load)),
@@ -269,26 +303,27 @@ class MoELayer(Op):
 
     def flops(self):
         """Forward FLOPs of the work done here: the router over all
-        experts, the expected held pairs through two matrices, the shared
-        expert."""
+        experts, the expected held pairs through an expert's two matrices
+        (three if gated), the shared expert."""
         d = self.input_shapes[0][-1]
         t = self.tokens
         pairs = t * self.k * self.experts_held / self.n_experts
         return int(2 * t * d * self.n_experts
-                   + 4 * pairs * d * self.hidden_size
+                   + 2 * self.matrices * pairs * d * self.hidden_size
                    + 4 * t * d * self.shared_width)
 
     def interior_bytes(self):
         """Kept for the backward pass besides the output: the buffer's
-        rows in and between the two products, the shared expert's hidden
-        activations, the scores."""
+        rows in and between the products (each first product's result and
+        what the second takes), the shared expert's hidden activations,
+        the scores."""
         d = self.input_shapes[0][-1]
-        return (self.buffer_rows * (d + 2 * self.hidden_size)
+        return (self.buffer_rows * (d + self.matrices * self.hidden_size)
                 + self.tokens * 2 * self.shared_width
                 ) * self.dtype.size + 4 * self.tokens * self.n_experts
 
     def params_elems(self):
         d = self.input_shapes[0][-1]
-        return ((d + 1) * self.n_experts
-                + 2 * self.experts_held * d * self.hidden_size
+        return ((d + (self.scoring == "sigmoid")) * self.n_experts
+                + self.matrices * self.experts_held * d * self.hidden_size
                 + 2 * d * self.shared_width)

@@ -33,14 +33,30 @@ from flexflow_tpu.ffconst import OperatorType
 from flexflow_tpu.ops.base import DimRole, Op, OpContext, register_op
 
 
-def route_scores(scores, bias, k: int, norm_topk: bool, scaling: float):
-    """scores [T, E] float32, bias [E] -> (weights [T, k] float32, experts
-    [T, k] int32): the k largest of scores + bias a token (the bias takes
-    part in the choice only), their weights s_j / (sum of the k + 1e-20)
-    if `norm_topk`, times `scaling`."""
-    _, idx = jax.lax.top_k(scores + bias, k)
+def route_scores(scores, bias, k: int, norm_topk: bool, scaling: float,
+                 scoring: str = "sigmoid"):
+    """scores [T, E] float32, bias [E] or None -> (weights [T, k] float32,
+    experts [T, k] int32): the k largest of scores + bias a token (the
+    bias takes part in the choice only).
+
+    ``scoring`` "sigmoid": ``scores`` are the sigmoids; the chosen ones'
+    weights are s_j / (sum of the k + 1e-20) if `norm_topk`, times
+    `scaling`. ``scoring`` "softmax": ``scores`` are the router's logits;
+    the weights are the softmax over the k chosen logits if `norm_topk`
+    (which is the softmax over all E, renormalised over the chosen), else
+    the chosen entries of the softmax over all E; times `scaling`."""
+    choose = scores if bias is None else scores + bias
+    _, idx = jax.lax.top_k(choose, k)
     top = jnp.take_along_axis(scores, idx, axis=-1)
-    if norm_topk:
+    if scoring == "softmax":
+        if norm_topk:
+            top = jax.nn.softmax(top, axis=-1)
+        else:
+            top = jnp.exp(top - jax.nn.logsumexp(scores, axis=-1,
+                                                 keepdims=True))
+    elif scoring != "sigmoid":
+        raise ValueError(f"route_scores: unknown scoring {scoring!r}")
+    elif norm_topk:
         top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
     return top * scaling, idx.astype(jnp.int32)
 

@@ -15,6 +15,14 @@ backward (`flash_bwd_blocked`), up to MAX_FLASH_SEQ — the one upper bound
 the gate (``flash_attention_available``), the backward dispatch and the
 native ``kernel_gate`` share; past it attention runs the einsum path.
 
+One visibility rule (``visible``, PR 31): under ``causal`` a query sees
+the keys up to its own, and with a ``window`` only the last ``window`` of
+them. The whole-tile kernels mask; the blocked ones also SKIP: the
+forward loops over the K chunks, the backward over the Q chunks, that
+hold a visible pair (``_k_chunks``, ``_q_chunks``), so a causal layer
+does half the square's work and a window layer S x window of it.
+``window >= S`` is ``causal``, bit for bit (``normalized_window``).
+
 One operand form (PR 30): q, k, v, o and their gradients are
 [B, S, H*D], the heads side by side along the lanes, which is what the
 projections' 2-D products write and the output projection reads. A
@@ -30,7 +38,7 @@ zeroed (``_only_head``) and the MXU contracts all 128.
 Every MXU product takes its operands in the dtype the caller stored and
 accumulates in float32 (``_dot``); softmax statistics, ``exp``, scale
 and mask are float32. Tile sizes follow from the shape
-(``_heads_per_block``, ``_rows_per_step``, ``_kv_block``); the timings
+(``_heads_per_block``, ``_rows_per_step``, ``_seq_block``); the timings
 quoted beside them are the kernels alone on a v5e (PR 28), and PERF.md
 has what they gave through the benchmark's full step.
 
@@ -49,7 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLK_Q = 128  # rows of Q per grid step of the Q-blocked forward
+BLK_Q = 128  # the tile every admitted S is a multiple of (flash_shape_legal)
 LANES = 128  # a vreg's and an HBM tile's minor extent
 
 # Mosaic's default scoped-VMEM budget on v5e is 16 MiB, which the
@@ -80,10 +88,10 @@ KERNEL_NAME_PREFIX = "tpu_custom_call_"
 MAX_BWD_SEQ = 1024
 # Upper bounds of the flash path, forward and K-blocked backward alike.
 # What binds is the VMEM budget above, not the chip's physical VMEM: the
-# blocked backward holds the Q/dO/dQ panels of a column block ([S, 128]:
+# blocked backward holds the Q/O/dO/dQ panels of a column block ([S, 128]:
 # one head of 128 or two of 64 side by side, every lane a value) plus
-# [BLK, S] f32 score tiles, and the forward holds the K/V panels plus a
-# [BLK_Q, S] tile. Mirrored by the native kernel_gate
+# [BLK, BLK] f32 score tiles, and the forward holds the K/V panels plus
+# a chunk's tile. Mirrored by the native kernel_gate
 # (native/ffs_strategy.hpp) so the search never prices a length the
 # compiler refuses.
 MAX_FLASH_SEQ = 16384
@@ -159,24 +167,113 @@ def _for_rows(rows: int, unroll: int, body) -> None:
     jax.lax.fori_loop(0, rows // unroll, step, None)
 
 
-def _kv_block(s: int) -> int:
-    """K/V rows a grid step of the K-blocked backward takes: the largest
-    of 512, 256, 128 that divides S and keeps the [BLK, S] tile within
-    2^22 elements. v5e, bf16, causal, head_dim 128 (PR 28): S = 8192:
-    2221 us at 128, 1923 at 512; S = 16384: 4343 at 128, 3923 at 256,
-    4414 at 512."""
-    blk = 512
-    while blk > BLK_Q and (blk * s > 1 << 22 or s % blk):
-        blk //= 2
-    return blk
+def _seq_block(s: int) -> int:
+    """Rows of a block along the sequence in the blocked kernels (the K
+    chunks the forward's loop takes, the K rows a grid step and the Q
+    chunks a loop step of the backward): the largest of 1024, 512, 256,
+    128 that divides S. Until PR 31 a step held a block against the
+    WHOLE sequence, a [BLK, S] tile, and the block had to shrink with S;
+    a step's tile is now [BLK, BLK] whatever S. v5e, bf16, kernels alone,
+    forward / backward ms (PR 31, Q blocks of 256): 7 heads of 128 at
+    S = 16384, window 4096: 3.90 / 6.21 at 256, 2.38 / 4.23 at 512,
+    2.43 / 4.02 at 1024; the same, full causal: 8.00 / 12.88, 4.40 /
+    8.33, 4.15 / 7.71 (the parent's kernels, which visit every block:
+    7.05 / 14.42); 4 heads of 128 at S = 8192, causal: 1.27 / 1.95,
+    0.75 / 1.27, 0.73 / 1.21 (parent 1.08 / 2.03); 16 heads of 64 at
+    S = 8192, not causal, where nothing is skipped and the chunks' running
+    softmax is all cost: 8.55 / 9.16, 4.59 / 6.18, 4.14 / 5.41 (parent,
+    one pass over a [128, S] tile: 3.85 / 5.30)."""
+    return next(b for b in (1024, 512, 256, BLK_Q) if s % b == 0)
 
 
-def _mask_causal(st, k0):
-    """-inf where the query may not see the key, in a [k, q] tile whose
-    first row is key ``k0`` and first column query 0."""
+def _q_block(s: int) -> int:
+    """Q rows a grid step of the blocked forward takes: 256 where that
+    divides S, else the 128 every admitted S is a multiple of. v5e as
+    above, K chunks of 512, forward ms at 128 / 256 / 512 rows: window
+    3.18 / 2.38 / 2.30, full causal 6.24 / 4.40 / 4.22, S = 8192 causal
+    1.00 / 0.75 / 0.74: a chunk's fixed cost (the loop, the rescaling of
+    the running sums) is paid half as often."""
+    return 2 * BLK_Q if s % (2 * BLK_Q) == 0 else BLK_Q
+
+
+# what a masked score is set to. Finite, so that a chunk of the blocked
+# forward in which a row sees no key at all (its window starts in the
+# next one) leaves max - max = 0, not inf - inf: what that row gathers
+# there is wiped by exp(_MASKED - m) = 0 once it meets a visible key,
+# which it always does (its own).
+_MASKED = -1e30
+
+
+def visible(qq, kk, window: int):
+    """THE visibility rule of causal attention, for the four kernels, the
+    einsum core and whoever counts pairs: query ``qq`` sees key ``kk`` iff
+    kk <= qq and, with a ``window``, qq - kk < window (the key at distance
+    exactly ``window`` is masked). ``window`` 0: no window."""
+    v = kk <= qq
+    if window:
+        v = v & (qq - kk < window)
+    return v
+
+
+def _mask(st, k0, q0, window: int):
+    """_MASKED where the query may not see the key, in a [k, q] tile
+    whose first row is key ``k0`` and first column query ``q0``."""
     kk = k0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
-    qq = jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-    return jnp.where(kk <= qq, st, -jnp.inf)
+    qq = q0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+    return jnp.where(visible(qq, kk, window), st, _MASKED)
+
+
+def _pick(of_ints, of_traced, a, b):
+    """``kv_blocks`` counts with Python ints, also while a step is being
+    traced, where a ``jnp`` call on them would be staged into the trace."""
+    return (of_ints if isinstance(a, int) else of_traced)(a, b)
+
+
+def _k_chunks(q0, blk_q: int, blk_k: int, s: int, causal: bool,
+              window: int):
+    """[lo, hi): the K chunks of ``blk_k`` rows that hold a key some
+    query of the block [q0, q0 + blk_q) sees. ``q0`` is the kernel's
+    traced block start or, where ``kv_blocks`` counts, a Python int: the
+    loop and the count are the same lines."""
+    hi = (q0 + blk_q - 1) // blk_k + 1 if causal else s // blk_k
+    lo = _pick(max, jnp.maximum, q0 - window + 1, 0) // blk_k if window else 0
+    return lo, hi
+
+
+def _q_chunks(k0, blk_k: int, blk_q: int, s: int, causal: bool,
+              window: int):
+    """[lo, hi): the Q chunks of ``blk_q`` rows that hold a query which
+    sees some key of the block [k0, k0 + blk_k): the backward's loop."""
+    lo = k0 // blk_q if causal else 0
+    hi = s // blk_q
+    if window:
+        hi = _pick(min, jnp.minimum, (k0 + blk_k + window - 2) // blk_q + 1,
+                   hi)
+    return lo, hi
+
+
+def normalized_window(s: int, causal: bool, window: int) -> int:
+    """0 where the window hides nothing (none given, or >= S): those run
+    the very code ``causal`` alone runs."""
+    if window and not causal:
+        raise ValueError("a sliding window needs causal attention")
+    return window if 0 < window < s else 0
+
+
+def kv_blocks(s: int, causal: bool, window: int = 0):
+    """(visited, total) tiles of [a Q block, a K chunk] that the
+    forward of one head works through at sequence ``s``: what the
+    gauges ``attention/kv_blocks_visited`` / ``_total`` add up. The
+    whole-tile kernels (S <= MAX_BWD_SEQ) hold one tile and mask."""
+    if s <= MAX_BWD_SEQ:
+        return 1, 1
+    window = normalized_window(s, causal, window)
+    blk_q, blk_k = _q_block(s), _seq_block(s)
+    visited = 0
+    for q0 in range(0, s, blk_q):
+        lo, hi = _k_chunks(q0, blk_q, blk_k, s, causal, window)
+        visited += hi - lo
+    return visited, (s // blk_q) * (s // blk_k)
 
 
 def _only_head(x, h: int, head_dim: int):
@@ -204,38 +301,61 @@ def _stack_heads(parts):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
-                      scale: float, blk_q: int, head_dim: int):
+                      window: int, scale: float, blk_q: int, blk_k: int,
+                      head_dim: int):
     """One (batch row, column block, q-block) grid cell: q [1,BLK_Q,W]
-    against the full K/V [1,S,W] resident in VMEM; scores never touch
-    HBM. Also emits the per-row logsumexp so the fused backward can
-    recompute P exactly. The forward of sequences past MAX_BWD_SEQ."""
+    against the K/V panels [1,S,W] resident in VMEM, in chunks of
+    ``blk_k`` keys with a running max and sum (the online softmax), and
+    ONLY the chunks that hold a key some query of the block sees
+    (``_k_chunks``): under ``causal`` those up to the diagonal, under a
+    window the few behind it. Scores never touch HBM. Also emits the
+    per-row logsumexp so the fused backward can recompute P exactly.
+    The forward of sequences past MAX_BWD_SEQ."""
     q = q_ref[0]  # [BLK_Q, W]
-    k = k_ref[0]  # [S, W]
-    v = v_ref[0]
-    if causal:
-        tile = (q.shape[0], k.shape[0])
-        rows = pl.program_id(2) * blk_q + jax.lax.broadcasted_iota(
-            jnp.int32, tile, 0)
-        visible = jax.lax.broadcasted_iota(jnp.int32, tile, 1) <= rows
-    o = None
-    for h in range(q.shape[-1] // head_dim):
-        s = _dot_head(q, k, h, head_dim) * scale
+    heads = q.shape[-1] // head_dim
+    q0 = pl.program_id(2) * blk_q
+    lo, hi = _k_chunks(q0, blk_q, blk_k, k_ref.shape[1], causal, window)
+    qs = [_only_head(q, h, head_dim) for h in range(heads)]
+    tile = (blk_q, blk_k)
+
+    def chunk(c, carry):
+        k0 = pl.multiple_of(c * blk_k, blk_k)
+        k = k_ref[0, pl.ds(k0, blk_k), :]  # [BLK_K, W]
+        v = v_ref[0, pl.ds(k0, blk_k), :]
         if causal:
-            s = jnp.where(visible, s, -jnp.inf)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        # P against every head of the block, the other heads' lanes
-        # dropped from the [BLK_Q, W] result: cheaper than masking V
-        oh = _only_head(_dot(p.astype(v.dtype), v, _NN) / l, h, head_dim)
+            seen = visible(
+                q0 + jax.lax.broadcasted_iota(jnp.int32, tile, 0),
+                k0 + jax.lax.broadcasted_iota(jnp.int32, tile, 1), window)
+        out = []
+        for h, (m, l, acc) in enumerate(carry):
+            s = _dot(qs[h], k, _NT) * scale
+            if causal:
+                s = jnp.where(seen, s, _MASKED)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+            # P against every head of the block; the other heads' lanes
+            # are dropped from the [BLK_Q, W] result at the end
+            acc = alpha * acc + _dot(p.astype(v.dtype), v, _NN)
+            out.append((m_new, l, acc))
+        return tuple(out)
+
+    carry = jax.lax.fori_loop(lo, hi, chunk, tuple(
+        (jnp.full((blk_q, 1), _MASKED, jnp.float32),
+         jnp.zeros((blk_q, 1), jnp.float32),
+         jnp.zeros(q.shape, jnp.float32)) for _ in range(heads)))
+    o = None
+    for h, (m, l, acc) in enumerate(carry):
+        oh = _only_head(acc / l, h, head_dim)
         o = oh if o is None else o + oh
         lse_ref[0, h, 0] = (m + jnp.log(l))[:, 0]
     o_ref[0] = o.astype(o_ref.dtype)
 
 
 def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                            causal: bool, scale: float, rows: int,
-                            head_dim: int):
+                            causal: bool, window: int, scale: float,
+                            rows: int, head_dim: int):
     """``rows`` batch rows a grid cell, each with the heads of one column
     block and their whole sequence: the forward up to MAX_BWD_SEQ. The
     score tile is held as [k, q]: the softmax's max and sum then run down
@@ -254,7 +374,7 @@ def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         for h in range(heads):
             st = _dot_head(k, q, h, head_dim) * scale   # [k, q]
             if causal:
-                st = _mask_causal(st, 0)
+                st = _mask(st, 0, 0, window)
             m = jnp.max(st, axis=0, keepdims=True)   # [1, S]
             pt = jnp.exp(st - m)
             l = jnp.sum(pt, axis=0, keepdims=True)
@@ -268,7 +388,7 @@ def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
 
 def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
-               out_dtype=None):
+               out_dtype=None, window: int = 0):
     """q, k, v: [B, S, H*D] with S % BLK_Q == 0 -> (o [B, S, H*D],
     lse [B, H, 1, S]). A block is S (or BLK_Q) rows by one column block
     of the operand, picked by the BlockSpec's last index: in HBM's
@@ -278,6 +398,7 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     hpb = _heads_per_block(num_heads, d)
     w = hpb * d
     scale = 1.0 / float(d) ** 0.5
+    window = normalized_window(s, causal, window)
     # lse is (b, h, 1, s): TPU requires the last two block dims be
     # (8,128)-aligned or span the array — a singleton before the
     # sequence satisfies that while keeping one row per head
@@ -288,7 +409,8 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
         seq_spec = pl.BlockSpec((rows, s, w), lambda i, j: (i, 0, j))
         return pl.pallas_call(
             functools.partial(_flash_fwd_whole_kernel, causal=causal,
-                              scale=scale, rows=rows, head_dim=d),
+                              window=window, scale=scale, rows=rows,
+                              head_dim=d),
             name=KERNEL_NAME_PREFIX + "flash_fwd_whole",
             out_shape=out_shape,
             grid=(b // rows, num_heads // hpb),
@@ -299,13 +421,11 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
         )(q, k, v)
-    # Q blocks of 128 rows: at S = 8192 and 16384 blocks of 256 take the
-    # same time within 2% (v5e, kernel alone, PR 28); not re-measured
-    # through a full step, where an older round had 128 ahead
-    blk = BLK_Q
+    blk = _q_block(s)
     return pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, causal=causal, scale=scale,
-                          blk_q=blk, head_dim=d),
+        functools.partial(_flash_fwd_kernel, causal=causal, window=window,
+                          scale=scale, blk_q=blk, blk_k=_seq_block(s),
+                          head_dim=d),
         name=KERNEL_NAME_PREFIX + "flash_fwd",
         out_shape=out_shape,
         grid=(b, num_heads // hpb, s // blk),
@@ -322,17 +442,20 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     )(q, k, v)
 
 
-def _flash_bwd_tile(q, k, v, o, do, lse, glse, scale: float, k0,
+def _flash_bwd_tile(q, k, kt, v, o, do, lse, glse, scale: float, mask,
                     head_dim: int):
     """FlashAttention-2 backward of the [k, q] tiles of one column
-    block: k, v [Bk, W] from key ``k0`` on (None: not causal) against
-    q, O, dO [Bq, W] and, a head, the [1, Bq] rows lse and g_lse, the
-    upstream gradient on the logsumexp output (zero when only o is
-    consumed; nonzero under ring attention's streaming merge, whose
-    weights are functions of each block's lse). Recompute P from the
-    saved lse, then dV = P^T dO, dS = P * (dO V^T - delta + g_lse) with
-    delta = rowsum(dO * O), dQ = dS K * scale, dK = dS^T Q * scale;
-    returns float32 dQ [Bq, W], dK, dV [Bk, W].
+    block: k, v [Bk, W] (and ``kt`` = k^T, which the caller forms once)
+    against q, O, dO [Bq, W] and, a head, the [1, Bq] rows lse and
+    g_lse, the upstream gradient on the logsumexp output (zero when only
+    o is consumed; nonzero under ring attention's streaming merge, whose
+    weights are functions of each block's lse). ``mask``: None (not
+    causal) or (first key, first query, window) of the tile. Recompute P
+    from the saved lse, then dV = P^T dO, dS = P * (dO V^T - delta +
+    g_lse) with delta = rowsum(dO * O), dQ = dS K, dK = dS^T Q; returns
+    them TRANSPOSED and without the softmax scale, float32 dQ^T [W, Bq],
+    dK^T, dV^T [W, Bk]: the blocked backward adds dK^T and dV^T up over
+    its Q chunks and turns them once.
 
     delta is formed here, from the O^T and dO^T tiles, as sums down a
     head's sublanes: outside, XLA writes the [.., S]-minor rows by
@@ -350,14 +473,14 @@ def _flash_bwd_tile(q, k, v, o, do, lse, glse, scale: float, k0,
     989 with the tile as [q, k] and dK, dV contracting over its rows;
     980 as [k, q] with [S, D] results, and 980 still with the
     element-wise work taken out of that one."""
-    qt, kt, dot = q.T, k.T, do.T                     # [W, S]
+    qt, dot = q.T, do.T                              # [W, S]
     dot_ot = dot.astype(jnp.float32) * o.T.astype(jnp.float32)
     dqt, dkt, dvt = [], [], []
     for h in range(q.shape[-1] // head_dim):
         mine = slice(h * head_dim, (h + 1) * head_dim)
         st = _dot_head(k, q, h, head_dim) * scale        # [k, q]
-        if k0 is not None:
-            st = _mask_causal(st, k0)
+        if mask is not None:
+            st = _mask(st, *mask)
         pt = jnp.exp(st - lse[h])                    # exact softmax probs
         dpt = _dot_head(v, do, h, head_dim)
         delta = jnp.sum(dot_ot[mine], axis=0, keepdims=True)   # [1, q]
@@ -365,56 +488,72 @@ def _flash_bwd_tile(q, k, v, o, do, lse, glse, scale: float, k0,
         dvt.append(_dot(dot[mine], pt.astype(do.dtype), _NT))  # [D, k]
         dkt.append(_dot(qt[mine], dst, _NT))                   # [D, k]
         dqt.append(_dot(kt[mine], dst, _NN))                   # [D, q]
-    return ((_stack_heads(dqt) * scale).T, (_stack_heads(dkt) * scale).T,
-            _stack_heads(dvt).T)
+    return _stack_heads(dqt), _stack_heads(dkt), _stack_heads(dvt)
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       glse_ref, dq_ref, dk_ref, dv_ref, *, causal: bool,
-                      scale: float, rows: int, head_dim: int):
+                      window: int, scale: float, rows: int, head_dim: int):
     """``rows`` batch rows a grid cell, each with the heads of one column
     block and the whole sequence in VMEM (gated by MAX_BWD_SEQ).
     Scores/probabilities never touch HBM — the reason XLA's einsum
     backward loses at these shapes."""
     def row(b):
-        dq, dk, dv = _flash_bwd_tile(
-            q_ref[b], k_ref[b], v_ref[b], o_ref[b], do_ref[b], lse_ref[b],
-            glse_ref[b], scale, 0 if causal else None, head_dim)
-        dq_ref[b] = dq.astype(dq_ref.dtype)
-        dk_ref[b] = dk.astype(dk_ref.dtype)
-        dv_ref[b] = dv.astype(dv_ref.dtype)
+        k = k_ref[b]
+        dqt, dkt, dvt = _flash_bwd_tile(
+            q_ref[b], k, k.T, v_ref[b], o_ref[b], do_ref[b], lse_ref[b],
+            glse_ref[b], scale, (0, 0, window) if causal else None,
+            head_dim)
+        dq_ref[b] = (dqt * scale).T.astype(dq_ref.dtype)
+        dk_ref[b] = (dkt * scale).T.astype(dk_ref.dtype)
+        dv_ref[b] = dvt.T.astype(dv_ref.dtype)
 
     _for_rows(rows, max(1, 2 // (q_ref.shape[-1] // head_dim)), row)
 
 
 def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                               glse_ref, dq_ref, dk_ref, dv_ref, *,
-                              causal: bool, scale: float, blk: int,
-                              head_dim: int):
+                              causal: bool, window: int, scale: float,
+                              blk: int, head_dim: int):
     """FA2 backward for sequences past MAX_BWD_SEQ: grid cell = one
-    (batch row, column block, K-block). The full Q/O/dO panels are
-    resident; the [BLK, S] score tile for this K-block is recomputed in
-    VMEM; dK/dV write their block, and dQ accumulates in-place across
-    the K-block grid dimension (same output block revisited -> Pallas
-    keeps it in VMEM between consecutive steps)."""
+    (batch row, column block, K-block). The Q/O/dO panels are resident;
+    the K-block meets them in chunks of ``blk`` queries, and ONLY the
+    chunks that hold a query which sees one of its keys (``_q_chunks``:
+    from the diagonal on, and under a window no further than the window
+    behind the block's last key); a chunk's [BLK, BLK] score tile is
+    recomputed in VMEM. dK/dV add up over the chunks and write their
+    block; dQ adds up in place, a chunk's rows at a time, across the
+    K-block grid dimension (same output block revisited -> Pallas keeps
+    it in VMEM between consecutive steps)."""
     j = pl.program_id(2)
-    dq_blk, dk, dv = _flash_bwd_tile(
-        q_ref[0], k_ref[0], v_ref[0], o_ref[0], do_ref[0], lse_ref[0],
-        glse_ref[0], scale, j * blk if causal else None, head_dim)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    k0 = j * blk
+    k, v = k_ref[0], v_ref[0]
+    kt = k.T
+    lo, hi = _q_chunks(k0, blk, blk, q_ref.shape[1], causal, window)
 
     @pl.when(j == 0)
     def _init():
-        dq_ref[0] = dq_blk
+        dq_ref[0] = jnp.zeros(dq_ref.shape[1:], dq_ref.dtype)
 
-    @pl.when(j > 0)
-    def _acc():
-        dq_ref[0] += dq_blk
+    def chunk(c, carry):
+        q0 = pl.multiple_of(c * blk, blk)
+        rows = pl.ds(q0, blk)
+        dqt, dkt, dvt = _flash_bwd_tile(
+            q_ref[0, rows, :], k, kt, v, o_ref[0, rows, :],
+            do_ref[0, rows, :], lse_ref[0, :, :, rows],
+            glse_ref[0, :, :, rows], scale,
+            (k0, q0, window) if causal else None, head_dim)
+        dq_ref[0, rows, :] += (dqt * scale).T
+        return carry[0] + dkt, carry[1] + dvt
+
+    zero = jnp.zeros((k.shape[1], blk), jnp.float32)
+    dkt, dvt = jax.lax.fori_loop(lo, hi, chunk, (zero, zero))
+    dk_ref[0] = (dkt * scale).T.astype(dk_ref.dtype)
+    dv_ref[0] = dvt.T.astype(dv_ref.dtype)
 
 
 def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
-               interpret: bool, glse=None):
+               interpret: bool, glse=None, window: int = 0):
     """dq, dk, dv [B, S, H*D] from the saved (o, lse[B, H, 1, S]): one
     whole-tile step for several heads up to MAX_BWD_SEQ, K-blocked past
     it — scores stay in VMEM tiles at every length the gate admits
@@ -424,6 +563,7 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
     hpb = _heads_per_block(num_heads, d)
     w = hpb * d
     scale = 1.0 / float(d) ** 0.5
+    window = normalized_window(s, causal, window)
     # the ring's merge hands a float32 dO (its o is float32): as an MXU
     # operand it takes the stored dtype, like P and dS
     do = do.astype(q.dtype)
@@ -435,7 +575,8 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         row_spec = pl.BlockSpec((rows, hpb, 1, s), lambda i, j: (i, j, 0, 0))
         return pl.pallas_call(
             functools.partial(_flash_bwd_kernel, causal=causal,
-                              scale=scale, rows=rows, head_dim=d),
+                              window=window, scale=scale, rows=rows,
+                              head_dim=d),
             name=KERNEL_NAME_PREFIX + "flash_bwd",
             out_shape=(jax.ShapeDtypeStruct((b, s, hd), q.dtype),
                        jax.ShapeDtypeStruct((b, s, hd), k.dtype),
@@ -447,13 +588,13 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
         )(q, k, v, o, do, lse, glse)
-    blk = _kv_block(s)
+    blk = _seq_block(s)
     seq_spec = pl.BlockSpec((1, s, w), lambda b, c, j: (b, 0, c))
     kblk_spec = pl.BlockSpec((1, blk, w), lambda b, c, j: (b, j, c))
     row_spec = pl.BlockSpec((1, hpb, 1, s), lambda b, c, j: (b, c, 0, 0))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_blocked_kernel, causal=causal,
-                          scale=scale, blk=blk, head_dim=d),
+                          window=window, scale=scale, blk=blk, head_dim=d),
         name=KERNEL_NAME_PREFIX + "flash_bwd_blocked",
         out_shape=(jax.ShapeDtypeStruct((b, s, hd), jnp.float32),  # dq acc
                    jax.ShapeDtypeStruct((b, s, hd), k.dtype),
@@ -468,19 +609,20 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
     return dq.astype(q.dtype), dk, dv
 
 
-def _xla_attention(q, k, v, causal: bool):
+def _xla_attention(q, k, v, causal: bool, window: int = 0):
     """Reference einsum attention the kernel tests compare against."""
-    return _xla_attention_lse(q, k, v, causal)[0]
+    return _xla_attention_lse(q, k, v, causal, window)[0]
 
 
-def _xla_attention_lse(q, k, v, causal: bool):
+def _xla_attention_lse(q, k, v, causal: bool, window: int = 0):
     """Reference einsum path that also emits the per-row logsumexp."""
     d = q.shape[-1]
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) / jnp.sqrt(jnp.float32(d))
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        mask = visible(jnp.arange(sq)[:, None] + (sk - sq),
+                       jnp.arange(sk)[None, :], window)
         s = jnp.where(mask, s, jnp.finfo(jnp.float32).min)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
@@ -488,19 +630,22 @@ def _xla_attention_lse(q, k, v, causal: bool):
     return o, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, num_heads, causal, interpret):
-    return _flash_fwd(q, k, v, num_heads, causal, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, num_heads, causal, interpret, window=0):
+    return _flash_fwd(q, k, v, num_heads, causal, interpret,
+                      window=window)[0]
 
 
-def _flash_vjp_fwd(q, k, v, num_heads, causal, interpret):
-    o, lse = _flash_fwd(q, k, v, num_heads, causal, interpret)
+def _flash_vjp_fwd(q, k, v, num_heads, causal, interpret, window=0):
+    o, lse = _flash_fwd(q, k, v, num_heads, causal, interpret,
+                        window=window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(num_heads, causal, interpret, res, g):
+def _flash_vjp_bwd(num_heads, causal, interpret, window, res, g):
     q, k, v, o, lse = res
-    return _flash_bwd(q, k, v, o, lse, g, num_heads, causal, interpret)
+    return _flash_bwd(q, k, v, o, lse, g, num_heads, causal, interpret,
+                      window=window)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -514,7 +659,8 @@ def flash_attention_lse(q, k, v, num_heads, causal, interpret):
     gradient (the merge weights are functions of lse). q, k, v:
     [B, S, H*D]. ``o`` is emitted in f32: the ring merge accumulates in
     f32, and rounding each block's normalized output to bf16 first would
-    compound per-block error."""
+    compound per-block error. No window: a ring's blocks would each need
+    their own offset into it."""
     o, lse = _flash_fwd(q, k, v, num_heads, causal, interpret,
                         out_dtype=jnp.float32)
     return o, lse[:, :, 0, :]
@@ -585,7 +731,8 @@ def flash_attention_available(seq_len: int, head_dim: int,
     return mode == "interpret" or seq_len >= MIN_SEQ_FOR_FLASH
 
 
-def flash_attention(q, k, v, num_heads: int, causal: bool = False):
+def flash_attention(q, k, v, num_heads: int, causal: bool = False,
+                    window: int = 0):
     """q, k, v: [B, S, H*D] -> [B, S, H*D], the heads side by side along
     the lanes as the projections' plain 2-D products leave them, so that
     no layout change sits between a projection and a kernel and no
@@ -593,11 +740,13 @@ def flash_attention(q, k, v, num_heads: int, causal: bool = False):
     flash_attention_available first; self-attention only (Sq == Sk).
     Who holds [B, H, S, D] converts with ``merge_heads`` /
     ``split_heads`` at its own boundary."""
-    return _flash(q, k, v, num_heads, causal, pallas_mode() == "interpret")
+    return _flash(q, k, v, num_heads, causal, pallas_mode() == "interpret",
+                  window)
 
 
 def flash_attention_sharded(q, k, v, num_heads: int, mesh, batch_axis=None,
-                            head_axis=None, causal: bool = False):
+                            head_axis=None, causal: bool = False,
+                            window: int = 0):
     """Flash attention inside a GSPMD-sharded jit: a bare ``pallas_call``
     is an unpartitionable custom call to the partitioner, so wrap it in
     ``shard_map`` over the mesh axes the batch/head dims are sharded on —
@@ -609,6 +758,7 @@ def flash_attention_sharded(q, k, v, num_heads: int, mesh, batch_axis=None,
 
     spec = P(batch_axis, None, head_axis)
     local = num_heads // (mesh.shape[head_axis] if head_axis else 1)
-    fn = functools.partial(flash_attention, num_heads=local, causal=causal)
+    fn = functools.partial(flash_attention, num_heads=local, causal=causal,
+                           window=window)
     return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)

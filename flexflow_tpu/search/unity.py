@@ -44,6 +44,10 @@ def _node_attrs(op) -> Dict[str, Any]:
     if (getattr(op, "head_dim", None) and getattr(op, "embed_dim", None)
             and op.head_dim != op.embed_dim // op.num_heads):
         attrs["head_dim"] = int(op.head_dim)
+    # a sliding window that hides something: the scores an attention op
+    # forms (and einsum keeps) are S x window, not S^2
+    if getattr(op, "windowed", False):
+        attrs["window"] = int(op.window)
     if hasattr(op, "interior_bytes"):
         attrs["interior_bytes"] = float(op.interior_bytes())
     # conv/pool geometry (stored as (h, w) tuples on the op): needed so a

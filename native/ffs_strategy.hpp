@@ -174,6 +174,18 @@ inline const char* kernel_default_impl(const Node& n, const Choice& c) {
   return "";
 }
 
+// Keys a query of attention node `n` meets: the sequence, or the sliding
+// window where the op has one that hides something (attr `window`,
+// ops/attention.py). The scores are S x this, not S^2: what the einsum
+// path keeps, what flash spares, and (through the op's own `flops`) the
+// products both do. The kernel gate does not look at it: a window
+// changes no shape, so it is admitted wherever `flash_shape_legal` is.
+inline int64_t attention_keys_seen(const Node& n) {
+  int64_t seq = n.output_shapes[0][1];
+  int64_t window = n.attrs.get("window").as_int(0);
+  return window > 0 ? std::min(window, seq) : seq;
+}
+
 // Structural legality of a kernel alternative on `n`: "" = legal, else a
 // named rejection reason recorded in the search trace (the flash gate
 // mirrors ops/pallas_kernels.flash_shape_legal — Q-block tile
@@ -298,7 +310,7 @@ inline std::string remat_gate(const Node& n, const Choice& c,
     int64_t heads = n.attrs.get("num_heads").as_int(1);
     const Shape& os = n.output_shapes[0];
     interior += (double)os[0] * (double)heads * (double)os[1] *
-                (double)os[1] * 4.0;
+                (double)attention_keys_seen(n) * 4.0;
   }
   double boundary = 0;
   std::vector<std::pair<int64_t, int>> seen;
@@ -1225,7 +1237,7 @@ inline NodeCost node_cost(const Node& n, const Choice& c, const MeshShape& mesh,
       int64_t heads = n.attrs.get("num_heads").as_int(1);
       const Shape& os = n.output_shapes[0];
       double score_b = (double)os[0] * heads * (double)os[1] *
-                       (double)os[1] * 4.0 / div;
+                       (double)attention_keys_seen(n) * 4.0 / div;
       nc.fwd = std::max(nc.fwd - 2.0 * score_b / m.hbm_bw, floor_f);
       if (training)
         nc.bwd = std::max(nc.bwd - 4.0 * score_b / m.hbm_bw, floor_b);

@@ -189,9 +189,9 @@ def test_tiles_divide_what_they_tile():
     """Batch rows a step and K/V rows a block, for every shape the gate
     admits: a block that did not divide would drop the rest in silence."""
     for s in range(pk.BLK_Q, pk.MAX_FLASH_SEQ + 1, pk.BLK_Q):
-        blk = pk._kv_block(s)
-        assert blk in (128, 256, 512) and s % blk == 0, s
-        assert blk * s <= 1 << 22 or blk == pk.BLK_Q, s
+        blk = pk._seq_block(s)
+        assert blk in (128, 256, 512, 1024) and s % blk == 0, s
+        assert s % pk._q_block(s) == 0 and pk._q_block(s) in (128, 256), s
     for s in range(pk.BLK_Q, pk.MAX_BWD_SEQ + 1, pk.BLK_Q):
         for batch in (1, 2, 6, 12, 16, 32):
             for per_block in (1, 2, 4, 12, 16):
@@ -202,7 +202,9 @@ def test_tiles_divide_what_they_tile():
     assert pk._rows_per_step(32, 2, 512) == 4
     assert pk._rows_per_step(32, 2, pk.MAX_BWD_SEQ) == 1
     assert pk._rows_per_step(32, 1, pk.MAX_BWD_SEQ) == 2
-    assert pk._kv_block(8192) == 512 and pk._kv_block(1152) == 128
+    assert pk._seq_block(8192) == 1024 and pk._seq_block(1152) == 128
+    assert pk._seq_block(16384) == 1024 and pk._seq_block(1536) == 512
+    assert pk._q_block(1152) == 128 and pk._q_block(16384) == 256
 
 
 def test_blocks_that_do_not_divide_by_the_widest_block():

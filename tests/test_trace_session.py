@@ -345,3 +345,53 @@ def test_flash_lane_dense_ops_counts_the_models_flash_ops(
     assert header["flash_lane_dense_ops"] == flash
     gauges = json.load(open(paths["counters"]))["gauges"]
     assert gauges["executor.flash_lane_dense_ops"] == flash
+
+
+@pytest.mark.parametrize("seq,window", [(2048, 512), (2048, 0), (128, 32)])
+def test_window_attention_gauges_show_that_the_skip_engaged(
+        seq, window, tmp_path, monkeypatch, no_open_session):
+    """`executor.window_attention_ops`, `attention/kv_blocks_visited` and
+    `attention/kv_blocks_total` (PR 31), set when the train step is
+    traced: in the registry's snapshot and in the header of a session.
+    A blocked causal layer visits the K blocks up to the diagonal and a
+    windowed one fewer; the whole-tile kernels hold one tile and mask."""
+    import numpy as np
+    from flexflow_tpu import (FFConfig, FFModel, LossType, MetricsType,
+                              SGDOptimizer)
+    from flexflow_tpu.ops.pallas_kernels import kv_blocks
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    b, e = 1, 32
+    ff = FFModel(FFConfig(batch_size=b))
+    t = ff.create_tensor((b, seq, e))
+    t = ff.multihead_attention(t, t, t, e, 2, causal=True, name="full")
+    t = ff.multihead_attention(t, t, t, e, 2, causal=True, window=window,
+                               name="windowed")
+    ff.dense(t, 1)
+    ff.compile(SGDOptimizer(lr=0.01), LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
+               [MetricsType.MEAN_SQUARED_ERROR])
+    context = obs.model_context(ff)
+    assert context["window_attention_ops"] == (1 if window else 0)
+    assert context["attention_kv_blocks_total"] == 0      # not traced yet
+    rs = np.random.RandomState(0)
+    x = rs.randn(b, seq, e).astype(np.float32)
+    y = rs.randn(b, seq, 1).astype(np.float32)
+    ff.fit(x, y, epochs=1, verbose=False)   # traces and compiles the step
+    obs.start_trace(str(tmp_path), device=False)
+    ff.fit(x, y, epochs=1, verbose=False)
+    paths = obs.stop_trace()
+    full, total = kv_blocks(seq, True, 0)
+    part, _ = kv_blocks(seq, True, window)
+    header, _ = read_events(paths["events"])
+    gauges = json.load(open(paths["counters"]))["gauges"]
+    for got in (
+            (header["window_attention_ops"],
+             header["attention_kv_blocks_visited"],
+             header["attention_kv_blocks_total"]),
+            (gauges["executor.window_attention_ops"],
+             gauges["attention/kv_blocks_visited"],
+             gauges["attention/kv_blocks_total"])):
+        assert got == (1 if window else 0, full + part, 2 * total)
+    if seq > 1024:
+        assert full < total
+        assert (part < full) == bool(window)
