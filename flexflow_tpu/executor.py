@@ -631,6 +631,15 @@ class GraphExecutor:
         return sum(bool(getattr(n.op, "_flash_lane_dense", False))
                    for n in self.nodes)
 
+    def moe_gather_combine_ops(self) -> int:
+        """`MoELayer` ops whose forward, as last traced, sent rows to the
+        experts and brought them back to their tokens by gathers through
+        the routing sort and its inverse (ops/experts.py sets the flag;
+        PR 32): the gauge `executor.moe_gather_combine_ops`, and
+        `moe_gather_combine_ops` in every trace header."""
+        return sum(bool(getattr(n.op, "_gather_combine", False))
+                   for n in self.nodes)
+
     def attention_gauges(self) -> Dict[str, int]:
         """What the attention ops' forwards, as last traced, recorded on
         the host (PR 31): the ops whose window hides something at their
@@ -717,6 +726,8 @@ class GraphExecutor:
                                  self.flash_lane_dense_ops())
             for gauge, value in self.attention_gauges().items():
                 get_registry().gauge(gauge, value)
+            get_registry().gauge("executor.moe_gather_combine_ops",
+                                 self.moe_gather_combine_ops())
             # gradient sync over the data axes is inserted by GSPMD here
             # (in bf16 under the master-weight regime — half the bytes).
             # Under WUS the shard constraint turns that all-reduce into a

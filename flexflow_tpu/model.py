@@ -1399,6 +1399,8 @@ class FFModel:
             self.executor.flash_lane_dense_ops())
         self.op_counters.update(
             (k, float(v)) for k, v in self.executor.attention_gauges().items())
+        self.op_counters["executor.moe_gather_combine_ops"] = float(
+            self.executor.moe_gather_combine_ops())
 
     def fit(self, x=None, y=None, batch_size: Optional[int] = None,
             epochs: Optional[int] = None, verbose: bool = True,

@@ -12,9 +12,10 @@ all-to-all / collective-permute counts and payload byte volumes.
 
 from __future__ import annotations
 
+import math
 import re
 from collections import defaultdict
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s32": 4, "u32": 4,
@@ -291,6 +292,21 @@ def export_step_summary(ff, tracer) -> Dict[str, Any]:
     return summary
 
 
+_SCATTER = re.compile(r"= \(?\w+\[([\d,]*)\][^\n]*? scatter\([^\n]*?"
+                      r"op_name=\"([^\"]*)\"")
+
+
+def scatters_in(hlo_text: str, scope: str = "") -> List[Tuple[str, int]]:
+    """(`op_name`, elements of the result) of every `scatter` instruction
+    of an HLO text whose `op_name` holds `scope` (say "jit(moe_layer)").
+    On the TPU v5e a scatter-add of rows runs row by row (ops/moe.py), so
+    a layer that means to move rows by gathers checks its compiled step
+    with this. The chip's compiler may cut an `op_name` down to the
+    primitive's; the size tells a table of tile ids from an activation."""
+    return [(name, math.prod(int(n) for n in dims.split(",") if n))
+            for dims, name in _SCATTER.findall(hlo_text) if scope in name]
+
+
 def model_context(ff) -> Dict[str, Any]:
     """Graph/mesh context the raw XLA numbers need to be interpreted —
     the ONE definition shared by the trace header (FFModel._make_tracer)
@@ -312,6 +328,8 @@ def model_context(ff) -> Dict[str, Any]:
         # against the whole square's (`executor.attention_gauges`)
         **{k.split(".")[-1].replace("/", "_"): v
            for k, v in ff.executor.attention_gauges().items()},
+        # expert layers whose traced forward moved rows by gathers only
+        moe_gather_combine_ops=ff.executor.moe_gather_combine_ops(),
     )
 
 

@@ -26,9 +26,10 @@ from flexflow_tpu.ffconst import OperatorType
 from flexflow_tpu.initializers import DefaultWeightInitializer
 from flexflow_tpu.ops.base import (DimRole, Op, OpContext, register_op,
                                    scoped)
-from flexflow_tpu.ops.moe import (expert_capacity, grouped_matmul,
-                                  load_balance_loss, make_dispatch_tensors,
-                                  route_held_experts, route_scores)
+from flexflow_tpu.ops.moe import (combine_rows, expert_capacity,
+                                  grouped_matmul, load_balance_loss,
+                                  make_dispatch_tensors, route_held_experts,
+                                  route_scores, rows_from_tokens)
 
 
 @register_op(OperatorType.EXPERTS)
@@ -145,6 +146,17 @@ class MoELayer(Op):
     with `moe/slots_held` and `moe/load_max_over_mean` beside it: they
     leave the step with the metrics and are read once an epoch).
 
+    Rows and tokens (PR 32): the routing sort is kept in both directions
+    (`route_held_experts`: `slot` row -> pair, `row_of_pair` pair -> row).
+    A row reads its token (`rows_from_tokens`) and a token reads its k
+    rows and adds them, weighted, in float32 (`combine_rows`); each has a
+    `custom_vjp` whose backward is the other direction's gather
+    (`tokens_from_rows` for the first, a row gather of dY for the
+    second), because the transpose autodiff picks for a gather is a
+    scatter-add, which this chip runs row by row at 0.43 us a row: two of
+    them took 63 of a 226 ms step (ops/moe.py). Both calls run under the
+    nested scope `moe_combine`.
+
     Weights: w_router [D, n_experts], e_bias [n_experts] (b, the
     score-correction bias, sigmoid scoring only: it enters the choice
     only, so its gradient is exactly zero and no optimizer moves it;
@@ -182,6 +194,8 @@ class MoELayer(Op):
         self.kernel_init = (p.get("kernel_initializer")
                             or DefaultWeightInitializer())
         self._counters = None
+        # set when a forward has been traced (`moe_gather_combine_ops`)
+        self._gather_combine = False
         super().__init__(layer, input_shapes)
 
     def compute_output_shapes(self):
@@ -234,7 +248,7 @@ class MoELayer(Op):
         b, s, d = x.shape
         rows = self.buffer_rows
 
-        def route(params, xt, xr):
+        def route(params, xr):
             logits = jnp.dot(xr.astype(jnp.float32),
                              params["w_router"].astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
@@ -246,12 +260,11 @@ class MoELayer(Op):
             weights, experts = route_scores(
                 logits, bias, self.k, self.norm_topk, self.routed_scaling,
                 self.scoring)
-            r = route_held_experts(experts, self.experts_held,
-                                   self.expert_offset, rows)
-            token = r["slot"] // self.k
-            w_row = jnp.where(r["valid"], weights.reshape(-1)[r["slot"]],
-                              0.0)
-            return xt[token].astype(cd), token, w_row, r
+            return weights, route_held_experts(
+                experts, self.experts_held, self.expert_offset, rows)
+
+        def dispatch(xt, r):
+            return rows_from_tokens(xt, r).astype(cd)
 
         def experts_held(params, x_buf, group_sizes):
             h = grouped_matmul(x_buf, params["w_up"].astype(cd), group_sizes)
@@ -274,18 +287,20 @@ class MoELayer(Op):
 
         def layer(params, x, x_router):
             xt = x.reshape(b * s, d)
-            x_buf, token, w_row, r = scoped("moe_route", route)(
-                params, xt, x_router.reshape(b * s, d))
+            weights, r = scoped("moe_route", route)(
+                params, x_router.reshape(b * s, d))
+            x_buf = scoped("moe_combine", dispatch)(xt, r)
             o = scoped("moe_grouped_matmul", experts_held)(
                 params, x_buf, r["group_sizes"])
-            # rows past the groups are zero, and their weight is
-            o = o.astype(jnp.float32) * w_row[:, None]
-            y = jnp.zeros((b * s, d), jnp.float32).at[token].add(o)
+            y = scoped("moe_combine", combine_rows)(o, weights, r)
             if self.shared_width:
                 y = y + scoped("moe_shared", shared)(params, xt)
             return y.reshape(b, s, d).astype(x.dtype), r["load"], \
                 r["overflow"]
 
+        # for `executor.moe_gather_combine_ops`: rows went out to and came
+        # back from the experts by gathers (this is trace time)
+        self._gather_combine = True
         y, load, overflow = scoped("moe_layer", layer)(params, x, x_router)
         load = load.astype(jnp.float32)
         self._counters = {

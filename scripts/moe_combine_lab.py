@@ -1,0 +1,259 @@
+#!/usr/bin/env python3
+"""The hand look behind how `MoELayer` brings rows back to tokens (PR 32).
+
+On the chip, at the shapes of the two cells that run `MoELayer`, each
+piece alone, 20 calls after a warm-up, wall time a call:
+
+- what the layer did until PR 32, kept HERE as the yardstick: the combine
+  as a scatter-add of the buffer's rows, and the dispatch gather whose
+  automatic backward is the same scatter-add;
+- what ships (`flexflow_tpu/ops/moe.py`): `route_held_experts` with the
+  inverse map, `tokens_from_rows` (k row gathers and one multiply-add),
+  `combine_rows` and `rows_from_tokens` forward and backward;
+- forms that were tried against it: one gather of all T*k rows and a
+  reduction over k; the inverse map from a cumulative count in place of
+  the second sort; a form whose cost follows the buffer's rows (rows into
+  token order, then megablox `tgmm` with tiles of 128 tokens as groups).
+
+Prints one JSON line a shape and writes them to
+`chiprun_out/moe_combine_lab.json`. With `--deviceless` nothing runs: each
+piece is compiled for a described v5e and the line holds the compiler's
+temporary bytes and the number of scatters in the optimized HLO. Nothing
+here is a benchmark metric.
+
+    python scripts/moe_combine_lab.py [--deviceless]
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# tokens, k, experts, held, width, buffer rows (`MoELayer.buffer_rows`)
+SHAPES = {
+    "smallthinker_21b_a3b.s16384_b1": dict(T=16384, k=6, E=64, held=8,
+                                           d=2560, rows=18560),
+    "nemotron3_nano_30b_a3b.s8192_b1": dict(T=8192, k=6, E=128, held=8,
+                                            d=2688, rows=4736),
+}
+
+
+def pieces(s):
+    """name -> (function, argument names): every piece takes arrays only."""
+    import jax
+    import jax.numpy as jnp
+    from flexflow_tpu.ops import moe
+
+    T, k, held, rows = s["T"], s["k"], s["held"], s["rows"]
+    f32 = jnp.float32
+
+    def route(experts):
+        return moe.route_held_experts(experts, held, 0, rows)
+
+    def total(x):
+        return jnp.sum(x.astype(f32))
+
+    # --- until PR 32 -----------------------------------------------------
+    def sort_only_old(experts):
+        flat = experts.reshape(-1)
+        key = jnp.where(flat < held, flat, held)
+        return jnp.argsort(key, stable=True).astype(jnp.int32)[:rows]
+
+    def scatter_combine(o, w_row, token):
+        return jnp.zeros((T, o.shape[1]), f32).at[token].add(
+            o.astype(f32) * w_row[:, None])
+
+    def scatter_combine_bwd(o, w_row, token):
+        return jax.grad(lambda o, w: total(scatter_combine(o, w, token)),
+                        (0, 1))(o, w_row)
+
+    def gather_dispatch_bwd_scatter(x, token):
+        return jax.grad(lambda x: total(x[token]))(x)
+
+    # --- what ships ------------------------------------------------------
+    def route_with_inverse(experts):
+        r = route(experts)
+        return r["slot"], r["row_of_pair"], r["pair_valid"]
+
+    def tokens_from_rows_weighted(o, weights, experts):
+        return moe.tokens_from_rows(o, route(experts), weights, f32)
+
+    def tokens_from_rows_plain(o, experts):
+        return moe.tokens_from_rows(o, route(experts))
+
+    def combine_fwd_bwd(o, weights, experts):
+        r = route(experts)
+        return jax.value_and_grad(
+            lambda o, w: total(moe.combine_rows(o, w, r)), (0, 1))(o, weights)
+
+    def dispatch_fwd(x, experts):
+        return moe.rows_from_tokens(x, route(experts))
+
+    def dispatch_fwd_bwd(x, experts):
+        r = route(experts)
+        return jax.value_and_grad(
+            lambda x: total(moe.rows_from_tokens(x, r)))(x)
+
+    # --- tried against it ------------------------------------------------
+    def one_gather_of_all_pairs(o, weights, experts):
+        r = route(experts)
+        w = jnp.where(r["pair_valid"], weights, 0.0)
+        return jnp.sum(o[r["row_of_pair"]].astype(f32) * w[..., None], axis=1)
+
+    def inverse_by_count(experts):
+        flat = experts.reshape(-1)
+        key = jnp.where(flat < held, flat, held)
+        hot = jax.nn.one_hot(key, held + 1, dtype=jnp.int32)
+        load = jnp.sum(hot, axis=0)
+        start = jnp.cumsum(load) - load
+        before = jnp.cumsum(hot, axis=0) - hot
+        return jnp.sum(hot * (before + start), axis=1).reshape(experts.shape)
+
+    def by_tile_product(o, weights, experts, tile=128):
+        """Cost that follows the buffer's rows, not T*k: the valid pairs
+        in token order (a stable sort on `not valid`), their rows by one
+        `rows`-long gather, then every tile of 128 tokens adds its run of
+        rows by a one-hot product on the MXU: megablox `tgmm` with the
+        TILES as groups. A weighted row goes in as three bf16 pieces of
+        its float32 product, so that the sum is float32's."""
+        r = route(experts)
+        valid = r["pair_valid"].reshape(-1)
+        pair = jnp.arange(T * k, dtype=jnp.int32)
+        _, in_token_order = jax.lax.sort((~valid, pair), num_keys=1,
+                                         is_stable=True)
+        in_token_order = in_token_order[:rows]
+        live = jnp.arange(rows) < jnp.sum(valid)
+        row = jnp.where(live, r["row_of_pair"].reshape(-1)[in_token_order], 0)
+        hot = ((in_token_order // k % tile)[None, :]
+               == jnp.arange(tile)[:, None]) & live[None, :]
+        sizes = jnp.sum(valid.reshape(T // tile, tile * k), axis=1,
+                        dtype=jnp.int32)
+        picked = o[row]
+        pieces = 1
+        if weights is not None:
+            exact = picked.astype(f32) * weights.reshape(-1)[
+                in_token_order][:, None]
+            parts = []
+            for _ in range(3):
+                parts.append(exact.astype(jnp.bfloat16))
+                exact = exact - parts[-1].astype(f32)
+            pieces = 3
+            picked = jnp.stack(parts, axis=1).reshape(3 * rows, -1)
+            hot = jnp.repeat(hot, 3, axis=1)
+        out = moe._megablox().tgmm(
+            hot.astype(jnp.bfloat16), picked, sizes * pieces, f32,
+            moe._gmm_tiling(pieces * rows, tile, picked.shape[1]),
+            num_actual_groups=T // tile)
+        return out.reshape(T, -1)
+
+    def by_tile_product_plain(o, experts):
+        return by_tile_product(o, None, experts).astype(o.dtype)
+
+    return {
+        "old.sort_only": (sort_only_old, ("experts",)),
+        "old.scatter_combine_fwd": (scatter_combine, ("o", "w_row", "token")),
+        "old.scatter_combine_bwd": (scatter_combine_bwd,
+                                    ("o", "w_row", "token")),
+        "old.dispatch_bwd_scatter": (gather_dispatch_bwd_scatter,
+                                     ("x", "token")),
+        "route_with_inverse": (route_with_inverse, ("experts",)),
+        "route+tokens_from_rows_weighted": (tokens_from_rows_weighted,
+                                            ("o", "weights", "experts")),
+        "route+tokens_from_rows_plain": (tokens_from_rows_plain,
+                                         ("o", "experts")),
+        "route+combine_fwd_bwd": (combine_fwd_bwd,
+                                  ("o", "weights", "experts")),
+        "route+dispatch_fwd": (dispatch_fwd, ("x", "experts")),
+        "route+dispatch_fwd_bwd": (dispatch_fwd_bwd, ("x", "experts")),
+        "tried.route+one_gather_of_all_pairs": (
+            one_gather_of_all_pairs, ("o", "weights", "experts")),
+        "tried.inverse_by_count": (inverse_by_count, ("experts",)),
+        "tried.route+by_tile_product_weighted": (
+            by_tile_product, ("o", "weights", "experts")),
+        "tried.route+by_tile_product_plain": (
+            by_tile_product_plain, ("o", "experts")),
+    }
+
+
+def make_arguments(s):
+    """Distinct experts a token, uniform: the held ones get their share."""
+    import jax
+    import jax.numpy as jnp
+    from flexflow_tpu.ops import moe
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    _, experts = jax.lax.top_k(
+        jax.random.uniform(ks[0], (s["T"], s["E"])), s["k"])
+    experts = experts.astype(jnp.int32)
+    r = moe.route_held_experts(experts, s["held"], 0, s["rows"])
+    weights = jax.random.uniform(ks[1], (s["T"], s["k"]))
+    return dict(
+        x=jax.random.normal(ks[2], (s["T"], s["d"]), jnp.bfloat16),
+        o=jax.random.normal(ks[3], (s["rows"], s["d"]), jnp.bfloat16),
+        weights=weights, experts=experts, token=r["slot"] // s["k"],
+        w_row=jnp.where(r["valid"], weights.reshape(-1)[r["slot"]], 0.0),
+        overflow=r["overflow"])
+
+
+def timed_ms(fn, args, n=20):
+    import jax
+    jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t) / n * 1e3
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--deviceless", action="store_true")
+    opts = ap.parse_args()
+    if opts.deviceless:
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from flexflow_tpu.obs.inspect import scatters_in
+
+    if opts.deviceless:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+        chip = SingleDeviceSharding(topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices[0])
+    elif jax.devices()[0].platform != "tpu":
+        sys.exit("moe_combine_lab: no TPU here (try --deviceless)")
+
+    out = {}
+    for cell, s in SHAPES.items():
+        line = dict(cell=cell, device=("deviceless v5e" if opts.deviceless
+                                       else jax.devices()[0].device_kind))
+        if opts.deviceless:
+            arrays = jax.eval_shape(lambda: make_arguments(s))
+        else:
+            arrays = make_arguments(s)
+            assert int(arrays["overflow"]) == 0
+        for name, (fn, names) in pieces(s).items():
+            if opts.deviceless:
+                compiled = jax.jit(fn).lower(*(
+                    jax.ShapeDtypeStruct(arrays[n].shape, arrays[n].dtype,
+                                         sharding=chip)
+                    for n in names)).compile()
+                line[name] = dict(
+                    temp_mb=round(
+                        compiled.memory_analysis().temp_size_in_bytes / 1e6),
+                    scatters=len(scatters_in(compiled.as_text())))
+            else:
+                line[name + "_ms"] = round(timed_ms(
+                    jax.jit(fn), [arrays[n] for n in names]), 3)
+        print(json.dumps(line), flush=True)
+        out[cell] = line
+    if not opts.deviceless:
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/moe_combine_lab.json", "w") as f:
+            json.dump(out, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()

@@ -198,6 +198,187 @@ def test_routing_sorts_held_pairs_and_counts_what_does_not_fit():
     assert int(small["overflow"]) == 2
 
 
+def hand_routing():
+    """Token 0 holds no pair here, token 1 all three, token 3 one."""
+    return jnp.asarray([[0, 9, 3], [4, 5, 7], [7, 6, 2], [2, 3, 4]],
+                       jnp.int32)
+
+
+def random_routing(tokens, k, n_experts, seed):
+    """k distinct experts a token, as `top_k` gives them."""
+    rs = np.random.RandomState(seed)
+    return jnp.asarray(np.stack([rs.permutation(n_experts)[:k]
+                                 for _ in range(tokens)]), jnp.int32)
+
+
+# name -> (experts [T, k], held, offset, rows)
+ROUTINGS = {
+    "by_hand": (hand_routing(), 4, 4, 8),
+    "by_hand_buffer_too_small": (hand_routing(), 4, 4, 3),
+    "random_an_eighth_held": (random_routing(96, 6, 64, 0), 8, 16, 128),
+    "random_buffer_too_small": (random_routing(96, 6, 64, 1), 8, 0, 40),
+    "all_experts_held": (random_routing(40, 3, 16, 2), 16, 0, 128),
+    "buffer_past_the_pairs": (random_routing(8, 2, 4, 3), 2, 1, 128),
+}
+
+
+@pytest.mark.parametrize("name", list(ROUTINGS))
+def test_row_of_pair_is_the_inverse_of_slot(name):
+    """A valid row's pair points back at the row; the pairs with a row
+    are the held ones below the cut, and the others are counted."""
+    experts, held, offset, rows = ROUTINGS[name]
+    r = jax.tree.map(np.asarray,
+                     moe.route_held_experts(experts, held, offset, rows))
+    flat = np.asarray(experts).reshape(-1)
+    here = (flat >= offset) & (flat < offset + held)
+    n_rows = int(r["valid"].sum())
+    assert n_rows == min(int(here.sum()), rows) == r["group_sizes"].sum()
+    assert int(r["overflow"]) == int(here.sum()) - n_rows
+    assert ("too_small" in name) == (int(r["overflow"]) > 0)
+    row_of_pair = r["row_of_pair"].reshape(-1)
+    pair_valid = r["pair_valid"].reshape(-1)
+    assert r["row_of_pair"].shape == r["pair_valid"].shape == experts.shape
+    assert int(pair_valid.sum()) == n_rows and not pair_valid[~here].any()
+    held_slots = r["slot"][:n_rows]
+    assert pair_valid[held_slots].all()
+    assert row_of_pair[held_slots].tolist() == list(range(n_rows))
+    assert not row_of_pair[~pair_valid].any()
+    # rows are sorted by expert, and within an expert by pair
+    assert (np.diff(flat[held_slots]) >= 0).all()
+    if name == "by_hand":
+        assert r["pair_valid"].sum(axis=1).tolist() == [0, 3, 2, 1]
+    if name == "all_experts_held":
+        assert pair_valid.all() and n_rows == flat.size
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "unweighted"])
+@pytest.mark.parametrize("name", list(ROUTINGS))
+def test_tokens_from_rows_is_the_scatter_add_of_the_rows(name, weighted):
+    """`tokens_from_rows` against the form it replaced, `.at[token].add`
+    of the valid rows, to float32 rounding."""
+    experts, held, offset, rows = ROUTINGS[name]
+    tokens, k = experts.shape
+    r = moe.route_held_experts(experts, held, offset, rows)
+    rs = np.random.RandomState(7)
+    buf = jnp.asarray(rs.randn(rows, 20), jnp.float32)
+    weights = jnp.asarray(rs.rand(tokens, k), jnp.float32)
+    w_row = jnp.where(r["valid"],
+                      weights.reshape(-1)[r["slot"]] if weighted else 1.0,
+                      0.0)
+    want = jnp.zeros((tokens, 20), jnp.float32).at[r["slot"] // k].add(
+        buf * w_row[:, None])
+    got = moe.tokens_from_rows(buf, r, weights if weighted else None)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert got.dtype == buf.dtype
+    half = moe.tokens_from_rows(buf.astype(jnp.bfloat16), r, None,
+                                jnp.float32)
+    assert half.dtype == jnp.float32      # rounded once, to what is asked
+
+
+def scatter_add_layer(op, params, inputs):
+    """`MoELayer.forward` as it was until PR 32, kept here as the
+    reference of the gradients: rows go out by `xt[token]`, come back by
+    `.at[token].add`, and autodiff transposes both."""
+    x, x_router = inputs[0], inputs[-1]
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    scores = jnp.dot(x_router.reshape(b * s, d), params["w_router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    bias = None
+    if op.scoring == "sigmoid":
+        scores, bias = jax.nn.sigmoid(scores), params["e_bias"]
+    choose = scores if bias is None else scores + bias
+    _, experts = jax.lax.top_k(choose, op.k)
+    top = jnp.take_along_axis(scores, experts, axis=-1)
+    if op.scoring == "softmax":
+        weights = jax.nn.softmax(top, axis=-1)
+    else:
+        weights = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    weights = weights * op.routed_scaling
+    r = moe.route_held_experts(experts.astype(jnp.int32), op.experts_held,
+                               op.expert_offset, op.buffer_rows)
+    token = r["slot"] // op.k
+    w_row = jnp.where(r["valid"], weights.reshape(-1)[r["slot"]], 0.0)
+    x_buf = xt[token]
+    h = moe.grouped_matmul(x_buf, params["w_up"], r["group_sizes"])
+    if op.gated:
+        h = jax.nn.relu(moe.grouped_matmul(x_buf, params["w_gate"],
+                                           r["group_sizes"])) * h
+    else:
+        h = jnp.square(jax.nn.relu(h))
+    o = moe.grouped_matmul(h, params["w_down"], r["group_sizes"])
+    y = jnp.zeros((b * s, d), jnp.float32).at[token].add(o * w_row[:, None])
+    if op.shared_width:
+        y = y + jnp.square(jax.nn.relu(xt @ params["ws_up"])) \
+            @ params["ws_down"]
+    return y.reshape(b, s, d)
+
+
+# what the two models' layers state, and a buffer that overflows (512
+# tokens: a buffer is at least 128 rows); name -> (properties, inputs,
+# tokens a sample)
+LAYERS = {
+    "softmax_gated_second_router_input": (dict(
+        n_experts=16, k=3, hidden_size=24, scoring="softmax", gated=True,
+        experts_held=4, expert_offset=8, slot_slack=15.0), 2, 24),
+    "sigmoid_bias_shared_expert": (dict(
+        n_experts=16, k=3, hidden_size=24, shared_width=48,
+        routed_scaling=2.5, experts_held=4, expert_offset=4,
+        slot_slack=15.0), 1, 24),
+    "all_held": (dict(n_experts=8, k=2, hidden_size=24, shared_width=16),
+                 1, 24),
+    "buffer_too_small": (dict(
+        n_experts=16, k=3, hidden_size=24, scoring="softmax", gated=True,
+        experts_held=8, slot_slack=-0.5), 2, 256),
+}
+
+
+@pytest.mark.parametrize("name", list(LAYERS))
+def test_layer_gradients_match_the_scatter_add_form(name):
+    """Output and `jax.grad` of a whole `MoELayer`, every leaf and every
+    input, against the scatter-add form above."""
+    props, n_inputs, seq = LAYERS[name]
+    rs = np.random.RandomState(11)
+    inputs = [jnp.asarray(rs.randn(2, seq, 32), jnp.float32)
+              for _ in range(n_inputs)]
+    probe = jnp.asarray(rs.randn(2, seq, 32), jnp.float32)
+    layer = Layer(OperatorType.MOE_LAYER, "op", [])
+    layer.properties.update(props)
+    op = OpRegistry.create(layer, [x.shape for x in inputs])
+    params = op.init_params(jax.random.PRNGKey(4))
+    if "e_bias" in params:
+        params["e_bias"] = jnp.asarray(0.1 * rs.randn(props["n_experts"]),
+                                       jnp.float32)
+    ctx = OpContext(training=True, compute_dtype=jnp.float32)
+
+    def program(params, inputs):
+        return op.forward(params, inputs, ctx)[0]
+
+    def loss(layer_fn):
+        return lambda p, xs: jnp.sum(layer_fn(p, xs) * probe)
+
+    with HIGHEST:
+        np.testing.assert_allclose(
+            program(params, inputs), scatter_add_layer(op, params, inputs),
+            rtol=1e-5, atol=1e-5)
+        got = jax.grad(loss(program), argnums=(0, 1))(params, inputs)
+        want = jax.grad(loss(lambda p, xs: scatter_add_layer(op, p, xs)),
+                        argnums=(0, 1))(params, inputs)
+    overflow = float(op._counters["moe/overflow_slots"][1])
+    assert (overflow > 0) == (name == "buffer_too_small")
+    flat_got, tree = jax.tree.flatten(got)
+    flat_want, tree_want = jax.tree.flatten(want)
+    assert tree == tree_want and len(flat_got) == len(params) + n_inputs
+    for path, a, b in zip(jax.tree_util.tree_leaves_with_path(got),
+                          flat_got, flat_want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5,
+                                   err_msg=str(path[0]))
+    assert any(float(jnp.abs(g).max()) > 0 for g in got[1])
+    if "e_bias" in params:   # enters the choice only
+        assert not np.asarray(got[0]["e_bias"]).any()
+
+
 # ---------------------------------------------------------------------------
 # the share tests: a chip's share of a layer, summed over the chips,
 # is the uncut layer
@@ -403,3 +584,20 @@ def test_scopes_reach_the_compiled_steps_op_names(model):
                   "jit(moe_route)", "jit(moe_grouped_matmul)",
                   "jit(moe_shared)"):
         assert scope in names, scope
+
+
+def test_the_compiled_step_moves_expert_rows_by_gathers_only(model):
+    """No `scatter` under `jit(moe_layer)` in the compiled train step
+    (rows go out and come back by gathers, forward and backward), and the
+    `moe_combine` scope is in its `op_name`s, in both directions."""
+    from flexflow_tpu.obs.inspect import scatters_in
+    ff = model[0]
+    (ids,), labels = family.make_data(TINY, 0)
+    text = ff.executor.make_train_step().lower(
+        ff.params, ff.opt_state, ff.state, ff._stage_inputs([ids]),
+        ff._shard_batch(labels), jax.random.PRNGKey(0)).compile().as_text()
+    assert scatters_in(text, "jit(moe_layer)") == []
+    assert scatters_in(text)          # the embedding's backward is one
+    for scope in ("/jvp(jit(moe_layer))/jit(moe_combine)/",
+                  "/transpose(jvp(jit(moe_layer)))/jit(moe_combine)/"):
+        assert scope in text, scope

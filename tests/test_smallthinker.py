@@ -370,6 +370,28 @@ def test_scopes_reach_the_compiled_steps_op_names(model, monkeypatch):
                    for n in scopes.values()), scope
 
 
+def test_the_compiled_step_moves_expert_rows_by_gathers_only(
+        model, monkeypatch):
+    """No `scatter` under `jit(moe_layer)` in the compiled train step but
+    the megablox kernels' own tile tables (a few int32 of group and tile
+    ids under `jit(gmm)` / `jit(tgmm)`, here in interpret mode as on the
+    chip), and the `moe_combine` scope in its `op_name`s both ways."""
+    from flexflow_tpu.obs.inspect import scatters_in
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    ff = model[0]
+    (ids,), labels = family.make_data(TINY, 0)
+    text = ff.executor.make_train_step().lower(
+        ff.params, ff.opt_state, ff.state, ff._stage_inputs([ids]),
+        ff._shard_batch(labels), jax.random.PRNGKey(0)).compile().as_text()
+    under = scatters_in(text, "jit(moe_layer)")
+    assert under and all(("/jit(gmm)/" in name or "/jit(tgmm)/" in name)
+                         and size < 16 for name, size in under), under
+    for scope in ("/jvp(jit(moe_layer))/jit(moe_combine)/",
+                  "/transpose(jvp(jit(moe_layer)))/jit(moe_combine)/"):
+        assert scope in text, scope
+    assert model[0].op_counters["executor.moe_gather_combine_ops"] == 4
+
+
 # ---------------------------------------------------------------------------
 # the search
 

@@ -248,6 +248,55 @@ class TestHybridDecoderKernels:
         # two products forward, two for the rows, two for the weights
         assert pallas_kernel_count(hlo) == 6
 
+    @pytest.mark.parametrize("tokens,width,props", [
+        pytest.param(8192, 2688, dict(
+            n_experts=128, k=6, hidden_size=1856, shared_width=3712,
+            routed_scaling=2.5, experts_held=8), id="nemotron"),
+        pytest.param(16384, 2560, dict(
+            n_experts=64, k=6, hidden_size=768, scoring="softmax",
+            gated=True, experts_held=8), id="smallthinker"),
+    ])
+    def test_expert_layer_moves_rows_by_gathers_at_the_cells_widths(
+            self, topo, on_tpu, tokens, width, props):
+        """A whole `MoELayer`, forward and backward, as the two cells run
+        it: no scatter in the chip's program but the megablox kernels' own
+        tile tables, and no float32 [tokens, k, width] among the
+        temporaries (the combine adds slot by slot)."""
+        from flexflow_tpu.ffconst import OperatorType
+        from flexflow_tpu.layer import Layer
+        from flexflow_tpu.obs.inspect import scatters_in
+        from flexflow_tpu.ops.base import OpContext, OpRegistry
+        one = SingleDeviceSharding(topo.devices[0])
+        layer = Layer(OperatorType.MOE_LAYER, "experts", [])
+        layer.properties.update(props)
+        shapes = [(1, tokens, width)] * (2 if props.get("gated") else 1)
+        op = OpRegistry.create(layer, shapes)
+        ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
+
+        def abstract(a):
+            full = a.ndim < 3 and a.shape[-1] == props["n_experts"]
+            return jax.ShapeDtypeStruct(
+                a.shape, jnp.float32 if full else jnp.bfloat16, sharding=one)
+
+        params = jax.tree.map(abstract, jax.eval_shape(
+            op.init_params, jax.random.PRNGKey(0)))
+        inputs = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one)
+                  for s in shapes]
+
+        def loss(params, inputs):
+            return op.forward(params, inputs, ctx)[0].astype(
+                jnp.float32).sum()
+
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, inputs).compile()
+        # (the chip's compiler cuts some of their names to `scatter-add`:
+        # a table has a tile's or a group's entry, an activation a row's)
+        scatters = scatters_in(compiled.as_text())
+        assert scatters and all(size < 256 for _, size in scatters), scatters
+        assert "jit(moe_combine)" in compiled.as_text()
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < 4 * tokens * props["k"] * width)
+
     def test_chunked_scan_at_the_cells_widths(self, topo):
         from flexflow_tpu.ops.ssm import ssd_chunked
         one = SingleDeviceSharding(topo.devices[0])

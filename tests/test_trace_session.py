@@ -347,6 +347,49 @@ def test_flash_lane_dense_ops_counts_the_models_flash_ops(
     assert gauges["executor.flash_lane_dense_ops"] == flash
 
 
+@pytest.mark.parametrize("moe_layers", [2, 1, 0])
+def test_moe_gather_combine_ops_counts_the_models_expert_layers(
+        moe_layers, tmp_path, no_open_session):
+    """`executor.moe_gather_combine_ops` (PR 32): `MoELayer` ops whose
+    traced forward moved rows to the experts and back by gathers. 0 until
+    the step is traced; then the model's `MoELayer` count in the header
+    of a session, the registry's snapshot and `FFModel.op_counters`
+    (which exist where an op counts something: a model with such a
+    layer)."""
+    import numpy as np
+    from flexflow_tpu import (FFConfig, FFModel, LossType, MetricsType,
+                              SGDOptimizer)
+    from flexflow_tpu.ffconst import OperatorType
+
+    b, s, e = 2, 16, 32
+    ff = FFModel(FFConfig(batch_size=b))
+    t = ff.create_tensor((b, s, e))
+    for i in range(moe_layers):
+        t = ff.moe_layer(t, 8, 2, 16, experts_held=4, slot_slack=7.0,
+                         scoring="softmax" if i else "sigmoid",
+                         gated=bool(i), name=f"experts{i}")
+    ff.dense(t, 1)
+    ff.compile(SGDOptimizer(lr=0.01), LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
+               [MetricsType.MEAN_SQUARED_ERROR])
+    assert sum(n.op.op_type == OperatorType.MOE_LAYER
+               for n in ff.executor.nodes) == moe_layers
+    assert obs.model_context(ff)["moe_gather_combine_ops"] == 0  # not traced
+    rs = np.random.RandomState(0)
+    x = rs.randn(2 * b, s, e).astype(np.float32)
+    y = rs.randn(2 * b, s, 1).astype(np.float32)
+    ff.fit(x, y, epochs=1, verbose=False)   # traces and compiles the step
+    obs.start_trace(str(tmp_path), device=False)
+    ff.fit(x, y, epochs=1, verbose=False)
+    paths = obs.stop_trace()
+    header, _ = read_events(paths["events"])
+    assert header["moe_gather_combine_ops"] == moe_layers
+    gauges = json.load(open(paths["counters"]))["gauges"]
+    assert gauges["executor.moe_gather_combine_ops"] == moe_layers
+    if moe_layers:
+        assert ff.op_counters["executor.moe_gather_combine_ops"] == moe_layers
+        assert ff.op_counters["moe/overflow_slots"] == 0
+
+
 @pytest.mark.parametrize("seq,window", [(2048, 512), (2048, 0), (128, 32)])
 def test_window_attention_gauges_show_that_the_skip_engaged(
         seq, window, tmp_path, monkeypatch, no_open_session):
