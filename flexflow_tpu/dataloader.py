@@ -21,6 +21,37 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
+def block_diffusion_batch(x0, block_length: int, mask_id: int, rng,
+                          t_min: float = 1e-3):
+    """Samples x0 [n, L] int -> (ids [n, 2L] int32, labels [n, L, 2]
+    float32) of the block-diffusion objective, on the host.
+
+    One noise level t a block of ``block_length`` tokens, uniform on
+    [t_min, 1]; every token of the block becomes ``mask_id`` with
+    probability t, independently. ``ids`` lays the noised copy before
+    the clean one. ``labels[..., 0]`` is the clean token and
+    ``labels[..., 1]`` its weight, 1/t of its block where the token was
+    masked and 0 elsewhere: what
+    ``LossType.WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY`` takes. ``rng``
+    is a ``numpy.random.Generator`` the caller seeds."""
+    x0 = np.asarray(x0)
+    n, length = x0.shape
+    if length % block_length:
+        raise ValueError(f"block_diffusion_batch: {length} tokens are not "
+                         f"whole blocks of {block_length}")
+    if max(int(x0.max(initial=0)), mask_id) >= 1 << 24:
+        raise ValueError("block_diffusion_batch: ids past 2^24 do not "
+                         "survive the labels' float32")
+    t = rng.uniform(t_min, 1.0, size=(n, length // block_length))
+    t = np.repeat(t, block_length, axis=1)
+    masked = rng.random((n, length)) < t
+    ids = np.concatenate([np.where(masked, mask_id, x0), x0], axis=1)
+    labels = np.stack([x0.astype(np.float32),
+                       np.where(masked, 1.0 / t, 0.0).astype(np.float32)],
+                      axis=-1)
+    return ids.astype(np.int32), labels
+
+
 class SingleDataLoader:
     """One input (or label) tensor's loader.
 

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flexflow_tpu.ffconst import CompMode, LossType, OperatorType
-from flexflow_tpu.losses import get_loss_fn
+from flexflow_tpu.losses import get_loss_fn, target_positions
 from flexflow_tpu.metrics import Metrics
 from flexflow_tpu.obs.registry import get_registry
 from flexflow_tpu.ops.base import Op, OpContext
@@ -643,7 +643,8 @@ class GraphExecutor:
     def attention_gauges(self) -> Dict[str, int]:
         """What the attention ops' forwards, as last traced, recorded on
         the host (PR 31): the ops whose window hides something at their
-        sequence length, and the [Q block, K chunk] tiles a head's flash
+        sequence length, those under the block-diffusion mask (PR 34),
+        and the [Q block, K chunk] tiles a head's flash
         forward works through against those of the whole square, added
         up over the ops that ran flash (`kv_blocks`; 0 / 0 until one has
         been traced). Published as gauges when the train step is traced,
@@ -653,6 +654,9 @@ class GraphExecutor:
         return {
             "executor.window_attention_ops": sum(
                 bool(getattr(n.op, "windowed", False)) for n in self.nodes),
+            "executor.block_diffusion_attention_ops": sum(
+                bool(getattr(n.op, "block_diffusion", None))
+                for n in self.nodes),
             "attention/kv_blocks_visited": sum(b[0] for b in blocks),
             "attention/kv_blocks_total": sum(b[1] for b in blocks)}
 
@@ -715,6 +719,12 @@ class GraphExecutor:
                     loss = self._loss_value(logits, labels)
                     for a in aux:
                         loss = loss + a
+                    if (self.loss_type ==
+                            LossType.WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY):
+                        # leaves the step with the ops' counters: the
+                        # positions that carried a target
+                        counters["loss/target_positions"] = target_positions(
+                            labels)
                 return loss, (logits, new_state, counters)
 
             (loss, (logits, new_state, counters)), grads = jax.value_and_grad(

@@ -59,6 +59,9 @@ class LossType(enum.Enum):
     MEAN_SQUARED_ERROR_AVG_REDUCE = 12
     MEAN_SQUARED_ERROR_SUM_REDUCE = 13
     IDENTITY = 14
+    # token-level cross-entropy whose labels carry a weight a position:
+    # labels [B, S, 2] float32, (token id, weight)
+    WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY = 15
 
 
 class MetricsType(enum.Enum):

@@ -35,6 +35,24 @@ def sparse_categorical_crossentropy(logits, labels):
     return -jnp.mean(jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32), axis=-1))
 
 
+def weighted_sparse_categorical_crossentropy(logits, labels):
+    """[B, S, V] logits with labels [B, S, 2] float32 that carry, a
+    position, the target's id and its weight c: sum(c * ce) / (B * S), the
+    mean over ALL positions of the weighted cross-entropy (a masked
+    diffusion objective: c = 1/t where the token was masked, else 0). A
+    position of weight 0 gets exactly zero gradient."""
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    ids = labels[..., 0].astype(jnp.int32)
+    tok = jnp.take_along_axis(logp, ids[..., None], axis=-1)[..., 0]
+    return -jnp.mean(labels[..., 1].astype(jnp.float32) * tok)
+
+
+def target_positions(labels):
+    """Positions of weighted labels [B, S, 2] whose weight is not zero
+    (the op counter `loss/target_positions`)."""
+    return jnp.sum(labels[..., 1] > 0).astype(jnp.float32)
+
+
 def mse_avg(preds, labels):
     return jnp.mean((preds.astype(jnp.float32) - labels.astype(jnp.float32)) ** 2)
 
@@ -54,6 +72,8 @@ def identity(preds, labels):
 LOSS_FNS = {
     LossType.CATEGORICAL_CROSSENTROPY: categorical_crossentropy,
     LossType.SPARSE_CATEGORICAL_CROSSENTROPY: sparse_categorical_crossentropy,
+    LossType.WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY:
+        weighted_sparse_categorical_crossentropy,
     LossType.MEAN_SQUARED_ERROR_AVG_REDUCE: mse_avg,
     LossType.MEAN_SQUARED_ERROR_SUM_REDUCE: mse_sum,
     LossType.IDENTITY: identity,

@@ -203,6 +203,8 @@ class FFModel:
                             kernel_initializer=None,
                             seq_parallel: Optional[str] = None,
                             head_dim: int = 0, window: int = 0,
+                            block_diffusion=None, rope_wrap: int = 0,
+                            qk_norm: bool = False, qk_norm_eps: float = 1e-6,
                             name: Optional[str] = None) -> Tensor:
         """``seq_parallel='seq'`` runs the attention core as ring attention
         over that mesh axis (context parallelism for long sequences).
@@ -212,7 +214,14 @@ class FFModel:
         ``window`` (with ``causal``): a query sees its last ``window``
         keys, itself among them, and no key at distance ``window`` or
         more; the blocked flash kernels skip the blocks that leaves
-        empty."""
+        empty. ``block_diffusion=(L, B)`` (not with ``causal``): the
+        sequence is a noised copy of an L-token sample and then the clean
+        one, in blocks of B; a noised block sees itself and the clean
+        blocks before it, a clean block the clean ones up to itself.
+        ``rope_wrap``: rotary positions repeat with this period (both
+        copies at positions 0..L-1). ``qk_norm``: RMS norm of every query
+        and key head over ``head_dim``, with a learned scale each, ahead
+        of the rotary embedding."""
         layer = self._add_layer(OperatorType.MULTIHEAD_ATTENTION,
                                 [query, key, value], dict(
             embed_dim=embed_dim, num_heads=num_heads, kdim=kdim or embed_dim,
@@ -222,7 +231,12 @@ class FFModel:
             rope_theta=rope_theta,
             kernel_initializer=kernel_initializer, seq_parallel=seq_parallel,
             **({"head_dim": head_dim} if head_dim else {}),
-            **({"window": window} if window else {})), name)
+            **({"window": window} if window else {}),
+            **({"block_diffusion": tuple(block_diffusion)}
+               if block_diffusion else {}),
+            **({"rope_wrap": rope_wrap} if rope_wrap else {}),
+            **({"qk_norm": True, "qk_norm_eps": qk_norm_eps}
+               if qk_norm else {})), name)
         return self._finish(layer)
 
     def ssm_mixer(self, input: Tensor, num_heads: int, head_dim: int,
@@ -249,6 +263,7 @@ class FFModel:
                   slot_slack: float = 0.5, kernel_initializer=None,
                   scoring: str = "sigmoid", gated: bool = False,
                   router_input: Optional[Tensor] = None,
+                  activation: str = "relu",
                   name: Optional[str] = None) -> Tensor:
         """Dropless mixture-of-experts layer over [B, S, D] with top-k
         routing over all ``n_experts``, computing the part of the
@@ -256,10 +271,12 @@ class FFModel:
         default) and a shared expert (ops/experts.py ``MoELayer``).
         ``scoring``: "sigmoid" scores with a score-correction bias, or
         "softmax" over the chosen logits; ``gated``: experts of three
-        matrices, down(relu(gate(x)) * up(x)); ``router_input``: a second
-        tensor of the input's shape that the router reads instead."""
-        extra = {k_: v for k_, v in (("scoring", scoring), ("gated", gated))
-                 if v not in ("sigmoid", False)}
+        matrices, down(act(gate(x)) * up(x)) with ``activation`` "relu"
+        or "silu"; ``router_input``: a second tensor of the input's shape
+        that the router reads instead."""
+        extra = {k_: v for k_, v in (("scoring", scoring), ("gated", gated),
+                                     ("activation", activation))
+                 if v not in ("sigmoid", False, "relu")}
         layer = self._add_layer(
             OperatorType.MOE_LAYER,
             [input] + ([router_input] if router_input is not None else []),

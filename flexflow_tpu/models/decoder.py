@@ -20,10 +20,22 @@ family.
         experts down(relu(gate(x)) * up(x)), no shared expert
     W   the same with a sliding window of ``sliding_window_size`` keys
         and rotary embedding (``rope_theta``) over the whole head
+    D   the block of a block-diffusion model: attention, then experts,
+        each behind its own norm and residual. The sequence is a noised
+        copy of a sample and then the clean one (``seq_length`` = 2L);
+        attention under the block-diffusion mask of ``block_length``
+        (``attention_mask``), rotary positions that repeat after L, an
+        RMS norm of every query and key head (``qk_norm``); the router
+        reads the POST-attention norm, softmax over all experts with the
+        chosen renormalised, experts down(act(gate(x)) * up(x)) of width
+        ``moe_intermediate_size`` with act ``hidden_act``, no shared one
 
 After the last block ``rms_norm`` and the head, ``logits = x W_head``
 (untied); train with ``SPARSE_CATEGORICAL_CROSSENTROPY`` on labels
-``[B, S]``.
+``[B, S]``. A pattern with ``D`` keeps the noised half alone from there
+on (logits ``[B, L, V]``) and trains with
+``WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY`` on labels ``[B, L, 2]``
+(``dataloader.block_diffusion_batch`` makes both).
 
 A chip's share of a layer is a configuration like any other: fewer heads
 (``mamba_num_heads`` with ``n_groups``, ``num_attention_heads`` with
@@ -56,6 +68,13 @@ class DecoderConfig:
     head_dim: int = 0                       # 0: hidden_size // heads
     rope_theta: float = 10000.0             # `L`, `W`
     sliding_window_size: int = 0            # `W`
+    # `D`: the mask ("block_diffusion", or "causal" over the 2L
+    # positions), its block, and whether the two copies share positions
+    attention_mask: str = "block_diffusion"
+    block_length: int = 4
+    shared_positions: bool = True
+    qk_norm: bool = True
+    hidden_act: str = "silu"
     # Mamba-2 mixer (`M`)
     mamba_num_heads: int = 4
     mamba_head_dim: int = 16
@@ -111,6 +130,35 @@ def _attention_experts_block(ff, t, i, cfg, windowed):
     return ff.add(t, m, name=f"b{i}_res2")
 
 
+def _block_diffusion_block(ff, t, i, cfg):
+    """`D`: x' = x + attention(norm(x)), x'' = x' + experts(norm(x'))."""
+    eps = cfg.layer_norm_epsilon
+    if cfg.attention_mask not in ("block_diffusion", "causal"):
+        raise ValueError(f"decoder: unknown attention_mask "
+                         f"{cfg.attention_mask!r}")
+    half = cfg.seq_length // 2
+    masked = cfg.attention_mask == "block_diffusion"
+    h = ff.rms_norm(t, eps=eps, name=f"b{i}_norm")
+    a = ff.multihead_attention(
+        h, h, h, cfg.hidden_size, cfg.num_attention_heads, bias=False,
+        causal=not masked, num_kv_heads=cfg.num_key_value_heads, rope=True,
+        rope_theta=cfg.rope_theta, head_dim=cfg.head_dim,
+        block_diffusion=(half, cfg.block_length) if masked else None,
+        rope_wrap=half if cfg.shared_positions else 0,
+        qk_norm=cfg.qk_norm, qk_norm_eps=eps, name=f"b{i}_attn")
+    t = ff.add(t, a, name=f"b{i}_res1")
+    g = ff.rms_norm(t, eps=eps, name=f"b{i}_post_norm")
+    m = ff.moe_layer(
+        g, cfg.n_routed_experts, cfg.num_experts_per_tok,
+        cfg.moe_intermediate_size, experts_held=cfg.experts_held,
+        expert_offset=cfg.expert_offset,
+        routed_scaling=cfg.routed_scaling_factor,
+        norm_topk=cfg.norm_topk_prob, slot_slack=cfg.slot_slack,
+        scoring="softmax", gated=True, activation=cfg.hidden_act,
+        name=f"b{i}_mixer")
+    return ff.add(t, m, name=f"b{i}_res2")
+
+
 def _llama_block(ff, t, i, cfg):
     h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"l{i}_input_ln")
     t = ff.add(t, _attention(ff, h, cfg, f"l{i}_attn", rope=True),
@@ -155,7 +203,7 @@ def _mixer(ff, h, letter, i, cfg):
         return ff.dense(ff.multiply(up, up, name=f"b{i}_sq"),
                         cfg.hidden_size, use_bias=False, name=name)
     raise ValueError(f"decoder pattern: unknown block letter {letter!r} "
-                     f"(known: M E * - L G W)")
+                     f"(known: M E * - L G W D)")
 
 
 def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
@@ -171,8 +219,15 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
         if letter in "GW":
             t = _attention_experts_block(ff, t, i, cfg, letter == "W")
             continue
+        if letter == "D":
+            t = _block_diffusion_block(ff, t, i, cfg)
+            continue
         h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"b{i}_norm")
         t = ff.add(t, _mixer(ff, h, letter, i, cfg), name=f"b{i}_res")
+    if "D" in cfg.hybrid_override_pattern:
+        # the head and the loss read the noised half alone
+        half = cfg.seq_length // 2
+        t = ff.split(t, [half, half], axis=1, name="noised_half")[0]
     t = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name="final_ln")
     t = ff.dense(t, cfg.vocab_size, use_bias=False, name="lm_head")
     return ff

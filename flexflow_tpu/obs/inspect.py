@@ -324,12 +324,17 @@ def model_context(ff) -> Dict[str, Any]:
         # their [B, S, heads*head_dim] operand form (0 until a step or a
         # forward has been traced)
         flash_lane_dense_ops=ff.executor.flash_lane_dense_ops(),
-        # windowed attention ops, and the flash forwards' K blocks visited
-        # against the whole square's (`executor.attention_gauges`)
+        # windowed attention ops, those under the block-diffusion mask,
+        # and the flash forwards' K blocks visited against the whole
+        # square's (`executor.attention_gauges`)
         **{k.split(".")[-1].replace("/", "_"): v
            for k, v in ff.executor.attention_gauges().items()},
         # expert layers whose traced forward moved rows by gathers only
         moe_gather_combine_ops=ff.executor.moe_gather_combine_ops(),
+        # positions that carried a target in the last epoch of a weighted
+        # loss (None before one, or under another loss)
+        loss_target_positions=(getattr(ff, "op_counters", None) or {}).get(
+            "loss/target_positions"),
     )
 
 

@@ -28,16 +28,20 @@ from flexflow_tpu.ops.base import (DimRole, Op, OpContext, register_op,
 
 
 def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
-                     seq_axis: int = 2):
+                     seq_axis: int = 2, wrap: int = 0):
     """Apply RoPE to [B, H, S, D], or with ``seq_axis=1`` to [B, S, H, D]
     (HF Llama rotate-half convention): positions offset..offset+S-1,
     inv_freq = theta^(-2i/D). ``position_offset`` (static or traced
     scalar) is the absolute position of the first row — the
     incremental-decode path rotates the new token at its true position,
-    not at 0."""
+    not at 0. ``wrap``: positions repeat with this period (row i stands
+    at position (offset + i) mod wrap), for a sequence that holds several
+    copies of one sample side by side."""
     s, d = x.shape[seq_axis], x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     pos = position_offset + jnp.arange(s, dtype=jnp.float32)
+    if wrap:
+        pos = pos % wrap
     angles = pos[:, None] * inv_freq[None, :]
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)  # [S, D]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
@@ -51,10 +55,12 @@ def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
 
 def scaled_dot_product_attention(q, k, v, *, causal=False, dropout_rate=0.0,
                                  rng=None, compute_dtype=jnp.float32,
-                                 window=0):
+                                 window=0, block_diffusion=None):
     """q,k,v: [B, H, S, D] -> [B, H, S, D]. Softmax in f32 for stability.
     ``window``: under ``causal``, a query sees only its last ``window``
-    keys (the rule is the flash kernels': ``pallas_kernels.visible``)."""
+    keys; ``block_diffusion`` (L, B): the three-part block mask over a
+    noised and a clean copy (the rule is the flash kernels':
+    ``pallas_kernels.visible``)."""
     d = q.shape[-1]
     scores = jnp.einsum(
         "bhqd,bhkd->bhqk",
@@ -62,12 +68,12 @@ def scaled_dot_product_attention(q, k, v, *, causal=False, dropout_rate=0.0,
         k.astype(compute_dtype),
         preferred_element_type=jnp.float32,
     ) / jnp.sqrt(jnp.float32(d))
-    if causal:
+    if causal or block_diffusion:
         from flexflow_tpu.ops.pallas_kernels import visible
 
         s_q, s_k = scores.shape[-2], scores.shape[-1]
         mask = visible(jnp.arange(s_q)[:, None] + (s_k - s_q),
-                       jnp.arange(s_k)[None, :], window)
+                       jnp.arange(s_k)[None, :], window, block_diffusion)
         scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1)
     if dropout_rate > 0.0 and rng is not None:
@@ -111,6 +117,17 @@ class MultiHeadAttention(Op):
         if self.window and not self.causal:
             raise ValueError(f"attention '{layer.name}': a sliding window "
                              f"needs causal attention")
+        # block-diffusion mask (L, B): the sequence is a noised copy of
+        # an L-token sample and then the clean one, in blocks of B; a
+        # noised block sees itself and the clean blocks before it, a
+        # clean block the clean ones up to itself (pallas_kernels.visible)
+        self.block_diffusion = tuple(p.get("block_diffusion") or ()) or None
+        if self.block_diffusion:
+            from flexflow_tpu.ops.pallas_kernels import (
+                checked_block_diffusion)
+
+            checked_block_diffusion(input_shapes[0][1], self.causal,
+                                    self.window, self.block_diffusion)
         self.use_bias = p.get("bias", True)
         # grouped-query attention (Llama-family): kv heads may be fewer
         # than query heads; kv repeat to H before the core
@@ -122,6 +139,12 @@ class MultiHeadAttention(Op):
         # rotary position embeddings applied to q/k after projection
         self.rope = p.get("rope", False)
         self.rope_theta = p.get("rope_theta", 10000.0)
+        # rotary positions repeat with this period (0: they do not)
+        self.rope_wrap = p.get("rope_wrap", 0) or 0
+        # RMS norm of every query and key head over head_dim, ahead of
+        # the rotary embedding, with a learned scale each
+        self.qk_norm = p.get("qk_norm", False)
+        self.qk_norm_eps = p.get("qk_norm_eps", 1e-6)
         # separate q/k/v projection biases (torch nn.MultiheadAttention
         # parity — in_proj_bias). Off by default: they cost an extra
         # elementwise pass over q/k/v every step and native models
@@ -169,6 +192,9 @@ class MultiHeadAttention(Op):
             "wv": self.kernel_init(ks[2], (hk, self.vdim, d)),
             "wo": self.kernel_init(ks[3], (h, d, e)),
         }
+        if self.qk_norm:
+            params["q_norm"] = jnp.ones((d,))
+            params["k_norm"] = jnp.ones((d,))
         if self.use_bias:
             params["bo"] = jnp.zeros((e,))
             if self.qkv_bias:
@@ -194,15 +220,42 @@ class MultiHeadAttention(Op):
             y = y + bias.reshape(h * d)
         return y
 
+    def _heads_normed(self, x, heads, scale):
+        """RMS norm over head_dim of every head of x [B, S, heads*D],
+        float32, which the projection left it in."""
+        b, s, _ = x.shape
+        xh = x.reshape(b, s, heads, self.head_dim)
+        rms = jax.lax.rsqrt(jnp.mean(xh * xh, axis=-1, keepdims=True)
+                            + self.qk_norm_eps)
+        return (xh * rms * scale.astype(jnp.float32)).reshape(x.shape)
+
     @property
     def windowed(self) -> bool:
         """The window hides something at this sequence length."""
         return 0 < self.window < self.input_shapes[0][1]
 
+    @property
+    def visible_pairs(self) -> int:
+        """(query, key) pairs of one sequence and head that the mask
+        leaves, counted exactly."""
+        sq = self.input_shapes[0][1]
+        sk = self.input_shapes[1][1] if len(self.input_shapes) > 1 else sq
+        if self.block_diffusion:
+            length, b = self.block_diffusion
+            n = length // b
+            # noised-noised n B^2, noised-clean and clean-clean B^2 a
+            # pair of blocks: n(n-1)/2 and n(n+1)/2 of those
+            return b * b * (n + n * n)
+        w = min(sk, self.window) if self.window else sk
+        return sq * w
+
     def forward(self, params, inputs, ctx: OpContext):
         # the op's rng is split off here: inside the nested call below it
         # would leave a tracer of that call in `ctx`
         rng = ctx.next_rng() if (self.dropout > 0 and ctx.training) else None
+        if self.block_diffusion:
+            return self._scoped_forward(params, inputs, ctx, rng,
+                                        "block_diffusion")
         if not self.causal:
             return self._forward(params, inputs, ctx, rng, None)
         # the ops under the causal visibility rule carry a scope for the
@@ -212,8 +265,13 @@ class MultiHeadAttention(Op):
         # after the first scope inside the nested call it came from, so a
         # causal op's kernel events read `flash_window.N` / `flash_full.N`
         # (as the grouped products' read `gmm.N`); the kernel's own name
-        # stays in `op_name`. Non-causal ops run unscoped, as they did.
+        # stays in `op_name`. Under the block-diffusion mask the scopes
+        # are `attention_block_diffusion` / `flash_block_diffusion`.
+        # Other non-causal ops run unscoped, as they did.
         kind = "window" if self.windowed else "full"
+        return self._scoped_forward(params, inputs, ctx, rng, kind)
+
+    def _scoped_forward(self, params, inputs, ctx, rng, kind):
         return scoped("attention_" + kind,
                       lambda params, inputs: self._forward(
                           params, inputs, ctx, rng, "flash_" + kind))(
@@ -233,11 +291,16 @@ class MultiHeadAttention(Op):
         k = self._project(key, params["wk"], params["bk"] if biased else None, cd)
         v = self._project(value, params["wv"], params["bv"] if biased else None, cd)
         b, sq, sk = q.shape[0], q.shape[1], k.shape[1]
+        if self.qk_norm:
+            q = self._heads_normed(q, h, params["q_norm"])
+            k = self._heads_normed(k, hk, params["k_norm"])
         if self.rope:
             q = rotary_embedding(q.reshape(b, sq, h, d), theta=self.rope_theta,
-                                 seq_axis=1).reshape(b, sq, h * d)
+                                 seq_axis=1, wrap=self.rope_wrap
+                                 ).reshape(b, sq, h * d)
             k = rotary_embedding(k.reshape(b, sk, hk, d), theta=self.rope_theta,
-                                 seq_axis=1).reshape(b, sk, hk * d)
+                                 seq_axis=1, wrap=self.rope_wrap
+                                 ).reshape(b, sk, hk * d)
         if hk != h:
             k, v = (jnp.repeat(x.reshape(b, sk, hk, d), h // hk, axis=2
                                ).reshape(b, sk, h * d) for x in (k, v))
@@ -257,11 +320,11 @@ class MultiHeadAttention(Op):
         mesh_axes = (dict(zip(ctx.mesh.axis_names, ctx.mesh.devices.shape))
                      if ctx.mesh is not None else {})
         if seq_axis and mesh_axes.get(seq_axis, 1) > 1 and sq == sk:
-            if self.windowed:
+            if self.windowed or self.block_diffusion:
                 raise NotImplementedError(
                     f"attention '{self.name}': ring attention has no "
-                    f"sliding window (each block of the ring would need "
-                    f"its own offset into it)")
+                    f"sliding window and no block-diffusion mask (each "
+                    f"block of the ring would need its own offset into it)")
             if dropout_rate > 0.0 and not getattr(self, "_warned_dropout", False):
                 import warnings
 
@@ -294,12 +357,14 @@ class MultiHeadAttention(Op):
             if available:
                 # for `executor.flash_lane_dense_ops`
                 self._flash_lane_dense = True
-                self._kv_blocks = kv_blocks(sq, self.causal, self.window)
+                self._kv_blocks = kv_blocks(sq, self.causal, self.window,
+                                            self.block_diffusion)
 
                 def flash(kernel, **where):
                     call = functools.partial(
                         kernel, num_heads=h, causal=self.causal,
-                        window=self.window, **where)
+                        window=self.window,
+                        block_diffusion=self.block_diffusion, **where)
                     return (scoped(flash_scope, call) if flash_scope
                             else call)(q, k, v)
 
@@ -333,7 +398,8 @@ class MultiHeadAttention(Op):
             else:
                 o = heads_first(lambda q, k, v: scaled_dot_product_attention(
                     q, k, v, causal=self.causal, dropout_rate=0.0,
-                    rng=None, compute_dtype=cd, window=self.window))
+                    rng=None, compute_dtype=cd, window=self.window,
+                    block_diffusion=self.block_diffusion))
         else:
             if self.kernel_impl == "flash" and self._kernel_fallback is None:
                 # forced flash but this forward cannot take the flash
@@ -346,7 +412,8 @@ class MultiHeadAttention(Op):
                     f"Sk={sk}) — einsum executed instead")
             o = heads_first(lambda q, k, v: scaled_dot_product_attention(
                 q, k, v, causal=self.causal, dropout_rate=dropout_rate,
-                rng=rng, compute_dtype=cd, window=self.window))
+                rng=rng, compute_dtype=cd, window=self.window,
+                block_diffusion=self.block_diffusion))
         y = jnp.dot(o.astype(cd), params["wo"].astype(cd).reshape(h * d, -1),
                     preferred_element_type=jnp.float32)
         if self.use_bias:
@@ -394,6 +461,16 @@ class MultiHeadAttention(Op):
         (a bidirectional row would need future K/V that doesn't exist
         yet); non-causal ops refuse rather than silently drift.
         """
+        if self.block_diffusion:
+            raise NotImplementedError(
+                f"attention '{self.name}': KV-cache incremental decode has "
+                f"no block-diffusion mask (a block is generated over "
+                f"several denoising passes, not a token a step)")
+        if self.qk_norm or self.rope_wrap:
+            raise NotImplementedError(
+                f"attention '{self.name}': KV-cache incremental decode "
+                f"applies no query/key norm and no wrapped positions; the "
+                f"cached path would drift from the training forward")
         if not self.causal:
             raise NotImplementedError(
                 f"attention '{self.name}': KV-cache incremental decode "
@@ -488,15 +565,17 @@ class MultiHeadAttention(Op):
                 + 2 * b * hk * d * (sk * self.kdim + sk * self.vdim))
         # under a window a query meets at most `window` keys: S x W
         # products, not S^2 (a plain causal layer is priced at the whole
-        # square, as it always was)
-        core = 2 * b * h * sq * (min(sk, self.window) if self.window
-                                 else sk) * d * 2
+        # square, as it always was); under the block-diffusion mask the
+        # pairs it leaves, counted exactly
+        core = 2 * b * h * self.visible_pairs * d * 2
         return proj + core
 
     def params_elems(self):
         h, e, d = self.num_heads, self.embed_dim, self.head_dim
         hk = self.num_kv_heads
         n = h * d * (e + e) + hk * d * (self.kdim + self.vdim)
+        if self.qk_norm:
+            n += 2 * d
         if self.use_bias:
             n += e + ((h + 2 * hk) * d if self.qkv_bias else 0)
         return n

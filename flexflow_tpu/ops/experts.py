@@ -112,6 +112,10 @@ def squared_relu(x):
     return jnp.square(jax.nn.relu(x))
 
 
+# what the gate's product goes through in the gated form
+GATE_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+
+
 @register_op(OperatorType.MOE_LAYER)
 class MoELayer(Op):
     """A mixture-of-experts feed-forward layer over the experts HELD here:
@@ -125,8 +129,10 @@ class MoELayer(Op):
 
     Three things a model may state otherwise (PR 31), each a property:
     `scoring` "softmax": the k largest LOGITS x W_r are chosen and w is
-    the softmax over them (no bias leaf); `gated`: expert_j(x) =
-    (relu(x G_j) * (x U_j)) D_j with a third leaf `w_gate`; a second
+    the softmax over them (no bias leaf; with `norm_topk` that is the
+    softmax over all `n_experts` renormalised over the chosen); `gated`:
+    expert_j(x) = (act(x G_j) * (x U_j)) D_j with a third leaf `w_gate`,
+    and `activation` names act: "relu" or (PR 34) "silu"; a second
     input [B, S, D] that the ROUTER reads in place of x (a model that
     routes from the pre-attention norm, so that the experts' choice is
     known while attention runs): the experts still transform the first.
@@ -183,9 +189,16 @@ class MoELayer(Op):
         self.slot_slack = p.get("slot_slack", 0.5)
         self.scoring = p.get("scoring", "sigmoid")
         self.gated = p.get("gated", False)
+        self.activation = p.get("activation", "relu")
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"moe_layer '{layer.name}': unknown scoring "
                              f"{self.scoring!r}")
+        if self.activation not in GATE_ACTIVATIONS or (
+                self.activation != "relu" and not self.gated):
+            raise ValueError(
+                f"moe_layer '{layer.name}': activation {self.activation!r} "
+                f"(the gated form knows {sorted(GATE_ACTIVATIONS)}; the "
+                f"ungated form is relu squared)")
         if self.expert_offset + self.experts_held > self.n_experts:
             raise ValueError(
                 f"moe_layer '{layer.name}': experts {self.expert_offset}.."
@@ -271,7 +284,8 @@ class MoELayer(Op):
             if self.gated:
                 g = grouped_matmul(x_buf, params["w_gate"].astype(cd),
                                    group_sizes)
-                h = (jax.nn.relu(g.astype(jnp.float32))
+                h = (GATE_ACTIVATIONS[self.activation](
+                    g.astype(jnp.float32))
                      * h.astype(jnp.float32)).astype(cd)
             else:
                 h = squared_relu(h.astype(jnp.float32)).astype(cd)

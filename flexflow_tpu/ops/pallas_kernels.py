@@ -22,6 +22,13 @@ forward loops over the K chunks, the backward over the Q chunks, that
 hold a visible pair (``_k_chunks``, ``_q_chunks``), so a causal layer
 does half the square's work and a window layer S x window of it.
 ``window >= S`` is ``causal``, bit for bit (``normalized_window``).
+A third kind (PR 34) is not an interval of keys: ``block_diffusion =
+(L, B)`` over a sequence of 2L positions that holds a noised copy of a
+sample and then the clean one, in blocks of B. A noised block sees itself
+and the clean blocks before it, a clean block the clean blocks up to
+itself: a tile's visible K chunks (forward) and Q chunks (backward) are
+then TWO ranges (``_k_ranges``, ``_q_ranges``), and the blocked kernels
+loop over both.
 
 One operand form (PR 30): q, k, v, o and their gradients are
 [B, S, H*D], the heads side by side along the lanes, which is what the
@@ -167,7 +174,7 @@ def _for_rows(rows: int, unroll: int, body) -> None:
     jax.lax.fori_loop(0, rows // unroll, step, None)
 
 
-def _seq_block(s: int) -> int:
+def _seq_block(s: int, block_diffusion=None) -> int:
     """Rows of a block along the sequence in the blocked kernels (the K
     chunks the forward's loop takes, the K rows a grid step and the Q
     chunks a loop step of the backward): the largest of 1024, 512, 256,
@@ -182,17 +189,29 @@ def _seq_block(s: int) -> int:
     0.75 / 1.27, 0.73 / 1.21 (parent 1.08 / 2.03); 16 heads of 64 at
     S = 8192, not causal, where nothing is skipped and the chunks' running
     softmax is all cost: 8.55 / 9.16, 4.59 / 6.18, 4.14 / 5.41 (parent,
-    one pass over a [128, S] tile: 3.85 / 5.30)."""
+    one pass over a [128, S] tile: 3.85 / 5.30).
+
+    Under ``block_diffusion`` (L, B) the block divides L, so that none
+    lies across the halves, and is 512 at most: a quarter of the square
+    is visible, along two diagonals, and the tiles on them are visited
+    whole; at S = 16384 blocks of 1024 visit 0.3125 of the square's
+    tiles, blocks of 512 0.281."""
+    if block_diffusion is not None:
+        return next(b for b in (512, 256, BLK_Q)
+                    if block_diffusion[0] % b == 0)
     return next(b for b in (1024, 512, 256, BLK_Q) if s % b == 0)
 
 
-def _q_block(s: int) -> int:
+def _q_block(s: int, block_diffusion=None) -> int:
     """Q rows a grid step of the blocked forward takes: 256 where that
     divides S, else the 128 every admitted S is a multiple of. v5e as
     above, K chunks of 512, forward ms at 128 / 256 / 512 rows: window
     3.18 / 2.38 / 2.30, full causal 6.24 / 4.40 / 4.22, S = 8192 causal
     1.00 / 0.75 / 0.74: a chunk's fixed cost (the loop, the rescaling of
-    the running sums) is paid half as often."""
+    the running sums) is paid half as often. Under ``block_diffusion``
+    the block divides L."""
+    if block_diffusion is not None:
+        s = block_diffusion[0]
     return 2 * BLK_Q if s % (2 * BLK_Q) == 0 else BLK_Q
 
 
@@ -204,20 +223,60 @@ def _q_block(s: int) -> int:
 _MASKED = -1e30
 
 
-def visible(qq, kk, window: int):
-    """THE visibility rule of causal attention, for the four kernels, the
-    einsum core and whoever counts pairs: query ``qq`` sees key ``kk`` iff
-    kk <= qq and, with a ``window``, qq - kk < window (the key at distance
-    exactly ``window`` is masked). ``window`` 0: no window."""
-    v = kk <= qq
-    if window:
-        v = v & (qq - kk < window)
-    return v
+def visible(qq, kk, window: int, block_diffusion=None):
+    """THE visibility rule, for the four kernels, the einsum core and
+    whoever counts pairs. Under ``causal``: query ``qq`` sees key ``kk``
+    iff kk <= qq and, with a ``window``, qq - kk < window (the key at
+    distance exactly ``window`` is masked). ``window`` 0: no window.
+
+    ``block_diffusion`` (L, B): the sequence is 2L positions, a noised
+    copy of a sample at 0..L-1 and the clean copy at L..2L-1, in blocks
+    of B. With half(i) = i // L and blk(i) = (i mod L) // B,
+        noised query, noised key:  blk(k) == blk(q)
+        noised query, clean key:   blk(k) <  blk(q)
+        clean query,  clean key:   blk(k) <= blk(q)
+        clean query,  noised key:  never.
+    Written as two compares of the pair: a query carries the last clean
+    block it sees (``q_clean``) and the noised block it sees
+    (``q_noised``, none for a clean query), a key its block on the side
+    it is on and a value no query matches on the other; what is taken
+    per query and per key alone stays as small as the caller's ``qq`` and
+    ``kk`` are."""
+    if block_diffusion is None:
+        v = kk <= qq
+        if window:
+            v = v & (qq - kk < window)
+        return v
+    length, b = block_diffusion
+    q_is_clean, k_is_clean = qq >= length, kk >= length
+    qb = (qq - jnp.where(q_is_clean, length, 0)) // b
+    kb = (kk - jnp.where(k_is_clean, length, 0)) // b
+    q_clean = jnp.where(q_is_clean, qb, qb - 1)
+    q_noised = jnp.where(q_is_clean, -1, qb)
+    k_clean = jnp.where(k_is_clean, kb, 2 * length)
+    k_noised = jnp.where(k_is_clean, -2, kb)
+    return (k_clean <= q_clean) | (k_noised == q_noised)
 
 
-def _mask(st, k0, q0, window: int):
+def _tile_visible(q0, k0, shape, q_axis: int, window: int, block_diffusion):
+    """``visible`` over a score tile of ``shape`` whose queries run along
+    ``q_axis`` from ``q0`` and whose keys along the other axis from
+    ``k0``. The positions are a column and a row, so that what
+    ``visible`` takes per query and per key is not tile-sized work."""
+    k_axis = 1 - q_axis
+    q_shape = tuple(n if a == q_axis else 1 for a, n in enumerate(shape))
+    k_shape = tuple(n if a == k_axis else 1 for a, n in enumerate(shape))
+    qq = q0 + jax.lax.broadcasted_iota(jnp.int32, q_shape, q_axis)
+    kk = k0 + jax.lax.broadcasted_iota(jnp.int32, k_shape, k_axis)
+    return visible(qq, kk, window, block_diffusion)
+
+
+def _mask(st, k0, q0, window: int, block_diffusion=None):
     """_MASKED where the query may not see the key, in a [k, q] tile
     whose first row is key ``k0`` and first column query ``q0``."""
+    if block_diffusion is not None:
+        return jnp.where(_tile_visible(q0, k0, st.shape, 1, window,
+                                       block_diffusion), st, _MASKED)
     kk = k0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
     qq = q0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
     return jnp.where(visible(qq, kk, window), st, _MASKED)
@@ -227,6 +286,13 @@ def _pick(of_ints, of_traced, a, b):
     """``kv_blocks`` counts with Python ints, also while a step is being
     traced, where a ``jnp`` call on them would be staged into the trace."""
     return (of_ints if isinstance(a, int) else of_traced)(a, b)
+
+
+def _either(cond, a, b):
+    """``a`` where ``cond`` else ``b``, for a Python bool (``kv_blocks``
+    counts with ints) or a traced one (a kernel's block start)."""
+    return (a if cond else b) if isinstance(cond, bool) else jnp.where(
+        cond, a, b)
 
 
 def _k_chunks(q0, blk_q: int, blk_k: int, s: int, causal: bool,
@@ -252,6 +318,51 @@ def _q_chunks(k0, blk_k: int, blk_q: int, s: int, causal: bool,
     return lo, hi
 
 
+def _k_ranges(q0, blk_q: int, blk_k: int, s: int, causal: bool, window: int,
+              block_diffusion=None):
+    """The ranges [lo, hi) of K chunks the forward's loops run over for
+    the query block at ``q0``: ``_k_chunks``' one under ``causal`` and a
+    window; two under ``block_diffusion`` (first the noised keys of the
+    block's own diagonal, empty for a clean block; then the clean keys
+    from L on). Both block sizes then divide L (``_seq_block``,
+    ``_q_block`` are taken of L), so no block lies across the halves."""
+    if block_diffusion is None:
+        return (_k_chunks(q0, blk_q, blk_k, s, causal, window),)
+    length, b = block_diffusion
+    noised = q0 < length
+    # the block's first and last query as positions of their half
+    first = q0 - _either(noised, 0, length)
+    last = first + blk_q - 1
+    lo1 = first // b * b // blk_k
+    hi1 = _either(noised, ((last // b + 1) * b - 1) // blk_k + 1, lo1)
+    # clean keys seen: the blocks before the last query's, and for a
+    # clean query its own too
+    clean = _either(noised, last // b * b, (last // b + 1) * b)
+    lo2 = length // blk_k
+    return (lo1, hi1), (lo2, lo2 + (clean + blk_k - 1) // blk_k)
+
+
+def _q_ranges(k0, blk_k: int, blk_q: int, s: int, causal: bool, window: int,
+              block_diffusion=None):
+    """The ranges [lo, hi) of Q chunks the backward's loops run over for
+    the key block at ``k0``, the transpose of ``_k_ranges``. Under
+    ``block_diffusion`` a noised key block is seen by the noised queries
+    of its own blocks alone; a clean one by the noised queries of the
+    blocks after its first and by the clean queries from its first
+    block on."""
+    if block_diffusion is None:
+        return (_q_chunks(k0, blk_k, blk_q, s, causal, window),)
+    length, b = block_diffusion
+    noised = k0 < length
+    first = k0 - _either(noised, 0, length)
+    last = first + blk_k - 1
+    lo1 = _either(noised, first // b * b, (first // b + 1) * b) // blk_q
+    hi1 = _either(noised, ((last // b + 1) * b - 1) // blk_q + 1,
+                  length // blk_q)
+    lo2 = (length + first // b * b) // blk_q
+    return (lo1, hi1), (_either(noised, s // blk_q, lo2), s // blk_q)
+
+
 def normalized_window(s: int, causal: bool, window: int) -> int:
     """0 where the window hides nothing (none given, or >= S): those run
     the very code ``causal`` alone runs."""
@@ -260,7 +371,23 @@ def normalized_window(s: int, causal: bool, window: int) -> int:
     return window if 0 < window < s else 0
 
 
-def kv_blocks(s: int, causal: bool, window: int = 0):
+def checked_block_diffusion(s: int, causal: bool, window: int,
+                            block_diffusion):
+    """``block_diffusion`` as the (L, B) tuple the kernels take, or None;
+    refuses one that does not describe the sequence."""
+    if not block_diffusion:
+        return None
+    length, b = (int(n) for n in block_diffusion)
+    if causal or window:
+        raise ValueError("the block-diffusion mask is a visibility rule "
+                         "of its own: not with causal or a window")
+    if s != 2 * length or b <= 0 or length % b:
+        raise ValueError(f"block-diffusion mask (L={length}, B={b}) over "
+                         f"{s} positions: needs 2L positions and B | L")
+    return length, b
+
+
+def kv_blocks(s: int, causal: bool, window: int = 0, block_diffusion=None):
     """(visited, total) tiles of [a Q block, a K chunk] that the
     forward of one head works through at sequence ``s``: what the
     gauges ``attention/kv_blocks_visited`` / ``_total`` add up. The
@@ -268,11 +395,12 @@ def kv_blocks(s: int, causal: bool, window: int = 0):
     if s <= MAX_BWD_SEQ:
         return 1, 1
     window = normalized_window(s, causal, window)
-    blk_q, blk_k = _q_block(s), _seq_block(s)
+    bd = checked_block_diffusion(s, causal, window, block_diffusion)
+    blk_q, blk_k = _q_block(s, bd), _seq_block(s, bd)
     visited = 0
     for q0 in range(0, s, blk_q):
-        lo, hi = _k_chunks(q0, blk_q, blk_k, s, causal, window)
-        visited += hi - lo
+        visited += sum(hi - lo for lo, hi in _k_ranges(
+            q0, blk_q, blk_k, s, causal, window, bd))
     return visited, (s // blk_q) * (s // blk_k)
 
 
@@ -302,34 +430,40 @@ def _stack_heads(parts):
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
                       window: int, scale: float, blk_q: int, blk_k: int,
-                      head_dim: int):
+                      head_dim: int, block_diffusion=None):
     """One (batch row, column block, q-block) grid cell: q [1,BLK_Q,W]
     against the K/V panels [1,S,W] resident in VMEM, in chunks of
     ``blk_k`` keys with a running max and sum (the online softmax), and
     ONLY the chunks that hold a key some query of the block sees
     (``_k_chunks``): under ``causal`` those up to the diagonal, under a
-    window the few behind it. Scores never touch HBM. Also emits the
+    window the few behind it, under ``block_diffusion`` the block's own
+    noised tile and then the clean chunks it sees. Scores never touch
+    HBM. Also emits the
     per-row logsumexp so the fused backward can recompute P exactly.
     The forward of sequences past MAX_BWD_SEQ."""
     q = q_ref[0]  # [BLK_Q, W]
     heads = q.shape[-1] // head_dim
     q0 = pl.program_id(2) * blk_q
-    lo, hi = _k_chunks(q0, blk_q, blk_k, k_ref.shape[1], causal, window)
+    ranges = _k_ranges(q0, blk_q, blk_k, k_ref.shape[1], causal, window,
+                       block_diffusion)
     qs = [_only_head(q, h, head_dim) for h in range(heads)]
     tile = (blk_q, blk_k)
+    masked = causal or block_diffusion is not None
 
     def chunk(c, carry):
         k0 = pl.multiple_of(c * blk_k, blk_k)
         k = k_ref[0, pl.ds(k0, blk_k), :]  # [BLK_K, W]
         v = v_ref[0, pl.ds(k0, blk_k), :]
-        if causal:
+        if block_diffusion is not None:
+            seen = _tile_visible(q0, k0, tile, 0, window, block_diffusion)
+        elif causal:
             seen = visible(
                 q0 + jax.lax.broadcasted_iota(jnp.int32, tile, 0),
                 k0 + jax.lax.broadcasted_iota(jnp.int32, tile, 1), window)
         out = []
         for h, (m, l, acc) in enumerate(carry):
             s = _dot(qs[h], k, _NT) * scale
-            if causal:
+            if masked:
                 s = jnp.where(seen, s, _MASKED)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
@@ -341,10 +475,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
             out.append((m_new, l, acc))
         return tuple(out)
 
-    carry = jax.lax.fori_loop(lo, hi, chunk, tuple(
+    carry = tuple(
         (jnp.full((blk_q, 1), _MASKED, jnp.float32),
          jnp.zeros((blk_q, 1), jnp.float32),
-         jnp.zeros(q.shape, jnp.float32)) for _ in range(heads)))
+         jnp.zeros(q.shape, jnp.float32)) for _ in range(heads))
+    for lo, hi in ranges:
+        carry = jax.lax.fori_loop(lo, hi, chunk, carry)
     o = None
     for h, (m, l, acc) in enumerate(carry):
         oh = _only_head(acc / l, h, head_dim)
@@ -355,7 +491,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
 
 def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                             causal: bool, window: int, scale: float,
-                            rows: int, head_dim: int):
+                            rows: int, head_dim: int, block_diffusion=None):
     """``rows`` batch rows a grid cell, each with the heads of one column
     block and their whole sequence: the forward up to MAX_BWD_SEQ. The
     score tile is held as [k, q]: the softmax's max and sum then run down
@@ -373,8 +509,8 @@ def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         ots = []
         for h in range(heads):
             st = _dot_head(k, q, h, head_dim) * scale   # [k, q]
-            if causal:
-                st = _mask(st, 0, 0, window)
+            if causal or block_diffusion is not None:
+                st = _mask(st, 0, 0, window, block_diffusion)
             m = jnp.max(st, axis=0, keepdims=True)   # [1, S]
             pt = jnp.exp(st - m)
             l = jnp.sum(pt, axis=0, keepdims=True)
@@ -388,7 +524,7 @@ def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
 
 def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
-               out_dtype=None, window: int = 0):
+               out_dtype=None, window: int = 0, block_diffusion=None):
     """q, k, v: [B, S, H*D] with S % BLK_Q == 0 -> (o [B, S, H*D],
     lse [B, H, 1, S]). A block is S (or BLK_Q) rows by one column block
     of the operand, picked by the BlockSpec's last index: in HBM's
@@ -399,6 +535,7 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     w = hpb * d
     scale = 1.0 / float(d) ** 0.5
     window = normalized_window(s, causal, window)
+    bd = checked_block_diffusion(s, causal, window, block_diffusion)
     # lse is (b, h, 1, s): TPU requires the last two block dims be
     # (8,128)-aligned or span the array — a singleton before the
     # sequence satisfies that while keeping one row per head
@@ -410,7 +547,7 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
         return pl.pallas_call(
             functools.partial(_flash_fwd_whole_kernel, causal=causal,
                               window=window, scale=scale, rows=rows,
-                              head_dim=d),
+                              head_dim=d, block_diffusion=bd),
             name=KERNEL_NAME_PREFIX + "flash_fwd_whole",
             out_shape=out_shape,
             grid=(b // rows, num_heads // hpb),
@@ -421,11 +558,11 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
         )(q, k, v)
-    blk = _q_block(s)
+    blk = _q_block(s, bd)
     return pl.pallas_call(
         functools.partial(_flash_fwd_kernel, causal=causal, window=window,
-                          scale=scale, blk_q=blk, blk_k=_seq_block(s),
-                          head_dim=d),
+                          scale=scale, blk_q=blk, blk_k=_seq_block(s, bd),
+                          head_dim=d, block_diffusion=bd),
         name=KERNEL_NAME_PREFIX + "flash_fwd",
         out_shape=out_shape,
         grid=(b, num_heads // hpb, s // blk),
@@ -449,8 +586,9 @@ def _flash_bwd_tile(q, k, kt, v, o, do, lse, glse, scale: float, mask,
     against q, O, dO [Bq, W] and, a head, the [1, Bq] rows lse and
     g_lse, the upstream gradient on the logsumexp output (zero when only
     o is consumed; nonzero under ring attention's streaming merge, whose
-    weights are functions of each block's lse). ``mask``: None (not
-    causal) or (first key, first query, window) of the tile. Recompute P
+    weights are functions of each block's lse). ``mask``: None (every
+    pair visible) or ``_mask``'s (first key, first query, window, block
+    diffusion) of the tile. Recompute P
     from the saved lse, then dV = P^T dO, dS = P * (dO V^T - delta +
     g_lse) with delta = rowsum(dO * O), dQ = dS K, dK = dS^T Q; returns
     them TRANSPOSED and without the softmax scale, float32 dQ^T [W, Bq],
@@ -493,7 +631,8 @@ def _flash_bwd_tile(q, k, kt, v, o, do, lse, glse, scale: float, mask,
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       glse_ref, dq_ref, dk_ref, dv_ref, *, causal: bool,
-                      window: int, scale: float, rows: int, head_dim: int):
+                      window: int, scale: float, rows: int, head_dim: int,
+                      block_diffusion=None):
     """``rows`` batch rows a grid cell, each with the heads of one column
     block and the whole sequence in VMEM (gated by MAX_BWD_SEQ).
     Scores/probabilities never touch HBM — the reason XLA's einsum
@@ -502,8 +641,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         k = k_ref[b]
         dqt, dkt, dvt = _flash_bwd_tile(
             q_ref[b], k, k.T, v_ref[b], o_ref[b], do_ref[b], lse_ref[b],
-            glse_ref[b], scale, (0, 0, window) if causal else None,
-            head_dim)
+            glse_ref[b], scale, (0, 0, window, block_diffusion)
+            if causal or block_diffusion is not None else None, head_dim)
         dq_ref[b] = (dqt * scale).T.astype(dq_ref.dtype)
         dk_ref[b] = (dkt * scale).T.astype(dk_ref.dtype)
         dv_ref[b] = dvt.T.astype(dv_ref.dtype)
@@ -514,13 +653,15 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                               glse_ref, dq_ref, dk_ref, dv_ref, *,
                               causal: bool, window: int, scale: float,
-                              blk: int, head_dim: int):
+                              blk: int, head_dim: int, block_diffusion=None):
     """FA2 backward for sequences past MAX_BWD_SEQ: grid cell = one
     (batch row, column block, K-block). The Q/O/dO panels are resident;
     the K-block meets them in chunks of ``blk`` queries, and ONLY the
     chunks that hold a query which sees one of its keys (``_q_chunks``:
     from the diagonal on, and under a window no further than the window
-    behind the block's last key); a chunk's [BLK, BLK] score tile is
+    behind the block's last key; under ``block_diffusion`` two ranges,
+    the noised queries and the clean ones that see it); a chunk's
+    [BLK, BLK] score tile is
     recomputed in VMEM. dK/dV add up over the chunks and write their
     block; dQ adds up in place, a chunk's rows at a time, across the
     K-block grid dimension (same output block revisited -> Pallas keeps
@@ -529,7 +670,9 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     k0 = j * blk
     k, v = k_ref[0], v_ref[0]
     kt = k.T
-    lo, hi = _q_chunks(k0, blk, blk, q_ref.shape[1], causal, window)
+    ranges = _q_ranges(k0, blk, blk, q_ref.shape[1], causal, window,
+                       block_diffusion)
+    masked = causal or block_diffusion is not None
 
     @pl.when(j == 0)
     def _init():
@@ -542,18 +685,21 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             q_ref[0, rows, :], k, kt, v, o_ref[0, rows, :],
             do_ref[0, rows, :], lse_ref[0, :, :, rows],
             glse_ref[0, :, :, rows], scale,
-            (k0, q0, window) if causal else None, head_dim)
+            (k0, q0, window, block_diffusion) if masked else None, head_dim)
         dq_ref[0, rows, :] += (dqt * scale).T
         return carry[0] + dkt, carry[1] + dvt
 
     zero = jnp.zeros((k.shape[1], blk), jnp.float32)
-    dkt, dvt = jax.lax.fori_loop(lo, hi, chunk, (zero, zero))
+    dkt, dvt = zero, zero
+    for lo, hi in ranges:
+        dkt, dvt = jax.lax.fori_loop(lo, hi, chunk, (dkt, dvt))
     dk_ref[0] = (dkt * scale).T.astype(dk_ref.dtype)
     dv_ref[0] = dvt.T.astype(dv_ref.dtype)
 
 
 def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
-               interpret: bool, glse=None, window: int = 0):
+               interpret: bool, glse=None, window: int = 0,
+               block_diffusion=None):
     """dq, dk, dv [B, S, H*D] from the saved (o, lse[B, H, 1, S]): one
     whole-tile step for several heads up to MAX_BWD_SEQ, K-blocked past
     it — scores stay in VMEM tiles at every length the gate admits
@@ -564,6 +710,7 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
     w = hpb * d
     scale = 1.0 / float(d) ** 0.5
     window = normalized_window(s, causal, window)
+    bd = checked_block_diffusion(s, causal, window, block_diffusion)
     # the ring's merge hands a float32 dO (its o is float32): as an MXU
     # operand it takes the stored dtype, like P and dS
     do = do.astype(q.dtype)
@@ -576,7 +723,7 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         return pl.pallas_call(
             functools.partial(_flash_bwd_kernel, causal=causal,
                               window=window, scale=scale, rows=rows,
-                              head_dim=d),
+                              head_dim=d, block_diffusion=bd),
             name=KERNEL_NAME_PREFIX + "flash_bwd",
             out_shape=(jax.ShapeDtypeStruct((b, s, hd), q.dtype),
                        jax.ShapeDtypeStruct((b, s, hd), k.dtype),
@@ -588,13 +735,14 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
         )(q, k, v, o, do, lse, glse)
-    blk = _seq_block(s)
+    blk = _seq_block(s, bd)
     seq_spec = pl.BlockSpec((1, s, w), lambda b, c, j: (b, 0, c))
     kblk_spec = pl.BlockSpec((1, blk, w), lambda b, c, j: (b, j, c))
     row_spec = pl.BlockSpec((1, hpb, 1, s), lambda b, c, j: (b, c, 0, 0))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_blocked_kernel, causal=causal,
-                          window=window, scale=scale, blk=blk, head_dim=d),
+                          window=window, scale=scale, blk=blk, head_dim=d,
+                          block_diffusion=bd),
         name=KERNEL_NAME_PREFIX + "flash_bwd_blocked",
         out_shape=(jax.ShapeDtypeStruct((b, s, hd), jnp.float32),  # dq acc
                    jax.ShapeDtypeStruct((b, s, hd), k.dtype),
@@ -609,20 +757,22 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
     return dq.astype(q.dtype), dk, dv
 
 
-def _xla_attention(q, k, v, causal: bool, window: int = 0):
+def _xla_attention(q, k, v, causal: bool, window: int = 0,
+                   block_diffusion=None):
     """Reference einsum attention the kernel tests compare against."""
-    return _xla_attention_lse(q, k, v, causal, window)[0]
+    return _xla_attention_lse(q, k, v, causal, window, block_diffusion)[0]
 
 
-def _xla_attention_lse(q, k, v, causal: bool, window: int = 0):
+def _xla_attention_lse(q, k, v, causal: bool, window: int = 0,
+                       block_diffusion=None):
     """Reference einsum path that also emits the per-row logsumexp."""
     d = q.shape[-1]
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) / jnp.sqrt(jnp.float32(d))
-    if causal:
+    if causal or block_diffusion:
         sq, sk = s.shape[-2], s.shape[-1]
         mask = visible(jnp.arange(sq)[:, None] + (sk - sq),
-                       jnp.arange(sk)[None, :], window)
+                       jnp.arange(sk)[None, :], window, block_diffusion)
         s = jnp.where(mask, s, jnp.finfo(jnp.float32).min)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
@@ -630,22 +780,25 @@ def _xla_attention_lse(q, k, v, causal: bool, window: int = 0):
     return o, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, num_heads, causal, interpret, window=0):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, num_heads, causal, interpret, window=0,
+           block_diffusion=None):
     return _flash_fwd(q, k, v, num_heads, causal, interpret,
-                      window=window)[0]
+                      window=window, block_diffusion=block_diffusion)[0]
 
 
-def _flash_vjp_fwd(q, k, v, num_heads, causal, interpret, window=0):
+def _flash_vjp_fwd(q, k, v, num_heads, causal, interpret, window=0,
+                   block_diffusion=None):
     o, lse = _flash_fwd(q, k, v, num_heads, causal, interpret,
-                        window=window)
+                        window=window, block_diffusion=block_diffusion)
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(num_heads, causal, interpret, window, res, g):
+def _flash_vjp_bwd(num_heads, causal, interpret, window, block_diffusion,
+                   res, g):
     q, k, v, o, lse = res
     return _flash_bwd(q, k, v, o, lse, g, num_heads, causal, interpret,
-                      window=window)
+                      window=window, block_diffusion=block_diffusion)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -732,7 +885,7 @@ def flash_attention_available(seq_len: int, head_dim: int,
 
 
 def flash_attention(q, k, v, num_heads: int, causal: bool = False,
-                    window: int = 0):
+                    window: int = 0, block_diffusion=None):
     """q, k, v: [B, S, H*D] -> [B, S, H*D], the heads side by side along
     the lanes as the projections' plain 2-D products leave them, so that
     no layout change sits between a projection and a kernel and no
@@ -741,12 +894,12 @@ def flash_attention(q, k, v, num_heads: int, causal: bool = False,
     Who holds [B, H, S, D] converts with ``merge_heads`` /
     ``split_heads`` at its own boundary."""
     return _flash(q, k, v, num_heads, causal, pallas_mode() == "interpret",
-                  window)
+                  window, tuple(block_diffusion) if block_diffusion else None)
 
 
 def flash_attention_sharded(q, k, v, num_heads: int, mesh, batch_axis=None,
                             head_axis=None, causal: bool = False,
-                            window: int = 0):
+                            window: int = 0, block_diffusion=None):
     """Flash attention inside a GSPMD-sharded jit: a bare ``pallas_call``
     is an unpartitionable custom call to the partitioner, so wrap it in
     ``shard_map`` over the mesh axes the batch/head dims are sharded on —
@@ -759,6 +912,6 @@ def flash_attention_sharded(q, k, v, num_heads: int, mesh, batch_axis=None,
     spec = P(batch_axis, None, head_axis)
     local = num_heads // (mesh.shape[head_axis] if head_axis else 1)
     fn = functools.partial(flash_attention, num_heads=local, causal=causal,
-                           window=window)
+                           window=window, block_diffusion=block_diffusion)
     return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)

@@ -48,6 +48,10 @@ def _node_attrs(op) -> Dict[str, Any]:
     # forms (and einsum keeps) are S x window, not S^2
     if getattr(op, "windowed", False):
         attrs["window"] = int(op.window)
+    # a block-diffusion mask: the keys a query meets, on average, are the
+    # visible pairs over the queries (a quarter of the sequence and a few)
+    if getattr(op, "block_diffusion", None):
+        attrs["keys_seen"] = -(-op.visible_pairs // op.input_shapes[0][1])
     if hasattr(op, "interior_bytes"):
         attrs["interior_bytes"] = float(op.interior_bytes())
     # conv/pool geometry (stored as (h, w) tuples on the op): needed so a

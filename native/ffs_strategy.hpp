@@ -176,12 +176,16 @@ inline const char* kernel_default_impl(const Node& n, const Choice& c) {
 
 // Keys a query of attention node `n` meets: the sequence, or the sliding
 // window where the op has one that hides something (attr `window`,
-// ops/attention.py). The scores are S x this, not S^2: what the einsum
+// ops/attention.py), or under a mask that is not an interval of keys the
+// op's own count (attr `keys_seen`: the block-diffusion mask's visible
+// pairs over its queries). The scores are S x this, not S^2: what the einsum
 // path keeps, what flash spares, and (through the op's own `flops`) the
 // products both do. The kernel gate does not look at it: a window
 // changes no shape, so it is admitted wherever `flash_shape_legal` is.
 inline int64_t attention_keys_seen(const Node& n) {
   int64_t seq = n.output_shapes[0][1];
+  int64_t seen = n.attrs.get("keys_seen").as_int(0);
+  if (seen > 0) return std::min(seen, seq);
   int64_t window = n.attrs.get("window").as_int(0);
   return window > 0 ? std::min(window, seq) : seq;
 }

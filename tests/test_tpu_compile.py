@@ -150,6 +150,31 @@ class TestFlashKernels:
             sharding=SingleDeviceSharding(topo.devices[0]))
         assert pallas_kernel_count(_compile(grads(1), q, q, q)) == 2
 
+    @pytest.mark.parametrize("seq,block", [(16384, 4), (2048, 32),
+                                           (512, 4)])
+    def test_block_diffusion_mask_compiles_at_the_cells_widths(
+            self, topo, seq, block):
+        """The sdar cell's 8 heads of 128 under the block-diffusion mask
+        (PR 34): the blocked kernels with two ranges of chunks a tile at
+        16,384 and 2,048 positions, the whole-tile ones at 512; the
+        mask's positions are a column and a row that Mosaic has to
+        broadcast against each other."""
+        q = jax.ShapeDtypeStruct((1, seq, 8 * 128), jnp.bfloat16,
+                                 sharding=SingleDeviceSharding(
+                                     topo.devices[0]))
+
+        def grads(q, k, v):
+            def loss(q, k, v):
+                return pk._flash(q, k, v, 8, False, False, 0,
+                                 (seq // 2, block)).astype(
+                                     jnp.float32).sum()
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        hlo = _compile(grads, q, q, q)
+        assert pallas_kernel_count(hlo) == 2
+        assert layout_faults(hlo, q.size * 2) == []
+        assert ("tpu_custom_call_flash_bwd_blocked" in hlo) == (seq > 1024)
+
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
     @pytest.mark.parametrize("batch,heads,seq,head_dim", [
         # whole-tile kernels: 8 heads a step, then 2 at their longest,

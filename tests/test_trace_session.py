@@ -438,3 +438,65 @@ def test_window_attention_gauges_show_that_the_skip_engaged(
     if seq > 1024:
         assert full < total
         assert (part < full) == bool(window)
+
+
+@pytest.mark.parametrize("seq,block", [(2048, 4), (256, 32)])
+def test_block_diffusion_gauges_and_the_target_counter(
+        seq, block, tmp_path, monkeypatch, no_open_session):
+    """`executor.block_diffusion_attention_ops` and the
+    `attention/kv_blocks_*` counts of the new tiles (PR 34), set when the
+    train step is traced, and `loss/target_positions`, which leaves the
+    step with the ops' counters and is read once an epoch: in the header
+    of a session, the registry's snapshot and `FFModel.op_counters`."""
+    import numpy as np
+    from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
+    from flexflow_tpu.dataloader import block_diffusion_batch
+    from flexflow_tpu.ops.pallas_kernels import kv_blocks
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    b, e, half = 1, 32, seq // 2
+    ff = FFModel(FFConfig(batch_size=b))
+    t = ff.create_tensor((b, seq, e))
+    t = ff.multihead_attention(t, t, t, e, 2, block_diffusion=(half, block),
+                               rope=True, rope_wrap=half, qk_norm=True,
+                               name="masked")
+    t = ff.multihead_attention(t, t, t, e, 2, causal=True, name="causal")
+    t = ff.split(t, [half, half], axis=1)[0]
+    ff.dense(t, 16)
+    ff.compile(SGDOptimizer(lr=0.01),
+               LossType.WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY, [])
+    context = obs.model_context(ff)
+    assert context["block_diffusion_attention_ops"] == 1
+    assert context["attention_kv_blocks_total"] == 0      # not traced yet
+    assert context["loss_target_positions"] is None
+    rs = np.random.default_rng(0)
+    _, labels = block_diffusion_batch(rs.integers(0, 15, (2 * b, half)),
+                                      block, 15, rs)
+    x = rs.standard_normal((2 * b, seq, e)).astype(np.float32)
+    targets = int((labels[..., 1] > 0).sum())
+    ff.fit(x, labels, epochs=1, verbose=False)   # traces, compiles, counts
+    assert ff.op_counters["loss/target_positions"] == targets
+    obs.start_trace(str(tmp_path), device=False)
+    ff.fit(x, labels, epochs=1, verbose=False)
+    paths = obs.stop_trace()
+    masked, total = kv_blocks(seq, False, 0, (half, block))
+    causal, causal_total = kv_blocks(seq, True, 0)
+    header, _ = read_events(paths["events"])
+    gauges = json.load(open(paths["counters"]))["gauges"]
+    for got in (
+            (header["block_diffusion_attention_ops"],
+             header["window_attention_ops"],
+             header["attention_kv_blocks_visited"],
+             header["attention_kv_blocks_total"],
+             header["loss_target_positions"]),
+            (gauges["executor.block_diffusion_attention_ops"],
+             gauges["executor.window_attention_ops"],
+             gauges["attention/kv_blocks_visited"],
+             gauges["attention/kv_blocks_total"],
+             gauges["loss/target_positions"])):
+        assert got == (1, 0, masked + causal, total + causal_total, targets)
+    if seq > 1024:
+        # a quarter of the square and the tiles on its two diagonals (in
+        # chunks of 512 keys), where the causal layer visits the half
+        # under one (in chunks of 1024)
+        assert masked / total < causal / causal_total < 1
