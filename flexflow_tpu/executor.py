@@ -645,10 +645,12 @@ class GraphExecutor:
         the host (PR 31): the ops whose window hides something at their
         sequence length, those under the block-diffusion mask (PR 34),
         and the [Q block, K chunk] tiles a head's flash
-        forward works through against those of the whole square, added
-        up over the ops that ran flash (`kv_blocks`; 0 / 0 until one has
-        been traced). Published as gauges when the train step is traced,
-        in every trace header and in `FFModel.op_counters`."""
+        forward works through against those of the whole square, and
+        of them those that hold a hidden pair and run the masked body
+        (PR 35), added up over the ops that ran flash (`kv_blocks`,
+        `kv_blocks_masked`; 0 until one has been traced). Published as
+        gauges when the train step is traced, in every trace header and
+        in `FFModel.op_counters`."""
         blocks = [n.op._kv_blocks for n in self.nodes
                   if getattr(n.op, "_kv_blocks", None)]
         return {
@@ -658,7 +660,8 @@ class GraphExecutor:
                 bool(getattr(n.op, "block_diffusion", None))
                 for n in self.nodes),
             "attention/kv_blocks_visited": sum(b[0] for b in blocks),
-            "attention/kv_blocks_total": sum(b[1] for b in blocks)}
+            "attention/kv_blocks_total": sum(b[1] for b in blocks),
+            "attention/kv_blocks_masked": sum(b[2] for b in blocks)}
 
     def _training_nodes(self):
         """Node list the TRAIN step runs: (Conv2D, BatchNorm) pairs whose

@@ -326,7 +326,8 @@ def model_context(ff) -> Dict[str, Any]:
         flash_lane_dense_ops=ff.executor.flash_lane_dense_ops(),
         # windowed attention ops, those under the block-diffusion mask,
         # and the flash forwards' K blocks visited against the whole
-        # square's (`executor.attention_gauges`)
+        # square's, and of them those masked
+        # (`executor.attention_gauges`)
         **{k.split(".")[-1].replace("/", "_"): v
            for k, v in ff.executor.attention_gauges().items()},
         # expert layers whose traced forward moved rows by gathers only

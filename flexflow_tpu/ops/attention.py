@@ -169,8 +169,9 @@ class MultiHeadAttention(Op):
         # set when a forward hands the flash kernels [B, S, H*D]
         # operands (counted by `executor.flash_lane_dense_ops`)
         self._flash_lane_dense = False
-        # (visited, total) K blocks of the flash forward as traced, a
-        # head (`attention/kv_blocks_*`); None until a forward ran flash
+        # (visited, total, masked) K blocks of the flash forward as
+        # traced, a head (`attention/kv_blocks_*`); None until a forward
+        # ran flash
         self._kv_blocks = None
         # batch-dim sharding (str or tuple of mesh axes under the sample2
         # 'data+model' 2-D partition), recorded by apply_strategy
@@ -345,7 +346,8 @@ class MultiHeadAttention(Op):
               and dropout_rate == 0.0 and sq == sk):
             from flexflow_tpu.ops.pallas_kernels import (
                 flash_attention, flash_attention_available,
-                flash_attention_sharded, flash_shape_legal, kv_blocks)
+                flash_attention_sharded, flash_shape_legal, kv_blocks,
+                kv_blocks_masked)
 
             available = flash_attention_available(sq, d, h)
             if self.kernel_impl == "flash" and not available:
@@ -357,8 +359,8 @@ class MultiHeadAttention(Op):
             if available:
                 # for `executor.flash_lane_dense_ops`
                 self._flash_lane_dense = True
-                self._kv_blocks = kv_blocks(sq, self.causal, self.window,
-                                            self.block_diffusion)
+                kind = (sq, self.causal, self.window, self.block_diffusion)
+                self._kv_blocks = (*kv_blocks(*kind), kv_blocks_masked(*kind))
 
                 def flash(kernel, **where):
                     call = functools.partial(

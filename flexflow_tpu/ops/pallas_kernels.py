@@ -28,7 +28,10 @@ sample and then the clean one, in blocks of B. A noised block sees itself
 and the clean blocks before it, a clean block the clean blocks up to
 itself: a tile's visible K chunks (forward) and Q chunks (backward) are
 then TWO ranges (``_k_ranges``, ``_q_ranges``), and the blocked kernels
-loop over both.
+loop over both. Of the tiles they visit they MASK only those that hold a
+hidden pair (PR 35): each range is cut, by ``visible`` at a tile's
+extreme pairs, into sub-ranges of interior tiles, whose loop body builds
+no mask, and of edge tiles (``_k_split``, ``_q_split``).
 
 One operand form (PR 30): q, k, v, o and their gradients are
 [B, S, H*D], the heads side by side along the lanes, which is what the
@@ -192,13 +195,16 @@ def _seq_block(s: int, block_diffusion=None) -> int:
     one pass over a [128, S] tile: 3.85 / 5.30).
 
     Under ``block_diffusion`` (L, B) the block divides L, so that none
-    lies across the halves, and is 512 at most: a quarter of the square
-    is visible, along two diagonals, and the tiles on them are visited
-    whole; at S = 16384 blocks of 1024 visit 0.3125 of the square's
-    tiles, blocks of 512 0.281."""
+    lies across the halves. Until PR 35 it was 512 at most there (a
+    quarter of the square is visible, along two diagonals whose tiles
+    are visited whole: at 2L = 16384 blocks of 1024 visit 0.3125 of the
+    square, blocks of 512 0.281); re-measured (PR 35, 8 heads of 128,
+    2L = 16384, B = 4, Q blocks of 256, forward / backward ms): 2.87 /
+    5.11 at 512, 2.83 / 4.97 at 1024: a chunk's fixed cost (the running
+    sums' rescaling, the seven transposes and dQ's read-modify-write a
+    tile) outweighs a ninth more of the square."""
     if block_diffusion is not None:
-        return next(b for b in (512, 256, BLK_Q)
-                    if block_diffusion[0] % b == 0)
+        s = block_diffusion[0]
     return next(b for b in (1024, 512, 256, BLK_Q) if s % b == 0)
 
 
@@ -285,7 +291,8 @@ def _mask(st, k0, q0, window: int, block_diffusion=None):
 def _pick(of_ints, of_traced, a, b):
     """``kv_blocks`` counts with Python ints, also while a step is being
     traced, where a ``jnp`` call on them would be staged into the trace."""
-    return (of_ints if isinstance(a, int) else of_traced)(a, b)
+    return (of_ints if isinstance(a, int) and isinstance(b, int)
+            else of_traced)(a, b)
 
 
 def _either(cond, a, b):
@@ -363,6 +370,108 @@ def _q_ranges(k0, blk_k: int, blk_q: int, s: int, causal: bool, window: int,
     return (lo1, hi1), (_either(noised, s // blk_q, lo2), s // blk_q)
 
 
+def _cut(lo, hi, points, edge: bool):
+    """The range [lo, hi) cut at ``points`` into consecutive sub-ranges
+    (lo, hi, edge), edge and interior by turns from ``edge`` on. A point
+    is held into what is left of the range, so a sub-range may be empty
+    and the sub-ranges are the range, in its order, whatever the points."""
+    out = []
+    for p in points:
+        p = _pick(min, jnp.minimum, _pick(max, jnp.maximum, p, lo), hi)
+        out.append((lo, p, edge))
+        lo, edge = p, not edge
+    return (*out, (lo, hi, edge))
+
+
+def _k_split(q0, blk_q: int, blk_k: int, s: int, causal: bool, window: int,
+             block_diffusion=None):
+    """``_k_ranges`` cut into sub-ranges (lo, hi, edge) in the order the
+    forward's loop runs (PR 35): a tile is INTERIOR where every query of
+    the block sees every key of the chunk, which ``visible`` says at the
+    tile's extreme pairs, and EDGE where it holds a hidden pair; only an
+    edge tile is masked. Under ``causal`` the chunks whose last key is
+    the block's first query at most are interior, then the diagonal;
+    under a window the chunks ahead of those whose first key the block's
+    last query still sees are the far edge. Under ``block_diffusion`` the
+    clean chunks that end among the keys the block's FIRST query sees are
+    interior, then the last; a noised tile is interior only where one
+    block of B holds all its queries and keys (never at B = 4: one edge
+    range). Without a mask every tile is interior. Of a head's forward
+    at the cells' shapes 96 of 320 tiles are edge (sdar), 64 of 544 (full
+    causal, S 16384), 112 of 280 (window 4096): ``kv_blocks_masked``.
+
+    Returns (sub-ranges, whether the LAST sub-range is exactly one chunk
+    for every block): a Q block that lies inside one K chunk has one
+    diagonal chunk under ``causal``, and under ``block_diffusion`` with
+    B | BLK_Q one last clean chunk. The forward runs that chunk as
+    straight-line code after its loops."""
+    ranges = _k_ranges(q0, blk_q, blk_k, s, causal, window, block_diffusion)
+    whole = blk_k % blk_q == 0    # a Q block lies inside one K chunk
+    if block_diffusion is None:
+        (lo, hi), = ranges
+        if not causal:
+            return ((lo, hi, False),), False
+        diag = (q0 + 1) // blk_k
+        if not window:
+            return _cut(lo, hi, (diag,), False), whole
+        near = (_pick(max, jnp.maximum, q0 + blk_q - window, 0)
+                + blk_k - 1) // blk_k
+        # a narrow window's far edge runs into the diagonal, which stays
+        # the last sub-range
+        near = _pick(min, jnp.minimum, near, diag)
+        return _cut(lo, hi, (near, diag), True), whole
+    length, b = block_diffusion
+    (lo1, hi1), (lo2, hi2) = ranges
+    noised = q0 < length
+    first = q0 - _either(noised, 0, length)
+    own = ()
+    if b >= max(blk_q, blk_k):
+        # the chunks inside the block of B that holds the whole Q block
+        at = first // b * b
+        inside = (at + blk_k - 1) // blk_k
+        own = (inside, _either((first + blk_q - 1) // b * b == at,
+                               (at + b) // blk_k, inside))
+    clean = _either(noised, first // b * b, (first // b + 1) * b)
+    return (_cut(lo1, hi1, own, True)
+            + _cut(lo2, hi2, (lo2 + clean // blk_k,), False),
+            whole and b < blk_q and blk_q % b == 0)
+
+
+def _q_split(k0, blk_k: int, blk_q: int, s: int, causal: bool, window: int,
+             block_diffusion=None):
+    """``_q_ranges`` cut into sub-ranges (lo, hi, edge) in the order the
+    backward's loop runs, the transpose of ``_k_split``: under ``causal``
+    the diagonal, then the chunks whose first query is the block's last
+    key at least, and under a window the far edge after the chunks whose
+    last query still sees the block's first key. Under
+    ``block_diffusion`` a noised key block's own queries are edge (but
+    where one block of B holds the tile); a clean one's are interior
+    from the chunk on whose first query sees its LAST key, among the
+    noised queries and among the clean ones."""
+    ranges = _q_ranges(k0, blk_k, blk_q, s, causal, window, block_diffusion)
+    if block_diffusion is None:
+        (lo, hi), = ranges
+        if not causal:
+            return ((lo, hi, False),)
+        diag = (k0 + blk_k + blk_q - 2) // blk_q
+        far = ((k0 + window) // blk_q,) if window else ()
+        return _cut(lo, hi, (diag, *far), True)
+    length, b = block_diffusion
+    (lo1, hi1), (lo2, hi2) = ranges
+    noised = k0 < length
+    first = k0 - _either(noised, 0, length)
+    after = (first + blk_k - 1) // b * b   # the last key's block starts here
+    own = (_either(noised, hi1, (after + b + blk_q - 1) // blk_q),)
+    if b >= max(blk_q, blk_k):
+        at = first // b * b
+        inside = (at + blk_q - 1) // blk_q
+        own = (_either(noised, inside, own[0]),
+               _either(noised, _either(after == at, (at + b) // blk_q,
+                                       inside), hi1))
+    return (_cut(lo1, hi1, own, True)
+            + _cut(lo2, hi2, ((length + after + blk_q - 1) // blk_q,), True))
+
+
 def normalized_window(s: int, causal: bool, window: int) -> int:
     """0 where the window hides nothing (none given, or >= S): those run
     the very code ``causal`` alone runs."""
@@ -387,6 +496,19 @@ def checked_block_diffusion(s: int, causal: bool, window: int,
     return length, b
 
 
+def _forward_tiles(s: int, causal: bool, window: int, block_diffusion):
+    """(every sub-range (lo, hi, edge) of K chunks that the blocked
+    forward of one head loops over at sequence ``s``, Q block after Q
+    block; the tiles of the whole square): what ``kv_blocks`` and
+    ``kv_blocks_masked`` count, by the kernel's own lines."""
+    window = normalized_window(s, causal, window)
+    bd = checked_block_diffusion(s, causal, window, block_diffusion)
+    blk_q, blk_k = _q_block(s, bd), _seq_block(s, bd)
+    cut = [sub for q0 in range(0, s, blk_q)
+           for sub in _k_split(q0, blk_q, blk_k, s, causal, window, bd)[0]]
+    return cut, (s // blk_q) * (s // blk_k)
+
+
 def kv_blocks(s: int, causal: bool, window: int = 0, block_diffusion=None):
     """(visited, total) tiles of [a Q block, a K chunk] that the
     forward of one head works through at sequence ``s``: what the
@@ -394,14 +516,20 @@ def kv_blocks(s: int, causal: bool, window: int = 0, block_diffusion=None):
     whole-tile kernels (S <= MAX_BWD_SEQ) hold one tile and mask."""
     if s <= MAX_BWD_SEQ:
         return 1, 1
-    window = normalized_window(s, causal, window)
-    bd = checked_block_diffusion(s, causal, window, block_diffusion)
-    blk_q, blk_k = _q_block(s, bd), _seq_block(s, bd)
-    visited = 0
-    for q0 in range(0, s, blk_q):
-        visited += sum(hi - lo for lo, hi in _k_ranges(
-            q0, blk_q, blk_k, s, causal, window, bd))
-    return visited, (s // blk_q) * (s // blk_k)
+    cut, total = _forward_tiles(s, causal, window, block_diffusion)
+    return sum(hi - lo for lo, hi, _ in cut), total
+
+
+def kv_blocks_masked(s: int, causal: bool, window: int = 0,
+                     block_diffusion=None) -> int:
+    """Of ``kv_blocks``' visited tiles, those that hold a hidden pair and
+    run the masked body (``_k_split``'s edge tiles): what the gauge
+    ``attention/kv_blocks_masked`` adds up. The whole-tile kernels mask
+    their one tile under any mask."""
+    if s <= MAX_BWD_SEQ:
+        return int(causal or bool(block_diffusion))
+    cut, _ = _forward_tiles(s, causal, window, block_diffusion)
+    return sum(hi - lo for lo, hi, edge in cut if edge)
 
 
 def _only_head(x, h: int, head_dim: int):
@@ -437,34 +565,51 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
     ONLY the chunks that hold a key some query of the block sees
     (``_k_chunks``): under ``causal`` those up to the diagonal, under a
     window the few behind it, under ``block_diffusion`` the block's own
-    noised tile and then the clean chunks it sees. Scores never touch
-    HBM. Also emits the
-    per-row logsumexp so the fused backward can recompute P exactly.
-    The forward of sequences past MAX_BWD_SEQ."""
+    noised tile and then the clean chunks it sees. Each range runs as
+    ``_k_split``'s sub-ranges, one loop each: the body masks an edge
+    tile and builds no mask for an interior one. Scores never touch
+    HBM. Also emits the per-row logsumexp so the fused backward can
+    recompute P exactly. The forward of sequences past MAX_BWD_SEQ.
+
+    The last sub-range, where the split says it is one chunk for every
+    block (the diagonal; the last clean chunk), runs as straight-line
+    code after the loops and not as a loop of one step: the scheduler
+    then overlaps it with the division by the sums and the logsumexp.
+
+    v5e, kernels alone, forward ms, every tile masked in one loop a
+    range -> the last chunk out of the loop -> the split (PR 35): 8
+    heads of 128 at 2L = 16384 under block diffusion 3.09 -> 2.92 ->
+    2.83 (K chunks of 512: 3.36 -> 3.24 -> 2.87; the mask there is
+    integer divisions on a [BLK_Q, 1] column a chunk); 7 heads at
+    S = 16384 full causal 4.08 -> 3.91 -> 3.84, window 4096 2.34 ->
+    2.19 -> 2.20 (three short loops a Q block); 4 heads at S = 8192
+    causal 0.66 -> 0.62 -> 0.61. At chunks of 1024 most of the mask's
+    passes hide under the products."""
     q = q_ref[0]  # [BLK_Q, W]
     heads = q.shape[-1] // head_dim
     q0 = pl.program_id(2) * blk_q
-    ranges = _k_ranges(q0, blk_q, blk_k, k_ref.shape[1], causal, window,
-                       block_diffusion)
+    split, last_is_one = _k_split(q0, blk_q, blk_k, k_ref.shape[1], causal,
+                                  window, block_diffusion)
     qs = [_only_head(q, h, head_dim) for h in range(heads)]
     tile = (blk_q, blk_k)
-    masked = causal or block_diffusion is not None
 
-    def chunk(c, carry):
+    def seen(k0):
+        if block_diffusion is not None:
+            return _tile_visible(q0, k0, tile, 0, window, block_diffusion)
+        return visible(
+            q0 + jax.lax.broadcasted_iota(jnp.int32, tile, 0),
+            k0 + jax.lax.broadcasted_iota(jnp.int32, tile, 1), window)
+
+    def chunk(c, carry, edge):
         k0 = pl.multiple_of(c * blk_k, blk_k)
         k = k_ref[0, pl.ds(k0, blk_k), :]  # [BLK_K, W]
         v = v_ref[0, pl.ds(k0, blk_k), :]
-        if block_diffusion is not None:
-            seen = _tile_visible(q0, k0, tile, 0, window, block_diffusion)
-        elif causal:
-            seen = visible(
-                q0 + jax.lax.broadcasted_iota(jnp.int32, tile, 0),
-                k0 + jax.lax.broadcasted_iota(jnp.int32, tile, 1), window)
+        mask = seen(k0) if edge else None
         out = []
         for h, (m, l, acc) in enumerate(carry):
             s = _dot(qs[h], k, _NT) * scale
-            if masked:
-                s = jnp.where(seen, s, _MASKED)
+            if edge:
+                s = jnp.where(mask, s, _MASKED)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
@@ -479,8 +624,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
         (jnp.full((blk_q, 1), _MASKED, jnp.float32),
          jnp.zeros((blk_q, 1), jnp.float32),
          jnp.zeros(q.shape, jnp.float32)) for _ in range(heads))
-    for lo, hi in ranges:
-        carry = jax.lax.fori_loop(lo, hi, chunk, carry)
+    for n, (lo, hi, edge) in enumerate(split):
+        if last_is_one and n == len(split) - 1:
+            carry = chunk(lo, carry, edge)
+        else:
+            carry = jax.lax.fori_loop(
+                lo, hi, functools.partial(chunk, edge=edge), carry)
     o = None
     for h, (m, l, acc) in enumerate(carry):
         oh = _only_head(acc / l, h, head_dim)
@@ -651,9 +800,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                              glse_ref, dq_ref, dk_ref, dv_ref, *,
-                              causal: bool, window: int, scale: float,
-                              blk: int, head_dim: int, block_diffusion=None):
+                              glse_ref, dq_ref, dk_ref, dv_ref, dkt_ref,
+                              dvt_ref, *, causal: bool, window: int,
+                              scale: float, blk: int, head_dim: int,
+                              block_diffusion=None):
     """FA2 backward for sequences past MAX_BWD_SEQ: grid cell = one
     (batch row, column block, K-block). The Q/O/dO panels are resident;
     the K-block meets them in chunks of ``blk`` queries, and ONLY the
@@ -661,38 +811,55 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     from the diagonal on, and under a window no further than the window
     behind the block's last key; under ``block_diffusion`` two ranges,
     the noised queries and the clean ones that see it); a chunk's
-    [BLK, BLK] score tile is
-    recomputed in VMEM. dK/dV add up over the chunks and write their
-    block; dQ adds up in place, a chunk's rows at a time, across the
-    K-block grid dimension (same output block revisited -> Pallas keeps
-    it in VMEM between consecutive steps)."""
+    [BLK, BLK] score tile is recomputed in VMEM, and masked only in the
+    sub-ranges ``_q_split`` classes edge. dK^T/dV^T add up over the
+    chunks in two float32 scratch tiles and are turned into their block
+    at the end; dQ adds up in place, a chunk's rows at a time, across
+    the K-block grid dimension (same output block revisited -> Pallas
+    keeps it in VMEM between consecutive steps).
+
+    The sums live in scratch and not in the loops' carries because a
+    loop boundary moves its carries: v5e, kernels alone, backward ms
+    with every tile masked, carries -> scratch (PR 35): 8 heads of 128
+    at 2L = 16384 under block diffusion 5.63 -> 5.39 (K blocks of 512)
+    and 5.17 -> 4.95 (1024); 7 heads, S = 16384, window 4096 3.95 ->
+    3.84, full causal 7.64 -> 7.44; 4 heads, S = 8192 causal 1.17 ->
+    1.13. With the loops cut by ``_q_split`` the carries cost more
+    (window 4.16, block diffusion at 1024 5.34), the scratch does not
+    (3.89, 4.97; full causal 7.31). The forward's carries are a Q
+    block's [BLK_Q, W] sums; in scratch they were slower (block
+    diffusion at 512 3.08 -> 4.57 ms: the [BLK_Q, 1] max and sum as
+    stores), so they stay carries. A first chunk in straight-line code
+    ahead of the loops, as the forward has its last, is refused by the
+    chip's compiler here (an internal check of its MXU pass)."""
     j = pl.program_id(2)
     k0 = j * blk
     k, v = k_ref[0], v_ref[0]
     kt = k.T
-    ranges = _q_ranges(k0, blk, blk, q_ref.shape[1], causal, window,
-                       block_diffusion)
-    masked = causal or block_diffusion is not None
+    split = _q_split(k0, blk, blk, q_ref.shape[1], causal, window,
+                     block_diffusion)
 
     @pl.when(j == 0)
     def _init():
         dq_ref[0] = jnp.zeros(dq_ref.shape[1:], dq_ref.dtype)
 
-    def chunk(c, carry):
+    def chunk(c, _, edge):
         q0 = pl.multiple_of(c * blk, blk)
         rows = pl.ds(q0, blk)
         dqt, dkt, dvt = _flash_bwd_tile(
             q_ref[0, rows, :], k, kt, v, o_ref[0, rows, :],
             do_ref[0, rows, :], lse_ref[0, :, :, rows],
             glse_ref[0, :, :, rows], scale,
-            (k0, q0, window, block_diffusion) if masked else None, head_dim)
+            (k0, q0, window, block_diffusion) if edge else None, head_dim)
         dq_ref[0, rows, :] += (dqt * scale).T
-        return carry[0] + dkt, carry[1] + dvt
+        dkt_ref[...] += dkt
+        dvt_ref[...] += dvt
 
-    zero = jnp.zeros((k.shape[1], blk), jnp.float32)
-    dkt, dvt = zero, zero
-    for lo, hi in ranges:
-        dkt, dvt = jax.lax.fori_loop(lo, hi, chunk, (dkt, dvt))
+    dkt_ref[...] = jnp.zeros(dkt_ref.shape, jnp.float32)
+    dvt_ref[...] = jnp.zeros(dvt_ref.shape, jnp.float32)
+    for lo, hi, edge in split:
+        jax.lax.fori_loop(lo, hi, functools.partial(chunk, edge=edge), None)
+    dkt, dvt = dkt_ref[...], dvt_ref[...]
     dk_ref[0] = (dkt * scale).T.astype(dk_ref.dtype)
     dv_ref[0] = dvt.T.astype(dv_ref.dtype)
 
@@ -751,6 +918,7 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         in_specs=[seq_spec, kblk_spec, kblk_spec, seq_spec, seq_spec,
                   row_spec, row_spec],
         out_specs=(seq_spec, kblk_spec, kblk_spec),
+        scratch_shapes=[pltpu.VMEM((w, blk), jnp.float32)] * 2,
         interpret=interpret,
         compiler_params=_FLASH_COMPILER_PARAMS,
     )(q, k, v, o, do, lse, glse)

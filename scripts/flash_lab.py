@@ -1,0 +1,150 @@
+#!/usr/bin/env python3
+"""The blocked flash kernels alone, on the chip (PR 35).
+
+At the shapes of the three decoder cells, forward (`flash_fwd`) and
+K-blocked backward (`flash_bwd_blocked`) each in a program of its own,
+bf16, ten calls after a warm-up inside one profiler trace; a line gives
+the kernel's device time a call from the trace's longest op. Three
+programs a shape:
+
+- `split`: what ships: only the tiles that hold a hidden pair run the
+  masked body (`pallas_kernels._k_split` / `_q_split`);
+- `every_tile_masked`: the kernels before PR 35 (but for the backward's
+  sums in scratch), every visited tile masked and each range one loop,
+  made here by classing every sub-range edge;
+- `every_tile_masked+peel`: the same with the forward's last chunk in
+  straight-line code after the loop, which tells what the unmasked body
+  is worth from what the peeled chunk is;
+
+and under the block-diffusion mask both at K blocks of 512 and of 1024
+(`_seq_block` caps them at 512 there).
+
+Prints one JSON line a measurement and writes them to
+`chiprun_out/flash_lab.json`. Nothing here is a benchmark metric.
+
+    python scripts/flash_lab.py [--tiny]
+
+`--tiny` is the CPU rehearsal (short sequences, the kernels interpreted,
+no device in the trace, so `device_ms` is null).
+"""
+
+import argparse
+import json
+import os
+import sys
+import tempfile
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# heads of 128, positions, causal, window, block diffusion
+SHAPES = {
+    "sdar.block_diffusion": (8, 16384, False, 0, (8192, 4)),
+    "smallthinker.window": (7, 16384, True, 4096, None),
+    "smallthinker.full": (7, 16384, True, 0, None),
+    "nemotron.full": (4, 8192, True, 0, None),
+}
+REPS = 10
+
+
+def every_tile_masked(ranges, forward, peel=False):
+    """The split of the kernels before PR 35 (every visited tile masked,
+    each range one loop); with ``peel`` the forward's last chunk is cut
+    off its loop into straight-line code, as the shipped split has it."""
+    def split(x0, blk_a, blk_b, s, causal, window, block_diffusion=None):
+        masked = causal or block_diffusion is not None
+        cut = [(lo, hi, masked) for lo, hi in ranges(
+            x0, blk_a, blk_b, s, causal, window, block_diffusion)]
+        if peel and masked:
+            lo, hi, _ = cut.pop()
+            cut += [(lo, hi - 1, True), (hi - 1, hi, True)]
+        return (tuple(cut), peel and masked) if forward else tuple(cut)
+    return split
+
+
+def kernel_ms(fn, args, interpret):
+    """Device ms a call of the flash kernel in ``fn``, from a trace of
+    REPS calls (None where the trace holds no device)."""
+    import jax
+    from benchmarks import trace_reduce as tr
+
+    jax.block_until_ready(fn(*args))
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(REPS):
+                out = fn(*args)
+            jax.block_until_ready(out)
+        if interpret:
+            return None
+        dev = tr.load_xplane(tr.newest_xplane(d))[0]
+    # alone in its program the kernel's event carries the program's name,
+    # not the kernel's: the op that took the longest is the kernel (the
+    # backward's other op is dQ's cast, a fortieth of it)
+    by_op = {}
+    for name, _, dur in dev.lines["XLA Ops"]:
+        by_op.setdefault(name, []).append(dur)
+    found = max(by_op.values(), key=sum)
+    assert len(found) == REPS, {n: len(d) for n, d in by_op.items()}
+    return 1e3 * sum(found) / REPS
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tiny", action="store_true")
+    tiny = ap.parse_args().tiny
+    if tiny:
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    if not tiny and jax.default_backend() != "tpu":
+        sys.exit("flash_lab.py times the kernels on a TPU; --tiny rehearses")
+    shipped = pk._k_split, pk._q_split, pk._seq_block
+    lines = []
+    for name, (heads, seq, causal, window, bd) in SHAPES.items():
+        if tiny:
+            seq, window, bd = 2048, window // 8, bd and (1024, 4)
+        rs = np.random.RandomState(0)
+        q, k, v, do = (jnp.asarray(rs.randn(1, seq, heads * 128),
+                                   jnp.bfloat16) for _ in range(4))
+        for blk in (512, 1024) if bd else (None,):
+            for program in ("every_tile_masked", "every_tile_masked+peel",
+                            "split"):
+                pk._k_split, pk._q_split, pk._seq_block = shipped
+                if program != "split":
+                    pk._k_split = every_tile_masked(
+                        pk._k_ranges, True, peel="peel" in program)
+                    pk._q_split = every_tile_masked(pk._q_ranges, False)
+                if blk:
+                    pk._seq_block = lambda s, block_diffusion=None, b=blk: b
+                mask = dict(window=window, block_diffusion=bd)
+                fwd = jax.jit(lambda q, k, v: pk._flash_fwd(
+                    q, k, v, heads, causal, tiny, **mask))
+                bwd = jax.jit(lambda q, k, v, o, lse, do: pk._flash_bwd(
+                    q, k, v, o, lse, do, heads, causal, tiny, **mask))
+                o, lse = fwd(q, k, v)
+                line = dict(
+                    shape=name, heads=heads, seq=seq, program=program,
+                    k_block=pk._seq_block(seq, bd),
+                    tiles_visited=pk.kv_blocks(seq, causal, window, bd)[0],
+                    tiles_masked=pk.kv_blocks_masked(seq, causal, window, bd),
+                    last_chunk_peeled=pk._k_split(
+                        0, pk._q_block(seq, bd), pk._seq_block(seq, bd), seq,
+                        causal, window, bd)[1],
+                    forward_device_ms=kernel_ms(fwd, (q, k, v), tiny),
+                    backward_device_ms=kernel_ms(
+                        bwd, (q, k, v, o, lse, do), tiny),
+                    device=jax.devices()[0].device_kind)
+                print(json.dumps(line), flush=True)
+                lines.append(line)
+    pk._k_split, pk._q_split, pk._seq_block = shipped
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/flash_lab.json", "w") as f:
+        json.dump(lines, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()

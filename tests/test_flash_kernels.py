@@ -301,3 +301,152 @@ def test_every_product_takes_the_stored_dtype(seq, dtype, grads):
     for name, operands, preferred, result in dots:
         assert operands == (dtype, dtype), (name, operands)
         assert preferred == jnp.float32 and result == jnp.float32, name
+
+
+# the three masks the blocked kernels know: (causal, window,
+# block_diffusion) at a length past MAX_BWD_SEQ
+KINDS = {
+    "causal": (2048, True, 0, None),
+    "causal-4096": (4096, True, 0, None),
+    "window-128": (2048, True, 128, None),
+    "window-1000": (4096, True, 1000, None),
+    "window-2500": (4096, True, 2500, None),
+    "window-4096": (4096, True, 4096, None),     # hides nothing: causal
+    "block-diffusion-4": (4096, False, 0, (2048, 4)),
+    "block-diffusion-32": (4096, False, 0, (2048, 32)),
+    # a half is one K chunk: every tile holds a hidden pair
+    "block-diffusion-short": (2048, False, 0, (1024, 4)),
+    # one block of B holds whole tiles: the noised diagonal has
+    # interior tiles too
+    "block-diffusion-768": (3072, False, 0, (1536, 768)),
+    "not-causal": (2048, False, 0, None),
+}
+
+
+def _tiles(seq, causal, window, bd, blk_q, blk_k, reduce):
+    """``reduce`` (numpy's all or any) of the mask over every
+    [blk_q queries, blk_k keys] tile, from ``visible`` pair by pair."""
+    i = np.arange(seq)
+    seen = (np.asarray(pk.visible(i[:, None], i[None, :], window, bd))
+            if causal or bd else np.ones((seq, seq), bool))
+    return reduce(seen.reshape(seq // blk_q, blk_q, seq // blk_k, blk_k),
+                  axis=(1, 3))
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_split_keeps_the_ranges_and_masks_the_tiles_with_a_hidden_pair(
+        kind, direction):
+    """`_k_split` / `_q_split` (PR 35): the sub-ranges are the ranges of
+    `_k_ranges` / `_q_ranges`, chunk for chunk in the loop's order, and a
+    tile is classed interior iff `visible` holds on the whole of it."""
+    seq, causal, window, bd = KINDS[kind]
+    window = pk.normalized_window(seq, causal, window)
+    blk_k = pk._seq_block(seq, bd)
+    if direction == "forward":
+        blk, ranges = pk._q_block(seq, bd), pk._k_ranges
+        split = lambda *a: pk._k_split(*a)[0]               # noqa: E731
+        last_is_one = pk._k_split(0, blk, blk_k, seq, causal, window, bd)[1]
+    else:
+        blk, ranges, split, last_is_one = blk_k, pk._q_ranges, pk._q_split, 0
+    whole = _tiles(seq, causal, window, bd, blk, blk_k, np.all)
+    if direction == "backward":
+        whole = whole.T
+    interior = 0
+    for n, x0 in enumerate(range(0, seq, blk)):
+        args = (x0, blk, blk_k, seq, causal, window, bd)
+        cut = split(*args)
+        assert ([c for lo, hi in ranges(*args) for c in range(lo, hi)]
+                == [c for lo, hi, _ in cut for c in range(lo, hi)]), x0
+        if last_is_one:     # the chunk the forward runs outside a loop
+            assert cut[-1][1] - cut[-1][0] == 1, (x0, cut)
+        for lo, hi, edge in cut:
+            assert lo <= hi and isinstance(edge, bool)
+            assert all(whole[n, c] != edge for c in range(lo, hi)), (x0, cut)
+            interior += 0 if edge else hi - lo
+    if direction == "forward":
+        visited, _ = pk.kv_blocks(seq, causal, window, bd)
+        assert pk.kv_blocks_masked(seq, causal, window, bd) == (
+            visited - interior)
+    # every masked kind but a B that does not divide the Q block
+    assert bool(last_is_one) == (direction == "forward" and (
+        causal or (bd is not None and bd[1] < 256)))
+    # what the case is there for: a narrow window leaves no tile whole
+    assert (interior > 0) == (kind not in (
+        "window-128", "window-1000", "block-diffusion-short"))
+
+
+def test_masked_tiles_of_the_cells_layers():
+    """By hand: of the sdar cell's 320 tiles a head, the noised diagonal
+    tile of each of the 32 noised Q blocks and the last clean chunk of
+    each of the 64; a full causal layer at 16,384 the 64 diagonal tiles;
+    a window of 4096 those and the far edge of the 48 Q blocks whose
+    window starts inside a chunk; the whole-tile kernels mask their one
+    tile under any mask."""
+    assert pk.kv_blocks_masked(16384, False, 0, (8192, 4)) == 96
+    assert pk.kv_blocks_masked(16384, True, 0) == 64
+    assert pk.kv_blocks_masked(16384, True, 4096) == 64 + 48
+    assert pk.kv_blocks_masked(16384, True, 1 << 20) == 64
+    assert pk.kv_blocks_masked(8192, True, 0) == 32
+    assert pk.kv_blocks_masked(8192, False, 0) == 0
+    assert pk.kv_blocks_masked(512, True, 128) == 1
+    assert pk.kv_blocks_masked(512, False, 0, (256, 4)) == 1
+    assert pk.kv_blocks_masked(512, False, 0) == 0
+
+
+def _every_tile_edge(ranges):
+    """The split of the kernels before PR 35: every visited tile of a
+    masked op runs the masked body, each range in one loop."""
+    def split(x0, blk_a, blk_b, s, causal, window, block_diffusion=None):
+        cut = tuple(
+            (lo, hi, causal or block_diffusion is not None)
+            for lo, hi in ranges(x0, blk_a, blk_b, s, causal, window,
+                                 block_diffusion))
+        return (cut, False) if ranges is pk._k_ranges else cut
+    return split
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("kind", ["causal", "window-2500",
+                                  "block-diffusion-4",
+                                  "block-diffusion-768", "not-causal"])
+def test_unmasked_interior_tiles_give_the_bits_of_masking_every_tile(
+        kind, head_dim, monkeypatch):
+    """`jnp.where(all true, s, _MASKED)` is `s` and the chunks keep their
+    order: output, logsumexp, dQ, dK and dV of the blocked kernels are
+    bitwise those of the same kernels with every tile classed edge,
+    which is the program before PR 35.
+
+    Bitwise at head_dim 64, whose scale 1/8 is a power of two. At 128
+    (one head a column block, the decoder cells' width) the interpreter
+    itself stands in the way: XLA's CPU backend contracts the unmasked
+    body's `dot * scale - m` into one fused multiply-add, which it cannot
+    across the masked body's select, and with a scale that is not a power
+    of two the product's rounding then differs in about one element of a
+    thousand by one unit in the last place (measured: 13-920 elements of
+    0.3-1M; the logsumexp by 1e-6 at most). Held there to a sixteenth of
+    one bf16 rounding over the whole tensor."""
+    seq, causal, window, bd = KINDS[kind]
+    h = 2
+    q, k, v, do = _qkv(seq, head_dim, jnp.bfloat16, seed=seq + window, h=h)
+
+    def run():
+        o, lse = pk._flash_fwd(q, k, v, h, causal, True, window=window,
+                               block_diffusion=bd)
+        return (o, lse) + tuple(pk._flash_bwd(
+            q, k, v, o, lse, do, h, causal, True, window=window,
+            block_diffusion=bd))
+
+    if kind != "not-causal":      # the case is not vacuous
+        assert 0 < pk.kv_blocks_masked(seq, causal, window, bd) < (
+            pk.kv_blocks(seq, causal, window, bd)[0])
+    got = run()
+    monkeypatch.setattr(pk, "_k_split", _every_tile_edge(pk._k_ranges))
+    monkeypatch.setattr(pk, "_q_split", _every_tile_edge(pk._q_ranges))
+    want = run()
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if head_dim == 64:
+            assert np.array_equal(a, b), name
+        else:
+            assert _rel_rms(a, b) < U / 16, (name, _rel_rms(a, b) / U)

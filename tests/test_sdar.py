@@ -200,18 +200,21 @@ def test_blocked_kernels_visit_exactly_the_tiles_with_a_visible_pair(
         assert np.array_equal(got, back[:, n]), k0
 
 
-def test_the_cells_layers_visit_under_three_tenths_of_the_square():
+def test_the_cells_layers_visit_under_a_third_of_the_square():
     """8,192-token samples, 16,384 positions, blocks of 4: 67,141,632
     visible pairs a head of 268,435,456 (0.250); by hand, with Q blocks of
-    256 and K chunks of 512: 32 noised Q blocks see their own noised tile
-    and ceil((256 i + 252) / 512) clean chunks, 32 clean ones
-    ceil((256 i + 256) / 512): 32 + 272 + 272 of 64 x 32 tiles."""
+    256 and K chunks of 1024 (PR 35; 512 until then): 32 noised Q blocks
+    see their own noised tile and ceil((256 i + 252) / 1024) clean chunks,
+    32 clean ones ceil((256 i + 256) / 1024): 32 + 144 + 144 of 64 x 16
+    tiles."""
     s = dict(seq=8192, block_length=4)
     assert family.visible_pairs(s) == 67_141_632 == (
         33_570_816 + 33_538_048 + 32_768)
     visited, total = pk.kv_blocks(16384, False, 0, (8192, 4))
-    assert (visited, total) == (32 + 272 + 272, 64 * 32)
-    assert 0.25 < visited / total < 0.30
+    assert (visited, total) == (32 + 144 + 144, 64 * 16)
+    assert 0.25 < visited / total < 1 / 3
+    assert pk._seq_block(16384, (8192, 4)) == 1024
+    assert pk._seq_block(3072, (1536, 12)) == 512     # divides L
     assert pk.kv_blocks(512, False, 0, (256, 4)) == (1, 1)  # whole tile
 
 

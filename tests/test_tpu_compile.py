@@ -175,6 +175,33 @@ class TestFlashKernels:
         assert layout_faults(hlo, q.size * 2) == []
         assert ("tpu_custom_call_flash_bwd_blocked" in hlo) == (seq > 1024)
 
+    @pytest.mark.parametrize("heads,seq,window", [
+        (7, 16384, 4096), (7, 16384, 0), (4, 8192, 0), (7, 16384, 1000)])
+    def test_causal_and_window_split_compile_at_the_cells_shapes(
+            self, topo, heads, seq, window):
+        """The smallthinker cell's 7 heads of 128 at 16,384 under a
+        window of 4096 and under none, and the nemotron cell's 4 at
+        8,192: forward and K-blocked backward whose loops are cut into
+        far edge, interior and diagonal (PR 35), each a `fori_loop` with
+        bounds computed from the grid index, inside the 96 MiB budget;
+        and a window so narrow that the interior range is empty."""
+        q = jax.ShapeDtypeStruct((1, seq, heads * 128), jnp.bfloat16,
+                                 sharding=SingleDeviceSharding(
+                                     topo.devices[0]))
+
+        def grads(q, k, v):
+            def loss(q, k, v):
+                return pk._flash(q, k, v, heads, True, False,
+                                 window).astype(jnp.float32).sum()
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        hlo = _compile(grads, q, q, q)
+        assert pallas_kernel_count(hlo) == 2
+        assert layout_faults(hlo, q.size * 2) == []
+        assert "tpu_custom_call_flash_bwd_blocked" in hlo
+        assert 0 < pk.kv_blocks_masked(seq, True, window) <= (
+            pk.kv_blocks(seq, True, window)[0])
+
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
     @pytest.mark.parametrize("batch,heads,seq,head_dim", [
         # whole-tile kernels: 8 heads a step, then 2 at their longest,

@@ -394,14 +394,16 @@ def test_moe_gather_combine_ops_counts_the_models_expert_layers(
 def test_window_attention_gauges_show_that_the_skip_engaged(
         seq, window, tmp_path, monkeypatch, no_open_session):
     """`executor.window_attention_ops`, `attention/kv_blocks_visited` and
-    `attention/kv_blocks_total` (PR 31), set when the train step is
-    traced: in the registry's snapshot and in the header of a session.
+    `attention/kv_blocks_total` (PR 31) and `attention/kv_blocks_masked`
+    (PR 35), set when the train step is traced: in the registry's
+    snapshot and in the header of a session.
     A blocked causal layer visits the K blocks up to the diagonal and a
-    windowed one fewer; the whole-tile kernels hold one tile and mask."""
+    windowed one fewer, and masks only those that hold a hidden pair;
+    the whole-tile kernels hold one tile and mask."""
     import numpy as np
     from flexflow_tpu import (FFConfig, FFModel, LossType, MetricsType,
                               SGDOptimizer)
-    from flexflow_tpu.ops.pallas_kernels import kv_blocks
+    from flexflow_tpu.ops.pallas_kernels import kv_blocks, kv_blocks_masked
 
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
     b, e = 1, 32
@@ -416,6 +418,7 @@ def test_window_attention_gauges_show_that_the_skip_engaged(
     context = obs.model_context(ff)
     assert context["window_attention_ops"] == (1 if window else 0)
     assert context["attention_kv_blocks_total"] == 0      # not traced yet
+    assert context["attention_kv_blocks_masked"] == 0
     rs = np.random.RandomState(0)
     x = rs.randn(b, seq, e).astype(np.float32)
     y = rs.randn(b, seq, 1).astype(np.float32)
@@ -425,22 +428,33 @@ def test_window_attention_gauges_show_that_the_skip_engaged(
     paths = obs.stop_trace()
     full, total = kv_blocks(seq, True, 0)
     part, _ = kv_blocks(seq, True, window)
+    masked = kv_blocks_masked(seq, True, 0) + kv_blocks_masked(
+        seq, True, window)
     header, _ = read_events(paths["events"])
     gauges = json.load(open(paths["counters"]))["gauges"]
     for got in (
             (header["window_attention_ops"],
              header["attention_kv_blocks_visited"],
-             header["attention_kv_blocks_total"]),
+             header["attention_kv_blocks_total"],
+             header["attention_kv_blocks_masked"]),
             (gauges["executor.window_attention_ops"],
              gauges["attention/kv_blocks_visited"],
-             gauges["attention/kv_blocks_total"])):
-        assert got == (1 if window else 0, full + part, 2 * total)
+             gauges["attention/kv_blocks_total"],
+             gauges["attention/kv_blocks_masked"])):
+        assert got == (1 if window else 0, full + part, 2 * total, masked)
     if seq > 1024:
         assert full < total
         assert (part < full) == bool(window)
+        # a full layer masks its diagonal alone; at this window no tile
+        # of the windowed layer is wholly visible
+        diagonal = seq // 256
+        assert masked == diagonal + (part if window else diagonal)
+        assert masked < full + part
+    else:
+        assert masked == 2
 
 
-@pytest.mark.parametrize("seq,block", [(2048, 4), (256, 32)])
+@pytest.mark.parametrize("seq,block", [(4096, 4), (256, 32)])
 def test_block_diffusion_gauges_and_the_target_counter(
         seq, block, tmp_path, monkeypatch, no_open_session):
     """`executor.block_diffusion_attention_ops` and the
@@ -451,7 +465,7 @@ def test_block_diffusion_gauges_and_the_target_counter(
     import numpy as np
     from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
     from flexflow_tpu.dataloader import block_diffusion_batch
-    from flexflow_tpu.ops.pallas_kernels import kv_blocks
+    from flexflow_tpu.ops.pallas_kernels import kv_blocks, kv_blocks_masked
 
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
     b, e, half = 1, 32, seq // 2
@@ -468,6 +482,7 @@ def test_block_diffusion_gauges_and_the_target_counter(
     context = obs.model_context(ff)
     assert context["block_diffusion_attention_ops"] == 1
     assert context["attention_kv_blocks_total"] == 0      # not traced yet
+    assert context["attention_kv_blocks_masked"] == 0
     assert context["loss_target_positions"] is None
     rs = np.random.default_rng(0)
     _, labels = block_diffusion_batch(rs.integers(0, 15, (2 * b, half)),
@@ -481,6 +496,8 @@ def test_block_diffusion_gauges_and_the_target_counter(
     paths = obs.stop_trace()
     masked, total = kv_blocks(seq, False, 0, (half, block))
     causal, causal_total = kv_blocks(seq, True, 0)
+    edge = kv_blocks_masked(seq, False, 0, (half, block)) + (
+        kv_blocks_masked(seq, True, 0))
     header, _ = read_events(paths["events"])
     gauges = json.load(open(paths["counters"]))["gauges"]
     for got in (
@@ -488,15 +505,23 @@ def test_block_diffusion_gauges_and_the_target_counter(
              header["window_attention_ops"],
              header["attention_kv_blocks_visited"],
              header["attention_kv_blocks_total"],
+             header["attention_kv_blocks_masked"],
              header["loss_target_positions"]),
             (gauges["executor.block_diffusion_attention_ops"],
              gauges["executor.window_attention_ops"],
              gauges["attention/kv_blocks_visited"],
              gauges["attention/kv_blocks_total"],
+             gauges["attention/kv_blocks_masked"],
              gauges["loss/target_positions"])):
-        assert got == (1, 0, masked + causal, total + causal_total, targets)
+        assert got == (1, 0, masked + causal, total + causal_total, edge,
+                       targets)
+    assert ff.op_counters["attention/kv_blocks_masked"] == edge
     if seq > 1024:
-        # a quarter of the square and the tiles on its two diagonals (in
-        # chunks of 512 keys), where the causal layer visits the half
-        # under one (in chunks of 1024)
+        # a quarter of the square and the tiles on its two diagonals,
+        # where the causal layer visits the half under one
         assert masked / total < causal / causal_total < 1
+        # of them the noised diagonal tile and the last clean chunk of a
+        # Q block, and the causal layer's diagonal, run the masked body
+        assert edge == 8 + 16 + 16 < masked + causal
+    else:
+        assert edge == 2                                  # whole tiles
