@@ -25,7 +25,7 @@ from flexflow_tpu.ffconst import CompMode, LossType, OperatorType
 from flexflow_tpu.losses import get_loss_fn, target_positions
 from flexflow_tpu.metrics import Metrics
 from flexflow_tpu.obs.registry import get_registry
-from flexflow_tpu.ops.base import Op, OpContext
+from flexflow_tpu.ops.base import Op, OpContext, scoped
 from flexflow_tpu.parallel.choice import ExecPlan
 
 
@@ -35,6 +35,9 @@ from flexflow_tpu.parallel.choice import ExecPlan
 COMPUTE_PARAMS_KEY = "__compute_params__"
 # beside a counter that is a mean over ops and steps: how many were added
 COUNT_SUFFIX = "#n"
+# what an op's forward leaves on the op for `_run_nodes` to pick up; traced
+# values, so a forward run as a nested call hands them out as results
+SIDE_CHANNELS = ("_aux_loss", "_counters", "_new_state", "_new_states")
 
 
 def settled_spec(spec: P) -> P:
@@ -503,6 +506,62 @@ class GraphExecutor:
                 values[self.final_ref], TO_NCHW)
         return values, new_state, aux_losses
 
+    def _scoped_forward(self, op, ctx: OpContext):
+        """``op``'s forward as ``forward(params, args[, state])``, for
+        the device trace. An op that names its own nested calls
+        (``Op.scopes_itself``) runs as it is. Every other op runs as
+        one nested call (``ops.base.scoped``) named for its kind,
+        ``op_<operator type, lower case>``, and the op that produces the
+        model's output as ``head``: every instruction of the compiled
+        step then says in its ``op_name`` which op kind it came from,
+        and ``jvp`` / ``transpose`` beside it which direction
+        (obs/step_scopes.py). What a forward leaves behind as traced
+        values (``SIDE_CHANNELS`` on the op, the context's rng) is
+        handed out of the nested call and put back where
+        ``_run_nodes`` looks for it, so no tracer of the inner trace
+        outlives it."""
+        stateful = (getattr(op, "param_sources", None) is not None
+                    or hasattr(op, "init_state"))
+
+        def plain(params, args, state=None):
+            if stateful:
+                return op.forward(params, args, ctx, state=state)
+            return op.forward(params, args, ctx)
+
+        if getattr(op, "scopes_itself", False):
+            return plain
+        if op.guid == self.final_ref[0]:
+            name = "head"
+        else:
+            layer = getattr(op, "layer", None)
+            name = "op_" + getattr(layer, "op_type", op.op_type).name.lower()
+
+        def inner(params, args, state, rng):
+            ctx.rng = rng
+            outs = plain(params, args, state)
+            side = {}
+            for k in SIDE_CHANNELS:
+                if getattr(op, k, None) is not None:
+                    side[k] = getattr(op, k)
+                    setattr(op, k, None)
+            # None unless the op drew from the rng
+            return tuple(outs), side, None if ctx.rng is rng else ctx.rng
+
+        def forward(params, args, state=None):
+            rng = ctx.rng
+            try:
+                outs, side, drawn = scoped(name, inner)(
+                    params, list(args), state, rng)
+            finally:
+                ctx.rng = rng
+            if drawn is not None:
+                ctx.rng = drawn
+            for k, v in side.items():
+                setattr(op, k, v)
+            return outs
+
+        return forward
+
     def _run_nodes(self, nodes, params, state, inputs, values, new_state,
                    aux_losses, ctx: OpContext, counters=None):
         """Evaluate the given nodes in order, reading/writing the shared
@@ -540,14 +599,15 @@ class GraphExecutor:
                 fetch(ref, in_layouts[j] if in_layouts else "NCHW")
                 for j, ref in enumerate(node.input_refs)
             ]
+            forward = self._scoped_forward(op, ctx)
             sources = getattr(op, "param_sources", None)
             if sources is not None:
                 # fused execution-time op (FoldedConvBN eval fold /
                 # TrainFusedConvBN searched kernel): reads the
                 # parameter/state subtrees of the ops it folded
-                outs = op.forward(
-                    {s: params.get(s, {}) for s in sources}, args, ctx,
-                    state={s: state.get(s) for s in sources})
+                outs = forward(
+                    {s: params.get(s, {}) for s in sources}, args,
+                    {s: state.get(s) for s in sources})
                 # train-time fused regions update their sources' state
                 # (BN running stats) under the SOURCE names, keeping the
                 # state tree's shape checkpoint-compatible
@@ -564,8 +624,8 @@ class GraphExecutor:
                                             "init_state"):
                             new_state.setdefault(s, state[s])
             elif hasattr(op, "init_state"):
-                outs = op.forward(params.get(op.name, {}), args, ctx,
-                                  state=state.get(op.name))
+                outs = forward(params.get(op.name, {}), args,
+                               state.get(op.name))
                 if getattr(op, "_new_state", None) is not None:
                     new_state[op.name] = op._new_state
                     op._new_state = None
@@ -575,13 +635,14 @@ class GraphExecutor:
                     and op.name in self.remat_ops:
                 # searched '_r' choice: checkpoint the op's boundary and
                 # recompute its interior in backward (gate-legal ops are
-                # stateless with no aux side channel)
+                # stateless with no aux side channel); the op's scope
+                # lies inside the checkpoint, so the recomputation
+                # carries it too
                 outs = jax.checkpoint(
-                    lambda p_, a_, f_=op.forward: tuple(f_(p_, list(a_),
-                                                           ctx))
+                    lambda p_, a_: tuple(forward(p_, list(a_)))
                 )(params.get(op.name, {}), tuple(args))
             else:
-                outs = op.forward(params.get(op.name, {}), args, ctx)
+                outs = forward(params.get(op.name, {}), args)
             if getattr(op, "_aux_loss", None) is not None:
                 aux_losses.append(op._aux_loss)
                 op._aux_loss = None
@@ -715,10 +776,8 @@ class GraphExecutor:
                     p, state, inputs, ctx, nodes=train_nodes,
                     counters=counters)
                 logits = values[self.final_ref]
-                # named for the device trace: an op's `op_name` holds
-                # `loss/` here and `optimizer_update/` below, beside the
-                # `jvp`/`transpose` that tell forward from backward
-                with jax.named_scope("loss"):
+
+                def loss_of(logits, labels, aux):
                     loss = self._loss_value(logits, labels)
                     for a in aux:
                         loss = loss + a
@@ -726,8 +785,12 @@ class GraphExecutor:
                             LossType.WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY):
                         # leaves the step with the ops' counters: the
                         # positions that carried a target
-                        counters["loss/target_positions"] = target_positions(
-                            labels)
+                        return loss, target_positions(labels)
+                    return loss, None
+
+                loss, targets = scoped("loss", loss_of)(logits, labels, aux)
+                if targets is not None:
+                    counters["loss/target_positions"] = targets
                 return loss, (logits, new_state, counters)
 
             (loss, (logits, new_state, counters)), grads = jax.value_and_grad(
@@ -747,19 +810,25 @@ class GraphExecutor:
             # reduce-scatter: each chip receives only the gradient shard
             # whose master-param/moment shard it owns.
             grads = self._wus_shard(grads)
-            with jax.named_scope("optimizer_update"):
+
+            def update(grads, opt_state, params):
                 new_params, new_opt_state = self._optimizer_update(
-                    grads, opt_state, params
-                )
+                    grads, opt_state, params)
                 new_params = self._wus_shard(new_params)
-                if self.use_master_copy:
-                    # next step's bf16 working copy, fused into the update
-                    # loop (one extra bf16 write instead of a separate cast
-                    # pass; under WUS the compute-spec constraint is the
-                    # all-gather that rebuilds the replicated copy from the
-                    # shards)
-                    new_state[COMPUTE_PARAMS_KEY] = self._constrain_compute(
-                        self._cast_tree(new_params))
+                if not self.use_master_copy:
+                    return new_params, new_opt_state, None
+                # next step's bf16 working copy, fused into the update
+                # loop (one extra bf16 write instead of a separate cast
+                # pass; under WUS the compute-spec constraint is the
+                # all-gather that rebuilds the replicated copy from the
+                # shards)
+                return new_params, new_opt_state, self._constrain_compute(
+                    self._cast_tree(new_params))
+
+            new_params, new_opt_state, compute_copy = scoped(
+                "optimizer_update", update)(grads, opt_state, params)
+            if compute_copy is not None:
+                new_state[COMPUTE_PARAMS_KEY] = compute_copy
             metric_vals = self.metrics.compute(logits, labels)
             # what the ops counted leaves the step with the metrics and is
             # read with them, once an epoch

@@ -1285,9 +1285,13 @@ class FFModel:
         and lets the exception carry ``PREEMPTED_EXIT`` out."""
         from flexflow_tpu.ckpt import faults as _faults
         from flexflow_tpu.obs import NULL_CAPTURE, NULL_TRACER
+        from flexflow_tpu.obs.session import step_keeper
         tracer = tracer or NULL_TRACER
         devtrace = devtrace or NULL_CAPTURE
         train_step = self.executor.make_train_step()
+        # None unless a session with the profiler is open and has not
+        # seen this executor's step yet
+        keep_step = step_keeper(self.executor)
         self._refresh_compute_params()
         tracer.setup_done()
         start = time.time()
@@ -1320,6 +1324,13 @@ class FFModel:
                     with tracer.phase("rng_split"):
                         self._rng, sub = jax.random.split(self._rng)
                     with tracer.phase("dispatch"):
+                        if keep_step is not None:
+                            # shapes and shardings of this call, for the
+                            # session's join table (obs/step_scopes.py)
+                            keep_step(train_step, (
+                                self.params, self.opt_state, self.state,
+                                inputs, labels, sub))
+                            keep_step = None
                         (self.params, self.opt_state, self.state, loss,
                          mvals) = train_step(
                             self.params, self.opt_state, self.state,

@@ -67,16 +67,18 @@ def atomic_write_text(path: str, text: str) -> None:
 def write_artifact(path: str, payload: Dict[str, Any],
                    host_id: Optional[int] = None,
                    kind: Optional[str] = None,
-                   header_extra: Optional[Dict[str, Any]] = None) -> str:
+                   header_extra: Optional[Dict[str, Any]] = None,
+                   indent: Optional[int] = 1) -> str:
     """Stamp ``payload`` with the provenance header (plus any
     ``header_extra`` fields, e.g. the tracer's run_name) and write it
-    atomically. Returns ``path``."""
+    atomically (``indent=None``: on one line, for a table of many
+    thousand rows). Returns ``path``."""
     body = dict(payload)
     if "header" not in body:
         header = artifact_header(host_id=host_id, kind=kind)
         header.update(header_extra or {})
         body["header"] = header
-    atomic_write_text(path, json.dumps(body, indent=1, default=_json_safe))
+    atomic_write_text(path, json.dumps(body, indent=indent, default=_json_safe))
     return path
 
 

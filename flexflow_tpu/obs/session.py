@@ -22,12 +22,19 @@ the shift lands in the artifact's header as ``clock_shift_us`` (add it
 to a profiler timestamp in microseconds to get a span's ``ts``).
 
 ``stop_trace`` writes the spans and a counters snapshot and returns.
-It compiles nothing and replays nothing: the summary, drift and
-simulator reports stay with the per-call ``fit(trace_dir=...)`` form.
+It replays nothing: the summary, drift and simulator reports stay with
+the per-call ``fit(trace_dir=...)`` form. With ``device=True`` it also
+writes the join table of the train step the session's ``fit`` calls
+ran (``<stem>.step_scopes.json``, obs/step_scopes.py): once the
+profiler has stopped, the step is lowered and compiled once more from
+the shapes and shardings its first call had, and every instruction's
+part and direction are read from the text. Without ``device=True``
+nothing is lowered and nothing compiled.
 """
 
 from __future__ import annotations
 
+import functools
 import glob
 import os
 import statistics
@@ -35,6 +42,7 @@ import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from flexflow_tpu.obs.registry import get_registry
+from flexflow_tpu.obs.step_scopes import StepScopes
 from flexflow_tpu.obs.tracer import StepTracer
 
 # the marker the clock tie looks up in the profile
@@ -48,6 +56,17 @@ _SESSION: Optional["TraceSession"] = None
 def session_tracer() -> Optional[StepTracer]:
     """The open session's tracer, or None."""
     return _SESSION.tracer if _SESSION is not None else None
+
+
+def step_keeper(executor):
+    """``keep(step, args)`` if a session with ``device=True`` is open
+    and holds no train step of ``executor`` yet, else None: ``fit``'s
+    first dispatch hands it the jitted step and the call's arguments,
+    of which the shapes and shardings are kept for the join table."""
+    scopes = _SESSION.step_scopes if _SESSION is not None else None
+    if scopes is None or not scopes.wants(executor):
+        return None
+    return functools.partial(scopes.keep, executor)
 
 
 # ---------------------------------------------------------------------------
@@ -135,6 +154,8 @@ class TraceSession:
         self.profile_dir = os.path.join(
             trace_dir, self.tracer.file_stem + ".jaxprof")
         self._brackets: List[Tuple[float, float]] = []
+        # the train steps dispatched under the profiler, for their tables
+        self.step_scopes = StepScopes() if device else None
         if device:
             start_profiler(self.profile_dir)
             self._mark()
@@ -155,7 +176,7 @@ class TraceSession:
 
     def stop(self) -> Dict[str, Optional[str]]:
         tracer = self.tracer
-        xplane = None
+        xplane = step_scopes = None
         if self.device:
             self._mark()
             stop_profiler()
@@ -164,12 +185,21 @@ class TraceSession:
                 tracer.set_meta(
                     xplane=os.path.relpath(xplane, tracer.trace_dir),
                     **self._tie(xplane))
+            # the profiler has stopped: what lowering and compiling the
+            # step again costs falls into no traced second
+            meta = self.step_scopes.write(
+                tracer.trace_dir, tracer.file_stem, host_id=tracer.host_id)
+            tracer.set_meta(**meta)
+            if "step_scopes" in meta:
+                step_scopes = os.path.join(tracer.trace_dir,
+                                           meta["step_scopes"])
         paths: Dict[str, Optional[str]] = dict(tracer.export())
         paths["counters"] = get_registry().export(
             os.path.join(tracer.trace_dir,
                          tracer.file_stem + ".counters.json"),
             host_id=tracer.host_id)
         paths["xplane"] = xplane
+        paths["step_scopes"] = step_scopes
         return paths
 
 
@@ -185,9 +215,10 @@ def start_trace(trace_dir: str, device: bool = True) -> TraceSession:
 
 def stop_trace() -> Dict[str, Optional[str]]:
     """Close the session: stop the profiler, write the spans
-    (``*.trace.json``, ``*.events.jsonl``) and the counters snapshot.
-    Returns their paths and the ``.xplane.pb`` path (None without
-    ``device=True``)."""
+    (``*.trace.json``, ``*.events.jsonl``), the counters snapshot and,
+    with ``device=True``, the train step's join table
+    (``*.step_scopes.json``). Returns their paths and the ``.xplane.pb``
+    path (both None without ``device=True``)."""
     global _SESSION
     session, _SESSION = _SESSION, None
     if session is None:

@@ -98,6 +98,8 @@ class MultiHeadAttention(Op):
     forward multiplies through their [E, H*D] / [H*D, E] views.
     """
 
+    scopes_itself = True
+
     def __init__(self, layer, input_shapes):
         p = layer.properties
         self.embed_dim = p["embed_dim"]
@@ -254,33 +256,44 @@ class MultiHeadAttention(Op):
         # the op's rng is split off here: inside the nested call below it
         # would leave a tracer of that call in `ctx`
         rng = ctx.next_rng() if (self.dropout > 0 and ctx.training) else None
-        if self.block_diffusion:
-            return self._scoped_forward(params, inputs, ctx, rng,
-                                        "block_diffusion")
-        if not self.causal:
-            return self._forward(params, inputs, ctx, rng, None)
-        # the ops under the causal visibility rule carry a scope for the
-        # device trace: `attention_window` where the window hides
-        # something, else `attention_full`; in it `flash_window` /
+        # every op carries a scope for the device trace. Under the
+        # causal visibility rule `attention_window` where the window
+        # hides something, else `attention_full`; in it `flash_window` /
         # `flash_full` around the kernel calls. XLA names an instruction
         # after the first scope inside the nested call it came from, so a
         # causal op's kernel events read `flash_window.N` / `flash_full.N`
         # (as the grouped products' read `gmm.N`); the kernel's own name
         # stays in `op_name`. Under the block-diffusion mask the scopes
         # are `attention_block_diffusion` / `flash_block_diffusion`.
-        # Other non-causal ops run unscoped, as they did.
-        kind = "window" if self.windowed else "full"
-        return self._scoped_forward(params, inputs, ctx, rng, kind)
-
-    def _scoped_forward(self, params, inputs, ctx, rng, kind):
+        if self.block_diffusion:
+            kind = "block_diffusion"
+        elif self.causal:
+            kind = "window" if self.windowed else "full"
+        else:
+            # a non-causal op's kernel events keep the name of a
+            # top-level call, `tpu_custom_call*` (what
+            # `kernels.flash_roofline` sums): `attention_plain` lies
+            # around the kernel calls, not over them
+            return self._forward(params, inputs, ctx, rng, None,
+                                 functools.partial(scoped, "attention_plain"))
         return scoped("attention_" + kind,
                       lambda params, inputs: self._forward(
                           params, inputs, ctx, rng, "flash_" + kind))(
                               params, inputs)
 
-    def _forward(self, params, inputs, ctx: OpContext, rng, flash_scope):
-        from flexflow_tpu.ops.pallas_kernels import merge_heads, split_heads
+    def _forward(self, params, inputs, ctx: OpContext, rng, flash_scope,
+                 around=lambda fn: fn):
+        """Projections, core, output projection. ``around`` wraps every
+        piece but a flash kernel call (the non-causal op's scope)."""
+        q, k, v = around(lambda params, inputs: self._qkv(
+            params, inputs, ctx))(params, inputs)
+        o = self._core(q, k, v, ctx, rng, flash_scope, around)
+        return [around(lambda params, o: self._output(
+            params, o, ctx, inputs[0].dtype))(params, o)]
 
+    def _qkv(self, params, inputs, ctx: OpContext):
+        """q, k, v [B, S, heads*head_dim] in the compute dtype: the
+        projections, the heads' norms, rotary and the K/V repeat."""
         query, key, value = (inputs + inputs[:1] * 2)[:3] if len(inputs) == 1 else inputs
         cd = ctx.compute_dtype
         h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
@@ -305,17 +318,34 @@ class MultiHeadAttention(Op):
         if hk != h:
             k, v = (jnp.repeat(x.reshape(b, sk, hk, d), h // hk, axis=2
                                ).reshape(b, sk, h * d) for x in (k, v))
-        dropout_rate = self.dropout if ctx.training else 0.0
         # the attention core consumes q/k/v in the compute dtype (the
         # projections accumulate in f32): softmax/accumulation inside every
         # path below is f32 regardless, and bf16 kernel I/O halves the
         # flash kernel's HBM traffic
-        q, k, v = q.astype(cd), k.astype(cd), v.astype(cd)
+        return q.astype(cd), k.astype(cd), v.astype(cd)
+
+    def _output(self, params, o, ctx: OpContext, dtype):
+        cd = ctx.compute_dtype
+        h, d = self.num_heads, self.head_dim
+        y = jnp.dot(o.astype(cd), params["wo"].astype(cd).reshape(h * d, -1),
+                    preferred_element_type=jnp.float32)
+        if self.use_bias:
+            y = y + params["bo"]
+        return y.astype(dtype)
+
+    def _core(self, q, k, v, ctx: OpContext, rng, flash_scope, around):
+        from flexflow_tpu.ops.pallas_kernels import merge_heads, split_heads
+
+        cd = ctx.compute_dtype
+        h, d = self.num_heads, self.head_dim
+        b, sq, sk = q.shape[0], q.shape[1], k.shape[1]
+        dropout_rate = self.dropout if ctx.training else 0.0
 
         def heads_first(core):
             """A core that wants [B, H, S, D], at its own boundary."""
-            return merge_heads(core(split_heads(q, h), split_heads(k, h),
-                                    split_heads(v, h)))
+            return around(lambda q, k, v: merge_heads(core(
+                split_heads(q, h), split_heads(k, h), split_heads(v, h)))
+            )(q, k, v)
 
         seq_axis = self.seq_parallel
         mesh_axes = (dict(zip(ctx.mesh.axis_names, ctx.mesh.devices.shape))
@@ -339,11 +369,11 @@ class MultiHeadAttention(Op):
             # ring, scores never leave the shard
             from flexflow_tpu.parallel.ring_attention import ring_attention
 
-            o = heads_first(lambda q, k, v: ring_attention(
+            return heads_first(lambda q, k, v: ring_attention(
                 q, k, v, ctx.mesh, seq_axis=seq_axis,
                 head_axis=self.head_parallel, causal=self.causal))
-        elif (self.kernel_impl != "einsum"
-              and dropout_rate == 0.0 and sq == sk):
+        if (self.kernel_impl != "einsum"
+                and dropout_rate == 0.0 and sq == sk):
             from flexflow_tpu.ops.pallas_kernels import (
                 flash_attention, flash_attention_available,
                 flash_attention_sharded, flash_shape_legal, kv_blocks,
@@ -393,34 +423,26 @@ class MultiHeadAttention(Op):
                                  and flash_shape_legal(
                                      sq, d, h // mesh_axes[hp])
                                  else None)
-                    o = flash(flash_attention_sharded, mesh=ctx.mesh,
-                              batch_axis=batch_axis, head_axis=head_axis)
-                else:
-                    o = flash(flash_attention)
-            else:
-                o = heads_first(lambda q, k, v: scaled_dot_product_attention(
-                    q, k, v, causal=self.causal, dropout_rate=0.0,
-                    rng=None, compute_dtype=cd, window=self.window,
-                    block_diffusion=self.block_diffusion))
-        else:
-            if self.kernel_impl == "flash" and self._kernel_fallback is None:
-                # forced flash but this forward cannot take the flash
-                # branch at all (attention-prob dropout in training, or
-                # cross-attention) — record the silent fallback so
-                # fflint FFL209 surfaces the priced-vs-executed gap
-                self._kernel_fallback = (
-                    f"flash has no lowering for this forward "
-                    f"(dropout_rate={dropout_rate}, Sq={sq}, "
-                    f"Sk={sk}) — einsum executed instead")
-            o = heads_first(lambda q, k, v: scaled_dot_product_attention(
-                q, k, v, causal=self.causal, dropout_rate=dropout_rate,
-                rng=rng, compute_dtype=cd, window=self.window,
+                    return flash(flash_attention_sharded, mesh=ctx.mesh,
+                                 batch_axis=batch_axis, head_axis=head_axis)
+                return flash(flash_attention)
+            return heads_first(lambda q, k, v: scaled_dot_product_attention(
+                q, k, v, causal=self.causal, dropout_rate=0.0,
+                rng=None, compute_dtype=cd, window=self.window,
                 block_diffusion=self.block_diffusion))
-        y = jnp.dot(o.astype(cd), params["wo"].astype(cd).reshape(h * d, -1),
-                    preferred_element_type=jnp.float32)
-        if self.use_bias:
-            y = y + params["bo"]
-        return [y.astype(query.dtype)]
+        if self.kernel_impl == "flash" and self._kernel_fallback is None:
+            # forced flash but this forward cannot take the flash
+            # branch at all (attention-prob dropout in training, or
+            # cross-attention) — record the silent fallback so
+            # fflint FFL209 surfaces the priced-vs-executed gap
+            self._kernel_fallback = (
+                f"flash has no lowering for this forward "
+                f"(dropout_rate={dropout_rate}, Sq={sq}, "
+                f"Sk={sk}) — einsum executed instead")
+        return heads_first(lambda q, k, v: scaled_dot_product_attention(
+            q, k, v, causal=self.causal, dropout_rate=dropout_rate,
+            rng=rng, compute_dtype=cd, window=self.window,
+            block_diffusion=self.block_diffusion))
 
     def selected_impl(self, mesh_axes=None, training: bool = False) -> str:
         """Which attention kernel ``forward`` will execute on THIS

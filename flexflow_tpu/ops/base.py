@@ -65,7 +65,28 @@ def scoped(name: str, fn: Callable) -> Callable:
     that come from a nested call (read on the v5e, PR 27: `dot_general`
     where the same step compiled for a described chip had
     `jit(train_step)/jvp(ssm_mixer)/ssd_scan/dot_general`). XLA inlines
-    the call, so nothing changes in what runs."""
+    the call, so nothing changes in what runs.
+
+    The one mechanism behind every name of a train step
+    (`obs/step_scopes.py` holds the rule that reads them back). Taken:
+    `loss`, `optimizer_update` (the executor's step), `head` (the op
+    that produces the model's output), `op_<operator type, lower case>`
+    (every op that does not scope itself; `GraphExecutor._run_nodes`),
+    `ssm_mixer` / `ssd_scan`, `moe_layer` / `moe_route` / `moe_combine`
+    / `moe_grouped_matmul` / `moe_shared`, `attention_window` /
+    `attention_full` / `attention_block_diffusion` with `flash_window` /
+    `flash_full` / `flash_block_diffusion` in them, and
+    `attention_plain`. The benchmark's readers match nine of them as
+    bare substrings of an `op_name`, so a new name holds none of them.
+
+    A nested call also renames the events of the kernels in it: XLA
+    names a custom call after the innermost scope (`flash_window.N`,
+    `gmm.N`) where a top-level one reads `tpu_custom_call.N`, and
+    `kernels.flash_roofline` sums the events of that name. So the
+    non-causal attention op scopes what lies around its kernels
+    (`attention_plain`: projections, rotary, repeat, output projection)
+    and calls the kernels themselves at the top level; the join table
+    names such an instruction by the kernel's own `name=`."""
     def call(*args):
         return fn(*args)
 
@@ -77,6 +98,9 @@ class Op:
     op_type: OperatorType = OperatorType.NOOP
     # parameter names the executor keeps in float32 in the compute copy
     full_precision_params: Tuple[str, ...] = ()
+    # the op's forward names its own nested calls (`scoped`); the
+    # executor wraps every other op in one named for its kind
+    scopes_itself: bool = False
 
     def __init__(self, layer: Layer, input_shapes: Sequence[Tuple[int, ...]]):
         self.layer = layer

@@ -174,6 +174,8 @@ class MoELayer(Op):
     alike.
     """
 
+    scopes_itself = True
+
     full_precision_params = ("w_router", "e_bias")
 
     def __init__(self, layer, input_shapes):

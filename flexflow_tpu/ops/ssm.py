@@ -160,6 +160,8 @@ class SSMMixer(Op):
     (dt_bias, a_log, d) stay float32 in the compute copy: they set every
     position's decay."""
 
+    scopes_itself = True
+
     full_precision_params = ("dt_bias", "a_log", "d")
 
     def __init__(self, layer, input_shapes):

@@ -55,7 +55,19 @@ def build_branchy(layout):
     return ff
 
 
+def _built(name):
+    """A layer's name ends in the process's layer counter: order by it,
+    not by the name (`conv2d_99` sorts after `conv2d_101` as a string,
+    and two models built one after the other then pair different
+    leaves)."""
+    stem, _, count = str(name).rpartition("_")
+    return (int(count), stem) if count.isdigit() else (-1, str(name))
+
+
 def leaves(tree):
+    if isinstance(tree, dict):
+        return [leaf for key in sorted(tree, key=_built)
+                for leaf in leaves(tree[key])]
     return [np.asarray(v) for v in jax.tree.leaves(tree)]
 
 

@@ -16,6 +16,7 @@ train step at depth 2; the full-depth steps of every `chip_smoke.py` arm
 four-chip call.
 """
 
+import collections
 import os
 import re
 
@@ -32,6 +33,7 @@ from flexflow_tpu import AdamOptimizer, FFConfig, LossType, MetricsType
 from flexflow_tpu.machine import MachineSpec, make_mesh
 from flexflow_tpu.models import TransformerConfig, create_transformer
 from flexflow_tpu.obs.inspect import pallas_kernel_count
+from flexflow_tpu.obs.step_scopes import table_of
 from flexflow_tpu.ops import pallas_kernels as pk
 
 
@@ -471,8 +473,21 @@ def test_wus_step_with_fused_update_compiles_for_four_chips(topo, on_tpu):
     # a forward and a backward per flash op, plus at least one update
     # kernel of the fused ops
     assert pallas_kernel_count(hlo) > 2 * flash
-    # the scopes that tell the optimizer and the loss from the layers
-    assert "/optimizer_update/" in hlo and "/jvp(loss)/" in hlo
+    # the nested calls that tell the optimizer and the loss from the
+    # layers; the update's kernels lie in its scope, the attention
+    # kernels at the top level, told apart by their own names
+    assert "/jit(optimizer_update)/" in hlo and "/jvp(jit(loss))/" in hlo
+    table = table_of(hlo)
+    kernels = collections.Counter(
+        (table[m.group(1)]["part"], table[m.group(1)]["direction"])
+        for m in re.finditer(r"^\s*%?([\w.\-]+) = [^\n]*custom_call_target="
+                             r'"tpu_custom_call"', hlo, re.M))
+    assert kernels[("attention", "forward")] == flash
+    assert kernels[("attention", "backward")] == flash
+    assert kernels[("optimizer_update", "optimizer")] >= 1
+    assert set(kernels) == {("attention", "forward"),
+                            ("attention", "backward"),
+                            ("optimizer_update", "optimizer")}
     # on each chip q, k, v, o reach the kernels as the projections wrote
     # them: no copy of a [8, 512, 1024] array, no 64-wide minor dimension
     # (the FFN kernels are as large there, and are copied as parameters)
@@ -496,6 +511,19 @@ def test_one_chip_step_keeps_qkvo_lane_dense(topo, on_tpu):
         if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(kernels) == ["flash_bwd"] * 2 + ["flash_fwd_whole"] * 2
     assert layout_faults(hlo, 32 * 512 * 1024 * 2) == []
+    # the non-causal op's kernels stay top-level calls (their events keep
+    # the name `tpu_custom_call*` that `kernels.flash_roofline` sums);
+    # what lies around them is under `attention_plain`
+    table = table_of(hlo)
+    calls = [table[m.group(1)] for m in re.finditer(
+        r'^\s*%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"',
+        hlo, re.M)]
+    assert all("jit(" not in c["op_name"].replace("jit(train_step)", "")
+               for c in calls)
+    assert sorted((c["part"], c["direction"]) for c in calls) == [
+        ("attention", "backward")] * 2 + [("attention", "forward")] * 2
+    assert "jvp(jit(attention_plain))" in hlo
+    assert "transpose(jvp(jit(attention_plain)))" in hlo
     # the guard sees the form it guards against
     assert layout_faults(
         "  %copy.1 = bf16[32,16,512,64]{3,2,1,0:T(8,128)(2,1)} copy(%x)\n"

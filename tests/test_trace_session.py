@@ -6,6 +6,7 @@ nothing compiled at the end; no session, no tracer; the clock tie on a
 CPU profiler trace; `compile_phases` in the header.
 """
 
+import functools
 import glob
 import json
 import os
@@ -213,15 +214,63 @@ def test_stop_trace_neither_lowers_nor_compiles(model, tmp_path,
     jits = obs.get_registry().get("executor.train_step_jits")
     seen = Compiles()
     try:
-        obs.stop_trace()
+        paths = obs.stop_trace()
     finally:
         seen.on = False
     assert seen.n == 0
+    assert paths["step_scopes"] is None
     assert obs.get_registry().get("executor.train_step_jits") == jits
     seen.on = True   # the control: the listener does see a compile
     jax.jit(lambda a: a + 1)(1.0)
     seen.on = False
     assert seen.n > 0
+
+
+def test_stop_trace_with_the_profiler_lowers_the_step_once_after_it(
+        model, tmp_path, monkeypatch, no_open_session):
+    """`device=True`: the session keeps the shapes of the first
+    dispatched train step (and of no later one) and `stop_trace` lowers
+    the step once for its join table, after the profiler has stopped."""
+    ff, x, y = model
+    order = []
+    stop_profiler = obs_session.stop_profiler
+    monkeypatch.setattr(obs_session, "stop_profiler",
+                        lambda: (stop_profiler(), order.append("profiler")))
+    session = obs.start_trace(str(tmp_path), device=True)
+    ff.fit(x, y, epochs=1, verbose=False)
+    kept = dict(session.step_scopes._steps)
+    assert list(kept) == [id(ff.executor)]
+    step, args = kept[id(ff.executor)]
+    assert step is ff.executor.make_train_step()
+    # shapes only: nothing of the call's arrays stays alive
+    assert all(isinstance(a, jax.ShapeDtypeStruct)
+               for a in jax.tree.leaves(args))
+    ff.fit(x, y, epochs=1, verbose=False)
+    assert session.step_scopes._steps[id(ff.executor)][1] is args
+    jits = obs.get_registry().get("executor.train_step_jits")
+    monkeypatch.setattr(
+        type(session.step_scopes), "write", functools.partialmethod(
+            lambda self, *a, _write=type(session.step_scopes).write, **kw: (
+                order.append("table"), _write(self, *a, **kw))[1]))
+
+    def seen(event, duration, **_):
+        if event.endswith("jaxpr_to_mlir_module_duration"):
+            order.append("lowering")
+
+    jax.monitoring.register_event_duration_secs_listener(seen)
+    try:
+        paths = obs.stop_trace()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(seen)
+    # (JAX answers the lowering from its caches where the shapes are
+    # those of the call that ran: then it is not even one)
+    assert order in (["profiler", "table"],
+                     ["profiler", "table", "lowering"])
+    assert os.path.exists(paths["step_scopes"])
+    assert obs.get_registry().get("executor.train_step_jits") == jits
+    header, _ = read_events(paths["events"])
+    assert header["step_scopes_s"] > 0
+    assert header["step_scopes_instructions"] > 100
 
 
 def test_no_session_no_tracer(model, monkeypatch):
