@@ -701,6 +701,15 @@ class GraphExecutor:
         return sum(bool(getattr(n.op, "_gather_combine", False))
                    for n in self.nodes)
 
+    def moe_sum_rows_ops(self) -> int:
+        """`MoELayer` ops whose forward, as last traced, had
+        `tokens_from_rows` add the buffer's rows into their tokens by the
+        kernel `moe_sum_rows` (PR 37; 0 where a layer holds all its
+        experts, and on the CPU): the gauge `executor.moe_sum_rows_ops`,
+        and `moe_sum_rows_ops` in every trace header."""
+        return sum(bool(getattr(n.op, "_sum_rows", False))
+                   for n in self.nodes)
+
     def attention_gauges(self) -> Dict[str, int]:
         """What the attention ops' forwards, as last traced, recorded on
         the host (PR 31): the ops whose window hides something at their
@@ -804,6 +813,8 @@ class GraphExecutor:
                 get_registry().gauge(gauge, value)
             get_registry().gauge("executor.moe_gather_combine_ops",
                                  self.moe_gather_combine_ops())
+            get_registry().gauge("executor.moe_sum_rows_ops",
+                                 self.moe_sum_rows_ops())
             # gradient sync over the data axes is inserted by GSPMD here
             # (in bf16 under the master-weight regime — half the bytes).
             # Under WUS the shard constraint turns that all-reduce into a

@@ -1429,6 +1429,8 @@ class FFModel:
             (k, float(v)) for k, v in self.executor.attention_gauges().items())
         self.op_counters["executor.moe_gather_combine_ops"] = float(
             self.executor.moe_gather_combine_ops())
+        self.op_counters["executor.moe_sum_rows_ops"] = float(
+            self.executor.moe_sum_rows_ops())
 
     def fit(self, x=None, y=None, batch_size: Optional[int] = None,
             epochs: Optional[int] = None, verbose: bool = True,

@@ -332,6 +332,9 @@ def model_context(ff) -> Dict[str, Any]:
            for k, v in ff.executor.attention_gauges().items()},
         # expert layers whose traced forward moved rows by gathers only
         moe_gather_combine_ops=ff.executor.moe_gather_combine_ops(),
+        # of them, those that added the rows into their tokens by the
+        # kernel `moe_sum_rows`
+        moe_sum_rows_ops=ff.executor.moe_sum_rows_ops(),
         # positions that carried a target in the last epoch of a weighted
         # loss (None before one, or under another loss)
         loss_target_positions=(getattr(ff, "op_counters", None) or {}).get(

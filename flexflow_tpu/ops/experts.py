@@ -29,7 +29,8 @@ from flexflow_tpu.ops.base import (DimRole, Op, OpContext, register_op,
 from flexflow_tpu.ops.moe import (combine_rows, expert_capacity,
                                   grouped_matmul, load_balance_loss,
                                   make_dispatch_tensors, route_held_experts,
-                                  route_scores, rows_from_tokens)
+                                  route_scores, rows_from_tokens,
+                                  sums_rows_by_kernel)
 
 
 @register_op(OperatorType.EXPERTS)
@@ -161,7 +162,10 @@ class MoELayer(Op):
     second), because the transpose autodiff picks for a gather is a
     scatter-add, which this chip runs row by row at 0.43 us a row: two of
     them took 63 of a 226 ms step (ops/moe.py). Both calls run under the
-    nested scope `moe_combine`.
+    nested scope `moe_combine`. Where the layer holds a small share of
+    the experts, on the TPU, `tokens_from_rows` reads the buffer's rows
+    once, in token order, and a kernel adds them (PR 37:
+    `moe.sums_rows_by_kernel`, from the static shapes alone).
 
     Weights: w_router [D, n_experts], e_bias [n_experts] (b, the
     score-correction bias, sigmoid scoring only: it enters the choice
@@ -209,8 +213,10 @@ class MoELayer(Op):
         self.kernel_init = (p.get("kernel_initializer")
                             or DefaultWeightInitializer())
         self._counters = None
-        # set when a forward has been traced (`moe_gather_combine_ops`)
+        # set when a forward has been traced (`moe_gather_combine_ops`,
+        # `moe_sum_rows_ops`)
         self._gather_combine = False
+        self._sum_rows = False
         super().__init__(layer, input_shapes)
 
     def compute_output_shapes(self):
@@ -315,8 +321,11 @@ class MoELayer(Op):
                 r["overflow"]
 
         # for `executor.moe_gather_combine_ops`: rows went out to and came
-        # back from the experts by gathers (this is trace time)
+        # back from the experts by gathers (this is trace time); for
+        # `executor.moe_sum_rows_ops`: `tokens_from_rows`, for the combine
+        # and for the dispatch's backward, summed them by the kernel
         self._gather_combine = True
+        self._sum_rows = sums_rows_by_kernel(rows, d, b * s, self.k)
         y, load, overflow = scoped("moe_layer", layer)(params, x, x_router)
         load = load.astype(jnp.float32)
         self._counters = {

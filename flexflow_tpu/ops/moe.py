@@ -29,6 +29,18 @@ row (18,560 rows of 2560 in 8.0 ms, 3% of the HBM's rate), where the same
 rows are gathered in 0.3 ms. So each `custom_vjp` below replaces the
 transpose autodiff would pick (a gather's is a scatter-add) by the gather
 through the other map.
+
+The token's side at the cost of the buffer's rows (PR 37). A chip holds
+an eighth or a sixteenth of the experts, so of a token's k pairs most
+have no row here, and k gathers of T rows each read T * k rows to use
+`rows` of them: XLA's gather costs 14-19 ns a row asked for, whatever it
+holds. A third sort, of the rows by their pair, puts them in token order
+(`rows_in_token_order`); one gather of `rows` rows makes that copy, and
+the kernel `pallas_kernels.moe_sum_rows` adds each token tile's run of
+it: 0.84 ms against 1.80 (weighted) and 0.50 against 1.68 a call at
+T = 16,384, k = 8, 24,704 rows of 2048. `sums_rows_by_kernel` picks the
+body from the static shapes; the k gathers stay where the buffer holds
+every pair, for shapes the kernel does not take whole, and off the TPU.
 """
 
 from __future__ import annotations
@@ -42,6 +54,7 @@ import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from flexflow_tpu.ffconst import OperatorType
+from flexflow_tpu.ops import pallas_kernels
 from flexflow_tpu.ops.base import DimRole, Op, OpContext, register_op
 
 
@@ -94,6 +107,9 @@ def route_held_experts(experts, held: int, offset: int, rows: int):
       overflow    []     int32  held pairs that found no row (0 unless the
                                 buffer is too small): counted, never
                                 silently dropped
+      in_token_order     dict   the rows sorted by the pair they hold,
+                                which is by token and within a token by
+                                slot (`rows_in_token_order`)
     """
     flat = experts.reshape(-1) - offset
     here = (flat >= 0) & (flat < held)
@@ -111,8 +127,11 @@ def route_held_experts(experts, held: int, offset: int, rows: int):
     group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
     n_rows = ends[-1]
     pair_valid = here & (place < n_rows)
-    return dict(slot=order[:rows],
-                valid=jnp.arange(rows, dtype=jnp.int32) < n_rows,
+    slot = order[:rows]
+    valid = jnp.arange(rows, dtype=jnp.int32) < n_rows
+    return dict(slot=slot, valid=valid,
+                in_token_order=rows_in_token_order(slot, valid,
+                                                   *experts.shape),
                 row_of_pair=jnp.where(pair_valid, place, 0
                                       ).reshape(experts.shape),
                 pair_valid=pair_valid.reshape(experts.shape),
@@ -120,9 +139,56 @@ def route_held_experts(experts, held: int, offset: int, rows: int):
                 overflow=jnp.sum(load) - n_rows)
 
 
+def rows_in_token_order(slot, valid, tokens: int, k: int):
+    """The buffer's rows in the order of the pairs they hold (PR 37):
+    `slot[r] = t * k + j` is a row's place among the pairs, so one stable
+    key/value sort of `slot` puts a token's rows side by side, in the
+    order of their slots; rows that hold no pair sort last. What
+    `tokens_from_rows` needs to read each row once:
+
+      row        [rows] int32  the buffer row at each place of the order
+      pair       [rows] int32  the pair it holds (any pair past the valid)
+      token      [rows] int32  its token; `tokens` past the valid rows
+      tile_start [tiles + 1]   where the run of each tile of
+                               `pallas_kernels.SUM_TOKENS` tokens starts
+                               (the last: where the valid rows end)
+      items      dict          the kernel's grid (`moe_sum_rows_items`)
+    """
+    rows = slot.shape[0]
+    pair, row = jax.lax.sort(
+        (jnp.where(valid, slot, tokens * k),
+         jnp.arange(rows, dtype=jnp.int32)), num_keys=1, is_stable=True)
+    token = pair // k
+    tile = pallas_kernels.SUM_TOKENS
+    bounds = jnp.minimum(
+        jnp.arange(-(-tokens // tile) + 1, dtype=jnp.int32) * tile, tokens)
+    tile_start = jnp.searchsorted(token, bounds, side="left",
+                                  method="compare_all").astype(jnp.int32)
+    return dict(row=row, pair=jnp.minimum(pair, tokens * k - 1), token=token,
+                tile_start=tile_start,
+                items=pallas_kernels.moe_sum_rows_items(tile_start, rows))
+
+
 def _rows(buf, index):
     """buf[index] for indices known to be in range."""
     return buf.at[index].get(mode="promise_in_bounds")
+
+
+# `tokens_from_rows` sums by the kernel when the buffer holds at most this
+# share of the tokens' pairs: the k gathers cost per PAIR, the order's
+# gather and the kernel per ROW. (the lab's numbers: PERF.md section 6)
+SUM_ROWS_MAX_SHARE = 0.5
+
+
+def sums_rows_by_kernel(rows: int, width: int, tokens: int, k: int) -> bool:
+    """Whether `tokens_from_rows` runs `moe_sum_rows` for these static
+    shapes here: the Pallas kernels are on (the TPU, or interpreted), the
+    shape is one the kernel takes whole, and the buffer is a small enough
+    share of the pairs. A layer that holds every expert has a row a pair,
+    gains nothing from the order and keeps the k gathers."""
+    return (pallas_kernels.pallas_mode() != "off"
+            and pallas_kernels.moe_sum_rows_shape_legal(rows, width, tokens)
+            and rows <= SUM_ROWS_MAX_SHARE * tokens * k)
 
 
 def tokens_from_rows(buf, route, weight=None, dtype=None):
@@ -133,9 +199,39 @@ def tokens_from_rows(buf, route, weight=None, dtype=None):
 
     buf [rows, d], `route` what `route_held_experts` returned, weight
     [T, k] float32 or None for 1 -> [T, d] in `dtype` (buf's if None):
-    products and the sum over j in float32, rounded once. k row gathers
-    and one masked multiply-add over them, slot by slot, so that no
-    float32 [T, k, d] exists; no scatter."""
+    products and the sum over j in float32, rounded once; no scatter.
+
+    Two bodies, picked by what is static (`sums_rows_by_kernel`). Where
+    the buffer is a small share of the T * k pairs, on the TPU: the rows
+    into token order by ONE gather of `rows` rows, then the kernel
+    `moe_sum_rows`, which adds each token tile's run of them (PR 37): a
+    cost that follows the buffer's rows. Else k row gathers of T rows
+    each and one masked multiply-add over them, slot by slot, so that no
+    float32 [T, k, d] exists: T * k rows, of which the pairs held
+    elsewhere read row 0 and are multiplied by nothing."""
+    tokens, k = route["row_of_pair"].shape
+    body = (_sum_by_kernel if sums_rows_by_kernel(*buf.shape, tokens, k)
+            else _sum_by_gathers)
+    # rows come out of a gather (and of the kernel) row-major; a consumer
+    # that keeps [T, d] with T minor (the decoders' residual stream on the
+    # TPU) would have each of the k gathered arrays transposed to meet
+    # it: ask for the sum row-major, so that it is transposed once
+    return with_layout_constraint(
+        body(buf, route, weight, dtype or buf.dtype),
+        Layout(major_to_minor=(0, 1)))
+
+
+def _sum_by_kernel(buf, route, weight, dtype):
+    order = route["in_token_order"]
+    return pallas_kernels.moe_sum_rows(
+        _rows(buf, order["row"]), order["token"],
+        None if weight is None else _rows(
+            weight.reshape(-1).astype(jnp.float32), order["pair"]),
+        order["items"], route["row_of_pair"].shape[0], dtype,
+        pallas_kernels.pallas_mode() == "interpret")
+
+
+def _sum_by_gathers(buf, route, weight, dtype):
     row_of_pair, pair_valid = route["row_of_pair"], route["pair_valid"]
     acc = None
     for j in range(row_of_pair.shape[1]):
@@ -144,12 +240,7 @@ def tokens_from_rows(buf, route, weight=None, dtype=None):
             term = term * weight[:, j, None].astype(jnp.float32)
         term = jnp.where(pair_valid[:, j, None], term, 0.0)
         acc = term if acc is None else acc + term
-    # rows come out of a gather row-major; a consumer that keeps [T, d]
-    # with T minor (the decoders' residual stream on the TPU) would have
-    # each of the k gathered arrays transposed to meet it: ask for the
-    # sum row-major, so that it is transposed once
-    return with_layout_constraint(acc.astype(dtype or buf.dtype),
-                                  Layout(major_to_minor=(0, 1)))
+    return acc.astype(dtype)
 
 
 @jax.custom_vjp
@@ -273,8 +364,7 @@ def grouped_matmul(lhs, rhs, group_sizes):
     under FLEXFLOW_TPU_PALLAS=interpret) the Pallas megablox kernels that
     ship with JAX, with tiles sized here and row tiles of at least 128
     (m must be a multiple of 128); elsewhere `lax.ragged_dot`."""
-    from flexflow_tpu.ops.pallas_kernels import pallas_mode
-    mode = pallas_mode()
+    mode = pallas_kernels.pallas_mode()
     if mode != "off" and lhs.shape[0] % 128 == 0:
         return _megablox_gmm(lhs, rhs, group_sizes, mode == "interpret")
     return _zero_past(

@@ -7,8 +7,8 @@ in HBM is the bottleneck. ``flash_attention`` keeps a head's scores in
 VMEM: forward and backward recompute them from Q and K, and only the
 per-row logsumexp goes to HBM beside the output.
 
-Four kernels, told apart in a trace by name. Up to MAX_BWD_SEQ a grid
-step holds the whole S x S tile of several heads (`flash_fwd_whole`,
+Four attention kernels, told apart in a trace by name. Up to MAX_BWD_SEQ
+a grid step holds the whole S x S tile of several heads (`flash_fwd_whole`,
 `flash_bwd`: dQ/dK/dV from P recomputed out of the saved LSE); longer
 sequences take the Q-blocked forward (`flash_fwd`) and the K-blocked
 backward (`flash_bwd_blocked`), up to MAX_FLASH_SEQ — the one upper bound
@@ -83,9 +83,9 @@ _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 # Every kernel's ``pallas_call`` carries a ``name=``: XLA names the custom
 # call's HLO instruction after it, and the profiler names a device event
 # by its instruction, so a chip trace shows `tpu_custom_call_flash_fwd.3`
-# where it showed `tpu_custom_call.3`. The names keep the prefix an
-# unnamed kernel gets: reductions of a trace find kernels by it
-# (benchmarks/trace_reduce.KERNEL_PREFIX).
+# where it showed `tpu_custom_call.3`. The flash names keep the prefix an
+# unnamed kernel gets: reductions of a trace find them by it (benchmarks/
+# trace_reduce.KERNEL_PREFIX); `moe_sum_rows` goes without, as `gmm` does.
 KERNEL_NAME_PREFIX = "tpu_custom_call_"
 
 # Longest sequence whose whole S x S f32 score tile of a head is one grid
@@ -1002,6 +1002,152 @@ def _flash_lse_vjp_bwd(num_heads, causal, interpret, res, gs):
 
 
 flash_attention_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the expert layer's sum of rows into their tokens (PR 37): the one place
+# outside attention where XLA's lowering (a gather a (token, slot) pair,
+# held here or not) lost to a kernel by a factor (ops/moe.py
+# `tokens_from_rows`).
+
+SUM_TOKENS = 128  # tokens a tile of `moe_sum_rows`: one MXU pass high
+SUM_ROWS = 128    # token-ordered rows a block: the product's contraction
+# widest row whose blocks (rows in, float32 accumulator, output twice)
+# stay inside the compiler's default 16 MiB of scoped VMEM
+MAX_SUM_WIDTH = 4096
+
+
+def moe_sum_rows_shape_legal(rows: int, width: int, tokens: int) -> bool:
+    """Whole blocks of rows, whole tiles of tokens, rows that fill the
+    lanes: what `moe_sum_rows` takes without padding anything."""
+    return (rows % SUM_ROWS == 0 and tokens % SUM_TOKENS == 0
+            and width % LANES == 0 and width <= MAX_SUM_WIDTH)
+
+
+def moe_sum_rows_items(tile_start, rows: int):
+    """The grid of `moe_sum_rows`: one item a (token tile, block of
+    token-ordered rows) that meet, tile by tile.
+
+    tile_start [tiles + 1] int32, the place in token order at which each
+    tile's run of rows starts (the last entry: where the rows that hold a
+    pair end) -> dict of `tile`, `block` [tiles + rows / SUM_ROWS] int32
+    and `count` [1] int32, the items that are real. A run may be any
+    length: an empty one still gets one item (the tile has to be written,
+    with zeros), one of SUM_TOKENS * k rows as many as it spans. Two
+    neighbours share the block in which one ends and the other starts, so
+    tiles + blocks items are enough; those past `count` repeat the last
+    one's tile and block, and the kernel passes over them."""
+    blocks = -(-rows // SUM_ROWS)
+    start, end = tile_start[:-1], tile_start[1:]
+    first = jnp.minimum(start // SUM_ROWS, blocks - 1)
+    count = jnp.maximum(-(-end // SUM_ROWS) - first, 1)
+    ends = jnp.cumsum(count)
+    item = jnp.arange(start.shape[0] + blocks, dtype=jnp.int32)
+    tile = jnp.minimum(
+        jnp.searchsorted(ends, item, side="right", method="compare_all"),
+        start.shape[0] - 1).astype(jnp.int32)
+    block = first[tile] + jnp.minimum(item - (ends - count)[tile],
+                                      count[tile] - 1)
+    return dict(tile=tile, block=block.astype(jnp.int32),
+                count=ends[-1:].astype(jnp.int32))
+
+
+def _bf16_pieces(x, n: int):
+    """float32 x as a sum of n bfloat16 arrays (3 hold all 24 bits)."""
+    parts = []
+    for _ in range(n):
+        parts.append(x.astype(jnp.bfloat16))
+        x = x - parts[-1].astype(jnp.float32)
+    return parts
+
+
+def _moe_sum_rows_kernel(tile_ref, block_ref, count_ref, token_ref, *refs,
+                         weighted: bool):
+    """One item: add the rows of this block that belong to this tile's
+    tokens into the tile's accumulator, by a [tokens, rows] matrix on the
+    MXU that holds a row's weight (or 1) where the row is the token's and
+    0 elsewhere. bfloat16 rows are exact MXU operands, and a float32
+    weight goes in as three bfloat16 pieces, each product accumulated in
+    float32: w * row to float32's rounding, nothing rounded to bfloat16.
+    float32 rows take the MXU's float32 passes."""
+    if weighted:
+        weight_ref, x_ref, o_ref, acc_ref = refs
+    else:
+        x_ref, o_ref, acc_ref = refs
+    n = pl.program_id(0)
+    tile = tile_ref[n]
+
+    @pl.when((n == 0) | (tile_ref[jnp.maximum(n - 1, 0)] != tile))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n < count_ref[0])
+    def _():
+        local = token_ref[0] - tile * SUM_TOKENS                 # [1, rows]
+        mine = jax.lax.broadcasted_iota(
+            jnp.int32, (SUM_TOKENS, SUM_ROWS), 0) == local
+        x = x_ref[...]
+        if weighted:
+            hot = jnp.where(mine, weight_ref[0], 0.0)
+        else:
+            hot = mine.astype(jnp.float32)
+        if x.dtype == jnp.bfloat16:
+            acc = acc_ref[...]
+            for piece in _bf16_pieces(hot, 3 if weighted else 1):
+                acc = acc + jax.lax.dot_general(
+                    piece, x, _NN, preferred_element_type=jnp.float32)
+            acc_ref[...] = acc
+        else:
+            acc_ref[...] += jax.lax.dot_general(
+                hot, x.astype(jnp.float32), _NN,
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+
+    last = pl.num_programs(0) - 1
+
+    @pl.when((n == last) | (tile_ref[jnp.minimum(n + 1, last)] != tile))
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def moe_sum_rows(x, token, weight, items, tokens: int, dtype,
+                 interpret: bool):
+    """out[t] = sum of weight[i] * x[i] over the rows i with token[i] == t.
+
+    x [rows, d] the rows IN TOKEN ORDER, token [rows] int32 ascending
+    (`tokens` for a row that holds nothing: it matches no tile), weight
+    [rows] float32 or None for 1, `items` what `moe_sum_rows_items` made
+    of the tiles' starts -> [tokens, d] in `dtype`: products and sums in
+    float32, rounded once. Every row is read once, and the block two
+    tiles share twice; the cost follows `rows`, whatever share of the
+    tokens' pairs they are. `moe_sum_rows_shape_legal` says which shapes.
+    A row's 0 in another token's sum is a product, not a select: a row
+    that is not finite reaches its whole tile."""
+    rows, d = x.shape
+    blocks = rows // SUM_ROWS
+
+    def lanes(v):
+        return v.reshape(blocks, 1, SUM_ROWS)
+
+    of_block = pl.BlockSpec((1, 1, SUM_ROWS),
+                            lambda n, tile, block, count: (block[n], 0, 0))
+    operands = [lanes(token)] + ([] if weight is None else [lanes(weight)])
+    return pl.pallas_call(
+        functools.partial(_moe_sum_rows_kernel, weighted=weight is not None),
+        name="moe_sum_rows",
+        out_shape=jax.ShapeDtypeStruct((tokens, d), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(items["tile"].shape[0],),
+            in_specs=[of_block] * len(operands) + [pl.BlockSpec(
+                (SUM_ROWS, d), lambda n, tile, block, count: (block[n], 0))],
+            out_specs=pl.BlockSpec(
+                (SUM_TOKENS, d), lambda n, tile, block, count: (tile[n], 0)),
+            scratch_shapes=[pltpu.VMEM((SUM_TOKENS, d), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(items["tile"], items["block"], items["count"], *operands, x)
 
 
 def pallas_mode() -> str:

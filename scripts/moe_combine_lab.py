@@ -1,27 +1,35 @@
 #!/usr/bin/env python3
-"""The hand look behind how `MoELayer` brings rows back to tokens (PR 32).
+"""The hand look behind how `MoELayer` brings rows back to tokens (PR 32,
+PR 37).
 
-On the chip, at the shapes of the two cells that run `MoELayer`, each
-piece alone, 20 calls after a warm-up, wall time a call:
+On the chip, at the shapes of the three cells that run `MoELayer`, each
+piece alone, 20 calls after a warm-up, wall time a call (`<piece>_ms`),
+and then under the profiler the device's own time of the piece's program
+(`<piece>_device_ms`, with its ops by stem):
 
 - what the layer did until PR 32, kept HERE as the yardstick: the combine
   as a scatter-add of the buffer's rows, and the dispatch gather whose
   automatic backward is the same scatter-add;
 - what ships (`flexflow_tpu/ops/moe.py`): `route_held_experts` with the
-  inverse map, `tokens_from_rows` (k row gathers and one multiply-add),
-  `combine_rows` and `rows_from_tokens` forward and backward;
+  inverse map and the rows' token order, `tokens_from_rows` in both its
+  bodies, each without the routing (k row gathers and one multiply-add,
+  PR 32; the rows into token order by one gather and the kernel
+  `moe_sum_rows`, PR 37, and that form's parts alone: the order's sort,
+  the gather, the kernel), `combine_rows` and `rows_from_tokens` forward
+  and backward;
 - forms that were tried against it: one gather of all T*k rows and a
   reduction over k; the inverse map from a cumulative count in place of
   the second sort; a form whose cost follows the buffer's rows (rows into
   token order, then megablox `tgmm` with tiles of 128 tokens as groups).
 
-Prints one JSON line a shape and writes them to
-`chiprun_out/moe_combine_lab.json`. With `--deviceless` nothing runs: each
-piece is compiled for a described v5e and the line holds the compiler's
-temporary bytes and the number of scatters in the optimized HLO. Nothing
-here is a benchmark metric.
+Prints one JSON line a shape, with `byte_floor_ms` (the buffer read once
+in bfloat16 and [T, d] written once in float32, at the HBM's peak), and
+writes them to `chiprun_out/moe_combine_lab.json`. With `--deviceless`
+nothing runs: each piece is compiled for a described v5e and the line
+holds the compiler's temporary bytes and the number of scatters in the
+optimized HLO. Nothing here is a benchmark metric.
 
-    python scripts/moe_combine_lab.py [--deviceless]
+    python scripts/moe_combine_lab.py [--deviceless] [--only by_kernel]
 """
 
 import argparse
@@ -32,20 +40,25 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# tokens, k, experts, held, width, buffer rows (`MoELayer.buffer_rows`)
+# tokens, k, experts, held, width, buffer rows (`MoELayer.buffer_rows`);
+# `mask_share` of the tokens choose the same k experts, as the mask token
+# of a block-diffusion sample does
 SHAPES = {
     "smallthinker_21b_a3b.s16384_b1": dict(T=16384, k=6, E=64, held=8,
                                            d=2560, rows=18560),
     "nemotron3_nano_30b_a3b.s8192_b1": dict(T=8192, k=6, E=128, held=8,
                                             d=2688, rows=4736),
+    "sdar_30b_a3b.s8192_b1": dict(T=16384, k=8, E=128, held=16, d=2048,
+                                  rows=24704, mask_share=0.25),
 }
+HBM_BYTES_PER_S = 819e9   # v5e, as benchmarks/peaks.json has it
 
 
 def pieces(s):
     """name -> (function, argument names): every piece takes arrays only."""
     import jax
     import jax.numpy as jnp
-    from flexflow_tpu.ops import moe
+    from flexflow_tpu.ops import moe, pallas_kernels
 
     T, k, held, rows = s["T"], s["k"], s["held"], s["rows"]
     f32 = jnp.float32
@@ -83,6 +96,37 @@ def pieces(s):
 
     def tokens_from_rows_plain(o, experts):
         return moe.tokens_from_rows(o, route(experts))
+
+    # `tokens_from_rows`' two bodies and the new one's parts, the routing
+    # done outside: ms a call as the issue counts them
+    def by_gathers_weighted(o, weights, r):
+        return moe._sum_by_gathers(o, r, weights, f32)
+
+    def by_gathers_plain(o, r):
+        return moe._sum_by_gathers(o, r, None, o.dtype)
+
+    def by_kernel_weighted(o, weights, r):
+        return moe._sum_by_kernel(o, r, weights, f32)
+
+    def by_kernel_plain(o, r):
+        return moe._sum_by_kernel(o, r, None, o.dtype)
+
+    def order_sort(r):
+        return moe.rows_in_token_order(r["slot"], r["valid"], T, k)
+
+    def order_gather(o, r):
+        return moe._rows(o, r["in_token_order"]["row"])
+
+    def kernel_weighted(o, weights, r):
+        order = r["in_token_order"]
+        return pallas_kernels.moe_sum_rows(
+            o, order["token"], weights.reshape(-1)[:rows], order["items"],
+            T, f32, False)
+
+    def kernel_plain(o, r):
+        order = r["in_token_order"]
+        return pallas_kernels.moe_sum_rows(
+            o, order["token"], None, order["items"], T, o.dtype, False)
 
     def combine_fwd_bwd(o, weights, experts):
         r = route(experts)
@@ -136,12 +180,9 @@ def pieces(s):
         if weights is not None:
             exact = picked.astype(f32) * weights.reshape(-1)[
                 in_token_order][:, None]
-            parts = []
-            for _ in range(3):
-                parts.append(exact.astype(jnp.bfloat16))
-                exact = exact - parts[-1].astype(f32)
             pieces = 3
-            picked = jnp.stack(parts, axis=1).reshape(3 * rows, -1)
+            picked = jnp.stack(pallas_kernels._bf16_pieces(exact, pieces),
+                               axis=1).reshape(pieces * rows, -1)
             hot = jnp.repeat(hot, 3, axis=1)
         out = moe._megablox().tgmm(
             hot.astype(jnp.bfloat16), picked, sizes * pieces, f32,
@@ -164,6 +205,17 @@ def pieces(s):
                                             ("o", "weights", "experts")),
         "route+tokens_from_rows_plain": (tokens_from_rows_plain,
                                          ("o", "experts")),
+        "by_gathers_weighted": (by_gathers_weighted,
+                                ("o", "weights", "route")),
+        "by_gathers_plain": (by_gathers_plain, ("o", "route")),
+        "by_kernel_weighted": (by_kernel_weighted,
+                               ("o", "weights", "route")),
+        "by_kernel_plain": (by_kernel_plain, ("o", "route")),
+        "by_kernel.order_sort": (order_sort, ("route",)),
+        "by_kernel.order_gather": (order_gather, ("o", "route")),
+        "by_kernel.kernel_weighted": (kernel_weighted,
+                                      ("o", "weights", "route")),
+        "by_kernel.kernel_plain": (kernel_plain, ("o", "route")),
         "route+combine_fwd_bwd": (combine_fwd_bwd,
                                   ("o", "weights", "experts")),
         "route+dispatch_fwd": (dispatch_fwd, ("x", "experts")),
@@ -179,14 +231,28 @@ def pieces(s):
 
 
 def make_arguments(s):
-    """Distinct experts a token, uniform: the held ones get their share."""
+    """Distinct experts a token, uniform: the held ones get their share.
+    With `mask_share`, that share of the tokens choose the same k
+    experts, one of them held: half of them at random places, half as one
+    run from token 0; and the 256 tokens after that run choose k held
+    experts, so that tiles with no row, with a row a token and with k
+    rows a token all occur."""
     import jax
     import jax.numpy as jnp
     from flexflow_tpu.ops import moe
 
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    _, experts = jax.lax.top_k(
-        jax.random.uniform(ks[0], (s["T"], s["E"])), s["k"])
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    T, k = s["T"], s["k"]
+    _, experts = jax.lax.top_k(jax.random.uniform(ks[0], (T, s["E"])), k)
+    if s.get("mask_share"):
+        token = jnp.arange(T)
+        run = int(T * s["mask_share"] / 2)
+        masked = (token < run) | (
+            jax.random.uniform(ks[4], (T,)) < s["mask_share"] / 2)
+        of_mask = jnp.arange(k).at[1:].add(s["held"])   # expert 0 is held
+        experts = jnp.where(masked[:, None], of_mask, experts)
+        experts = jnp.where(((token >= run) & (token < run + 256))[:, None],
+                            jnp.arange(k), experts)
     experts = experts.astype(jnp.int32)
     r = moe.route_held_experts(experts, s["held"], 0, s["rows"])
     weights = jax.random.uniform(ks[1], (s["T"], s["k"]))
@@ -194,6 +260,7 @@ def make_arguments(s):
         x=jax.random.normal(ks[2], (s["T"], s["d"]), jnp.bfloat16),
         o=jax.random.normal(ks[3], (s["rows"], s["d"]), jnp.bfloat16),
         weights=weights, experts=experts, token=r["slot"] // s["k"],
+        route=r,
         w_row=jnp.where(r["valid"], weights.reshape(-1)[r["slot"]], 0.0),
         overflow=r["overflow"])
 
@@ -208,9 +275,48 @@ def timed_ms(fn, args, n=20):
     return (time.perf_counter() - t) / n * 1e3
 
 
+def device_ms(jitted, calls=5):
+    """name -> (function, arguments): every piece `calls` times under the
+    profiler -> name -> (median device ms of its program, ms a call of
+    its ops by stem, largest first). A call's wall time has a floor, what
+    the host takes to hand over a piece's arguments (0.25 ms with a
+    routing's fifteen arrays); the device's own clock has none."""
+    import collections
+    import statistics
+    import tempfile
+
+    import jax
+    from benchmarks import trace_reduce as tr
+
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for fn, args in jitted.values():
+            for _ in range(calls):
+                out = fn(*args)
+            jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        dev = tr.load_xplane(tr.newest_xplane(trace_dir))[0]
+    out = {}
+    for name, (fn, _) in jitted.items():
+        runs = [(s, s + d) for n, s, d in dev.lines.get(tr.MODULES, ())
+                if n.split("(")[0] == "jit_" + fn.__name__]
+        if not runs:
+            continue
+        ops = collections.Counter()
+        for n, s, d in dev.lines.get(tr.OPS, ()):
+            if any(a <= s < b for a, b in runs) and not n.startswith(
+                    tr.ENCLOSING):
+                ops[tr.stem(n)] += d / len(runs) * 1e3
+        out[name] = (statistics.median(b - a for a, b in runs) * 1e3,
+                     {k: round(v, 4) for k, v in ops.most_common(6)})
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--deviceless", action="store_true")
+    ap.add_argument("--only", default="",
+                    help="pieces whose name holds this")
     opts = ap.parse_args()
     if opts.deviceless:
         os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -228,25 +334,35 @@ def main():
     out = {}
     for cell, s in SHAPES.items():
         line = dict(cell=cell, device=("deviceless v5e" if opts.deviceless
-                                       else jax.devices()[0].device_kind))
+                                       else jax.devices()[0].device_kind),
+                    byte_floor_ms=round(
+                        (2 * s["rows"] + 4 * s["T"]) * s["d"]
+                        / HBM_BYTES_PER_S * 1e3, 3))
         if opts.deviceless:
             arrays = jax.eval_shape(lambda: make_arguments(s))
         else:
             arrays = make_arguments(s)
             assert int(arrays["overflow"]) == 0
+            line["rows_held"] = int(arrays["route"]["valid"].sum())
+        jitted = {}
         for name, (fn, names) in pieces(s).items():
+            if opts.only not in name:
+                continue
             if opts.deviceless:
                 compiled = jax.jit(fn).lower(*(
-                    jax.ShapeDtypeStruct(arrays[n].shape, arrays[n].dtype,
-                                         sharding=chip)
+                    jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype, sharding=chip), arrays[n])
                     for n in names)).compile()
                 line[name] = dict(
                     temp_mb=round(
                         compiled.memory_analysis().temp_size_in_bytes / 1e6),
                     scatters=len(scatters_in(compiled.as_text())))
             else:
-                line[name + "_ms"] = round(timed_ms(
-                    jax.jit(fn), [arrays[n] for n in names]), 3)
+                jitted[name] = (jax.jit(fn), [arrays[n] for n in names])
+                line[name + "_ms"] = round(timed_ms(*jitted[name]), 3)
+        for name, (ms, ops) in (device_ms(jitted) if jitted else {}).items():
+            line[name + "_device_ms"] = round(ms, 3)
+            line[name + "_device_ops"] = ops
         print(json.dumps(line), flush=True)
         out[cell] = line
     if not opts.deviceless:

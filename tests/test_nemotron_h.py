@@ -20,7 +20,7 @@ from benchmarks import harness as hs  # noqa: E402
 from benchmarks.references import nemotron_h as ref  # noqa: E402
 from flexflow_tpu.ffconst import OperatorType  # noqa: E402
 from flexflow_tpu.layer import Layer  # noqa: E402
-from flexflow_tpu.ops import moe, ssm  # noqa: E402
+from flexflow_tpu.ops import moe, pallas_kernels, ssm  # noqa: E402
 from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
 
 family = hs.load_by_path("families", "nemotron_h")
@@ -211,7 +211,31 @@ def random_routing(tokens, k, n_experts, seed):
                                  for _ in range(tokens)]), jnp.int32)
 
 
-# name -> (experts [T, k], held, offset, rows)
+def tiles_routing():
+    """Three tiles of 128 tokens: every pair of the first is held (a run
+    of 128 * k rows), none of the second (an empty tile), of the third
+    what falls to the two held experts; in it token 300 holds no pair and
+    token 301 both."""
+    experts = np.array(random_routing(384, 2, 16, 5))
+    experts[:128] = [0, 1]
+    experts[128:256] = [7, 9]
+    experts[300], experts[301] = [5, 6], [1, 0]
+    return jnp.asarray(experts)
+
+
+def mask_token_routing(tokens, k, n_experts, seed):
+    """A quarter of the tokens choose the same k experts, one of them
+    held (the first), as the mask token of a block-diffusion sample."""
+    rs = np.random.RandomState(seed)
+    experts = np.array(random_routing(tokens, k, n_experts, seed))
+    experts[rs.rand(tokens) < 0.25] = [0] + list(range(n_experts - k + 1,
+                                                       n_experts))
+    return jnp.asarray(experts)
+
+
+# name -> (experts [T, k], held, offset, rows); the `kernel_` ones at
+# shapes `moe_sum_rows` takes (whole tiles of tokens, whole blocks of
+# rows), which under FLEXFLOW_TPU_PALLAS=interpret run it
 ROUTINGS = {
     "by_hand": (hand_routing(), 4, 4, 8),
     "by_hand_buffer_too_small": (hand_routing(), 4, 4, 3),
@@ -219,7 +243,18 @@ ROUTINGS = {
     "random_buffer_too_small": (random_routing(96, 6, 64, 1), 8, 0, 40),
     "all_experts_held": (random_routing(40, 3, 16, 2), 16, 0, 128),
     "buffer_past_the_pairs": (random_routing(8, 2, 4, 3), 2, 1, 128),
+    "kernel_a_sixteenth_held": (random_routing(256, 4, 32, 4), 2, 8, 128),
+    "kernel_full_empty_and_mixed_tiles": (tiles_routing(), 2, 0, 384),
+    "kernel_a_quarter_on_the_same_experts": (
+        mask_token_routing(512, 4, 32, 6), 4, 0, 512),
+    "kernel_buffer_too_small": (random_routing(256, 4, 16, 7), 4, 4, 128),
+    "a_row_a_pair_keeps_the_gathers": (random_routing(128, 2, 4, 8), 4, 0,
+                                       256),
 }
+
+
+def sums_by_kernel(name, mode):
+    return name.startswith("kernel_") and mode == "interpret"
 
 
 @pytest.mark.parametrize("name", list(ROUTINGS))
@@ -251,29 +286,89 @@ def test_row_of_pair_is_the_inverse_of_slot(name):
         assert pair_valid.all() and n_rows == flat.size
 
 
+@pytest.mark.parametrize("name", list(ROUTINGS))
+def test_rows_in_token_order_is_the_stable_sort_of_slot(name):
+    """The order `tokens_from_rows`' kernel reads the rows in (PR 37):
+    the valid rows by the pair they hold, the others last and in place;
+    each tile of tokens a run of it, and the kernel's items, tile by
+    tile, the blocks of the order that the run touches."""
+    SUM_ROWS, SUM_TOKENS = pallas_kernels.SUM_ROWS, pallas_kernels.SUM_TOKENS
+    experts, held, offset, rows = ROUTINGS[name]
+    tokens, k = experts.shape
+    r = jax.tree.map(np.asarray,
+                     moe.route_held_experts(experts, held, offset, rows))
+    order = r["in_token_order"]
+    key = np.where(r["valid"], r["slot"], tokens * k)
+    assert order["row"].tolist() == np.argsort(key, kind="stable").tolist()
+    n_rows = int(r["valid"].sum())
+    assert order["pair"][:n_rows].tolist() == sorted(r["slot"][:n_rows])
+    assert (order["pair"] < tokens * k).all()
+    assert order["token"].tolist() == (np.sort(key) // k).tolist()
+    tiles = -(-tokens // SUM_TOKENS)
+    start = order["tile_start"]
+    assert start.tolist() == np.searchsorted(
+        order["token"],
+        np.minimum(np.arange(tiles + 1) * SUM_TOKENS, tokens)).tolist()
+    assert start[0] == 0 and start[-1] == n_rows
+    if name == "kernel_full_empty_and_mixed_tiles":
+        assert start[:3].tolist() == [0, 256, 256]
+        assert r["pair_valid"][300:302].sum(axis=1).tolist() == [0, 2]
+    items = order["items"]
+    blocks = -(-rows // SUM_ROWS)
+    count = int(items["count"][0])
+    assert items["tile"].shape == items["block"].shape == (tiles + blocks,)
+    assert tiles <= count <= tiles + blocks
+    # past the real items: the last one again, so that nothing is fetched
+    assert (items["tile"][count:] == items["tile"][count - 1]).all()
+    assert (items["block"][count:] == items["block"][count - 1]).all()
+    assert (np.diff(items["tile"]) >= 0).all()
+    for tile in range(tiles):
+        mine = items["block"][:count][items["tile"][:count] == tile]
+        assert len(mine) >= 1 and (np.diff(mine) == 1).all()
+        assert 0 <= mine[0] and mine[-1] < blocks
+        if start[tile + 1] > start[tile]:
+            assert mine[0] * SUM_ROWS <= start[tile]
+            assert start[tile + 1] <= (mine[-1] + 1) * SUM_ROWS
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("weighted", [True, False],
                          ids=["weighted", "unweighted"])
 @pytest.mark.parametrize("name", list(ROUTINGS))
-def test_tokens_from_rows_is_the_scatter_add_of_the_rows(name, weighted):
+def test_tokens_from_rows_is_the_scatter_add_of_the_rows(name, weighted,
+                                                         dtype, mode,
+                                                         monkeypatch):
     """`tokens_from_rows` against the form it replaced, `.at[token].add`
-    of the valid rows, to float32 rounding."""
+    of the valid rows, to float32 rounding: the k gathers, and under
+    `interpret` at the shapes it takes the kernel `moe_sum_rows` (PR 37),
+    whose products and sums are float32's too, whatever the buffer
+    holds."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
     experts, held, offset, rows = ROUTINGS[name]
     tokens, k = experts.shape
+    # wide enough for the kernel where tokens and rows are whole tiles
+    width = 256 if pallas_kernels.moe_sum_rows_shape_legal(
+        rows, 256, tokens) else 20
+    assert moe.sums_rows_by_kernel(rows, width, tokens, k) == sums_by_kernel(
+        name, mode)
     r = moe.route_held_experts(experts, held, offset, rows)
     rs = np.random.RandomState(7)
-    buf = jnp.asarray(rs.randn(rows, 20), jnp.float32)
+    buf = jnp.asarray(rs.randn(rows, width), jnp.float32).astype(dtype)
     weights = jnp.asarray(rs.rand(tokens, k), jnp.float32)
     w_row = jnp.where(r["valid"],
                       weights.reshape(-1)[r["slot"]] if weighted else 1.0,
                       0.0)
-    want = jnp.zeros((tokens, 20), jnp.float32).at[r["slot"] // k].add(
-        buf * w_row[:, None])
-    got = moe.tokens_from_rows(buf, r, weights if weighted else None)
+    want = jnp.zeros((tokens, width), jnp.float32).at[r["slot"] // k].add(
+        buf.astype(jnp.float32) * w_row[:, None])
+    got = moe.tokens_from_rows(buf, r, weights if weighted else None,
+                               jnp.float32)
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
-    assert got.dtype == buf.dtype
-    half = moe.tokens_from_rows(buf.astype(jnp.bfloat16), r, None,
-                                jnp.float32)
-    assert half.dtype == jnp.float32      # rounded once, to what is asked
+    assert got.dtype == jnp.float32       # rounded once, to what is asked
+    same = moe.tokens_from_rows(buf, r, weights if weighted else None)
+    assert same.dtype == dtype
+    np.testing.assert_array_equal(same, got.astype(dtype))
 
 
 def scatter_add_layer(op, params, inputs):
@@ -331,18 +426,32 @@ LAYERS = {
     "buffer_too_small": (dict(
         n_experts=16, k=3, hidden_size=24, scoring="softmax", gated=True,
         experts_held=8, slot_slack=-0.5), 2, 256),
+    # 256 tokens 128 wide, an eighth of the experts held: under
+    # `interpret` the rows are summed by the kernel, forward (weighted)
+    # and in the dispatch's backward (unweighted)
+    "kernel_an_eighth_held": (dict(
+        n_experts=16, k=2, hidden_size=24, scoring="softmax", gated=True,
+        experts_held=2, expert_offset=6), 2, 128, 128),
+    "kernel_shared_expert_buffer_too_small": (dict(
+        n_experts=16, k=4, hidden_size=24, shared_width=48,
+        routed_scaling=2.5, experts_held=4, slot_slack=-0.6), 1, 128, 128),
 }
 
 
+@pytest.mark.parametrize("mode", ["off", "interpret"])
 @pytest.mark.parametrize("name", list(LAYERS))
-def test_layer_gradients_match_the_scatter_add_form(name):
+def test_layer_gradients_match_the_scatter_add_form(name, mode, monkeypatch):
     """Output and `jax.grad` of a whole `MoELayer`, every leaf and every
-    input, against the scatter-add form above."""
-    props, n_inputs, seq = LAYERS[name]
+    input, against the scatter-add form above, with the Pallas kernels
+    off and interpreted (the grouped products' and, in the `kernel_`
+    layers, `moe_sum_rows`)."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+    props, n_inputs, seq, *width = LAYERS[name]
+    width = width[0] if width else 32
     rs = np.random.RandomState(11)
-    inputs = [jnp.asarray(rs.randn(2, seq, 32), jnp.float32)
+    inputs = [jnp.asarray(rs.randn(2, seq, width), jnp.float32)
               for _ in range(n_inputs)]
-    probe = jnp.asarray(rs.randn(2, seq, 32), jnp.float32)
+    probe = jnp.asarray(rs.randn(2, seq, width), jnp.float32)
     layer = Layer(OperatorType.MOE_LAYER, "op", [])
     layer.properties.update(props)
     op = OpRegistry.create(layer, [x.shape for x in inputs])
@@ -366,7 +475,8 @@ def test_layer_gradients_match_the_scatter_add_form(name):
         want = jax.grad(loss(lambda p, xs: scatter_add_layer(op, p, xs)),
                         argnums=(0, 1))(params, inputs)
     overflow = float(op._counters["moe/overflow_slots"][1])
-    assert (overflow > 0) == (name == "buffer_too_small")
+    assert (overflow > 0) == ("buffer_too_small" in name)
+    assert op._gather_combine and op._sum_rows == sums_by_kernel(name, mode)
     flat_got, tree = jax.tree.flatten(got)
     flat_want, tree_want = jax.tree.flatten(want)
     assert tree == tree_want and len(flat_got) == len(params) + n_inputs

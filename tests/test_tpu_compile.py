@@ -309,13 +309,18 @@ class TestHybridDecoderKernels:
         pytest.param(16384, 2560, dict(
             n_experts=64, k=6, hidden_size=768, scoring="softmax",
             gated=True, experts_held=8), id="smallthinker"),
+        pytest.param(16384, 2048, dict(
+            n_experts=128, k=8, hidden_size=768, scoring="softmax",
+            gated=True, activation="silu", experts_held=16), id="sdar"),
     ])
     def test_expert_layer_moves_rows_by_gathers_at_the_cells_widths(
             self, topo, on_tpu, tokens, width, props):
-        """A whole `MoELayer`, forward and backward, as the two cells run
-        it: no scatter in the chip's program but the megablox kernels' own
-        tile tables, and no float32 [tokens, k, width] among the
-        temporaries (the combine adds slot by slot)."""
+        """A whole `MoELayer`, forward and backward, as the three cells
+        run it: no scatter in the chip's program but the megablox kernels'
+        own tile tables, and no float32 [tokens, k, width] among the
+        temporaries. The rows come back to their tokens through the
+        kernel `moe_sum_rows` (PR 37), once for the combine and once for
+        the dispatch's backward, beside the grouped products' kernels."""
         from flexflow_tpu.ffconst import OperatorType
         from flexflow_tpu.layer import Layer
         from flexflow_tpu.obs.inspect import scatters_in
@@ -323,7 +328,8 @@ class TestHybridDecoderKernels:
         one = SingleDeviceSharding(topo.devices[0])
         layer = Layer(OperatorType.MOE_LAYER, "experts", [])
         layer.properties.update(props)
-        shapes = [(1, tokens, width)] * (2 if props.get("gated") else 1)
+        second_input = props.get("gated") and "activation" not in props
+        shapes = [(1, tokens, width)] * (2 if second_input else 1)
         op = OpRegistry.create(layer, shapes)
         ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
 
@@ -341,15 +347,24 @@ class TestHybridDecoderKernels:
             return op.forward(params, inputs, ctx)[0].astype(
                 jnp.float32).sum()
 
-        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        # (the value too: a sum's gradient does not need the combine)
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
             params, inputs).compile()
         # (the chip's compiler cuts some of their names to `scatter-add`:
         # a table has a tile's or a group's entry, an activation a row's)
         scatters = scatters_in(compiled.as_text())
         assert scatters and all(size < 256 for _, size in scatters), scatters
-        assert "jit(moe_combine)" in compiled.as_text()
+        hlo = compiled.as_text()
+        assert "jit(moe_combine)" in hlo
         assert (compiled.memory_analysis().temp_size_in_bytes
                 < 4 * tokens * props["k"] * width)
+        assert op._sum_rows
+        sums = [line for line in hlo.splitlines()
+                if "custom_call_target=\"tpu_custom_call\"" in line
+                and "moe_sum_rows" in line]
+        assert len(sums) == 2 and all("moe_combine" in s for s in sums)
+        # three products an expert matrix: forward, d rows, d weights
+        assert pallas_kernel_count(hlo) == 2 + 3 * op.matrices
 
     def test_chunked_scan_at_the_cells_widths(self, topo):
         from flexflow_tpu.ops.ssm import ssd_chunked

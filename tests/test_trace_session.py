@@ -439,6 +439,49 @@ def test_moe_gather_combine_ops_counts_the_models_expert_layers(
         assert ff.op_counters["moe/overflow_slots"] == 0
 
 
+@pytest.mark.parametrize("mode,held,sums", [
+    ("interpret", 1, True), ("interpret", 8, False), ("off", 1, False)],
+    ids=["an_eighth_held", "all_held", "kernels_off"])
+def test_moe_sum_rows_ops_counts_the_layers_that_sum_by_the_kernel(
+        mode, held, sums, tmp_path, monkeypatch, no_open_session):
+    """`executor.moe_sum_rows_ops` (PR 37): of the `MoELayer` ops, those
+    whose traced forward added the buffer's rows into their tokens by the
+    kernel `moe_sum_rows`. 0 until the step is traced; then the layers'
+    count where the kernels run (here interpreted) and a layer holds a
+    small share of its experts, 0 where it holds them all (a row a pair:
+    the k gathers stay) and where the kernels are off, as on the CPU."""
+    import numpy as np
+    from flexflow_tpu import (FFConfig, FFModel, LossType, MetricsType,
+                              SGDOptimizer)
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+    b, s, e, layers = 2, 64, 128, 2
+    ff = FFModel(FFConfig(batch_size=b))
+    t = ff.create_tensor((b, s, e))
+    for i in range(layers):
+        t = ff.moe_layer(t, 8, 2, 16, experts_held=held, slot_slack=1.0,
+                         scoring="softmax", name=f"experts{i}")
+    ff.dense(t, 1)
+    ff.compile(SGDOptimizer(lr=0.01), LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
+               [MetricsType.MEAN_SQUARED_ERROR])
+    assert obs.model_context(ff)["moe_sum_rows_ops"] == 0     # not traced
+    rs = np.random.RandomState(0)
+    x = rs.randn(b, s, e).astype(np.float32)
+    y = rs.randn(b, s, 1).astype(np.float32)
+    ff.fit(x, y, epochs=1, verbose=False)   # traces and compiles the step
+    obs.start_trace(str(tmp_path), device=False)
+    ff.fit(x, y, epochs=1, verbose=False)
+    paths = obs.stop_trace()
+    header, _ = read_events(paths["events"])
+    expected = layers if sums else 0
+    assert header["moe_sum_rows_ops"] == expected
+    assert header["moe_gather_combine_ops"] == layers
+    gauges = json.load(open(paths["counters"]))["gauges"]
+    assert gauges["executor.moe_sum_rows_ops"] == expected
+    assert ff.op_counters["executor.moe_sum_rows_ops"] == expected
+    assert ff.op_counters["moe/overflow_slots"] == 0
+
+
 @pytest.mark.parametrize("seq,window", [(2048, 512), (2048, 0), (128, 32)])
 def test_window_attention_gauges_show_that_the_skip_engaged(
         seq, window, tmp_path, monkeypatch, no_open_session):
