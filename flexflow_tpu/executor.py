@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flexflow_tpu.ffconst import CompMode, LossType, OperatorType
-from flexflow_tpu.losses import get_loss_fn, target_positions
+from flexflow_tpu.losses import (get_loss_fn, part_nll_sums,
+                                 target_positions)
 from flexflow_tpu.metrics import Metrics
 from flexflow_tpu.obs.registry import get_registry
 from flexflow_tpu.ops.base import Op, OpContext, scoped
@@ -99,6 +100,9 @@ class GraphExecutor:
         self.compute_dtype = compute_dtype
         self.data_axes = data_axes
         self.final_is_softmax = final_is_softmax
+        # names of the equal parts along the sequence of a weighted
+        # loss's logits (FFModel.compile sets it from the model)
+        self.loss_parts = None
         # mixed-precision master-weight regime (bf16 compute): forward and
         # backward run on a bf16 copy of the parameters that is produced
         # INSIDE the previous step's optimizer fusion (state key
@@ -528,13 +532,20 @@ class GraphExecutor:
                 return op.forward(params, args, ctx, state=state)
             return op.forward(params, args, ctx)
 
-        if getattr(op, "scopes_itself", False):
-            return plain
+        layer = getattr(op, "layer", None)
+        # outermost first: the scope the model's builder put the layer
+        # under (`FFModel.scope`: `mtp`), then the op's kind
+        names = [n for n in (getattr(layer, "properties", {}).get("scope"),)
+                 if n]
         if op.guid == self.final_ref[0]:
-            name = "head"
-        else:
-            layer = getattr(op, "layer", None)
-            name = "op_" + getattr(layer, "op_type", op.op_type).name.lower()
+            names.append("head")
+        elif not getattr(op, "scopes_itself", False):
+            names.append(
+                "op_" + getattr(layer, "op_type", op.op_type).name.lower())
+        if not names:
+            return plain
+
+        kinds = {}   # a counter's "sum" | "mean" is no traced value
 
         def inner(params, args, state, rng):
             ctx.rng = rng
@@ -544,18 +555,30 @@ class GraphExecutor:
                 if getattr(op, k, None) is not None:
                     side[k] = getattr(op, k)
                     setattr(op, k, None)
+            if "_counters" in side:
+                kinds.update((n, kind) for n, (kind, _) in
+                             side["_counters"].items())
+                side["_counters"] = {n: v for n, (_, v) in
+                                     side["_counters"].items()}
             # None unless the op drew from the rng
             return tuple(outs), side, None if ctx.rng is rng else ctx.rng
+
+        nested = inner
+        for name in reversed(names):
+            nested = scoped(name, nested)
 
         def forward(params, args, state=None):
             rng = ctx.rng
             try:
-                outs, side, drawn = scoped(name, inner)(
+                outs, side, drawn = nested(
                     params, list(args), state, rng)
             finally:
                 ctx.rng = rng
             if drawn is not None:
                 ctx.rng = drawn
+            if "_counters" in side:
+                side["_counters"] = {n: (kinds[n], v) for n, v in
+                                     side["_counters"].items()}
             for k, v in side.items():
                 setattr(op, k, v)
             return outs
@@ -714,6 +737,7 @@ class GraphExecutor:
         """What the attention ops' forwards, as last traced, recorded on
         the host (PR 31): the ops whose window hides something at their
         sequence length, those under the block-diffusion mask (PR 34),
+        the latent-attention ops (PR 39),
         and the [Q block, K chunk] tiles a head's flash
         forward works through against those of the whole square, and
         of them those that hold a hidden pair and run the masked body
@@ -729,6 +753,8 @@ class GraphExecutor:
             "executor.block_diffusion_attention_ops": sum(
                 bool(getattr(n.op, "block_diffusion", None))
                 for n in self.nodes),
+            "executor.latent_attention_ops": sum(
+                bool(getattr(n.op, "latent", None)) for n in self.nodes),
             "attention/kv_blocks_visited": sum(b[0] for b in blocks),
             "attention/kv_blocks_total": sum(b[1] for b in blocks),
             "attention/kv_blocks_masked": sum(b[2] for b in blocks)}
@@ -793,13 +819,21 @@ class GraphExecutor:
                     if (self.loss_type ==
                             LossType.WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY):
                         # leaves the step with the ops' counters: the
-                        # positions that carried a target
-                        return loss, target_positions(labels)
-                    return loss, None
+                        # positions that carried a target, and for a
+                        # model whose logits are several parts laid end
+                        # to end each part's unweighted cross-entropy
+                        counted = {"loss/target_positions":
+                                   target_positions(labels)}
+                        if self.loss_parts:
+                            counted.update(
+                                (f"loss/{part}_nll", v) for part, v in
+                                part_nll_sums(logits, labels,
+                                              self.loss_parts).items())
+                        return loss, counted
+                    return loss, {}
 
-                loss, targets = scoped("loss", loss_of)(logits, labels, aux)
-                if targets is not None:
-                    counters["loss/target_positions"] = targets
+                loss, counted = scoped("loss", loss_of)(logits, labels, aux)
+                counters.update(counted)
                 return loss, (logits, new_state, counters)
 
             (loss, (logits, new_state, counters)), grads = jax.value_and_grad(

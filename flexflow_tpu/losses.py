@@ -41,16 +41,36 @@ def weighted_sparse_categorical_crossentropy(logits, labels):
     mean over ALL positions of the weighted cross-entropy (a masked
     diffusion objective: c = 1/t where the token was masked, else 0). A
     position of weight 0 gets exactly zero gradient."""
+    return -jnp.mean(labels[..., 1].astype(jnp.float32)
+                     * _target_log_probs(logits, labels))
+
+
+def _target_log_probs(logits, labels):
+    """log softmax(logits)[target] a position, [B, S] float32, for labels
+    [B, S, 2] whose [..., 0] is the target's id."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     ids = labels[..., 0].astype(jnp.int32)
-    tok = jnp.take_along_axis(logp, ids[..., None], axis=-1)[..., 0]
-    return -jnp.mean(labels[..., 1].astype(jnp.float32) * tok)
+    return jnp.take_along_axis(logp, ids[..., None], axis=-1)[..., 0]
 
 
 def target_positions(labels):
     """Positions of weighted labels [B, S, 2] whose weight is not zero
     (the op counter `loss/target_positions`)."""
     return jnp.sum(labels[..., 1] > 0).astype(jnp.float32)
+
+
+def part_nll_sums(logits, labels, parts):
+    """name -> the sum of the UNWEIGHTED cross-entropies over the
+    positions that carry a target, for each of the equal ``parts`` along
+    the sequence that logits [B, S, V] and labels [B, S, 2] consist of
+    (a main model's half and a multi-token-prediction module's: the op
+    counters `loss/<part>_nll`). Beside the loss in one program the
+    log-probabilities are one computation (the compiler merges the two)."""
+    nll = jnp.where(labels[..., 1] > 0,
+                    -_target_log_probs(logits, labels), 0.0)
+    b, s = nll.shape
+    sums = jnp.sum(nll.reshape(b, len(parts), s // len(parts)), axis=(0, 2))
+    return {name: sums[i] for i, name in enumerate(parts)}
 
 
 def mse_avg(preds, labels):

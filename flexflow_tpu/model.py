@@ -14,6 +14,7 @@ than Legion task launches. ``fit/eval`` mirror the Python frontend's loop
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -108,8 +109,21 @@ class FFModel:
             layer.name = f"{base}_{layer.guid}"
         self._used_names.add(layer.name)
         layer.properties.update(props)
+        if getattr(self, "_scope", None):
+            layer.properties["scope"] = self._scope
         self.layers.append(layer)
         return layer
+
+    @contextlib.contextmanager
+    def scope(self, name: str):
+        """The layers added inside run under the nested call ``name`` in
+        the device trace, around their own scopes (``ops.base.scoped``;
+        `obs/step_scopes.py` makes ``mtp`` a part of the step)."""
+        prev, self._scope = getattr(self, "_scope", None), name
+        try:
+            yield
+        finally:
+            self._scope = prev
 
     def _finish(self, layer: Layer) -> Tensor:
         op = OpRegistry.create(layer, [t.shape for t in layer.inputs])
@@ -205,6 +219,10 @@ class FFModel:
                             head_dim: int = 0, window: int = 0,
                             block_diffusion=None, rope_wrap: int = 0,
                             qk_norm: bool = False, qk_norm_eps: float = 1e-6,
+                            q_lora_rank: int = 0, kv_lora_rank: int = 0,
+                            qk_rope_head_dim: int = 0,
+                            latent_norm_eps: float = 1e-6,
+                            rope_whole_head: bool = False,
                             name: Optional[str] = None) -> Tensor:
         """``seq_parallel='seq'`` runs the attention core as ring attention
         over that mesh axis (context parallelism for long sequences).
@@ -221,7 +239,20 @@ class FFModel:
         ``rope_wrap``: rotary positions repeat with this period (both
         copies at positions 0..L-1). ``qk_norm``: RMS norm of every query
         and key head over ``head_dim``, with a learned scale each, ahead
-        of the rotary embedding."""
+        of the rotary embedding. ``kv_lora_rank`` (with ``q_lora_rank``,
+        ``qk_rope_head_dim``; causal self-attention, no bias): latent
+        attention. Queries and keys/values come out of low-rank latents
+        with an RMS norm (``latent_norm_eps``) on each; a head's query
+        and key are ``head_dim`` lanes that are not rotated and
+        ``qk_rope_head_dim`` that are (``rope_theta``, over adjacent
+        pairs), the rotated key ONE vector a position
+        for all heads; values are ``head_dim`` wide (``rope_whole_head``
+        is a control: every lane of a head rotated)."""
+        latent = dict(q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+                      qk_rope_head_dim=qk_rope_head_dim,
+                      latent_norm_eps=latent_norm_eps,
+                      **({"rope_whole_head": True} if rope_whole_head
+                         else {})) if kv_lora_rank else {}
         layer = self._add_layer(OperatorType.MULTIHEAD_ATTENTION,
                                 [query, key, value], dict(
             embed_dim=embed_dim, num_heads=num_heads, kdim=kdim or embed_dim,
@@ -236,7 +267,7 @@ class FFModel:
                if block_diffusion else {}),
             **({"rope_wrap": rope_wrap} if rope_wrap else {}),
             **({"qk_norm": True, "qk_norm_eps": qk_norm_eps}
-               if qk_norm else {})), name)
+               if qk_norm else {}), **latent), name)
         return self._finish(layer)
 
     def ssm_mixer(self, input: Tensor, num_heads: int, head_dim: int,
@@ -888,6 +919,9 @@ class FFModel:
                 nodes, input_names, final_ref, self.mesh, loss_type,
                 self.metrics, self.optimizer, **exec_kwargs)
         self.executor.comp_mode = comp_mode
+        # names of the equal parts along the sequence that the logits of
+        # a weighted loss consist of (a builder sets `loss_parts`)
+        self.executor.loss_parts = getattr(self, "loss_parts", None)
         t_built = time.perf_counter()
         # --- fflint static verification (flexflow_tpu/analysis) ----------
         # runs BEFORE parameter allocation so an illegal strategy fails

@@ -29,6 +29,35 @@ family.
         reads the POST-attention norm, softmax over all experts with the
         chosen renormalised, experts down(act(gate(x)) * up(x)) of width
         ``moe_intermediate_size`` with act ``hidden_act``, no shared one
+    A   latent attention, then a SwiGLU MLP of ``intermediate_size``
+        (gate and up as one product, ``b<i>_gate_up_proj``),
+        each behind its own norm and residual. Latent attention
+        (``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
+        ``qk_rope_head_dim``, ``v_head_dim`` = the not-rotated width):
+        queries and keys/values out of low-rank latents with an RMS norm
+        on each; a head's query and key are [not rotated ; rotated], the
+        rotated key ONE vector a position for all heads, rotary
+        (``rope_theta``) over adjacent pairs of the rotated lanes only
+    X   latent attention, then experts: sigmoid scores with the
+        score-correction bias, the chosen renormalised and scaled
+        (``routed_scaling_factor``), experts down(act(gate(x)) * up(x))
+        of ``moe_intermediate_size``, and a shared expert of the same
+        gated form, ``n_shared_experts * moe_intermediate_size`` wide
+
+``num_nextn_predict_layers`` 1 adds the multi-token-prediction module: a
+second branch off the last block's output x_L (before the final norm)
+that reads the NEXT token's embedding, u_i = [rms_norm(e(t_{i+1})) ;
+rms_norm(x_L,i)] W_eh, runs one more `X` block on u with weights of its
+own (ops named ``mtp_*``, under the trace scope ``mtp``) and shares the
+embedding and the head with the main model: the two normed hidden
+sequences are laid end to end and ONE head gives logits [B, 2S, V], the
+main model's and then the module's, whose row i predicts t_{i+2}. Train
+with ``WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY`` on labels [B, 2S, 2]
+(token, weight; weight 0 where a target does not exist, the module's
+half scaled by the weight between the two losses); ``ff.loss_parts``
+then names the halves, and an epoch's ``ff.op_counters`` hold
+``loss/main_nll`` and ``loss/mtp_nll``, the sums of the two unweighted
+cross-entropies over their targets.
 
 After the last block ``rms_norm`` and the head, ``logits = x W_head``
 (untied); train with ``SPARSE_CATEGORICAL_CROSSENTROPY`` on labels
@@ -98,6 +127,19 @@ class DecoderConfig:
     moe_ffn_hidden_size: int = 32           # expert width of `G`, `W`
     # dense MLP (`-`, `L`)
     intermediate_size: int = 128
+    # latent attention (`A`, `X`); `rope_whole_head` is a control: rotary
+    # over all lanes of a head, as a model without the split would
+    q_lora_rank: int = 32
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    rope_whole_head: bool = False
+    n_shared_experts: int = 1               # `X`
+    # the multi-token-prediction module: 0 or 1; `mtp_shift` is the
+    # distance of the token whose embedding it reads (1; 0 is a control)
+    num_nextn_predict_layers: int = 0
+    mtp_shift: int = 1
     batch_size: int = 2
     seq_length: int = 16
     seq_parallel: Optional[str] = None      # 'seq': ring attention
@@ -159,21 +201,89 @@ def _block_diffusion_block(ff, t, i, cfg):
     return ff.add(t, m, name=f"b{i}_res2")
 
 
+def _swiglu_mlp(ff, h, cfg, prefix, one_product=False):
+    """down(silu(gate(x)) * up(x)); with ``one_product`` gate and up are
+    the two halves of one product's columns (the leaf
+    ``<prefix>_gate_up_proj``), which is what the search's rewrite makes
+    of the two anyway, under a name the builder knows."""
+    if one_product:
+        gate, up = ff.split(
+            ff.dense(h, 2 * cfg.intermediate_size, use_bias=False,
+                     name=f"{prefix}_gate_up_proj"),
+            [cfg.intermediate_size] * 2, axis=2, name=f"{prefix}_gate_up")
+    else:
+        gate = ff.dense(h, cfg.intermediate_size, use_bias=False,
+                        name=f"{prefix}_gate_proj")
+        up = ff.dense(h, cfg.intermediate_size, use_bias=False,
+                      name=f"{prefix}_up_proj")
+    silu = ff.multiply(gate, ff.sigmoid(gate, name=f"{prefix}_sig"),
+                       name=f"{prefix}_silu")
+    h = ff.multiply(silu, up, name=f"{prefix}_swiglu")
+    return ff.dense(h, cfg.hidden_size, use_bias=False,
+                    name=f"{prefix}_down_proj")
+
+
 def _llama_block(ff, t, i, cfg):
     h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"l{i}_input_ln")
     t = ff.add(t, _attention(ff, h, cfg, f"l{i}_attn", rope=True),
                name=f"l{i}_res1")
-    # SwiGLU MLP: down(silu(gate(x)) * up(x))
     h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"l{i}_post_ln")
-    gate = ff.dense(h, cfg.intermediate_size, use_bias=False,
-                    name=f"l{i}_gate_proj")
-    up = ff.dense(h, cfg.intermediate_size, use_bias=False,
-                  name=f"l{i}_up_proj")
-    silu = ff.multiply(gate, ff.sigmoid(gate, name=f"l{i}_sig"),
-                       name=f"l{i}_silu")
-    h = ff.multiply(silu, up, name=f"l{i}_swiglu")
-    h = ff.dense(h, cfg.hidden_size, use_bias=False, name=f"l{i}_down_proj")
-    return ff.add(t, h, name=f"l{i}_res2")
+    return ff.add(t, _swiglu_mlp(ff, h, cfg, f"l{i}"), name=f"l{i}_res2")
+
+
+def _latent_block(ff, t, prefix, cfg, experts):
+    """`A` / `X`: x' = x + latent_attention(norm(x)), x'' = x' + f(norm(x'))
+    with f the SwiGLU MLP or the experts with their shared expert."""
+    eps = cfg.layer_norm_epsilon
+    if cfg.v_head_dim != cfg.qk_nope_head_dim:
+        raise ValueError("decoder: latent attention takes a value head as "
+                         "wide as the query/key head's not-rotated part")
+    h = ff.rms_norm(t, eps=eps, name=f"{prefix}_norm")
+    a = ff.multihead_attention(
+        h, h, h, cfg.hidden_size, cfg.num_attention_heads, bias=False,
+        causal=True, rope=True, rope_theta=cfg.rope_theta,
+        head_dim=cfg.qk_nope_head_dim, q_lora_rank=cfg.q_lora_rank,
+        kv_lora_rank=cfg.kv_lora_rank,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, latent_norm_eps=eps,
+        rope_whole_head=cfg.rope_whole_head, name=f"{prefix}_attn")
+    t = ff.add(t, a, name=f"{prefix}_res1")
+    g = ff.rms_norm(t, eps=eps, name=f"{prefix}_post_norm")
+    if not experts:
+        return ff.add(t, _swiglu_mlp(ff, g, cfg, prefix, one_product=True),
+                      name=f"{prefix}_res2")
+    m = ff.moe_layer(
+        g, cfg.n_routed_experts, cfg.num_experts_per_tok,
+        cfg.moe_intermediate_size,
+        shared_width=cfg.n_shared_experts * cfg.moe_intermediate_size,
+        experts_held=cfg.experts_held, expert_offset=cfg.expert_offset,
+        routed_scaling=cfg.routed_scaling_factor,
+        norm_topk=cfg.norm_topk_prob, slot_slack=cfg.slot_slack,
+        gated=True, activation=cfg.hidden_act, name=f"{prefix}_mixer")
+    return ff.add(t, m, name=f"{prefix}_res2")
+
+
+def _mtp_module(ff, embedded, x_last, cfg):
+    """The multi-token-prediction module's hidden states, normed for the
+    shared head: [B, S, E] whose row i stands for the token after next."""
+    eps, seq = cfg.layer_norm_epsilon, cfg.seq_length
+    if cfg.num_nextn_predict_layers != 1:
+        raise NotImplementedError("decoder: one multi-token-prediction "
+                                  "module (num_nextn_predict_layers 0 or 1)")
+    with ff.scope("mtp"):
+        e_next = embedded
+        if cfg.mtp_shift:
+            # row i reads e(t_{i + shift}); the last rows wrap around to
+            # the first tokens and carry no target
+            first, rest = ff.split(embedded, [cfg.mtp_shift,
+                                              seq - cfg.mtp_shift],
+                                   axis=1, name="mtp_shift")
+            e_next = ff.concat([rest, first], axis=1, name="mtp_next")
+        u = ff.concat([ff.rms_norm(e_next, eps=eps, name="mtp_enorm"),
+                       ff.rms_norm(x_last, eps=eps, name="mtp_hnorm")],
+                      axis=2, name="mtp_eh")
+        u = ff.dense(u, cfg.hidden_size, use_bias=False, name="mtp_eh_proj")
+        u = _latent_block(ff, u, "mtp", cfg, experts=True)
+        return ff.rms_norm(u, eps=eps, name="mtp_final_ln")
 
 
 def _mixer(ff, h, letter, i, cfg):
@@ -203,16 +313,19 @@ def _mixer(ff, h, letter, i, cfg):
         return ff.dense(ff.multiply(up, up, name=f"b{i}_sq"),
                         cfg.hidden_size, use_bias=False, name=name)
     raise ValueError(f"decoder pattern: unknown block letter {letter!r} "
-                     f"(known: M E * - L G W D)")
+                     f"(known: M E * - L G W D A X)")
 
 
 def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
     ff = FFModel(ff_config or FFConfig(batch_size=cfg.batch_size))
     ids = ff.create_tensor((cfg.batch_size, cfg.seq_length),
                            dtype=DataType.INT32, name="input_ids")
-    t = ff.embedding(ids, cfg.vocab_size, cfg.hidden_size,
-                     name="embed_tokens")
+    t = embedded = ff.embedding(ids, cfg.vocab_size, cfg.hidden_size,
+                                name="embed_tokens")
     for i, letter in enumerate(cfg.hybrid_override_pattern):
+        if letter in "AX":
+            t = _latent_block(ff, t, f"b{i}", cfg, experts=letter == "X")
+            continue
         if letter == "L":
             t = _llama_block(ff, t, i, cfg)
             continue
@@ -228,6 +341,12 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
         # the head and the loss read the noised half alone
         half = cfg.seq_length // 2
         t = ff.split(t, [half, half], axis=1, name="noised_half")[0]
+    mtp = (_mtp_module(ff, embedded, t, cfg)
+           if cfg.num_nextn_predict_layers else None)
     t = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name="final_ln")
+    if mtp is not None:
+        # one head over both hidden sequences laid end to end
+        t = ff.concat([t, mtp], axis=1, name="main_and_mtp")
+        ff.loss_parts = ("main", "mtp")
     t = ff.dense(t, cfg.vocab_size, use_bias=False, name="lm_head")
     return ff

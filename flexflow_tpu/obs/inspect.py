@@ -339,6 +339,11 @@ def model_context(ff) -> Dict[str, Any]:
         # loss (None before one, or under another loss)
         loss_target_positions=(getattr(ff, "op_counters", None) or {}).get(
             "loss/target_positions"),
+        # the two unweighted cross-entropy sums of a model with a
+        # multi-token-prediction module, its last epoch's
+        **{"loss_" + part + "_nll": (getattr(ff, "op_counters", None)
+                                     or {}).get(f"loss/{part}_nll")
+           for part in (getattr(ff, "loss_parts", None) or ())},
     )
 
 

@@ -30,7 +30,10 @@ SUFFIX = ".step_scopes.json"
 # program scope -> part; beside them `attention_<kind>` -> "attention"
 # and `op_<kind>` -> itself
 SCOPE_PARTS = {"optimizer_update": "optimizer_update", "loss": "loss",
-               "head": "head", "moe_layer": "experts", "ssm_mixer": "ssm"}
+               "head": "head", "moe_layer": "experts", "ssm_mixer": "ssm",
+               # a multi-token-prediction module's own ops, whatever their
+               # kind: the scope lies around theirs (`FFModel.scope`)
+               "mtp": "mtp"}
 ATTENTION_SCOPE = "attention_"
 OP_SCOPE = "op_"
 # a Pallas kernel called at the top level (the non-causal attention op's,

@@ -53,6 +53,39 @@ def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
             ).astype(x.dtype)
 
 
+def rotary_interleaved(x, *, theta: float, head_dim: int, lanes_of=None):
+    """RoPE over the ADJACENT pairs (2i, 2i+1) of x [B, S, n*D], n heads of
+    ``head_dim`` D side by side along the minor axis, positions 0..S-1. A
+    lane's partner is its neighbour, fetched by a roll of the minor axis:
+    no strided slice, and no head is taken apart. ``lanes_of`` (first,
+    total): the D lanes are those from ``first`` of a rotated head
+    ``total`` wide, whose frequencies they take (a control's)."""
+    seq_axis, d = 1, head_dim
+    s, width = x.shape[seq_axis], x.shape[-1]
+    first, total = lanes_of or (0, d)
+    inv_freq = 1.0 / (theta ** ((first + jnp.arange(
+        0, d, 2, dtype=jnp.float32)) / total))
+    pos = jnp.arange(s, dtype=jnp.float32)
+    angles = jnp.repeat(pos[:, None] * inv_freq[None, :], 2, axis=-1)  # [S, D]
+    angles = jnp.tile(angles, (1, width // d))
+    shape = [1] * x.ndim
+    shape[seq_axis], shape[-1] = s, width
+    cos, sin = jnp.cos(angles).reshape(shape), jnp.sin(angles).reshape(shape)
+    xf = x.astype(jnp.float32)
+    even = jax.lax.broadcasted_iota(jnp.int32, shape, x.ndim - 1) % 2 == 0
+    # lane 2i gets -x[2i+1], lane 2i+1 gets x[2i]
+    partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
+                        jnp.roll(xf, 1, axis=-1))
+    return (xf * cos + partner * sin).astype(x.dtype)
+
+
+def rms_normed(x, scale, eps):
+    """RMS norm over the minor axis in float32, with a learned scale."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * scale.astype(jnp.float32)
+
+
 def scaled_dot_product_attention(q, k, v, *, causal=False, dropout_rate=0.0,
                                  rng=None, compute_dtype=jnp.float32,
                                  window=0, block_diffusion=None):
@@ -96,6 +129,26 @@ class MultiHeadAttention(Op):
     wo [H, D, E] — the head axis is the attribute-parallel dim the search
     may shard on the model mesh axis (reference attention.cc:214). The
     forward multiplies through their [E, H*D] / [H*D, E] views.
+
+    Latent attention (PR 39; DeepSeek-V2's MLA) is this op with further
+    properties, ``q_lora_rank`` / ``kv_lora_rank`` / ``qk_rope_head_dim``:
+    queries and keys/values come out of low-rank latents with an RMS norm
+    on each, a head's query and key are ``[head_dim not rotated ;
+    qk_rope_head_dim rotated]``, the rotated key part is ONE vector a
+    position shared by all heads, values are ``head_dim`` wide, and the
+    softmax scale is (head_dim + qk_rope_head_dim)^-0.5:
+        c_q = rms_norm(x wq_a);  q_nope, q_rope = c_q wq_b_nope, c_q wq_b_rope
+        [c_kv ; k_r] = x wkv_a;  c_kv = rms_norm(c_kv)
+        k_nope, v = c_kv wkv_b_k, c_kv wkv_b_v
+        scores = (q_nope k_nope^T + rope(q_rope) rope(k_r)^T) * scale
+    with rotary over the ADJACENT pairs (2i, 2i+1) of the rotated lanes.
+    Leaves: wq_a [E, Rq], q_a_norm [Rq], wq_b_nope [H, Rq, D], wq_b_rope
+    [H, Rq, R], wkv_a [E, Rkv + R], kv_a_norm [Rkv], wkv_b_k, wkv_b_v
+    [H, Rkv, D], wo [H, D, E]. What differs from the plain op is the
+    projections (``_qkv_latent``, ``init_params``, the counts); the core's
+    dispatch, scopes, counters and kernel choice are shared, and the
+    flash kernels take the rotated parts as two more operands
+    (``pallas_kernels._flash_fwd``), so nothing is assembled per head.
     """
 
     scopes_itself = True
@@ -147,6 +200,25 @@ class MultiHeadAttention(Op):
         # the rotary embedding, with a learned scale each
         self.qk_norm = p.get("qk_norm", False)
         self.qk_norm_eps = p.get("qk_norm_eps", 1e-6)
+        # latent attention: (q rank, kv rank, rotated width) or None
+        self.latent = None
+        if p.get("kv_lora_rank"):
+            self.latent = (p["q_lora_rank"], p["kv_lora_rank"],
+                           p["qk_rope_head_dim"])
+            self.latent_norm_eps = p.get("latent_norm_eps", 1e-6)
+            # a control, not a model: every lane of a head is rotated,
+            # as one rotary over head_dim + qk_rope_head_dim lanes
+            self.rope_whole_head = p.get("rope_whole_head", False)
+            if not (self.causal and self.num_kv_heads == self.num_heads
+                    and len(input_shapes) == 3
+                    and input_shapes[0] == input_shapes[1]) or (
+                        self.window or self.block_diffusion or self.qk_norm
+                        or self.use_bias or self.rope_wrap
+                        or p.get("seq_parallel")):
+                raise ValueError(
+                    f"attention '{layer.name}': latent attention is causal "
+                    f"self-attention with as many key/value heads as query "
+                    f"heads, no bias, window, mask, head norm or ring")
         # separate q/k/v projection biases (torch nn.MultiheadAttention
         # parity — in_proj_bias). Off by default: they cost an extra
         # elementwise pass over q/k/v every step and native models
@@ -188,6 +260,17 @@ class MultiHeadAttention(Op):
     def init_params(self, rng):
         h, e, d = self.num_heads, self.embed_dim, self.head_dim
         hk = self.num_kv_heads
+        if self.latent:
+            rq, rkv, r = self.latent
+            shapes = {"wq_a": (e, rq), "wq_b_nope": (h, rq, d),
+                      "wq_b_rope": (h, rq, r), "wkv_a": (e, rkv + r),
+                      "wkv_b_k": (h, rkv, d), "wkv_b_v": (h, rkv, d),
+                      "wo": (h, d, e)}
+            params = {n: self.kernel_init(k, shape) for (n, shape), k in zip(
+                shapes.items(), jax.random.split(rng, len(shapes)))}
+            params["q_a_norm"] = jnp.ones((rq,))
+            params["kv_a_norm"] = jnp.ones((rkv,))
+            return params
         ks = jax.random.split(rng, 4)
         params = {
             "wq": self.kernel_init(ks[0], (h, e, d)),
@@ -233,6 +316,12 @@ class MultiHeadAttention(Op):
         return (xh * rms * scale.astype(jnp.float32)).reshape(x.shape)
 
     @property
+    def rope_dim(self) -> int:
+        """Rotated lanes a head's query and key carry beside ``head_dim``
+        (latent attention's two-part score), else 0."""
+        return self.latent[2] if self.latent else 0
+
+    @property
     def windowed(self) -> bool:
         """The window hides something at this sequence length."""
         return 0 < self.window < self.input_shapes[0][1]
@@ -267,6 +356,9 @@ class MultiHeadAttention(Op):
         # are `attention_block_diffusion` / `flash_block_diffusion`.
         if self.block_diffusion:
             kind = "block_diffusion"
+        elif self.latent:
+            # `attention_latent` / `flash_latent` (PR 39)
+            kind = "latent"
         elif self.causal:
             kind = "window" if self.windowed else "full"
         else:
@@ -285,9 +377,10 @@ class MultiHeadAttention(Op):
                  around=lambda fn: fn):
         """Projections, core, output projection. ``around`` wraps every
         piece but a flash kernel call (the non-causal op's scope)."""
-        q, k, v = around(lambda params, inputs: self._qkv(
-            params, inputs, ctx))(params, inputs)
-        o = self._core(q, k, v, ctx, rng, flash_scope, around)
+        q, k, v, rope = around(lambda params, inputs: (
+            self._qkv_latent if self.latent else self._qkv)(
+                params, inputs, ctx))(params, inputs)
+        o = self._core(q, k, v, ctx, rng, flash_scope, around, rope)
         return [around(lambda params, o: self._output(
             params, o, ctx, inputs[0].dtype))(params, o)]
 
@@ -322,7 +415,43 @@ class MultiHeadAttention(Op):
         # projections accumulate in f32): softmax/accumulation inside every
         # path below is f32 regardless, and bf16 kernel I/O halves the
         # flash kernel's HBM traffic
-        return q.astype(cd), k.astype(cd), v.astype(cd)
+        return q.astype(cd), k.astype(cd), v.astype(cd), None
+
+    def _qkv_latent(self, params, inputs, ctx: OpContext):
+        """q_nope, k_nope, v [B, S, H*D] and (q_rope [B, S, H*R], the one
+        rotated key [B, S, R]) in the compute dtype: both latents, their
+        norms, the up-projections (the not-rotated and the rotated parts
+        as products of their own, so that each comes out lane-dense and
+        nothing is sliced out of a 192-wide head) and rotary."""
+        x = inputs[0]
+        cd = ctx.compute_dtype
+        _, rkv, r = self.latent
+        eps, theta = self.latent_norm_eps, self.rope_theta
+
+        def dot(a, w):
+            return jnp.dot(a.astype(cd), w.astype(cd),
+                           preferred_element_type=jnp.float32)
+
+        def rotated(t):     # [B, S, n*R], n heads side by side
+            return rotary_interleaved(
+                t, theta=theta, head_dim=r,
+                lanes_of=(self.head_dim, self.head_dim + r)
+                if self.rope_whole_head else None)
+
+        c_q = rms_normed(dot(x, params["wq_a"]), params["q_a_norm"], eps)
+        q_nope = self._project(c_q, params["wq_b_nope"], None, cd)
+        q_rope = rotated(self._project(c_q, params["wq_b_rope"], None, cd))
+        kv = dot(x, params["wkv_a"])
+        c_kv = rms_normed(kv[..., :rkv], params["kv_a_norm"], eps)
+        k_nope = self._project(c_kv, params["wkv_b_k"], None, cd)
+        v = self._project(c_kv, params["wkv_b_v"], None, cd)
+        k_rope = rotated(kv[..., rkv:])
+        if self.rope_whole_head:
+            q_nope, k_nope = (rotary_interleaved(
+                t, theta=theta, head_dim=self.head_dim,
+                lanes_of=(0, self.head_dim + r)) for t in (q_nope, k_nope))
+        return (q_nope.astype(cd), k_nope.astype(cd), v.astype(cd),
+                (q_rope.astype(cd), k_rope.astype(cd)))
 
     def _output(self, params, o, ctx: OpContext, dtype):
         cd = ctx.compute_dtype
@@ -333,29 +462,41 @@ class MultiHeadAttention(Op):
             y = y + params["bo"]
         return y.astype(dtype)
 
-    def _core(self, q, k, v, ctx: OpContext, rng, flash_scope, around):
+    def _core(self, q, k, v, ctx: OpContext, rng, flash_scope, around,
+              rope=None):
         from flexflow_tpu.ops.pallas_kernels import merge_heads, split_heads
 
         cd = ctx.compute_dtype
         h, d = self.num_heads, self.head_dim
+        rope_dim = self.rope_dim
         b, sq, sk = q.shape[0], q.shape[1], k.shape[1]
         dropout_rate = self.dropout if ctx.training else 0.0
 
         def heads_first(core):
-            """A core that wants [B, H, S, D], at its own boundary."""
-            return around(lambda q, k, v: merge_heads(core(
-                split_heads(q, h), split_heads(k, h), split_heads(v, h)))
-            )(q, k, v)
+            """A core that wants [B, H, S, D], at its own boundary. Under
+            latent attention a head's query and key are assembled here,
+            the obvious way: [not rotated ; rotated], the one rotated key
+            repeated to every head."""
+            def whole(q, k, v, rope):
+                q, k, v = (split_heads(t, h) for t in (q, k, v))
+                if rope is not None:
+                    q = jnp.concatenate([q, split_heads(rope[0], h)], -1)
+                    k = jnp.concatenate([k, jnp.broadcast_to(
+                        rope[1][:, None], k.shape[:3] + (rope_dim,))], -1)
+                return merge_heads(core(q, k, v))
+            return around(whole)(q, k, v, rope)
 
         seq_axis = self.seq_parallel
         mesh_axes = (dict(zip(ctx.mesh.axis_names, ctx.mesh.devices.shape))
                      if ctx.mesh is not None else {})
         if seq_axis and mesh_axes.get(seq_axis, 1) > 1 and sq == sk:
-            if self.windowed or self.block_diffusion:
+            if self.windowed or self.block_diffusion or self.latent:
                 raise NotImplementedError(
                     f"attention '{self.name}': ring attention has no "
                     f"sliding window and no block-diffusion mask (each "
-                    f"block of the ring would need its own offset into it)")
+                    f"block of the ring would need its own offset into it) "
+                    f"and no latent attention (the ring would pass the "
+                    f"latent and the one rotated key, not whole heads)")
             if dropout_rate > 0.0 and not getattr(self, "_warned_dropout", False):
                 import warnings
 
@@ -379,7 +520,7 @@ class MultiHeadAttention(Op):
                 flash_attention_sharded, flash_shape_legal, kv_blocks,
                 kv_blocks_masked)
 
-            available = flash_attention_available(sq, d, h)
+            available = flash_attention_available(sq, d, h, rope_dim)
             if self.kernel_impl == "flash" and not available:
                 # the search chose flash but this platform/shape cannot
                 # run it: record the silent fallback for fflint FFL209
@@ -397,6 +538,9 @@ class MultiHeadAttention(Op):
                         kernel, num_heads=h, causal=self.causal,
                         window=self.window,
                         block_diffusion=self.block_diffusion, **where)
+                    if rope is not None:
+                        return scoped(flash_scope, lambda q, k, v, rope: call(
+                            q, k, v, rope=rope))(q, k, v, rope)
                     return (scoped(flash_scope, call) if flash_scope
                             else call)(q, k, v)
 
@@ -418,6 +562,7 @@ class MultiHeadAttention(Op):
                         else (batch_axis,)
                     # a shard's heads still have to tile the lanes
                     head_axis = (hp if hp and hp not in in_batch
+                                 and rope is None   # one key for all heads
                                  and mesh_axes.get(hp, 1) > 1
                                  and h % mesh_axes[hp] == 0
                                  and flash_shape_legal(
@@ -461,8 +606,8 @@ class MultiHeadAttention(Op):
             return "einsum"
         b, s, e = self.input_shapes[0]
         sk = self.input_shapes[1][1] if len(self.input_shapes) > 1 else s
-        if s == sk and flash_attention_available(s, self.head_dim,
-                                                 self.num_heads):
+        if s == sk and flash_attention_available(
+                s, self.head_dim, self.num_heads, self.rope_dim):
             return "flash"
         return "einsum"
 
@@ -485,6 +630,12 @@ class MultiHeadAttention(Op):
         (a bidirectional row would need future K/V that doesn't exist
         yet); non-causal ops refuse rather than silently drift.
         """
+        if self.latent:
+            raise NotImplementedError(
+                f"attention '{self.name}': KV-cache incremental decode has "
+                f"no latent attention (its cache would hold the compressed "
+                f"latent and the one rotated key a position, read in the "
+                f"absorbed form; this path caches whole heads)")
         if self.block_diffusion:
             raise NotImplementedError(
                 f"attention '{self.name}': KV-cache incremental decode has "
@@ -584,6 +735,12 @@ class MultiHeadAttention(Op):
         b, sq, e = self.input_shapes[0]
         sk = self.input_shapes[1][1] if len(self.input_shapes) > 1 else sq
         h, d = self.num_heads, self.head_dim
+        if self.latent:
+            # both latents' projections and the output's through their
+            # weights; scores over d + rope lanes, values over d
+            matrices = self.params_elems() - self.latent[0] - self.latent[1]
+            return (2 * b * sq * matrices
+                    + 2 * b * h * self.visible_pairs * (2 * d + self.rope_dim))
         hk = self.num_kv_heads  # GQA: k/v projections use the kv heads
         proj = (2 * b * h * d * (sq * e + sq * e)
                 + 2 * b * hk * d * (sk * self.kdim + sk * self.vdim))
@@ -596,6 +753,10 @@ class MultiHeadAttention(Op):
 
     def params_elems(self):
         h, e, d = self.num_heads, self.embed_dim, self.head_dim
+        if self.latent:
+            rq, rkv, r = self.latent
+            return (e * rq + rq + rq * h * (d + r) + e * (rkv + r) + rkv
+                    + rkv * h * 2 * d + h * d * e)
         hk = self.num_kv_heads
         n = h * d * (e + e) + hk * d * (self.kdim + self.vdim)
         if self.qk_norm:

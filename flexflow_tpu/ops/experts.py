@@ -126,7 +126,9 @@ class MoELayer(Op):
         top-k of s + b; w_j = s_j / (sum of the k + 1e-20) * routed_scaling
         expert_j(x) = relu(x U_j)^2 D_j        (no gate, no bias)
         out = sum over the chosen j that are held of w_j expert_j(x)
-              + shared(x)             (the same form, `shared_width` wide)
+              + shared(x)             (the experts' own form, `shared_width`
+                                       wide: with `gated` a third leaf
+                                       `ws_gate`, PR 39)
 
     Three things a model may state otherwise (PR 31), each a property:
     `scoring` "softmax": the k largest LOGITS x W_r are chosen and w is
@@ -173,9 +175,9 @@ class MoELayer(Op):
     models that balance their experts without an auxiliary loss adjust it
     outside the gradient), w_up [held, D, F], w_down [held, F, D], with
     `gated` w_gate [held, D, F], and with a shared expert ws_up [D, Fs],
-    ws_down [Fs, D]. The router's leaves stay float32 in the compute
-    copy: a bias rounded to bfloat16 moves the choice of every token
-    alike.
+    ws_down [Fs, D] (and with `gated` ws_gate [D, Fs]). The router's
+    leaves stay float32 in the compute copy: a bias rounded to bfloat16
+    moves the choice of every token alike.
     """
 
     scopes_itself = True
@@ -261,6 +263,9 @@ class MoELayer(Op):
             params["ws_up"] = self.kernel_init(ks[3], (d, self.shared_width))
             params["ws_down"] = self.kernel_init(ks[4],
                                                  (self.shared_width, d))
+            if self.gated:
+                params["ws_gate"] = self.kernel_init(
+                    jax.random.fold_in(ks[3], 1), (d, self.shared_width))
         return params
 
     def forward(self, params, inputs, ctx: OpContext):
@@ -303,8 +308,13 @@ class MoELayer(Op):
         def shared(params, xt):
             hs = jnp.dot(xt.astype(cd), params["ws_up"].astype(cd),
                          preferred_element_type=jnp.float32)
-            return jnp.dot(squared_relu(hs).astype(cd),
-                           params["ws_down"].astype(cd),
+            if self.gated:     # the experts' own form (PR 39)
+                gs = jnp.dot(xt.astype(cd), params["ws_gate"].astype(cd),
+                             preferred_element_type=jnp.float32)
+                hs = GATE_ACTIVATIONS[self.activation](gs) * hs
+            else:
+                hs = squared_relu(hs)
+            return jnp.dot(hs.astype(cd), params["ws_down"].astype(cd),
                            preferred_element_type=jnp.float32)
 
         def layer(params, x, x_router):
@@ -350,7 +360,7 @@ class MoELayer(Op):
         pairs = t * self.k * self.experts_held / self.n_experts
         return int(2 * t * d * self.n_experts
                    + 2 * self.matrices * pairs * d * self.hidden_size
-                   + 4 * t * d * self.shared_width)
+                   + 2 * self.matrices * t * d * self.shared_width)
 
     def interior_bytes(self):
         """Kept for the backward pass besides the output: the buffer's
@@ -359,11 +369,11 @@ class MoELayer(Op):
         the scores."""
         d = self.input_shapes[0][-1]
         return (self.buffer_rows * (d + self.matrices * self.hidden_size)
-                + self.tokens * 2 * self.shared_width
+                + self.tokens * self.matrices * self.shared_width
                 ) * self.dtype.size + 4 * self.tokens * self.n_experts
 
     def params_elems(self):
         d = self.input_shapes[0][-1]
         return ((d + (self.scoring == "sigmoid")) * self.n_experts
                 + self.matrices * self.experts_held * d * self.hidden_size
-                + 2 * d * self.shared_width)
+                + self.matrices * d * self.shared_width)

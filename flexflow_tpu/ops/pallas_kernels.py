@@ -550,15 +550,34 @@ def _dot_head(a, b, h: int, head_dim: int):
     return _dot(_only_head(a, h, head_dim), b, _NT)
 
 
+def _rope_lanes(x, head, rope_dim: int):
+    """``x`` [rows, 128], a column block of the rotated query parts
+    (128 // rope_dim heads side by side), with every lane but those of
+    head ``head`` of the operand zeroed: ``head`` is the grid's (traced)
+    column block of the 128-wide parts, one head each. Against the
+    shared rotated key laid side by side as often ([k_r ; k_r]) the MXU
+    then contracts head ``head``'s rope_dim lanes alone."""
+    return jnp.where(_rope_lanes_of(x.shape, head, rope_dim), x,
+                     jnp.zeros_like(x))
+
+
+def _rope_lanes_of(shape, head, rope_dim: int):
+    """Where, in a [rows, 128] block of rotated parts, head ``head``'s
+    lanes are."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return lane // rope_dim == head % (LANES // rope_dim)
+
+
 def _stack_heads(parts):
     """Per-head [D, S] results as the [W, S] tile of their column block:
     heads are sublane ranges there, so this moves nothing across lanes."""
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
                       window: int, scale: float, blk_q: int, blk_k: int,
-                      head_dim: int, block_diffusion=None):
+                      head_dim: int, block_diffusion=None,
+                      rope_dim: int = 0):
     """One (batch row, column block, q-block) grid cell: q [1,BLK_Q,W]
     against the K/V panels [1,S,W] resident in VMEM, in chunks of
     ``blk_k`` keys with a running max and sum (the online softmax), and
@@ -584,7 +603,17 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
     S = 16384 full causal 4.08 -> 3.91 -> 3.84, window 4096 2.34 ->
     2.19 -> 2.20 (three short loops a Q block); 4 heads at S = 8192
     causal 0.66 -> 0.62 -> 0.61. At chunks of 1024 most of the mask's
-    passes hide under the products."""
+    passes hide under the products.
+
+    With ``rope_dim`` (PR 39, the two-part score of latent attention)
+    two more operands stand before the outputs: the heads' rotated query
+    parts (``_rope_lanes`` picks this head's) and the ONE rotated key a
+    position, whose product is added to the score ahead of the scale."""
+    if rope_dim:
+        qr_ref, kr_ref, o_ref, lse_ref = rest
+        qr = _rope_lanes(qr_ref[0], pl.program_id(1), rope_dim)
+    else:
+        o_ref, lse_ref = rest
     q = q_ref[0]  # [BLK_Q, W]
     heads = q.shape[-1] // head_dim
     q0 = pl.program_id(2) * blk_q
@@ -607,7 +636,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
         mask = seen(k0) if edge else None
         out = []
         for h, (m, l, acc) in enumerate(carry):
-            s = _dot(qs[h], k, _NT) * scale
+            s = _dot(qs[h], k, _NT)
+            if rope_dim:
+                s = s + _dot(qr, kr_ref[0, pl.ds(k0, blk_k), :], _NT)
+            s = s * scale
             if edge:
                 s = jnp.where(mask, s, _MASKED)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -638,9 +670,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
     o_ref[0] = o.astype(o_ref.dtype)
 
 
-def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, *rest,
                             causal: bool, window: int, scale: float,
-                            rows: int, head_dim: int, block_diffusion=None):
+                            rows: int, head_dim: int, block_diffusion=None,
+                            rope_dim: int = 0):
     """``rows`` batch rows a grid cell, each with the heads of one column
     block and their whole sequence: the forward up to MAX_BWD_SEQ. The
     score tile is held as [k, q]: the softmax's max and sum then run down
@@ -649,15 +682,25 @@ def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     reductions a row and a relayout; and O^T = V^T P^T streams D rows
     through the MXU against the tile, which a head_dim of 64 fills where
     P V fills half its width. V^T and O^T are [W, S] tiles of the whole
-    block: one transpose each serves every head of it."""
+    block: one transpose each serves every head of it. ``rope_dim``:
+    the two-part score, as in ``_flash_fwd_kernel``."""
     heads = q_ref.shape[-1] // head_dim
+    if rope_dim:
+        qr_ref, kr_ref, o_ref, lse_ref = rest
+        head = pl.program_id(1)   # taken outside the rows' loop
+    else:
+        o_ref, lse_ref = rest
 
     def row(b):
         q, k, v = q_ref[b], k_ref[b], v_ref[b]      # [S, W]
         vt = v.T                                     # [W, S]
         ots = []
         for h in range(heads):
-            st = _dot_head(k, q, h, head_dim) * scale   # [k, q]
+            st = _dot_head(k, q, h, head_dim)           # [k, q]
+            if rope_dim:
+                st = st + _dot(kr_ref[b], _rope_lanes(
+                    qr_ref[b], head, rope_dim), _NT)
+            st = st * scale
             if causal or block_diffusion is not None:
                 st = _mask(st, 0, 0, window, block_diffusion)
             m = jnp.max(st, axis=0, keepdims=True)   # [1, S]
@@ -672,17 +715,41 @@ def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     _for_rows(rows, max(1, 4 // heads), row)
 
 
+def _rope_operands(rope, num_heads: int, head_dim: int):
+    """The two-part score's further operands as the kernels take them:
+    (rope_dim, heads a 128-lane block of the rotated query parts,
+    q_rope [B, S, H*rope_dim], the one rotated key [B, S, rope_dim] laid
+    side by side to fill 128 lanes), or (0, 1) and nothing."""
+    if rope is None:
+        return 0, 1, ()
+    q_rope, k_rope = rope
+    rope_dim = k_rope.shape[-1]
+    per = LANES // rope_dim
+    assert head_dim == LANES and q_rope.shape[-1] == num_heads * rope_dim \
+        and num_heads % per == 0, (head_dim, q_rope.shape, k_rope.shape)
+    return rope_dim, per, (q_rope, jnp.concatenate([k_rope] * per, axis=-1))
+
+
 def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
-               out_dtype=None, window: int = 0, block_diffusion=None):
+               out_dtype=None, window: int = 0, block_diffusion=None,
+               rope=None):
     """q, k, v: [B, S, H*D] with S % BLK_Q == 0 -> (o [B, S, H*D],
     lse [B, H, 1, S]). A block is S (or BLK_Q) rows by one column block
     of the operand, picked by the BlockSpec's last index: in HBM's
-    (8, 128) tiles that is a run of whole tiles, no lane of it padding."""
+    (8, 128) tiles that is a run of whole tiles, no lane of it padding.
+
+    ``rope`` = (q_rope [B, S, H*R], k_rope [B, S, R]): the score of a
+    head is q k^T + q_rope k_rope^T over sqrt(D + R), the rotated key
+    ONE vector a position for all heads (latent attention; D = 128). The
+    kernel reads that key as it is, laid twice side by side so that a
+    128-lane block of q_rope (two heads' parts, the other's zeroed)
+    contracts against it; nothing is assembled per head in HBM."""
     b, s, hd = q.shape
     d = hd // num_heads
     hpb = _heads_per_block(num_heads, d)
     w = hpb * d
-    scale = 1.0 / float(d) ** 0.5
+    rope_dim, per, rope_ops = _rope_operands(rope, num_heads, d)
+    scale = 1.0 / float(d + rope_dim) ** 0.5
     window = normalized_window(s, causal, window)
     bd = checked_block_diffusion(s, causal, window, block_diffusion)
     # lse is (b, h, 1, s): TPU requires the last two block dims be
@@ -693,25 +760,34 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     if s <= MAX_BWD_SEQ:
         rows = _rows_per_step(b, hpb, s)
         seq_spec = pl.BlockSpec((rows, s, w), lambda i, j: (i, 0, j))
+        rope_specs = [
+            pl.BlockSpec((rows, s, LANES), lambda i, j: (i, 0, j // per)),
+            pl.BlockSpec((rows, s, LANES), lambda i, j: (i, 0, 0)),
+        ] if rope_dim else []
         return pl.pallas_call(
             functools.partial(_flash_fwd_whole_kernel, causal=causal,
                               window=window, scale=scale, rows=rows,
-                              head_dim=d, block_diffusion=bd),
+                              head_dim=d, block_diffusion=bd,
+                              rope_dim=rope_dim),
             name=KERNEL_NAME_PREFIX + "flash_fwd_whole",
             out_shape=out_shape,
             grid=(b // rows, num_heads // hpb),
-            in_specs=[seq_spec, seq_spec, seq_spec],
+            in_specs=[seq_spec, seq_spec, seq_spec] + rope_specs,
             out_specs=(seq_spec,
                        pl.BlockSpec((rows, hpb, 1, s),
                                     lambda i, j: (i, j, 0, 0))),
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
-        )(q, k, v)
+        )(q, k, v, *rope_ops)
     blk = _q_block(s, bd)
+    rope_specs = [
+        pl.BlockSpec((1, blk, LANES), lambda b, j, i: (b, i, j // per)),
+        pl.BlockSpec((1, s, LANES), lambda b, j, i: (b, 0, 0)),
+    ] if rope_dim else []
     return pl.pallas_call(
         functools.partial(_flash_fwd_kernel, causal=causal, window=window,
                           scale=scale, blk_q=blk, blk_k=_seq_block(s, bd),
-                          head_dim=d, block_diffusion=bd),
+                          head_dim=d, block_diffusion=bd, rope_dim=rope_dim),
         name=KERNEL_NAME_PREFIX + "flash_fwd",
         out_shape=out_shape,
         grid=(b, num_heads // hpb, s // blk),
@@ -719,17 +795,17 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
             pl.BlockSpec((1, blk, w), lambda b, j, i: (b, i, j)),
             pl.BlockSpec((1, s, w), lambda b, j, i: (b, 0, j)),
             pl.BlockSpec((1, s, w), lambda b, j, i: (b, 0, j)),
-        ],
+        ] + rope_specs,
         out_specs=(pl.BlockSpec((1, blk, w), lambda b, j, i: (b, i, j)),
                    pl.BlockSpec((1, hpb, 1, blk),
                                 lambda b, j, i: (b, j, 0, i))),
         interpret=interpret,
         compiler_params=_FLASH_COMPILER_PARAMS,
-    )(q, k, v)
+    )(q, k, v, *rope_ops)
 
 
 def _flash_bwd_tile(q, k, kt, v, o, do, lse, glse, scale: float, mask,
-                    head_dim: int):
+                    head_dim: int, rope=None):
     """FlashAttention-2 backward of the [k, q] tiles of one column
     block: k, v [Bk, W] (and ``kt`` = k^T, which the caller forms once)
     against q, O, dO [Bq, W] and, a head, the [1, Bq] rows lse and
@@ -759,13 +835,22 @@ def _flash_bwd_tile(q, k, kt, v, o, do, lse, glse, scale: float, mask,
     heads, one a step at the [B*H, S, 64] form (PR 28): 864 us this way;
     989 with the tile as [q, k] and dK, dV contracting over its rows;
     980 as [k, q] with [S, D] results, and 980 still with the
-    element-wise work taken out of that one."""
+    element-wise work taken out of that one.
+
+    ``rope`` = (qr [Bq, 128] with this head's lanes alone, kr [Bk, 128]
+    the shared rotated key laid side by side): the two-part score; two
+    more results then, dQr^T [128, Bq] = Kr^T dS (every copy of the key
+    gives the same rows: the caller keeps this head's) and dKr^T
+    [128, Bk] = Qr^T dS^T (this head's sublanes, zeros elsewhere)."""
     qt, dot = q.T, do.T                              # [W, S]
     dot_ot = dot.astype(jnp.float32) * o.T.astype(jnp.float32)
     dqt, dkt, dvt = [], [], []
     for h in range(q.shape[-1] // head_dim):
         mine = slice(h * head_dim, (h + 1) * head_dim)
-        st = _dot_head(k, q, h, head_dim) * scale        # [k, q]
+        st = _dot_head(k, q, h, head_dim)                # [k, q]
+        if rope is not None:
+            st = st + _dot(rope[1], rope[0], _NT)
+        st = st * scale
         if mask is not None:
             st = _mask(st, *mask)
         pt = jnp.exp(st - lse[h])                    # exact softmax probs
@@ -775,35 +860,53 @@ def _flash_bwd_tile(q, k, kt, v, o, do, lse, glse, scale: float, mask,
         dvt.append(_dot(dot[mine], pt.astype(do.dtype), _NT))  # [D, k]
         dkt.append(_dot(qt[mine], dst, _NT))                   # [D, k]
         dqt.append(_dot(kt[mine], dst, _NN))                   # [D, q]
+    if rope is not None:     # one head a block (head_dim 128): `dst` is its
+        return (_stack_heads(dqt), _stack_heads(dkt), _stack_heads(dvt),
+                _dot(rope[1].T, dst, _NN), _dot(rope[0].T, dst, _NT))
     return _stack_heads(dqt), _stack_heads(dkt), _stack_heads(dvt)
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                      glse_ref, dq_ref, dk_ref, dv_ref, *, causal: bool,
-                      window: int, scale: float, rows: int, head_dim: int,
-                      block_diffusion=None):
+                      glse_ref, *rest, causal: bool, window: int,
+                      scale: float, rows: int, head_dim: int,
+                      block_diffusion=None, rope_dim: int = 0):
     """``rows`` batch rows a grid cell, each with the heads of one column
     block and the whole sequence in VMEM (gated by MAX_BWD_SEQ).
     Scores/probabilities never touch HBM — the reason XLA's einsum
-    backward loses at these shapes."""
+    backward loses at these shapes. ``rope_dim``: the two-part score;
+    dQr's block holds 128 // rope_dim heads and stays in VMEM while the
+    grid runs through them, each writing its own lanes."""
+    if rope_dim:
+        qr_ref, kr_ref, dq_ref, dk_ref, dv_ref, dqr_ref, dkr_ref = rest
+        head = pl.program_id(1)
+    else:
+        dq_ref, dk_ref, dv_ref = rest
+
     def row(b):
         k = k_ref[b]
-        dqt, dkt, dvt = _flash_bwd_tile(
+        rope = (_rope_lanes(qr_ref[b], head, rope_dim),
+                kr_ref[b]) if rope_dim else None
+        dqt, dkt, dvt, *dr = _flash_bwd_tile(
             q_ref[b], k, k.T, v_ref[b], o_ref[b], do_ref[b], lse_ref[b],
             glse_ref[b], scale, (0, 0, window, block_diffusion)
-            if causal or block_diffusion is not None else None, head_dim)
+            if causal or block_diffusion is not None else None, head_dim,
+            rope)
         dq_ref[b] = (dqt * scale).T.astype(dq_ref.dtype)
         dk_ref[b] = (dkt * scale).T.astype(dk_ref.dtype)
         dv_ref[b] = dvt.T.astype(dv_ref.dtype)
+        if rope_dim:
+            dqr = (dr[0] * scale).T.astype(dqr_ref.dtype)
+            dqr_ref[b] = jnp.where(_rope_lanes_of(dqr.shape, head, rope_dim),
+                                   dqr, dqr_ref[b])
+            dkr_ref[b] = (dr[1] * scale).T.astype(dkr_ref.dtype)
 
     _for_rows(rows, max(1, 2 // (q_ref.shape[-1] // head_dim)), row)
 
 
 def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                              glse_ref, dq_ref, dk_ref, dv_ref, dkt_ref,
-                              dvt_ref, *, causal: bool, window: int,
+                              glse_ref, *rest, causal: bool, window: int,
                               scale: float, blk: int, head_dim: int,
-                              block_diffusion=None):
+                              block_diffusion=None, rope_dim: int = 0):
     """FA2 backward for sequences past MAX_BWD_SEQ: grid cell = one
     (batch row, column block, K-block). The Q/O/dO panels are resident;
     the K-block meets them in chunks of ``blk`` queries, and ONLY the
@@ -831,13 +934,35 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     diffusion at 512 3.08 -> 4.57 ms: the [BLK_Q, 1] max and sum as
     stores), so they stay carries. A first chunk in straight-line code
     ahead of the loops, as the forward has its last, is refused by the
-    chip's compiler here (an internal check of its MXU pass)."""
+    chip's compiler here (an internal check of its MXU pass).
+
+    ``rope_dim`` (PR 39): the two-part score. The rotated query parts'
+    panel and dQr's hold 128 // rope_dim heads: dQr's block stays in
+    VMEM while the grid runs through those heads and all their K
+    blocks, zeroed at the first and each head adding into its own
+    lanes. dKr, the gradient of the ONE rotated key, leaves a head at a
+    time (this head's lanes of a 128-wide block, zeros in the others):
+    the grid's order (a head's K blocks together, for dQ) lets no two
+    heads meet in one output block, so XLA adds the heads up."""
     j = pl.program_id(2)
     k0 = j * blk
     k, v = k_ref[0], v_ref[0]
     kt = k.T
     split = _q_split(k0, blk, blk, q_ref.shape[1], causal, window,
                      block_diffusion)
+    if rope_dim:
+        (qr_ref, kr_ref, dq_ref, dk_ref, dv_ref, dqr_ref, dkr_ref, dkt_ref,
+         dvt_ref, dkrt_ref) = rest
+        head = pl.program_id(1)
+        kr = kr_ref[0]
+
+        @pl.when((j == 0) & (head % (LANES // rope_dim) == 0))
+        def _init_rope():
+            dqr_ref[0] = jnp.zeros(dqr_ref.shape[1:], dqr_ref.dtype)
+
+        dkrt_ref[...] = jnp.zeros(dkrt_ref.shape, jnp.float32)
+    else:
+        dq_ref, dk_ref, dv_ref, dkt_ref, dvt_ref = rest
 
     @pl.when(j == 0)
     def _init():
@@ -846,14 +971,21 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     def chunk(c, _, edge):
         q0 = pl.multiple_of(c * blk, blk)
         rows = pl.ds(q0, blk)
-        dqt, dkt, dvt = _flash_bwd_tile(
+        rope = (_rope_lanes(qr_ref[0, rows, :], head, rope_dim),
+                kr) if rope_dim else None
+        dqt, dkt, dvt, *dr = _flash_bwd_tile(
             q_ref[0, rows, :], k, kt, v, o_ref[0, rows, :],
             do_ref[0, rows, :], lse_ref[0, :, :, rows],
             glse_ref[0, :, :, rows], scale,
-            (k0, q0, window, block_diffusion) if edge else None, head_dim)
+            (k0, q0, window, block_diffusion) if edge else None, head_dim,
+            rope)
         dq_ref[0, rows, :] += (dqt * scale).T
         dkt_ref[...] += dkt
         dvt_ref[...] += dvt
+        if rope_dim:
+            dqr_ref[0, rows, :] += _rope_lanes((dr[0] * scale).T, head,
+                                               rope_dim)
+            dkrt_ref[...] += dr[1]
 
     dkt_ref[...] = jnp.zeros(dkt_ref.shape, jnp.float32)
     dvt_ref[...] = jnp.zeros(dvt_ref.shape, jnp.float32)
@@ -862,20 +994,26 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     dkt, dvt = dkt_ref[...], dvt_ref[...]
     dk_ref[0] = (dkt * scale).T.astype(dk_ref.dtype)
     dv_ref[0] = dvt.T.astype(dv_ref.dtype)
+    if rope_dim:
+        dkr_ref[0] = (dkrt_ref[...] * scale).T.astype(dkr_ref.dtype)
 
 
 def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
                interpret: bool, glse=None, window: int = 0,
-               block_diffusion=None):
+               block_diffusion=None, rope=None):
     """dq, dk, dv [B, S, H*D] from the saved (o, lse[B, H, 1, S]): one
     whole-tile step for several heads up to MAX_BWD_SEQ, K-blocked past
     it — scores stay in VMEM tiles at every length the gate admits
-    (flash_attention_available caps S at MAX_FLASH_SEQ)."""
+    (flash_attention_available caps S at MAX_FLASH_SEQ). With ``rope``
+    (``_flash_fwd``) also (dq_rope [B, S, H*R], dk_rope [B, S, R]): the
+    kernels hand dk_rope out a head at a time and the sum over the
+    heads, into the one rotated key, is taken here."""
     b, s, hd = q.shape
     d = hd // num_heads
     hpb = _heads_per_block(num_heads, d)
     w = hpb * d
-    scale = 1.0 / float(d) ** 0.5
+    rope_dim, per, rope_ops = _rope_operands(rope, num_heads, d)
+    scale = 1.0 / float(d + rope_dim) ** 0.5
     window = normalized_window(s, causal, window)
     bd = checked_block_diffusion(s, causal, window, block_diffusion)
     # the ring's merge hands a float32 dO (its o is float32): as an MXU
@@ -883,46 +1021,74 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
     do = do.astype(q.dtype)
     if glse is None:
         glse = jnp.zeros((b, num_heads, 1, s), jnp.float32)
+
+    def finish(dq, dk, dv, dqr=None, dkr=None):
+        if not rope_dim:
+            return dq.astype(q.dtype), dk, dv
+        # dkr [B, S, H*128]: head h's part in its own lanes of block h;
+        # the heads add up, then the copies of the key laid side by side
+        dkr = jnp.sum(dkr.astype(jnp.float32).reshape(
+            b, s, num_heads, per, rope_dim), axis=(2, 3))
+        return (dq.astype(q.dtype), dk, dv,
+                (dqr.astype(rope[0].dtype), dkr.astype(rope[1].dtype)))
+
     if s <= MAX_BWD_SEQ:
         rows = _rows_per_step(b, hpb, s)
         seq_spec = pl.BlockSpec((rows, s, w), lambda i, j: (i, 0, j))
         row_spec = pl.BlockSpec((rows, hpb, 1, s), lambda i, j: (i, j, 0, 0))
-        return pl.pallas_call(
+        qr_spec = pl.BlockSpec((rows, s, LANES), lambda i, j: (i, 0, j // per))
+        kr_spec = pl.BlockSpec((rows, s, LANES), lambda i, j: (i, 0, 0))
+        return finish(*pl.pallas_call(
             functools.partial(_flash_bwd_kernel, causal=causal,
                               window=window, scale=scale, rows=rows,
-                              head_dim=d, block_diffusion=bd),
+                              head_dim=d, block_diffusion=bd,
+                              rope_dim=rope_dim),
             name=KERNEL_NAME_PREFIX + "flash_bwd",
             out_shape=(jax.ShapeDtypeStruct((b, s, hd), q.dtype),
                        jax.ShapeDtypeStruct((b, s, hd), k.dtype),
-                       jax.ShapeDtypeStruct((b, s, hd), v.dtype)),
+                       jax.ShapeDtypeStruct((b, s, hd), v.dtype)) + ((
+                           jax.ShapeDtypeStruct(rope_ops[0].shape, q.dtype),
+                           jax.ShapeDtypeStruct((b, s, num_heads * LANES),
+                                                k.dtype),
+                       ) if rope_dim else ()),
             grid=(b // rows, num_heads // hpb),
             in_specs=[seq_spec, seq_spec, seq_spec, seq_spec, seq_spec,
-                      row_spec, row_spec],
-            out_specs=(seq_spec, seq_spec, seq_spec),
+                      row_spec, row_spec] + (
+                          [qr_spec, kr_spec] if rope_dim else []),
+            out_specs=(seq_spec, seq_spec, seq_spec) + (
+                (qr_spec, seq_spec) if rope_dim else ()),
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
-        )(q, k, v, o, do, lse, glse)
+        )(q, k, v, o, do, lse, glse, *rope_ops))
     blk = _seq_block(s, bd)
     seq_spec = pl.BlockSpec((1, s, w), lambda b, c, j: (b, 0, c))
     kblk_spec = pl.BlockSpec((1, blk, w), lambda b, c, j: (b, j, c))
     row_spec = pl.BlockSpec((1, hpb, 1, s), lambda b, c, j: (b, c, 0, 0))
-    dq, dk, dv = pl.pallas_call(
+    qr_spec = pl.BlockSpec((1, s, LANES), lambda b, c, j: (b, 0, c // per))
+    kr_spec = pl.BlockSpec((1, blk, LANES), lambda b, c, j: (b, j, 0))
+    return finish(*pl.pallas_call(
         functools.partial(_flash_bwd_blocked_kernel, causal=causal,
                           window=window, scale=scale, blk=blk, head_dim=d,
-                          block_diffusion=bd),
+                          block_diffusion=bd, rope_dim=rope_dim),
         name=KERNEL_NAME_PREFIX + "flash_bwd_blocked",
         out_shape=(jax.ShapeDtypeStruct((b, s, hd), jnp.float32),  # dq acc
                    jax.ShapeDtypeStruct((b, s, hd), k.dtype),
-                   jax.ShapeDtypeStruct((b, s, hd), v.dtype)),
+                   jax.ShapeDtypeStruct((b, s, hd), v.dtype)) + ((
+                       jax.ShapeDtypeStruct(rope_ops[0].shape, jnp.float32),
+                       jax.ShapeDtypeStruct((b, s, num_heads * LANES),
+                                            k.dtype),
+                   ) if rope_dim else ()),
         grid=(b, num_heads // hpb, s // blk),
         in_specs=[seq_spec, kblk_spec, kblk_spec, seq_spec, seq_spec,
-                  row_spec, row_spec],
-        out_specs=(seq_spec, kblk_spec, kblk_spec),
-        scratch_shapes=[pltpu.VMEM((w, blk), jnp.float32)] * 2,
+                  row_spec, row_spec] + (
+                      [qr_spec, kr_spec] if rope_dim else []),
+        out_specs=(seq_spec, kblk_spec, kblk_spec) + (
+            (qr_spec, kblk_spec) if rope_dim else ()),
+        scratch_shapes=[pltpu.VMEM((w, blk), jnp.float32)] * 2 + (
+            [pltpu.VMEM((LANES, blk), jnp.float32)] if rope_dim else []),
         interpret=interpret,
         compiler_params=_FLASH_COMPILER_PARAMS,
-    )(q, k, v, o, do, lse, glse)
-    return dq.astype(q.dtype), dk, dv
+    )(q, k, v, o, do, lse, glse, *rope_ops))
 
 
 def _xla_attention(q, k, v, causal: bool, window: int = 0,
@@ -950,23 +1116,27 @@ def _xla_attention_lse(q, k, v, causal: bool, window: int = 0,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, num_heads, causal, interpret, window=0,
-           block_diffusion=None):
+           block_diffusion=None, rope=None):
     return _flash_fwd(q, k, v, num_heads, causal, interpret,
-                      window=window, block_diffusion=block_diffusion)[0]
+                      window=window, block_diffusion=block_diffusion,
+                      rope=rope)[0]
 
 
 def _flash_vjp_fwd(q, k, v, num_heads, causal, interpret, window=0,
-                   block_diffusion=None):
+                   block_diffusion=None, rope=None):
     o, lse = _flash_fwd(q, k, v, num_heads, causal, interpret,
-                        window=window, block_diffusion=block_diffusion)
-    return o, (q, k, v, o, lse)
+                        window=window, block_diffusion=block_diffusion,
+                        rope=rope)
+    return o, (q, k, v, rope, o, lse)
 
 
 def _flash_vjp_bwd(num_heads, causal, interpret, window, block_diffusion,
                    res, g):
-    q, k, v, o, lse = res
-    return _flash_bwd(q, k, v, o, lse, g, num_heads, causal, interpret,
-                      window=window, block_diffusion=block_diffusion)
+    q, k, v, rope, o, lse = res
+    grads = _flash_bwd(q, k, v, o, lse, g, num_heads, causal, interpret,
+                       window=window, block_diffusion=block_diffusion,
+                       rope=rope)
+    return grads if rope is not None else (*grads, None)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -1167,7 +1337,8 @@ def pallas_mode() -> str:
 MIN_SEQ_FOR_FLASH = 512
 
 
-def flash_shape_legal(seq_len: int, head_dim: int, num_heads: int) -> bool:
+def flash_shape_legal(seq_len: int, head_dim: int, num_heads: int,
+                      rope_dim: int = 0) -> bool:
     """The shape half of the flash gate, platform aside: Q-block tile
     divisibility, sublane-aligned head dim, the VMEM-budget upper bounds
     past which the compiler refuses the kernels, and heads that tile the
@@ -1177,9 +1348,16 @@ def flash_shape_legal(seq_len: int, head_dim: int, num_heads: int) -> bool:
     divide the heads and fill 128 lanes exactly, unless one block is the
     whole row (H*D <= 128, or a single head). 16 heads of 64 and 4 of
     128 pass; 3 heads of 64 or 4 of 96 are refused and run the einsum
-    path. The native ``kernel_gate`` (native/ffs_strategy.hpp) admits
-    the same shapes."""
+    path. With ``rope_dim`` (the two-part score: a head's query and key
+    are ``head_dim`` lanes and ``rope_dim`` more, its value ``head_dim``)
+    a head is one block of 128 lanes and the heads' rotated parts tile
+    128-lane blocks among themselves: 32 heads of 128 + 64 pass, a
+    192-wide head as ONE width does not. The native ``kernel_gate``
+    (native/ffs_strategy.hpp) admits the same shapes."""
     if num_heads <= 0:
+        return False
+    if rope_dim and (head_dim != LANES or rope_dim % 8 or LANES % rope_dim
+                     or num_heads % (LANES // rope_dim)):
         return False
     hpb = _heads_per_block(num_heads, head_dim)
     return (seq_len % BLK_Q == 0 and head_dim % 8 == 0
@@ -1189,9 +1367,10 @@ def flash_shape_legal(seq_len: int, head_dim: int, num_heads: int) -> bool:
 
 
 def flash_attention_available(seq_len: int, head_dim: int,
-                              num_heads: int) -> bool:
+                              num_heads: int, rope_dim: int = 0) -> bool:
     mode = pallas_mode()
-    if mode == "off" or not flash_shape_legal(seq_len, head_dim, num_heads):
+    if mode == "off" or not flash_shape_legal(seq_len, head_dim, num_heads,
+                                              rope_dim):
         return False
     # interpret mode (tests) exercises any legal shape; on hardware only
     # take over where the kernel beats XLA
@@ -1199,21 +1378,25 @@ def flash_attention_available(seq_len: int, head_dim: int,
 
 
 def flash_attention(q, k, v, num_heads: int, causal: bool = False,
-                    window: int = 0, block_diffusion=None):
+                    window: int = 0, block_diffusion=None, rope=None):
     """q, k, v: [B, S, H*D] -> [B, S, H*D], the heads side by side along
     the lanes as the projections' plain 2-D products leave them, so that
     no layout change sits between a projection and a kernel and no
     operand's minor dimension is narrower than a vreg. Caller checks
     flash_attention_available first; self-attention only (Sq == Sk).
     Who holds [B, H, S, D] converts with ``merge_heads`` /
-    ``split_heads`` at its own boundary."""
+    ``split_heads`` at its own boundary. ``rope`` = (q_rope [B, S, H*R],
+    k_rope [B, S, R]): the two-part score (``_flash_fwd``); the gradient
+    of k_rope is the sum over the heads."""
     return _flash(q, k, v, num_heads, causal, pallas_mode() == "interpret",
-                  window, tuple(block_diffusion) if block_diffusion else None)
+                  window, tuple(block_diffusion) if block_diffusion else None,
+                  tuple(rope) if rope is not None else None)
 
 
 def flash_attention_sharded(q, k, v, num_heads: int, mesh, batch_axis=None,
                             head_axis=None, causal: bool = False,
-                            window: int = 0, block_diffusion=None):
+                            window: int = 0, block_diffusion=None,
+                            rope=None):
     """Flash attention inside a GSPMD-sharded jit: a bare ``pallas_call``
     is an unpartitionable custom call to the partitioner, so wrap it in
     ``shard_map`` over the mesh axes the batch/head dims are sharded on —
@@ -1227,5 +1410,12 @@ def flash_attention_sharded(q, k, v, num_heads: int, mesh, batch_axis=None,
     local = num_heads // (mesh.shape[head_axis] if head_axis else 1)
     fn = functools.partial(flash_attention, num_heads=local, causal=causal,
                            window=window, block_diffusion=block_diffusion)
+    if rope is not None:
+        # the one rotated key has no head axis to shard: batch axes only
+        assert head_axis is None, "latent attention: no head axis here"
+        return jax.shard_map(
+            lambda q, k, v, qr, kr: fn(q, k, v, rope=(qr, kr)), mesh=mesh,
+            in_specs=(spec,) * 5, out_specs=spec, check_vma=False)(
+                q, k, v, *rope)
     return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)

@@ -44,6 +44,10 @@ def _node_attrs(op) -> Dict[str, Any]:
     if (getattr(op, "head_dim", None) and getattr(op, "embed_dim", None)
             and op.head_dim != op.embed_dim // op.num_heads):
         attrs["head_dim"] = int(op.head_dim)
+    # latent attention: the rotated lanes a head's query and key carry
+    # beside `head_dim` (the flash gate's two-part score)
+    if getattr(op, "rope_dim", 0):
+        attrs["rope_head_dim"] = int(op.rope_dim)
     # a sliding window that hides something: the scores an attention op
     # forms (and einsum keeps) are S x window, not S^2
     if getattr(op, "windowed", False):

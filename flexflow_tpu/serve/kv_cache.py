@@ -102,6 +102,11 @@ def init_kv_cache(ff, batch: Optional[int] = None,
             raise NotImplementedError(
                 f"attention '{op.name}' is not causal — KV-cache decode "
                 f"only decomposes causal attention incrementally")
+        if getattr(op, "latent", None):
+            raise NotImplementedError(
+                f"attention '{op.name}' is latent attention — this cache "
+                f"holds whole key and value heads; serving it from the "
+                f"compressed latent (the absorbed form) is not built")
         spec = cache_partition_spec(ff, node, batch, max_len)
         sharding = NamedSharding(ff.mesh, spec)
         shape = (batch, op.num_kv_heads, max_len, op.head_dim)

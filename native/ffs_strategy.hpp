@@ -217,6 +217,15 @@ inline std::string kernel_gate(const Node& n, const std::string& impl,
     // them the executor runs einsum, so pricing flash would misrank
     if (seq > 16384) return "seq_exceeds_flash_vmem_budget_16384";
     if (head_dim > 128) return "head_dim_exceeds_flash_vmem_budget_128";
+    // latent attention's two-part score (attr `rope_head_dim`: a head's
+    // query and key are `head_dim` lanes and that many more, rotated;
+    // flash_shape_legal's `rope_dim`): a head is one block of 128 lanes,
+    // and the heads' rotated parts tile 128-lane blocks among themselves
+    int64_t rope_dim = n.attrs.get("rope_head_dim").as_int(0);
+    if (rope_dim > 0 &&
+        (head_dim != 128 || rope_dim % 8 || 128 % rope_dim ||
+         heads % (128 / rope_dim)))
+      return "latent_heads_do_not_tile_128_lanes";
     // the kernels take [B, S, H*D] operands in column blocks of the heads
     // that fill 128 lanes (pallas_kernels._heads_per_block): those have
     // to divide the heads and fill the lanes exactly, unless one block

@@ -17,12 +17,16 @@ programs a shape:
   is worth from what the peeled chunk is;
 
 and under the block-diffusion mask both at K blocks of 512 and of 1024
-(`_seq_block` caps them at 512 there).
+(`_seq_block` caps them at 512 there). The latent-attention shape (PR 39:
+32 heads of 128 + 64 / 128 at 4,096 positions, the two-part score with the
+ONE rotated key a position) runs `split` twice: as it ships, and
+`split, no rotated part` (the same kernels on the 128-wide parts alone),
+so that the difference is what the second product and its operands cost.
 
 Prints one JSON line a measurement and writes them to
 `chiprun_out/flash_lab.json`. Nothing here is a benchmark metric.
 
-    python scripts/flash_lab.py [--tiny]
+    python scripts/flash_lab.py [--tiny] [--only <part of a shape's name>]
 
 `--tiny` is the CPU rehearsal (short sequences, the kernels interpreted,
 no device in the trace, so `device_ms` is null).
@@ -42,7 +46,9 @@ SHAPES = {
     "smallthinker.window": (7, 16384, True, 4096, None),
     "smallthinker.full": (7, 16384, True, 0, None),
     "nemotron.full": (4, 8192, True, 0, None),
+    "joyai.latent": (32, 4096, True, 0, None),
 }
+ROPE_DIM = 64      # the rotated lanes of a `latent` shape's query and key
 REPS = 10
 
 
@@ -91,7 +97,9 @@ def kernel_ms(fn, args, interpret):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true")
-    tiny = ap.parse_args().tiny
+    ap.add_argument("--only", default="")
+    args = ap.parse_args()
+    tiny, only = args.tiny, args.only
     if tiny:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
@@ -105,22 +113,31 @@ def main():
     shipped = pk._k_split, pk._q_split, pk._seq_block
     lines = []
     for name, (heads, seq, causal, window, bd) in SHAPES.items():
+        if only not in name:
+            continue
         if tiny:
             seq, window, bd = 2048, window // 8, bd and (1024, 4)
+            heads = min(heads, 4)
         rs = np.random.RandomState(0)
         q, k, v, do = (jnp.asarray(rs.randn(1, seq, heads * 128),
                                    jnp.bfloat16) for _ in range(4))
+        latent = name.endswith(".latent")
+        rope = (jnp.asarray(rs.randn(1, seq, heads * ROPE_DIM), jnp.bfloat16),
+                jnp.asarray(rs.randn(1, seq, ROPE_DIM), jnp.bfloat16))
         for blk in (512, 1024) if bd else (None,):
-            for program in ("every_tile_masked", "every_tile_masked+peel",
-                            "split"):
+            for program in (("split", "split, no rotated part") if latent
+                            else ("every_tile_masked",
+                                  "every_tile_masked+peel", "split")):
                 pk._k_split, pk._q_split, pk._seq_block = shipped
-                if program != "split":
+                if not program.startswith("split"):
                     pk._k_split = every_tile_masked(
                         pk._k_ranges, True, peel="peel" in program)
                     pk._q_split = every_tile_masked(pk._q_ranges, False)
                 if blk:
                     pk._seq_block = lambda s, block_diffusion=None, b=blk: b
                 mask = dict(window=window, block_diffusion=bd)
+                if program == "split" and latent:
+                    mask["rope"] = rope
                 fwd = jax.jit(lambda q, k, v: pk._flash_fwd(
                     q, k, v, heads, causal, tiny, **mask))
                 bwd = jax.jit(lambda q, k, v, o, lse, do: pk._flash_bwd(
