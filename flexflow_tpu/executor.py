@@ -22,8 +22,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flexflow_tpu.ffconst import CompMode, LossType, OperatorType
-from flexflow_tpu.losses import (get_loss_fn, part_nll_sums,
-                                 target_positions)
+from flexflow_tpu.losses import (class_ids, get_loss_fn, part_nll_sums,
+                                 target_log_probs, target_positions,
+                                 weighted_nll_mean)
 from flexflow_tpu.metrics import Metrics
 from flexflow_tpu.obs.registry import get_registry
 from flexflow_tpu.ops.base import Op, OpContext, scoped
@@ -692,8 +693,13 @@ class GraphExecutor:
                 values[(op.guid, i)] = o
 
     # ---- jitted steps ------------------------------------------------------
-    def _loss_value(self, logits, labels):
+    def _loss_value(self, logits, labels, counted=None):
+        """The loss of the model's output. ``counted`` (the train step's
+        dict) receives what the weighted loss counts on the device."""
         fn = get_loss_fn(self.loss_type)
+        # whether the loss, as last traced, reached the logits through
+        # `losses.target_log_probs` (the gauge `executor.loss_own_vjp`)
+        self._loss_own_vjp = False
         if self.final_is_softmax and self.loss_type in (
             LossType.CATEGORICAL_CROSSENTROPY,
             LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
@@ -705,7 +711,33 @@ class GraphExecutor:
                 return -jnp.mean(jnp.sum(labels * logp, axis=-1))
             lab = labels.reshape(labels.shape[0], -1)[:, 0].astype(jnp.int32)
             return -jnp.mean(jnp.take_along_axis(logp, lab[:, None], axis=-1))
+        if self.loss_type == LossType.WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY:
+            # the targets' log-probabilities ONCE, for the loss and for
+            # what leaves the step with the ops' counters: the positions
+            # that carried a target, and for a model whose logits are
+            # several parts laid end to end each part's unweighted
+            # cross-entropy
+            self._loss_own_vjp = True
+            logp = target_log_probs(logits, class_ids(logits, labels))
+            if counted is not None:
+                counted["loss/target_positions"] = target_positions(labels)
+                if self.loss_parts:
+                    counted.update(
+                        (f"loss/{part}_nll", v) for part, v in part_nll_sums(
+                            logp, labels, self.loss_parts).items())
+            return weighted_nll_mean(logp, labels)
+        self._loss_own_vjp = (
+            self.loss_type == LossType.SPARSE_CATEGORICAL_CROSSENTROPY)
         return fn(logits, labels)
+
+    def loss_own_vjp(self) -> int:
+        """1 when the loss, as last traced, took the log-probability of a
+        row's target from `losses.target_log_probs` (its own backward: no
+        float32 array of the logits' shape, no scatter; PR 40), else 0
+        (MSE, dense one-hot labels, probabilities in): the gauge
+        `executor.loss_own_vjp`, and `loss_own_vjp` in every trace
+        header."""
+        return int(getattr(self, "_loss_own_vjp", False))
 
     def flash_lane_dense_ops(self) -> int:
         """Attention ops whose forward, as last traced, called the flash
@@ -813,24 +845,11 @@ class GraphExecutor:
                 logits = values[self.final_ref]
 
                 def loss_of(logits, labels, aux):
-                    loss = self._loss_value(logits, labels)
+                    counted = {}
+                    loss = self._loss_value(logits, labels, counted)
                     for a in aux:
                         loss = loss + a
-                    if (self.loss_type ==
-                            LossType.WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY):
-                        # leaves the step with the ops' counters: the
-                        # positions that carried a target, and for a
-                        # model whose logits are several parts laid end
-                        # to end each part's unweighted cross-entropy
-                        counted = {"loss/target_positions":
-                                   target_positions(labels)}
-                        if self.loss_parts:
-                            counted.update(
-                                (f"loss/{part}_nll", v) for part, v in
-                                part_nll_sums(logits, labels,
-                                              self.loss_parts).items())
-                        return loss, counted
-                    return loss, {}
+                    return loss, counted
 
                 loss, counted = scoped("loss", loss_of)(logits, labels, aux)
                 counters.update(counted)
@@ -849,6 +868,8 @@ class GraphExecutor:
                                  self.moe_gather_combine_ops())
             get_registry().gauge("executor.moe_sum_rows_ops",
                                  self.moe_sum_rows_ops())
+            get_registry().gauge("executor.loss_own_vjp",
+                                 self.loss_own_vjp())
             # gradient sync over the data axes is inserted by GSPMD here
             # (in bf16 under the master-weight regime — half the bytes).
             # Under WUS the shard constraint turns that all-reduce into a

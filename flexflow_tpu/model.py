@@ -1465,6 +1465,8 @@ class FFModel:
             self.executor.moe_gather_combine_ops())
         self.op_counters["executor.moe_sum_rows_ops"] = float(
             self.executor.moe_sum_rows_ops())
+        self.op_counters["executor.loss_own_vjp"] = float(
+            self.executor.loss_own_vjp())
 
     def fit(self, x=None, y=None, batch_size: Optional[int] = None,
             epochs: Optional[int] = None, verbose: bool = True,

@@ -292,8 +292,8 @@ def export_step_summary(ff, tracer) -> Dict[str, Any]:
     return summary
 
 
-_SCATTER = re.compile(r"= \(?\w+\[([\d,]*)\][^\n]*? scatter\([^\n]*?"
-                      r"op_name=\"([^\"]*)\"")
+_SCATTER = re.compile(r"= \(?\w+\[([\d,]*)\][^\n]*? scatter\("
+                      r"(?:[^\n]*?op_name=\"([^\"]*)\")?")
 
 
 def scatters_in(hlo_text: str, scope: str = "") -> List[Tuple[str, int]]:
@@ -302,9 +302,42 @@ def scatters_in(hlo_text: str, scope: str = "") -> List[Tuple[str, int]]:
     On the TPU v5e a scatter-add of rows runs row by row (ops/moe.py), so
     a layer that means to move rows by gathers checks its compiled step
     with this. The chip's compiler may cut an `op_name` down to the
-    primitive's; the size tells a table of tile ids from an activation."""
+    primitive's, or drop it (then ""); the size tells a table of tile ids
+    from an activation."""
     return [(name, math.prod(int(n) for n in dims.split(",") if n))
             for dims, name in _SCATTER.findall(hlo_text) if scope in name]
+
+
+# (a header of six results or more holds `/*index=5*/`, the ENTRY's among
+# them: `step_scopes._COMPUTATION`, which stops at an `=`, misses those)
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%?[\w.\-]+) = (.*?) ([\w\-]+)\(")
+
+
+def arrays_between_fusions(hlo_text: str, dtype: str,
+                           elements: int) -> List[str]:
+    """Names of the instructions of an optimized HLO text whose result is
+    a ``dtype`` (say "f32") array of ``elements`` elements and which are
+    NOT inside a fusion's body: arrays the program writes to memory
+    (parameters left out). A loss that means to keep the float32 copy of
+    its [B, S, V] logits out of memory checks its compiled step with
+    this (PR 40)."""
+    array = re.compile(r"\b" + re.escape(dtype) + r"\[([\d,]+)\]")
+    out, fused = [], False
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            fused = "fused_computation" in head.group(1)
+            continue
+        # name = result type(s) opcode(operands): a fusion of several
+        # results has a tuple of types
+        m = None if fused else _INSTRUCTION.match(line)
+        if m and m.group(3) != "parameter" and any(
+                math.prod(int(n) for n in dims.split(",")) == elements
+                for dims in array.findall(m.group(2))):
+            out.append(m.group(1))
+    return out
 
 
 def model_context(ff) -> Dict[str, Any]:
@@ -335,6 +368,9 @@ def model_context(ff) -> Dict[str, Any]:
         # of them, those that added the rows into their tokens by the
         # kernel `moe_sum_rows`
         moe_sum_rows_ops=ff.executor.moe_sum_rows_ops(),
+        # 1 when the traced loss took the targets' log-probabilities from
+        # `losses.target_log_probs` (its own backward), else 0
+        loss_own_vjp=ff.executor.loss_own_vjp(),
         # positions that carried a target in the last epoch of a weighted
         # loss (None before one, or under another loss)
         loss_target_positions=(getattr(ff, "op_counters", None) or {}).get(

@@ -296,8 +296,10 @@ def test_part_sums_of_the_weighted_loss():
     labels[..., 0] = rs.randint(0, 11, size=(2, 8))
     labels[:, :3, 1] = 8 / 3
     labels[:, 4:6, 1] = 0.3 * 8 / 2
-    parts = losses.part_nll_sums(logits, jnp.asarray(labels),
-                                 ("main", "mtp"))
+    labels_j = jnp.asarray(labels)
+    parts = losses.part_nll_sums(
+        losses.target_log_probs(logits, losses.class_ids(logits, labels_j)),
+        labels_j, ("main", "mtp"))
     logp = np.asarray(jax.nn.log_softmax(logits, -1))
     nll = -np.take_along_axis(
         logp, labels[..., 0].astype(int)[..., None], -1)[..., 0]

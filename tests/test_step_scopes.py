@@ -170,6 +170,44 @@ def test_a_fusion_lists_the_parts_of_its_body(lowered):
             assert row["part"] in row["parts"], row
 
 
+def test_the_loss_scope_holds_the_own_backward_of_the_cross_entropy(lowered):
+    """`losses.target_log_probs` is a `custom_vjp` INSIDE the nested call
+    `loss` (PR 40): its forward's instructions still read
+    `jvp(jit(loss))`, its backward's `transpose(jvp(jit(loss)))`, and
+    where it runs no scatter and no `log_softmax` is left in the scope."""
+    model, table = lowered
+    names = collections.defaultdict(list)
+    for row in table.values():
+        if row["part"] == "loss":
+            names[row["direction"]].append(row["op_name"])
+    assert any("jvp(jit(loss))" in n for n in names["forward"])
+    assert any("transpose(jvp(jit(loss)))" in n for n in names["backward"])
+    if not model.startswith("decoder"):
+        return
+    assert any(n.endswith("/exp") for n in names["forward"])
+    assert any(n.endswith("/exp") for n in names["backward"])
+    every = names["forward"] + names["backward"]
+    assert not any("scatter" in n or "log_softmax" in n for n in every), [
+        n for n in every if "scatter" in n or "log_softmax" in n]
+
+
+@pytest.mark.parametrize("model,want", [
+    ("decoder_GWWW", 1), ("decoder_D", 1), ("transformer", 0), ("conv", 0)])
+def test_the_gauge_says_whether_the_loss_took_its_own_backward(model, want):
+    """`executor.loss_own_vjp`: 1 for a sparse or weighted sparse
+    cross-entropy on logits, 0 for MSE and for the `final_is_softmax`
+    branch (probabilities in); set when the train step is traced, in the
+    registry, `op_counters` and every trace header."""
+    ff, xs, y = MODELS[model]()
+    assert obs.model_context(ff)["loss_own_vjp"] == 0          # not traced
+    batch = ff.config.batch_size
+    ff.fit([x[:batch] for x in xs], y[:batch], epochs=1, verbose=False)
+    assert ff.executor.loss_own_vjp() == want
+    assert obs.model_context(ff)["loss_own_vjp"] == want
+    assert obs.get_registry().to_dict()["gauges"][
+        "executor.loss_own_vjp"] == want
+
+
 HLO = """HloModule jit_train_step
 
 %fused_computation.1 (p.1: f32[8]) -> f32[8] {
@@ -294,8 +332,16 @@ def test_three_steps_are_those_of_the_unscoped_model(model, monkeypatch):
             ff.fit([x[:batch] for x in xs], y[:batch], epochs=1,
                    verbose=False)
             losses.append(ff._last_loss)
-        # (a layer's name carries a counter of the process: leaves in order)
-        return losses, [np.asarray(p) for p in jax.tree.leaves(ff.params)]
+        # (a layer's name carries a counter of the process, and a dict's
+        # leaves come in the names' STRING order: `conv2d_98`, `conv2d_100`
+        # swap places where the two models' counters straddle a power of
+        # ten, so the layers go by the numbers in their names)
+        def by_number(name):
+            return [int(t) if t.isdigit() else t
+                    for t in re.split(r"(\d+)", name)]
+        return losses, [np.asarray(p) for name in sorted(ff.params,
+                                                         key=by_number)
+                        for p in jax.tree.leaves(ff.params[name])]
 
     losses, params = train()
     _identity_scoped(monkeypatch)

@@ -415,6 +415,57 @@ class TestFusedAdam:
         assert "all-gather" not in hlo and "all-reduce" not in hlo
 
 
+class TestHeadAndLoss:
+    """`dense` + the sparse cross-entropy + `grad` at [1, 2048, 16384]
+    bf16 logits (PR 40): with `losses.target_log_probs` the optimized
+    program writes no float32 array of the logits' shape and runs no
+    scatter; the body it replaced, inlined here as the control, does
+    both (the float32 log-probabilities kept for the backward, and the
+    transpose of `take_along_axis`)."""
+    ROWS, HIDDEN, VOCAB = 2048, 1024, 16384
+
+    def _hlo(self, topo, loss):
+        from flexflow_tpu.ops.base import scoped
+        one = SingleDeviceSharding(topo.devices[0])
+        x = jax.ShapeDtypeStruct((1, self.ROWS, self.HIDDEN), jnp.bfloat16,
+                                 sharding=one)
+        w = jax.ShapeDtypeStruct((self.HIDDEN, self.VOCAB), jnp.bfloat16,
+                                 sharding=one)
+        ids = jax.ShapeDtypeStruct((1, self.ROWS), jnp.int32, sharding=one)
+
+        def head(x, w):     # ops/linear.py `Linear.forward`
+            return jnp.dot(x, w, preferred_element_type=jnp.float32
+                           ).astype(x.dtype)
+
+        def objective(x, w, ids):
+            return scoped("loss", loss)(scoped("head", head)(x, w), ids)
+
+        return _compile(jax.value_and_grad(objective, (0, 1)), x, w, ids)
+
+    def _faults(self, hlo):
+        from flexflow_tpu.obs.inspect import (arrays_between_fusions,
+                                              scatters_in)
+        return (arrays_between_fusions(hlo, "f32", self.ROWS * self.VOCAB),
+                scatters_in(hlo))
+
+    def test_own_backward_leaves_no_float32_logits_and_no_scatter(
+            self, topo):
+        from flexflow_tpu.losses import sparse_categorical_crossentropy
+        hlo = self._hlo(topo, sparse_categorical_crossentropy)
+        assert self._faults(hlo) == ([], [])
+        # both directions of the loss are there, under its scope
+        assert "jvp(jit(loss))" in hlo
+        assert "transpose(jvp(jit(loss)))" in hlo
+
+    def test_the_body_it_replaced_holds_both(self, topo):
+        def parent_style(logits, ids):
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            return -jnp.mean(jnp.take_along_axis(logp, ids[..., None],
+                                                 axis=-1))
+        f32, scatters = self._faults(self._hlo(topo, parent_style))
+        assert f32 and scatters, (f32, scatters)
+
+
 # ---------------------------------------------------------------------------
 # whole train steps
 
