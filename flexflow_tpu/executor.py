@@ -776,10 +776,25 @@ class GraphExecutor:
         (PR 35), added up over the ops that ran flash (`kv_blocks`,
         `kv_blocks_masked`; 0 until one has been traced). Published as
         gauges when the train step is traced, in every trace header and
-        in `FFModel.op_counters`."""
+        in `FFModel.op_counters`. PR 41: over the window ops that ran
+        flash, the (query, key) pairs a head's kernels work through,
+        forward and backward, against twice the pairs visible
+        (`pallas_kernels.visited_pairs` / `visible_pairs`); and, in a
+        model whose attention ops differ in their query heads, each op's
+        (`attention/heads_by_op/<op>`)."""
         blocks = [n.op._kv_blocks for n in self.nodes
                   if getattr(n.op, "_kv_blocks", None)]
+        pairs = [n.op._window_pairs for n in self.nodes
+                 if getattr(n.op, "_window_pairs", None)]
+        heads = {n.op.name: n.op.num_heads for n in self.nodes
+                 if hasattr(n.op, "num_kv_heads")}
+        by_op = ({f"attention/heads_by_op/{name}": h
+                  for name, h in heads.items()}
+                 if len(set(heads.values())) > 1 else {})
         return {
+            **by_op,
+            "attention/window_keys_visited": sum(p[0] for p in pairs),
+            "attention/window_keys_visible": sum(p[1] for p in pairs),
             "executor.window_attention_ops": sum(
                 bool(getattr(n.op, "windowed", False)) for n in self.nodes),
             "executor.block_diffusion_attention_ops": sum(

@@ -223,6 +223,10 @@ class FFModel:
                             qk_rope_head_dim: int = 0,
                             latent_norm_eps: float = 1e-6,
                             rope_whole_head: bool = False,
+                            gate: bool = False,
+                            gate_activation: str = "softplus",
+                            partial_rotary_factor: float = 1.0,
+                            rope_scaling: Optional[dict] = None,
                             name: Optional[str] = None) -> Tensor:
         """``seq_parallel='seq'`` runs the attention core as ring attention
         over that mesh axis (context parallelism for long sequences).
@@ -247,7 +251,16 @@ class FFModel:
         ``qk_rope_head_dim`` that are (``rope_theta``, over adjacent
         pairs), the rotated key ONE vector a position
         for all heads; values are ``head_dim`` wide (``rope_whole_head``
-        is a control: every lane of a head rotated)."""
+        is a control: every lane of a head rotated). ``gate``: one
+        scalar a head and position, softplus(x w_gate) in float32 (leaf
+        ``w_gate`` [embed_dim, num_heads]; ``gate_activation`` "sigmoid"
+        is a control), times the head's output ahead of the output
+        projection. ``partial_rotary_factor``: rotary over the first
+        ``head_dim * factor`` lanes of every head, the rest pass.
+        ``rope_scaling``: a public config's ``rope_type`` "yarn" keys
+        (``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+        ``beta_slow``, ``attention_factor``) for the frequency table
+        (``ops.attention.rotary_frequencies``)."""
         latent = dict(q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
                       qk_rope_head_dim=qk_rope_head_dim,
                       latent_norm_eps=latent_norm_eps,
@@ -267,7 +280,14 @@ class FFModel:
                if block_diffusion else {}),
             **({"rope_wrap": rope_wrap} if rope_wrap else {}),
             **({"qk_norm": True, "qk_norm_eps": qk_norm_eps}
-               if qk_norm else {}), **latent), name)
+               if qk_norm else {}),
+            **({"gate": True} if gate else {}),
+            **({"gate_activation": gate_activation}
+               if gate_activation != "softplus" else {}),
+            **({"partial_rotary_factor": partial_rotary_factor}
+               if partial_rotary_factor != 1.0 else {}),
+            **({"rope_scaling": dict(rope_scaling)}
+               if rope_scaling else {}), **latent), name)
         return self._finish(layer)
 
     def ssm_mixer(self, input: Tensor, num_heads: int, head_dim: int,

@@ -44,6 +44,33 @@ family.
         of ``moe_intermediate_size``, and a shared expert of the same
         gated form, ``n_shared_experts * moe_intermediate_size`` wide
 
+    F   gated full attention, then a feed-forward, each behind its own
+        norm and residual (PR 41). Attention: causal GQA with layer i's
+        own number of query heads (``num_attention_heads_per_layer[i]``;
+        ``num_key_value_heads`` and ``head_dim`` are the model's), rotary
+        by ``rope_parameters["full_attention"]`` (``rope_theta``,
+        ``partial_rotary_factor``: the first lanes of every head rotate,
+        the rest pass; ``rope_type`` "yarn" with its keys: scaled
+        frequencies and ``attention_factor``), and with ``gating`` one
+        scalar a head and position, softplus(h w_gate), on the head's
+        output ahead of the output projection. Feed-forward by
+        ``mlp_layer_types[i]``: "dense" the SwiGLU MLP of
+        ``intermediate_size`` (as `A`'s), "sparse" (the default) `X`'s
+        experts, the shared expert ``moe_shared_expert_intermediate_size``
+        wide
+    S   the same under a sliding window of ``sliding_window_size`` keys,
+        rotary by ``rope_parameters["sliding_attention"]``
+
+``layer_types`` (a public config's list of "full_attention" /
+"sliding_attention", one entry a layer that runs) stands for the pattern:
+`F` and `S` in its order. ``num_attention_heads_per_layer`` and
+``mlp_layer_types`` are read at the layer's index and may be longer than
+the pattern (a model cut in depth can keep its published lists); a
+model-wide ``num_attention_heads``, ``rope_theta`` and an all-"sparse"
+feed-forward are the short form. The three attention properties are the
+op's (``FFModel.multihead_attention(..., gate, partial_rotary_factor,
+rope_scaling)``), off by default.
+
 ``num_nextn_predict_layers`` 1 adds the multi-token-prediction module: a
 second branch off the last block's output x_L (before the final norm)
 that reads the NEXT token's embedding, u_i = [rms_norm(e(t_{i+1})) ;
@@ -77,7 +104,7 @@ widths (``hidden_size``, the head sizes, the expert widths, the router's
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Sequence
 
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.ffconst import ActiMode, DataType
@@ -136,6 +163,16 @@ class DecoderConfig:
     v_head_dim: int = 16
     rope_whole_head: bool = False
     n_shared_experts: int = 1               # `X`
+    # `F`, `S`: a layer's own query heads, attention kind ("full_attention"
+    # / "sliding_attention": the pattern, where given) and feed-forward
+    # kind ("dense" / "sparse"), rotary parameters by attention kind, the
+    # per-head output gate (`gate_activation` "sigmoid" is a control)
+    num_attention_heads_per_layer: Optional[Sequence[int]] = None
+    layer_types: Optional[Sequence[str]] = None
+    mlp_layer_types: Optional[Sequence[str]] = None
+    rope_parameters: Optional[dict] = None
+    gating: bool = False
+    gate_activation: str = "softplus"
     # the multi-token-prediction module: 0 or 1; `mtp_shift` is the
     # distance of the token whose embedding it reads (1; 0 is a control)
     num_nextn_predict_layers: int = 0
@@ -231,35 +268,87 @@ def _llama_block(ff, t, i, cfg):
     return ff.add(t, _swiglu_mlp(ff, h, cfg, f"l{i}"), name=f"l{i}_res2")
 
 
-def _latent_block(ff, t, prefix, cfg, experts):
-    """`A` / `X`: x' = x + latent_attention(norm(x)), x'' = x' + f(norm(x'))
-    with f the SwiGLU MLP or the experts with their shared expert."""
+def _attention_ffn_block(ff, t, prefix, cfg, attention, experts,
+                         shared_width):
+    """x' = x + attention(norm(x)), x'' = x' + f(norm(x')) with f the
+    SwiGLU MLP or the sigmoid-scored experts with their gated shared
+    expert: the block of `A` / `X` and of `F` / `S`, which differ in
+    ``attention(h, name)``."""
     eps = cfg.layer_norm_epsilon
-    if cfg.v_head_dim != cfg.qk_nope_head_dim:
-        raise ValueError("decoder: latent attention takes a value head as "
-                         "wide as the query/key head's not-rotated part")
     h = ff.rms_norm(t, eps=eps, name=f"{prefix}_norm")
-    a = ff.multihead_attention(
-        h, h, h, cfg.hidden_size, cfg.num_attention_heads, bias=False,
-        causal=True, rope=True, rope_theta=cfg.rope_theta,
-        head_dim=cfg.qk_nope_head_dim, q_lora_rank=cfg.q_lora_rank,
-        kv_lora_rank=cfg.kv_lora_rank,
-        qk_rope_head_dim=cfg.qk_rope_head_dim, latent_norm_eps=eps,
-        rope_whole_head=cfg.rope_whole_head, name=f"{prefix}_attn")
-    t = ff.add(t, a, name=f"{prefix}_res1")
+    t = ff.add(t, attention(h, f"{prefix}_attn"), name=f"{prefix}_res1")
     g = ff.rms_norm(t, eps=eps, name=f"{prefix}_post_norm")
     if not experts:
         return ff.add(t, _swiglu_mlp(ff, g, cfg, prefix, one_product=True),
                       name=f"{prefix}_res2")
     m = ff.moe_layer(
         g, cfg.n_routed_experts, cfg.num_experts_per_tok,
-        cfg.moe_intermediate_size,
-        shared_width=cfg.n_shared_experts * cfg.moe_intermediate_size,
+        cfg.moe_intermediate_size, shared_width=shared_width,
         experts_held=cfg.experts_held, expert_offset=cfg.expert_offset,
         routed_scaling=cfg.routed_scaling_factor,
         norm_topk=cfg.norm_topk_prob, slot_slack=cfg.slot_slack,
         gated=True, activation=cfg.hidden_act, name=f"{prefix}_mixer")
     return ff.add(t, m, name=f"{prefix}_res2")
+
+
+def _latent_block(ff, t, prefix, cfg, experts):
+    """`A` / `X`: latent attention, then the SwiGLU MLP or the experts."""
+    if cfg.v_head_dim != cfg.qk_nope_head_dim:
+        raise ValueError("decoder: latent attention takes a value head as "
+                         "wide as the query/key head's not-rotated part")
+
+    def attention(h, name):
+        return ff.multihead_attention(
+            h, h, h, cfg.hidden_size, cfg.num_attention_heads, bias=False,
+            causal=True, rope=True, rope_theta=cfg.rope_theta,
+            head_dim=cfg.qk_nope_head_dim, q_lora_rank=cfg.q_lora_rank,
+            kv_lora_rank=cfg.kv_lora_rank,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            latent_norm_eps=cfg.layer_norm_epsilon,
+            rope_whole_head=cfg.rope_whole_head, name=name)
+
+    return _attention_ffn_block(
+        ff, t, prefix, cfg, attention, experts,
+        cfg.n_shared_experts * cfg.moe_intermediate_size)
+
+
+LAYER_TYPE_LETTERS = {"full_attention": "F", "sliding_attention": "S"}
+LETTER_LAYER_TYPES = {v: k for k, v in LAYER_TYPE_LETTERS.items()}
+
+
+def _of_layer(values, i, default):
+    """Layer i's entry of a per-layer list, or the model-wide value."""
+    return default if values is None else values[i]
+
+
+def _gated_block(ff, t, i, cfg, letter):
+    """`F` / `S`: layer i's own heads, its kind's rotary parameters and
+    window, the gate; then layer i's kind of feed-forward."""
+    rope = dict((cfg.rope_parameters or {}).get(
+        LETTER_LAYER_TYPES[letter]) or {})
+    theta = float(rope.pop("rope_theta", cfg.rope_theta))
+    partial = float(rope.pop("partial_rotary_factor", 1.0))
+    scaled = rope.get("rope_type", "default") != "default"
+    feed_forward = _of_layer(cfg.mlp_layer_types, i, "sparse")
+    if feed_forward not in ("dense", "sparse"):
+        raise ValueError(f"decoder: mlp_layer_types[{i}] is "
+                         f"{feed_forward!r} (known: dense, sparse)")
+
+    def attention(h, name):
+        return ff.multihead_attention(
+            h, h, h, cfg.hidden_size,
+            _of_layer(cfg.num_attention_heads_per_layer, i,
+                      cfg.num_attention_heads),
+            bias=False, causal=True, num_kv_heads=cfg.num_key_value_heads,
+            rope=True, rope_theta=theta, head_dim=cfg.head_dim,
+            window=cfg.sliding_window_size if letter == "S" else 0,
+            gate=cfg.gating, gate_activation=cfg.gate_activation,
+            partial_rotary_factor=partial,
+            rope_scaling=rope if scaled else None, name=name)
+
+    return _attention_ffn_block(ff, t, f"b{i}", cfg, attention,
+                                feed_forward == "sparse",
+                                cfg.moe_shared_expert_intermediate_size)
 
 
 def _mtp_module(ff, embedded, x_last, cfg):
@@ -313,7 +402,7 @@ def _mixer(ff, h, letter, i, cfg):
         return ff.dense(ff.multiply(up, up, name=f"b{i}_sq"),
                         cfg.hidden_size, use_bias=False, name=name)
     raise ValueError(f"decoder pattern: unknown block letter {letter!r} "
-                     f"(known: M E * - L G W D A X)")
+                     f"(known: M E * - L G W D A X F S)")
 
 
 def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
@@ -322,7 +411,17 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
                            dtype=DataType.INT32, name="input_ids")
     t = embedded = ff.embedding(ids, cfg.vocab_size, cfg.hidden_size,
                                 name="embed_tokens")
-    for i, letter in enumerate(cfg.hybrid_override_pattern):
+    pattern = cfg.hybrid_override_pattern
+    if cfg.layer_types is not None:
+        unknown = set(cfg.layer_types) - set(LAYER_TYPE_LETTERS)
+        if unknown:
+            raise ValueError(f"decoder: layer_types holds {sorted(unknown)} "
+                             f"(known: {sorted(LAYER_TYPE_LETTERS)})")
+        pattern = "".join(LAYER_TYPE_LETTERS[k] for k in cfg.layer_types)
+    for i, letter in enumerate(pattern):
+        if letter in "FS":
+            t = _gated_block(ff, t, i, cfg, letter)
+            continue
         if letter in "AX":
             t = _latent_block(ff, t, f"b{i}", cfg, experts=letter == "X")
             continue
@@ -337,7 +436,7 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
             continue
         h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"b{i}_norm")
         t = ff.add(t, _mixer(ff, h, letter, i, cfg), name=f"b{i}_res")
-    if "D" in cfg.hybrid_override_pattern:
+    if "D" in pattern:
         # the head and the loss read the noised half alone
         half = cfg.seq_length // 2
         t = ff.split(t, [half, half], axis=1, name="noised_half")[0]

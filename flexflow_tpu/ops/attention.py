@@ -16,6 +16,7 @@ and a kernel. The einsum core and ring attention, which want
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +78,102 @@ def rotary_interleaved(x, *, theta: float, head_dim: int, lanes_of=None):
     partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
                         jnp.roll(xf, 1, axis=-1))
     return (xf * cos + partner * sin).astype(x.dtype)
+
+
+def rotary_frequencies(dim: int, theta: float, scaling=None):
+    """(inv_freq [dim/2] float32, attention_factor) of a rotary embedding
+    over ``dim`` rotated lanes: f_j = theta^(-2j/dim) and 1, or under
+    ``scaling`` = {"rope_type": "yarn", factor, original_max_position_
+    embeddings, beta_fast, beta_slow, attention_factor} (the keys of a
+    public ``config.json``) YaRN's table as the `transformers` library
+    forms it: with c(r) = dim ln(original / (2 pi r)) / (2 ln theta),
+    low = floor(c(beta_fast)) and high = ceil(c(beta_slow)) held to
+    [0, dim - 1], m_j = 1 - clip((j - low) / (high - low), 0, 1):
+        inv_freq_j = (f_j / factor) (1 - m_j) + f_j m_j
+    (the fast lanes keep their frequency, the slow ones are interpolated)
+    and cos and sin scaled by ``attention_factor`` (0.1 ln factor + 1
+    where the config gives none)."""
+    f = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    kind = (scaling or {}).get("rope_type", "default")
+    if kind == "default":
+        return f, 1.0
+    if kind != "yarn":
+        raise ValueError(f"rotary embedding: unknown rope_type {kind!r} "
+                         f"(known: default, yarn)")
+    factor = float(scaling["factor"])
+    original = scaling["original_max_position_embeddings"]
+
+    def correction(rotations):
+        return (dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction(scaling.get("beta_slow", 1))), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    m = 1.0 - ramp
+    factor_of_attention = scaling.get("attention_factor") or (
+        0.1 * math.log(factor) + 1.0)
+    return (f / factor) * (1.0 - m) + f * m, float(factor_of_attention)
+
+
+def rotary_partial(x, inv_freq, *, rotary_dim: int,
+                   attention_factor: float = 1.0):
+    """RoPE over the FIRST ``rotary_dim`` lanes of every head of x
+    [B, S, H, D], half-split pairs (j, j + rotary_dim / 2) as
+    ``rotary_embedding`` has them, positions 0..S-1, angles position *
+    ``inv_freq`` (``rotary_frequencies``), cos and sin times
+    ``attention_factor``; the other lanes pass as they are (they meet cos
+    1 and sin 0). A lane's partner comes by a product of the head's D
+    lanes with a signed permutation [D, D] at precision `highest` (every
+    sum has one term: exact), so no head is cut at half a vreg and
+    nothing is laid end to end again: v5e, 48 heads of 128 at 8,192
+    positions, forward and backward ms (`scripts/gate_lab.py`, PR 41):
+    1.90 this way, 6.12 with the rotated halves sliced out and
+    concatenated, 7.30 with the partner by two rolls and a select; 64
+    heads 2.54 / 8.36 / 9.90 (`rotary_embedding`'s whole-head form, which
+    slices: 4.77 and 6.37)."""
+    s, d = x.shape[1], x.shape[-1]
+    half = rotary_dim // 2
+    angles = (jnp.arange(s, dtype=jnp.float32)[:, None]
+              * inv_freq[None, :])                          # [S, r/2]
+    rest = (s, d - rotary_dim)
+    cos = jnp.concatenate([jnp.cos(angles) * attention_factor] * 2
+                          + [jnp.ones(rest, jnp.float32)], axis=-1)
+    sin = jnp.concatenate([jnp.sin(angles) * attention_factor] * 2
+                          + [jnp.zeros(rest, jnp.float32)], axis=-1)
+    # partner = x @ turn: lane j < half gets -x[j + half], lane
+    # half <= j < rotary_dim gets x[j - half], the others nothing
+    turn = np.zeros((d, d), np.float32)
+    lanes = np.arange(half)
+    turn[lanes + half, lanes] = -1.0
+    turn[lanes, lanes + half] = 1.0
+    xf = x.astype(jnp.float32)
+    partner = jnp.dot(xf, jnp.asarray(turn),
+                      precision=jax.lax.Precision.HIGHEST)
+    return (xf * cos[:, None, :] + partner * sin[:, None, :]).astype(x.dtype)
+
+
+# the per-head output gate's activation; "sigmoid" is a control
+GATE_ACTIVATIONS = {"softplus": jax.nn.softplus, "sigmoid": jax.nn.sigmoid}
+
+
+def gate_lanes(a, head_dim: int):
+    """a [B, S, H] float32, one value a head, laid along the lanes of a
+    [B, S, H * head_dim] operand: head n's value over its head_dim lanes.
+    By a product with the 0/1 matrix [H, H * head_dim] at precision
+    `highest` (every sum has one term: exact), not by a broadcast through
+    a [B, S, H, D] view: H is 48 or 64 lanes of a [.., H] array, and XLA
+    turns that broadcast into a float32 array of the output's size and a
+    copy of the view (`scripts/gate_lab.py` has both forms' times). The
+    transpose, the backward's sum over a head's lanes, is a product too."""
+    h = a.shape[-1]
+    of_head = jnp.arange(h * head_dim, dtype=jnp.int32) // head_dim
+    spread = (of_head[None, :] == jnp.arange(h, dtype=jnp.int32)[:, None])
+    return jnp.dot(a, spread.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def rms_normed(x, scale, eps):
@@ -149,9 +246,23 @@ class MultiHeadAttention(Op):
     dispatch, scopes, counters and kernel choice are shared, and the
     flash kernels take the rotated parts as two more operands
     (``pallas_kernels._flash_fwd``), so nothing is assembled per head.
+
+    Three more properties of the plain op (PR 41), off by default.
+    ``gate``: a leaf ``w_gate`` [E, H] and one scalar a head and
+    position, a = softplus(x w_gate) in float32, that multiplies the
+    head's output lanes between the core and ``wo`` (scope
+    ``attention_gate``; XLA around the kernels).
+    ``partial_rotary_factor``: rotary over the first ``head_dim *
+    factor`` lanes of every query and key head, the others pass.
+    ``rope_scaling``: the frequency table and ``attention_factor`` of
+    ``rotary_frequencies`` (YaRN). Whole-head rotary with plain
+    frequencies runs as it always did, under the scope ``rotary_whole``;
+    anything else ``rotary_partial`` under ``rotary_partial_yarn``.
     """
 
     scopes_itself = True
+    # the gate's product is float32, as a router's
+    full_precision_params = ("w_gate",)
 
     def __init__(self, layer, input_shapes):
         p = layer.properties
@@ -200,6 +311,21 @@ class MultiHeadAttention(Op):
         # the rotary embedding, with a learned scale each
         self.qk_norm = p.get("qk_norm", False)
         self.qk_norm_eps = p.get("qk_norm_eps", 1e-6)
+        # rotary over the first `rotary_dim` lanes of a head, and the
+        # frequency table's scaling (`rotary_frequencies`; None: plain)
+        self.rotary_dim = int(self.head_dim
+                              * p.get("partial_rotary_factor", 1.0))
+        self.rope_scaling = dict(p.get("rope_scaling") or {}) or None
+        if self.rope and (self.rotary_dim % 2 or not self.rotary_dim):
+            raise ValueError(
+                f"attention '{layer.name}': partial_rotary_factor leaves "
+                f"{self.rotary_dim} rotated lanes of {self.head_dim}")
+        # a per-head gate on the core's output, and its activation
+        self.gate = bool(p.get("gate", False))
+        self.gate_activation = p.get("gate_activation", "softplus")
+        if self.gate_activation not in GATE_ACTIVATIONS:
+            raise ValueError(f"attention '{layer.name}': unknown "
+                             f"gate_activation {self.gate_activation!r}")
         # latent attention: (q rank, kv rank, rotated width) or None
         self.latent = None
         if p.get("kv_lora_rank"):
@@ -213,12 +339,15 @@ class MultiHeadAttention(Op):
                     and len(input_shapes) == 3
                     and input_shapes[0] == input_shapes[1]) or (
                         self.window or self.block_diffusion or self.qk_norm
-                        or self.use_bias or self.rope_wrap
+                        or self.use_bias or self.rope_wrap or self.gate
+                        or self.rope_scaling
+                        or self.rotary_dim != self.head_dim
                         or p.get("seq_parallel")):
                 raise ValueError(
                     f"attention '{layer.name}': latent attention is causal "
                     f"self-attention with as many key/value heads as query "
-                    f"heads, no bias, window, mask, head norm or ring")
+                    f"heads, no bias, window, mask, head norm, gate, "
+                    f"partial or scaled rotary, or ring")
         # separate q/k/v projection biases (torch nn.MultiheadAttention
         # parity — in_proj_bias). Off by default: they cost an extra
         # elementwise pass over q/k/v every step and native models
@@ -247,6 +376,10 @@ class MultiHeadAttention(Op):
         # traced, a head (`attention/kv_blocks_*`); None until a forward
         # ran flash
         self._kv_blocks = None
+        # of a windowed op that ran flash: (the (query, key) pairs in the
+        # tiles its kernels work through, forward and backward; twice the
+        # pairs visible), a head (`attention/window_keys_*`)
+        self._window_pairs = None
         # batch-dim sharding (str or tuple of mesh axes under the sample2
         # 'data+model' 2-D partition), recorded by apply_strategy
         self.batch_parallel = p.get("batch_parallel", None)
@@ -281,6 +414,10 @@ class MultiHeadAttention(Op):
         if self.qk_norm:
             params["q_norm"] = jnp.ones((d,))
             params["k_norm"] = jnp.ones((d,))
+        if self.gate:
+            # a key of its own: the four above stay what they were
+            params["w_gate"] = self.kernel_init(jax.random.fold_in(rng, 4),
+                                                (e, h))
         if self.use_bias:
             params["bo"] = jnp.zeros((e,))
             if self.qkv_bias:
@@ -381,6 +518,9 @@ class MultiHeadAttention(Op):
             self._qkv_latent if self.latent else self._qkv)(
                 params, inputs, ctx))(params, inputs)
         o = self._core(q, k, v, ctx, rng, flash_scope, around, rope)
+        if self.gate:
+            o = scoped("attention_gate", lambda w, x, o: self._gated(
+                w, x, o, ctx))(params["w_gate"], inputs[0], o)
         return [around(lambda params, o: self._output(
             params, o, ctx, inputs[0].dtype))(params, o)]
 
@@ -402,12 +542,9 @@ class MultiHeadAttention(Op):
             q = self._heads_normed(q, h, params["q_norm"])
             k = self._heads_normed(k, hk, params["k_norm"])
         if self.rope:
-            q = rotary_embedding(q.reshape(b, sq, h, d), theta=self.rope_theta,
-                                 seq_axis=1, wrap=self.rope_wrap
-                                 ).reshape(b, sq, h * d)
-            k = rotary_embedding(k.reshape(b, sk, hk, d), theta=self.rope_theta,
-                                 seq_axis=1, wrap=self.rope_wrap
-                                 ).reshape(b, sk, hk * d)
+            q, k = self._rotated(q.reshape(b, sq, h, d),
+                                 k.reshape(b, sk, hk, d))
+            q, k = q.reshape(b, sq, h * d), k.reshape(b, sk, hk * d)
         if hk != h:
             k, v = (jnp.repeat(x.reshape(b, sk, hk, d), h // hk, axis=2
                                ).reshape(b, sk, h * d) for x in (k, v))
@@ -416,6 +553,40 @@ class MultiHeadAttention(Op):
         # path below is f32 regardless, and bf16 kernel I/O halves the
         # flash kernel's HBM traffic
         return q.astype(cd), k.astype(cd), v.astype(cd), None
+
+    def _rotated(self, q, k):
+        """Rotary on q [B, S, H, D] and k [B, S, Hk, D], under the scope
+        of its form: `rotary_whole` (every lane, plain frequencies: the
+        one form until PR 41, its arithmetic untouched) or
+        `rotary_partial_yarn`."""
+        if self.rotary_dim == self.head_dim and not self.rope_scaling:
+            return scoped("rotary_whole", lambda q, k: tuple(
+                rotary_embedding(t, theta=self.rope_theta, seq_axis=1,
+                                 wrap=self.rope_wrap) for t in (q, k)))(q, k)
+        if self.rope_wrap:
+            raise NotImplementedError(
+                f"attention '{self.name}': wrapped positions with partial "
+                f"or scaled rotary")
+
+        def rotate(q, k):
+            inv_freq, factor = rotary_frequencies(
+                self.rotary_dim, self.rope_theta, self.rope_scaling)
+            return tuple(rotary_partial(
+                t, inv_freq, rotary_dim=self.rotary_dim,
+                attention_factor=factor) for t in (q, k))
+
+        return scoped("rotary_partial_yarn", rotate)(q, k)
+
+    def _gated(self, w_gate, x, o, ctx: OpContext):
+        """o [B, S, H*D] with head n's lanes times a_n = act(x w_gate)_n,
+        one scalar a head and position, the product and the activation
+        in float32 (the backward: a 128-lane sum of dO * o a head and
+        position, and dO rescaled on its way into the kernels)."""
+        a = GATE_ACTIVATIONS[self.gate_activation](jnp.dot(
+            x.astype(jnp.float32), w_gate.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))           # [B, S, H]
+        return (o.astype(jnp.float32) * gate_lanes(a, self.head_dim)
+                ).astype(ctx.compute_dtype)
 
     def _qkv_latent(self, params, inputs, ctx: OpContext):
         """q_nope, k_nope, v [B, S, H*D] and (q_rope [B, S, H*R], the one
@@ -518,7 +689,7 @@ class MultiHeadAttention(Op):
             from flexflow_tpu.ops.pallas_kernels import (
                 flash_attention, flash_attention_available,
                 flash_attention_sharded, flash_shape_legal, kv_blocks,
-                kv_blocks_masked)
+                kv_blocks_masked, visible_pairs, visited_pairs)
 
             available = flash_attention_available(sq, d, h, rope_dim)
             if self.kernel_impl == "flash" and not available:
@@ -532,6 +703,10 @@ class MultiHeadAttention(Op):
                 self._flash_lane_dense = True
                 kind = (sq, self.causal, self.window, self.block_diffusion)
                 self._kv_blocks = (*kv_blocks(*kind), kv_blocks_masked(*kind))
+                if self.windowed:
+                    self._window_pairs = (
+                        visited_pairs(*kind),
+                        2 * visible_pairs(sq, self.causal, self.window))
 
                 def flash(kernel, **where):
                     call = functools.partial(
@@ -641,6 +816,13 @@ class MultiHeadAttention(Op):
                 f"attention '{self.name}': KV-cache incremental decode has "
                 f"no block-diffusion mask (a block is generated over "
                 f"several denoising passes, not a token a step)")
+        if self.gate or self.rope_scaling or self.rotary_dim != self.head_dim:
+            raise NotImplementedError(
+                f"attention '{self.name}': KV-cache incremental decode has "
+                f"no per-head output gate (`gate`), no partial rotary "
+                f"(`partial_rotary_factor`) and no scaled frequencies "
+                f"(`rope_scaling`); the cached path would drift from the "
+                f"training forward")
         if self.qk_norm or self.rope_wrap:
             raise NotImplementedError(
                 f"attention '{self.name}': KV-cache incremental decode "
@@ -749,7 +931,9 @@ class MultiHeadAttention(Op):
         # square, as it always was); under the block-diffusion mask the
         # pairs it leaves, counted exactly
         core = 2 * b * h * self.visible_pairs * d * 2
-        return proj + core
+        # the gate's product and its multiply of the core's output
+        gate = (2 * e + d) * b * sq * h if self.gate else 0
+        return proj + core + gate
 
     def params_elems(self):
         h, e, d = self.num_heads, self.embed_dim, self.head_dim
@@ -761,6 +945,8 @@ class MultiHeadAttention(Op):
         n = h * d * (e + e) + hk * d * (self.kdim + self.vdim)
         if self.qk_norm:
             n += 2 * d
+        if self.gate:
+            n += e * h
         if self.use_bias:
             n += e + ((h + 2 * hk) * d if self.qkv_bias else 0)
         return n

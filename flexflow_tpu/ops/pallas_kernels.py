@@ -177,7 +177,7 @@ def _for_rows(rows: int, unroll: int, body) -> None:
     jax.lax.fori_loop(0, rows // unroll, step, None)
 
 
-def _seq_block(s: int, block_diffusion=None) -> int:
+def _seq_block(s: int, block_diffusion=None, window: int = 0) -> int:
     """Rows of a block along the sequence in the blocked kernels (the K
     chunks the forward's loop takes, the K rows a grid step and the Q
     chunks a loop step of the backward): the largest of 1024, 512, 256,
@@ -202,10 +202,24 @@ def _seq_block(s: int, block_diffusion=None) -> int:
     2L = 16384, B = 4, Q blocks of 256, forward / backward ms): 2.87 /
     5.11 at 512, 2.83 / 4.97 at 1024: a chunk's fixed cost (the running
     sums' rescaling, the seven transposes and dQ's read-modify-write a
-    tile) outweighs a ninth more of the square."""
+    tile) outweighs a ninth more of the square.
+
+    Under a ``window`` (as ``normalized_window`` leaves it) narrower than
+    1024 the block is the window's size at most, and 256 at least (PR
+    41): a Q block of 256 rows reaches 255 + window keys, which lie in
+    one or two chunks of 1024 whatever the window, and a K block of 1024
+    meets two Q chunks of 1024. At window 512, S = 8192 the forward
+    visits 1536 keys a row at chunks of 1024 and 1024 at 512, the
+    backward 2048 queries a key and 1024, for 512 visible; every visited
+    tile is an edge tile either way. v5e, bf16, kernels alone, 64 heads
+    of 128, S = 8192, window 512, forward / backward ms (PR 41,
+    `scripts/flash_lab.py --only laguna`): 4.19 / 7.97 at 1024, 3.75 /
+    5.81 at 512, 4.64 / 7.13 at 256 (a chunk's fixed cost again)."""
     if block_diffusion is not None:
         s = block_diffusion[0]
-    return next(b for b in (1024, 512, 256, BLK_Q) if s % b == 0)
+    most = max(window, 2 * BLK_Q) if 0 < window < 1024 else 1024
+    return next(b for b in (1024, 512, 256, BLK_Q)
+                if b <= most and s % b == 0)
 
 
 def _q_block(s: int, block_diffusion=None) -> int:
@@ -503,10 +517,41 @@ def _forward_tiles(s: int, causal: bool, window: int, block_diffusion):
     ``kv_blocks_masked`` count, by the kernel's own lines."""
     window = normalized_window(s, causal, window)
     bd = checked_block_diffusion(s, causal, window, block_diffusion)
-    blk_q, blk_k = _q_block(s, bd), _seq_block(s, bd)
+    blk_q, blk_k = _q_block(s, bd), _seq_block(s, bd, window)
     cut = [sub for q0 in range(0, s, blk_q)
            for sub in _k_split(q0, blk_q, blk_k, s, causal, window, bd)[0]]
     return cut, (s // blk_q) * (s // blk_k)
+
+
+def visited_pairs(s: int, causal: bool, window: int = 0,
+                  block_diffusion=None):
+    """(query, key) pairs of one head and sequence that lie in the tiles
+    the flash kernels work through, forward and backward added: the
+    forward's [Q block, K chunk] tiles (``_k_split``) and the backward's
+    [K block, Q chunk] tiles (``_q_split``), by the kernels' own ranges.
+    Against twice the visible pairs it says how much of the kernels' work
+    the mask then throws away. The whole-tile kernels (S <= MAX_BWD_SEQ)
+    hold the square, once each."""
+    if s <= MAX_BWD_SEQ:
+        return 2 * s * s
+    window = normalized_window(s, causal, window)
+    bd = checked_block_diffusion(s, causal, window, block_diffusion)
+    cut, _ = _forward_tiles(s, causal, window, block_diffusion)
+    blk = _seq_block(s, bd, window)
+    forward = sum(hi - lo for lo, hi, _ in cut) * _q_block(s, bd) * blk
+    backward = sum(hi - lo for k0 in range(0, s, blk) for lo, hi, _ in
+                   _q_split(k0, blk, blk, s, causal, window, bd)) * blk * blk
+    return forward + backward
+
+
+def visible_pairs(s: int, causal: bool, window: int = 0) -> int:
+    """(query, key) pairs of one head and sequence that ``visible`` admits
+    under ``causal`` and a window, counted exactly: a query sees itself
+    and the keys before it, the last ``window`` of them at most."""
+    if not causal:
+        return s * s
+    w = normalized_window(s, causal, window) or s
+    return w * (w + 1) // 2 + (s - w) * w
 
 
 def kv_blocks(s: int, causal: bool, window: int = 0, block_diffusion=None):
@@ -786,7 +831,8 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     ] if rope_dim else []
     return pl.pallas_call(
         functools.partial(_flash_fwd_kernel, causal=causal, window=window,
-                          scale=scale, blk_q=blk, blk_k=_seq_block(s, bd),
+                          scale=scale, blk_q=blk,
+                          blk_k=_seq_block(s, bd, window),
                           head_dim=d, block_diffusion=bd, rope_dim=rope_dim),
         name=KERNEL_NAME_PREFIX + "flash_fwd",
         out_shape=out_shape,
@@ -1060,7 +1106,7 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
         )(q, k, v, o, do, lse, glse, *rope_ops))
-    blk = _seq_block(s, bd)
+    blk = _seq_block(s, bd, window)
     seq_spec = pl.BlockSpec((1, s, w), lambda b, c, j: (b, 0, c))
     kblk_spec = pl.BlockSpec((1, blk, w), lambda b, c, j: (b, j, c))
     row_spec = pl.BlockSpec((1, hpb, 1, s), lambda b, c, j: (b, c, 0, 0))

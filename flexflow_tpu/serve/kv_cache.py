@@ -107,6 +107,14 @@ def init_kv_cache(ff, batch: Optional[int] = None,
                 f"attention '{op.name}' is latent attention — this cache "
                 f"holds whole key and value heads; serving it from the "
                 f"compressed latent (the absorbed form) is not built")
+        if (getattr(op, "gate", False) or getattr(op, "rope_scaling", None)
+                or getattr(op, "rotary_dim", op.head_dim) != op.head_dim):
+            raise NotImplementedError(
+                f"attention '{op.name}' has a per-head output gate "
+                f"(`gate`), partial rotary (`partial_rotary_factor`) or "
+                f"scaled frequencies (`rope_scaling`) — the cached decode "
+                f"path applies none of them; serving such an op is not "
+                f"built")
         spec = cache_partition_spec(ff, node, batch, max_len)
         sharding = NamedSharding(ff.mesh, spec)
         shape = (batch, op.num_kv_heads, max_len, op.head_dim)

@@ -22,6 +22,10 @@ and under the block-diffusion mask both at K blocks of 512 and of 1024
 ONE rotated key a position) runs `split` twice: as it ships, and
 `split, no rotated part` (the same kernels on the 128-wide parts alone),
 so that the difference is what the second product and its operands cost.
+The narrow-window shape (PR 41: 64 heads of 128 at 8,192 positions under
+a window of 512, half of a K chunk of 1024) runs `split` at K blocks of
+1024 (what `_seq_block` gave before it took the block from the window),
+512 (what ships) and 256.
 
 Prints one JSON line a measurement and writes them to
 `chiprun_out/flash_lab.json`. Nothing here is a benchmark metric.
@@ -47,6 +51,8 @@ SHAPES = {
     "smallthinker.full": (7, 16384, True, 0, None),
     "nemotron.full": (4, 8192, True, 0, None),
     "joyai.latent": (32, 4096, True, 0, None),
+    "laguna.narrow_window": (64, 8192, True, 512, None),
+    "laguna.full": (48, 8192, True, 0, None),
 }
 ROPE_DIM = 64      # the rotated lanes of a `latent` shape's query and key
 REPS = 10
@@ -124,8 +130,13 @@ def main():
         latent = name.endswith(".latent")
         rope = (jnp.asarray(rs.randn(1, seq, heads * ROPE_DIM), jnp.bfloat16),
                 jnp.asarray(rs.randn(1, seq, ROPE_DIM), jnp.bfloat16))
-        for blk in (512, 1024) if bd else (None,):
+        narrow = name.endswith(".narrow_window")
+        for blk in ((512, 1024) if bd else (1024, 512, 256) if narrow
+                    else (None,)):
+            if tiny and narrow:
+                blk //= 2
             for program in (("split", "split, no rotated part") if latent
+                            else ("split",) if narrow
                             else ("every_tile_masked",
                                   "every_tile_masked+peel", "split")):
                 pk._k_split, pk._q_split, pk._seq_block = shipped
@@ -134,7 +145,8 @@ def main():
                         pk._k_ranges, True, peel="peel" in program)
                     pk._q_split = every_tile_masked(pk._q_ranges, False)
                 if blk:
-                    pk._seq_block = lambda s, block_diffusion=None, b=blk: b
+                    pk._seq_block = (lambda s, block_diffusion=None,
+                                     window=0, b=blk: b)
                 mask = dict(window=window, block_diffusion=bd)
                 if program == "split" and latent:
                     mask["rope"] = rope
@@ -145,11 +157,13 @@ def main():
                 o, lse = fwd(q, k, v)
                 line = dict(
                     shape=name, heads=heads, seq=seq, program=program,
-                    k_block=pk._seq_block(seq, bd),
+                    k_block=pk._seq_block(seq, bd, window),
+                    pairs_visited=pk.visited_pairs(seq, causal, window, bd),
                     tiles_visited=pk.kv_blocks(seq, causal, window, bd)[0],
                     tiles_masked=pk.kv_blocks_masked(seq, causal, window, bd),
                     last_chunk_peeled=pk._k_split(
-                        0, pk._q_block(seq, bd), pk._seq_block(seq, bd), seq,
+                        0, pk._q_block(seq, bd),
+                        pk._seq_block(seq, bd, window), seq,
                         causal, window, bd)[1],
                     forward_device_ms=kernel_ms(fwd, (q, k, v), tiny),
                     backward_device_ms=kernel_ms(

@@ -113,7 +113,9 @@ def test_blocked_kernels_visit_exactly_the_blocks_with_a_visible_pair(
         seq, causal, window):
     """The forward's K chunks and the backward's Q chunks, from the loop
     bounds the kernels use, against a count on the mask itself."""
-    blk, blk_q = pk._seq_block(seq), pk._q_block(seq)
+    # the chunk follows a window narrower than 1024 (PR 41)
+    blk = pk._seq_block(seq, None, pk.normalized_window(seq, causal, window))
+    blk_q = pk._q_block(seq)
     visited, total = pk.kv_blocks(seq, causal, window)
     assert total == (seq // blk_q) * (seq // blk)
     assert visited == tiles_with_a_visible_pair(seq, blk_q, blk, causal,

@@ -178,7 +178,8 @@ class TestFlashKernels:
         assert ("tpu_custom_call_flash_bwd_blocked" in hlo) == (seq > 1024)
 
     @pytest.mark.parametrize("heads,seq,window", [
-        (7, 16384, 4096), (7, 16384, 0), (4, 8192, 0), (7, 16384, 1000)])
+        (7, 16384, 4096), (7, 16384, 0), (4, 8192, 0), (7, 16384, 1000),
+        (64, 8192, 512), (48, 8192, 0)])
     def test_causal_and_window_split_compile_at_the_cells_shapes(
             self, topo, heads, seq, window):
         """The smallthinker cell's 7 heads of 128 at 16,384 under a
@@ -186,7 +187,9 @@ class TestFlashKernels:
         8,192: forward and K-blocked backward whose loops are cut into
         far edge, interior and diagonal (PR 35), each a `fori_loop` with
         bounds computed from the grid index, inside the 96 MiB budget;
-        and a window so narrow that the interior range is empty."""
+        and a window so narrow that the interior range is empty. The
+        laguna cell's 64 heads under a window of 512, half of a chunk of
+        1024 (K chunks of 512 then, PR 41), and its 48 under none."""
         q = jax.ShapeDtypeStruct((1, seq, heads * 128), jnp.bfloat16,
                                  sharding=SingleDeviceSharding(
                                      topo.devices[0]))
