@@ -747,6 +747,15 @@ class GraphExecutor:
         return sum(bool(getattr(n.op, "_flash_lane_dense", False))
                    for n in self.nodes)
 
+    def rotary_lane_dense_ops(self) -> int:
+        """Attention ops whose forward, as last traced, ran the heads'
+        norm and rotary as the one lane-dense pass over [B, S,
+        heads*head_dim] (`pallas_kernels.rotary_lanes`; ops/attention.py
+        sets the flag; PR 42): the gauge `executor.rotary_lane_dense_ops`,
+        and `rotary_lane_dense_ops` in every trace header."""
+        return sum(bool(getattr(n.op, "_rotary_lane_dense", False))
+                   for n in self.nodes)
+
     def moe_gather_combine_ops(self) -> int:
         """`MoELayer` ops whose forward, as last traced, sent rows to the
         experts and brought them back to their tokens by gathers through
@@ -877,6 +886,8 @@ class GraphExecutor:
             # attention ops handed the flash kernels [B, S, H*D] operands
             get_registry().gauge("executor.flash_lane_dense_ops",
                                  self.flash_lane_dense_ops())
+            get_registry().gauge("executor.rotary_lane_dense_ops",
+                                 self.rotary_lane_dense_ops())
             for gauge, value in self.attention_gauges().items():
                 get_registry().gauge(gauge, value)
             get_registry().gauge("executor.moe_gather_combine_ops",

@@ -1479,6 +1479,8 @@ class FFModel:
         # their forwards recorded on the host
         self.op_counters["executor.flash_lane_dense_ops"] = float(
             self.executor.flash_lane_dense_ops())
+        self.op_counters["executor.rotary_lane_dense_ops"] = float(
+            self.executor.rotary_lane_dense_ops())
         self.op_counters.update(
             (k, float(v)) for k, v in self.executor.attention_gauges().items())
         self.op_counters["executor.moe_gather_combine_ops"] = float(

@@ -357,6 +357,9 @@ def model_context(ff) -> Dict[str, Any]:
         # their [B, S, heads*head_dim] operand form (0 until a step or a
         # forward has been traced)
         flash_lane_dense_ops=ff.executor.flash_lane_dense_ops(),
+        # of the rotary ops, those whose heads' norm and rotary ran as
+        # the one lane-dense pass
+        rotary_lane_dense_ops=ff.executor.rotary_lane_dense_ops(),
         # windowed attention ops, those under the block-diffusion mask,
         # and the flash forwards' K blocks visited against the whole
         # square's, and of them those masked

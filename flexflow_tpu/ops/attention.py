@@ -10,7 +10,9 @@ q, k, v and o are [B, S, heads*head_dim] from the projections to the
 output projection (PR 30): the form the Pallas flash-attention kernels
 (ops/pallas_kernels.py) take, with no layout copy between a projection
 and a kernel. The einsum core and ring attention, which want
-[B, H, S, D], convert at their own boundary; XLA fuses that.
+[B, H, S, D], convert at their own boundary; XLA fuses that. Rotary and
+the per-head norm before it keep to that form too where a head fills the
+128 lanes (PR 42: `MultiHeadAttention._rotated`).
 """
 
 from __future__ import annotations
@@ -28,6 +30,27 @@ from flexflow_tpu.ops.base import (DimRole, Op, OpContext, register_op,
                                    scoped)
 
 
+def rotary_tables(s: int, d: int, inv_freq, attention_factor: float = 1.0,
+                  position_offset=0, wrap: int = 0):
+    """(cos, sin) [S, d] float32 of a rotary embedding over the first
+    ``2 len(inv_freq)`` lanes of a ``d``-wide head, half-split pairs: row
+    i stands at position offset + i (mod ``wrap`` where that is not 0),
+    a pair's angle is position * ``inv_freq``, both halves of the rotated
+    lanes hold the same cos and sin times ``attention_factor``, and the
+    lanes past them 1 and 0. The one place the tables are formed: every
+    form of the rotation below multiplies by the same bits."""
+    pos = position_offset + jnp.arange(s, dtype=jnp.float32)
+    if wrap:
+        pos = pos % wrap
+    angles = pos[:, None] * inv_freq[None, :]               # [S, r/2]
+    rest = (s, d - 2 * inv_freq.shape[0])
+    cos = jnp.concatenate([jnp.cos(angles) * attention_factor] * 2
+                          + [jnp.ones(rest, jnp.float32)], axis=-1)
+    sin = jnp.concatenate([jnp.sin(angles) * attention_factor] * 2
+                          + [jnp.zeros(rest, jnp.float32)], axis=-1)
+    return cos, sin
+
+
 def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
                      seq_axis: int = 2, wrap: int = 0):
     """Apply RoPE to [B, H, S, D], or with ``seq_axis=1`` to [B, S, H, D]
@@ -39,13 +62,8 @@ def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
     at position (offset + i) mod wrap), for a sequence that holds several
     copies of one sample side by side."""
     s, d = x.shape[seq_axis], x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    pos = position_offset + jnp.arange(s, dtype=jnp.float32)
-    if wrap:
-        pos = pos % wrap
-    angles = pos[:, None] * inv_freq[None, :]
-    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)  # [S, D]
-    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
+    cos, sin = rotary_tables(s, d, rotary_frequencies(d, theta)[0],
+                             position_offset=position_offset, wrap=wrap)
     if seq_axis == 1:
         cos, sin = cos[:, None, :], sin[:, None, :]        # [S, 1, D]
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
@@ -129,21 +147,24 @@ def rotary_partial(x, inv_freq, *, rotary_dim: int,
     1 and sin 0). A lane's partner comes by a product of the head's D
     lanes with a signed permutation [D, D] at precision `highest` (every
     sum has one term: exact), so no head is cut at half a vreg and
-    nothing is laid end to end again: v5e, 48 heads of 128 at 8,192
-    positions, forward and backward ms (`scripts/gate_lab.py`, PR 41):
-    1.90 this way, 6.12 with the rotated halves sliced out and
-    concatenated, 7.30 with the partner by two rolls and a select; 64
-    heads 2.54 / 8.36 / 9.90 (`rotary_embedding`'s whole-head form, which
-    slices: 4.77 and 6.37)."""
+    nothing is laid end to end again. The form of every shape the
+    lane-dense pass does not take (`MultiHeadAttention._rotated`).
+
+    v5e, 48 heads of 128 at 8,192 positions, forward and backward ms of
+    THE ROTATION ALONE, a [B, S, H, D] array in and out
+    (`scripts/gate_lab.py`, PR 41): 1.90 this way, 6.12 with the rotated
+    halves sliced out and concatenated, 7.30 with the partner by two
+    rolls and a select; 64 heads 2.54 / 8.36 / 9.90 (`rotary_embedding`'s
+    whole-head form, which slices: 4.77 and 6.37). That is not what a
+    step pays: between a projection's [B, S, H*D] product and a flash
+    kernel's [B, S, H*D] operand the 4-D view costs copies of the whole
+    float32 array each way, and the 48-head op as a whole, forward and
+    backward, reads 39.67 ms this way against 33.53 with the pass and
+    32.01 with no rotary at all (`rotary.in_context.partial`, my chip
+    runs, PR 42): 7.7 ms, not 1.9."""
     s, d = x.shape[1], x.shape[-1]
     half = rotary_dim // 2
-    angles = (jnp.arange(s, dtype=jnp.float32)[:, None]
-              * inv_freq[None, :])                          # [S, r/2]
-    rest = (s, d - rotary_dim)
-    cos = jnp.concatenate([jnp.cos(angles) * attention_factor] * 2
-                          + [jnp.ones(rest, jnp.float32)], axis=-1)
-    sin = jnp.concatenate([jnp.sin(angles) * attention_factor] * 2
-                          + [jnp.zeros(rest, jnp.float32)], axis=-1)
+    cos, sin = rotary_tables(s, d, inv_freq, attention_factor)
     # partner = x @ turn: lane j < half gets -x[j + half], lane
     # half <= j < rotary_dim gets x[j - half], the others nothing
     turn = np.zeros((d, d), np.float32)
@@ -256,8 +277,19 @@ class MultiHeadAttention(Op):
     factor`` lanes of every query and key head, the others pass.
     ``rope_scaling``: the frequency table and ``attention_factor`` of
     ``rotary_frequencies`` (YaRN). Whole-head rotary with plain
-    frequencies runs as it always did, under the scope ``rotary_whole``;
-    anything else ``rotary_partial`` under ``rotary_partial_yarn``.
+    frequencies runs under the scope ``rotary_whole``, anything else
+    under ``rotary_partial_yarn``.
+
+    The per-head pass between a projection and the core (PR 42): where a
+    head is one 128-lane column, S whole blocks of 128 rows, Pallas on
+    and the mesh one device, the heads' norm (``qk_norm``) and the
+    rotation run as ONE kernel over the projection's float32
+    [B, S, H*128] result as it lies (``pallas_kernels.rotary_lanes``,
+    with its own backward), which writes the core's operand in the
+    compute dtype. Every other shape takes ``_heads_normed`` and
+    ``rotary_embedding`` / ``rotary_partial`` over a [B, S, H, D] view:
+    the same float32 products from the same tables, at the price of
+    XLA's copies of the whole array between the two layouts.
     """
 
     scopes_itself = True
@@ -372,6 +404,9 @@ class MultiHeadAttention(Op):
         # set when a forward hands the flash kernels [B, S, H*D]
         # operands (counted by `executor.flash_lane_dense_ops`)
         self._flash_lane_dense = False
+        # set when a forward ran the heads' norm and rotary as the
+        # lane-dense pass (counted by `executor.rotary_lane_dense_ops`)
+        self._rotary_lane_dense = False
         # (visited, total, masked) K blocks of the flash forward as
         # traced, a head (`attention/kv_blocks_*`); None until a forward
         # ran flash
@@ -537,14 +572,12 @@ class MultiHeadAttention(Op):
         q = self._project(query, params["wq"], params["bq"] if biased else None, cd)
         k = self._project(key, params["wk"], params["bk"] if biased else None, cd)
         v = self._project(value, params["wv"], params["bv"] if biased else None, cd)
-        b, sq, sk = q.shape[0], q.shape[1], k.shape[1]
-        if self.qk_norm:
+        b, sk = k.shape[0], k.shape[1]
+        if self.rope:
+            q, k = self._rotated(q, k, params, ctx)
+        elif self.qk_norm:
             q = self._heads_normed(q, h, params["q_norm"])
             k = self._heads_normed(k, hk, params["k_norm"])
-        if self.rope:
-            q, k = self._rotated(q.reshape(b, sq, h, d),
-                                 k.reshape(b, sk, hk, d))
-            q, k = q.reshape(b, sq, h * d), k.reshape(b, sk, hk * d)
         if hk != h:
             k, v = (jnp.repeat(x.reshape(b, sk, hk, d), h // hk, axis=2
                                ).reshape(b, sk, h * d) for x in (k, v))
@@ -554,28 +587,78 @@ class MultiHeadAttention(Op):
         # flash kernel's HBM traffic
         return q.astype(cd), k.astype(cd), v.astype(cd), None
 
-    def _rotated(self, q, k):
-        """Rotary on q [B, S, H, D] and k [B, S, Hk, D], under the scope
-        of its form: `rotary_whole` (every lane, plain frequencies: the
-        one form until PR 41, its arithmetic untouched) or
-        `rotary_partial_yarn`."""
-        if self.rotary_dim == self.head_dim and not self.rope_scaling:
-            return scoped("rotary_whole", lambda q, k: tuple(
-                rotary_embedding(t, theta=self.rope_theta, seq_axis=1,
-                                 wrap=self.rope_wrap) for t in (q, k)))(q, k)
-        if self.rope_wrap:
+    def _rotated(self, q, k, params, ctx: OpContext):
+        """The heads' norms (``qk_norm``) and rotary of q [B, S, H*D] and
+        k [B, S, Hk*D], float32 as the projections left them, under the
+        scope of the rotary's form: `rotary_whole` (every lane, plain
+        frequencies) or `rotary_partial_yarn`. Where the shapes allow
+        (``_rotates_in_lanes``) both are ONE pass of the kernel
+        `pallas_kernels.rotary_lanes` over the operands as they lie,
+        which leaves them in the compute dtype; else the norm, and the
+        rotation over a [B, S, H, D] view (`rotary_embedding` /
+        `rotary_partial`), in float32. The same float32 products and sum
+        either way, from the same tables."""
+        from flexflow_tpu.ops.pallas_kernels import rotary_lanes
+
+        d, r = self.head_dim, self.rotary_dim
+        whole = r == d and not self.rope_scaling
+        if self.rope_wrap and not whole:
             raise NotImplementedError(
                 f"attention '{self.name}': wrapped positions with partial "
                 f"or scaled rotary")
+        lanes = self._rotary_lane_dense = self._rotates_in_lanes(
+            ctx, q.shape[1], k.shape[1])
+        scales = ((params["q_norm"], params["k_norm"]) if self.qk_norm
+                  else (None, None))
+        if not lanes and self.qk_norm:
+            q, k = (self._heads_normed(t, t.shape[-1] // d, scale)
+                    for t, scale in zip((q, k), scales))
 
-        def rotate(q, k):
-            inv_freq, factor = rotary_frequencies(
-                self.rotary_dim, self.rope_theta, self.rope_scaling)
-            return tuple(rotary_partial(
-                t, inv_freq, rotary_dim=self.rotary_dim,
-                attention_factor=factor) for t in (q, k))
+        # keys that a repeat follows stay float32, as they are today: the
+        # repeat's backward adds a group's heads in float32
+        cd = ctx.compute_dtype
+        dtypes = (cd, cd if self.num_kv_heads == self.num_heads
+                  else jnp.float32)
 
-        return scoped("rotary_partial_yarn", rotate)(q, k)
+        def rotate(q, k, scales):
+            inv_freq, factor = rotary_frequencies(r, self.rope_theta,
+                                                  self.rope_scaling)
+            if lanes:
+                def tables(s):  # one head's; the sine has the partner's sign
+                    cos, sin = rotary_tables(s, d, inv_freq, factor,
+                                             wrap=self.rope_wrap)
+                    return cos, jnp.where(jnp.arange(d) < r // 2, -sin, sin)
+
+                of_length = {s: tables(s) for s in {q.shape[1], k.shape[1]}}
+                return tuple(rotary_lanes(
+                    t, *of_length[t.shape[1]], r // 2, dtype,
+                    norm=None if scale is None else (scale, self.qk_norm_eps))
+                    for t, scale, dtype in zip((q, k), scales, dtypes))
+            out = []
+            for t in (q, k):
+                b, s, width = t.shape
+                t = t.reshape(b, s, width // d, d)
+                t = (rotary_embedding(t, theta=self.rope_theta, seq_axis=1,
+                                      wrap=self.rope_wrap) if whole
+                     else rotary_partial(t, inv_freq, rotary_dim=r,
+                                         attention_factor=factor))
+                out.append(t.reshape(b, s, width))
+            return tuple(out)
+
+        return scoped("rotary_whole" if whole else "rotary_partial_yarn",
+                      rotate)(q, k, scales)
+
+    def _rotates_in_lanes(self, ctx: OpContext, sq: int, sk: int) -> bool:
+        """Whether this forward's norm and rotary run as the lane-dense
+        pass: Pallas on, a head that is one 128-lane column, whole row
+        blocks, one device (a bare kernel call has no partitioning)."""
+        from flexflow_tpu.ops.pallas_kernels import (
+            pallas_mode, rotary_lanes_shape_legal)
+
+        return (pallas_mode() != "off"
+                and rotary_lanes_shape_legal(sq, self.head_dim)
+                and rotary_lanes_shape_legal(sk, self.head_dim)
+                and (ctx.mesh is None or ctx.mesh.devices.size == 1))
 
     def _gated(self, w_gate, x, o, ctx: OpContext):
         """o [B, S, H*D] with head n's lanes times a_n = act(x w_gate)_n,

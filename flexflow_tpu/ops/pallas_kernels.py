@@ -52,6 +52,11 @@ and mask are float32. Tile sizes follow from the shape
 quoted beside them are the kernels alone on a v5e (PR 28), and PERF.md
 has what they gave through the benchmark's full step.
 
+Two kernels outside the attention core: `moe_sum_rows` (PR 37), the
+expert layer's sum of rows into their tokens, and `rotary_lanes` (PR
+42), the heads' RMS norm and rotary in one pass over a projection's
+[B, S, H*128] result, between the product and a flash kernel.
+
 CPU fallback: the same kernels run under ``interpret=True`` when
 FLEXFLOW_TPU_PALLAS=interpret (used by the deviceless tests); otherwise
 non-TPU backends take the XLA path.
@@ -1364,6 +1369,176 @@ def moe_sum_rows(x, token, weight, items, tokens: int, dtype,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(items["tile"], items["block"], items["count"], *operands, x)
+
+
+# ---------------------------------------------------------------------------
+# the per-head pass between a projection and a flash kernel (PR 42): the
+# heads' RMS norm and rotary on the projection's float32 [B, S, H*128]
+# result as it lies. Through a [B, S, H, D] view XLA tiles (H, D), and
+# every crossing between that and the [B, S, H*D] the products write and
+# the kernels take is a copy of a float32 array of S x H*D elements.
+
+ROTARY_ROWS = 512   # rows a block of `rotary_lanes`, where S allows
+ROTARY_HEADS = 4    # heads (128-lane columns) a block, where H allows
+
+
+def rotary_lanes_shape_legal(seq_len: int, head_dim: int) -> bool:
+    """What `rotary_lanes` takes: a head that is one 128-lane column and
+    whole blocks of rows (the flash kernels' tile)."""
+    return head_dim == LANES and seq_len > 0 and seq_len % BLK_Q == 0
+
+
+def _rotary_block(s: int, heads: int):
+    """(rows, heads) of a block: the most up to ROTARY_ROWS x
+    ROTARY_HEADS that divide the operand."""
+    rows = next(r for r in (ROTARY_ROWS, 256, BLK_Q) if s % r == 0)
+    return rows, next(h for h in range(ROTARY_HEADS, 0, -1)
+                      if heads % h == 0)
+
+
+def _partner(x, half: int):
+    """x [rows, 128], one head: lane j < half gets x[j + half], lane
+    half <= j < 2 half gets x[j - half] (the sign is the sine table's);
+    past the rotated lanes what comes meets a sine of 0."""
+    if 2 * half == LANES:
+        return pltpu.roll(x, half, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane < half, pltpu.roll(x, LANES - half, 1),
+                     pltpu.roll(x, half, 1))
+
+
+def _rotary_lanes_kernel(*refs, heads: int, half: int, transposed: bool,
+                         eps):
+    """One block of rows of ``heads`` heads. Forward: y = n cos +
+    partner(n) sin, n = x, or with ``eps`` the head's RMS norm x
+    rsqrt(mean(x^2) + eps) scale; float32, rounded once into the output.
+    ``transposed``: the backward. dn = g cos - partner(g) sin (the two
+    halves of a table are equal bit for bit and the sine's sign is the
+    partner's, so this is autodiff's sum term for term); with the norm
+    dx = r u - x r^3 mean(u x), u = dn scale, and the block's rows of
+    d scale = sum dn x r added into the one output block every step
+    revisits."""
+    normed = eps is not None
+    # a: what is rotated, x forward and the cotangent backward
+    if transposed and normed:
+        x_ref, a_ref, cos_ref, sin_ref, scale_ref, o_ref, dscale_ref = refs
+
+        @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0)
+                 & (pl.program_id(2) == 0))
+        def _():
+            dscale_ref[...] = jnp.zeros_like(dscale_ref)
+    elif normed:
+        a_ref, cos_ref, sin_ref, scale_ref, o_ref = refs
+    else:
+        a_ref, cos_ref, sin_ref, o_ref = refs
+    cos, sin = cos_ref[...], sin_ref[...]
+    for n in range(heads):
+        lanes = slice(n * LANES, (n + 1) * LANES)
+        a = a_ref[0, :, lanes].astype(jnp.float32)
+        if not transposed:
+            if normed:
+                a = a * jax.lax.rsqrt(jnp.mean(a * a, axis=-1, keepdims=True)
+                                      + eps) * scale_ref[...]
+            o_ref[0, :, lanes] = (a * cos + _partner(a, half) * sin
+                                  ).astype(o_ref.dtype)
+            continue
+        dn = a * cos - _partner(a, half) * sin
+        if normed:
+            x = x_ref[0, :, lanes]
+            r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+            u = dn * scale_ref[...]
+            dscale_ref[...] += (dn * x * r).reshape(
+                -1, 8, LANES).sum(axis=0)
+            dn = r * u - x * (r * r * r * jnp.mean(u * x, axis=-1,
+                                                   keepdims=True))
+        o_ref[0, :, lanes] = dn.astype(o_ref.dtype)
+
+
+def _rotary_lanes_call(operands, cos, sin, scale, half, eps, dtype,
+                       interpret, transposed, block=None):
+    """`_rotary_lanes_kernel` over ``operands`` (x, or x and g) [B, S,
+    H*128]: grid (batch, row block, column block), the column block last,
+    so that a row block's tables are fetched once. The operands allow
+    input fusion: the cast XLA would run ahead of the call as a pass of
+    its own (the flash backward's float32 dQ rounded to the cotangent's
+    bfloat16: 0.76 ms a 64-head op on the v5e) rides in the kernel's
+    fetch."""
+    b, s, width = operands[-1].shape
+    rows, heads = block or _rotary_block(s, width // LANES)
+    of_block = pl.BlockSpec((1, rows, heads * LANES),
+                            lambda i, r, c: (i, r, c))
+    table = pl.BlockSpec((rows, LANES), lambda i, r, c: (r, 0))
+    out_shape = [jax.ShapeDtypeStruct((b, s, width), dtype)]
+    out_specs = [of_block]
+    normed = eps is not None
+    if normed:
+        scale = (scale.astype(jnp.float32).reshape(1, LANES),)
+        if transposed:
+            out_shape.append(jax.ShapeDtypeStruct((8, LANES), jnp.float32))
+            out_specs.append(pl.BlockSpec((8, LANES), lambda i, r, c: (0, 0)))
+    else:
+        scale = ()
+    out = pl.pallas_call(
+        functools.partial(_rotary_lanes_kernel, heads=heads, half=half,
+                          transposed=transposed, eps=eps),
+        name="rotary_lanes_bwd" if transposed else "rotary_lanes",
+        out_shape=out_shape,
+        grid=(b, s // rows, width // (heads * LANES)),
+        in_specs=[of_block] * len(operands) + [table, table] + [
+            pl.BlockSpec((1, LANES), lambda i, r, c: (0, 0))] * len(scale),
+        out_specs=out_specs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(("arbitrary",) if transposed and normed
+                                 else ("parallel",)) * 3,
+            allow_input_fusion=[True] * len(operands)
+            + [False] * (2 + len(scale))),
+        interpret=interpret,
+    )(*operands, cos, sin, *scale)
+    return out if transposed and normed else out[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _rotary_lanes(x, cos, sin, scale, half, eps, dtype, interpret):
+    return _rotary_lanes_fwd(x, cos, sin, scale, half, eps, dtype,
+                             interpret)[0]
+
+
+def _rotary_lanes_fwd(x, cos, sin, scale, half, eps, dtype, interpret):
+    y = _rotary_lanes_call((x,), cos, sin, scale, half, eps, dtype,
+                           interpret, False)
+    # the plain rotation's backward reads the cotangent alone
+    return y, (x if eps is not None else None, cos, sin, scale)
+
+
+def _rotary_lanes_bwd(half, eps, dtype, interpret, res, g):
+    x, cos, sin, scale = res
+    back = _rotary_lanes_call((g,) if x is None else (x, g), cos, sin, scale,
+                              half, eps, jnp.float32, interpret, True)
+    if x is None:
+        return back, None, None, None
+    dx, dscale = back
+    return dx, None, None, dscale.sum(axis=0).astype(scale.dtype)
+
+
+_rotary_lanes.defvjp(_rotary_lanes_fwd, _rotary_lanes_bwd)
+
+
+def rotary_lanes(x, cos, sin, half: int, dtype, norm=None):
+    """The heads' RMS norm (``norm`` = (scale [128], eps), or None) and
+    rotary of x [B, S, H*128] float32, every head one 128-lane column as
+    a projection's product leaves it -> the same shape in ``dtype``, what
+    a flash kernel takes: one pass, float32 inside, rounded once, no
+    [B, S, H, D] view. ``cos``, ``sin`` [S, 128] float32: a row's tables
+    for one head, the sine with the partner's sign (minus on the first
+    ``half`` lanes), 1 and 0 on the lanes a partial rotary leaves alone;
+    positions, wrapping and YaRN's factor are the tables'. Its own
+    backward is the same kernel transposed: dx in float32 from the
+    cotangent as it comes (and x under the norm), d scale summed over
+    the rows in the kernel; no table has a gradient. Caller checks
+    `rotary_lanes_shape_legal` and `pallas_mode` first."""
+    scale, eps = norm or (None, None)
+    return _rotary_lanes(x, cos, sin, scale, half, eps, dtype,
+                         pallas_mode() == "interpret")
 
 
 def pallas_mode() -> str:

@@ -275,7 +275,7 @@ def timed_ms(fn, args, n=20):
     return (time.perf_counter() - t) / n * 1e3
 
 
-def device_ms(jitted, calls=5):
+def device_ms(jitted, calls=5, stems=6):
     """name -> (function, arguments): every piece `calls` times under the
     profiler -> name -> (median device ms of its program, ms a call of
     its ops by stem, largest first). A call's wall time has a floor, what
@@ -308,7 +308,7 @@ def device_ms(jitted, calls=5):
                     tr.ENCLOSING):
                 ops[tr.stem(n)] += d / len(runs) * 1e3
         out[name] = (statistics.median(b - a for a, b in runs) * 1e3,
-                     {k: round(v, 4) for k, v in ops.most_common(6)})
+                     {k: round(v, 4) for k, v in ops.most_common(stems)})
     return out
 
 

@@ -469,6 +469,131 @@ class TestHeadAndLoss:
         assert f32 and scatters, (f32, scatters)
 
 
+class TestRotaryLanes:
+    """One attention op, forward + backward, at two decoder cells' shapes
+    (PR 42): with the heads' norm and rotary as the lane-dense pass
+    (`pallas_kernels.rotary_lanes`) the optimized program writes no
+    float32 array of S x H x 128 elements between a projection's product
+    and a flash kernel but the product's own result (the pass's operand)
+    and the pass's, and copies no float32 `[.., H, 128]` array there. The
+    same op steered to the `[B, S, H, D]` view, the shipped form until PR
+    42, holds them; what is left with the pass is the K/V repeat's
+    backward (dK and dV of the repeated heads as float32 through a 4-D
+    view: a convert and a copy each), the next thing to go."""
+    YARN = dict(rope_type="yarn", factor=64, beta_fast=64, beta_slow=1,
+                original_max_position_embeddings=4096,
+                attention_factor=1.4158883083359672)
+    # seq, width, the op's properties; XLA's passes over an S x H x 128
+    # float32 array with the pass (the repeat's backward) and on the view
+    OPS = {
+        "laguna_window_64_8": (8192, 2048, dict(
+            num_heads=64, num_kv_heads=8, causal=True, window=512,
+            gate=True), 4, 12),
+        "laguna_full_48_8_partial_yarn": (8192, 2048, dict(
+            num_heads=48, num_kv_heads=8, causal=True, gate=True,
+            rope_theta=500000.0, partial_rotary_factor=0.5,
+            rope_scaling=YARN), 2, 11),
+        "sdar_8_1_normed": (16384, 2048, dict(
+            num_heads=8, num_kv_heads=1, block_diffusion=(8192, 4),
+            rope_wrap=8192, qk_norm=True, rope_theta=1000000.0), 4, 17),
+    }
+
+    def _hlo(self, topo, seq, hidden, props, lanes):
+        from flexflow_tpu.ffconst import OperatorType
+        from flexflow_tpu.layer import Layer
+        from flexflow_tpu.ops.base import OpContext, OpRegistry
+
+        layer = Layer(OperatorType.MULTIHEAD_ATTENTION, "op", [])
+        layer.properties.update(dict(props, embed_dim=hidden, head_dim=128,
+                                     bias=False, rope=True))
+        op = OpRegistry.create(layer, [(1, seq, hidden)] * 3)
+        if not lanes:
+            op._rotates_in_lanes = lambda *a: False
+        one = SingleDeviceSharding(topo.devices[0])
+
+        def step(params, x, g):
+            ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
+            return jax.value_and_grad(lambda p, x: jnp.sum(
+                op.forward(p, [x], ctx)[0].astype(jnp.float32) * g),
+                argnums=(0, 1))(params, x)
+
+        x = jax.ShapeDtypeStruct((1, seq, hidden), jnp.bfloat16, sharding=one)
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            jax.eval_shape(op.init_params, jax.random.PRNGKey(0)))
+        hlo = _compile(step, params, x, x)
+        assert op._rotary_lane_dense == lanes
+        return hlo
+
+    @staticmethod
+    def passes_over(hlo, elements):
+        """(opcode, name, result, op_name) of the instructions outside a
+        fusion's body that write a float32 array of ``elements`` elements
+        and are neither a kernel nor a product: XLA's own passes over
+        it."""
+        from flexflow_tpu.obs.inspect import (_INSTRUCTION,
+                                              arrays_between_fusions)
+        names = set(arrays_between_fusions(hlo, "f32", elements))
+        out = []
+        for line in hlo.splitlines():
+            m = _INSTRUCTION.match(line)
+            if not m or m.group(1) not in names:
+                continue
+            name, result, opcode = m.groups()
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            if opcode in ("custom-call", "get-tuple-element", "bitcast",
+                          "tuple") or opcode.endswith(("-start", "-done")):
+                continue
+            # a product, or a kernel with the cast of its operand fused
+            # into its fetch (`allow_input_fusion`)
+            if opcode == "fusion" and op_name and op_name.group(
+                    1).endswith(("dot_general", "pallas_call")):
+                continue
+            out.append((opcode, name, result,
+                        op_name.group(1) if op_name else ""))
+        return out
+
+    @pytest.mark.parametrize("kind", list(OPS))
+    def test_no_float32_relayout_between_projection_and_flash(
+            self, topo, on_tpu, kind):
+        seq, hidden, props, with_the_pass, on_the_view = self.OPS[kind]
+        heads = props["num_heads"]
+        left = self.passes_over(self._hlo(topo, seq, hidden, props, True),
+                                seq * heads * 128)
+        # the repeat's backward alone: copies (and converts) of dK and dV
+        assert len(left) == with_the_pass, left
+        assert all(o in ("copy", "fusion") and "rotary" not in scope
+                   for o, _, _, scope in left), left
+        view = self.passes_over(self._hlo(topo, seq, hidden, props, False),
+                                seq * heads * 128)
+        # what this PR took out of the op
+        assert len(view) == on_the_view, view
+        assert sum("jit(rotary_" in scope for _, _, _, scope in view) >= 5
+
+    def test_the_kernels_scopes(self, topo, on_tpu):
+        """The pass's calls sit under the rotary scope inside the
+        attention op's, forward and backward, so the share metrics count
+        them with the op; they are under no `flash_*` scope (the flash
+        rooflines divide by those events) and no top-level
+        `tpu_custom_call*` (what `kernels.flash_roofline` sums). In the
+        benchmark's step on the chip their events read `rotary_whole.N`
+        / `rotary_partial_yarn.N` (my chip runs, PR 42); compiled here
+        the instructions keep the kernels' names."""
+        _, hidden, props, _, _ = self.OPS["laguna_window_64_8"]
+        hlo = self._hlo(topo, 1024, hidden, props, True)
+        calls = [line for line in hlo.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line
+                 and "rotary_lanes" in line.split("metadata=")[-1][:400]]
+        assert len(calls) == 4      # q and k, forward and backward
+        for line in calls:
+            name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) =", line).group(1)
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert not name.startswith(("tpu_custom_call", "flash")), name
+            assert "jit(attention_window)" in op_name
+            assert "jit(rotary_whole)/rotary_lanes" in op_name
+            assert "flash" not in op_name
+
+
 # ---------------------------------------------------------------------------
 # whole train steps
 

@@ -396,6 +396,54 @@ def test_flash_lane_dense_ops_counts_the_models_flash_ops(
     assert gauges["executor.flash_lane_dense_ops"] == flash
 
 
+@pytest.mark.parametrize("lane_layers,view_layers,plain_layers",
+                         [(2, 1, 1), (1, 0, 0), (0, 1, 1)])
+def test_rotary_lane_dense_ops_counts_the_ops_the_pass_took(
+        lane_layers, view_layers, plain_layers, tmp_path, monkeypatch,
+        no_open_session):
+    """`executor.rotary_lane_dense_ops` (PR 42): attention ops whose
+    forward ran the heads' norm and rotary as the lane-dense pass
+    (`pallas_kernels.rotary_lanes`). A rotary op with heads of 128 takes
+    it; one with heads of 32 keeps the [B, S, H, D] view; an op without
+    rotary has nothing to pass. In the registry's snapshot, the trace
+    header and `FFModel.op_counters`, beside `flash_lane_dense_ops`."""
+    import numpy as np
+    from flexflow_tpu import (FFConfig, FFModel, LossType, MetricsType,
+                              SGDOptimizer)
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    b, s, e = 1, 128, 32        # one device: a bare kernel call
+    ff = FFModel(FFConfig(batch_size=b))
+    t = ff.create_tensor((b, s, e))
+    for i in range(lane_layers):
+        t = ff.multihead_attention(t, t, t, e, 2, head_dim=128, rope=True,
+                                   causal=True, name=f"lanes{i}")
+    for i in range(view_layers):
+        t = ff.multihead_attention(t, t, t, e, 2, head_dim=32, rope=True,
+                                   causal=True, name=f"view{i}")
+    for i in range(plain_layers):
+        t = ff.multihead_attention(t, t, t, e, 2, head_dim=128,
+                                   name=f"plain{i}")
+    ff.dense(t, 1)
+    ff.compile(SGDOptimizer(lr=0.01), LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
+               [MetricsType.MEAN_SQUARED_ERROR])
+    assert obs.model_context(ff)["rotary_lane_dense_ops"] == 0  # not traced
+    rs = np.random.RandomState(0)
+    x = rs.randn(2 * b, s, e).astype(np.float32)
+    y = rs.randn(2 * b, s, 1).astype(np.float32)
+    ff.fit(x, y, epochs=1, verbose=False)   # traces and compiles the step
+    obs.start_trace(str(tmp_path), device=False)
+    ff.fit(x, y, epochs=1, verbose=False)
+    paths = obs.stop_trace()
+    header, _ = read_events(paths["events"])
+    assert header["rotary_lane_dense_ops"] == lane_layers
+    assert header["flash_lane_dense_ops"] == (lane_layers + view_layers
+                                              + plain_layers)
+    gauges = json.load(open(paths["counters"]))["gauges"]
+    assert gauges["executor.rotary_lane_dense_ops"] == lane_layers
+    assert ff.executor.rotary_lane_dense_ops() == lane_layers
+
+
 @pytest.mark.parametrize("moe_layers", [2, 1, 0])
 def test_moe_gather_combine_ops_counts_the_models_expert_layers(
         moe_layers, tmp_path, no_open_session):
