@@ -756,6 +756,15 @@ class GraphExecutor:
         return sum(bool(getattr(n.op, "_rotary_lane_dense", False))
                    for n in self.nodes)
 
+    def flash_grouped_kv_ops(self) -> int:
+        """Attention ops whose forward, as last traced, handed the flash
+        kernels the [B, S, Hk*D] keys and values of fewer KV heads than
+        query heads, not repeated (ops/attention.py `_takes_grouped_kv`
+        sets the flag; PR 43): the gauge `executor.flash_grouped_kv_ops`,
+        and `flash_grouped_kv_ops` in every trace header."""
+        return sum(bool(getattr(n.op, "_flash_grouped_kv", False))
+                   for n in self.nodes)
+
     def moe_gather_combine_ops(self) -> int:
         """`MoELayer` ops whose forward, as last traced, sent rows to the
         experts and brought them back to their tokens by gathers through
@@ -888,6 +897,8 @@ class GraphExecutor:
                                  self.flash_lane_dense_ops())
             get_registry().gauge("executor.rotary_lane_dense_ops",
                                  self.rotary_lane_dense_ops())
+            get_registry().gauge("executor.flash_grouped_kv_ops",
+                                 self.flash_grouped_kv_ops())
             for gauge, value in self.attention_gauges().items():
                 get_registry().gauge(gauge, value)
             get_registry().gauge("executor.moe_gather_combine_ops",

@@ -1481,6 +1481,8 @@ class FFModel:
             self.executor.flash_lane_dense_ops())
         self.op_counters["executor.rotary_lane_dense_ops"] = float(
             self.executor.rotary_lane_dense_ops())
+        self.op_counters["executor.flash_grouped_kv_ops"] = float(
+            self.executor.flash_grouped_kv_ops())
         self.op_counters.update(
             (k, float(v)) for k, v in self.executor.attention_gauges().items())
         self.op_counters["executor.moe_gather_combine_ops"] = float(

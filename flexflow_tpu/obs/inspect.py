@@ -360,6 +360,9 @@ def model_context(ff) -> Dict[str, Any]:
         # of the rotary ops, those whose heads' norm and rotary ran as
         # the one lane-dense pass
         rotary_lane_dense_ops=ff.executor.rotary_lane_dense_ops(),
+        # of the grouped-query ops, those whose flash kernels read K and
+        # V at the KV heads, not repeated
+        flash_grouped_kv_ops=ff.executor.flash_grouped_kv_ops(),
         # windowed attention ops, those under the block-diffusion mask,
         # and the flash forwards' K blocks visited against the whole
         # square's, and of them those masked

@@ -12,7 +12,9 @@ output projection (PR 30): the form the Pallas flash-attention kernels
 and a kernel. The einsum core and ring attention, which want
 [B, H, S, D], convert at their own boundary; XLA fuses that. Rotary and
 the per-head norm before it keep to that form too where a head fills the
-128 lanes (PR 42: `MultiHeadAttention._rotated`).
+128 lanes (PR 42: `MultiHeadAttention._rotated`), and there the keys and
+values of grouped-query attention reach the flash kernels at their own
+[B, S, Hk*D], never repeated (PR 43: `_takes_grouped_kv`).
 """
 
 from __future__ import annotations
@@ -197,6 +199,12 @@ def gate_lanes(a, head_dim: int):
                    precision=jax.lax.Precision.HIGHEST)
 
 
+def _mesh_axes(ctx: OpContext) -> dict:
+    """{axis name: size} of the context's mesh ({} without one)."""
+    return (dict(zip(ctx.mesh.axis_names, ctx.mesh.devices.shape))
+            if ctx.mesh is not None else {})
+
+
 def rms_normed(x, scale, eps):
     """RMS norm over the minor axis in float32, with a learned scale."""
     x = x.astype(jnp.float32)
@@ -328,7 +336,9 @@ class MultiHeadAttention(Op):
                                     self.window, self.block_diffusion)
         self.use_bias = p.get("bias", True)
         # grouped-query attention (Llama-family): kv heads may be fewer
-        # than query heads; kv repeat to H before the core
+        # than query heads; the flash kernels read a group's K and V as
+        # they are where `_takes_grouped_kv` says so, every other core
+        # gets them repeated to H (`_qkv`)
         self.num_kv_heads = p.get("num_kv_heads") or self.num_heads
         if self.num_heads % self.num_kv_heads:
             raise ValueError(
@@ -407,6 +417,10 @@ class MultiHeadAttention(Op):
         # set when a forward ran the heads' norm and rotary as the
         # lane-dense pass (counted by `executor.rotary_lane_dense_ops`)
         self._rotary_lane_dense = False
+        # set when a forward handed the flash kernels the [B, S, Hk*D]
+        # keys and values of fewer KV heads than query heads, not
+        # repeated (counted by `executor.flash_grouped_kv_ops`)
+        self._flash_grouped_kv = False
         # (visited, total, masked) K blocks of the flash forward as
         # traced, a head (`attention/kv_blocks_*`); None until a forward
         # ran flash
@@ -560,8 +574,14 @@ class MultiHeadAttention(Op):
             params, o, ctx, inputs[0].dtype))(params, o)]
 
     def _qkv(self, params, inputs, ctx: OpContext):
-        """q, k, v [B, S, heads*head_dim] in the compute dtype: the
-        projections, the heads' norms, rotary and the K/V repeat."""
+        """q [B, S, H*D] in the compute dtype and k, v: the projections,
+        the heads' norms and rotary. Under grouped-query attention
+        (Hk < H) k and v are repeated to [B, S, H*D] here, for every
+        core that wants whole heads; where the core is the flash kernels
+        and they take a group's keys as they are (``_takes_grouped_kv``,
+        PR 43) k and v stay [B, S, Hk*D], in float32: the kernels round
+        what they read, and the groups' dK and dV come back as float32
+        sums, as the repeat's backward gave them."""
         query, key, value = (inputs + inputs[:1] * 2)[:3] if len(inputs) == 1 else inputs
         cd = ctx.compute_dtype
         h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
@@ -578,6 +598,10 @@ class MultiHeadAttention(Op):
         elif self.qk_norm:
             q = self._heads_normed(q, h, params["q_norm"])
             k = self._heads_normed(k, hk, params["k_norm"])
+        self._flash_grouped_kv = self._takes_grouped_kv(
+            ctx, q.shape[0], q.shape[1], sk)
+        if self._flash_grouped_kv:
+            return q.astype(cd), k, v, None
         if hk != h:
             k, v = (jnp.repeat(x.reshape(b, sk, hk, d), h // hk, axis=2
                                ).reshape(b, sk, h * d) for x in (k, v))
@@ -614,8 +638,9 @@ class MultiHeadAttention(Op):
             q, k = (self._heads_normed(t, t.shape[-1] // d, scale)
                     for t, scale in zip((q, k), scales))
 
-        # keys that a repeat follows stay float32, as they are today: the
-        # repeat's backward adds a group's heads in float32
+        # a group's keys stay float32: dK of the group arrives float32,
+        # from the repeat's backward or, under grouped flash keys, from
+        # the kernels (which read the keys rounded either way)
         cd = ctx.compute_dtype
         dtypes = (cd, cd if self.num_kv_heads == self.num_heads
                   else jnp.float32)
@@ -659,6 +684,69 @@ class MultiHeadAttention(Op):
                 and rotary_lanes_shape_legal(sq, self.head_dim)
                 and rotary_lanes_shape_legal(sk, self.head_dim)
                 and (ctx.mesh is None or ctx.mesh.devices.size == 1))
+
+    def _runs_flash(self, ctx: OpContext, sq: int, sk: int) -> bool:
+        """Whether this forward's core is the flash kernels: no ring, no
+        pinned einsum, no dropout on the probabilities, self-attention,
+        and a shape and platform the kernels take."""
+        from flexflow_tpu.ops.pallas_kernels import flash_attention_available
+
+        mesh_axes = _mesh_axes(ctx)
+        ring = (self.seq_parallel and sq == sk
+                and mesh_axes.get(self.seq_parallel, 1) > 1)
+        return (not ring and self.kernel_impl != "einsum"
+                and not (self.dropout and ctx.training) and sq == sk
+                and flash_attention_available(sq, self.head_dim,
+                                              self.num_heads, self.rope_dim))
+
+    def _shard_axes(self, ctx: OpContext, b: int, sq: int):
+        """(batch_axis, head_axis) of the `shard_map` the flash kernels
+        run under on a mesh of several devices, else None (a bare kernel
+        call): the batch axes (possibly the joint ('data','model')
+        sample2 partition) and, when the search picked a head choice,
+        the head axis, where a shard's heads still tile the lanes."""
+        from flexflow_tpu.ops.pallas_kernels import flash_shape_legal
+
+        mesh_axes = _mesh_axes(ctx)
+        if not any(n > 1 for n in mesh_axes.values()):
+            return None
+        h, d = self.num_heads, self.head_dim
+        bp = getattr(self, "batch_parallel", None) or "data"
+        bp = bp if isinstance(bp, tuple) else (bp,)
+        bp = tuple(a for a in bp if mesh_axes.get(a, 1) > 1)
+        bsz = int(np.prod([mesh_axes[a] for a in bp])) if bp else 1
+        batch_axis = (bp if bp and b % bsz == 0 else None)
+        if batch_axis is not None and len(batch_axis) == 1:
+            batch_axis = batch_axis[0]
+        hp = self.head_parallel
+        in_batch = batch_axis if isinstance(batch_axis, tuple) \
+            else (batch_axis,)
+        head_axis = (hp if hp and hp not in in_batch
+                     and not self.latent    # one key for all heads
+                     and mesh_axes.get(hp, 1) > 1
+                     and h % mesh_axes[hp] == 0
+                     and flash_shape_legal(sq, d, h // mesh_axes[hp])
+                     else None)
+        return batch_axis, head_axis
+
+    def _takes_grouped_kv(self, ctx: OpContext, b: int, sq: int,
+                          sk: int) -> bool:
+        """Whether this forward hands the flash kernels K and V at the
+        KV heads, [B, S, Hk*D], not repeated (PR 43): the core is the
+        flash kernels, a head is one 128-lane column block
+        (`pallas_kernels.grouped_kv_shape_legal`), and a head axis of
+        the mesh leaves every shard whole groups. Everything else (a
+        head of 64, ring attention, the einsum core, a head axis that
+        would split a group) repeats K and V in ``_qkv``."""
+        from flexflow_tpu.ops.pallas_kernels import grouped_kv_shape_legal
+
+        if not (grouped_kv_shape_legal(self.num_heads, self.num_kv_heads,
+                                       self.head_dim)
+                and self._runs_flash(ctx, sq, sk)):
+            return False
+        axes = self._shard_axes(ctx, b, sq)
+        return (axes is None or axes[1] is None
+                or self.num_kv_heads % _mesh_axes(ctx)[axes[1]] == 0)
 
     def _gated(self, w_gate, x, o, ctx: OpContext):
         """o [B, S, H*D] with head n's lanes times a_n = act(x w_gate)_n,
@@ -741,8 +829,7 @@ class MultiHeadAttention(Op):
             return around(whole)(q, k, v, rope)
 
         seq_axis = self.seq_parallel
-        mesh_axes = (dict(zip(ctx.mesh.axis_names, ctx.mesh.devices.shape))
-                     if ctx.mesh is not None else {})
+        mesh_axes = _mesh_axes(ctx)
         if seq_axis and mesh_axes.get(seq_axis, 1) > 1 and sq == sk:
             if self.windowed or self.block_diffusion or self.latent:
                 raise NotImplementedError(
@@ -767,81 +854,57 @@ class MultiHeadAttention(Op):
             return heads_first(lambda q, k, v: ring_attention(
                 q, k, v, ctx.mesh, seq_axis=seq_axis,
                 head_axis=self.head_parallel, causal=self.causal))
-        if (self.kernel_impl != "einsum"
-                and dropout_rate == 0.0 and sq == sk):
+        if self._runs_flash(ctx, sq, sk):
             from flexflow_tpu.ops.pallas_kernels import (
-                flash_attention, flash_attention_available,
-                flash_attention_sharded, flash_shape_legal, kv_blocks,
+                flash_attention, flash_attention_sharded, kv_blocks,
                 kv_blocks_masked, visible_pairs, visited_pairs)
 
-            available = flash_attention_available(sq, d, h, rope_dim)
-            if self.kernel_impl == "flash" and not available:
-                # the search chose flash but this platform/shape cannot
-                # run it: record the silent fallback for fflint FFL209
+            # for `executor.flash_lane_dense_ops`
+            self._flash_lane_dense = True
+            kind = (sq, self.causal, self.window, self.block_diffusion)
+            self._kv_blocks = (*kv_blocks(*kind), kv_blocks_masked(*kind))
+            if self.windowed:
+                self._window_pairs = (
+                    visited_pairs(*kind),
+                    2 * visible_pairs(sq, self.causal, self.window))
+
+            def flash(kernel, **where):
+                if self._flash_grouped_kv:   # k, v are [B, S, Hk*D]
+                    where["num_kv_heads"] = self.num_kv_heads
+                call = functools.partial(
+                    kernel, num_heads=h, causal=self.causal,
+                    window=self.window,
+                    block_diffusion=self.block_diffusion, **where)
+                if rope is not None:
+                    return scoped(flash_scope, lambda q, k, v, rope: call(
+                        q, k, v, rope=rope))(q, k, v, rope)
+                return (scoped(flash_scope, call) if flash_scope
+                        else call)(q, k, v)
+
+            axes = self._shard_axes(ctx, b, sq)
+            if axes is not None:
+                # non-trivial mesh: the raw pallas_call would be an
+                # unpartitionable custom call under GSPMD — run it
+                # per-shard via shard_map (`_shard_axes`)
+                return flash(flash_attention_sharded, mesh=ctx.mesh,
+                             batch_axis=axes[0], head_axis=axes[1])
+            return flash(flash_attention)
+        if self.kernel_impl == "flash":
+            # the search chose flash and the einsum core runs: record
+            # the silent fallback so fflint FFL209 surfaces the
+            # priced-vs-executed gap
+            if dropout_rate == 0.0 and sq == sk:
+                # this platform/shape cannot run the kernels
                 self._kernel_fallback = (
                     f"flash unavailable at runtime (seq={sq}, "
                     f"head_dim={d}, heads={h}) — einsum executed instead")
-            if available:
-                # for `executor.flash_lane_dense_ops`
-                self._flash_lane_dense = True
-                kind = (sq, self.causal, self.window, self.block_diffusion)
-                self._kv_blocks = (*kv_blocks(*kind), kv_blocks_masked(*kind))
-                if self.windowed:
-                    self._window_pairs = (
-                        visited_pairs(*kind),
-                        2 * visible_pairs(sq, self.causal, self.window))
-
-                def flash(kernel, **where):
-                    call = functools.partial(
-                        kernel, num_heads=h, causal=self.causal,
-                        window=self.window,
-                        block_diffusion=self.block_diffusion, **where)
-                    if rope is not None:
-                        return scoped(flash_scope, lambda q, k, v, rope: call(
-                            q, k, v, rope=rope))(q, k, v, rope)
-                    return (scoped(flash_scope, call) if flash_scope
-                            else call)(q, k, v)
-
-                if any(s > 1 for s in mesh_axes.values()):
-                    # non-trivial mesh: the raw pallas_call would be an
-                    # unpartitionable custom call under GSPMD — run it
-                    # per-shard via shard_map over the batch axes (possibly
-                    # the joint ('data','model') sample2 partition) and,
-                    # when the search picked a head choice, the head axis
-                    bp = getattr(self, "batch_parallel", None) or "data"
-                    bp = bp if isinstance(bp, tuple) else (bp,)
-                    bp = tuple(a for a in bp if mesh_axes.get(a, 1) > 1)
-                    bsz = int(np.prod([mesh_axes[a] for a in bp])) if bp else 1
-                    batch_axis = (bp if bp and b % bsz == 0 else None)
-                    if batch_axis is not None and len(batch_axis) == 1:
-                        batch_axis = batch_axis[0]
-                    hp = self.head_parallel
-                    in_batch = batch_axis if isinstance(batch_axis, tuple) \
-                        else (batch_axis,)
-                    # a shard's heads still have to tile the lanes
-                    head_axis = (hp if hp and hp not in in_batch
-                                 and rope is None   # one key for all heads
-                                 and mesh_axes.get(hp, 1) > 1
-                                 and h % mesh_axes[hp] == 0
-                                 and flash_shape_legal(
-                                     sq, d, h // mesh_axes[hp])
-                                 else None)
-                    return flash(flash_attention_sharded, mesh=ctx.mesh,
-                                 batch_axis=batch_axis, head_axis=head_axis)
-                return flash(flash_attention)
-            return heads_first(lambda q, k, v: scaled_dot_product_attention(
-                q, k, v, causal=self.causal, dropout_rate=0.0,
-                rng=None, compute_dtype=cd, window=self.window,
-                block_diffusion=self.block_diffusion))
-        if self.kernel_impl == "flash" and self._kernel_fallback is None:
-            # forced flash but this forward cannot take the flash
-            # branch at all (attention-prob dropout in training, or
-            # cross-attention) — record the silent fallback so
-            # fflint FFL209 surfaces the priced-vs-executed gap
-            self._kernel_fallback = (
-                f"flash has no lowering for this forward "
-                f"(dropout_rate={dropout_rate}, Sq={sq}, "
-                f"Sk={sk}) — einsum executed instead")
+            elif self._kernel_fallback is None:
+                # this forward cannot take the flash branch at all
+                # (attention-prob dropout in training, or cross-attention)
+                self._kernel_fallback = (
+                    f"flash has no lowering for this forward "
+                    f"(dropout_rate={dropout_rate}, Sq={sq}, "
+                    f"Sk={sk}) — einsum executed instead")
         return heads_first(lambda q, k, v: scaled_dot_product_attention(
             q, k, v, causal=self.causal, dropout_rate=dropout_rate,
             rng=rng, compute_dtype=cd, window=self.window,

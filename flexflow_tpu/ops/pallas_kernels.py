@@ -43,7 +43,12 @@ projection and a kernel, and no array the kernels touch pads a 64-wide
 minor dimension to 128 lanes in HBM. Inside, a block's K^T, V^T, O^T,
 dK^T ... are [128, S] tiles whose heads are sublane ranges; where one
 head's lanes are wanted out of a [rows, 128] operand the others are
-zeroed (``_only_head``) and the MXU contracts all 128.
+zeroed (``_only_head``) and the MXU contracts all 128. Under
+grouped-query attention with heads of 128 (PR 43) k and v are
+[B, S, Hk*D], the KV heads alone: a K / V BlockSpec's last index is the
+query column block's group, ``j // rep``, and the backward adds a
+group's dK and dV up in the KV head's float32 panel; no array holds a
+K or V head once for each of its query heads.
 
 Every MXU product takes its operands in the dtype the caller stored and
 accumulates in float32 (``_dot``); softmax statistics, ``exp``, scale
@@ -780,13 +785,32 @@ def _rope_operands(rope, num_heads: int, head_dim: int):
     return rope_dim, per, (q_rope, jnp.concatenate([k_rope] * per, axis=-1))
 
 
+def _group_size(num_heads: int, num_kv_heads, head_dim: int,
+                rope=None) -> int:
+    """Query heads that share a K/V head in the operands the kernels are
+    handed: 1 where k and v hold every head (``num_kv_heads`` None or
+    the heads), else the group's size, which ``grouped_kv_shape_legal``
+    has admitted."""
+    rep = num_heads // (num_kv_heads or num_heads)
+    assert rep == 1 or (rope is None and grouped_kv_shape_legal(
+        num_heads, num_kv_heads, head_dim)), (num_heads, num_kv_heads)
+    return rep
+
+
 def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
                out_dtype=None, window: int = 0, block_diffusion=None,
-               rope=None):
+               rope=None, num_kv_heads=None):
     """q, k, v: [B, S, H*D] with S % BLK_Q == 0 -> (o [B, S, H*D],
     lse [B, H, 1, S]). A block is S (or BLK_Q) rows by one column block
     of the operand, picked by the BlockSpec's last index: in HBM's
     (8, 128) tiles that is a run of whole tiles, no lane of it padding.
+
+    With ``num_kv_heads`` < H (PR 43) k and v are [B, S, Hk*D], as their
+    projections leave them, and the K / V BlockSpecs' last index is the
+    query column block's group, ``j // rep``: consecutive heads of a
+    group name the same block, which Pallas does not fetch again. The
+    kernels' bodies are the same: the same K and V values meet the same
+    Q block, so o and lse are those of the repeated keys bit for bit.
 
     ``rope`` = (q_rope [B, S, H*R], k_rope [B, S, R]): the score of a
     head is q k^T + q_rope k_rope^T over sqrt(D + R), the rotated key
@@ -799,6 +823,7 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     hpb = _heads_per_block(num_heads, d)
     w = hpb * d
     rope_dim, per, rope_ops = _rope_operands(rope, num_heads, d)
+    rep = _group_size(num_heads, num_kv_heads, d, rope)
     scale = 1.0 / float(d + rope_dim) ** 0.5
     window = normalized_window(s, causal, window)
     bd = checked_block_diffusion(s, causal, window, block_diffusion)
@@ -810,6 +835,8 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     if s <= MAX_BWD_SEQ:
         rows = _rows_per_step(b, hpb, s)
         seq_spec = pl.BlockSpec((rows, s, w), lambda i, j: (i, 0, j))
+        kv_spec = seq_spec if rep == 1 else pl.BlockSpec(
+            (rows, s, w), lambda i, j: (i, 0, j // rep))
         rope_specs = [
             pl.BlockSpec((rows, s, LANES), lambda i, j: (i, 0, j // per)),
             pl.BlockSpec((rows, s, LANES), lambda i, j: (i, 0, 0)),
@@ -822,7 +849,7 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
             name=KERNEL_NAME_PREFIX + "flash_fwd_whole",
             out_shape=out_shape,
             grid=(b // rows, num_heads // hpb),
-            in_specs=[seq_spec, seq_spec, seq_spec] + rope_specs,
+            in_specs=[seq_spec, kv_spec, kv_spec] + rope_specs,
             out_specs=(seq_spec,
                        pl.BlockSpec((rows, hpb, 1, s),
                                     lambda i, j: (i, j, 0, 0))),
@@ -844,9 +871,9 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
         grid=(b, num_heads // hpb, s // blk),
         in_specs=[
             pl.BlockSpec((1, blk, w), lambda b, j, i: (b, i, j)),
-            pl.BlockSpec((1, s, w), lambda b, j, i: (b, 0, j)),
-            pl.BlockSpec((1, s, w), lambda b, j, i: (b, 0, j)),
-        ] + rope_specs,
+        ] + [pl.BlockSpec((1, s, w), (lambda b, j, i: (b, 0, j)) if rep == 1
+                          else (lambda b, j, i: (b, 0, j // rep)))] * 2
+        + rope_specs,
         out_specs=(pl.BlockSpec((1, blk, w), lambda b, j, i: (b, i, j)),
                    pl.BlockSpec((1, hpb, 1, blk),
                                 lambda b, j, i: (b, j, 0, i))),
@@ -917,16 +944,32 @@ def _flash_bwd_tile(q, k, kt, v, o, do, lse, glse, scale: float, mask,
     return _stack_heads(dqt), _stack_heads(dkt), _stack_heads(dvt)
 
 
+def _group_sum(ref, at, value, grouped: bool, member):
+    """``ref[at] = value``, or under grouped keys the sum over a group:
+    dK's and dV's blocks are the KV head's, float32, resident while the
+    grid runs through the group's heads; the first (``member`` 0) writes
+    and the others add. A select, not a branch: what the block holds
+    before the first head wrote is never the result."""
+    if not grouped:
+        ref[at] = value.astype(ref.dtype)
+    else:
+        ref[at] = jnp.where(member == 0, value, ref[at] + value)
+
+
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       glse_ref, *rest, causal: bool, window: int,
                       scale: float, rows: int, head_dim: int,
-                      block_diffusion=None, rope_dim: int = 0):
+                      block_diffusion=None, rope_dim: int = 0,
+                      grouped: bool = False):
     """``rows`` batch rows a grid cell, each with the heads of one column
     block and the whole sequence in VMEM (gated by MAX_BWD_SEQ).
     Scores/probabilities never touch HBM — the reason XLA's einsum
     backward loses at these shapes. ``rope_dim``: the two-part score;
     dQr's block holds 128 // rope_dim heads and stays in VMEM while the
-    grid runs through them, each writing its own lanes."""
+    grid runs through them, each writing its own lanes. ``grouped``
+    (PR 43): the grid is (rows, KV head, head of its group) and dK, dV
+    the group's float32 sums (``_group_sum``)."""
+    member = pl.program_id(2) if grouped else 0
     if rope_dim:
         qr_ref, kr_ref, dq_ref, dk_ref, dv_ref, dqr_ref, dkr_ref = rest
         head = pl.program_id(1)
@@ -943,8 +986,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             if causal or block_diffusion is not None else None, head_dim,
             rope)
         dq_ref[b] = (dqt * scale).T.astype(dq_ref.dtype)
-        dk_ref[b] = (dkt * scale).T.astype(dk_ref.dtype)
-        dv_ref[b] = dvt.T.astype(dv_ref.dtype)
+        _group_sum(dk_ref, b, (dkt * scale).T, grouped, member)
+        _group_sum(dv_ref, b, dvt.T, grouped, member)
         if rope_dim:
             dqr = (dr[0] * scale).T.astype(dqr_ref.dtype)
             dqr_ref[b] = jnp.where(_rope_lanes_of(dqr.shape, head, rope_dim),
@@ -957,7 +1000,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                               glse_ref, *rest, causal: bool, window: int,
                               scale: float, blk: int, head_dim: int,
-                              block_diffusion=None, rope_dim: int = 0):
+                              block_diffusion=None, rope_dim: int = 0,
+                              grouped: bool = False):
     """FA2 backward for sequences past MAX_BWD_SEQ: grid cell = one
     (batch row, column block, K-block). The Q/O/dO panels are resident;
     the K-block meets them in chunks of ``blk`` queries, and ONLY the
@@ -994,8 +1038,20 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     lanes. dKr, the gradient of the ONE rotated key, leaves a head at a
     time (this head's lanes of a 128-wide block, zeros in the others):
     the grid's order (a head's K blocks together, for dQ) lets no two
-    heads meet in one output block, so XLA adds the heads up."""
-    j = pl.program_id(2)
+    heads meet in one output block, so XLA adds the heads up.
+
+    ``grouped`` (PR 43): k and v are the KV heads' and the grid is
+    (batch row, KV head, head of its group, K block): the same order of
+    steps, so dQ's block is still revisited consecutively. A group's dK
+    and dV cannot be a K block each (between two heads' visits of one
+    the grid passes through the other K blocks, and Pallas keeps only a
+    block that is revisited at once), so their blocks are the KV head's
+    WHOLE float32 [S, W] panels, resident while the grid runs through
+    the group's heads and all their K blocks and written once a group:
+    a step turns its sums into the block's rows, the group's first head
+    writing and the others adding (``_group_sum``). Nothing of H * D
+    width leaves the backward but dQ."""
+    j = pl.program_id(3 if grouped else 2)
     k0 = j * blk
     k, v = k_ref[0], v_ref[0]
     kt = k.T
@@ -1043,27 +1099,36 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     for lo, hi, edge in split:
         jax.lax.fori_loop(lo, hi, functools.partial(chunk, edge=edge), None)
     dkt, dvt = dkt_ref[...], dvt_ref[...]
-    dk_ref[0] = (dkt * scale).T.astype(dk_ref.dtype)
-    dv_ref[0] = dvt.T.astype(dv_ref.dtype)
+    at = (0, pl.ds(pl.multiple_of(k0, blk), blk)) if grouped else 0
+    member = pl.program_id(2) if grouped else 0
+    _group_sum(dk_ref, at, (dkt * scale).T, grouped, member)
+    _group_sum(dv_ref, at, dvt.T, grouped, member)
     if rope_dim:
         dkr_ref[0] = (dkrt_ref[...] * scale).T.astype(dkr_ref.dtype)
 
 
 def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
                interpret: bool, glse=None, window: int = 0,
-               block_diffusion=None, rope=None):
+               block_diffusion=None, rope=None, num_kv_heads=None):
     """dq, dk, dv [B, S, H*D] from the saved (o, lse[B, H, 1, S]): one
     whole-tile step for several heads up to MAX_BWD_SEQ, K-blocked past
     it — scores stay in VMEM tiles at every length the gate admits
     (flash_attention_available caps S at MAX_FLASH_SEQ). With ``rope``
     (``_flash_fwd``) also (dq_rope [B, S, H*R], dk_rope [B, S, R]): the
     kernels hand dk_rope out a head at a time and the sum over the
-    heads, into the one rotated key, is taken here."""
+    heads, into the one rotated key, is taken here. With
+    ``num_kv_heads`` < H (k, v [B, S, Hk*D]) dk and dv are the groups'
+    sums [B, S, Hk*D] in FLOAT32, added up inside the kernels from their
+    float32 tiles: no query head's dK or dV is written, or rounded."""
     b, s, hd = q.shape
     d = hd // num_heads
     hpb = _heads_per_block(num_heads, d)
     w = hpb * d
     rope_dim, per, rope_ops = _rope_operands(rope, num_heads, d)
+    rep = _group_size(num_heads, num_kv_heads, d, rope)
+    grouped = rep > 1
+    dk_shape, dv_shape = (jax.ShapeDtypeStruct(
+        t.shape, jnp.float32 if grouped else t.dtype) for t in (k, v))
     scale = 1.0 / float(d + rope_dim) ** 0.5
     window = normalized_window(s, causal, window)
     bd = checked_block_diffusion(s, causal, window, block_diffusion)
@@ -1089,24 +1154,31 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         row_spec = pl.BlockSpec((rows, hpb, 1, s), lambda i, j: (i, j, 0, 0))
         qr_spec = pl.BlockSpec((rows, s, LANES), lambda i, j: (i, 0, j // per))
         kr_spec = pl.BlockSpec((rows, s, LANES), lambda i, j: (i, 0, 0))
+        kv_spec, grid = seq_spec, (b // rows, num_heads // hpb)
+        if grouped:     # (rows, KV head g, head r of its group)
+            seq_spec = pl.BlockSpec((rows, s, w),
+                                    lambda i, g, r: (i, 0, g * rep + r))
+            row_spec = pl.BlockSpec((rows, hpb, 1, s),
+                                    lambda i, g, r: (i, g * rep + r, 0, 0))
+            kv_spec = pl.BlockSpec((rows, s, w), lambda i, g, r: (i, 0, g))
+            grid = (b // rows, num_heads // rep, rep)
         return finish(*pl.pallas_call(
             functools.partial(_flash_bwd_kernel, causal=causal,
                               window=window, scale=scale, rows=rows,
                               head_dim=d, block_diffusion=bd,
-                              rope_dim=rope_dim),
+                              rope_dim=rope_dim, grouped=grouped),
             name=KERNEL_NAME_PREFIX + "flash_bwd",
             out_shape=(jax.ShapeDtypeStruct((b, s, hd), q.dtype),
-                       jax.ShapeDtypeStruct((b, s, hd), k.dtype),
-                       jax.ShapeDtypeStruct((b, s, hd), v.dtype)) + ((
+                       dk_shape, dv_shape) + ((
                            jax.ShapeDtypeStruct(rope_ops[0].shape, q.dtype),
                            jax.ShapeDtypeStruct((b, s, num_heads * LANES),
                                                 k.dtype),
                        ) if rope_dim else ()),
-            grid=(b // rows, num_heads // hpb),
-            in_specs=[seq_spec, seq_spec, seq_spec, seq_spec, seq_spec,
+            grid=grid,
+            in_specs=[seq_spec, kv_spec, kv_spec, seq_spec, seq_spec,
                       row_spec, row_spec] + (
                           [qr_spec, kr_spec] if rope_dim else []),
-            out_specs=(seq_spec, seq_spec, seq_spec) + (
+            out_specs=(seq_spec, kv_spec, kv_spec) + (
                 (qr_spec, seq_spec) if rope_dim else ()),
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
@@ -1117,23 +1189,33 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
     row_spec = pl.BlockSpec((1, hpb, 1, s), lambda b, c, j: (b, c, 0, 0))
     qr_spec = pl.BlockSpec((1, s, LANES), lambda b, c, j: (b, 0, c // per))
     kr_spec = pl.BlockSpec((1, blk, LANES), lambda b, c, j: (b, j, 0))
+    dkv_spec, grid = kblk_spec, (b, num_heads // hpb, s // blk)
+    if grouped:     # (batch row, KV head g, head r of its group, K block)
+        seq_spec = pl.BlockSpec((1, s, w),
+                                lambda b, g, r, j: (b, 0, g * rep + r))
+        row_spec = pl.BlockSpec((1, hpb, 1, s),
+                                lambda b, g, r, j: (b, g * rep + r, 0, 0))
+        kblk_spec = pl.BlockSpec((1, blk, w), lambda b, g, r, j: (b, j, g))
+        # the KV head's whole panel: resident across its group
+        dkv_spec = pl.BlockSpec((1, s, w), lambda b, g, r, j: (b, 0, g))
+        grid = (b, num_heads // rep, rep, s // blk)
     return finish(*pl.pallas_call(
         functools.partial(_flash_bwd_blocked_kernel, causal=causal,
                           window=window, scale=scale, blk=blk, head_dim=d,
-                          block_diffusion=bd, rope_dim=rope_dim),
+                          block_diffusion=bd, rope_dim=rope_dim,
+                          grouped=grouped),
         name=KERNEL_NAME_PREFIX + "flash_bwd_blocked",
         out_shape=(jax.ShapeDtypeStruct((b, s, hd), jnp.float32),  # dq acc
-                   jax.ShapeDtypeStruct((b, s, hd), k.dtype),
-                   jax.ShapeDtypeStruct((b, s, hd), v.dtype)) + ((
+                   dk_shape, dv_shape) + ((
                        jax.ShapeDtypeStruct(rope_ops[0].shape, jnp.float32),
                        jax.ShapeDtypeStruct((b, s, num_heads * LANES),
                                             k.dtype),
                    ) if rope_dim else ()),
-        grid=(b, num_heads // hpb, s // blk),
+        grid=grid,
         in_specs=[seq_spec, kblk_spec, kblk_spec, seq_spec, seq_spec,
                   row_spec, row_spec] + (
                       [qr_spec, kr_spec] if rope_dim else []),
-        out_specs=(seq_spec, kblk_spec, kblk_spec) + (
+        out_specs=(seq_spec, dkv_spec, dkv_spec) + (
             (qr_spec, kblk_spec) if rope_dim else ()),
         scratch_shapes=[pltpu.VMEM((w, blk), jnp.float32)] * 2 + (
             [pltpu.VMEM((LANES, blk), jnp.float32)] if rope_dim else []),
@@ -1165,28 +1247,40 @@ def _xla_attention_lse(q, k, v, causal: bool, window: int = 0,
     return o, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _stored(q, k, v, num_kv_heads):
+    """k and v as the kernels read them. Grouped keys and values
+    (``num_kv_heads``) come in float32 (``flash_attention``), so that
+    their cotangents, a group's sums, leave in float32; the kernels read
+    them rounded to q's dtype, as they read the repeated ones."""
+    if num_kv_heads is None:
+        return k, v
+    return k.astype(q.dtype), v.astype(q.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 9))
 def _flash(q, k, v, num_heads, causal, interpret, window=0,
-           block_diffusion=None, rope=None):
-    return _flash_fwd(q, k, v, num_heads, causal, interpret,
-                      window=window, block_diffusion=block_diffusion,
-                      rope=rope)[0]
+           block_diffusion=None, rope=None, num_kv_heads=None):
+    return _flash_fwd(q, *_stored(q, k, v, num_kv_heads), num_heads, causal,
+                      interpret, window=window,
+                      block_diffusion=block_diffusion, rope=rope,
+                      num_kv_heads=num_kv_heads)[0]
 
 
 def _flash_vjp_fwd(q, k, v, num_heads, causal, interpret, window=0,
-                   block_diffusion=None, rope=None):
+                   block_diffusion=None, rope=None, num_kv_heads=None):
+    k, v = _stored(q, k, v, num_kv_heads)
     o, lse = _flash_fwd(q, k, v, num_heads, causal, interpret,
                         window=window, block_diffusion=block_diffusion,
-                        rope=rope)
+                        rope=rope, num_kv_heads=num_kv_heads)
     return o, (q, k, v, rope, o, lse)
 
 
 def _flash_vjp_bwd(num_heads, causal, interpret, window, block_diffusion,
-                   res, g):
+                   num_kv_heads, res, g):
     q, k, v, rope, o, lse = res
     grads = _flash_bwd(q, k, v, o, lse, g, num_heads, causal, interpret,
                        window=window, block_diffusion=block_diffusion,
-                       rope=rope)
+                       rope=rope, num_kv_heads=num_kv_heads)
     return grads if rope is not None else (*grads, None)
 
 
@@ -1598,8 +1692,21 @@ def flash_attention_available(seq_len: int, head_dim: int,
     return mode == "interpret" or seq_len >= MIN_SEQ_FOR_FLASH
 
 
+def grouped_kv_shape_legal(num_heads: int, num_kv_heads: int,
+                           head_dim: int) -> bool:
+    """Whether the flash kernels take grouped-query keys and values as
+    [B, S, Hk*D] (PR 43), the shape half of the rule: whole groups, and
+    a head that is ONE 128-lane column block, so that a K / V BlockSpec
+    picks a group's block by ``j // rep``. At head_dim 64 a column block
+    holds two query heads, which may belong to two groups: those ops
+    repeat K and V as before."""
+    return (0 < num_kv_heads < num_heads and num_heads % num_kv_heads == 0
+            and head_dim == LANES)
+
+
 def flash_attention(q, k, v, num_heads: int, causal: bool = False,
-                    window: int = 0, block_diffusion=None, rope=None):
+                    window: int = 0, block_diffusion=None, rope=None,
+                    num_kv_heads=None):
     """q, k, v: [B, S, H*D] -> [B, S, H*D], the heads side by side along
     the lanes as the projections' plain 2-D products leave them, so that
     no layout change sits between a projection and a kernel and no
@@ -1608,29 +1715,46 @@ def flash_attention(q, k, v, num_heads: int, causal: bool = False,
     Who holds [B, H, S, D] converts with ``merge_heads`` /
     ``split_heads`` at its own boundary. ``rope`` = (q_rope [B, S, H*R],
     k_rope [B, S, R]): the two-part score (``_flash_fwd``); the gradient
-    of k_rope is the sum over the heads."""
-    return _flash(q, k, v, num_heads, causal, pallas_mode() == "interpret",
-                  window, tuple(block_diffusion) if block_diffusion else None,
-                  tuple(rope) if rope is not None else None)
+    of k_rope is the sum over the heads.
+
+    ``num_kv_heads`` < H (PR 43; caller checks ``grouped_kv_shape_legal``):
+    k and v are [B, S, Hk*D], a K/V head shared by H / Hk consecutive
+    query heads, and are never repeated: the kernels read a group's
+    block for each of its heads. Their gradients are the groups' sums in
+    float32 (whatever dtype k and v come in, the kernels read them
+    rounded to q's); ``num_kv_heads`` None or H is the call without it,
+    jaxpr and all."""
+    static = (num_heads, causal, pallas_mode() == "interpret", window,
+              tuple(block_diffusion) if block_diffusion else None,
+              tuple(rope) if rope is not None else None)
+    if num_kv_heads in (None, num_heads):
+        return _flash(q, k, v, *static)
+    return _flash(q, k.astype(jnp.float32), v.astype(jnp.float32), *static,
+                  num_kv_heads)
 
 
 def flash_attention_sharded(q, k, v, num_heads: int, mesh, batch_axis=None,
                             head_axis=None, causal: bool = False,
                             window: int = 0, block_diffusion=None,
-                            rope=None):
+                            rope=None, num_kv_heads=None):
     """Flash attention inside a GSPMD-sharded jit: a bare ``pallas_call``
     is an unpartitionable custom call to the partitioner, so wrap it in
     ``shard_map`` over the mesh axes the batch/head dims are sharded on —
     each device runs the kernel on its local [B/dp, S, (H/mp)*D] block
     (scores never cross shards; no collectives needed; the caller keeps
-    a head axis only where the local heads still tile the lanes). Axes
-    not named stay replicated, which GSPMD enforces on entry."""
+    a head axis only where the local heads still tile the lanes, and
+    under grouped keys, ``num_kv_heads``, only where it divides the KV
+    heads: a shard then holds whole groups). Axes not named stay
+    replicated, which GSPMD enforces on entry."""
     from jax.sharding import PartitionSpec as P
 
     spec = P(batch_axis, None, head_axis)
-    local = num_heads // (mesh.shape[head_axis] if head_axis else 1)
-    fn = functools.partial(flash_attention, num_heads=local, causal=causal,
-                           window=window, block_diffusion=block_diffusion)
+    shards = mesh.shape[head_axis] if head_axis else 1
+    assert not num_kv_heads or num_kv_heads % shards == 0, num_kv_heads
+    fn = functools.partial(flash_attention, num_heads=num_heads // shards,
+                           causal=causal, window=window,
+                           block_diffusion=block_diffusion,
+                           num_kv_heads=num_kv_heads and num_kv_heads // shards)
     if rope is not None:
         # the one rotated key has no head axis to shard: batch axes only
         assert head_axis is None, "latent attention: no head axis here"

@@ -27,6 +27,18 @@ a window of 512, half of a K chunk of 1024) runs `split` at K blocks of
 1024 (what `_seq_block` gave before it took the block from the window),
 512 (what ships) and 256.
 
+`--only grouped` (PR 43) times ONE WHOLE ATTENTION OP instead, forward
+and backward (`value_and_grad` over its parameters and input, as a train
+step runs it), at the five grouped-query shapes of the decoder cells
+(laguna's 64 : 8 window and 48 : 8 full ops, sdar's 8 : 1, smallthinker's
+7 : 1, nemotron's 4 : 1), in two forms: `repeated` (K and V repeated to
+[B, S, H*128] ahead of the kernels, the shipped form until PR 43, made
+here by steering the op's `_takes_grouped_kv` to no) and `grouped` (the
+kernels read K and V at the KV heads and add a group's dK / dV up in
+their resident float32 panel: what ships). A line holds both forms'
+device ms, their ops by stem, and the largest difference of the value
+and of every gradient between them (`grouped_vs_repeated`).
+
 Prints one JSON line a measurement and writes them to
 `chiprun_out/flash_lab.json`. Nothing here is a benchmark metric.
 
@@ -100,6 +112,82 @@ def kernel_ms(fn, args, interpret):
     return 1e3 * sum(found) / REPS
 
 
+def grouped_op_lines(tiny):
+    """`--only grouped`: one line a grouped-query attention op of the
+    decoder cells, repeated against grouped keys."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from gate_lab import IN_CONTEXT, _tiny
+    from moe_combine_lab import device_ms
+
+    from flexflow_tpu.ffconst import OperatorType
+    from flexflow_tpu.layer import Layer
+    from flexflow_tpu.ops.base import OpContext, OpRegistry
+
+    ops = {"laguna.window_64_8": IN_CONTEXT["whole"],
+           "laguna.full_48_8": IN_CONTEXT["partial"],
+           "sdar.block_diffusion_8_1": IN_CONTEXT["norm_whole"],
+           "smallthinker.window_7_1": IN_CONTEXT["whole_7_1"],
+           "nemotron.full_4_1": (8192, 2688, dict(
+               num_heads=4, num_kv_heads=1, causal=True, rope=False))}
+    lines = []
+    for name, (seq, hidden, props) in ops.items():
+        if tiny:
+            seq, hidden, props = _tiny(props)
+        jitted, outs = {}, {}
+        for form in FORMS:
+            layer = Layer(OperatorType.MULTIHEAD_ATTENTION, "op", [])
+            layer.properties.update(dict(
+                dict(rope=True), **props, embed_dim=hidden, head_dim=128,
+                bias=False))
+            op = OpRegistry.create(layer, [(1, seq, hidden)] * 3)
+            if form == "repeated":
+                op._takes_grouped_kv = lambda *a: False
+
+            def run(params, x, g, op=op):
+                ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
+                return jax.value_and_grad(lambda p, x: jnp.sum(
+                    op.forward(p, [x], ctx)[0].astype(jnp.float32) * g),
+                    argnums=(0, 1))(params, x)
+            run.__name__ = run.__qualname__ = (
+                f"grouped_{name}_{form}".replace(".", "_"))
+            rs = np.random.RandomState(0)
+            shapes = jax.eval_shape(op.init_params, jax.random.PRNGKey(0))
+            x = jax.ShapeDtypeStruct((1, seq, hidden), jnp.bfloat16)
+            args = jax.tree.map(lambda a: jnp.asarray(
+                0.02 * rs.randn(*a.shape), a.dtype), (shapes, x, x))
+            jitted[form] = (jax.jit(run), args)
+            outs[form] = jax.block_until_ready(jitted[form][0](*args))
+            assert op._flash_grouped_kv == (form != "repeated"), (name, form)
+        (a, da), (b, db) = outs["grouped"], outs["repeated"]
+        line = dict(
+            shape="grouped." + name, seq=seq, hidden=hidden,
+            heads=props["num_heads"], kv_heads=props["num_kv_heads"],
+            device=jax.devices()[0].device_kind,
+            grouped_vs_repeated=dict(
+                value_rel=float(abs(a - b) / abs(b)),
+                grads_rel={jax.tree_util.keystr(path): float(
+                    abs(x.astype("float32") - y.astype("float32")).max()
+                    / abs(y.astype("float32")).max())
+                    for (path, x), y in zip(
+                        jax.tree_util.tree_leaves_with_path(da),
+                        jax.tree.leaves(db))}))
+        # a CPU trace has no device lane to read
+        for form, (ms, by_stem) in ({} if tiny else device_ms(
+                jitted, stems=12)).items():
+            line[form + "_device_ms"] = round(ms, 3)
+            line[form + "_device_ops"] = by_stem
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+    return lines
+
+
+FORMS = ("repeated", "grouped")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true")
@@ -117,7 +205,7 @@ def main():
     if not tiny and jax.default_backend() != "tpu":
         sys.exit("flash_lab.py times the kernels on a TPU; --tiny rehearses")
     shipped = pk._k_split, pk._q_split, pk._seq_block
-    lines = []
+    lines = grouped_op_lines(tiny) if only and only in "grouped" else []
     for name, (heads, seq, causal, window, bd) in SHAPES.items():
         if only not in name:
             continue

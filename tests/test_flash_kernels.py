@@ -454,3 +454,180 @@ def test_unmasked_interior_tiles_give_the_bits_of_masking_every_tile(
             assert np.array_equal(a, b), name
         else:
             assert _rel_rms(a, b) < U / 16, (name, _rel_rms(a, b) / U)
+
+
+# ---------------------------------------------------------------------------
+# grouped-query keys read at the KV heads (PR 43)
+
+GROUPED_MASKS = {
+    "causal": lambda s: dict(causal=True),
+    "window": lambda s: dict(causal=True, window=300 if s > 1024 else 100),
+    "block_diffusion": lambda s: dict(causal=False,
+                                      block_diffusion=(s // 2, 4)),
+}
+
+
+def _repeated(x, hk, rep):
+    """[B, S, Hk*D] with every head repeated ``rep`` times, as
+    `ops/attention.py` `_qkv` lays it out for the cores that want whole
+    heads."""
+    b, s, w = x.shape
+    return jnp.repeat(x.reshape(b, s, hk, w // hk), rep, axis=2
+                      ).reshape(b, s, rep * w)
+
+
+# S <= MAX_BWD_SEQ: `flash_fwd_whole` + `flash_bwd`; past it the blocked
+# kernels (K blocks of 256 at 1280 positions, of 128 under the
+# block-diffusion mask: five and ten a head)
+@pytest.mark.parametrize("seq", [256, 1280])
+@pytest.mark.parametrize("mask", list(GROUPED_MASKS))
+@pytest.mark.parametrize("rep", [1, 4, 6, 7, 8])
+def test_grouped_keys_match_the_repeated_ones(rep, mask, seq, monkeypatch):
+    """`num_kv_heads`: K and V as [B, S, Hk*128], a K / V BlockSpec
+    picking the query column block's group (`j // rep`), against the same
+    call on `jnp.repeat`ed keys, two KV heads of ``rep`` query heads
+    each. The kernels' bodies see the same blocks: o, lse and dQ are
+    equal bit for bit. dK and dV are a group's sums, float32, added up
+    in the kernels from their float32 tiles; the repeated form rounds
+    every head's dK and dV to bf16 first, so the two differ by at most
+    one bf16 rounding of each partial, half a unit in the last of its 8
+    bits: |difference| <= 2 U * sum over the group of |partial| (and a
+    float32 rounding of the sums).
+
+    ``rep`` 1 is the call without ``num_kv_heads``: the same jaxpr,
+    character for character, through `flash_attention` and through its
+    gradient."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    hk, d = 2, 128
+    h = hk * rep
+    kw = GROUPED_MASKS[mask](seq)
+    causal = kw.pop("causal")
+    q, _, _, do = _qkv(seq, d, jnp.bfloat16, seed=seq + rep, h=h)
+    k, v, _, _ = _qkv(seq, d, jnp.bfloat16, seed=seq + rep + 1, h=hk)
+
+    if rep == 1:
+        def grads(**more):
+            def run(q, k, v):
+                return jax.grad(lambda q, k, v: jnp.sum(pk._flash(
+                    q, k, v, h, causal, True, kw.get("window", 0),
+                    kw.get("block_diffusion"), None, **more).astype(
+                        jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+            return str(jax.make_jaxpr(run)(q, k, v))
+        assert grads(num_kv_heads=h) == grads()
+        assert str(jax.make_jaxpr(lambda q, k, v: pk.flash_attention(
+            q, k, v, h, causal, num_kv_heads=h, **kw))(q, k, v)) == str(
+                jax.make_jaxpr(lambda q, k, v: pk.flash_attention(
+                    q, k, v, h, causal, **kw))(q, k, v))
+        return
+
+    o, lse = pk._flash_fwd(q, k, v, h, causal, True, num_kv_heads=hk, **kw)
+    kr, vr = _repeated(k, hk, rep), _repeated(v, hk, rep)
+    want_o, want_lse = pk._flash_fwd(q, kr, vr, h, causal, True, **kw)
+    assert np.array_equal(np.asarray(o, np.float32),
+                          np.asarray(want_o, np.float32))
+    assert np.array_equal(np.asarray(lse), np.asarray(want_lse))
+
+    dq, dk, dv = pk._flash_bwd(q, k, v, o, lse, do, h, causal, True,
+                               num_kv_heads=hk, **kw)
+    want_dq, dkr, dvr = pk._flash_bwd(q, kr, vr, o, lse, do, h, causal,
+                                      True, **kw)
+    assert np.array_equal(np.asarray(dq, np.float32),
+                          np.asarray(want_dq, np.float32))
+    for name, got, parts in (("dk", dk, dkr), ("dv", dv, dvr)):
+        assert got.dtype == jnp.float32 and got.shape == (1, seq, hk * d)
+        parts = np.asarray(parts, np.float32).reshape(1, seq, hk, rep, d)
+        want, room = parts.sum(3), np.abs(parts).sum(3)
+        off = np.abs(np.asarray(got).reshape(want.shape) - want)
+        assert np.all(off <= 2.02 * U * room + 1e-6 * np.abs(want).max()), (
+            name, float(np.max(off / (U * room + 1e-30))))
+        # and it is the group's sum, not one head's: the partials differ
+        assert np.abs(want).max() > 0 and not np.allclose(want,
+                                                          parts[..., 0, :])
+
+    # through the public call: float32 keys in (as the op hands them),
+    # float32 group sums out, the same values
+    g = jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
+        q, k, v, h, causal, num_kv_heads=hk, **kw).astype(jnp.float32)
+        * do.astype(jnp.float32)), argnums=(0, 1, 2))(
+            q, k.astype(jnp.float32), v.astype(jnp.float32))
+    assert [a.dtype for a in g] == [jnp.bfloat16, jnp.float32, jnp.float32]
+    for a, b in zip(g, (dq, dk, dv)):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+
+
+def _attention_op(h, hk, d, e, s, b=1, **props):
+    from flexflow_tpu.ffconst import DataType, OperatorType
+    from flexflow_tpu.layer import Layer
+    from flexflow_tpu.ops import OpRegistry
+
+    layer = Layer(OperatorType.MULTIHEAD_ATTENTION, "attn", [],
+                  data_type=DataType.FLOAT)
+    layer.properties.update(dict(embed_dim=e, num_heads=h, num_kv_heads=hk,
+                                 head_dim=d, causal=True, bias=False,
+                                 rope=True), **props)
+    return OpRegistry.create(layer, [(b, s, e)] * 3)
+
+
+# what the op does with grouped-query keys, by the one rule on static
+# shapes (`_takes_grouped_kv`): (heads, KV heads, head_dim, mesh axes,
+# whether the flash kernels get the keys at the KV heads)
+GROUPED_OPS = {
+    "heads_of_128": (4, 2, 128, None, True),
+    "one_kv_head": (7, 1, 128, None, True),
+    # a column block of 128 lanes holds two heads of 64, which may
+    # belong to two groups
+    "head_dim_64_repeats": (4, 2, 64, None, False),
+    "every_head_its_own_keys": (4, 4, 128, None, False),
+    # a head axis of 4 over 8 query heads: 4 KV heads leave a shard one
+    # whole group, 2 KV heads would be cut
+    "head_axis_keeps_whole_groups": (8, 4, 128, {"data": 2, "model": 4},
+                                     True),
+    "head_axis_would_split_a_group_repeats": (8, 2, 128,
+                                              {"data": 2, "model": 4}, False),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPED_OPS))
+def test_the_op_hands_over_grouped_keys_where_the_shapes_allow(
+        case, monkeypatch):
+    """`MultiHeadAttention` under grouped-query attention: where the
+    rule admits it the flash kernels get K and V as [B, S, Hk*D]
+    (`_flash_grouped_kv`, counted by `executor.flash_grouped_kv_ops`),
+    elsewhere the repeat stays; either way the op's output and every
+    gradient match the same op steered to the repeat."""
+    from flexflow_tpu.machine import make_mesh
+    from flexflow_tpu.ops.base import OpContext
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    h, hk, d, axes, grouped = GROUPED_OPS[case]
+    b, s, e = 2, 256, 32
+    mesh = make_mesh(8, axes) if axes else None
+    props = dict(head_parallel="model") if axes else {}
+    op = _attention_op(h, hk, d, e, s, b, **props)
+    steered = _attention_op(h, hk, d, e, s, b, **props)
+    steered._takes_grouped_kv = lambda *a: False
+    params = op.init_params(jax.random.PRNGKey(0))
+    rs = np.random.RandomState(5)
+    x, g = (jnp.asarray(rs.randn(b, s, e).astype(np.float32))
+            for _ in range(2))
+
+    def run(op):
+        def loss(p, x):
+            ctx = OpContext(training=True, mesh=mesh,
+                            compute_dtype=jnp.bfloat16)
+            return jnp.sum(op.forward(p, [x], ctx)[0] * g)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+
+    got, want = run(op), run(steered)
+    assert op._flash_grouped_kv == grouped and op._flash_lane_dense
+    assert not steered._flash_grouped_kv
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-6)
+    for (path, a), b_ in zip(jax.tree_util.tree_leaves_with_path(got[1]),
+                             jax.tree.leaves(want[1])):
+        if not grouped or "wq" in str(path) or "wo" in str(path):
+            # the same program, or a gradient the keys' form cannot reach
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_),
+                                          err_msg=str(path))
+        else:
+            assert _rel_rms(a, b_) < 2 * U, (path, _rel_rms(a, b_) / U)

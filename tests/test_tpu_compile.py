@@ -207,6 +207,44 @@ class TestFlashKernels:
         assert 0 < pk.kv_blocks_masked(seq, True, window) <= (
             pk.kv_blocks(seq, True, window)[0])
 
+    @pytest.mark.parametrize("heads,kv_heads,seq,window,block_diffusion", [
+        (7, 1, 16384, 4096, None), (7, 1, 16384, 0, None),
+        (4, 1, 8192, 0, None), (64, 8, 8192, 512, None),
+        (48, 8, 8192, 0, None), (8, 1, 16384, 0, (8192, 4)),
+        (8, 2, 1024, 0, None)])
+    def test_grouped_keys_compile_at_the_cells_shapes(
+            self, topo, heads, kv_heads, seq, window, block_diffusion):
+        """K and V at the KV heads (PR 43) at the five grouped-query
+        shapes of the decoder cells, and the whole-tile kernels at their
+        longest: the backward's dK and dV are the KV head's whole float32
+        [S, 128] panels, resident across a group's heads (8 MB each at
+        16,384 positions, twice with the pipeline's second buffer), beside
+        the q, o, dO and dQ panels, inside the 96 MiB budget. No operand
+        or result but q, o, dO and dQ is H * 128 wide."""
+        one = SingleDeviceSharding(topo.devices[0])
+        q = jax.ShapeDtypeStruct((1, seq, heads * 128), jnp.bfloat16,
+                                 sharding=one)
+        k = jax.ShapeDtypeStruct((1, seq, kv_heads * 128), jnp.float32,
+                                 sharding=one)
+
+        def grads(q, k, v):
+            def loss(q, k, v):
+                return pk._flash(q, k, v, heads, not block_diffusion, False,
+                                 window, block_diffusion, None,
+                                 kv_heads).astype(jnp.float32).sum()
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        compiled = jax.jit(grads).lower(q, k, k).compile()
+        hlo = compiled.as_text()
+        assert pallas_kernel_count(hlo) == 2
+        assert layout_faults(hlo, q.size * 2) == []
+        # q, o, dO, lse live at once; nothing else of q's size
+        assert compiled.memory_analysis().temp_size_in_bytes < 5 * q.size * 2
+        out = jax.eval_shape(grads, q, k, k)
+        assert [(a.shape, a.dtype) for a in out] == [
+            (q.shape, jnp.bfloat16), (k.shape, jnp.float32),
+            (k.shape, jnp.float32)]
+
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
     @pytest.mark.parametrize("batch,heads,seq,head_dim", [
         # whole-tile kernels: 8 heads a step, then 2 at their longest,
@@ -477,25 +515,28 @@ class TestRotaryLanes:
     and a flash kernel but the product's own result (the pass's operand)
     and the pass's, and copies no float32 `[.., H, 128]` array there. The
     same op steered to the `[B, S, H, D]` view, the shipped form until PR
-    42, holds them; what is left with the pass is the K/V repeat's
-    backward (dK and dV of the repeated heads as float32 through a 4-D
-    view: a convert and a copy each), the next thing to go."""
+    42, holds them. With the pass nothing is left (PR 43): the K/V
+    repeat's backward (dK and dV of the repeated heads as float32
+    through a 4-D view: a convert and a copy each, 4 / 2 / 4 such passes
+    an op until then) is gone too, because the flash kernels read K and
+    V at the KV heads and hand back the groups' float32 sums."""
     YARN = dict(rope_type="yarn", factor=64, beta_fast=64, beta_slow=1,
                 original_max_position_embeddings=4096,
                 attention_factor=1.4158883083359672)
     # seq, width, the op's properties; XLA's passes over an S x H x 128
-    # float32 array with the pass (the repeat's backward) and on the view
+    # float32 array with the pass (none) and on the view (rotary's own:
+    # with grouped keys the repeat's 4 / 2 / 4 are gone from it as well)
     OPS = {
         "laguna_window_64_8": (8192, 2048, dict(
             num_heads=64, num_kv_heads=8, causal=True, window=512,
-            gate=True), 4, 12),
+            gate=True), 0, 8),
         "laguna_full_48_8_partial_yarn": (8192, 2048, dict(
             num_heads=48, num_kv_heads=8, causal=True, gate=True,
             rope_theta=500000.0, partial_rotary_factor=0.5,
-            rope_scaling=YARN), 2, 11),
+            rope_scaling=YARN), 0, 9),
         "sdar_8_1_normed": (16384, 2048, dict(
             num_heads=8, num_kv_heads=1, block_diffusion=(8192, 4),
-            rope_wrap=8192, qk_norm=True, rope_theta=1000000.0), 4, 17),
+            rope_wrap=8192, qk_norm=True, rope_theta=1000000.0), 0, 13),
     }
 
     def _hlo(self, topo, seq, hidden, props, lanes):
@@ -553,17 +594,56 @@ class TestRotaryLanes:
                         op_name.group(1) if op_name else ""))
         return out
 
+    @staticmethod
+    def assert_keys_stay_at_the_kv_heads(hlo, seq, heads, kv_heads):
+        """The K/V repeat is in the program in neither direction (PR
+        43): no bf16 array of S x H x 128 elements is written by a
+        `broadcast`, `reshape` or `copy` (the repeated K or V); the
+        flash forward reads ONE operand that wide, q, and K and V at the
+        KV heads; the flash backward hands out one float32 result that
+        wide, dQ, and dK and dV as float32 [1, S, Hk*128], the groups'
+        sums, which is all that lies between it and the K / V
+        projections' transposed products."""
+        from flexflow_tpu.obs.inspect import (_INSTRUCTION,
+                                              arrays_between_fusions)
+        wide, narrow = [1, seq, heads * 128], [1, seq, kv_heads * 128]
+        names = set(arrays_between_fusions(hlo, "bf16", seq * heads * 128))
+        calls = {}
+        for line in hlo.splitlines():
+            m = _INSTRUCTION.match(line)
+            if m and m.group(1) in names:
+                assert m.group(3) not in ("broadcast", "reshape", "copy"), line
+            if 'custom_call_target="tpu_custom_call"' not in line:
+                continue
+            kernel = re.search(r"tpu_custom_call_(flash_\w+)/pallas_call",
+                               line.split("metadata=")[-1])
+            if kernel:
+                results, operands = line.split("custom-call(")[0], line.split(
+                    "operand_layout_constraints=")[1].split("}}")[0]
+                calls[kernel.group(1)] = tuple(
+                    [(dt, [int(n) for n in dims.split(",")])
+                     for dt, dims in re.findall(r"(bf16|f32)\[([\d,]+)\]",
+                                                part)]
+                    for part in (results, operands))
+        _, operands = calls["flash_fwd"]
+        assert operands == [("bf16", wide), ("bf16", narrow),
+                            ("bf16", narrow)], operands
+        results, operands = calls["flash_bwd_blocked"]
+        assert results[:3] == [("f32", wide), ("f32", narrow),
+                               ("f32", narrow)], results
+        assert [o for o in operands if o[1] == wide] == [
+            ("bf16", wide)] * 3, operands        # q, o and dO
+
     @pytest.mark.parametrize("kind", list(OPS))
     def test_no_float32_relayout_between_projection_and_flash(
             self, topo, on_tpu, kind):
         seq, hidden, props, with_the_pass, on_the_view = self.OPS[kind]
-        heads = props["num_heads"]
-        left = self.passes_over(self._hlo(topo, seq, hidden, props, True),
-                                seq * heads * 128)
-        # the repeat's backward alone: copies (and converts) of dK and dV
+        heads, kv_heads = props["num_heads"], props["num_kv_heads"]
+        hlo = self._hlo(topo, seq, hidden, props, True)
+        left = self.passes_over(hlo, seq * heads * 128)
+        # nothing: the repeat's backward is gone too
         assert len(left) == with_the_pass, left
-        assert all(o in ("copy", "fusion") and "rotary" not in scope
-                   for o, _, _, scope in left), left
+        self.assert_keys_stay_at_the_kv_heads(hlo, seq, heads, kv_heads)
         view = self.passes_over(self._hlo(topo, seq, hidden, props, False),
                                 seq * heads * 128)
         # what this PR took out of the op

@@ -444,6 +444,61 @@ def test_rotary_lane_dense_ops_counts_the_ops_the_pass_took(
     assert ff.executor.rotary_lane_dense_ops() == lane_layers
 
 
+@pytest.mark.parametrize("grouped_layers,narrow_layers,mha_layers",
+                         [(2, 1, 1), (1, 0, 0), (0, 0, 2)])
+def test_flash_grouped_kv_ops_counts_the_ops_whose_keys_stay_at_the_kv_heads(
+        grouped_layers, narrow_layers, mha_layers, tmp_path, monkeypatch,
+        no_open_session):
+    """`executor.flash_grouped_kv_ops` (PR 43): attention ops whose
+    forward handed the flash kernels K and V as [B, S, Hk*D] with fewer
+    KV heads than query heads. A grouped-query op with heads of 128
+    takes it; one with heads of 64 repeats its keys (a column block
+    holds two heads); an op with as many KV heads as query heads has no
+    group: 0 for a model of those. In the registry's snapshot, the trace
+    header and `FFModel.op_counters`, beside `flash_lane_dense_ops`."""
+    import numpy as np
+    from flexflow_tpu import (FFConfig, FFModel, LossType, MetricsType,
+                              SGDOptimizer)
+
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    b, s, e = 1, 128, 32        # one device: a bare kernel call
+    ff = FFModel(FFConfig(batch_size=b))
+    t = ff.create_tensor((b, s, e))
+    for i in range(grouped_layers):
+        t = ff.multihead_attention(t, t, t, e, 4, head_dim=128,
+                                   num_kv_heads=2, causal=True,
+                                   name=f"grouped{i}")
+    for i in range(narrow_layers):
+        t = ff.multihead_attention(t, t, t, e, 4, head_dim=64,
+                                   num_kv_heads=2, causal=True,
+                                   name=f"narrow{i}")
+    for i in range(mha_layers):
+        t = ff.multihead_attention(t, t, t, e, 2, head_dim=128,
+                                   name=f"mha{i}")
+    ff.dense(t, 1)
+    ff.compile(SGDOptimizer(lr=0.01), LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
+               [MetricsType.MEAN_SQUARED_ERROR])
+    assert obs.model_context(ff)["flash_grouped_kv_ops"] == 0  # not traced
+    rs = np.random.RandomState(0)
+    x = rs.randn(2 * b, s, e).astype(np.float32)
+    y = rs.randn(2 * b, s, 1).astype(np.float32)
+    ff.fit(x, y, epochs=1, verbose=False)   # traces and compiles the step
+    obs.start_trace(str(tmp_path), device=False)
+    ff.fit(x, y, epochs=1, verbose=False)
+    paths = obs.stop_trace()
+    header, _ = read_events(paths["events"])
+    assert header["flash_grouped_kv_ops"] == grouped_layers
+    assert header["flash_lane_dense_ops"] == (grouped_layers + narrow_layers
+                                              + mha_layers)
+    gauges = json.load(open(paths["counters"]))["gauges"]
+    assert gauges["executor.flash_grouped_kv_ops"] == grouped_layers
+    assert ff.executor.flash_grouped_kv_ops() == grouped_layers
+    # `fit` publishes the op counters where a model's ops count on the
+    # device (no op of this model does): what it would publish
+    ff._publish_op_counters({})
+    assert ff.op_counters["executor.flash_grouped_kv_ops"] == grouped_layers
+
+
 @pytest.mark.parametrize("moe_layers", [2, 1, 0])
 def test_moe_gather_combine_ops_counts_the_models_expert_layers(
         moe_layers, tmp_path, no_open_session):
