@@ -367,13 +367,15 @@ class CollectiveInferencePass:
         silently falls back, so the priced and the executed kernel
         differ. The same priced-vs-executed closure FFL207 gave the
         '_ovl' dimension."""
-        from flexflow_tpu.ffconst import OperatorType
         from flexflow_tpu.ops.pallas_kernels import (
-            BLK_Q, MAX_FLASH_HEAD_DIM, MAX_FLASH_SEQ, flash_shape_legal,
-            pallas_mode)
+            BLK_Q, MAX_FLASH_HEAD_DIM, MAX_FLASH_SEQ, pallas_mode)
 
         out: List[Diagnostic] = []
         fusable = None
+        training = True
+        if ctx.ff is not None and ctx.ff.executor is not None:
+            training = getattr(ctx.ff.executor, "comp_mode",
+                               CompMode.TRAINING) == CompMode.TRAINING
         for node in ctx.nodes:
             st = ctx.strategy.get(node.op.guid)
             impl = st.parsed.kernel if st is not None else None
@@ -387,10 +389,13 @@ class CollectiveInferencePass:
                         f"'_k:flash' recorded on a non-attention op",
                         op=op.name, hint="re-search the strategy"))
                     continue
+                # the op's own decision (`MultiHeadAttention.route`), the
+                # mesh aside: under ring attention the choice names the
+                # kernel of the ring's blocks
+                route = op.route({}, training)
                 seq = op.input_shapes[0][1]
-                sk = (op.input_shapes[1][1]
-                      if len(op.input_shapes) > 1 else seq)
-                if sk != seq:
+                if route.blocked == "cross_attention":
+                    sk = op.input_shapes[1][1]
                     out.append(error(
                         "FFL208",
                         f"'_k:flash' recorded on cross-attention "
@@ -399,14 +404,7 @@ class CollectiveInferencePass:
                         op=op.name,
                         hint="the graph changed since the search — "
                              "re-search the strategy"))
-                    continue
-                training = True
-                if ctx.ff is not None and ctx.ff.executor is not None:
-                    training = getattr(ctx.ff.executor, "comp_mode",
-                                       CompMode.TRAINING) \
-                        == CompMode.TRAINING
-                if not flash_shape_legal(seq, op.head_dim,
-                                         op.num_heads):
+                elif route.blocked == "shape":
                     out.append(error(
                         "FFL208",
                         f"'_k:flash' is illegal at this shape (seq={seq}"
@@ -419,7 +417,7 @@ class CollectiveInferencePass:
                         op=op.name,
                         hint="re-search (the flash gate rejects this "
                              "shape) or drop the stale strategy file"))
-                elif training and getattr(op, "dropout", 0) > 0:
+                elif route.blocked == "dropout":
                     # mirrors the native gate's
                     # attention_prob_dropout_unsupported: the training
                     # forward can never take the flash branch
@@ -431,22 +429,18 @@ class CollectiveInferencePass:
                         op=op.name,
                         hint="the dropout changed since the search — "
                              "re-search the strategy"))
-                else:
-                    from flexflow_tpu.ops.pallas_kernels import (
-                        flash_attention_available)
-                    if not flash_attention_available(
-                            seq, op.head_dim, op.num_heads):
-                        out.append(info(
-                            "FFL209",
-                            f"'_k:flash' was priced but this platform "
-                            f"falls back to einsum (pallas mode "
-                            f"'{pallas_mode()}', seq={seq}) — the "
-                            f"executed kernel differs from the priced "
-                            f"one",
-                            op=op.name,
-                            hint="set FLEXFLOW_TPU_PALLAS=interpret "
-                                 "(tests) or run on TPU; predictions "
-                                 "for this op are optimistic meanwhile"))
+                elif route.core != "flash":
+                    out.append(info(
+                        "FFL209",
+                        f"'_k:flash' was priced but this platform "
+                        f"falls back to einsum (pallas mode "
+                        f"'{pallas_mode()}', seq={seq}) — the "
+                        f"executed kernel differs from the priced "
+                        f"one",
+                        op=op.name,
+                        hint="set FLEXFLOW_TPU_PALLAS=interpret "
+                             "(tests) or run on TPU; predictions "
+                             "for this op are optimistic meanwhile"))
             elif impl == "conv_bn_fused":
                 if fusable is None:
                     from flexflow_tpu.layout import train_fusable_conv_guids

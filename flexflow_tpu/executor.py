@@ -698,7 +698,7 @@ class GraphExecutor:
         dict) receives what the weighted loss counts on the device."""
         fn = get_loss_fn(self.loss_type)
         # whether the loss, as last traced, reached the logits through
-        # `losses.target_log_probs` (the gauge `executor.loss_own_vjp`)
+        # `losses.target_log_probs` (`traced_gauges`)
         self._loss_own_vjp = False
         if self.final_is_softmax and self.loss_type in (
             LossType.CATEGORICAL_CROSSENTROPY,
@@ -730,99 +730,31 @@ class GraphExecutor:
             self.loss_type == LossType.SPARSE_CATEGORICAL_CROSSENTROPY)
         return fn(logits, labels)
 
-    def loss_own_vjp(self) -> int:
-        """1 when the loss, as last traced, took the log-probability of a
-        row's target from `losses.target_log_probs` (its own backward: no
-        float32 array of the logits' shape, no scatter; PR 40), else 0
-        (MSE, dense one-hot labels, probabilities in): the gauge
-        `executor.loss_own_vjp`, and `loss_own_vjp` in every trace
-        header."""
-        return int(getattr(self, "_loss_own_vjp", False))
-
-    def flash_lane_dense_ops(self) -> int:
-        """Attention ops whose forward, as last traced, called the flash
-        kernels with q, k, v as [B, S, heads*head_dim] (ops/attention.py
-        sets the flag): the gauge `executor.flash_lane_dense_ops`, and
-        `flash_lane_dense_ops` in every trace header."""
-        return sum(bool(getattr(n.op, "_flash_lane_dense", False))
-                   for n in self.nodes)
-
-    def rotary_lane_dense_ops(self) -> int:
-        """Attention ops whose forward, as last traced, ran the heads'
-        norm and rotary as the one lane-dense pass over [B, S,
-        heads*head_dim] (`pallas_kernels.rotary_lanes`; ops/attention.py
-        sets the flag; PR 42): the gauge `executor.rotary_lane_dense_ops`,
-        and `rotary_lane_dense_ops` in every trace header."""
-        return sum(bool(getattr(n.op, "_rotary_lane_dense", False))
-                   for n in self.nodes)
-
-    def flash_grouped_kv_ops(self) -> int:
-        """Attention ops whose forward, as last traced, handed the flash
-        kernels the [B, S, Hk*D] keys and values of fewer KV heads than
-        query heads, not repeated (ops/attention.py `_takes_grouped_kv`
-        sets the flag; PR 43): the gauge `executor.flash_grouped_kv_ops`,
-        and `flash_grouped_kv_ops` in every trace header."""
-        return sum(bool(getattr(n.op, "_flash_grouped_kv", False))
-                   for n in self.nodes)
-
-    def moe_gather_combine_ops(self) -> int:
-        """`MoELayer` ops whose forward, as last traced, sent rows to the
-        experts and brought them back to their tokens by gathers through
-        the routing sort and its inverse (ops/experts.py sets the flag;
-        PR 32): the gauge `executor.moe_gather_combine_ops`, and
-        `moe_gather_combine_ops` in every trace header."""
-        return sum(bool(getattr(n.op, "_gather_combine", False))
-                   for n in self.nodes)
-
-    def moe_sum_rows_ops(self) -> int:
-        """`MoELayer` ops whose forward, as last traced, had
-        `tokens_from_rows` add the buffer's rows into their tokens by the
-        kernel `moe_sum_rows` (PR 37; 0 where a layer holds all its
-        experts, and on the CPU): the gauge `executor.moe_sum_rows_ops`,
-        and `moe_sum_rows_ops` in every trace header."""
-        return sum(bool(getattr(n.op, "_sum_rows", False))
-                   for n in self.nodes)
-
-    def attention_gauges(self) -> Dict[str, int]:
-        """What the attention ops' forwards, as last traced, recorded on
-        the host (PR 31): the ops whose window hides something at their
-        sequence length, those under the block-diffusion mask (PR 34),
-        the latent-attention ops (PR 39),
-        and the [Q block, K chunk] tiles a head's flash
-        forward works through against those of the whole square, and
-        of them those that hold a hidden pair and run the masked body
-        (PR 35), added up over the ops that ran flash (`kv_blocks`,
-        `kv_blocks_masked`; 0 until one has been traced). Published as
-        gauges when the train step is traced, in every trace header and
-        in `FFModel.op_counters`. PR 41: over the window ops that ran
-        flash, the (query, key) pairs a head's kernels work through,
-        forward and backward, against twice the pairs visible
-        (`pallas_kernels.visited_pairs` / `visible_pairs`); and, in a
-        model whose attention ops differ in their query heads, each op's
-        (`attention/heads_by_op/<op>`)."""
-        blocks = [n.op._kv_blocks for n in self.nodes
-                  if getattr(n.op, "_kv_blocks", None)]
-        pairs = [n.op._window_pairs for n in self.nodes
-                 if getattr(n.op, "_window_pairs", None)]
+    def traced_gauges(self) -> Dict[str, float]:
+        """What the trace of the step recorded on the host, by gauge key:
+        the ops' own `Op.traced_gauges` added up over the nodes (which
+        kernels and operand forms their forwards took; 0 until one has
+        been traced); `executor.loss_own_vjp`, 1 when the loss as last
+        traced took the log-probability of a row's target from
+        `losses.target_log_probs` (its own backward, PR 40), else 0 (MSE,
+        dense one-hot labels, probabilities in); and, in a model whose
+        attention ops differ in their query heads, each op's
+        (`attention/heads_by_op/<op>`, PR 41). The ONE list of them:
+        published as registry gauges when the train step is traced, in
+        `FFModel.op_counters` and in every trace header
+        (`obs.model_context`)."""
+        out: Dict[str, float] = {}
+        for n in self.nodes:
+            for key, value in n.op.traced_gauges().items():
+                out[key] = out.get(key, 0) + value
+        out["executor.loss_own_vjp"] = int(
+            getattr(self, "_loss_own_vjp", False))
         heads = {n.op.name: n.op.num_heads for n in self.nodes
                  if hasattr(n.op, "num_kv_heads")}
-        by_op = ({f"attention/heads_by_op/{name}": h
-                  for name, h in heads.items()}
-                 if len(set(heads.values())) > 1 else {})
-        return {
-            **by_op,
-            "attention/window_keys_visited": sum(p[0] for p in pairs),
-            "attention/window_keys_visible": sum(p[1] for p in pairs),
-            "executor.window_attention_ops": sum(
-                bool(getattr(n.op, "windowed", False)) for n in self.nodes),
-            "executor.block_diffusion_attention_ops": sum(
-                bool(getattr(n.op, "block_diffusion", None))
-                for n in self.nodes),
-            "executor.latent_attention_ops": sum(
-                bool(getattr(n.op, "latent", None)) for n in self.nodes),
-            "attention/kv_blocks_visited": sum(b[0] for b in blocks),
-            "attention/kv_blocks_total": sum(b[1] for b in blocks),
-            "attention/kv_blocks_masked": sum(b[2] for b in blocks)}
+        if len(set(heads.values())) > 1:
+            out.update((f"attention/heads_by_op/{name}", h)
+                       for name, h in heads.items())
+        return out
 
     def _training_nodes(self):
         """Node list the TRAIN step runs: (Conv2D, BatchNorm) pairs whose
@@ -891,22 +823,10 @@ class GraphExecutor:
             (loss, (logits, new_state, counters)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(cparams)
-            # the ops' forwards have run (this is trace time): how many
-            # attention ops handed the flash kernels [B, S, H*D] operands
-            get_registry().gauge("executor.flash_lane_dense_ops",
-                                 self.flash_lane_dense_ops())
-            get_registry().gauge("executor.rotary_lane_dense_ops",
-                                 self.rotary_lane_dense_ops())
-            get_registry().gauge("executor.flash_grouped_kv_ops",
-                                 self.flash_grouped_kv_ops())
-            for gauge, value in self.attention_gauges().items():
+            # the ops' forwards have run (this is trace time): what
+            # they and the loss witnessed
+            for gauge, value in self.traced_gauges().items():
                 get_registry().gauge(gauge, value)
-            get_registry().gauge("executor.moe_gather_combine_ops",
-                                 self.moe_gather_combine_ops())
-            get_registry().gauge("executor.moe_sum_rows_ops",
-                                 self.moe_sum_rows_ops())
-            get_registry().gauge("executor.loss_own_vjp",
-                                 self.loss_own_vjp())
             # gradient sync over the data axes is inserted by GSPMD here
             # (in bf16 under the master-weight regime — half the bytes).
             # Under WUS the shard constraint turns that all-reduce into a

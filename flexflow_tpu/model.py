@@ -1477,20 +1477,8 @@ class FFModel:
             get_registry().gauge(k, self.op_counters[k])
         # beside what the ops counted on the device, what the trace of
         # their forwards recorded on the host
-        self.op_counters["executor.flash_lane_dense_ops"] = float(
-            self.executor.flash_lane_dense_ops())
-        self.op_counters["executor.rotary_lane_dense_ops"] = float(
-            self.executor.rotary_lane_dense_ops())
-        self.op_counters["executor.flash_grouped_kv_ops"] = float(
-            self.executor.flash_grouped_kv_ops())
         self.op_counters.update(
-            (k, float(v)) for k, v in self.executor.attention_gauges().items())
-        self.op_counters["executor.moe_gather_combine_ops"] = float(
-            self.executor.moe_gather_combine_ops())
-        self.op_counters["executor.moe_sum_rows_ops"] = float(
-            self.executor.moe_sum_rows_ops())
-        self.op_counters["executor.loss_own_vjp"] = float(
-            self.executor.loss_own_vjp())
+            (k, float(v)) for k, v in self.executor.traced_gauges().items())
 
     def fit(self, x=None, y=None, batch_size: Optional[int] = None,
             epochs: Optional[int] = None, verbose: bool = True,

@@ -353,30 +353,12 @@ def model_context(ff) -> Dict[str, Any]:
         # set_parameter call since (model.py records both, always)
         compile_phases=getattr(ff, "compile_phases", None),
         set_parameter_s=getattr(ff, "set_parameter_s", None),
-        # attention ops whose traced forward took the flash kernels in
-        # their [B, S, heads*head_dim] operand form (0 until a step or a
-        # forward has been traced)
-        flash_lane_dense_ops=ff.executor.flash_lane_dense_ops(),
-        # of the rotary ops, those whose heads' norm and rotary ran as
-        # the one lane-dense pass
-        rotary_lane_dense_ops=ff.executor.rotary_lane_dense_ops(),
-        # of the grouped-query ops, those whose flash kernels read K and
-        # V at the KV heads, not repeated
-        flash_grouped_kv_ops=ff.executor.flash_grouped_kv_ops(),
-        # windowed attention ops, those under the block-diffusion mask,
-        # and the flash forwards' K blocks visited against the whole
-        # square's, and of them those masked
-        # (`executor.attention_gauges`)
+        # what the trace of the ops' forwards and of the loss recorded on
+        # the host (`GraphExecutor.traced_gauges`: zeros until a step or
+        # a forward has been traced), each under its key's last dotted
+        # part
         **{k.split(".")[-1].replace("/", "_"): v
-           for k, v in ff.executor.attention_gauges().items()},
-        # expert layers whose traced forward moved rows by gathers only
-        moe_gather_combine_ops=ff.executor.moe_gather_combine_ops(),
-        # of them, those that added the rows into their tokens by the
-        # kernel `moe_sum_rows`
-        moe_sum_rows_ops=ff.executor.moe_sum_rows_ops(),
-        # 1 when the traced loss took the targets' log-probabilities from
-        # `losses.target_log_probs` (its own backward), else 0
-        loss_own_vjp=ff.executor.loss_own_vjp(),
+           for k, v in ff.executor.traced_gauges().items()},
         # positions that carried a target in the last epoch of a weighted
         # loss (None before one, or under another loss)
         loss_target_positions=(getattr(ff, "op_counters", None) or {}).get(

@@ -14,13 +14,23 @@ and a kernel. The einsum core and ring attention, which want
 the per-head norm before it keep to that form too where a head fills the
 128 lanes (PR 42: `MultiHeadAttention._rotated`), and there the keys and
 values of grouped-query attention reach the flash kernels at their own
-[B, S, Hk*D], never repeated (PR 43: `_takes_grouped_kv`).
+[B, S, Hk*D], never repeated (PR 43).
+
+Which core an op runs and in which operand form is decided in ONE place,
+`MultiHeadAttention.route`, whose answer is an `AttentionRoute`: `forward`
+computes it once and hands it down, `selected_impl`, fflint (FFL208 /
+FFL209) and `parallel/choice.py` read the same function, and
+`traced_gauges` publishes what the last forward's route says. A new
+property of the op or a new operand form of the kernels is a field of the
+route and an entry of that dict, in this file.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -247,6 +257,40 @@ def scaled_dot_product_attention(q, k, v, *, causal=False, dropout_rate=0.0,
     return out
 
 
+@dataclasses.dataclass(frozen=True)
+class AttentionRoute:
+    """What one forward of an attention op runs, as
+    `MultiHeadAttention.route` decides it from the op's properties, its
+    static shapes, the mesh's axis sizes, ``training`` and
+    `pallas_mode()`. `forward` keeps the route of the last trace as
+    ``op._route`` and decides nothing else."""
+
+    # "ring" | "flash" | "einsum"
+    core: str
+    # why the flash kernels cannot lower for this op whatever the
+    # platform: None | "cross_attention" | "shape" | "dropout"
+    blocked: Optional[str] = None
+    # a forced `kernel_impl="flash"` that runs einsum: the sentence
+    # FFL209 shows (`op._kernel_fallback`), else None
+    fallback: Optional[str] = None
+    # the scopes' kind: "plain" (non-causal) | "full" | "window" |
+    # "block_diffusion" | "latent" (`attention_<scope>`, `flash_<scope>`)
+    scope: str = "plain"
+    # (batch_axis, head_axis) of the `shard_map` the flash kernels run
+    # under on a mesh of several devices; None: a bare kernel call
+    shard_axes: Optional[Tuple] = None
+    # K and V reach the flash kernels at the KV heads, [B, S, Hk*D]
+    grouped_kv: bool = False
+    # the heads' norm and rotary run as the one lane-dense pass
+    rotary_in_lanes: bool = False
+    # of the flash forward, a head: (visited, total, masked) K blocks,
+    # and under a window that hides something (the (query, key) pairs in
+    # the tiles the kernels work through, forward and backward; twice the
+    # pairs visible); None where flash does not run
+    kv_blocks: Optional[Tuple[int, int, int]] = None
+    window_pairs: Optional[Tuple[int, int]] = None
+
+
 @register_op(OperatorType.MULTIHEAD_ATTENTION)
 class MultiHeadAttention(Op):
     """inputs: query [B,Sq,E], key [B,Sk,E], value [B,Sk,E] -> [B,Sq,E].
@@ -298,6 +342,12 @@ class MultiHeadAttention(Op):
     ``rotary_embedding`` / ``rotary_partial`` over a [B, S, H, D] view:
     the same float32 products from the same tables, at the price of
     XLA's copies of the whole array between the two layouts.
+
+    ``route`` is where every such choice is made (core, rotary form, K/V
+    form, the `shard_map`'s axes): a further property or operand form is
+    decided there, recorded as a field of `AttentionRoute` and published
+    by ``traced_gauges``; ``_qkv``, ``_rotated`` and ``_core`` take the
+    route and decide nothing.
     """
 
     scopes_itself = True
@@ -337,8 +387,8 @@ class MultiHeadAttention(Op):
         self.use_bias = p.get("bias", True)
         # grouped-query attention (Llama-family): kv heads may be fewer
         # than query heads; the flash kernels read a group's K and V as
-        # they are where `_takes_grouped_kv` says so, every other core
-        # gets them repeated to H (`_qkv`)
+        # they are where the route says so (`grouped_kv`), every other
+        # core gets them repeated to H (`_qkv`)
         self.num_kv_heads = p.get("num_kv_heads") or self.num_heads
         if self.num_heads % self.num_kv_heads:
             raise ValueError(
@@ -407,28 +457,14 @@ class MultiHeadAttention(Op):
         # the reference einsum path even when flash is available, None =
         # availability-based auto pick (pre-kernel-search behavior).
         # When a forced "flash" cannot run (platform/shape), forward
-        # falls back to einsum and records why in _kernel_fallback —
-        # fflint FFL209 surfaces the priced-vs-executed gap.
+        # falls back to einsum and records why in _kernel_fallback (the
+        # route's `fallback`) — fflint FFL209 surfaces the
+        # priced-vs-executed gap.
         self.kernel_impl = p.get("kernel_impl", None)
         self._kernel_fallback = None
-        # set when a forward hands the flash kernels [B, S, H*D]
-        # operands (counted by `executor.flash_lane_dense_ops`)
-        self._flash_lane_dense = False
-        # set when a forward ran the heads' norm and rotary as the
-        # lane-dense pass (counted by `executor.rotary_lane_dense_ops`)
-        self._rotary_lane_dense = False
-        # set when a forward handed the flash kernels the [B, S, Hk*D]
-        # keys and values of fewer KV heads than query heads, not
-        # repeated (counted by `executor.flash_grouped_kv_ops`)
-        self._flash_grouped_kv = False
-        # (visited, total, masked) K blocks of the flash forward as
-        # traced, a head (`attention/kv_blocks_*`); None until a forward
-        # ran flash
-        self._kv_blocks = None
-        # of a windowed op that ran flash: (the (query, key) pairs in the
-        # tiles its kernels work through, forward and backward; twice the
-        # pairs visible), a head (`attention/window_keys_*`)
-        self._window_pairs = None
+        # the `AttentionRoute` of the last forward traced (what
+        # `traced_gauges` publishes); None until one has been
+        self._route = None
         # batch-dim sharding (str or tuple of mesh axes under the sample2
         # 'data+model' 2-D partition), recorded by apply_strategy
         self.batch_parallel = p.get("batch_parallel", None)
@@ -527,6 +563,170 @@ class MultiHeadAttention(Op):
         w = min(sk, self.window) if self.window else sk
         return sq * w
 
+    def route(self, mesh_axes: Dict[str, int], training: bool, *,
+              batch: Optional[int] = None, sq: Optional[int] = None,
+              sk: Optional[int] = None) -> AttentionRoute:
+        """What a forward of this op runs on a mesh of ``mesh_axes``
+        ({axis: size}) on THIS platform (`pallas_mode()`): a function of
+        the op's properties, the static shapes (``batch``, ``sq``, ``sk``
+        default to ``input_shapes``) and ``training``, with no other
+        input. The one place the core, the rotary's form, the K/V form
+        and the `shard_map`'s axes are chosen; `forward`,
+        ``selected_impl``, fflint's FFL208 / FFL209 and
+        `parallel/choice.py` all read it.
+
+        The core: ring attention where the ``seq_parallel`` axis is
+        larger than 1 (self-attention only); else the flash kernels
+        unless einsum is pinned, the probabilities are dropped in
+        training, the op is cross-attention, or the shape or the platform
+        refuse them; else einsum. Under flash on a mesh of several
+        devices the kernels run per shard (``shard_axes``): the batch
+        axes (possibly the joint ('data','model') sample2 partition) and,
+        when the search picked a head choice, the head axis, where a
+        shard's heads still tile the lanes. Grouped-query K and V stay at
+        the KV heads (PR 43) where a head is one 128-lane column block
+        and a head axis leaves every shard whole groups. The heads' norm
+        and rotary run as the lane-dense pass (PR 42) with Pallas on, a
+        head that is one 128-lane column, whole row blocks, and one
+        device (a bare kernel call has no partitioning)."""
+        from flexflow_tpu.ops import pallas_kernels as pk
+
+        shapes = self.input_shapes
+        batch = shapes[0][0] if batch is None else batch
+        sq = shapes[0][1] if sq is None else sq
+        if sk is None:
+            sk = shapes[1][1] if len(shapes) > 1 else sq
+        h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
+        dropout_rate = self.dropout if training else 0.0
+
+        def legal(heads):   # the kernels' shape rule, at these widths
+            return pk.flash_shape_legal(sq, d, heads, self.rope_dim)
+
+        if sq != sk:
+            blocked = "cross_attention"
+        elif dropout_rate > 0:
+            blocked = "dropout"
+        elif not legal(h):
+            blocked = "shape"
+        else:
+            blocked = None
+        if (self.seq_parallel and sq == sk
+                and mesh_axes.get(self.seq_parallel, 1) > 1):
+            core = "ring"
+        elif (self.kernel_impl != "einsum" and blocked is None
+              and pk.flash_attention_available(sq, d, h, self.rope_dim)):
+            core = "flash"
+        else:
+            core = "einsum"
+        fallback = None
+        if core == "einsum" and self.kernel_impl == "flash":
+            # the search chose flash and the einsum core runs
+            fallback = (
+                # this platform/shape cannot run the kernels
+                f"flash unavailable at runtime (seq={sq}, head_dim={d}, "
+                f"heads={h}) — einsum executed instead"
+                if blocked in (None, "shape") else
+                # attention-prob dropout in training, or cross-attention
+                f"flash has no lowering for this forward "
+                f"(dropout_rate={dropout_rate}, Sq={sq}, Sk={sk}) — "
+                f"einsum executed instead")
+        if self.block_diffusion:
+            scope = "block_diffusion"
+        elif self.latent:
+            scope = "latent"
+        elif self.causal:
+            scope = "window" if self.windowed else "full"
+        else:
+            scope = "plain"
+        one_device = not any(n > 1 for n in mesh_axes.values())
+        rotary_in_lanes = bool(
+            self.rope and not self.latent and pk.pallas_mode() != "off"
+            and pk.rotary_lanes_shape_legal(sq, d)
+            and pk.rotary_lanes_shape_legal(sk, d) and one_device)
+        if core != "flash":
+            return AttentionRoute(core, blocked, fallback, scope,
+                                  rotary_in_lanes=rotary_in_lanes)
+
+        shard_axes = None
+        if not one_device:
+            # the raw pallas_call would be an unpartitionable custom
+            # call under GSPMD: it runs per shard via shard_map
+            bp = self.batch_parallel or "data"
+            bp = bp if isinstance(bp, tuple) else (bp,)
+            bp = tuple(a for a in bp if mesh_axes.get(a, 1) > 1)
+            bsz = int(np.prod([mesh_axes[a] for a in bp])) if bp else 1
+            batch_axis = (bp if bp and batch % bsz == 0 else None)
+            if batch_axis is not None and len(batch_axis) == 1:
+                batch_axis = batch_axis[0]
+            hp = self.head_parallel
+            in_batch = batch_axis if isinstance(batch_axis, tuple) \
+                else (batch_axis,)
+            head_axis = (hp if hp and hp not in in_batch
+                         and not self.latent    # one key for all heads
+                         and mesh_axes.get(hp, 1) > 1
+                         and h % mesh_axes[hp] == 0
+                         and legal(h // mesh_axes[hp])
+                         else None)
+            shard_axes = (batch_axis, head_axis)
+        grouped_kv = bool(
+            pk.grouped_kv_shape_legal(h, hk, d)
+            and (shard_axes is None or shard_axes[1] is None
+                 or hk % mesh_axes[shard_axes[1]] == 0))
+        kind = (sq, self.causal, self.window, self.block_diffusion)
+        return AttentionRoute(
+            core, blocked, fallback, scope, shard_axes, grouped_kv,
+            rotary_in_lanes,
+            kv_blocks=(*pk.kv_blocks(*kind), pk.kv_blocks_masked(*kind)),
+            window_pairs=(
+                (pk.visited_pairs(*kind),
+                 2 * pk.visible_pairs(sq, self.causal, self.window))
+                if self.windowed else None))
+
+    def selected_impl(self, mesh_axes=None, training: bool = False) -> str:
+        """``route(mesh_axes or {}, training).core`` at the op's static
+        shapes: the core ('ring' | 'flash' | 'einsum') a forward runs on
+        THIS platform, from the function `forward` itself reads. Serve
+        observability, the benchmark's families and `bench.py` record
+        it. The KV-cache ``decode_forward`` is always the cached einsum —
+        flash has no incremental decomposition there."""
+        return self.route(mesh_axes or {}, training).core
+
+    def traced_gauges(self) -> Dict[str, float]:
+        """What the last traced forward's route says, and the op's kind
+        (0 for everything a route holds until a forward has been
+        traced). `executor.flash_lane_dense_ops`: the flash kernels took
+        [B, S, heads*head_dim] operands (PR 30);
+        `executor.rotary_lane_dense_ops`: the heads' norm and rotary ran
+        as the one lane-dense pass (PR 42);
+        `executor.flash_grouped_kv_ops`: K and V reached the kernels at
+        the KV heads (PR 43); `executor.window_attention_ops` (the window
+        hides something at this length, PR 31), `executor.
+        block_diffusion_attention_ops` (PR 34), `executor.
+        latent_attention_ops` (PR 39); `attention/kv_blocks_*`: the
+        [Q block, K chunk] tiles a head's flash forward works through,
+        those of the whole square, and of the first those that hold a
+        hidden pair and run the masked body (PR 35);
+        `attention/window_keys_*`: of a window op that ran flash, the
+        (query, key) pairs a head's kernels work through, forward and
+        backward, against twice the pairs visible (PR 41)."""
+        route = self._route or AttentionRoute("einsum")   # not traced
+        visited, total, masked = route.kv_blocks or (0, 0, 0)
+        keys_visited, keys_visible = route.window_pairs or (0, 0)
+        return {
+            "executor.flash_lane_dense_ops": int(route.core == "flash"),
+            "executor.rotary_lane_dense_ops": int(route.rotary_in_lanes),
+            "executor.flash_grouped_kv_ops": int(route.grouped_kv),
+            "executor.window_attention_ops": int(self.windowed),
+            "executor.block_diffusion_attention_ops": int(
+                bool(self.block_diffusion)),
+            "executor.latent_attention_ops": int(bool(self.latent)),
+            "attention/kv_blocks_visited": visited,
+            "attention/kv_blocks_total": total,
+            "attention/kv_blocks_masked": masked,
+            "attention/window_keys_visited": keys_visited,
+            "attention/window_keys_visible": keys_visible,
+        }
+
     def forward(self, params, inputs, ctx: OpContext):
         # the op's rng is split off here: inside the nested call below it
         # would leave a tracer of that call in `ctx`
@@ -540,45 +740,48 @@ class MultiHeadAttention(Op):
         # (as the grouped products' read `gmm.N`); the kernel's own name
         # stays in `op_name`. Under the block-diffusion mask the scopes
         # are `attention_block_diffusion` / `flash_block_diffusion`.
-        if self.block_diffusion:
-            kind = "block_diffusion"
-        elif self.latent:
-            # `attention_latent` / `flash_latent` (PR 39)
-            kind = "latent"
-        elif self.causal:
-            kind = "window" if self.windowed else "full"
-        else:
-            # a non-causal op's kernel events keep the name of a
-            # top-level call, `tpu_custom_call*` (what
-            # `kernels.flash_roofline` sums): `attention_plain` lies
-            # around the kernel calls, not over them
-            return self._forward(params, inputs, ctx, rng, None,
-                                 functools.partial(scoped, "attention_plain"))
-        return scoped("attention_" + kind,
+        # `attention_latent` / `flash_latent` since PR 39. The one
+        # decision of this forward (this is trace time):
+        query, key = inputs[0], inputs[1 if len(inputs) > 1 else 0]
+        route = self._route = self.route(
+            _mesh_axes(ctx), ctx.training, batch=query.shape[0],
+            sq=query.shape[1], sk=key.shape[1])
+        # a forward that cannot take the flash branch at all (dropout,
+        # cross-attention) leaves an earlier record as it is
+        if route.fallback and (self._kernel_fallback is None
+                               or route.blocked in (None, "shape")):
+            self._kernel_fallback = route.fallback
+        if route.scope == "plain":
+            return self._forward(params, inputs, ctx, rng, route)
+        return scoped("attention_" + route.scope,
                       lambda params, inputs: self._forward(
-                          params, inputs, ctx, rng, "flash_" + kind))(
-                              params, inputs)
+                          params, inputs, ctx, rng, route))(params, inputs)
 
-    def _forward(self, params, inputs, ctx: OpContext, rng, flash_scope,
-                 around=lambda fn: fn):
-        """Projections, core, output projection. ``around`` wraps every
-        piece but a flash kernel call (the non-causal op's scope)."""
+    def _forward(self, params, inputs, ctx: OpContext, rng,
+                 route: AttentionRoute):
+        """Projections, core, output projection. A non-causal op's
+        kernel events keep the name of a top-level call,
+        `tpu_custom_call*` (what `kernels.flash_roofline` sums): its
+        scope `attention_plain` lies ``around`` every piece but a flash
+        kernel call, not over the op."""
+        around = (functools.partial(scoped, "attention_plain")
+                  if route.scope == "plain" else lambda fn: fn)
         q, k, v, rope = around(lambda params, inputs: (
-            self._qkv_latent if self.latent else self._qkv)(
-                params, inputs, ctx))(params, inputs)
-        o = self._core(q, k, v, ctx, rng, flash_scope, around, rope)
+            self._qkv_latent(params, inputs, ctx) if self.latent
+            else self._qkv(params, inputs, ctx, route)))(params, inputs)
+        o = self._core(q, k, v, ctx, rng, route, around, rope)
         if self.gate:
             o = scoped("attention_gate", lambda w, x, o: self._gated(
                 w, x, o, ctx))(params["w_gate"], inputs[0], o)
         return [around(lambda params, o: self._output(
             params, o, ctx, inputs[0].dtype))(params, o)]
 
-    def _qkv(self, params, inputs, ctx: OpContext):
+    def _qkv(self, params, inputs, ctx: OpContext, route: AttentionRoute):
         """q [B, S, H*D] in the compute dtype and k, v: the projections,
         the heads' norms and rotary. Under grouped-query attention
         (Hk < H) k and v are repeated to [B, S, H*D] here, for every
         core that wants whole heads; where the core is the flash kernels
-        and they take a group's keys as they are (``_takes_grouped_kv``,
+        and they take a group's keys as they are (``route.grouped_kv``,
         PR 43) k and v stay [B, S, Hk*D], in float32: the kernels round
         what they read, and the groups' dK and dV come back as float32
         sums, as the repeat's backward gave them."""
@@ -594,13 +797,11 @@ class MultiHeadAttention(Op):
         v = self._project(value, params["wv"], params["bv"] if biased else None, cd)
         b, sk = k.shape[0], k.shape[1]
         if self.rope:
-            q, k = self._rotated(q, k, params, ctx)
+            q, k = self._rotated(q, k, params, ctx, route.rotary_in_lanes)
         elif self.qk_norm:
             q = self._heads_normed(q, h, params["q_norm"])
             k = self._heads_normed(k, hk, params["k_norm"])
-        self._flash_grouped_kv = self._takes_grouped_kv(
-            ctx, q.shape[0], q.shape[1], sk)
-        if self._flash_grouped_kv:
+        if route.grouped_kv:
             return q.astype(cd), k, v, None
         if hk != h:
             k, v = (jnp.repeat(x.reshape(b, sk, hk, d), h // hk, axis=2
@@ -611,12 +812,12 @@ class MultiHeadAttention(Op):
         # flash kernel's HBM traffic
         return q.astype(cd), k.astype(cd), v.astype(cd), None
 
-    def _rotated(self, q, k, params, ctx: OpContext):
+    def _rotated(self, q, k, params, ctx: OpContext, lanes: bool):
         """The heads' norms (``qk_norm``) and rotary of q [B, S, H*D] and
         k [B, S, Hk*D], float32 as the projections left them, under the
         scope of the rotary's form: `rotary_whole` (every lane, plain
-        frequencies) or `rotary_partial_yarn`. Where the shapes allow
-        (``_rotates_in_lanes``) both are ONE pass of the kernel
+        frequencies) or `rotary_partial_yarn`. With ``lanes`` (the
+        route's ``rotary_in_lanes``) both are ONE pass of the kernel
         `pallas_kernels.rotary_lanes` over the operands as they lie,
         which leaves them in the compute dtype; else the norm, and the
         rotation over a [B, S, H, D] view (`rotary_embedding` /
@@ -630,8 +831,6 @@ class MultiHeadAttention(Op):
             raise NotImplementedError(
                 f"attention '{self.name}': wrapped positions with partial "
                 f"or scaled rotary")
-        lanes = self._rotary_lane_dense = self._rotates_in_lanes(
-            ctx, q.shape[1], k.shape[1])
         scales = ((params["q_norm"], params["k_norm"]) if self.qk_norm
                   else (None, None))
         if not lanes and self.qk_norm:
@@ -672,81 +871,6 @@ class MultiHeadAttention(Op):
 
         return scoped("rotary_whole" if whole else "rotary_partial_yarn",
                       rotate)(q, k, scales)
-
-    def _rotates_in_lanes(self, ctx: OpContext, sq: int, sk: int) -> bool:
-        """Whether this forward's norm and rotary run as the lane-dense
-        pass: Pallas on, a head that is one 128-lane column, whole row
-        blocks, one device (a bare kernel call has no partitioning)."""
-        from flexflow_tpu.ops.pallas_kernels import (
-            pallas_mode, rotary_lanes_shape_legal)
-
-        return (pallas_mode() != "off"
-                and rotary_lanes_shape_legal(sq, self.head_dim)
-                and rotary_lanes_shape_legal(sk, self.head_dim)
-                and (ctx.mesh is None or ctx.mesh.devices.size == 1))
-
-    def _runs_flash(self, ctx: OpContext, sq: int, sk: int) -> bool:
-        """Whether this forward's core is the flash kernels: no ring, no
-        pinned einsum, no dropout on the probabilities, self-attention,
-        and a shape and platform the kernels take."""
-        from flexflow_tpu.ops.pallas_kernels import flash_attention_available
-
-        mesh_axes = _mesh_axes(ctx)
-        ring = (self.seq_parallel and sq == sk
-                and mesh_axes.get(self.seq_parallel, 1) > 1)
-        return (not ring and self.kernel_impl != "einsum"
-                and not (self.dropout and ctx.training) and sq == sk
-                and flash_attention_available(sq, self.head_dim,
-                                              self.num_heads, self.rope_dim))
-
-    def _shard_axes(self, ctx: OpContext, b: int, sq: int):
-        """(batch_axis, head_axis) of the `shard_map` the flash kernels
-        run under on a mesh of several devices, else None (a bare kernel
-        call): the batch axes (possibly the joint ('data','model')
-        sample2 partition) and, when the search picked a head choice,
-        the head axis, where a shard's heads still tile the lanes."""
-        from flexflow_tpu.ops.pallas_kernels import flash_shape_legal
-
-        mesh_axes = _mesh_axes(ctx)
-        if not any(n > 1 for n in mesh_axes.values()):
-            return None
-        h, d = self.num_heads, self.head_dim
-        bp = getattr(self, "batch_parallel", None) or "data"
-        bp = bp if isinstance(bp, tuple) else (bp,)
-        bp = tuple(a for a in bp if mesh_axes.get(a, 1) > 1)
-        bsz = int(np.prod([mesh_axes[a] for a in bp])) if bp else 1
-        batch_axis = (bp if bp and b % bsz == 0 else None)
-        if batch_axis is not None and len(batch_axis) == 1:
-            batch_axis = batch_axis[0]
-        hp = self.head_parallel
-        in_batch = batch_axis if isinstance(batch_axis, tuple) \
-            else (batch_axis,)
-        head_axis = (hp if hp and hp not in in_batch
-                     and not self.latent    # one key for all heads
-                     and mesh_axes.get(hp, 1) > 1
-                     and h % mesh_axes[hp] == 0
-                     and flash_shape_legal(sq, d, h // mesh_axes[hp])
-                     else None)
-        return batch_axis, head_axis
-
-    def _takes_grouped_kv(self, ctx: OpContext, b: int, sq: int,
-                          sk: int) -> bool:
-        """Whether this forward hands the flash kernels K and V at the
-        KV heads, [B, S, Hk*D], not repeated (PR 43): the core is the
-        flash kernels, a head is one 128-lane column block
-        (`pallas_kernels.grouped_kv_shape_legal`), and a head axis of
-        the mesh leaves every shard whole groups. Everything else (a
-        head of 64, ring attention, the einsum core, a head axis that
-        would split a group) repeats K and V in ``_qkv``."""
-        from flexflow_tpu.ops.pallas_kernels import grouped_kv_shape_legal
-
-        if not (grouped_kv_shape_legal(self.num_heads, self.num_kv_heads,
-                                       self.head_dim)
-                and self._runs_flash(ctx, sq, sk)):
-            return False
-        axes = self._shard_axes(ctx, b, sq)
-        return (axes is None or axes[1] is None
-                or self.num_kv_heads % _mesh_axes(ctx)[axes[1]] == 0)
 
     def _gated(self, w_gate, x, o, ctx: OpContext):
         """o [B, S, H*D] with head n's lanes times a_n = act(x w_gate)_n,
@@ -804,14 +928,13 @@ class MultiHeadAttention(Op):
             y = y + params["bo"]
         return y.astype(dtype)
 
-    def _core(self, q, k, v, ctx: OpContext, rng, flash_scope, around,
-              rope=None):
+    def _core(self, q, k, v, ctx: OpContext, rng, route: AttentionRoute,
+              around, rope=None):
         from flexflow_tpu.ops.pallas_kernels import merge_heads, split_heads
 
         cd = ctx.compute_dtype
-        h, d = self.num_heads, self.head_dim
+        h = self.num_heads
         rope_dim = self.rope_dim
-        b, sq, sk = q.shape[0], q.shape[1], k.shape[1]
         dropout_rate = self.dropout if ctx.training else 0.0
 
         def heads_first(core):
@@ -828,9 +951,7 @@ class MultiHeadAttention(Op):
                 return merge_heads(core(q, k, v))
             return around(whole)(q, k, v, rope)
 
-        seq_axis = self.seq_parallel
-        mesh_axes = _mesh_axes(ctx)
-        if seq_axis and mesh_axes.get(seq_axis, 1) > 1 and sq == sk:
+        if route.core == "ring":
             if self.windowed or self.block_diffusion or self.latent:
                 raise NotImplementedError(
                     f"attention '{self.name}': ring attention has no "
@@ -852,24 +973,17 @@ class MultiHeadAttention(Op):
             from flexflow_tpu.parallel.ring_attention import ring_attention
 
             return heads_first(lambda q, k, v: ring_attention(
-                q, k, v, ctx.mesh, seq_axis=seq_axis,
+                q, k, v, ctx.mesh, seq_axis=self.seq_parallel,
                 head_axis=self.head_parallel, causal=self.causal))
-        if self._runs_flash(ctx, sq, sk):
+        if route.core == "flash":
             from flexflow_tpu.ops.pallas_kernels import (
-                flash_attention, flash_attention_sharded, kv_blocks,
-                kv_blocks_masked, visible_pairs, visited_pairs)
+                flash_attention, flash_attention_sharded)
 
-            # for `executor.flash_lane_dense_ops`
-            self._flash_lane_dense = True
-            kind = (sq, self.causal, self.window, self.block_diffusion)
-            self._kv_blocks = (*kv_blocks(*kind), kv_blocks_masked(*kind))
-            if self.windowed:
-                self._window_pairs = (
-                    visited_pairs(*kind),
-                    2 * visible_pairs(sq, self.causal, self.window))
+            flash_scope = (None if route.scope == "plain"
+                           else "flash_" + route.scope)
 
             def flash(kernel, **where):
-                if self._flash_grouped_kv:   # k, v are [B, S, Hk*D]
+                if route.grouped_kv:   # k, v are [B, S, Hk*D]
                     where["num_kv_heads"] = self.num_kv_heads
                 call = functools.partial(
                     kernel, num_heads=h, causal=self.causal,
@@ -881,56 +995,16 @@ class MultiHeadAttention(Op):
                 return (scoped(flash_scope, call) if flash_scope
                         else call)(q, k, v)
 
-            axes = self._shard_axes(ctx, b, sq)
-            if axes is not None:
-                # non-trivial mesh: the raw pallas_call would be an
-                # unpartitionable custom call under GSPMD — run it
-                # per-shard via shard_map (`_shard_axes`)
+            if route.shard_axes is not None:
+                # a mesh of several devices: per shard via shard_map
+                batch_axis, head_axis = route.shard_axes
                 return flash(flash_attention_sharded, mesh=ctx.mesh,
-                             batch_axis=axes[0], head_axis=axes[1])
+                             batch_axis=batch_axis, head_axis=head_axis)
             return flash(flash_attention)
-        if self.kernel_impl == "flash":
-            # the search chose flash and the einsum core runs: record
-            # the silent fallback so fflint FFL209 surfaces the
-            # priced-vs-executed gap
-            if dropout_rate == 0.0 and sq == sk:
-                # this platform/shape cannot run the kernels
-                self._kernel_fallback = (
-                    f"flash unavailable at runtime (seq={sq}, "
-                    f"head_dim={d}, heads={h}) — einsum executed instead")
-            elif self._kernel_fallback is None:
-                # this forward cannot take the flash branch at all
-                # (attention-prob dropout in training, or cross-attention)
-                self._kernel_fallback = (
-                    f"flash has no lowering for this forward "
-                    f"(dropout_rate={dropout_rate}, Sq={sq}, "
-                    f"Sk={sk}) — einsum executed instead")
         return heads_first(lambda q, k, v: scaled_dot_product_attention(
             q, k, v, causal=self.causal, dropout_rate=dropout_rate,
             rng=rng, compute_dtype=cd, window=self.window,
             block_diffusion=self.block_diffusion))
-
-    def selected_impl(self, mesh_axes=None, training: bool = False) -> str:
-        """Which attention kernel ``forward`` will execute on THIS
-        platform ('ring' | 'flash' | 'einsum') — a static derivation of
-        forward's dispatch, recorded by serve observability and checked
-        by fflint so provenance never re-derives (and disagrees with)
-        the executed path. The KV-cache ``decode_forward`` is always the
-        cached einsum — flash has no incremental decomposition there."""
-        from flexflow_tpu.ops.pallas_kernels import (
-            flash_attention_available)
-
-        mesh_axes = mesh_axes or {}
-        if self.seq_parallel and mesh_axes.get(self.seq_parallel, 1) > 1:
-            return "ring"
-        if self.kernel_impl == "einsum" or (training and self.dropout > 0):
-            return "einsum"
-        b, s, e = self.input_shapes[0]
-        sk = self.input_shapes[1][1] if len(self.input_shapes) > 1 else s
-        if s == sk and flash_attention_available(
-                s, self.head_dim, self.num_heads, self.rope_dim):
-            return "flash"
-        return "einsum"
 
     def decode_forward(self, params, inputs, ctx: OpContext,
                        k_cache, v_cache, pos):

@@ -102,6 +102,17 @@ class Op:
     # executor wraps every other op in one named for its kind
     scopes_itself: bool = False
 
+    def traced_gauges(self) -> Dict[str, float]:
+        """{gauge key: value} of what this op's forward, as last traced,
+        did on the host (which kernel or operand form it took, what its
+        kernels will visit): {} for an op that witnesses nothing. The
+        ONE place an op says it; `GraphExecutor.traced_gauges` adds the
+        ops' dicts up key by key and publishes the sums to the registry's
+        gauges, `FFModel.op_counters` and every trace header (there under
+        the key's last dotted part with `/` as `_`). Values are 0 before
+        a forward has been traced."""
+        return {}
+
     def __init__(self, layer: Layer, input_shapes: Sequence[Tuple[int, ...]]):
         self.layer = layer
         self.name = layer.name

@@ -215,9 +215,7 @@ class MoELayer(Op):
         self.kernel_init = (p.get("kernel_initializer")
                             or DefaultWeightInitializer())
         self._counters = None
-        # set when a forward has been traced (`moe_gather_combine_ops`,
-        # `moe_sum_rows_ops`)
-        self._gather_combine = False
+        # whether the forward, as last traced, summed rows by the kernel
         self._sum_rows = False
         super().__init__(layer, input_shapes)
 
@@ -330,11 +328,9 @@ class MoELayer(Op):
             return y.reshape(b, s, d).astype(x.dtype), r["load"], \
                 r["overflow"]
 
-        # for `executor.moe_gather_combine_ops`: rows went out to and came
-        # back from the experts by gathers (this is trace time); for
-        # `executor.moe_sum_rows_ops`: `tokens_from_rows`, for the combine
-        # and for the dispatch's backward, summed them by the kernel
-        self._gather_combine = True
+        # for `executor.moe_sum_rows_ops` (this is trace time):
+        # `tokens_from_rows`, for the combine and for the dispatch's
+        # backward, sums the rows by the kernel
         self._sum_rows = sums_rows_by_kernel(rows, d, b * s, self.k)
         y, load, overflow = scoped("moe_layer", layer)(params, x, x_router)
         load = load.astype(jnp.float32)
@@ -345,6 +341,13 @@ class MoELayer(Op):
                 "mean", jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0)),
         }
         return [y]
+
+    def traced_gauges(self):
+        """`executor.moe_sum_rows_ops`: the forward, as last traced, had
+        `tokens_from_rows` add the buffer's rows into their tokens by the
+        kernel `moe_sum_rows` (PR 37; 0 where the layer holds all its
+        experts, and on the CPU)."""
+        return {"executor.moe_sum_rows_ops": int(bool(self._sum_rows))}
 
     def output_dim_roles(self):
         # routing sorts the tokens of the whole batch into one buffer: the

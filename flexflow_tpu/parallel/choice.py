@@ -97,17 +97,15 @@ class ExecPlan:
 
 
 def _flash_was_enumerable(op, comp_mode) -> bool:
-    """Mirror of the native flash gate (ffs_strategy.hpp kernel_gate):
-    whether a ``_k:flash`` twin could exist for this op. Where the gate
-    excluded flash (dropout, tile divisibility, cross-attention) the
-    search never priced it, and the availability-based pick must
-    survive: eval and serve forwards may run flash legally."""
-    from flexflow_tpu.ops.pallas_kernels import flash_shape_legal
+    """Whether a ``_k:flash`` twin could exist for this op: the op's own
+    route names nothing that blocks the kernels whatever the platform
+    (the native flash gate, ffs_strategy.hpp kernel_gate, mirrors the
+    same rule). Where the gate excluded flash (dropout, tile
+    divisibility, cross-attention) the search never priced it, and the
+    availability-based pick must survive: eval and serve forwards may
+    run flash legally."""
     try:
-        _, s, _ = op.input_shapes[0]
-        sk = op.input_shapes[1][1] if len(op.input_shapes) > 1 else s
-        return (sk == s and flash_shape_legal(s, op.head_dim, op.num_heads)
-                and not (comp_mode == CompMode.TRAINING and op.dropout > 0))
+        return op.route({}, comp_mode == CompMode.TRAINING).blocked is None
     except Exception:
         return False
 

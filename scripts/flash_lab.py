@@ -33,7 +33,7 @@ step runs it), at the five grouped-query shapes of the decoder cells
 (laguna's 64 : 8 window and 48 : 8 full ops, sdar's 8 : 1, smallthinker's
 7 : 1, nemotron's 4 : 1), in two forms: `repeated` (K and V repeated to
 [B, S, H*128] ahead of the kernels, the shipped form until PR 43, made
-here by steering the op's `_takes_grouped_kv` to no) and `grouped` (the
+here by holding the op's route to `grouped_kv=False`) and `grouped` (the
 kernels read K and V at the KV heads and add a group's dK / dV up in
 their resident float32 panel: what ships). A line holds both forms'
 device ms, their ops by stem, and the largest difference of the value
@@ -49,6 +49,7 @@ no device in the trace, so `device_ms` is null).
 """
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -145,7 +146,9 @@ def grouped_op_lines(tiny):
                 bias=False))
             op = OpRegistry.create(layer, [(1, seq, hidden)] * 3)
             if form == "repeated":
-                op._takes_grouped_kv = lambda *a: False
+                route = op.route
+                op.route = lambda *a, route=route, **k: dataclasses.replace(
+                    route(*a, **k), grouped_kv=False)
 
             def run(params, x, g, op=op):
                 ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
@@ -161,7 +164,7 @@ def grouped_op_lines(tiny):
                 0.02 * rs.randn(*a.shape), a.dtype), (shapes, x, x))
             jitted[form] = (jax.jit(run), args)
             outs[form] = jax.block_until_ready(jitted[form][0](*args))
-            assert op._flash_grouped_kv == (form != "repeated"), (name, form)
+            assert op._route.grouped_kv == (form != "repeated"), (name, form)
         (a, da), (b, db) = outs["grouped"], outs["repeated"]
         line = dict(
             shape="grouped." + name, seq=seq, hidden=hidden,

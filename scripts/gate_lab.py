@@ -72,6 +72,7 @@ nothing). Nothing here is a benchmark metric.
 """
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -227,7 +228,9 @@ def in_context_pieces(name, seq, hidden, props):
         op = OpRegistry.create(layer, [(1, seq, hidden)] * 3)
         if not lanes:
             # the lab steers the op to the form it compares against
-            op._rotates_in_lanes = lambda *a: False
+            route = op.route
+            op.route = lambda *a, **k: dataclasses.replace(
+                route(*a, **k), rotary_in_lanes=False)
         return op
 
     out = {}

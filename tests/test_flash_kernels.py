@@ -14,6 +14,8 @@ The kernels run in interpret mode on the CPU: values and structure, no
 times.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -570,7 +572,7 @@ def _attention_op(h, hk, d, e, s, b=1, **props):
 
 
 # what the op does with grouped-query keys, by the one rule on static
-# shapes (`_takes_grouped_kv`): (heads, KV heads, head_dim, mesh axes,
+# shapes (`MultiHeadAttention.route`): (heads, KV heads, head_dim, mesh axes,
 # whether the flash kernels get the keys at the KV heads)
 GROUPED_OPS = {
     "heads_of_128": (4, 2, 128, None, True),
@@ -593,8 +595,8 @@ def test_the_op_hands_over_grouped_keys_where_the_shapes_allow(
         case, monkeypatch):
     """`MultiHeadAttention` under grouped-query attention: where the
     rule admits it the flash kernels get K and V as [B, S, Hk*D]
-    (`_flash_grouped_kv`, counted by `executor.flash_grouped_kv_ops`),
-    elsewhere the repeat stays; either way the op's output and every
+    (the route's `grouped_kv`, counted by
+    `executor.flash_grouped_kv_ops`), elsewhere the repeat stays; either way the op's output and every
     gradient match the same op steered to the repeat."""
     from flexflow_tpu.machine import make_mesh
     from flexflow_tpu.ops.base import OpContext
@@ -606,7 +608,9 @@ def test_the_op_hands_over_grouped_keys_where_the_shapes_allow(
     props = dict(head_parallel="model") if axes else {}
     op = _attention_op(h, hk, d, e, s, b, **props)
     steered = _attention_op(h, hk, d, e, s, b, **props)
-    steered._takes_grouped_kv = lambda *a: False
+    route = steered.route
+    steered.route = lambda *a, **k: dataclasses.replace(
+        route(*a, **k), grouped_kv=False)
     params = op.init_params(jax.random.PRNGKey(0))
     rs = np.random.RandomState(5)
     x, g = (jnp.asarray(rs.randn(b, s, e).astype(np.float32))
@@ -620,8 +624,9 @@ def test_the_op_hands_over_grouped_keys_where_the_shapes_allow(
         return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
 
     got, want = run(op), run(steered)
-    assert op._flash_grouped_kv == grouped and op._flash_lane_dense
-    assert not steered._flash_grouped_kv
+    assert op._route.grouped_kv == grouped and op._route.core == "flash"
+    assert op.traced_gauges()["executor.flash_grouped_kv_ops"] == grouped
+    assert not steered._route.grouped_kv
     np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-6)
     for (path, a), b_ in zip(jax.tree_util.tree_leaves_with_path(got[1]),
                              jax.tree.leaves(want[1])):

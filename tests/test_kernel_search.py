@@ -523,21 +523,38 @@ class TestCorpusImpl:
 
 class TestFflintKernelRules:
     @pytest.mark.analysis
-    def test_ffl208_illegal_flash_shape(self):
+    @pytest.mark.parametrize("seq,props", [
+        (96, dict(num_heads=4)),        # 96 % 128 != 0
+        # a latent op whose rotated width the kernels refuse (128 lanes
+        # do not divide by 48): legal by seq, head_dim and heads alone,
+        # which is all the parent's FFL208 / FFL209 and the plan's
+        # `_flash_was_enumerable` asked, so an imported `_k:flash` passed
+        # both rules while forward ran einsum (PR 44)
+        (128, dict(num_heads=2, head_dim=128, causal=True, bias=False,
+                   q_lora_rank=16, kv_lora_rank=16, qk_rope_head_dim=48)),
+    ], ids=["seq_96", "latent_rope_48"])
+    def test_ffl208_illegal_flash_shape(self, seq, props, monkeypatch):
         from flexflow_tpu.analysis import lint_model
+        from flexflow_tpu.ffconst import CompMode
+        from flexflow_tpu.parallel.choice import _flash_was_enumerable
+        monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
         cfg = FFConfig(batch_size=4, seed=42)
         ff = FFModel(cfg)
-        x = ff.create_tensor((4, 96, 32), name="x")  # 96 % 128 != 0
-        t = ff.multihead_attention(x, x, x, 32, 4, name="attn")
+        x = ff.create_tensor((4, seq, 32), name="x")
+        t = ff.multihead_attention(x, x, x, 32, name="attn", **props)
         t = ff.dense(t, 32, name="fc")
         ff.compile(SGDOptimizer(lr=0.01),
                    LossType.MEAN_SQUARED_ERROR_AVG_REDUCE, [])
-        attn_guid = next(n.op.guid for n in ff.executor.nodes
-                         if n.op.name == "attn")
-        ff.strategy[attn_guid].choice = "dp_k:flash"  # stale/corrupt
+        attn = next(n.op for n in ff.executor.nodes if n.op.name == "attn")
+        ff.strategy[attn.guid].choice = "dp_k:flash"  # stale/corrupt
         report = lint_model(ff)
-        assert any(d.rule == "FFL208" for d in report.diagnostics), \
-            [d.rule for d in report.diagnostics]
+        d208 = [d for d in report.diagnostics if d.rule == "FFL208"]
+        assert d208 and "illegal at this shape" in d208[0].message, \
+            [(d.rule, d.message) for d in report.diagnostics]
+        # the plan and the forward agree with the rule
+        assert not _flash_was_enumerable(attn, CompMode.TRAINING)
+        assert attn.route({}, True).blocked == "shape"
+        assert attn.selected_impl(training=True) == "einsum"
 
     @pytest.mark.analysis
     def test_ffl209_platform_fallback_is_info(self, monkeypatch):

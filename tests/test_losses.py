@@ -269,7 +269,7 @@ def test_losses_that_do_not_take_class_ids_are_left_alone():
     rs = np.random.RandomState(6)
     ff.fit([rs.randn(4, 16).astype(np.float32)],
            rs.randn(4, 5).astype(np.float32), epochs=1, verbose=False)
-    assert ff.executor.loss_own_vjp() == 0
+    assert ff.executor.traced_gauges()["executor.loss_own_vjp"] == 0
     logits = jnp.asarray(rs.randn(4, 5), jnp.float32)
     onehot = jax.nn.one_hot(jnp.asarray(rs.randint(0, 5, 4)), 5)
     assert "custom_vjp_call" not in str(jax.make_jaxpr(

@@ -476,7 +476,8 @@ def test_layer_gradients_match_the_scatter_add_form(name, mode, monkeypatch):
                         argnums=(0, 1))(params, inputs)
     overflow = float(op._counters["moe/overflow_slots"][1])
     assert (overflow > 0) == ("buffer_too_small" in name)
-    assert op._gather_combine and op._sum_rows == sums_by_kernel(name, mode)
+    assert op.traced_gauges() == {
+        "executor.moe_sum_rows_ops": int(sums_by_kernel(name, mode))}
     flat_got, tree = jax.tree.flatten(got)
     flat_want, tree_want = jax.tree.flatten(want)
     assert tree == tree_want and len(flat_got) == len(params) + n_inputs

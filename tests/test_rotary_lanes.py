@@ -9,6 +9,8 @@ product and the sum it enters may or may not contract into one fused
 multiply-add, a unit in the last place that says nothing about either
 form. On the chip the two read the same (`scripts/gate_lab.py`)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -170,13 +172,15 @@ def step(op, params, x, dtype, lanes=None):
     """Loss and gradients of one training forward + backward of the op;
     ``lanes`` False steers it to the view form whatever its shapes."""
     if lanes is False:
-        op._rotates_in_lanes = lambda *a: False
+        route = op.route
+        op.route = lambda *a, **k: dataclasses.replace(
+            route(*a, **k), rotary_in_lanes=False)
     ctx = OpContext(training=True, compute_dtype=dtype)
     g = jnp.asarray(np.random.RandomState(9).randn(*x.shape), jnp.float32)
     out = jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(
         op.forward(p, [x], ctx)[0].astype(jnp.float32) * g),
         argnums=(0, 1)))(params, x)
-    return out, op._rotary_lane_dense
+    return out, op._route.rotary_in_lanes
 
 
 OPS = {
@@ -248,9 +252,9 @@ def test_one_device_only():
     from flexflow_tpu.machine import make_mesh
     op = make_op(S, 64, num_heads=2, head_dim=D, causal=True)
     mesh = make_mesh(2, {"data": 2})
-    assert not op._rotates_in_lanes(
-        OpContext(compute_dtype=jnp.float32, mesh=mesh), S, S)
-    assert op._rotates_in_lanes(OpContext(compute_dtype=jnp.float32), S, S)
+    assert not op.route(dict(zip(mesh.axis_names, mesh.devices.shape)),
+                        False).rotary_in_lanes
+    assert op.route({}, False).rotary_in_lanes
 
 
 def test_decode_forward_rotates_at_its_offset_as_before():
@@ -264,7 +268,7 @@ def test_decode_forward_rotates_at_its_offset_as_before():
                     jnp.float32)
     ctx = OpContext(compute_dtype=jnp.float32)
     full = op.forward(params, [x], ctx)[0]
-    assert op._rotary_lane_dense
+    assert op._route.rotary_in_lanes
     cache = jnp.zeros((1, 2, t, D), jnp.float32)
     y, kc, vc = op.decode_forward(params, [x[:, :t - 1]], ctx, cache, cache,
                                   0)

@@ -202,7 +202,7 @@ def test_the_gauge_says_whether_the_loss_took_its_own_backward(model, want):
     assert obs.model_context(ff)["loss_own_vjp"] == 0          # not traced
     batch = ff.config.batch_size
     ff.fit([x[:batch] for x in xs], y[:batch], epochs=1, verbose=False)
-    assert ff.executor.loss_own_vjp() == want
+    assert ff.executor.traced_gauges()["executor.loss_own_vjp"] == want
     assert obs.model_context(ff)["loss_own_vjp"] == want
     assert obs.get_registry().to_dict()["gauges"][
         "executor.loss_own_vjp"] == want

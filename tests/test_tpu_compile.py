@@ -17,6 +17,7 @@ four-chip call.
 """
 
 import collections
+import dataclasses
 import os
 import re
 
@@ -399,7 +400,7 @@ class TestHybridDecoderKernels:
         assert "jit(moe_combine)" in hlo
         assert (compiled.memory_analysis().temp_size_in_bytes
                 < 4 * tokens * props["k"] * width)
-        assert op._sum_rows
+        assert op.traced_gauges()["executor.moe_sum_rows_ops"] == 1
         sums = [line for line in hlo.splitlines()
                 if "custom_call_target=\"tpu_custom_call\"" in line
                 and "moe_sum_rows" in line]
@@ -549,7 +550,9 @@ class TestRotaryLanes:
                                      bias=False, rope=True))
         op = OpRegistry.create(layer, [(1, seq, hidden)] * 3)
         if not lanes:
-            op._rotates_in_lanes = lambda *a: False
+            route = op.route
+            op.route = lambda *a, **k: dataclasses.replace(
+                route(*a, **k), rotary_in_lanes=False)
         one = SingleDeviceSharding(topo.devices[0])
 
         def step(params, x, g):
@@ -563,7 +566,7 @@ class TestRotaryLanes:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
             jax.eval_shape(op.init_params, jax.random.PRNGKey(0)))
         hlo = _compile(step, params, x, x)
-        assert op._rotary_lane_dense == lanes
+        assert op._route.rotary_in_lanes == lanes
         return hlo
 
     @staticmethod
