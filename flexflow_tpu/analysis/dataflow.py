@@ -317,6 +317,15 @@ def required_input_specs(node, getspec, getparam) -> List[Any]:
             reqs.append(tuple(r))
         return reqs
 
+    if t == OperatorType.SHORT_CONV:
+        # [B, S, E] in and out: the batch follows the output; a position
+        # reads the K - 1 before it and the products contract over E,
+        # so the sequence and the lanes arrive whole
+        r = [None] * len(in_shapes[0])
+        if r and in_shapes[0][0] == out_shape[0]:
+            r[0] = out0[0]
+        return [tuple(r)]
+
     if t == OperatorType.BATCHMATMUL:
         reqs = []
         for s in in_shapes:

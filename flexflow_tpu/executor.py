@@ -54,6 +54,18 @@ def settled_spec(spec: P) -> P:
     return P(*entries)
 
 
+def op_params(op, params) -> Dict[str, jax.Array]:
+    """The parameters ``op``'s forward reads: its own subtree of
+    ``params`` and, for an op with ``tied_params``, the leaves it reads
+    out of their owners' subtrees (a tied head's table). One array, two
+    readers: differentiated through both."""
+    own = params.get(op.name, {})
+    if not op.tied_params:
+        return own
+    return {**own, **{local: params[owner][leaf] for local, (owner, leaf)
+                      in op.tied_params.items()}}
+
+
 class OpNode:
     """One materialized operator + where its inputs come from.
 
@@ -648,7 +660,7 @@ class GraphExecutor:
                                             "init_state"):
                             new_state.setdefault(s, state[s])
             elif hasattr(op, "init_state"):
-                outs = forward(params.get(op.name, {}), args,
+                outs = forward(op_params(op, params), args,
                                state.get(op.name))
                 if getattr(op, "_new_state", None) is not None:
                     new_state[op.name] = op._new_state
@@ -664,9 +676,9 @@ class GraphExecutor:
                 # carries it too
                 outs = jax.checkpoint(
                     lambda p_, a_: tuple(forward(p_, list(a_)))
-                )(params.get(op.name, {}), tuple(args))
+                )(op_params(op, params), tuple(args))
             else:
-                outs = forward(params.get(op.name, {}), args)
+                outs = forward(op_params(op, params), args)
             if getattr(op, "_aux_loss", None) is not None:
                 aux_losses.append(op._aux_loss)
                 op._aux_loss = None

@@ -180,6 +180,8 @@ class OperatorType(enum.Enum):
     # layer over the experts held here (ops/experts.py)
     SSM_MIXER = enum.auto()
     MOE_LAYER = enum.auto()
+    # appended (PR 45): the gated short convolution (ops/short_conv.py)
+    SHORT_CONV = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

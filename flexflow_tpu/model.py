@@ -139,11 +139,28 @@ class FFModel:
     def dense(self, input: Tensor, out_dim: int,
               activation: ActiMode = ActiMode.AC_MODE_NONE, use_bias: bool = True,
               datatype: Optional[DataType] = None, kernel_initializer=None,
-              bias_initializer=None, name: Optional[str] = None) -> Tensor:
+              bias_initializer=None, tied_to: Optional[Tensor] = None,
+              name: Optional[str] = None) -> Tensor:
+        """``tied_to``: the output of an ``embedding`` whose table
+        [out_dim, in_dim] this product reads, y = x E^T, in place of a
+        kernel of its own (a tied head): the model then holds ONE leaf,
+        the embedding's, with one optimizer state, and its gradient is
+        the sum over both uses (ops/linear.py)."""
+        tie = {}
+        if tied_to is not None:
+            source = tied_to.owner_layer
+            if (source is None or source.op_type != OperatorType.EMBEDDING
+                    or use_bias):
+                raise ValueError(
+                    f"dense '{name}': tied_to takes the output of an "
+                    f"embedding layer, and no bias")
+            tie = dict(tied_to=(source.name, "kernel"),
+                       tied_shape=(source.properties["num_entries"],
+                                   source.properties["out_dim"]))
         layer = self._add_layer(OperatorType.LINEAR, [input], dict(
             out_dim=out_dim, activation=activation, use_bias=use_bias,
             kernel_initializer=kernel_initializer, bias_initializer=bias_initializer,
-        ), name, datatype)
+            **tie), name, datatype)
         return self._finish(layer)
 
     def conv2d(self, input: Tensor, out_channels: int, kernel_h: int, kernel_w: int,
@@ -305,6 +322,18 @@ class FFModel:
             chunk_size=chunk_size, eps=eps, time_step_min=time_step_min,
             time_step_max=time_step_max, time_step_floor=time_step_floor,
             kernel_initializer=kernel_initializer), name)
+        return self._finish(layer)
+
+    def short_conv(self, input: Tensor, kernel: int = 3,
+                   output_gate: bool = True, kernel_initializer=None,
+                   name: Optional[str] = None) -> Tensor:
+        """Gated short convolution over [B, S, E] (ops/short_conv.py):
+        [B ; C ; x] = h W_in, a causal depthwise convolution of
+        ``kernel`` taps over B * x, the gate C, then W_out; no bias, no
+        activation. ``output_gate=False`` leaves C out (a control)."""
+        layer = self._add_layer(OperatorType.SHORT_CONV, [input], dict(
+            kernel=kernel, kernel_initializer=kernel_initializer,
+            **({} if output_gate else {"output_gate": False})), name)
         return self._finish(layer)
 
     def moe_layer(self, input: Tensor, n_experts: int, k: int,

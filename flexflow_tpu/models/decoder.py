@@ -61,9 +61,19 @@ family.
     S   the same under a sliding window of ``sliding_window_size`` keys,
         rotary by ``rope_parameters["sliding_attention"]``
 
+    C   a gated short convolution, then a feed-forward, each behind its
+        own norm and residual (PR 45). The mixer (ops/short_conv.py):
+        [B ; C ; x] = h W_in, a causal depthwise convolution of
+        ``conv_L_cache`` taps over B * x, the gate C, then W_out; no
+        bias, no activation. Feed-forward as `F`'s
+
 ``layer_types`` (a public config's list of "full_attention" /
-"sliding_attention", one entry a layer that runs) stands for the pattern:
-`F` and `S` in its order. ``num_attention_heads_per_layer`` and
+"sliding_attention" / "conv", one entry a layer that runs) stands for
+the pattern: `F`, `S` and `C` in its order. ``num_dense_layers`` is the
+short form of ``mlp_layer_types``: the layers below it are "dense", the
+others "sparse". ``qk_layernorm`` gives `F` / `S` an RMS norm of every
+query and key head ahead of rotary (scales of ``head_dim``, shared by
+the heads). ``num_attention_heads_per_layer`` and
 ``mlp_layer_types`` are read at the layer's index and may be longer than
 the pattern (a model cut in depth can keep its published lists); a
 model-wide ``num_attention_heads``, ``rope_theta`` and an all-"sparse"
@@ -87,7 +97,9 @@ then names the halves, and an epoch's ``ff.op_counters`` hold
 cross-entropies over their targets.
 
 After the last block ``rms_norm`` and the head, ``logits = x W_head``
-(untied); train with ``SPARSE_CATEGORICAL_CROSSENTROPY`` on labels
+(untied), or with ``tie_word_embeddings`` ``logits = x E^T`` with E the
+table ``embed_tokens`` gathers from: ONE leaf, read by both ops
+(``FFModel.dense(..., tied_to=)``); train with ``SPARSE_CATEGORICAL_CROSSENTROPY`` on labels
 ``[B, S]``. A pattern with ``D`` keeps the noised half alone from there
 on (logits ``[B, L, V]``) and trains with
 ``WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY`` on labels ``[B, L, 2]``
@@ -173,6 +185,14 @@ class DecoderConfig:
     rope_parameters: Optional[dict] = None
     gating: bool = False
     gate_activation: str = "softplus"
+    qk_layernorm: bool = False
+    num_dense_layers: Optional[int] = None
+    # `C`: the short convolution's taps (`conv_output_gate` False is a
+    # control: the gate C left out)
+    conv_L_cache: int = 3
+    conv_output_gate: bool = True
+    # the head reads the embedding's table (one leaf) in place of its own
+    tie_word_embeddings: bool = False
     # the multi-token-prediction module: 0 or 1; `mtp_shift` is the
     # distance of the token whose embedding it reads (1; 0 is a control)
     num_nextn_predict_layers: int = 0
@@ -268,15 +288,15 @@ def _llama_block(ff, t, i, cfg):
     return ff.add(t, _swiglu_mlp(ff, h, cfg, f"l{i}"), name=f"l{i}_res2")
 
 
-def _attention_ffn_block(ff, t, prefix, cfg, attention, experts,
+def _attention_ffn_block(ff, t, prefix, cfg, mixer, experts,
                          shared_width):
-    """x' = x + attention(norm(x)), x'' = x' + f(norm(x')) with f the
+    """x' = x + mixer(norm(x)), x'' = x' + f(norm(x')) with f the
     SwiGLU MLP or the sigmoid-scored experts with their gated shared
-    expert: the block of `A` / `X` and of `F` / `S`, which differ in
-    ``attention(h, name)``."""
+    expert (none at ``shared_width`` 0): the block of `A` / `X` and of
+    `F` / `S` / `C`, which differ in ``mixer(h)``."""
     eps = cfg.layer_norm_epsilon
     h = ff.rms_norm(t, eps=eps, name=f"{prefix}_norm")
-    t = ff.add(t, attention(h, f"{prefix}_attn"), name=f"{prefix}_res1")
+    t = ff.add(t, mixer(h), name=f"{prefix}_res1")
     g = ff.rms_norm(t, eps=eps, name=f"{prefix}_post_norm")
     if not experts:
         return ff.add(t, _swiglu_mlp(ff, g, cfg, prefix, one_product=True),
@@ -297,7 +317,7 @@ def _latent_block(ff, t, prefix, cfg, experts):
         raise ValueError("decoder: latent attention takes a value head as "
                          "wide as the query/key head's not-rotated part")
 
-    def attention(h, name):
+    def attention(h):
         return ff.multihead_attention(
             h, h, h, cfg.hidden_size, cfg.num_attention_heads, bias=False,
             causal=True, rope=True, rope_theta=cfg.rope_theta,
@@ -305,14 +325,15 @@ def _latent_block(ff, t, prefix, cfg, experts):
             kv_lora_rank=cfg.kv_lora_rank,
             qk_rope_head_dim=cfg.qk_rope_head_dim,
             latent_norm_eps=cfg.layer_norm_epsilon,
-            rope_whole_head=cfg.rope_whole_head, name=name)
+            rope_whole_head=cfg.rope_whole_head, name=f"{prefix}_attn")
 
     return _attention_ffn_block(
         ff, t, prefix, cfg, attention, experts,
         cfg.n_shared_experts * cfg.moe_intermediate_size)
 
 
-LAYER_TYPE_LETTERS = {"full_attention": "F", "sliding_attention": "S"}
+LAYER_TYPE_LETTERS = {"full_attention": "F", "sliding_attention": "S",
+                      "conv": "C"}
 LETTER_LAYER_TYPES = {v: k for k, v in LAYER_TYPE_LETTERS.items()}
 
 
@@ -323,18 +344,26 @@ def _of_layer(values, i, default):
 
 def _gated_block(ff, t, i, cfg, letter):
     """`F` / `S`: layer i's own heads, its kind's rotary parameters and
-    window, the gate; then layer i's kind of feed-forward."""
+    window, the heads' norm, the gate; `C`: the gated short convolution;
+    then layer i's kind of feed-forward."""
     rope = dict((cfg.rope_parameters or {}).get(
         LETTER_LAYER_TYPES[letter]) or {})
     theta = float(rope.pop("rope_theta", cfg.rope_theta))
     partial = float(rope.pop("partial_rotary_factor", 1.0))
     scaled = rope.get("rope_type", "default") != "default"
-    feed_forward = _of_layer(cfg.mlp_layer_types, i, "sparse")
+    feed_forward = _of_layer(
+        cfg.mlp_layer_types, i,
+        "dense" if i < (cfg.num_dense_layers or 0) else "sparse")
     if feed_forward not in ("dense", "sparse"):
         raise ValueError(f"decoder: mlp_layer_types[{i}] is "
                          f"{feed_forward!r} (known: dense, sparse)")
 
-    def attention(h, name):
+    def short_conv(h):
+        return ff.short_conv(h, kernel=cfg.conv_L_cache,
+                             output_gate=cfg.conv_output_gate,
+                             name=f"b{i}_conv")
+
+    def attention(h):
         return ff.multihead_attention(
             h, h, h, cfg.hidden_size,
             _of_layer(cfg.num_attention_heads_per_layer, i,
@@ -344,9 +373,12 @@ def _gated_block(ff, t, i, cfg, letter):
             window=cfg.sliding_window_size if letter == "S" else 0,
             gate=cfg.gating, gate_activation=cfg.gate_activation,
             partial_rotary_factor=partial,
-            rope_scaling=rope if scaled else None, name=name)
+            rope_scaling=rope if scaled else None,
+            qk_norm=cfg.qk_layernorm, qk_norm_eps=cfg.layer_norm_epsilon,
+            name=f"b{i}_attn")
 
-    return _attention_ffn_block(ff, t, f"b{i}", cfg, attention,
+    return _attention_ffn_block(ff, t, f"b{i}", cfg,
+                                short_conv if letter == "C" else attention,
                                 feed_forward == "sparse",
                                 cfg.moe_shared_expert_intermediate_size)
 
@@ -402,7 +434,7 @@ def _mixer(ff, h, letter, i, cfg):
         return ff.dense(ff.multiply(up, up, name=f"b{i}_sq"),
                         cfg.hidden_size, use_bias=False, name=name)
     raise ValueError(f"decoder pattern: unknown block letter {letter!r} "
-                     f"(known: M E * - L G W D A X F S)")
+                     f"(known: M E * - L G W D A X F S C)")
 
 
 def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
@@ -419,7 +451,7 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
                              f"(known: {sorted(LAYER_TYPE_LETTERS)})")
         pattern = "".join(LAYER_TYPE_LETTERS[k] for k in cfg.layer_types)
     for i, letter in enumerate(pattern):
-        if letter in "FS":
+        if letter in "FSC":
             t = _gated_block(ff, t, i, cfg, letter)
             continue
         if letter in "AX":
@@ -447,5 +479,6 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
         # one head over both hidden sequences laid end to end
         t = ff.concat([t, mtp], axis=1, name="main_and_mtp")
         ff.loss_parts = ("main", "mtp")
-    t = ff.dense(t, cfg.vocab_size, use_bias=False, name="lm_head")
+    t = ff.dense(t, cfg.vocab_size, use_bias=False, name="lm_head",
+                 tied_to=embedded if cfg.tie_word_embeddings else None)
     return ff

@@ -34,6 +34,8 @@ class LlamaModelConfig:
     batch_size: int = 4
     seq_length: int = 16
     seq_parallel: Optional[str] = None  # 'seq' for ring attention
+    # the head reads the embedding's table (ONE leaf) and not a copy
+    tie_word_embeddings: bool = False
 
 
 def create_llama(cfg: LlamaModelConfig, ff_config: FFConfig = None) -> FFModel:
@@ -48,7 +50,8 @@ def create_llama(cfg: LlamaModelConfig, ff_config: FFConfig = None) -> FFModel:
         num_key_value_heads=cfg.num_key_value_heads,
         layer_norm_epsilon=cfg.rms_norm_eps, rope_theta=cfg.rope_theta,
         batch_size=cfg.batch_size, seq_length=cfg.seq_length,
-        seq_parallel=cfg.seq_parallel), ff_config)
+        seq_parallel=cfg.seq_parallel,
+        tie_word_embeddings=cfg.tie_word_embeddings), ff_config)
 
 
 def import_hf_weights(ff: FFModel, hf_model) -> int:
@@ -91,8 +94,11 @@ def import_hf_weights(ff: FFModel, hf_model) -> int:
         put(f"l{i}_up_proj", sd[p + "mlp.up_proj.weight"].T)
         put(f"l{i}_down_proj", sd[p + "mlp.down_proj.weight"].T)
     put("final_ln", sd["model.norm.weight"], "scale")
+    if not ff.params.get("lm_head"):
+        # built with `tie_word_embeddings`: the head reads the table
+        return copied
     lm = sd.get("lm_head.weight")
-    if lm is None:  # tied embeddings
+    if lm is None:  # a tied checkpoint into an untied model: a copy
         lm = sd["model.embed_tokens.weight"]
     put("lm_head", lm.T)
     return copied

@@ -76,8 +76,9 @@ def scoped(name: str, fn: Callable) -> Callable:
     / `moe_grouped_matmul` / `moe_shared`, `attention_window` /
     `attention_full` / `attention_block_diffusion` with `flash_window` /
     `flash_full` / `flash_block_diffusion` in them, and
-    `attention_plain`. The benchmark's readers match nine of them as
-    bare substrings of an `op_name`, so a new name holds none of them.
+    `attention_plain`; `gated_conv` inside `op_short_conv` (PR 45). The
+    benchmark's readers match nine of them as bare substrings of an
+    `op_name`, so a new name holds none of them.
 
     A nested call also renames the events of the kernels in it: XLA
     names a custom call after the innermost scope (`flash_window.N`,
@@ -101,6 +102,12 @@ class Op:
     # the op's forward names its own nested calls (`scoped`); the
     # executor wraps every other op in one named for its kind
     scopes_itself: bool = False
+    # leaves this op's forward reads out of ANOTHER op's parameters:
+    # {name in this op's `params`: (owner op's name, its leaf's name)}.
+    # The owner holds the one leaf (and its optimizer state); the
+    # executor hands it in (`executor.op_params`), inside the
+    # differentiated function, so its gradient sums every use
+    tied_params: Dict[str, Tuple[str, str]] = {}
 
     def traced_gauges(self) -> Dict[str, float]:
         """{gauge key: value} of what this op's forward, as last traced,
@@ -152,6 +159,10 @@ class Op:
 
     def params_elems(self) -> int:
         return 0
+
+    def tied_param_shapes(self) -> Dict[str, Tuple[int, ...]]:
+        """Shapes of the ``tied_params``, for who runs the op alone."""
+        return {}
 
     def param_key(self) -> Tuple:
         """Structural identity for node dedup / cost caching

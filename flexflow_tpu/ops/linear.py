@@ -43,14 +43,28 @@ class Linear(Op):
         self.use_bias = layer.get_property("use_bias", True)
         self.kernel_init = layer.get_property("kernel_initializer") or DefaultWeightInitializer()
         self.bias_init = layer.get_property("bias_initializer") or DefaultBiasInitializer()
+        # a tied product reads ANOTHER op's leaf, a table [out_dim,
+        # in_dim] (an embedding's), as y = x E^T and holds none of its own
+        tied = layer.get_property("tied_to")
+        self._tied_traced = False
+        if tied:
+            self.tied_params = {"kernel": tuple(tied)}
+            self.tied_shape = tuple(layer.get_property("tied_shape"))
         super().__init__(layer, input_shapes)
         self.in_dim = self.input_shapes[0][-1]
+        if tied and self.tied_shape != (self.out_dim, self.in_dim):
+            raise ValueError(
+                f"dense '{layer.name}': the tied table is "
+                f"{self.tied_shape}, the product needs "
+                f"{(self.out_dim, self.in_dim)}")
 
     def compute_output_shapes(self):
         (in_shape,) = self.input_shapes
         return [tuple(in_shape[:-1]) + (self.out_dim,)]
 
     def init_params(self, rng):
+        if self.tied_params:
+            return {}
         in_dim = self.input_shapes[0][-1]
         k1, k2 = jax.random.split(rng)
         params = {"kernel": self.kernel_init(k1, (in_dim, self.out_dim))}
@@ -61,9 +75,13 @@ class Linear(Op):
     def forward(self, params, inputs, ctx: OpContext):
         (x,) = inputs
         w = params["kernel"].astype(ctx.compute_dtype)
-        y = jnp.dot(
-            x.astype(ctx.compute_dtype), w, preferred_element_type=jnp.float32
-        )
+        if self.tied_params:
+            self._tied_traced = True
+            y = jnp.einsum("...e,ve->...v", x.astype(ctx.compute_dtype), w,
+                           preferred_element_type=jnp.float32)
+        else:
+            y = jnp.dot(x.astype(ctx.compute_dtype), w,
+                        preferred_element_type=jnp.float32)
         if self.use_bias:
             y = y + params["bias"]
         y = apply_activation(y, self.activation)
@@ -81,5 +99,17 @@ class Linear(Op):
         batch = int(np.prod(self.input_shapes[0][:-1]))
         return 2 * batch * self.in_dim * self.out_dim
 
+    def traced_gauges(self):
+        """`executor.tied_head_ops`: this product read its table out of
+        another op's leaf when last traced (only a tied op says so)."""
+        if not self.tied_params:
+            return {}
+        return {"executor.tied_head_ops": int(self._tied_traced)}
+
+    def tied_param_shapes(self):
+        return {"kernel": self.tied_shape} if self.tied_params else {}
+
     def params_elems(self):
+        if self.tied_params:
+            return 0
         return self.in_dim * self.out_dim + (self.out_dim if self.use_bias else 0)

@@ -1635,6 +1635,197 @@ def rotary_lanes(x, cos, sin, half: int, dtype, norm=None):
                          pallas_mode() == "interpret")
 
 
+# ---------------------------------------------------------------------------
+# the gated short convolution's pass between its two products (PR 45):
+# y = C * conv(B * x), a causal depthwise convolution of K taps a lane.
+# XLA writes B * x out in float32, reads it back once a tap and splits
+# the backward into five fusions: 2.8 GB an op, forward and backward, at
+# 16,384 x 2048 where the operands and results are 0.74 GB (deviceless
+# count, scripts/conv_lab.py). Here every operand is read once and every
+# result written once; the K - 1 rows a block needs of its neighbour
+# come as a second, 16-row block of the same array.
+
+CONV_ROWS = 256     # rows a block of `gated_conv_lanes`, where S allows
+CONV_LANES = 512    # lanes worked through at a time inside a block
+CONV_HALO = 16      # rows of the neighbouring block fetched (a bf16 tile)
+MAX_CONV_TAPS = 8   # the taps' gradient is one [8, E] block
+MAX_CONV_WIDTH = 4096   # E: a block holds rows x 3 E of the projection
+
+
+def gated_conv_shape_legal(seq_len: int, width: int, taps: int) -> bool:
+    """What `gated_conv_lanes` takes: whole blocks of rows (the flash
+    kernels' tile), whole 128-lane columns a third, taps a halo's rows
+    cover."""
+    return (seq_len > 0 and seq_len % BLK_Q == 0 and width % LANES == 0
+            and width <= MAX_CONV_WIDTH and 1 <= taps <= MAX_CONV_TAPS)
+
+
+def _rows_from_before(x, before, shift: int):
+    """x [rows, W] moved ``shift`` rows down: row t holds x[t - shift],
+    its first ``shift`` rows the last ones of ``before`` [8, W] (the 8
+    rows ahead of the block; zeros at a sample's start)."""
+    if not shift:
+        return x
+    row = jax.lax.broadcasted_iota(jnp.int32, before.shape, 0)
+    head = jnp.where(row < shift, pltpu.roll(before, shift, 0),
+                     pltpu.roll(x[:8], shift, 0))
+    return jnp.concatenate([head, pltpu.roll(x, shift, 0)[8:]], axis=0)
+
+
+def _rows_from_after(x, after, shift: int):
+    """x [rows, W] moved ``shift`` rows up: row t holds x[t + shift], its
+    last ``shift`` rows the first ones of ``after`` [8, W] (the 8 rows
+    past the block; zeros at a sample's end)."""
+    if not shift:
+        return x
+    rows = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, after.shape, 0)
+    tail = jnp.where(row >= 8 - shift, pltpu.roll(after, 8 - shift, 0),
+                     pltpu.roll(x[rows - 8:], 8 - shift, 0))
+    return jnp.concatenate([pltpu.roll(x, rows - shift, 0)[:rows - 8], tail],
+                           axis=0)
+
+
+def _gated_conv_kernel(*refs, width: int, taps: int, gate: bool,
+                       transposed: bool):
+    """One block of rows of the projection [B ; C ; x], all 3 E lanes of
+    it, worked through CONV_LANES at a time; float32 inside, every
+    result rounded once. Forward: u = B x (and the 8 rows ahead of the
+    block, zeros in a sample's first block), conv_t = sum_j w_j
+    u_{t-(K-1)+j}, y = C conv. ``transposed``: the backward, which forms
+    u and conv again: dC = dy conv, e = dy C, d_t = sum_j w_j e_{t+(K-1)-j}
+    (the 8 rows past the block, zeros in a sample's last), dB = d x,
+    dx = d B, and the block's rows of dw_j = sum_t e_t u_{t-(K-1)+j}
+    added into the one [8, E] block that every step revisits."""
+    f32 = jnp.float32
+    if transposed:
+        p_ref, dy_ref, p0_ref, p1_ref, dy1_ref, w_ref, dp_ref, dw_ref = refs
+
+        @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+        def _():
+            dw_ref[...] = jnp.zeros_like(dw_ref)
+    else:
+        p_ref, p0_ref, w_ref, y_ref = refs
+    r, blocks = pl.program_id(1), pl.num_programs(1)
+    started = (r > 0).astype(f32)
+    goes_on = (r < blocks - 1).astype(f32)
+    chunk = next(n for n in (CONV_LANES, 256, LANES) if width % n == 0)
+    for at in range(0, width, chunk):
+        of_b, of_c, of_x = (slice(k * width + at, k * width + at + chunk)
+                            for k in range(3))
+        lanes = slice(at, at + chunk)
+        b, x = p_ref[0, :, of_b].astype(f32), p_ref[0, :, of_x].astype(f32)
+        u = b * x
+        # the last 8 rows of the 16 fetched ahead of the block
+        before = (p0_ref[0, :, of_b].astype(f32)
+                  * p0_ref[0, :, of_x].astype(f32))[8:] * started
+        moved = [_rows_from_before(u, before, taps - 1 - j)
+                 for j in range(taps)]
+        conv = sum(w_ref[j:j + 1, lanes] * moved[j] for j in range(taps))
+        if not transposed:
+            if gate:
+                conv = p_ref[0, :, of_c].astype(f32) * conv
+            y_ref[0, :, lanes] = conv.astype(y_ref.dtype)
+            continue
+        e = dy_ref[0, :, lanes].astype(f32)
+        after = dy1_ref[0, :, lanes].astype(f32)[:8] * goes_on
+        if gate:
+            dp_ref[0, :, of_c] = (e * conv).astype(dp_ref.dtype)
+            e = e * p_ref[0, :, of_c].astype(f32)
+            after = after * p1_ref[0, :, of_c].astype(f32)[:8]
+        else:
+            dp_ref[0, :, of_c] = jnp.zeros((e.shape[0], chunk), dp_ref.dtype)
+        d = sum(w_ref[j:j + 1, lanes]
+                * _rows_from_after(e, after, taps - 1 - j)
+                for j in range(taps))
+        dp_ref[0, :, of_b] = (d * x).astype(dp_ref.dtype)
+        dp_ref[0, :, of_x] = (d * b).astype(dp_ref.dtype)
+        for j in range(taps):
+            dw_ref[j:j + 1, lanes] += jnp.sum(e * moved[j], axis=0,
+                                              keepdims=True)
+
+
+def _gated_conv_call(proj, w, dy, gate, interpret):
+    """`_gated_conv_kernel` over proj [B, S, 3 E] (and the cotangent
+    ``dy`` [B, S, E] for the backward): grid (batch, row block); the
+    K - 1 rows a block needs of its neighbour come as a second, 16-row
+    block of the same array."""
+    n, s, width3 = proj.shape
+    width, taps = width3 // 3, w.shape[0]
+    rows = next(r for r in (CONV_ROWS, BLK_Q) if s % r == 0)
+    per, halos = rows // CONV_HALO, s // CONV_HALO
+
+    def block(lanes):
+        return pl.BlockSpec((1, rows, lanes), lambda i, r: (i, r, 0))
+
+    def before(lanes):
+        return pl.BlockSpec((1, CONV_HALO, lanes), lambda i, r: (
+            i, jnp.maximum(r * per - 1, 0), 0))
+
+    def after(lanes):
+        return pl.BlockSpec((1, CONV_HALO, lanes), lambda i, r: (
+            i, jnp.minimum((r + 1) * per, halos - 1), 0))
+
+    tap_block = pl.BlockSpec((MAX_CONV_TAPS, width), lambda i, r: (0, 0))
+    w8 = jnp.pad(w.astype(jnp.float32), ((0, MAX_CONV_TAPS - taps), (0, 0)))
+    kernel = functools.partial(_gated_conv_kernel, width=width, taps=taps,
+                               gate=gate, transposed=dy is not None)
+    if dy is None:
+        return pl.pallas_call(
+            kernel, name="gated_conv",
+            out_shape=jax.ShapeDtypeStruct((n, s, width), proj.dtype),
+            grid=(n, s // rows),
+            in_specs=[block(width3), before(width3), tap_block],
+            out_specs=block(width),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel"),
+                vmem_limit_bytes=64 << 20),
+            interpret=interpret)(proj, proj, w8)
+    dproj, dw = pl.pallas_call(
+        kernel, name="gated_conv_bwd",
+        out_shape=(jax.ShapeDtypeStruct(proj.shape, proj.dtype),
+                   jax.ShapeDtypeStruct((MAX_CONV_TAPS, width),
+                                        jnp.float32)),
+        grid=(n, s // rows),
+        in_specs=[block(width3), block(width), before(width3),
+                  after(width3), after(width), tap_block],
+        out_specs=(block(width3), tap_block),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret)(proj, dy, proj, proj, dy, w8)
+    return dproj, dw[:taps].astype(w.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _gated_conv_lanes(proj, w, gate, interpret):
+    return _gated_conv_call(proj, w, None, gate, interpret)
+
+
+def _gated_conv_lanes_fwd(proj, w, gate, interpret):
+    return _gated_conv_call(proj, w, None, gate, interpret), (proj, w)
+
+
+def _gated_conv_lanes_bwd(gate, interpret, kept, dy):
+    return _gated_conv_call(*kept, dy, gate, interpret)
+
+
+_gated_conv_lanes.defvjp(_gated_conv_lanes_fwd, _gated_conv_lanes_bwd)
+
+
+def gated_conv_lanes(proj, w, gate: bool = True):
+    """y = C * conv(B * x) (``gate`` False: conv(B * x)) [B, S, E] for
+    proj [B, S, 3 E] = [B ; C ; x] as the projection stored it and taps
+    w [K, E]: the causal depthwise convolution conv(u)_t = sum_j w_j
+    u_{t-(K-1)+j} with zeros ahead of a sample's start, in one pass,
+    float32 inside, rounded once into proj's dtype. Its own backward is
+    one pass too: it keeps proj alone, forms u and conv again, and
+    returns d proj in proj's dtype and the taps' gradient summed over
+    the rows in the kernel. Caller checks `gated_conv_shape_legal` and
+    `pallas_mode` first."""
+    return _gated_conv_lanes(proj, w, gate, pallas_mode() == "interpret")
+
+
 def pallas_mode() -> str:
     """'tpu' (compile), 'interpret' (CPU emulation for tests), or 'off'."""
     env = os.environ.get("FLEXFLOW_TPU_PALLAS", "auto")

@@ -41,15 +41,19 @@ from flexflow_tpu.ops.base import (DimRole, Op, OpContext, register_op,
                                    scoped)
 
 
-def causal_depthwise_conv1d(x, w, b):
-    """x [B, L, C], w [K, C], b [C] -> [B, L, C]:
-    y_t = sum_j w[j] * x_{t - (K-1) + j} + b, zeros before the start."""
+def causal_depthwise_conv1d(x, w, b=None, reverse=False):
+    """x [B, L, C], w [K, C], b [C] or None -> [B, L, C] float32:
+    y_t = sum_j w[j] * x_{t - (K-1) + j} (+ b), zeros before the start.
+    ``reverse``: y_t = sum_j w[j] * x_{t + (K-1) - j}, zeros after the
+    end, which is the transpose of the first form in x (what its
+    backward applies to the cotangent)."""
     k = w.shape[0]
     length = x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    y = b.astype(jnp.float32)
+    xp = jnp.pad(x, ((0, 0), (0, k - 1) if reverse else (k - 1, 0), (0, 0)))
+    y = 0.0 if b is None else b.astype(jnp.float32)
     for j in range(k):
-        y = y + xp[:, j:j + length].astype(jnp.float32) * w[j].astype(
+        at = k - 1 - j if reverse else j
+        y = y + xp[:, at:at + length].astype(jnp.float32) * w[j].astype(
             jnp.float32)
     return y
 

@@ -219,6 +219,9 @@ def measure_op(op, repeats: int = 3, warmup: int = 1,
         return _CACHE[op_cost_key(op)]
     rs = np.random.RandomState(0)
     params = op.init_params(jax.random.PRNGKey(0))
+    # a leaf the op reads out of another op's parameters: alone, its own
+    params.update({name: jnp.zeros(shape, jnp.float32)
+                   for name, shape in op.tied_param_shapes().items()})
     inputs = _example_inputs(op, rs)
     rng = jax.random.PRNGKey(1)
 

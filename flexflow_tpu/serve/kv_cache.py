@@ -95,6 +95,13 @@ def init_kv_cache(ff, batch: Optional[int] = None,
     if max_len <= 0:
         raise ValueError("model has no sequence dim to cache")
     dtype = dtype or ff.executor.compute_dtype
+    for node in ff.executor.nodes:
+        if node.op.op_type == OperatorType.SHORT_CONV:
+            raise NotImplementedError(
+                f"'{node.op.name}' is a short convolution: a new token "
+                f"reads the K - 1 positions before it, which this cache "
+                f"does not hold; serving a convolution layer's state is "
+                f"not built")
     caches: Dict[str, Dict[str, Any]] = {}
     for node in _attention_nodes(ff):
         op = node.op
@@ -208,6 +215,7 @@ class DecodeSession:
                       if n.op.op_type == OperatorType.MULTIHEAD_ATTENTION}
 
         def step(params, state, caches, inputs, pos):
+            from flexflow_tpu.executor import op_params
             from flexflow_tpu.ops.base import OpContext
             ctx = OpContext(training=False,
                             compute_dtype=ff.executor.compute_dtype,
@@ -235,7 +243,7 @@ class DecodeSession:
                                       state=state.get(op.name))
                     op._new_state = None  # eval mode: stats don't advance
                 else:
-                    outs = op.forward(params.get(op.name, {}), args, ctx)
+                    outs = op.forward(op_params(op, params), args, ctx)
                 if getattr(op, "_aux_loss", None) is not None:
                     op._aux_loss = None  # inference: no objective
                 for i, o in enumerate(outs):

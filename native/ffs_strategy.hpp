@@ -1164,7 +1164,7 @@ inline NodeCost node_cost(const Node& n, const Choice& c, const MeshShape& mesh,
   // a col-parallel Linear runs an N/mp-wide matmul per chip, a
   // dp-sharded one an M/dp-tall one. Measured costs override all this.
   double eff = -1.0;
-  if (n.type == "LINEAR" || n.type == "CONV2D") {
+  if (n.type == "LINEAR" || n.type == "CONV2D" || n.type == "SHORT_CONV") {
     // per-chip (M, N, K) from the choice's STRUCTURED per-dim axis
     // assignments (not its name, which would rot as choices grow): each
     // sharded dim divides by its mesh-axis extent
@@ -1184,6 +1184,16 @@ inline NodeCost node_cost(const Node& n, const Choice& c, const MeshShape& mesh,
       for (size_t i = 0; i + 1 < os.size(); ++i)
         M *= (double)os[i] / dim_shards(c.out, 0, i);
       N = (double)os.back() / dim_shards(c.out, 0, os.size() - 1);
+    } else if (n.type == "SHORT_CONV" && !n.output_shapes.empty() &&
+               n.output_shapes[0].size() == 3) {
+      // the gated short convolution's FLOPs are its two products,
+      // [B S, E] x [E, 3 E] and [B S, E] x [E, E] (the depthwise taps
+      // between them are bytes, counted in interior_bytes): priced at
+      // the narrower one's efficiency, rows divided as the choice
+      // shards the batch
+      const Shape& os = n.output_shapes[0];
+      M = (double)os[0] / dim_shards(c.out, 0, 0) * (double)os[1];
+      N = K = (double)os[2];
     } else if (n.type == "CONV2D") {
       auto kit = n.params.find("kernel");  // OIHW
       if (kit != n.params.end() && kit->second.size() == 4 &&

@@ -44,7 +44,7 @@ if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu") \
                                + " --xla_force_host_platform_device_count=8")
 
 ZOO = ["mlp", "alexnet", "resnet", "resnext", "inception", "dlrm", "xdl",
-       "candle_uno", "moe", "moe_encoder", "transformer", "llama"]
+       "candle_uno", "moe", "moe_encoder", "transformer", "llama", "lfm2"]
 
 
 def build_model(name: str, ff_config):
@@ -117,6 +117,16 @@ def build_model(name: str, ff_config):
         from flexflow_tpu.models.llama import (LlamaModelConfig,
                                                create_llama)
         return create_llama(LlamaModelConfig(), ff_config), "cat"
+    if name == "lfm2":
+        # gated short convolutions 3 : 1 with grouped-query attention, a
+        # dense layer, then experts; the head reads the embedding's table
+        from flexflow_tpu.models import DecoderConfig, create_decoder
+        return create_decoder(DecoderConfig(
+            layer_types=["conv", "full_attention", "conv", "conv"],
+            num_dense_layers=1, qk_layernorm=True, head_dim=16,
+            moe_shared_expert_intermediate_size=0,
+            tie_word_embeddings=True, batch_size=8, seq_length=16),
+            ff_config), "cat"
     raise SystemExit(f"unknown --model {name!r} (zoo: {', '.join(ZOO)})")
 
 

@@ -18,7 +18,8 @@ def _compiled(cfg, **ffkw):
 
 
 class TestLlama:
-    def test_logits_match_hf(self):
+    @pytest.mark.parametrize("tied", [False, True])
+    def test_logits_match_hf(self, tied):
         torch = pytest.importorskip("torch")
         transformers = pytest.importorskip("transformers")
         hf_cfg = transformers.LlamaConfig(
@@ -26,13 +27,16 @@ class TestLlama:
             num_hidden_layers=2, num_attention_heads=4,
             num_key_value_heads=2, max_position_embeddings=64,
             rms_norm_eps=1e-6, rope_theta=10000.0,
-            attention_bias=False, tie_word_embeddings=False)
+            attention_bias=False, tie_word_embeddings=tied)
         torch.manual_seed(0)
         hf = transformers.LlamaForCausalLM(hf_cfg).eval()
 
-        cfg = LlamaModelConfig(batch_size=2, seq_length=16)
+        cfg = LlamaModelConfig(batch_size=2, seq_length=16,
+                               tie_word_embeddings=tied)
         ff = _compiled(cfg, only_data_parallel=True, workers_per_node=1)
-        assert import_hf_weights(ff, hf) == 3 + 9 * 2  # embed+final_ln+head + 9/layer
+        # embed + final_ln (+ the head's own leaf, untied) + 9 a layer
+        assert import_hf_weights(ff, hf) == 3 - tied + 9 * 2
+        assert bool(ff.params.get("lm_head")) != tied
         rs = np.random.RandomState(0)
         ids = rs.randint(0, 256, (2, 16)).astype(np.int32)
         want = hf(torch.from_numpy(ids.astype(np.int64))).logits.detach().numpy()

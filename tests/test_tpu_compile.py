@@ -428,6 +428,45 @@ class TestHybridDecoderKernels:
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+    def test_gated_conv_op_at_the_cells_widths(self, topo, on_tpu):
+        """The short convolution op of the lfm2 cell (16,384 positions,
+        2048 lanes, 3 taps, bfloat16), forward and backward: two kernels
+        between the two products, and no float32 [S, E] array written
+        outside them (XLA's own fusions of the pass write four)."""
+        from flexflow_tpu.ffconst import OperatorType
+        from flexflow_tpu.layer import Layer
+        from flexflow_tpu.obs.inspect import arrays_between_fusions
+        from flexflow_tpu.ops.base import OpContext, OpRegistry
+        seq, width = 16384, 2048
+        assert pk.gated_conv_shape_legal(seq, width, 3)
+        one = SingleDeviceSharding(topo.devices[0])
+        op = OpRegistry.create(Layer(OperatorType.SHORT_CONV, "conv", []),
+                               [(1, seq, width)])
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, jnp.float32 if a.shape[0] == 3 else jnp.bfloat16,
+                sharding=one),
+            jax.eval_shape(op.init_params, jax.random.PRNGKey(0)))
+        x = jax.ShapeDtypeStruct((1, seq, width), jnp.bfloat16, sharding=one)
+
+        def hlo_of(pallas):
+            ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
+            if not pallas:
+                op.in_one_pass = lambda *a: False
+            text = _compile(jax.grad(lambda p, x: op.forward(
+                p, [x], ctx)[0].astype(jnp.float32).sum(), argnums=(0, 1)),
+                params, x)
+            assert op.traced_gauges()[
+                "executor.gated_conv_kernel_ops"] == int(pallas)
+            return text
+
+        hlo = hlo_of(True)
+        assert pallas_kernel_count(hlo) == 2
+        assert not arrays_between_fusions(hlo, "f32", seq * width)
+        assert len(arrays_between_fusions(hlo_of(False), "f32",
+                                          seq * width)) >= 3
+
+
 class TestFusedAdam:
     KW = dict(beta1=0.9, beta2=0.999, eps=1e-8, wd=1e-4)
 
