@@ -283,6 +283,10 @@ class AttentionRoute:
     grouped_kv: bool = False
     # the heads' norm and rotary run as the one lane-dense pass
     rotary_in_lanes: bool = False
+    # the blocked flash kernels take a block's reachable positions as
+    # ONE tile, no chunk loop (`pallas_kernels.one_span`: a causal
+    # window narrower than a K chunk at S past the whole-tile kernels)
+    one_span: bool = False
     # of the flash forward, a head: (visited, total, masked) K blocks,
     # and under a window that hides something (the (query, key) pairs in
     # the tiles the kernels work through, forward and backward; twice the
@@ -672,10 +676,11 @@ class MultiHeadAttention(Op):
             pk.grouped_kv_shape_legal(h, hk, d)
             and (shard_axes is None or shard_axes[1] is None
                  or hk % mesh_axes[shard_axes[1]] == 0))
-        kind = (sq, self.causal, self.window, self.block_diffusion)
+        kind = (sq, self.causal, self.window, self.block_diffusion,
+                self.rope_dim)
         return AttentionRoute(
             core, blocked, fallback, scope, shard_axes, grouped_kv,
-            rotary_in_lanes,
+            rotary_in_lanes, one_span=pk.one_span(*kind) is not None,
             kv_blocks=(*pk.kv_blocks(*kind), pk.kv_blocks_masked(*kind)),
             window_pairs=(
                 (pk.visited_pairs(*kind),
@@ -699,7 +704,9 @@ class MultiHeadAttention(Op):
         `executor.rotary_lane_dense_ops`: the heads' norm and rotary ran
         as the one lane-dense pass (PR 42);
         `executor.flash_grouped_kv_ops`: K and V reached the kernels at
-        the KV heads (PR 43); `executor.window_attention_ops` (the window
+        the KV heads (PR 43); `executor.flash_one_span_ops`: the blocked
+        flash kernels took a block's reachable positions as one tile
+        (PR 46); `executor.window_attention_ops` (the window
         hides something at this length, PR 31), `executor.
         block_diffusion_attention_ops` (PR 34), `executor.
         latent_attention_ops` (PR 39); `attention/kv_blocks_*`: the
@@ -716,6 +723,7 @@ class MultiHeadAttention(Op):
             "executor.flash_lane_dense_ops": int(route.core == "flash"),
             "executor.rotary_lane_dense_ops": int(route.rotary_in_lanes),
             "executor.flash_grouped_kv_ops": int(route.grouped_kv),
+            "executor.flash_one_span_ops": int(route.one_span),
             "executor.window_attention_ops": int(self.windowed),
             "executor.block_diffusion_attention_ops": int(
                 bool(self.block_diffusion)),

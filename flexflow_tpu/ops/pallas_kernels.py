@@ -31,7 +31,13 @@ then TWO ranges (``_k_ranges``, ``_q_ranges``), and the blocked kernels
 loop over both. Of the tiles they visit they MASK only those that hold a
 hidden pair (PR 35): each range is cut, by ``visible`` at a tile's
 extreme pairs, into sub-ranges of interior tiles, whose loop body builds
-no mask, and of edge tiles (``_k_split``, ``_q_split``).
+no mask, and of edge tiles (``_k_split``, ``_q_split``). Under a causal
+window so narrow that a block's reach fits one score tile (PR 46;
+``one_span``: window + 255 <= 1024 positions, laguna's 512)
+there is no chunk loop at all: a Q block meets the keys it reaches, a K
+block the queries that see it, as ONE masked tile each, the plain
+softmax in straight-line code (``_flash_fwd_span_kernel``,
+``_flash_bwd_span_kernel``).
 
 One operand form (PR 30): q, k, v, o and their gradients are
 [B, S, H*D], the heads side by side along the lanes, which is what the
@@ -224,7 +230,39 @@ def _seq_block(s: int, block_diffusion=None, window: int = 0) -> int:
     tile is an edge tile either way. v5e, bf16, kernels alone, 64 heads
     of 128, S = 8192, window 512, forward / backward ms (PR 41,
     `scripts/flash_lab.py --only laguna`): 4.19 / 7.97 at 1024, 3.75 /
-    5.81 at 512, 4.64 / 7.13 at 256 (a chunk's fixed cost again)."""
+    5.81 at 512, 4.64 / 7.13 at 256 (a chunk's fixed cost again).
+
+    Since PR 46 such a window does not take this loop at all: where a
+    block's reach fits one tile (``one_span``) the kernels take it as
+    ONE span, and this block is only what the chunk loop WOULD take (the
+    two-part score still does). The same shape, the same lab (PR 46,
+    `--only narrow_window`; the three rows above re-read to the digit),
+    forward / backward ms, a line's two kernels at one tile, written
+    rows of a block / positions of its span, and tiles a grid step x
+    tiles a loop iteration (``_span_tiles``; the loop carries nothing).
+    At 256 / 768 (1.55 visited pairs a visible one): 2.64 / 4.61 at
+    1 x 1; 2.22 / 3.51 at 8 x 1 and the same at 16 x 1; 2.02 / 3.18 at
+    8 x 2, 2.00 / 3.15 at 16 x 2; 1.89 / 3.01 at 16 x 4 and at 32 x 4;
+    1.97 / 3.15 at 8 x 8. At 128 / 640 (1.29): 2.81 / 5.24 at 1 x 1;
+    2.47 / 3.18 at 32 x 2; 2.16 / 3.10 at 16 x 4, 2.16 / 2.76 at 32 x 4,
+    2.15 / 2.74 at 64 x 4; 2.17 / 3.05 at 16 x 8. At 512 / 1024 (2.06):
+    2.58 / 4.95 at 1 x 1, 2.25 / 3.84 at 16 x 2. **As shipped, 256 / 768
+    forward at 32 x 4 and 128 / 640 backward at 64 x 4: 1.89 / 2.74**
+    (3.75 / 5.81 in the chunk loop). What the table says: a third of
+    the gain over the chunk loop is the visited pairs and the loop's
+    carries; a grid step's own cost is small (1 x 1 -> 8 x 1) next to
+    what the scheduler gains from several tiles in one loop iteration,
+    one tile's MXU waits filled with the next one's VPU passes (x 1 ->
+    x 4; all eight unrolled lose again); the backward's five products of
+    a [256, 768] tile are 2.6 ms at the chip's peak, so at 3.01 it is
+    bound by the pairs it visits and only the shorter block helps it,
+    and that only where a step is a head long (16 x 4 -> 64 x 4: a
+    head's 10 MB of panels are fetched behind the step before); the
+    forward is bound by the tile's element-wise passes and wants the
+    taller tile. The upper end of the rule, a window of 768 (spans 1024
+    and 896): chunk loop (chunks of 512) 4.26 / 7.63; 256 / 1024 2.77 /
+    5.49 at 1 x 1, 2.22 / 3.89 at 32 x 4; 128 / 896 2.55 / 3.70 at
+    64 x 4; as shipped 2.22 / 3.70."""
     if block_diffusion is not None:
         s = block_diffusion[0]
     most = max(window, 2 * BLK_Q) if 0 < window < 1024 else 1024
@@ -243,6 +281,84 @@ def _q_block(s: int, block_diffusion=None) -> int:
     if block_diffusion is not None:
         s = block_diffusion[0]
     return 2 * BLK_Q if s % (2 * BLK_Q) == 0 else BLK_Q
+
+
+# widest score tile (positions along the span) the one-span form takes
+MAX_SPAN = 1024
+
+
+def one_span(s: int, causal: bool, window: int = 0, block_diffusion=None,
+             rope_dim: int = 0):
+    """THE rule of the blocked kernels' form under a narrow causal window
+    (PR 46): ((Q rows, span of keys), (K rows, span of queries)), the
+    forward's and the backward's tile, where the positions a block can
+    reach, window + rows - 1 rounded up to the 128 every S is a multiple
+    of, fit ONE score tile of MAX_SPAN positions at most; None where the
+    kernels take the chunk loop. A function of what the code can
+    observe, (S, window), and of nothing else: `_flash_fwd`,
+    `_flash_bwd`, the counters (``kv_blocks``, ``kv_blocks_masked``,
+    ``visited_pairs``) and the op's route
+    (``executor.flash_one_span_ops``) all ask it.
+
+    Under a span the forward takes a Q block against the keys that end
+    with the block's last query (``_k_span``), the backward a K block
+    against the queries from its first key on (``_q_span``): one masked
+    tile each, straight-line code, the plain softmax and no sums over
+    chunks. The forward's block is ``_q_block``'s 256 rows (768 keys at a
+    window of 512: 1.5 visited pairs a visible one), the backward's 128
+    (640 queries: 1.25): the backward's five products run near the
+    MXU's peak, so fewer visited pairs are its only gain, while the
+    forward's element-wise passes want the taller tile (the lab's table
+    in ``_seq_block``). The chunk loop stays for every wider window
+    (smallthinker's 4096), for full causal, for ``block_diffusion``
+    (which never carries a window) and for the two-part score
+    (``rope_dim``; no cell's latent op has a window): a third
+    half-supported form would be worse than none. The whole-tile kernels
+    (S <= MAX_BWD_SEQ) hold the square."""
+    window = normalized_window(s, causal, window)
+    if (not window or s <= MAX_BWD_SEQ or rope_dim
+            or checked_block_diffusion(s, causal, window, block_diffusion)):
+        return None
+
+    def tile(rows):
+        return rows, -(-(window + rows - 1) // BLK_Q) * BLK_Q
+
+    forward, backward = tile(_q_block(s)), tile(BLK_Q)
+    return (forward, backward) if forward[1] <= MAX_SPAN else None
+
+
+def _k_span(q0, blk: int, span: int):
+    """First key of the ``span`` keys the one-span forward takes for the
+    Q block at ``q0``: they end with the block's last query, and at the
+    sequence's start the span is held at 0 (the mask hides what lies
+    past the diagonal). A multiple of BLK_Q. Traced or, where the
+    counters count, a Python int."""
+    return _pick(max, jnp.maximum, q0 + blk - span, 0)
+
+
+def _q_span(k0, span: int, s: int):
+    """First query of the ``span`` queries the one-span backward takes
+    for the K block at ``k0``: from the block's first key on, and at the
+    sequence's end held at S - span (the mask hides the queries before
+    ``k0``)."""
+    return _pick(min, jnp.minimum, k0, s - span)
+
+
+def _span_tiles(s: int, blk: int):
+    """(one-span tiles a grid step works through, tiles a loop
+    iteration at most): a step takes ``tiles`` blocks of ``blk`` rows,
+    each against its own span, in a loop that carries nothing and whose
+    iteration holds several tiles, so that the scheduler fills one
+    tile's MXU waits with the next one's VPU passes (``_for_rows``). As
+    many tiles as make a step a whole head up to 8,192 positions: a
+    head's panels (10 MB in the backward) are fetched while the step
+    before runs, and only a long step hides them. Past that a step's
+    rows times S stay within 2^26 (half a head at 16,384), which keeps
+    the float32 backward's panels and a step's K, V, dK, dV blocks
+    inside the 96 MiB of VMEM. The lab's table is in ``_seq_block``'s
+    docstring."""
+    return next(n for n in (64, 32, 16, 8, 4, 2, 1)
+                if n * blk * s <= 1 << 26 and s % (n * blk) == 0), 4
 
 
 # what a masked score is set to. Finite, so that a chunk of the blocked
@@ -520,13 +636,21 @@ def checked_block_diffusion(s: int, causal: bool, window: int,
     return length, b
 
 
-def _forward_tiles(s: int, causal: bool, window: int, block_diffusion):
+def _forward_tiles(s: int, causal: bool, window: int, block_diffusion,
+                   rope_dim: int = 0):
     """(every sub-range (lo, hi, edge) of K chunks that the blocked
     forward of one head loops over at sequence ``s``, Q block after Q
     block; the tiles of the whole square): what ``kv_blocks`` and
-    ``kv_blocks_masked`` count, by the kernel's own lines."""
+    ``kv_blocks_masked`` count, by the kernel's own lines. Under one
+    span (``one_span``) a Q block's tile is its one masked span, and
+    the square is cut into tiles of that size, the last of a row
+    rounded up."""
     window = normalized_window(s, causal, window)
     bd = checked_block_diffusion(s, causal, window, block_diffusion)
+    one = one_span(s, causal, window, bd, rope_dim)
+    if one is not None:
+        blk, span = one[0]
+        return [(0, 1, True)] * (s // blk), (s // blk) * -(-s // span)
     blk_q, blk_k = _q_block(s, bd), _seq_block(s, bd, window)
     cut = [sub for q0 in range(0, s, blk_q)
            for sub in _k_split(q0, blk_q, blk_k, s, causal, window, bd)[0]]
@@ -534,19 +658,23 @@ def _forward_tiles(s: int, causal: bool, window: int, block_diffusion):
 
 
 def visited_pairs(s: int, causal: bool, window: int = 0,
-                  block_diffusion=None):
+                  block_diffusion=None, rope_dim: int = 0):
     """(query, key) pairs of one head and sequence that lie in the tiles
     the flash kernels work through, forward and backward added: the
     forward's [Q block, K chunk] tiles (``_k_split``) and the backward's
-    [K block, Q chunk] tiles (``_q_split``), by the kernels' own ranges.
-    Against twice the visible pairs it says how much of the kernels' work
-    the mask then throws away. The whole-tile kernels (S <= MAX_BWD_SEQ)
-    hold the square, once each."""
+    [K block, Q chunk] tiles (``_q_split``), by the kernels' own ranges;
+    under one span (``one_span``) a [rows, span] tile a Q block and
+    one a K block. Against twice the visible pairs it says how much of
+    the kernels' work the mask then throws away. The whole-tile kernels
+    (S <= MAX_BWD_SEQ) hold the square, once each."""
     if s <= MAX_BWD_SEQ:
         return 2 * s * s
     window = normalized_window(s, causal, window)
     bd = checked_block_diffusion(s, causal, window, block_diffusion)
-    cut, _ = _forward_tiles(s, causal, window, block_diffusion)
+    one = one_span(s, causal, window, bd, rope_dim)
+    if one is not None:
+        return s * (one[0][1] + one[1][1])
+    cut, _ = _forward_tiles(s, causal, window, block_diffusion, rope_dim)
     blk = _seq_block(s, bd, window)
     forward = sum(hi - lo for lo, hi, _ in cut) * _q_block(s, bd) * blk
     backward = sum(hi - lo for k0 in range(0, s, blk) for lo, hi, _ in
@@ -564,26 +692,27 @@ def visible_pairs(s: int, causal: bool, window: int = 0) -> int:
     return w * (w + 1) // 2 + (s - w) * w
 
 
-def kv_blocks(s: int, causal: bool, window: int = 0, block_diffusion=None):
+def kv_blocks(s: int, causal: bool, window: int = 0, block_diffusion=None,
+              rope_dim: int = 0):
     """(visited, total) tiles of [a Q block, a K chunk] that the
     forward of one head works through at sequence ``s``: what the
     gauges ``attention/kv_blocks_visited`` / ``_total`` add up. The
     whole-tile kernels (S <= MAX_BWD_SEQ) hold one tile and mask."""
     if s <= MAX_BWD_SEQ:
         return 1, 1
-    cut, total = _forward_tiles(s, causal, window, block_diffusion)
+    cut, total = _forward_tiles(s, causal, window, block_diffusion, rope_dim)
     return sum(hi - lo for lo, hi, _ in cut), total
 
 
 def kv_blocks_masked(s: int, causal: bool, window: int = 0,
-                     block_diffusion=None) -> int:
+                     block_diffusion=None, rope_dim: int = 0) -> int:
     """Of ``kv_blocks``' visited tiles, those that hold a hidden pair and
     run the masked body (``_k_split``'s edge tiles): what the gauge
     ``attention/kv_blocks_masked`` adds up. The whole-tile kernels mask
     their one tile under any mask."""
     if s <= MAX_BWD_SEQ:
         return int(causal or bool(block_diffusion))
-    cut, _ = _forward_tiles(s, causal, window, block_diffusion)
+    cut, _ = _forward_tiles(s, causal, window, block_diffusion, rope_dim)
     return sum(hi - lo for lo, hi, edge in cut if edge)
 
 
@@ -725,6 +854,51 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
     o_ref[0] = o.astype(o_ref.dtype)
 
 
+def _flash_fwd_span_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                           window: int, scale: float, blk_q: int, span: int,
+                           tiles: int, unroll: int, head_dim: int):
+    """The blocked forward under a window so narrow that the keys a Q
+    block reaches are ONE score tile (``one_span``, PR 46): each of the
+    grid step's ``tiles`` Q blocks [BLK_Q, W] against the ``span`` keys
+    of the resident K/V panels that end with the block's last query
+    (``_k_span``: a ``pl.ds`` slice at a multiple of the block, not of
+    the span), as straight-line code: one product, one mask, ONE max,
+    one ``exp``, one sum, P V, the division and the logsumexp: the plain
+    softmax. No loop over chunks, no running (max, sum, accumulator), no
+    rescaling; the loop over the step's Q blocks carries nothing. Every
+    row sees its own key, so no row's sum is empty. The same products at
+    the same precision as ``_flash_fwd_kernel``: operands as stored,
+    float32 scores, statistics and lse."""
+    first = pl.program_id(2) * tiles
+
+    def block(t):
+        at = pl.multiple_of(t * blk_q, blk_q)
+        rows = pl.ds(at, blk_q)
+        q = q_ref[0, rows, :]  # [BLK_Q, W]
+        q0 = (first + t) * blk_q
+        k0 = pl.multiple_of(_k_span(q0, blk_q, span), BLK_Q)
+        k = k_ref[0, pl.ds(k0, span), :]  # [SPAN, W]
+        v = v_ref[0, pl.ds(k0, span), :]
+        tile = (blk_q, span)
+        mask = visible(
+            q0 + jax.lax.broadcasted_iota(jnp.int32, tile, 0),
+            k0 + jax.lax.broadcasted_iota(jnp.int32, tile, 1), window)
+        o = None
+        for h in range(q.shape[-1] // head_dim):
+            s = jnp.where(mask, _dot_head(q, k, h, head_dim) * scale,
+                          _MASKED)
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            # P against every head of the block, this head's lanes kept
+            oh = _only_head(_dot(p.astype(v.dtype), v, _NN) / l, h, head_dim)
+            o = oh if o is None else o + oh
+            lse_ref[0, h, 0, rows] = (m + jnp.log(l))[:, 0]
+        o_ref[0, rows, :] = o.astype(o_ref.dtype)
+
+    _for_rows(tiles, unroll, block)
+
+
 def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, *rest,
                             causal: bool, window: int, scale: float,
                             rows: int, head_dim: int, block_diffusion=None,
@@ -856,16 +1030,26 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
         )(q, k, v, *rope_ops)
-    blk = _q_block(s, bd)
+    one = one_span(s, causal, window, bd, rope_dim)
+    if one is not None:     # the reachable keys of a Q block as one tile
+        rows, span = one[0]
+        tiles, unroll = _span_tiles(s, rows)
+        kernel = functools.partial(_flash_fwd_span_kernel, window=window,
+                                   scale=scale, blk_q=rows, span=span,
+                                   tiles=tiles, unroll=unroll, head_dim=d)
+        blk = tiles * rows
+    else:
+        blk = _q_block(s, bd)
+        kernel = functools.partial(
+            _flash_fwd_kernel, causal=causal, window=window, scale=scale,
+            blk_q=blk, blk_k=_seq_block(s, bd, window), head_dim=d,
+            block_diffusion=bd, rope_dim=rope_dim)
     rope_specs = [
         pl.BlockSpec((1, blk, LANES), lambda b, j, i: (b, i, j // per)),
         pl.BlockSpec((1, s, LANES), lambda b, j, i: (b, 0, 0)),
     ] if rope_dim else []
     return pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, causal=causal, window=window,
-                          scale=scale, blk_q=blk,
-                          blk_k=_seq_block(s, bd, window),
-                          head_dim=d, block_diffusion=bd, rope_dim=rope_dim),
+        kernel,
         name=KERNEL_NAME_PREFIX + "flash_fwd",
         out_shape=out_shape,
         grid=(b, num_heads // hpb, s // blk),
@@ -1107,6 +1291,47 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dkr_ref[0] = (dkrt_ref[...] * scale).T.astype(dkr_ref.dtype)
 
 
+def _flash_bwd_span_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                           glse_ref, dq_ref, dk_ref, dv_ref, *, window: int,
+                           scale: float, blk: int, span: int, tiles: int,
+                           unroll: int, head_dim: int, grouped: bool = False):
+    """The K-blocked backward under a window so narrow that the queries
+    which see a K block are ONE score tile (``one_span``, PR 46): each
+    of the grid step's ``tiles`` K blocks meets the ``span`` queries of
+    the resident Q/O/dO panels from its first key on (``_q_span``) as
+    one masked [BLK, SPAN] tile of ``_flash_bwd_tile``, straight-line
+    code. dK^T and dV^T are complete after that tile: no scratch sums,
+    no zeroing, no ``+=``; dQ's rows of the span add into the resident
+    float32 panel as in ``_flash_bwd_blocked_kernel`` (neighbouring K
+    blocks' spans overlap). The grids, the BlockSpecs and the grouped
+    form's sums into the KV head's panels (``_group_sum``) are that
+    kernel's."""
+    j = pl.program_id(3 if grouped else 2)
+    member = pl.program_id(2) if grouped else 0
+
+    @pl.when(j == 0)
+    def _init():
+        dq_ref[0] = jnp.zeros(dq_ref.shape[1:], dq_ref.dtype)
+
+    def block(t):
+        mine = pl.ds(pl.multiple_of(t * blk, blk), blk)
+        k0 = pl.multiple_of((j * tiles + t) * blk, blk)
+        k, v = k_ref[0, mine, :], v_ref[0, mine, :]
+        q0 = pl.multiple_of(_q_span(k0, span, q_ref.shape[1]), BLK_Q)
+        rows = pl.ds(q0, span)
+        dqt, dkt, dvt = _flash_bwd_tile(
+            q_ref[0, rows, :], k, k.T, v, o_ref[0, rows, :],
+            do_ref[0, rows, :], lse_ref[0, :, :, rows],
+            glse_ref[0, :, :, rows], scale, (k0, q0, window, None), head_dim)
+        dq_ref[0, rows, :] += (dqt * scale).T
+        # a group's dK / dV block is the KV head's whole panel
+        at = (0, pl.ds(k0, blk) if grouped else mine)
+        _group_sum(dk_ref, at, (dkt * scale).T, grouped, member)
+        _group_sum(dv_ref, at, dvt.T, grouped, member)
+
+    _for_rows(tiles, unroll, block)
+
+
 def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
                interpret: bool, glse=None, window: int = 0,
                block_diffusion=None, rope=None, num_kv_heads=None):
@@ -1183,7 +1408,24 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
             interpret=interpret,
             compiler_params=_FLASH_COMPILER_PARAMS,
         )(q, k, v, o, do, lse, glse, *rope_ops))
-    blk = _seq_block(s, bd, window)
+    one = one_span(s, causal, window, bd, rope_dim)
+    if one is not None:     # the queries that see a K block as one tile
+        rows, span = one[1]
+        tiles, unroll = _span_tiles(s, rows)
+        kernel = functools.partial(_flash_bwd_span_kernel, window=window,
+                                   scale=scale, blk=rows, span=span,
+                                   tiles=tiles, unroll=unroll, head_dim=d,
+                                   grouped=grouped)
+        blk, scratch = tiles * rows, []
+    else:
+        blk = _seq_block(s, bd, window)
+        kernel = functools.partial(
+            _flash_bwd_blocked_kernel, causal=causal, window=window,
+            scale=scale, blk=blk, head_dim=d, block_diffusion=bd,
+            rope_dim=rope_dim, grouped=grouped)
+        # dK^T and dV^T (and dKr^T) added up over a block's Q chunks
+        scratch = [pltpu.VMEM((w, blk), jnp.float32)] * 2 + (
+            [pltpu.VMEM((LANES, blk), jnp.float32)] if rope_dim else [])
     seq_spec = pl.BlockSpec((1, s, w), lambda b, c, j: (b, 0, c))
     kblk_spec = pl.BlockSpec((1, blk, w), lambda b, c, j: (b, j, c))
     row_spec = pl.BlockSpec((1, hpb, 1, s), lambda b, c, j: (b, c, 0, 0))
@@ -1200,10 +1442,7 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         dkv_spec = pl.BlockSpec((1, s, w), lambda b, g, r, j: (b, 0, g))
         grid = (b, num_heads // rep, rep, s // blk)
     return finish(*pl.pallas_call(
-        functools.partial(_flash_bwd_blocked_kernel, causal=causal,
-                          window=window, scale=scale, blk=blk, head_dim=d,
-                          block_diffusion=bd, rope_dim=rope_dim,
-                          grouped=grouped),
+        kernel,
         name=KERNEL_NAME_PREFIX + "flash_bwd_blocked",
         out_shape=(jax.ShapeDtypeStruct((b, s, hd), jnp.float32),  # dq acc
                    dk_shape, dv_shape) + ((
@@ -1217,8 +1456,7 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
                       [qr_spec, kr_spec] if rope_dim else []),
         out_specs=(seq_spec, dkv_spec, dkv_spec) + (
             (qr_spec, kblk_spec) if rope_dim else ()),
-        scratch_shapes=[pltpu.VMEM((w, blk), jnp.float32)] * 2 + (
-            [pltpu.VMEM((LANES, blk), jnp.float32)] if rope_dim else []),
+        scratch_shapes=scratch,
         interpret=interpret,
         compiler_params=_FLASH_COMPILER_PARAMS,
     )(q, k, v, o, do, lse, glse, *rope_ops))

@@ -23,9 +23,18 @@ ONE rotated key a position) runs `split` twice: as it ships, and
 `split, no rotated part` (the same kernels on the 128-wide parts alone),
 so that the difference is what the second product and its operands cost.
 The narrow-window shape (PR 41: 64 heads of 128 at 8,192 positions under
-a window of 512, half of a K chunk of 1024) runs `split` at K blocks of
-1024 (what `_seq_block` gave before it took the block from the window),
-512 (what ships) and 256.
+a window of 512, half of a K chunk of 1024) runs `split`, the chunk loop
+(`one_span` held to None), at K blocks of 1024 (what `_seq_block` gave
+before it took the block from the window), 512 (what shipped until PR
+46) and 256, and then `one_span` (PR 46: a block's reachable positions
+as ONE tile, no loop and no running softmax) at blocks of 256, 128 and
+512 rows (forward and backward alike in a line), the span following from
+the window (768, 640, 1024), over tiles a grid step x tiles a loop
+iteration (`_span_tiles`; a whole head a step and four an iteration
+ship: 32 x 4 at 256 rows forward, 64 x 4 at 128 backward), and last
+`one_span as shipped`, nothing held. The same at a window of 768
+(`laguna.narrow_window_768`), the upper end of the rule: the chunk loop
+as `_seq_block` cuts it (chunks of 512) against spans of 1024 and 896.
 
 `--only grouped` (PR 43) times ONE WHOLE ATTENTION OP instead, forward
 and backward (`value_and_grad` over its parameters and input, as a train
@@ -35,7 +44,10 @@ step runs it), at the five grouped-query shapes of the decoder cells
 [B, S, H*128] ahead of the kernels, the shipped form until PR 43, made
 here by holding the op's route to `grouped_kv=False`) and `grouped` (the
 kernels read K and V at the KV heads and add a group's dK / dV up in
-their resident float32 panel: what ships). A line holds both forms'
+their resident float32 panel: what ships), and where the shipped kernels
+take the one-span form (laguna's window op) a third, `grouped_chunks`:
+grouped keys with `one_span` held to None, the chunk loop of PR 41. A
+line holds the forms'
 device ms, their ops by stem, and the largest difference of the value
 and of every gradient between them (`grouped_vs_repeated`).
 
@@ -65,6 +77,7 @@ SHAPES = {
     "nemotron.full": (4, 8192, True, 0, None),
     "joyai.latent": (32, 4096, True, 0, None),
     "laguna.narrow_window": (64, 8192, True, 512, None),
+    "laguna.narrow_window_768": (64, 8192, True, 768, None),
     "laguna.full": (48, 8192, True, 0, None),
 }
 ROPE_DIM = 64      # the rotated lanes of a `latent` shape's query and key
@@ -84,6 +97,34 @@ def every_tile_masked(ranges, forward, peel=False):
             cut += [(lo, hi - 1, True), (hi - 1, hi, True)]
         return (tuple(cut), peel and masked) if forward else tuple(cut)
     return split
+
+
+def variants(name, window, bd, tiny):
+    """(program, K block held | None as `_seq_block` gives it, (block,
+    span, tiles a grid step) of the one-span form | None) a line of the
+    shape ``name``."""
+    if ".narrow_window" in name:
+        blocks = (1024, 512, 256) if name.endswith(".narrow_window") else (
+            None,)
+        # rows a tile -> tiles a grid step
+        # rows a tile -> (tiles a grid step, tiles a loop iteration)
+        rows = {256: ((1, 1), (8, 1), (16, 1), (8, 2), (16, 2), (16, 4),
+                      (32, 4), (8, 8)),
+                128: ((1, 1), (32, 2), (16, 4), (32, 4), (64, 4), (16, 8)),
+                512: ((1, 1), (16, 2))}
+        if tiny:
+            blocks = (b and b // 2 for b in blocks)
+            rows = {256: ((1, 1), (4, 2), (8, 1)), 128: ((4, 4),)}
+        spans = [(b, -(-(window + b - 1) // 128) * 128, n)
+                 for b, steps in rows.items() for n in steps]
+        return [("split", b, None) for b in blocks] + [
+            ("one_span", None, one) for one in spans if one[1] <= 1024] + [
+            ("one_span as shipped", None, None)]
+    if name.endswith(".latent"):
+        return [("split", None, None), ("split, no rotated part", None, None)]
+    return [(program, blk, None) for blk in ((512, 1024) if bd else (None,))
+            for program in ("every_tile_masked", "every_tile_masked+peel",
+                            "split")]
 
 
 def kernel_ms(fn, args, interpret):
@@ -126,8 +167,10 @@ def grouped_op_lines(tiny):
 
     from flexflow_tpu.ffconst import OperatorType
     from flexflow_tpu.layer import Layer
+    from flexflow_tpu.ops import pallas_kernels as pk
     from flexflow_tpu.ops.base import OpContext, OpRegistry
 
+    shipped = pk.one_span
     ops = {"laguna.window_64_8": IN_CONTEXT["whole"],
            "laguna.full_48_8": IN_CONTEXT["partial"],
            "sdar.block_diffusion_8_1": IN_CONTEXT["norm_whole"],
@@ -138,8 +181,10 @@ def grouped_op_lines(tiny):
     for name, (seq, hidden, props) in ops.items():
         if tiny:
             seq, hidden, props = _tiny(props)
+            if "window" in props:   # past the whole-tile kernels: one span
+                seq = 1536
         jitted, outs = {}, {}
-        for form in FORMS:
+        for form in FORMS + ("grouped_chunks",):
             layer = Layer(OperatorType.MULTIHEAD_ATTENTION, "op", [])
             layer.properties.update(dict(
                 dict(rope=True), **props, embed_dim=hidden, head_dim=128,
@@ -162,22 +207,36 @@ def grouped_op_lines(tiny):
             x = jax.ShapeDtypeStruct((1, seq, hidden), jnp.bfloat16)
             args = jax.tree.map(lambda a: jnp.asarray(
                 0.02 * rs.randn(*a.shape), a.dtype), (shapes, x, x))
-            jitted[form] = (jax.jit(run), args)
-            outs[form] = jax.block_until_ready(jitted[form][0](*args))
+            if form == "grouped_chunks":
+                if not op.route({}, True).one_span:
+                    continue    # the shipped kernels are the chunk loop
+                pk.one_span = lambda *a, **k: None
+            try:     # the first call traces: the form is held around it
+                jitted[form] = (jax.jit(run), args)
+                outs[form] = jax.block_until_ready(jitted[form][0](*args))
+            finally:
+                pk.one_span = shipped
             assert op._route.grouped_kv == (form != "repeated"), (name, form)
-        (a, da), (b, db) = outs["grouped"], outs["repeated"]
-        line = dict(
-            shape="grouped." + name, seq=seq, hidden=hidden,
-            heads=props["num_heads"], kv_heads=props["num_kv_heads"],
-            device=jax.devices()[0].device_kind,
-            grouped_vs_repeated=dict(
+        def against(other):
+            """The largest difference of the shipped form's value and of
+            each of its gradients from ``other``'s."""
+            (a, da), (b, db) = outs["grouped"], outs[other]
+            return dict(
                 value_rel=float(abs(a - b) / abs(b)),
                 grads_rel={jax.tree_util.keystr(path): float(
                     abs(x.astype("float32") - y.astype("float32")).max()
                     / abs(y.astype("float32")).max())
                     for (path, x), y in zip(
                         jax.tree_util.tree_leaves_with_path(da),
-                        jax.tree.leaves(db))}))
+                        jax.tree.leaves(db))})
+
+        line = dict(
+            shape="grouped." + name, seq=seq, hidden=hidden,
+            heads=props["num_heads"], kv_heads=props["num_kv_heads"],
+            device=jax.devices()[0].device_kind,
+            grouped_vs_repeated=against("repeated"))
+        if "grouped_chunks" in outs:
+            line["one_span_vs_chunks"] = against("grouped_chunks")
         # a CPU trace has no device lane to read
         for form, (ms, by_stem) in ({} if tiny else device_ms(
                 jitted, stems=12)).items():
@@ -199,6 +258,8 @@ def main():
     tiny, only = args.tiny, args.only
     if tiny:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        # the whole op of `--only grouped` asks `pallas_mode()` for its core
+        os.environ.setdefault("FLEXFLOW_TPU_PALLAS", "interpret")
 
     import jax
     import jax.numpy as jnp
@@ -207,7 +268,13 @@ def main():
 
     if not tiny and jax.default_backend() != "tpu":
         sys.exit("flash_lab.py times the kernels on a TPU; --tiny rehearses")
-    shipped = pk._k_split, pk._q_split, pk._seq_block
+    shipped = {name: getattr(pk, name) for name in (
+        "_k_split", "_q_split", "_seq_block", "one_span", "_span_tiles")}
+
+    def restore():
+        for name, fn in shipped.items():
+            setattr(pk, name, fn)
+
     lines = grouped_op_lines(tiny) if only and only in "grouped" else []
     for name, (heads, seq, causal, window, bd) in SHAPES.items():
         if only not in name:
@@ -218,51 +285,52 @@ def main():
         rs = np.random.RandomState(0)
         q, k, v, do = (jnp.asarray(rs.randn(1, seq, heads * 128),
                                    jnp.bfloat16) for _ in range(4))
-        latent = name.endswith(".latent")
         rope = (jnp.asarray(rs.randn(1, seq, heads * ROPE_DIM), jnp.bfloat16),
                 jnp.asarray(rs.randn(1, seq, ROPE_DIM), jnp.bfloat16))
-        narrow = name.endswith(".narrow_window")
-        for blk in ((512, 1024) if bd else (1024, 512, 256) if narrow
-                    else (None,)):
-            if tiny and narrow:
-                blk //= 2
-            for program in (("split", "split, no rotated part") if latent
-                            else ("split",) if narrow
-                            else ("every_tile_masked",
-                                  "every_tile_masked+peel", "split")):
-                pk._k_split, pk._q_split, pk._seq_block = shipped
-                if not program.startswith("split"):
-                    pk._k_split = every_tile_masked(
-                        pk._k_ranges, True, peel="peel" in program)
-                    pk._q_split = every_tile_masked(pk._q_ranges, False)
-                if blk:
-                    pk._seq_block = (lambda s, block_diffusion=None,
-                                     window=0, b=blk: b)
-                mask = dict(window=window, block_diffusion=bd)
-                if program == "split" and latent:
-                    mask["rope"] = rope
-                fwd = jax.jit(lambda q, k, v: pk._flash_fwd(
-                    q, k, v, heads, causal, tiny, **mask))
-                bwd = jax.jit(lambda q, k, v, o, lse, do: pk._flash_bwd(
-                    q, k, v, o, lse, do, heads, causal, tiny, **mask))
-                o, lse = fwd(q, k, v)
-                line = dict(
-                    shape=name, heads=heads, seq=seq, program=program,
-                    k_block=pk._seq_block(seq, bd, window),
-                    pairs_visited=pk.visited_pairs(seq, causal, window, bd),
-                    tiles_visited=pk.kv_blocks(seq, causal, window, bd)[0],
-                    tiles_masked=pk.kv_blocks_masked(seq, causal, window, bd),
-                    last_chunk_peeled=pk._k_split(
-                        0, pk._q_block(seq, bd),
-                        pk._seq_block(seq, bd, window), seq,
-                        causal, window, bd)[1],
-                    forward_device_ms=kernel_ms(fwd, (q, k, v), tiny),
-                    backward_device_ms=kernel_ms(
-                        bwd, (q, k, v, o, lse, do), tiny),
-                    device=jax.devices()[0].device_kind)
-                print(json.dumps(line), flush=True)
-                lines.append(line)
-    pk._k_split, pk._q_split, pk._seq_block = shipped
+        for program, blk, one in variants(name, window, bd, tiny):
+            restore()
+            if program.startswith("every_tile_masked"):
+                pk._k_split = every_tile_masked(
+                    pk._k_ranges, True, peel="peel" in program)
+                pk._q_split = every_tile_masked(pk._q_ranges, False)
+            if blk:
+                pk._seq_block = (lambda s, block_diffusion=None,
+                                 window=0, b=blk: b)
+            # the form under test, held (`one` None: the chunk loop)
+            if ".narrow_window" in name and program != "one_span as shipped":
+                pk.one_span = lambda *a, held=one and (one[:2],) * 2, **k: held
+                pk._span_tiles = lambda s, blk, n=one and one[2]: n
+            mask = dict(window=window, block_diffusion=bd)
+            if program == "split" and name.endswith(".latent"):
+                mask["rope"] = rope
+            fwd = jax.jit(lambda q, k, v: pk._flash_fwd(
+                q, k, v, heads, causal, tiny, **mask))
+            bwd = jax.jit(lambda q, k, v, o, lse, do: pk._flash_bwd(
+                q, k, v, o, lse, do, heads, causal, tiny, **mask))
+            o, lse = fwd(q, k, v)
+            line = dict(
+                shape=name, heads=heads, seq=seq, window=window,
+                program=program,
+                k_block=pk._seq_block(seq, bd, window),
+                one_span=pk.one_span(seq, causal, window, bd),
+                tiles_a_step=one[2] if one else pk.one_span(
+                    seq, causal, window, bd) and pk._span_tiles(seq, 128),
+                pairs_visited=pk.visited_pairs(seq, causal, window, bd),
+                pairs_visible=2 * pk.visible_pairs(seq, causal, window)
+                if causal else None,
+                tiles_visited=pk.kv_blocks(seq, causal, window, bd)[0],
+                tiles_masked=pk.kv_blocks_masked(seq, causal, window, bd),
+                last_chunk_peeled=pk._k_split(
+                    0, pk._q_block(seq, bd),
+                    pk._seq_block(seq, bd, window), seq,
+                    causal, window, bd)[1],
+                forward_device_ms=kernel_ms(fwd, (q, k, v), tiny),
+                backward_device_ms=kernel_ms(
+                    bwd, (q, k, v, o, lse, do), tiny),
+                device=jax.devices()[0].device_kind)
+            print(json.dumps(line), flush=True)
+            lines.append(line)
+    restore()
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/flash_lab.json", "w") as f:
         json.dump(lines, f, indent=1)

@@ -32,10 +32,26 @@ OPS = {
         dict(num_heads=4, causal=True, window=128, rope=True),
         (1, 512, 512, 256), None, "interpret", True,
         dict(core="flash", scope="window", rotary_in_lanes=False,
+             one_span=False,            # the whole-tile kernels
              kv_blocks=(*pk.kv_blocks(512, True, 128),
                         pk.kv_blocks_masked(512, True, 128)),
              window_pairs=(pk.visited_pairs(512, True, 128),
                            2 * pk.visible_pairs(512, True, 128)))),
+    # PR 46: past the whole-tile kernels a window narrower than a tile
+    # takes the one-span form, and the counts are that form's
+    "narrow_window_one_span": (
+        dict(num_heads=4, causal=True, window=128, rope=True),
+        (1, 1536, 1536, 64), None, "interpret", True,
+        dict(core="flash", scope="window", one_span=True,
+             kv_blocks=(6, 6 * 4, 6),
+             window_pairs=(1536 * (384 + 256),
+                           2 * pk.visible_pairs(1536, True, 128)))),
+    "wide_window_chunk_loop": (
+        dict(num_heads=4, causal=True, window=800, rope=True),
+        (1, 1536, 1536, 64), None, "interpret", True,
+        dict(core="flash", scope="window", one_span=False,
+             kv_blocks=(*pk.kv_blocks(1536, True, 800),
+                        pk.kv_blocks_masked(1536, True, 800)))),
     "block_diffusion": (
         dict(num_heads=2, head_dim=128, block_diffusion=(128, 4), rope=True,
              rope_wrap=128, qk_norm=True), (1, 256, 256, 64), None,
@@ -154,6 +170,7 @@ def test_forward_route_selected_impl_and_gauges_agree(case, monkeypatch):
         "executor.flash_lane_dense_ops": int(flash),
         "executor.rotary_lane_dense_ops": int(route.rotary_in_lanes),
         "executor.flash_grouped_kv_ops": int(route.grouped_kv),
+        "executor.flash_one_span_ops": int(route.one_span),
         "executor.window_attention_ops": int(route.scope == "window"),
         "executor.block_diffusion_attention_ops": int(
             route.scope == "block_diffusion"),

@@ -74,17 +74,43 @@ def repeat_kv(x, kv, heads):
                       ).reshape(b, s, heads * (w // kv))
 
 
+def one_span_by_hand(seq, window):
+    """What `pk.one_span` should answer under a causal window that
+    hides something past the whole-tile kernels: the forward's Q block
+    (256 rows where that divides S) and the backward's K block (128),
+    each with the positions it reaches rounded up to 128, where the
+    forward's fit one tile."""
+    forward, backward = ((rows, -(-(window + rows - 1) // 128) * 128)
+                         for rows in (pk._q_block(seq), 128))
+    return (forward, backward) if forward[1] <= pk.MAX_SPAN else None
+
+
 @pytest.mark.parametrize("seq,window,group", [
-    (1536, 128, 1), (1536, 128, 6), (1536, 128, 8), (1536, 512, 8)])
+    (1536, 128, 1), (1536, 128, 6), (1536, 128, 8), (1536, 512, 8),
+    # PR 46, the one-span form at its edges: windows that are no
+    # multiple of 128 (spans of 512 and, exactly, 768), the widest window
+    # the rule admits (769: 1024 keys) and the first it does not (770:
+    # the chunk loop), Q blocks of 128 where 256 does not divide S, and a
+    # span that is most of the sequence (held at 0 for three of the five
+    # Q blocks, at S - span for two of the five K blocks)
+    (1536, 200, 1), (1536, 513, 6), (1536, 769, 1), (1536, 770, 1),
+    (1152, 200, 8), (1280, 769, 1)])
 def test_narrow_window_flash_matches_the_einsum_core(seq, window, group):
-    """The blocked kernels (S several chunks long, the chunk taken from
-    the window: 256 and 512), forward and the gradients of q, k, v, the
-    key/value head's through the repeat to `group` query heads (heads of
-    16 lanes: one column block holds them all, and the interpreter's
-    grid is short)."""
+    """The blocked kernels (S several chunks long), forward and the
+    gradients of q, k, v, the key/value head's through the repeat to
+    `group` query heads (heads of 16 lanes: one column block holds them
+    all, and the interpreter's grid is short). Since PR 46 every case but
+    the window of 770 takes the one-span form: a Q block against the
+    keys it reaches and a K block against the queries that see it as ONE
+    masked tile each, the span held at 0 at the sequence's start and at
+    S - span at its end."""
     d, kv = 16, 1
     heads = kv * group
-    assert pk._seq_block(seq, None, window) == max(window, 256) < 1024
+    if window in (128, 512):    # the chunk the loop would take (PR 41)
+        assert pk._seq_block(seq, None, window) == max(window, 256) < 1024
+    one = pk.one_span(seq, True, window)
+    assert one == one_span_by_hand(seq, window)
+    assert (one is None) == (window == 770)
     keys = jax.random.split(jax.random.PRNGKey(seq + group), 4)
     q, weight = (jax.random.normal(k, (1, seq, heads * d), jnp.float32)
                  for k in keys[:2])
@@ -114,6 +140,165 @@ def test_narrow_window_flash_matches_the_einsum_core(seq, window, group):
                                    np.asarray(w) / scale, atol=2e-5)
 
 
+def test_the_widest_one_span_window_agrees_with_the_chunk_loop(monkeypatch):
+    """A window of 769 is the widest whose reach from a Q block of 256
+    (1,024 keys) is one tile, 770 the first that takes the chunk loop
+    (both against the einsum core above). Here the two FORMS at one
+    window: output, logsumexp, dQ, dK, dV of the one-span kernels against
+    those of the chunk loop (`one_span` held to None), which differ only
+    in the order of float32 roundings (the plain softmax against the
+    online one), heads of 128 in bfloat16 as the cells run them."""
+    seq, window, heads = 1536, 769, 2
+    assert pk.one_span(seq, True, window) == ((256, pk.MAX_SPAN), (128, 896))
+    assert pk.one_span(seq, True, window + 1) is None
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, k, v, do = (jax.random.normal(key, (1, seq, heads * 128),
+                                     jnp.float32).astype(jnp.bfloat16)
+                   for key in keys)
+
+    def run():
+        o, lse = pk._flash_fwd(q, k, v, heads, True, True, window=window)
+        return (o, lse) + tuple(pk._flash_bwd(
+            q, k, v, o, lse, do, heads, True, True, window=window))
+
+    got = run()
+    monkeypatch.setattr(pk, "one_span", lambda *a, **k: None)
+    want = run()
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        # a bf16 rounding (2^-8) of the largest entry at most, and far
+        # less over the whole tensor
+        assert np.abs(a - b).max() <= 2.0 ** -8 * np.abs(b).max(), name
+        assert (np.sqrt(np.mean((a - b) ** 2))
+                <= 1e-3 * np.sqrt(np.mean(b ** 2))), name
+
+
+@pytest.mark.parametrize("group", [1, 6, 8])
+def test_one_span_reads_grouped_keys_at_the_kv_head(group, monkeypatch):
+    """`flash_attention(..., num_kv_heads=)` under a narrow window: ONE
+    KV head of 128 lanes read by `group` query heads through the one-span
+    kernels (the K / V BlockSpec's `j // rep`, dK and dV a group's
+    float32 sums in the KV head's resident panel), against the einsum
+    core on repeated keys, forward and the gradients of q, k, v."""
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+    seq, window, d = 1280, 300, 128
+    assert pk.one_span(seq, True, window) == ((256, 640), (128, 512))
+    keys = jax.random.split(jax.random.PRNGKey(group), 4)
+    q, weight = (jax.random.normal(key, (1, seq, group * d), jnp.float32)
+                 for key in keys[:2])
+    k, v = (jax.random.normal(key, (1, seq, d), jnp.float32)
+            for key in keys[2:])
+
+    def flash(q, k, v):
+        return pk.flash_attention(q, k, v, group, True, window=window,
+                                  num_kv_heads=1)
+
+    def einsum_core(q, k, v):
+        split = lambda x: pk.split_heads(x, group)  # noqa: E731
+        return pk.merge_heads(scaled_dot_product_attention(
+            split(q), split(repeat_kv(k, 1, group)),
+            split(repeat_kv(v, 1, group)), causal=True, window=window))
+
+    with HIGHEST:
+        np.testing.assert_allclose(flash(q, k, v), einsum_core(q, k, v),
+                                   rtol=2e-4, atol=2e-5)
+        got = jax.grad(lambda *a: jnp.sum(flash(*a) * weight),
+                       argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(einsum_core(*a) * weight),
+                        argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.dtype == jnp.float32 and g.shape == w.shape
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(np.asarray(g) / scale,
+                                   np.asarray(w) / scale, atol=2e-5)
+
+
+def test_the_rule_leaves_every_other_kind_alone(monkeypatch):
+    """`one_span` answers None for whatever is not a causal window that
+    hides something, narrower than a tile, past the whole-tile kernels:
+    `flash_attention_lse` (the ring's primitive carries no window: its
+    logsumexp's cotangent goes through the chunk loop as before), full
+    causal, a window that hides nothing, smallthinker's 4096, block
+    diffusion, the two-part score, and S <= MAX_BWD_SEQ."""
+    assert pk.one_span(8192, True, 512) == ((256, 768), (128, 640))
+    for kind in [(8192, True, 0), (8192, False, 0), (8192, True, 8192),
+                 (16384, True, 4096), (8192, True, 1024), (1024, True, 128),
+                 (512, True, 100), (4096, False, 0, (2048, 4)),
+                 (8192, True, 512, None, 64)]:
+        assert pk.one_span(*kind) is None, kind
+    with pytest.raises(ValueError):
+        pk.one_span(8192, False, 512)      # a window needs causal
+    seq, heads = 1280, 2
+    keys = jax.random.split(jax.random.PRNGKey(3), 5)
+    q, k, v, wo = (jax.random.normal(key, (1, seq, heads * 16), jnp.float32)
+                   for key in keys[:4])
+    wl = jax.random.normal(keys[4], (1, heads, seq), jnp.float32)
+
+    def by_kernel(q, k, v):
+        o, lse = pk.flash_attention_lse(q, k, v, heads, True, True)
+        return jnp.sum(o * wo) + jnp.sum(lse * wl)
+
+    def by_einsum(q, k, v):
+        split = lambda x: pk.split_heads(x, heads).reshape(  # noqa: E731
+            heads, seq, 16)
+        o, lse = pk._xla_attention_lse(split(q), split(k), split(v), True)
+        return (jnp.sum(pk.merge_heads(o[None]) * wo)
+                + jnp.sum(lse[None] * wl))
+
+    asked, rule = [], pk.one_span
+    monkeypatch.setattr(pk, "one_span", lambda *a, **k: asked.append(
+        rule(*a, **k)) or asked[-1])
+    with HIGHEST:
+        got = jax.grad(by_kernel, argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(by_einsum, argnums=(0, 1, 2))(q, k, v)
+    # forward and backward both asked, and both took the chunk loop
+    assert len(asked) >= 2 and all(one is None for one in asked)
+    for g, w in zip(got, want):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(np.asarray(g) / scale,
+                                   np.asarray(w) / scale, atol=2e-5)
+
+
+def one_span_squares(seq, forward_tile, backward_tile):
+    """The pairs the one-span kernels visit, as a [query, key] boolean
+    square a direction, from the kernels' own starts (`_k_span`,
+    `_q_span`)."""
+    forward = np.zeros((seq, seq), bool)
+    backward = np.zeros((seq, seq), bool)
+    blk, span = forward_tile
+    for q0 in range(0, seq, blk):
+        k0 = pk._k_span(q0, blk, span)
+        assert k0 % 128 == 0 and 0 <= k0 <= seq - span
+        forward[q0:q0 + blk, k0:k0 + span] = True
+    blk, span = backward_tile
+    for k0 in range(0, seq, blk):
+        q0 = pk._q_span(k0, span, seq)
+        assert q0 % 128 == 0 and 0 <= q0 <= seq - span
+        backward[q0:q0 + span, k0:k0 + blk] = True
+    return forward, backward
+
+
+@pytest.mark.parametrize("seq,window", [
+    (1536, 128), (1536, 200), (1536, 513), (1536, 769), (1152, 200),
+    (1280, 769), (8192, 512)])
+def test_every_visible_pair_lies_in_a_one_span_tile(seq, window):
+    """The one-span form's tiles, forward ([Q block, span of keys]) and
+    backward ([span of queries, K block]), hold every pair `visible`
+    admits, and `visited_pairs` / `kv_blocks` count exactly those
+    tiles."""
+    one = pk.one_span(seq, True, window)
+    (blk, span), (_, back_span) = one
+    i = np.arange(seq)
+    seen = np.asarray(pk.visible(i[:, None], i[None, :], window))
+    forward, backward = one_span_squares(seq, *one)
+    assert not (seen & ~forward).any() and not (seen & ~backward).any()
+    assert pk.visited_pairs(seq, True, window) == int(
+        forward.sum() + backward.sum()) == seq * (span + back_span)
+    assert pk.kv_blocks(seq, True, window) == (
+        seq // blk, (seq // blk) * -(-seq // span))
+    assert pk.kv_blocks_masked(seq, True, window) == seq // blk
+
+
 @pytest.mark.parametrize("seq,window,block", [
     (8192, 512, 512), (8192, 4096, 1024), (8192, 0, 1024), (8192, 128, 256),
     (16384, 4096, 1024), (8192, 1024, 1024), (3072, 512, 512)])
@@ -131,12 +316,26 @@ def test_the_chunk_follows_a_narrow_window_and_the_counts_follow_it(
     visited = pk.visited_pairs(seq, True, window)
     assert visited >= 2 * visible
     if (seq, window) == (8192, 512):
-        # a Q block of 256 meets two chunks of 512, a K block of 512 two
-        # Q chunks of 512 (but the first and the last): 2 and 2 times the
-        # window's 512 keys a row, where chunks of 1024 visit 3 and 4
-        assert visited == 8192 * 1024 - 256 * 512 * 2 + (
-            8192 * 1024 - 512 * 512)
+        # PR 46: ONE tile a block, from the rule's own answer: a Q block
+        # of 256 against the 768 keys it reaches, a K block of 128
+        # against the 640 queries that see it: 1.5 and 1.25 times the
+        # window's 512 keys a row (1.42 times the visible pairs)
+        (blk, span), (back_blk, back_span) = pk.one_span(seq, True, window)
+        assert (blk, span, back_blk, back_span) == (256, 768, 128, 640)
+        assert visited == seq * (span + back_span) == 2.75 * seq * window
+        assert 1.4 < visited / (2 * visible) < 1.42
+        assert pk.kv_blocks(seq, True, window) == (
+            seq // blk, (seq // blk) * -(-seq // span)) == (32, 32 * 11)
+        assert pk.kv_blocks_masked(seq, True, window) == 32
+        # the chunk loop of PR 41 (the rule held to None): a Q block of
+        # 256 meets two chunks of 512, a K block of 512 two Q chunks of
+        # 512 (but the first and the last): 2 and 2 times the window
+        monkeypatch.setattr(pk, "one_span", lambda *a, **k: None)
+        chunks = pk.visited_pairs(seq, True, window)
+        assert chunks == 8192 * 1024 - 256 * 512 * 2 + (
+            8192 * 1024 - 512 * 512) > 1.4 * visited
         assert pk.kv_blocks(seq, True, window) == (2 * 32 - 2, 32 * 16)
+        visited = chunks
         # at the chunks of 1024 that S alone gives
         monkeypatch.setattr(pk, "_seq_block", lambda s, bd=None, w=0: 1024)
         # 46 forward tiles of [256, 1024] (a Q block meets one chunk or

@@ -153,6 +153,33 @@ class TestFlashKernels:
             sharding=SingleDeviceSharding(topo.devices[0]))
         assert pallas_kernel_count(_compile(grads(1), q, q, q)) == 2
 
+    @pytest.mark.parametrize("seq", [8192, pk.MAX_FLASH_SEQ])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("kv_heads", [None, 1])
+    def test_one_span_steps_stay_inside_the_vmem_budget(self, topo, seq,
+                                                        dtype, kv_heads):
+        """The one-span kernels (PR 46) at the widest window the rule
+        admits: a grid step takes a whole head's blocks up to 8,192
+        positions and half a head's at 16,384 (`_span_tiles`), with the
+        Q / O / dO / dQ panels resident; in float32, and with a group's
+        whole float32 dK / dV panels, that is what fills the 96 MiB."""
+        heads, window = 2, 769
+        assert pk.one_span(seq, True, window) == ((256, 1024), (128, 896))
+        assert pk._span_tiles(seq, 128)[0] * 128 == {8192: 8192,
+                                                     16384: 4096}[seq]
+        one = SingleDeviceSharding(topo.devices[0])
+        q = jax.ShapeDtypeStruct((1, seq, heads * 128), dtype, sharding=one)
+        k = q if kv_heads is None else jax.ShapeDtypeStruct(
+            (1, seq, kv_heads * 128), jnp.float32, sharding=one)
+
+        def grads(q, k, v):
+            return jax.grad(lambda q, k, v: pk._flash(
+                q, k, v, heads, True, False, window, None, None,
+                kv_heads).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+
+        assert pallas_kernel_count(_compile(grads, q, k, k)) == 2
+
     @pytest.mark.parametrize("seq,block", [(16384, 4), (2048, 32),
                                            (512, 4)])
     def test_block_diffusion_mask_compiles_at_the_cells_widths(
@@ -180,7 +207,8 @@ class TestFlashKernels:
 
     @pytest.mark.parametrize("heads,seq,window", [
         (7, 16384, 4096), (7, 16384, 0), (4, 8192, 0), (7, 16384, 1000),
-        (64, 8192, 512), (48, 8192, 0)])
+        (64, 8192, 512), (48, 8192, 0), (8, 16384, 512), (8, 8192, 769),
+        (8, 1152, 200)])
     def test_causal_and_window_split_compile_at_the_cells_shapes(
             self, topo, heads, seq, window):
         """The smallthinker cell's 7 heads of 128 at 16,384 under a
@@ -190,7 +218,11 @@ class TestFlashKernels:
         bounds computed from the grid index, inside the 96 MiB budget;
         and a window so narrow that the interior range is empty. The
         laguna cell's 64 heads under a window of 512, half of a chunk of
-        1024 (K chunks of 512 then, PR 41), and its 48 under none."""
+        1024, and its 48 under none. Since PR 46 that window takes the
+        one-span kernels (a block's reach as ONE [256, 768] tile, sixteen
+        tiles a grid step, no chunk loop): the same at 16,384 positions,
+        at the widest window the rule admits (769: a [256, 1024] tile)
+        and at Q blocks of 128 (S = 1152)."""
         q = jax.ShapeDtypeStruct((1, seq, heads * 128), jnp.bfloat16,
                                  sharding=SingleDeviceSharding(
                                      topo.devices[0]))
@@ -207,6 +239,8 @@ class TestFlashKernels:
         assert "tpu_custom_call_flash_bwd_blocked" in hlo
         assert 0 < pk.kv_blocks_masked(seq, True, window) <= (
             pk.kv_blocks(seq, True, window)[0])
+        assert (pk.one_span(seq, True, window) is not None) == (
+            0 < window <= 769)
 
     @pytest.mark.parametrize("heads,kv_heads,seq,window,block_diffusion", [
         (7, 1, 16384, 4096, None), (7, 1, 16384, 0, None),

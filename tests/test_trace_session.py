@@ -603,7 +603,7 @@ WITNESS_KEYS = [
     "attention/window_keys_visited",
     "executor.block_diffusion_attention_ops",
     "executor.flash_grouped_kv_ops", "executor.flash_lane_dense_ops",
-    "executor.latent_attention_ops", "executor.loss_own_vjp",
+    "executor.flash_one_span_ops", "executor.latent_attention_ops", "executor.loss_own_vjp",
     "executor.moe_sum_rows_ops", "executor.rotary_lane_dense_ops",
     "executor.window_attention_ops"]
 DEVICE_COUNTER_KEYS = ["moe/load_max_over_mean", "moe/overflow_slots",
@@ -613,7 +613,8 @@ CONTEXT_KEYS = [
     "attention_kv_blocks_visited", "attention_window_keys_visible",
     "attention_window_keys_visited", "batch_size",
     "block_diffusion_attention_ops", "compile_phases",
-    "flash_grouped_kv_ops", "flash_lane_dense_ops", "latent_attention_ops",
+    "flash_grouped_kv_ops", "flash_lane_dense_ops", "flash_one_span_ops",
+    "latent_attention_ops",
     "loss_own_vjp", "loss_target_positions", "mesh_axes",
     "moe_sum_rows_ops", "num_ops", "rotary_lane_dense_ops",
     "set_parameter_s", "window_attention_ops"]
