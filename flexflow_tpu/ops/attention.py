@@ -11,10 +11,12 @@ output projection (PR 30): the form the Pallas flash-attention kernels
 (ops/pallas_kernels.py) take, with no layout copy between a projection
 and a kernel. The einsum core and ring attention, which want
 [B, H, S, D], convert at their own boundary; XLA fuses that. Rotary and
-the per-head norm before it keep to that form too where a head fills the
-128 lanes (PR 42: `MultiHeadAttention._rotated`), and there the keys and
-values of grouped-query attention reach the flash kernels at their own
-[B, S, Hk*D], never repeated (PR 43).
+the per-head norm before it keep to that form too where heads fill
+128-lane columns, one of 128 (PR 42: `MultiHeadAttention._rotated`) or
+two of 64 (PR 47), and there the keys and values of grouped-query
+attention reach the flash kernels at their own [B, S, Hk*D], never
+repeated (PR 43; at heads of 64 where a column's two query heads share
+a KV head, PR 47).
 
 Which core an op runs and in which operand form is decided in ONE place,
 `MultiHeadAttention.route`, whose answer is an `AttentionRoute`: `forward`
@@ -336,14 +338,15 @@ class MultiHeadAttention(Op):
     frequencies runs under the scope ``rotary_whole``, anything else
     under ``rotary_partial_yarn``.
 
-    The per-head pass between a projection and the core (PR 42): where a
-    head is one 128-lane column, S whole blocks of 128 rows, Pallas on
-    and the mesh one device, the heads' norm (``qk_norm``) and the
-    rotation run as ONE kernel over the projection's float32
-    [B, S, H*128] result as it lies (``pallas_kernels.rotary_lanes``,
-    with its own backward), which writes the core's operand in the
-    compute dtype. Every other shape takes ``_heads_normed`` and
-    ``rotary_embedding`` / ``rotary_partial`` over a [B, S, H, D] view:
+    The per-head pass between a projection and the core (PR 42): where
+    the heads are whole 128-lane columns (one of 128, or since PR 47 two
+    of 64), S whole blocks of 128 rows, Pallas on and the mesh one
+    device, the heads' norm (``qk_norm``) and the rotation run as ONE
+    kernel over the projection's float32 [B, S, H*D] result as it lies
+    (``pallas_kernels.rotary_lanes``, with its own backward), which
+    writes the core's operand in the compute dtype. Every other shape
+    takes ``_heads_normed`` and ``rotary_embedding`` /
+    ``rotary_partial`` over a [B, S, H, D] view:
     the same float32 products from the same tables, at the price of
     XLA's copies of the whole array between the two layouts.
 
@@ -588,11 +591,15 @@ class MultiHeadAttention(Op):
         axes (possibly the joint ('data','model') sample2 partition) and,
         when the search picked a head choice, the head axis, where a
         shard's heads still tile the lanes. Grouped-query K and V stay at
-        the KV heads (PR 43) where a head is one 128-lane column block
-        and a head axis leaves every shard whole groups. The heads' norm
-        and rotary run as the lane-dense pass (PR 42) with Pallas on, a
-        head that is one 128-lane column, whole row blocks, and one
-        device (a bare kernel call has no partitioning)."""
+        the KV heads (PR 43) where `pallas_kernels.grouped_kv_shape_legal`
+        admits the heads A SHARD holds: a head of 128 a column block, or
+        (PR 47) two of 64 that share one KV head of a K / V lane block of
+        two, with a head axis leaving every shard whole groups and whole
+        K / V lane blocks. The heads' norm and rotary run as the
+        lane-dense pass (PR 42) with Pallas on, heads that are whole
+        128-lane columns (`rotary_lanes_shape_legal`: one of 128, two of
+        64), whole row blocks, and one device (a bare kernel call has no
+        partitioning). Both rules are functions of shapes alone."""
         from flexflow_tpu.ops import pallas_kernels as pk
 
         shapes = self.input_shapes
@@ -645,8 +652,8 @@ class MultiHeadAttention(Op):
         one_device = not any(n > 1 for n in mesh_axes.values())
         rotary_in_lanes = bool(
             self.rope and not self.latent and pk.pallas_mode() != "off"
-            and pk.rotary_lanes_shape_legal(sq, d)
-            and pk.rotary_lanes_shape_legal(sk, d) and one_device)
+            and pk.rotary_lanes_shape_legal(sq, d, h)
+            and pk.rotary_lanes_shape_legal(sk, d, hk) and one_device)
         if core != "flash":
             return AttentionRoute(core, blocked, fallback, scope,
                                   rotary_in_lanes=rotary_in_lanes)
@@ -672,10 +679,11 @@ class MultiHeadAttention(Op):
                          and legal(h // mesh_axes[hp])
                          else None)
             shard_axes = (batch_axis, head_axis)
-        grouped_kv = bool(
-            pk.grouped_kv_shape_legal(h, hk, d)
-            and (shard_axes is None or shard_axes[1] is None
-                 or hk % mesh_axes[shard_axes[1]] == 0))
+        # of the heads a shard holds: whole groups, whole K / V lane blocks
+        shards = mesh_axes[shard_axes[1]] if (
+            shard_axes is not None and shard_axes[1] is not None) else 1
+        grouped_kv = bool(hk % shards == 0 and pk.grouped_kv_shape_legal(
+            h // shards, hk // shards, d))
         kind = (sq, self.causal, self.window, self.block_diffusion,
                 self.rope_dim)
         return AttentionRoute(
@@ -702,9 +710,10 @@ class MultiHeadAttention(Op):
         traced). `executor.flash_lane_dense_ops`: the flash kernels took
         [B, S, heads*head_dim] operands (PR 30);
         `executor.rotary_lane_dense_ops`: the heads' norm and rotary ran
-        as the one lane-dense pass (PR 42);
+        as the one lane-dense pass (PR 42; heads of 64 too since PR 47);
         `executor.flash_grouped_kv_ops`: K and V reached the kernels at
-        the KV heads (PR 43); `executor.flash_one_span_ops`: the blocked
+        the KV heads (PR 43; at heads of 64 through a half of a lane
+        block since PR 47); `executor.flash_one_span_ops`: the blocked
         flash kernels took a block's reachable positions as one tile
         (PR 46); `executor.window_attention_ops` (the window
         hides something at this length, PR 31), `executor.

@@ -54,7 +54,12 @@ grouped-query attention with heads of 128 (PR 43) k and v are
 [B, S, Hk*D], the KV heads alone: a K / V BlockSpec's last index is the
 query column block's group, ``j // rep``, and the backward adds a
 group's dK and dV up in the KV head's float32 panel; no array holds a
-K or V head once for each of its query heads.
+K or V head once for each of its query heads. The same at heads of 64
+(PR 47) where a column block's two query heads share ONE KV head, which
+is then a 64-lane half of the K / V lane block ``j // rep``: the
+kernels move the query heads to that half (``_half_moved``, the blocked
+forwards) or lay it twice (``_own_kv_head``), and add the two heads' dK
+and dV into it (``_group_halves``).
 
 Every MXU product takes its operands in the dtype the caller stored and
 accumulates in float32 (``_dot``); softmax statistics, ``exp``, scale
@@ -66,7 +71,8 @@ has what they gave through the benchmark's full step.
 Two kernels outside the attention core: `moe_sum_rows` (PR 37), the
 expert layer's sum of rows into their tokens, and `rotary_lanes` (PR
 42), the heads' RMS norm and rotary in one pass over a projection's
-[B, S, H*128] result, between the product and a flash kernel.
+[B, S, H*128] result (or [B, S, H*64], two heads a 128-lane column, PR
+47), between the product and a flash kernel.
 
 CPU fallback: the same kernels run under ``interpret=True`` when
 FLEXFLOW_TPU_PALLAS=interpret (used by the deviceless tests); otherwise
@@ -758,10 +764,74 @@ def _stack_heads(parts):
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
+def _kv_part(axis: int, group):
+    """Which of the KV heads that lie side by side in a K / V lane block
+    this grid step's query column block reads (PR 47). ``group`` = (rep,
+    heads a lane block): the lane block serves ``rep`` consecutive query
+    column blocks, the first rep / heads of them its first KV head, and
+    so on; grid axis ``axis`` runs over the column blocks (the forward)
+    or over a lane block's ``rep`` (the grouped backward). None without
+    a ``group`` (one head a lane block, or every head its own keys):
+    ``_own_kv_head`` and ``_group_halves`` then hand back what they are
+    given, and nothing is traced."""
+    if group is None:
+        return None
+    rep, hpb = group
+    return pl.program_id(axis) % rep * hpb // rep
+
+
+def _lane_roll(x, shift: int):
+    """x [rows, 128] rolled ``shift`` lanes. The chip's compiler rotates
+    32-bit words only, so a bfloat16 tile goes as the words that hold two
+    rows' values a lane: a lane roll does not look at a row."""
+    if x.dtype.itemsize == 4:
+        return pltpu.roll(x, shift, 1)
+    words = pltpu.roll(pltpu.bitcast(x, jnp.uint32), shift, 1)
+    return pltpu.bitcast(words, x.dtype)
+
+
+def _own_kv_head(x, part, head_dim: int):
+    """x [rows, 128], a K or V lane block of two KV heads of 64, with
+    head ``part`` (traced) laid in both halves, [k_g ; k_g]: the column
+    block the repeated form would have fetched, so every product after
+    it sees the values it saw there. One lane roll by half a block (its
+    own inverse) and a select. Where K and V are what a grid step holds
+    (the backward's K block, the whole-tile kernels' rows); the blocked
+    forwards, which read K and V a chunk at a time, move the query
+    block instead (``_half_moved``)."""
+    if part is None:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane // head_dim == part, x,
+                     _lane_roll(x, head_dim))
+
+
+def _half_moved(x, there, head_dim: int):
+    """x [rows, 128] as it is where ``there`` (a traced scalar) holds,
+    else with its two 64-lane halves exchanged: a query head moved to
+    the half of the lane block its KV head lies in, or a head's output
+    moved back from it."""
+    return jnp.where(there, x, _lane_roll(x, head_dim))
+
+
+def _group_halves(xt, part, head_dim: int):
+    """xt [128, n], the dK^T (dV^T) of a column block's two query heads
+    as sublane ranges, both of them gradients of KV head ``part``
+    (traced) of the K / V lane block: their sum in that head's sublanes
+    and zeros in the other's, which these query heads do not read.
+    Nothing moves across lanes."""
+    if part is None:
+        return xt
+    both = xt[:head_dim] + xt[head_dim:]
+    zero = jnp.zeros_like(both)
+    return jnp.concatenate([jnp.where(part == 0, both, zero),
+                            jnp.where(part == 0, zero, both)], axis=0)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
                       window: int, scale: float, blk_q: int, blk_k: int,
                       head_dim: int, block_diffusion=None,
-                      rope_dim: int = 0):
+                      rope_dim: int = 0, group=None):
     """One (batch row, column block, q-block) grid cell: q [1,BLK_Q,W]
     against the K/V panels [1,S,W] resident in VMEM, in chunks of
     ``blk_k`` keys with a running max and sum (the online softmax), and
@@ -792,18 +862,34 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
     With ``rope_dim`` (PR 39, the two-part score of latent attention)
     two more operands stand before the outputs: the heads' rotated query
     parts (``_rope_lanes`` picks this head's) and the ONE rotated key a
-    position, whose product is added to the score ahead of the scale."""
+    position, whose product is added to the score ahead of the scale.
+
+    ``group`` (PR 47; ``_kv_part``): the K / V panels hold two KV heads
+    of 64, of which this column block's two query heads share one, in
+    half ``part`` of the lanes. The chunks are read as they lie: each
+    query head is moved to that half ONCE a grid step (``_half_moved``;
+    the other half zeros), so that q k^T contracts it against its KV
+    head alone, and P V's half ``part`` is moved back to the head's own
+    lanes at the end. Laying the KV head twice a fetched chunk
+    (``_own_kv_head``) costs a roll and a select of [1024, 128] K and V
+    8,704 times an op at S = 16384 and was 1.25 ms slower in the lab
+    (``grouped_kv_shape_legal``'s table)."""
     if rope_dim:
         qr_ref, kr_ref, o_ref, lse_ref = rest
         qr = _rope_lanes(qr_ref[0], pl.program_id(1), rope_dim)
     else:
         o_ref, lse_ref = rest
+    part = _kv_part(1, group)
     q = q_ref[0]  # [BLK_Q, W]
     heads = q.shape[-1] // head_dim
     q0 = pl.program_id(2) * blk_q
     split, last_is_one = _k_split(q0, blk_q, blk_k, k_ref.shape[1], causal,
                                   window, block_diffusion)
-    qs = [_only_head(q, h, head_dim) for h in range(heads)]
+    if group:   # head h's lanes in its KV head's half, zeros in the other
+        qs = [_only_head(_half_moved(q, part == h, head_dim), part, head_dim)
+              for h in range(heads)]
+    else:
+        qs = [_only_head(q, h, head_dim) for h in range(heads)]
     tile = (blk_q, blk_k)
 
     def seen(k0):
@@ -848,7 +934,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
                 lo, hi, functools.partial(chunk, edge=edge), carry)
     o = None
     for h, (m, l, acc) in enumerate(carry):
-        oh = _only_head(acc / l, h, head_dim)
+        oh = acc / l
+        if group:   # P against the lane block's two KV heads: this one's
+            oh = _half_moved(oh, part == h, head_dim)
+        oh = _only_head(oh, h, head_dim)
         o = oh if o is None else o + oh
         lse_ref[0, h, 0] = (m + jnp.log(l))[:, 0]
     o_ref[0] = o.astype(o_ref.dtype)
@@ -856,7 +945,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
 
 def _flash_fwd_span_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                            window: int, scale: float, blk_q: int, span: int,
-                           tiles: int, unroll: int, head_dim: int):
+                           tiles: int, unroll: int, head_dim: int,
+                           group=None):
     """The blocked forward under a window so narrow that the keys a Q
     block reaches are ONE score tile (``one_span``, PR 46): each of the
     grid step's ``tiles`` Q blocks [BLK_Q, W] against the ``span`` keys
@@ -868,8 +958,10 @@ def _flash_fwd_span_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     rescaling; the loop over the step's Q blocks carries nothing. Every
     row sees its own key, so no row's sum is empty. The same products at
     the same precision as ``_flash_fwd_kernel``: operands as stored,
-    float32 scores, statistics and lse."""
+    float32 scores, statistics and lse; ``group`` as there: the query
+    heads move to their KV head's half, the keys are read as they lie."""
     first = pl.program_id(2) * tiles
+    part = _kv_part(1, group)
 
     def block(t):
         at = pl.multiple_of(t * blk_q, blk_q)
@@ -885,13 +977,20 @@ def _flash_fwd_span_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
             k0 + jax.lax.broadcasted_iota(jnp.int32, tile, 1), window)
         o = None
         for h in range(q.shape[-1] // head_dim):
-            s = jnp.where(mask, _dot_head(q, k, h, head_dim) * scale,
-                          _MASKED)
+            if group:   # the head in its KV head's half (`_flash_fwd_kernel`)
+                s = _dot_head(_half_moved(q, part == h, head_dim), k, part,
+                              head_dim)
+            else:
+                s = _dot_head(q, k, h, head_dim)
+            s = jnp.where(mask, s * scale, _MASKED)
             m = jnp.max(s, axis=-1, keepdims=True)
             p = jnp.exp(s - m)
             l = jnp.sum(p, axis=-1, keepdims=True)
             # P against every head of the block, this head's lanes kept
-            oh = _only_head(_dot(p.astype(v.dtype), v, _NN) / l, h, head_dim)
+            oh = _dot(p.astype(v.dtype), v, _NN) / l
+            if group:
+                oh = _half_moved(oh, part == h, head_dim)
+            oh = _only_head(oh, h, head_dim)
             o = oh if o is None else o + oh
             lse_ref[0, h, 0, rows] = (m + jnp.log(l))[:, 0]
         o_ref[0, rows, :] = o.astype(o_ref.dtype)
@@ -902,7 +1001,7 @@ def _flash_fwd_span_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, *rest,
                             causal: bool, window: int, scale: float,
                             rows: int, head_dim: int, block_diffusion=None,
-                            rope_dim: int = 0):
+                            rope_dim: int = 0, group=None):
     """``rows`` batch rows a grid cell, each with the heads of one column
     block and their whole sequence: the forward up to MAX_BWD_SEQ. The
     score tile is held as [k, q]: the softmax's max and sum then run down
@@ -912,16 +1011,19 @@ def _flash_fwd_whole_kernel(q_ref, k_ref, v_ref, *rest,
     through the MXU against the tile, which a head_dim of 64 fills where
     P V fills half its width. V^T and O^T are [W, S] tiles of the whole
     block: one transpose each serves every head of it. ``rope_dim``:
-    the two-part score, as in ``_flash_fwd_kernel``."""
+    the two-part score, ``group``: the KV head two query heads of 64
+    share, both as in ``_flash_fwd_kernel``."""
     heads = q_ref.shape[-1] // head_dim
     if rope_dim:
         qr_ref, kr_ref, o_ref, lse_ref = rest
         head = pl.program_id(1)   # taken outside the rows' loop
     else:
         o_ref, lse_ref = rest
+    part = _kv_part(1, group)
 
     def row(b):
-        q, k, v = q_ref[b], k_ref[b], v_ref[b]      # [S, W]
+        q = q_ref[b]                                 # [S, W]
+        k, v = (_own_kv_head(t[b], part, head_dim) for t in (k_ref, v_ref))
         vt = v.T                                     # [W, S]
         ots = []
         for h in range(heads):
@@ -959,16 +1061,19 @@ def _rope_operands(rope, num_heads: int, head_dim: int):
     return rope_dim, per, (q_rope, jnp.concatenate([k_rope] * per, axis=-1))
 
 
-def _group_size(num_heads: int, num_kv_heads, head_dim: int,
-                rope=None) -> int:
-    """Query heads that share a K/V head in the operands the kernels are
-    handed: 1 where k and v hold every head (``num_kv_heads`` None or
-    the heads), else the group's size, which ``grouped_kv_shape_legal``
-    has admitted."""
+def _group_size(num_heads: int, num_kv_heads, head_dim: int, rope=None):
+    """(rep, group): the query heads that share a K/V head in the
+    operands the kernels are handed, 1 where k and v hold every head
+    (``num_kv_heads`` None or the heads), else the group's size, which
+    ``grouped_kv_shape_legal`` has admitted; and what the kernels are
+    told where a K / V lane block holds two KV heads of 64 (PR 47),
+    (rep, heads a lane block) for ``_kv_part``, else None: at one head a
+    lane block the BlockSpec's ``j // rep`` has picked the head."""
     rep = num_heads // (num_kv_heads or num_heads)
     assert rep == 1 or (rope is None and grouped_kv_shape_legal(
         num_heads, num_kv_heads, head_dim)), (num_heads, num_kv_heads)
-    return rep
+    hpb = _heads_per_block(num_heads, head_dim)
+    return rep, (rep, hpb) if rep > 1 and hpb > 1 else None
 
 
 def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
@@ -985,6 +1090,13 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     group name the same block, which Pallas does not fetch again. The
     kernels' bodies are the same: the same K and V values meet the same
     Q block, so o and lse are those of the repeated keys bit for bit.
+    At heads of 64 (PR 47) the column block ``j`` holds two query heads
+    of ONE KV head ``2 j // rep``, which is a half of lane block
+    ``j // rep`` of k and v: the same index map, and the kernels lay
+    that half twice side by side (``_own_kv_head``), which is the block
+    the repeated form fetched, or, where K and V are read a chunk at a
+    time, move the query heads to that half (``_half_moved``): the same
+    products of the same values either way.
 
     ``rope`` = (q_rope [B, S, H*R], k_rope [B, S, R]): the score of a
     head is q k^T + q_rope k_rope^T over sqrt(D + R), the rotated key
@@ -997,7 +1109,7 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     hpb = _heads_per_block(num_heads, d)
     w = hpb * d
     rope_dim, per, rope_ops = _rope_operands(rope, num_heads, d)
-    rep = _group_size(num_heads, num_kv_heads, d, rope)
+    rep, group = _group_size(num_heads, num_kv_heads, d, rope)
     scale = 1.0 / float(d + rope_dim) ** 0.5
     window = normalized_window(s, causal, window)
     bd = checked_block_diffusion(s, causal, window, block_diffusion)
@@ -1019,7 +1131,7 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
             functools.partial(_flash_fwd_whole_kernel, causal=causal,
                               window=window, scale=scale, rows=rows,
                               head_dim=d, block_diffusion=bd,
-                              rope_dim=rope_dim),
+                              rope_dim=rope_dim, group=group),
             name=KERNEL_NAME_PREFIX + "flash_fwd_whole",
             out_shape=out_shape,
             grid=(b // rows, num_heads // hpb),
@@ -1036,14 +1148,15 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
         tiles, unroll = _span_tiles(s, rows)
         kernel = functools.partial(_flash_fwd_span_kernel, window=window,
                                    scale=scale, blk_q=rows, span=span,
-                                   tiles=tiles, unroll=unroll, head_dim=d)
+                                   tiles=tiles, unroll=unroll, head_dim=d,
+                                   group=group)
         blk = tiles * rows
     else:
         blk = _q_block(s, bd)
         kernel = functools.partial(
             _flash_fwd_kernel, causal=causal, window=window, scale=scale,
             blk_q=blk, blk_k=_seq_block(s, bd, window), head_dim=d,
-            block_diffusion=bd, rope_dim=rope_dim)
+            block_diffusion=bd, rope_dim=rope_dim, group=group)
     rope_specs = [
         pl.BlockSpec((1, blk, LANES), lambda b, j, i: (b, i, j // per)),
         pl.BlockSpec((1, s, LANES), lambda b, j, i: (b, 0, 0)),
@@ -1144,16 +1257,21 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       glse_ref, *rest, causal: bool, window: int,
                       scale: float, rows: int, head_dim: int,
                       block_diffusion=None, rope_dim: int = 0,
-                      grouped: bool = False):
+                      grouped: bool = False, group=None):
     """``rows`` batch rows a grid cell, each with the heads of one column
     block and the whole sequence in VMEM (gated by MAX_BWD_SEQ).
     Scores/probabilities never touch HBM — the reason XLA's einsum
     backward loses at these shapes. ``rope_dim``: the two-part score;
     dQr's block holds 128 // rope_dim heads and stays in VMEM while the
     grid runs through them, each writing its own lanes. ``grouped``
-    (PR 43): the grid is (rows, KV head, head of its group) and dK, dV
-    the group's float32 sums (``_group_sum``)."""
+    (PR 43): the grid is (rows, KV lane block, query column block of
+    its group) and dK, dV the group's float32 sums (``_group_sum``).
+    ``group`` (PR 47): the lane block is two KV heads of 64 and the
+    column block's two query heads share one: K and V are laid as that
+    head twice (``_own_kv_head``) and the two heads' dK^T / dV^T added
+    into its sublanes (``_group_halves``)."""
     member = pl.program_id(2) if grouped else 0
+    part = _kv_part(2, group)
     if rope_dim:
         qr_ref, kr_ref, dq_ref, dk_ref, dv_ref, dqr_ref, dkr_ref = rest
         head = pl.program_id(1)
@@ -1161,14 +1279,16 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dq_ref, dk_ref, dv_ref = rest
 
     def row(b):
-        k = k_ref[b]
+        k = _own_kv_head(k_ref[b], part, head_dim)
         rope = (_rope_lanes(qr_ref[b], head, rope_dim),
                 kr_ref[b]) if rope_dim else None
         dqt, dkt, dvt, *dr = _flash_bwd_tile(
-            q_ref[b], k, k.T, v_ref[b], o_ref[b], do_ref[b], lse_ref[b],
-            glse_ref[b], scale, (0, 0, window, block_diffusion)
+            q_ref[b], k, k.T, _own_kv_head(v_ref[b], part, head_dim),
+            o_ref[b], do_ref[b], lse_ref[b], glse_ref[b], scale,
+            (0, 0, window, block_diffusion)
             if causal or block_diffusion is not None else None, head_dim,
             rope)
+        dkt, dvt = (_group_halves(t, part, head_dim) for t in (dkt, dvt))
         dq_ref[b] = (dqt * scale).T.astype(dq_ref.dtype)
         _group_sum(dk_ref, b, (dkt * scale).T, grouped, member)
         _group_sum(dv_ref, b, dvt.T, grouped, member)
@@ -1185,7 +1305,7 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                               glse_ref, *rest, causal: bool, window: int,
                               scale: float, blk: int, head_dim: int,
                               block_diffusion=None, rope_dim: int = 0,
-                              grouped: bool = False):
+                              grouped: bool = False, group=None):
     """FA2 backward for sequences past MAX_BWD_SEQ: grid cell = one
     (batch row, column block, K-block). The Q/O/dO panels are resident;
     the K-block meets them in chunks of ``blk`` queries, and ONLY the
@@ -1234,10 +1354,21 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     the group's heads and all their K blocks and written once a group:
     a step turns its sums into the block's rows, the group's first head
     writing and the others adding (``_group_sum``). Nothing of H * D
-    width leaves the backward but dQ."""
+    width leaves the backward but dQ.
+
+    ``group`` (PR 47): the K / V lane block holds two KV heads of 64 and
+    the grid's second and third axes are (KV lane block, query column
+    block of the ``rep`` it serves). The column block's two query heads
+    share the KV head ``_kv_part`` names: the step's K and V blocks are
+    laid as that head twice (``_own_kv_head``, once a grid step, not a
+    chunk), and at the end the two heads' dK^T / dV^T, sublane ranges of
+    the scratch, are added into that head's sublanes
+    (``_group_halves``); the other half gets zeros from this member, so
+    the lane block's first member still writes without reading."""
     j = pl.program_id(3 if grouped else 2)
     k0 = j * blk
-    k, v = k_ref[0], v_ref[0]
+    part = _kv_part(2, group)
+    k, v = (_own_kv_head(t[0], part, head_dim) for t in (k_ref, v_ref))
     kt = k.T
     split = _q_split(k0, blk, blk, q_ref.shape[1], causal, window,
                      block_diffusion)
@@ -1282,7 +1413,8 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     dvt_ref[...] = jnp.zeros(dvt_ref.shape, jnp.float32)
     for lo, hi, edge in split:
         jax.lax.fori_loop(lo, hi, functools.partial(chunk, edge=edge), None)
-    dkt, dvt = dkt_ref[...], dvt_ref[...]
+    dkt, dvt = (_group_halves(t[...], part, head_dim)
+                for t in (dkt_ref, dvt_ref))
     at = (0, pl.ds(pl.multiple_of(k0, blk), blk)) if grouped else 0
     member = pl.program_id(2) if grouped else 0
     _group_sum(dk_ref, at, (dkt * scale).T, grouped, member)
@@ -1294,7 +1426,8 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 def _flash_bwd_span_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                            glse_ref, dq_ref, dk_ref, dv_ref, *, window: int,
                            scale: float, blk: int, span: int, tiles: int,
-                           unroll: int, head_dim: int, grouped: bool = False):
+                           unroll: int, head_dim: int, grouped: bool = False,
+                           group=None):
     """The K-blocked backward under a window so narrow that the queries
     which see a K block are ONE score tile (``one_span``, PR 46): each
     of the grid step's ``tiles`` K blocks meets the ``span`` queries of
@@ -1305,9 +1438,10 @@ def _flash_bwd_span_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     float32 panel as in ``_flash_bwd_blocked_kernel`` (neighbouring K
     blocks' spans overlap). The grids, the BlockSpecs and the grouped
     form's sums into the KV head's panels (``_group_sum``) are that
-    kernel's."""
+    kernel's, ``group`` too."""
     j = pl.program_id(3 if grouped else 2)
     member = pl.program_id(2) if grouped else 0
+    part = _kv_part(2, group)
 
     @pl.when(j == 0)
     def _init():
@@ -1316,13 +1450,15 @@ def _flash_bwd_span_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     def block(t):
         mine = pl.ds(pl.multiple_of(t * blk, blk), blk)
         k0 = pl.multiple_of((j * tiles + t) * blk, blk)
-        k, v = k_ref[0, mine, :], v_ref[0, mine, :]
+        k, v = (_own_kv_head(t[0, mine, :], part, head_dim)
+                for t in (k_ref, v_ref))
         q0 = pl.multiple_of(_q_span(k0, span, q_ref.shape[1]), BLK_Q)
         rows = pl.ds(q0, span)
         dqt, dkt, dvt = _flash_bwd_tile(
             q_ref[0, rows, :], k, k.T, v, o_ref[0, rows, :],
             do_ref[0, rows, :], lse_ref[0, :, :, rows],
             glse_ref[0, :, :, rows], scale, (k0, q0, window, None), head_dim)
+        dkt, dvt = (_group_halves(t, part, head_dim) for t in (dkt, dvt))
         dq_ref[0, rows, :] += (dqt * scale).T
         # a group's dK / dV block is the KV head's whole panel
         at = (0, pl.ds(k0, blk) if grouped else mine)
@@ -1344,13 +1480,15 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
     heads, into the one rotated key, is taken here. With
     ``num_kv_heads`` < H (k, v [B, S, Hk*D]) dk and dv are the groups'
     sums [B, S, Hk*D] in FLOAT32, added up inside the kernels from their
-    float32 tiles: no query head's dK or dV is written, or rounded."""
+    float32 tiles: no query head's dK or dV is written, or rounded. At
+    heads of 64 (PR 47) the grouped grids run over the KV LANE blocks
+    (two KV heads) and the ``rep`` query column blocks each serves."""
     b, s, hd = q.shape
     d = hd // num_heads
     hpb = _heads_per_block(num_heads, d)
     w = hpb * d
     rope_dim, per, rope_ops = _rope_operands(rope, num_heads, d)
-    rep = _group_size(num_heads, num_kv_heads, d, rope)
+    rep, group = _group_size(num_heads, num_kv_heads, d, rope)
     grouped = rep > 1
     dk_shape, dv_shape = (jax.ShapeDtypeStruct(
         t.shape, jnp.float32 if grouped else t.dtype) for t in (k, v))
@@ -1380,18 +1518,19 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         qr_spec = pl.BlockSpec((rows, s, LANES), lambda i, j: (i, 0, j // per))
         kr_spec = pl.BlockSpec((rows, s, LANES), lambda i, j: (i, 0, 0))
         kv_spec, grid = seq_spec, (b // rows, num_heads // hpb)
-        if grouped:     # (rows, KV head g, head r of its group)
+        if grouped:     # (rows, KV lane block g, column block r of its rep)
             seq_spec = pl.BlockSpec((rows, s, w),
                                     lambda i, g, r: (i, 0, g * rep + r))
             row_spec = pl.BlockSpec((rows, hpb, 1, s),
                                     lambda i, g, r: (i, g * rep + r, 0, 0))
             kv_spec = pl.BlockSpec((rows, s, w), lambda i, g, r: (i, 0, g))
-            grid = (b // rows, num_heads // rep, rep)
+            grid = (b // rows, num_heads // hpb // rep, rep)
         return finish(*pl.pallas_call(
             functools.partial(_flash_bwd_kernel, causal=causal,
                               window=window, scale=scale, rows=rows,
                               head_dim=d, block_diffusion=bd,
-                              rope_dim=rope_dim, grouped=grouped),
+                              rope_dim=rope_dim, grouped=grouped,
+                              group=group),
             name=KERNEL_NAME_PREFIX + "flash_bwd",
             out_shape=(jax.ShapeDtypeStruct((b, s, hd), q.dtype),
                        dk_shape, dv_shape) + ((
@@ -1415,14 +1554,14 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         kernel = functools.partial(_flash_bwd_span_kernel, window=window,
                                    scale=scale, blk=rows, span=span,
                                    tiles=tiles, unroll=unroll, head_dim=d,
-                                   grouped=grouped)
+                                   grouped=grouped, group=group)
         blk, scratch = tiles * rows, []
     else:
         blk = _seq_block(s, bd, window)
         kernel = functools.partial(
             _flash_bwd_blocked_kernel, causal=causal, window=window,
             scale=scale, blk=blk, head_dim=d, block_diffusion=bd,
-            rope_dim=rope_dim, grouped=grouped)
+            rope_dim=rope_dim, grouped=grouped, group=group)
         # dK^T and dV^T (and dKr^T) added up over a block's Q chunks
         scratch = [pltpu.VMEM((w, blk), jnp.float32)] * 2 + (
             [pltpu.VMEM((LANES, blk), jnp.float32)] if rope_dim else [])
@@ -1432,7 +1571,7 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
     qr_spec = pl.BlockSpec((1, s, LANES), lambda b, c, j: (b, 0, c // per))
     kr_spec = pl.BlockSpec((1, blk, LANES), lambda b, c, j: (b, j, 0))
     dkv_spec, grid = kblk_spec, (b, num_heads // hpb, s // blk)
-    if grouped:     # (batch row, KV head g, head r of its group, K block)
+    if grouped:     # (batch row, KV lane block g, column block r, K block)
         seq_spec = pl.BlockSpec((1, s, w),
                                 lambda b, g, r, j: (b, 0, g * rep + r))
         row_spec = pl.BlockSpec((1, hpb, 1, s),
@@ -1440,7 +1579,7 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         kblk_spec = pl.BlockSpec((1, blk, w), lambda b, g, r, j: (b, j, g))
         # the KV head's whole panel: resident across its group
         dkv_spec = pl.BlockSpec((1, s, w), lambda b, g, r, j: (b, 0, g))
-        grid = (b, num_heads // rep, rep, s // blk)
+        grid = (b, num_heads // hpb // rep, rep, s // blk)
     return finish(*pl.pallas_call(
         kernel,
         name=KERNEL_NAME_PREFIX + "flash_bwd_blocked",
@@ -1706,7 +1845,8 @@ def moe_sum_rows(x, token, weight, items, tokens: int, dtype,
 # ---------------------------------------------------------------------------
 # the per-head pass between a projection and a flash kernel (PR 42): the
 # heads' RMS norm and rotary on the projection's float32 [B, S, H*128]
-# result as it lies. Through a [B, S, H, D] view XLA tiles (H, D), and
+# (PR 47: or [B, S, H*64]) result as it lies. Through a [B, S, H, D] view
+# XLA tiles (H, D), and
 # every crossing between that and the [B, S, H*D] the products write and
 # the kernels take is a copy of a float32 array of S x H*D elements.
 
@@ -1714,10 +1854,14 @@ ROTARY_ROWS = 512   # rows a block of `rotary_lanes`, where S allows
 ROTARY_HEADS = 4    # heads (128-lane columns) a block, where H allows
 
 
-def rotary_lanes_shape_legal(seq_len: int, head_dim: int) -> bool:
-    """What `rotary_lanes` takes: a head that is one 128-lane column and
-    whole blocks of rows (the flash kernels' tile)."""
-    return head_dim == LANES and seq_len > 0 and seq_len % BLK_Q == 0
+def rotary_lanes_shape_legal(seq_len: int, head_dim: int,
+                             num_heads: int = 1) -> bool:
+    """What `rotary_lanes` takes: heads that are whole 128-lane columns,
+    one of 128 or (PR 47) two of 64 side by side, so an even number of
+    those, and whole blocks of rows (the flash kernels' tile)."""
+    return (head_dim in (LANES, LANES // 2)
+            and num_heads * head_dim % LANES == 0
+            and seq_len > 0 and seq_len % BLK_Q == 0)
 
 
 def _rotary_block(s: int, heads: int):
@@ -1728,20 +1872,40 @@ def _rotary_block(s: int, heads: int):
                       if heads % h == 0)
 
 
-def _partner(x, half: int):
-    """x [rows, 128], one head: lane j < half gets x[j + half], lane
-    half <= j < 2 half gets x[j - half] (the sign is the sine table's);
-    past the rotated lanes what comes meets a sine of 0."""
+def _partner(x, half: int, head_dim: int = LANES):
+    """x [rows, 128], one head of 128 or two of 64: lane j < half OF ITS
+    HEAD gets x[j + half], lane half <= j < 2 half gets x[j - half] (the
+    sign is the sine table's); past the rotated lanes what comes meets a
+    sine of 0. Neither roll leaves the head on a lane that takes it."""
     if 2 * half == LANES:
         return pltpu.roll(x, half, 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    if head_dim < LANES:
+        lane = lane % head_dim
     return jnp.where(lane < half, pltpu.roll(x, LANES - half, 1),
                      pltpu.roll(x, half, 1))
 
 
+def _head_mean(x, head_dim: int):
+    """The mean of x [rows, 128] over a head's lanes, float32: [rows, 1]
+    for one head of 128; for two heads of 64 two masked sums, each laid
+    over its own head's lanes, [rows, 128]."""
+    if head_dim == LANES:
+        return jnp.mean(x, axis=-1, keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    first = lane < head_dim
+    sums = [jnp.sum(jnp.where(mine, x, 0.0), axis=-1, keepdims=True)
+            for mine in (first, lane >= head_dim)]
+    return jnp.where(first, *sums) / head_dim
+
+
 def _rotary_lanes_kernel(*refs, heads: int, half: int, transposed: bool,
-                         eps):
-    """One block of rows of ``heads`` heads. Forward: y = n cos +
+                         eps, head_dim: int = LANES):
+    """One block of rows of ``heads`` 128-lane columns, each one head of
+    128 or two of 64 (``head_dim``; the tables and the scale are then
+    one head's laid twice, the partner lane and the norm's mean stay
+    inside a 64-lane half, and d scale's two halves are added by the
+    caller). Forward: y = n cos +
     partner(n) sin, n = x, or with ``eps`` the head's RMS norm x
     rsqrt(mean(x^2) + eps) scale; float32, rounded once into the output.
     ``transposed``: the backward. dn = g cos - partner(g) sin (the two
@@ -1769,33 +1933,37 @@ def _rotary_lanes_kernel(*refs, heads: int, half: int, transposed: bool,
         a = a_ref[0, :, lanes].astype(jnp.float32)
         if not transposed:
             if normed:
-                a = a * jax.lax.rsqrt(jnp.mean(a * a, axis=-1, keepdims=True)
+                a = a * jax.lax.rsqrt(_head_mean(a * a, head_dim)
                                       + eps) * scale_ref[...]
-            o_ref[0, :, lanes] = (a * cos + _partner(a, half) * sin
+            o_ref[0, :, lanes] = (a * cos + _partner(a, half, head_dim) * sin
                                   ).astype(o_ref.dtype)
             continue
-        dn = a * cos - _partner(a, half) * sin
+        dn = a * cos - _partner(a, half, head_dim) * sin
         if normed:
             x = x_ref[0, :, lanes]
-            r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+            r = jax.lax.rsqrt(_head_mean(x * x, head_dim) + eps)
             u = dn * scale_ref[...]
             dscale_ref[...] += (dn * x * r).reshape(
                 -1, 8, LANES).sum(axis=0)
-            dn = r * u - x * (r * r * r * jnp.mean(u * x, axis=-1,
-                                                   keepdims=True))
+            dn = r * u - x * (r * r * r * _head_mean(u * x, head_dim))
         o_ref[0, :, lanes] = dn.astype(o_ref.dtype)
 
 
 def _rotary_lanes_call(operands, cos, sin, scale, half, eps, dtype,
                        interpret, transposed, block=None):
     """`_rotary_lanes_kernel` over ``operands`` (x, or x and g) [B, S,
-    H*128]: grid (batch, row block, column block), the column block last,
-    so that a row block's tables are fetched once. The operands allow
+    H*D]: grid (batch, row block, column block), the column block last,
+    so that a row block's tables are fetched once. ``cos``, ``sin``
+    [S, D] and ``scale`` [D] are one head's, D 128 or 64: at 64 they are
+    laid twice side by side here, a 128-lane column's. The operands allow
     input fusion: the cast XLA would run ahead of the call as a pass of
     its own (the flash backward's float32 dQ rounded to the cotangent's
     bfloat16: 0.76 ms a 64-head op on the v5e) rides in the kernel's
     fetch."""
     b, s, width = operands[-1].shape
+    d = cos.shape[-1]
+    if d < LANES:
+        cos, sin = (jnp.tile(t, (1, LANES // d)) for t in (cos, sin))
     rows, heads = block or _rotary_block(s, width // LANES)
     of_block = pl.BlockSpec((1, rows, heads * LANES),
                             lambda i, r, c: (i, r, c))
@@ -1804,7 +1972,9 @@ def _rotary_lanes_call(operands, cos, sin, scale, half, eps, dtype,
     out_specs = [of_block]
     normed = eps is not None
     if normed:
-        scale = (scale.astype(jnp.float32).reshape(1, LANES),)
+        scale = (jnp.tile(scale.astype(jnp.float32), LANES // d)
+                 if d < LANES else scale.astype(jnp.float32))
+        scale = (scale.reshape(1, LANES),)
         if transposed:
             out_shape.append(jax.ShapeDtypeStruct((8, LANES), jnp.float32))
             out_specs.append(pl.BlockSpec((8, LANES), lambda i, r, c: (0, 0)))
@@ -1812,7 +1982,7 @@ def _rotary_lanes_call(operands, cos, sin, scale, half, eps, dtype,
         scale = ()
     out = pl.pallas_call(
         functools.partial(_rotary_lanes_kernel, heads=heads, half=half,
-                          transposed=transposed, eps=eps),
+                          transposed=transposed, eps=eps, head_dim=d),
         name="rotary_lanes_bwd" if transposed else "rotary_lanes",
         out_shape=out_shape,
         grid=(b, s // rows, width // (heads * LANES)),
@@ -1849,18 +2019,22 @@ def _rotary_lanes_bwd(half, eps, dtype, interpret, res, g):
     if x is None:
         return back, None, None, None
     dx, dscale = back
-    return dx, None, None, dscale.sum(axis=0).astype(scale.dtype)
+    # the block's 8 rows, and at heads of 64 a column's two heads
+    dscale = dscale.reshape(-1, scale.shape[0]).sum(axis=0)
+    return dx, None, None, dscale.astype(scale.dtype)
 
 
 _rotary_lanes.defvjp(_rotary_lanes_fwd, _rotary_lanes_bwd)
 
 
 def rotary_lanes(x, cos, sin, half: int, dtype, norm=None):
-    """The heads' RMS norm (``norm`` = (scale [128], eps), or None) and
-    rotary of x [B, S, H*128] float32, every head one 128-lane column as
-    a projection's product leaves it -> the same shape in ``dtype``, what
-    a flash kernel takes: one pass, float32 inside, rounded once, no
-    [B, S, H, D] view. ``cos``, ``sin`` [S, 128] float32: a row's tables
+    """The heads' RMS norm (``norm`` = (scale [D], eps), or None) and
+    rotary of x [B, S, H*D] float32, the heads side by side as a
+    projection's product leaves them, D 128 (a head a 128-lane column)
+    or 64 (two a column, PR 47) -> the same shape in ``dtype``, what a
+    flash kernel takes: one pass, float32 inside, rounded once, no
+    [B, S, H, D] view. ``cos``, ``sin`` [S, D] float32, whose width says
+    what D is: a row's tables
     for one head, the sine with the partner's sign (minus on the first
     ``half`` lanes), 1 and 0 on the lanes a partial rotary leaves alone;
     positions, wrapping and YaRN's factor are the tables'. Its own
@@ -2125,12 +2299,43 @@ def grouped_kv_shape_legal(num_heads: int, num_kv_heads: int,
                            head_dim: int) -> bool:
     """Whether the flash kernels take grouped-query keys and values as
     [B, S, Hk*D] (PR 43), the shape half of the rule: whole groups, and
-    a head that is ONE 128-lane column block, so that a K / V BlockSpec
-    picks a group's block by ``j // rep``. At head_dim 64 a column block
-    holds two query heads, which may belong to two groups: those ops
-    repeat K and V as before."""
+    heads that fill 128-lane column blocks such that every query column
+    block reads ONE KV head of the K / V lane block its BlockSpec picks
+    by ``j // rep``. A head of 128 is a column block of its own. At
+    heads of 64 (PR 47) a column block holds two query heads and a K / V
+    lane block two KV heads: the two query heads share a KV head where
+    the group's size is even, and k and v are whole lane blocks where
+    the KV heads are (32 : 8 and 4 : 2 pass; 6 : 2, a group of 3, and
+    14 : 7, an odd Hk, repeat K and V as before). The kernels then take
+    that head's half of the lane block (``_own_kv_head``,
+    ``_half_moved``).
+
+    v5e, lfm2's op whole (32 : 8 heads of 64, 16,384 positions, the
+    heads' norm, whole rotary, causal; bf16; forward and backward of one
+    attention op, device ms; PR 47, `scripts/flash_lab.py --only
+    grouped.lfm2`), the flash forward / backward kernel and what else
+    the form runs. As shipped until PR 47 (`repeated_view`: the norm and
+    rotary over a [B, S, H, 64] view, K and V repeated): **53.14**,
+    kernels 16.64 / 22.48, the projections' fusions 5.63, XLA's passes
+    between them 7.9 (copy 2.75, multiply_reduce 0.90, add_convert 0.83,
+    broadcast_multiply 0.80, slice_negate 0.68, add_add 0.48,
+    convert_convert 0.42, broadcast 0.41, reduce 0.36, pad_maximum
+    0.30). The pass in lanes, K and V still repeated (`repeated`):
+    **47.29**, kernels 16.64 / 22.47, `rotary_lanes` 0.41 + 0.77, the
+    repeat and its sum 1.24 (copy 0.67, reduce 0.36, bitcast_convert
+    0.21). K and V at the KV heads with the KV head laid twice a
+    fetched CHUNK in the forward: **47.52**, kernels 40.36 together
+    (+1.25: a roll and a select of a [1024, 128] K and V tile 8,704
+    times an op do not hide under the MXU's passes). **As shipped**
+    (`grouped`: the forward moves the query block's heads once a grid
+    step, the backward lays the K block's head twice once a grid step):
+    **46.40**, kernels 16.68 / 22.56, fusions 5.71, `rotary_lanes`
+    0.42 + 0.82, everything else 0.2: 6.74 ms an op under the shipped
+    form, of which the view forms 5.85 and the repeat 0.89."""
+    hpb = LANES // head_dim if head_dim in (LANES, LANES // 2) else 0
     return (0 < num_kv_heads < num_heads and num_heads % num_kv_heads == 0
-            and head_dim == LANES)
+            and hpb > 0 and (num_heads // num_kv_heads) % hpb == 0
+            and num_kv_heads % hpb == 0)
 
 
 def flash_attention(q, k, v, num_heads: int, causal: bool = False,
@@ -2149,10 +2354,11 @@ def flash_attention(q, k, v, num_heads: int, causal: bool = False,
     ``num_kv_heads`` < H (PR 43; caller checks ``grouped_kv_shape_legal``):
     k and v are [B, S, Hk*D], a K/V head shared by H / Hk consecutive
     query heads, and are never repeated: the kernels read a group's
-    block for each of its heads. Their gradients are the groups' sums in
-    float32 (whatever dtype k and v come in, the kernels read them
-    rounded to q's); ``num_kv_heads`` None or H is the call without it,
-    jaxpr and all."""
+    block for each of its heads (at heads of 64 a half of a lane block
+    for each of its column blocks, PR 47). Their gradients are the
+    groups' sums in float32 (whatever dtype k and v come in, the kernels
+    read them rounded to q's); ``num_kv_heads`` None or H is the call
+    without it, jaxpr and all."""
     static = (num_heads, causal, pallas_mode() == "interpret", window,
               tuple(block_diffusion) if block_diffusion else None,
               tuple(rope) if rope is not None else None)

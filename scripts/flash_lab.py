@@ -38,15 +38,19 @@ as `_seq_block` cuts it (chunks of 512) against spans of 1024 and 896.
 
 `--only grouped` (PR 43) times ONE WHOLE ATTENTION OP instead, forward
 and backward (`value_and_grad` over its parameters and input, as a train
-step runs it), at the five grouped-query shapes of the decoder cells
+step runs it), at the six grouped-query shapes of the decoder cells
 (laguna's 64 : 8 window and 48 : 8 full ops, sdar's 8 : 1, smallthinker's
-7 : 1, nemotron's 4 : 1), in two forms: `repeated` (K and V repeated to
-[B, S, H*128] ahead of the kernels, the shipped form until PR 43, made
+7 : 1, nemotron's 4 : 1, and since PR 47 lfm2's 32 : 8 at heads of 64
+with the heads' norm), in two forms: `repeated` (K and V repeated to
+[B, S, H*D] ahead of the kernels, the shipped form until PR 43, made
 here by holding the op's route to `grouped_kv=False`) and `grouped` (the
 kernels read K and V at the KV heads and add a group's dK / dV up in
 their resident float32 panel: what ships), and where the shipped kernels
 take the one-span form (laguna's window op) a third, `grouped_chunks`:
-grouped keys with `one_span` held to None, the chunk loop of PR 41. A
+grouped keys with `one_span` held to None, the chunk loop of PR 41. At
+heads of 64 (lfm2) one more, `repeated_view`: the repeat AND the heads'
+norm and rotary over the [B, S, H, 64] view, what shipped until PR 47
+(`grouped_kv` and `rotary_in_lanes` both held False). A
 line holds the forms'
 device ms, their ops by stem, and the largest difference of the value
 and of every gradient between them (`grouped_vs_repeated`).
@@ -55,6 +59,7 @@ Prints one JSON line a measurement and writes them to
 `chiprun_out/flash_lab.json`. Nothing here is a benchmark metric.
 
     python scripts/flash_lab.py [--tiny] [--only <part of a shape's name>]
+    python scripts/flash_lab.py --only grouped[.<part of an op's name>]
 
 `--tiny` is the CPU rehearsal (short sequences, the kernels interpreted,
 no device in the trace, so `device_ms` is null).
@@ -154,9 +159,10 @@ def kernel_ms(fn, args, interpret):
     return 1e3 * sum(found) / REPS
 
 
-def grouped_op_lines(tiny):
+def grouped_op_lines(tiny, only=""):
     """`--only grouped`: one line a grouped-query attention op of the
-    decoder cells, repeated against grouped keys."""
+    decoder cells, repeated against grouped keys (`--only grouped.lfm2`:
+    the ops whose name holds what follows the dot)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -176,24 +182,33 @@ def grouped_op_lines(tiny):
            "sdar.block_diffusion_8_1": IN_CONTEXT["norm_whole"],
            "smallthinker.window_7_1": IN_CONTEXT["whole_7_1"],
            "nemotron.full_4_1": (8192, 2688, dict(
-               num_heads=4, num_kv_heads=1, causal=True, rope=False))}
+               num_heads=4, num_kv_heads=1, causal=True, rope=False)),
+           "lfm2.full_32_8": (16384, 2048, dict(
+               num_heads=32, num_kv_heads=8, head_dim=64, causal=True,
+               qk_norm=True, rope_theta=1000000.0))}
     lines = []
     for name, (seq, hidden, props) in ops.items():
+        if only not in name:
+            continue
         if tiny:
             seq, hidden, props = _tiny(props)
             if "window" in props:   # past the whole-tile kernels: one span
                 seq = 1536
+            elif props.get("head_dim") == 64:   # the chunk loop, as the cell
+                seq = 1280
         jitted, outs = {}, {}
-        for form in FORMS + ("grouped_chunks",):
+        for form, held in FORMS.items():
+            if form == "repeated_view" and props.get("head_dim") != 64:
+                continue    # at 128 lanes the pass has no shape rule to hold
             layer = Layer(OperatorType.MULTIHEAD_ATTENTION, "op", [])
             layer.properties.update(dict(
-                dict(rope=True), **props, embed_dim=hidden, head_dim=128,
+                dict(rope=True, head_dim=128), **props, embed_dim=hidden,
                 bias=False))
             op = OpRegistry.create(layer, [(1, seq, hidden)] * 3)
-            if form == "repeated":
+            if held:
                 route = op.route
-                op.route = lambda *a, route=route, **k: dataclasses.replace(
-                    route(*a, **k), grouped_kv=False)
+                op.route = lambda *a, route=route, held=held, **k: (
+                    dataclasses.replace(route(*a, **k), **held))
 
             def run(params, x, g, op=op):
                 ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
@@ -216,7 +231,10 @@ def grouped_op_lines(tiny):
                 outs[form] = jax.block_until_ready(jitted[form][0](*args))
             finally:
                 pk.one_span = shipped
-            assert op._route.grouped_kv == (form != "repeated"), (name, form)
+            assert op._route.grouped_kv == ("repeated" not in form) and (
+                op._route.rotary_in_lanes == (
+                    props.get("rope", True) and form != "repeated_view")), (
+                        name, form, op._route)
         def against(other):
             """The largest difference of the shipped form's value and of
             each of its gradients from ``other``'s."""
@@ -237,9 +255,12 @@ def grouped_op_lines(tiny):
             grouped_vs_repeated=against("repeated"))
         if "grouped_chunks" in outs:
             line["one_span_vs_chunks"] = against("grouped_chunks")
+        if "repeated_view" in outs:
+            line["grouped_vs_repeated_view"] = against("repeated_view")
         # a CPU trace has no device lane to read
+        # the forward's kernel apart from the backward's where causal
         for form, (ms, by_stem) in ({} if tiny else device_ms(
-                jitted, stems=12)).items():
+                jitted, stems=13, whole=("flash_full",))).items():
             line[form + "_device_ms"] = round(ms, 3)
             line[form + "_device_ops"] = by_stem
         print(json.dumps(line), flush=True)
@@ -247,7 +268,10 @@ def grouped_op_lines(tiny):
     return lines
 
 
-FORMS = ("repeated", "grouped")
+# form -> the fields of the op's route held (nothing: what ships)
+FORMS = {"repeated": dict(grouped_kv=False), "grouped": {},
+         "grouped_chunks": {},
+         "repeated_view": dict(grouped_kv=False, rotary_in_lanes=False)}
 
 
 def main():
@@ -275,7 +299,9 @@ def main():
         for name, fn in shipped.items():
             setattr(pk, name, fn)
 
-    lines = grouped_op_lines(tiny) if only and only in "grouped" else []
+    lines = []
+    if only and (only in "grouped" or only.startswith("grouped.")):
+        lines = grouped_op_lines(tiny, only[len("grouped."):])
     for name, (heads, seq, causal, window, bd) in SHAPES.items():
         if only not in name:
             continue

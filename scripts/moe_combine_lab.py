@@ -275,10 +275,11 @@ def timed_ms(fn, args, n=20):
     return (time.perf_counter() - t) / n * 1e3
 
 
-def device_ms(jitted, calls=5, stems=6):
+def device_ms(jitted, calls=5, stems=6, whole=()):
     """name -> (function, arguments): every piece `calls` times under the
     profiler -> name -> (median device ms of its program, ms a call of
-    its ops by stem, largest first). A call's wall time has a floor, what
+    its ops by stem, largest first; an op whose stem is in ``whole``
+    under its own name, `flash_full.3` apart from `flash_full.4`). A call's wall time has a floor, what
     the host takes to hand over a piece's arguments (0.25 ms with a
     routing's fifteen arrays); the device's own clock has none."""
     import collections
@@ -306,7 +307,8 @@ def device_ms(jitted, calls=5, stems=6):
         for n, s, d in dev.lines.get(tr.OPS, ()):
             if any(a <= s < b for a, b in runs) and not n.startswith(
                     tr.ENCLOSING):
-                ops[tr.stem(n)] += d / len(runs) * 1e3
+                ops[n if tr.stem(n) in whole else tr.stem(n)] += (
+                    d / len(runs) * 1e3)
         out[name] = (statistics.median(b - a for a, b in runs) * 1e3,
                      {k: round(v, 4) for k, v in ops.most_common(stems)})
     return out

@@ -31,7 +31,8 @@ OPS = {
     "causal_window": (
         dict(num_heads=4, causal=True, window=128, rope=True),
         (1, 512, 512, 256), None, "interpret", True,
-        dict(core="flash", scope="window", rotary_in_lanes=False,
+        # four heads of 64: two whole 128-lane columns (PR 47)
+        dict(core="flash", scope="window", rotary_in_lanes=True,
              one_span=False,            # the whole-tile kernels
              kv_blocks=(*pk.kv_blocks(512, True, 128),
                         pk.kv_blocks_masked(512, True, 128)),
@@ -63,11 +64,38 @@ OPS = {
              rope=True), (1, 128, 128, 64), None, "interpret", True,
         dict(core="flash", scope="full", grouped_kv=True,
              rotary_in_lanes=True)),
-    "gqa_8_of_32_heads_of_64_repeated": (
+    # PR 47: lfm2's op. Two heads of 64 a 128-lane column, both of one
+    # KV head, which is half a K / V lane block: the lane-dense route
+    "gqa_8_of_32_heads_of_64_grouped": (
         dict(num_heads=32, num_kv_heads=8, head_dim=64, causal=True,
+             rope=True, qk_norm=True), (1, 128, 128, 64), None, "interpret",
+        True,
+        dict(core="flash", scope="full", grouped_kv=True,
+             rotary_in_lanes=True)),
+    # a group of 3 heads of 64: a column block would meet two KV heads,
+    # the repeat stays; the pass does not ask about groups
+    "gqa_2_of_6_heads_of_64_repeated": (
+        dict(num_heads=6, num_kv_heads=2, head_dim=64, causal=True,
+             rope=True), (1, 128, 128, 64), None, "interpret", True,
+        dict(core="flash", scope="full", grouped_kv=False,
+             rotary_in_lanes=True)),
+    # three KV heads of 64 are one and a half lane blocks: neither form
+    "gqa_3_of_6_heads_of_64_view_and_repeat": (
+        dict(num_heads=6, num_kv_heads=3, head_dim=64, causal=True,
              rope=True), (1, 128, 128, 64), None, "interpret", True,
         dict(core="flash", scope="full", grouped_kv=False,
              rotary_in_lanes=False)),
+    # under a head axis a shard holds whole K / V lane blocks or repeats
+    "head_axis_keeps_whole_lane_blocks_of_64": (
+        dict(num_heads=16, num_kv_heads=8, head_dim=64, causal=True,
+             head_parallel="model"), (2, 128, 128, 64),
+        {"data": 2, "model": 4}, "interpret", True,
+        dict(core="flash", shard_axes=("data", "model"), grouped_kv=True)),
+    "head_axis_splits_a_lane_block_of_64": (
+        dict(num_heads=16, num_kv_heads=4, head_dim=64, causal=True,
+             head_parallel="model"), (2, 128, 128, 64),
+        {"data": 2, "model": 4}, "interpret", True,
+        dict(core="flash", shard_axes=("data", "model"), grouped_kv=False)),
     "latent_32_heads_of_128_and_64": (
         dict(LATENT, num_heads=32, head_dim=128, qk_rope_head_dim=64,
              rope=True), (1, 128, 128, 64), None, "interpret", True,

@@ -478,6 +478,48 @@ def _repeated(x, hk, rep):
                       ).reshape(b, s, rep * w)
 
 
+def _assert_grouped_is_the_repeated_form(q, k, v, do, h, hk, causal, kw):
+    """o, lse and dQ of the kernels on k, v [B, S, Hk*D] equal, bit for
+    bit, those on the `jnp.repeat`ed keys; dK and dV the groups' float32
+    sums within a bf16 rounding of each head's partial; the public call
+    the same values."""
+    rep, seq, d = h // hk, q.shape[1], k.shape[-1] // hk
+    o, lse = pk._flash_fwd(q, k, v, h, causal, True, num_kv_heads=hk, **kw)
+    kr, vr = _repeated(k, hk, rep), _repeated(v, hk, rep)
+    want_o, want_lse = pk._flash_fwd(q, kr, vr, h, causal, True, **kw)
+    assert np.array_equal(np.asarray(o, np.float32),
+                          np.asarray(want_o, np.float32))
+    assert np.array_equal(np.asarray(lse), np.asarray(want_lse))
+
+    dq, dk, dv = pk._flash_bwd(q, k, v, o, lse, do, h, causal, True,
+                               num_kv_heads=hk, **kw)
+    want_dq, dkr, dvr = pk._flash_bwd(q, kr, vr, o, lse, do, h, causal,
+                                      True, **kw)
+    assert np.array_equal(np.asarray(dq, np.float32),
+                          np.asarray(want_dq, np.float32))
+    for name, got, parts in (("dk", dk, dkr), ("dv", dv, dvr)):
+        assert got.dtype == jnp.float32 and got.shape == (1, seq, hk * d)
+        parts = np.asarray(parts, np.float32).reshape(1, seq, hk, rep, d)
+        want, room = parts.sum(3), np.abs(parts).sum(3)
+        off = np.abs(np.asarray(got).reshape(want.shape) - want)
+        assert np.all(off <= 2.02 * U * room + 1e-6 * np.abs(want).max()), (
+            name, float(np.max(off / (U * room + 1e-30))))
+        # and it is the group's sum, not one head's: the partials differ
+        assert np.abs(want).max() > 0 and not np.allclose(want,
+                                                          parts[..., 0, :])
+
+    # through the public call: float32 keys in (as the op hands them),
+    # float32 group sums out, the same values
+    g = jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
+        q, k, v, h, causal, num_kv_heads=hk, **kw).astype(jnp.float32)
+        * do.astype(jnp.float32)), argnums=(0, 1, 2))(
+            q, k.astype(jnp.float32), v.astype(jnp.float32))
+    assert [a.dtype for a in g] == [jnp.bfloat16, jnp.float32, jnp.float32]
+    for a, b in zip(g, (dq, dk, dv)):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+
+
 # S <= MAX_BWD_SEQ: `flash_fwd_whole` + `flash_bwd`; past it the blocked
 # kernels (K blocks of 256 at 1280 positions, of 128 under the
 # block-diffusion mask: five and ten a head)
@@ -522,40 +564,7 @@ def test_grouped_keys_match_the_repeated_ones(rep, mask, seq, monkeypatch):
                     q, k, v, h, causal, **kw))(q, k, v))
         return
 
-    o, lse = pk._flash_fwd(q, k, v, h, causal, True, num_kv_heads=hk, **kw)
-    kr, vr = _repeated(k, hk, rep), _repeated(v, hk, rep)
-    want_o, want_lse = pk._flash_fwd(q, kr, vr, h, causal, True, **kw)
-    assert np.array_equal(np.asarray(o, np.float32),
-                          np.asarray(want_o, np.float32))
-    assert np.array_equal(np.asarray(lse), np.asarray(want_lse))
-
-    dq, dk, dv = pk._flash_bwd(q, k, v, o, lse, do, h, causal, True,
-                               num_kv_heads=hk, **kw)
-    want_dq, dkr, dvr = pk._flash_bwd(q, kr, vr, o, lse, do, h, causal,
-                                      True, **kw)
-    assert np.array_equal(np.asarray(dq, np.float32),
-                          np.asarray(want_dq, np.float32))
-    for name, got, parts in (("dk", dk, dkr), ("dv", dv, dvr)):
-        assert got.dtype == jnp.float32 and got.shape == (1, seq, hk * d)
-        parts = np.asarray(parts, np.float32).reshape(1, seq, hk, rep, d)
-        want, room = parts.sum(3), np.abs(parts).sum(3)
-        off = np.abs(np.asarray(got).reshape(want.shape) - want)
-        assert np.all(off <= 2.02 * U * room + 1e-6 * np.abs(want).max()), (
-            name, float(np.max(off / (U * room + 1e-30))))
-        # and it is the group's sum, not one head's: the partials differ
-        assert np.abs(want).max() > 0 and not np.allclose(want,
-                                                          parts[..., 0, :])
-
-    # through the public call: float32 keys in (as the op hands them),
-    # float32 group sums out, the same values
-    g = jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
-        q, k, v, h, causal, num_kv_heads=hk, **kw).astype(jnp.float32)
-        * do.astype(jnp.float32)), argnums=(0, 1, 2))(
-            q, k.astype(jnp.float32), v.astype(jnp.float32))
-    assert [a.dtype for a in g] == [jnp.bfloat16, jnp.float32, jnp.float32]
-    for a, b in zip(g, (dq, dk, dv)):
-        assert np.array_equal(np.asarray(a, np.float32),
-                              np.asarray(b, np.float32))
+    _assert_grouped_is_the_repeated_form(q, k, v, do, h, hk, causal, kw)
 
 
 def _attention_op(h, hk, d, e, s, b=1, **props):
@@ -577,9 +586,14 @@ def _attention_op(h, hk, d, e, s, b=1, **props):
 GROUPED_OPS = {
     "heads_of_128": (4, 2, 128, None, True),
     "one_kv_head": (7, 1, 128, None, True),
-    # a column block of 128 lanes holds two heads of 64, which may
-    # belong to two groups
-    "head_dim_64_repeats": (4, 2, 64, None, False),
+    # a column block of 128 lanes holds two heads of 64: of one group
+    # where the group's size is even (PR 47) ...
+    "heads_of_64": (4, 2, 64, None, True),
+    "heads_of_64_32_of_8": (32, 8, 64, None, True),
+    # ... of two where it is odd, and three KV heads of 64 are no whole
+    # lane blocks: the repeat stays
+    "heads_of_64_group_of_3_repeats": (6, 2, 64, None, False),
+    "heads_of_64_odd_kv_heads_repeat": (6, 3, 64, None, False),
     "every_head_its_own_keys": (4, 4, 128, None, False),
     # a head axis of 4 over 8 query heads: 4 KV heads leave a shard one
     # whole group, 2 KV heads would be cut
@@ -587,6 +601,12 @@ GROUPED_OPS = {
                                      True),
     "head_axis_would_split_a_group_repeats": (8, 2, 128,
                                               {"data": 2, "model": 4}, False),
+    # at heads of 64 a shard has to hold whole K / V lane blocks too: 8 KV
+    # heads over 4 shards leave each one block, 4 KV heads half a block
+    "head_axis_keeps_whole_lane_blocks_of_64": (
+        16, 8, 64, {"data": 2, "model": 4}, True),
+    "head_axis_would_split_a_lane_block_of_64_repeats": (
+        16, 4, 64, {"data": 2, "model": 4}, False),
 }
 
 
@@ -627,10 +647,18 @@ def test_the_op_hands_over_grouped_keys_where_the_shapes_allow(
     assert op._route.grouped_kv == grouped and op._route.core == "flash"
     assert op.traced_gauges()["executor.flash_grouped_kv_ops"] == grouped
     assert not steered._route.grouped_kv
-    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-6)
+    # under a mesh, at heads of 64, the CPU's partitioned programs
+    # contract the view form's float32 rotation differently ahead of the
+    # two forms (tests/test_rotary_lanes.py): a last place that a
+    # bfloat16 rounding carries into a key in a few thousand, and from
+    # there into the output
+    last_place = bool(grouped and axes and d < 128)
+    np.testing.assert_allclose(float(got[0]), float(want[0]),
+                               rtol=1e-4 if last_place else 1e-6)
     for (path, a), b_ in zip(jax.tree_util.tree_leaves_with_path(got[1]),
                              jax.tree.leaves(want[1])):
-        if not grouped or "wq" in str(path) or "wo" in str(path):
+        if not grouped or (not last_place and (
+                "wq" in str(path) or "wo" in str(path))):
             # the same program, or a gradient the keys' form cannot reach
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b_),
                                           err_msg=str(path))

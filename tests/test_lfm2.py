@@ -275,7 +275,9 @@ def test_model_against_the_reference_logits_and_three_losses(tiny):
     assert counters["executor.short_conv_ops"] == 4
     assert counters["executor.tied_head_ops"] == 1
     # heads of 8 lanes on the CPU: the einsum core, the 4-D rotary, the
-    # repeat (on the chip at heads of 64: flash, and the same two forms)
+    # repeat (on the chip at heads of 64, two a 128-lane column: flash,
+    # the heads' norm and rotary as the lane-dense pass and K and V at
+    # the KV heads since PR 47: both counters 1)
     assert counters["executor.rotary_lane_dense_ops"] == 0
     assert counters["executor.flash_grouped_kv_ops"] == 0
 

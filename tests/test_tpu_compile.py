@@ -246,20 +246,27 @@ class TestFlashKernels:
         (7, 1, 16384, 4096, None), (7, 1, 16384, 0, None),
         (4, 1, 8192, 0, None), (64, 8, 8192, 512, None),
         (48, 8, 8192, 0, None), (8, 1, 16384, 0, (8192, 4)),
-        (8, 2, 1024, 0, None)])
+        (8, 2, 1024, 0, None),
+        # heads of 64 (PR 47): lfm2's op, the whole-tile kernels at their
+        # longest, and the one-span kernels
+        ((32, 64), 8, 16384, 0, None), ((32, 64), 8, 1024, 0, None),
+        ((32, 64), 8, 8192, 512, None)])
     def test_grouped_keys_compile_at_the_cells_shapes(
             self, topo, heads, kv_heads, seq, window, block_diffusion):
-        """K and V at the KV heads (PR 43) at the five grouped-query
+        """K and V at the KV heads (PR 43) at the six grouped-query
         shapes of the decoder cells, and the whole-tile kernels at their
         longest: the backward's dK and dV are the KV head's whole float32
         [S, 128] panels, resident across a group's heads (8 MB each at
         16,384 positions, twice with the pipeline's second buffer), beside
         the q, o, dO and dQ panels, inside the 96 MiB budget. No operand
-        or result but q, o, dO and dQ is H * 128 wide."""
+        or result but q, o, dO and dQ is H * D wide. At heads of 64
+        (``heads`` = (H, 64); PR 47) a panel is a K / V lane block of two
+        KV heads, resident across the four column blocks it serves."""
+        heads, d = heads if isinstance(heads, tuple) else (heads, 128)
         one = SingleDeviceSharding(topo.devices[0])
-        q = jax.ShapeDtypeStruct((1, seq, heads * 128), jnp.bfloat16,
+        q = jax.ShapeDtypeStruct((1, seq, heads * d), jnp.bfloat16,
                                  sharding=one)
-        k = jax.ShapeDtypeStruct((1, seq, kv_heads * 128), jnp.float32,
+        k = jax.ShapeDtypeStruct((1, seq, kv_heads * d), jnp.float32,
                                  sharding=one)
 
         def grads(q, k, v):
@@ -593,13 +600,16 @@ class TestRotaryLanes:
     repeat's backward (dK and dV of the repeated heads as float32
     through a 4-D view: a convert and a copy each, 4 / 2 / 4 such passes
     an op until then) is gone too, because the flash kernels read K and
-    V at the KV heads and hand back the groups' float32 sums."""
+    V at the KV heads and hand back the groups' float32 sums. Since PR
+    47 the same holds of lfm2's op at heads of 64, two a 128-lane
+    column: 32 : 8 heads with the heads' norm at 16,384 positions."""
     YARN = dict(rope_type="yarn", factor=64, beta_fast=64, beta_slow=1,
                 original_max_position_embeddings=4096,
                 attention_factor=1.4158883083359672)
-    # seq, width, the op's properties; XLA's passes over an S x H x 128
-    # float32 array with the pass (none) and on the view (rotary's own:
-    # with grouped keys the repeat's 4 / 2 / 4 are gone from it as well)
+    # seq, width, the op's properties (heads of 128 unless they say);
+    # XLA's passes over an S x H x D float32 array with the pass (none)
+    # and on the view (rotary's own: with grouped keys the repeat's
+    # 4 / 2 / 4 are gone from it as well)
     OPS = {
         "laguna_window_64_8": (8192, 2048, dict(
             num_heads=64, num_kv_heads=8, causal=True, window=512,
@@ -611,6 +621,9 @@ class TestRotaryLanes:
         "sdar_8_1_normed": (16384, 2048, dict(
             num_heads=8, num_kv_heads=1, block_diffusion=(8192, 4),
             rope_wrap=8192, qk_norm=True, rope_theta=1000000.0), 0, 13),
+        "lfm2_full_32_8_normed_heads_of_64": (16384, 2048, dict(
+            num_heads=32, num_kv_heads=8, head_dim=64, causal=True,
+            qk_norm=True, rope_theta=1000000.0), 0, 13),
     }
 
     def _hlo(self, topo, seq, hidden, props, lanes):
@@ -619,8 +632,8 @@ class TestRotaryLanes:
         from flexflow_tpu.ops.base import OpContext, OpRegistry
 
         layer = Layer(OperatorType.MULTIHEAD_ATTENTION, "op", [])
-        layer.properties.update(dict(props, embed_dim=hidden, head_dim=128,
-                                     bias=False, rope=True))
+        layer.properties.update(dict(dict(head_dim=128), **props,
+                                     embed_dim=hidden, bias=False, rope=True))
         op = OpRegistry.create(layer, [(1, seq, hidden)] * 3)
         if not lanes:
             route = op.route
@@ -671,9 +684,9 @@ class TestRotaryLanes:
         return out
 
     @staticmethod
-    def assert_keys_stay_at_the_kv_heads(hlo, seq, heads, kv_heads):
+    def assert_keys_stay_at_the_kv_heads(hlo, seq, heads, kv_heads, d=128):
         """The K/V repeat is in the program in neither direction (PR
-        43): no bf16 array of S x H x 128 elements is written by a
+        43): no bf16 array of S x H x D elements is written by a
         `broadcast`, `reshape` or `copy` (the repeated K or V); the
         flash forward reads ONE operand that wide, q, and K and V at the
         KV heads; the flash backward hands out one float32 result that
@@ -682,8 +695,8 @@ class TestRotaryLanes:
         projections' transposed products."""
         from flexflow_tpu.obs.inspect import (_INSTRUCTION,
                                               arrays_between_fusions)
-        wide, narrow = [1, seq, heads * 128], [1, seq, kv_heads * 128]
-        names = set(arrays_between_fusions(hlo, "bf16", seq * heads * 128))
+        wide, narrow = [1, seq, heads * d], [1, seq, kv_heads * d]
+        names = set(arrays_between_fusions(hlo, "bf16", seq * heads * d))
         calls = {}
         for line in hlo.splitlines():
             m = _INSTRUCTION.match(line)
@@ -715,13 +728,14 @@ class TestRotaryLanes:
             self, topo, on_tpu, kind):
         seq, hidden, props, with_the_pass, on_the_view = self.OPS[kind]
         heads, kv_heads = props["num_heads"], props["num_kv_heads"]
+        d = props.get("head_dim", 128)
         hlo = self._hlo(topo, seq, hidden, props, True)
-        left = self.passes_over(hlo, seq * heads * 128)
+        left = self.passes_over(hlo, seq * heads * d)
         # nothing: the repeat's backward is gone too
         assert len(left) == with_the_pass, left
-        self.assert_keys_stay_at_the_kv_heads(hlo, seq, heads, kv_heads)
+        self.assert_keys_stay_at_the_kv_heads(hlo, seq, heads, kv_heads, d)
         view = self.passes_over(self._hlo(topo, seq, hidden, props, False),
-                                seq * heads * 128)
+                                seq * heads * d)
         # what this PR took out of the op
         assert len(view) == on_the_view, view
         assert sum("jit(rotary_" in scope for _, _, _, scope in view) >= 5

@@ -453,9 +453,10 @@ def test_grouped_kv_flash_ops_are_the_ops_whose_keys_stay_at_the_kv_heads(
     """`executor.flash_grouped_kv_ops` (PR 43): attention ops whose
     forward handed the flash kernels K and V as [B, S, Hk*D] with fewer
     KV heads than query heads. A grouped-query op with heads of 128
-    takes it; one with heads of 64 repeats its keys (a column block
-    holds two heads); an op with as many KV heads as query heads has no
-    group: 0 for a model of those. In the registry's snapshot, the trace
+    takes it; one with heads of 64 in groups of 3 repeats its keys (a
+    column block holds two heads, here of two KV heads; an even group
+    takes it too since PR 47); an op with as many KV heads as query
+    heads has no group: 0 for a model of those. In the registry's snapshot, the trace
     header and `FFModel.op_counters`, beside `flash_lane_dense_ops`."""
     import numpy as np
     from flexflow_tpu import (FFConfig, FFModel, LossType, MetricsType,
@@ -470,7 +471,7 @@ def test_grouped_kv_flash_ops_are_the_ops_whose_keys_stay_at_the_kv_heads(
                                    num_kv_heads=2, causal=True,
                                    name=f"grouped{i}")
     for i in range(narrow_layers):
-        t = ff.multihead_attention(t, t, t, e, 4, head_dim=64,
+        t = ff.multihead_attention(t, t, t, e, 6, head_dim=64,
                                    num_kv_heads=2, causal=True,
                                    name=f"narrow{i}")
     for i in range(mha_layers):
