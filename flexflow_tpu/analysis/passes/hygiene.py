@@ -16,7 +16,12 @@ memory or the gradient-sync collectives fflint's other passes price.
           substitution rewrites — the executor would crash deep inside
           jit with an inscrutable broadcast error);
 * FFL604  duplicate op names: parameters are keyed by name, so two ops
-          sharing one silently share (and doubly-update) parameters.
+          sharing one silently share (and doubly-update) parameters;
+* FFL605  shared leaves: an op that reads its leaves out of another op
+          (`FFModel._add_layer(shared_op=)`, a tied head) whose owner is
+          not in the graph, holds no leaves of its own, or differs in
+          kind or in a property that shapes a leaf (what `compile`
+          raises on; reachable afterwards through a rewrite or an edit).
 """
 
 from __future__ import annotations
@@ -24,6 +29,9 @@ from __future__ import annotations
 from typing import Dict, List, Set
 
 from flexflow_tpu.analysis.diagnostics import Diagnostic, error, warning
+from flexflow_tpu.ops.base import shared_leaves_error
+
+SHARED_LEAVES = "FFL605"
 
 
 class GraphHygienePass:
@@ -36,6 +44,20 @@ class GraphHygienePass:
         diags.extend(self._unused_inputs(ctx, live))
         diags.extend(self._shape_contradictions(ctx))
         diags.extend(self._duplicate_names(ctx))
+        diags.extend(self._shared_leaves(ctx))
+        return diags
+
+    def _shared_leaves(self, ctx) -> List[Diagnostic]:
+        ops = {n.op.name: n.op for n in ctx.nodes}
+        diags = []
+        for op in ops.values():
+            why = shared_leaves_error(op, ops)
+            if why:
+                diags.append(error(
+                    SHARED_LEAVES, why, op=op.name, guid=op.guid,
+                    hint="a reader takes ALL its leaves from a layer of "
+                         "its own kind and leaf shapes that is in the "
+                         "graph and holds them itself"))
         return diags
 
     def _live_set(self, ctx) -> Set[int]:

@@ -22,9 +22,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flexflow_tpu.ffconst import CompMode, LossType, OperatorType
-from flexflow_tpu.losses import (class_ids, get_loss_fn, part_nll_sums,
-                                 target_log_probs, target_positions,
-                                 weighted_nll_mean)
+from flexflow_tpu.losses import (class_ids, expected_exit_loss, get_loss_fn,
+                                 part_nll_sums, target_log_probs,
+                                 target_positions, weighted_nll_mean)
 from flexflow_tpu.metrics import Metrics
 from flexflow_tpu.obs.registry import get_registry
 from flexflow_tpu.ops.base import Op, OpContext, scoped
@@ -40,6 +40,9 @@ COUNT_SUFFIX = "#n"
 # what an op's forward leaves on the op for `_run_nodes` to pick up; traced
 # values, so a forward run as a nested call hands them out as results
 SIDE_CHANNELS = ("_aux_loss", "_counters", "_new_state", "_new_states")
+# the op kinds of which a decoder layer holds one
+SEQUENCE_MIXERS = (OperatorType.MULTIHEAD_ATTENTION, OperatorType.SSM_MIXER,
+                   OperatorType.SHORT_CONV)
 
 
 def settled_spec(spec: P) -> P:
@@ -114,8 +117,13 @@ class GraphExecutor:
         self.data_axes = data_axes
         self.final_is_softmax = final_is_softmax
         # names of the equal parts along the sequence of a weighted
-        # loss's logits (FFModel.compile sets it from the model)
+        # loss's logits, or of a looped model's passes (FFModel.compile
+        # sets it from the model), and the weight of the entropy bonus
+        # of a looped model's exit distribution; `exit_uniform` puts
+        # 1 / T in the distribution's place (a control)
         self.loss_parts = None
+        self.exit_entropy_beta = 0.0
+        self.exit_uniform = False
         # mixed-precision master-weight regime (bf16 compute): forward and
         # backward run on a bf16 copy of the parameters that is produced
         # INSIDE the previous step's optimizer fusion (state key
@@ -395,16 +403,25 @@ class GraphExecutor:
         params: Dict[str, Dict[str, jax.Array]] = {}
         state: Dict[str, Dict[str, jax.Array]] = {}
 
-        def _init(rng):
+        # a key a node, split off one after the other OUTSIDE the
+        # program that draws the leaves: inside it the chain of splits
+        # is compiled link by link (half a second a node on a v5e; a
+        # looped model's 321 nodes took 176 s), and the keys are the
+        # same either way
+        keys = []
+        for _ in self.nodes:
+            rng, sub = jax.random.split(rng)
+            keys.append(sub)
+
+        def _init(keys):
             p = {}
-            for node in self.nodes:
-                rng, sub = jax.random.split(rng)
+            for node, sub in zip(self.nodes, keys):
                 ps = node.op.init_params(sub)
                 if ps:
                     p[node.op.name] = ps
             return p
 
-        params = jax.jit(_init)(rng)
+        params = jax.jit(_init)(keys)
         params = jax.device_put(params, self.param_shardings(params,
                                                             master=True))
         for node in self.nodes:
@@ -738,6 +755,23 @@ class GraphExecutor:
                         (f"loss/{part}_nll", v) for part, v in part_nll_sums(
                             logp, labels, self.loss_parts).items())
             return weighted_nll_mean(logp, labels)
+        if self.loss_type == \
+                LossType.EXPECTED_EXIT_SPARSE_CATEGORICAL_CROSSENTROPY:
+            # the T passes' logits lie end to end: their targets'
+            # log-probabilities ONCE, then the mixture under the exit
+            # distribution and its entropy, over [B, T, S] in float32
+            self._loss_own_vjp = True
+            loss, sums = expected_exit_loss(
+                logits, labels, len(self.loss_parts),
+                self.exit_entropy_beta, uniform=self.exit_uniform)
+            if counted is not None:
+                for key, value in sums.items():
+                    if value.ndim == 0:
+                        counted[key] = value
+                        continue
+                    counted.update((f"{key}_{part}", value[i]) for i, part
+                                   in enumerate(self.loss_parts))
+            return loss
         self._loss_own_vjp = (
             self.loss_type == LossType.SPARSE_CATEGORICAL_CROSSENTROPY)
         return fn(logits, labels)
@@ -761,6 +795,18 @@ class GraphExecutor:
                 out[key] = out.get(key, 0) + value
         out["executor.loss_own_vjp"] = int(
             getattr(self, "_loss_own_vjp", False))
+        # leaves with more than one reader: the ops that read another
+        # op's (a tied head, every further application of a looped
+        # layer: `op_params` hands them the owner's array, no copy),
+        # the distinct leaves so read, and the sequence mixers the step
+        # runs, one a layer APPLICATION
+        read = [tuple(getattr(n.op, "tied_params", {}).values())
+                for n in self.nodes]
+        out["executor.shared_weight_ops"] = sum(map(bool, read))
+        out["executor.shared_leaves"] = len(
+            {leaf for leaves in read for leaf in leaves})
+        out["executor.layer_applications"] = sum(
+            n.op.op_type in SEQUENCE_MIXERS for n in self.nodes)
         heads = {n.op.name: n.op.num_heads for n in self.nodes
                  if hasattr(n.op, "num_kv_heads")}
         if len(set(heads.values())) > 1:

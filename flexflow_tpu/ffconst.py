@@ -62,6 +62,11 @@ class LossType(enum.Enum):
     # token-level cross-entropy whose labels carry a weight a position:
     # labels [B, S, 2] float32, (token id, weight)
     WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY = 15
+    # a looped model's objective: the expectation of the T passes'
+    # cross-entropies under the exit distribution the model's gate emits,
+    # less an entropy bonus; output [B, T*S, V + 1] (the passes' logits
+    # laid end to end, the gate's logit the last column), labels [B, S]
+    EXPECTED_EXIT_SPARSE_CATEGORICAL_CROSSENTROPY = 16
 
 
 class MetricsType(enum.Enum):

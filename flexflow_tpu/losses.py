@@ -162,6 +162,55 @@ def part_nll_sums(logp, labels, parts):
     return {name: sums[i] for i, name in enumerate(parts)}
 
 
+def exit_log_probs(gate_logits):
+    """log p_t [B, T, S] of the exit distribution a looped model's gate
+    emits, from the gate's logits [B, T, S] (float32): with lambda_t =
+    sigmoid(g_t), p_t = lambda_t prod_{j<t} (1 - lambda_j) for t < T and
+    p_T = prod_{j<T} (1 - lambda_j): the last pass takes what is left,
+    its own gate is not read (and gets no gradient). In logs, so that
+    the entropy is finite where a pass's mass underflows."""
+    g = gate_logits.astype(jnp.float32)
+    stay = jax.nn.log_sigmoid(-g)               # log(1 - lambda_t)
+    before = jnp.cumsum(stay, axis=1) - stay    # log prod_{j<t}
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(g[:, :-1]) + before[:, :-1], before[:, -1:]],
+        axis=1)
+
+
+def expected_exit_loss(output, labels, passes, beta=0.0, uniform=False):
+    """(loss, counted) of a looped model from its output [B, T*S, V + 1]
+    (the T passes' logits laid end to end, pass-major, the gate's logit
+    the last column) and labels [B, S], which every pass predicts: the
+    targets' log-probabilities through `target_log_probs` ONCE over all
+    T * S rows, then, in float32 over [B, T, S], the mean over the B * S
+    positions of sum_t p_t * ce_t - beta * H(p), H = -sum_t p_t log p_t.
+    ``uniform`` puts p_t = 1 / T in the gate's place (a control).
+    ``counted``: sums over the positions for the epoch's op counters:
+    `loss/exit_nll` and `loss/exit_mass` [T] (the unweighted
+    cross-entropies and the masses p_t, a pass), `loss/exit_entropy`,
+    `loss/target_positions`."""
+    b, rows, width = output.shape
+    s = rows // passes
+    ids = jnp.tile(class_ids(output[:, :s], labels), (1, passes))
+    nll = -target_log_probs(output[..., :width - 1], ids).reshape(
+        b, passes, s)
+    log_p = exit_log_probs(output[..., width - 1].reshape(b, passes, s))
+    if uniform:
+        log_p = jnp.full_like(log_p, -jnp.log(jnp.float32(passes)))
+    p = jnp.exp(log_p)
+    entropy = -jnp.sum(p * log_p, axis=1)
+    loss = jnp.mean(jnp.sum(p * nll, axis=1) - beta * entropy)
+    return loss, {"loss/exit_nll": jnp.sum(nll, axis=(0, 2)),
+                  "loss/exit_mass": jnp.sum(p, axis=(0, 2)),
+                  "loss/exit_entropy": jnp.sum(entropy),
+                  "loss/target_positions": jnp.float32(b * s)}
+
+
+def expected_exit_sparse_categorical_crossentropy(output, labels, passes=1,
+                                                  beta=0.0):
+    return expected_exit_loss(output, labels, passes, beta)[0]
+
+
 def mse_avg(preds, labels):
     return jnp.mean((preds.astype(jnp.float32) - labels.astype(jnp.float32)) ** 2)
 
@@ -183,6 +232,8 @@ LOSS_FNS = {
     LossType.SPARSE_CATEGORICAL_CROSSENTROPY: sparse_categorical_crossentropy,
     LossType.WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY:
         weighted_sparse_categorical_crossentropy,
+    LossType.EXPECTED_EXIT_SPARSE_CATEGORICAL_CROSSENTROPY:
+        expected_exit_sparse_categorical_crossentropy,
     LossType.MEAN_SQUARED_ERROR_AVG_REDUCE: mse_avg,
     LossType.MEAN_SQUARED_ERROR_SUM_REDUCE: mse_sum,
     LossType.IDENTITY: identity,

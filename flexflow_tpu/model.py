@@ -41,6 +41,7 @@ from flexflow_tpu.layer import Layer
 from flexflow_tpu.machine import MachineSpec, detect_machine_spec, make_mesh
 from flexflow_tpu.metrics import Metrics, PerfMetrics
 from flexflow_tpu.ops import OpRegistry
+from flexflow_tpu.ops.base import shared_leaves_error
 from flexflow_tpu.optimizers import Optimizer, SGDOptimizer
 from flexflow_tpu.tensor import Tensor
 
@@ -59,6 +60,7 @@ class FFModel:
     def __init__(self, config: Optional[FFConfig] = None):
         self.config = config or FFConfig()
         self.layers: List[Layer] = []
+        self._layer_named: Dict[str, Layer] = {}
         self.input_tensors: List[Tensor] = []
         self.label_tensor: Optional[Tensor] = None
         self.optimizer: Optional[Optimizer] = None
@@ -98,7 +100,21 @@ class FFModel:
 
     def _add_layer(self, op_type: OperatorType, inputs: List[Tensor],
                    props: Dict[str, Any], name: Optional[str] = None,
-                   dtype: Optional[DataType] = None) -> Layer:
+                   dtype: Optional[DataType] = None,
+                   shared_op: Optional[Tensor] = None) -> Layer:
+        """``shared_op`` (upstream's argument of that name): the output
+        of the layer whose leaves this one reads in place of its own,
+        ALL of them. The model then holds each leaf once, under the
+        owner's name, with one optimizer state; its gradient is the sum
+        over every reader (``Op.tied_params``). ``compile`` raises where
+        the owner is not a layer of the reader's kind with leaves of the
+        reader's shapes."""
+        owner = shared_op.owner_layer if shared_op is not None else None
+        again = getattr(self, "_again", None)
+        if owner is None and again and name:
+            # a further application of the layer called `name`
+            owner = self._layer_named.get(name) if again[1] else None
+            name = again[0] + name
         layer = Layer(op_type, name, inputs,
                       data_type=dtype or (inputs[0].dtype if inputs else DataType.FLOAT))
         # parameters are keyed by layer name — names must be unique
@@ -111,22 +127,45 @@ class FFModel:
         layer.properties.update(props)
         if getattr(self, "_scope", None):
             layer.properties["scope"] = self._scope
+        if owner is not None:
+            layer.properties["shared_op"] = owner.name
         self.layers.append(layer)
+        self._layer_named[layer.name] = layer
         return layer
 
     @contextlib.contextmanager
     def scope(self, name: str):
         """The layers added inside run under the nested call ``name`` in
         the device trace, around their own scopes (``ops.base.scoped``;
-        `obs/step_scopes.py` makes ``mtp`` a part of the step)."""
+        `obs/step_scopes.py` makes ``mtp``, ``ut<t>`` and ``exit`` parts
+        of the step)."""
         prev, self._scope = getattr(self, "_scope", None), name
         try:
             yield
         finally:
             self._scope = prev
 
+    @contextlib.contextmanager
+    def applied_again(self, prefix: str, share_leaves: bool = True):
+        """The layers added inside are further applications of layers
+        the model has: one added as ``name`` is called ``prefix + name``
+        and reads ALL its leaves out of the layer ``name``
+        (``_add_layer(shared_op=)``), so that a builder runs the same
+        code for every pass of a looped stack. With ``share_leaves``
+        False only the names change (every pass leaves of its own: a
+        control)."""
+        prev, self._again = getattr(self, "_again", None), (prefix,
+                                                            share_leaves)
+        try:
+            yield
+        finally:
+            self._again = prev
+
     def _finish(self, layer: Layer) -> Tensor:
         op = OpRegistry.create(layer, [t.shape for t in layer.inputs])
+        if "shared_op" in layer.properties and not op.tied_params:
+            # an op without leaves (an add, a split) shares nothing
+            del layer.properties["shared_op"]
         outs = [
             Tensor(s, layer.data_type, owner_layer=layer, owner_idx=i,
                    name=f"{layer.name}_out{i}")
@@ -140,40 +179,48 @@ class FFModel:
               activation: ActiMode = ActiMode.AC_MODE_NONE, use_bias: bool = True,
               datatype: Optional[DataType] = None, kernel_initializer=None,
               bias_initializer=None, tied_to: Optional[Tensor] = None,
+              shared_op: Optional[Tensor] = None,
+              full_precision: bool = False,
               name: Optional[str] = None) -> Tensor:
-        """``tied_to``: the output of an ``embedding`` whose table
-        [out_dim, in_dim] this product reads, y = x E^T, in place of a
-        kernel of its own (a tied head): the model then holds ONE leaf,
-        the embedding's, with one optimizer state, and its gradient is
-        the sum over both uses (ops/linear.py)."""
-        tie = {}
+        """``shared_op``: the output of another ``dense`` whose kernel
+        and bias this one reads (``_add_layer``). ``tied_to``: the
+        one-leaf, transposed case of it: the output of an ``embedding``
+        whose table [out_dim, in_dim] this product reads, y = x E^T, in
+        place of a kernel of its own (a tied head): the model then holds
+        ONE leaf, the embedding's, with one optimizer state, and its
+        gradient is the sum over both uses (ops/linear.py).
+        ``full_precision``: the product in float32, whatever the
+        compute dtype (a gate of one column)."""
+        extra = {}
         if tied_to is not None:
             source = tied_to.owner_layer
             if (source is None or source.op_type != OperatorType.EMBEDDING
-                    or use_bias):
+                    or use_bias or shared_op is not None):
                 raise ValueError(
                     f"dense '{name}': tied_to takes the output of an "
                     f"embedding layer, and no bias")
-            tie = dict(tied_to=(source.name, "kernel"),
-                       tied_shape=(source.properties["num_entries"],
-                                   source.properties["out_dim"]))
+            extra["tied_to"] = True
+        if full_precision:
+            extra["full_precision"] = True
         layer = self._add_layer(OperatorType.LINEAR, [input], dict(
             out_dim=out_dim, activation=activation, use_bias=use_bias,
             kernel_initializer=kernel_initializer, bias_initializer=bias_initializer,
-            **tie), name, datatype)
+            **extra), name, datatype,
+            shared_op=tied_to if tied_to is not None else shared_op)
         return self._finish(layer)
 
     def conv2d(self, input: Tensor, out_channels: int, kernel_h: int, kernel_w: int,
                stride_h: int, stride_w: int, padding_h: int, padding_w: int,
                activation: ActiMode = ActiMode.AC_MODE_NONE, groups: int = 1,
                use_bias: bool = True, kernel_initializer=None,
-               bias_initializer=None, name: Optional[str] = None) -> Tensor:
+               bias_initializer=None, shared_op: Optional[Tensor] = None,
+               name: Optional[str] = None) -> Tensor:
         layer = self._add_layer(OperatorType.CONV2D, [input], dict(
             out_channels=out_channels, kernel_h=kernel_h, kernel_w=kernel_w,
             stride_h=stride_h, stride_w=stride_w, padding_h=padding_h,
             padding_w=padding_w, activation=activation, groups=groups,
             use_bias=use_bias, kernel_initializer=kernel_initializer,
-            bias_initializer=bias_initializer), name)
+            bias_initializer=bias_initializer), name, shared_op=shared_op)
         return self._finish(layer)
 
     def pool2d(self, input: Tensor, kernel_h: int, kernel_w: int, stride_h: int,
@@ -218,10 +265,13 @@ class FFModel:
 
     def embedding(self, input: Tensor, num_entries: int, out_dim: int,
                   aggr: AggrMode = AggrMode.AGGR_MODE_NONE,
-                  kernel_initializer=None, name: Optional[str] = None) -> Tensor:
+                  kernel_initializer=None,
+                  shared_op: Optional[Tensor] = None,
+                  name: Optional[str] = None) -> Tensor:
         layer = self._add_layer(OperatorType.EMBEDDING, [input], dict(
             num_entries=num_entries, out_dim=out_dim, aggr=aggr,
-            kernel_initializer=kernel_initializer), name, DataType.FLOAT)
+            kernel_initializer=kernel_initializer), name, DataType.FLOAT,
+            shared_op=shared_op)
         return self._finish(layer)
 
     def multihead_attention(self, query: Tensor, key: Tensor, value: Tensor,
@@ -632,6 +682,11 @@ class FFModel:
             for i, t in enumerate(layer.outputs):
                 tensor_ref[t.guid] = ("op", op.guid, i)
                 shape_of[t.guid] = op.output_shapes[i]
+        ops = {n.op.name: n.op for n in nodes}
+        for op in ops.values():
+            error = shared_leaves_error(op, ops)
+            if error:
+                raise ValueError(f"layer '{op.name}': {error}")
         return nodes, input_names, tensor_ref
 
     def _select_final_ref(self, nodes, tensor_ref):
@@ -971,6 +1026,8 @@ class FFModel:
         # names of the equal parts along the sequence that the logits of
         # a weighted loss consist of (a builder sets `loss_parts`)
         self.executor.loss_parts = getattr(self, "loss_parts", None)
+        self.executor.exit_entropy_beta = getattr(
+            self, "exit_entropy_beta", 0.0)
         t_built = time.perf_counter()
         # --- fflint static verification (flexflow_tpu/analysis) ----------
         # runs BEFORE parameter allocation so an illegal strategy fails

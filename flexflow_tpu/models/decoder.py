@@ -67,6 +67,14 @@ family.
         ``conv_L_cache`` taps over B * x, the gate C, then W_out; no
         bias, no activation. Feed-forward as `F`'s
 
+    U   the block of a looped (universal-transformer) model (PR 48):
+        `L`'s mixers, causal rotary GQA attention and then the SwiGLU
+        MLP (gate and up as one product), with SANDWICH norms: a norm
+        on each branch's input and another on its OUTPUT, x' = x +
+        rms_norm(attention(rms_norm(x))), x'' = x' +
+        rms_norm(mlp(rms_norm(x'))) (``sandwich_norm`` False leaves the
+        output norms out: a control)
+
 ``layer_types`` (a public config's list of "full_attention" /
 "sliding_attention" / "conv", one entry a layer that runs) stands for
 the pattern: `F`, `S` and `C` in its order. ``num_dense_layers`` is the
@@ -96,6 +104,24 @@ then names the halves, and an epoch's ``ff.op_counters`` hold
 ``loss/main_nll`` and ``loss/mtp_nll``, the sums of the two unweighted
 cross-entropies over their targets.
 
+``total_ut_steps`` T > 1 applies the whole stack T times with ONE set
+of leaves (a looped model): pass 1 builds the layers under the trace
+scope ``ut0``; pass t + 1 builds them again under ``ut<t>`` as
+``ut<t>_<name>``, every one reading ALL its leaves out of pass 1's
+(``FFModel.applied_again``: `jax.grad` sums the T uses, the optimizer
+holds one state). The final norm closes EVERY pass and its output is
+the next pass's input. The T normed sequences are laid end to end,
+pass-major, and under the scope ``exit`` ONE head gives logits [B, T*S,
+V] and ONE gate of one column (``exit_gate``, with a bias, the product
+in float32) the logit of leaving after that pass; the model's output is
+[B, T*S, V + 1], the gate's logit last. Train with
+``EXPECTED_EXIT_SPARSE_CATEGORICAL_CROSSENTROPY`` on labels [B, S]:
+the expectation of the T cross-entropies under the exit distribution
+less ``exit_entropy_beta`` times its entropy (losses.py);
+``ff.loss_parts`` names the passes and an epoch's ``ff.op_counters``
+hold ``loss/exit_nll_ut<t>``, ``loss/exit_mass_ut<t>`` and
+``loss/exit_entropy``.
+
 After the last block ``rms_norm`` and the head, ``logits = x W_head``
 (untied), or with ``tie_word_embeddings`` ``logits = x E^T`` with E the
 table ``embed_tokens`` gathers from: ONE leaf, read by both ops
@@ -115,6 +141,7 @@ widths (``hidden_size``, the head sizes, the expert widths, the router's
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional, Sequence
 
@@ -193,6 +220,16 @@ class DecoderConfig:
     conv_output_gate: bool = True
     # the head reads the embedding's table (one leaf) in place of its own
     tie_word_embeddings: bool = False
+    # a looped model: the stack applied this many times with one set of
+    # leaves, an exit gate beside the head, the entropy bonus's weight.
+    # Controls: `sandwich_norm` False (`U` without its output norms),
+    # `share_ut_leaves` False (every pass leaves of its own),
+    # `norm_between_passes` False (the final norm feeds the head alone)
+    total_ut_steps: int = 1
+    exit_entropy_beta: float = 0.1
+    sandwich_norm: bool = True
+    share_ut_leaves: bool = True
+    norm_between_passes: bool = True
     # the multi-token-prediction module: 0 or 1; `mtp_shift` is the
     # distance of the token whose embedding it reads (1; 0 is a control)
     num_nextn_predict_layers: int = 0
@@ -289,18 +326,24 @@ def _llama_block(ff, t, i, cfg):
 
 
 def _attention_ffn_block(ff, t, prefix, cfg, mixer, experts,
-                         shared_width):
+                         shared_width, sandwich=False):
     """x' = x + mixer(norm(x)), x'' = x' + f(norm(x')) with f the
     SwiGLU MLP or the sigmoid-scored experts with their gated shared
-    expert (none at ``shared_width`` 0): the block of `A` / `X` and of
-    `F` / `S` / `C`, which differ in ``mixer(h)``."""
+    expert (none at ``shared_width`` 0): the block of `A` / `X`, of
+    `F` / `S` / `C` and of `U`, which differ in ``mixer(h)``; with
+    ``sandwich`` each branch's output is normed too."""
     eps = cfg.layer_norm_epsilon
     h = ff.rms_norm(t, eps=eps, name=f"{prefix}_norm")
-    t = ff.add(t, mixer(h), name=f"{prefix}_res1")
+    a = mixer(h)
+    if sandwich:
+        a = ff.rms_norm(a, eps=eps, name=f"{prefix}_attn_out_norm")
+    t = ff.add(t, a, name=f"{prefix}_res1")
     g = ff.rms_norm(t, eps=eps, name=f"{prefix}_post_norm")
     if not experts:
-        return ff.add(t, _swiglu_mlp(ff, g, cfg, prefix, one_product=True),
-                      name=f"{prefix}_res2")
+        m = _swiglu_mlp(ff, g, cfg, prefix, one_product=True)
+        if sandwich:
+            m = ff.rms_norm(m, eps=eps, name=f"{prefix}_mlp_out_norm")
+        return ff.add(t, m, name=f"{prefix}_res2")
     m = ff.moe_layer(
         g, cfg.n_routed_experts, cfg.num_experts_per_tok,
         cfg.moe_intermediate_size, shared_width=shared_width,
@@ -434,23 +477,18 @@ def _mixer(ff, h, letter, i, cfg):
         return ff.dense(ff.multiply(up, up, name=f"b{i}_sq"),
                         cfg.hidden_size, use_bias=False, name=name)
     raise ValueError(f"decoder pattern: unknown block letter {letter!r} "
-                     f"(known: M E * - L G W D A X F S C)")
+                     f"(known: M E * - L G W D A X F S C U)")
 
 
-def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
-    ff = FFModel(ff_config or FFConfig(batch_size=cfg.batch_size))
-    ids = ff.create_tensor((cfg.batch_size, cfg.seq_length),
-                           dtype=DataType.INT32, name="input_ids")
-    t = embedded = ff.embedding(ids, cfg.vocab_size, cfg.hidden_size,
-                                name="embed_tokens")
-    pattern = cfg.hybrid_override_pattern
-    if cfg.layer_types is not None:
-        unknown = set(cfg.layer_types) - set(LAYER_TYPE_LETTERS)
-        if unknown:
-            raise ValueError(f"decoder: layer_types holds {sorted(unknown)} "
-                             f"(known: {sorted(LAYER_TYPE_LETTERS)})")
-        pattern = "".join(LAYER_TYPE_LETTERS[k] for k in cfg.layer_types)
+def _stack(ff, t, pattern, cfg):
+    """One application of the blocks ``pattern`` names."""
     for i, letter in enumerate(pattern):
+        if letter == "U":
+            t = _attention_ffn_block(
+                ff, t, f"b{i}", cfg,
+                lambda h: _attention(ff, h, cfg, f"b{i}_attn", rope=True),
+                False, 0, sandwich=cfg.sandwich_norm)
+            continue
         if letter in "FSC":
             t = _gated_block(ff, t, i, cfg, letter)
             continue
@@ -468,6 +506,56 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
             continue
         h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"b{i}_norm")
         t = ff.add(t, _mixer(ff, h, letter, i, cfg), name=f"b{i}_res")
+    return t
+
+
+def _looped(ff, t, pattern, cfg):
+    """A looped model from the embedding on: the stack and the final
+    norm ``total_ut_steps`` times, pass 1 the owner of every leaf and
+    the passes after it their readers, then the head and the exit gate
+    over all passes' normed sequences laid end to end."""
+    eps, passes = cfg.layer_norm_epsilon, []
+    for ut in range(cfg.total_ut_steps):
+        again = (ff.applied_again(f"ut{ut}_", cfg.share_ut_leaves) if ut
+                 else contextlib.nullcontext())
+        with ff.scope(f"ut{ut}"), again:
+            t = _stack(ff, t, pattern, cfg)
+            normed = ff.rms_norm(t, eps=eps, name="final_ln")
+        passes.append(normed)
+        if cfg.norm_between_passes:
+            t = normed
+    with ff.scope("exit"):
+        t = ff.concat(passes, axis=1, name="ut_passes")
+        logits = ff.dense(t, cfg.vocab_size, use_bias=False, name="lm_head")
+        gate = ff.dense(t, 1, full_precision=True, name="exit_gate")
+        ff.concat([logits, gate], axis=2, name="logits_and_exit_gate")
+    ff.loss_parts = tuple(f"ut{ut}" for ut in range(cfg.total_ut_steps))
+    ff.exit_entropy_beta = cfg.exit_entropy_beta
+    return ff
+
+
+def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
+    ff = FFModel(ff_config or FFConfig(batch_size=cfg.batch_size))
+    ids = ff.create_tensor((cfg.batch_size, cfg.seq_length),
+                           dtype=DataType.INT32, name="input_ids")
+    t = embedded = ff.embedding(ids, cfg.vocab_size, cfg.hidden_size,
+                                name="embed_tokens")
+    pattern = cfg.hybrid_override_pattern
+    if cfg.layer_types is not None:
+        unknown = set(cfg.layer_types) - set(LAYER_TYPE_LETTERS)
+        if unknown:
+            raise ValueError(f"decoder: layer_types holds {sorted(unknown)} "
+                             f"(known: {sorted(LAYER_TYPE_LETTERS)})")
+        pattern = "".join(LAYER_TYPE_LETTERS[k] for k in cfg.layer_types)
+    if cfg.total_ut_steps > 1:
+        if ("D" in pattern or cfg.num_nextn_predict_layers
+                or cfg.tie_word_embeddings):
+            raise NotImplementedError(
+                "decoder: a looped model (total_ut_steps > 1) takes an "
+                "untied head, no multi-token-prediction module and no "
+                "block-diffusion block")
+        return _looped(ff, t, pattern, cfg)
+    t = _stack(ff, t, pattern, cfg)
     if "D" in pattern:
         # the head and the loss read the noised half alone
         half = cfg.seq_length // 2

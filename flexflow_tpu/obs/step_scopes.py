@@ -27,13 +27,19 @@ from flexflow_tpu.obs.artifacts import write_artifact
 
 SUFFIX = ".step_scopes.json"
 
-# program scope -> part; beside them `attention_<kind>` -> "attention"
-# and `op_<kind>` -> itself
+# program scope -> part; beside them `attention_<kind>` -> "attention",
+# `op_<kind>` -> itself and `ut<t>` -> itself
 SCOPE_PARTS = {"optimizer_update": "optimizer_update", "loss": "loss",
                "head": "head", "moe_layer": "experts", "ssm_mixer": "ssm",
                # a multi-token-prediction module's own ops, whatever their
                # kind: the scope lies around theirs (`FFModel.scope`)
-               "mtp": "mtp"}
+               "mtp": "mtp",
+               # what a looped model's passes share: the concatenation of
+               # the passes, the head's and the exit gate's products
+               "exit": "exit"}
+# a looped model's pass `ut<t>`, every op of it: itself, so that the
+# table tells pass from pass (the same kinds and shapes run in each)
+PASS_SCOPE = re.compile(r"ut\d+$")
 ATTENTION_SCOPE = "attention_"
 OP_SCOPE = "op_"
 # a Pallas kernel called at the top level (the non-causal attention op's,
@@ -59,6 +65,8 @@ def scope_part(scope: str) -> Optional[str]:
     """The part a program scope's name stands for, or None."""
     if scope in SCOPE_PARTS:
         return SCOPE_PARTS[scope]
+    if PASS_SCOPE.match(scope):
+        return scope
     if scope.startswith(ATTENTION_SCOPE):
         return "attention"
     if scope.startswith(OP_SCOPE):

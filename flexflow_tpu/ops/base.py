@@ -15,6 +15,7 @@ include/flexflow/ops/linear_params.h) map to ``Op.param_key()``.
 from __future__ import annotations
 
 import enum
+import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -95,6 +96,21 @@ def scoped(name: str, fn: Callable) -> Callable:
     return jax.jit(call)
 
 
+def _unless_reader(method: Callable, nothing: Callable) -> Callable:
+    """``method`` (an op's ``init_params`` / ``params_elems``) for an op
+    that owns its leaves; ``nothing()`` for one that reads them out of
+    another op (``Op.tied_params``): it holds none."""
+    @functools.wraps(method)
+    def owned(self, *args):
+        return nothing() if self.tied_params else method(self, *args)
+
+    return owned
+
+
+# layer properties that place an op and shape nothing of it
+PLACING_PROPERTIES = ("scope", "shared_op")
+
+
 class Op:
     op_type: OperatorType = OperatorType.NOOP
     # parameter names the executor keeps in float32 in the compute copy
@@ -102,12 +118,45 @@ class Op:
     # the op's forward names its own nested calls (`scoped`); the
     # executor wraps every other op in one named for its kind
     scopes_itself: bool = False
-    # leaves this op's forward reads out of ANOTHER op's parameters:
-    # {name in this op's `params`: (owner op's name, its leaf's name)}.
-    # The owner holds the one leaf (and its optimizer state); the
-    # executor hands it in (`executor.op_params`), inside the
-    # differentiated function, so its gradient sums every use
-    tied_params: Dict[str, Tuple[str, str]] = {}
+
+    def __init_subclass__(cls, **kw):
+        super().__init_subclass__(**kw)
+        for name, nothing in (("init_params", dict), ("params_elems", int)):
+            if name in cls.__dict__:
+                setattr(cls, name, _unless_reader(cls.__dict__[name],
+                                                  nothing))
+
+    @property
+    def tied_params(self) -> Dict[str, Tuple[str, str]]:
+        """Leaves this op's forward reads out of ANOTHER op's parameters:
+        {name in this op's `params`: (owner op's name, its leaf's name)}.
+        Filled for ALL of the op's leaves where its layer names an owner
+        (`FFModel._add_layer(shared_op=)`, upstream's argument of that
+        name). The owner holds the one leaf (and its optimizer state);
+        the executor hands it in (`executor.op_params`), inside the
+        differentiated function, so its gradient sums every use. A
+        reader holds no leaf: `init_params` gives {}, `params_elems` 0."""
+        if "_tied_params" not in self.__dict__:
+            layer = getattr(self, "layer", None)
+            owner = getattr(layer, "properties", {}).get("shared_op")
+            self._tied_params = {
+                leaf: (owner, leaf) for leaf in self.shared_leaves()[1]
+            } if owner else {}
+        return self._tied_params
+
+    def shared_leaves(self) -> Tuple[OperatorType,
+                                     Dict[str, Tuple[int, ...]]]:
+        """(kind, {leaf: shape}) of the op whose leaves this one can
+        read: by default an op of its own kind with the leaves it would
+        initialise itself. `FFModel.compile` holds a reader's owner to
+        it."""
+        if "_own_leaves" not in self.__dict__:
+            own = getattr(type(self).init_params, "__wrapped__",
+                          type(self).init_params)
+            tree = jax.eval_shape(lambda rng: own(self, rng),
+                                  jax.random.PRNGKey(0))
+            self._own_leaves = {k: tuple(v.shape) for k, v in tree.items()}
+        return self.op_type, self._own_leaves
 
     def traced_gauges(self) -> Dict[str, float]:
         """{gauge key: value} of what this op's forward, as last traced,
@@ -162,16 +211,19 @@ class Op:
 
     def tied_param_shapes(self) -> Dict[str, Tuple[int, ...]]:
         """Shapes of the ``tied_params``, for who runs the op alone."""
-        return {}
+        return self.shared_leaves()[1] if self.tied_params else {}
 
     def param_key(self) -> Tuple:
         """Structural identity for node dedup / cost caching
-        (analog of *Params hashing, model.h:677)."""
+        (analog of *Params hashing, model.h:677): where a layer lies
+        (its trace scope, whose leaves it reads) is no part of it, so
+        the applications of one layer are measured once."""
         return (
             self.op_type,
             tuple(self.input_shapes),
             tuple(sorted(
                 (k, repr(v)) for k, v in self.layer.properties.items()
+                if k not in PLACING_PROPERTIES
             )),
         )
 
@@ -196,3 +248,30 @@ def register_op(op_type: OperatorType):
         return klass
 
     return deco
+
+
+def shared_leaves_error(op: "Op", ops: Dict[str, "Op"]) -> Optional[str]:
+    """Why ``op`` cannot read its leaves out of the op its layer names
+    as their owner (``_add_layer(shared_op=)``), or None: the owner has
+    to be in the graph, hold its own leaves, and be of the kind and
+    with the leaves' shapes that the reader states
+    (``Op.shared_leaves``: its own, or for a tied head an embedding's
+    table). ``ops``: name -> op. `FFModel.compile` raises on it, fflint's
+    hygiene pass reports it (FFL605)."""
+    owners = {owner for owner, _ in op.tied_params.values()}
+    if not owners:
+        return None
+    (name,) = owners
+    owner = ops.get(name)
+    if owner is None:
+        return (f"reads its leaves out of '{name}', which is no layer of "
+                f"the graph")
+    if owner.tied_params:
+        return (f"reads its leaves out of '{name}', which holds none of "
+                f"its own")
+    wanted, has = op.shared_leaves(), owner.shared_leaves()
+    if wanted != has:
+        return (f"reads its leaves out of '{name}', a {has[0].name} with "
+                f"leaves {has[1]}, and needs a {wanted[0].name}'s "
+                f"{wanted[1]}")
+    return None

@@ -43,28 +43,30 @@ class Linear(Op):
         self.use_bias = layer.get_property("use_bias", True)
         self.kernel_init = layer.get_property("kernel_initializer") or DefaultWeightInitializer()
         self.bias_init = layer.get_property("bias_initializer") or DefaultBiasInitializer()
-        # a tied product reads ANOTHER op's leaf, a table [out_dim,
-        # in_dim] (an embedding's), as y = x E^T and holds none of its own
-        tied = layer.get_property("tied_to")
+        # a tied product reads the leaf of ANOTHER KIND of op, a table
+        # [out_dim, in_dim] (an embedding's), as y = x E^T: the one-leaf,
+        # transposed case of `shared_op` (`Op.tied_params`)
+        self.transposed = bool(layer.get_property("tied_to"))
         self._tied_traced = False
-        if tied:
-            self.tied_params = {"kernel": tuple(tied)}
-            self.tied_shape = tuple(layer.get_property("tied_shape"))
+        # the product in float32 at full precision, its leaves kept
+        # float32 in the compute copy (an exit gate: one column)
+        self.full_precision = bool(layer.get_property("full_precision"))
+        if self.full_precision:
+            self.full_precision_params = ("kernel", "bias")
         super().__init__(layer, input_shapes)
         self.in_dim = self.input_shapes[0][-1]
-        if tied and self.tied_shape != (self.out_dim, self.in_dim):
-            raise ValueError(
-                f"dense '{layer.name}': the tied table is "
-                f"{self.tied_shape}, the product needs "
-                f"{(self.out_dim, self.in_dim)}")
 
     def compute_output_shapes(self):
         (in_shape,) = self.input_shapes
         return [tuple(in_shape[:-1]) + (self.out_dim,)]
 
+    def shared_leaves(self):
+        if self.transposed:
+            return OperatorType.EMBEDDING, {
+                "kernel": (self.out_dim, self.input_shapes[0][-1])}
+        return super().shared_leaves()
+
     def init_params(self, rng):
-        if self.tied_params:
-            return {}
         in_dim = self.input_shapes[0][-1]
         k1, k2 = jax.random.split(rng)
         params = {"kernel": self.kernel_init(k1, (in_dim, self.out_dim))}
@@ -75,10 +77,14 @@ class Linear(Op):
     def forward(self, params, inputs, ctx: OpContext):
         (x,) = inputs
         w = params["kernel"].astype(ctx.compute_dtype)
-        if self.tied_params:
+        if self.transposed:
             self._tied_traced = True
             y = jnp.einsum("...e,ve->...v", x.astype(ctx.compute_dtype), w,
                            preferred_element_type=jnp.float32)
+        elif self.full_precision:
+            y = jnp.dot(x.astype(jnp.float32),
+                        params["kernel"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
         else:
             y = jnp.dot(x.astype(ctx.compute_dtype), w,
                         preferred_element_type=jnp.float32)
@@ -102,14 +108,9 @@ class Linear(Op):
     def traced_gauges(self):
         """`executor.tied_head_ops`: this product read its table out of
         another op's leaf when last traced (only a tied op says so)."""
-        if not self.tied_params:
+        if not self.transposed:
             return {}
         return {"executor.tied_head_ops": int(self._tied_traced)}
 
-    def tied_param_shapes(self):
-        return {"kernel": self.tied_shape} if self.tied_params else {}
-
     def params_elems(self):
-        if self.tied_params:
-            return 0
         return self.in_dim * self.out_dim + (self.out_dim if self.use_bias else 0)

@@ -133,11 +133,21 @@ def executed_kernel_choices(nodes, strategy, mesh_axes,
     return out
 
 
-def serialize_graph(nodes, final_guid: Optional[int] = None
+def serialize_graph(nodes, final_guid: Optional[int] = None,
+                    act_dtype_size: Optional[int] = None
                     ) -> List[Dict[str, Any]]:
+    """``act_dtype_size``: the element size of what an op's forward
+    leaves for its backward pass, where it is not the op's own (2 under
+    mixed precision: bfloat16 activations beside float32 leaves); the
+    native memory terms count saved outputs and interiors at it."""
     from flexflow_tpu.layout import train_fusable_conv_guids
     from flexflow_tpu.search.rewrite import external_input_ids
     neg_of = external_input_ids(nodes)
+    # ops no rewrite may re-form (ffs_subst.hpp `pinned`): the readers of
+    # another op's leaves and their owners (a rewritten op would hold
+    # leaves of its own, under a new name), a full-precision product
+    shared = {owner for n in nodes for owner, _ in
+              getattr(n.op, "tied_params", {}).values()}
     # conv guids whose sole consumer is a foldable BatchNorm — the
     # legality the native "_k:conv_bn_fused" kernel twin gates on
     # (shipped as a node attr: the gate is a GRAPH property the native
@@ -161,6 +171,9 @@ def serialize_graph(nodes, final_guid: Optional[int] = None
         attrs = _node_attrs(op)
         if op.guid in bn_fusable:
             attrs["bn_fusable"] = 1
+        if (op.name in shared or getattr(op, "tied_params", None)
+                or getattr(op, "full_precision", False)):
+            attrs["pinned"] = 1
         out.append(dict(
             guid=op.guid,
             type=op.op_type.name,
@@ -172,6 +185,8 @@ def serialize_graph(nodes, final_guid: Optional[int] = None
             params=_param_shapes(op),
             flops=float(op.flops()),
             dtype_size=op.dtype.size,
+            act_dtype_size=min(act_dtype_size or op.dtype.size,
+                               op.dtype.size),
             attrs=attrs,
         ))
     return out
@@ -346,8 +361,9 @@ def graph_optimize(nodes, machine_spec, config, num_devices: int,
     # mixed precision (TPU): activations + grads move in bf16 — halve the
     # collective payloads the cost model prices (matches the executor's
     # master-weight regime; CPU/f32 machines keep 1.0)
-    comm_factor = 0.5 if (getattr(config, "allow_mixed_precision", True)
-                          and machine_spec.chip != "cpu-sim") else 1.0
+    mixed = (getattr(config, "allow_mixed_precision", True)
+             and machine_spec.chip != "cpu-sim")
+    comm_factor = 0.5 if mixed else 1.0
     # learned per-op-class cost table (flexflow_tpu/costmodel): trained
     # COSTMODEL.json coefficients the DP queries where coverage exists,
     # analytic fallback elsewhere. None (no trained model, platform
@@ -370,7 +386,11 @@ def graph_optimize(nodes, machine_spec, config, num_devices: int,
     request = dict(
         nodes=serialize_graph(
             nodes,
-            final_guid=final_ref[0] if final_ref is not None else None),
+            final_guid=final_ref[0] if final_ref is not None else None,
+            # ... and every op's forward leaves bf16 for its backward
+            # pass (a looped model's step is saved activations first:
+            # 24 layer applications over one set of leaves)
+            act_dtype_size=2 if mixed else None),
         machine=machine_to_json(machine_spec, num_devices,
                                 comm_bytes_factor=comm_factor,
                                 learned=learned),

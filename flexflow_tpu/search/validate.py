@@ -163,8 +163,9 @@ def simulate_strategy(ff, learned: Any = "auto") -> Dict[str, Any]:
             plan.executed_choice(node, searched))
     axes = dict(zip(ff.mesh.axis_names, ff.mesh.devices.shape))
     req = dict(
-        nodes=serialize_graph(nodes,
-                              final_guid=ff.executor.final_ref[0]),
+        nodes=serialize_graph(
+            nodes, final_guid=ff.executor.final_ref[0],
+            act_dtype_size=ff.executor.compute_dtype.dtype.itemsize),
         machine=machine_to_json(ff.machine_spec, ff.mesh.devices.size,
                                 learned=learned),
         config=dict(training=True, overlap=True,

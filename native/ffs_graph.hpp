@@ -52,6 +52,10 @@ struct Node {
   std::map<std::string, Shape> params;          // param name -> shape
   double fwd_flops = 0.0;
   int dtype_size = 4;
+  // element size of what the op's forward leaves for its backward pass:
+  // the compute dtype's under mixed precision (bfloat16 activations
+  // beside float32 leaves), else `dtype_size`
+  int act_dtype_size = 4;
   Json attrs;  // op-specific attributes (num_heads, axis, ...)
 
   int64_t param_bytes() const {
@@ -61,6 +65,15 @@ struct Node {
   }
   int64_t output_bytes(int i) const {
     return shape_elems(output_shapes[i]) * dtype_size;
+  }
+  // a saved output's bytes, and the op's stated interior at the same
+  // element size (the memory terms; times are priced on `output_bytes`)
+  double act_bytes(int i) const {
+    return (double)shape_elems(output_shapes[i]) * act_dtype_size;
+  }
+  double act_interior_bytes() const {
+    return attrs.get("interior_bytes").as_double(0.0) * act_dtype_size /
+           dtype_size;
   }
   int64_t input_bytes(int i) const {
     return shape_elems(input_shapes[i]) * dtype_size;
@@ -118,6 +131,8 @@ struct Graph {
       }
       n.fwd_flops = nj.get("flops").as_double();
       n.dtype_size = static_cast<int>(nj.get("dtype_size").as_int(4));
+      n.act_dtype_size = static_cast<int>(
+          nj.get("act_dtype_size").as_int(n.dtype_size));
       n.attrs = nj.get("attrs");
       g.index_of[n.guid] = static_cast<int>(g.nodes.size());
       g.nodes.push_back(std::move(n));

@@ -1363,12 +1363,12 @@ inline double node_act_bytes(const Node& n, const Choice& c,
   double mem = 0;
   for (size_t i = 0; i < n.output_shapes.size(); ++i) {
     int k = i < c.out.size() ? shards_of(c.out[i], mesh) : 1;
-    mem += (double)n.output_bytes(i) / k;
+    mem += n.act_bytes(i) / k;
   }
   // what an op with a wide interior keeps for its backward pass besides
   // its outputs (the scan's projections and chunk states, the experts'
   // buffer): the op states it, and it shards as the first output does
-  double interior = n.attrs.get("interior_bytes").as_double(0.0);
+  double interior = n.act_interior_bytes();
   if (interior > 0 && !c.out.empty())
     mem += interior / shards_of(c.out[0], mesh);
   return mem;

@@ -439,6 +439,10 @@ inline std::vector<Match> find_matches(const Graph& g, const SubstRule& rule,
       const Node& n = g.nodes[ni];
       if (n.type != pop.type) continue;
       if (n.inputs.size() != pop.inputs.size()) continue;
+      // an op the graph says no rewrite may re-form: one whose leaves
+      // other ops read or that reads another's (the rewritten op would
+      // hold leaves of its own), a product stated in full precision
+      if (n.attrs.get("pinned").as_double(0.0) > 0) continue;
       Match saved = m;
       bool ok = subst_detail::check_params(pop, n, m);
       // edge consistency
@@ -571,9 +575,11 @@ inline std::optional<Graph> apply_rule(const Graph& g, const SubstRule& rule,
       n.attrs = base->attrs;
       n.params = base->params;
       n.dtype_size = base->dtype_size;
+      n.act_dtype_size = base->act_dtype_size;
       n.fwd_flops = base->fwd_flops;
     } else {
       n.dtype_size = g.nodes[match.node_of[0]].dtype_size;
+      n.act_dtype_size = g.nodes[match.node_of[0]].act_dtype_size;
     }
 
     // wire inputs + collect input shapes

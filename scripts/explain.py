@@ -319,6 +319,20 @@ def write_sim_trace_file(trace_dir, model, sim_resp, name_of):
     return path
 
 
+def shared_leaves_rows(ff):
+    """[(owner, its leaves, the ops that read them)] of the ops whose
+    leaves other ops read (`FFModel._add_layer(shared_op=)`, a tied
+    head): one leaf, one optimizer state, the gradient summed over the
+    readers."""
+    readers = {}
+    for node in ff.executor.nodes:
+        for owner in {owner for owner, _ in
+                      getattr(node.op, "tied_params", {}).values()}:
+            readers.setdefault(owner, []).append(node.op.name)
+    return [(owner, sorted(ff.params.get(owner, {})), names)
+            for owner, names in readers.items()]
+
+
 def to_markdown(model, ff, trace, sim_resp, rows, total_ops, feasible,
                 reasons, path_rows, path_total, merged_path,
                 disagreements=None, n_compared=0, kernel_rows=None,
@@ -346,6 +360,17 @@ def to_markdown(model, ff, trace, sim_resp, rows, total_ops, feasible,
         "| mesh | status | sim step ms | memory | note |",
         "|---|---|---|---|---|",
     ]
+    shared = shared_leaves_rows(ff)
+    if shared:
+        at = lines.index("## Mesh candidates")
+        lines[at:at] = [
+            "## Shared leaves", "",
+            f"{sum(len(r) for _, _, r in shared)} ops read the leaves of "
+            f"{len(shared)} others (held once, priced once: a reader has "
+            f"no weight memory, gradient sync or update of its own):", "",
+            "| owner | leaves | read by |", "|---|---|---|",
+            *(f"| {owner} | {', '.join(leaves)} | {', '.join(names)} |"
+              for owner, leaves, names in shared), ""]
     for m in feasible[:12]:
         pl = m.get("pipeline_candidates")
         note = m.get("reason", "")

@@ -44,7 +44,8 @@ if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu") \
                                + " --xla_force_host_platform_device_count=8")
 
 ZOO = ["mlp", "alexnet", "resnet", "resnext", "inception", "dlrm", "xdl",
-       "candle_uno", "moe", "moe_encoder", "transformer", "llama", "lfm2"]
+       "candle_uno", "moe", "moe_encoder", "transformer", "llama", "lfm2",
+       "ouro"]
 
 
 def build_model(name: str, ff_config):
@@ -127,14 +128,24 @@ def build_model(name: str, ff_config):
             moe_shared_expert_intermediate_size=0,
             tie_word_embeddings=True, batch_size=8, seq_length=16),
             ff_config), "cat"
+    if name == "ouro":
+        # a looped model: two sandwich-norm blocks applied three times
+        # with one set of leaves (the readers name their owners), an
+        # exit gate beside the head
+        from flexflow_tpu.models import DecoderConfig, create_decoder
+        return create_decoder(DecoderConfig(
+            hybrid_override_pattern="UU", total_ut_steps=3,
+            num_attention_heads=4, num_key_value_heads=4, batch_size=8,
+            seq_length=16), ff_config), "exit"
     raise SystemExit(f"unknown --model {name!r} (zoo: {', '.join(ZOO)})")
 
 
 def compile_model(ff, loss_kind: str):
     from flexflow_tpu.ffconst import LossType
     from flexflow_tpu.optimizers import SGDOptimizer
-    loss = (LossType.MEAN_SQUARED_ERROR_AVG_REDUCE if loss_kind == "mse"
-            else LossType.SPARSE_CATEGORICAL_CROSSENTROPY)
+    loss = {"mse": LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
+            "exit": LossType.EXPECTED_EXIT_SPARSE_CATEGORICAL_CROSSENTROPY,
+            }.get(loss_kind, LossType.SPARSE_CATEGORICAL_CROSSENTROPY)
     ff.compile(SGDOptimizer(lr=0.01), loss)
     return ff
 

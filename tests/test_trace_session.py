@@ -604,8 +604,10 @@ WITNESS_KEYS = [
     "attention/window_keys_visited",
     "executor.block_diffusion_attention_ops",
     "executor.flash_grouped_kv_ops", "executor.flash_lane_dense_ops",
-    "executor.flash_one_span_ops", "executor.latent_attention_ops", "executor.loss_own_vjp",
+    "executor.flash_one_span_ops", "executor.latent_attention_ops",
+    "executor.layer_applications", "executor.loss_own_vjp",
     "executor.moe_sum_rows_ops", "executor.rotary_lane_dense_ops",
+    "executor.shared_leaves", "executor.shared_weight_ops",
     "executor.window_attention_ops"]
 DEVICE_COUNTER_KEYS = ["moe/load_max_over_mean", "moe/overflow_slots",
                        "moe/slots_held"]
@@ -615,10 +617,11 @@ CONTEXT_KEYS = [
     "attention_window_keys_visited", "batch_size",
     "block_diffusion_attention_ops", "compile_phases",
     "flash_grouped_kv_ops", "flash_lane_dense_ops", "flash_one_span_ops",
-    "latent_attention_ops",
+    "latent_attention_ops", "layer_applications",
     "loss_own_vjp", "loss_target_positions", "mesh_axes",
     "moe_sum_rows_ops", "num_ops", "rotary_lane_dense_ops",
-    "set_parameter_s", "window_attention_ops"]
+    "set_parameter_s", "shared_leaves", "shared_weight_ops",
+    "window_attention_ops"]
 
 
 def test_the_published_keys_are_these(tmp_path, no_open_session):
