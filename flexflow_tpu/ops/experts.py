@@ -167,7 +167,9 @@ class MoELayer(Op):
     nested scope `moe_combine`. Where the layer holds a small share of
     the experts, on the TPU, `tokens_from_rows` reads the buffer's rows
     once, in token order, and a kernel adds them (PR 37:
-    `moe.sums_rows_by_kernel`, from the static shapes alone).
+    `moe.sums_rows_by_kernel`, from the static shapes alone); there the
+    combine's backward is that kernel's transpose, one kernel more, in
+    place of the row gather of dY (PR 49).
 
     Weights: w_router [D, n_experts], e_bias [n_experts] (b, the
     score-correction bias, sigmoid scoring only: it enters the choice
@@ -217,6 +219,7 @@ class MoELayer(Op):
         self._counters = None
         # whether the forward, as last traced, summed rows by the kernel
         self._sum_rows = False
+        self._spread_rows = False
         super().__init__(layer, input_shapes)
 
     def compute_output_shapes(self):
@@ -303,6 +306,9 @@ class MoELayer(Op):
             return grouped_matmul(h, params["w_down"].astype(cd),
                                   group_sizes)
 
+        def combine(o, weights, r):
+            return combine_rows(o, weights, r, self._saw_spread_rows)
+
         def shared(params, xt):
             hs = jnp.dot(xt.astype(cd), params["ws_up"].astype(cd),
                          preferred_element_type=jnp.float32)
@@ -322,7 +328,7 @@ class MoELayer(Op):
             x_buf = scoped("moe_combine", dispatch)(xt, r)
             o = scoped("moe_grouped_matmul", experts_held)(
                 params, x_buf, r["group_sizes"])
-            y = scoped("moe_combine", combine_rows)(o, weights, r)
+            y = scoped("moe_combine", combine)(o, weights, r)
             if self.shared_width:
                 y = y + scoped("moe_shared", shared)(params, xt)
             return y.reshape(b, s, d).astype(x.dtype), r["load"], \
@@ -342,12 +348,19 @@ class MoELayer(Op):
         }
         return [y]
 
+    def _saw_spread_rows(self):
+        self._spread_rows = True
+
     def traced_gauges(self):
         """`executor.moe_sum_rows_ops`: the forward, as last traced, had
         `tokens_from_rows` add the buffer's rows into their tokens by the
         kernel `moe_sum_rows` (PR 37; 0 where the layer holds all its
-        experts, and on the CPU)."""
-        return {"executor.moe_sum_rows_ops": int(bool(self._sum_rows))}
+        experts, and on the CPU). `executor.moe_spread_rows_ops`: a
+        backward of `combine_rows` has been traced, and it took that
+        kernel's transpose, `moe_spread_rows` (PR 49; `combine_rows`
+        calls `_saw_spread_rows` from there)."""
+        return {"executor.moe_sum_rows_ops": int(bool(self._sum_rows)),
+                "executor.moe_spread_rows_ops": int(self._spread_rows)}
 
     def output_dim_roles(self):
         # routing sorts the tokens of the whole batch into one buffer: the

@@ -41,6 +41,25 @@ it: 0.84 ms against 1.80 (weighted) and 0.50 against 1.68 a call at
 T = 16,384, k = 8, 24,704 rows of 2048. `sums_rows_by_kernel` picks the
 body from the static shapes; the k gathers stay where the buffer holds
 every pair, for shapes the kernel does not take whole, and off the TPU.
+
+The other direction at that cost too (PR 49). `combine_rows`' backward
+went from the row's side: dY's rows gathered as float32 and passed over
+twice, and `d weights` gathered through `row_of_pair`, T * k scalars,
+the last cost that followed the pairs. Where the forward sums by the
+kernel the backward is now that kernel's transpose, over the same grid
+and by the same 0/1 matrix the other way round:
+`pallas_kernels.moe_spread_rows` gives a row its token's dY in VMEM,
+`d o = w * dY` rounded once and `<dY, o>`, and from the latter `d
+weights` a token tile; one gather of `rows` rows through the order's
+inverse (`place`, a fourth sort, of `rows` keys) brings `d o` back to
+the buffer's order. The forward keeps its token-ordered rows for it in
+place of `o`. 0.62 ms against 2.29 a call at the shape above, 0.62
+against 1.97 at T = 16,384, k = 6, 18,560 rows of 2560 (the kernel
+0.46-0.61, the gather back 0.15, the sort 0.02). So, by direction:
+tokens -> rows (`rows_from_tokens`) is a gather forward and the sum by
+the kernel backward; rows -> tokens (`combine_rows`) is the sum by the
+kernel forward and its transpose backward; each falls back to gathers
+through the other map where `sums_rows_by_kernel` says no.
 """
 
 from __future__ import annotations
@@ -149,15 +168,19 @@ def rows_in_token_order(slot, valid, tokens: int, k: int):
       row        [rows] int32  the buffer row at each place of the order
       pair       [rows] int32  the pair it holds (any pair past the valid)
       token      [rows] int32  its token; `tokens` past the valid rows
+      place      [rows] int32  the inverse of `row`: where in the order
+                               each buffer row stands (what brings the
+                               combine's `d o` back, PR 49)
       tile_start [tiles + 1]   where the run of each tile of
                                `pallas_kernels.SUM_TOKENS` tokens starts
                                (the last: where the valid rows end)
-      items      dict          the kernel's grid (`moe_sum_rows_items`)
+      items      dict          the kernels' grid (`moe_sum_rows_items`)
     """
     rows = slot.shape[0]
-    pair, row = jax.lax.sort(
-        (jnp.where(valid, slot, tokens * k),
-         jnp.arange(rows, dtype=jnp.int32)), num_keys=1, is_stable=True)
+    at = jnp.arange(rows, dtype=jnp.int32)
+    pair, row = jax.lax.sort((jnp.where(valid, slot, tokens * k), at),
+                             num_keys=1, is_stable=True)
+    _, place = jax.lax.sort((row, at), num_keys=1)
     token = pair // k
     tile = pallas_kernels.SUM_TOKENS
     bounds = jnp.minimum(
@@ -165,7 +188,7 @@ def rows_in_token_order(slot, valid, tokens: int, k: int):
     tile_start = jnp.searchsorted(token, bounds, side="left",
                                   method="compare_all").astype(jnp.int32)
     return dict(row=row, pair=jnp.minimum(pair, tokens * k - 1), token=token,
-                tile_start=tile_start,
+                place=place, tile_start=tile_start,
                 items=pallas_kernels.moe_sum_rows_items(tile_start, rows))
 
 
@@ -212,22 +235,35 @@ def tokens_from_rows(buf, route, weight=None, dtype=None):
     tokens, k = route["row_of_pair"].shape
     body = (_sum_by_kernel if sums_rows_by_kernel(*buf.shape, tokens, k)
             else _sum_by_gathers)
+    return _row_major(body(buf, route, weight, dtype or buf.dtype))
+
+
+def _row_major(out):
     # rows come out of a gather (and of the kernel) row-major; a consumer
     # that keeps [T, d] with T minor (the decoders' residual stream on the
     # TPU) would have each of the k gathered arrays transposed to meet
     # it: ask for the sum row-major, so that it is transposed once
-    return with_layout_constraint(
-        body(buf, route, weight, dtype or buf.dtype),
-        Layout(major_to_minor=(0, 1)))
+    return with_layout_constraint(out, Layout(major_to_minor=(0, 1)))
+
+
+def _in_token_order(buf, route, weight):
+    """The buffer's rows and their pairs' weights (None for None) in
+    token order: one gather of `rows` rows, one of `rows` scalars."""
+    order = route["in_token_order"]
+    return _rows(buf, order["row"]), None if weight is None else _rows(
+        weight.reshape(-1).astype(jnp.float32), order["pair"])
 
 
 def _sum_by_kernel(buf, route, weight, dtype):
+    return _sum_in_token_order(*_in_token_order(buf, route, weight), route,
+                               dtype)
+
+
+def _sum_in_token_order(ordered, weight, route, dtype):
     order = route["in_token_order"]
     return pallas_kernels.moe_sum_rows(
-        _rows(buf, order["row"]), order["token"],
-        None if weight is None else _rows(
-            weight.reshape(-1).astype(jnp.float32), order["pair"]),
-        order["items"], route["row_of_pair"].shape[0], dtype,
+        ordered, order["token"], weight, order["items"],
+        route["row_of_pair"].shape[0], dtype,
         pallas_kernels.pallas_mode() == "interpret")
 
 
@@ -264,22 +300,45 @@ def _rows_from_tokens_bwd(route, d_rows):
 rows_from_tokens.defvjp(_rows_from_tokens_fwd, _rows_from_tokens_bwd)
 
 
-@jax.custom_vjp
-def combine_rows(o, weights, route):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def combine_rows(o, weights, route, spreads=None):
     """o [rows, d] the experts' outputs, weights [T, k] float32 ->
     [T, d] float32: every token's held pairs' rows, each times its weight
     (`tokens_from_rows`). Backward, as autodiff has it for the
-    scatter-add this replaces: d o = dY[token] * w on the row side (a row
-    gather), d weights = <dY[token], o> of a pair's row."""
+    scatter-add this replaces: d o = dY[token] * w on the row side,
+    d weights = <dY[token], o> of a pair's row. Where the forward sums by
+    the kernel (`sums_rows_by_kernel`) the backward is its transpose
+    (PR 49): ONE kernel, `moe_spread_rows`, over the rows in token order
+    (kept from the forward in place of `o`) gives both, and one gather of
+    `rows` rows brings `d o` back to the buffer's order; nothing follows
+    the T * k pairs. Else dY's rows are gathered (float32) and `d
+    weights` through `row_of_pair`, as since PR 32. `spreads`, if given,
+    is called (at trace time) by a backward that took the kernel."""
     return tokens_from_rows(o, route, weights, jnp.float32)
 
 
-def _combine_rows_fwd(o, weights, route):
-    return combine_rows(o, weights, route), (o, weights, route)
+def _combine_rows_fwd(o, weights, route, spreads):
+    if not sums_rows_by_kernel(*o.shape, *weights.shape):
+        return combine_rows(o, weights, route), (o, None, weights, route)
+    ordered, w_row = _in_token_order(o, route, weights)
+    return (_row_major(_sum_in_token_order(ordered, w_row, route,
+                                           jnp.float32)),
+            (ordered, w_row, weights, route))
 
 
-def _combine_rows_bwd(res, d_y):
-    o, weights, route = res
+def _combine_rows_bwd(spreads, res, d_y):
+    o, w_row, weights, route = res
+    if w_row is None:
+        return *_spread_by_gathers(o, weights, route, d_y), None
+    if spreads is not None:
+        spreads()
+    return *_spread_in_token_order(o, w_row, weights, route, d_y), None
+
+
+def _spread_by_gathers(o, weights, route, d_y):
+    """`combine_rows`' backward from the row's side: dY's rows gathered
+    as float32 and passed over twice, `d weights` a gather of T * k
+    scalars through `row_of_pair`."""
     slot, valid = route["slot"], route["valid"]
     d_rows = _rows(d_y, slot // weights.shape[1]).astype(jnp.float32)
     w_row = jnp.where(valid, _rows(weights.reshape(-1), slot), 0.0)
@@ -287,7 +346,21 @@ def _combine_rows_bwd(res, d_y):
     d_w_row = jnp.sum(d_rows * o.astype(jnp.float32), axis=-1)
     d_weights = jnp.where(route["pair_valid"],
                           _rows(d_w_row, route["row_of_pair"]), 0.0)
-    return d_o, d_weights.astype(weights.dtype), None
+    return d_o, d_weights.astype(weights.dtype)
+
+
+def _spread_in_token_order(ordered, w_row, weights, route, d_y):
+    """`combine_rows`' backward where its forward summed by the kernel:
+    `ordered` and `w_row` are the forward's rows and weights in token
+    order, and the kernel's `d ordered` goes back to the buffer's order
+    by one gather of `rows` rows."""
+    order = route["in_token_order"]
+    k = weights.shape[1]
+    d_ordered, d_weights = pallas_kernels.moe_spread_rows(
+        d_y.astype(jnp.float32), ordered, order["token"], order["pair"] % k,
+        w_row, order["items"], k,
+        pallas_kernels.pallas_mode() == "interpret")
+    return _rows(d_ordered, order["place"]), d_weights.astype(weights.dtype)
 
 
 combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)

@@ -68,8 +68,9 @@ and mask are float32. Tile sizes follow from the shape
 quoted beside them are the kernels alone on a v5e (PR 28), and PERF.md
 has what they gave through the benchmark's full step.
 
-Two kernels outside the attention core: `moe_sum_rows` (PR 37), the
-expert layer's sum of rows into their tokens, and `rotary_lanes` (PR
+Kernels outside the attention core: `moe_sum_rows` (PR 37), the expert
+layer's sum of rows into their tokens, with its transpose
+`moe_spread_rows` (PR 49), that sum's backward; and `rotary_lanes` (PR
 42), the heads' RMS norm and rotary in one pass over a projection's
 [B, S, H*128] result (or [B, S, H*64], two heads a 128-lane column, PR
 47), between the product and a flash kernel.
@@ -107,7 +108,8 @@ _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 # by its instruction, so a chip trace shows `tpu_custom_call_flash_fwd.3`
 # where it showed `tpu_custom_call.3`. The flash names keep the prefix an
 # unnamed kernel gets: reductions of a trace find them by it (benchmarks/
-# trace_reduce.KERNEL_PREFIX); `moe_sum_rows` goes without, as `gmm` does.
+# trace_reduce.KERNEL_PREFIX); `moe_sum_rows` and `moe_spread_rows` go
+# without, as `gmm` does.
 KERNEL_NAME_PREFIX = "tpu_custom_call_"
 
 # Longest sequence whose whole S x S f32 score tile of a head is one grid
@@ -1700,7 +1702,8 @@ flash_attention_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 # the expert layer's sum of rows into their tokens (PR 37): the one place
 # outside attention where XLA's lowering (a gather a (token, slot) pair,
 # held here or not) lost to a kernel by a factor (ops/moe.py
-# `tokens_from_rows`).
+# `tokens_from_rows`), and that sum's transpose (PR 49, `combine_rows`'
+# backward), over the same grid.
 
 SUM_TOKENS = 128  # tokens a tile of `moe_sum_rows`: one MXU pass high
 SUM_ROWS = 128    # token-ordered rows a block: the product's contraction
@@ -1728,7 +1731,10 @@ def moe_sum_rows_items(tile_start, rows: int):
     with zeros), one of SUM_TOKENS * k rows as many as it spans. Two
     neighbours share the block in which one ends and the other starts, so
     tiles + blocks items are enough; those past `count` repeat the last
-    one's tile and block, and the kernel passes over them."""
+    one's tile and block, and the kernel passes over them. `every_block`
+    is `block` for the kernel that WRITES the blocks (`moe_spread_rows`):
+    past `count` it walks on, a block an item, through the blocks no run
+    reaches (rows that hold no pair), which have to be written too."""
     blocks = -(-rows // SUM_ROWS)
     start, end = tile_start[:-1], tile_start[1:]
     first = jnp.minimum(start // SUM_ROWS, blocks - 1)
@@ -1740,7 +1746,10 @@ def moe_sum_rows_items(tile_start, rows: int):
         start.shape[0] - 1).astype(jnp.int32)
     block = first[tile] + jnp.minimum(item - (ends - count)[tile],
                                       count[tile] - 1)
+    every_block = jnp.minimum(block + jnp.maximum(item - (ends[-1] - 1), 0),
+                              blocks - 1)
     return dict(tile=tile, block=block.astype(jnp.int32),
+                every_block=every_block.astype(jnp.int32),
                 count=ends[-1:].astype(jnp.int32))
 
 
@@ -1840,6 +1849,142 @@ def moe_sum_rows(x, token, weight, items, tokens: int, dtype,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(items["tile"], items["block"], items["count"], *operands, x)
+
+
+def _spread_chunk(width: int) -> int:
+    """Lanes of a row that `moe_spread_rows` takes at a time: the widest
+    of these that divides the row (a [128, 512] float32 is the vector
+    registers' whole file)."""
+    return next(c for c in (512, 384, 256, 128) if width % c == 0)
+
+
+def _moe_spread_rows_kernel(tile_ref, _, block_ref, count_ref, token_ref,
+                            slot_ref, weight_ref, dy_ref, x_ref, dx_ref,
+                            dw_ref, dx_acc, dw_acc, *, k: int):
+    """One item of `moe_sum_rows`' grid, the product the other way round:
+    the [rows, tokens] 0/1 matrix of this block's rows and this tile's
+    tokens times the tile's dY gives each row of the tile its token's dY
+    (three bfloat16 pieces of it, each picked by a 1 and added in
+    float32: the float32 value to the bit) and the other rows of the
+    block exact zeros, so a block two tiles share accumulates. On that
+    [rows, lanes] tile, a chunk of lanes at a time: w * dY into the
+    block's accumulator and <dY, x> summed over the lanes; the latter,
+    spread over [slots, tokens] by the same 0/1 matrix and the rows'
+    slots, into the tile's `d weights`. `block_ref` is the items'
+    `every_block` (what is written); their `block`, unnamed here, only
+    steers what is fetched."""
+    n = pl.program_id(0)
+    last = pl.num_programs(0) - 1
+    tile, block = tile_ref[n], block_ref[n]
+    before, after = jnp.maximum(n - 1, 0), jnp.minimum(n + 1, last)
+
+    @pl.when((n == 0) | (block_ref[before] != block))
+    def _():
+        dx_acc[...] = jnp.zeros_like(dx_acc)
+
+    @pl.when((n == 0) | (tile_ref[before] != tile))
+    def _():
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    @pl.when(n < count_ref[0])
+    def _():
+        square = (SUM_ROWS, SUM_TOKENS)
+        lane = jax.lax.broadcasted_iota(jnp.int32, square, 1)
+        diagonal = jax.lax.broadcasted_iota(jnp.int32, square, 0) == lane
+
+        def column(v):      # [1, rows] along the lanes -> [rows, 1]
+            return jnp.sum(jnp.where(diagonal, v, 0), axis=1, keepdims=True)
+
+        mine = column(token_ref[0]) - tile * SUM_TOKENS == lane
+        hot = mine.astype(jnp.bfloat16)                   # [rows, tokens]
+        w = column(weight_ref[0])
+        d_w = jnp.zeros((SUM_ROWS, 1), jnp.float32)
+        chunk = _spread_chunk(dy_ref.shape[1])
+        for at in range(0, dy_ref.shape[1], chunk):
+            lanes = slice(at, at + chunk)
+            own = None
+            for piece in _bf16_pieces(dy_ref[:, lanes], 3):
+                part = jax.lax.dot_general(
+                    hot, piece, _NN, preferred_element_type=jnp.float32)
+                own = part if own is None else own + part
+            d_w = d_w + jnp.sum(own * x_ref[:, lanes].astype(jnp.float32),
+                                axis=1, keepdims=True)
+            dx_acc[:, lanes] += w * own
+        # a (token, slot) pair has at most one row: sums of one term
+        of_token = jnp.where(mine, d_w, 0.0)              # [rows, tokens]
+        slot = column(slot_ref[0])
+        at_slot = jax.lax.broadcasted_iota(jnp.int32, dw_acc.shape, 0)
+        d_weights = dw_acc[...]
+        for j in range(k):
+            d_weights = d_weights + jnp.where(
+                at_slot == j,
+                jnp.sum(jnp.where(slot == j, of_token, 0.0), axis=0,
+                        keepdims=True), 0.0)
+        dw_acc[...] = d_weights
+
+    @pl.when((n == last) | (block_ref[after] != block))
+    def _():
+        dx_ref[...] = dx_acc[...].astype(dx_ref.dtype)
+
+    @pl.when((n == last) | (tile_ref[after] != tile))
+    def _():
+        dw_ref[0] = dw_acc[...]
+
+
+def moe_spread_rows(d_y, x, token, slot, weight, items, k: int,
+                    interpret: bool):
+    """The transpose of `moe_sum_rows` (weighted), for the rows IN TOKEN
+    ORDER: of out[t] = sum of weight[i] * x[i] over the rows i of token t,
+
+        d x[i]               = weight[i] * d_y[token[i]]
+        d weights[t, slot[i]] = <d_y[t], x[i]>   (t = token[i])
+
+    d_y [tokens, d] float32, x [rows, d], token / slot / weight [rows]
+    (`token` ascending, `tokens` for a row that holds nothing; `slot` in
+    [0, k)), `items` the grid `moe_sum_rows_items` made -> (d x [rows, d]
+    in x's dtype, rounded once; d weights [tokens, k] float32, 0 for a
+    (token, slot) no row holds). d_y and x are read once and d x written
+    once (a block two tiles share, its tiles' d_y twice): d_y's rows
+    never exist in memory, and no cost follows the tokens * k pairs. Rows
+    that hold nothing come out 0. As in `moe_sum_rows`, a 0 is a product:
+    a d_y row that is not finite reaches the rows of its tile's blocks."""
+    rows, d = x.shape
+    tokens = d_y.shape[0]
+    blocks, tiles = rows // SUM_ROWS, tokens // SUM_TOKENS
+    slots = -(-k // 8) * 8          # whole sublanes
+
+    def lanes(v):
+        return v.reshape(blocks, 1, SUM_ROWS)
+
+    def at(which, *rest):
+        """The block index a prefetched map gives an item: 0 `tile`, 1
+        `block` (what is read: nothing is fetched past `count`), 2
+        `every_block` (what is written)."""
+        return lambda n, *maps: (maps[which][n], *rest)
+
+    of_block = pl.BlockSpec((1, 1, SUM_ROWS), at(1, 0, 0))
+    d_x, d_w = pl.pallas_call(
+        functools.partial(_moe_spread_rows_kernel, k=k),
+        name="moe_spread_rows",
+        out_shape=(jax.ShapeDtypeStruct((rows, d), x.dtype),
+                   jax.ShapeDtypeStruct((tiles, slots, SUM_TOKENS),
+                                        jnp.float32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(items["tile"].shape[0],),
+            in_specs=[of_block] * 3 + [
+                pl.BlockSpec((SUM_TOKENS, d), at(0, 0)),
+                pl.BlockSpec((SUM_ROWS, d), at(1, 0))],
+            out_specs=(pl.BlockSpec((SUM_ROWS, d), at(2, 0)),
+                       pl.BlockSpec((1, slots, SUM_TOKENS), at(0, 0, 0))),
+            scratch_shapes=[pltpu.VMEM((SUM_ROWS, d), jnp.float32),
+                            pltpu.VMEM((slots, SUM_TOKENS), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(items["tile"], items["block"], items["every_block"], items["count"],
+      lanes(token), lanes(slot), lanes(weight), d_y, x)
+    return d_x, d_w.swapaxes(1, 2).reshape(tokens, slots)[:, :k]
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """The hand look behind how `MoELayer` brings rows back to tokens (PR 32,
-PR 37).
+PR 37, PR 49).
 
-On the chip, at the shapes of the three cells that run `MoELayer`, each
+On the chip, at the shapes of five of the cells that run `MoELayer`, each
 piece alone, 20 calls after a warm-up, wall time a call (`<piece>_ms`),
 and then under the profiler the device's own time of the piece's program
 (`<piece>_device_ms`, with its ops by stem):
@@ -16,7 +16,12 @@ and then under the profiler the device's own time of the piece's program
   PR 32; the rows into token order by one gather and the kernel
   `moe_sum_rows`, PR 37, and that form's parts alone: the order's sort,
   the gather, the kernel), `combine_rows` and `rows_from_tokens` forward
-  and backward;
+  and backward; `combine_rows`' backward alone in both its bodies, the
+  forward's residuals given (`combine_bwd.by_gathers`: dY's rows
+  gathered, PR 32; `combine_bwd.by_kernel`: the kernel `moe_spread_rows`
+  over the rows in token order and the gather back, PR 49, and that
+  form's parts alone: the kernel, the gather back, the sort that makes
+  the order's inverse);
 - forms that were tried against it: one gather of all T*k rows and a
   reduction over k; the inverse map from a cumulative count in place of
   the second sort; a form whose cost follows the buffer's rows (rows into
@@ -50,6 +55,10 @@ SHAPES = {
                                             d=2688, rows=4736),
     "sdar_30b_a3b.s8192_b1": dict(T=16384, k=8, E=128, held=16, d=2048,
                                   rows=24704, mask_share=0.25),
+    "lfm2_8b_a1b.s16384_b1": dict(T=16384, k=4, E=32, held=8, d=2048,
+                                  rows=24704),
+    "laguna_xs2.s8192_b1": dict(T=8192, k=8, E=256, held=16, d=2048,
+                                rows=6272),
 }
 HBM_BYTES_PER_S = 819e9   # v5e, as benchmarks/peaks.json has it
 
@@ -132,6 +141,34 @@ def pieces(s):
         r = route(experts)
         return jax.value_and_grad(
             lambda o, w: total(moe.combine_rows(o, w, r)), (0, 1))(o, weights)
+
+    # `combine_rows` backward alone, the routing and the forward's
+    # residuals given: the row's side (PR 32; dY's rows gathered) and the
+    # kernel's side with its parts (PR 49)
+    def bwd_by_gathers(o, weights, r, d_y):
+        return moe._spread_by_gathers(o, weights, r, d_y)
+
+    def bwd_by_kernel(o_ordered, w_ordered, weights, r, d_y):
+        return moe._spread_in_token_order(o_ordered, w_ordered, weights, r,
+                                          d_y)
+
+    def bwd_kernel(o_ordered, w_ordered, r, d_y):
+        order = r["in_token_order"]
+        return pallas_kernels.moe_spread_rows(
+            d_y, o_ordered, order["token"], order["pair"] % k, w_ordered,
+            order["items"], k, False)
+
+    def bwd_gather_back(o_ordered, r):
+        return moe._rows(o_ordered, r["in_token_order"]["place"])
+
+    def bwd_inverse_sort(r):
+        row = r["in_token_order"]["row"]
+        return jax.lax.sort((row, jnp.arange(rows, dtype=jnp.int32)),
+                            num_keys=1)[1]
+
+    def combine_fwd_bwd_routed(o, weights, r, d_y):
+        y, back = jax.vjp(lambda o, w: moe.combine_rows(o, w, r), o, weights)
+        return y, back(d_y)
 
     def dispatch_fwd(x, experts):
         return moe.rows_from_tokens(x, route(experts))
@@ -218,6 +255,18 @@ def pieces(s):
         "by_kernel.kernel_plain": (kernel_plain, ("o", "route")),
         "route+combine_fwd_bwd": (combine_fwd_bwd,
                                   ("o", "weights", "experts")),
+        "combine_fwd_bwd": (combine_fwd_bwd_routed,
+                            ("o", "weights", "route", "d_y")),
+        "combine_bwd.by_gathers": (bwd_by_gathers,
+                                   ("o", "weights", "route", "d_y")),
+        "combine_bwd.by_kernel": (
+            bwd_by_kernel,
+            ("o_ordered", "w_ordered", "weights", "route", "d_y")),
+        "combine_bwd.by_kernel.kernel": (
+            bwd_kernel, ("o_ordered", "w_ordered", "route", "d_y")),
+        "combine_bwd.by_kernel.gather_back": (bwd_gather_back,
+                                              ("o_ordered", "route")),
+        "combine_bwd.by_kernel.inverse_sort": (bwd_inverse_sort, ("route",)),
         "route+dispatch_fwd": (dispatch_fwd, ("x", "experts")),
         "route+dispatch_fwd_bwd": (dispatch_fwd_bwd, ("x", "experts")),
         "tried.route+one_gather_of_all_pairs": (
@@ -256,9 +305,12 @@ def make_arguments(s):
     experts = experts.astype(jnp.int32)
     r = moe.route_held_experts(experts, s["held"], 0, s["rows"])
     weights = jax.random.uniform(ks[1], (s["T"], s["k"]))
+    o = jax.random.normal(ks[3], (s["rows"], s["d"]), jnp.bfloat16)
+    o_ordered, w_ordered = moe._in_token_order(o, r, weights)
     return dict(
         x=jax.random.normal(ks[2], (s["T"], s["d"]), jnp.bfloat16),
-        o=jax.random.normal(ks[3], (s["rows"], s["d"]), jnp.bfloat16),
+        d_y=jax.random.normal(ks[2], (s["T"], s["d"]), jnp.float32),
+        o=o, o_ordered=o_ordered, w_ordered=w_ordered,
         weights=weights, experts=experts, token=r["slot"] // s["k"],
         route=r,
         w_row=jnp.where(r["valid"], weights.reshape(-1)[r["slot"]], 0.0),

@@ -371,6 +371,93 @@ def test_tokens_from_rows_is_the_scatter_add_of_the_rows(name, weighted,
     np.testing.assert_array_equal(same, got.astype(dtype))
 
 
+KERNEL_ROUTINGS = [name for name in ROUTINGS if name.startswith("kernel_")]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("name", KERNEL_ROUTINGS)
+def test_moe_spread_rows_is_the_gather_of_the_tokens_gradient(name, dtype):
+    """`moe_spread_rows` (PR 49), interpreted, against the plain form
+    with the rows in token order: d x = w * dY[token], float32's product
+    rounded once, to the bit; d weights[token, slot] = <dY[token], x>.
+    The routings hold an empty tile and a run of two blocks
+    (`kernel_full_empty_and_mixed_tiles`), blocks that two tiles share
+    and a block no run reaches (`kernel_a_quarter_on_the_same_experts`),
+    and rows that hold no pair, which come out 0."""
+    experts, held, offset, rows = ROUTINGS[name]
+    tokens, k = experts.shape
+    order = moe.route_held_experts(experts, held, offset,
+                                   rows)["in_token_order"]
+    token, slot = np.asarray(order["token"]), np.asarray(order["pair"]) % k
+    rs = np.random.RandomState(11)
+    x = jnp.asarray(rs.randn(rows, 256), jnp.float32).astype(dtype)
+    w = jnp.asarray(rs.rand(rows), jnp.float32)
+    d_y = jnp.asarray(rs.randn(tokens, 256), jnp.float32)
+    d_x, d_w = pallas_kernels.moe_spread_rows(
+        d_y, x, order["token"], jnp.asarray(slot), w, order["items"], k, True)
+    held_rows = token < tokens
+    assert 0 < held_rows.sum() <= rows
+    own = np.where(held_rows[:, None],
+                   np.asarray(d_y)[np.minimum(token, tokens - 1)], 0.0)
+    assert d_x.dtype == dtype and d_w.shape == (tokens, k)
+    np.testing.assert_array_equal(
+        d_x, jnp.asarray(np.asarray(w)[:, None] * own).astype(dtype))
+    want = np.zeros((tokens, k), np.float32)
+    want[token[held_rows], slot[held_rows]] = np.sum(
+        own * np.asarray(x.astype(jnp.float32)), axis=1)[held_rows]
+    np.testing.assert_allclose(d_w, want, rtol=1e-5, atol=1e-5)
+    assert (np.asarray(d_w)[want == 0] == 0).all()
+    if name == "kernel_a_quarter_on_the_same_experts":
+        items = jax.tree.map(np.asarray, order["items"])
+        count = int(items["count"][0])
+        assert (np.diff(items["block"][:count]) == 0).any()     # shared
+        assert items["block"].max() < rows // 128 - 1           # unreached
+        assert items["every_block"][:count].tolist() == (
+            items["block"][:count].tolist())
+        assert items["every_block"][count:].tolist() == np.minimum(
+            items["block"][count - 1] + 1 + np.arange(len(items["block"])
+                                                      - count),
+            rows // 128 - 1).tolist()
+
+
+@pytest.mark.parametrize("name,dtype", [
+    (name, jnp.bfloat16) for name in KERNEL_ROUTINGS] + [
+    ("kernel_full_empty_and_mixed_tiles", jnp.float32),
+    ("a_row_a_pair_keeps_the_gathers", jnp.bfloat16)])
+def test_combine_rows_gradients_are_the_same_both_ways(name, dtype,
+                                                       monkeypatch):
+    """`jax.grad` through `combine_rows` where its backward is the kernel
+    `moe_spread_rows` over the rows in token order (PR 49: the `kernel_`
+    routings, interpreted) equals the backward that gathers dY's rows:
+    `d o` to the bit after its one rounding, `d weights` to float32's
+    rounding. The kernel's backward says so to its caller, the other,
+    and every backward of a shape the kernel does not take, does not."""
+    experts, held, offset, rows = ROUTINGS[name]
+    tokens, k = experts.shape
+    width = 256 if pallas_kernels.moe_sum_rows_shape_legal(
+        rows, 256, tokens) else 20
+    rs = np.random.RandomState(13)
+    o = jnp.asarray(rs.randn(rows, width), jnp.float32).astype(dtype)
+    weights = jnp.asarray(rs.rand(tokens, k), jnp.float32)
+    d_y = jnp.asarray(rs.randn(tokens, width), jnp.float32)
+    got = {}
+    for mode in ("off", "interpret"):
+        monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
+        r = moe.route_held_experts(experts, held, offset, rows)
+        said = []
+        got[mode] = jax.grad(
+            lambda o, w: jnp.sum(moe.combine_rows(
+                o, w, r, lambda: said.append(mode)) * d_y), (0, 1))(
+                    o, weights)
+        assert bool(said) == sums_by_kernel(name, mode)
+    (d_o, d_w), (d_o_kernel, d_w_kernel) = got["off"], got["interpret"]
+    assert d_o_kernel.dtype == dtype and d_w_kernel.dtype == jnp.float32
+    np.testing.assert_array_equal(d_o_kernel, d_o)
+    np.testing.assert_allclose(d_w_kernel, d_w, rtol=1e-5, atol=1e-5)
+    assert (np.asarray(d_w_kernel)[~np.asarray(r["pair_valid"])] == 0).all()
+
+
 def scatter_add_layer(op, params, inputs):
     """`MoELayer.forward` as it was until PR 32, kept here as the
     reference of the gradients: rows go out by `xt[token]`, come back by
@@ -444,7 +531,8 @@ def test_layer_gradients_match_the_scatter_add_form(name, mode, monkeypatch):
     """Output and `jax.grad` of a whole `MoELayer`, every leaf and every
     input, against the scatter-add form above, with the Pallas kernels
     off and interpreted (the grouped products' and, in the `kernel_`
-    layers, `moe_sum_rows`)."""
+    layers, `moe_sum_rows` and the combine's backward `moe_spread_rows`,
+    which the op's gauges then say)."""
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", mode)
     props, n_inputs, seq, *width = LAYERS[name]
     width = width[0] if width else 32
@@ -477,7 +565,8 @@ def test_layer_gradients_match_the_scatter_add_form(name, mode, monkeypatch):
     overflow = float(op._counters["moe/overflow_slots"][1])
     assert (overflow > 0) == ("buffer_too_small" in name)
     assert op.traced_gauges() == {
-        "executor.moe_sum_rows_ops": int(sums_by_kernel(name, mode))}
+        "executor.moe_sum_rows_ops": int(sums_by_kernel(name, mode)),
+        "executor.moe_spread_rows_ops": int(sums_by_kernel(name, mode))}
     flat_got, tree = jax.tree.flatten(got)
     flat_want, tree_want = jax.tree.flatten(want)
     assert tree == tree_want and len(flat_got) == len(params) + n_inputs

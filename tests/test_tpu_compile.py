@@ -442,12 +442,23 @@ class TestHybridDecoderKernels:
         assert (compiled.memory_analysis().temp_size_in_bytes
                 < 4 * tokens * props["k"] * width)
         assert op.traced_gauges()["executor.moe_sum_rows_ops"] == 1
-        sums = [line for line in hlo.splitlines()
-                if "custom_call_target=\"tpu_custom_call\"" in line
-                and "moe_sum_rows" in line]
+        assert op.traced_gauges()["executor.moe_spread_rows_ops"] == 1
+        kernels = [line for line in hlo.splitlines()
+                   if "custom_call_target=\"tpu_custom_call\"" in line]
+        sums = [line for line in kernels if "moe_sum_rows" in line]
         assert len(sums) == 2 and all("moe_combine" in s for s in sums)
+        # the combine's backward is their transpose, ONE kernel (PR 49)
+        spreads = [line for line in kernels if "moe_spread_rows" in line]
+        assert len(spreads) == 1 and "moe_combine" in spreads[0]
+        # and nothing in it follows the tokens * k pairs: no gather
+        # through `row_of_pair`, which gave d weights [tokens, k]
+        pairs = re.compile(r" = \w+\[(%d,%d|%d)\]\S* gather\(" % (
+            tokens, props["k"], tokens * props["k"]))
+        assert not [
+            line for line in hlo.splitlines() if pairs.search(line)
+            and "transpose(jvp(jit(moe_layer)))/jit(moe_combine)" in line]
         # three products an expert matrix: forward, d rows, d weights
-        assert pallas_kernel_count(hlo) == 2 + 3 * op.matrices
+        assert pallas_kernel_count(hlo) == 3 + 3 * op.matrices
 
     def test_chunked_scan_at_the_cells_widths(self, topo):
         from flexflow_tpu.ops.ssm import ssd_chunked

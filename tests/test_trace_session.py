@@ -512,7 +512,9 @@ def test_expert_ops_counts_the_models_expert_layers(
     and here, with the kernels off; in the header of a session, the
     registry's snapshot and `FFModel.op_counters` (which exist where an
     op counts something: a model with such a layer). A model without
-    such a layer publishes no such key."""
+    such a layer publishes no such key. Beside it, and the same way,
+    `executor.moe_spread_rows_ops` (PR 49): the layers whose traced
+    backward of the combine took the kernel `moe_spread_rows`."""
     import numpy as np
     from flexflow_tpu import (FFConfig, FFModel, LossType, MetricsType,
                               SGDOptimizer)
@@ -543,10 +545,14 @@ def test_expert_ops_counts_the_models_expert_layers(
     gauges = json.load(open(paths["counters"]))["gauges"]
     assert gauges["executor.expert_ops"] == moe_layers
     assert ("moe_sum_rows_ops" in header) == bool(moe_layers)
+    assert ("moe_spread_rows_ops" in header) == bool(moe_layers)
     if moe_layers:
         assert header["moe_sum_rows_ops"] == 0
+        assert header["moe_spread_rows_ops"] == 0
         assert gauges["executor.moe_sum_rows_ops"] == 0
+        assert gauges["executor.moe_spread_rows_ops"] == 0
         assert ff.op_counters["executor.moe_sum_rows_ops"] == 0
+        assert ff.op_counters["executor.moe_spread_rows_ops"] == 0
         assert ff.op_counters["moe/overflow_slots"] == 0
 
 
@@ -560,7 +566,10 @@ def test_moe_sum_rows_ops_counts_the_layers_that_sum_by_the_kernel(
     kernel `moe_sum_rows`. 0 until the step is traced; then the layers'
     count where the kernels run (here interpreted) and a layer holds a
     small share of its experts, 0 where it holds them all (a row a pair:
-    the k gathers stay) and where the kernels are off, as on the CPU."""
+    the k gathers stay) and where the kernels are off, as on the CPU.
+    `executor.moe_spread_rows_ops` (PR 49) counts those whose traced
+    BACKWARD of the combine took the kernel `moe_spread_rows`: the same
+    layers, by the same rule."""
     import numpy as np
     from flexflow_tpu import (FFConfig, FFModel, LossType, MetricsType,
                               SGDOptimizer)
@@ -576,6 +585,7 @@ def test_moe_sum_rows_ops_counts_the_layers_that_sum_by_the_kernel(
     ff.compile(SGDOptimizer(lr=0.01), LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
                [MetricsType.MEAN_SQUARED_ERROR])
     assert obs.model_context(ff)["moe_sum_rows_ops"] == 0     # not traced
+    assert obs.model_context(ff)["moe_spread_rows_ops"] == 0
     rs = np.random.RandomState(0)
     x = rs.randn(b, s, e).astype(np.float32)
     y = rs.randn(b, s, 1).astype(np.float32)
@@ -586,10 +596,13 @@ def test_moe_sum_rows_ops_counts_the_layers_that_sum_by_the_kernel(
     header, _ = read_events(paths["events"])
     expected = layers if sums else 0
     assert header["moe_sum_rows_ops"] == expected
+    assert header["moe_spread_rows_ops"] == expected
     gauges = json.load(open(paths["counters"]))["gauges"]
     assert gauges["executor.expert_ops"] == layers
     assert gauges["executor.moe_sum_rows_ops"] == expected
+    assert gauges["executor.moe_spread_rows_ops"] == expected
     assert ff.op_counters["executor.moe_sum_rows_ops"] == expected
+    assert ff.op_counters["executor.moe_spread_rows_ops"] == expected
     assert ff.op_counters["moe/overflow_slots"] == 0
 
 
@@ -606,7 +619,8 @@ WITNESS_KEYS = [
     "executor.flash_grouped_kv_ops", "executor.flash_lane_dense_ops",
     "executor.flash_one_span_ops", "executor.latent_attention_ops",
     "executor.layer_applications", "executor.loss_own_vjp",
-    "executor.moe_sum_rows_ops", "executor.rotary_lane_dense_ops",
+    "executor.moe_spread_rows_ops", "executor.moe_sum_rows_ops",
+    "executor.rotary_lane_dense_ops",
     "executor.shared_leaves", "executor.shared_weight_ops",
     "executor.window_attention_ops"]
 DEVICE_COUNTER_KEYS = ["moe/load_max_over_mean", "moe/overflow_slots",
@@ -619,7 +633,8 @@ CONTEXT_KEYS = [
     "flash_grouped_kv_ops", "flash_lane_dense_ops", "flash_one_span_ops",
     "latent_attention_ops", "layer_applications",
     "loss_own_vjp", "loss_target_positions", "mesh_axes",
-    "moe_sum_rows_ops", "num_ops", "rotary_lane_dense_ops",
+    "moe_spread_rows_ops", "moe_sum_rows_ops", "num_ops",
+    "rotary_lane_dense_ops",
     "set_parameter_s", "shared_leaves", "shared_weight_ops",
     "window_attention_ops"]
 
