@@ -27,6 +27,7 @@ from flexflow_tpu.losses import (class_ids, expected_exit_loss, get_loss_fn,
                                  target_positions, weighted_nll_mean)
 from flexflow_tpu.metrics import Metrics
 from flexflow_tpu.obs.registry import get_registry
+from flexflow_tpu.obs.step_scopes import scope_part
 from flexflow_tpu.ops.base import Op, OpContext, scoped
 from flexflow_tpu.parallel.choice import ExecPlan
 
@@ -540,6 +541,39 @@ class GraphExecutor:
                 values[self.final_ref], TO_NCHW)
         return values, new_state, aux_losses
 
+    def scope_names(self, op) -> List[str]:
+        """The nested calls ``op``'s forward runs under in the train
+        step, outermost first: the scope the model's builder put the
+        layer under (``FFModel.scope``: ``mtp``, ``ut<t>``), ``head`` for
+        the op that produces the model's output, then the op's own
+        (``Op.scopes_itself``; an attention op's is the stem, the route
+        of a trace gives the rest) or ``op_<operator type, lower
+        case>``. The one rule from an op to the names its instructions
+        carry: ``_scoped_forward`` nests these, ``part_of_node`` reads
+        them as ``obs.step_scopes.part_of`` reads an ``op_name`` back."""
+        layer = getattr(op, "layer", None)
+        names = [n for n in (getattr(layer, "properties", {}).get("scope"),)
+                 if n]
+        own = getattr(op, "scopes_itself", "")
+        if op.guid == self.final_ref[0]:
+            names.append("head")
+        elif not own:
+            names.append(
+                "op_" + getattr(layer, "op_type", op.op_type).name.lower())
+        if own:
+            names.append(own)
+        return names
+
+    def part_of_node(self, node) -> Optional[str]:
+        """The part of the train step ``node``'s instructions lie in
+        (obs/step_scopes.py): that of the outermost of its scopes that
+        names one."""
+        for name in self.scope_names(node.op):
+            part = scope_part(name)
+            if part is not None:
+                return part
+        return None
+
     def _scoped_forward(self, op, ctx: OpContext):
         """``op``'s forward as ``forward(params, args[, state])``, for
         the device trace. An op that names its own nested calls
@@ -562,16 +596,9 @@ class GraphExecutor:
                 return op.forward(params, args, ctx, state=state)
             return op.forward(params, args, ctx)
 
-        layer = getattr(op, "layer", None)
-        # outermost first: the scope the model's builder put the layer
-        # under (`FFModel.scope`: `mtp`), then the op's kind
-        names = [n for n in (getattr(layer, "properties", {}).get("scope"),)
-                 if n]
-        if op.guid == self.final_ref[0]:
-            names.append("head")
-        elif not getattr(op, "scopes_itself", False):
-            names.append(
-                "op_" + getattr(layer, "op_type", op.op_type).name.lower())
+        names = self.scope_names(op)
+        if getattr(op, "scopes_itself", ""):
+            names = names[:-1]      # the last is the op's own call
         if not names:
             return plain
 

@@ -1079,6 +1079,15 @@ class FFModel:
             param_init_s=t_init - t_linted,
             state_placement_s=time.perf_counter() - t_init)
         self.set_parameter_s = 0.0
+        # what the search believes of the strategy it chose, beside the
+        # counters an operator reads (a session's header holds the
+        # allocator's peak: obs/session.py); nothing without a search
+        from flexflow_tpu.obs.inspect import search_predictions
+        from flexflow_tpu.obs.registry import get_registry
+        for key, value in search_predictions(self).items():
+            if value is not None:
+                get_registry().gauge(key.replace("search_", "search/", 1),
+                                     value)
         self._iter = 0
         self._seq_execs: Dict[int, Any] = {}  # seq-length bucket executors
         self._unpackers: Dict[Tuple, Any] = {}  # staging programs, by shape
@@ -1431,7 +1440,7 @@ class FFModel:
         train_step = self.executor.make_train_step()
         # None unless a session with the profiler is open and has not
         # seen this executor's step yet
-        keep_step = step_keeper(self.executor)
+        keep_step = step_keeper(self)
         self._refresh_compute_params()
         tracer.setup_done()
         start = time.time()

@@ -17,6 +17,8 @@ import re
 from collections import defaultdict
 from typing import Any, Dict, List, Optional, Tuple
 
+from flexflow_tpu.obs.step_scopes import COMPUTATION
+
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s32": 4, "u32": 4,
     "s16": 2, "u16": 2, "s8": 1, "u8": 1, "pred": 1, "s64": 8, "u64": 8,
@@ -308,9 +310,6 @@ def scatters_in(hlo_text: str, scope: str = "") -> List[Tuple[str, int]]:
             for dims, name in _SCATTER.findall(hlo_text) if scope in name]
 
 
-# (a header of six results or more holds `/*index=5*/`, the ENTRY's among
-# them: `step_scopes._COMPUTATION`, which stops at an `=`, misses those)
-_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
 _INSTRUCTION = re.compile(
     r"^\s*(?:ROOT )?(%?[\w.\-]+) = (.*?) ([\w\-]+)\(")
 
@@ -326,7 +325,7 @@ def arrays_between_fusions(hlo_text: str, dtype: str,
     array = re.compile(r"\b" + re.escape(dtype) + r"\[([\d,]+)\]")
     out, fused = [], False
     for line in hlo_text.splitlines():
-        head = _COMPUTATION.match(line)
+        head = COMPUTATION.match(line)
         if head:
             fused = "fused_computation" in head.group(1)
             continue
@@ -338,6 +337,16 @@ def arrays_between_fusions(hlo_text: str, dtype: str,
                 for dims in array.findall(m.group(2))):
             out.append(m.group(1))
     return out
+
+
+def search_predictions(ff) -> Dict[str, Any]:
+    """What the search returned for the strategy it chose, seconds a
+    step and bytes a chip (``search_info``); None each where no search
+    ran. The allocator's ``device_peak_bytes`` of a session's header
+    and the device's busy time a step are what they are held against."""
+    info = ff.search_info if isinstance(ff.search_info, dict) else {}
+    return dict(search_predicted_step_s=info.get("predicted_time"),
+                search_predicted_memory_bytes=info.get("predicted_memory"))
 
 
 def model_context(ff) -> Dict[str, Any]:
@@ -353,6 +362,7 @@ def model_context(ff) -> Dict[str, Any]:
         # set_parameter call since (model.py records both, always)
         compile_phases=getattr(ff, "compile_phases", None),
         set_parameter_s=getattr(ff, "set_parameter_s", None),
+        **search_predictions(ff),
         # what the trace of the ops' forwards and of the loss recorded on
         # the host (`GraphExecutor.traced_gauges`: zeros until a step or
         # a forward has been traced), each under its key's last dotted

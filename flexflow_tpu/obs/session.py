@@ -21,15 +21,19 @@ clock to the profiler's: short marker annotations whose
 the shift lands in the artifact's header as ``clock_shift_us`` (add it
 to a profiler timestamp in microseconds to get a span's ``ts``).
 
-``stop_trace`` writes the spans and a counters snapshot and returns.
-It replays nothing: the summary, drift and simulator reports stay with
-the per-call ``fit(trace_dir=...)`` form. With ``device=True`` it also
-writes the join table of the train step the session's ``fit`` calls
-ran (``<stem>.step_scopes.json``, obs/step_scopes.py): once the
+``stop_trace`` writes the spans and a counters snapshot and returns;
+the header holds the allocator's peak over the local devices
+(``device_peak_bytes``). The summary, drift and simulator reports stay
+with the per-call ``fit(trace_dir=...)`` form. With ``device=True`` it
+also writes the join table of the train step the session's ``fit``
+calls ran (``<stem>.step_scopes.json``, obs/step_scopes.py): once the
 profiler has stopped, the step is lowered and compiled once more from
 the shapes and shardings its first call had, and every instruction's
-part and direction are read from the text. Without ``device=True``
-nothing is lowered and nothing compiled.
+part and direction are read from the text; beside the table, where a
+search compiled the model, stand the native simulator's ``prices`` of
+the strategy that ran, by the table's parts and directions (one
+replay, obs/simtrace.py). Without ``device=True`` nothing is lowered,
+compiled or replayed.
 """
 
 from __future__ import annotations
@@ -58,15 +62,16 @@ def session_tracer() -> Optional[StepTracer]:
     return _SESSION.tracer if _SESSION is not None else None
 
 
-def step_keeper(executor):
+def step_keeper(ff):
     """``keep(step, args)`` if a session with ``device=True`` is open
-    and holds no train step of ``executor`` yet, else None: ``fit``'s
-    first dispatch hands it the jitted step and the call's arguments,
-    of which the shapes and shardings are kept for the join table."""
+    and holds no train step of ``ff``'s executor yet, else None:
+    ``fit``'s first dispatch hands it the jitted step and the call's
+    arguments, of which the shapes and shardings are kept for the join
+    table, and the model, whose executed strategy is priced beside it."""
     scopes = _SESSION.step_scopes if _SESSION is not None else None
-    if scopes is None or not scopes.wants(executor):
+    if scopes is None or not scopes.wants(ff.executor):
         return None
-    return functools.partial(scopes.keep, executor)
+    return functools.partial(scopes.keep, ff)
 
 
 # ---------------------------------------------------------------------------
@@ -142,6 +147,29 @@ def annotation_starts_us(xplane_path: str, name: str = TIE_ANNOTATION
     return sorted(out)
 
 
+def device_peaks() -> Dict[str, Optional[int]]:
+    """The allocator's peak on the fullest local device, for a session's
+    header: ``device_peak_bytes`` is ``peak_bytes_in_use`` plus
+    ``peak_bytes_reserved`` of ``memory_stats()`` (the TPU runtime keeps
+    a loaded program's scratch memory in the reserved region, which
+    ``bytes_in_use`` leaves out), ``device_peak_bytes_in_use`` the first
+    part alone. What the search's predicted memory is held against: did
+    the step fit, and by how much was it misjudged. None each on a
+    backend without the counters (the CPU)."""
+    import jax
+    best = None
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        if "peak_bytes_in_use" not in stats:
+            continue
+        in_use = int(stats["peak_bytes_in_use"])
+        peak = in_use + int(stats.get("peak_bytes_reserved", 0))
+        if best is None or peak > best[0]:
+            best = (peak, in_use)
+    return dict(device_peak_bytes=best and best[0],
+                device_peak_bytes_in_use=best and best[1])
+
+
 # ---------------------------------------------------------------------------
 
 
@@ -193,6 +221,7 @@ class TraceSession:
             if "step_scopes" in meta:
                 step_scopes = os.path.join(tracer.trace_dir,
                                            meta["step_scopes"])
+        tracer.set_meta(**device_peaks())
         paths: Dict[str, Optional[str]] = dict(tracer.export())
         paths["counters"] = get_registry().export(
             os.path.join(tracer.trace_dir,

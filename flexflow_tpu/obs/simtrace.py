@@ -92,32 +92,118 @@ def sim_lane_events(tasks: List[Dict[str, Any]],
     return events
 
 
+def task_seconds(task: Dict[str, Any]) -> float:
+    """A scheduled task's duration: per-chip seconds of the schedule."""
+    return max(0.0, float(task.get("finish", 0.0))
+               - float(task.get("start", 0.0)))
+
+
 def per_op_predicted(tasks: List[Dict[str, Any]]
                      ) -> Dict[int, Dict[str, float]]:
     """Node index -> priced seconds per term, aggregated from the
-    simulated schedule (fwd_s / bwd_s / comm_s / gradsync_s). Collective
-    census bytes accumulate under ``collective_bytes``."""
+    simulated schedule (fwd_s / bwd_s / comm_s / gradsync_s / update_s).
+    Collective census bytes accumulate under ``collective_bytes``. The
+    optimizer's ``update`` task belongs to no node (index -1): its
+    seconds stand under that key, the only negative one kept."""
     out: Dict[int, Dict[str, float]] = {}
     for t in tasks:
         node = t.get("node", -1)
-        if node is None or node < 0:
+        kind = str(t.get("kind", ""))
+        if node is None or (node < 0 and kind != "update"):
             continue
         row = out.setdefault(int(node), dict(
-            fwd_s=0.0, bwd_s=0.0, comm_s=0.0, gradsync_s=0.0,
+            fwd_s=0.0, bwd_s=0.0, comm_s=0.0, gradsync_s=0.0, update_s=0.0,
             hidden_s=0.0, collective_bytes=0.0))
-        dur = max(0.0, float(t.get("finish", 0.0))
-                  - float(t.get("start", 0.0)))
-        kind = str(t.get("kind", ""))
-        if kind in ("fwd", "bwd"):
-            row[f"{kind}_s"] += dur
-        elif kind == "comm":
-            row["comm_s"] += dur
-        elif kind == "gradsync":
-            row["gradsync_s"] += dur
+        if f"{kind}_s" in row:
+            row[f"{kind}_s"] += task_seconds(t)
         row["hidden_s"] += float(t.get("hidden_s", 0.0))
         if t.get("collective"):
             row["collective_bytes"] += float(t.get("bytes", 0.0))
     return out
+
+
+# a task's kind -> the direction of the join table (obs/step_scopes.py)
+# its seconds are compared with
+DIRECTION_OF_KIND = {"fwd": "forward", "bwd": "backward",
+                     "update": "optimizer"}
+COLLECTIVES = "collectives"    # the part of every `comm` / `gradsync` task
+
+
+def prices_by_part(ff, resp: Dict[str, Any]) -> List[List[Any]]:
+    """The replayed schedule's seconds as the join table of the compiled
+    step cuts the device's: rows ``[part, direction, seconds, ops]``
+    (``ops``: the tasks added up, one an op and direction),
+    ``part`` by ``GraphExecutor.part_of_node`` (the rule the step's
+    ``op_name``s are read back with) and ``direction`` ``forward`` /
+    ``backward`` for the ``fwd`` / ``bwd`` tasks, ``optimizer`` under
+    the part ``optimizer_update`` for the ``update`` tasks (which
+    belong to no node). The ``comm`` and ``gradsync`` tasks stand under
+    the part ``collectives`` with their kind as the direction and a
+    fifth field, the seconds the schedule hid under compute. Seconds
+    are the schedule's per-chip durations, as ``corpus_rows`` documents;
+    a pipe mesh's replay returns census records of no duration and so
+    no seconds here."""
+    nodes = ff.executor.nodes
+    rows: Dict[Any, List[float]] = {}
+    for t in resp.get("tasks") or []:
+        kind, node = str(t.get("kind", "")), t.get("node", -1)
+        if kind in SIM_COMMS_KINDS:
+            key = (COLLECTIVES, kind)
+        elif kind == "update":
+            key = ("optimizer_update", DIRECTION_OF_KIND[kind])
+        elif kind in DIRECTION_OF_KIND and 0 <= node < len(nodes):
+            key = (ff.executor.part_of_node(nodes[node]),
+                   DIRECTION_OF_KIND[kind])
+        else:
+            continue
+        row = rows.setdefault(key, [0.0, 0, 0.0])
+        row[0] += task_seconds(t)
+        row[1] += 1
+        row[2] += float(t.get("hidden_s", 0.0))
+    return [[part, direction, seconds, count]
+            + ([hidden] if part == COLLECTIVES else [])
+            for (part, direction), (seconds, count, hidden) in rows.items()]
+
+
+def predicted_totals(resp: Dict[str, Any]) -> Dict[str, Any]:
+    """The replay's own totals, seconds a step and bytes a chip."""
+    return dict(
+        step_s=resp.get("iteration_time"),
+        fwd_s=resp.get("fwd_time"),
+        bwd_s=resp.get("bwd_time"),
+        comm_s=resp.get("comm_time"),
+        gradsync_s=resp.get("gradsync_time"),
+        # predicted comm seconds hidden under compute (the schedule's
+        # overlapped intervals + the '_ovl'/pipeline analytic hidden
+        # terms) — the predicted twin of the devtrace's measured
+        # overlapped_comms_s
+        hidden_comm_s=resp.get("hidden_comm_time"),
+        memory_bytes=resp.get("memory"))
+
+
+def step_prices(ff, resp: Dict[str, Any]) -> Dict[str, Any]:
+    """The ``prices`` object of a session's ``<stem>.step_scopes.json``:
+    what the cost model says of the strategy that ran, in the terms the
+    device trace of the same step is read in (``benchmarks/
+    step_prices.py`` joins the two). ``memory_bytes`` is the replayed
+    strategy's, ``search_predicted_*`` what the search itself returned
+    for the strategy it chose (they differ where the executor runs
+    another choice than the search priced: ``ExecPlan.executed_choice``)."""
+    from flexflow_tpu.obs.inspect import search_predictions
+
+    census: Dict[str, int] = {}
+    for source in (resp.get("cost_sources") or {}).values():
+        census[source] = census.get(source, 0) + 1
+    searched = search_predictions(ff)
+    return dict(
+        predicted_totals(resp),
+        update_s=sum(task_seconds(t) for t in resp.get("tasks") or []
+                     if t.get("kind") == "update"),
+        search_predicted_s=searched["search_predicted_step_s"],
+        search_predicted_memory_bytes=searched[
+            "search_predicted_memory_bytes"],
+        cost_sources=census,
+        by_part=prices_by_part(ff, resp))
 
 
 def corpus_rows(ff, resp: Dict[str, Any],
@@ -204,6 +290,8 @@ def simtrace_report(ff, resp: Dict[str, Any],
     learned per-op costs, the analytic twin rides along so the obs
     report can show simulator accuracy analytic-vs-learned side by side
     (the SCALE-Sim-style tracked metric)."""
+    from flexflow_tpu.obs.inspect import search_predictions
+
     rows = corpus_rows(ff, resp, measured=measured)
     src_census: Dict[str, int] = {}
     for r in rows:
@@ -211,21 +299,9 @@ def simtrace_report(ff, resp: Dict[str, Any],
         src_census[s] = src_census.get(s, 0) + 1
     report = dict(
         corpus_schema=CORPUS_SCHEMA_VERSION,
-        predicted=dict(
-            step_s=resp.get("iteration_time"),
-            fwd_s=resp.get("fwd_time"),
-            bwd_s=resp.get("bwd_time"),
-            comm_s=resp.get("comm_time"),
-            gradsync_s=resp.get("gradsync_time"),
-            # predicted comm seconds hidden under compute (the schedule's
-            # overlapped intervals + the '_ovl'/pipeline analytic hidden
-            # terms) — the predicted twin of the devtrace's measured
-            # overlapped_comms_s
-            hidden_comm_s=resp.get("hidden_comm_time"),
-            memory_bytes=resp.get("memory"),
-        ),
-        search_predicted_s=(ff.search_info or {}).get("predicted_time")
-        if isinstance(ff.search_info, dict) else None,
+        predicted=predicted_totals(resp),
+        search_predicted_s=search_predictions(ff)[
+            "search_predicted_step_s"],
         mesh_axes=dict(zip(ff.mesh.axis_names, ff.mesh.devices.shape)),
         tasks=sum(1 for t in (resp.get("tasks") or [])
                   if float(t.get("finish", 0.0))

@@ -54,7 +54,10 @@ _JIT = re.compile(r"jit\(([\w.\-]+)\)")
 _NAME = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
-_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) [^=]*\{\s*$")
+# a computation's header, `name (parameters) -> results {`: a tuple of six
+# results or more holds `/*index=5*/` (the ENTRY's among them), so nothing
+# here may stop at an `=`
+COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$")
 _PAYLOAD = re.compile(r'"body":"([A-Za-z0-9+/=]+)"')
 _REFERENCE = re.compile(r"(\w+=)?%([\w.\-]+)")
 # how far a part is looked for beyond an instruction that has none
@@ -149,7 +152,7 @@ def table_of(hlo_text: str) -> Dict[str, Dict[str, Any]]:
     for line in hlo_text.splitlines():
         m = _NAME.match(line)
         if m is None:
-            c = _COMPUTATION.match(line)
+            c = COMPUTATION.match(line)
             if c is not None:
                 computation = c.group(1)
             continue
@@ -224,17 +227,17 @@ class StepScopes:
     its `fit` calls dispatch, and the tables written from it."""
 
     def __init__(self):
-        # executor id -> (jitted step, abstract arguments), in order
-        self._steps: Dict[int, Tuple[Any, Any]] = {}
+        # executor id -> (model, jitted step, abstract arguments), in order
+        self._steps: Dict[int, Tuple[Any, Any, Any]] = {}
 
     def wants(self, executor) -> bool:
         return id(executor) not in self._steps
 
-    def keep(self, executor, step, args) -> None:
+    def keep(self, ff, step, args) -> None:
         """Once a session and executor, before the call (it donates)."""
         if hasattr(step, "lower"):
-            self._steps.setdefault(id(executor),
-                                   (step, abstract_args(args)))
+            self._steps.setdefault(id(ff.executor),
+                                   (ff, step, abstract_args(args)))
 
     def write(self, trace_dir: str, file_stem: str,
               host_id: Optional[int] = None) -> Dict[str, Any]:
@@ -242,9 +245,12 @@ class StepScopes:
         write `<stem>.step_scopes.json` (a second executor's step
         `<stem>.step_scopes.1.json`, ...) and return the header fields:
         the first table's path, what the lowering cost, how many
-        instructions the table holds. The profiler has stopped."""
+        instructions the table holds. Beside `instructions` the file
+        holds `prices`: what the native simulator says of the strategy
+        that ran (`priced_step`), and what that replay cost on the host
+        (`step_prices_s`). The profiler has stopped."""
         meta: Dict[str, Any] = {}
-        for k, (step, args) in enumerate(self._steps.values()):
+        for k, (ff, step, args) in enumerate(self._steps.values()):
             t0 = time.perf_counter()
             try:
                 text = step.lower(*args).compile().as_text()
@@ -253,16 +259,39 @@ class StepScopes:
                 meta.setdefault("step_scopes_error", repr(e))
                 continue
             seconds = time.perf_counter() - t0
+            payload, header = dict(instructions=table), dict(
+                step_scopes_s=seconds, step_scopes_instructions=len(table))
+            prices, priced = priced_step(ff)
+            header.update(priced)
+            if prices is not None:
+                payload["prices"] = prices
             path = os.path.join(trace_dir, file_stem + (
                 SUFFIX if k == 0 else f".step_scopes.{k}.json"))
-            write_artifact(
-                path, dict(instructions=table), host_id=host_id,
-                kind="step_scopes", indent=None,
-                header_extra=dict(step_scopes_s=seconds,
-                                  step_scopes_instructions=len(table)))
+            write_artifact(path, payload, host_id=host_id,
+                           kind="step_scopes", indent=None,
+                           header_extra=header)
             if "step_scopes" not in meta:
-                meta.update(step_scopes=os.path.basename(path),
-                            step_scopes_s=seconds,
-                            step_scopes_instructions=len(table))
+                meta.update(step_scopes=os.path.basename(path), **header)
         self._steps.clear()
         return meta
+
+
+def priced_step(ff) -> Tuple[Optional[Dict[str, Any]], Dict[str, Any]]:
+    """(the `prices` object of `ff`'s executed strategy, the header
+    fields that say what it cost): ONE replay through the native
+    simulator (`search.validate.simulate_strategy`), read by
+    `obs.simtrace.step_prices`. A model that no search compiled has no
+    prices to hold against the chip: (None, {}). A replay that fails
+    leaves `step_prices_error` and no prices, and every reader then
+    returns nothing, as for a missing table."""
+    if not isinstance(ff.search_info, dict):
+        return None, {}
+    from flexflow_tpu.obs.simtrace import step_prices
+    from flexflow_tpu.search.validate import simulate_strategy
+
+    t0 = time.perf_counter()
+    try:
+        prices = step_prices(ff, simulate_strategy(ff))
+    except Exception as e:
+        return None, dict(step_prices_error=repr(e))
+    return prices, dict(step_prices_s=time.perf_counter() - t0)

@@ -357,7 +357,8 @@ class MultiHeadAttention(Op):
     route and decide nothing.
     """
 
-    scopes_itself = True
+    # the stem of the op's own scope: `forward` adds the route's kind
+    scopes_itself = "attention_"
     # the gate's product is float32, as a router's
     full_precision_params = ("w_gate",)
 
@@ -770,7 +771,7 @@ class MultiHeadAttention(Op):
             self._kernel_fallback = route.fallback
         if route.scope == "plain":
             return self._forward(params, inputs, ctx, rng, route)
-        return scoped("attention_" + route.scope,
+        return scoped(self.scopes_itself + route.scope,
                       lambda params, inputs: self._forward(
                           params, inputs, ctx, rng, route))(params, inputs)
 
@@ -781,7 +782,7 @@ class MultiHeadAttention(Op):
         `tpu_custom_call*` (what `kernels.flash_roofline` sums): its
         scope `attention_plain` lies ``around`` every piece but a flash
         kernel call, not over the op."""
-        around = (functools.partial(scoped, "attention_plain")
+        around = (functools.partial(scoped, self.scopes_itself + "plain")
                   if route.scope == "plain" else lambda fn: fn)
         q, k, v, rope = around(lambda params, inputs: (
             self._qkv_latent(params, inputs, ctx) if self.latent

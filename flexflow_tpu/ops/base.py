@@ -115,9 +115,9 @@ class Op:
     op_type: OperatorType = OperatorType.NOOP
     # parameter names the executor keeps in float32 in the compute copy
     full_precision_params: Tuple[str, ...] = ()
-    # the op's forward names its own nested calls (`scoped`); the
-    # executor wraps every other op in one named for its kind
-    scopes_itself: bool = False
+    # the nested call (`scoped`) the op's forward makes around itself;
+    # "": the executor wraps the op in one named for its kind
+    scopes_itself: str = ""
 
     def __init_subclass__(cls, **kw):
         super().__init_subclass__(**kw)

@@ -182,7 +182,7 @@ class MoELayer(Op):
     moves the choice of every token alike.
     """
 
-    scopes_itself = True
+    scopes_itself = "moe_layer"
 
     full_precision_params = ("w_router", "e_bias")
 
@@ -338,7 +338,8 @@ class MoELayer(Op):
         # `tokens_from_rows`, for the combine and for the dispatch's
         # backward, sums the rows by the kernel
         self._sum_rows = sums_rows_by_kernel(rows, d, b * s, self.k)
-        y, load, overflow = scoped("moe_layer", layer)(params, x, x_router)
+        y, load, overflow = scoped(self.scopes_itself, layer)(
+            params, x, x_router)
         load = load.astype(jnp.float32)
         self._counters = {
             "moe/slots_held": ("sum", jnp.sum(load)),

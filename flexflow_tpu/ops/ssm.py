@@ -164,7 +164,7 @@ class SSMMixer(Op):
     (dt_bias, a_log, d) stay float32 in the compute copy: they set every
     position's decay."""
 
-    scopes_itself = True
+    scopes_itself = "ssm_mixer"
 
     full_precision_params = ("dt_bias", "a_log", "d")
 
@@ -247,7 +247,7 @@ class SSMMixer(Op):
                               params["w_out"].astype(cd),
                               preferred_element_type=jnp.float32)
 
-        return [scoped("ssm_mixer", mixer)(params, x).astype(x.dtype)]
+        return [scoped(self.scopes_itself, mixer)(params, x).astype(x.dtype)]
 
     def output_dim_roles(self):
         # the sequence dim recurs: not position-independent, so no SEQ role

@@ -244,6 +244,47 @@ def test_a_fusions_body_and_the_compilers_own_copies():
     assert "feeds" not in table["fusion.5"]
 
 
+SIX_RESULTS = """HloModule jit_train_step
+
+%fused_computation.1 (p.1: f32[8]) -> f32[8] {
+  %p.1 = f32[8]{0} parameter(0)
+  ROOT %mul.3 = f32[8]{0} multiply(%p.1, %p.1), metadata={op_name="jit(train_step)/jvp(jit(op_linear))/mul"}
+}
+
+%fused_computation.2 (p.2: f32[8]) -> (f32[8], f32[8], f32[8], f32[8], f32[8], /*index=5*/f32[8]) {
+  %p.2 = f32[8]{0} parameter(0)
+  %add.4 = f32[8]{0} add(%p.2, %p.2), metadata={op_name="jit(train_step)/jit(optimizer_update)/add"}
+  ROOT %tuple.5 = (f32[8]{0}, f32[8]{0}, f32[8]{0}, f32[8]{0}, f32[8]{0}, /*index=5*/f32[8]{0}) tuple(%add.4, %add.4, %add.4, %add.4, %add.4, /*index=5*/%add.4)
+}
+
+ENTRY %main.9 (w.1: f32[8]) -> (f32[8], f32[8], f32[8], f32[8], f32[8], /*index=5*/f32[8]) {
+  %w.1 = f32[8]{0} parameter(0)
+  %fusion.6 = f32[8]{0} fusion(%w.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/jvp(jit(op_linear))/mul"}
+  ROOT %fusion.7 = (f32[8]{0}, f32[8]{0}, f32[8]{0}, f32[8]{0}, f32[8]{0}, /*index=5*/f32[8]{0}) fusion(%fusion.6), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(train_step)/jit(optimizer_update)/add"}
+}
+"""
+
+
+def test_a_header_of_six_results_opens_its_computation():
+    """A tuple of six results or more holds `/*index=5*/`: a header
+    pattern that stops at the first `=` files the computation's
+    instructions under the one before (38 of the 1,403 headers of
+    nemotron's step, ENTRY among them, PR 50), and a fusion's `parts`
+    then list another body."""
+    for line, name in (
+            ("%fused_computation.2 (p.2: f32[8]) -> (f32[8], /*index=5*/"
+             "f32[8]) {", "fused_computation.2"),
+            ("ENTRY %main.9 (w.1: f32[8]) -> f32[8] {", "main.9"),
+            ("region_0.5 (a: f32[], b: f32[]) -> f32[] { ", "region_0.5")):
+        assert ss.COMPUTATION.match(line).group(1) == name
+    assert ss.COMPUTATION.match(
+        "  %add.4 = f32[8]{0} add(%p.2, %p.2)") is None
+    table = ss.table_of(SIX_RESULTS)
+    assert table["fusion.6"]["parts"] == {"op_linear": 1}
+    assert table["fusion.7"]["parts"] == {"optimizer_update": 1}
+    assert table["fusion.7"]["directions"] == {"optimizer": 1}
+
+
 class TestTheRule:
     def test_outermost_program_scope_never_a_bare_substring(self):
         assert ss.part_of(

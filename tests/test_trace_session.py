@@ -240,13 +240,14 @@ def test_stop_trace_with_the_profiler_lowers_the_step_once_after_it(
     ff.fit(x, y, epochs=1, verbose=False)
     kept = dict(session.step_scopes._steps)
     assert list(kept) == [id(ff.executor)]
-    step, args = kept[id(ff.executor)]
+    model_kept, step, args = kept[id(ff.executor)]
+    assert model_kept is ff     # whose executed strategy is priced
     assert step is ff.executor.make_train_step()
     # shapes only: nothing of the call's arrays stays alive
     assert all(isinstance(a, jax.ShapeDtypeStruct)
                for a in jax.tree.leaves(args))
     ff.fit(x, y, epochs=1, verbose=False)
-    assert session.step_scopes._steps[id(ff.executor)][1] is args
+    assert session.step_scopes._steps[id(ff.executor)][2] is args
     jits = obs.get_registry().get("executor.train_step_jits")
     monkeypatch.setattr(
         type(session.step_scopes), "write", functools.partialmethod(
@@ -634,7 +635,8 @@ CONTEXT_KEYS = [
     "latent_attention_ops", "layer_applications",
     "loss_own_vjp", "loss_target_positions", "mesh_axes",
     "moe_spread_rows_ops", "moe_sum_rows_ops", "num_ops",
-    "rotary_lane_dense_ops",
+    "rotary_lane_dense_ops", "search_predicted_memory_bytes",
+    "search_predicted_step_s",
     "set_parameter_s", "shared_leaves", "shared_weight_ops",
     "window_attention_ops"]
 
