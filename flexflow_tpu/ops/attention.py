@@ -289,6 +289,9 @@ class AttentionRoute:
     # ONE tile, no chunk loop (`pallas_kernels.one_span`: a causal
     # window narrower than a K chunk at S past the whole-tile kernels)
     one_span: bool = False
+    # the chunk-loop flash kernels take several blocks a grid step
+    # (`pallas_kernels.super_block`)
+    super_block: bool = False
     # of the flash forward, a head: (visited, total, masked) K blocks,
     # and under a window that hides something (the (query, key) pairs in
     # the tiles the kernels work through, forward and backward; twice the
@@ -690,6 +693,7 @@ class MultiHeadAttention(Op):
         return AttentionRoute(
             core, blocked, fallback, scope, shard_axes, grouped_kv,
             rotary_in_lanes, one_span=pk.one_span(*kind) is not None,
+            super_block=pk.super_block_engaged(*kind),
             kv_blocks=(*pk.kv_blocks(*kind), pk.kv_blocks_masked(*kind)),
             window_pairs=(
                 (pk.visited_pairs(*kind),
@@ -716,7 +720,9 @@ class MultiHeadAttention(Op):
         the KV heads (PR 43; at heads of 64 through a half of a lane
         block since PR 47); `executor.flash_one_span_ops`: the blocked
         flash kernels took a block's reachable positions as one tile
-        (PR 46); `executor.window_attention_ops` (the window
+        (PR 46); `executor.flash_super_block_ops`: the chunk-loop flash
+        kernels took more than one block a grid step (PR 51);
+        `executor.window_attention_ops` (the window
         hides something at this length, PR 31), `executor.
         block_diffusion_attention_ops` (PR 34), `executor.
         latent_attention_ops` (PR 39); `attention/kv_blocks_*`: the
@@ -734,6 +740,7 @@ class MultiHeadAttention(Op):
             "executor.rotary_lane_dense_ops": int(route.rotary_in_lanes),
             "executor.flash_grouped_kv_ops": int(route.grouped_kv),
             "executor.flash_one_span_ops": int(route.one_span),
+            "executor.flash_super_block_ops": int(route.super_block),
             "executor.window_attention_ops": int(self.windowed),
             "executor.block_diffusion_attention_ops": int(
                 bool(self.block_diffusion)),

@@ -37,7 +37,13 @@ window so narrow that a block's reach fits one score tile (PR 46;
 there is no chunk loop at all: a Q block meets the keys it reaches, a K
 block the queries that see it, as ONE masked tile each, the plain
 softmax in straight-line code (``_flash_fwd_span_kernel``,
-``_flash_bwd_span_kernel``).
+``_flash_bwd_span_kernel``). In the chunk loop a forward grid step
+takes the Q blocks of one K chunk together (PR 51; ``super_block``), as
+independent chains of one loop body, and of a chunk that holds a
+block's own positions, or lies a whole window from them, a sub-tile
+(a sub-block of the backward's K block) takes the static slice it can
+see any of (``_key_trim``, ``_query_trim``): 1.06 visited pairs a
+visible one at 4,096 positions where whole tiles visit 1.25.
 
 One operand form (PR 30): q, k, v, o and their gradients are
 [B, S, H*D], the heads side by side along the lanes, which is what the
@@ -270,7 +276,13 @@ def _seq_block(s: int, block_diffusion=None, window: int = 0) -> int:
     taller tile. The upper end of the rule, a window of 768 (spans 1024
     and 896): chunk loop (chunks of 512) 4.26 / 7.63; 256 / 1024 2.77 /
     5.49 at 1 x 1, 2.22 / 3.89 at 32 x 4; 128 / 896 2.55 / 3.70 at
-    64 x 4; as shipped 2.22 / 3.70."""
+    64 x 4; as shipped 2.22 / 3.70.
+
+    Since PR 51 the chunk loop's own blocks a grid step follow
+    ``super_block``: the forward takes the Q blocks of one K chunk
+    together, and both directions take of a chunk at their own
+    positions the part a sub-tile can see (that lab's table is in
+    ``super_block``'s docstring)."""
     if block_diffusion is not None:
         s = block_diffusion[0]
     most = max(window, 2 * BLK_Q) if 0 < window < 1024 else 1024
@@ -285,7 +297,9 @@ def _q_block(s: int, block_diffusion=None) -> int:
     3.18 / 2.38 / 2.30, full causal 6.24 / 4.40 / 4.22, S = 8192 causal
     1.00 / 0.75 / 0.74: a chunk's fixed cost (the loop, the rescaling of
     the running sums) is paid half as often. Under ``block_diffusion``
-    the block divides L."""
+    the block divides L. Since PR 51 a grid step takes the Q blocks of
+    one K chunk together (``super_block``, whose docstring has that
+    lab's table)."""
     if block_diffusion is not None:
         s = block_diffusion[0]
     return 2 * BLK_Q if s % (2 * BLK_Q) == 0 else BLK_Q
@@ -367,6 +381,197 @@ def _span_tiles(s: int, blk: int):
     docstring."""
     return next(n for n in (64, 32, 16, 8, 4, 2, 1)
                 if n * blk * s <= 1 << 26 and s % (n * blk) == 0), 4
+
+
+def _kept(trim, n: int, parts: int):
+    """[lo, hi) of the ``parts`` equal parts of a chunk that part ``n``
+    of a block of as many parts meets under ``trim``: the whole chunk
+    (None); the parts up to its own ("upto": the keys of a diagonal
+    chunk that lie behind a sub-tile's last query; the queries of a far
+    chunk that still see a K sub-block); the parts from its own on
+    ("from": the transpose of those); its own part alone ("own": the
+    noised diagonal of the block-diffusion mask). In the parts left out
+    no pair is visible. The kernels slice by it and ``visited_pairs``
+    counts by it."""
+    return {None: (0, parts), "upto": (0, n + 1), "from": (n, parts),
+            "own": (n, n + 1)}[trim]
+
+
+def _key_trim(n: int, of: int, peeled: bool, window: int, blk_k: int,
+              block_diffusion):
+    """``_kept``'s ``trim`` for sub-range ``n`` of the ``of`` that
+    ``_k_split`` cut (in ITS order: who hands the kernels another cut
+    holds ``super_block`` to one block), for a forward super-block that IS a
+    K chunk and whose last sub-range is one chunk (``peeled``). That
+    last chunk, the diagonal (under ``block_diffusion`` the last clean
+    one), holds no visible key past a sub-tile's own last query. Under a
+    window of whole chunks the first sub-range is the ONE far chunk,
+    whose keys ahead of a sub-tile's first query lie more than the
+    window behind it. Under ``block_diffusion`` (B | BLK_Q, which
+    ``peeled`` says) the first is the noised chunk at the super-block's
+    own positions, of which a sub-tile sees its own blocks of B."""
+    if not peeled:
+        return None
+    if n == of - 1:
+        return "upto"
+    if n == 0 and block_diffusion is not None:
+        return "own"
+    if n == 0 and window and window % blk_k == 0:
+        return "from"
+    return None
+
+
+def _query_trim(n: int, causal: bool, window: int, blk: int, sub: int,
+                block_diffusion):
+    """``_kept``'s ``trim`` for sub-range ``n`` of ``_q_split`` (in its
+    order), for a K block in sub-blocks of ``sub`` keys: the transpose
+    of ``_key_trim``. Under ``causal`` the first sub-range is the ONE
+    chunk at the K block's own positions, hidden from the queries ahead
+    of a sub-block's first key; under a window of whole chunks the third
+    is the ONE far chunk, whose queries past a sub-block's last key no
+    longer see it. Under ``block_diffusion`` (B | sub) the first and the
+    third are the noised and the clean chunk at the K block's own
+    positions, both hidden from the queries ahead of a sub-block's first
+    key; whether the block is a noised one, whose own chunk ends with
+    the sub-block too, is not known where the kernel is traced."""
+    if block_diffusion is not None:
+        b = block_diffusion[1]
+        return "from" if n in (0, 2) and b < blk and sub % b == 0 else None
+    if not causal:
+        return None
+    if n == 0:
+        return "from"
+    return "upto" if n == 2 and window % blk == 0 else None
+
+
+def _union_is_each(s: int, window: int, block_diffusion, chains: int) -> bool:
+    """Whether a super-block of ``chains`` Q blocks loops over the very
+    sub-ranges each of its Q blocks would alone (``_k_split`` of the
+    union against ``_k_split`` of every block, by the kernel's own
+    lines): then no Q block meets a chunk it sees nothing of, no tile
+    changes its class, and every count of tiles stands. True for a
+    super-block that IS a K chunk under full causal, under a window of
+    whole chunks (smallthinker's 4096: the far edge is chunk a - 4 and
+    ``near`` a - 3 for every Q block of chunk a) and under
+    ``block_diffusion`` with B | BLK_Q; not under a window that ends
+    inside a chunk, where the later Q blocks of a super-block have
+    left the first one's far chunk behind."""
+    blk_q = _q_block(s, block_diffusion)
+    blk_k = _seq_block(s, block_diffusion, window)
+    causal = block_diffusion is None   # without a mask nothing is cut
+    rows = chains * blk_q
+    return s % rows == 0 and all(
+        _k_split(q0, blk_q, blk_k, s, causal, window, block_diffusion)
+        == _k_split(first, rows, blk_k, s, causal, window, block_diffusion)
+        for first in range(0, s, rows)
+        for q0 in range(first, first + rows, blk_q))
+
+
+def super_block(s: int, window: int = 0, block_diffusion=None):
+    """THE rule of the chunk-loop kernels' blocks a grid step (PR 51):
+    ((Q blocks a grid step, Q blocks a loop iteration, whether their
+    sub-tiles are trimmed), K sub-blocks of a trimmed chunk of the
+    backward). A function of what the code can observe, (S, window,
+    block_diffusion) as ``_flash_fwd`` normalized them, and of nothing
+    else: `_flash_fwd`, `_flash_bwd`, ``visited_pairs`` and the op's
+    route (``super_block_engaged``) ask it, as they ask ``one_span``.
+
+    The forward's grid step takes a SUPER-BLOCK, the Q blocks of
+    ``_q_block`` rows that make up one K chunk (4 x 256 = 1024; 2 at
+    chunks of 512), where ``_union_is_each`` holds. Its chunk loops are
+    one Q block's; an iteration loads the K / V chunk once and runs the
+    Q blocks' [256, 1024] tiles against it, each with its own (max, sum,
+    accumulator): independent chains, one's MXU waits filled with the
+    next one's VPU passes. And because the super-block is aligned to
+    the chunks, what a Q block sees of a chunk at the super-block's own
+    positions (or a whole window behind them) is known where the kernel
+    is traced: a sub-tile takes the STATIC slice of keys it sees any of
+    (``_key_trim``), 10 of a diagonal chunk's 16 squares of 256 x 256.
+    The backward's grid step is a K block as before; its chunk at the
+    block's own positions (and a whole window ahead) runs as the K
+    block's sub-blocks of ``_q_block`` keys, each against the slice of
+    queries that see any of it (``_query_trim``), with the chunk's
+    Q^T, dO^T and delta formed once and dQ's rows written once.
+
+    v5e, bf16, kernels alone, ms an op (`scripts/flash_lab.py
+    --programs super_block`, PR 51, three runs that agree to 0.002), at
+    the cells' shapes: ouro 16 heads of 128, S 4096 | laguna 48, 8192 |
+    joyai 32 of 128 + 64, 4096 | nemotron 4, 8192 | smallthinker 7,
+    16384, full | the same, window 4096 | sdar 8, 2L 16384, B 4 | lfm2
+    32 : 8 heads of 64, 16384. FORWARD, Q blocks a grid step x Q blocks
+    a loop iteration, sub-tiles whole:
+    1 x 1 (the parent) 0.712 | 7.215 | 1.918 | 0.608 | 3.841 | 2.205 |
+    2.830 | 16.706;
+    2 x 2  0.683 | 6.884 | 1.833 | 0.580 | 3.648 | 2.101 | 2.653 | 16.213;
+    4 x 4  0.676 | 6.729 | 1.801 | 0.567 | 3.533 | 2.054 | 2.603 | 16.307;
+    4 x 2 (two super-blocks of 512 rows a step, in a loop that carries
+    nothing) 0.680 | 6.856 | 1.820 | 0.578 | 3.631 | 2.086 | 2.650 | 16.207;
+    4 x 1  0.709 | 7.196 | 1.891 | 0.606 | 3.834 | 2.152 | 2.785 | 16.656;
+    16 x 1 0.707 | 7.181 | 1.888 | 0.607 | 3.833 | 2.156 | 2.781 | 16.640;
+    8 x 4  0.676 | 6.726 | 1.794 | 0.568 | 3.535 | 2.054 | 2.604 | 16.310;
+    16 x 4 0.676 | 6.722 | 1.794 | 0.568 | 3.535 | 2.053 | 2.603 | 16.305;
+    a head a step (16 / 32 / 64 x 4) - | 6.720 | - | 0.571 | 3.542 |
+    2.062 | 2.605 | 16.309;
+    4 x 4 TRIMMED (**as shipped**) **0.622 | 6.416 | 1.599 | 0.541 |
+    3.449 | 1.875 | 2.413 | 15.870**;
+    16 x 4 trimmed 0.620 | 6.378 | 1.593 | 0.540 | 3.431 | 1.873 | 2.402
+    | 15.840.
+    BACKWARD, the chunk at the K block's own positions (under a window
+    the far one too; under block diffusion the noised and the clean one)
+    in sub-blocks, each against the queries that see it:
+    x 1 (the parent) 1.273 | 13.444 | 3.811 | 1.119 | 7.312 | 3.888 |
+    4.966 | 22.566;
+    x 2  1.154 | 12.925 | 3.474 | 1.066 | 7.123 | 3.586 | 4.697 | 22.040;
+    x 4 (**as shipped**) **1.109 | 12.703 | 3.305 | 1.043 | 7.040 |
+    3.444 | 4.602 | 21.804**.
+    Rows of the first run that are not the shipped kernel's, a backward
+    whose EVERY chunk ran as sub-blocks (the candidate of ISSUE 51: the
+    chunk's three transposes and delta formed once, dQ written once,
+    the sub-blocks' products independent): whole, x 2 1.269 | 13.385 |
+    3.849 | 1.114 | 7.275 | 3.883 | 4.962 | 22.714, x 4 1.269 | 13.406 |
+    3.826 | 1.116 | 7.292 | 3.892 | 4.968 | 22.902: within 0.5% of the
+    parent and 1.5% SLOWER at heads of 64: a measured no; with the own
+    chunk trimmed besides, x 4 1.109 | 12.687 | 3.312 | 1.041 | 7.026 |
+    3.648 (no far chunk yet) | - | 22.065: what the trimming gives, and
+    at heads of 64 less than with the other chunks left whole.
+
+    What the table says. A grid step's own cost is nothing here (4 x 1,
+    16 x 1 against 1 x 1: a step already holds 1-16 chunks); several
+    super-blocks a step give 0.0-0.6% (16 x 4: one ships). Independent
+    chains give the forward 5-8% (1 x 1 -> 4 x 4), a third of what PR
+    46's one-span kernels gained from them: with a running softmax a
+    chain's rescaling still stands in line, and at two heads a lane
+    block (lfm2) four Q blocks are eight chains and no better than two
+    (2 x 2 16.213, 4 x 4 16.307). The larger part is the PAIRS: a
+    trimmed diagonal is 10 of 16 squares, which at S 4096 is 136 of 160
+    squares a head forward and backward (ouro -8.0% and -12.9%, joyai
+    -11.2% and -13.3%), at 16,384 full 2,080 of 2,176 (-2.4%, -3.7%);
+    under smallthinker's window the far chunk doubles it (-8.7%,
+    -11.4%); under sdar's mask the noised chunk is 4 of 16 squares
+    forward (-7.3%) and 10 of 16 backward (-7.3%; that a noised K
+    block's chunk also ENDS with the sub-block cannot be known where
+    the kernel is traced). Visited pairs a visible one, forward and
+    backward: 1.25 -> 1.06 at S 4096, 1.12 -> 1.03 at 8,192, 1.14 ->
+    1.06 under the window (``visited_pairs``). Neither the heads a lane
+    block nor the two-part score (``rope_dim``) changes which form
+    wins, so the rule does not ask them."""
+    rows = _q_block(s, block_diffusion)
+    chains = _seq_block(s, block_diffusion, window) // rows
+    aligned = _union_is_each(s, window, block_diffusion, chains)
+    forward = (chains, chains, True) if aligned else (1, 1, False)
+    return forward, chains
+
+
+def super_block_engaged(s: int, causal: bool, window: int = 0,
+                        block_diffusion=None, rope_dim: int = 0) -> bool:
+    """Whether the flash kernels of this op take the chunk loop with
+    more than one block a grid step (``super_block``): what the op's
+    route publishes as ``executor.flash_super_block_ops``."""
+    window = normalized_window(s, causal, window)
+    bd = checked_block_diffusion(s, causal, window, block_diffusion)
+    if s <= MAX_BWD_SEQ or one_span(s, causal, window, bd, rope_dim):
+        return False
+    return super_block(s, window, bd)[0][0] > 1
 
 
 # what a masked score is set to. Finite, so that a chunk of the blocked
@@ -672,7 +877,9 @@ def visited_pairs(s: int, causal: bool, window: int = 0,
     forward's [Q block, K chunk] tiles (``_k_split``) and the backward's
     [K block, Q chunk] tiles (``_q_split``), by the kernels' own ranges;
     under one span (``one_span``) a [rows, span] tile a Q block and
-    one a K block. Against twice the visible pairs it says how much of
+    one a K block; in the chunk loop less what ``super_block``'s
+    sub-tiles leave out of a tile (``_kept``: the kernels slice by it,
+    this counts by it). Against twice the visible pairs it says how much of
     the kernels' work the mask then throws away. The whole-tile kernels
     (S <= MAX_BWD_SEQ) hold the square, once each."""
     if s <= MAX_BWD_SEQ:
@@ -682,11 +889,28 @@ def visited_pairs(s: int, causal: bool, window: int = 0,
     one = one_span(s, causal, window, bd, rope_dim)
     if one is not None:
         return s * (one[0][1] + one[1][1])
-    cut, _ = _forward_tiles(s, causal, window, block_diffusion, rope_dim)
-    blk = _seq_block(s, bd, window)
-    forward = sum(hi - lo for lo, hi, _ in cut) * _q_block(s, bd) * blk
-    backward = sum(hi - lo for k0 in range(0, s, blk) for lo, hi, _ in
-                   _q_split(k0, blk, blk, s, causal, window, bd)) * blk * blk
+    blk_q, blk = _q_block(s, bd), _seq_block(s, bd, window)
+    (_, chains, trim), subs = super_block(s, window, bd)
+    rows, sub = chains * blk_q, blk // subs
+
+    def squares(trim, parts):   # of a [block, chunk] tile, in parts^2
+        return sum(hi - lo for lo, hi in (
+            _kept(trim, n, parts) for n in range(parts)))
+
+    forward = backward = 0
+    for first in range(0, s, rows):     # a forward grid step's loops
+        split, peeled = _k_split(first, rows, blk, s, causal, window, bd)
+        for n, (lo, hi, _) in enumerate(split):
+            kept = _key_trim(n, len(split), peeled, window, blk,
+                             bd) if trim else None
+            forward += (hi - lo) * squares(kept, chains) * blk_q * (
+                blk // chains)
+    for k0 in range(0, s, blk):         # a backward grid step's loops
+        for n, (lo, hi, _) in enumerate(
+                _q_split(k0, blk, blk, s, causal, window, bd)):
+            kept = _query_trim(n, causal, window, blk, sub,
+                               bd) if subs > 1 else None
+            backward += (hi - lo) * squares(kept, subs) * sub ** 2
     return forward + backward
 
 
@@ -833,18 +1057,37 @@ def _group_halves(xt, part, head_dim: int):
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
                       window: int, scale: float, blk_q: int, blk_k: int,
                       head_dim: int, block_diffusion=None,
-                      rope_dim: int = 0, group=None):
-    """One (batch row, column block, q-block) grid cell: q [1,BLK_Q,W]
-    against the K/V panels [1,S,W] resident in VMEM, in chunks of
-    ``blk_k`` keys with a running max and sum (the online softmax), and
-    ONLY the chunks that hold a key some query of the block sees
-    (``_k_chunks``): under ``causal`` those up to the diagonal, under a
-    window the few behind it, under ``block_diffusion`` the block's own
-    noised tile and then the clean chunks it sees. Each range runs as
-    ``_k_split``'s sub-ranges, one loop each: the body masks an edge
-    tile and builds no mask for an interior one. Scores never touch
-    HBM. Also emits the per-row logsumexp so the fused backward can
-    recompute P exactly. The forward of sequences past MAX_BWD_SEQ.
+                      rope_dim: int = 0, group=None, tiles: int = 1,
+                      chains: int = 1, trim: bool = False):
+    """One (batch row, column block, ``tiles`` Q blocks) grid cell: q
+    [1, tiles * BLK_Q, W] against the K/V panels [1,S,W] resident in
+    VMEM, in chunks of ``blk_k`` keys with a running max and sum (the
+    online softmax), and ONLY the chunks that hold a key some query
+    sees (``_k_chunks``): under ``causal`` those up to the diagonal,
+    under a window the few behind it, under ``block_diffusion`` the
+    block's own noised tile and then the clean chunks it sees. Each
+    range runs as ``_k_split``'s sub-ranges, one loop each: the body
+    masks an edge tile and builds no mask for an interior one. Scores
+    never touch HBM. Also emits the per-row logsumexp so the fused
+    backward can recompute P exactly. The forward of sequences past
+    MAX_BWD_SEQ.
+
+    A SUPER-BLOCK (PR 51; ``super_block`` is the rule and has the
+    lab's table): ``chains`` consecutive Q blocks share their chunk
+    loops, which run over the sub-ranges of their union (the rule takes
+    a super-block only where those are each Q block's own). An
+    iteration loads the K / V chunk (and the rotated key's) ONCE and
+    runs every Q block's [BLK_Q, blk_k] tile against it, each with its
+    own (max, sum, accumulator) carry and its own mask: chains x heads
+    a lane block independent chains in one loop body. With ``trim``,
+    where a super-block is one K chunk, a sub-tile takes of the chunks
+    ``_key_trim`` names the static slice of keys it sees any of. The
+    tile, the products, their operand dtypes, the float32 statistics
+    and the order of a row's chunks are one Q block's: without ``trim``
+    o and lse are that kernel's bit for bit, with it a row's sums run
+    over fewer keys whose terms were exact zeros. ``tiles`` > ``chains``:
+    several super-blocks a grid step in a loop that carries nothing
+    (the lab's 8 x 4, 16 x 4; one ships).
 
     The last sub-range, where the split says it is one chunk for every
     block (the diagonal; the last clean chunk), runs as straight-line
@@ -878,71 +1121,112 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
     (``grouped_kv_shape_legal``'s table)."""
     if rope_dim:
         qr_ref, kr_ref, o_ref, lse_ref = rest
-        qr = _rope_lanes(qr_ref[0], pl.program_id(1), rope_dim)
     else:
         o_ref, lse_ref = rest
     part = _kv_part(1, group)
-    q = q_ref[0]  # [BLK_Q, W]
-    heads = q.shape[-1] // head_dim
-    q0 = pl.program_id(2) * blk_q
-    split, last_is_one = _k_split(q0, blk_q, blk_k, k_ref.shape[1], causal,
-                                  window, block_diffusion)
-    if group:   # head h's lanes in its KV head's half, zeros in the other
-        qs = [_only_head(_half_moved(q, part == h, head_dim), part, head_dim)
-              for h in range(heads)]
+    # the grid's place, taken outside the loop over the super-blocks
+    head, step = pl.program_id(1), pl.program_id(2)
+    heads = q_ref.shape[-1] // head_dim
+    rows = chains * blk_q             # a super-block's
+    supers = tiles // chains          # super-blocks a grid step
+    # trimmed, a super-block IS a K chunk: where that chunk holds the
+    # super-block's own positions, or lies a whole window behind them, a
+    # sub-tile takes the static slice of keys it sees any of (`_key_trim`)
+    assert not trim or rows == blk_k, (rows, blk_k)
+
+    def super_block(i):
+        """The ``chains`` Q blocks from row ``i * rows`` of the step's
+        block on (``i`` a Python 0 where the step holds one), against the
+        chunks their union sees."""
+        first = (step * supers + i) * rows
+        split, last_is_one = _k_split(first, rows, blk_k, k_ref.shape[1],
+                                      causal, window, block_diffusion)
+        at = [pl.ds(i * rows + t * blk_q, blk_q) if isinstance(i, int)
+              else pl.ds(pl.multiple_of(i * rows + t * blk_q, blk_q), blk_q)
+              for t in range(chains)]
+        q0s = [first + t * blk_q for t in range(chains)]
+        qs = []
+        for t in range(chains):
+            q = q_ref[0, at[t], :]  # [BLK_Q, W]
+            if group:   # head h's lanes in its KV head's half, zeros beside
+                qs.append([_only_head(_half_moved(q, part == h, head_dim),
+                                      part, head_dim) for h in range(heads)])
+            else:
+                qs.append([_only_head(q, h, head_dim) for h in range(heads)])
+        if rope_dim:
+            qrs = [_rope_lanes(qr_ref[0, at[t], :], head, rope_dim)
+                   for t in range(chains)]
+
+        def seen(q0, k0, tile):
+            if block_diffusion is not None:
+                return _tile_visible(q0, k0, tile, 0, window,
+                                     block_diffusion)
+            return visible(
+                q0 + jax.lax.broadcasted_iota(jnp.int32, tile, 0),
+                k0 + jax.lax.broadcasted_iota(jnp.int32, tile, 1), window)
+
+        def chunk(c, carry, edge, kept=None):
+            k0 = pl.multiple_of(c * blk_k, blk_k)
+            k = k_ref[0, pl.ds(k0, blk_k), :]  # [BLK_K, W], once a chunk
+            v = v_ref[0, pl.ds(k0, blk_k), :]
+            kr = kr_ref[0, pl.ds(k0, blk_k), :] if rope_dim else None
+            out = []
+            for t in range(chains):
+                # the keys sub-tile t can see any of: a static slice
+                lo, hi = (blk_q * n for n in _kept(kept, t, chains)
+                          ) if kept else (0, blk_k)
+                whole = (lo, hi) == (0, blk_k)
+                kt, vt, krt = (k, v, kr) if whole else (
+                    k[lo:hi], v[lo:hi], kr[lo:hi] if rope_dim else None)
+                mask = seen(q0s[t], k0 + lo,
+                            (blk_q, hi - lo)) if edge else None
+                for h in range(heads):
+                    m, l, acc = carry[t * heads + h]
+                    s = _dot(qs[t][h], kt, _NT)
+                    if rope_dim:
+                        s = s + _dot(qrs[t], krt, _NT)
+                    s = s * scale
+                    if edge:
+                        s = jnp.where(mask, s, _MASKED)
+                    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                    alpha = jnp.exp(m - m_new)
+                    p = jnp.exp(s - m_new)
+                    l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+                    # P against every head of the block; the other heads'
+                    # lanes are dropped from the [BLK_Q, W] result at the end
+                    acc = alpha * acc + _dot(p.astype(vt.dtype), vt, _NN)
+                    out.append((m_new, l, acc))
+            return tuple(out)
+
+        carry = tuple(
+            (jnp.full((blk_q, 1), _MASKED, jnp.float32),
+             jnp.zeros((blk_q, 1), jnp.float32),
+             jnp.zeros((blk_q, q_ref.shape[-1]), jnp.float32))
+            for _ in range(chains * heads))
+        for n, (lo, hi, edge) in enumerate(split):
+            kept = _key_trim(n, len(split), last_is_one, window, blk_k,
+                             block_diffusion) if trim else None
+            if last_is_one and n == len(split) - 1:
+                carry = chunk(lo, carry, edge, kept)
+            else:
+                carry = jax.lax.fori_loop(lo, hi, functools.partial(
+                    chunk, edge=edge, kept=kept), carry)
+        for t in range(chains):
+            o = None
+            for h in range(heads):
+                m, l, acc = carry[t * heads + h]
+                oh = acc / l
+                if group:   # P against the lane block's two KV heads: this one's
+                    oh = _half_moved(oh, part == h, head_dim)
+                oh = _only_head(oh, h, head_dim)
+                o = oh if o is None else o + oh
+                lse_ref[0, h, 0, at[t]] = (m + jnp.log(l))[:, 0]
+            o_ref[0, at[t], :] = o.astype(o_ref.dtype)
+
+    if supers == 1:
+        super_block(0)
     else:
-        qs = [_only_head(q, h, head_dim) for h in range(heads)]
-    tile = (blk_q, blk_k)
-
-    def seen(k0):
-        if block_diffusion is not None:
-            return _tile_visible(q0, k0, tile, 0, window, block_diffusion)
-        return visible(
-            q0 + jax.lax.broadcasted_iota(jnp.int32, tile, 0),
-            k0 + jax.lax.broadcasted_iota(jnp.int32, tile, 1), window)
-
-    def chunk(c, carry, edge):
-        k0 = pl.multiple_of(c * blk_k, blk_k)
-        k = k_ref[0, pl.ds(k0, blk_k), :]  # [BLK_K, W]
-        v = v_ref[0, pl.ds(k0, blk_k), :]
-        mask = seen(k0) if edge else None
-        out = []
-        for h, (m, l, acc) in enumerate(carry):
-            s = _dot(qs[h], k, _NT)
-            if rope_dim:
-                s = s + _dot(qr, kr_ref[0, pl.ds(k0, blk_k), :], _NT)
-            s = s * scale
-            if edge:
-                s = jnp.where(mask, s, _MASKED)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new)
-            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-            # P against every head of the block; the other heads' lanes
-            # are dropped from the [BLK_Q, W] result at the end
-            acc = alpha * acc + _dot(p.astype(v.dtype), v, _NN)
-            out.append((m_new, l, acc))
-        return tuple(out)
-
-    carry = tuple(
-        (jnp.full((blk_q, 1), _MASKED, jnp.float32),
-         jnp.zeros((blk_q, 1), jnp.float32),
-         jnp.zeros(q.shape, jnp.float32)) for _ in range(heads))
-    for n, (lo, hi, edge) in enumerate(split):
-        if last_is_one and n == len(split) - 1:
-            carry = chunk(lo, carry, edge)
-        else:
-            carry = jax.lax.fori_loop(
-                lo, hi, functools.partial(chunk, edge=edge), carry)
-    o = None
-    for h, (m, l, acc) in enumerate(carry):
-        oh = acc / l
-        if group:   # P against the lane block's two KV heads: this one's
-            oh = _half_moved(oh, part == h, head_dim)
-        oh = _only_head(oh, h, head_dim)
-        o = oh if o is None else o + oh
-        lse_ref[0, h, 0] = (m + jnp.log(l))[:, 0]
-    o_ref[0] = o.astype(o_ref.dtype)
+        _for_rows(supers, 1, super_block)
 
 
 def _flash_fwd_span_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
@@ -1154,11 +1438,14 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
                                    group=group)
         blk = tiles * rows
     else:
-        blk = _q_block(s, bd)
+        rows = _q_block(s, bd)
+        tiles, chains, trim = super_block(s, window, bd)[0]
         kernel = functools.partial(
             _flash_fwd_kernel, causal=causal, window=window, scale=scale,
-            blk_q=blk, blk_k=_seq_block(s, bd, window), head_dim=d,
-            block_diffusion=bd, rope_dim=rope_dim, group=group)
+            blk_q=rows, blk_k=_seq_block(s, bd, window), head_dim=d,
+            block_diffusion=bd, rope_dim=rope_dim, group=group,
+            tiles=tiles, chains=chains, trim=trim)
+        blk = tiles * rows
     rope_specs = [
         pl.BlockSpec((1, blk, LANES), lambda b, j, i: (b, i, j // per)),
         pl.BlockSpec((1, s, LANES), lambda b, j, i: (b, 0, 0)),
@@ -1181,8 +1468,16 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     )(q, k, v, *rope_ops)
 
 
+def _turned(q, o, do):
+    """What ``_flash_bwd_tile`` forms of a Q chunk alone, whatever keys
+    it meets: Q^T, dO^T [W, Bq] and the float32 dO^T * O^T whose sums
+    down a head's sublanes are delta."""
+    qt, dot = q.T, do.T
+    return qt, dot, dot.astype(jnp.float32) * o.T.astype(jnp.float32)
+
+
 def _flash_bwd_tile(q, k, kt, v, o, do, lse, glse, scale: float, mask,
-                    head_dim: int, rope=None):
+                    head_dim: int, rope=None, turned=None):
     """FlashAttention-2 backward of the [k, q] tiles of one column
     block: k, v [Bk, W] (and ``kt`` = k^T, which the caller forms once)
     against q, O, dO [Bq, W] and, a head, the [1, Bq] rows lse and
@@ -1219,8 +1514,7 @@ def _flash_bwd_tile(q, k, kt, v, o, do, lse, glse, scale: float, mask,
     more results then, dQr^T [128, Bq] = Kr^T dS (every copy of the key
     gives the same rows: the caller keeps this head's) and dKr^T
     [128, Bk] = Qr^T dS^T (this head's sublanes, zeros elsewhere)."""
-    qt, dot = q.T, do.T                              # [W, S]
-    dot_ot = dot.astype(jnp.float32) * o.T.astype(jnp.float32)
+    qt, dot, dot_ot = turned or _turned(q, o, do)    # [W, S]
     dqt, dkt, dvt = [], [], []
     for h in range(q.shape[-1] // head_dim):
         mine = slice(h * head_dim, (h + 1) * head_dim)
@@ -1307,7 +1601,8 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                               glse_ref, *rest, causal: bool, window: int,
                               scale: float, blk: int, head_dim: int,
                               block_diffusion=None, rope_dim: int = 0,
-                              grouped: bool = False, group=None):
+                              grouped: bool = False, group=None,
+                              subs: int = 1):
     """FA2 backward for sequences past MAX_BWD_SEQ: grid cell = one
     (batch row, column block, K-block). The Q/O/dO panels are resident;
     the K-block meets them in chunks of ``blk`` queries, and ONLY the
@@ -1321,6 +1616,17 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     at the end; dQ adds up in place, a chunk's rows at a time, across
     the K-block grid dimension (same output block revisited -> Pallas
     keeps it in VMEM between consecutive steps).
+
+    ``subs`` > 1 (PR 51; ``super_block``): the chunks ``_query_trim``
+    names, those at the K block's own positions and a whole window
+    ahead of them, run as ``subs`` sub-blocks of the K block's keys,
+    each against the static slice of the chunk's queries that see any
+    of it, one ``_flash_bwd_tile`` each: the chunk's Q^T, dO^T and
+    delta are formed once (``_turned``), a sub-block's dK^T / dV^T add
+    into its lanes of the scratch, and the sub-blocks' dQ^T, padded
+    back to the chunk with zeros, are added up and written once. Every
+    other chunk is the one whole tile it was: run as sub-blocks too
+    they gained nothing (the lab's table in ``super_block``).
 
     The sums live in scratch and not in the loops' carries because a
     loop boundary moves its carries: v5e, kernels alone, backward ms
@@ -1392,29 +1698,59 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     def _init():
         dq_ref[0] = jnp.zeros(dq_ref.shape[1:], dq_ref.dtype)
 
-    def chunk(c, _, edge):
+    def chunk(c, _, edge, kept=None):
         q0 = pl.multiple_of(c * blk, blk)
         rows = pl.ds(q0, blk)
-        rope = (_rope_lanes(qr_ref[0, rows, :], head, rope_dim),
-                kr) if rope_dim else None
-        dqt, dkt, dvt, *dr = _flash_bwd_tile(
-            q_ref[0, rows, :], k, kt, v, o_ref[0, rows, :],
-            do_ref[0, rows, :], lse_ref[0, :, :, rows],
-            glse_ref[0, :, :, rows], scale,
-            (k0, q0, window, block_diffusion) if edge else None, head_dim,
-            rope)
-        dq_ref[0, rows, :] += (dqt * scale).T
-        dkt_ref[...] += dkt
-        dvt_ref[...] += dvt
+        q, o, do = q_ref[0, rows, :], o_ref[0, rows, :], do_ref[0, rows, :]
+        lse, glse = lse_ref[0, :, :, rows], glse_ref[0, :, :, rows]
+        qr = _rope_lanes(qr_ref[0, rows, :], head, rope_dim) if rope_dim \
+            else None
+        turned = _turned(q, o, do)    # once for the chunk's sub-blocks
+        parts = subs if kept else 1
+        sub = blk // parts
+        sums = None
+        for n in range(parts):
+            # the queries that see any key of sub-block n: a static slice
+            lo, hi = (sub * m for m in _kept(kept, n, parts))
+            whole, keys = (lo, hi) == (0, blk), slice(n * sub, (n + 1) * sub)
+
+            def of_rows(x):
+                return x if whole else x[lo:hi]
+
+            def of_lanes(x):
+                return x if whole else x[..., lo:hi]
+
+            kn, ktn, vn, krn = (
+                (k, kt, v, kr if rope_dim else None) if parts == 1 else
+                (k[keys], kt[:, keys], v[keys], kr[keys] if rope_dim else None))
+            dqt, dkt, dvt, *dr = _flash_bwd_tile(
+                of_rows(q), kn, ktn, vn, of_rows(o), of_rows(do),
+                of_lanes(lse), of_lanes(glse), scale,
+                (k0 + n * sub, q0 + lo, window, block_diffusion)
+                if edge else None, head_dim,
+                (of_rows(qr), krn) if rope_dim else None,
+                tuple(of_lanes(t) for t in turned))
+            dkt_ref[:, keys] += dkt
+            dvt_ref[:, keys] += dvt
+            if rope_dim:
+                dkrt_ref[:, keys] += dr[1]
+            mine = [dqt] + dr[:1]
+            if not whole:   # zeros for the queries that do not meet it
+                mine = [jnp.pad(t, ((0, 0), (lo, blk - hi))) for t in mine]
+            sums = mine if sums is None else [a + b for a, b in
+                                              zip(sums, mine)]
+        dq_ref[0, rows, :] += (sums[0] * scale).T
         if rope_dim:
-            dqr_ref[0, rows, :] += _rope_lanes((dr[0] * scale).T, head,
+            dqr_ref[0, rows, :] += _rope_lanes((sums[1] * scale).T, head,
                                                rope_dim)
-            dkrt_ref[...] += dr[1]
 
     dkt_ref[...] = jnp.zeros(dkt_ref.shape, jnp.float32)
     dvt_ref[...] = jnp.zeros(dvt_ref.shape, jnp.float32)
-    for lo, hi, edge in split:
-        jax.lax.fori_loop(lo, hi, functools.partial(chunk, edge=edge), None)
+    for n, (lo, hi, edge) in enumerate(split):
+        kept = _query_trim(n, causal, window, blk, blk // subs,
+                           block_diffusion) if subs > 1 else None
+        jax.lax.fori_loop(lo, hi, functools.partial(
+            chunk, edge=edge, kept=kept), None)
     dkt, dvt = (_group_halves(t[...], part, head_dim)
                 for t in (dkt_ref, dvt_ref))
     at = (0, pl.ds(pl.multiple_of(k0, blk), blk)) if grouped else 0
@@ -1563,7 +1899,8 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         kernel = functools.partial(
             _flash_bwd_blocked_kernel, causal=causal, window=window,
             scale=scale, blk=blk, head_dim=d, block_diffusion=bd,
-            rope_dim=rope_dim, grouped=grouped, group=group)
+            rope_dim=rope_dim, grouped=grouped, group=group,
+            subs=super_block(s, window, bd)[1])
         # dK^T and dV^T (and dKr^T) added up over a block's Q chunks
         scratch = [pltpu.VMEM((w, blk), jnp.float32)] * 2 + (
             [pltpu.VMEM((LANES, blk), jnp.float32)] if rope_dim else [])

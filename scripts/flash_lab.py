@@ -36,6 +36,20 @@ ship: 32 x 4 at 256 rows forward, 64 x 4 at 128 backward), and last
 (`laguna.narrow_window_768`), the upper end of the rule: the chunk loop
 as `_seq_block` cuts it (chunks of 512) against spans of 1024 and 896.
 
+Every chunk-loop shape (all but the narrow windows; since PR 51 also
+`ouro.full`, 16 heads of 128 at 4,096 positions, and `lfm2.full`, 32 : 8
+heads of 64 at 16,384) then runs the `super_block` axis (PR 51;
+`pallas_kernels.super_block` held): the forward at Q blocks a grid step x
+Q blocks a loop iteration, 1 x 1 the parent's kernel, the sub-tiles whole
+or `trimmed` (a sub-tile takes the part of a chunk it can see any of),
+against the parent's backward; the backward with the chunks at a K block's
+own positions (and a whole window ahead) in 1, 2 or 4 sub-blocks, each
+against the queries that see it, against the parent's forward; and
+`super_block as shipped`, nothing held. A line of that axis times the
+direction under test alone. `--programs super_block` runs that axis alone
+(any comma-separated parts of programs' names). A form the chip's compiler
+refuses is a line with an `error`.
+
 `--only grouped` (PR 43) times ONE WHOLE ATTENTION OP instead, forward
 and backward (`value_and_grad` over its parameters and input, as a train
 step runs it), at the six grouped-query shapes of the decoder cells
@@ -59,6 +73,7 @@ Prints one JSON line a measurement and writes them to
 `chiprun_out/flash_lab.json`. Nothing here is a benchmark metric.
 
     python scripts/flash_lab.py [--tiny] [--only <part of a shape's name>]
+                                [--programs <part of a program's name>,...]
     python scripts/flash_lab.py --only grouped[.<part of an op's name>]
 
 `--tiny` is the CPU rehearsal (short sequences, the kernels interpreted,
@@ -84,6 +99,9 @@ SHAPES = {
     "laguna.narrow_window": (64, 8192, True, 512, None),
     "laguna.narrow_window_768": (64, 8192, True, 768, None),
     "laguna.full": (48, 8192, True, 0, None),
+    "ouro.full": (16, 4096, True, 0, None),
+    # heads of 64, two a lane block, four query heads a KV head
+    "lfm2.full": (32, 16384, True, 0, None, dict(head_dim=64, kv_heads=8)),
 }
 ROPE_DIM = 64      # the rotated lanes of a `latent` shape's query and key
 REPS = 10
@@ -102,6 +120,42 @@ def every_tile_masked(ranges, forward, peel=False):
             cut += [(lo, hi - 1, True), (hi - 1, hi, True)]
         return (tuple(cut), peel and masked) if forward else tuple(cut)
     return split
+
+
+# `super_block` (PR 51), the chunk-loop kernels' blocks a grid step. The
+# forward: (Q blocks a grid step, Q blocks a loop iteration, whether the
+# peeled last chunk's sub-tiles stop at their own last query); 1 x 1 is
+# the parent's kernel.
+FORWARD_FORMS = ((1, 1, False), (2, 2, False), (4, 4, False), (4, 2, False),
+                 (4, 1, False), (8, 4, False), (16, 4, False),
+                 (16, 1, False), ("head", 4, False), (4, 4, True),
+                 (16, 4, True))
+# The backward: the sub-blocks of a K block at its own chunk (the
+# diagonal), each against the queries from its first key on; 1 is the
+# parent's kernel.
+BACKWARD_FORMS = (1, 2, 4)
+
+
+def super_block_variants(seq, bd, tiny):
+    """(program, forward form | None, backward form | None) a line: each
+    direction's forms against the other's parent, then what ships."""
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    rows = pk._q_block(seq, bd)
+    most = pk._seq_block(seq, bd) // rows
+    out = []
+    for tiles, chains, trim in FORWARD_FORMS:
+        if tiles == "head":
+            tiles = (bd[0] if bd else seq) // rows
+        if chains > most or (trim and chains < most) or (tiny and tiles > 8):
+            continue
+        line = (f"super_block forward {tiles} x {chains}"
+                + (" trimmed" if trim else ""), (tiles, chains, trim), None)
+        if line not in out:
+            out.append(line)
+    out += [(f"super_block backward, diagonal x {subs}", None, subs)
+            for subs in BACKWARD_FORMS]
+    return out + [("super_block as shipped", None, None)]
 
 
 def variants(name, window, bd, tiny):
@@ -278,8 +332,11 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--only", default="")
+    ap.add_argument("--programs", default="",
+                    help="only the programs whose name holds one of these, "
+                    "comma-separated")
     args = ap.parse_args()
-    tiny, only = args.tiny, args.only
+    tiny, only, programs = args.tiny, args.only, args.programs
     if tiny:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         # the whole op of `--only grouped` asks `pallas_mode()` for its core
@@ -293,7 +350,8 @@ def main():
     if not tiny and jax.default_backend() != "tpu":
         sys.exit("flash_lab.py times the kernels on a TPU; --tiny rehearses")
     shipped = {name: getattr(pk, name) for name in (
-        "_k_split", "_q_split", "_seq_block", "one_span", "_span_tiles")}
+        "_k_split", "_q_split", "_seq_block", "one_span", "_span_tiles",
+        "super_block")}
 
     def restore():
         for name, fn in shipped.items():
@@ -302,23 +360,42 @@ def main():
     lines = []
     if only and (only in "grouped" or only.startswith("grouped.")):
         lines = grouped_op_lines(tiny, only[len("grouped."):])
-    for name, (heads, seq, causal, window, bd) in SHAPES.items():
+    for name, (heads, seq, causal, window, bd, *wide) in SHAPES.items():
         if only not in name:
             continue
+        head_dim, kv_heads = 128, None
+        if wide:
+            head_dim, kv_heads = wide[0]["head_dim"], wide[0]["kv_heads"]
         if tiny:
             seq, window, bd = 2048, window // 8, bd and (1024, 4)
             heads = min(heads, 4)
+            kv_heads = kv_heads and 2
         rs = np.random.RandomState(0)
-        q, k, v, do = (jnp.asarray(rs.randn(1, seq, heads * 128),
-                                   jnp.bfloat16) for _ in range(4))
+        q, k, v, do = (jnp.asarray(rs.randn(1, seq, n * head_dim),
+                                   jnp.bfloat16)
+                       for n in (heads, kv_heads or heads,
+                                 kv_heads or heads, heads))
         rope = (jnp.asarray(rs.randn(1, seq, heads * ROPE_DIM), jnp.bfloat16),
                 jnp.asarray(rs.randn(1, seq, ROPE_DIM), jnp.bfloat16))
-        for program, blk, one in variants(name, window, bd, tiny):
+        latent = name.endswith(".latent")
+        lines_of = [(program, blk, one, None, None)
+                    for program, blk, one in variants(name, window, bd, tiny)
+                    if not wide]     # PR 35's programs: heads of 128
+        if ".narrow_window" not in name:
+            lines_of += [(program, None, None, forward, backward)
+                         for program, forward, backward
+                         in super_block_variants(seq, bd, tiny)]
+        for program, blk, one, forward, backward in lines_of:
+            if not any(part in program for part in programs.split(",")):
+                continue
             restore()
             if program.startswith("every_tile_masked"):
                 pk._k_split = every_tile_masked(
                     pk._k_ranges, True, peel="peel" in program)
                 pk._q_split = every_tile_masked(pk._q_ranges, False)
+                # PR 35's kernels: one block a grid step, whole tiles
+                # (a sub-tile's part of a chunk goes by the split's order)
+                pk.super_block = lambda *a, **k: ((1, 1, False), 1)
             if blk:
                 pk._seq_block = (lambda s, block_diffusion=None,
                                  window=0, b=blk: b)
@@ -326,14 +403,18 @@ def main():
             if ".narrow_window" in name and program != "one_span as shipped":
                 pk.one_span = lambda *a, held=one and (one[:2],) * 2, **k: held
                 pk._span_tiles = lambda s, blk, n=one and one[2]: n
-            mask = dict(window=window, block_diffusion=bd)
-            if program == "split" and name.endswith(".latent"):
+            if program.startswith("super_block") and (forward or backward):
+                # one direction's form against the other's parent
+                pk.super_block = lambda *a, held=(
+                    forward or (1, 1, False), backward or 1), **k: held
+            mask = dict(window=window, block_diffusion=bd,
+                        num_kv_heads=kv_heads)
+            if latent and program != "split, no rotated part":
                 mask["rope"] = rope
             fwd = jax.jit(lambda q, k, v: pk._flash_fwd(
                 q, k, v, heads, causal, tiny, **mask))
             bwd = jax.jit(lambda q, k, v, o, lse, do: pk._flash_bwd(
                 q, k, v, o, lse, do, heads, causal, tiny, **mask))
-            o, lse = fwd(q, k, v)
             line = dict(
                 shape=name, heads=heads, seq=seq, window=window,
                 program=program,
@@ -341,19 +422,27 @@ def main():
                 one_span=pk.one_span(seq, causal, window, bd),
                 tiles_a_step=one[2] if one else pk.one_span(
                     seq, causal, window, bd) and pk._span_tiles(seq, 128),
-                pairs_visited=pk.visited_pairs(seq, causal, window, bd),
-                pairs_visible=2 * pk.visible_pairs(seq, causal, window)
-                if causal else None,
-                tiles_visited=pk.kv_blocks(seq, causal, window, bd)[0],
-                tiles_masked=pk.kv_blocks_masked(seq, causal, window, bd),
-                last_chunk_peeled=pk._k_split(
-                    0, pk._q_block(seq, bd),
-                    pk._seq_block(seq, bd, window), seq,
-                    causal, window, bd)[1],
-                forward_device_ms=kernel_ms(fwd, (q, k, v), tiny),
-                backward_device_ms=kernel_ms(
-                    bwd, (q, k, v, o, lse, do), tiny),
+                super_block=pk.super_block(seq, window, bd),
                 device=jax.devices()[0].device_kind)
+            try:    # a form the chip's compiler refuses is a row too
+                o, lse = fwd(q, k, v)
+                line.update(
+                    pairs_visited=pk.visited_pairs(seq, causal, window, bd),
+                    pairs_visible=2 * pk.visible_pairs(seq, causal, window)
+                    if causal else None,
+                    tiles_visited=pk.kv_blocks(seq, causal, window, bd)[0],
+                    tiles_masked=pk.kv_blocks_masked(seq, causal, window, bd),
+                    last_chunk_peeled=pk._k_split(
+                        0, pk._q_block(seq, bd),
+                        pk._seq_block(seq, bd, window), seq,
+                        causal, window, bd)[1])
+                if not backward:
+                    line["forward_device_ms"] = kernel_ms(fwd, (q, k, v), tiny)
+                if not forward:
+                    line["backward_device_ms"] = kernel_ms(
+                        bwd, (q, k, v, o, lse, do), tiny)
+            except Exception as e:  # noqa: BLE001: the compiler's, any kind
+                line["error"] = f"{type(e).__name__}: {e}"[-600:]
             print(json.dumps(line), flush=True)
             lines.append(line)
     restore()

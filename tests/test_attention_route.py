@@ -34,6 +34,7 @@ OPS = {
         # four heads of 64: two whole 128-lane columns (PR 47)
         dict(core="flash", scope="window", rotary_in_lanes=True,
              one_span=False,            # the whole-tile kernels
+             super_block=False,
              kv_blocks=(*pk.kv_blocks(512, True, 128),
                         pk.kv_blocks_masked(512, True, 128)),
              window_pairs=(pk.visited_pairs(512, True, 128),
@@ -44,15 +45,41 @@ OPS = {
         dict(num_heads=4, causal=True, window=128, rope=True),
         (1, 1536, 1536, 64), None, "interpret", True,
         dict(core="flash", scope="window", one_span=True,
+             super_block=False,
              kv_blocks=(6, 6 * 4, 6),
              window_pairs=(1536 * (384 + 256),
                            2 * pk.visible_pairs(1536, True, 128)))),
     "wide_window_chunk_loop": (
         dict(num_heads=4, causal=True, window=800, rope=True),
         (1, 1536, 1536, 64), None, "interpret", True,
+        # a window that ends inside a K chunk: one block a grid step
         dict(core="flash", scope="window", one_span=False,
+             super_block=False,
              kv_blocks=(*pk.kv_blocks(1536, True, 800),
                         pk.kv_blocks_masked(1536, True, 800)))),
+    # PR 51: the chunk loop takes a super-block of Q blocks a grid step
+    # where that is a K chunk (512 of 1536: two blocks of 256)
+    "causal_chunk_loop_super_block": (
+        dict(num_heads=4, causal=True, rope=True),
+        (1, 1536, 1536, 64), None, "interpret", True,
+        dict(core="flash", scope="full", one_span=False, super_block=True,
+             kv_blocks=(*pk.kv_blocks(1536, True),
+                        pk.kv_blocks_masked(1536, True)))),
+    "window_of_whole_chunks_super_block": (
+        dict(num_heads=1, head_dim=128, causal=True, window=1024),
+        (1, 2048, 2048, 64), None, "interpret", True,
+        dict(core="flash", scope="window", one_span=False,
+             super_block=True)),
+    "latent_chunk_loop_super_block": (
+        dict(LATENT, num_heads=2, head_dim=128, qk_rope_head_dim=64,
+             rope=True), (1, 2048, 2048, 64), None, "interpret", True,
+        dict(core="flash", scope="latent", one_span=False,
+             super_block=True)),
+    # blocks of 256 against chunks of 256 (S = 1280): nothing to gather
+    "chunk_of_one_block": (
+        dict(num_heads=4, causal=True), (1, 1280, 1280, 64), None,
+        "interpret", True,
+        dict(core="flash", one_span=False, super_block=False)),
     "block_diffusion": (
         dict(num_heads=2, head_dim=128, block_diffusion=(128, 4), rope=True,
              rope_wrap=128, qk_norm=True), (1, 256, 256, 64), None,
@@ -199,6 +226,7 @@ def test_forward_route_selected_impl_and_gauges_agree(case, monkeypatch):
         "executor.rotary_lane_dense_ops": int(route.rotary_in_lanes),
         "executor.flash_grouped_kv_ops": int(route.grouped_kv),
         "executor.flash_one_span_ops": int(route.one_span),
+        "executor.flash_super_block_ops": int(route.super_block),
         "executor.window_attention_ops": int(route.scope == "window"),
         "executor.block_diffusion_attention_ops": int(
             route.scope == "block_diffusion"),

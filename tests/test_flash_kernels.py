@@ -446,6 +446,10 @@ def test_unmasked_interior_tiles_give_the_bits_of_masking_every_tile(
     if kind != "not-causal":      # the case is not vacuous
         assert 0 < pk.kv_blocks_masked(seq, causal, window, bd) < (
             pk.kv_blocks(seq, causal, window, bd)[0])
+    # one block a grid step, whole tiles (PR 51): which part of a chunk
+    # a sub-tile takes goes by `_k_split`'s own order of sub-ranges
+    monkeypatch.setattr(pk, "super_block", lambda *a, **k: (
+        (1, 1, False), 1))
     got = run()
     monkeypatch.setattr(pk, "_k_split", _every_tile_edge(pk._k_ranges))
     monkeypatch.setattr(pk, "_q_split", _every_tile_edge(pk._q_ranges))

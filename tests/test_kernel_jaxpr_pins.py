@@ -69,15 +69,21 @@ PINS = {
     # grouped keys at heads of 128 (PR 43) in the three kernel families
     "flash_whole_8_2_128": (lambda: flash(256, 8, 2, 128),
                             "54ddd10fdcbbea9a"),
+    # the chunk-loop kernels: moved ON PURPOSE by PR 51 (a grid step's
+    # body is a super-block's, the backward's chunks may run as
+    # sub-blocks: `pallas_kernels.super_block`; at S 1280 the rule gives
+    # one block a step and the values are the parent's bit for bit,
+    # `tests/test_flash_kernels.py`); PR 46's digest was 39333fa460ad8def
     "flash_blocked_8_2_128": (lambda: flash(1280, 8, 2, 128),
-                              "39333fa460ad8def"),
+                              "ec2e5424166d584b"),
     "flash_span_8_2_128": (lambda: flash(1536, 8, 2, 128, window=128),
                            "34723cece079bc2a"),
     # heads of 64, every head its own keys: bert_ae's form, the control
     "flash_whole_4_4_64": (lambda: flash(256, 4, 4, 64),
                            "2a1b70ccb08cedec"),
+    # (PR 51, as above; PR 46's was 700c0b0e0a675e45)
     "flash_blocked_4_4_64": (lambda: flash(1280, 4, 4, 64),
-                             "700c0b0e0a675e45"),
+                             "e64e476a0f224d85"),
     # the pass at heads of 128: whole and partial rotary, with the norm
     "rotary_whole_128": (lambda: rotary(256, 3, 128, 128, False),
                          "c225bef5cc5849c9"),

@@ -332,16 +332,31 @@ def test_the_chunk_follows_a_narrow_window_and_the_counts_follow_it(
         # 512 (but the first and the last): 2 and 2 times the window
         monkeypatch.setattr(pk, "one_span", lambda *a, **k: None)
         chunks = pk.visited_pairs(seq, True, window)
-        assert chunks == 8192 * 1024 - 256 * 512 * 2 + (
-            8192 * 1024 - 512 * 512) > 1.4 * visited
+        whole = 8192 * 1024 - 256 * 512 * 2 + (8192 * 1024 - 512 * 512)
+        assert whole > 1.4 * visited
         assert pk.kv_blocks(seq, True, window) == (2 * 32 - 2, 32 * 16)
-        visited = chunks
+        # since PR 51 a sub-tile takes the part of a chunk it can see: a
+        # super-block of two Q blocks is one chunk of 512, and of its
+        # diagonal chunk and of the far one (a whole window behind) three
+        # of the four squares of 256 are met; the backward's K block of
+        # two sub-blocks the same: (16 + 15) chunks x 3 squares each way,
+        # within 6% of the one-span kernels' pairs (whose tiles have no
+        # loop to carry sums through)
+        assert pk.super_block(seq, window) == ((2, 2, True), 2)
+        assert chunks == 2 * (16 + 15) * 3 * 256 * 256
+        assert chunks == whole - 2 * (16 + 15) * 256 * 256
+        assert 1.05 < chunks / visited < 1.06
         # at the chunks of 1024 that S alone gives
         monkeypatch.setattr(pk, "_seq_block", lambda s, bd=None, w=0: 1024)
         # 46 forward tiles of [256, 1024] (a Q block meets one chunk or
-        # two), 15 backward tiles of [1024, 1024]: 1.7 times as many pairs
+        # two; the window ends inside a chunk, so a step is one Q block
+        # and its tiles are whole), 15 backward tiles of [1024, 1024],
+        # of the 8 at a K block's own positions 10 of 16 squares: 2.1
+        # times as many pairs (2.4 with those whole, until PR 51)
+        assert pk.super_block(seq, window) == ((1, 1, False), 4)
         assert pk.visited_pairs(seq, True, window) == (
-            46 * 256 * 1024 + 15 * 1024 * 1024) > 1.7 * visited
+            46 * 256 * 1024 + 15 * 1024 * 1024 - 8 * 6 * 256 * 256
+        ) > 2.1 * visited
 
 
 # ---------------------------------------------------------------------------

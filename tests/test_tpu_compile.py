@@ -180,6 +180,55 @@ class TestFlashKernels:
 
         assert pallas_kernel_count(_compile(grads, q, k, k)) == 2
 
+    @pytest.mark.parametrize("case,dtype", [
+        ("ouro", jnp.bfloat16), ("joyai", jnp.bfloat16),
+        ("chunk-512", jnp.bfloat16), ("smallthinker", jnp.bfloat16),
+        ("sdar", jnp.bfloat16), ("lfm2", jnp.bfloat16),
+        # the panels and the tiles at twice the bytes, at 16,384 positions
+        ("smallthinker", jnp.float32), ("sdar", jnp.float32),
+        ("lfm2", jnp.float32)])
+    def test_super_block_steps_stay_inside_the_vmem_budget(self, topo, case,
+                                                           dtype):
+        """The chunk-loop kernels' super-blocks (PR 51) at the cells'
+        shapes: a forward grid step holds four Q blocks' [256, 1024]
+        float32 score tiles and their (max, sum, accumulator) carries
+        beside the K / V panels (2 x 4 MB double-buffered at 16,384
+        positions, float32 twice that), and slices a chunk at multiples
+        of 256 keys for the sub-tiles of the diagonal, the far and the
+        noised chunk; the backward's own chunk runs as four [256, <=
+        1024] sub-blocks whose dQ^T parts are padded back to the chunk.
+        The compiler takes each form inside the 96 MiB, in float32 too."""
+        heads, kv_heads, d, seq, window, bd, rope = {
+            "ouro": (2, None, 128, 4096, 0, None, False),
+            "joyai": (2, None, 128, 4096, 0, None, True),
+            "smallthinker": (7, 1, 128, 16384, 4096, None, False),
+            "sdar": (8, 1, 128, 16384, 0, (8192, 4), False),
+            "lfm2": (8, 2, 64, 16384, 0, None, False),
+            "chunk-512": (2, None, 128, 1536, 0, None, False),
+        }[case]
+        parts = {"chunk-512": 2}.get(case, 4)
+        assert pk.super_block(seq, window, bd) == (
+            (parts, parts, True), parts)
+        assert pk.super_block_engaged(seq, bd is None, window, bd,
+                                      64 if rope else 0)
+        one = SingleDeviceSharding(topo.devices[0])
+        q = jax.ShapeDtypeStruct((1, seq, heads * d), dtype, sharding=one)
+        k = q if kv_heads is None else jax.ShapeDtypeStruct(
+            (1, seq, kv_heads * d), jnp.float32, sharding=one)
+        parts_of_score = (
+            jax.ShapeDtypeStruct((1, seq, heads * 64), dtype, sharding=one),
+            jax.ShapeDtypeStruct((1, seq, 64), dtype, sharding=one),
+        ) if rope else ()
+
+        def grads(q, k, v, *r):
+            return jax.grad(lambda q, k, v, *r: pk._flash(
+                q, k, v, heads, bd is None, False, window, bd, r or None,
+                kv_heads).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v, *r)
+
+        assert pallas_kernel_count(
+            _compile(grads, q, k, k, *parts_of_score)) == 2
+
     @pytest.mark.parametrize("seq,block", [(16384, 4), (2048, 32),
                                            (512, 4)])
     def test_block_diffusion_mask_compiles_at_the_cells_widths(

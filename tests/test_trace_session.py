@@ -618,7 +618,8 @@ WITNESS_KEYS = [
     "attention/window_keys_visited",
     "executor.block_diffusion_attention_ops",
     "executor.flash_grouped_kv_ops", "executor.flash_lane_dense_ops",
-    "executor.flash_one_span_ops", "executor.latent_attention_ops",
+    "executor.flash_one_span_ops", "executor.flash_super_block_ops",
+    "executor.latent_attention_ops",
     "executor.layer_applications", "executor.loss_own_vjp",
     "executor.moe_spread_rows_ops", "executor.moe_sum_rows_ops",
     "executor.rotary_lane_dense_ops",
@@ -632,6 +633,7 @@ CONTEXT_KEYS = [
     "attention_window_keys_visited", "batch_size",
     "block_diffusion_attention_ops", "compile_phases",
     "flash_grouped_kv_ops", "flash_lane_dense_ops", "flash_one_span_ops",
+    "flash_super_block_ops",
     "latent_attention_ops", "layer_applications",
     "loss_own_vjp", "loss_target_positions", "mesh_axes",
     "moe_spread_rows_ops", "moe_sum_rows_ops", "num_ops",
