@@ -317,10 +317,11 @@ def required_input_specs(node, getspec, getparam) -> List[Any]:
             reqs.append(tuple(r))
         return reqs
 
-    if t == OperatorType.SHORT_CONV:
+    if t in (OperatorType.SHORT_CONV, OperatorType.MAMBA_MIXER):
         # [B, S, E] in and out: the batch follows the output; a position
-        # reads the K - 1 before it and the products contract over E,
-        # so the sequence and the lanes arrive whole
+        # reads the K - 1 before it (the scan: all before it) and the
+        # products contract over E, so the sequence and the lanes arrive
+        # whole
         r = [None] * len(in_shapes[0])
         if r and in_shapes[0][0] == out_shape[0]:
             r[0] = out0[0]

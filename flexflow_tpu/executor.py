@@ -28,7 +28,7 @@ from flexflow_tpu.losses import (class_ids, expected_exit_loss, get_loss_fn,
 from flexflow_tpu.metrics import Metrics
 from flexflow_tpu.obs.registry import get_registry
 from flexflow_tpu.obs.step_scopes import scope_part
-from flexflow_tpu.ops.base import Op, OpContext, scoped
+from flexflow_tpu.ops.base import Op, OpContext, exported_reads, scoped
 from flexflow_tpu.parallel.choice import ExecPlan
 
 
@@ -43,7 +43,7 @@ COUNT_SUFFIX = "#n"
 SIDE_CHANNELS = ("_aux_loss", "_counters", "_new_state", "_new_states")
 # the op kinds of which a decoder layer holds one
 SEQUENCE_MIXERS = (OperatorType.MULTIHEAD_ATTENTION, OperatorType.SSM_MIXER,
-                   OperatorType.SHORT_CONV)
+                   OperatorType.SHORT_CONV, OperatorType.MAMBA_MIXER)
 
 
 def settled_spec(spec: P) -> P:
@@ -812,7 +812,8 @@ class GraphExecutor:
         `losses.target_log_probs` (its own backward, PR 40), else 0 (MSE,
         dense one-hot labels, probabilities in); and, in a model whose
         attention ops differ in their query heads, each op's
-        (`attention/heads_by_op/<op>`, PR 41). The ONE list of them:
+        (`attention/heads_by_op/<op>`, PR 41); `executor.shared_tensors`
+        and `executor.shared_tensor_readers` (PR 52). The ONE list of them:
         published as registry gauges when the train step is traced, in
         `FFModel.op_counters` and in every trace header
         (`obs.model_context`)."""
@@ -834,6 +835,13 @@ class GraphExecutor:
             {leaf for leaves in read for leaf in leaves})
         out["executor.layer_applications"] = sum(
             n.op.op_type in SEQUENCE_MIXERS for n in self.nodes)
+        # tensors one op makes for other layers to read (`Op.exports`: a
+        # scan's memory, an attention op's keys and values), and the
+        # readers of them all; published where the model has one
+        exported, reads = exported_reads(self.nodes)
+        if exported:
+            out["executor.shared_tensors"] = len(exported)
+            out["executor.shared_tensor_readers"] = len(reads)
         heads = {n.op.name: n.op.num_heads for n in self.nodes
                  if hasattr(n.op, "num_kv_heads")}
         if len(set(heads.values())) > 1:

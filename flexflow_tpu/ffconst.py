@@ -187,6 +187,8 @@ class OperatorType(enum.Enum):
     MOE_LAYER = enum.auto()
     # appended (PR 45): the gated short convolution (ops/short_conv.py)
     SHORT_CONV = enum.auto()
+    # appended (PR 52): the Mamba-1 mixer (ops/ssm.py `MambaMixer`)
+    MAMBA_MIXER = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

@@ -294,6 +294,12 @@ class FFModel:
                             gate_activation: str = "softplus",
                             partial_rotary_factor: float = 1.0,
                             rope_scaling: Optional[dict] = None,
+                            differential: bool = False,
+                            lambda_init: float = 0.8,
+                            lambda_scale: float = 1.0,
+                            diff_norm_eps: float = 1e-5,
+                            kv_given: bool = False,
+                            export_kv: bool = False,
                             name: Optional[str] = None) -> Tensor:
         """``seq_parallel='seq'`` runs the attention core as ring attention
         over that mesh axis (context parallelism for long sequences).
@@ -327,7 +333,24 @@ class FFModel:
         ``rope_scaling``: a public config's ``rope_type`` "yarn" keys
         (``factor``, ``original_max_position_embeddings``, ``beta_fast``,
         ``beta_slow``, ``attention_factor``) for the frequency table
-        (``ops.attention.rotary_frequencies``)."""
+        (``ops.attention.rotary_frequencies``). ``differential``: the
+        heads in pairs, two softmax maps a pair, A1 - lambda A2 on the
+        pair's values, a norm of the pair's 2 * head_dim lanes
+        (``diff_norm_eps``), lambda from four learned vectors and
+        ``lambda_init`` (`ops/attention.py`; ``lambda_scale`` 0 is a
+        control: the second map weighs nothing). ``kv_given``: ``key`` and
+        ``value`` are [B, S, num_kv_heads * head_dim], ALREADY projected
+        by another op (the op holds wq and wo alone). ``export_kv``: the
+        op's projected keys and values are outputs too; the call then
+        returns (out, k, v), for ``kv_given`` readers."""
+        shared = {k: v for k, v in (("differential", differential),
+                                    ("kv_given", kv_given),
+                                    ("export_kv", export_kv)) if v}
+        if differential:
+            shared.update(lambda_init=lambda_init,
+                          diff_norm_eps=diff_norm_eps,
+                          **({"lambda_scale": lambda_scale}
+                             if lambda_scale != 1.0 else {}))
         latent = dict(q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
                       qk_rope_head_dim=qk_rope_head_dim,
                       latent_norm_eps=latent_norm_eps,
@@ -354,7 +377,7 @@ class FFModel:
             **({"partial_rotary_factor": partial_rotary_factor}
                if partial_rotary_factor != 1.0 else {}),
             **({"rope_scaling": dict(rope_scaling)}
-               if rope_scaling else {}), **latent), name)
+               if rope_scaling else {}), **shared, **latent), name)
         return self._finish(layer)
 
     def ssm_mixer(self, input: Tensor, num_heads: int, head_dim: int,
@@ -372,6 +395,28 @@ class FFModel:
             chunk_size=chunk_size, eps=eps, time_step_min=time_step_min,
             time_step_max=time_step_max, time_step_floor=time_step_floor,
             kernel_initializer=kernel_initializer), name)
+        return self._finish(layer)
+
+    def mamba_mixer(self, input: Tensor, state_size: int = 16,
+                    conv_kernel: int = 4, expand: int = 2, dt_rank: int = 0,
+                    export_memory: bool = False, export_gated: bool = False,
+                    time_step_min: float = 1e-3, time_step_max: float = 1e-1,
+                    kernel_initializer=None, name: Optional[str] = None):
+        """Mamba-1 mixer over [B, S, E] (ops/ssm.py `MambaMixer`): input
+        projection to ``expand * E`` channels and their gate, causal
+        depthwise convolution, the selective scan (a decay a channel AND
+        state: one Pallas kernel each way), the gate, output projection.
+        ``dt_rank`` 0 is ceil(E / 16). With ``export_memory`` the call
+        returns (out, memory): the scan's output before its gate, [B, S,
+        expand * E], for the layers that read it (``export_gated`` is a
+        control: the output AFTER the gate)."""
+        layer = self._add_layer(OperatorType.MAMBA_MIXER, [input], dict(
+            d_inner=expand * input.shape[-1], state_size=state_size,
+            conv_kernel=conv_kernel, dt_rank=dt_rank,
+            time_step_min=time_step_min, time_step_max=time_step_max,
+            kernel_initializer=kernel_initializer,
+            **({"export_memory": True} if export_memory else {}),
+            **({"export_gated": True} if export_gated else {})), name)
         return self._finish(layer)
 
     def short_conv(self, input: Tensor, kernel: int = 3,

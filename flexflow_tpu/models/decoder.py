@@ -75,6 +75,33 @@ family.
         rms_norm(mlp(rms_norm(x'))) (``sandwich_norm`` False leaves the
         output norms out: a control)
 
+    m w y f g c   the six blocks of a decoder-hybrid-decoder model
+        (SambaY, PR 52; `_sambay_block`), each a mixer and then `A`'s
+        SwiGLU MLP, behind LayerNorms WITH bias and residuals, no
+        position embedding anywhere. ``mb_per_layer`` 2 names them by
+        the published rule from a layer's PUBLISHED index i of L
+        (``first_layer_index`` + its place here; L =
+        ``published_num_hidden_layers`` or ``num_hidden_layers``, the
+        layers built): even i below L / 2 `m`, the Mamba-1 mixer
+        (``mamba_d_state``, ``mamba_d_conv``, ``mamba_expand``,
+        ``mamba_dt_rank``; ops/ssm.py `MambaMixer`); odd i below L / 2
+        `w`, DIFFERENTIAL causal attention (ops/attention.py; lambda_init
+        = 0.8 - 0.6 exp(-0.3 i), biases on every projection) under a
+        window of ``sliding_window`` keys; i = L / 2 `y`, the Mamba-1
+        mixer whose scan output (before its gate) is kept as the MEMORY;
+        i = L / 2 + 1 `f`, full differential attention whose projected
+        keys and values are kept as the SHARED ones; above them even i
+        `g`, the gated memory unit (silu(h W_1) * memory) W_2 (scope
+        ``gated_memory``), and odd i `c`, differential cross-attention: a
+        query projection of its own over the shared keys and values.
+        The memory and the shared keys/values are second (and third)
+        OUTPUTS of the ops that make them, PCG tensors which the readers
+        take as inputs. A stage that holds a reader and not its producer
+        is refused. Controls: ``diff_lambda_scale`` 0 (plain attention:
+        the second map left out), ``memory_gated`` (the memory taken
+        AFTER the scan's gate), ``cross_own_kv`` (a `c` layer projects
+        keys and values of its own)
+
 ``layer_types`` (a public config's list of "full_attention" /
 "sliding_attention" / "conv", one entry a layer that runs) stands for
 the pattern: `F`, `S` and `C` in its order. ``num_dense_layers`` is the
@@ -143,6 +170,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 from typing import Optional, Sequence
 
 from flexflow_tpu.config import FFConfig
@@ -234,6 +262,23 @@ class DecoderConfig:
     # distance of the token whose embedding it reads (1; 0 is a control)
     num_nextn_predict_layers: int = 0
     mtp_shift: int = 1
+    # a decoder-hybrid-decoder model (`m w y f g c`): 2 names the blocks
+    # by the published rule (0: the pattern does); the layers built, the
+    # published index of the first and the published depth (0: the
+    # layers built); the window of `w`; the Mamba-1 sizes (rank 0:
+    # ceil(hidden / 16)); three controls (module docstring)
+    mb_per_layer: int = 0
+    num_hidden_layers: int = 0
+    first_layer_index: int = 0
+    published_num_hidden_layers: int = 0
+    sliding_window: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    diff_lambda_scale: float = 1.0
+    memory_gated: bool = False
+    cross_own_kv: bool = False
     batch_size: int = 2
     seq_length: int = 16
     seq_parallel: Optional[str] = None      # 'seq': ring attention
@@ -426,6 +471,98 @@ def _gated_block(ff, t, i, cfg, letter):
                                 cfg.moe_shared_expert_intermediate_size)
 
 
+SAMBAY_LETTERS = "mwyfgc"
+
+
+def sambay_pattern(cfg) -> str:
+    """The blocks of a decoder-hybrid-decoder stage by the published
+    rule (module docstring), one letter a layer built."""
+    if cfg.mb_per_layer != 2:
+        raise ValueError(f"decoder: mb_per_layer {cfg.mb_per_layer} (the "
+                         f"decoder-hybrid-decoder rule is written for 2)")
+    total = cfg.published_num_hidden_layers or cfg.num_hidden_layers
+    first = cfg.first_layer_index
+    if not 0 < cfg.num_hidden_layers <= total - first or total % 4:
+        # L / 2 is a Mamba layer's index, so it is even
+        raise ValueError(
+            f"decoder: layers {first}..{first + cfg.num_hidden_layers - 1} "
+            f"of a published depth of {total} (a multiple of 4, and the "
+            f"stage inside it)")
+    half = total // 2
+    return "".join(
+        ("m" if i < half else "y" if i == half else "g") if i % 2 == 0
+        else ("w" if i < half else "f" if i == half + 1 else "c")
+        for i in range(first, first + cfg.num_hidden_layers))
+
+
+def _sambay_block(ff, t, i, letter, cfg, shared):
+    """One `m w y f g c` block: x' = x + mixer(LN(x)), x'' = x' +
+    mlp(LN(x')). ``shared`` holds the memory and the keys/values the
+    stage's layers have made so far; `y` and `f` fill it, `g` and `c`
+    read it."""
+    eps, depth = cfg.layer_norm_epsilon, cfg.first_layer_index + i
+    total = cfg.published_num_hidden_layers or cfg.num_hidden_layers
+    half = total // 2
+    h = ff.layer_norm(t, eps=eps, name=f"b{i}_norm")
+
+    def missing(what, maker):
+        return ValueError(
+            f"decoder: layer {depth} reads {what} of layer {maker}, which "
+            f"this stage (layers {cfg.first_layer_index}.."
+            f"{cfg.first_layer_index + len(shared['pattern']) - 1} of "
+            f"{total}) does not hold: a stage keeps a reader with its "
+            f"producer (exported tensors do not cross stages)")
+
+    def attention(kv=None, window=0, export=False):
+        return ff.multihead_attention(
+            h, *(kv or (h, h)), cfg.hidden_size, cfg.num_attention_heads,
+            bias=True, qkv_bias=True, causal=True,
+            num_kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+            window=window, differential=True,
+            lambda_init=0.8 - 0.6 * math.exp(-0.3 * depth),
+            lambda_scale=cfg.diff_lambda_scale,
+            diff_norm_eps=eps, kv_given=kv is not None, export_kv=export,
+            name=f"b{i}_attn")
+
+    if letter in "my":
+        a = ff.mamba_mixer(
+            h, state_size=cfg.mamba_d_state, conv_kernel=cfg.mamba_d_conv,
+            expand=cfg.mamba_expand, dt_rank=cfg.mamba_dt_rank,
+            export_memory=letter == "y", export_gated=cfg.memory_gated,
+            time_step_min=cfg.time_step_min,
+            time_step_max=cfg.time_step_max, name=f"b{i}_mixer")
+        if letter == "y":
+            a, shared["memory"] = a
+    elif letter == "w":
+        a = attention(window=cfg.sliding_window)
+    elif letter == "f":
+        a, *shared["kv"] = attention(export=True)
+    elif letter == "g":
+        if "memory" not in shared:
+            raise missing("the memory (the scan's output)", half)
+        with ff.scope("gated_memory"):
+            width = shared["memory"].shape[-1]
+            gate = ff.dense(h, width, use_bias=False,
+                            name=f"b{i}_memory_in_proj")
+            silu = ff.multiply(gate, ff.sigmoid(gate, name=f"b{i}_memory_sig"),
+                               name=f"b{i}_memory_silu")
+            a = ff.dense(ff.multiply(silu, shared["memory"],
+                                     name=f"b{i}_memory_gated"),
+                         cfg.hidden_size, use_bias=False,
+                         name=f"b{i}_memory_out_proj")
+    else:
+        if cfg.cross_own_kv:
+            a = attention()
+        elif "kv" not in shared:
+            raise missing("the shared keys and values", half + 1)
+        else:
+            a = attention(kv=shared["kv"])
+    t = ff.add(t, a, name=f"b{i}_res1")
+    g = ff.layer_norm(t, eps=eps, name=f"b{i}_post_norm")
+    return ff.add(t, _swiglu_mlp(ff, g, cfg, f"b{i}", one_product=True),
+                  name=f"b{i}_res2")
+
+
 def _mtp_module(ff, embedded, x_last, cfg):
     """The multi-token-prediction module's hidden states, normed for the
     shared head: [B, S, E] whose row i stands for the token after next."""
@@ -477,12 +614,16 @@ def _mixer(ff, h, letter, i, cfg):
         return ff.dense(ff.multiply(up, up, name=f"b{i}_sq"),
                         cfg.hidden_size, use_bias=False, name=name)
     raise ValueError(f"decoder pattern: unknown block letter {letter!r} "
-                     f"(known: M E * - L G W D A X F S C U)")
+                     f"(known: M E * - L G W D A X F S C U m w y f g c)")
 
 
 def _stack(ff, t, pattern, cfg):
     """One application of the blocks ``pattern`` names."""
+    shared = {"pattern": pattern}   # what `y` and `f` make for `g` and `c`
     for i, letter in enumerate(pattern):
+        if letter in SAMBAY_LETTERS:
+            t = _sambay_block(ff, t, i, letter, cfg, shared)
+            continue
         if letter == "U":
             t = _attention_ffn_block(
                 ff, t, f"b{i}", cfg,
@@ -541,6 +682,8 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
     t = embedded = ff.embedding(ids, cfg.vocab_size, cfg.hidden_size,
                                 name="embed_tokens")
     pattern = cfg.hybrid_override_pattern
+    if cfg.mb_per_layer:
+        pattern = sambay_pattern(cfg)
     if cfg.layer_types is not None:
         unknown = set(cfg.layer_types) - set(LAYER_TYPE_LETTERS)
         if unknown:
@@ -562,7 +705,10 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
         t = ff.split(t, [half, half], axis=1, name="noised_half")[0]
     mtp = (_mtp_module(ff, embedded, t, cfg)
            if cfg.num_nextn_predict_layers else None)
-    t = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name="final_ln")
+    # a decoder-hybrid-decoder model's norms are LayerNorms with bias
+    final_norm = (ff.layer_norm if set(pattern) <= set(SAMBAY_LETTERS)
+                  else ff.rms_norm)
+    t = final_norm(t, eps=cfg.layer_norm_epsilon, name="final_ln")
     if mtp is not None:
         # one head over both hidden sequences laid end to end
         t = ff.concat([t, mtp], axis=1, name="main_and_mtp")

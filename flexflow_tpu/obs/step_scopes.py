@@ -31,6 +31,10 @@ SUFFIX = ".step_scopes.json"
 # `op_<kind>` -> itself and `ut<t>` -> itself
 SCOPE_PARTS = {"optimizer_update": "optimizer_update", "loss": "loss",
                "head": "head", "moe_layer": "experts", "ssm_mixer": "ssm",
+               # the Mamba-1 mixer, and the gated memory unit that reads
+               # its scan's output (the builder's scope around the
+               # unit's products and multiplies)
+               "mamba_mixer": "mamba", "gated_memory": "gated_memory",
                # a multi-token-prediction module's own ops, whatever their
                # kind: the scope lies around theirs (`FFModel.scope`)
                "mtp": "mtp",

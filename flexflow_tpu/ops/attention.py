@@ -353,6 +353,29 @@ class MultiHeadAttention(Op):
     the same float32 products from the same tables, at the price of
     XLA's copies of the whole array between the two layouts.
 
+    Three more (PR 52), off by default. ``differential`` (Ye et al.,
+    "Differential Transformer"): the heads go in pairs; pair p's two
+    query heads (2p, 2p + 1) meet key heads (2P, 2P + 1) of its
+    key/value pair P = p // (H / Hk) in TWO softmax maps, whose
+    difference A1 - lambda A2 weighs the pair's values [v_2P ; v_2P+1],
+    2 D wide; the pair's output is RMS-normed over its 2 D lanes
+    (``diff_norm``, eps ``diff_norm_eps``), scaled by 1 - lambda_init and
+    goes back as heads 2p, 2p + 1. lambda = exp(lambda_q1 . lambda_k1) -
+    exp(lambda_q2 . lambda_k2) + ``lambda_init``, four float32 leaves of
+    ``head_dim``. Both maps run the op's ordinary core on heads of 2 D:
+    a pair's lanes of q as they lie are [q1 ; q2], of k [k1 ; k2] and of
+    v the pair's values, so map j is the core at H / 2 heads of 2 D over
+    Hk / 2 key/value heads with the OTHER query head's lanes zeroed, q
+    times sqrt(2) (the cores scale by (2 D)^-1/2, the maps by D^-1/2):
+    two flash calls a forward (scope ``flash_diff``), no kernel of its
+    own, and the MXU contracts 128 lanes where D = 64 would fill half.
+    ``kv_given``: inputs [x, k, v] with k, v [B, S, Hk * D] ALREADY
+    projected by another op; the op then holds wq and wo alone
+    (``attention_diff_cross`` / ``attention_cross``), and at Sq == Sk it
+    takes the flash route like any self-attention. ``export_kv``: the
+    op's projected k and v (after their biases) are its second and third
+    OUTPUT, for such readers.
+
     ``route`` is where every such choice is made (core, rotary form, K/V
     form, the `shard_map`'s axes): a further property or operand form is
     decided there, recorded as a field of `AttentionRoute` and published
@@ -362,7 +385,8 @@ class MultiHeadAttention(Op):
 
     # the stem of the op's own scope: `forward` adds the route's kind
     scopes_itself = "attention_"
-    # the gate's product is float32, as a router's
+    # the gate's product is float32, as a router's (a differential op
+    # adds lambda's four vectors, `__init__`)
     full_precision_params = ("w_gate",)
 
     def __init__(self, layer, input_shapes):
@@ -472,6 +496,41 @@ class MultiHeadAttention(Op):
         # route's `fallback`) — fflint FFL209 surfaces the
         # priced-vs-executed gap.
         self.kernel_impl = p.get("kernel_impl", None)
+        # differential attention, keys/values given, keys/values exported
+        self.differential = bool(p.get("differential", False))
+        self.lambda_init = float(p.get("lambda_init", 0.8))
+        self.lambda_scale = float(p.get("lambda_scale", 1.0))   # a control
+        if self.differential:
+            self.full_precision_params += ("lambda_q1", "lambda_k1",
+                                           "lambda_q2", "lambda_k2")
+        self.diff_norm_eps = p.get("diff_norm_eps", 1e-5)
+        self.kv_given = bool(p.get("kv_given", False))
+        self.export_kv = bool(p.get("export_kv", False))
+        if self.differential and (
+                self.latent or self.rope or self.qk_norm or self.gate
+                or self.block_diffusion or self.seq_parallel
+                or self.num_heads % 2 or self.num_kv_heads % 2):
+            raise ValueError(
+                f"attention '{layer.name}': differential attention takes "
+                f"even numbers of query and key/value heads and no latent, "
+                f"rotary, head norm, gate, block-diffusion mask or ring")
+        if (self.kv_given or self.export_kv) and (self.latent or self.rope
+                                                  or self.qk_norm):
+            raise ValueError(
+                f"attention '{layer.name}': keys and values are given or "
+                f"exported as projected: no latent, rotary or head norm")
+        if self.kv_given and (len(input_shapes) != 3 or self.export_kv or any(
+                tuple(shape[2:]) != (self.num_kv_heads * self.head_dim,)
+                for shape in input_shapes[1:])):
+            raise ValueError(
+                f"attention '{layer.name}': kv_given takes [x, k, v] with "
+                f"k, v [B, S, {self.num_kv_heads} * {self.head_dim}] "
+                f"(got {list(input_shapes)}), and exports none")
+        # the heads the CORE runs: under differential attention a pair
+        # of heads is one head of twice the width
+        pair = 2 if self.differential else 1
+        self.core_heads = (self.num_heads // pair,
+                           self.num_kv_heads // pair, self.head_dim * pair)
         self._kernel_fallback = None
         # the `AttentionRoute` of the last forward traced (what
         # `traced_gauges` publishes); None until one has been
@@ -482,9 +541,16 @@ class MultiHeadAttention(Op):
         self.kernel_init = p.get("kernel_initializer") or DefaultWeightInitializer()
         super().__init__(layer, input_shapes)
 
+    @property
+    def exports(self) -> int:
+        """Outputs beyond the first that other layers read."""
+        return 2 if self.export_kv else 0
+
     def compute_output_shapes(self):
         b, sq, _ = self.input_shapes[0]
-        return [(b, sq, self.embed_dim)]
+        sk = self.input_shapes[1][1] if len(self.input_shapes) > 1 else sq
+        return [(b, sq, self.embed_dim)] + [
+            (b, sk, self.num_kv_heads * self.head_dim)] * self.exports
 
     def init_params(self, rng):
         h, e, d = self.num_heads, self.embed_dim, self.head_dim
@@ -507,6 +573,15 @@ class MultiHeadAttention(Op):
             "wv": self.kernel_init(ks[2], (hk, self.vdim, d)),
             "wo": self.kernel_init(ks[3], (h, d, e)),
         }
+        if self.kv_given:
+            del params["wk"], params["wv"]
+        if self.differential:
+            # keys of their own: the four above stay what they were
+            for i, name in enumerate(("lambda_q1", "lambda_k1",
+                                      "lambda_q2", "lambda_k2")):
+                params[name] = 0.1 * jax.random.normal(
+                    jax.random.fold_in(rng, 5 + i), (d,), jnp.float32)
+            params["diff_norm"] = jnp.ones((2 * d,))
         if self.qk_norm:
             params["q_norm"] = jnp.ones((d,))
             params["k_norm"] = jnp.ones((d,))
@@ -521,8 +596,9 @@ class MultiHeadAttention(Op):
                 # with the weights (torch in_proj_bias parity); bk/bv
                 # carry the kv-head count under GQA
                 params["bq"] = jnp.zeros((h, d))
-                params["bk"] = jnp.zeros((hk, d))
-                params["bv"] = jnp.zeros((hk, d))
+                if not self.kv_given:
+                    params["bk"] = jnp.zeros((hk, d))
+                    params["bv"] = jnp.zeros((hk, d))
         return params
 
     def _project(self, x, w, bias, cd):
@@ -611,7 +687,7 @@ class MultiHeadAttention(Op):
         sq = shapes[0][1] if sq is None else sq
         if sk is None:
             sk = shapes[1][1] if len(shapes) > 1 else sq
-        h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
+        h, hk, d = self.core_heads
         dropout_rate = self.dropout if training else 0.0
 
         def legal(heads):   # the kernels' shape rule, at these widths
@@ -650,7 +726,10 @@ class MultiHeadAttention(Op):
         elif self.latent:
             scope = "latent"
         elif self.causal:
-            scope = "window" if self.windowed else "full"
+            scope = ("cross" if self.kv_given
+                     else "window" if self.windowed else "full")
+            if self.differential:
+                scope = "diff_" + scope
         else:
             scope = "plain"
         one_device = not any(n > 1 for n in mesh_axes.values())
@@ -731,11 +810,17 @@ class MultiHeadAttention(Op):
         hidden pair and run the masked body (PR 35);
         `attention/window_keys_*`: of a window op that ran flash, the
         (query, key) pairs a head's kernels work through, forward and
-        backward, against twice the pairs visible (PR 41)."""
+        backward, against twice the pairs visible (PR 41);
+        `executor.flash_diff_ops`: a differential op whose two maps ran
+        the flash kernels (PR 52)."""
         route = self._route or AttentionRoute("einsum")   # not traced
         visited, total, masked = route.kv_blocks or (0, 0, 0)
         keys_visited, keys_visible = route.window_pairs or (0, 0)
+        # only a differential op publishes its key, as a tied product its
+        extra = ({"executor.flash_diff_ops": int(route.core == "flash")}
+                 if self.differential else {})
         return {
+            **extra,
             "executor.flash_lane_dense_ops": int(route.core == "flash"),
             "executor.rotary_lane_dense_ops": int(route.rotary_in_lanes),
             "executor.flash_grouped_kv_ops": int(route.grouped_kv),
@@ -777,10 +862,18 @@ class MultiHeadAttention(Op):
                                or route.blocked in (None, "shape")):
             self._kernel_fallback = route.fallback
         if route.scope == "plain":
-            return self._forward(params, inputs, ctx, rng, route)
-        return scoped(self.scopes_itself + route.scope,
-                      lambda params, inputs: self._forward(
-                          params, inputs, ctx, rng, route))(params, inputs)
+            outs, lam = self._forward(params, inputs, ctx, rng, route)
+        else:
+            outs, lam = scoped(self.scopes_itself + route.scope,
+                               lambda params, inputs: self._forward(
+                                   params, inputs, ctx, rng, route))(
+                                       params, inputs)
+        if lam is not None:
+            # the layer's lambda leaves the step with the ops' counters
+            self._counters = {"attention/diff_lambda_"
+                              + self.name.removesuffix("_attn"):
+                              ("mean", lam)}
+        return outs
 
     def _forward(self, params, inputs, ctx: OpContext, rng,
                  route: AttentionRoute):
@@ -794,12 +887,21 @@ class MultiHeadAttention(Op):
         q, k, v, rope = around(lambda params, inputs: (
             self._qkv_latent(params, inputs, ctx) if self.latent
             else self._qkv(params, inputs, ctx, route)))(params, inputs)
-        o = self._core(q, k, v, ctx, rng, route, around, rope)
+        dtype = inputs[0].dtype
+        exported = [t.astype(dtype) for t in (k, v)] if self.export_kv else []
+        k, v = self._kv_for_core(k, v, ctx, route)
+        lam = None
+        if self.differential:
+            o, lam = self._differential(params, q, k, v, ctx, rng, route,
+                                        around)
+        else:
+            o = self._core(q.astype(ctx.compute_dtype), k, v, ctx, rng,
+                           route, around, rope)
         if self.gate:
             o = scoped("attention_gate", lambda w, x, o: self._gated(
                 w, x, o, ctx))(params["w_gate"], inputs[0], o)
         return [around(lambda params, o: self._output(
-            params, o, ctx, inputs[0].dtype))(params, o)]
+            params, o, ctx, dtype))(params, o)] + exported, lam
 
     def _qkv(self, params, inputs, ctx: OpContext, route: AttentionRoute):
         """q [B, S, H*D] in the compute dtype and k, v: the projections,
@@ -812,22 +914,32 @@ class MultiHeadAttention(Op):
         sums, as the repeat's backward gave them."""
         query, key, value = (inputs + inputs[:1] * 2)[:3] if len(inputs) == 1 else inputs
         cd = ctx.compute_dtype
-        h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
+        h, hk = self.num_heads, self.num_kv_heads
         biased = self.qkv_bias and "bq" in params
         # q, k, v and o stay [B, S, heads*head_dim] from the projections
         # to the output projection: what the products write, what the
         # flash kernels take, and no minor dimension under 128 lanes
         q = self._project(query, params["wq"], params["bq"] if biased else None, cd)
+        if self.kv_given:   # as another op projected (and exported) them
+            return q, key, value, None
         k = self._project(key, params["wk"], params["bk"] if biased else None, cd)
         v = self._project(value, params["wv"], params["bv"] if biased else None, cd)
-        b, sk = k.shape[0], k.shape[1]
         if self.rope:
             q, k = self._rotated(q, k, params, ctx, route.rotary_in_lanes)
         elif self.qk_norm:
             q = self._heads_normed(q, h, params["q_norm"])
             k = self._heads_normed(k, hk, params["k_norm"])
-        if route.grouped_kv:
-            return q.astype(cd), k, v, None
+        return q, k, v, None
+
+    def _kv_for_core(self, k, v, ctx: OpContext, route: AttentionRoute):
+        """k and v as the core takes them: at the KV heads, as they are
+        (float32 where the projection left them so), where the flash
+        kernels read a group's keys (``route.grouped_kv``); else repeated
+        to the core's heads, in the compute dtype."""
+        if self.latent or route.grouped_kv:
+            return k, v
+        h, hk, d = self.core_heads
+        b, sk = k.shape[0], k.shape[1]
         if hk != h:
             k, v = (jnp.repeat(x.reshape(b, sk, hk, d), h // hk, axis=2
                                ).reshape(b, sk, h * d) for x in (k, v))
@@ -835,7 +947,32 @@ class MultiHeadAttention(Op):
         # projections accumulate in f32): softmax/accumulation inside every
         # path below is f32 regardless, and bf16 kernel I/O halves the
         # flash kernel's HBM traffic
-        return q.astype(cd), k.astype(cd), v.astype(cd), None
+        cd = ctx.compute_dtype
+        return k.astype(cd), v.astype(cd)
+
+    def _differential(self, params, q, k, v, ctx: OpContext, rng,
+                      route: AttentionRoute, around):
+        """(o [B, S, H*D] in the compute dtype, lambda): the two maps as
+        two runs of the core at heads of 2 D (class docstring), their
+        difference, the pairs' norm and 1 - lambda_init."""
+        f32, cd = jnp.float32, ctx.compute_dtype
+        d2 = 2 * self.head_dim
+        first = (jnp.arange(q.shape[-1]) % d2) < self.head_dim
+        q = q.astype(f32) * (2.0 ** 0.5)
+        maps = [self._core(jnp.where(keep, q, 0.0).astype(cd), k, v, ctx,
+                           rng, route, around)
+                for keep in (first, ~first)]
+        lam = (jnp.exp(jnp.sum(params["lambda_q1"].astype(f32)
+                               * params["lambda_k1"].astype(f32)))
+               - jnp.exp(jnp.sum(params["lambda_q2"].astype(f32)
+                                 * params["lambda_k2"].astype(f32)))
+               + self.lambda_init)
+        o = maps[0].astype(f32) - (self.lambda_scale * lam
+                                   ) * maps[1].astype(f32)
+        pairs = rms_normed(o.reshape(o.shape[:2] + (-1, d2)),
+                           params["diff_norm"], self.diff_norm_eps)
+        return (pairs * (1.0 - self.lambda_init)).reshape(o.shape).astype(
+            cd), lam
 
     def _rotated(self, q, k, params, ctx: OpContext, lanes: bool):
         """The heads' norms (``qk_norm``) and rotary of q [B, S, H*D] and
@@ -958,7 +1095,7 @@ class MultiHeadAttention(Op):
         from flexflow_tpu.ops.pallas_kernels import merge_heads, split_heads
 
         cd = ctx.compute_dtype
-        h = self.num_heads
+        h, hk, _ = self.core_heads
         rope_dim = self.rope_dim
         dropout_rate = self.dropout if ctx.training else 0.0
 
@@ -1005,11 +1142,12 @@ class MultiHeadAttention(Op):
                 flash_attention, flash_attention_sharded)
 
             flash_scope = (None if route.scope == "plain"
+                           else "flash_diff" if self.differential
                            else "flash_" + route.scope)
 
             def flash(kernel, **where):
                 if route.grouped_kv:   # k, v are [B, S, Hk*D]
-                    where["num_kv_heads"] = self.num_kv_heads
+                    where["num_kv_heads"] = hk
                 call = functools.partial(
                     kernel, num_heads=h, causal=self.causal,
                     window=self.window,
@@ -1050,6 +1188,12 @@ class MultiHeadAttention(Op):
         (a bidirectional row would need future K/V that doesn't exist
         yet); non-causal ops refuse rather than silently drift.
         """
+        if self.differential or self.kv_given or self.export_kv:
+            raise NotImplementedError(
+                f"attention '{self.name}': KV-cache incremental decode has "
+                f"no differential attention and no keys/values shared "
+                f"between layers (ONE cache entry would serve the producer "
+                f"and every reader; this path holds one a layer)")
         if self.latent:
             raise NotImplementedError(
                 f"attention '{self.name}': KV-cache incremental decode has "
@@ -1156,7 +1300,8 @@ class MultiHeadAttention(Op):
         return y.astype(query.dtype), k_cache, v_cache
 
     def output_dim_roles(self):
-        return [(DimRole.SAMPLE, DimRole.SEQ, DimRole.CHANNEL)]
+        return [(DimRole.SAMPLE, DimRole.SEQ, DimRole.CHANNEL)] * (
+            1 + self.exports)
 
     def flops(self):
         b, sq, e = self.input_shapes[0]
@@ -1169,13 +1314,16 @@ class MultiHeadAttention(Op):
             return (2 * b * sq * matrices
                     + 2 * b * h * self.visible_pairs * (2 * d + self.rope_dim))
         hk = self.num_kv_heads  # GQA: k/v projections use the kv heads
-        proj = (2 * b * h * d * (sq * e + sq * e)
-                + 2 * b * hk * d * (sk * self.kdim + sk * self.vdim))
+        proj = 2 * b * h * d * (sq * e + sq * e)
+        if not self.kv_given:
+            proj += 2 * b * hk * d * (sk * self.kdim + sk * self.vdim)
         # under a window a query meets at most `window` keys: S x W
         # products, not S^2 (a plain causal layer is priced at the whole
         # square, as it always was); under the block-diffusion mask the
         # pairs it leaves, counted exactly
-        core = 2 * b * h * self.visible_pairs * d * 2
+        # (a differential pair's two maps weigh values twice as wide)
+        core = 2 * b * h * self.visible_pairs * d * (
+            3 if self.differential else 2)
         # the gate's product and its multiply of the core's output
         gate = (2 * e + d) * b * sq * h if self.gate else 0
         return proj + core + gate
@@ -1186,12 +1334,14 @@ class MultiHeadAttention(Op):
             rq, rkv, r = self.latent
             return (e * rq + rq + rq * h * (d + r) + e * (rkv + r) + rkv
                     + rkv * h * 2 * d + h * d * e)
-        hk = self.num_kv_heads
+        hk = 0 if self.kv_given else self.num_kv_heads
         n = h * d * (e + e) + hk * d * (self.kdim + self.vdim)
         if self.qk_norm:
             n += 2 * d
         if self.gate:
             n += e * h
+        if self.differential:
+            n += 6 * d
         if self.use_bias:
             n += e + ((h + 2 * hk) * d if self.qkv_bias else 0)
         return n

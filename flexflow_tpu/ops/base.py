@@ -77,7 +77,10 @@ def scoped(name: str, fn: Callable) -> Callable:
     / `moe_grouped_matmul` / `moe_shared`, `attention_window` /
     `attention_full` / `attention_block_diffusion` with `flash_window` /
     `flash_full` / `flash_block_diffusion` in them, and
-    `attention_plain`; `gated_conv` inside `op_short_conv` (PR 45). The
+    `attention_plain`; `gated_conv` inside `op_short_conv` (PR 45);
+    `mamba_mixer` / `selective_scan`, `gated_memory`,
+    `attention_diff_full` / `attention_diff_window` /
+    `attention_diff_cross` with `flash_diff` in them (PR 52). The
     benchmark's readers match nine of them as bare substrings of an
     `op_name`, so a new name holds none of them.
 
@@ -118,6 +121,12 @@ class Op:
     # the nested call (`scoped`) the op's forward makes around itself;
     # "": the executor wraps the op in one named for its kind
     scopes_itself: str = ""
+    # outputs beyond the first that OTHER LAYERS read (a scan's memory,
+    # an attention op's projected keys and values): PCG tensors like any
+    # other, whose readers take them as inputs; the producer's gradient
+    # sums over them. No rewrite re-forms such an op or its readers, and
+    # no remat twin stands for it (search/unity.py `serialize_graph`)
+    exports: int = 0
 
     def __init_subclass__(cls, **kw):
         super().__init_subclass__(**kw)
@@ -248,6 +257,19 @@ def register_op(op_type: OperatorType):
         return klass
 
     return deco
+
+
+def exported_reads(nodes) -> Tuple[set, List[Tuple[int, Tuple[int, int]]]]:
+    """(outputs, reads) of a node list: the (guid, index) of every output
+    beyond an op's first that other layers read (``Op.exports``), and the
+    input edges that read one, as (reader's guid, (guid, index)). The one
+    place the PCG's exported edges are found: the executor counts them,
+    the search pins both their ends."""
+    outputs = {(n.guid, i) for n in nodes
+               for i in range(1, 1 + n.op.exports)}
+    reads = [(n.guid, tuple(ref[1:3])) for n in nodes for ref in n.input_refs
+             if ref[0] == "op" and tuple(ref[1:3]) in outputs]
+    return outputs, reads
 
 
 def shared_leaves_error(op: "Op", ops: Dict[str, "Op"]) -> Optional[str]:

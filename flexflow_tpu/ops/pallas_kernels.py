@@ -79,7 +79,10 @@ layer's sum of rows into their tokens, with its transpose
 `moe_spread_rows` (PR 49), that sum's backward; and `rotary_lanes` (PR
 42), the heads' RMS norm and rotary in one pass over a projection's
 [B, S, H*128] result (or [B, S, H*64], two heads a 128-lane column, PR
-47), between the product and a flash kernel.
+47), between the product and a flash kernel; `gated_conv_lanes` (PR 45),
+the gated short convolution's pass between its two products; and
+`selective_scan` (PR 52), the Mamba-1 recurrence with a decay a channel
+and state, forward and backward, time walked inside the kernel.
 
 CPU fallback: the same kernels run under ``interpret=True`` when
 FLEXFLOW_TPU_PALLAS=interpret (used by the deviceless tests); otherwise
@@ -2718,6 +2721,259 @@ def gated_conv_lanes(proj, w, gate: bool = True):
     the rows in the kernel. Caller checks `gated_conv_shape_legal` and
     `pallas_mode` first."""
     return _gated_conv_lanes(proj, w, gate, pallas_mode() == "interpret")
+
+
+# ---------------------------------------------------------------------------
+# The selective scan of a Mamba-1 mixer (PR 52):
+#     h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+#     y_t[c]    = sum_n h_t[n, c] C_t[n] + D[c] x_t[c]
+# The decay differs by channel AND state, so there is no chunked-product
+# form (`ops/ssm.py` `ssd_chunked`), and the state [N, C] a position is
+# never written to HBM: time is walked inside the kernel, the state stays
+# in VMEM. A vreg holds 8 x 128 = 1024 CHANNELS of one position
+# (operands [B, S, C / 128, 128], whose (8, 128) tiles XLA lays out in
+# one pass over [B, S, C]), the N states are N such arrays, and B_t[n],
+# C_t[n] are scalars out of SMEM: every sum over the states is a
+# vreg-wise add, and the forward holds no cross-lane operation at all.
+# The backward walks a chunk forward again from the state kept at the
+# chunk's start, then backward; its sums over the CHANNELS (dB_t[n],
+# dC_t[n]) are the one cross-lane work: added over the channel vregs
+# first, one sublane reduce a (position, state), the lanes once a chunk.
+
+SCAN_CHUNK = 64       # positions a grid step; the backward keeps their states
+SCAN_CHANNELS = 1024  # channels a vreg: 8 sublanes x 128 lanes
+_SCAN_COMPILER_PARAMS = dict(vmem_limit_bytes=100 << 20)
+
+
+def _scan_fwd_kernel(b_ref, c_ref, x_ref, dt_ref, a_ref, d_ref, y_ref,
+                     hs_ref, h_scr, *, states: int, chunk: int):
+    """One chunk of positions, all channels: the state entering the chunk
+    goes out (the backward starts from it), then the recurrence a
+    position a loop step."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        h_scr[...] = jnp.zeros_like(h_scr)
+
+    hs_ref[0, 0] = h_scr[...]
+
+    def step(t, carry):
+        dt, x = dt_ref[0, t], x_ref[0, t]
+        u = dt * x
+        y = d_ref[...] * x
+        for n in range(states):
+            h = jnp.exp(dt * a_ref[n]) * h_scr[n] + u * b_ref[0, 0, t * states + n]
+            h_scr[n] = h
+            y = y + h * c_ref[0, 0, t * states + n]
+        y_ref[0, t] = y
+        return carry
+
+    jax.lax.fori_loop(0, chunk, step, None)
+
+
+def _scan_bwd_kernel(b_ref, c_ref, x_ref, dt_ref, dy_ref, a_ref, d_ref,
+                     hs_ref, dx_ref, ddt_ref, dbc_ref, da_ref, dd_ref,
+                     h_all, g_scr, rows, *, states: int, chunk: int):
+    """One chunk, the chunks in REVERSE order. The chunk's states once
+    more from the one kept at its start (``h_all[t + 1]`` = h_t), then
+    from its last position down: g_t = dy_t C_t + a_{t+1} g_{t+1} (the
+    cotangent of h_t, carried across chunks in ``g_scr``);
+    dC_t[n] = sum_c h_t dy_t, dB_t[n] = sum_c g_t u_t (``rows`` holds
+    their sublane sums, the lanes are added once a chunk);
+    du_t = sum_n g_t B_t[n]; with e = g_t h_{t-1} a_t (= dL/d(dt_t A)):
+    d dt_t = sum_n e A + du_t x_t, dA += e dt_t; dx_t = du_t dt_t +
+    D dy_t, dD += dy_t x_t."""
+    first = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
+
+    @pl.when(first)
+    def _():
+        da_ref[...] = jnp.zeros_like(da_ref)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        g_scr[...] = jnp.zeros_like(g_scr)
+
+    h_all[0] = hs_ref[0, 0]
+
+    def forward(t, carry):
+        dt = dt_ref[0, t]
+        u = dt * x_ref[0, t]
+        for n in range(states):
+            h_all[t + 1, n] = (jnp.exp(dt * a_ref[n]) * h_all[t, n]
+                               + u * b_ref[0, 0, t * states + n])
+        return carry
+
+    jax.lax.fori_loop(0, chunk, forward, None)
+
+    def backward(i, carry):
+        t = chunk - 1 - i
+        dt, x, dy = dt_ref[0, t], x_ref[0, t], dy_ref[0, t]
+        u = dt * x
+        du = jnp.zeros_like(u)
+        ddt = jnp.zeros_like(u)
+        for n in range(states):
+            a_n = a_ref[n]
+            decay = jnp.exp(dt * a_n)
+            g = g_scr[n] + dy * c_ref[0, 0, t * states + n]
+            rows[t, n:n + 1, :] = jnp.sum(
+                jnp.sum(h_all[t + 1, n] * dy, axis=0), axis=0, keepdims=True)
+            rows[t, states + n:states + n + 1, :] = jnp.sum(
+                jnp.sum(g * u, axis=0), axis=0, keepdims=True)
+            du = du + g * b_ref[0, 0, t * states + n]
+            e = g * h_all[t, n] * decay
+            ddt = ddt + e * a_n
+            da_ref[n] += e * dt
+            g_scr[n] = g * decay
+        ddt_ref[0, t] = ddt + du * x
+        dx_ref[0, t] = du * dt + d_ref[...] * dy
+        dd_ref[...] += dy * x
+        return carry
+
+    jax.lax.fori_loop(0, chunk, backward, None)
+    dbc_ref[0] = jnp.sum(rows[...], axis=-1)
+
+
+def _scan_blocks(chunks: int, vregs: int, states: int, chunk: int,
+                 reverse: bool):
+    """The BlockSpecs both kernels share; ``reverse``: the grid's second
+    index counts the chunks from the last."""
+    def at(j):
+        return chunks - 1 - j if reverse else j
+
+    scalars = pl.BlockSpec((1, 1, chunk * states),
+                           lambda b, j: (b * chunks + at(j), 0, 0),
+                           memory_space=pltpu.SMEM)
+    rows = pl.BlockSpec((1, chunk, vregs, 8, LANES),
+                        lambda b, j: (b, at(j), 0, 0, 0))
+    a = pl.BlockSpec((states, vregs, 8, LANES), lambda b, j: (0, 0, 0, 0))
+    d = pl.BlockSpec((vregs, 8, LANES), lambda b, j: (0, 0, 0))
+    kept = pl.BlockSpec((1, 1, states, vregs, 8, LANES),
+                        lambda b, j: (b, at(j), 0, 0, 0, 0))
+    return scalars, rows, a, d, kept
+
+
+def _scan_rows(t):
+    """t [B, S, C] as the kernels read it, float32 [B, S', V, 8, 128]:
+    S' whole chunks and V whole vregs of channels, zeros past the end
+    (dt = 0 there: decay 1, no input)."""
+    batch, s, c = t.shape
+    s_pad, c_pad = (-s) % SCAN_CHUNK, (-c) % SCAN_CHANNELS
+    t = jnp.pad(t.astype(jnp.float32), ((0, 0), (0, s_pad), (0, c_pad)))
+    return t.reshape(batch, s + s_pad, (c + c_pad) // SCAN_CHANNELS, 8, LANES)
+
+
+def _scan_operands(x, dt, bm, cm, a, d):
+    """The kernels' operand forms, float32: x and dt by `_scan_rows`, B
+    and C [B * chunks, 1, chunk * N] (SMEM), A [N, V, 8, 128], D [V, 8,
+    128]."""
+    f32 = jnp.float32
+    batch, s, c = x.shape
+    n = bm.shape[-1]
+    s_pad, c_pad = (-s) % SCAN_CHUNK, (-c) % SCAN_CHANNELS
+
+    def scalars(t):
+        t = jnp.pad(t.astype(f32), ((0, 0), (0, s_pad), (0, 0)))
+        return t.reshape(batch * (s + s_pad) // SCAN_CHUNK, 1, SCAN_CHUNK * n)
+
+    a = jnp.pad(a.astype(f32).T, ((0, 0), (0, c_pad))).reshape(
+        n, -1, 8, LANES)
+    d = jnp.pad(d.astype(f32), (0, c_pad)).reshape(-1, 8, LANES)
+    return _scan_rows(x), _scan_rows(dt), scalars(bm), scalars(cm), a, d
+
+
+def _scan_forward(x, dt, bm, cm, a, d, interpret):
+    batch, s, c = x.shape
+    n = bm.shape[-1]
+    xs, dts, bs, cs, a4, d3 = _scan_operands(x, dt, bm, cm, a, d)
+    chunks, vregs = xs.shape[1] // SCAN_CHUNK, xs.shape[2]
+    scalars, rows, a_spec, d_spec, kept = _scan_blocks(
+        chunks, vregs, n, SCAN_CHUNK, False)
+    y, hs = pl.pallas_call(
+        functools.partial(_scan_fwd_kernel, states=n, chunk=SCAN_CHUNK),
+        name="selective_scan_fwd",
+        out_shape=(jax.ShapeDtypeStruct(xs.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((batch, chunks, n, vregs, 8, LANES),
+                                        jnp.float32)),
+        grid=(batch, chunks),
+        in_specs=[scalars, scalars, rows, rows, a_spec, d_spec],
+        out_specs=(rows, kept),
+        scratch_shapes=[pltpu.VMEM((n, vregs, 8, LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            **_SCAN_COMPILER_PARAMS),
+        interpret=interpret)(bs, cs, xs, dts, a4, d3)
+    return y.reshape(batch, -1, vregs * SCAN_CHANNELS)[:, :s, :c], hs
+
+
+def _scan_backward(x, dt, bm, cm, a, d, hs, dy, interpret):
+    batch, s, c = x.shape
+    n = bm.shape[-1]
+    xs, dts, bs, cs, a4, d3 = _scan_operands(x, dt, bm, cm, a, d)
+    dys = _scan_rows(dy)
+    chunks, vregs = xs.shape[1] // SCAN_CHUNK, xs.shape[2]
+    scalars, rows, a_spec, d_spec, kept = _scan_blocks(
+        chunks, vregs, n, SCAN_CHUNK, True)
+    sums = pl.BlockSpec((1, SCAN_CHUNK, 2 * n),
+                        lambda b, j: (b * chunks + chunks - 1 - j, 0, 0))
+    dx, ddt, dbc, da, dd = pl.pallas_call(
+        functools.partial(_scan_bwd_kernel, states=n, chunk=SCAN_CHUNK),
+        name="selective_scan_bwd",
+        out_shape=(jax.ShapeDtypeStruct(xs.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(xs.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((batch * chunks, SCAN_CHUNK, 2 * n),
+                                        jnp.float32),
+                   jax.ShapeDtypeStruct(a4.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(d3.shape, jnp.float32)),
+        grid=(batch, chunks),
+        in_specs=[scalars, scalars, rows, rows, rows, a_spec, d_spec, kept],
+        out_specs=(rows, rows, sums, a_spec, d_spec),
+        scratch_shapes=[
+            pltpu.VMEM((SCAN_CHUNK + 1, n, vregs, 8, LANES), jnp.float32),
+            pltpu.VMEM((n, vregs, 8, LANES), jnp.float32),
+            pltpu.VMEM((SCAN_CHUNK, 2 * n, LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            **_SCAN_COMPILER_PARAMS),
+        interpret=interpret)(bs, cs, xs, dts, dys, a4, d3, hs)
+
+    def unrowed(t):
+        return t.reshape(batch, -1, vregs * SCAN_CHANNELS)[:, :s, :c]
+
+    dbc = dbc.reshape(batch, -1, 2 * n)[:, :s]
+    return (unrowed(dx), unrowed(ddt), dbc[..., n:], dbc[..., :n],
+            da.reshape(n, -1)[:, :c].T, dd.reshape(-1)[:c])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _selective_scan(x, dt, bm, cm, a, d, interpret):
+    return _scan_forward(x, dt, bm, cm, a, d, interpret)[0]
+
+
+def _selective_scan_fwd(x, dt, bm, cm, a, d, interpret):
+    y, hs = _scan_forward(x, dt, bm, cm, a, d, interpret)
+    return y, (x, dt, bm, cm, a, d, hs)
+
+
+def _selective_scan_bwd(interpret, kept, dy):
+    # a cotangent in its operand's dtype (x comes in the compute dtype)
+    return tuple(g.astype(t.dtype) for g, t in
+                 zip(_scan_backward(*kept, dy, interpret), kept))
+
+
+_selective_scan.defvjp(_selective_scan_fwd, _selective_scan_bwd)
+
+
+def selective_scan(x, dt, bm, cm, a, d):
+    """y [B, S, C] float32 of the recurrence above for x, dt [B, S, C]
+    (dt after its softplus), bm, cm [B, S, N], a [C, N] (negative), d
+    [C]; the state is zero at a sample's start. ONE kernel forward and
+    ONE backward (which keeps the state entering every chunk of
+    SCAN_CHUNK positions, [B, S / 64, N, C] float32, and forms the rest
+    again); everything inside is float32. Any S and C: the operands are
+    padded to whole chunks and whole vregs of channels. Caller checks
+    `pallas_mode` first; `ops.ssm.selective_scan_stepwise` is the same
+    recurrence in `jax.numpy`."""
+    return _selective_scan(x, dt, bm, cm, a, d, pallas_mode() == "interpret")
 
 
 def pallas_mode() -> str:

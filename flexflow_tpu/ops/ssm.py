@@ -26,6 +26,25 @@ start of every sequence. Its backward pass is the autodiff of that
 program: the decay exponent is masked BEFORE the exponential, so the
 upper triangle contributes exact zeros to value and gradient alike.
 `ssd_stepwise` is the same recurrence one position at a time, for tests.
+
+Beside it the Mamba-1 mixer (`MambaMixer`, PR 52), whose decay differs by
+channel AND state, so that no chunked-product form exists:
+
+    [x ; z] = h W_in                    widths d_inner each, no bias
+    x = silu(conv1d_causal_depthwise(x, K) + b_conv)
+    [r ; B ; C] = x W_x                 widths R, N, N
+    dt = softplus(r W_dt + b_dt);  A = -exp(A_log)         [d_inner, N]
+    h_t[c, n] = exp(dt_t[c] A[c, n]) h_{t-1}[c, n] + dt_t[c] x_t[c] B_t[n]
+    y_t[c] = sum_n h_t[c, n] C_t[n] + D[c] x_t[c]
+    out = (y * silu(z)) W_out
+
+The recurrence is ONE Pallas kernel forward and one backward
+(`pallas_kernels.selective_scan`: time walked inside the kernel, the
+state resident in VMEM) under the scope `selective_scan` inside
+`mamba_mixer`; `selective_scan_stepwise` is the same in `jax.numpy`, a
+`lax.scan` a position, where Pallas is off and for the tests. With
+``export_memory`` the op has a SECOND output, y before its gate, which
+other layers read as their memory (`models/decoder.py`).
 """
 
 from __future__ import annotations
@@ -146,10 +165,10 @@ def gated_group_rms_norm(y, z, scale, groups, eps):
     return yg.reshape(shape) * scale.astype(jnp.float32)
 
 
-def dt_bias_init(rng, num_heads, dt_min, dt_max, dt_floor):
-    """The Mamba-2 initialisation of dt_bias: dt log-uniform in [dt_min,
-    dt_max], floored, then the inverse of softplus."""
-    u = jax.random.uniform(rng, (num_heads,), jnp.float32)
+def dt_bias_init(rng, shape, dt_min, dt_max, dt_floor):
+    """The Mamba initialisation of dt_bias [``shape``]: dt log-uniform in
+    [dt_min, dt_max], floored, then the inverse of softplus."""
+    u = jax.random.uniform(rng, shape, jnp.float32)
     dt = jnp.exp(u * (math.log(dt_max) - math.log(dt_min))
                  + math.log(dt_min))
     dt = jnp.maximum(dt, dt_floor)
@@ -205,7 +224,7 @@ class SSMMixer(Op):
                 ks[1], (self.conv_kernel, self.conv_dim), jnp.float32,
                 -bound, bound),
             "conv_b": jnp.zeros((self.conv_dim,)),
-            "dt_bias": dt_bias_init(ks[2], h, *self.dt_range),
+            "dt_bias": dt_bias_init(ks[2], (h,), *self.dt_range),
             # A in [1, 16), as the Mamba-2 code draws it
             "a_log": jnp.log(jax.random.uniform(ks[3], (h,), jnp.float32,
                                                 1.0, 16.0)),
@@ -286,3 +305,155 @@ class SSMMixer(Op):
         return (e * (self.d_inner + self.conv_dim + self.num_heads)
                 + self.conv_dim * (self.conv_kernel + 1)
                 + 3 * self.num_heads + self.d_inner + self.d_inner * e)
+
+
+def selective_scan_stepwise(x, dt, bm, cm, a, d):
+    """The Mamba-1 recurrence as written, one position a step, float32:
+    x, dt [B, S, C], bm / cm [B, S, N], a [C, N], d [C] -> y [B, S, C]
+    (the D skip included)."""
+    f32 = jnp.float32
+    a, d = a.astype(f32), d.astype(f32)
+
+    def step(state, inp):
+        x_t, dt_t, b_t, c_t = inp
+        state = (jnp.exp(dt_t[..., None] * a) * state
+                 + (dt_t * x_t)[..., None] * b_t[:, None, :])
+        return state, jnp.einsum("bcn,bn->bc", state, c_t) + d * x_t
+
+    seq = tuple(jnp.moveaxis(t.astype(f32), 1, 0) for t in (x, dt, bm, cm))
+    _, ys = jax.lax.scan(
+        step, jnp.zeros((x.shape[0],) + a.shape, f32), seq)
+    return jnp.moveaxis(ys, 0, 1)
+
+
+@register_op(OperatorType.MAMBA_MIXER)
+class MambaMixer(Op):
+    """input [B, S, E] -> [B, S, E], and with ``export_memory`` a second
+    output [B, S, d_inner]: the scan's output y before its gate. Weights:
+    w_in [E, 2 d_inner], conv_w [K, d_inner], conv_b, w_x [d_inner, R +
+    2 N], w_dt [R, d_inner], dt_bias [d_inner], a_log [d_inner, N], d
+    [d_inner], w_out [d_inner, E]. dt_bias, a_log and d stay float32 in
+    the compute copy; dt, A, the decays, the state and y are float32."""
+
+    scopes_itself = "mamba_mixer"
+
+    full_precision_params = ("dt_bias", "a_log", "d")
+
+    def __init__(self, layer, input_shapes):
+        p = layer.properties
+        e = input_shapes[0][-1]
+        self.d_inner = p.get("d_inner") or 2 * e
+        self.state_size = p.get("state_size", 16)
+        self.conv_kernel = p.get("conv_kernel", 4)
+        self.dt_rank = p.get("dt_rank") or -(-e // 16)
+        self.export_memory = bool(p.get("export_memory", False))
+        # a control: the memory taken after the gate
+        self.export_gated = bool(p.get("export_gated", False))
+        self.dt_range = (p.get("time_step_min", 1e-3),
+                         p.get("time_step_max", 1e-1),
+                         p.get("time_step_floor", 1e-4))
+        self.kernel_init = (p.get("kernel_initializer")
+                            or DefaultWeightInitializer())
+        # whether the last traced forward ran the kernel (`traced_gauges`)
+        self._in_kernel = None
+        super().__init__(layer, input_shapes)
+
+    @property
+    def exports(self) -> int:
+        """Outputs beyond the first that other layers read."""
+        return int(self.export_memory)
+
+    def compute_output_shapes(self):
+        b, s, e = self.input_shapes[0]
+        return [(b, s, e)] + [(b, s, self.d_inner)] * self.exports
+
+    def init_params(self, rng):
+        e = self.input_shapes[0][-1]
+        di, n, r = self.d_inner, self.state_size, self.dt_rank
+        ks = jax.random.split(rng, 6)
+        bound = r ** -0.5
+        return {
+            "w_in": self.kernel_init(ks[0], (e, 2 * di)),
+            "conv_w": jax.random.uniform(ks[1], (self.conv_kernel, di),
+                                         jnp.float32, -0.5, 0.5),
+            "conv_b": jnp.zeros((di,)),
+            "w_x": self.kernel_init(ks[2], (di, r + 2 * n)),
+            "w_dt": jax.random.uniform(ks[3], (r, di), jnp.float32,
+                                       -bound, bound),
+            "dt_bias": dt_bias_init(ks[4], (di,), *self.dt_range),
+            # A[c, n] = -(n + 1), as the Mamba code sets it
+            "a_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)), (di, n)),
+            "d": jnp.ones((di,)),
+            "w_out": self.kernel_init(ks[5], (di, e)),
+        }
+
+    def forward(self, params, inputs, ctx: OpContext):
+        from flexflow_tpu.ops import pallas_kernels as pk
+
+        (x,) = inputs
+        cd = ctx.compute_dtype
+        f32 = jnp.float32
+        di, n, r = self.d_inner, self.state_size, self.dt_rank
+        self._in_kernel = pk.pallas_mode() != "off"
+        scan = scoped("selective_scan", pk.selective_scan if self._in_kernel
+                      else selective_scan_stepwise)
+
+        def dot(a, w):
+            return jnp.dot(a.astype(cd), w.astype(cd),
+                           preferred_element_type=f32)
+
+        def mixer(params, x):
+            proj = dot(x, params["w_in"])
+            xs, z = proj[..., :di].astype(cd), proj[..., di:].astype(cd)
+            xs = jax.nn.silu(causal_depthwise_conv1d(
+                xs, params["conv_w"], params["conv_b"])).astype(cd)
+            xdbl = dot(xs, params["w_x"])
+            dt = jax.nn.softplus(dot(xdbl[..., :r], params["w_dt"])
+                                 + params["dt_bias"].astype(f32))
+            y = scan(xs, dt, xdbl[..., r:r + n], xdbl[..., r + n:],
+                     -jnp.exp(params["a_log"].astype(f32)), params["d"])
+            gated = y * jax.nn.silu(z.astype(f32))
+            out = dot(gated, params["w_out"])
+            memory = gated if self.export_gated else y
+            return [out.astype(x.dtype)] + [
+                memory.astype(x.dtype)] * self.exports
+
+        return scoped(self.scopes_itself, mixer)(params, x)
+
+    def traced_gauges(self):
+        """`ssm/selective_scan_ops`: Mamba-1 scans the step runs (this
+        op's), `ssm/selective_scan_kernel_ops`: of them those that ran
+        as the Pallas kernels, as last traced."""
+        return {"ssm/selective_scan_ops": 1,
+                "ssm/selective_scan_kernel_ops": int(bool(self._in_kernel))}
+
+    def output_dim_roles(self):
+        # the sequence dim recurs: not position-independent, so no SEQ role
+        return [(DimRole.SAMPLE, DimRole.OTHER, DimRole.CHANNEL)] * (
+            1 + self.exports)
+
+    def flops(self):
+        b, s, e = self.input_shapes[0]
+        di, n, r = self.d_inner, self.state_size, self.dt_rank
+        products = 2 * (e * 2 * di + di * (r + 2 * n) + r * di + di * e)
+        # a state's update and its read: decay, input, sum (7 a state)
+        return b * s * (products + 2 * di * self.conv_kernel + 7 * di * n)
+
+    def interior_bytes(self):
+        """Bytes the op keeps for its backward pass besides its outputs:
+        the projection (x, z) and the convolved x at the op's element
+        size; dt and y in float32; the state entering every chunk of the
+        scan kernel."""
+        from flexflow_tpu.ops.pallas_kernels import SCAN_CHUNK
+
+        b, s, _ = self.input_shapes[0]
+        di = self.d_inner
+        return (b * s * 3 * di * self.dtype.size + b * s * 2 * di * 4
+                + b * -(-s // SCAN_CHUNK) * self.state_size * di * 4)
+
+    def params_elems(self):
+        e = self.input_shapes[0][-1]
+        di, n, r = self.d_inner, self.state_size, self.dt_rank
+        return (e * 2 * di + di * (self.conv_kernel + 1) + di * (r + 2 * n)
+                + r * di + di + di * n + di + di * e)

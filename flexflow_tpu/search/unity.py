@@ -17,7 +17,7 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from flexflow_tpu.ffconst import CompMode, OperatorType
-from flexflow_tpu.ops.base import DimRole
+from flexflow_tpu.ops.base import DimRole, exported_reads
 from flexflow_tpu.parallel.strategy import OpStrategy, Strategy
 
 
@@ -148,6 +148,11 @@ def serialize_graph(nodes, final_guid: Optional[int] = None,
     # leaves of its own, under a new name), a full-precision product
     shared = {owner for n in nodes for owner, _ in
               getattr(n.op, "tied_params", {}).values()}
+    # and the two ends of a tensor one op makes for other layers to read
+    # (`Op.exports`): a rewrite that re-formed the producer or a reader
+    # would drop the edge, and a remat twin of the producer would price
+    # as freed a tensor that lives to its last reader's backward
+    reads_exported = {reader for reader, _ in exported_reads(nodes)[1]}
     # conv guids whose sole consumer is a foldable BatchNorm — the
     # legality the native "_k:conv_bn_fused" kernel twin gates on
     # (shipped as a node attr: the gate is a GRAPH property the native
@@ -172,8 +177,14 @@ def serialize_graph(nodes, final_guid: Optional[int] = None,
         if op.guid in bn_fusable:
             attrs["bn_fusable"] = 1
         if (op.name in shared or getattr(op, "tied_params", None)
-                or getattr(op, "full_precision", False)):
+                or getattr(op, "full_precision", False)
+                or op.exports or op.guid in reads_exported):
             attrs["pinned"] = 1
+        if op.exports:
+            attrs["exports"] = int(op.exports)
+        if getattr(op, "differential", False):
+            # its lambda leaves the step beside its output
+            attrs["side_counters"] = 1
         out.append(dict(
             guid=op.guid,
             type=op.op_type.name,

@@ -96,6 +96,16 @@ def init_kv_cache(ff, batch: Optional[int] = None,
         raise ValueError("model has no sequence dim to cache")
     dtype = dtype or ff.executor.compute_dtype
     for node in ff.executor.nodes:
+        if node.op.op_type == OperatorType.MAMBA_MIXER or getattr(
+                node.op, "exports", 0) or getattr(node.op, "kv_given",
+                                                  False):
+            raise NotImplementedError(
+                f"'{node.op.name}' is a Mamba-1 mixer, or makes or reads "
+                f"keys/values or a memory shared between layers: a new "
+                f"token needs the scan's state, and ONE cache entry that "
+                f"the producer writes and every reader reads; this cache "
+                f"holds one {{k, v}} pair a causal attention op. Serving "
+                f"them is not built")
         if node.op.op_type == OperatorType.SHORT_CONV:
             raise NotImplementedError(
                 f"'{node.op.name}' is a short convolution: a new token "

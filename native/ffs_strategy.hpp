@@ -302,7 +302,11 @@ inline std::string remat_gate(const Node& n, const Choice& c,
     return "stateful_interior";
   // the dropless layer's routing counts leave the step beside its output
   // (executor counters): a checkpointed interior would strand them
-  if (n.type == "MOE_LAYER") return "counter_side_channel";
+  if (n.type == "MOE_LAYER" || n.attrs.get("side_counters").as_double(0.0) > 0)
+    return "counter_side_channel";
+  // an output that other layers read lives to its last reader's
+  // backward: a twin would price it as freed
+  if (n.attrs.get("exports").as_double(0.0) > 0) return "exported_output";
   // the recompute re-runs the forward's collectives too; the pricing
   // charges compute only, so choices whose forward moves bytes (psum /
   // ring / gather / weight-gather) do not spawn twins — this also keeps

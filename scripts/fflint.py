@@ -45,7 +45,7 @@ if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu") \
 
 ZOO = ["mlp", "alexnet", "resnet", "resnext", "inception", "dlrm", "xdl",
        "candle_uno", "moe", "moe_encoder", "transformer", "llama", "lfm2",
-       "ouro"]
+       "ouro", "phi4flash"]
 
 
 def build_model(name: str, ff_config):
@@ -137,6 +137,17 @@ def build_model(name: str, ff_config):
             hybrid_override_pattern="UU", total_ut_steps=3,
             num_attention_heads=4, num_key_value_heads=4, batch_size=8,
             seq_length=16), ff_config), "exit"
+    if name == "phi4flash":
+        # a decoder-hybrid-decoder model, all five kinds of layer by the
+        # published rule: a Mamba-1 scan whose output two later layers
+        # read, keys and values that a later layer cross-attends (second
+        # and third OUTPUTS of their ops), differential attention
+        from flexflow_tpu.models import DecoderConfig, create_decoder
+        return create_decoder(DecoderConfig(
+            mb_per_layer=2, num_hidden_layers=8, sliding_window=8,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            tie_word_embeddings=True, batch_size=8, seq_length=16),
+            ff_config), "cat"
     raise SystemExit(f"unknown --model {name!r} (zoo: {', '.join(ZOO)})")
 
 

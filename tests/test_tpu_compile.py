@@ -568,6 +568,71 @@ class TestHybridDecoderKernels:
                                           seq * width)) >= 3
 
 
+    def test_the_new_ops_of_the_phi4_mini_flash_cell_at_its_widths(
+            self, topo, on_tpu):
+        """PR 52's op kinds at the cell's widths (8,192 positions, hidden
+        2560, bfloat16), forward and backward of each op alone: the
+        Mamba-1 mixer (d_inner 5120, state 16: two scan kernels, the
+        state never written out a position), differential attention at
+        40 : 20 heads of 64 that exports its keys and values and the
+        cross-attention op that reads them: each takes the flash route at
+        20 : 10 heads of 128 with the keys and values at the KV heads,
+        two maps a forward; no [S, S] and no [S, 5120, 16] array in any
+        of them."""
+        from flexflow_tpu import FFConfig, FFModel
+        from flexflow_tpu.ops.base import OpContext, OpRegistry
+        seq, hidden = 8192, 2560
+        one = SingleDeviceSharding(topo.devices[0])
+        ff = FFModel(FFConfig(batch_size=1))
+        x = ff.create_tensor((1, seq, hidden))
+        kw = dict(bias=True, qkv_bias=True, causal=True, num_kv_heads=20,
+                  head_dim=64, differential=True, lambda_init=0.79)
+        ff.mamba_mixer(x, export_memory=True, name="mamba")
+        _, k, v = ff.multihead_attention(x, x, x, hidden, 40, export_kv=True,
+                                         name="full", **kw)
+        ff.multihead_attention(x, k, v, hidden, 40, kv_given=True,
+                               name="cross", **kw)
+        square = re.compile(r"\[(?:\d+,)*8192,8192\]")
+        states = re.compile(r"8192,5120,16\]|8192,16,5120\]|"
+                            r"8192,16,5,8,128\]")
+        for name in ("mamba", "full", "cross"):
+            layer = ff._layer_named[name]
+            op = OpRegistry.create(layer, [t.shape for t in layer.inputs])
+            params = {
+                leaf: jax.ShapeDtypeStruct(
+                    a.shape, jnp.float32 if leaf in op.full_precision_params
+                    else jnp.bfloat16, sharding=one)
+                for leaf, a in jax.eval_shape(
+                    op.init_params, jax.random.PRNGKey(0)).items()}
+            inputs = tuple(jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                                sharding=one)
+                           for shape in op.input_shapes)
+
+            def loss(params, inputs, op=op):
+                ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
+                return sum(o.astype(jnp.float32).sum()
+                           for o in op.forward(params, list(inputs), ctx))
+
+            compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+                params, inputs).compile()
+            hlo = compiled.as_text()
+            assert not square.search(hlo), name
+            assert not states.search(hlo), name
+            assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+            if name == "mamba":
+                assert pallas_kernel_count(hlo) == 2
+                assert op.traced_gauges()[
+                    "ssm/selective_scan_kernel_ops"] == 1
+                continue
+            route = op._route
+            assert (route.core, route.grouped_kv) == ("flash", True), name
+            assert route.scope == "diff_" + name
+            assert route.super_block
+            assert op.core_heads == (20, 10, 128)
+            # two maps: two forward and two backward kernels
+            assert pallas_kernel_count(hlo) == 4, name
+
+
 class TestFusedAdam:
     KW = dict(beta1=0.9, beta2=0.999, eps=1e-8, wd=1e-4)
 
