@@ -27,7 +27,8 @@ from flexflow_tpu.initializers import DefaultWeightInitializer
 from flexflow_tpu.ops.base import (DimRole, Op, OpContext, register_op,
                                    scoped)
 from flexflow_tpu.ops.moe import (combine_rows, expert_capacity,
-                                  grouped_matmul, load_balance_loss,
+                                  gmm_row_tile, grouped_matmul,
+                                  grouped_products_walk, load_balance_loss,
                                   make_dispatch_tensors, route_held_experts,
                                   route_scores, rows_from_tokens,
                                   sums_rows_by_kernel)
@@ -113,6 +114,18 @@ def squared_relu(x):
     return jnp.square(jax.nn.relu(x))
 
 
+def buffer_rows(pairs: int, held: int, n_experts: int, slack: float) -> int:
+    """Rows of a layer's one local buffer: every pair if all experts are
+    held, else the expected held pairs with the slack; in 128s, and then
+    in the row tile the grouped products walk such a buffer in
+    (`moe.gmm_row_tile`: at most 128 rows more, never fewer)."""
+    rows = pairs if held == n_experts else min(
+        pairs, int(pairs * held / n_experts * (1.0 + slack)) + 1)
+    rows = -(-rows // 128) * 128
+    tile = gmm_row_tile(rows, held)
+    return -(-rows // tile) * tile
+
+
 # what the gate's product goes through in the gated form
 GATE_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
@@ -149,8 +162,9 @@ class MoELayer(Op):
 
     Routing is dropless for the held experts: the pairs are sorted by
     expert into ONE buffer whose rows are the expected number of held
-    pairs, tokens * k * held / n_experts, times (1 + `slot_slack`), and a
-    grouped matrix product runs over the buffer. There is no per-expert
+    pairs, tokens * k * held / n_experts, times (1 + `slot_slack`), in
+    whole row tiles of the grouped matrix product that runs over the
+    buffer (`buffer_rows`; PR 53). There is no per-expert
     capacity. Pairs beyond the buffer are counted (`moe/overflow_slots`,
     with `moe/slots_held` and `moe/load_max_over_mean` beside it: they
     leave the step with the metrics and are read once an epoch).
@@ -220,6 +234,9 @@ class MoELayer(Op):
         # whether the forward, as last traced, summed rows by the kernel
         self._sum_rows = False
         self._spread_rows = False
+        # the row tile and the products whose weights stay resident, of
+        # the grouped products as last traced (0: none, or not by kernel)
+        self._row_tile = self._resident_products = 0
         super().__init__(layer, input_shapes)
 
     def compute_output_shapes(self):
@@ -237,15 +254,9 @@ class MoELayer(Op):
 
     @property
     def buffer_rows(self):
-        """Rows of the one local buffer: every pair if all experts are
-        held, else the expected held pairs with the slack, in 128s."""
-        pairs = self.tokens * self.k
-        if self.experts_held == self.n_experts:
-            rows = pairs
-        else:
-            rows = min(pairs, int(pairs * self.experts_held / self.n_experts
-                                  * (1.0 + self.slot_slack)) + 1)
-        return -(-rows // 128) * 128
+        """Rows of the layer's one local buffer (`buffer_rows` above)."""
+        return buffer_rows(self.tokens * self.k, self.experts_held,
+                           self.n_experts, self.slot_slack)
 
     def init_params(self, rng):
         d = self.input_shapes[0][-1]
@@ -338,6 +349,8 @@ class MoELayer(Op):
         # `tokens_from_rows`, for the combine and for the dispatch's
         # backward, sums the rows by the kernel
         self._sum_rows = sums_rows_by_kernel(rows, d, b * s, self.k)
+        self._row_tile, self._resident_products = grouped_products_walk(
+            rows, self.experts_held, d, self.hidden_size, self.matrices)
         y, load, overflow = scoped(self.scopes_itself, layer)(
             params, x, x_router)
         load = load.astype(jnp.float32)
@@ -359,9 +372,20 @@ class MoELayer(Op):
         experts, and on the CPU). `executor.moe_spread_rows_ops`: a
         backward of `combine_rows` has been traced, and it took that
         kernel's transpose, `moe_spread_rows` (PR 49; `combine_rows`
-        calls `_saw_spread_rows` from there)."""
+        calls `_saw_spread_rows` from there). `executor.moe_row_tile` and
+        `executor.moe_resident_weight_products` (PR 53): the row tile the
+        layer's grouped products walk the buffer in, and how many of its
+        `gmm` products (an expert's matrices forward and their `d lhs`:
+        six in a gated layer, four else) contract in ONE tile, their
+        group's weight panel fetched once a group
+        (`moe.grouped_products_walk`; 0 where `lax.ragged_dot` runs). The
+        executor adds the layers' values up: over `executor.expert_ops`
+        layers."""
         return {"executor.moe_sum_rows_ops": int(bool(self._sum_rows)),
-                "executor.moe_spread_rows_ops": int(self._spread_rows)}
+                "executor.moe_spread_rows_ops": int(self._spread_rows),
+                "executor.moe_row_tile": self._row_tile,
+                "executor.moe_resident_weight_products":
+                    self._resident_products}
 
     def output_dim_roles(self):
         # routing sorts the tokens of the whole batch into one buffer: the

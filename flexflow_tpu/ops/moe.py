@@ -366,22 +366,69 @@ def _spread_in_token_order(ordered, w_row, weights, route, d_y):
 combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
-def _gmm_tiling(m: int, k: int, n: int):
-    """Tile sizes of the megablox kernels for an [m, k] x [g, k, n]
-    product (and, with the roles of k and n as the caller gives them, of
-    its two backward products): the widest row tile up to 512 that
-    divides m; a dimension up to 1024 whole, a longer one in its largest
-    divisor that is a multiple of 128, or in 1024s with a ragged last
-    tile, which the kernels mask. At these sizes the operand, accumulator
-    and output tiles stay under the compiler's 16 MiB of scoped VMEM."""
-    def tile(dim):
-        if dim <= 1024:
-            return dim
-        return next((t for t in range(1024, 127, -128) if dim % t == 0),
-                    1024)
+# What the megablox kernels' blocks of a grid step may take of the 16 MiB
+# of scoped VMEM the compiler gives a kernel that asks for nothing: the
+# operands' and the output's blocks twice (the pipeline's two buffers)
+# and the float32 accumulator and product. The count is on the safe
+# side (PR 53's lab: [256, 2688] x [2688, 1024], 16.9 MB by it, still
+# compiles and runs; [512, 2560] x [2560, 768], 17.8 MB, is refused).
+GMM_VMEM_BYTES = 15 << 20
 
-    tm = next(t for t in (512, 256, 128) if m % t == 0)
-    return tm, tile(k), tile(n)
+
+def gmm_row_tile(m: int, groups: int) -> int:
+    """The row tile of a layer's grouped products, from the buffer's rows
+    and the groups alone: 256 where a group's share of the buffer is
+    1,024 rows or more, else 128. A tile in which a group starts is
+    visited once more, whole, so a tile has to stay a small part of a
+    group; the lab (scripts/gmm_lab.py, PR 53) found 256 rows a step the
+    fastest from 1,544 rows of buffer a group up (about 1,000 that hold a
+    pair), 128 up to 592, and 512 nowhere."""
+    return 256 if m >= 1024 * groups else 128
+
+
+def _lane_tile(dim: int) -> int:
+    """A tile of a dimension that lies along the lanes: `dim` whole up to
+    1024, a longer one in its largest divisor that is a multiple of 128,
+    or in 1024s with a ragged last tile, which the kernels mask."""
+    if dim <= 1024:
+        return dim
+    return next((t for t in range(1024, 127, -128) if dim % t == 0), 1024)
+
+
+def _gmm_tiling(m: int, groups: int, k: int, n: int, transposed=False):
+    """(row tile, contraction tile, output tile) of the megablox kernels
+    for a grouped product over [m, ..] rows in `groups` groups:
+    [m, k] x [g, k, n] (with the roles of k and n as the caller gives
+    them, also the rows' gradient), or with `transposed` the weights'
+    gradient [m, k]^T [m, n] a group.
+
+    The row tile is `gmm_row_tile`'s if it divides m (`MoELayer` makes
+    its buffer so), else 128. The first kind contracts k WHOLE: the
+    kernel's grid is (n tiles, visited row tiles, k tiles) and the weight
+    block's index (group, k tile, n tile), so with ONE k tile the index
+    stays over a group's row tiles and the [k, tn] panel is fetched from
+    HBM once a (group, n tile); with two or more it changes every grid
+    step and the panel is fetched again for every row tile (until PR 53:
+    2 MB for every 128 rows, the products at 34-45% of the MXU's peak,
+    bound by the HBM). The output tile is narrowed while a step's blocks
+    overrun `GMM_VMEM_BYTES` (at the widest cell, nemotron's [128, 2688]
+    x [2688, 1024]: 14.0 MB; `lfm2`'s [256, 2048] x [2048, 896]: 12.2
+    MB), and a contraction too long even for 128 lanes of output is
+    split as before. The transposed kind holds a float32 [tk, tn]
+    accumulator over a group's row tiles and tiles both in up to 1024."""
+    tm = gmm_row_tile(m, groups)
+    tm = tm if m % tm == 0 else 128
+    if transposed:
+        return tm, _lane_tile(k), _lane_tile(n)
+
+    def fits(tk, tn):
+        return 4 * (tm * tk + tk * tn + tm * tn) + 8 * tm * tn <= (
+            GMM_VMEM_BYTES)
+
+    tn = _lane_tile(n)
+    while tn > 128 and not fits(k, tn):
+        tn = max(128, tn // 2 // 128 * 128)
+    return tm, k if fits(k, tn) else _lane_tile(k), tn
 
 
 def _megablox():
@@ -396,8 +443,9 @@ def _megablox():
 def _megablox_gmm(lhs, rhs, group_sizes, interpret):
     backend = _megablox()
     m, k = lhs.shape
+    groups, _, n = rhs.shape
     out = backend.gmm(lhs, rhs, group_sizes, lhs.dtype,
-                      _gmm_tiling(m, k, rhs.shape[2]), interpret=interpret)
+                      _gmm_tiling(m, groups, k, n), interpret=interpret)
     return _zero_past(out, group_sizes)
 
 
@@ -416,18 +464,26 @@ def _megablox_bwd(interpret, res, grad):
     backend = _megablox()
     lhs, rhs, group_sizes = res
     m, k = lhs.shape
-    n = rhs.shape[2]
+    groups, _, n = rhs.shape
     # d lhs = grad [m, n] x rhs^T: contracts n; d rhs[g] = lhs_g^T grad_g
     d_lhs = _zero_past(
-        backend.gmm(grad, rhs, group_sizes, lhs.dtype, _gmm_tiling(m, n, k),
-                    transpose_rhs=True, interpret=interpret), group_sizes)
+        backend.gmm(grad, rhs, group_sizes, lhs.dtype,
+                    _gmm_tiling(m, groups, n, k), transpose_rhs=True,
+                    interpret=interpret), group_sizes)
     d_rhs = backend.tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
-                         _gmm_tiling(m, k, n), num_actual_groups=rhs.shape[0],
-                         interpret=interpret)
+                         _gmm_tiling(m, groups, k, n, transposed=True),
+                         num_actual_groups=groups, interpret=interpret)
     return d_lhs, d_rhs, None
 
 
 _megablox_gmm.defvjp(_megablox_fwd, _megablox_bwd)
+
+
+def _kernels_mode(m: int):
+    """`pallas_mode()` where the kernels take a buffer of m rows, else
+    None (`lax.ragged_dot` runs)."""
+    mode = pallas_kernels.pallas_mode()
+    return mode if mode != "off" and m % 128 == 0 else None
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -435,15 +491,32 @@ def grouped_matmul(lhs, rhs, group_sizes):
     (sum <= m) -> [m, n]: rows of group i times rhs[i]; rows past the
     groups' sum are zero, and so is their gradient. On the TPU (and
     under FLEXFLOW_TPU_PALLAS=interpret) the Pallas megablox kernels that
-    ship with JAX, with tiles sized here and row tiles of at least 128
-    (m must be a multiple of 128); elsewhere `lax.ragged_dot`."""
-    mode = pallas_kernels.pallas_mode()
-    if mode != "off" and lhs.shape[0] % 128 == 0:
+    ship with JAX, walked as `_gmm_tiling` says: row tiles of 256 where
+    the groups are large and of 128 where they are small (m must be a
+    multiple of 128), and the contraction in ONE tile, so that a group's
+    weight panel is fetched once a group and not once a row tile;
+    elsewhere `lax.ragged_dot`."""
+    mode = _kernels_mode(lhs.shape[0])
+    if mode:
         return _megablox_gmm(lhs, rhs, group_sizes, mode == "interpret")
     return _zero_past(
         jax.lax.ragged_dot(lhs, rhs, group_sizes,
                            preferred_element_type=jnp.float32
                            ).astype(lhs.dtype), group_sizes)
+
+
+def grouped_products_walk(m: int, groups: int, d: int, f: int,
+                          matrices: int):
+    """(row tile, `gmm` products whose contraction is ONE tile) of an
+    expert layer's grouped products over [m, d] rows, its `matrices`
+    [d, f] / [f, d] a group: what `grouped_matmul` runs for these static
+    shapes here, forward and `d lhs`; (0, 0) where it is
+    `lax.ragged_dot`."""
+    if not _kernels_mode(m):
+        return 0, 0
+    # up (and gate) contract d forward and f in `d lhs`; down the reverse
+    up, down = _gmm_tiling(m, groups, d, f), _gmm_tiling(m, groups, f, d)
+    return up[0], matrices * ((up[1] == d) + (down[1] == f))
 
 
 def expert_capacity(batch: int, k: int, n_experts: int, alpha: float) -> int:

@@ -50,13 +50,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # of a block-diffusion sample does
 SHAPES = {
     "smallthinker_21b_a3b.s16384_b1": dict(T=16384, k=6, E=64, held=8,
-                                           d=2560, rows=18560),
+                                           d=2560, rows=18688),
     "nemotron3_nano_30b_a3b.s8192_b1": dict(T=8192, k=6, E=128, held=8,
                                             d=2688, rows=4736),
     "sdar_30b_a3b.s8192_b1": dict(T=16384, k=8, E=128, held=16, d=2048,
-                                  rows=24704, mask_share=0.25),
+                                  rows=24832, mask_share=0.25),
     "lfm2_8b_a1b.s16384_b1": dict(T=16384, k=4, E=32, held=8, d=2048,
-                                  rows=24704),
+                                  rows=24832),
     "laguna_xs2.s8192_b1": dict(T=8192, k=8, E=256, held=16, d=2048,
                                 rows=6272),
 }
@@ -223,7 +223,7 @@ def pieces(s):
             hot = jnp.repeat(hot, 3, axis=1)
         out = moe._megablox().tgmm(
             hot.astype(jnp.bfloat16), picked, sizes * pieces, f32,
-            moe._gmm_tiling(pieces * rows, tile, picked.shape[1]),
+            (128, tile, moe._lane_tile(picked.shape[1])),
             num_actual_groups=T // tile)
         return out.reshape(T, -1)
 
@@ -279,30 +279,39 @@ def pieces(s):
     }
 
 
-def make_arguments(s):
-    """Distinct experts a token, uniform: the held ones get their share.
-    With `mask_share`, that share of the tokens choose the same k
-    experts, one of them held: half of them at random places, half as one
-    run from token 0; and the 256 tokens after that run choose k held
-    experts, so that tiles with no row, with a row a token and with k
-    rows a token all occur."""
+def draw_experts(s, key):
+    """experts [T, k] int32: distinct experts a token, uniform: the held
+    ones get their share. With `mask_share`, that share of the tokens
+    choose the same k experts, one of them held: half of them at random
+    places, half as one run from token 0; and the 256 tokens after that
+    run choose k held experts, so that tiles with no row, with a row a
+    token and with k rows a token all occur."""
     import jax
     import jax.numpy as jnp
-    from flexflow_tpu.ops import moe
 
-    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    ks = jax.random.split(key, 2)
     T, k = s["T"], s["k"]
     _, experts = jax.lax.top_k(jax.random.uniform(ks[0], (T, s["E"])), k)
     if s.get("mask_share"):
         token = jnp.arange(T)
         run = int(T * s["mask_share"] / 2)
         masked = (token < run) | (
-            jax.random.uniform(ks[4], (T,)) < s["mask_share"] / 2)
+            jax.random.uniform(ks[1], (T,)) < s["mask_share"] / 2)
         of_mask = jnp.arange(k).at[1:].add(s["held"])   # expert 0 is held
         experts = jnp.where(masked[:, None], of_mask, experts)
         experts = jnp.where(((token >= run) & (token < run + 256))[:, None],
                             jnp.arange(k), experts)
-    experts = experts.astype(jnp.int32)
+    return experts.astype(jnp.int32)
+
+
+def make_arguments(s):
+    """A shape's arrays by the names `pieces` asks for them."""
+    import jax
+    import jax.numpy as jnp
+    from flexflow_tpu.ops import moe
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    experts = draw_experts(s, ks[0])
     r = moe.route_held_experts(experts, s["held"], 0, s["rows"])
     weights = jax.random.uniform(ks[1], (s["T"], s["k"]))
     o = jax.random.normal(ks[3], (s["rows"], s["d"]), jnp.bfloat16)

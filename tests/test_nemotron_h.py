@@ -564,9 +564,14 @@ def test_layer_gradients_match_the_scatter_add_form(name, mode, monkeypatch):
                         argnums=(0, 1))(params, inputs)
     overflow = float(op._counters["moe/overflow_slots"][1])
     assert (overflow > 0) == ("buffer_too_small" in name)
+    # small groups: the narrowest row tile, and every `gmm` product of the
+    # layer contracts in one tile (PR 53); nothing of it by `ragged_dot`
+    walk = (128, 2 * op.matrices) if mode == "interpret" else (0, 0)
     assert op.traced_gauges() == {
         "executor.moe_sum_rows_ops": int(sums_by_kernel(name, mode)),
-        "executor.moe_spread_rows_ops": int(sums_by_kernel(name, mode))}
+        "executor.moe_spread_rows_ops": int(sums_by_kernel(name, mode)),
+        "executor.moe_row_tile": walk[0],
+        "executor.moe_resident_weight_products": walk[1]}
     flat_got, tree = jax.tree.flatten(got)
     flat_want, tree_want = jax.tree.flatten(want)
     assert tree == tree_want and len(flat_got) == len(params) + n_inputs

@@ -415,24 +415,39 @@ class TestHybridDecoderKernels:
     `nemotron3_nano_30b_a3b` cell (one chip's share: 8 Mamba heads, 8
     held experts, 8,192 tokens), forward and backward."""
 
-    def test_grouped_matmul_at_the_cells_widths(self, topo, on_tpu):
+    @pytest.mark.parametrize("rows,groups,d,f,gated", [
+        pytest.param(24832, 8, 2048, 1792, True, id="lfm2"),
+        pytest.param(24832, 16, 2048, 768, True, id="sdar"),
+        pytest.param(18688, 8, 2560, 768, True, id="smallthinker"),
+        pytest.param(4736, 8, 2688, 1856, False, id="nemotron"),
+        pytest.param(6272, 16, 2048, 512, True, id="laguna"),
+        pytest.param(1664, 8, 2048, 768, True, id="joyai"),
+    ])
+    def test_grouped_matmul_at_the_cells_widths(self, topo, on_tpu, rows,
+                                                groups, d, f, gated):
+        """An expert layer's products at the six cells' shapes (the
+        buffer `MoELayer.buffer_rows` makes there), forward and
+        backward: the tiles `moe._gmm_tiling` picks, the contraction of
+        every `gmm` product whole, fit the compiler's 16 MiB of VMEM."""
         from flexflow_tpu.ops.moe import grouped_matmul
         one = SingleDeviceSharding(topo.devices[0])
-        rows = jax.ShapeDtypeStruct((4736, 2688), jnp.bfloat16, sharding=one)
-        up = jax.ShapeDtypeStruct((8, 2688, 1856), jnp.bfloat16,
-                                  sharding=one)
-        down = jax.ShapeDtypeStruct((8, 1856, 2688), jnp.bfloat16,
-                                    sharding=one)
-        sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
 
-        def loss(x, up, down, sizes):
-            h = jnp.square(jax.nn.relu(grouped_matmul(x, up, sizes)))
+        def shape(*dims):
+            return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one)
+
+        ups = [shape(groups, d, f)] * (2 if gated else 1)
+        sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one)
+
+        def loss(x, ups, down, sizes):
+            h = jax.nn.relu(grouped_matmul(x, ups[0], sizes))
+            h = h * (grouped_matmul(x, ups[1], sizes) if gated else h)
             return grouped_matmul(h, down, sizes).astype(jnp.float32).sum()
 
-        hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), rows, up,
-                       down, sizes)
-        # two products forward, two for the rows, two for the weights
-        assert pallas_kernel_count(hlo) == 6
+        hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                       shape(rows, d), ups, shape(groups, f, d), sizes)
+        # a matrix: one product forward, one for the rows' gradient, one
+        # for its own
+        assert pallas_kernel_count(hlo) == 3 * (len(ups) + 1)
 
     @pytest.mark.parametrize("tokens,width,props", [
         pytest.param(8192, 2688, dict(

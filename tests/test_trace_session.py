@@ -621,6 +621,7 @@ WITNESS_KEYS = [
     "executor.flash_one_span_ops", "executor.flash_super_block_ops",
     "executor.latent_attention_ops",
     "executor.layer_applications", "executor.loss_own_vjp",
+    "executor.moe_resident_weight_products", "executor.moe_row_tile",
     "executor.moe_spread_rows_ops", "executor.moe_sum_rows_ops",
     "executor.rotary_lane_dense_ops",
     "executor.shared_leaves", "executor.shared_weight_ops",
@@ -636,6 +637,7 @@ CONTEXT_KEYS = [
     "flash_super_block_ops",
     "latent_attention_ops", "layer_applications",
     "loss_own_vjp", "loss_target_positions", "mesh_axes",
+    "moe_resident_weight_products", "moe_row_tile",
     "moe_spread_rows_ops", "moe_sum_rows_ops", "num_ops",
     "rotary_lane_dense_ops", "search_predicted_memory_bytes",
     "search_predicted_step_s",
