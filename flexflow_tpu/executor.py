@@ -518,8 +518,9 @@ class GraphExecutor:
         ``counters`` (a dict, filled in place) collects what ops count
         during forward (``op._counters``: name -> ("sum" | "mean", value),
         e.g. the expert layers' routing counts): sums add up over ops,
-        means keep a count beside them under ``name + COUNT_SUFFIX``. The
-        train step returns them with the metrics.
+        means keep a count beside them under ``name + COUNT_SUFFIX``; an
+        INTEGER sum stays a vector of the ops' counts, exact, for the
+        host to add. The train step returns them with the metrics.
 
         aux_losses collects regularizer terms ops emit during forward (e.g.
         the MoE load-balance loss the reference computes inside Aggregate's
@@ -729,6 +730,15 @@ class GraphExecutor:
             if getattr(op, "_counters", None) is not None:
                 if counters is not None:
                     for cname, (kind, v) in op._counters.items():
+                        v = jnp.asarray(v)
+                        if jnp.issubdtype(v.dtype, jnp.integer):
+                            # an integer count leaves the step as it is,
+                            # one element an op: `FFModel` adds an epoch's
+                            # up on the host (int32 wraps at 2^31 pairs)
+                            counters[cname] = jnp.concatenate(
+                                [counters.get(cname, jnp.zeros(0, v.dtype)),
+                                 jnp.reshape(v, 1)])
+                            continue
                         counters[cname] = counters.get(cname, 0.0) + v
                         if kind == "mean":
                             n = cname + COUNT_SUFFIX

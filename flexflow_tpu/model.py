@@ -300,6 +300,10 @@ class FFModel:
                             diff_norm_eps: float = 1e-5,
                             kv_given: bool = False,
                             export_kv: bool = False,
+                            sparse_index=None,
+                            indexer_dtype: str = "float32",
+                            mrope_section=None, mrope_positions=None,
+                            index_loss: bool = True,
                             name: Optional[str] = None) -> Tensor:
         """``seq_parallel='seq'`` runs the attention core as ring attention
         over that mesh axis (context parallelism for long sequences).
@@ -342,10 +346,36 @@ class FFModel:
         ``value`` are [B, S, num_kv_heads * head_dim], ALREADY projected
         by another op (the op holds wq and wo alone). ``export_kv``: the
         op's projected keys and values are outputs too; the call then
-        returns (out, k, v), for ``kv_given`` readers."""
+        returns (out, k, v), for ``kv_given`` readers.
+        ``sparse_index`` = (index heads, their width, keys a query keeps):
+        learned sparse attention (causal self-attention): an indexer
+        (leaves ``w_iq``, ``w_ik``, ``w_iw``, a LayerNorm of its one key)
+        scores every causal pair from a DETACHED copy of the input, a
+        query keeps its best keys, the main softmax runs over them alone,
+        and the indexer's loss, the KL divergence from the main
+        attention's head-summed probabilities to its own softmax over
+        the kept keys, joins the step's loss (``ops/attention.py``
+        `_forward_sparse`). ``indexer_dtype``: the operands of the
+        index products ("float32" at `highest`, or "bfloat16").
+        ``mrope_section``: the rotary pairs each of three position
+        streams turns; ``mrope_positions`` [3][S]: the streams where
+        they differ (static; None: all three the token's index, which is
+        the plain rotation). ``index_loss`` False leaves the indexer's
+        loss out of the step's."""
         shared = {k: v for k, v in (("differential", differential),
                                     ("kv_given", kv_given),
                                     ("export_kv", export_kv)) if v}
+        if sparse_index:
+            shared.update(
+                sparse_index=tuple(int(n) for n in sparse_index),
+                **({"indexer_dtype": indexer_dtype}
+                   if indexer_dtype != "float32" else {}),
+                **({"index_loss": False} if not index_loss else {}))
+        if mrope_section:
+            shared.update(mrope_section=tuple(mrope_section), **(
+                {"mrope_positions": tuple(tuple(int(p) for p in row)
+                                          for row in mrope_positions)}
+                if mrope_positions is not None else {}))
         if differential:
             shared.update(lambda_init=lambda_init,
                           diff_norm_eps=diff_norm_eps,
@@ -1496,7 +1526,7 @@ class FFModel:
             if on_epoch_start is not None:
                 on_epoch_start()
             self._metrics_acc = PerfMetrics()
-            mtotals = None
+            mtotals, step_counts = None, []
             epoch_executed = 0
             for b in range(num_batches):
                 step_idx += 1
@@ -1531,6 +1561,14 @@ class FFModel:
                             inputs, labels, sub)
                     self._iter += 1
                     with tracer.phase("metric_accumulate"):
+                        # the ops' integer counts stay a step's own
+                        # until the epoch's fetch (an epoch's sum of
+                        # pairs passes what int32 holds)
+                        counts = {k: mvals.pop(k) for k in [
+                            k for k, v in mvals.items() if "/" in k
+                            and jnp.issubdtype(v.dtype, jnp.integer)]}
+                        if counts:
+                            step_counts.append(counts)
                         mtotals = (mvals if mtotals is None
                                    else jax.tree.map(jnp.add, mtotals,
                                                      mvals))
@@ -1578,6 +1616,10 @@ class FFModel:
                     # "<layer>/<what>") came with the metrics: one fetch
                     counted = {k: totals.pop(k) for k in list(totals)
                                if "/" in k}
+                    for step in jax.device_get(step_counts):
+                        for k, v in step.items():   # as Python ints
+                            counted[k] = counted.get(k, 0) + sum(
+                                int(n) for n in v)
                     self._metrics_acc.update(totals, bs * epoch_executed)
                     self._last_loss = float(loss)
                     if counted:

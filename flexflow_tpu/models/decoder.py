@@ -29,6 +29,18 @@ family.
         reads the POST-attention norm, softmax over all experts with the
         chosen renormalised, experts down(act(gate(x)) * up(x)) of width
         ``moe_intermediate_size`` with act ``hidden_act``, no shared one
+    K   learned sparse attention, then experts (PR 54): `D`'s block
+        under the causal mask, one sample a sequence, with
+        ``sa_config`` (``indexer_num_heads``, ``indexer_head_dim``,
+        ``indexer_num_kv_heads`` 1, ``topk``): an indexer scores every
+        causal pair from a detached copy of the layer's normed input, a
+        query keeps its ``topk`` best keys, the main GQA attention runs
+        over them alone, and the indexer's loss (the KL divergence from
+        the main attention's head-summed probabilities) joins the
+        step's; ``indexer_dtype``; rotary by three position streams
+        (``mrope_section``: the pairs each turns; ``mrope_positions``
+        [3][S] where they differ, else the token's index);
+        ``index_loss`` False leaves the indexer's loss out
     A   latent attention, then a SwiGLU MLP of ``intermediate_size``
         (gate and up as one product, ``b<i>_gate_up_proj``),
         each behind its own norm and residual. Latent attention
@@ -279,6 +291,13 @@ class DecoderConfig:
     diff_lambda_scale: float = 1.0
     memory_gated: bool = False
     cross_own_kv: bool = False
+    # `K`: learned sparse attention. `sa_config` as the public config
+    # names its keys; the three position streams; the indexer's loss
+    sa_config: Optional[dict] = None
+    indexer_dtype: str = "float32"
+    mrope_section: Optional[Sequence[int]] = None
+    mrope_positions: Optional[Sequence[Sequence[int]]] = None
+    index_loss: bool = True
     batch_size: int = 2
     seq_length: int = 16
     seq_parallel: Optional[str] = None      # 'seq': ring attention
@@ -311,8 +330,28 @@ def _attention_experts_block(ff, t, i, cfg, windowed):
     return ff.add(t, m, name=f"b{i}_res2")
 
 
-def _block_diffusion_block(ff, t, i, cfg):
-    """`D`: x' = x + attention(norm(x)), x'' = x' + experts(norm(x'))."""
+def _sparse_attention(ff, h, i, cfg):
+    """`K`'s attention: causal QK-normed rotary GQA over the keys an
+    indexer keeps (``sa_config``)."""
+    sa = dict(cfg.sa_config or {})
+    if sa.get("indexer_num_kv_heads", 1) != 1 or not sa.get("topk"):
+        raise ValueError(f"decoder: sa_config {sa} (an indexer of ONE key "
+                         f"head and a topk)")
+    return ff.multihead_attention(
+        h, h, h, cfg.hidden_size, cfg.num_attention_heads, bias=False,
+        causal=True, num_kv_heads=cfg.num_key_value_heads, rope=True,
+        rope_theta=cfg.rope_theta, head_dim=cfg.head_dim,
+        qk_norm=cfg.qk_norm, qk_norm_eps=cfg.layer_norm_epsilon,
+        sparse_index=(sa["indexer_num_heads"], sa["indexer_head_dim"],
+                      sa["topk"]),
+        indexer_dtype=cfg.indexer_dtype, mrope_section=cfg.mrope_section,
+        mrope_positions=cfg.mrope_positions, index_loss=cfg.index_loss,
+        name=f"b{i}_attn")
+
+
+def _block_diffusion_block(ff, t, i, cfg, sparse=False):
+    """`D`: x' = x + attention(norm(x)), x'' = x' + experts(norm(x'));
+    `K` (``sparse``): the same with learned sparse attention."""
     eps = cfg.layer_norm_epsilon
     if cfg.attention_mask not in ("block_diffusion", "causal"):
         raise ValueError(f"decoder: unknown attention_mask "
@@ -320,7 +359,7 @@ def _block_diffusion_block(ff, t, i, cfg):
     half = cfg.seq_length // 2
     masked = cfg.attention_mask == "block_diffusion"
     h = ff.rms_norm(t, eps=eps, name=f"b{i}_norm")
-    a = ff.multihead_attention(
+    a = _sparse_attention(ff, h, i, cfg) if sparse else ff.multihead_attention(
         h, h, h, cfg.hidden_size, cfg.num_attention_heads, bias=False,
         causal=not masked, num_kv_heads=cfg.num_key_value_heads, rope=True,
         rope_theta=cfg.rope_theta, head_dim=cfg.head_dim,
@@ -614,7 +653,7 @@ def _mixer(ff, h, letter, i, cfg):
         return ff.dense(ff.multiply(up, up, name=f"b{i}_sq"),
                         cfg.hidden_size, use_bias=False, name=name)
     raise ValueError(f"decoder pattern: unknown block letter {letter!r} "
-                     f"(known: M E * - L G W D A X F S C U m w y f g c)")
+                     f"(known: M E * - L G W D K A X F S C U m w y f g c)")
 
 
 def _stack(ff, t, pattern, cfg):
@@ -642,8 +681,8 @@ def _stack(ff, t, pattern, cfg):
         if letter in "GW":
             t = _attention_experts_block(ff, t, i, cfg, letter == "W")
             continue
-        if letter == "D":
-            t = _block_diffusion_block(ff, t, i, cfg)
+        if letter in "DK":
+            t = _block_diffusion_block(ff, t, i, cfg, sparse=letter == "K")
             continue
         h = ff.rms_norm(t, eps=cfg.layer_norm_epsilon, name=f"b{i}_norm")
         t = ff.add(t, _mixer(ff, h, letter, i, cfg), name=f"b{i}_res")
@@ -709,6 +748,10 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
     final_norm = (ff.layer_norm if set(pattern) <= set(SAMBAY_LETTERS)
                   else ff.rms_norm)
     t = final_norm(t, eps=cfg.layer_norm_epsilon, name="final_ln")
+    if "K" in pattern:
+        # the weighted loss then counts `loss/main_nll` beside the
+        # indexers' `loss/index_kl`
+        ff.loss_parts = ("main",)
     if mtp is not None:
         # one head over both hidden sequences laid end to end
         t = ff.concat([t, mtp], axis=1, name="main_and_mtp")

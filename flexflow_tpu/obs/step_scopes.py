@@ -38,6 +38,11 @@ SCOPE_PARTS = {"optimizer_update": "optimizer_update", "loss": "loss",
                # a multi-token-prediction module's own ops, whatever their
                # kind: the scope lies around theirs (`FFModel.scope`)
                "mtp": "mtp",
+               # the indexer of a learned-sparse-attention op: its
+               # projections, scores and selection, and its loss with the
+               # gradient (two nested calls beside the op's own
+               # `attention_sparse`, which is `attention` like its kind)
+               "sparse_indexer": "sparse_indexer",
                # what a looped model's passes share: the concatenation of
                # the passes, the head's and the exit gate's products
                "exit": "exit"}

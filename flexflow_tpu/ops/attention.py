@@ -44,8 +44,22 @@ from flexflow_tpu.ops.base import (DimRole, Op, OpContext, register_op,
                                    scoped)
 
 
+def mrope_angles(positions, sections, inv_freq):
+    """[S, r/2] angles of a multimodal rotary embedding: ``positions``
+    [3, S] (temporal, height, width streams), ``sections`` the rotary
+    pairs each stream turns, contiguous and in that order; pair i turns
+    by its stream's position times ``inv_freq[i]``."""
+    pos = jnp.asarray(positions, jnp.float32)
+    if sum(sections) != inv_freq.shape[0] or pos.shape[0] != len(sections):
+        raise ValueError(f"mrope: sections {tuple(sections)} over "
+                         f"{inv_freq.shape[0]} rotary pairs, positions "
+                         f"{pos.shape}")
+    stream = np.repeat(np.arange(len(sections)), sections)      # [r/2]
+    return pos[stream].T * inv_freq[None, :]
+
+
 def rotary_tables(s: int, d: int, inv_freq, attention_factor: float = 1.0,
-                  position_offset=0, wrap: int = 0):
+                  position_offset=0, wrap: int = 0, angles=None):
     """(cos, sin) [S, d] float32 of a rotary embedding over the first
     ``2 len(inv_freq)`` lanes of a ``d``-wide head, half-split pairs: row
     i stands at position offset + i (mod ``wrap`` where that is not 0),
@@ -53,10 +67,11 @@ def rotary_tables(s: int, d: int, inv_freq, attention_factor: float = 1.0,
     lanes hold the same cos and sin times ``attention_factor``, and the
     lanes past them 1 and 0. The one place the tables are formed: every
     form of the rotation below multiplies by the same bits."""
-    pos = position_offset + jnp.arange(s, dtype=jnp.float32)
-    if wrap:
-        pos = pos % wrap
-    angles = pos[:, None] * inv_freq[None, :]               # [S, r/2]
+    if angles is None:      # (``angles`` [S, r/2] given: `mrope_angles`)
+        pos = position_offset + jnp.arange(s, dtype=jnp.float32)
+        if wrap:
+            pos = pos % wrap
+        angles = pos[:, None] * inv_freq[None, :]           # [S, r/2]
     rest = (s, d - 2 * inv_freq.shape[0])
     cos = jnp.concatenate([jnp.cos(angles) * attention_factor] * 2
                           + [jnp.ones(rest, jnp.float32)], axis=-1)
@@ -66,7 +81,7 @@ def rotary_tables(s: int, d: int, inv_freq, attention_factor: float = 1.0,
 
 
 def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
-                     seq_axis: int = 2, wrap: int = 0):
+                     seq_axis: int = 2, wrap: int = 0, angles=None):
     """Apply RoPE to [B, H, S, D], or with ``seq_axis=1`` to [B, S, H, D]
     (HF Llama rotate-half convention): positions offset..offset+S-1,
     inv_freq = theta^(-2i/D). ``position_offset`` (static or traced
@@ -77,7 +92,8 @@ def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
     copies of one sample side by side."""
     s, d = x.shape[seq_axis], x.shape[-1]
     cos, sin = rotary_tables(s, d, rotary_frequencies(d, theta)[0],
-                             position_offset=position_offset, wrap=wrap)
+                             position_offset=position_offset, wrap=wrap,
+                             angles=angles)
     if seq_axis == 1:
         cos, sin = cos[:, None, :], sin[:, None, :]        # [S, 1, D]
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
@@ -259,6 +275,34 @@ def scaled_dot_product_attention(q, k, v, *, causal=False, dropout_rate=0.0,
     return out
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _index_kl_loss(qi, ki, w, lse_i, mask, q, k, lse, num_heads):
+    """The indexer's loss of one op through `pallas_kernels.index_kl`:
+    mean over the B S queries of a row's KL. The kernel forms the
+    gradient to (qi, ki, w) with the value, so the backward is a
+    scale."""
+    return _index_kl_fwd(qi, ki, w, lse_i, mask, q, k, lse, num_heads)[0]
+
+
+def _index_kl_fwd(qi, ki, w, lse_i, mask, q, k, lse, num_heads):
+    from flexflow_tpu.ops.pallas_kernels import index_kl
+
+    weight = 1.0 / (qi.shape[0] * qi.shape[1])
+    kl, dq, dw, dk = index_kl(qi, ki, w, lse_i, mask, q, k, lse, num_heads,
+                              weight)
+    return jnp.sum(kl) * weight, (dq.astype(qi.dtype), dk.astype(ki.dtype),
+                                  dw.astype(w.dtype))
+
+
+def _index_kl_bwd(num_heads, grads, g):
+    dq, dk, dw = grads
+    return (g.astype(dq.dtype) * dq, g.astype(dk.dtype) * dk,
+            g.astype(dw.dtype) * dw, None, None, None, None, None)
+
+
+_index_kl_loss.defvjp(_index_kl_fwd, _index_kl_bwd)
+
+
 @dataclasses.dataclass(frozen=True)
 class AttentionRoute:
     """What one forward of an attention op runs, as
@@ -298,6 +342,14 @@ class AttentionRoute:
     # pairs visible); None where flash does not run
     kv_blocks: Optional[Tuple[int, int, int]] = None
     window_pairs: Optional[Tuple[int, int]] = None
+    # learned sparse attention: the selection and the indexer's loss run
+    # the kernels `index_select` / `index_kl` (with core "flash": the
+    # chunk-loop kernels with the mask operand); else `ops/sparse_index`
+    sparse_kernels: bool = False
+
+
+# the indexer's leaves of an op with ``sparse_index``
+INDEXER_LEAVES = ("w_iq", "w_ik", "w_iw", "ik_norm_scale", "ik_norm_bias")
 
 
 @register_op(OperatorType.MULTIHEAD_ATTENTION)
@@ -514,6 +566,37 @@ class MultiHeadAttention(Op):
                 f"attention '{layer.name}': differential attention takes "
                 f"even numbers of query and key/value heads and no latent, "
                 f"rotary, head norm, gate, block-diffusion mask or ring")
+        # learned sparse attention (PR 54): (index heads, their width,
+        # keys a query keeps), the dtype of the index products' operands,
+        # the rotary pairs each of three position streams turns and, where
+        # the streams differ, their positions [3][S] (static: the same for
+        # every sample); whether the indexer's loss joins the step's
+        self.sparse_index = tuple(p.get("sparse_index") or ()) or None
+        self.indexer_dtype = p.get("indexer_dtype", "float32")
+        self.mrope_section = tuple(p.get("mrope_section") or ()) or None
+        self.mrope_positions = p.get("mrope_positions")
+        self.index_loss = bool(p.get("index_loss", True))
+        if self.sparse_index:
+            refused = [name for name, on in (
+                ("window", self.window),
+                ("block_diffusion", self.block_diffusion),
+                ("differential", self.differential),
+                ("kv_given", self.kv_given), ("export_kv", self.export_kv),
+                ("latent attention", self.latent), ("gate", self.gate),
+                ("seq_parallel", self.seq_parallel),
+                ("dropout", self.dropout)) if on]
+            if refused or not self.causal or len(input_shapes) != 3 \
+                    or input_shapes[0] != input_shapes[1]:
+                raise ValueError(
+                    f"attention '{layer.name}': sparse_index is causal "
+                    f"self-attention without {', '.join(refused) or 'cross inputs'}"
+                    f" (nobody has needed the combination yet)")
+            if self.indexer_dtype not in ("float32", "bfloat16"):
+                raise ValueError(
+                    f"attention '{layer.name}': indexer_dtype "
+                    f"{self.indexer_dtype!r}")
+            if self.indexer_dtype == "float32":
+                self.full_precision_params += INDEXER_LEAVES
         if (self.kv_given or self.export_kv) and (self.latent or self.rope
                                                   or self.qk_norm):
             raise ValueError(
@@ -589,6 +672,15 @@ class MultiHeadAttention(Op):
             # a key of its own: the four above stay what they were
             params["w_gate"] = self.kernel_init(jax.random.fold_in(rng, 4),
                                                 (e, h))
+        if self.sparse_index:
+            hi, di, _ = self.sparse_index
+            for i, (name, shape) in enumerate((
+                    ("w_iq", (e, hi * di)), ("w_ik", (e, di)),
+                    ("w_iw", (e, hi)))):
+                params[name] = self.kernel_init(
+                    jax.random.fold_in(rng, 16 + i), shape)
+            params["ik_norm_scale"] = jnp.ones((di,))
+            params["ik_norm_bias"] = jnp.zeros((di,))
         if self.use_bias:
             params["bo"] = jnp.zeros((e,))
             if self.qkv_bias:
@@ -725,6 +817,8 @@ class MultiHeadAttention(Op):
             scope = "block_diffusion"
         elif self.latent:
             scope = "latent"
+        elif self.sparse_index:
+            scope = "sparse"
         elif self.causal:
             scope = ("cross" if self.kv_given
                      else "window" if self.windowed else "full")
@@ -733,6 +827,10 @@ class MultiHeadAttention(Op):
         else:
             scope = "plain"
         one_device = not any(n > 1 for n in mesh_axes.values())
+        if self.sparse_index and core == "flash" and not (
+                one_device and pk.masked_flash_legal(sq, h, d)):
+            # the mask operand is the chunk-loop kernels', on one device
+            core = "einsum"
         rotary_in_lanes = bool(
             self.rope and not self.latent and pk.pallas_mode() != "off"
             and pk.rotary_lanes_shape_legal(sq, d, h)
@@ -769,6 +867,12 @@ class MultiHeadAttention(Op):
             h // shards, hk // shards, d))
         kind = (sq, self.causal, self.window, self.block_diffusion,
                 self.rope_dim)
+        if self.sparse_index:
+            return AttentionRoute(
+                core, blocked, fallback, scope, shard_axes, grouped_kv,
+                rotary_in_lanes, super_block=pk.super_block_engaged(*kind),
+                sparse_kernels=(self.sparse_index[1] == pk.INDEX_LANES
+                                and self.sparse_index[0] % 2 == 0))
         return AttentionRoute(
             core, blocked, fallback, scope, shard_axes, grouped_kv,
             rotary_in_lanes, one_span=pk.one_span(*kind) is not None,
@@ -819,6 +923,12 @@ class MultiHeadAttention(Op):
         # only a differential op publishes its key, as a tied product its
         extra = ({"executor.flash_diff_ops": int(route.core == "flash")}
                  if self.differential else {})
+        if self.sparse_index:   # published only where the model has one
+            kernels = route.core == "flash" and route.sparse_kernels
+            extra.update({
+                "executor.sparse_attention_ops": 1,
+                # the selection ran `index_select`, the loss `index_kl`
+                "executor.sparse_kernel_ops": int(bool(kernels))})
         return {
             **extra,
             "executor.flash_lane_dense_ops": int(route.core == "flash"),
@@ -861,6 +971,8 @@ class MultiHeadAttention(Op):
         if route.fallback and (self._kernel_fallback is None
                                or route.blocked in (None, "shape")):
             self._kernel_fallback = route.fallback
+        if self.sparse_index:
+            return self._forward_sparse(params, inputs, ctx, route)
         if route.scope == "plain":
             outs, lam = self._forward(params, inputs, ctx, rng, route)
         else:
@@ -902,6 +1014,154 @@ class MultiHeadAttention(Op):
                 w, x, o, ctx))(params["w_gate"], inputs[0], o)
         return [around(lambda params, o: self._output(
             params, o, ctx, dtype))(params, o)] + exported, lam
+
+    # ---- learned sparse attention (PR 54) ---------------------------------
+    @property
+    def _index_products(self):
+        """(operand dtype, XLA precision) of the indexer's products:
+        float32 at `highest`, or bfloat16 operands as they are."""
+        if self.indexer_dtype == "float32":
+            return jnp.float32, jax.lax.Precision.HIGHEST
+        return jnp.bfloat16, None
+
+    def _indexer(self, params, x, ctx: OpContext):
+        """The indexer's operands from a DETACHED copy of the op's
+        input x [B, S, E]: q_I [B, S, Hi * Di] and the ONE key k_I
+        [B, S, Di], both rotated over the
+        whole Di lanes at the temporal position stream, the key after a
+        LayerNorm; w [B, S, Hi] with Hi^-1/2 and Di^-1/2 folded in. The
+        products take ``indexer_dtype`` operands (float32 at `highest`)
+        and accumulate in float32."""
+        hi, di, _ = self.sparse_index
+        f32 = jnp.float32
+        od, precision = self._index_products
+        x = jax.lax.stop_gradient(x)
+        b, s, _ = x.shape
+
+        def product(w):
+            return jnp.dot(x.astype(od), w.astype(od), precision=precision,
+                           preferred_element_type=f32)
+
+        q, k, w = (product(params[n]) for n in ("w_iq", "w_ik", "w_iw"))
+        mean = jnp.mean(k, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(k - mean), axis=-1, keepdims=True)
+        k = ((k - mean) * jax.lax.rsqrt(var + self.qk_norm_eps)
+             * params["ik_norm_scale"].astype(f32)
+             + params["ik_norm_bias"].astype(f32))
+        angles = None
+        if self.mrope_positions is not None:   # the temporal stream
+            angles = (jnp.asarray(self.mrope_positions[0], f32)[:, None]
+                      * rotary_frequencies(di, self.rope_theta)[0][None, :])
+        q = rotary_embedding(q.reshape(b, s, hi, di), theta=self.rope_theta,
+                             seq_axis=1, angles=angles).reshape(b, s, hi * di)
+        k = rotary_embedding(k[:, :, None, :], theta=self.rope_theta,
+                             seq_axis=1, angles=angles)[:, :, 0, :]
+        return q.astype(od), k.astype(od), w * (hi * di) ** -0.5
+
+    def _kept_keys(self, params, x, ctx: OpContext, kernels: bool):
+        """The indexer's operands (`_indexer`) and the selection from
+        them: (q_I, k_I, w, mask [B, S, S] int8, the log-sum-exp of a
+        row's kept scores [B, S, 1], the pairs kept a tile or None). With
+        ``kernels`` `pallas_kernels.index_select` (float32 operands as
+        three bfloat16 passes), else `ops/sparse_index.py`'s whole
+        arrays; `lax.top_k`'s set either way."""
+        from flexflow_tpu.ops import pallas_kernels as pk
+        from flexflow_tpu.ops import sparse_index as si
+
+        topk = self.sparse_index[2]
+        od, precision = self._index_products
+        qi, ki, w = self._indexer(params, x, ctx)
+        if kernels:   # the selection is piecewise constant
+            mask, lse_i, counts = pk.index_select(*(
+                jax.lax.stop_gradient(t) for t in (qi, ki, w)), topk,
+                pk.BF16_3X if od == jnp.float32 else None)
+        else:
+            scores = si.index_scores(qi, ki, w, precision)
+            mask = si.select(scores, topk)
+            lse_i, counts = si.kept_lse(scores, mask), None
+        return qi, ki, w, mask, jax.lax.stop_gradient(lse_i), counts
+
+    def _forward_sparse(self, params, inputs, ctx: OpContext,
+                        route: AttentionRoute):
+        """An op with ``sparse_index``, as three nested calls: the
+        indexer's operands and the selection (`sparse_indexer`), the main
+        attention over the kept pairs with its projections
+        (`attention_sparse`, the kernels under `flash_sparse`), and in
+        training the indexer's loss (`sparse_indexer` again), which
+        leaves on the executor's ``_aux_loss`` side channel. The main
+        attention hands the indexer nothing but detached values: no
+        gradient of the language-model loss reaches the indexer's
+        leaves, and none of the indexer's loss any other leaf."""
+        from flexflow_tpu.ops import pallas_kernels as pk
+        from flexflow_tpu.ops import sparse_index as si
+
+        h, hk, _ = self.core_heads
+        cd, dtype = ctx.compute_dtype, inputs[0].dtype
+        precision = self._index_products[1]
+        kernels = route.core == "flash" and route.sparse_kernels
+
+        qi, ki, w, mask, lse_i, counts = scoped(
+            "sparse_indexer", lambda params, x: self._kept_keys(
+                params, x, ctx, kernels))(params, inputs[0])
+
+        def attend(params, inputs, mask, counts):
+            q, k, v, _ = self._qkv(params, inputs, ctx, route)
+            q = q.astype(cd)
+            if route.core == "flash":
+                k, v = self._kv_for_core(k, v, ctx, route)
+                o, lse = scoped("flash_sparse", lambda q, k, v, mask, counts: (
+                    pk.flash_attention_masked(
+                        q, k, v, mask, h, hk if route.grouped_kv else None,
+                        counts)))(q, k, v, mask, counts)
+                # (k stays at the KV heads: at heads of 128 every group
+                # is one the kernels read as it is)
+                lse = lse[:, :, 0, :].transpose(0, 2, 1)     # [B, S, H]
+            else:
+                k, v = k.astype(cd), v.astype(cd)
+                o, lse = si.masked_attention(q, k, v, mask, h, hk)
+            # the keys at the KV heads, for the indexer's loss
+            return self._output(params, o, ctx, dtype), q, k, lse
+
+        y, q, k, lse = scoped(self.scopes_itself + route.scope, attend)(
+            params, inputs, mask, counts)
+        pairs = (jnp.sum(mask, dtype=jnp.int32) if counts is None
+                 else jnp.sum(counts))
+        skipped = visited = jnp.int32(0)
+        if route.core == "flash":
+            # the pairs in the tiles the kernels work through, forward
+            # and backward added (`pallas_kernels.visited_pairs`), less
+            # the tiles their summaries let them skip
+            n = mask.shape[1]
+            visited = jnp.int32(mask.shape[0] * pk.visited_pairs(n, True))
+            for rows, keys in pk.masked_tiles(n):
+                tiles = pk.mask_tiles_any(mask, rows, keys, counts)
+                at_q = jnp.arange(tiles.shape[1])[:, None]
+                at_k = jnp.arange(tiles.shape[2])[None, :]
+                empty = jnp.sum((tiles == 0) & (at_k * keys
+                                                < (at_q + 1) * rows),
+                                dtype=jnp.int32)
+                skipped = skipped + empty
+                visited = visited - empty * (rows * keys)
+        self._counters = {
+            "attention/selected_pairs": ("sum", pairs),
+            "attention/visited_pairs": ("sum", visited),
+            "attention/mask_tiles_skipped": ("sum", skipped)}
+        if ctx.training and self.index_loss:
+            q, k, lse = (jax.lax.stop_gradient(t) for t in (q, k, lse))
+
+            def loss(qi, ki, w, lse_i, mask, q, k, lse):
+                if kernels:
+                    return _index_kl_loss(qi, ki, w, lse_i, mask, q, k, lse,
+                                          h)
+                scores = si.index_scores(qi, ki, w, precision)
+                return si.index_kl(scores, mask, si.head_sum(
+                    q, k, lse, mask, h, hk))
+
+            kl = scoped("sparse_indexer", loss)(qi, ki, w, lse_i, mask, q,
+                                                k, lse)
+            self._aux_loss = kl
+            self._counters["loss/index_kl"] = ("sum", kl)
+        return [y]
 
     def _qkv(self, params, inputs, ctx: OpContext, route: AttentionRoute):
         """q [B, S, H*D] in the compute dtype and k, v: the projections,
@@ -1009,10 +1269,16 @@ class MultiHeadAttention(Op):
         def rotate(q, k, scales):
             inv_freq, factor = rotary_frequencies(r, self.rope_theta,
                                                   self.rope_scaling)
+            # three position streams that differ (equal streams are the
+            # plain rotation: nothing is formed)
+            angles = (mrope_angles(self.mrope_positions, self.mrope_section,
+                                   inv_freq)
+                      if self.mrope_positions is not None else None)
             if lanes:
                 def tables(s):  # one head's; the sine has the partner's sign
                     cos, sin = rotary_tables(s, d, inv_freq, factor,
-                                             wrap=self.rope_wrap)
+                                             wrap=self.rope_wrap,
+                                             angles=angles)
                     return cos, jnp.where(jnp.arange(d) < r // 2, -sin, sin)
 
                 of_length = {s: tables(s) for s in {q.shape[1], k.shape[1]}}
@@ -1025,7 +1291,8 @@ class MultiHeadAttention(Op):
                 b, s, width = t.shape
                 t = t.reshape(b, s, width // d, d)
                 t = (rotary_embedding(t, theta=self.rope_theta, seq_axis=1,
-                                      wrap=self.rope_wrap) if whole
+                                      wrap=self.rope_wrap, angles=angles)
+                     if whole
                      else rotary_partial(t, inv_freq, rotary_dim=r,
                                          attention_factor=factor))
                 out.append(t.reshape(b, s, width))
@@ -1172,6 +1439,8 @@ class MultiHeadAttention(Op):
     def decode_forward(self, params, inputs, ctx: OpContext,
                        k_cache, v_cache, pos):
         """KV-cache incremental forward (flexflow_tpu/serve/kv_cache.py).
+        An op with ``sparse_index`` is refused (the selection over a
+        cache is not written: ROADMAP Reach).
 
         ``inputs``: the NEW token block only — query/key/value rows
         ``[B, T, E]`` at absolute positions ``pos..pos+T-1`` (prefill is
@@ -1188,6 +1457,12 @@ class MultiHeadAttention(Op):
         (a bidirectional row would need future K/V that doesn't exist
         yet); non-causal ops refuse rather than silently drift.
         """
+        if self.sparse_index:
+            raise NotImplementedError(
+                f"attention '{self.name}': KV-cache incremental decode has "
+                f"no learned sparse attention (a new query would score "
+                f"every cached position with the indexer, whose key the "
+                f"cache does not hold, and attend over the kept ones)")
         if self.differential or self.kv_given or self.export_kv:
             raise NotImplementedError(
                 f"attention '{self.name}': KV-cache incremental decode has "
@@ -1326,7 +1601,31 @@ class MultiHeadAttention(Op):
             3 if self.differential else 2)
         # the gate's product and its multiply of the core's output
         gate = (2 * e + d) * b * sq * h if self.gate else 0
+        if self.sparse_index:
+            # the main products over the pairs the kernels visit (every
+            # causal tile: a query's kept keys lie scattered), the
+            # indexer's projections, its scores over every causal pair
+            # and, with the loss, the main heads' probabilities and the
+            # three gradient products over them once more
+            hi, di, _ = self.sparse_index
+            pairs = b * sq * (sq + 1) // 2
+            core = 4 * h * d * pairs
+            index = (2 * b * sq * e * (hi * di + di + hi)
+                     + 2 * hi * di * pairs)
+            if self.index_loss:
+                index += (2 * hi * di * 3 + 2 * h * d) * pairs
+            return proj + core + index
         return proj + core + gate
+
+    def sparse_saved_bytes(self) -> int:
+        """What an op with ``sparse_index`` keeps for its backward pass
+        beside its output: the mask, a byte a (query, key) pair, and the
+        indexer's operands with their gradients, which the loss's
+        kernel forms with its value."""
+        b, s, _ = self.input_shapes[0]
+        hi, di, _ = self.sparse_index
+        width = 2 if self.indexer_dtype == "bfloat16" else 4
+        return b * s * s + b * s * (hi * di + di + hi) * (width + 4)
 
     def params_elems(self):
         h, e, d = self.num_heads, self.embed_dim, self.head_dim
@@ -1340,6 +1639,9 @@ class MultiHeadAttention(Op):
             n += 2 * d
         if self.gate:
             n += e * h
+        if self.sparse_index:
+            hi, di, _ = self.sparse_index
+            n += e * (hi * di + di + hi) + 2 * di
         if self.differential:
             n += 6 * d
         if self.use_bias:

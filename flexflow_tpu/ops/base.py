@@ -80,7 +80,9 @@ def scoped(name: str, fn: Callable) -> Callable:
     `attention_plain`; `gated_conv` inside `op_short_conv` (PR 45);
     `mamba_mixer` / `selective_scan`, `gated_memory`,
     `attention_diff_full` / `attention_diff_window` /
-    `attention_diff_cross` with `flash_diff` in them (PR 52). The
+    `attention_diff_cross` with `flash_diff` in them (PR 52);
+    `attention_sparse` with `flash_sparse` in it, and `sparse_indexer`
+    beside it (PR 54). The
     benchmark's readers match nine of them as bare substrings of an
     `op_name`, so a new name holds none of them.
 

@@ -111,6 +111,10 @@ LANES = 128  # a vreg's and an HBM tile's minor extent
 # S <= MAX_FLASH_SEQ at head_dim <= MAX_FLASH_HEAD_DIM in bf16 and f32
 # (tests/test_tpu_compile.py), and refuses head_dim 256 at S = 16384.
 _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
+# with a mask operand (PR 54) a grid step also holds its rows of the
+# mask, [1024, S] bytes twice (16 MiB each at S = 16384), which the
+# backward's panels leave no room for under 96 MiB
+_MASKED_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=120 << 20)
 
 # Every kernel's ``pallas_call`` carries a ``name=``: XLA names the custom
 # call's HLO instruction after it, and the profiler names a device event
@@ -1061,7 +1065,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
                       window: int, scale: float, blk_q: int, blk_k: int,
                       head_dim: int, block_diffusion=None,
                       rope_dim: int = 0, group=None, tiles: int = 1,
-                      chains: int = 1, trim: bool = False):
+                      chains: int = 1, trim: bool = False,
+                      masked: bool = False):
     """One (batch row, column block, ``tiles`` Q blocks) grid cell: q
     [1, tiles * BLK_Q, W] against the K/V panels [1,S,W] resident in
     VMEM, in chunks of ``blk_k`` keys with a running max and sum (the
@@ -1121,14 +1126,29 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
     lanes at the end. Laying the KV head twice a fetched chunk
     (``_own_kv_head``) costs a roll and a select of [1024, 128] K and V
     8,704 times an op at S = 16384 and was 1.25 ms slower in the lab
-    (``grouped_kv_shape_legal``'s table)."""
-    if rope_dim:
+    (``grouped_kv_shape_legal``'s table).
+
+    ``masked`` (PR 54, learned sparse attention): two more operands
+    stand before the outputs, a MASK that is data, one byte a (query,
+    key) pair, this step's rows of it [1, rows, S], and in SMEM its
+    per-tile summary [B, S / rows, S / blk_k] (pairs selected in the
+    tile of a super-block's rows and a K chunk). A tile's score is
+    masked where the byte is 0, in every chunk (the mask holds no pair
+    the causal rule hides, so the static rule's own mask is left out),
+    and a chunk whose summary is 0 is SKIPPED: the carry passes through
+    a `lax.cond`. Without ``masked`` nothing here is traced and the
+    kernel is what it was."""
+    if masked:
+        assert not rope_dim and not block_diffusion, "mask operand: plain"
+        mask_ref, any_ref, o_ref, lse_ref = rest
+    elif rope_dim:
         qr_ref, kr_ref, o_ref, lse_ref = rest
     else:
         o_ref, lse_ref = rest
     part = _kv_part(1, group)
     # the grid's place, taken outside the loop over the super-blocks
     head, step = pl.program_id(1), pl.program_id(2)
+    row = pl.program_id(0) if masked else None
     heads = q_ref.shape[-1] // head_dim
     rows = chains * blk_q             # a super-block's
     supers = tiles // chains          # super-blocks a grid step
@@ -1169,6 +1189,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
                 k0 + jax.lax.broadcasted_iota(jnp.int32, tile, 1), window)
 
         def chunk(c, carry, edge, kept=None):
+            if masked:      # a tile no query of which selected a key
+                return jax.lax.cond(
+                    any_ref[row, step * supers + i, c] > 0,
+                    lambda carry: work(c, carry, True, kept),
+                    lambda carry: carry, carry)
+            return work(c, carry, edge, kept)
+
+        def work(c, carry, edge, kept=None):
             k0 = pl.multiple_of(c * blk_k, blk_k)
             k = k_ref[0, pl.ds(k0, blk_k), :]  # [BLK_K, W], once a chunk
             v = v_ref[0, pl.ds(k0, blk_k), :]
@@ -1181,8 +1209,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
                 whole = (lo, hi) == (0, blk_k)
                 kt, vt, krt = (k, v, kr) if whole else (
                     k[lo:hi], v[lo:hi], kr[lo:hi] if rope_dim else None)
-                mask = seen(q0s[t], k0 + lo,
-                            (blk_q, hi - lo)) if edge else None
+                if masked:
+                    mask = mask_ref[0, at[t], pl.ds(k0 + lo, hi - lo)
+                                    ].astype(jnp.int32) != 0
+                else:
+                    mask = seen(q0s[t], k0 + lo,
+                                (blk_q, hi - lo)) if edge else None
                 for h in range(heads):
                     m, l, acc = carry[t * heads + h]
                     s = _dot(qs[t][h], kt, _NT)
@@ -1367,7 +1399,7 @@ def _group_size(num_heads: int, num_kv_heads, head_dim: int, rope=None):
 
 def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
                out_dtype=None, window: int = 0, block_diffusion=None,
-               rope=None, num_kv_heads=None):
+               rope=None, num_kv_heads=None, mask=None):
     """q, k, v: [B, S, H*D] with S % BLK_Q == 0 -> (o [B, S, H*D],
     lse [B, H, 1, S]). A block is S (or BLK_Q) rows by one column block
     of the operand, picked by the BlockSpec's last index: in HBM's
@@ -1392,7 +1424,11 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     ONE vector a position for all heads (latent attention; D = 128). The
     kernel reads that key as it is, laid twice side by side so that a
     128-lane block of q_rope (two heads' parts, the other's zeroed)
-    contracts against it; nothing is assembled per head in HBM."""
+    contracts against it; nothing is assembled per head in HBM.
+
+    ``mask`` (PR 54) = (mask [B, S, S] int8, its summary over the
+    forward's tiles, ``mask_tiles_any(mask, *masked_tiles(S)[0])``): the
+    chunk-loop kernel alone takes it (``masked_flash_legal``)."""
     b, s, hd = q.shape
     d = hd // num_heads
     hpb = _heads_per_block(num_heads, d)
@@ -1447,12 +1483,18 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
             _flash_fwd_kernel, causal=causal, window=window, scale=scale,
             blk_q=rows, blk_k=_seq_block(s, bd, window), head_dim=d,
             block_diffusion=bd, rope_dim=rope_dim, group=group,
-            tiles=tiles, chains=chains, trim=trim)
+            tiles=tiles, chains=chains, trim=trim,
+            **({"masked": True} if mask is not None else {}))
         blk = tiles * rows
     rope_specs = [
         pl.BlockSpec((1, blk, LANES), lambda b, j, i: (b, i, j // per)),
         pl.BlockSpec((1, s, LANES), lambda b, j, i: (b, 0, 0)),
     ] if rope_dim else []
+    mask_specs = []
+    if mask is not None:    # this step's rows of the mask; its summary
+        assert one is None and not rope_dim and tiles == chains, (s, window)
+        mask_specs = [pl.BlockSpec((1, blk, s), lambda b, j, i: (b, i, 0)),
+                      pl.BlockSpec(memory_space=pltpu.SMEM)]
     return pl.pallas_call(
         kernel,
         name=KERNEL_NAME_PREFIX + "flash_fwd",
@@ -1462,13 +1504,14 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
             pl.BlockSpec((1, blk, w), lambda b, j, i: (b, i, j)),
         ] + [pl.BlockSpec((1, s, w), (lambda b, j, i: (b, 0, j)) if rep == 1
                           else (lambda b, j, i: (b, 0, j // rep)))] * 2
-        + rope_specs,
+        + rope_specs + mask_specs,
         out_specs=(pl.BlockSpec((1, blk, w), lambda b, j, i: (b, i, j)),
                    pl.BlockSpec((1, hpb, 1, blk),
                                 lambda b, j, i: (b, j, 0, i))),
         interpret=interpret,
-        compiler_params=_FLASH_COMPILER_PARAMS,
-    )(q, k, v, *rope_ops)
+        compiler_params=(_FLASH_COMPILER_PARAMS if mask is None
+                         else _MASKED_COMPILER_PARAMS),
+    )(q, k, v, *rope_ops, *(mask or ()))
 
 
 def _turned(q, o, do):
@@ -1525,8 +1568,10 @@ def _flash_bwd_tile(q, k, kt, v, o, do, lse, glse, scale: float, mask,
         if rope is not None:
             st = st + _dot(rope[1], rope[0], _NT)
         st = st * scale
-        if mask is not None:
+        if isinstance(mask, tuple):
             st = _mask(st, *mask)
+        elif mask is not None:   # a mask that is data, [k, q] (PR 54)
+            st = jnp.where(mask, st, _MASKED)
         pt = jnp.exp(st - lse[h])                    # exact softmax probs
         dpt = _dot_head(v, do, h, head_dim)
         delta = jnp.sum(dot_ot[mine], axis=0, keepdims=True)   # [1, q]
@@ -1605,7 +1650,7 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                               scale: float, blk: int, head_dim: int,
                               block_diffusion=None, rope_dim: int = 0,
                               grouped: bool = False, group=None,
-                              subs: int = 1):
+                              subs: int = 1, masked: bool = False):
     """FA2 backward for sequences past MAX_BWD_SEQ: grid cell = one
     (batch row, column block, K-block). The Q/O/dO panels are resident;
     the K-block meets them in chunks of ``blk`` queries, and ONLY the
@@ -1675,7 +1720,13 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     chunk), and at the end the two heads' dK^T / dV^T, sublane ranges of
     the scratch, are added into that head's sublanes
     (``_group_halves``); the other half gets zeros from this member, so
-    the lane block's first member still writes without reading."""
+    the lane block's first member still writes without reading.
+
+    ``masked`` (PR 54): the mask that is data, TRANSPOSED, this K
+    block's rows of it [1, blk, S] (keys by queries, as the score tile
+    lies here), and in SMEM the summary [B, S / blk, S / blk] by (Q
+    chunk, K block); a chunk whose summary is 0 is skipped, every other
+    is masked by the bytes alone (``_flash_fwd_kernel``)."""
     j = pl.program_id(3 if grouped else 2)
     k0 = j * blk
     part = _kv_part(2, group)
@@ -1694,6 +1745,11 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             dqr_ref[0] = jnp.zeros(dqr_ref.shape[1:], dqr_ref.dtype)
 
         dkrt_ref[...] = jnp.zeros(dkrt_ref.shape, jnp.float32)
+    elif masked:
+        assert not block_diffusion, "mask operand: plain"
+        (maskt_ref, any_ref, dq_ref, dk_ref, dv_ref, dkt_ref,
+         dvt_ref) = rest
+        row = pl.program_id(0)
     else:
         dq_ref, dk_ref, dv_ref, dkt_ref, dvt_ref = rest
 
@@ -1702,6 +1758,13 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dq_ref[0] = jnp.zeros(dq_ref.shape[1:], dq_ref.dtype)
 
     def chunk(c, _, edge, kept=None):
+        if masked:      # a tile no query of which selected a key
+            pl.when(any_ref[row, c, j] > 0)(
+                lambda: work(c, True, kept))
+        else:
+            work(c, edge, kept)
+
+    def work(c, edge, kept=None):
         q0 = pl.multiple_of(c * blk, blk)
         rows = pl.ds(q0, blk)
         q, o, do = q_ref[0, rows, :], o_ref[0, rows, :], do_ref[0, rows, :]
@@ -1729,6 +1792,8 @@ def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             dqt, dkt, dvt, *dr = _flash_bwd_tile(
                 of_rows(q), kn, ktn, vn, of_rows(o), of_rows(do),
                 of_lanes(lse), of_lanes(glse), scale,
+                (maskt_ref[0, keys, pl.ds(q0 + lo, hi - lo)
+                           ].astype(jnp.int32) != 0) if masked else
                 (k0 + n * sub, q0 + lo, window, block_diffusion)
                 if edge else None, head_dim,
                 (of_rows(qr), krn) if rope_dim else None,
@@ -1811,7 +1876,8 @@ def _flash_bwd_span_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
                interpret: bool, glse=None, window: int = 0,
-               block_diffusion=None, rope=None, num_kv_heads=None):
+               block_diffusion=None, rope=None, num_kv_heads=None,
+               mask=None):
     """dq, dk, dv [B, S, H*D] from the saved (o, lse[B, H, 1, S]): one
     whole-tile step for several heads up to MAX_BWD_SEQ, K-blocked past
     it — scores stay in VMEM tiles at every length the gate admits
@@ -1903,7 +1969,8 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
             _flash_bwd_blocked_kernel, causal=causal, window=window,
             scale=scale, blk=blk, head_dim=d, block_diffusion=bd,
             rope_dim=rope_dim, grouped=grouped, group=group,
-            subs=super_block(s, window, bd)[1])
+            subs=super_block(s, window, bd)[1],
+            **({"masked": True} if mask is not None else {}))
         # dK^T and dV^T (and dKr^T) added up over a block's Q chunks
         scratch = [pltpu.VMEM((w, blk), jnp.float32)] * 2 + (
             [pltpu.VMEM((LANES, blk), jnp.float32)] if rope_dim else [])
@@ -1922,6 +1989,13 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         # the KV head's whole panel: resident across its group
         dkv_spec = pl.BlockSpec((1, s, w), lambda b, g, r, j: (b, 0, g))
         grid = (b, num_heads // hpb // rep, rep, s // blk)
+    mask_specs = []
+    if mask is not None:    # (mask^T [B, S, S] int8, its summary)
+        assert one is None and not rope_dim and s > MAX_BWD_SEQ, (s, window)
+        mask_specs = [
+            pl.BlockSpec((1, blk, s), (lambda b, g, r, j: (b, j, 0))
+                         if grouped else (lambda b, c, j: (b, j, 0))),
+            pl.BlockSpec(memory_space=pltpu.SMEM)]
     return finish(*pl.pallas_call(
         kernel,
         name=KERNEL_NAME_PREFIX + "flash_bwd_blocked",
@@ -1934,13 +2008,14 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         grid=grid,
         in_specs=[seq_spec, kblk_spec, kblk_spec, seq_spec, seq_spec,
                   row_spec, row_spec] + (
-                      [qr_spec, kr_spec] if rope_dim else []),
+                      [qr_spec, kr_spec] if rope_dim else []) + mask_specs,
         out_specs=(seq_spec, dkv_spec, dkv_spec) + (
             (qr_spec, kblk_spec) if rope_dim else ()),
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=_FLASH_COMPILER_PARAMS,
-    )(q, k, v, o, do, lse, glse, *rope_ops))
+        compiler_params=(_FLASH_COMPILER_PARAMS if mask is None
+                         else _MASKED_COMPILER_PARAMS),
+    )(q, k, v, o, do, lse, glse, *rope_ops, *(mask or ())))
 
 
 def _xla_attention(q, k, v, causal: bool, window: int = 0,
@@ -2036,6 +2111,453 @@ def _flash_lse_vjp_bwd(num_heads, causal, interpret, res, gs):
 
 
 flash_attention_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention (PR 54): a mask that is DATA. An indexer scores
+# every causal (query, key) pair, a query keeps its `topk` best keys, the
+# main attention runs over those alone (the chunk-loop kernels above with
+# a mask operand), and the indexer learns from the KL divergence between
+# the main attention's head-summed probabilities and its own softmax over
+# the kept keys. Three kernels: `index_select` (scores in blocks of query
+# rows that never leave VMEM, and an EXACT selection: the k-th largest of
+# a row by bisection over the bits of its scores, ties to the lower key,
+# `lax.top_k`'s set), the masked flash pair, and `index_kl` (the loss a
+# row and its gradient to the indexer's queries, key and weights, tile by
+# tile from q, k and the saved log-sum-exps).
+
+INDEX_LANES = 64    # an index head's width: two heads a 128-lane block
+
+
+def masked_flash_legal(s: int, num_heads: int, head_dim: int) -> bool:
+    """Whether the chunk-loop flash kernels take a mask operand at this
+    shape: a length past the whole-tile kernels, heads of 128 (a column
+    block a head; the grouped form reads the KV heads as they are)."""
+    return (s > MAX_BWD_SEQ and head_dim == LANES
+            and flash_shape_legal(s, head_dim, num_heads))
+
+
+def masked_tiles(s: int):
+    """((rows, keys) of the forward's tile a summary entry stands for,
+    the backward's): a super-block's rows by a K chunk, a Q chunk by a
+    K block."""
+    tiles, chains, _ = super_block(s)[0]
+    blk = _seq_block(s)
+    return (chains * _q_block(s), blk), (blk, blk)
+
+
+def mask_tiles_any(mask, rows: int, keys: int, counts=None):
+    """[B, S / rows, S / keys] int32: the pairs a tile of the mask
+    [B, S, S] holds (0: the kernels skip it). ``counts``
+    (`index_select`'s third result): added up from its finer tiles
+    where they nest in these, with no pass over the mask."""
+    b, s, _ = mask.shape
+    if counts is not None:
+        r, k = s // counts.shape[1], s // counts.shape[2]
+        if rows % r == 0 and keys % k == 0:
+            return jnp.sum(counts.reshape(b, s // rows, rows // r,
+                                          s // keys, keys // k), axis=(2, 4))
+    return jnp.sum(mask.reshape(b, s // rows, rows, s // keys, keys),
+                   axis=(2, 4), dtype=jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_masked(q, k, v, mask, counts, num_heads, num_kv_heads, interpret):
+    return _flash_masked_fwd(q, k, v, mask, counts, num_heads, num_kv_heads,
+                             interpret)[0]
+
+
+def _flash_masked_fwd(q, k, v, mask, counts, num_heads, num_kv_heads,
+                      interpret):
+    k, v = _stored(q, k, v, num_kv_heads)
+    fwd, bwd = masked_tiles(q.shape[1])
+    o, lse = _flash_fwd(q, k, v, num_heads, True, interpret,
+                        num_kv_heads=num_kv_heads,
+                        mask=(mask, mask_tiles_any(mask, *fwd, counts)))
+    return (o, lse), (q, k, v, mask, mask_tiles_any(mask, *bwd, counts), o,
+                      lse)
+
+
+def _flash_masked_bwd(num_heads, num_kv_heads, interpret, res, g):
+    q, k, v, mask, tiles, o, lse = res
+    # the score tile lies [keys, queries] in the backward: the mask
+    # turned once an op, and its summary by (Q chunk, K block)
+    dq, dk, dv = _flash_bwd(
+        q, k, v, o, lse, g[0], num_heads, True, interpret,
+        num_kv_heads=num_kv_heads, mask=(jnp.swapaxes(mask, 1, 2), tiles))
+    return dq, dk, dv, None, None
+
+
+_flash_masked.defvjp(_flash_masked_fwd, _flash_masked_bwd)
+
+
+def flash_attention_masked(q, k, v, mask, num_heads: int, num_kv_heads=None,
+                           counts=None):
+    """(o [B, S, H*D], lse [B, H, 1, S]) of causal attention over the
+    pairs ``mask`` [B, S, S] (int8, 0 or 1, no pair the causal rule
+    hides) keeps. The mask gets no gradient and lse's cotangent is not
+    read (the indexer's loss takes it detached). ``counts``:
+    `index_select`'s, for the per-tile summaries (`mask_tiles_any`)."""
+    assert masked_flash_legal(q.shape[1], num_heads,
+                              q.shape[2] // num_heads), q.shape
+    interpret = pallas_mode() == "interpret"
+    if num_kv_heads in (None, num_heads):
+        return _flash_masked(q, k, v, mask, counts, num_heads, None,
+                             interpret)
+    return _flash_masked(q, k.astype(jnp.float32), v.astype(jnp.float32),
+                         mask, counts, num_heads, num_kv_heads, interpret)
+
+
+def index_blocks(s: int):
+    """(query rows a grid step of `index_select`, keys a chunk of its
+    loops): the largest of 256 / 128 rows and 512 / 256 / 128 keys that
+    divide S (the published kernel tiles by 512)."""
+    rows = 256 if s % 256 == 0 else 128
+    keys = next(c for c in (512, 256, 128) if s % c == 0)
+    return rows, keys
+
+
+def _sortable(x):
+    """float32 -> int32 of the same order (-0.0 first made +0.0)."""
+    bits = jax.lax.bitcast_convert_type(x + 0.0, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _unsortable(key):
+    return jax.lax.bitcast_convert_type(
+        jnp.where(key < 0, key ^ jnp.int32(0x7FFFFFFF), key), jnp.float32)
+
+
+_INT_MIN = -2 ** 31
+
+
+BF16_3X = "bf16_3x"     # float32 operands as three bfloat16 MXU passes
+
+
+def _split(x):
+    """A float32 tile as (high, low) bfloat16 parts: x = high + low to
+    2^-17 of its size."""
+    high = x.astype(jnp.bfloat16)
+    return high, (x - high.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _index_scores(q, kk, w, heads: int, precision):
+    """I [rows, keys] float32 = sum_j w[:, j] relu(q_j . k): q [rows,
+    heads * 64], two heads a 128-lane block, against the ONE key laid
+    twice side by side kk [keys, 128], the other head's lanes zeroed.
+    ``precision``: None (the operands as they come, one MXU pass),
+    `Precision.HIGHEST` (float32 operands, the compiler's six passes:
+    no program takes it; `scripts/sparse_lab.py` and the tests compare
+    against it) or
+    ``BF16_3X`` (float32 operands split here into bfloat16 high and low
+    parts, high . high + high . low + low . high: XLA's `Precision.HIGH`,
+    which Mosaic's dot does not take; 2^-16 of a product's size)."""
+    split = precision == BF16_3X
+    if split:
+        (q, q_low), (kk, kk_low) = _split(q), _split(kk)
+        precision = None
+
+    def dot(a, b):
+        return jax.lax.dot_general(a, b, _NT, precision=precision,
+                                   preferred_element_type=jnp.float32)
+
+    acc = None
+    for j in range(heads):
+        lanes = slice((j // 2) * LANES, (j // 2 + 1) * LANES)
+        head = _only_head(q[:, lanes], j % 2, INDEX_LANES)
+        s = dot(head, kk)
+        if split:
+            s = s + dot(head, kk_low) + dot(
+                _only_head(q_low[:, lanes], j % 2, INDEX_LANES), kk)
+        term = w[:, j:j + 1] * jnp.maximum(s, 0.0)
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _index_select_kernel(q_ref, k_ref, w_ref, mask_ref, lse_ref, count_ref,
+                         key_ref, *, topk: int, heads: int, rows: int,
+                         keys: int, precision):
+    """One (batch row, block of ``rows`` queries) grid cell: the block's
+    index scores against every chunk of ``keys`` keys up to its own
+    positions, kept in VMEM as sortable integers (``key_ref`` [rows, S];
+    a pair the causal rule hides holds the smallest); then a row's
+    min(t + 1, topk)-th largest by bisection over the 32 bits (a pass
+    over the row's chunks a bit: a compare and a count), the ties at
+    that value to the lower key by a second bisection over positions
+    (run only where some row of the block has more ties than places);
+    then the mask's rows, the log-sum-exp of the kept scores and the
+    pairs kept a chunk (``count_ref`` [1, 1, 1, S / keys]: what the
+    flash kernels' per-tile summaries add up from, so that no pass over
+    the mask forms them)."""
+    i = pl.program_id(1)
+    q0 = i * rows
+    s = k_ref.shape[1]
+    chunks = (q0 + rows + keys - 1) // keys      # that hold a causal key
+    q, w = q_ref[0], w_ref[0]
+    t = q0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    want = jnp.minimum(t + 1, topk)
+
+    def score(c, top):
+        k0 = pl.multiple_of(c * keys, keys)
+        val = _index_scores(q, k_ref[0, pl.ds(k0, keys), :], w, heads,
+                            precision)
+        pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+        key_ref[:, pl.ds(k0, keys)] = jnp.where(pos <= t, _sortable(val),
+                                                _INT_MIN)
+        return jnp.maximum(top, jnp.max(
+            jnp.where(pos <= t, val, _MASKED), axis=1, keepdims=True))
+
+    # a row's largest score (always kept): the shift of its exponentials
+    top = jax.lax.fori_loop(0, chunks, score,
+                            jnp.full((rows, 1), _MASKED, jnp.float32))
+
+    def count(pred):
+        """[rows, 1]: the row's keys of the causal chunks for which
+        ``pred(keys, positions)`` holds; lane blocks add up elementwise
+        and ONE cross-lane sum closes the pass."""
+        def body(c, part):
+            k0 = pl.multiple_of(c * keys, keys)
+            x = key_ref[:, pl.ds(k0, keys)]
+            for u in range(keys // LANES):
+                lanes = slice(u * LANES, (u + 1) * LANES)
+                pos = k0 + u * LANES + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, LANES), 1)
+                part = part + pred(x[:, lanes], pos).astype(jnp.int32)
+            return part
+
+        part = jax.lax.fori_loop(0, chunks, body,
+                                 jnp.zeros((rows, LANES), jnp.int32))
+        return jnp.sum(part, axis=1, keepdims=True)
+
+    # the threshold: the largest value that `want` keys reach
+    thr = jnp.where(count(lambda x, _: x >= 0) >= want, 0, _INT_MIN)
+
+    def bit(n, thr):
+        cand = thr | (jnp.int32(1) << (30 - n))
+        return jnp.where(count(lambda x, _: x >= cand) >= want, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 31, bit, thr)
+    above = count(lambda x, _: x > thr)
+    ties = count(lambda x, _: x == thr)
+    places = want - above       # of the ties, the lowest keys
+
+    def lowest(_):
+        """The largest P a row with count(tie and position < P) <=
+        places."""
+        nbits = s.bit_length()
+
+        def step(n, p):
+            cand = p + (jnp.int32(1) << (nbits - 1 - n))
+            fits = count(lambda x, pos: (x == thr) & (pos < cand)) <= places
+            return jnp.where(fits, cand, p)
+
+        return jax.lax.fori_loop(0, nbits, step,
+                                 jnp.zeros((rows, 1), jnp.int32))
+
+    below = jax.lax.cond(jnp.max(ties - places) > 0, lowest,
+                         lambda _: jnp.full((rows, 1), s, jnp.int32), None)
+
+    def kept(c):
+        k0 = pl.multiple_of(c * keys, keys)
+        x = key_ref[:, pl.ds(k0, keys)]
+        pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+        return k0, x, (x > thr) | ((x == thr) & (pos < below))
+
+    chunk_of = jax.lax.broadcasted_iota(jnp.int32, count_ref.shape[2:], 1)
+
+    def write(c, carry):
+        total, counts = carry
+        k0, x, sel = kept(c)
+        mask_ref[0, :, pl.ds(k0, keys)] = sel.astype(jnp.int8)
+        counts = jnp.where(chunk_of == c, jnp.sum(sel.astype(jnp.int32)),
+                           counts)
+        return total + jnp.sum(jnp.where(
+            sel, jnp.exp(_unsortable(x) - top), 0.0), axis=1,
+            keepdims=True), counts
+
+    total, counts = jax.lax.fori_loop(
+        0, chunks, write, (jnp.zeros((rows, 1), jnp.float32),
+                           jnp.zeros(count_ref.shape[2:], jnp.int32)))
+    count_ref[0, 0] = counts
+
+    def blank(c, _):
+        mask_ref[0, :, pl.ds(pl.multiple_of(c * keys, keys), keys)] = (
+            jnp.zeros((rows, keys), jnp.int8))
+
+    jax.lax.fori_loop(chunks, s // keys, blank, None)
+    lse_ref[0] = top + jnp.log(total)
+
+
+def _key_twice(k):
+    """The one index key [B, S, 64] laid twice side by side."""
+    return jnp.concatenate([k, k], axis=-1)
+
+
+def index_select(q, k, w, topk: int, precision=None):
+    """(mask [B, S, S] int8, lse [B, S, 1] float32, counts [B, S / rows,
+    S / keys] int32 of `index_blocks`' tiles): for query t the
+    min(t + 1, topk) keys s <= t of largest I_ts = sum_j w_tj relu(q_tj .
+    k_s), ties to the lower s (`lax.top_k`'s set, exactly), the
+    log-sum-exp of the kept scores and the pairs kept a tile. q [B, S,
+    heads * 64], k [B, S, 64] (the dtype they come in is the products'
+    operand dtype; ``precision`` for float32 operands: ``BF16_3X`` or
+    HIGHEST, `_index_scores`), w [B, S, heads] float32 with every scale
+    folded in. No score leaves VMEM."""
+    b, s, width = q.shape
+    heads = width // INDEX_LANES
+    assert k.shape[-1] == INDEX_LANES and heads % 2 == 0, (q.shape, k.shape)
+    rows, keys = index_blocks(s)
+    mask, lse, counts = pl.pallas_call(
+        functools.partial(_index_select_kernel, topk=topk, heads=heads,
+                          rows=rows, keys=keys, precision=precision),
+        name="index_select",
+        out_shape=(jax.ShapeDtypeStruct((b, s, s), jnp.int8),
+                   jax.ShapeDtypeStruct((b, s, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((b, s // rows, 1, s // keys),
+                                        jnp.int32)),
+        grid=(b, s // rows),
+        in_specs=[pl.BlockSpec((1, rows, width), lambda b, i: (b, i, 0)),
+                  pl.BlockSpec((1, s, LANES), lambda b, i: (b, 0, 0)),
+                  pl.BlockSpec((1, rows, heads), lambda b, i: (b, i, 0))],
+        out_specs=(pl.BlockSpec((1, rows, s), lambda b, i: (b, i, 0)),
+                   pl.BlockSpec((1, rows, 1), lambda b, i: (b, i, 0)),
+                   pl.BlockSpec((1, 1, 1, s // keys),
+                                lambda b, i: (b, i, 0, 0))),
+        scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32)],
+        interpret=pallas_mode() == "interpret",
+        compiler_params=_FLASH_COMPILER_PARAMS,
+    )(q, _key_twice(k), w.astype(jnp.float32))
+    return mask, lse, counts[:, :, 0, :]
+
+
+def index_kl_blocks(s: int):
+    """(query rows, keys) of a tile of `index_kl`."""
+    rows = next(r for r in (1024, 512, 256, 128) if s % r == 0)
+    return rows, 256 if s % 256 == 0 else 128
+
+
+def _index_kl_kernel(qi_ref, ki_ref, w_ref, lsei_ref, mask_ref, qm_ref,
+                     km_ref, lsem_ref, kl_ref, dq_ref, dw_ref, dk_ref, *,
+                     heads: int, main_heads: int, rep: int, rows: int,
+                     keys: int, scale: float, weight: float):
+    """One (batch row, block of ``rows`` queries, block of ``keys``
+    keys) grid cell, the keys innermost; a tile past the diagonal does
+    nothing. Recomputes the tile's index scores I and the main heads'
+    probabilities A (from q, k and the saved log-sum-exps), p = sum_g A
+    / heads over the kept pairs, and adds up: a row's KL(p || softmax of
+    I over its kept keys), and with dI = (softmax(I) - p) * ``weight`` on
+    the kept pairs the gradients of I's three operands: dq_j = (dI w_j
+    [q_j . k > 0]) k, dw_j = sum dI relu(q_j . k), dk = sum_j (...)^T
+    q_j. dq, dw and the KL are the query block's, resident across its
+    keys; dk leaves as a PART a (query block, key block), [128] lanes
+    the two heads' sums side by side, for XLA to add up."""
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        kl_ref[0] = jnp.zeros(kl_ref.shape[1:], jnp.float32)
+        dq_ref[0] = jnp.zeros(dq_ref.shape[1:], jnp.float32)
+        dw_ref[0] = jnp.zeros(dw_ref.shape[1:], jnp.float32)
+
+    @pl.when(j * keys < (i + 1) * rows)
+    def _tile():
+        q, kk, w = qi_ref[0], ki_ref[0], w_ref[0]
+        sel = mask_ref[0].astype(jnp.int32) != 0
+        val = _index_scores(q, kk, w, heads, None)
+        logq = val - lsei_ref[0]                 # log softmax over the kept
+        km = km_ref[0].astype(qm_ref.dtype)
+        lsem = lsem_ref[0]
+        head_sum = None
+        for g in range(main_heads):
+            own = slice((g // rep) * LANES, (g // rep + 1) * LANES)
+            st = _dot(qm_ref[0, :, g * LANES:(g + 1) * LANES], km[:, own],
+                      _NT)
+            a = jnp.exp(st * scale - lsem[:, g:g + 1])
+            head_sum = a if head_sum is None else head_sum + a
+        p = jnp.where(sel, head_sum * (1.0 / main_heads), 0.0)
+        kl_ref[0] += jnp.sum(
+            jnp.where(p > 0, p * (jnp.log(jnp.maximum(p, 1e-37)) - logq),
+                      0.0), axis=1, keepdims=True)
+        d_val = jnp.where(sel, (jnp.exp(logq) - p) * weight, 0.0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, heads), 1)
+        dw = jnp.zeros((rows, heads), jnp.float32)
+        dk = jnp.zeros((keys, LANES), jnp.float32)
+        for blk in range(heads // 2):
+            lanes = slice(blk * LANES, (blk + 1) * LANES)
+            dq = None
+            for h in range(2):
+                n = 2 * blk + h
+                qh = _only_head(q[:, lanes], h, INDEX_LANES)
+                st = _dot(qh, kk, _NT)
+                dw = dw + jnp.where(lane == n, jnp.sum(
+                    d_val * jnp.maximum(st, 0.0), axis=1, keepdims=True),
+                    0.0)
+                gate = (jnp.where(st > 0, d_val, 0.0) * w[:, n:n + 1]
+                        ).astype(q.dtype)
+                part = _only_head(_dot(gate, kk, _NN), h, INDEX_LANES)
+                dq = part if dq is None else dq + part
+                dk = dk + jax.lax.dot_general(
+                    gate, qh, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            dq_ref[0, :, lanes] += dq
+        dw_ref[0] += dw
+        dk_ref[0, 0] = dk
+
+
+def index_kl(qi, ki, w, lse_i, mask, qm, km, lse_m, num_heads: int,
+             weight: float):
+    """(kl [B, S, 1], dq [B, S, heads * 64], dw [B, S, heads], dk
+    [B, S, 64]), float32: the indexer's loss a query row and the
+    gradient of ``weight`` times its sum to the indexer's operands
+    (``_index_kl_kernel``). qi, ki, w as `index_select` took them (in
+    the dtype the products are to take), lse_i its second result; qm
+    [B, S, H * 128], km [B, S, Hk * 128] the main attention's rotated
+    queries and keys, lse_m [B, S, H] its log-sum-exps."""
+    b, s, width = qi.shape
+    heads = width // INDEX_LANES
+    rows, keys = index_kl_blocks(s)
+    nq, nk = s // rows, s // keys
+    scale = 1.0 / float(LANES) ** 0.5
+
+    def last(i):        # the last key block that holds a causal pair
+        return ((i + 1) * rows - 1) // keys
+
+    def by_rows(width):
+        return pl.BlockSpec((1, rows, width), lambda b, i, j: (b, i, 0))
+
+    def by_keys(width):
+        return pl.BlockSpec((1, keys, width),
+                            lambda b, i, j: (b, jnp.minimum(j, last(i)), 0))
+
+    kl, dq, dw, parts = pl.pallas_call(
+        functools.partial(_index_kl_kernel, heads=heads,
+                          main_heads=num_heads,
+                          rep=num_heads * LANES // km.shape[-1], rows=rows,
+                          keys=keys,
+                          scale=scale, weight=weight),
+        name="index_kl",
+        out_shape=(jax.ShapeDtypeStruct((b, s, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((b, s, width), jnp.float32),
+                   jax.ShapeDtypeStruct((b, s, heads), jnp.float32),
+                   jax.ShapeDtypeStruct((b, nq, s, LANES), jnp.float32)),
+        grid=(b, nq, nk),
+        in_specs=[by_rows(width), by_keys(LANES), by_rows(heads),
+                  by_rows(1),
+                  pl.BlockSpec((1, rows, keys), lambda b, i, j: (
+                      b, i, jnp.minimum(j, last(i)))),
+                  by_rows(num_heads * LANES), by_keys(km.shape[-1]),
+                  by_rows(num_heads)],
+        out_specs=(by_rows(1), by_rows(width), by_rows(heads),
+                   pl.BlockSpec((1, 1, keys, LANES), lambda b, i, j: (
+                       b, i, jnp.minimum(j, last(i)), 0))),
+        interpret=pallas_mode() == "interpret",
+        compiler_params=_FLASH_COMPILER_PARAMS,
+    )(qi, _key_twice(ki), w.astype(jnp.float32), lse_i, mask, qm, km, lse_m)
+    # a part past a query block's diagonal was never written
+    held = (jnp.arange(nk)[None, :] <= last(jnp.arange(nq))[:, None])
+    held = jnp.repeat(held, keys, axis=1)[None, :, :, None]
+    dk = jnp.sum(jnp.where(held, parts, 0.0), axis=1)
+    return kl, dq, dw, dk[..., :INDEX_LANES] + dk[..., INDEX_LANES:]
 
 
 # ---------------------------------------------------------------------------

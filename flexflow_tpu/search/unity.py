@@ -58,6 +58,10 @@ def _node_attrs(op) -> Dict[str, Any]:
         attrs["keys_seen"] = -(-op.visible_pairs // op.input_shapes[0][1])
     if hasattr(op, "interior_bytes"):
         attrs["interior_bytes"] = float(op.interior_bytes())
+    # learned sparse attention: the mask (a byte a pair) and the
+    # indexer's operands are what the forward keeps beside its output
+    if getattr(op, "sparse_index", None):
+        attrs["interior_bytes"] = float(op.sparse_saved_bytes())
     # conv/pool geometry (stored as (h, w) tuples on the op): needed so a
     # rewrite that re-emits the op (Conv+BN fold) replays into a real
     # Conv2D
@@ -182,8 +186,10 @@ def serialize_graph(nodes, final_guid: Optional[int] = None,
             attrs["pinned"] = 1
         if op.exports:
             attrs["exports"] = int(op.exports)
-        if getattr(op, "differential", False):
-            # its lambda leaves the step beside its output
+        if getattr(op, "differential", False) or getattr(
+                op, "sparse_index", None):
+            # its lambda (the indexer's loss and the counts of pairs)
+            # leaves the step beside its output
             attrs["side_counters"] = 1
         out.append(dict(
             guid=op.guid,

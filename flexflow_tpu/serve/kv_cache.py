@@ -106,6 +106,13 @@ def init_kv_cache(ff, batch: Optional[int] = None,
                 f"the producer writes and every reader reads; this cache "
                 f"holds one {{k, v}} pair a causal attention op. Serving "
                 f"them is not built")
+        if getattr(node.op, "sparse_index", None):
+            raise NotImplementedError(
+                f"'{node.op.name}' is learned sparse attention: a new "
+                f"token's query would score every cached position with "
+                f"the indexer (whose key this cache does not hold) and "
+                f"attend over the kept ones; the selection over a cache "
+                f"is not built")
         if node.op.op_type == OperatorType.SHORT_CONV:
             raise NotImplementedError(
                 f"'{node.op.name}' is a short convolution: a new token "

@@ -333,6 +333,16 @@ def shared_leaves_rows(ff):
             for owner, names in readers.items()]
 
 
+def sparse_attention_rows(ff):
+    """[(op, index heads, their width, keys a query keeps, bytes the op
+    saves beside its output: the mask and the indexer's operands)] of
+    the learned-sparse-attention ops."""
+    return [(node.op.name, *node.op.sparse_index,
+             node.op.sparse_saved_bytes())
+            for node in ff.executor.nodes
+            if getattr(node.op, "sparse_index", None)]
+
+
 def to_markdown(model, ff, trace, sim_resp, rows, total_ops, feasible,
                 reasons, path_rows, path_total, merged_path,
                 disagreements=None, n_compared=0, kernel_rows=None,
@@ -371,6 +381,20 @@ def to_markdown(model, ff, trace, sim_resp, rows, total_ops, feasible,
             "| owner | leaves | read by |", "|---|---|---|",
             *(f"| {owner} | {', '.join(leaves)} | {', '.join(names)} |"
               for owner, leaves, names in shared), ""]
+    sparse = sparse_attention_rows(ff)
+    if sparse:
+        at = lines.index("## Mesh candidates")
+        lines[at:at] = [
+            "## Learned sparse attention", "",
+            f"{len(sparse)} attention ops keep a query's best keys by an "
+            f"indexer (`sparse_index`); the mask, a byte a (query, key) "
+            f"pair, and the indexer's operands are priced as saved "
+            f"activations, and no remat twin is taken (the indexer's loss "
+            f"leaves on a side channel):", "",
+            "| op | index heads | head size | top-k | saved |",
+            "|---|---|---|---|---|",
+            *(f"| {name} | {heads} | {size} | {topk} | {_fmt_bytes(saved)} |"
+              for name, heads, size, topk, saved in sparse), ""]
     for m in feasible[:12]:
         pl = m.get("pipeline_candidates")
         note = m.get("reason", "")

@@ -45,7 +45,7 @@ if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu") \
 
 ZOO = ["mlp", "alexnet", "resnet", "resnext", "inception", "dlrm", "xdl",
        "candle_uno", "moe", "moe_encoder", "transformer", "llama", "lfm2",
-       "ouro", "phi4flash"]
+       "ouro", "phi4flash", "keye"]
 
 
 def build_model(name: str, ff_config):
@@ -147,6 +147,21 @@ def build_model(name: str, ff_config):
             mb_per_layer=2, num_hidden_layers=8, sliding_window=8,
             num_attention_heads=4, num_key_value_heads=2, head_dim=16,
             tie_word_embeddings=True, batch_size=8, seq_length=16),
+            ff_config), "cat"
+    if name == "keye":
+        # learned sparse attention: an indexer keeps 6 keys a query, the
+        # main attention runs over them, the indexer's loss leaves on
+        # the executor's side channel; then experts. (The op refuses
+        # `sparse_index` with a window, a block-diffusion mask,
+        # differential pairs or given keys/values with a sentence of its
+        # own, so no such model reaches a lint pass.)
+        from flexflow_tpu.models import DecoderConfig, create_decoder
+        return create_decoder(DecoderConfig(
+            hybrid_override_pattern="KK", num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, experts_held=4,
+            sa_config=dict(indexer_num_heads=4, indexer_head_dim=8,
+                           indexer_num_kv_heads=1, topk=6),
+            mrope_section=(2, 3, 3), batch_size=8, seq_length=16),
             ff_config), "cat"
     raise SystemExit(f"unknown --model {name!r} (zoo: {', '.join(ZOO)})")
 

@@ -13,8 +13,9 @@ seed are made once, every control's program gives its predictions and
 three losses and is released, and the reference runs once at the end with
 the device to itself. A first row, `as_stated`, is the program as the
 cell states it and has to come out correct; every control has to come
-out NOT correct. One JSON line a row; the last line sums up. Not part of
-a benchmark run.
+out NOT correct, by the comparison or by a row of the family's
+`extra_checks` (named in `failed_by` alike). One JSON line a row; the
+last line sums up. Not part of a benchmark run.
 """
 
 import argparse
@@ -62,19 +63,23 @@ def main():
         s = family.sizes(config, traffic, dict(tiny or {}, **override))
         ff = family.build(config, s, cell["chips"], args.seed)
         family.install_weights(ff, weights)
+        faults = [c for c, ok, _ in family.extra_checks(
+            ff, s, cell["chips"], tiny is None) if not ok]
         system, _ = hs.system_side(ff, xs, y, batch)
         hs.release(ff)
-        systems.append((name, system))
-        hs.emit(phase="system", control=name, losses=system["losses"])
+        systems.append((name, system, faults))
+        hs.emit(phase="system", control=name, losses=system["losses"],
+                extra_checks_failed=faults)
     want = hs.reference_side(family, weights, stated, traffic, config, xs, y,
                              batch)
     rows = []
-    for name, system in systems:
+    for name, system, faults in systems:
         judged = hs.compare(system, want, family.TOLERANCES)
         row = dict(control=name, seed=args.seed,
-                   correct=all(r["ok"] for r in judged),
+                   correct=all(r["ok"] for r in judged) and not faults,
                    **{r["name"]: r["value"] for r in judged},
-                   failed_by=[r["name"] for r in judged if not r["ok"]])
+                   failed_by=[r["name"] for r in judged
+                              if not r["ok"]] + faults)
         hs.emit(**row)
         rows.append(row)
     print(json.dumps(dict(

@@ -647,6 +647,67 @@ class TestHybridDecoderKernels:
             # two maps: two forward and two backward kernels
             assert pallas_kernel_count(hlo) == 4, name
 
+    def test_learned_sparse_attention_at_the_keye_cells_widths(
+            self, topo, on_tpu):
+        """PR 54's op at the cell's widths (16,384 positions, hidden 2048,
+        8 : 1 heads of 128, an indexer of 16 heads of 64 that keeps 2,048
+        keys a query, bfloat16), forward with its loss and backward: the
+        selection, the loss and the main attention run their kernels
+        (`index_select`, `index_kl`, the chunk-loop flash kernels with
+        the mask operand); the compiled program holds no [S, S] float32
+        array and no [H, S, S] array of any dtype, and the mask's buffer
+        is the size the configuration's file says."""
+        import json
+
+        from flexflow_tpu import FFConfig, FFModel
+        from flexflow_tpu.ops.base import OpContext, OpRegistry
+        seq, hidden = 16384, 2048
+        one = SingleDeviceSharding(topo.devices[0])
+        ff = FFModel(FFConfig(batch_size=1))
+        x = ff.create_tensor((1, seq, hidden))
+        ff.multihead_attention(
+            x, x, x, hidden, 8, bias=False, causal=True, num_kv_heads=1,
+            head_dim=128, rope=True, rope_theta=1e7, qk_norm=True,
+            sparse_index=(16, 64, 2048), mrope_section=(16, 24, 24),
+            name="sparse")
+        layer = ff._layer_named["sparse"]
+        op = OpRegistry.create(layer, [t.shape for t in layer.inputs])
+        params = {
+            leaf: jax.ShapeDtypeStruct(
+                a.shape, jnp.float32 if leaf in op.full_precision_params
+                else jnp.bfloat16, sharding=one)
+            for leaf, a in jax.eval_shape(
+                op.init_params, jax.random.PRNGKey(0)).items()}
+        inputs = tuple(jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                            sharding=one)
+                       for shape in op.input_shapes)
+
+        def loss(params, inputs):
+            ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
+            (y,) = op.forward(params, list(inputs), ctx)
+            aux, op._aux_loss, op._counters = op._aux_loss, None, None
+            return y.astype(jnp.float32).sum() + aux
+
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, inputs).compile()
+        hlo = compiled.as_text()
+        route = op._route
+        assert (route.core, route.grouped_kv, route.sparse_kernels,
+                route.scope) == ("flash", True, True, "sparse")
+        assert not re.search(r"f32\[(?:\d+,)*16384,16384\]", hlo)
+        # [H, S, S], and a batch of more than one such square
+        assert not re.search(r"\[(?:\d+,)*(?:[2-9]|\d\d+),16384,16384\]", hlo)
+        squares = set(re.findall(r"(\w+)\[1,16384,16384\]", hlo))
+        assert squares == {"s8"}, squares       # the mask and its transpose
+        with open(os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "benchmarks", "configs",
+                "keye_vl2_30b_a3b.json")) as f:
+            assert json.load(f)["mask_bytes_a_layer"] == seq * seq
+        # index_select, flash forward, index_kl, flash backward, and the
+        # lane-dense rotary's two passes each way
+        assert pallas_kernel_count(hlo) >= 4
+        assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
 
 class TestFusedAdam:
     KW = dict(beta1=0.9, beta2=0.999, eps=1e-8, wd=1e-4)
