@@ -1063,8 +1063,9 @@ class MultiHeadAttention(Op):
         them: (q_I, k_I, w, mask [B, S, S] int8, the log-sum-exp of a
         row's kept scores [B, S, 1], the pairs kept a tile or None). With
         ``kernels`` `pallas_kernels.index_select` (float32 operands as
-        three bfloat16 passes), else `ops/sparse_index.py`'s whole
-        arrays; `lax.top_k`'s set either way."""
+        three bfloat16 products in two MXU passes), else
+        `ops/sparse_index.py`'s whole arrays; `lax.top_k`'s set either
+        way."""
         from flexflow_tpu.ops import pallas_kernels as pk
         from flexflow_tpu.ops import sparse_index as si
 

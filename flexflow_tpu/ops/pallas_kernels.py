@@ -55,7 +55,10 @@ projection and a kernel, and no array the kernels touch pads a 64-wide
 minor dimension to 128 lanes in HBM. Inside, a block's K^T, V^T, O^T,
 dK^T ... are [128, S] tiles whose heads are sublane ranges; where one
 head's lanes are wanted out of a [rows, 128] operand the others are
-zeroed (``_only_head``) and the MXU contracts all 128. Under
+zeroed (``_only_head``) and the MXU contracts all 128 (in one kernel
+the zeroed lanes carry something: `index_select`'s float32 index
+products lay a head's bfloat16 LOW part there, so three products are
+two passes; PR 55, ``_index_tiles``). Under
 grouped-query attention with heads of 128 (PR 43) k and v are
 [B, S, Hk*D], the KV heads alone: a K / V BlockSpec's last index is the
 query column block's group, ``j // rep``, and the backward adds a
@@ -2231,7 +2234,7 @@ def _unsortable(key):
 _INT_MIN = -2 ** 31
 
 
-BF16_3X = "bf16_3x"     # float32 operands as three bfloat16 MXU passes
+BF16_3X = "bf16_3x"     # float32 operands as three bfloat16 products
 
 
 def _split(x):
@@ -2241,20 +2244,49 @@ def _split(x):
     return high, (x - high.astype(jnp.float32)).astype(jnp.bfloat16)
 
 
-def _index_scores(q, kk, w, heads: int, precision):
-    """I [rows, keys] float32 = sum_j w[:, j] relu(q_j . k): q [rows,
-    heads * 64], two heads a 128-lane block, against the ONE key laid
-    twice side by side kk [keys, 128], the other head's lanes zeroed.
-    ``precision``: None (the operands as they come, one MXU pass),
-    `Precision.HIGHEST` (float32 operands, the compiler's six passes:
-    no program takes it; `scripts/sparse_lab.py` and the tests compare
-    against it) or
-    ``BF16_3X`` (float32 operands split here into bfloat16 high and low
-    parts, high . high + high . low + low . high: XLA's `Precision.HIGH`,
-    which Mosaic's dot does not take; 2^-16 of a product's size)."""
+def _index_tiles(q, heads: int, precision):
+    """The left-hand operands of `_index_products`, a tuple of [rows,
+    128] tiles a head, from q [rows, heads * 64], two heads a 128-lane
+    block. They depend on the query block alone, so `index_select` forms
+    them once a grid step and not once a chunk of keys. One tile, the
+    head's lanes with the other head's zeroed (``_only_head``), but under
+    ``BF16_3X`` two (PR 55): float32 q split into bfloat16 high and low
+    parts, the head's high part in its own 64 lanes with its LOW part in
+    the other head's ([h0 | l0], [l1 | h1]: one rotation of the block's
+    low parts by half a block, then each half kept by ``_only_head``),
+    then the high part alone."""
+    tiles = []
+    for blk in range(heads // 2):
+        x = q[:, blk * LANES:(blk + 1) * LANES]
+        if precision != BF16_3X:
+            tiles += [(_only_head(x, h, INDEX_LANES),) for h in range(2)]
+            continue
+        high = x.astype(jnp.bfloat16).astype(jnp.float32)
+        swapped = _lane_roll(x - high, INDEX_LANES)         # [l1 | l0]
+        for h in range(2):
+            alone = _only_head(high, h, INDEX_LANES)
+            both = alone + _only_head(swapped, 1 - h, INDEX_LANES)
+            tiles.append((both.astype(jnp.bfloat16),
+                          alone.astype(jnp.bfloat16)))
+    return tiles
+
+
+def _index_products(tiles, kk, w, precision):
+    """I [rows, keys] float32 = sum_j w[:, j] relu(q_j . k) from
+    `_index_tiles`' operands, against the ONE key laid twice side by
+    side kk [keys, 128]. ``precision``: None (the operands as they come,
+    one MXU pass), `Precision.HIGHEST` (float32 operands, the compiler's
+    six passes: no program takes it; `scripts/sparse_lab.py` and the
+    tests compare against it) or ``BF16_3X`` (float32 operands as
+    bfloat16 high and low parts, high . high + low . high + high . low:
+    XLA's `Precision.HIGH`, which Mosaic's dot does not take; 2^-16 of a
+    product's size). A head of 64 leaves half of a 128-deep contraction
+    zeroed, so the three products are TWO passes: [high | low] against
+    the key's high part twice adds the first two up in the MXU's float32
+    accumulator, and the high part alone meets the key's low part."""
     split = precision == BF16_3X
     if split:
-        (q, q_low), (kk, kk_low) = _split(q), _split(kk)
+        kk, kk_low = _split(kk)
         precision = None
 
     def dot(a, b):
@@ -2262,23 +2294,28 @@ def _index_scores(q, kk, w, heads: int, precision):
                                    preferred_element_type=jnp.float32)
 
     acc = None
-    for j in range(heads):
-        lanes = slice((j // 2) * LANES, (j // 2 + 1) * LANES)
-        head = _only_head(q[:, lanes], j % 2, INDEX_LANES)
-        s = dot(head, kk)
+    for j, tile in enumerate(tiles):
+        s = dot(tile[0], kk)
         if split:
-            s = s + dot(head, kk_low) + dot(
-                _only_head(q_low[:, lanes], j % 2, INDEX_LANES), kk)
+            s = s + dot(tile[1], kk_low)
         term = w[:, j:j + 1] * jnp.maximum(s, 0.0)
         acc = term if acc is None else acc + term
     return acc
 
 
+def _index_scores(q, kk, w, heads: int, precision):
+    """`_index_products` of `_index_tiles`: a tile's index scores where
+    the left-hand operands are used once (`index_kl`)."""
+    return _index_products(_index_tiles(q, heads, precision), kk, w,
+                           precision)
+
+
 def _index_select_kernel(q_ref, k_ref, w_ref, mask_ref, lse_ref, count_ref,
-                         key_ref, *, topk: int, heads: int, rows: int,
-                         keys: int, precision):
+                         key_ref, lhs_ref, *, topk: int, heads: int,
+                         rows: int, keys: int, precision):
     """One (batch row, block of ``rows`` queries) grid cell: the block's
-    index scores against every chunk of ``keys`` keys up to its own
+    index scores (their left-hand tiles laid once in ``lhs_ref``,
+    `_index_tiles`) against every chunk of ``keys`` keys up to its own
     positions, kept in VMEM as sortable integers (``key_ref`` [rows, S];
     a pair the causal rule hides holds the smallest); then a row's
     min(t + 1, topk)-th largest by bisection over the 32 bits (a pass
@@ -2293,14 +2330,19 @@ def _index_select_kernel(q_ref, k_ref, w_ref, mask_ref, lse_ref, count_ref,
     q0 = i * rows
     s = k_ref.shape[1]
     chunks = (q0 + rows + keys - 1) // keys      # that hold a causal key
-    q, w = q_ref[0], w_ref[0]
+    w = w_ref[0]
     t = q0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
     want = jnp.minimum(t + 1, topk)
+    for j, head in enumerate(_index_tiles(q_ref[0], heads, precision)):
+        for n, tile in enumerate(head):
+            lhs_ref[j, n] = tile
 
     def score(c, top):
         k0 = pl.multiple_of(c * keys, keys)
-        val = _index_scores(q, k_ref[0, pl.ds(k0, keys), :], w, heads,
-                            precision)
+        val = _index_products(
+            [[lhs_ref[j, n] for n in range(lhs_ref.shape[1])]
+             for j in range(heads)], k_ref[0, pl.ds(k0, keys), :], w,
+            precision)
         pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
         key_ref[:, pl.ds(k0, keys)] = jnp.where(pos <= t, _sortable(val),
                                                 _INT_MIN)
@@ -2400,13 +2442,17 @@ def index_select(q, k, w, topk: int, precision=None):
     k_s), ties to the lower s (`lax.top_k`'s set, exactly), the
     log-sum-exp of the kept scores and the pairs kept a tile. q [B, S,
     heads * 64], k [B, S, 64] (the dtype they come in is the products'
-    operand dtype; ``precision`` for float32 operands: ``BF16_3X`` or
-    HIGHEST, `_index_scores`), w [B, S, heads] float32 with every scale
-    folded in. No score leaves VMEM."""
+    operand dtype; ``precision`` for float32 operands: ``BF16_3X``,
+    three bfloat16 products in two MXU passes, or HIGHEST,
+    `_index_products`), w [B, S, heads] float32 with every scale folded
+    in. No score leaves VMEM; beside the [rows, S] integers a grid step
+    holds the products' left-hand tiles (`_index_tiles`: 2 MiB at 16
+    heads, 1 MiB in bfloat16 operands)."""
     b, s, width = q.shape
     heads = width // INDEX_LANES
     assert k.shape[-1] == INDEX_LANES and heads % 2 == 0, (q.shape, k.shape)
     rows, keys = index_blocks(s)
+    split = precision == BF16_3X        # `_index_tiles`: two a head
     mask, lse, counts = pl.pallas_call(
         functools.partial(_index_select_kernel, topk=topk, heads=heads,
                           rows=rows, keys=keys, precision=precision),
@@ -2423,7 +2469,9 @@ def index_select(q, k, w, topk: int, precision=None):
                    pl.BlockSpec((1, rows, 1), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, 1, 1, s // keys),
                                 lambda b, i: (b, i, 0, 0))),
-        scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32),
+                        pltpu.VMEM((heads, 1 + split, rows, LANES),
+                                   jnp.bfloat16 if split else q.dtype)],
         interpret=pallas_mode() == "interpret",
         compiler_params=_FLASH_COMPILER_PARAMS,
     )(q, _key_twice(k), w.astype(jnp.float32))

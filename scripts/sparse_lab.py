@@ -11,7 +11,9 @@ single kernels of milliseconds; a dispatch is 0.1 ms):
   over the bits, in VMEM: `kernel.f32` (float32 index products at the
   compiler's `highest`, six MXU passes), `kernel.bf16_3x` (what ships:
   float32 operands split into bfloat16 high and low parts, three
-  passes), `kernel.bf16` (bfloat16 operands, one pass),
+  products in two passes since PR 55: a head's low part rides in the
+  lanes a head of 64 leaves zeroed), `kernel.bf16` (bfloat16 operands,
+  one pass),
   `top_k` (XLA: the scores of 2,048 query rows formed whole, then
   `lax.top_k`; eight such blocks make a layer, so the line is times 8),
   `approx_max_k` (the same with `lax.approx_max_k`, which keeps ANOTHER

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from flexflow_tpu.ops import pallas_kernels as pk
 from flexflow_tpu.ops import sparse_index as si
@@ -54,7 +55,7 @@ def test_index_select_keeps_top_ks_set(operands, case, precision):
     scores = si.index_scores(qi, ki, w)
     want = si.select(scores, TOPK)
     if case == "seeded" and precision == pk.BF16_3X:
-        # three bfloat16 passes: 2^-16 of a product; a key at a row's
+        # three bfloat16 products: 2^-16 of a product; a key at a row's
         # threshold may change sides
         assert (np.asarray(mask) != np.asarray(want)).sum() <= 4
     else:
@@ -68,6 +69,82 @@ def test_index_select_keeps_top_ks_set(operands, case, precision):
     np.testing.assert_allclose(lse, si.kept_lse(scores, want), atol=1e-4)
     if case == "ties":
         assert np.asarray(mask)[0, -1, :TOPK].all()
+
+
+def _scores_in_a_kernel(q, kk, w, precision):
+    """`_index_scores` of one tile, run as a kernel in interpret mode."""
+    def kernel(q_ref, kk_ref, w_ref, out_ref):
+        out_ref[...] = pk._index_scores(q_ref[...], kk_ref[...], w_ref[...],
+                                        w.shape[1], precision)
+
+    return pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(
+        (q.shape[0], kk.shape[0]), jnp.float32), interpret=True)(q, kk, w)
+
+
+def test_index_scores_two_passes_hold_the_three_products():
+    """``BF16_3X`` (PR 55): a head's low part rides in the other head's
+    lanes of the high . high pass. At a tile of two lane blocks (four
+    heads, both parities), scores of size 35 and more: the three
+    bfloat16 products formed apart and summed in float32 to float32's
+    own rounding, `highest` to 2^-15."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (128, 4 * 64))
+    k = jax.random.normal(ks[1], (256, 64))
+    w = jax.random.uniform(ks[2], (128, 4), minval=-1.5, maxval=1.5)
+    kk = jnp.concatenate([k, k], axis=-1)
+    got = _scores_in_a_kernel(q, kk, w, pk.BF16_3X)
+
+    def dot(a, b):
+        return jnp.einsum("thd,sd->hts", a.reshape(128, 4, 64), b,
+                          preferred_element_type=jnp.float32)
+
+    (qh, ql), (kh, kl) = pk._split(q), pk._split(k)
+    apart = dot(qh, kh) + dot(qh, kl) + dot(ql, kh)
+    want = jnp.einsum("hts,th->ts", jnp.maximum(apart, 0.0), w,
+                      precision=HIGHEST)
+    top = float(jnp.max(jnp.abs(want)))
+    assert top > 35
+    assert float(jnp.max(jnp.abs(got - want))) <= 1e-6 * top
+    exact = _scores_in_a_kernel(q, kk, w, HIGHEST)
+    assert float(jnp.max(jnp.abs(got - exact))) <= 2.0 ** -15 * top
+    assert float(jnp.max(jnp.abs(got - exact))) > 0     # not `highest`
+
+
+def _inside(eqn, name: str) -> int:
+    """The equations of primitive ``name`` in the jaxprs ``eqn`` holds
+    (a kernel's body, a loop's), at any depth."""
+    total = 0
+    for value in eqn.params.values():
+        for sub in value if isinstance(value, (list, tuple)) else [value]:
+            sub = getattr(sub, "jaxpr", sub)
+            for inner in getattr(sub, "eqns", ()):
+                total += (inner.primitive.name == name) + _inside(inner, name)
+    return total
+
+
+@pytest.mark.parametrize("precision,dtype,passes", [
+    (pk.BF16_3X, jnp.float32, 2), (HIGHEST, jnp.float32, 1),
+    (None, jnp.bfloat16, 1)], ids=["bf16_3x", "highest", "bf16"])
+def test_index_select_issues_two_products_a_head_for_float32(
+        operands, precision, dtype, passes):
+    """The kernel's one chunk loop with products holds 2 x heads MXU
+    products under ``BF16_3X`` (three would be the form before PR 55)
+    and heads in one pass; the left-hand tiles' lane work (a roll a lane
+    block, the selects that keep a head's lanes) is outside every loop."""
+    qi, ki, w, *_ = operands
+    jaxpr = jax.make_jaxpr(lambda q, k, w: pk.index_select(
+        q, k, w, TOPK, precision))(qi.astype(dtype), ki.astype(dtype), w)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert _inside(call, "dot_general") == passes * HI
+    (scores,) = [e for e in call.params["jaxpr"].eqns
+                 if e.primitive.name in ("while", "scan")
+                 and _inside(e, "dot_general")]
+    assert _inside(scores, "dot_general") == passes * HI
+    assert _inside(scores, "roll") == 0
+    assert _inside(call, "roll") == (HI // 2 if passes == 2 else 0)
+    # the score loop selects for the causal rule alone (the sortable
+    # form, the stored key, the running top), never a head's lanes
+    assert _inside(scores, "select_n") <= 4
 
 
 def test_index_select_in_bfloat16_operands_agrees_with_its_own_form(
