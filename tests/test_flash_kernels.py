@@ -15,6 +15,7 @@ times.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -22,19 +23,30 @@ import numpy as np
 import pytest
 
 from flexflow_tpu.ops import pallas_kernels as pk
+from one_program import output_and_gradients
 
 # bf16 keeps 8 significant bits: rounding to nearest moves a value by at
 # most 2^-9 of itself.
 U = 2.0 ** -9
 
 
+# What a case costs here is the programs it compiles, not its operands'
+# size (ROADMAP D10): operands and expectations are numpy arrays (numpy's
+# cast to ml_dtypes' bfloat16 rounds as XLA's does), and what has to be
+# jax runs under one `jax.jit` a call, so that a case compiles the
+# kernels it is about and little else.
 def _qkv(s, d, dtype, seed=0, b=1, h=2):
-    """q, k, v, dO as [B, S, H*D]."""
+    """q, k, v, dO as [B, S, H*D], numpy arrays."""
     rs = np.random.RandomState(seed)
-    return tuple(jnp.asarray(rs.randn(b, s, h * d).astype(np.float32)
-                             ).astype(dtype) for _ in range(4))
+    return tuple(rs.randn(b, s, h * d).astype(np.float32).astype(dtype)
+                 for _ in range(4))
 
 
+def _f32(*xs):
+    return [np.asarray(x, np.float32) for x in xs]
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
 def _reference(q, k, v, h, causal):
     """The float32 einsum attention, a head at a time, on [B, S, H*D]:
     (o [B, S, H*D], lse [B, H, S])."""
@@ -45,11 +57,11 @@ def _reference(q, k, v, h, causal):
             lse.reshape(b, h, s))
 
 
-def _grads(fn, q, k, v, do):
-    def loss(q, k, v):
-        return jnp.sum(fn(q, k, v).astype(jnp.float32)
-                       * do.astype(jnp.float32))
-    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+def _grads(fn, q, k, v, do, output=False):
+    """dQ, dK, dV of sum(fn(q, k, v) * dO), one program; with ``output``
+    (fn's output, the gradients) of that same program."""
+    o, g = output_and_gradients(fn, do.astype(jnp.float32), q, k, v)
+    return (o, g) if output else g
 
 
 def _rel_rms(got, want):
@@ -64,7 +76,7 @@ def _assert_grads_close(g, gr, what=""):
         assert _rel_rms(a, b) < 4 * U, (what, name, _rel_rms(a, b) / U)
         worst = float(np.max(np.abs(np.asarray(a, np.float32)
                                     - np.asarray(b))))
-        assert worst < 8 * U * float(jnp.max(jnp.abs(b))), (what, name)
+        assert worst < 8 * U * float(np.max(np.abs(b))), (what, name)
 
 
 # S <= MAX_BWD_SEQ runs flash_fwd_whole + flash_bwd, S > MAX_BWD_SEQ runs
@@ -104,12 +116,13 @@ def test_bf16_operands_match_float32_attention(seq, head_dim, causal):
     h = 4
     q, k, v, do = _qkv(seq, head_dim, jnp.bfloat16, seed=seq + head_dim,
                        b=2, h=h)
-    f32 = [x.astype(jnp.float32) for x in (q, k, v, do)]
+    f32 = _f32(q, k, v, do)
 
-    got, lse = pk._flash_fwd(q, k, v, h, causal, True)
+    got, lse = jax.jit(lambda q, k, v: pk._flash_fwd(
+        q, k, v, h, causal, True))(q, k, v)
     want, want_lse = _reference(*f32[:3], h, causal)
     assert got.dtype == jnp.bfloat16 and got.shape == q.shape
-    vmax = float(jnp.max(jnp.abs(f32[2])))
+    vmax = float(np.max(np.abs(f32[2])))
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want), rtol=0,
                                atol=2 * U * vmax)
@@ -154,12 +167,11 @@ def test_heads_tile_the_lanes_or_the_gate_refuses(heads, head_dim,
         return
     assert pk._heads_per_block(heads, head_dim) == per_block
     q, k, v, do = _qkv(128, head_dim, jnp.float32, seed=heads, b=2, h=heads)
-    got = pk.flash_attention(q, k, v, heads, causal=True)
+    got, g = _grads(lambda q, k, v: pk.flash_attention(
+        q, k, v, heads, causal=True), q, k, v, do, output=True)
     want, _ = _reference(q, k, v, heads, True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-5)
-    g = _grads(lambda q, k, v: pk.flash_attention(q, k, v, heads,
-                                                  causal=True), q, k, v, do)
     gr = _grads(lambda q, k, v: _reference(q, k, v, heads, True)[0],
                 q, k, v, do)
     for a, b in zip(g, gr):
@@ -178,7 +190,7 @@ def test_grouped_query_heads_repeated_into_the_lanes(seq):
     k, v, _, _ = _qkv(seq, d, jnp.bfloat16, seed=seq + 1, h=hk)
     rep = lambda x: jnp.repeat(x.reshape(1, seq, hk, d), h // hk, axis=2
                                ).reshape(1, seq, h * d)
-    f32 = [x.astype(jnp.float32) for x in (q, k, v, do)]
+    f32 = _f32(q, k, v, do)
     g = _grads(lambda q, k, v: pk._flash(q, rep(k), rep(v), h, True, True),
                q, k, v, do)
     gr = _grads(lambda q, k, v: _reference(q, rep(k), rep(v), h, True)[0],
@@ -216,7 +228,7 @@ def test_blocks_that_do_not_divide_by_the_widest_block():
     for b, h, seq, causal in ((1, 1, pk.MAX_BWD_SEQ + 128, True),
                               (3, 2, 640, False)):
         q, k, v, do = _qkv(seq, 64, jnp.bfloat16, seed=seq, b=b, h=h)
-        f32 = [x.astype(jnp.float32) for x in (q, k, v, do)]
+        f32 = _f32(q, k, v, do)
         g = _grads(lambda q, k, v: pk._flash(q, k, v, h, causal, True),
                    q, k, v, do)
         gr = _grads(lambda q, k, v: _reference(q, k, v, h, causal)[0],
@@ -228,15 +240,19 @@ def test_tolerance_refuses_a_float8_operand():
     """The limits above are not so wide that a lower precision passes:
     `P` rounded to float8_e4m3 (2^-4 relative) fails the output's."""
     q, k, v, _ = _qkv(256, 64, jnp.bfloat16, seed=3, b=2, h=1)
-    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
-    s = jnp.einsum("bqd,bkd->bqk", qf, kf) / 8.0
-    p = jax.nn.softmax(s, axis=-1)
-    p8 = p.astype(jnp.float8_e4m3fn).astype(jnp.float32)
-    coarse = jnp.einsum("bqk,bkd->bqd", p8, vf) / jnp.sum(
-        p, axis=-1, keepdims=True)
-    want = pk._xla_attention(qf, kf, vf, False)
-    vmax = float(jnp.max(jnp.abs(vf)))
-    assert float(jnp.max(jnp.abs(coarse - want))) > 2 * U * vmax
+    qf, kf, vf = _f32(q, k, v)
+
+    @jax.jit
+    def off(qf, kf, vf):
+        s = jnp.einsum("bqd,bkd->bqk", qf, kf) / 8.0
+        p = jax.nn.softmax(s, axis=-1)
+        p8 = p.astype(jnp.float8_e4m3fn).astype(jnp.float32)
+        coarse = jnp.einsum("bqk,bkd->bqd", p8, vf) / jnp.sum(
+            p, axis=-1, keepdims=True)
+        want = pk._xla_attention(qf, kf, vf, False)
+        return jnp.max(jnp.abs(coarse - want))
+
+    assert float(off(qf, kf, vf)) > 2 * U * float(np.max(np.abs(vf)))
 
 
 def _kernel_dots(fn, *args):
@@ -277,7 +293,7 @@ def _flash_grads(q, k, v, do):
 def _flash_lse_grads(q, k, v, do):
     def loss(q, k, v):
         o, lse = pk.flash_attention_lse(q, k, v, 4, True, True)
-        return jnp.sum(o * do.astype(jnp.float32)) + jnp.sum(lse)
+        return jnp.sum(o * jnp.asarray(do, jnp.float32)) + jnp.sum(lse)
     return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
@@ -437,11 +453,14 @@ def test_unmasked_interior_tiles_give_the_bits_of_masking_every_tile(
     q, k, v, do = _qkv(seq, head_dim, jnp.bfloat16, seed=seq + window, h=h)
 
     def run():
-        o, lse = pk._flash_fwd(q, k, v, h, causal, True, window=window,
-                               block_diffusion=bd)
-        return (o, lse) + tuple(pk._flash_bwd(
-            q, k, v, o, lse, do, h, causal, True, window=window,
-            block_diffusion=bd))
+        # a new function a call: each is traced under the split in force
+        def both(q, k, v, do):
+            o, lse = pk._flash_fwd(q, k, v, h, causal, True, window=window,
+                                   block_diffusion=bd)
+            return (o, lse) + tuple(pk._flash_bwd(
+                q, k, v, o, lse, do, h, causal, True, window=window,
+                block_diffusion=bd))
+        return jax.jit(both)(q, k, v, do)
 
     if kind != "not-causal":      # the case is not vacuous
         assert 0 < pk.kv_blocks_masked(seq, causal, window, bd) < (
@@ -478,8 +497,9 @@ def _repeated(x, hk, rep):
     `ops/attention.py` `_qkv` lays it out for the cores that want whole
     heads."""
     b, s, w = x.shape
-    return jnp.repeat(x.reshape(b, s, hk, w // hk), rep, axis=2
-                      ).reshape(b, s, rep * w)
+    xp = np if isinstance(x, np.ndarray) else jnp    # operands are numpy
+    return xp.repeat(x.reshape(b, s, hk, w // hk), rep, axis=2
+                     ).reshape(b, s, rep * w)
 
 
 def _assert_grouped_is_the_repeated_form(q, k, v, do, h, hk, causal, kw):
@@ -488,17 +508,21 @@ def _assert_grouped_is_the_repeated_form(q, k, v, do, h, hk, causal, kw):
     sums within a bf16 rounding of each head's partial; the public call
     the same values."""
     rep, seq, d = h // hk, q.shape[1], k.shape[-1] // hk
-    o, lse = pk._flash_fwd(q, k, v, h, causal, True, num_kv_heads=hk, **kw)
+    # one program a kernel call, as the calls were when they ran eagerly
+    o, lse = jax.jit(lambda q, k, v: pk._flash_fwd(
+        q, k, v, h, causal, True, num_kv_heads=hk, **kw))(q, k, v)
     kr, vr = _repeated(k, hk, rep), _repeated(v, hk, rep)
-    want_o, want_lse = pk._flash_fwd(q, kr, vr, h, causal, True, **kw)
+    want_o, want_lse = jax.jit(lambda q, k, v: pk._flash_fwd(
+        q, k, v, h, causal, True, **kw))(q, kr, vr)
     assert np.array_equal(np.asarray(o, np.float32),
                           np.asarray(want_o, np.float32))
     assert np.array_equal(np.asarray(lse), np.asarray(want_lse))
 
-    dq, dk, dv = pk._flash_bwd(q, k, v, o, lse, do, h, causal, True,
-                               num_kv_heads=hk, **kw)
-    want_dq, dkr, dvr = pk._flash_bwd(q, kr, vr, o, lse, do, h, causal,
-                                      True, **kw)
+    dq, dk, dv = jax.jit(lambda q, k, v, o, lse, do: pk._flash_bwd(
+        q, k, v, o, lse, do, h, causal, True, num_kv_heads=hk, **kw))(
+            q, k, v, o, lse, do)
+    want_dq, dkr, dvr = jax.jit(lambda q, k, v, o, lse, do: pk._flash_bwd(
+        q, k, v, o, lse, do, h, causal, True, **kw))(q, kr, vr, o, lse, do)
     assert np.array_equal(np.asarray(dq, np.float32),
                           np.asarray(want_dq, np.float32))
     for name, got, parts in (("dk", dk, dkr), ("dv", dv, dvr)):
@@ -514,10 +538,8 @@ def _assert_grouped_is_the_repeated_form(q, k, v, do, h, hk, causal, kw):
 
     # through the public call: float32 keys in (as the op hands them),
     # float32 group sums out, the same values
-    g = jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
-        q, k, v, h, causal, num_kv_heads=hk, **kw).astype(jnp.float32)
-        * do.astype(jnp.float32)), argnums=(0, 1, 2))(
-            q, k.astype(jnp.float32), v.astype(jnp.float32))
+    g = _grads(lambda q, k, v: pk.flash_attention(
+        q, k, v, h, causal, num_kv_heads=hk, **kw), q, *_f32(k, v), do)
     assert [a.dtype for a in g] == [jnp.bfloat16, jnp.float32, jnp.float32]
     for a, b in zip(g, (dq, dk, dv)):
         assert np.array_equal(np.asarray(a, np.float32),

@@ -5,14 +5,18 @@ CPU: values and counts, no times. The helpers and the tolerances are
 hands a test file to ONE worker and that file is already the longest.
 """
 
+import contextlib
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from flexflow_tpu.ops import pallas_kernels as pk
-from test_flash_kernels import (U, _assert_grads_close, _qkv, _rel_rms,
-                                      _repeated)
+from one_program import output_and_gradients
+from test_flash_kernels import (U, _assert_grads_close, _f32, _qkv,
+                                      _rel_rms, _repeated)
 
 ROPE = 64   # the rotated lanes of the two-part score's query and key
 
@@ -77,6 +81,31 @@ def _one_block(form):
     return lambda s, window=0, block_diffusion=None: form
 
 
+_FORWARDS = {}
+
+
+def _forward(case, form=None):
+    """`_flash_fwd`'s (o, lse) of a case with ``form`` the forward's
+    part of `super_block`'s answer; None, and the form that ships, is
+    the rule's own answer, unpatched. One program a (case, form) for
+    the module: the two tests below both ask for the one that ships."""
+    q, k, v, _, h, kw = _super_block_operands(case)
+    seq, bd = q.shape[1], kw.get("block_diffusion")
+    causal = kw.pop("causal")
+    shipped = pk.super_block(
+        seq, pk.normalized_window(seq, causal, kw.get("window", 0)), bd)[0]
+    form = form or shipped
+    if (case, form) not in _FORWARDS:
+        rope = kw.pop("rope", None)
+        with (contextlib.nullcontext() if form == shipped else
+              mock.patch.object(pk, "super_block", _one_block((form, 1)))):
+            _FORWARDS[case, form] = jax.jit(
+                lambda q, k, v, rope: pk._flash_fwd(
+                    q, k, v, h, causal, True, rope=rope, **kw))(
+                        q, k, v, rope)
+    return _FORWARDS[case, form]
+
+
 @pytest.mark.parametrize("case", list(SUPER_BLOCKS))
 def test_the_rule_of_the_blocks_a_grid_step(case):
     """`super_block` at the case's shape, and that the counts of tiles
@@ -111,23 +140,17 @@ def test_super_block_forward_gives_the_bits_of_one_block_a_step(
     zeros: exp(_MASKED - m) is 0.0 exactly, so the bits stand here too;
     the chip's compiler may add a row up in another order, which the
     tolerances of the next test allow."""
-    q, k, v, _, h, kw = _super_block_operands(case)
+    q, _, _, _, _, kw = _super_block_operands(case)
     seq, bd = q.shape[1], kw.get("block_diffusion")
     window = pk.normalized_window(seq, kw["causal"], kw.get("window", 0))
     (_, chains, trimmed), _ = pk.super_block(seq, window, bd)
-    kw = {name: x for name, x in kw.items() if name != "causal"}
 
-    def forward(form):
-        monkeypatch.setattr(pk, "super_block", _one_block((form, 1)))
-        return pk._flash_fwd(q, k, v, h, SUPER_BLOCKS[case][5]["causal"],
-                             True, **kw)
-
-    want = forward((1, 1, False))
+    want = _forward(case, (1, 1, False))
     forms = [(chains, chains, False), (chains, chains, trimmed)]
     if bd is None and seq % (2 * chains * pk._q_block(seq)) == 0:
         forms.append((2 * chains, chains, trimmed))
     for form in forms:
-        for name, a, b in zip(("o", "lse"), forward(form), want):
+        for name, a, b in zip(("o", "lse"), _forward(case, form), want):
             assert np.array_equal(np.asarray(a, np.float32),
                                   np.asarray(b, np.float32)), (form, name)
 
@@ -170,37 +193,30 @@ def test_super_block_kernels_match_float32_attention(case):
     mask = SUPER_BLOCKS[case][5]
     hk, rope = kw["num_kv_heads"], kw.get("rope")
     causal = mask["causal"]
-    rest = {name: x for name, x in kw.items() if name != "causal"}
-    f32 = lambda *xs: [x.astype(jnp.float32) for x in xs]   # noqa: E731
-
-    got, lse = pk._flash_fwd(q, k, v, h, causal, True, **rest)
-    want, want_lse = _float32_attention(
-        *f32(q, k, v), h, hk, rope and f32(*rope), mask)
-    vmax = float(jnp.max(jnp.abs(v.astype(jnp.float32))))
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want), rtol=0, atol=2 * U * vmax)
-    np.testing.assert_allclose(np.asarray(lse[:, :, 0]),
-                               np.asarray(want_lse), rtol=1e-5, atol=1e-5)
-
-    def loss(attend):
-        return lambda *xs: jnp.sum(attend(*xs).astype(jnp.float32)
-                                   * do.astype(jnp.float32))
 
     def kernels(q, k, v, *r):
         return pk._flash(q, k, v, h, causal, True, mask.get("window", 0),
                          mask.get("block_diffusion"), r or None, hk)
 
     def reference(q, k, v, *r):
-        return _float32_attention(q, k, v, h, hk, r or None, mask)[0]
+        return _float32_attention(q, k, v, h, hk, r or None, mask)
 
     operands = (q, k, v) + (rope or ())
     # grouped keys go in as float32 (`flash_attention`), their sums come
     # back float32
-    given = tuple(x.astype(jnp.float32) if hk and n in (1, 2) else x
+    given = tuple(x.astype(np.float32) if hk and n in (1, 2) else x
                   for n, x in enumerate(operands))
-    argnums = tuple(range(len(operands)))
-    g = jax.grad(loss(kernels), argnums=argnums)(*given)
-    gr = jax.grad(loss(reference), argnums=argnums)(*f32(*operands))
+    (d_o,) = _f32(do)
+    _, g = output_and_gradients(kernels, d_o, *given)
+    (want, want_lse), gr = output_and_gradients(reference, d_o,
+                                                *_f32(*operands))
+
+    got, lse = _forward(case)
+    vmax = float(np.max(np.abs(v.astype(np.float32))))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), rtol=0, atol=2 * U * vmax)
+    np.testing.assert_allclose(np.asarray(lse[:, :, 0]),
+                               np.asarray(want_lse), rtol=1e-5, atol=1e-5)
     _assert_grads_close(g[:3], gr[:3], case)
     for name, a, b in zip(("dq_rope", "dk_rope"), g[3:], gr[3:]):
         assert _rel_rms(a, b) < 4 * U, (case, name, _rel_rms(a, b) / U)

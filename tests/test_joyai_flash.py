@@ -29,6 +29,7 @@ from flexflow_tpu.layer import Layer  # noqa: E402
 from flexflow_tpu.ops import pallas_kernels as pk  # noqa: E402
 from flexflow_tpu.ops.attention import rotary_interleaved  # noqa: E402
 from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
+from one_program import output_and_gradients  # noqa: E402
 
 HIGHEST = jax.default_matmul_precision("highest")
 CELL = "joyai_llm_flash.s4096_b1.1chip"
@@ -88,13 +89,10 @@ def test_two_part_flash_matches_the_einsum_core(seq, heads, monkeypatch):
     def flash(q, k, v, qr, kr):
         return pk.flash_attention(q, k, v, heads, causal=True, rope=(qr, kr))
 
-    np.testing.assert_allclose(flash(q, k, v, qr, kr),
-                               assembled(q, k, v, qr, kr, heads),
-                               rtol=1e-4, atol=2e-5)
-    got = jax.grad(lambda *a: jnp.sum(flash(*a) * g), argnums=range(5))(
-        q, k, v, qr, kr)
-    want = jax.grad(lambda *a: jnp.sum(assembled(*a, heads) * g),
-                    argnums=range(5))(q, k, v, qr, kr)
+    o, got = output_and_gradients(flash, g, q, k, v, qr, kr)
+    o_want, want = output_and_gradients(
+        lambda *a: assembled(*a, heads), g, q, k, v, qr, kr)
+    np.testing.assert_allclose(o, o_want, rtol=1e-4, atol=2e-5)
     for name, a, b in zip(("q", "k", "v", "q_rope", "k_rope"), got, want):
         assert a.shape == b.shape, name
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4,

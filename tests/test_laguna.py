@@ -32,6 +32,7 @@ from flexflow_tpu.ops.attention import (rotary_embedding,  # noqa: E402
                                         rotary_frequencies, rotary_partial,
                                         scaled_dot_product_attention)
 from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
+from one_program import output_and_gradients  # noqa: E402
 
 HIGHEST = jax.default_matmul_precision("highest")
 CELL = "laguna_xs2.s8192_b1.1chip"
@@ -128,14 +129,11 @@ def test_narrow_window_flash_matches_the_einsum_core(seq, window, group):
             split(repeat_kv(v, kv, heads)), causal=True, window=window))
 
     with HIGHEST:
-        np.testing.assert_allclose(flash(q, k, v), einsum_core(q, k, v),
-                                   rtol=2e-4, atol=2e-5)
-        got = jax.grad(lambda *a: jnp.sum(flash(*a) * weight),
-                       argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(lambda *a: jnp.sum(einsum_core(*a) * weight),
-                        argnums=(0, 1, 2))(q, k, v)
+        o, got = output_and_gradients(flash, weight, q, k, v)
+        o_want, want = output_and_gradients(einsum_core, weight, q, k, v)
+    np.testing.assert_allclose(o, o_want, rtol=2e-4, atol=2e-5)
     for g, w in zip(got, want):
-        scale = float(jnp.max(jnp.abs(w)))
+        scale = float(np.max(np.abs(w)))
         np.testing.assert_allclose(np.asarray(g) / scale,
                                    np.asarray(w) / scale, atol=2e-5)
 
@@ -157,9 +155,13 @@ def test_the_widest_one_span_window_agrees_with_the_chunk_loop(monkeypatch):
                    for key in keys)
 
     def run():
-        o, lse = pk._flash_fwd(q, k, v, heads, True, True, window=window)
-        return (o, lse) + tuple(pk._flash_bwd(
-            q, k, v, o, lse, do, heads, True, True, window=window))
+        # a new function a call: each is traced under the rule in force
+        def both(q, k, v, do):
+            o, lse = pk._flash_fwd(q, k, v, heads, True, True,
+                                   window=window)
+            return (o, lse) + tuple(pk._flash_bwd(
+                q, k, v, o, lse, do, heads, True, True, window=window))
+        return jax.jit(both)(q, k, v, do)
 
     got = run()
     monkeypatch.setattr(pk, "one_span", lambda *a, **k: None)
@@ -200,15 +202,12 @@ def test_one_span_reads_grouped_keys_at_the_kv_head(group, monkeypatch):
             split(repeat_kv(v, 1, group)), causal=True, window=window))
 
     with HIGHEST:
-        np.testing.assert_allclose(flash(q, k, v), einsum_core(q, k, v),
-                                   rtol=2e-4, atol=2e-5)
-        got = jax.grad(lambda *a: jnp.sum(flash(*a) * weight),
-                       argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(lambda *a: jnp.sum(einsum_core(*a) * weight),
-                        argnums=(0, 1, 2))(q, k, v)
+        o, got = output_and_gradients(flash, weight, q, k, v)
+        o_want, want = output_and_gradients(einsum_core, weight, q, k, v)
+    np.testing.assert_allclose(o, o_want, rtol=2e-4, atol=2e-5)
     for g, w in zip(got, want):
         assert g.dtype == jnp.float32 and g.shape == w.shape
-        scale = float(jnp.max(jnp.abs(w)))
+        scale = float(np.max(np.abs(w)))
         np.testing.assert_allclose(np.asarray(g) / scale,
                                    np.asarray(w) / scale, atol=2e-5)
 
@@ -249,12 +248,12 @@ def test_the_rule_leaves_every_other_kind_alone(monkeypatch):
     monkeypatch.setattr(pk, "one_span", lambda *a, **k: asked.append(
         rule(*a, **k)) or asked[-1])
     with HIGHEST:
-        got = jax.grad(by_kernel, argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(by_einsum, argnums=(0, 1, 2))(q, k, v)
+        got = jax.jit(jax.grad(by_kernel, argnums=(0, 1, 2)))(q, k, v)
+        want = jax.jit(jax.grad(by_einsum, argnums=(0, 1, 2)))(q, k, v)
     # forward and backward both asked, and both took the chunk loop
     assert len(asked) >= 2 and all(one is None for one in asked)
     for g, w in zip(got, want):
-        scale = float(jnp.max(jnp.abs(w)))
+        scale = float(np.max(np.abs(w)))
         np.testing.assert_allclose(np.asarray(g) / scale,
                                    np.asarray(w) / scale, atol=2e-5)
 
@@ -646,6 +645,26 @@ def test_every_gradient_leaf_matches_the_reference(tiny):
     assert leaves == 2 + 5 * 7 + 2 + 4 * 7 + 1    # the gates' among them
 
 
+_CONTROLS_REFERENCE = {}
+
+
+def controls_reference(tiny, layers):
+    """The weights and the reference's predictions of the model cut to
+    ``layers`` layers, made once a cut: the `program_*` keys reach
+    `family.build` alone, so every control of a cut is compared with the
+    same reference on the same weights."""
+    family, config, _, traffic, _, _, _, _ = tiny
+    if layers not in _CONTROLS_REFERENCE:
+        s = family.sizes(config, traffic,
+                         dict(TINY, num_hidden_layers=layers))
+        xs, y = family.make_data(s, 11)
+        weights = jax.device_get(family.make_weights(s, 11))
+        want = hs.reference_side(family, weights, s, traffic, config, xs, y,
+                                 s["batch"], steps=1)
+        _CONTROLS_REFERENCE[layers] = weights, xs, want["preds"]
+    return _CONTROLS_REFERENCE[layers]
+
+
 @pytest.mark.parametrize("layers,control", [
     (1, dict(program_gating=False)),
     (1, dict(program_gate_activation="sigmoid")),
@@ -659,17 +678,13 @@ def test_a_program_built_otherwise_is_not_correct(tiny, layers, control):
     first layer (full attention, dense), and its first two for the
     window's."""
     family, config, _, traffic, _, _, _, _ = tiny
-    cut = dict(TINY, num_hidden_layers=layers)
-    s = family.sizes(config, traffic, dict(cut, **control))
-    xs, y = family.make_data(s, 11)
-    weights = jax.device_get(family.make_weights(
-        family.sizes(config, traffic, cut), 11))
+    s = family.sizes(config, traffic,
+                     dict(TINY, num_hidden_layers=layers, **control))
+    weights, xs, want = controls_reference(tiny, layers)
     ff = family.build(config, s, 1, 11)
     family.install_weights(ff, weights)
     got = np.asarray(ff.predict([xs[0][:s["batch"]]])).astype(np.float32)
-    want = hs.reference_side(family, weights, s, traffic, config, xs, y,
-                             s["batch"], steps=1)
-    nrmse = hs.prediction_errors(got, want["preds"], False)["nrmse"]
+    nrmse = hs.prediction_errors(got, want, False)["nrmse"]
     assert nrmse > family.TOLERANCES["pred_nrmse"]
 
 

@@ -28,6 +28,7 @@ from benchmarks.references import lfm2 as ref  # noqa: E402
 from flexflow_tpu.ffconst import OperatorType  # noqa: E402
 from flexflow_tpu.layer import Layer  # noqa: E402
 from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
+from one_program import output_and_gradients  # noqa: E402
 
 HIGHEST = jax.default_matmul_precision("highest")
 CELL = "lfm2_8b_a1b.s16384_b1.1chip"
@@ -84,25 +85,26 @@ def test_short_conv_matches_the_three_term_sum(taps, gate):
     assert {k: v.shape for k, v in p.items()} == {
         "w_in": (16, 48), "conv_w": (taps, 16), "w_out": (16, 16)}
     assert op.params_elems() == 4 * 16 * 16 + taps * 16
+    # the sum as written and its gradients, one program
+    ctx = OpContext(training=True, compute_dtype=jnp.float32)
+    weight = rs.randn(*h.shape).astype(np.float32)
     with HIGHEST:
-        want = three_term_sum(h, p, taps, gate)
+        want, want_grads = output_and_gradients(
+            lambda p, h: three_term_sum(h, p, taps, gate), weight, p, h)
     np.testing.assert_allclose(run_op(op, p, [h]), want, rtol=1e-5,
                                atol=1e-6)
     # the reference writes the same sum as shifted products
     if gate:
         with HIGHEST:
-            np.testing.assert_allclose(ref.short_conv(h, p, "f32"), want,
-                                       rtol=1e-5, atol=1e-6)
+            np.testing.assert_allclose(
+                jax.jit(lambda h, p: ref.short_conv(h, p, "f32"))(h, p),
+                want, rtol=1e-5, atol=1e-6)
     # backward: the op's own (it keeps the projection alone) against
     # autodiff of the sum as written, for the input and every leaf
-    ctx = OpContext(training=True, compute_dtype=jnp.float32)
-    weight = jnp.asarray(rs.randn(*h.shape), jnp.float32)
     with HIGHEST:
         got = jax.jit(jax.grad(lambda p, h: jnp.sum(
             op.forward(p, [h], ctx)[0] * weight), argnums=(0, 1)))(p, h)
-        want = jax.grad(lambda p, h: jnp.sum(
-            three_term_sum(h, p, taps, gate) * weight), argnums=(0, 1))(p, h)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want_grads)):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
     assert op.traced_gauges() == {"executor.short_conv_ops": 1,
                                   "executor.gated_conv_kernel_ops": 0}
@@ -154,16 +156,18 @@ def test_the_one_pass_kernel_matches_the_jax_numpy_form(
     w = jax.random.normal(keys[1], (taps, width), jnp.float32)
     dy = jax.random.normal(keys[2], (batch, seq, width)).astype(dtype)
 
-    def both(form):
-        y, vjp = jax.vjp(lambda p, w: form(p, w, gate), proj, w)
-        return (y, *vjp(dy))
+    def both(form):     # one program a form
+        def run(proj, w, dy):
+            y, vjp = jax.vjp(lambda p, w: form(p, w, gate), proj, w)
+            return (y, *vjp(dy))
+        return jax.jit(run)(proj, w, dy)
 
     got, want = both(pk.gated_conv_lanes), both(gated_conv)
     # one rounding of the stored dtype apart, where the sums' order differs
     atol = 1e-5 if dtype == jnp.float32 else 2 ** -7
     for g, v in zip(got, want):
         assert g.dtype == v.dtype and g.shape == v.shape
-        scale = float(jnp.max(jnp.abs(v.astype(jnp.float32))))
+        scale = float(np.max(np.abs(np.asarray(v, np.float32))))
         np.testing.assert_allclose(np.asarray(g, np.float32) / scale,
                                    np.asarray(v, np.float32) / scale,
                                    atol=atol)
@@ -385,6 +389,7 @@ def test_one_table_with_both_uses_gradients_and_one_adam_state(
     assert np.isfinite(got_logits).all() and got_logits.std() > 0
 
 
+_CONTROLS_REFERENCE = []
 CONTROLS = [dict(program_conv_L_cache=2),
             dict(program_conv_output_gate=False),
             dict(program_tie_word_embeddings=False),
@@ -401,19 +406,27 @@ def test_a_program_built_otherwise_is_not_correct(tiny, control):
     family, config, _, traffic, _, _, _, _ = tiny
     cut = dict(TINY, num_hidden_layers=2)
     s = family.sizes(config, traffic, dict(cut, **control))
-    xs, y = family.make_data(s, 11)
-    weights = jax.device_get(family.make_weights(
-        family.sizes(config, traffic, cut), 11))
+    if not _CONTROLS_REFERENCE:
+        # the cut's weights, batch and reference, made once: the
+        # `program_*` keys reach `family.build` alone
+        stated = family.sizes(config, traffic, cut)
+        xs, y = family.make_data(stated, 11)
+        weights = jax.device_get(family.make_weights(stated, 11))
+        want = hs.reference_side(family, weights, stated, traffic, config,
+                                 xs, y, stated["batch"], steps=1)["preds"]
+        _CONTROLS_REFERENCE.extend([weights, xs, y, want])
+    weights, xs, y, want = _CONTROLS_REFERENCE
     if "program_qk_layernorm" in control:
         # scales of one and heads of unit variance would hide the norm
-        for name in ("q_norm", "k_norm"):
-            weights["b1_attn"][name] = weights["b1_attn"][name] * 3.0
+        weights = dict(weights, b1_attn=dict(weights["b1_attn"], **{
+            name: weights["b1_attn"][name] * 3.0
+            for name in ("q_norm", "k_norm")}))
+        want = hs.reference_side(family, weights, s, traffic, config, xs, y,
+                                 s["batch"], steps=1)["preds"]
     ff = family.build(config, s, 1, 11)
     family.install_weights(ff, weights)
     got = np.asarray(ff.predict([xs[0][:s["batch"]]])).astype(np.float32)
-    want = hs.reference_side(family, weights, s, traffic, config, xs, y,
-                             s["batch"], steps=1)
-    nrmse = hs.prediction_errors(got, want["preds"], False)["nrmse"]
+    nrmse = hs.prediction_errors(got, want, False)["nrmse"]
     assert nrmse > family.TOLERANCES["pred_nrmse"]
     checks = dict((n, ok) for n, ok, _ in family.extra_checks(ff, s, 1,
                                                               False))

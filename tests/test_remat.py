@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from flexflow_tpu.config import FFConfig
-from flexflow_tpu.ffconst import LossType
+from flexflow_tpu.ffconst import ActiMode, LossType
 from flexflow_tpu.machine import make_mesh
 from flexflow_tpu.model import FFModel
 from flexflow_tpu.optimizers import SGDOptimizer
@@ -280,21 +280,24 @@ class TestFlagPlumbing:
         assert ff.remat_ops is None
 
 
-def _mlp(remat_ops, layers=4, lint="off"):
+def _mlp(remat_ops, layers=4, lint="off", batch=BATCH, devices=8):
     """Heuristic MLP on the 8-way data mesh; remat forced per-op so both
-    runs share ONE strategy (the _plain_mlp pattern)."""
-    cfg = FFConfig(batch_size=BATCH, seed=42)
+    runs share ONE strategy (the _plain_mlp pattern). The wide
+    projections hold their activation: a checkpointed op frees what lies
+    INSIDE it (here the [BATCH, 2048] pre-activation and its mask), and
+    a bare projection has nothing inside."""
+    cfg = FFConfig(batch_size=batch, seed=42)
     cfg.lint = lint
     ff = FFModel(cfg)
-    x = ff.create_tensor((BATCH, 64), name="x")
+    x = ff.create_tensor((batch, 64), name="x")
     t = x
     for i in range(layers):
-        t = ff.dense(t, 2048, name=f"up{i}")
-        t = ff.relu(t)
+        t = ff.dense(t, 2048, activation=ActiMode.AC_MODE_RELU,
+                     name=f"up{i}")
         t = ff.dense(t, 64, name=f"down{i}")
     ff.compile(SGDOptimizer(lr=0.01),
                LossType.MEAN_SQUARED_ERROR_AVG_REDUCE, [],
-               mesh=make_mesh(8, {"data": 8}))
+               mesh=make_mesh(devices, {"data": devices}))
     if remat_ops:
         ff.executor.remat_ops = set(remat_ops)
     return ff
@@ -311,18 +314,60 @@ class TestExecutorParity:
         return [np.asarray(l) for l in
                 jax.tree_util.tree_leaves(ff.params)]
 
+    def _saved_activation_bytes(self, ff):
+        """Bytes of what the forward leaves for the backward, the
+        parameters aside: the leaves of `jax.vjp`'s pullback with a
+        leading dimension of BATCH."""
+        import jax
+        from flexflow_tpu.ops.base import OpContext
+        ex = ff.executor
+        inputs = ff._stage_inputs([np.zeros((BATCH, 64), np.float32)])
+
+        def loss(p):
+            ctx = OpContext(training=True, rng=jax.random.PRNGKey(0),
+                            compute_dtype=ex.compute_dtype, mesh=ex.mesh)
+            values, _, _ = ex.run_graph(p, ff.state, inputs, ctx,
+                                        nodes=ex._training_nodes())
+            return values[ex.final_ref].sum()
+
+        saved = jax.eval_shape(
+            lambda p: jax.tree.leaves(jax.vjp(loss, p)[1]), ff.params)
+        return sum(a.size * a.dtype.itemsize for a in saved
+                   if a.shape[:1] == (BATCH,))
+
     def test_remat_bitwise_and_cuts_hbm_on_8way_mesh(self):
         """Acceptance: jax.checkpoint per-op is bit-for-bit with the
-        plain forward over 3 seeded steps AND the compiled HBM peak
-        (args + temps) drops >= 20% when the wide interiors remat."""
+        plain forward over 3 seeded steps AND what the forward saves for
+        the backward drops >= 20% when the wide interiors remat
+        (measured: 1,196,032 -> 540,672 bytes of activations, the
+        pre-activation and the mask of each of the four).
+
+        Until PR 56 the second half read XLA:CPU's compiled peak (args +
+        temps) of a model whose `_r` ops were bare projections, and
+        failed from the seed on at {'off': 8985992, 'on': 8985992}. Two
+        causes. A bare projection has no interior: its backward reads
+        its two inputs, which are the region's boundary, so the
+        checkpoint region is there in the step's jaxpr with an EMPTY
+        recomputation and the saved values are the same with and without
+        it. And with the activation inside the op, where the regions do
+        drop 55% of the saved bytes, XLA:CPU's buffer assignment gives
+        the same peak to the byte (at 2 rows a device the step's memory
+        is the weights' gradients; at BATCH 1024 it is 13,374,024 either
+        way): the CPU compiler rematerializes cheap elementwise values
+        on its own. So the saving is asserted on what the program
+        controls, and the compiled peak is held to 'no worse' here; the
+        chip's compiler does show it, and
+        `tests/test_tpu_compile.py::test_remat_frees_an_ops_interior_on_the_chip`
+        holds its `temp_size_in_bytes` to the 20%."""
         from flexflow_tpu.search.validate import compiled_train_step
-        states, peaks = {}, {}
+        states, peaks, saved = {}, {}, {}
         for mode in ("off", "on"):
             ff = _mlp({f"up{i}" for i in range(4)}
                       if mode == "on" else None,
                       lint="warn" if mode == "on" else "off")
             ma = compiled_train_step(ff).memory_analysis()
             peaks[mode] = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            saved[mode] = self._saved_activation_bytes(ff)
             if mode == "on":
                 # no FFL2xx drift: recompute duplicates edges, not
                 # collectives — the priced-vs-emitted census stays clean
@@ -332,7 +377,8 @@ class TestExecutorParity:
             states[mode] = self._train(ff)
         for a, b in zip(states["off"], states["on"]):
             assert np.array_equal(a, b)
-        assert peaks["on"] <= 0.8 * peaks["off"], peaks
+        assert saved["on"] <= 0.8 * saved["off"], saved
+        assert peaks["on"] <= peaks["off"], peaks
 
     def test_long_context_attention_hbm_peak_at_seq_2k(self, monkeypatch):
         """Long-context attention (seq 2048): the winning composition is

@@ -29,6 +29,7 @@ from flexflow_tpu.ops import pallas_kernels as pk  # noqa: E402
 from flexflow_tpu.ops.attention import (rotary_embedding,  # noqa: E402
                                         scaled_dot_product_attention)
 from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
+from one_program import output_and_gradients  # noqa: E402
 
 family = hs.load_by_path("families", "sdar")
 HIGHEST = jax.default_matmul_precision("highest")
@@ -110,25 +111,24 @@ def test_block_mask_flash_matches_the_einsum_core(seq, block):
     q, k, v = qkv(seq)
     weight = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
     with HIGHEST:
-        np.testing.assert_allclose(flash(q, k, v, block_diffusion=bd),
-                                   einsum_core(q, k, v, bd),
-                                   rtol=2e-4, atol=2e-5)
-        got = jax.grad(lambda *a: jnp.sum(flash(*a, block_diffusion=bd)
-                                          * weight), argnums=(0, 1, 2))(
-                                              q, k, v)
-        want = jax.grad(lambda *a: jnp.sum(einsum_core(*a, bd) * weight),
-                        argnums=(0, 1, 2))(q, k, v)
+        o, got = output_and_gradients(
+            lambda *a: flash(*a, block_diffusion=bd), weight, q, k, v)
+        o_want, want = output_and_gradients(
+            lambda *a: einsum_core(*a, bd), weight, q, k, v)
+    np.testing.assert_allclose(o, o_want, rtol=2e-4, atol=2e-5)
     for g, w in zip(got, want):
-        scale = float(jnp.max(jnp.abs(w)))
+        scale = float(np.max(np.abs(w)))
         np.testing.assert_allclose(np.asarray(g) / scale,
                                    np.asarray(w) / scale, atol=2e-5)
 
 
 def test_the_mask_is_neither_causal_nor_none():
     q, k, v = qkv(512, seed=2)
-    masked = flash(q, k, v, block_diffusion=(256, 4))
-    assert not np.allclose(masked, flash(q, k, v), atol=1e-3)
-    assert not np.allclose(masked, flash(q, k, v, causal=True), atol=1e-3)
+    run = lambda **mask: jax.jit(lambda q, k, v: flash(  # noqa: E731
+        q, k, v, **mask))(q, k, v)
+    masked = run(block_diffusion=(256, 4))
+    assert not np.allclose(masked, run(), atol=1e-3)
+    assert not np.allclose(masked, run(causal=True), atol=1e-3)
     # the clean copy's first block sees itself alone, as under causal
     # attention a first block of one token would
     for bad in ((256, 4, True, 0), (200, 4, False, 0), (256, 5, False, 0)):
@@ -143,16 +143,24 @@ def test_causal_and_window_are_bitwise_what_they_were(seq):
     with the new argument left empty give the same bits, forward and
     backward, and the one-range tile bounds are PR 31's."""
     q, k, v = qkv(seq, seed=1)
-    rk, rv = repeat_kv(k), repeat_kv(v)
+    rk, rv = jax.jit(repeat_kv)(k), jax.jit(repeat_kv)(v)
+
+    def output_and_grads(f):
+        """f's output and the gradients of sum(output^2): one program a
+        spelling, the output that of the forward the gradients ran."""
+        def loss(*a):
+            o = f(*a)
+            return jnp.sum(o ** 2), o
+        (_, o), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, rk, rv)
+        return (o, *g)
+
     for causal, window in ((True, 0), (True, 128), (False, 0)):
         old = lambda q, k, v: pk._flash(q, k, v, HEADS, causal, True,  # noqa: E731,E501
                                         window)
         new = lambda q, k, v: pk._flash(q, k, v, HEADS, causal, True,  # noqa: E731,E501
                                         window, None)
-        assert np.array_equal(old(q, rk, rv), new(q, rk, rv))
-        grads = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) ** 2),  # noqa: E731,E501
-                                   argnums=(0, 1, 2))(q, rk, rv)
-        for g, c in zip(grads(old), grads(new)):
+        for g, c in zip(output_and_grads(old), output_and_grads(new)):
             assert np.array_equal(g, c)
     assert pk._k_ranges(1024, 256, 1024, 4096, True, 0) == ((0, 2),)
     assert pk._k_ranges(3072, 256, 512, 4096, True, 1024) == ((4, 7),)
@@ -489,14 +497,17 @@ CONFIG = dict(search_budget=2, adam=dict(
     state_dtype="float32"))
 
 
-def run_program(sizes):
+def run_program(sizes, weights=None):
+    """``weights``: the module's, for a control (the `program_*` keys
+    reach `family.build` alone, so making them again gives the same)."""
     # interpret mode: the attention ops run the flash kernels (whole tile
     # at this length), so the mask is the kernels' and not the core's
     old = os.environ.get("FLEXFLOW_TPU_PALLAS")
     os.environ["FLEXFLOW_TPU_PALLAS"] = "interpret"
     try:
         ff = family.build(CONFIG, sizes, 1, 3)
-        weights = jax.device_get(family.make_weights(sizes, 3))
+        if weights is None:
+            weights = jax.device_get(family.make_weights(sizes, 3))
         family.install_weights(ff, weights)
         (ids,), labels = family.make_data(sizes, 3)
         with HIGHEST:
@@ -524,7 +535,8 @@ def reference(model):
     _, weights, ids, labels, _, _ = model
     kw = family.reference_kw(TINY)
     with HIGHEST:
-        logits = np.asarray(ref.forward(weights, jnp.asarray(ids), **kw))
+        logits = np.asarray(jax.jit(lambda w, ids: ref.forward(
+            w, ids, **kw))(weights, ids))
     return logits, common.train_losses(ref, weights, ids, labels, 1, 3,
                                        CONFIG["adam"], **kw)
 
@@ -574,12 +586,13 @@ def test_logits_and_three_losses_match_the_reference(model, reference):
     dict(program_attention_mask="causal"),
     dict(program_shared_positions=False)])
 def test_a_program_with_another_mask_or_other_positions_fails(
-        control, reference):
+        control, model, reference):
     """The two controls of the mechanism: plain causal attention over the
     2L positions, and positions 0..2L-1 for the two copies, each against
     the reference of the objective: judged as the harness judges, with the
     cell's limits, and not correct."""
-    _, _, _, _, logits, step_losses = run_program(dict(TINY, **control))
+    _, _, _, _, logits, step_losses = run_program(dict(TINY, **control),
+                                                  weights=model[1])
     want, want_losses = reference
     rows = hs.compare(dict(preds=logits, losses=step_losses),
                       dict(preds=want, losses=want_losses),

@@ -26,6 +26,7 @@ from flexflow_tpu.ops import moe  # noqa: E402
 from flexflow_tpu.ops import pallas_kernels as pk  # noqa: E402
 from flexflow_tpu.ops.attention import scaled_dot_product_attention  # noqa: E402
 from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
+from one_program import output_and_gradients  # noqa: E402
 
 family = hs.load_by_path("families", "smallthinker")
 HIGHEST = jax.default_matmul_precision("highest")
@@ -70,15 +71,13 @@ def test_window_flash_matches_the_einsum_core(seq, window):
     q, k, v = qkv(seq)
     weight = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
     with HIGHEST:
-        np.testing.assert_allclose(flash(q, k, v, window),
-                                   einsum_core(q, k, v, window),
-                                   rtol=2e-4, atol=2e-5)
-        got = jax.grad(lambda *a: jnp.sum(flash(*a, window) * weight),
-                       argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(lambda *a: jnp.sum(einsum_core(*a, window) * weight),
-                        argnums=(0, 1, 2))(q, k, v)
+        o, got = output_and_gradients(
+            lambda *a: flash(*a, window), weight, q, k, v)
+        o_want, want = output_and_gradients(
+            lambda *a: einsum_core(*a, window), weight, q, k, v)
+    np.testing.assert_allclose(o, o_want, rtol=2e-4, atol=2e-5)
     for g, w in zip(got, want):
-        scale = float(jnp.max(jnp.abs(w)))
+        scale = float(np.max(np.abs(w)))
         np.testing.assert_allclose(np.asarray(g) / scale,
                                    np.asarray(w) / scale, atol=2e-5)
 
@@ -86,15 +85,24 @@ def test_window_flash_matches_the_einsum_core(seq, window):
 @pytest.mark.parametrize("seq", [512, 2048])
 def test_a_window_that_covers_the_sequence_is_causal_bit_for_bit(seq):
     q, k, v = qkv(seq, seed=1)
-    grads = lambda window: jax.grad(  # noqa: E731
-        lambda *a: jnp.sum(flash(*a, window) ** 2), argnums=(0, 1, 2))(
-            q, k, v)
+
+    def output_and_grads(window):
+        """The output and the gradients of sum(output^2): one program a
+        window, traced anew for each."""
+        def loss(*a):
+            o = flash(*a, window)
+            return jnp.sum(o ** 2), o
+        (_, o), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return (o, *g)
+
+    causal = output_and_grads(0)
     for window in (seq, seq + 1, 4096):
-        assert np.array_equal(flash(q, k, v, window), flash(q, k, v, 0))
-        for g, c in zip(grads(window), grads(0)):
+        for g, c in zip(output_and_grads(window), causal):
             assert np.array_equal(g, c)
     # one key less is another function
-    assert not np.array_equal(flash(q, k, v, seq - 1), flash(q, k, v, 0))
+    assert not np.array_equal(
+        jax.jit(lambda *a: flash(*a, seq - 1))(q, k, v), causal[0])
 
 
 def tiles_with_a_visible_pair(seq, blk_q, blk_k, causal, window):
@@ -329,7 +337,8 @@ def test_logits_and_three_losses_match_the_reference(model):
     ff, weights, ids, labels, logits, losses = model
     kw = family.reference_kw(TINY)
     with HIGHEST:
-        want = np.asarray(ref.forward(weights, jnp.asarray(ids), **kw))
+        want = np.asarray(jax.jit(lambda w, ids: ref.forward(
+            w, ids, **kw))(weights, ids))
     assert logits.shape == (2, 128, 64)
     np.testing.assert_allclose(logits, want, rtol=2e-4, atol=2e-5)
     want_losses = common.train_losses(ref, weights, ids, labels, 1, 3,
@@ -348,7 +357,8 @@ def test_a_windowed_layer_differs_from_a_full_one(model):
     _, weights, ids, _, logits, _ = model
     kw = dict(family.reference_kw(TINY), sliding_window_layout=(0, 0, 0, 0))
     with HIGHEST:
-        full = np.asarray(ref.forward(weights, jnp.asarray(ids), **kw))
+        full = np.asarray(jax.jit(lambda w, ids: ref.forward(
+            w, ids, **kw))(weights, ids))
     assert not np.allclose(full, logits, atol=1e-3)
     # up to the window's length no key is hidden
     np.testing.assert_allclose(full[:, :32], logits[:, :32], rtol=2e-4,

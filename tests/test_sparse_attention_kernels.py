@@ -51,9 +51,16 @@ def test_index_select_keeps_top_ks_set(operands, case, precision):
     if case == "quantised":     # a few distinct scores, many ties each
         qi, ki = jnp.round(qi), jnp.round(ki)
         w = jnp.round(w * 10) / 8
-    mask, lse, counts = pk.index_select(qi, ki, w, TOPK, precision)
-    scores = si.index_scores(qi, ki, w)
-    want = si.select(scores, TOPK)
+    mask, lse, counts = jax.jit(lambda q, k, w: pk.index_select(
+        q, k, w, TOPK, precision))(qi, ki, w)
+
+    @jax.jit    # the plain form, one program: scores, the set, its lse
+    def plain(qi, ki, w):
+        scores = si.index_scores(qi, ki, w)
+        want = si.select(scores, TOPK)
+        return want, si.kept_lse(scores, want)
+
+    want, want_lse = plain(qi, ki, w)
     if case == "seeded" and precision == pk.BF16_3X:
         # three bfloat16 products: 2^-16 of a product; a key at a row's
         # threshold may change sides
@@ -66,7 +73,7 @@ def test_index_select_keeps_top_ks_set(operands, case, precision):
         B, S // r, r, S // k, k).sum((2, 4)))
     rows = np.asarray(mask).sum(-1)[0]
     np.testing.assert_array_equal(rows, np.minimum(np.arange(S) + 1, TOPK))
-    np.testing.assert_allclose(lse, si.kept_lse(scores, want), atol=1e-4)
+    np.testing.assert_allclose(lse, want_lse, atol=1e-4)
     if case == "ties":
         assert np.asarray(mask)[0, -1, :TOPK].all()
 
@@ -179,10 +186,11 @@ def test_masked_flash_forward_and_backward(operands, mask, monkeypatch):
             return jnp.sum(o * jnp.cos(o)), lse
         return jax.value_and_grad(fn, argnums=(0, 1, 2), has_aux=True)
 
-    (got, lse_k), g_k = loss(lambda q, k, v: pk.flash_attention_masked(
-        q, k, v, mask, H, HK))(q, k, v)
-    (want, lse_j), g_j = loss(lambda q, k, v: si.masked_attention(
-        q, k, v, mask, H, HK))(q, k, v)
+    (got, lse_k), g_k = jax.jit(loss(
+        lambda q, k, v: pk.flash_attention_masked(q, k, v, mask, H, HK)))(
+            q, k, v)
+    (want, lse_j), g_j = jax.jit(loss(
+        lambda q, k, v: si.masked_attention(q, k, v, mask, H, HK)))(q, k, v)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     np.testing.assert_allclose(lse_k[:, :, 0, :].transpose(0, 2, 1), lse_j,
                                atol=1e-5)
@@ -193,20 +201,26 @@ def test_masked_flash_forward_and_backward(operands, mask, monkeypatch):
 def test_index_kl_value_and_gradients(operands, mask, monkeypatch):
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
     qi, ki, w, q, k, v = operands
-    scores = si.index_scores(qi, ki, w)
-    lse_i = si.kept_lse(scores, mask)
-    _, lse = si.masked_attention(q, k, v, mask, H, HK)
-    kl, dq, dw, dk = pk.index_kl(qi, ki, w, lse_i, mask, q, k, lse, H,
-                                 1.0 / (B * S))
+
+    @jax.jit
+    def by_kernel(qi, ki, w, q, k, v, mask):
+        scores = si.index_scores(qi, ki, w)
+        lse_i = si.kept_lse(scores, mask)
+        _, lse = si.masked_attention(q, k, v, mask, H, HK)
+        return lse, pk.index_kl(qi, ki, w, lse_i, mask, q, k, lse, H,
+                                1.0 / (B * S))
+
+    lse, (kl, dq, dw, dk) = by_kernel(qi, ki, w, q, k, v, mask)
 
     def loss(qi, ki, w):
         return si.index_kl(si.index_scores(qi, ki, w), mask,
                            si.head_sum(q, k, lse, mask, H, HK))
 
-    want, grads = jax.value_and_grad(loss, argnums=(0, 1, 2))(qi, ki, w)
-    np.testing.assert_allclose(jnp.sum(kl) / (B * S), want, rtol=1e-5)
+    want, grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        qi, ki, w)
+    np.testing.assert_allclose(np.sum(kl) / (B * S), want, rtol=1e-5)
     for got, g in zip((dq, dk, dw), grads):
-        scale = float(jnp.max(jnp.abs(g)))
+        scale = float(np.max(np.abs(g)))
         np.testing.assert_allclose(got / scale, g / scale, atol=2e-5)
 
 
@@ -241,7 +255,8 @@ def _op_terms(op, params, xs):
         return jnp.stack([jnp.sum(y * jnp.sin(y)), aux]), (
             y, aux, counters["attention/selected_pairs"][1])
 
-    return jax.jacrev(fn, argnums=(0, 1), has_aux=True)(params, xs)
+    # one program a call (`fn` is new each time: the mode in force)
+    return jax.jit(jax.jacrev(fn, argnums=(0, 1), has_aux=True))(params, xs)
 
 
 @pytest.fixture(scope="module")
@@ -275,7 +290,7 @@ def test_the_op_runs_its_kernels_where_pallas_is_on(on_the_kernels,
     np.testing.assert_allclose(aux_k, aux_j, rtol=1e-4)
     for a, b in zip(jax.tree.leaves(g_k), jax.tree.leaves(g_j)):
         a, b = a[0] + a[1], b[0] + b[1]     # of output + loss
-        scale = max(float(jnp.max(jnp.abs(b))), 1e-6)
+        scale = max(float(np.max(np.abs(b))), 1e-6)
         np.testing.assert_allclose(a / scale, b / scale, atol=5e-4)
 
 

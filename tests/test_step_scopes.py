@@ -113,9 +113,39 @@ def step_text(ff, xs, y):
         ff._shard_batch(y[:batch]), jax.random.PRNGKey(0)).compile().as_text()
 
 
+def train(ff, xs, y, steps=3):
+    """The losses of ``steps`` steps on the first batch."""
+    batch = ff.config.batch_size
+    losses = []
+    for _ in range(steps):
+        ff.fit([x[:batch] for x in xs], y[:batch], epochs=1, verbose=False)
+        losses.append(ff._last_loss)
+    return losses
+
+
+_TRAINED = {}
+
+
+def trained(model):
+    """`MODELS[model]()` and its first three steps, ONE model and one
+    compiled step a module for every test that reads it (`lower` and
+    `compile` of a step that has run find the program it ran): (ff, xs,
+    y, the three losses, `loss_own_vjp` as `obs` gave it before the step
+    was traced, the registry's gauge right after the first step)."""
+    if model not in _TRAINED:
+        ff, xs, y = MODELS[model]()
+        untraced = obs.model_context(ff)["loss_own_vjp"]
+        losses = train(ff, xs, y, 1)
+        gauge = obs.get_registry().to_dict()["gauges"][
+            "executor.loss_own_vjp"]
+        losses += train(ff, xs, y, 2)
+        _TRAINED[model] = ff, xs, y, losses, untraced, gauge
+    return _TRAINED[model]
+
+
 @pytest.fixture(scope="module", params=list(MODELS))
 def lowered(request):
-    ff, xs, y = MODELS[request.param]()
+    ff, xs, y, *_ = trained(request.param)
     return request.param, ss.table_of(step_text(ff, xs, y))
 
 
@@ -198,14 +228,11 @@ def test_the_gauge_says_whether_the_loss_took_its_own_backward(model, want):
     cross-entropy on logits, 0 for MSE and for the `final_is_softmax`
     branch (probabilities in); set when the train step is traced, in the
     registry, `op_counters` and every trace header."""
-    ff, xs, y = MODELS[model]()
-    assert obs.model_context(ff)["loss_own_vjp"] == 0          # not traced
-    batch = ff.config.batch_size
-    ff.fit([x[:batch] for x in xs], y[:batch], epochs=1, verbose=False)
+    ff, _, _, _, untraced, gauge = trained(model)
+    assert untraced == 0
     assert ff.executor.traced_gauges()["executor.loss_own_vjp"] == want
     assert obs.model_context(ff)["loss_own_vjp"] == want
-    assert obs.get_registry().to_dict()["gauges"][
-        "executor.loss_own_vjp"] == want
+    assert gauge == want
 
 
 HLO = """HloModule jit_train_step
@@ -365,14 +392,7 @@ def test_three_steps_are_those_of_the_unscoped_model(model, monkeypatch):
     float32 terms in another order. (The cells' steps compiled for the
     chip are compared with the parent's instruction by instruction in
     PERF.md section 6, PR 36: the three decoders' are the same program.)"""
-    def train():
-        ff, xs, y = MODELS[model]()
-        batch = ff.config.batch_size
-        losses = []
-        for _ in range(3):
-            ff.fit([x[:batch] for x in xs], y[:batch], epochs=1,
-                   verbose=False)
-            losses.append(ff._last_loss)
+    def leaves(ff):
         # (a layer's name carries a counter of the process, and a dict's
         # leaves come in the names' STRING order: `conv2d_98`, `conv2d_100`
         # swap places where the two models' counters straddle a power of
@@ -380,13 +400,14 @@ def test_three_steps_are_those_of_the_unscoped_model(model, monkeypatch):
         def by_number(name):
             return [int(t) if t.isdigit() else t
                     for t in re.split(r"(\d+)", name)]
-        return losses, [np.asarray(p) for name in sorted(ff.params,
-                                                         key=by_number)
-                        for p in jax.tree.leaves(ff.params[name])]
+        return [np.asarray(p) for name in sorted(ff.params, key=by_number)
+                for p in jax.tree.leaves(ff.params[name])]
 
-    losses, params = train()
+    ff, _, _, losses, _, _ = trained(model)
+    params = leaves(ff)
     _identity_scoped(monkeypatch)
-    plain_losses, plain_params = train()
+    plain, xs, y = MODELS[model]()
+    plain_losses, plain_params = train(plain, xs, y), leaves(plain)
     assert losses[0] == plain_losses[0]
     np.testing.assert_allclose(losses, plain_losses, rtol=1e-6)
     assert len(params) == len(plain_params)
