@@ -182,11 +182,7 @@ def rows_in_token_order(slot, valid, tokens: int, k: int):
                              num_keys=1, is_stable=True)
     _, place = jax.lax.sort((row, at), num_keys=1)
     token = pair // k
-    tile = pallas_kernels.SUM_TOKENS
-    bounds = jnp.minimum(
-        jnp.arange(-(-tokens // tile) + 1, dtype=jnp.int32) * tile, tokens)
-    tile_start = jnp.searchsorted(token, bounds, side="left",
-                                  method="compare_all").astype(jnp.int32)
+    tile_start = pallas_kernels.sum_rows_tile_starts(token, tokens)
     return dict(row=row, pair=jnp.minimum(pair, tokens * k - 1), token=token,
                 place=place, tile_start=tile_start,
                 items=pallas_kernels.moe_sum_rows_items(tile_start, rows))

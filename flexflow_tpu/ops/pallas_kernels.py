@@ -2622,11 +2622,29 @@ SUM_ROWS = 128    # token-ordered rows a block: the product's contraction
 MAX_SUM_WIDTH = 4096
 
 
+def sum_rows_blocks_legal(rows: int, width: int) -> bool:
+    """Whole blocks of rows, rows that fill the lanes: the operand
+    `moe_sum_rows` reads without padding anything."""
+    return (rows % SUM_ROWS == 0 and width % LANES == 0
+            and width <= MAX_SUM_WIDTH)
+
+
 def moe_sum_rows_shape_legal(rows: int, width: int, tokens: int) -> bool:
-    """Whole blocks of rows, whole tiles of tokens, rows that fill the
-    lanes: what `moe_sum_rows` takes without padding anything."""
-    return (rows % SUM_ROWS == 0 and tokens % SUM_TOKENS == 0
-            and width % LANES == 0 and width <= MAX_SUM_WIDTH)
+    """`sum_rows_blocks_legal` and whole tiles of tokens: what
+    `moe_sum_rows` AND its transpose `moe_spread_rows` take. The sum
+    alone also takes a last tile short of SUM_TOKENS (its docstring)."""
+    return sum_rows_blocks_legal(rows, width) and tokens % SUM_TOKENS == 0
+
+
+def sum_rows_tile_starts(token, tokens: int):
+    """token [rows] int32 ascending -> [tiles + 1] int32: the place at
+    which the run of each tile of SUM_TOKENS tokens starts (the last
+    entry: where the rows of a token below `tokens` end); the last tile
+    may be short."""
+    bounds = jnp.minimum(jnp.arange(-(-tokens // SUM_TOKENS) + 1,
+                                    dtype=jnp.int32) * SUM_TOKENS, tokens)
+    return jnp.searchsorted(token, bounds, side="left",
+                            method="compare_all").astype(jnp.int32)
 
 
 def moe_sum_rows_items(tile_start, rows: int):
@@ -2722,7 +2740,7 @@ def _moe_sum_rows_kernel(tile_ref, block_ref, count_ref, token_ref, *refs,
 
 
 def moe_sum_rows(x, token, weight, items, tokens: int, dtype,
-                 interpret: bool):
+                 interpret: bool, name: str = "moe_sum_rows"):
     """out[t] = sum of weight[i] * x[i] over the rows i with token[i] == t.
 
     x [rows, d] the rows IN TOKEN ORDER, token [rows] int32 ascending
@@ -2731,7 +2749,12 @@ def moe_sum_rows(x, token, weight, items, tokens: int, dtype,
     of the tiles' starts -> [tokens, d] in `dtype`: products and sums in
     float32, rounded once. Every row is read once, and the block two
     tiles share twice; the cost follows `rows`, whatever share of the
-    tokens' pairs they are. `moe_sum_rows_shape_legal` says which shapes.
+    tokens' pairs they are. `moe_sum_rows_shape_legal` says which shapes;
+    `tokens` may also end inside a tile (`sum_rows_blocks_legal` alone:
+    the embedding's table, PR 57): the last output block is then ragged,
+    written as far as the output goes, and `items` has that tile as any
+    other (`ceil(tokens / SUM_TOKENS)` tiles). `name` is the kernel's in
+    the device trace, for a caller that is not the expert layer.
     A row's 0 in another token's sum is a product, not a select: a row
     that is not finite reaches its whole tile."""
     rows, d = x.shape
@@ -2745,7 +2768,7 @@ def moe_sum_rows(x, token, weight, items, tokens: int, dtype,
     operands = [lanes(token)] + ([] if weight is None else [lanes(weight)])
     return pl.pallas_call(
         functools.partial(_moe_sum_rows_kernel, weighted=weight is not None),
-        name="moe_sum_rows",
+        name=name,
         out_shape=jax.ShapeDtypeStruct((tokens, d), dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,

@@ -695,6 +695,70 @@ class TestHybridDecoderKernels:
         assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
 
 
+class TestEmbeddingBackward:
+    """The `Embedding` op, forward and backward under its scope, at the
+    two tables that paid most for `take`'s transpose (PR 57): the table's
+    gradient comes from the kernel `embedding_sum_rows`, whose last
+    output block is ragged (18,992 = 148 x 128 + 48, as 25,008 is), and
+    the program under `op_embedding` holds no scatter; with the Pallas
+    kernels off, the body it replaced, it holds the scatter-add into
+    the table."""
+    SHAPES = {"smallthinker": (16384, 18992, 2560),
+              "phi4": (8192, 25008, 2560)}
+
+    def _hlo(self, topo, lookups, entries, width, dtype):
+        from flexflow_tpu.ffconst import OperatorType
+        from flexflow_tpu.layer import Layer
+        from flexflow_tpu.ops.base import OpContext, OpRegistry, scoped
+        one = SingleDeviceSharding(topo.devices[0])
+        layer = Layer(OperatorType.EMBEDDING, "embed_tokens", [])
+        layer.properties.update(num_entries=entries, out_dim=width)
+        op = OpRegistry.create(layer, [(1, lookups)])
+        ctx = OpContext(training=True, compute_dtype=dtype)
+
+        def lookup(table, ids):
+            return op.forward({"kernel": table}, [ids], ctx)[0]
+
+        def objective(table, ids, weight):
+            return jnp.sum(scoped("op_embedding", lookup)(table, ids).astype(
+                jnp.float32) * weight)
+
+        hlo = _compile(
+            jax.grad(objective),
+            jax.ShapeDtypeStruct((entries, width), dtype, sharding=one),
+            jax.ShapeDtypeStruct((1, lookups), jnp.int32, sharding=one),
+            jax.ShapeDtypeStruct((1, lookups, width), dtype, sharding=one))
+        return op, hlo
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                             ids=["bfloat16", "float32"])
+    @pytest.mark.parametrize("cell", list(SHAPES))
+    def test_the_tables_gradient_is_the_kernels_at_the_cells_shapes(
+            self, topo, on_tpu, cell, dtype):
+        from flexflow_tpu.obs.inspect import scatters_in
+        from flexflow_tpu.ops.embedding import SUM_KERNEL_NAME
+        op, hlo = self._hlo(topo, *self.SHAPES[cell], dtype)
+        assert op.traced_gauges()["executor.embedding_sum_kernel_ops"] == 1
+        kernels = [line for line in hlo.splitlines()
+                   if "custom_call_target=\"tpu_custom_call\"" in line]
+        assert len(kernels) == 1 and SUM_KERNEL_NAME in kernels[0]
+        # under the op's part, in a nested call of its own: the name
+        # the device trace gives the kernel's events
+        assert ("transpose(jvp(jit(op_embedding)))/jit(%s)" % SUM_KERNEL_NAME
+                in kernels[0])
+        assert scatters_in(hlo) == []
+
+    def test_the_body_it_replaced_holds_the_scatter(self, topo, monkeypatch):
+        from flexflow_tpu.obs.inspect import scatters_in
+        monkeypatch.setattr(pk, "pallas_mode", lambda: "off")
+        lookups, entries, width = self.SHAPES["smallthinker"]
+        op, hlo = self._hlo(topo, lookups, entries, width, jnp.bfloat16)
+        assert op.traced_gauges()["executor.embedding_sum_kernel_ops"] == 0
+        assert pallas_kernel_count(hlo) == 0
+        assert (entries * width) in [
+            size for _, size in scatters_in(hlo, "op_embedding")]
+
+
 class TestFusedAdam:
     KW = dict(beta1=0.9, beta2=0.999, eps=1e-8, wd=1e-4)
 

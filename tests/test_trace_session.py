@@ -617,6 +617,7 @@ WITNESS_KEYS = [
     "attention/kv_blocks_visited", "attention/window_keys_visible",
     "attention/window_keys_visited",
     "executor.block_diffusion_attention_ops",
+    "executor.embedding_sum_kernel_ops",
     "executor.flash_grouped_kv_ops", "executor.flash_lane_dense_ops",
     "executor.flash_one_span_ops", "executor.flash_super_block_ops",
     "executor.latent_attention_ops",
@@ -633,7 +634,7 @@ CONTEXT_KEYS = [
     "attention_kv_blocks_visited", "attention_window_keys_visible",
     "attention_window_keys_visited", "batch_size",
     "block_diffusion_attention_ops", "compile_phases",
-    "flash_grouped_kv_ops", "flash_lane_dense_ops", "flash_one_span_ops",
+    "embedding_sum_kernel_ops", "flash_grouped_kv_ops", "flash_lane_dense_ops", "flash_one_span_ops",
     "flash_super_block_ops",
     "latent_attention_ops", "layer_applications",
     "loss_own_vjp", "loss_target_positions", "mesh_axes",
