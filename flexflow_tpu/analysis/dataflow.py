@@ -317,7 +317,8 @@ def required_input_specs(node, getspec, getparam) -> List[Any]:
             reqs.append(tuple(r))
         return reqs
 
-    if t in (OperatorType.SHORT_CONV, OperatorType.MAMBA_MIXER):
+    if t in (OperatorType.SHORT_CONV, OperatorType.MAMBA_MIXER,
+             OperatorType.DELTA_MIXER):
         # [B, S, E] in and out: the batch follows the output; a position
         # reads the K - 1 before it (the scan: all before it) and the
         # products contract over E, so the sequence and the lanes arrive

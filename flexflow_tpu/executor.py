@@ -43,7 +43,8 @@ COUNT_SUFFIX = "#n"
 SIDE_CHANNELS = ("_aux_loss", "_counters", "_new_state", "_new_states")
 # the op kinds of which a decoder layer holds one
 SEQUENCE_MIXERS = (OperatorType.MULTIHEAD_ATTENTION, OperatorType.SSM_MIXER,
-                   OperatorType.SHORT_CONV, OperatorType.MAMBA_MIXER)
+                   OperatorType.SHORT_CONV, OperatorType.MAMBA_MIXER,
+                   OperatorType.DELTA_MIXER)
 
 
 def settled_spec(spec: P) -> P:

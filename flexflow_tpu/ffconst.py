@@ -189,6 +189,8 @@ class OperatorType(enum.Enum):
     SHORT_CONV = enum.auto()
     # appended (PR 52): the Mamba-1 mixer (ops/ssm.py `MambaMixer`)
     MAMBA_MIXER = enum.auto()
+    # appended (PR 58): the gated delta-rule mixer (ops/delta_rule.py)
+    DELTA_MIXER = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

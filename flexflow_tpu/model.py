@@ -256,11 +256,13 @@ class FFModel:
         return self._finish(layer)
 
     def rms_norm(self, input: Tensor, eps: float = 1e-6,
+                 zero_centered: bool = False,
                  name: Optional[str] = None) -> Tensor:
         """RMSNorm over the last dim (Llama/T5 family; new scope vs the
-        reference)."""
-        layer = self._add_layer(OperatorType.RMSNORM, [input],
-                                dict(eps=eps), name)
+        reference); ``zero_centered``: the scale is 1 + the leaf."""
+        layer = self._add_layer(OperatorType.RMSNORM, [input], dict(
+            eps=eps, **({"zero_centered": True} if zero_centered else {})),
+            name)
         return self._finish(layer)
 
     def embedding(self, input: Tensor, num_entries: int, out_dim: int,
@@ -304,6 +306,8 @@ class FFModel:
                             indexer_dtype: str = "float32",
                             mrope_section=None, mrope_positions=None,
                             index_loss: bool = True,
+                            lane_gate: bool = False,
+                            qk_norm_zero_centered: bool = False,
                             name: Optional[str] = None) -> Tensor:
         """``seq_parallel='seq'`` runs the attention core as ring attention
         over that mesh axis (context parallelism for long sequences).
@@ -402,6 +406,9 @@ class FFModel:
             **({"qk_norm": True, "qk_norm_eps": qk_norm_eps}
                if qk_norm else {}),
             **({"gate": True} if gate else {}),
+            **({"lane_gate": True} if lane_gate else {}),
+            **({"qk_norm_zero_centered": True}
+               if qk_norm and qk_norm_zero_centered else {}),
             **({"gate_activation": gate_activation}
                if gate_activation != "softplus" else {}),
             **({"partial_rotary_factor": partial_rotary_factor}
@@ -449,6 +456,25 @@ class FFModel:
             **({"export_gated": True} if export_gated else {})), name)
         return self._finish(layer)
 
+    def delta_mixer(self, input: Tensor, num_key_heads: int,
+                    num_value_heads: int, key_head_dim: int,
+                    value_head_dim: int, conv_kernel: int = 4,
+                    chunk_size: int = 128, eps: float = 1e-6,
+                    kernel_initializer=None,
+                    name: Optional[str] = None) -> Tensor:
+        """Gated delta-rule mixer over [B, S, E] (ops/delta_rule.py):
+        the projections to q, k, v, the output gate z and the rates, a
+        causal depthwise convolution with SiLU over q, k, v, the heads'
+        L2 norms, the chunked delta rule (a key head serves
+        ``num_value_heads / num_key_heads`` value heads), the gated head
+        norm, output projection."""
+        layer = self._add_layer(OperatorType.DELTA_MIXER, [input], dict(
+            num_key_heads=num_key_heads, num_value_heads=num_value_heads,
+            key_head_dim=key_head_dim, value_head_dim=value_head_dim,
+            conv_kernel=conv_kernel, chunk_size=chunk_size, eps=eps,
+            kernel_initializer=kernel_initializer), name)
+        return self._finish(layer)
+
     def short_conv(self, input: Tensor, kernel: int = 3,
                    output_gate: bool = True, kernel_initializer=None,
                    name: Optional[str] = None) -> Tensor:
@@ -468,7 +494,7 @@ class FFModel:
                   slot_slack: float = 0.5, kernel_initializer=None,
                   scoring: str = "sigmoid", gated: bool = False,
                   router_input: Optional[Tensor] = None,
-                  activation: str = "relu",
+                  activation: str = "relu", shared_gate: bool = False,
                   name: Optional[str] = None) -> Tensor:
         """Dropless mixture-of-experts layer over [B, S, D] with top-k
         routing over all ``n_experts``, computing the part of the
@@ -478,9 +504,12 @@ class FFModel:
         "softmax" over the chosen logits; ``gated``: experts of three
         matrices, down(act(gate(x)) * up(x)) with ``activation`` "relu"
         or "silu"; ``router_input``: a second tensor of the input's shape
-        that the router reads instead."""
+        that the router reads instead; ``shared_gate``: the shared
+        expert's output times sigmoid(x w), one scalar a position (leaf
+        ``w_shared_gate`` [D, 1])."""
         extra = {k_: v for k_, v in (("scoring", scoring), ("gated", gated),
-                                     ("activation", activation))
+                                     ("activation", activation),
+                                     ("shared_gate", shared_gate))
                  if v not in ("sigmoid", False, "relu")}
         layer = self._add_layer(
             OperatorType.MOE_LAYER,

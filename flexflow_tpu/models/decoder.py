@@ -79,6 +79,30 @@ family.
         ``conv_L_cache`` taps over B * x, the gate C, then W_out; no
         bias, no activation. Feed-forward as `F`'s
 
+    R   a gated delta-rule linear-attention mixer, then `F`'s
+        feed-forward, each behind its own norm and residual (PR 58). The
+        mixer (ops/delta_rule.py; ``linear_num_key_heads`` Hk,
+        ``linear_num_value_heads`` Hv, ``linear_key_head_dim``,
+        ``linear_value_head_dim``, ``linear_conv_kernel_dim`` taps):
+        [q ; k ; v ; z] = h W_qkvz, [b ; a] = h W_ba, q, k, v through a
+        causal depthwise convolution and SiLU, beta = sigmoid(b), g =
+        -exp(A_log) softplus(a + dt_bias), q and k L2-normed a head, a
+        value head's state S <- exp(g) S; S <- S + k (x) beta (v - S^T k);
+        o = S^T q, then (rms_norm(o) w_n) * silu(z) a head and W_out;
+        no bias. The model's switches, which `F` / `S` read too:
+        ``attn_output_gate`` (the attention op's query projection is
+        [E, H x 2 x D], a head's columns [query ; gate], and
+        sigmoid(gate) a LANE multiplies the core's output ahead of the
+        output projection, where ``gating`` is one scalar a head),
+        ``zero_centered_norms`` (every norm of the stream, the final norm
+        and the q / k head norms are x_hat * (1 + w), w drawn at zero;
+        the delta mixer's own gated norm is not), ``partial_rotary_factor``
+        (the model-wide short form of ``rope_parameters``' key),
+        ``router_scoring`` "softmax" (`D`'s router: softmax over ALL
+        outputs, the chosen renormalised, no score-correction bias;
+        "sigmoid" is `X`'s) and ``shared_expert_gate`` (the shared
+        expert's output times sigmoid(h w_sg), one scalar a position)
+
     U   the block of a looped (universal-transformer) model (PR 48):
         `L`'s mixers, causal rotary GQA attention and then the SwiGLU
         MLP (gate and up as one product), with SANDWICH norms: a norm
@@ -115,8 +139,8 @@ family.
         keys and values of its own)
 
 ``layer_types`` (a public config's list of "full_attention" /
-"sliding_attention" / "conv", one entry a layer that runs) stands for
-the pattern: `F`, `S` and `C` in its order. ``num_dense_layers`` is the
+"sliding_attention" / "conv" / "linear_attention", one entry a layer
+that runs) stands for the pattern: `F`, `S`, `C` and `R` in its order. ``num_dense_layers`` is the
 short form of ``mlp_layer_types``: the layers below it are "dense", the
 others "sparse". ``qk_layernorm`` gives `F` / `S` an RMS norm of every
 query and key head ahead of rotary (scales of ``head_dim``, shared by
@@ -258,6 +282,20 @@ class DecoderConfig:
     # control: the gate C left out)
     conv_L_cache: int = 3
     conv_output_gate: bool = True
+    # `R`: the gated delta-rule mixer's heads, taps and chunk, and the
+    # switches of its model that `F` / `S` and the feed-forward read
+    # (module docstring)
+    linear_num_key_heads: int = 2
+    linear_num_value_heads: int = 4
+    linear_key_head_dim: int = 16
+    linear_value_head_dim: int = 16
+    linear_conv_kernel_dim: int = 4
+    delta_chunk_size: int = 128
+    attn_output_gate: bool = False
+    zero_centered_norms: bool = False
+    partial_rotary_factor: float = 1.0
+    router_scoring: str = "sigmoid"
+    shared_expert_gate: bool = False
     # the head reads the embedding's table (one leaf) in place of its own
     tie_word_embeddings: bool = False
     # a looped model: the stack applied this many times with one set of
@@ -416,13 +454,14 @@ def _attention_ffn_block(ff, t, prefix, cfg, mixer, experts,
     expert (none at ``shared_width`` 0): the block of `A` / `X`, of
     `F` / `S` / `C` and of `U`, which differ in ``mixer(h)``; with
     ``sandwich`` each branch's output is normed too."""
-    eps = cfg.layer_norm_epsilon
-    h = ff.rms_norm(t, eps=eps, name=f"{prefix}_norm")
+    eps, zero = cfg.layer_norm_epsilon, cfg.zero_centered_norms
+    h = ff.rms_norm(t, eps=eps, zero_centered=zero, name=f"{prefix}_norm")
     a = mixer(h)
     if sandwich:
         a = ff.rms_norm(a, eps=eps, name=f"{prefix}_attn_out_norm")
     t = ff.add(t, a, name=f"{prefix}_res1")
-    g = ff.rms_norm(t, eps=eps, name=f"{prefix}_post_norm")
+    g = ff.rms_norm(t, eps=eps, zero_centered=zero,
+                    name=f"{prefix}_post_norm")
     if not experts:
         m = _swiglu_mlp(ff, g, cfg, prefix, one_product=True)
         if sandwich:
@@ -434,7 +473,8 @@ def _attention_ffn_block(ff, t, prefix, cfg, mixer, experts,
         experts_held=cfg.experts_held, expert_offset=cfg.expert_offset,
         routed_scaling=cfg.routed_scaling_factor,
         norm_topk=cfg.norm_topk_prob, slot_slack=cfg.slot_slack,
-        gated=True, activation=cfg.hidden_act, name=f"{prefix}_mixer")
+        scoring=cfg.router_scoring, gated=True, activation=cfg.hidden_act,
+        shared_gate=cfg.shared_expert_gate, name=f"{prefix}_mixer")
     return ff.add(t, m, name=f"{prefix}_res2")
 
 
@@ -460,7 +500,7 @@ def _latent_block(ff, t, prefix, cfg, experts):
 
 
 LAYER_TYPE_LETTERS = {"full_attention": "F", "sliding_attention": "S",
-                      "conv": "C"}
+                      "conv": "C", "linear_attention": "R"}
 LETTER_LAYER_TYPES = {v: k for k, v in LAYER_TYPE_LETTERS.items()}
 
 
@@ -472,11 +512,13 @@ def _of_layer(values, i, default):
 def _gated_block(ff, t, i, cfg, letter):
     """`F` / `S`: layer i's own heads, its kind's rotary parameters and
     window, the heads' norm, the gate; `C`: the gated short convolution;
-    then layer i's kind of feed-forward."""
+    `R`: the gated delta-rule mixer; then layer i's kind of
+    feed-forward."""
     rope = dict((cfg.rope_parameters or {}).get(
         LETTER_LAYER_TYPES[letter]) or {})
     theta = float(rope.pop("rope_theta", cfg.rope_theta))
-    partial = float(rope.pop("partial_rotary_factor", 1.0))
+    partial = float(rope.pop("partial_rotary_factor",
+                             cfg.partial_rotary_factor))
     scaled = rope.get("rope_type", "default") != "default"
     feed_forward = _of_layer(
         cfg.mlp_layer_types, i,
@@ -490,6 +532,14 @@ def _gated_block(ff, t, i, cfg, letter):
                              output_gate=cfg.conv_output_gate,
                              name=f"b{i}_conv")
 
+    def delta_rule(h):
+        return ff.delta_mixer(
+            h, cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+            conv_kernel=cfg.linear_conv_kernel_dim,
+            chunk_size=cfg.delta_chunk_size, eps=cfg.layer_norm_epsilon,
+            name=f"b{i}_delta")
+
     def attention(h):
         return ff.multihead_attention(
             h, h, h, cfg.hidden_size,
@@ -502,10 +552,13 @@ def _gated_block(ff, t, i, cfg, letter):
             partial_rotary_factor=partial,
             rope_scaling=rope if scaled else None,
             qk_norm=cfg.qk_layernorm, qk_norm_eps=cfg.layer_norm_epsilon,
+            lane_gate=cfg.attn_output_gate,
+            qk_norm_zero_centered=cfg.zero_centered_norms,
             name=f"b{i}_attn")
 
     return _attention_ffn_block(ff, t, f"b{i}", cfg,
-                                short_conv if letter == "C" else attention,
+                                {"C": short_conv, "R": delta_rule}.get(
+                                    letter, attention),
                                 feed_forward == "sparse",
                                 cfg.moe_shared_expert_intermediate_size)
 
@@ -653,7 +706,7 @@ def _mixer(ff, h, letter, i, cfg):
         return ff.dense(ff.multiply(up, up, name=f"b{i}_sq"),
                         cfg.hidden_size, use_bias=False, name=name)
     raise ValueError(f"decoder pattern: unknown block letter {letter!r} "
-                     f"(known: M E * - L G W D K A X F S C U m w y f g c)")
+                     f"(known: M E * - L G W D K A X F S C R U m w y f g c)")
 
 
 def _stack(ff, t, pattern, cfg):
@@ -669,7 +722,7 @@ def _stack(ff, t, pattern, cfg):
                 lambda h: _attention(ff, h, cfg, f"b{i}_attn", rope=True),
                 False, 0, sandwich=cfg.sandwich_norm)
             continue
-        if letter in "FSC":
+        if letter in "FSCR":
             t = _gated_block(ff, t, i, cfg, letter)
             continue
         if letter in "AX":
@@ -747,7 +800,9 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
     # a decoder-hybrid-decoder model's norms are LayerNorms with bias
     final_norm = (ff.layer_norm if set(pattern) <= set(SAMBAY_LETTERS)
                   else ff.rms_norm)
-    t = final_norm(t, eps=cfg.layer_norm_epsilon, name="final_ln")
+    t = final_norm(t, eps=cfg.layer_norm_epsilon, name="final_ln",
+                   **({"zero_centered": True} if cfg.zero_centered_norms
+                      else {}))
     if "K" in pattern:
         # the weighted loss then counts `loss/main_nll` beside the
         # indexers' `loss/index_kl`

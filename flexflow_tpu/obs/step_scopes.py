@@ -35,6 +35,9 @@ SCOPE_PARTS = {"optimizer_update": "optimizer_update", "loss": "loss",
                # its scan's output (the builder's scope around the
                # unit's products and multiplies)
                "mamba_mixer": "mamba", "gated_memory": "gated_memory",
+               # the gated delta-rule mixer (its chunked rule lies in the
+               # nested call `delta_rule` inside it)
+               "delta_mixer": "delta_mixer",
                # a multi-token-prediction module's own ops, whatever their
                # kind: the scope lies around theirs (`FFModel.scope`)
                "mtp": "mtp",

@@ -342,6 +342,9 @@ class AttentionRoute:
     # pairs visible); None where flash does not run
     kv_blocks: Optional[Tuple[int, int, int]] = None
     window_pairs: Optional[Tuple[int, int]] = None
+    # a head of two 128-lane blocks (256): the flash kernels that take
+    # one [Q block, K block] tile a grid step
+    wide_head: bool = False
     # learned sparse attention: the selection and the indexer's loss run
     # the kernels `index_select` / `index_kl` (with core "flash": the
     # chunk-loop kernels with the mask operand); else `ops/sparse_index`
@@ -505,6 +508,16 @@ class MultiHeadAttention(Op):
         if self.gate_activation not in GATE_ACTIVATIONS:
             raise ValueError(f"attention '{layer.name}': unknown "
                              f"gate_activation {self.gate_activation!r}")
+        # a gate a LANE (PR 58): the query projection is [E, H x 2 x D], a
+        # head's columns [query ; gate], and sigmoid(gate) multiplies the
+        # core's output ahead of the output projection
+        self.lane_gate = bool(p.get("lane_gate", False))
+        if self.lane_gate and self.gate:
+            raise ValueError(f"attention '{layer.name}': one output gate, "
+                             f"a head's scalar (gate) or a lane's "
+                             f"(lane_gate)")
+        # the heads' norm is zero-centred: the scale is 1 + the leaf
+        self.qk_norm_offset = 1.0 if p.get("qk_norm_zero_centered") else 0.0
         # latent attention: (q rank, kv rank, rotated width) or None
         self.latent = None
         if p.get("kv_lora_rank"):
@@ -519,6 +532,7 @@ class MultiHeadAttention(Op):
                     and input_shapes[0] == input_shapes[1]) or (
                         self.window or self.block_diffusion or self.qk_norm
                         or self.use_bias or self.rope_wrap or self.gate
+                        or self.lane_gate
                         or self.rope_scaling
                         or self.rotary_dim != self.head_dim
                         or p.get("seq_parallel")):
@@ -560,6 +574,7 @@ class MultiHeadAttention(Op):
         self.export_kv = bool(p.get("export_kv", False))
         if self.differential and (
                 self.latent or self.rope or self.qk_norm or self.gate
+                or self.lane_gate
                 or self.block_diffusion or self.seq_parallel
                 or self.num_heads % 2 or self.num_kv_heads % 2):
             raise ValueError(
@@ -583,6 +598,7 @@ class MultiHeadAttention(Op):
                 ("differential", self.differential),
                 ("kv_given", self.kv_given), ("export_kv", self.export_kv),
                 ("latent attention", self.latent), ("gate", self.gate),
+                ("lane_gate", self.lane_gate),
                 ("seq_parallel", self.seq_parallel),
                 ("dropout", self.dropout)) if on]
             if refused or not self.causal or len(input_shapes) != 3 \
@@ -651,7 +667,8 @@ class MultiHeadAttention(Op):
             return params
         ks = jax.random.split(rng, 4)
         params = {
-            "wq": self.kernel_init(ks[0], (h, e, d)),
+            "wq": self.kernel_init(ks[0], (h, e, (2 if self.lane_gate
+                                                  else 1) * d)),
             "wk": self.kernel_init(ks[1], (hk, self.kdim, d)),
             "wv": self.kernel_init(ks[2], (hk, self.vdim, d)),
             "wo": self.kernel_init(ks[3], (h, d, e)),
@@ -666,8 +683,8 @@ class MultiHeadAttention(Op):
                     jax.random.fold_in(rng, 5 + i), (d,), jnp.float32)
             params["diff_norm"] = jnp.ones((2 * d,))
         if self.qk_norm:
-            params["q_norm"] = jnp.ones((d,))
-            params["k_norm"] = jnp.ones((d,))
+            params["q_norm"] = jnp.full((d,), 1.0 - self.qk_norm_offset)
+            params["k_norm"] = jnp.full((d,), 1.0 - self.qk_norm_offset)
         if self.gate:
             # a key of its own: the four above stay what they were
             params["w_gate"] = self.kernel_init(jax.random.fold_in(rng, 4),
@@ -714,7 +731,8 @@ class MultiHeadAttention(Op):
         xh = x.reshape(b, s, heads, self.head_dim)
         rms = jax.lax.rsqrt(jnp.mean(xh * xh, axis=-1, keepdims=True)
                             + self.qk_norm_eps)
-        return (xh * rms * scale.astype(jnp.float32)).reshape(x.shape)
+        return (xh * rms * (scale.astype(jnp.float32)
+                            + self.qk_norm_offset)).reshape(x.shape)
 
     @property
     def rope_dim(self) -> int:
@@ -789,7 +807,10 @@ class MultiHeadAttention(Op):
             blocked = "cross_attention"
         elif dropout_rate > 0:
             blocked = "dropout"
-        elif not legal(h):
+        elif not legal(h) or (d > pk.LANES and (
+                self.block_diffusion or self.sparse_index)):
+            # the kernels for a head of two lane blocks take causal
+            # attention and a window, no other mask
             blocked = "shape"
         else:
             blocked = None
@@ -867,6 +888,12 @@ class MultiHeadAttention(Op):
             h // shards, hk // shards, d))
         kind = (sq, self.causal, self.window, self.block_diffusion,
                 self.rope_dim)
+        if d > pk.LANES:    # a tile a grid step: no span, no super-block
+            return AttentionRoute(
+                core, blocked, fallback, scope, shard_axes, grouped_kv,
+                rotary_in_lanes,
+                kv_blocks=pk.wide_kv_blocks(sq, self.causal, self.window),
+                wide_head=True)
         if self.sparse_index:
             return AttentionRoute(
                 core, blocked, fallback, scope, shard_axes, grouped_kv,
@@ -923,6 +950,8 @@ class MultiHeadAttention(Op):
         # only a differential op publishes its key, as a tied product its
         extra = ({"executor.flash_diff_ops": int(route.core == "flash")}
                  if self.differential else {})
+        if self.head_dim > 128:     # published only where the model has one
+            extra["executor.flash_wide_head_ops"] = int(route.wide_head)
         if self.sparse_index:   # published only where the model has one
             kernels = route.core == "flash" and route.sparse_kernels
             extra.update({
@@ -1012,6 +1041,9 @@ class MultiHeadAttention(Op):
         if self.gate:
             o = scoped("attention_gate", lambda w, x, o: self._gated(
                 w, x, o, ctx))(params["w_gate"], inputs[0], o)
+        if self.lane_gate:
+            o = scoped("attention_gate", lambda w, x, o: self._lane_gated(
+                w, x, o, ctx))(params["wq"], inputs[0], o)
         return [around(lambda params, o: self._output(
             params, o, ctx, dtype))(params, o)] + exported, lam
 
@@ -1180,7 +1212,10 @@ class MultiHeadAttention(Op):
         # q, k, v and o stay [B, S, heads*head_dim] from the projections
         # to the output projection: what the products write, what the
         # flash kernels take, and no minor dimension under 128 lanes
-        q = self._project(query, params["wq"], params["bq"] if biased else None, cd)
+        wq = params["wq"]
+        if self.lane_gate:      # a head's first D columns; `_lane_gated`
+            wq = wq[..., :self.head_dim]    # takes the rest
+        q = self._project(query, wq, params["bq"] if biased else None, cd)
         if self.kv_given:   # as another op projected (and exported) them
             return q, key, value, None
         k = self._project(key, params["wk"], params["bk"] if biased else None, cd)
@@ -1256,6 +1291,9 @@ class MultiHeadAttention(Op):
                 f"or scaled rotary")
         scales = ((params["q_norm"], params["k_norm"]) if self.qk_norm
                   else (None, None))
+        if lanes and self.qk_norm and self.qk_norm_offset:
+            scales = tuple(t.astype(jnp.float32) + self.qk_norm_offset
+                           for t in scales)
         if not lanes and self.qk_norm:
             q, k = (self._heads_normed(t, t.shape[-1] // d, scale)
                     for t, scale in zip((q, k), scales))
@@ -1312,6 +1350,16 @@ class MultiHeadAttention(Op):
             precision=jax.lax.Precision.HIGHEST))           # [B, S, H]
         return (o.astype(jnp.float32) * gate_lanes(a, self.head_dim)
                 ).astype(ctx.compute_dtype)
+
+    def _lane_gated(self, wq, x, o, ctx: OpContext):
+        """o [B, S, H*D] times sigmoid of the gate a lane, the second D
+        columns of every head of the query projection wq [H, E, 2 D]:
+        one more [E, H*D] product, the sigmoid and the multiply in
+        float32."""
+        gate = self._project(x, wq[..., self.head_dim:], None,
+                             ctx.compute_dtype)
+        return (o.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(
+            ctx.compute_dtype)
 
     def _qkv_latent(self, params, inputs, ctx: OpContext):
         """q_nope, k_nope, v [B, S, H*D] and (q_rope [B, S, H*R], the one
@@ -1464,6 +1512,11 @@ class MultiHeadAttention(Op):
                 f"no learned sparse attention (a new query would score "
                 f"every cached position with the indexer, whose key the "
                 f"cache does not hold, and attend over the kept ones)")
+        if self.lane_gate:
+            raise NotImplementedError(
+                f"attention '{self.name}': KV-cache incremental decode has "
+                f"no gate a lane out of the query projection (this path "
+                f"projects whole [E, H*D] queries and applies no gate)")
         if self.differential or self.kv_given or self.export_kv:
             raise NotImplementedError(
                 f"attention '{self.name}': KV-cache incremental decode has "
@@ -1602,6 +1655,8 @@ class MultiHeadAttention(Op):
             3 if self.differential else 2)
         # the gate's product and its multiply of the core's output
         gate = (2 * e + d) * b * sq * h if self.gate else 0
+        if self.lane_gate:  # its columns of the query projection, a lane
+            gate = (2 * e + 1) * d * b * sq * h
         if self.sparse_index:
             # the main products over the pairs the kernels visit (every
             # causal tile: a query's kept keys lie scattered), the
@@ -1640,6 +1695,8 @@ class MultiHeadAttention(Op):
             n += 2 * d
         if self.gate:
             n += e * h
+        if self.lane_gate:
+            n += h * d * e
         if self.sparse_index:
             hi, di, _ = self.sparse_index
             n += e * (hi * di + di + hi) + 2 * di

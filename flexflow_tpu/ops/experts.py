@@ -214,6 +214,12 @@ class MoELayer(Op):
         self.scoring = p.get("scoring", "sigmoid")
         self.gated = p.get("gated", False)
         self.activation = p.get("activation", "relu")
+        # the shared expert's output times sigmoid(x w_shared_gate), one
+        # scalar a position (PR 58)
+        self.shared_gate = bool(p.get("shared_gate", False))
+        if self.shared_gate and not p.get("shared_width", 0):
+            raise ValueError(f"moe_layer '{layer.name}': shared_gate "
+                             f"without a shared expert")
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"moe_layer '{layer.name}': unknown scoring "
                              f"{self.scoring!r}")
@@ -278,6 +284,9 @@ class MoELayer(Op):
             if self.gated:
                 params["ws_gate"] = self.kernel_init(
                     jax.random.fold_in(ks[3], 1), (d, self.shared_width))
+            if self.shared_gate:
+                params["w_shared_gate"] = self.kernel_init(
+                    jax.random.fold_in(ks[3], 2), (d, 1))
         return params
 
     def forward(self, params, inputs, ctx: OpContext):
@@ -329,8 +338,13 @@ class MoELayer(Op):
                 hs = GATE_ACTIVATIONS[self.activation](gs) * hs
             else:
                 hs = squared_relu(hs)
-            return jnp.dot(hs.astype(cd), params["ws_down"].astype(cd),
-                           preferred_element_type=jnp.float32)
+            ys = jnp.dot(hs.astype(cd), params["ws_down"].astype(cd),
+                         preferred_element_type=jnp.float32)
+            if self.shared_gate:
+                ys = ys * jax.nn.sigmoid(jnp.dot(
+                    xt.astype(cd), params["w_shared_gate"].astype(cd),
+                    preferred_element_type=jnp.float32))
+            return ys
 
         def layer(params, x, x_router):
             xt = x.reshape(b * s, d)
@@ -401,7 +415,8 @@ class MoELayer(Op):
         pairs = t * self.k * self.experts_held / self.n_experts
         return int(2 * t * d * self.n_experts
                    + 2 * self.matrices * pairs * d * self.hidden_size
-                   + 2 * self.matrices * t * d * self.shared_width)
+                   + 2 * self.matrices * t * d * self.shared_width
+                   + 2 * t * d * self.shared_gate)
 
     def interior_bytes(self):
         """Kept for the backward pass besides the output: the buffer's
@@ -417,4 +432,5 @@ class MoELayer(Op):
         d = self.input_shapes[0][-1]
         return ((d + (self.scoring == "sigmoid")) * self.n_experts
                 + self.matrices * self.experts_held * d * self.hidden_size
-                + self.matrices * d * self.shared_width)
+                + self.matrices * d * self.shared_width
+                + d * self.shared_gate)

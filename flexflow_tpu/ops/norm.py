@@ -119,24 +119,31 @@ class GroupNorm(Op):
 @register_op(OperatorType.RMSNORM)
 class RMSNorm(Op):
     """Root-mean-square normalization over the last dim (Llama/T5 family;
-    new scope vs the reference). y = x / rms(x) * scale, computed in f32."""
+    new scope vs the reference). y = x / rms(x) * scale, computed in f32.
+    ``zero_centered`` (PR 58): y = x / rms(x) * (1 + scale), the leaf
+    drawn at zero, so that weight decay pulls the scale toward one."""
 
     def __init__(self, layer, input_shapes):
         self.eps = layer.get_property("eps", 1e-6)
+        self.zero_centered = bool(layer.get_property("zero_centered", False))
         super().__init__(layer, input_shapes)
 
     def compute_output_shapes(self):
         return [self.input_shapes[0]]
 
     def init_params(self, rng):
-        return {"scale": jnp.ones((self.input_shapes[0][-1],))}
+        return {"scale": jnp.full((self.input_shapes[0][-1],),
+                                  0.0 if self.zero_centered else 1.0)}
 
     def forward(self, params, inputs, ctx: OpContext):
         (x,) = inputs
         xf = x.astype(jnp.float32)
         rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
                             + self.eps)
-        return [(xf * rms * params["scale"]).astype(x.dtype)]
+        scale = params["scale"]
+        if self.zero_centered:
+            scale = 1.0 + scale.astype(jnp.float32)
+        return [(xf * rms * scale).astype(x.dtype)]
 
     def output_dim_roles(self):
         shp = self.output_shapes[0]

@@ -111,8 +111,15 @@ LANES = 128  # a vreg's and an HBM tile's minor extent
 # refuses the bf16 K-blocked backward from S = 8192 and the forward from
 # S = 16384. Every flash pallas_call asks for 96 MiB instead; with that
 # the deviceless v5e compile accepts forward and both backwards for
-# S <= MAX_FLASH_SEQ at head_dim <= MAX_FLASH_HEAD_DIM in bf16 and f32
-# (tests/test_tpu_compile.py), and refuses head_dim 256 at S = 16384.
+# S <= MAX_FLASH_SEQ at head_dim <= 128 in bf16 and f32
+# (tests/test_tpu_compile.py). A head of 256 lanes (PR 58) does not fit
+# these kernels' resident [S, W] panels at S = 16384 (the compile was
+# refused); it takes kernels of its own, one [Q block, K block] tile a
+# grid step (`_wide_flash_fwd`, `_wide_flash_bwd`: blocks of 512 x 1024,
+# 16 : 2 heads of 256 at S = 16384 accepted deviceless, bf16 and f32),
+# which ask for the same 96 MiB and use about 8 MiB of it: a [512, 1024]
+# float32 score tile, its probabilities, and double-buffered [512, 256]
+# and [1024, 256] operand blocks.
 _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 # with a mask operand (PR 54) a grid step also holds its rows of the
 # mask, [1024, S] bytes twice (16 MiB each at S = 16384), which the
@@ -145,7 +152,9 @@ MAX_BWD_SEQ = 1024
 # (native/ffs_strategy.hpp) so the search never prices a length the
 # compiler refuses.
 MAX_FLASH_SEQ = 16384
-MAX_FLASH_HEAD_DIM = 128
+# 128 for every form of the kernels; 256 exactly (two lane blocks a head)
+# under a causal or plain mask with or without a window (PR 58)
+MAX_FLASH_HEAD_DIM = 256
 
 _NT = (((1,), (1,)), ((), ()))  # a[m, c] . b[n, c] -> [m, n]
 _NN = (((1,), (0,)), ((), ()))  # a[m, c] . b[c, n] -> [m, n]
@@ -1434,6 +1443,10 @@ def _flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     chunk-loop kernel alone takes it (``masked_flash_legal``)."""
     b, s, hd = q.shape
     d = hd // num_heads
+    if d > LANES:   # a head of two lane blocks: a tile a grid step
+        assert block_diffusion is None and rope is None and mask is None
+        return _wide_flash_fwd(q, k, v, num_heads, causal, interpret,
+                               out_dtype, window, num_kv_heads)
     hpb = _heads_per_block(num_heads, d)
     w = hpb * d
     rope_dim, per, rope_ops = _rope_operands(rope, num_heads, d)
@@ -1895,6 +1908,10 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
     (two KV heads) and the ``rep`` query column blocks each serves."""
     b, s, hd = q.shape
     d = hd // num_heads
+    if d > LANES:
+        assert block_diffusion is None and rope is None and mask is None
+        return _wide_flash_bwd(q, k, v, o, lse, do, num_heads, causal,
+                               interpret, window, num_kv_heads, glse)
     hpb = _heads_per_block(num_heads, d)
     w = hpb * d
     rope_dim, per, rope_ops = _rope_operands(rope, num_heads, d)
@@ -2019,6 +2036,340 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
         compiler_params=(_FLASH_COMPILER_PARAMS if mask is None
                          else _MASKED_COMPILER_PARAMS),
     )(q, k, v, o, do, lse, glse, *rope_ops, *(mask or ())))
+
+
+# ---------------------------------------------------------------------------
+# heads wider than a lane block (PR 58): a head of 256 is two 128-lane
+# blocks side by side, the score the sum of two 128-deep products (one
+# contraction of 256), the output two halves. The panels the kernels
+# above keep resident ([S, W] of K and V forward, of Q, O, dO, dQ
+# backward) would be [S, 256] here, which the 96 MiB do not hold at
+# S = 16384 beside the score tiles; so these take ONE [Q block, K block]
+# tile a grid step, every operand a block its BlockSpec fetches, the
+# running statistics and the sums in VMEM scratch across the innermost
+# grid axis. Causal or not, a window; no block-diffusion mask, no
+# two-part score, no mask operand (nobody has needed them at this width).
+WIDE_BLK_Q = 512
+WIDE_BLK_K = 1024
+
+
+def _wide_blocks(s: int):
+    return (next(b for b in (WIDE_BLK_Q, 256, BLK_Q) if s % b == 0),
+            next(b for b in (WIDE_BLK_K, 512, 256, BLK_Q) if s % b == 0))
+
+
+def wide_kv_blocks(s: int, causal: bool, window: int = 0):
+    """(visited, total, masked) [Q block, K block] tiles a head of the
+    wide-head forward works through, as `kv_blocks` / `kv_blocks_masked`
+    count the chunk loop's."""
+    window = normalized_window(s, causal, window)
+    blk_q, blk_k = _wide_blocks(s)
+    nq, nk = s // blk_q, s // blk_k
+    visited = masked = 0
+    for iq in range(nq):
+        first, last = _wide_k_range(iq, blk_q, blk_k, nk, causal, window)
+        for ik in range(int(first), int(last) + 1):
+            visited += 1
+            masked += not _wide_interior(iq * blk_q, ik * blk_k, blk_q,
+                                         blk_k, causal, window)
+    return visited, nq * nk, int(masked)
+
+
+def _wide_k_range(iq, blk_q: int, blk_k: int, nk: int, causal: bool,
+                  window: int):
+    """(first, last) K block that holds a key some query of Q block
+    ``iq`` sees."""
+    if not causal:
+        return 0, nk - 1
+    last = (iq * blk_q + blk_q - 1) // blk_k
+    first = jnp.maximum(iq * blk_q - window + 1, 0) // blk_k if window else 0
+    return first, last
+
+
+def _wide_q_range(ik, blk_q: int, blk_k: int, nq: int, causal: bool,
+                  window: int):
+    """(first, last) Q block that holds a query which sees some key of K
+    block ``ik``."""
+    if not causal:
+        return 0, nq - 1
+    first = ik * blk_k // blk_q
+    last = (jnp.minimum((ik * blk_k + blk_k - 1 + window - 1) // blk_q,
+                        nq - 1) if window else nq - 1)
+    return first, last
+
+
+def _wide_interior(q0, k0, blk_q: int, blk_k: int, causal: bool,
+                   window: int):
+    """Every pair of the tile is visible: no mask is built."""
+    if not causal:
+        return True
+    inside = k0 + blk_k - 1 <= q0
+    if window:
+        inside = jnp.logical_and(inside, k0 > q0 + blk_q - 1 - window)
+    return inside
+
+
+def _wide_visit(tile, at, span, q0, k0, blk_q: int, blk_k: int,
+                causal: bool, window: int) -> None:
+    """``tile(masked)`` for the grid step's [Q block, K block] tile where
+    block ``at`` lies inside ``span`` (first, last), the blocks that hold
+    a visible pair: without a mask where every pair is visible, and not
+    at all outside the span."""
+    if not causal:
+        tile(False)
+        return
+    needed = jnp.logical_and(at >= span[0], at <= span[1])
+    interior = _wide_interior(q0, k0, blk_q, blk_k, causal, window)
+    pl.when(jnp.logical_and(needed, interior))(lambda: tile(False))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(interior)))(
+        lambda: tile(True))
+
+
+def _wide_visible(q0, k0, shape, q_axis: int, window: int):
+    qq = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    kk = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    seen = kk <= qq
+    if window:
+        seen = jnp.logical_and(seen, kk > qq - window)
+    return seen
+
+
+def _wide_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                     acc_scr, *, scale: float, causal: bool, window: int,
+                     blk_q: int, blk_k: int, nk: int):
+    iq, ik = pl.program_id(2), pl.program_id(3)
+    q0, k0 = iq * blk_q, ik * blk_k
+
+    @pl.when(ik == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, _MASKED)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def tile(masked: bool):
+        s = _dot(q_ref[0], k_ref[0], _NT) * scale
+        if masked:
+            s = jnp.where(_wide_visible(q0, k0, s.shape, 0, window), s,
+                          _MASKED)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + _dot(
+            p.astype(v_ref.dtype), v_ref[0], _NN)
+        m_scr[...] = m_new
+
+    _wide_visit(tile, ik, _wide_k_range(iq, blk_q, blk_k, nk, causal, window),
+                q0, k0, blk_q, blk_k, causal, window)
+
+    @pl.when(ik == nk - 1)
+    def _():
+        l = l_scr[...]
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0, 0, :] = (m_scr[...] + jnp.log(l))[:, 0]
+
+
+def _wide_bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q0, k0,
+                   masked: bool, scale: float, window: int):
+    """(P^T, dS^T) [Bk, Bq] of one tile from the saved log-sum-exp and
+    delta rows: the tile as [k, q], so that the row statistics broadcast
+    down the sublanes as they are stored."""
+    st = _dot(k_ref[0], q_ref[0], _NT) * scale
+    if masked:
+        st = jnp.where(_wide_visible(q0, k0, st.shape, 1, window), st,
+                       _MASKED)
+    pt = jnp.exp(st - lse_ref[0, 0])
+    dpt = _dot(v_ref[0], do_ref[0], _NT)
+    return pt, (pt * (dpt - delta_ref[0, 0])).astype(q_ref.dtype)
+
+
+def _wide_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                    acc_scr, *, scale: float, causal: bool, window: int,
+                    blk_q: int, blk_k: int, nk: int):
+    iq, ik = pl.program_id(2), pl.program_id(3)
+    q0, k0 = iq * blk_q, ik * blk_k
+
+    @pl.when(ik == 0)
+    def _():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def tile(masked: bool):
+        _, dst = _wide_bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                delta_ref, q0, k0, masked, scale, window)
+        acc_scr[...] += _dot(dst, k_ref[0], _TN)
+
+    _wide_visit(tile, ik, _wide_k_range(iq, blk_q, blk_k, nk, causal, window),
+                q0, k0, blk_q, blk_k, causal, window)
+
+    @pl.when(ik == nk - 1)
+    def _():
+        dq_ref[0] = (acc_scr[...] * scale).astype(dq_ref.dtype)
+
+
+def _wide_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
+                     causal: bool, window: int, blk_q: int, blk_k: int,
+                     nq: int, rep: int):
+    ik, r, iq = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    q0, k0 = iq * blk_q, ik * blk_k
+
+    @pl.when(jnp.logical_and(r == 0, iq == 0))
+    def _():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def tile(masked: bool):
+        pt, dst = _wide_bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                 delta_ref, q0, k0, masked, scale, window)
+        dv_scr[...] += _dot(pt.astype(do_ref.dtype), do_ref[0], _NN)
+        dk_scr[...] += _dot(dst, q_ref[0], _NN)
+
+    _wide_visit(tile, iq, _wide_q_range(ik, blk_q, blk_k, nq, causal, window),
+                q0, k0, blk_q, blk_k, causal, window)
+
+    @pl.when(jnp.logical_and(r == rep - 1, iq == nq - 1))
+    def _():
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _wide_key_block(blk_q: int, blk_k: int, nk: int, causal: bool,
+                    window: int):
+    """(Q block, K block) -> the K block a grid step's BlockSpec names:
+    the step's own inside the Q block's span, else the span's nearest,
+    so that a block no query sees is not fetched anew."""
+    def key_block(iq, ik):
+        first, last = _wide_k_range(iq, blk_q, blk_k, nk, causal, window)
+        return jnp.clip(ik, first, last) if causal else ik
+
+    return key_block
+
+
+def _wide_flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
+                    out_dtype, window: int, num_kv_heads):
+    """`_flash_fwd` at a head wider than 128 lanes: (o [B, S, H*D], lse
+    [B, H, 1, S]); k and v [B, S, Hk*D], a KV head's block fetched for
+    each of its query heads by the BlockSpec's ``h // rep``."""
+    b, s, hd = q.shape
+    d = hd // num_heads
+    rep = num_heads // (num_kv_heads or num_heads)
+    window = normalized_window(s, causal, window)
+    blk_q, blk_k = _wide_blocks(s)
+    nq, nk = s // blk_q, s // blk_k
+
+    key_block = _wide_key_block(blk_q, blk_k, nk, causal, window)
+    return pl.pallas_call(
+        functools.partial(_wide_fwd_kernel, scale=1.0 / float(d) ** 0.5,
+                          causal=causal, window=window, blk_q=blk_q,
+                          blk_k=blk_k, nk=nk),
+        name=KERNEL_NAME_PREFIX + "flash_fwd_wide",
+        out_shape=(jax.ShapeDtypeStruct((b, s, hd), out_dtype or q.dtype),
+                   jax.ShapeDtypeStruct((b, num_heads, 1, s), jnp.float32)),
+        grid=(b, num_heads, nq, nk),
+        in_specs=[pl.BlockSpec((1, blk_q, d), lambda b, h, i, j: (b, i, h))]
+        + [pl.BlockSpec((1, blk_k, d),
+                        lambda b, h, i, j: (b, key_block(i, j), h // rep))
+           ] * 2,
+        out_specs=(pl.BlockSpec((1, blk_q, d), lambda b, h, i, j: (b, i, h)),
+                   pl.BlockSpec((1, 1, 1, blk_q),
+                                lambda b, h, i, j: (b, h, 0, i))),
+        scratch_shapes=[pltpu.VMEM((blk_q, 1), jnp.float32),
+                        pltpu.VMEM((blk_q, 1), jnp.float32),
+                        pltpu.VMEM((blk_q, d), jnp.float32)],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=96 << 20),
+    )(q, k, v)
+
+
+def _wide_flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
+                    interpret: bool, window: int, num_kv_heads, glse=None):
+    """`_flash_bwd` at a head wider than 128 lanes, two kernels: dQ a Q
+    block over its K blocks, and dK, dV a K block over the Q blocks of
+    its group's heads (float32 sums [B, S, Hk*D] under grouped keys).
+    delta = rowsum(dO * O) is formed here, [B, H, 1, S] like lse, less
+    ``glse``, the upstream gradient on the logsumexp output (the ring's
+    streaming merge): dS = P * (dP - delta + g_lse)."""
+    b, s, hd = q.shape
+    d = hd // num_heads
+    hk = num_kv_heads or num_heads
+    rep = num_heads // hk
+    window = normalized_window(s, causal, window)
+    blk_q, blk_k = _wide_blocks(s)
+    nq, nk = s // blk_q, s // blk_k
+    scale = 1.0 / float(d) ** 0.5
+    do = do.astype(q.dtype)
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
+        b, s, num_heads, d), axis=-1).transpose(0, 2, 1)[:, :, None, :]
+    if glse is not None:
+        delta = delta - glse
+    params = dict(scale=scale, causal=causal, window=window, blk_q=blk_q,
+                  blk_k=blk_k)
+
+    key_block = _wide_key_block(blk_q, blk_k, nk, causal, window)
+
+    def query_block(ik, iq):
+        first, last = _wide_q_range(ik, blk_q, blk_k, nq, causal, window)
+        return jnp.clip(iq, first, last) if causal else iq
+
+    dq = pl.pallas_call(
+        functools.partial(_wide_dq_kernel, nk=nk, **params),
+        name=KERNEL_NAME_PREFIX + "flash_bwd_wide_dq",
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(b, num_heads, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, blk_q, d), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, blk_k, d),
+                         lambda b, h, i, j: (b, key_block(i, j), h // rep)),
+            pl.BlockSpec((1, blk_k, d),
+                         lambda b, h, i, j: (b, key_block(i, j), h // rep)),
+            pl.BlockSpec((1, blk_q, d), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, 1, 1, blk_q), lambda b, h, i, j: (b, h, 0, i)),
+            pl.BlockSpec((1, 1, 1, blk_q), lambda b, h, i, j: (b, h, 0, i)),
+        ],
+        out_specs=pl.BlockSpec((1, blk_q, d), lambda b, h, i, j: (b, i, h)),
+        scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=96 << 20),
+    )(q, k, v, do, lse, delta)
+
+    def of_query(b, g, j, r, i):
+        return b, query_block(j, i), g * rep + r
+
+    def row_of_query(b, g, j, r, i):
+        return b, g * rep + r, 0, query_block(j, i)
+
+    kv_dtype = jnp.float32 if rep > 1 else k.dtype
+    dk, dv = pl.pallas_call(
+        functools.partial(_wide_dkv_kernel, nq=nq, rep=rep, **params),
+        name=KERNEL_NAME_PREFIX + "flash_bwd_wide_dkv",
+        out_shape=(jax.ShapeDtypeStruct(k.shape, kv_dtype),
+                   jax.ShapeDtypeStruct(v.shape, kv_dtype)),
+        grid=(b, hk, nk, rep, nq),
+        in_specs=[
+            pl.BlockSpec((1, blk_q, d), of_query),
+            pl.BlockSpec((1, blk_k, d), lambda b, g, j, r, i: (b, j, g)),
+            pl.BlockSpec((1, blk_k, d), lambda b, g, j, r, i: (b, j, g)),
+            pl.BlockSpec((1, blk_q, d), of_query),
+            pl.BlockSpec((1, 1, 1, blk_q), row_of_query),
+            pl.BlockSpec((1, 1, 1, blk_q), row_of_query),
+        ],
+        out_specs=(pl.BlockSpec((1, blk_k, d), lambda b, g, j, r, i: (b, j, g)),
+                   pl.BlockSpec((1, blk_k, d), lambda b, g, j, r, i: (b, j, g))),
+        scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32)] * 2,
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary", "arbitrary"),
+            vmem_limit_bytes=96 << 20),
+    )(q, k, v, do, lse, delta)
+    return dq, dk, dv
 
 
 def _xla_attention(q, k, v, causal: bool, window: int = 0,
@@ -3569,6 +3920,331 @@ def selective_scan(x, dt, bm, cm, a, d):
     return _selective_scan(x, dt, bm, cm, a, d, pallas_mode() == "interpret")
 
 
+# ---------------------------------------------------------------------------
+# the gated delta rule in chunks of 128 positions (PR 58), EVERYTHING of a
+# chunk in VMEM: the decay matrix exp(G_t - G_s), K K^T, the inverse of
+# the unit lower-triangular I + A, W = T (K exp(G)), U = T V, the masked
+# Q K^T and, with the state S a [128, 128] float32 scratch carried from
+# chunk to chunk and from grid step to grid step,
+#     V' = U - W S;   O = (Q exp(G)) S + P V';   S <- exp(G_C) S + Kg^T V'
+# from q, k, v [128, 128] and the chunk's rows of the decays' running sum
+# and of beta; around it, in the same pass, the convolution's SiLU, the
+# heads' L2 norms of q and k (a row's sum over its 128 lanes) and the
+# gated head norm of the output.
+# Nothing of a chunk but its output and the state that entered it (kept
+# for the backward: a state a CHUNK, 1 / 128 of a state a position) goes
+# to HBM. The backward is the SAME function's `jax.vjp`,
+# taken inside the kernel a chunk at a time in reverse with dS carried;
+# the inverse has a backward of its own (-T^T dT T^T). A grid step is
+# DELTA_ROWS rows of one value head.
+#
+# v5e, bf16, 16,384 positions, 16 key and 32 value heads of 128, one
+# layer's rule alone, forward / forward and backward device ms
+# (`scripts/delta_lab.py`, my chip runs, PR 58). The batched form
+# (`ops.delta_rule._chunk_operands` in XLA: a dozen [4096, 128, 128]
+# float32 arrays written and read, the inverse 17.4 of it at `highest`,
+# 12.4 at one bfloat16 pass, 44.3 by `lax.linalg.triangular_solve`) with
+# the walk as a `lax.scan`: 25.8 / 82.5; the same with the walk as a
+# kernel pair that read those operands: 32.8 / 87.6 (the walk itself 1.7
+# + 3.6; the relayout to [B, S, H*128] cost what it saved). **As
+# shipped, one kernel each way: 12.7 / 33.6** (forward 12.7, backward
+# 16.4, the sums over a key head's two value heads and the relayouts of
+# g and beta 4.5).
+DELTA_CHUNK = 128     # rows a chunk: one MXU tile, as the heads' 128 lanes
+DELTA_ROWS = 1024     # rows a grid step, where S allows
+MAX_DELTA_WHOLE = 4096    # longest S taken as ONE block of rows
+_DELTA_COMPILER_PARAMS = dict(vmem_limit_bytes=64 << 20)
+_TN = (((0,), (0,)), ((), ()))  # a[c, m] . b[c, n] -> [m, n]
+
+
+def _delta_rows(s: int) -> int:
+    return DELTA_ROWS if s % DELTA_ROWS == 0 else s
+
+
+def delta_rule_shape_legal(seq_len: int, key_dim: int, value_dim: int,
+                           chunk: int) -> bool:
+    """The shapes `delta_rule_fused` takes: heads of 128 lanes (keys and
+    values), chunks of 128 rows, and a sequence of whole blocks of
+    DELTA_ROWS rows, or of whole chunks up to MAX_DELTA_WHOLE rows (one
+    block)."""
+    return (key_dim == value_dim == LANES and chunk == DELTA_CHUNK
+            and seq_len % DELTA_CHUNK == 0
+            and (seq_len % DELTA_ROWS == 0 or seq_len <= MAX_DELTA_WHOLE))
+
+
+def _chunk_rows(c):
+    return pl.ds(pl.multiple_of(c * DELTA_CHUNK, DELTA_CHUNK), DELTA_CHUNK)
+
+
+def _dot32(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _unit_lower_inverse_tile(a):
+    """(I + A)^-1 of one strictly lower-triangular [C, C] float32 tile by
+    log2(C) - 1 doublings, float32 products."""
+    c = a.shape[-1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    inv = jnp.where(rows == cols, 1.0, 0.0) - a
+    power, reach = a, 2
+    while reach < c:
+        power = _dot32(power, power, _NN)
+        inv = inv + _dot32(inv, power, _NN)
+        reach *= 2
+    return inv
+
+
+def _delta_heads(q, k, v):
+    """A chunk's q, k, v [C, 128] as the convolution left them -> after
+    its SiLU, q and k L2-normed a row (q to length Dk^-1/2, k to 1), in
+    the operands' dtype."""
+    f32, cd = jnp.float32, q.dtype
+
+    def unit(x, scale):
+        x = jax.nn.silu(x.astype(f32))
+        return (x * (jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True)
+                                   + 1e-6) * scale)).astype(cd)
+
+    return (unit(q, float(q.shape[1]) ** -0.5), unit(k, 1.0),
+            jax.nn.silu(v.astype(f32)).astype(cd))
+
+
+def _delta_decays(g_row):
+    """(row index, column index, G down a column, G_C down a column, the
+    decay matrix exp(G_t - G_s) on and under the diagonal) of one chunk
+    from its row [1, C] of running sums."""
+    c = g_row.shape[1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    g_col = _column(g_row, rows, cols)
+    g_end = jnp.sum(jnp.where(cols == c - 1, g_row, 0.0), axis=1,
+                    keepdims=True)
+    return rows, cols, g_col, g_end, jnp.exp(
+        jnp.where(rows >= cols, g_col - g_row, _MASKED))
+
+
+def _column(row, rows, cols):    # [1, C] -> [C, 1]
+    return jnp.sum(jnp.where(rows == cols, row, 0.0), axis=1, keepdims=True)
+
+
+def _delta_a(k, g_row, b_row):
+    """A = strict_tril(diag(beta) (K K^T) * exp(G_t - G_s)) of one chunk,
+    float32; k [C, Dk] normed."""
+    rows, cols, _, _, decay = _delta_decays(g_row)
+    return jnp.where(rows > cols, _dot(k, k, _NT) * decay
+                     * _column(b_row, rows, cols), 0.0)
+
+
+def _delta_walk(s, q, k, v, z, g_row, b_row, w_n, inv, *, eps: float):
+    """One chunk of one value head past the inverse: (y [C, Dv] float32,
+    the state after it). s [Dk, Dv] float32 the state before; q, k, v as
+    `_delta_heads` leaves them; z [C, Dv] the gate; g_row, b_row [1, C]
+    float32: the running sum of the log-decays inside the chunk, and
+    beta; w_n [1, Dv] float32 the head norm's scale; inv = (I + A)^-1
+    float32. The gated head norm is a row's mean over its 128 lanes, so
+    it is here. C = Dk (the columns serve both)."""
+    f32, cd = jnp.float32, q.dtype
+    rows, cols, g_col, g_end, decay = _delta_decays(g_row)
+    t = (inv * b_row).astype(cd)
+    into = jnp.exp(g_col)
+    w = _dot(t, (k.astype(f32) * into).astype(cd), _NN).astype(cd)
+    u = _dot(t, v, _NN).astype(cd)
+    p = jnp.where(rows >= cols, _dot(q, k, _NT) * decay, 0.0).astype(cd)
+    qg = (q.astype(f32) * into).astype(cd)
+    kg = (k.astype(f32) * jnp.exp(g_end - g_col)).astype(cd)
+    sb = s.astype(cd)
+    vp = u.astype(f32) - _dot(w, sb, _NN)
+    vb = vp.astype(cd)
+    o = _dot(qg, sb, _NN) + _dot(p, vb, _NN)
+    y = (o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
+         * w_n * jax.nn.silu(z.astype(f32)))
+    return y, jnp.exp(g_end) * s + _dot(kg, vb, _TN)
+
+
+def _delta_fused_fwd_kernel(q_ref, k_ref, v_ref, z_ref, g_ref, b_ref, w_ref,
+                            y_ref, kept_ref, inv_ref, state, *, chunks: int,
+                            eps: float):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    def chunk(c, carry):
+        rows = _chunk_rows(c)
+        s = state[...]
+        kept_ref[0, rows, :] = s
+        g_row, b_row = g_ref[0, 0, :, rows], b_ref[0, 0, :, rows]
+        q, k, v = _delta_heads(q_ref[0, rows, :], k_ref[0, rows, :],
+                               v_ref[0, rows, :])
+        inv = _unit_lower_inverse_tile(_delta_a(k, g_row, b_row))
+        inv_ref[0, rows, :] = inv
+        y, state[...] = _delta_walk(s, q, k, v, z_ref[0, rows, :], g_row,
+                                    b_row, w_ref[...], inv, eps=eps)
+        y_ref[0, rows, :] = y.astype(y_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, chunk, None)
+
+
+def _delta_fused_bwd_kernel(q_ref, k_ref, v_ref, z_ref, g_ref, b_ref, w_ref,
+                            kept_ref, inv_ref, dy_ref, dq_ref, dk_ref, dv_ref,
+                            dz_ref, dg_ref, db_ref, dw_ref, dstate, *,
+                            chunks: int, eps: float):
+    """A chunk's functions differentiated where they stand, the chunks in
+    reverse with dS carried. The inverse is not formed again: the forward
+    kept it, and its own backward is -T^T dT T^T."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    def chunk(j, carry):
+        c = chunks - 1 - j
+        rows = _chunk_rows(c)
+        g_row, b_row = g_ref[0, 0, :, rows], b_ref[0, 0, :, rows]
+        inv = inv_ref[0, rows, :]
+        (q, k, v), pull_heads = jax.vjp(
+            _delta_heads, q_ref[0, rows, :], k_ref[0, rows, :],
+            v_ref[0, rows, :])
+        _, pull_walk = jax.vjp(
+            functools.partial(_delta_walk, eps=eps), kept_ref[0, rows, :],
+            q, k, v, z_ref[0, rows, :], g_row, b_row, w_ref[...], inv)
+        ds, dq, dk, dv, dz, dg, db, dw, dinv = pull_walk(
+            (dy_ref[0, rows, :].astype(jnp.float32), dstate[...]))
+        _, pull_a = jax.vjp(_delta_a, k, g_row, b_row)
+        dk_a, dg_a, db_a = pull_a(-_dot32(_dot32(inv, dinv, _TN), inv, _NT))
+        dk = (dk.astype(jnp.float32) + dk_a.astype(jnp.float32)).astype(
+            dk.dtype)
+        dq, dk, dv = pull_heads((dq, dk, dv))
+        dstate[...] = ds
+        dq_ref[0, rows, :] = dq
+        dk_ref[0, rows, :] = dk
+        dv_ref[0, rows, :] = dv
+        dz_ref[0, rows, :] = dz
+        dg_ref[0, 0, :, rows] = dg + dg_a
+        db_ref[0, 0, :, rows] = db + db_a
+        dw_ref[0, 0] += dw
+        return carry
+
+    jax.lax.fori_loop(0, chunks, chunk, None)
+
+
+def _delta_fused_specs(s: int, key_heads: int, rep: int, reverse: bool):
+    """(rows, a value head's [rows, 128] block of its own array; q's, k's
+    and v's blocks of the ONE [B, S, (2 Hk + Hv) * 128] array the
+    convolution wrote; a head's [1, rows] row; the norm's scale; a value
+    head's [1, 128] sum). ``reverse``: the grid's last axis walks the
+    blocks of rows from the last to the first."""
+    rows = _delta_rows(s)
+    blocks = s // rows
+
+    def at(i):
+        return blocks - 1 - i if reverse else i
+
+    def lanes(of_head):
+        return pl.BlockSpec((1, rows, LANES),
+                            lambda b, h, i: (b, at(i), of_head(h)))
+
+    return (rows, lanes(lambda h: h),
+            (lanes(lambda h: h // rep), lanes(lambda h: key_heads + h // rep),
+             lanes(lambda h: 2 * key_heads + h)),
+            pl.BlockSpec((1, 1, 1, rows), lambda b, h, i: (b, h, 0, at(i))),
+            pl.BlockSpec((1, LANES), lambda b, h, i: (0, 0)),
+            pl.BlockSpec((1, 1, 1, LANES), lambda b, h, i: (b, h, 0, 0)))
+
+
+def _delta_fused_forward(qkv, z, g, beta, w_n, key_heads, eps, interpret):
+    b, s, width = z.shape
+    heads = width // LANES
+    rows, own, qkv_specs, row, scale, _ = _delta_fused_specs(
+        s, key_heads, heads // key_heads, False)
+    return pl.pallas_call(
+        functools.partial(_delta_fused_fwd_kernel,
+                          chunks=rows // DELTA_CHUNK, eps=eps),
+        name="delta_rule_fwd",
+        out_shape=(jax.ShapeDtypeStruct(z.shape, z.dtype),
+                   jax.ShapeDtypeStruct(z.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(z.shape, jnp.float32)),
+        grid=(b, heads, s // rows),
+        in_specs=[*qkv_specs, own, row, row, scale],
+        out_specs=(own, own, own),
+        scratch_shapes=[pltpu.VMEM((LANES, LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            **_DELTA_COMPILER_PARAMS),
+        interpret=interpret)(qkv, qkv, qkv, z, g, beta, w_n)
+
+
+def _delta_fused_backward(qkv, z, g, beta, w_n, kept, inv, dy, key_heads,
+                          eps, interpret):
+    b, s, width = z.shape
+    heads = width // LANES
+    rep = heads // key_heads
+    rows, own, qkv_specs, row, scale, total = _delta_fused_specs(
+        s, key_heads, rep, True)
+    like = jax.ShapeDtypeStruct(z.shape, z.dtype)
+    a_row = jax.ShapeDtypeStruct(g.shape, jnp.float32)
+    dq, dk, dv, dz, dg, db, dw = pl.pallas_call(
+        functools.partial(_delta_fused_bwd_kernel,
+                          chunks=rows // DELTA_CHUNK, eps=eps),
+        name="delta_rule_bwd",
+        out_shape=(like, like, like, like, a_row, a_row,
+                   jax.ShapeDtypeStruct((b, heads, 1, LANES), jnp.float32)),
+        grid=(b, heads, s // rows),
+        in_specs=[*qkv_specs, own, row, row, scale, own, own, own],
+        out_specs=(own, own, own, own, row, row, total),
+        scratch_shapes=[pltpu.VMEM((LANES, LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            **_DELTA_COMPILER_PARAMS),
+        interpret=interpret)(qkv, qkv, qkv, z, g, beta, w_n, kept, inv, dy)
+    if rep > 1:     # a key head's q and k served `rep` value heads
+        dq, dk = (jnp.sum(t.astype(jnp.float32).reshape(
+            b, s, key_heads, rep, LANES), axis=3).reshape(
+                b, s, key_heads * LANES).astype(qkv.dtype) for t in (dq, dk))
+    return (jnp.concatenate([dq, dk, dv], axis=-1), dz, dg, db,
+            jnp.sum(dw, axis=(0, 1)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _delta_rule_fused(qkv, z, g, beta, w_n, key_heads, eps, interpret):
+    return _delta_fused_forward(qkv, z, g, beta, w_n, key_heads, eps,
+                                interpret)[0]
+
+
+def _delta_rule_fused_fwd(qkv, z, g, beta, w_n, key_heads, eps, interpret):
+    y, kept, inv = _delta_fused_forward(qkv, z, g, beta, w_n, key_heads, eps,
+                                        interpret)
+    return y, (qkv, z, g, beta, w_n, kept, inv)
+
+
+def _delta_rule_fused_bwd(key_heads, eps, interpret, res, dy):
+    return _delta_fused_backward(*res, dy, key_heads, eps, interpret)
+
+
+_delta_rule_fused.defvjp(_delta_rule_fused_fwd, _delta_rule_fused_bwd)
+
+
+def delta_rule_fused(qkv, z, g, beta, w_n, key_heads: int, eps: float):
+    """y [B, S, Hv*128]: the heads' L2 norms, the gated delta rule in
+    chunks of 128 and the gated head norm, (o * rsqrt(mean(o^2) + eps) *
+    w_n) * silu(z). qkv [B, S, (2 Hk + Hv) * 128]: q, k at the key heads
+    and v at the value heads as the convolution left them (its SiLU is
+    taken here), side by side along the lanes (the kernels' BlockSpecs pick a head's
+    128 lanes of each: nothing is sliced or laid out again in HBM); z
+    [B, S, Hv*128], in qkv's dtype, the products' operands'; g, beta
+    [B, Hv, 1, S] float32: the RUNNING SUM of the log-decays inside each
+    chunk of 128 positions, and beta; w_n [1, 128] float32. ONE kernel
+    forward (`delta_rule_fwd`, which also keeps the state that entered
+    every chunk and the chunk's inverse, [B, S, Hv*128] float32 each)
+    and ONE backward (`delta_rule_bwd`). Caller checks `pallas_mode` and
+    `delta_rule_shape_legal`; `ops.delta_rule` has the same in
+    `jax.numpy`."""
+    return _delta_rule_fused(qkv, z, g, beta, w_n, key_heads, eps,
+                             pallas_mode() == "interpret")
+
+
 def pallas_mode() -> str:
     """'tpu' (compile), 'interpret' (CPU emulation for tests), or 'off'."""
     env = os.environ.get("FLEXFLOW_TPU_PALLAS", "auto")
@@ -3601,13 +4277,19 @@ def flash_shape_legal(seq_len: int, head_dim: int, num_heads: int,
     are ``head_dim`` lanes and ``rope_dim`` more, its value ``head_dim``)
     a head is one block of 128 lanes and the heads' rotated parts tile
     128-lane blocks among themselves: 32 heads of 128 + 64 pass, a
-    192-wide head as ONE width does not. The native ``kernel_gate``
+    192-wide head as ONE width does not. A head wider than 128 lanes
+    is 256 exactly, two lane blocks (PR 58: 16 heads of 256 pass, at any
+    number of heads; 192 or 384 do not); the kernels for it take a
+    causal or plain mask and a window and nothing else, which the
+    attention op's `route` holds. The native ``kernel_gate``
     (native/ffs_strategy.hpp) admits the same shapes."""
     if num_heads <= 0:
         return False
     if rope_dim and (head_dim != LANES or rope_dim % 8 or LANES % rope_dim
                      or num_heads % (LANES // rope_dim)):
         return False
+    if head_dim > LANES and head_dim != 2 * LANES:
+        return False    # past one lane block a head is exactly two
     hpb = _heads_per_block(num_heads, head_dim)
     return (seq_len % BLK_Q == 0 and head_dim % 8 == 0
             and seq_len <= MAX_FLASH_SEQ and head_dim <= MAX_FLASH_HEAD_DIM
@@ -3663,7 +4345,8 @@ def grouped_kv_shape_legal(num_heads: int, num_kv_heads: int,
     **46.40**, kernels 16.68 / 22.56, fusions 5.71, `rotary_lanes`
     0.42 + 0.82, everything else 0.2: 6.74 ms an op under the shipped
     form, of which the view forms 5.85 and the repeat 0.89."""
-    hpb = LANES // head_dim if head_dim in (LANES, LANES // 2) else 0
+    hpb = (1 if head_dim == 2 * LANES   # a column block of its own (PR 58)
+           else LANES // head_dim if head_dim in (LANES, LANES // 2) else 0)
     return (0 < num_kv_heads < num_heads and num_heads % num_kv_heads == 0
             and hpb > 0 and (num_heads // num_kv_heads) % hpb == 0
             and num_kv_heads % hpb == 0)

@@ -186,8 +186,9 @@ def serialize_graph(nodes, final_guid: Optional[int] = None,
             attrs["pinned"] = 1
         if op.exports:
             attrs["exports"] = int(op.exports)
-        if getattr(op, "differential", False) or getattr(
-                op, "sparse_index", None):
+        if (getattr(op, "differential", False)
+                or getattr(op, "sparse_index", None)
+                or op.op_type == OperatorType.DELTA_MIXER):
             # its lambda (the indexer's loss and the counts of pairs)
             # leaves the step beside its output
             attrs["side_counters"] = 1

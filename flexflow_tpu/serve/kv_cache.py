@@ -113,6 +113,18 @@ def init_kv_cache(ff, batch: Optional[int] = None,
                 f"the indexer (whose key this cache does not hold) and "
                 f"attend over the kept ones; the selection over a cache "
                 f"is not built")
+        if node.op.op_type == OperatorType.DELTA_MIXER:
+            raise NotImplementedError(
+                f"'{node.op.name}' is a gated delta-rule mixer: a new "
+                f"token needs the value heads' [Dk, Dv] states and the "
+                f"convolution's last K - 1 rows, which this cache (one "
+                f"{{k, v}} pair a causal attention op) does not hold; "
+                f"serving a linear-attention layer's state is not built")
+        if getattr(node.op, "lane_gate", False):
+            raise NotImplementedError(
+                f"'{node.op.name}' gates its output a lane from the query "
+                f"projection: `decode_forward` does not apply that gate; "
+                f"serving the family is not built")
         if node.op.op_type == OperatorType.SHORT_CONV:
             raise NotImplementedError(
                 f"'{node.op.name}' is a short convolution: a new token "

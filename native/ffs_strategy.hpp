@@ -216,7 +216,10 @@ inline std::string kernel_gate(const Node& n, const std::string& impl,
     // MAX_FLASH_HEAD_DIM in flexflow_tpu/ops/pallas_kernels.py; past
     // them the executor runs einsum, so pricing flash would misrank
     if (seq > 16384) return "seq_exceeds_flash_vmem_budget_16384";
-    if (head_dim > 128) return "head_dim_exceeds_flash_vmem_budget_128";
+    // past one 128-lane block a head is exactly two (the wide-head
+    // kernels, a tile a grid step)
+    if (head_dim > 128 && head_dim != 256)
+      return "head_dim_exceeds_flash_vmem_budget_128";
     // latent attention's two-part score (attr `rope_head_dim`: a head's
     // query and key are `head_dim` lanes and that many more, rotated;
     // flash_shape_legal's `rope_dim`): a head is one block of 128 lanes,

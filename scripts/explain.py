@@ -343,6 +343,18 @@ def sparse_attention_rows(ff):
             if getattr(node.op, "sparse_index", None)]
 
 
+def delta_mixer_rows(ff):
+    """[(op, key heads, value heads, head size, chunk, the rule's share
+    of the op's FLOPs, bytes the op saves beside its output)] of the
+    gated delta-rule mixers."""
+    return [(node.op.name, node.op.key_heads, node.op.value_heads,
+             node.op.value_dim, node.op.chunk_size,
+             node.op.rule_flops() / node.op.flops(),
+             node.op.interior_bytes())
+            for node in ff.executor.nodes
+            if node.op.op_type.name == "DELTA_MIXER"]
+
+
 def to_markdown(model, ff, trace, sim_resp, rows, total_ops, feasible,
                 reasons, path_rows, path_total, merged_path,
                 disagreements=None, n_compared=0, kernel_rows=None,
@@ -395,6 +407,21 @@ def to_markdown(model, ff, trace, sim_resp, rows, total_ops, feasible,
             "|---|---|---|---|---|",
             *(f"| {name} | {heads} | {size} | {topk} | {_fmt_bytes(saved)} |"
               for name, heads, size, topk, saved in sparse), ""]
+    delta = delta_mixer_rows(ff)
+    if delta:
+        at = lines.index("## Mesh candidates")
+        lines[at:at] = [
+            "## Gated delta-rule mixers", "",
+            f"{len(delta)} ops run the chunked gated delta rule "
+            f"(`ops/delta_rule.py`): priced by their FLOPs (the "
+            f"projections and the rule's products a chunk) and the "
+            f"activations they keep; no remat twin is taken (the chunks' "
+            f"count and the decays leave on a side channel):", "",
+            "| op | key heads | value heads | head size | chunk | "
+            "rule's FLOPs | saved |", "|---|---|---|---|---|---|---|",
+            *(f"| {name} | {hk} | {hv} | {d} | {c} | {share:.1%} | "
+              f"{_fmt_bytes(saved)} |"
+              for name, hk, hv, d, c, share, saved in delta), ""]
     for m in feasible[:12]:
         pl = m.get("pipeline_candidates")
         note = m.get("reason", "")

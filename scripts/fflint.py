@@ -45,7 +45,7 @@ if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu") \
 
 ZOO = ["mlp", "alexnet", "resnet", "resnext", "inception", "dlrm", "xdl",
        "candle_uno", "moe", "moe_encoder", "transformer", "llama", "lfm2",
-       "ouro", "phi4flash", "keye"]
+       "ouro", "phi4flash", "keye", "qwen3_next"]
 
 
 def build_model(name: str, ff_config):
@@ -163,6 +163,21 @@ def build_model(name: str, ff_config):
                            indexer_num_kv_heads=1, topk=6),
             mrope_section=(2, 3, 3), batch_size=8, seq_length=16),
             ff_config), "cat"
+    if name == "qwen3_next":
+        # gated delta-rule mixers in three layers of four, then gated
+        # attention whose gate comes a lane out of the query projection;
+        # zero-centred norms; softmax top-k experts with a gated shared
+        # expert (the delta mixer's chunk counts and decays leave on the
+        # executor's side channel: no remat twin)
+        from flexflow_tpu.models import DecoderConfig, create_decoder
+        return create_decoder(DecoderConfig(
+            layer_types=["linear_attention"] * 3 + ["full_attention"],
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            qk_layernorm=True, attn_output_gate=True,
+            zero_centered_norms=True, partial_rotary_factor=0.25,
+            router_scoring="softmax", shared_expert_gate=True,
+            experts_held=4, delta_chunk_size=8, batch_size=8,
+            seq_length=16), ff_config), "cat"
     raise SystemExit(f"unknown --model {name!r} (zoo: {', '.join(ZOO)})")
 
 

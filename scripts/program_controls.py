@@ -16,6 +16,13 @@ cell states it and has to come out correct; every control has to come
 out NOT correct, by the comparison or by a row of the family's
 `extra_checks` (named in `failed_by` alike). One JSON line a row; the
 last line sums up. Not part of a benchmark run.
+
+A control named `reference_*` (PR 58) is of the other kind, for a
+mechanism that no published key switches: it alters the REFERENCE (the
+family's `reference_kw` passes the key on to its reference's forward) and
+the program as the cell states it is judged against that reference, its
+predictions and first loss (no Adam steps: one forward of the reference
+a control). It too has to come out NOT correct.
 """
 
 import argparse
@@ -55,9 +62,10 @@ def main():
     batch = stated["batch"]
     xs, y = family.make_data(dict(stated, steps_per_epoch=1), args.seed)
     weights = jax.device_get(family.make_weights(stated, args.seed))
+    given = [(c, {c.split("=", 1)[0]: json.loads(c.split("=", 1)[1])})
+             for c in args.control]
     controls = [("as_stated", {})] + [
-        (c, {c.split("=", 1)[0]: json.loads(c.split("=", 1)[1])})
-        for c in args.control]
+        c for c in given if not c[0].startswith("reference_")]
     systems = []
     for name, override in controls:
         s = family.sizes(config, traffic, dict(tiny or {}, **override))
@@ -82,6 +90,21 @@ def main():
                               if not r["ok"]] + faults)
         hs.emit(**row)
         rows.append(row)
+    for name, override in given:
+        if not name.startswith("reference_"):
+            continue
+        altered = hs.reference_side(
+            family, weights, family.sizes(config, traffic,
+                                          dict(tiny or {}, **override)),
+            traffic, config, xs, y, batch, steps=1)
+        judged = hs.compare(systems[0][1], altered, family.TOLERANCES)
+        row = dict(control=name, seed=args.seed,
+                   correct=all(r["ok"] for r in judged),
+                   **{r["name"]: r["value"] for r in judged},
+                   failed_by=[r["name"] for r in judged if not r["ok"]])
+        hs.emit(**row)
+        rows.append(row)
+    family.sizes(config, traffic, tiny)     # what the family keeps of them
     print(json.dumps(dict(
         summary=args.workload, seed=args.seed,
         limits=family.TOLERANCES, as_stated_correct=rows[0]["correct"],

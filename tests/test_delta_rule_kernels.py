@@ -1,0 +1,269 @@
+"""The gated delta rule's forms against each other (PR 58): the chunked
+`jax.numpy` form against the recurrence a position at a length no chunk
+divides, at C and 2 C, with a head that never forgets and one that
+always does; the reference's own recurrence and its control; the
+triangular inverse and its backward. And the kernels of PR 58 in
+`interpret` mode against their `jax.numpy` forms: the delta rule's kernel pair (`pallas_kernels.delta_rule_fused`,
+forward and backward, value heads twice the key heads, over one block of
+rows and over two) against the `jax.numpy` chunked form and the
+recurrence a position; the flash kernels at a head of 256 lanes, 16 : 2
+style groups, causal with and without a window and plain, against the
+einsum path; the attention op with 64 rotated lanes of 256 and the gate a
+lane through them; the rules that say where they run."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks.references import qwen3_next as ref  # noqa: E402
+from flexflow_tpu.ffconst import OperatorType  # noqa: E402
+from flexflow_tpu.layer import Layer  # noqa: E402
+from flexflow_tpu.ops import delta_rule as dr  # noqa: E402
+from flexflow_tpu.ops import pallas_kernels as pk  # noqa: E402
+from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
+
+HIGHEST = jax.default_matmul_precision("highest")
+
+
+@pytest.fixture
+def interpret(monkeypatch):
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
+
+
+def small_rule_inputs(length, hk=2, hv=4, d=8, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (2, length, hk, d))
+    k = jax.random.normal(ks[1], (2, length, hk, d))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (2, length, hv, d))
+    g = -jnp.exp(jax.random.uniform(ks[3], (2, length, hv), minval=-6.0,
+                                    maxval=1.0))
+    # a head that remembers everything and one that forgets at once
+    g = g.at[:, :, 0].set(-1e-5).at[:, :, 1].set(-30.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (2, length, hv)))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("chunk", [8, 16])
+def test_chunked_rule_matches_the_recurrence_a_position(chunk):
+    ins = small_rule_inputs(37)       # no chunk divides it
+    weight = jnp.cos(jnp.arange(8.0))
+
+    def grads(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a) * weight), argnums=(0, 1, 2, 3, 4)))
+
+    with HIGHEST:
+        want = jax.jit(dr.delta_rule_stepwise)(*ins)
+        got = jax.jit(lambda *a: dr.delta_rule_chunked(*a, chunk))(*ins)
+        _, dwant = grads(dr.delta_rule_stepwise)(*ins)
+        _, dgot = grads(lambda *a: dr.delta_rule_chunked(*a, chunk))(*ins)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    # the forgetful head's output is the present position's alone
+    assert float(jnp.max(jnp.abs(want[:, :, 1]))) > 0
+    for name, a, b in zip("q k v g beta".split(), dgot, dwant):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(b) / scale, atol=2e-5,
+                                   err_msg=name)
+
+
+def test_the_reference_runs_the_same_recurrence_and_its_two_controls():
+    q, k, v, g, beta = small_rule_inputs(21)
+    rep = lambda t: jnp.repeat(t, 2, axis=2)    # noqa: E731
+    with HIGHEST:
+        want = dr.delta_rule_stepwise(q, k, v, g, beta)
+        got = jax.jit(ref.delta_rule)(rep(q), rep(k), v, g, beta)
+        plain = jax.jit(lambda *a: ref.delta_rule(*a, correction=False))(
+            rep(q), rep(k), v, g, beta)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    # without the correction the state only accumulates: another output
+    assert float(jnp.max(jnp.abs(plain - want))) > 1e-2
+
+
+def test_unit_lower_inverse_and_its_own_backward():
+    a = jnp.tril(0.3 * jax.random.normal(jax.random.PRNGKey(2), (3, 16, 16)),
+                 -1)
+    with HIGHEST:
+        t = dr.unit_lower_inverse(a)
+        np.testing.assert_allclose(t @ (jnp.eye(16) + a),
+                                   jnp.broadcast_to(jnp.eye(16), a.shape),
+                                   atol=1e-5)
+        w = jax.random.normal(jax.random.PRNGKey(3), a.shape)
+        got = jax.grad(lambda a: jnp.sum(dr.unit_lower_inverse(a) * w))(a)
+        want = jax.grad(lambda a: jnp.sum(
+            jnp.linalg.inv(jnp.eye(16) + a) * w))(a)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+def core_inputs(length, hk=1, hv=2, d=128, seed=0):
+    """(qkv, z, g, beta, the norm's scale) as the op hands them over."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    qkv = jax.random.normal(ks[0], (1, length, (2 * hk + hv) * d))
+    z = jax.random.normal(ks[1], (1, length, hv * d))
+    g = -jnp.exp(jax.random.uniform(ks[3], (1, length, hv), minval=-6.0,
+                                    maxval=1.0))
+    g = g.at[:, :, 0].set(-1e-3)        # a head that remembers
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, length, hv)))
+    scale = 1.0 + 0.1 * jax.random.normal(ks[5], (d,))
+    return qkv, z, g, beta, scale
+
+
+@pytest.mark.parametrize("length", [384, 2048])
+def test_rule_kernels_match_the_scan_forward_and_backward(interpret, length):
+    """One block of three chunks (the whole sequence), and two blocks of
+    eight with the state and its gradient carried between them: the
+    SiLU, the heads' L2 norms, the rule and the gated head norm as ONE kernel each
+    way against the `jax.numpy` form, and that against the recurrence a
+    position."""
+    ins = core_inputs(length)
+    weight = jnp.cos(jnp.arange(256.0))
+
+    def both(kernel):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(dr.delta_rule_core(
+                *a, 1, 128, 1e-6, jnp.float32, kernel) * weight),
+            argnums=(0, 1, 2, 3, 4)))
+
+    with HIGHEST:
+        (want, dwant), (got, dgot) = both(False)(*ins), both(True)(*ins)
+        if length == 384:
+            qkv, z, g, beta, scale = ins
+            f = jax.nn.silu(qkv).reshape(1, length, 4, 128)
+            q, k, v = f[:, :, :1], f[:, :, 1:2], f[:, :, 2:]
+            q = q / jnp.sqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+                * 128 ** -0.5
+            k = k / jnp.sqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+            step = dr.heads_rms_norm_gated(
+                jax.jit(dr.delta_rule_stepwise)(q, k, v, g, beta),
+                z.reshape(1, length, 2, 128), scale, 1e-6)
+            out = jax.jit(lambda *a: dr.delta_rule_core(
+                *a, 1, 128, 1e-6, jnp.float32, True))(*ins)
+            np.testing.assert_allclose(out.reshape(step.shape), step,
+                                       rtol=1e-3, atol=5e-5)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for name, a, b in zip("qkv z g beta scale".split(), dgot, dwant):
+        scale_ = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a) / scale_,
+                                   np.asarray(b) / scale_, atol=2e-5,
+                                   err_msg=name)
+
+
+def test_where_the_walk_runs_as_kernels(interpret, monkeypatch):
+    legal = pk.delta_rule_shape_legal
+    assert legal(16384, 128, 128, 128) and legal(384, 128, 128, 128)
+    assert legal(4096, 128, 128, 128) and not legal(4096 + 128, 128, 128, 128)
+    assert not legal(16384, 64, 128, 128) and not legal(16384, 128, 128, 64)
+    assert not legal(200, 128, 128, 128)
+    layer = Layer(OperatorType.DELTA_MIXER, "op", [])
+    layer.properties.update(num_key_heads=1, num_value_heads=2,
+                            key_head_dim=128, value_head_dim=128)
+    op = OpRegistry.create(layer, [(1, 256, 64)])
+    assert op.walks_by_kernel(None)
+    assert not op.walks_by_kernel(None, seq=200)
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
+    assert not op.walks_by_kernel(None)
+
+
+def heads(t, n):
+    return pk.split_heads(t, n)[0]
+
+
+def einsum_attention(q, k, v, h, hk, causal, window):
+    q, k, v = heads(q, h), heads(k, hk), heads(v, hk)
+    k, v = (jnp.repeat(t, h // hk, axis=0) for t in (k, v))
+    o = pk._xla_attention(q, k, v, causal, window)
+    return pk.merge_heads(o[None])
+
+
+@pytest.mark.parametrize("seq,causal,window", [
+    (2048, True, 0),        # blocks of 512 x 1024: interior and edge tiles
+    (1536, True, 300),      # a window that ends inside a block
+    (1024, False, 0)])
+def test_flash_at_a_head_of_256_matches_the_einsum_path(interpret, seq,
+                                                        causal, window):
+    h, hk, d = 4, 2, 256
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    q = jax.random.normal(ks[0], (1, seq, h * d))
+    k = jax.random.normal(ks[1], (1, seq, hk * d))
+    v = jax.random.normal(ks[2], (1, seq, hk * d))
+    weight = jax.random.normal(ks[3], (1, seq, h * d))
+    assert pk.flash_attention_available(seq, d, h)
+    assert pk.grouped_kv_shape_legal(h, hk, d)
+    assert pk.grouped_kv_shape_legal(16, 2, 256)
+
+    def both(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a) * weight), argnums=(0, 1, 2)))
+
+    with HIGHEST:
+        want, dwant = both(lambda *a: einsum_attention(
+            *a, h, hk, causal, window))(q, k, v)
+        got, dgot = both(lambda *a: pk.flash_attention(
+            *a, h, causal=causal, window=window, num_kv_heads=hk))(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for name, a, b in zip("qkv", dgot, dwant):
+        assert a.dtype == jnp.float32
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5, err_msg=name)
+    visited, total, masked = pk.wide_kv_blocks(seq, causal, window)
+    assert 0 < visited <= total and masked <= visited
+    if causal and not window:
+        assert (visited, total, masked) == (6, 8, 4)
+
+
+def test_attention_op_at_heads_of_256_runs_the_wide_kernels(interpret):
+    """16 : 2 style groups at a head of 256, 64 rotated lanes, the heads'
+    zero-centred norm and the gate a lane: the op through the kernels
+    against itself on the einsum core."""
+    props = dict(embed_dim=64, num_heads=4, num_kv_heads=2, head_dim=256,
+                 bias=False, causal=True, rope=True, rope_theta=1e7,
+                 partial_rotary_factor=0.25, qk_norm=True,
+                 qk_norm_zero_centered=True, lane_gate=True)
+
+    def op_of(**more):
+        layer = Layer(OperatorType.MULTIHEAD_ATTENTION, "attn", [])
+        layer.properties.update(props, **more)
+        return OpRegistry.create(layer, [(1, 1024, 64)] * 3)
+
+    op, plain = op_of(), op_of(kernel_impl="einsum")
+    route = op.route({}, True)
+    assert (route.core, route.wide_head, route.grouped_kv) == (
+        "flash", True, True)
+    assert not route.rotary_in_lanes and not route.super_block
+    assert route.kv_blocks == pk.wide_kv_blocks(1024, True, 0)
+    assert plain.route({}, True).core == "einsum"
+    assert op.rotary_dim == 64
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 1024, 64))
+    p = op.init_params(jax.random.PRNGKey(3))
+    p = dict(p, q_norm=p["q_norm"] + 1.5, k_norm=p["k_norm"] + 1.5)
+    ctx = OpContext(training=True, compute_dtype=jnp.float32)
+
+    def loss(o):
+        return jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(
+            o.forward(p, [x, x, x], ctx)[0] ** 2), argnums=(0, 1)))
+
+    with HIGHEST:
+        (got, dgot), (want, dwant) = loss(op)(p, x), loss(plain)(p, x)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(dgot), jax.tree.leaves(dwant)):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(b) / scale, atol=2e-5)
+    assert op.traced_gauges()["executor.flash_wide_head_ops"] == 1
+    assert op.traced_gauges()["executor.flash_grouped_kv_ops"] == 1
+    # a mask the wide kernels do not take keeps the einsum core
+    layer = Layer(OperatorType.MULTIHEAD_ATTENTION, "bd", [])
+    layer.properties.update(embed_dim=64, num_heads=4, head_dim=256,
+                            bias=False, block_diffusion=(512, 4))
+    masked = OpRegistry.create(layer, [(1, 1024, 64)] * 3)
+    assert masked.route({}, True).core == "einsum"
+    assert masked.route({}, True).blocked == "shape"

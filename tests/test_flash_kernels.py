@@ -144,10 +144,13 @@ PAIRINGS = {
     (16, 64): 2, (4, 128): 1, (2, 64): 2, (16, 8): 16, (32, 16): 8,
     # the whole row is one block: a single head, or H*D <= 128
     (1, 64): 1, (4, 8): 4, (12, 8): 12, (1, 96): 1,
+    # a head of two lane blocks (PR 58): a column block of its own, the
+    # kernels that take one tile a grid step
+    (2, 256): 1,
     # an odd head out, lanes that do not fill, a head_dim off the
-    # sublanes or past the VMEM budget
+    # sublanes, past one lane block and not two exactly
     (3, 64): None, (4, 96): None, (24, 8): None, (6, 48): None,
-    (2, 60): None, (2, 256): None,
+    (2, 60): None, (2, 192): None, (2, 384): None,
 }
 
 

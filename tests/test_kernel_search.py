@@ -152,6 +152,33 @@ class TestNativeEnumeration:
         assert rej.get("flash") == (
             None if legal else "heads_do_not_tile_128_lanes")
 
+    @pytest.mark.parametrize("heads,kv_heads,head_dim,reason", [
+        # a head of two lane blocks (PR 58), the qwen3_next cell's 16 : 2
+        (16, 2, 256, None), (1, 1, 256, None),
+        # past one lane block a head is 256 exactly
+        (4, 4, 192, "head_dim_exceeds_flash_vmem_budget_128"),
+        (4, 4, 384, "head_dim_exceeds_flash_vmem_budget_128")])
+    def test_a_head_of_two_lane_blocks_passes_both_gates(self, heads,
+                                                         kv_heads, head_dim,
+                                                         reason):
+        """`flash_shape_legal`, the native `kernel_gate` and the grouped
+        K / V rule say the same shapes at heads wider than 128 lanes."""
+        from flexflow_tpu.ops.pallas_kernels import (flash_shape_legal,
+                                                     grouped_kv_shape_legal)
+        native = _native()
+        resp = native.native_optimize(_req(_attn_linear_nodes(
+            heads=heads, head_dim=head_dim)))
+        ops = {o["name"]: o for o in resp["search_trace"]["ops"]}
+        rej = {r["impl"]: r["reason"]
+               for r in ops["attn"].get("kernel_rejections") or []}
+        twins = any("_k:flash" in c["choice"]
+                    for c in ops["attn"]["candidates"])
+        assert flash_shape_legal(16384, head_dim, heads) == (reason is None)
+        assert twins == (reason is None)
+        assert rej.get("flash") == reason
+        if reason is None and kv_heads < heads:
+            assert grouped_kv_shape_legal(heads, kv_heads, head_dim)
+
     def test_dropout_attention_rejects_flash(self):
         """Attention-prob dropout has no flash lowering: the training
         gate rejects the twin with a named reason instead of pricing a

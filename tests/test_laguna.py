@@ -580,7 +580,7 @@ def test_create_decoder_builds_the_cut_from_the_per_layer_lists(tiny):
     names = [layer.name for layer in by_letters.layers]
     assert {"b0_attn", "b2_mixer"} <= set(names)
     with pytest.raises(ValueError, match="layer_types"):
-        create_decoder(DecoderConfig(layer_types=["linear_attention"]))
+        create_decoder(DecoderConfig(layer_types=["hyena"]))
     with pytest.raises(ValueError, match="mlp_layer_types"):
         create_decoder(DecoderConfig(layer_types=["full_attention"],
                                      mlp_layer_types=["conv"]))

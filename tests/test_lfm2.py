@@ -260,7 +260,7 @@ def test_create_decoder_builds_the_cut_from_the_public_keys(tiny):
     names = [layer.name for layer in by_letters.layers]
     assert {"b0_conv", "b0_gate_up_proj", "b1_attn", "b2_mixer"} <= set(names)
     with pytest.raises(ValueError, match="conv.*full_attention"):
-        create_decoder(DecoderConfig(layer_types=["linear_attention"]))
+        create_decoder(DecoderConfig(layer_types=["hyena"]))
 
 
 def test_model_against_the_reference_logits_and_three_losses(tiny):

@@ -179,6 +179,7 @@ class TestFlashKernels:
         assert "tpu_custom_call_flash_fwd" in hlo
         assert "tpu_custom_call_flash_bwd" in hlo
 
+    @pytest.mark.parametrize("head_dim", [128, pk.MAX_FLASH_HEAD_DIM])
     @pytest.mark.parametrize("grads,dtype", [
         # the ring variant (f32 output, lse gradient) needs the most VMEM
         (_flash_lse_grads, jnp.bfloat16),
@@ -187,12 +188,16 @@ class TestFlashKernels:
         pytest.param(_flash_lse_grads, jnp.float32,
                      marks=pytest.mark.slow),
     ])
-    def test_longest_admitted_shape_compiles(self, topo, grads, dtype):
-        """Forward and K-blocked backward at the gate's upper bounds."""
+    def test_longest_admitted_shape_compiles(self, topo, grads, dtype,
+                                             head_dim):
+        """Forward and K-blocked backward at the gate's upper bounds: a
+        head of one lane block (two kernels), and of two (PR 58: the
+        forward, dQ, and dK with dV)."""
         q = jax.ShapeDtypeStruct(
-            (1, pk.MAX_FLASH_SEQ, pk.MAX_FLASH_HEAD_DIM), dtype,
+            (1, pk.MAX_FLASH_SEQ, head_dim), dtype,
             sharding=SingleDeviceSharding(topo.devices[0]))
-        assert pallas_kernel_count(_compile(grads(1), q, q, q)) == 2
+        assert pallas_kernel_count(_compile(grads(1), q, q, q)) == (
+            2 if head_dim == 128 else 3)
 
     @pytest.mark.parametrize("seq", [8192, pk.MAX_FLASH_SEQ])
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
@@ -373,6 +378,9 @@ class TestFlashKernels:
         assert ok(pk.MAX_FLASH_SEQ, pk.MAX_FLASH_HEAD_DIM, 1)
         assert not ok(pk.MAX_FLASH_SEQ + pk.BLK_Q, 64, 2)
         assert not ok(512, pk.MAX_FLASH_HEAD_DIM + 8, 1)
+        # past one lane block a head is two exactly (PR 58)
+        assert pk.MAX_FLASH_HEAD_DIM == 256 and ok(pk.MAX_FLASH_SEQ, 256, 16)
+        assert not ok(512, 192, 2) and not ok(512, 136, 1)
         # both cells' shapes, and heads that do not tile the lanes
         assert ok(512, 64, 16) and ok(8192, 128, 4)
         assert not ok(512, 64, 3) and not ok(512, 96, 4)
@@ -632,6 +640,70 @@ class TestHybridDecoderKernels:
             assert op.core_heads == (20, 10, 128)
             # two maps: two forward and two backward kernels
             assert pallas_kernel_count(hlo) == 4, name
+
+    def test_the_new_ops_of_the_qwen3_next_cell_at_its_widths(
+            self, topo, on_tpu):
+        """PR 58's ops at the cell's widths (16,384 positions, hidden
+        2048, bfloat16), forward and backward of each op alone: the gated
+        delta-rule mixer (16 key and 32 value heads of 128, chunks of
+        128: the walk's two kernels, no state a position and no [S, S]
+        array) and the attention op at 16 : 2 heads of 256 with the gate
+        a lane (the wide-head kernels: forward, dQ, dK with dV; the keys
+        and values at the KV heads; no [S, S] array); each inside the
+        VMEM its kernels ask for, or the compile would have refused."""
+        from flexflow_tpu import FFConfig, FFModel
+        from flexflow_tpu.ops.base import OpContext, OpRegistry
+        seq, hidden = 16384, 2048
+        one = SingleDeviceSharding(topo.devices[0])
+        ff = FFModel(FFConfig(batch_size=1))
+        x = ff.create_tensor((1, seq, hidden))
+        ff.delta_mixer(x, 16, 32, 128, 128, name="delta")
+        ff.multihead_attention(
+            x, x, x, hidden, 16, bias=False, causal=True, num_kv_heads=2,
+            head_dim=256, rope=True, rope_theta=1e7,
+            partial_rotary_factor=0.25, qk_norm=True,
+            qk_norm_zero_centered=True, lane_gate=True, name="attn")
+        square = re.compile(r"\[(?:\d+,)*16384,16384\]")
+        # a [128, 128] state a position, whatever the layout
+        states = re.compile(r"16384,32,128,128\]|32,16384,128,128\]|"
+                            r"16384,4096,128\]")
+        for name in ("delta", "attn"):
+            layer = ff._layer_named[name]
+            op = OpRegistry.create(layer, [t.shape for t in layer.inputs])
+            params = {
+                leaf: jax.ShapeDtypeStruct(
+                    a.shape, jnp.float32 if leaf in op.full_precision_params
+                    else jnp.bfloat16, sharding=one)
+                for leaf, a in jax.eval_shape(
+                    op.init_params, jax.random.PRNGKey(0)).items()}
+            inputs = tuple(jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                                sharding=one)
+                           for shape in op.input_shapes)
+
+            def loss(params, inputs, op=op):
+                ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
+                (y,) = op.forward(params, list(inputs), ctx)
+                op._counters = None
+                return y.astype(jnp.float32).sum()
+
+            compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+                params, inputs).compile()
+            hlo = compiled.as_text()
+            assert not square.search(hlo), name
+            assert not states.search(hlo), name
+            assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
+            if name == "delta":
+                assert "delta_rule_fwd" in hlo and "delta_rule_bwd" in hlo
+                assert op.traced_gauges() == {
+                    "executor.delta_mixer_ops": 1,
+                    "executor.delta_rule_kernel_ops": 1}
+                continue
+            route = op._route
+            assert (route.core, route.grouped_kv, route.wide_head,
+                    route.scope) == ("flash", True, True, "full")
+            for kernel in ("flash_fwd_wide", "flash_bwd_wide_dq",
+                           "flash_bwd_wide_dkv"):
+                assert kernel in hlo, kernel
 
     def test_learned_sparse_attention_at_the_keye_cells_widths(
             self, topo, on_tpu):
