@@ -345,6 +345,8 @@ class AttentionRoute:
     # a head of two 128-lane blocks (256): the flash kernels that take
     # one [Q block, K block] tile a grid step
     wide_head: bool = False
+    # of them the backward, a head: the tiles whose scores it forms
+    wide_bwd_score_tiles: int = 0
     # learned sparse attention: the selection and the indexer's loss run
     # the kernels `index_select` / `index_kl` (with core "flash": the
     # chunk-loop kernels with the mask operand); else `ops/sparse_index`
@@ -893,7 +895,8 @@ class MultiHeadAttention(Op):
                 core, blocked, fallback, scope, shard_axes, grouped_kv,
                 rotary_in_lanes,
                 kv_blocks=pk.wide_kv_blocks(sq, self.causal, self.window),
-                wide_head=True)
+                wide_head=True, wide_bwd_score_tiles=pk.wide_bwd_score_tiles(
+                    sq, self.causal, self.window))
         if self.sparse_index:
             return AttentionRoute(
                 core, blocked, fallback, scope, shard_axes, grouped_kv,
@@ -943,7 +946,10 @@ class MultiHeadAttention(Op):
         (query, key) pairs a head's kernels work through, forward and
         backward, against twice the pairs visible (PR 41);
         `executor.flash_diff_ops`: a differential op whose two maps ran
-        the flash kernels (PR 52)."""
+        the flash kernels (PR 52); `executor.flash_wide_head_ops`: a
+        head of 256 lanes ran the wide-head kernels (PR 58), and
+        `executor.flash_wide_bwd_score_tiles`: the tiles a head whose
+        scores their backward forms, each once (PR 59)."""
         route = self._route or AttentionRoute("einsum")   # not traced
         visited, total, masked = route.kv_blocks or (0, 0, 0)
         keys_visited, keys_visible = route.window_pairs or (0, 0)
@@ -952,6 +958,8 @@ class MultiHeadAttention(Op):
                  if self.differential else {})
         if self.head_dim > 128:     # published only where the model has one
             extra["executor.flash_wide_head_ops"] = int(route.wide_head)
+            extra["executor.flash_wide_bwd_score_tiles"] = (
+                route.wide_bwd_score_tiles)
         if self.sparse_index:   # published only where the model has one
             kernels = route.core == "flash" and route.sparse_kernels
             extra.update({

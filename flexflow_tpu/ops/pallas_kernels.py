@@ -115,11 +115,15 @@ LANES = 128  # a vreg's and an HBM tile's minor extent
 # (tests/test_tpu_compile.py). A head of 256 lanes (PR 58) does not fit
 # these kernels' resident [S, W] panels at S = 16384 (the compile was
 # refused); it takes kernels of its own, one [Q block, K block] tile a
-# grid step (`_wide_flash_fwd`, `_wide_flash_bwd`: blocks of 512 x 1024,
-# 16 : 2 heads of 256 at S = 16384 accepted deviceless, bf16 and f32),
-# which ask for the same 96 MiB and use about 8 MiB of it: a [512, 1024]
-# float32 score tile, its probabilities, and double-buffered [512, 256]
-# and [1024, 256] operand blocks.
+# grid step: two since PR 59, `_wide_flash_fwd` (tiles of 512 x 1024) and
+# `_wide_flash_bwd` (tiles of 1024 x 1024; dQ, dK and dV from ONE kernel);
+# 16 : 2 heads of 256 at S = 16384 accepted deviceless, bf16 and f32.
+# They ask for the same 96 MiB: the forward uses about 8 MiB of it (a
+# [512, 1024] float32 score tile, its probabilities, double-buffered
+# [512, 256] and [1024, 256] operand blocks), the backward about 50 in
+# bf16 and 66 in f32 (K, V, dK and dV as blocks of four K blocks,
+# [4096, 256], double-buffered, dK and dV float32 scratch of the same,
+# and four [1024, 1024] float32 tiles of scores).
 _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
 # with a mask operand (PR 54) a grid step also holds its rows of the
 # mask, [1024, S] bytes twice (16 MiB each at S = 16384), which the
@@ -2049,8 +2053,41 @@ def _flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
 # running statistics and the sums in VMEM scratch across the innermost
 # grid axis. Causal or not, a window; no block-diffusion mask, no
 # two-part score, no mask operand (nobody has needed them at this width).
+#
+# The backward is ONE kernel (PR 59; PR 58 shipped two, dQ and dK with
+# dV, which both formed a visited tile's P^T and dS^T: seven 256-deep
+# products a tile and the tile's exp twice, where five and once are the
+# mathematics). `scripts/delta_lab.py` keeps the two-kernel form and
+# times both (my chip runs, PR 59; v5e, 16 : 2 heads of 256, 16,384
+# causal positions, bf16; device ms of the backward's kernels alone; the
+# forward beside them 16.80):
+#
+#   two kernels, 512 x 1024 (PR 58)     dQ 20.91 + dK, dV 25.64 = 46.55
+#   one kernel, Q x K block, K blocks a run:
+#     512 x 1024, 1    36.59   (dQ's copies left out, a wrong dQ: 32.44)
+#     512 x  512, 1    42.59       256 x 1024, 1    40.20
+#     512 x 2048, 1    34.92       256 x 2048, 1    36.51
+#    1024 x 2048, 1    75.85      1024 x 1024, 1    34.71  (no copies: 31.31)
+#    1024 x 1024, 2    33.23      1024 x 1024, 4    32.43  <- ships
+#    1024 x 1024, 8    32.07  (float32: VMEM refused); 4, no copies: 31.67
+#     512 x 1024, 4    33.87      1024 x  512, 8    34.07
+#     512 x 2048, 2    34.07
+#
+# dQ's float32 sum cannot stay in VMEM from one K block to the next (the
+# other Q blocks and the group's heads come between), so it travels to
+# HBM and back by the kernel's own copies. Those copies cost the products
+# about a microsecond a MB WHEREVER they are started and waited for (at
+# 512 x 1024: fetched under the first products and sent back under the
+# last 36.53, sent back and waited for in the next visited step 36.80,
+# waited for where started 43.27): not latency to hide but bytes, so the
+# grid walks a RUN of K blocks under each Q block before it moves on, the
+# sum staying in VMEM meanwhile; a run of four moves a quarter of the
+# bytes. A tile of 1024 x 1024 halves the grid's steps at the same
+# visited pairs (136 tiles a head for the forward's 272 of 512 x 1024).
 WIDE_BLK_Q = 512
 WIDE_BLK_K = 1024
+WIDE_BWD_BLK = 1024     # the backward's tile, both ways
+WIDE_BWD_RUN = 4        # K blocks under a Q block before its dQ sum leaves
 
 
 def _wide_blocks(s: int):
@@ -2058,12 +2095,31 @@ def _wide_blocks(s: int):
             next(b for b in (WIDE_BLK_K, 512, 256, BLK_Q) if s % b == 0))
 
 
+def _wide_bwd_blocks(s: int):
+    """The backward's (Q block, K block, K blocks a run), its own choice
+    (the lab's table above)."""
+    blk = next(b for b in (WIDE_BWD_BLK, 512, 256, BLK_Q) if s % b == 0)
+    run = next(n for n in (WIDE_BWD_RUN, 2, 1) if s // blk % n == 0)
+    return blk, blk, run
+
+
 def wide_kv_blocks(s: int, causal: bool, window: int = 0):
     """(visited, total, masked) [Q block, K block] tiles a head of the
     wide-head forward works through, as `kv_blocks` / `kv_blocks_masked`
     count the chunk loop's."""
+    return _wide_tiles(s, *_wide_blocks(s), causal, window)
+
+
+def wide_bwd_score_tiles(s: int, causal: bool, window: int = 0) -> int:
+    """The backward's own [K block, Q block] tiles a head whose P^T and
+    dS^T it forms, each ONCE (PR 58's two kernels formed each twice):
+    136 of [1024, 1024] at 16,384 causal positions."""
+    blk_q, blk_k, _ = _wide_bwd_blocks(s)
+    return _wide_tiles(s, blk_q, blk_k, causal, window)[0]
+
+
+def _wide_tiles(s: int, blk_q: int, blk_k: int, causal: bool, window: int):
     window = normalized_window(s, causal, window)
-    blk_q, blk_k = _wide_blocks(s)
     nq, nk = s // blk_q, s // blk_k
     visited = masked = 0
     for iq in range(nq):
@@ -2170,65 +2226,96 @@ def _wide_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         lse_ref[0, 0, 0, :] = (m_scr[...] + jnp.log(l))[:, 0]
 
 
-def _wide_bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q0, k0,
-                   masked: bool, scale: float, window: int):
+def _wide_bwd_tile(q, k, v, do, lse, delta, q0, k0, masked: bool,
+                   scale: float, window: int):
     """(P^T, dS^T) [Bk, Bq] of one tile from the saved log-sum-exp and
     delta rows: the tile as [k, q], so that the row statistics broadcast
     down the sublanes as they are stored."""
-    st = _dot(k_ref[0], q_ref[0], _NT) * scale
+    st = _dot(k, q, _NT) * scale
     if masked:
         st = jnp.where(_wide_visible(q0, k0, st.shape, 1, window), st,
                        _MASKED)
-    pt = jnp.exp(st - lse_ref[0, 0])
-    dpt = _dot(v_ref[0], do_ref[0], _NT)
-    return pt, (pt * (dpt - delta_ref[0, 0])).astype(q_ref.dtype)
+    pt = jnp.exp(st - lse)
+    dpt = _dot(v, do, _NT)
+    return pt, (pt * (dpt - delta)).astype(q.dtype)
 
 
-def _wide_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                    acc_scr, *, scale: float, causal: bool, window: int,
-                    blk_q: int, blk_k: int, nk: int):
-    iq, ik = pl.program_id(2), pl.program_id(3)
+def _wide_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dq_ref, dk_ref, dv_ref, sums_ref, dk_scr, dv_scr,
+                     dq_scr, out_scr, sem, *, scale: float, causal: bool,
+                     window: int, blk_q: int, blk_k: int, nq: int, nk: int,
+                     rep: int, run: int):
+    """One [K block, Q block] tile a grid step: P^T and dS^T are formed
+    ONCE and give all three gradients. The grid walks ``run`` K blocks
+    (the innermost axis) under each Q block of each of the group's heads,
+    and then the next ``run``: K, V, dK and dV are blocks of ``run`` K
+    blocks, dK and dV float32 scratch over the group's heads and the Q
+    blocks. dQ's rows meet the next run of K blocks ``rep * nq * run``
+    steps later, so their float32 sum lives in HBM between runs
+    (``sums_ref`` [B, H, S, D], this kernel's alone): a run's first tile
+    fetches its block under its first products, its last tile sends it
+    back under its last ones, and between them the sum stays in VMEM (a
+    copy costs the products its bytes' time wherever it is put: the
+    lab's table above). A Q block's first tile of all fetches nothing and
+    its last rounds the sum into dQ, so no pass runs before or after the
+    kernel; a step outside the causal span starts no copy."""
+    b, g, jk, r, iq, c = (pl.program_id(a) for a in range(6))
+    h, ik = g * rep + r, jk * run + c
     q0, k0 = iq * blk_q, ik * blk_k
+    d = q_ref.shape[-1]
+    rows = pl.ds(pl.multiple_of(c * blk_k, blk_k), blk_k)
 
-    @pl.when(ik == 0)
-    def _():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    def tile(masked: bool):
-        _, dst = _wide_bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                delta_ref, q0, k0, masked, scale, window)
-        acc_scr[...] += _dot(dst, k_ref[0], _TN)
-
-    _wide_visit(tile, ik, _wide_k_range(iq, blk_q, blk_k, nk, causal, window),
-                q0, k0, blk_q, blk_k, causal, window)
-
-    @pl.when(ik == nk - 1)
-    def _():
-        dq_ref[0] = (acc_scr[...] * scale).astype(dq_ref.dtype)
-
-
-def _wide_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
-                     causal: bool, window: int, blk_q: int, blk_k: int,
-                     nq: int, rep: int):
-    ik, r, iq = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-    q0, k0 = iq * blk_q, ik * blk_k
-
-    @pl.when(jnp.logical_and(r == 0, iq == 0))
+    @pl.when(jnp.logical_and(jnp.logical_and(r == 0, iq == 0), c == 0))
     def _():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    first, last = _wide_k_range(iq, blk_q, blk_k, nk, causal, window)
+    # the K blocks of this run that the Q block meets
+    enter = jnp.maximum(first, jk * run)
+    leave = jnp.minimum(last, jk * run + run - 1)
+    fetches = jnp.logical_and(ik == enter, ik > first)
+    keeps = jnp.logical_and(ik == leave, ik < last)
+    sums = sums_ref.at[b, h, pl.ds(q0, blk_q)]
+    fetch = pltpu.make_async_copy(sums, dq_scr, sem.at[0])
+    keep = pltpu.make_async_copy(dq_scr, sums, sem.at[1])
+    hand_out = pltpu.make_async_copy(
+        out_scr, dq_ref.at[b, pl.ds(q0, blk_q), pl.ds(h * d, d)], sem.at[1])
+
     def tile(masked: bool):
-        pt, dst = _wide_bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                 delta_ref, q0, k0, masked, scale, window)
-        dv_scr[...] += _dot(pt.astype(do_ref.dtype), do_ref[0], _NN)
-        dk_scr[...] += _dot(dst, q_ref[0], _NN)
+        pl.when(fetches)(fetch.start)
+        k = k_ref[0, rows]
+        pt, dst = _wide_bwd_tile(q_ref[0], k, v_ref[0, rows], do_ref[0],
+                                 lse_ref[0, 0], delta_ref[0, 0], q0, k0,
+                                 masked, scale, window)
+        part = _dot(dst, k, _TN)
+
+        @pl.when(ik == first)
+        def _():
+            dq_scr[...] = part
+
+        @pl.when(ik > first)
+        def _():
+            pl.when(fetches)(fetch.wait)
+            dq_scr[...] += part
+
+        pl.when(keeps)(keep.start)
+
+        @pl.when(ik == last)
+        def _():
+            out_scr[...] = (dq_scr[...] * scale).astype(out_scr.dtype)
+            hand_out.start()
+
+        dv_scr[rows] += _dot(pt.astype(do_ref.dtype), do_ref[0], _NN)
+        dk_scr[rows] += _dot(dst, q_ref[0], _NN)
+        pl.when(keeps)(keep.wait)
+        pl.when(ik == last)(hand_out.wait)
 
     _wide_visit(tile, iq, _wide_q_range(ik, blk_q, blk_k, nq, causal, window),
                 q0, k0, blk_q, blk_k, causal, window)
 
-    @pl.when(jnp.logical_and(r == rep - 1, iq == nq - 1))
+    @pl.when(jnp.logical_and(jnp.logical_and(r == rep - 1, iq == nq - 1),
+                             c == run - 1))
     def _():
         dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -2285,88 +2372,78 @@ def _wide_flash_fwd(q, k, v, num_heads: int, causal: bool, interpret: bool,
     )(q, k, v)
 
 
+def _wide_delta(q, o, do, num_heads: int, glse):
+    """(dO as an MXU operand, delta [B, H, 1, S] like lse): delta =
+    rowsum(dO * O) less ``glse``, the upstream gradient on the logsumexp
+    output (the ring's streaming merge): dS = P * (dP - delta + g_lse)."""
+    b, s, hd = q.shape
+    do = do.astype(q.dtype)
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
+        b, s, num_heads, hd // num_heads), axis=-1).transpose(0, 2, 1)
+    delta = delta[:, :, None, :]
+    return do, delta if glse is None else delta - glse
+
+
 def _wide_flash_bwd(q, k, v, o, lse, do, num_heads: int, causal: bool,
                     interpret: bool, window: int, num_kv_heads, glse=None):
-    """`_flash_bwd` at a head wider than 128 lanes, two kernels: dQ a Q
-    block over its K blocks, and dK, dV a K block over the Q blocks of
-    its group's heads (float32 sums [B, S, Hk*D] under grouped keys).
-    delta = rowsum(dO * O) is formed here, [B, H, 1, S] like lse, less
-    ``glse``, the upstream gradient on the logsumexp output (the ring's
-    streaming merge): dS = P * (dP - delta + g_lse)."""
+    """`_flash_bwd` at a head wider than 128 lanes, ONE kernel
+    (`_wide_bwd_kernel`): a K block over the Q blocks of its group's
+    heads gives dK and dV (float32 sums [B, S, Hk*D] under grouped keys)
+    and each tile's part of dQ."""
     b, s, hd = q.shape
     d = hd // num_heads
     hk = num_kv_heads or num_heads
     rep = num_heads // hk
     window = normalized_window(s, causal, window)
-    blk_q, blk_k = _wide_blocks(s)
+    blk_q, blk_k, run = _wide_bwd_blocks(s)
     nq, nk = s // blk_q, s // blk_k
-    scale = 1.0 / float(d) ** 0.5
-    do = do.astype(q.dtype)
-    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
-        b, s, num_heads, d), axis=-1).transpose(0, 2, 1)[:, :, None, :]
-    if glse is not None:
-        delta = delta - glse
-    params = dict(scale=scale, causal=causal, window=window, blk_q=blk_q,
-                  blk_k=blk_k)
+    do, delta = _wide_delta(q, o, do, num_heads, glse)
 
-    key_block = _wide_key_block(blk_q, blk_k, nk, causal, window)
-
-    def query_block(ik, iq):
-        first, last = _wide_q_range(ik, blk_q, blk_k, nq, causal, window)
+    def query_block(jk, iq):
+        first, last = _wide_q_range(jk, blk_q, run * blk_k, nq, causal,
+                                    window)
         return jnp.clip(iq, first, last) if causal else iq
 
-    dq = pl.pallas_call(
-        functools.partial(_wide_dq_kernel, nk=nk, **params),
-        name=KERNEL_NAME_PREFIX + "flash_bwd_wide_dq",
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=(b, num_heads, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, h, i, j: (b, i, h)),
-            pl.BlockSpec((1, blk_k, d),
-                         lambda b, h, i, j: (b, key_block(i, j), h // rep)),
-            pl.BlockSpec((1, blk_k, d),
-                         lambda b, h, i, j: (b, key_block(i, j), h // rep)),
-            pl.BlockSpec((1, blk_q, d), lambda b, h, i, j: (b, i, h)),
-            pl.BlockSpec((1, 1, 1, blk_q), lambda b, h, i, j: (b, h, 0, i)),
-            pl.BlockSpec((1, 1, 1, blk_q), lambda b, h, i, j: (b, h, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, blk_q, d), lambda b, h, i, j: (b, i, h)),
-        scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-            vmem_limit_bytes=96 << 20),
-    )(q, k, v, do, lse, delta)
-
-    def of_query(b, g, j, r, i):
+    def of_query(b, g, j, r, i, c):
         return b, query_block(j, i), g * rep + r
 
-    def row_of_query(b, g, j, r, i):
+    def row_of_query(b, g, j, r, i, c):
         return b, g * rep + r, 0, query_block(j, i)
 
+    def of_keys(b, g, j, r, i, c):
+        return b, j, g
+
     kv_dtype = jnp.float32 if rep > 1 else k.dtype
-    dk, dv = pl.pallas_call(
-        functools.partial(_wide_dkv_kernel, nq=nq, rep=rep, **params),
-        name=KERNEL_NAME_PREFIX + "flash_bwd_wide_dkv",
-        out_shape=(jax.ShapeDtypeStruct(k.shape, kv_dtype),
-                   jax.ShapeDtypeStruct(v.shape, kv_dtype)),
-        grid=(b, hk, nk, rep, nq),
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    keys = pl.BlockSpec((1, run * blk_k, d), of_keys)
+    dq, dk, dv, _ = pl.pallas_call(
+        functools.partial(_wide_bwd_kernel, scale=1.0 / float(d) ** 0.5,
+                          causal=causal, window=window, blk_q=blk_q,
+                          blk_k=blk_k, nq=nq, nk=nk, rep=rep, run=run),
+        name=KERNEL_NAME_PREFIX + "flash_bwd_wide",
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, kv_dtype),
+                   jax.ShapeDtypeStruct(v.shape, kv_dtype),
+                   jax.ShapeDtypeStruct((b, num_heads, s, d), jnp.float32)),
+        grid=(b, hk, nk // run, rep, nq, run),
         in_specs=[
-            pl.BlockSpec((1, blk_q, d), of_query),
-            pl.BlockSpec((1, blk_k, d), lambda b, g, j, r, i: (b, j, g)),
-            pl.BlockSpec((1, blk_k, d), lambda b, g, j, r, i: (b, j, g)),
+            pl.BlockSpec((1, blk_q, d), of_query), keys, keys,
             pl.BlockSpec((1, blk_q, d), of_query),
             pl.BlockSpec((1, 1, 1, blk_q), row_of_query),
             pl.BlockSpec((1, 1, 1, blk_q), row_of_query),
         ],
-        out_specs=(pl.BlockSpec((1, blk_k, d), lambda b, g, j, r, i: (b, j, g)),
-                   pl.BlockSpec((1, blk_k, d), lambda b, g, j, r, i: (b, j, g))),
-        scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32)] * 2,
+        out_specs=(in_hbm, keys, keys, in_hbm),
+        scratch_shapes=[pltpu.VMEM((run * blk_k, d), jnp.float32),
+                        pltpu.VMEM((run * blk_k, d), jnp.float32),
+                        pltpu.VMEM((blk_q, d), jnp.float32),
+                        pltpu.VMEM((blk_q, d), q.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
         interpret=interpret,
+        # a run of K blocks reads what the one before it left of dQ's
+        # sums: the runs go in order on one core
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=96 << 20),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

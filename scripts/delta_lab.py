@@ -34,8 +34,19 @@ what a train step runs; a `.fwd` piece the value alone.
 - `flash256.{fwd,grad}`: `pallas_kernels.flash_attention` at 16 : 2 heads
   of 256, causal; `flash128.{fwd,grad}`: the same pairs and lanes as 32 : 4
   heads of 128 through the chunk-loop kernels, for scale;
+- `flash256.two_kernels.grad` (PR 59): the backward as PR 58 shipped it,
+  kept HERE alone (`two_kernel_wide_flash_bwd`: dQ a Q block over its K
+  blocks, dK and dV a K block over its Q blocks, each forming the tile's
+  P^T and dS^T), beside the ONE kernel that ships;
+  `flash256.q<Bq>_k<Bk>_run<n>.grad`: the shipped kernel at other
+  backward blocks and K blocks a run; `flash256.no_sums_traffic.grad`:
+  the shipped kernel with dQ's copies to and from HBM left out (a WRONG
+  dQ: a control of what the copies cost, not a candidate);
 - `check`: the kernel form against `delta_rule_stepwise` at 2,048
-  positions (max abs error over the largest value, bfloat16 operands).
+  positions (max abs error over the largest value, bfloat16 operands);
+  `flash256_vs_two_kernels`: dQ, dK, dV of the shipped backward, of
+  every block tried and of the control against the two-kernel form's
+  (largest difference over the largest value; the control's dQ is far).
 
 Prints one JSON line and writes it to `chiprun_out/delta_lab.json`.
 `--tiny` runs small shapes wherever it is, the kernels interpreted (a
@@ -48,6 +59,7 @@ import argparse
 import json
 import os
 import sys
+from unittest import mock
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -86,6 +98,193 @@ def normed_heads(qkv, hk, hv, d):
     return q, k, v
 
 
+def _two_kernel_kernels():
+    """PR 58's dQ and dK / dV kernels at a head of 256, for the lab's row
+    and the test of the sum's order."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    def dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                  acc_scr, *, scale, causal, window, blk_q, blk_k, nk):
+        iq, ik = pl.program_id(2), pl.program_id(3)
+        q0, k0 = iq * blk_q, ik * blk_k
+
+        @pl.when(ik == 0)
+        def _():
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        def tile(masked):
+            _, dst = pk._wide_bwd_tile(q_ref[0], k_ref[0], v_ref[0], do_ref[0],
+                                       lse_ref[0, 0], delta_ref[0, 0], q0,
+                                       k0, masked, scale, window)
+            acc_scr[...] += pk._dot(dst, k_ref[0], pk._TN)
+
+        pk._wide_visit(tile, ik, pk._wide_k_range(iq, blk_q, blk_k, nk,
+                                                  causal, window),
+                       q0, k0, blk_q, blk_k, causal, window)
+
+        @pl.when(ik == nk - 1)
+        def _():
+            dq_ref[0] = (acc_scr[...] * scale).astype(dq_ref.dtype)
+
+    def dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                   dv_ref, dk_scr, dv_scr, *, scale, causal, window, blk_q,
+                   blk_k, nq, rep):
+        ik, r, iq = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+        q0, k0 = iq * blk_q, ik * blk_k
+
+        @pl.when(jnp.logical_and(r == 0, iq == 0))
+        def _():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+            dv_scr[...] = jnp.zeros_like(dv_scr)
+
+        def tile(masked):
+            pt, dst = pk._wide_bwd_tile(q_ref[0], k_ref[0], v_ref[0],
+                                        do_ref[0], lse_ref[0, 0],
+                                        delta_ref[0, 0], q0, k0, masked,
+                                        scale, window)
+            dv_scr[...] += pk._dot(pt.astype(do_ref.dtype), do_ref[0], pk._NN)
+            dk_scr[...] += pk._dot(dst, q_ref[0], pk._NN)
+
+        pk._wide_visit(tile, iq, pk._wide_q_range(ik, blk_q, blk_k, nq,
+                                                  causal, window),
+                       q0, k0, blk_q, blk_k, causal, window)
+
+        @pl.when(jnp.logical_and(r == rep - 1, iq == nq - 1))
+        def _():
+            dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    return dq_kernel, dkv_kernel
+
+
+def two_kernel_wide_flash_bwd(q, k, v, o, lse, do, num_heads, causal,
+                              interpret, window, num_kv_heads, glse=None):
+    """`pallas_kernels._wide_flash_bwd` as PR 58 shipped it (its
+    signature): two `pallas_call`s, both forming a visited tile's P^T and
+    dS^T; dQ's sum a Q block's float32 scratch over ascending K blocks."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    dq_kernel, dkv_kernel = _two_kernel_kernels()
+    b, s, hd = q.shape
+    d = hd // num_heads
+    hk = num_kv_heads or num_heads
+    rep = num_heads // hk
+    window = pk.normalized_window(s, causal, window)
+    blk_q, blk_k = pk._wide_blocks(s)
+    nq, nk = s // blk_q, s // blk_k
+    do, delta = pk._wide_delta(q, o, do, num_heads, glse)
+    params = dict(scale=1.0 / float(d) ** 0.5, causal=causal, window=window,
+                  blk_q=blk_q, blk_k=blk_k)
+    key_block = pk._wide_key_block(blk_q, blk_k, nk, causal, window)
+
+    def query_block(ik, iq):
+        first, last = pk._wide_q_range(ik, blk_q, blk_k, nq, causal, window)
+        return jnp.clip(iq, first, last) if causal else iq
+
+    def of_key(b, h, i, j):
+        return b, key_block(i, j), h // rep
+
+    rows = pl.BlockSpec((1, blk_q, d), lambda b, h, i, j: (b, i, h))
+    stat = pl.BlockSpec((1, 1, 1, blk_q), lambda b, h, i, j: (b, h, 0, i))
+    dq = pl.pallas_call(
+        functools.partial(dq_kernel, nk=nk, **params),
+        name=pk.KERNEL_NAME_PREFIX + "flash_bwd_wide_dq",
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(b, num_heads, nq, nk),
+        in_specs=[rows, pl.BlockSpec((1, blk_k, d), of_key),
+                  pl.BlockSpec((1, blk_k, d), of_key), rows, stat, stat],
+        out_specs=rows,
+        scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=96 << 20),
+    )(q, k, v, do, lse, delta)
+
+    def of_query(b, g, j, r, i):
+        return b, query_block(j, i), g * rep + r
+
+    def row_of_query(b, g, j, r, i):
+        return b, g * rep + r, 0, query_block(j, i)
+
+    keys = pl.BlockSpec((1, blk_k, d), lambda b, g, j, r, i: (b, j, g))
+    kv_dtype = jnp.float32 if rep > 1 else k.dtype
+    dk, dv = pl.pallas_call(
+        functools.partial(dkv_kernel, nq=nq, rep=rep, **params),
+        name=pk.KERNEL_NAME_PREFIX + "flash_bwd_wide_dkv",
+        out_shape=(jax.ShapeDtypeStruct(k.shape, kv_dtype),
+                   jax.ShapeDtypeStruct(v.shape, kv_dtype)),
+        grid=(b, hk, nk, rep, nq),
+        in_specs=[pl.BlockSpec((1, blk_q, d), of_query), keys, keys,
+                  pl.BlockSpec((1, blk_q, d), of_query),
+                  pl.BlockSpec((1, 1, 1, blk_q), row_of_query),
+                  pl.BlockSpec((1, 1, 1, blk_q), row_of_query)],
+        out_specs=(keys, keys),
+        scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32)] * 2,
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary", "arbitrary"),
+            vmem_limit_bytes=96 << 20),
+    )(q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+class _NoCopy:
+    """A copy that is never made (`flash256.no_sums_traffic`)."""
+
+    def start(self):
+        pass
+
+    wait = start
+
+
+# the backward's (Q block, K block, K blocks a run) the lab tries beside
+# the shipped ones
+BWD_BLOCKS = ((512, 1024, 1), (1024, 1024, 1), (1024, 1024, 2),
+              (1024, 1024, 8), (512, 1024, 4), (512, 2048, 2))
+
+
+def wide_bwd_forms(seq):
+    """name -> what to hold in place while the backward at a head of 256
+    is traced, (object, attribute, value): the two-kernel form, the
+    control without dQ's copies, the other blocks that divide ``seq``."""
+    from flexflow_tpu.ops import pallas_kernels as pk
+    forms = {"two_kernels": (pk, "_wide_flash_bwd",
+                             two_kernel_wide_flash_bwd),
+             "no_sums_traffic": (pk.pltpu, "make_async_copy",
+                                 lambda *a: _NoCopy())}
+    forms.update(("q%d_k%d_run%d" % blk,
+                  (pk, "_wide_bwd_blocks", lambda s, blk=blk: blk))
+                 for blk in BWD_BLOCKS
+                 if seq % blk[0] == 0 and seq % (blk[1] * blk[2]) == 0)
+    return forms
+
+
+def traced_holding(held, fn):
+    """``fn``, traced with ``held`` (object, attribute, value) in place;
+    as it is where ``held`` is None."""
+    if held is None:
+        return fn
+
+    def run(*a):
+        with mock.patch.object(*held):
+            return fn(*a)
+
+    return run
+
+
 def pieces(seq, hk, hv, d, heads256, dtype, chunk):
     """name -> (jitted function, arguments)."""
     import jax
@@ -101,11 +300,21 @@ def pieces(seq, hk, hv, d, heads256, dtype, chunk):
         fn.__name__ = name.replace(".", "_")
         return jax.jit(fn)
 
+    def grad_of(name, fn, args, wgt, held=None):
+        """The value and gradients of a weighted sum of ``fn`` (the
+        weight an argument: no constant of the program), traced with
+        ``held`` in place."""
+        def grads(wgt, *a):
+            return jax.value_and_grad(
+                lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * wgt),
+                argnums=tuple(range(len(args))))(*a)
+
+        out[name + ".grad"] = (named(name + ".grad", traced_holding(
+            held, grads)), (wgt,) + args)
+
     def both(name, fn, args, wgt):
         out[name + ".fwd"] = (named(name + ".fwd", fn), args)
-        out[name + ".grad"] = (named(name + ".grad", jax.value_and_grad(
-            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * wgt),
-            argnums=tuple(range(len(args))))), args)
+        grad_of(name, fn, args, wgt)
 
     both("rule.kernel", lambda *a: dr.delta_rule_core(
         *a, hk, chunk, 1e-6, dtype, True), ins, wgt)
@@ -149,9 +358,17 @@ def pieces(seq, hk, hv, d, heads256, dtype, chunk):
         q = jax.random.normal(ks[0], (1, seq, h * hd)).astype(dtype)
         k = jax.random.normal(ks[1], (1, seq, hkv * hd))
         v = jax.random.normal(ks[2], (1, seq, hkv * hd))
-        both(name, lambda q, k, v, h=h, hkv=hkv: pk.flash_attention(
-            q, k, v, h, causal=True, num_kv_heads=hkv), (q, k, v),
-            jax.random.normal(ks[3], (1, seq, h * hd)))
+        wgt = jax.random.normal(ks[3], (1, seq, h * hd))
+
+        def flash(q, k, v, h=h, hkv=hkv):
+            return pk.flash_attention(q, k, v, h, causal=True,
+                                      num_kv_heads=hkv)
+
+        both(name, flash, (q, k, v), wgt)
+        if name == "flash128":
+            continue
+        for form, held in wide_bwd_forms(seq).items():
+            grad_of(name + "." + form, flash, (q, k, v), wgt, held)
     return out
 
 
@@ -173,6 +390,36 @@ def check(seq, hk, hv, d, dtype, chunk):
     top = float(jnp.max(jnp.abs(want)))
     return {name + "_vs_stepwise": float(jnp.max(jnp.abs(o - want))) / top
             for name, o in got.items()}
+
+
+def flash_check(seq, heads256, dtype):
+    """The shipped one-kernel backward and the lab's controls against the
+    two-kernel form at the lab's shape: largest difference of each
+    gradient over the gradient's largest value."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops import pallas_kernels as pk
+    h, hkv, hd = heads256
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(ks[0], (1, seq, h * hd)).astype(dtype)
+    k = jax.random.normal(ks[1], (1, seq, hkv * hd))
+    v = jax.random.normal(ks[2], (1, seq, hkv * hd))
+    wgt = jax.random.normal(ks[3], (1, seq, h * hd))
+
+    def grads(held):
+        fn = jax.grad(lambda q, k, v, wgt: jnp.sum(pk.flash_attention(
+            q, k, v, h, causal=True, num_kv_heads=hkv).astype(jnp.float32)
+            * wgt), argnums=(0, 1, 2))
+        return [g.astype(jnp.float32)
+                for g in jax.jit(traced_holding(held, fn))(q, k, v, wgt)]
+
+    forms = dict(wide_bwd_forms(seq), shipped=None)
+    want = grads(forms.pop("two_kernels"))
+    return {form: {name: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+                   for name, a, b in zip(("dq", "dk", "dv"), grads(held),
+                                         want)}
+            for form, held in forms.items()}
 
 
 def main():
@@ -209,6 +456,10 @@ def main():
     if opts.only in "check":
         line["check"] = check(short, shape["hk"], shape["hv"], shape["d"],
                               shape["dtype"], shape["chunk"])
+    if opts.only in "flash256.check":
+        line["flash256_vs_two_kernels"] = flash_check(
+            2048 if opts.tiny else shape["seq"], shape["heads256"],
+            shape["dtype"])
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/delta_lab.json", "w") as f:
         json.dump(line, f)

@@ -185,20 +185,31 @@ def einsum_attention(q, k, v, h, hk, causal, window):
     return pk.merge_heads(o[None])
 
 
-@pytest.mark.parametrize("seq,causal,window", [
-    (2048, True, 0),        # blocks of 512 x 1024: interior and edge tiles
-    (1536, True, 300),      # a window that ends inside a block
-    (1024, False, 0)])
+@pytest.mark.parametrize("seq,causal,window,h,hk", [
+    (2048, True, 0, 4, 2),      # forward 512 x 1024: interior and edge tiles
+    (1536, True, 300, 4, 2),    # a window that ends inside a block
+    (1024, False, 0, 4, 2),
+    # the one-kernel backward (PR 59; tiles of 1024, two K blocks a run at
+    # 2,048 positions, four at 4,096): K blocks past the first leave the
+    # Q blocks ahead of them untouched, and the dQ sum of a Q block
+    # leaves for HBM and comes back between runs
+    (2048, True, 0, 1, 1),      # every head its own keys: dK, dV as stored
+    (2048, True, 700, 4, 1),    # a window that skips the oldest K block
+    (2048, False, 0, 2, 2),
+    (1536, True, 0, 2, 2),      # three blocks of 512, one a run
+    (5120, True, 0, 1, 1),      # five K blocks, one a run: dQ through HBM
+    (4096, True, 1500, 1, 1)])  # four K blocks a run under a window
 def test_flash_at_a_head_of_256_matches_the_einsum_path(interpret, seq,
-                                                        causal, window):
-    h, hk, d = 4, 2, 256
+                                                        causal, window, h,
+                                                        hk):
+    d = 256
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(ks[0], (1, seq, h * d))
     k = jax.random.normal(ks[1], (1, seq, hk * d))
     v = jax.random.normal(ks[2], (1, seq, hk * d))
     weight = jax.random.normal(ks[3], (1, seq, h * d))
     assert pk.flash_attention_available(seq, d, h)
-    assert pk.grouped_kv_shape_legal(h, hk, d)
+    assert pk.grouped_kv_shape_legal(h, hk, d) == (hk < h)
     assert pk.grouped_kv_shape_legal(16, 2, 256)
 
     def both(fn):
@@ -209,15 +220,98 @@ def test_flash_at_a_head_of_256_matches_the_einsum_path(interpret, seq,
         want, dwant = both(lambda *a: einsum_attention(
             *a, h, hk, causal, window))(q, k, v)
         got, dgot = both(lambda *a: pk.flash_attention(
-            *a, h, causal=causal, window=window, num_kv_heads=hk))(q, k, v)
+            *a, h, causal=causal, window=window,
+            num_kv_heads=hk if hk < h else None))(q, k, v)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for name, a, b in zip("qkv", dgot, dwant):
         assert a.dtype == jnp.float32
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5, err_msg=name)
     visited, total, masked = pk.wide_kv_blocks(seq, causal, window)
     assert 0 < visited <= total and masked <= visited
-    if causal and not window:
+    if (seq, causal, window) == (2048, True, 0):
         assert (visited, total, masked) == (6, 8, 4)
+        assert pk._wide_bwd_blocks(seq) == (1024, 1024, 2)
+        assert pk.wide_bwd_score_tiles(seq, causal, window) == 3
+
+
+def test_wide_backward_blocks_and_the_tiles_it_forms():
+    """The backward's own blocks: tiles of 1024 where the length allows,
+    four K blocks a run where their count allows; its score tiles a head
+    at the qwen3_next cell's length are the forward's visited pairs."""
+    assert pk._wide_bwd_blocks(16384) == (1024, 1024, 4)
+    assert pk._wide_bwd_blocks(4096) == (1024, 1024, 4)
+    assert pk._wide_bwd_blocks(5120) == (1024, 1024, 1)
+    assert pk._wide_bwd_blocks(1536) == (512, 512, 1)
+    assert pk._wide_bwd_blocks(640) == (128, 128, 1)
+    assert pk.wide_bwd_score_tiles(16384, True) == 136
+    assert pk.wide_kv_blocks(16384, True)[0] == 272    # of half the rows
+    assert pk.wide_bwd_score_tiles(16384, False) == 256
+    # a window of 4,096: a Q block of 1,024 meets at most five K blocks
+    assert pk.wide_bwd_score_tiles(16384, True, 4096) == sum(
+        min(i, 4) + 1 for i in range(16))
+
+
+@pytest.mark.parametrize("seq,window,h,hk", [(2048, 0, 2, 1),
+                                             (4096, 1500, 1, 1)])
+def test_one_kernel_wide_backward_keeps_the_two_kernel_sum_order(
+        interpret, seq, window, h, hk):
+    """dQ of the one kernel (its sum leaving for HBM between runs of K
+    blocks) against the two kernels PR 58 shipped, which `scripts/
+    delta_lab.py` keeps: K blocks of 1024 ascending in both, so dQ is
+    the same float32 sum to round-off (a Q block of 1024 adds the same
+    tiles' products in the same order as two of 512); dK and dV add their
+    Q rows 1024 at a time where the two kernels added 512."""
+    sys.path.insert(0, os.path.join(ROOT, "scripts"))
+    import delta_lab
+    d = 256
+    ks = jax.random.split(jax.random.PRNGKey(4), 5)
+    q = jax.random.normal(ks[0], (1, seq, h * d))
+    k = jax.random.normal(ks[1], (1, seq, hk * d))
+    v = jax.random.normal(ks[2], (1, seq, hk * d))
+    do = jax.random.normal(ks[3], (1, seq, h * d))
+    glse = jax.random.normal(ks[4], (1, h, 1, seq))
+    o, lse = pk._flash_fwd(q, k, v, h, True, True, window=window,
+                           num_kv_heads=hk)
+    args = (q, k, v, o, lse, do, h, True, True, window, hk, glse)
+    with HIGHEST:
+        got = jax.jit(lambda: pk._wide_flash_bwd(*args))()
+        want = jax.jit(lambda: delta_lab.two_kernel_wide_flash_bwd(*args))()
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(b) / scale,
+                                   atol=1e-7 if name == "dq" else 2e-6,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_wide_backward_carries_the_logsumexp_cotangent(interpret, causal):
+    """A ``glse`` cotangent (the ring's merge) through the one-kernel
+    backward at a head of 256: `flash_attention_lse`'s gradients of a
+    loss on both outputs against the einsum path's."""
+    h, d, seq = 2, 256, 2048
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    q, k, v, wo = (jax.random.normal(key, (1, seq, h * d)) for key in ks[:4])
+    wl = jax.random.normal(ks[4], (1, h, seq))
+
+    def einsum_both(q, k, v):
+        o, lse = pk._xla_attention_lse(heads(q, h), heads(k, h),
+                                       heads(v, h), causal)
+        return pk.merge_heads(o[None]), lse[None]
+
+    def loss(fn):
+        def run(q, k, v):
+            o, lse = fn(q, k, v)
+            return jnp.sum(o * wo) + jnp.sum(lse * wl)
+        return jax.jit(jax.grad(run, argnums=(0, 1, 2)))
+
+    with HIGHEST:
+        want = loss(einsum_both)(q, k, v)
+        got = loss(lambda *a: pk.flash_attention_lse(
+            *a, h, causal, True))(q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5, err_msg=name)
 
 
 def test_attention_op_at_heads_of_256_runs_the_wide_kernels(interpret):
@@ -259,6 +353,11 @@ def test_attention_op_at_heads_of_256_runs_the_wide_kernels(interpret):
         np.testing.assert_allclose(np.asarray(a) / scale,
                                    np.asarray(b) / scale, atol=2e-5)
     assert op.traced_gauges()["executor.flash_wide_head_ops"] == 1
+    # one [1024, 1024] tile a head whose scores the backward forms, once
+    assert route.wide_bwd_score_tiles == 1
+    assert op.traced_gauges()["executor.flash_wide_bwd_score_tiles"] == (
+        pk.wide_bwd_score_tiles(1024, True, 0))
+    assert plain.traced_gauges()["executor.flash_wide_bwd_score_tiles"] == 0
     assert op.traced_gauges()["executor.flash_grouped_kv_ops"] == 1
     # a mask the wide kernels do not take keeps the einsum core
     layer = Layer(OperatorType.MULTIHEAD_ATTENTION, "bd", [])

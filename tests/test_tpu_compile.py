@@ -129,6 +129,16 @@ def _flash_grads(heads):
     return grads
 
 
+def _wide_grads(heads, kv_heads):
+    """Gradients through the wide-head kernels, causal, grouped keys."""
+    def grads(q, k, v):
+        def loss(q, k, v):
+            return pk._flash(q, k, v, heads, True, False, 0, None, None,
+                             kv_heads).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return grads
+
+
 def _flash_lse_grads(heads):
     def grads(q, k, v):
         def loss(q, k, v):
@@ -191,13 +201,13 @@ class TestFlashKernels:
     def test_longest_admitted_shape_compiles(self, topo, grads, dtype,
                                              head_dim):
         """Forward and K-blocked backward at the gate's upper bounds: a
-        head of one lane block (two kernels), and of two (PR 58: the
-        forward, dQ, and dK with dV)."""
+        head of one lane block (two kernels), and of two (PR 58's
+        forward and PR 59's one backward kernel: dQ, dK and dV from a
+        tile's scores formed once)."""
         q = jax.ShapeDtypeStruct(
             (1, pk.MAX_FLASH_SEQ, head_dim), dtype,
             sharding=SingleDeviceSharding(topo.devices[0]))
-        assert pallas_kernel_count(_compile(grads(1), q, q, q)) == (
-            2 if head_dim == 128 else 3)
+        assert pallas_kernel_count(_compile(grads(1), q, q, q)) == 2
 
     @pytest.mark.parametrize("seq", [8192, pk.MAX_FLASH_SEQ])
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
@@ -648,9 +658,13 @@ class TestHybridDecoderKernels:
         delta-rule mixer (16 key and 32 value heads of 128, chunks of
         128: the walk's two kernels, no state a position and no [S, S]
         array) and the attention op at 16 : 2 heads of 256 with the gate
-        a lane (the wide-head kernels: forward, dQ, dK with dV; the keys
-        and values at the KV heads; no [S, S] array); each inside the
-        VMEM its kernels ask for, or the compile would have refused."""
+        a lane (the wide-head kernels: the forward and, since PR 59, ONE
+        backward kernel, `flash_bwd_wide`, in place of `flash_bwd_wide_dq`
+        and `flash_bwd_wide_dkv`; the keys and values at the KV heads; no
+        [S, S] array); each inside the VMEM its kernels ask for (96 MiB:
+        the backward holds four K blocks of 1024 of K, V, dK and dV), or
+        the compile would have refused. The attention op compiles in
+        float32 too."""
         from flexflow_tpu import FFConfig, FFModel
         from flexflow_tpu.ops.base import OpContext, OpRegistry
         seq, hidden = 16384, 2048
@@ -701,9 +715,15 @@ class TestHybridDecoderKernels:
             route = op._route
             assert (route.core, route.grouped_kv, route.wide_head,
                     route.scope) == ("flash", True, True, "full")
-            for kernel in ("flash_fwd_wide", "flash_bwd_wide_dq",
-                           "flash_bwd_wide_dkv"):
-                assert kernel in hlo, kernel
+            assert "flash_fwd_wide" in hlo and "flash_bwd_wide" in hlo
+            assert "flash_bwd_wide_d" not in hlo    # PR 58's two kernels
+            assert pallas_kernel_count(hlo) == 2
+            assert route.wide_bwd_score_tiles == 136
+            assert pk._wide_bwd_blocks(seq) == (1024, 1024, 4)
+            kernels = jax.jit(_wide_grads(16, 2)).lower(*(
+                jax.ShapeDtypeStruct((1, seq, n * 256), jnp.float32,
+                                     sharding=one) for n in (16, 2, 2)))
+            assert pallas_kernel_count(kernels.compile().as_text()) == 2
 
     def test_learned_sparse_attention_at_the_keye_cells_widths(
             self, topo, on_tpu):
