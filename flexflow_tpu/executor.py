@@ -41,6 +41,9 @@ COUNT_SUFFIX = "#n"
 # what an op's forward leaves on the op for `_run_nodes` to pick up; traced
 # values, so a forward run as a nested call hands them out as results
 SIDE_CHANNELS = ("_aux_loss", "_counters", "_new_state", "_new_states")
+# `Op.traced_gauges` keys that say a size, not a count: the model's is the
+# largest of its ops', where every other key is added up
+GAUGES_OF_THE_LARGEST_OP = frozenset({"executor.delta_rule_heads_a_step"})
 # the op kinds of which a decoder layer holds one
 SEQUENCE_MIXERS = (OperatorType.MULTIHEAD_ATTENTION, OperatorType.SSM_MIXER,
                    OperatorType.SHORT_CONV, OperatorType.MAMBA_MIXER,
@@ -818,7 +821,8 @@ class GraphExecutor:
         """What the trace of the step recorded on the host, by gauge key:
         the ops' own `Op.traced_gauges` added up over the nodes (which
         kernels and operand forms their forwards took; 0 until one has
-        been traced); `executor.loss_own_vjp`, 1 when the loss as last
+        been traced; the largest of them for GAUGES_OF_THE_LARGEST_OP);
+        `executor.loss_own_vjp`, 1 when the loss as last
         traced took the log-probability of a row's target from
         `losses.target_log_probs` (its own backward, PR 40), else 0 (MSE,
         dense one-hot labels, probabilities in); and, in a model whose
@@ -831,7 +835,9 @@ class GraphExecutor:
         out: Dict[str, float] = {}
         for n in self.nodes:
             for key, value in n.op.traced_gauges().items():
-                out[key] = out.get(key, 0) + value
+                out[key] = (max(out.get(key, 0), value)
+                            if key in GAUGES_OF_THE_LARGEST_OP
+                            else out.get(key, 0) + value)
         out["executor.loss_own_vjp"] = int(
             getattr(self, "_loss_own_vjp", False))
         # leaves with more than one reader: the ops that read another

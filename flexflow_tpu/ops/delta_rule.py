@@ -36,7 +36,8 @@ in one of two bodies, one mathematics. Where Pallas is on, the heads are
 (`DeltaMixer.walks_by_kernel`): ONE kernel forward and ONE backward
 (`pallas_kernels.delta_rule_fused`), everything of a chunk in VMEM and
 the state carried from chunk to chunk in scratch, the backward the chunk
-function's own `jax.vjp` inside the kernel; the heads' L2 norms and the
+function's own `jax.vjp` inside the kernel, a grid step a KEY head whose
+value heads walk together (PR 60); the heads' L2 norms and the
 gated head norm are rows' sums over a head's 128 lanes and ride in the
 same pass (`delta_rule_core`). Else `jax.numpy`: everything
 that does not read the state (A, T, W, U, the two decayed copies of q and
@@ -391,10 +392,15 @@ class DeltaMixer(Op):
     def traced_gauges(self):
         """`executor.delta_mixer_ops`: the op's forward has been traced;
         `executor.delta_rule_kernel_ops`: its walk over the chunks ran as
-        the kernel pair when it was."""
+        the kernel pair when it was; `executor.delta_rule_heads_a_step`:
+        the value heads one grid step of that pair walks together (0
+        where the walk is the `lax.scan`)."""
+        from flexflow_tpu.ops.pallas_kernels import delta_heads_a_step
+        kernel = int(self._traced and self._kernel)
         return {"executor.delta_mixer_ops": int(self._traced),
-                "executor.delta_rule_kernel_ops": int(
-                    self._traced and self._kernel)}
+                "executor.delta_rule_kernel_ops": kernel,
+                "executor.delta_rule_heads_a_step": kernel
+                and delta_heads_a_step(self.value_heads // self.key_heads)}
 
     def output_dim_roles(self):
         # the sequence dim recurs: not position-independent, so no SEQ role

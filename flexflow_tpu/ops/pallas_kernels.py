@@ -4010,26 +4010,41 @@ def selective_scan(x, dt, bm, cm, a, d):
 # gated head norm of the output.
 # Nothing of a chunk but its output and the state that entered it (kept
 # for the backward: a state a CHUNK, 1 / 128 of a state a position) goes
-# to HBM. The backward is the SAME function's `jax.vjp`,
+# to HBM. The backward is the SAME functions' `jax.vjp`,
 # taken inside the kernel a chunk at a time in reverse with dS carried;
-# the inverse has a backward of its own (-T^T dT T^T). A grid step is
-# DELTA_ROWS rows of one value head.
+# the inverse has a backward of its own (-T^T dT T^T).
+#
+# A grid step is DELTA_ROWS rows of one KEY head (PR 60), and the chunk
+# loop walks the key head's value heads TOGETHER, one loop body a chunk:
+# q and k arrive, take their SiLU and norm and form K K^T and Q K^T once
+# (`_delta_heads`, `_delta_scores`: nothing in them differs by value
+# head); the heads' inverses stand in one basic block as independent
+# chains, a doubling of every chain at a time (`_unit_lower_inverse_tiles`);
+# the backward adds the heads' dq, dk, d(K K^T) and d(Q K^T) in float32
+# and pulls them through the scores and the norm once, so dq and dk leave
+# at key-head width.
 #
 # v5e, bf16, 16,384 positions, 16 key and 32 value heads of 128, one
 # layer's rule alone, forward / forward and backward device ms
-# (`scripts/delta_lab.py`, my chip runs, PR 58). The batched form
-# (`ops.delta_rule._chunk_operands` in XLA: a dozen [4096, 128, 128]
+# (`scripts/delta_lab.py`). The batched form (my chip runs, PR 58;
+# `ops.delta_rule._chunk_operands` in XLA: a dozen [4096, 128, 128]
 # float32 arrays written and read, the inverse 17.4 of it at `highest`,
 # 12.4 at one bfloat16 pass, 44.3 by `lax.linalg.triangular_solve`) with
 # the walk as a `lax.scan`: 25.8 / 82.5; the same with the walk as a
 # kernel pair that read those operands: 32.8 / 87.6 (the walk itself 1.7
-# + 3.6; the relayout to [B, S, H*128] cost what it saved). **As
-# shipped, one kernel each way: 12.7 / 33.6** (forward 12.7, backward
-# 16.4, the sums over a key head's two value heads and the relayouts of
-# g and beta 4.5).
+# + 3.6; the relayout to [B, S, H*128] cost what it saved).
+# One kernel each way (my chip runs, PR 60; the three forms side by side
+# in the lab): a grid step a value head, as PR 58 shipped it, 13.7 / 26.8
+# (forward 13.7, backward 8.9, XLA's sums over a key head's two value
+# heads 2.6, its relayouts of g and beta 1.6; PR 58's own kernels 13.5
+# and 9.1); a key head a step with each head's whole inverse after the
+# other's, 11.9 / 20.8 (backward 7.3); **as shipped, a doubling of both
+# chains at a time: 9.7 / 18.5** (forward 9.7, backward 7.2, the
+# relayouts 1.6).
 DELTA_CHUNK = 128     # rows a chunk: one MXU tile, as the heads' 128 lanes
 DELTA_ROWS = 1024     # rows a grid step, where S allows
 MAX_DELTA_WHOLE = 4096    # longest S taken as ONE block of rows
+MAX_DELTA_HEADS_A_STEP = 4    # value heads one loop body walks
 _DELTA_COMPILER_PARAMS = dict(vmem_limit_bytes=64 << 20)
 _TN = (((0,), (0,)), ((), ()))  # a[c, m] . b[c, n] -> [m, n]
 
@@ -4049,8 +4064,23 @@ def delta_rule_shape_legal(seq_len: int, key_dim: int, value_dim: int,
             and (seq_len % DELTA_ROWS == 0 or seq_len <= MAX_DELTA_WHOLE))
 
 
+def delta_heads_a_step(rep: int) -> int:
+    """The value heads one grid step of the kernel pair walks, of the
+    ``rep`` a key head serves: all of them up to MAX_DELTA_HEADS_A_STEP
+    (a block of rows of every operand for each, doubled, in VMEM: 7.5 MB
+    forward and 13 backward at four; the loop body unrolled over them),
+    else the largest divisor of ``rep`` under it: a key head then takes
+    ``rep`` over that many steps, and XLA adds the steps' dq and dk."""
+    return max(n for n in range(1, min(rep, MAX_DELTA_HEADS_A_STEP) + 1)
+               if rep % n == 0)
+
+
 def _chunk_rows(c):
     return pl.ds(pl.multiple_of(c * DELTA_CHUNK, DELTA_CHUNK), DELTA_CHUNK)
+
+
+def _head_lanes(h: int):
+    return slice(h * LANES, (h + 1) * LANES)
 
 
 def _dot32(a, b, dims):
@@ -4058,25 +4088,35 @@ def _dot32(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _unit_lower_inverse_tile(a):
-    """(I + A)^-1 of one strictly lower-triangular [C, C] float32 tile by
-    log2(C) - 1 doublings, float32 products."""
-    c = a.shape[-1]
-    rows = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
-    inv = jnp.where(rows == cols, 1.0, 0.0) - a
-    power, reach = a, 2
+def _unit_lower_inverse_tiles(tiles):
+    """(I + A)^-1 of each strictly lower-triangular [C, C] float32 tile by
+    log2(C) - 1 doublings, float32 products. The tiles' chains share no
+    value: every chain's squaring, then every chain's multiply, a
+    doubling at a time, so that one chain's product can fill the MXU
+    while another's drains."""
+    c = tiles[0].shape[-1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    invs = [jnp.where(rows == cols, 1.0, 0.0) - a for a in tiles]
+    powers, reach = list(tiles), 2
     while reach < c:
-        power = _dot32(power, power, _NN)
-        inv = inv + _dot32(inv, power, _NN)
+        powers = [_dot32(p, p, _NN) for p in powers]
+        invs = [inv + _dot32(inv, p, _NN) for inv, p in zip(invs, powers)]
         reach *= 2
-    return inv
+    return invs
 
 
-def _delta_heads(q, k, v):
-    """A chunk's q, k, v [C, 128] as the convolution left them -> after
-    its SiLU, q and k L2-normed a row (q to length Dk^-1/2, k to 1), in
-    the operands' dtype."""
+def _unit_lower_inverse_pullbacks(invs, dinvs):
+    """d A = -T^T dT T^T of each tile, the chains side by side as above."""
+    halves = [_dot32(inv, dinv, _TN) for inv, dinv in zip(invs, dinvs)]
+    return [-_dot32(half, inv, _NT) for half, inv in zip(halves, invs)]
+
+
+def _delta_heads(q, k, vs):
+    """A chunk's q, k [C, 128] of a key head and the v [C, 128] of each
+    of its value heads as the convolution left them -> after its SiLU, q
+    and k L2-normed a row (q to length Dk^-1/2, k to 1), in the operands'
+    dtype."""
     f32, cd = jnp.float32, q.dtype
 
     def unit(x, scale):
@@ -4085,7 +4125,13 @@ def _delta_heads(q, k, v):
                                    + 1e-6) * scale)).astype(cd)
 
     return (unit(q, float(q.shape[1]) ** -0.5), unit(k, 1.0),
-            jax.nn.silu(v.astype(f32)).astype(cd))
+            tuple(jax.nn.silu(v.astype(f32)).astype(cd) for v in vs))
+
+
+def _delta_scores(q, k):
+    """(K K^T, Q K^T) [C, C] float32 of a key head's chunk, before any
+    decay, beta or mask: what its value heads share."""
+    return _dot(k, k, _NT), _dot(q, k, _NT)
 
 
 def _delta_decays(g_row):
@@ -4106,29 +4152,29 @@ def _column(row, rows, cols):    # [1, C] -> [C, 1]
     return jnp.sum(jnp.where(rows == cols, row, 0.0), axis=1, keepdims=True)
 
 
-def _delta_a(k, g_row, b_row):
-    """A = strict_tril(diag(beta) (K K^T) * exp(G_t - G_s)) of one chunk,
-    float32; k [C, Dk] normed."""
+def _delta_a(kk, g_row, b_row):
+    """A = strict_tril(diag(beta) (K K^T) * exp(G_t - G_s)) of one value
+    head's chunk, float32; kk = K K^T of the normed k."""
     rows, cols, _, _, decay = _delta_decays(g_row)
-    return jnp.where(rows > cols, _dot(k, k, _NT) * decay
-                     * _column(b_row, rows, cols), 0.0)
+    return jnp.where(rows > cols, kk * decay * _column(b_row, rows, cols),
+                     0.0)
 
 
-def _delta_walk(s, q, k, v, z, g_row, b_row, w_n, inv, *, eps: float):
+def _delta_walk(s, q, k, v, z, g_row, b_row, w_n, inv, qk, *, eps: float):
     """One chunk of one value head past the inverse: (y [C, Dv] float32,
     the state after it). s [Dk, Dv] float32 the state before; q, k, v as
     `_delta_heads` leaves them; z [C, Dv] the gate; g_row, b_row [1, C]
     float32: the running sum of the log-decays inside the chunk, and
     beta; w_n [1, Dv] float32 the head norm's scale; inv = (I + A)^-1
-    float32. The gated head norm is a row's mean over its 128 lanes, so
-    it is here. C = Dk (the columns serve both)."""
+    float32; qk = Q K^T float32. The gated head norm is a row's mean over
+    its 128 lanes, so it is here. C = Dk (the columns serve both)."""
     f32, cd = jnp.float32, q.dtype
     rows, cols, g_col, g_end, decay = _delta_decays(g_row)
     t = (inv * b_row).astype(cd)
     into = jnp.exp(g_col)
     w = _dot(t, (k.astype(f32) * into).astype(cd), _NN).astype(cd)
     u = _dot(t, v, _NN).astype(cd)
-    p = jnp.where(rows >= cols, _dot(q, k, _NT) * decay, 0.0).astype(cd)
+    p = jnp.where(rows >= cols, qk * decay, 0.0).astype(cd)
     qg = (q.astype(f32) * into).astype(cd)
     kg = (k.astype(f32) * jnp.exp(g_end - g_col)).astype(cd)
     sb = s.astype(cd)
@@ -4140,145 +4186,187 @@ def _delta_walk(s, q, k, v, z, g_row, b_row, w_n, inv, *, eps: float):
     return y, jnp.exp(g_end) * s + _dot(kg, vb, _TN)
 
 
-def _delta_fused_fwd_kernel(q_ref, k_ref, v_ref, z_ref, g_ref, b_ref, w_ref,
-                            y_ref, kept_ref, inv_ref, state, *, chunks: int,
-                            eps: float):
+def _delta_fused_fwd_kernel(*refs, heads: int, chunks: int, eps: float):
+    q_ref, k_ref = refs[:2]
+    v_refs = refs[2:2 + heads]
+    z_ref, g_ref, b_ref, w_ref, y_ref, kept_ref, inv_ref, state = \
+        refs[2 + heads:]
+
     @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
 
     def chunk(c, carry):
         rows = _chunk_rows(c)
-        s = state[...]
-        kept_ref[0, rows, :] = s
-        g_row, b_row = g_ref[0, 0, :, rows], b_ref[0, 0, :, rows]
-        q, k, v = _delta_heads(q_ref[0, rows, :], k_ref[0, rows, :],
-                               v_ref[0, rows, :])
-        inv = _unit_lower_inverse_tile(_delta_a(k, g_row, b_row))
-        inv_ref[0, rows, :] = inv
-        y, state[...] = _delta_walk(s, q, k, v, z_ref[0, rows, :], g_row,
-                                    b_row, w_ref[...], inv, eps=eps)
-        y_ref[0, rows, :] = y.astype(y_ref.dtype)
+        q, k, vs = _delta_heads(q_ref[0, rows, :], k_ref[0, rows, :],
+                                tuple(v[0, rows, :] for v in v_refs))
+        kk, qk = _delta_scores(q, k)
+        g_rows = [g_ref[0, h, :, rows] for h in range(heads)]
+        b_rows = [b_ref[0, h, :, rows] for h in range(heads)]
+        invs = _unit_lower_inverse_tiles(
+            [_delta_a(kk, g, b) for g, b in zip(g_rows, b_rows)])
+        for h, inv in enumerate(invs):
+            at = _head_lanes(h)
+            s = state[h]
+            kept_ref[0, rows, at] = s
+            inv_ref[0, rows, at] = inv
+            y, state[h] = _delta_walk(s, q, k, vs[h], z_ref[0, rows, at],
+                                      g_rows[h], b_rows[h], w_ref[...], inv,
+                                      qk, eps=eps)
+            y_ref[0, rows, at] = y.astype(y_ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, chunks, chunk, None)
 
 
-def _delta_fused_bwd_kernel(q_ref, k_ref, v_ref, z_ref, g_ref, b_ref, w_ref,
-                            kept_ref, inv_ref, dy_ref, dq_ref, dk_ref, dv_ref,
-                            dz_ref, dg_ref, db_ref, dw_ref, dstate, *,
-                            chunks: int, eps: float):
+def _delta_fused_bwd_kernel(*refs, heads: int, chunks: int, eps: float):
     """A chunk's functions differentiated where they stand, the chunks in
     reverse with dS carried. The inverse is not formed again: the forward
-    kept it, and its own backward is -T^T dT T^T."""
+    kept it, and its own backward is -T^T dT T^T. What the value heads
+    send back to their key head's q, k and scores is added in float32
+    and pulled through the scores and the norm once."""
+    q_ref, k_ref = refs[:2]
+    v_refs = refs[2:2 + heads]
+    (z_ref, g_ref, b_ref, w_ref, kept_ref, inv_ref, dy_ref, dq_ref, dk_ref,
+     dv_ref, dz_ref, dg_ref, db_ref, dw_ref, dstate) = refs[2 + heads:]
+    f32 = jnp.float32
+
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
     def chunk(j, carry):
-        c = chunks - 1 - j
-        rows = _chunk_rows(c)
-        g_row, b_row = g_ref[0, 0, :, rows], b_ref[0, 0, :, rows]
-        inv = inv_ref[0, rows, :]
-        (q, k, v), pull_heads = jax.vjp(
+        rows = _chunk_rows(chunks - 1 - j)
+        (q, k, vs), pull_heads = jax.vjp(
             _delta_heads, q_ref[0, rows, :], k_ref[0, rows, :],
-            v_ref[0, rows, :])
-        _, pull_walk = jax.vjp(
-            functools.partial(_delta_walk, eps=eps), kept_ref[0, rows, :],
-            q, k, v, z_ref[0, rows, :], g_row, b_row, w_ref[...], inv)
-        ds, dq, dk, dv, dz, dg, db, dw, dinv = pull_walk(
-            (dy_ref[0, rows, :].astype(jnp.float32), dstate[...]))
-        _, pull_a = jax.vjp(_delta_a, k, g_row, b_row)
-        dk_a, dg_a, db_a = pull_a(-_dot32(_dot32(inv, dinv, _TN), inv, _NT))
-        dk = (dk.astype(jnp.float32) + dk_a.astype(jnp.float32)).astype(
-            dk.dtype)
-        dq, dk, dv = pull_heads((dq, dk, dv))
-        dstate[...] = ds
+            tuple(v[0, rows, :] for v in v_refs))
+        (kk, qk), pull_scores = jax.vjp(_delta_scores, q, k)
+        g_rows = [g_ref[0, h, :, rows] for h in range(heads)]
+        b_rows = [b_ref[0, h, :, rows] for h in range(heads)]
+        invs = [inv_ref[0, rows, _head_lanes(h)] for h in range(heads)]
+        walked = []
+        for h in range(heads):
+            at = _head_lanes(h)
+            _, pull_walk = jax.vjp(
+                functools.partial(_delta_walk, eps=eps), kept_ref[0, rows, at],
+                q, k, vs[h], z_ref[0, rows, at], g_rows[h], b_rows[h],
+                w_ref[...], invs[h], qk)
+            walked.append(pull_walk(
+                (dy_ref[0, rows, at].astype(f32), dstate[h])))
+        das = _unit_lower_inverse_pullbacks(invs, [w[8] for w in walked])
+        dq = dk = dkk = dqk = 0.0
+        dvs = []
+        for h, (ds, dq_h, dk_h, dv, dz, dg, db, dw, _, dqk_h) in enumerate(
+                walked):
+            at = _head_lanes(h)
+            _, pull_a = jax.vjp(_delta_a, kk, g_rows[h], b_rows[h])
+            dkk_h, dg_a, db_a = pull_a(das[h])
+            dq, dk = dq + dq_h.astype(f32), dk + dk_h.astype(f32)
+            dkk, dqk = dkk + dkk_h, dqk + dqk_h
+            dvs.append(dv)
+            dstate[h] = ds
+            dz_ref[0, rows, at] = dz
+            dg_ref[0, h, :, rows] = dg + dg_a
+            db_ref[0, h, :, rows] = db + db_a
+            dw_ref[0, h] += dw
+        dq_s, dk_s = pull_scores((dkk, dqk))
+        dq, dk, dvs = pull_heads(((dq + dq_s.astype(f32)).astype(q.dtype),
+                                  (dk + dk_s.astype(f32)).astype(k.dtype),
+                                  tuple(dvs)))
         dq_ref[0, rows, :] = dq
         dk_ref[0, rows, :] = dk
-        dv_ref[0, rows, :] = dv
-        dz_ref[0, rows, :] = dz
-        dg_ref[0, 0, :, rows] = dg + dg_a
-        db_ref[0, 0, :, rows] = db + db_a
-        dw_ref[0, 0] += dw
+        for h, dv in enumerate(dvs):
+            dv_ref[0, rows, _head_lanes(h)] = dv
         return carry
 
     jax.lax.fori_loop(0, chunks, chunk, None)
 
 
 def _delta_fused_specs(s: int, key_heads: int, rep: int, reverse: bool):
-    """(rows, a value head's [rows, 128] block of its own array; q's, k's
-    and v's blocks of the ONE [B, S, (2 Hk + Hv) * 128] array the
-    convolution wrote; a head's [1, rows] row; the norm's scale; a value
-    head's [1, 128] sum). ``reverse``: the grid's last axis walks the
-    blocks of rows from the last to the first."""
+    """(rows, the value heads a step walks; their [rows, heads * 128]
+    block of an array of their own; a step's own [rows, 128] block (dq,
+    dk); q's and k's blocks and the heads' v blocks, 128 lanes each, of
+    the ONE [B, S, (2 Hk + Hv) * 128] array the convolution wrote; the
+    heads' [1, rows] rows; the norm's scale; the heads' [1, 128] sums).
+    The grid's second axis is a step's value heads, ``rep`` over the
+    heads a step of such steps to a key head. ``reverse``: the last axis
+    walks the blocks of rows from the last to the first."""
     rows = _delta_rows(s)
     blocks = s // rows
+    heads = delta_heads_a_step(rep)
+    steps_a_key_head = rep // heads
 
     def at(i):
         return blocks - 1 - i if reverse else i
 
-    def lanes(of_head):
-        return pl.BlockSpec((1, rows, LANES),
-                            lambda b, h, i: (b, at(i), of_head(h)))
+    def lanes(width, of_step):
+        return pl.BlockSpec((1, rows, width),
+                            lambda b, h, i: (b, at(i), of_step(h)))
 
-    return (rows, lanes(lambda h: h),
-            (lanes(lambda h: h // rep), lanes(lambda h: key_heads + h // rep),
-             lanes(lambda h: 2 * key_heads + h)),
-            pl.BlockSpec((1, 1, 1, rows), lambda b, h, i: (b, h, 0, at(i))),
+    return (rows, heads, lanes(heads * LANES, lambda h: h),
+            lanes(LANES, lambda h: h),
+            (lanes(LANES, lambda h: h // steps_a_key_head),
+             lanes(LANES, lambda h: key_heads + h // steps_a_key_head),
+             *(lanes(LANES, lambda h, j=j: 2 * key_heads + h * heads + j)
+               for j in range(heads))),
+            pl.BlockSpec((1, heads, 1, rows),
+                         lambda b, h, i: (b, h, 0, at(i))),
             pl.BlockSpec((1, LANES), lambda b, h, i: (0, 0)),
-            pl.BlockSpec((1, 1, 1, LANES), lambda b, h, i: (b, h, 0, 0)))
+            pl.BlockSpec((1, heads, 1, LANES), lambda b, h, i: (b, h, 0, 0)))
 
 
 def _delta_fused_forward(qkv, z, g, beta, w_n, key_heads, eps, interpret):
     b, s, width = z.shape
-    heads = width // LANES
-    rows, own, qkv_specs, row, scale, _ = _delta_fused_specs(
-        s, key_heads, heads // key_heads, False)
+    value_heads = width // LANES
+    rows, heads, own, _, qkv_specs, row, scale, _ = _delta_fused_specs(
+        s, key_heads, value_heads // key_heads, False)
     return pl.pallas_call(
-        functools.partial(_delta_fused_fwd_kernel,
+        functools.partial(_delta_fused_fwd_kernel, heads=heads,
                           chunks=rows // DELTA_CHUNK, eps=eps),
         name="delta_rule_fwd",
         out_shape=(jax.ShapeDtypeStruct(z.shape, z.dtype),
                    jax.ShapeDtypeStruct(z.shape, jnp.float32),
                    jax.ShapeDtypeStruct(z.shape, jnp.float32)),
-        grid=(b, heads, s // rows),
+        grid=(b, value_heads // heads, s // rows),
         in_specs=[*qkv_specs, own, row, row, scale],
         out_specs=(own, own, own),
-        scratch_shapes=[pltpu.VMEM((LANES, LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((heads, LANES, LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             **_DELTA_COMPILER_PARAMS),
-        interpret=interpret)(qkv, qkv, qkv, z, g, beta, w_n)
+        interpret=interpret)(*[qkv] * len(qkv_specs), z, g, beta, w_n)
 
 
 def _delta_fused_backward(qkv, z, g, beta, w_n, kept, inv, dy, key_heads,
                           eps, interpret):
     b, s, width = z.shape
-    heads = width // LANES
-    rep = heads // key_heads
-    rows, own, qkv_specs, row, scale, total = _delta_fused_specs(
-        s, key_heads, rep, True)
+    value_heads = width // LANES
+    rows, heads, own, step, qkv_specs, row, scale, total = \
+        _delta_fused_specs(s, key_heads, value_heads // key_heads, True)
+    steps = value_heads // heads
     like = jax.ShapeDtypeStruct(z.shape, z.dtype)
+    a_step = jax.ShapeDtypeStruct((b, s, steps * LANES), z.dtype)
     a_row = jax.ShapeDtypeStruct(g.shape, jnp.float32)
     dq, dk, dv, dz, dg, db, dw = pl.pallas_call(
-        functools.partial(_delta_fused_bwd_kernel,
+        functools.partial(_delta_fused_bwd_kernel, heads=heads,
                           chunks=rows // DELTA_CHUNK, eps=eps),
         name="delta_rule_bwd",
-        out_shape=(like, like, like, like, a_row, a_row,
-                   jax.ShapeDtypeStruct((b, heads, 1, LANES), jnp.float32)),
-        grid=(b, heads, s // rows),
+        out_shape=(a_step, a_step, like, like, a_row, a_row,
+                   jax.ShapeDtypeStruct((b, value_heads, 1, LANES),
+                                        jnp.float32)),
+        grid=(b, steps, s // rows),
         in_specs=[*qkv_specs, own, row, row, scale, own, own, own],
-        out_specs=(own, own, own, own, row, row, total),
-        scratch_shapes=[pltpu.VMEM((LANES, LANES), jnp.float32)],
+        out_specs=(step, step, own, own, row, row, total),
+        scratch_shapes=[pltpu.VMEM((heads, LANES, LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             **_DELTA_COMPILER_PARAMS),
-        interpret=interpret)(qkv, qkv, qkv, z, g, beta, w_n, kept, inv, dy)
-    if rep > 1:     # a key head's q and k served `rep` value heads
+        interpret=interpret)(*[qkv] * len(qkv_specs), z, g, beta, w_n, kept,
+                             inv, dy)
+    if steps > key_heads:   # a key head's value heads took several steps
         dq, dk = (jnp.sum(t.astype(jnp.float32).reshape(
-            b, s, key_heads, rep, LANES), axis=3).reshape(
+            b, s, key_heads, steps // key_heads, LANES), axis=3).reshape(
                 b, s, key_heads * LANES).astype(qkv.dtype) for t in (dq, dk))
     return (jnp.concatenate([dq, dk, dv], axis=-1), dz, dg, db,
             jnp.sum(dw, axis=(0, 1)))

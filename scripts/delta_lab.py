@@ -15,8 +15,18 @@ what a train step runs; a `.fwd` piece the value alone.
   norms, the rule, the gated head norm) as ONE kernel each way
   (`pallas_kernels.delta_rule_fused`: the inverse, W, U and the walk
   over the chunks in VMEM, the backward the chunk function's `jax.vjp`
-  inside the kernel; what ships where `DeltaMixer.walks_by_kernel` says
-  so);
+  inside the kernel; a grid step a KEY head whose value heads walk
+  together since PR 60; what ships where `DeltaMixer.walks_by_kernel`
+  says so);
+- `rule.value_head_step.{fwd,grad}` (PR 60): a grid step one VALUE
+  head, as PR 58 shipped the pair: the kernels that ship with
+  `pallas_kernels.MAX_DELTA_HEADS_A_STEP` held to 1, so a key head's q
+  and k fetched, normed and multiplied once a value head, dq and dk
+  written at value-head width and the pairs added by XLA;
+  `rule.key_head_serial.{fwd,grad}`: the shipped key-head step
+  with each head's whole inverse (and its backward's pair of products)
+  written one head after the other, not a doubling of every head at a
+  time: what the interleaving alone gives;
 - `rule.scan.{fwd,grad}`: the same in `jax.numpy`, the chunks' operands
   batched in XLA and the walk a `lax.scan` (where Pallas is off). (The
   RULE alone, q and k already normed and no head norm, was timed here
@@ -44,6 +54,9 @@ what a train step runs; a `.fwd` piece the value alone.
   dQ: a control of what the copies cost, not a candidate);
 - `check`: the kernel form against `delta_rule_stepwise` at 2,048
   positions (max abs error over the largest value, bfloat16 operands);
+  `rule_forms_vs_kernel`: the output and every gradient of the other
+  two forms of the pair against the shipped one's there (largest
+  difference over the largest value);
   `flash256_vs_two_kernels`: dQ, dK, dV of the shipped backward, of
   every block tried and of the control against the two-kernel form's
   (largest difference over the largest value; the control's dQ is far).
@@ -56,6 +69,7 @@ rehearsal: its times mean nothing). Nothing here is a benchmark metric.
 """
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -241,6 +255,30 @@ def two_kernel_wide_flash_bwd(q, k, v, o, lse, do, num_heads, causal,
     return dq, dk, dv
 
 
+def rule_forms():
+    """name -> what to hold in place while the rule's kernel pair is
+    traced, [(object, attribute, value), ...]: the pair that ships; a
+    grid step one VALUE head, as PR 58 shipped it (the same kernels held
+    to one head a step: a key head's q and k fetched, normed and
+    multiplied once a value head, dq and dk written at value-head width
+    and the pairs added by XLA; a copy of PR 58's own kernels, timed
+    beside it once in PR 60, read 13.52 + 9.09 ms where this reads 13.66
+    + 8.91); and the key-head step with the heads' inverses (and their
+    backward's products) one head after the other."""
+    from flexflow_tpu.ops import pallas_kernels as pk
+    tiles, pullbacks = (pk._unit_lower_inverse_tiles,
+                        pk._unit_lower_inverse_pullbacks)
+    return {
+        "kernel": [],
+        "value_head_step": [(pk, "MAX_DELTA_HEADS_A_STEP", 1)],
+        "key_head_serial": [
+            (pk, "_unit_lower_inverse_tiles",
+             lambda many: [tiles([a])[0] for a in many]),
+            (pk, "_unit_lower_inverse_pullbacks",
+             lambda invs, dinvs: [pullbacks([t], [d])[0]
+                                  for t, d in zip(invs, dinvs)])]}
+
+
 class _NoCopy:
     """A copy that is never made (`flash256.no_sums_traffic`)."""
 
@@ -258,28 +296,30 @@ BWD_BLOCKS = ((512, 1024, 1), (1024, 1024, 1), (1024, 1024, 2),
 
 def wide_bwd_forms(seq):
     """name -> what to hold in place while the backward at a head of 256
-    is traced, (object, attribute, value): the two-kernel form, the
+    is traced, [(object, attribute, value)]: the two-kernel form, the
     control without dQ's copies, the other blocks that divide ``seq``."""
     from flexflow_tpu.ops import pallas_kernels as pk
-    forms = {"two_kernels": (pk, "_wide_flash_bwd",
-                             two_kernel_wide_flash_bwd),
-             "no_sums_traffic": (pk.pltpu, "make_async_copy",
-                                 lambda *a: _NoCopy())}
+    forms = {"two_kernels": [(pk, "_wide_flash_bwd",
+                              two_kernel_wide_flash_bwd)],
+             "no_sums_traffic": [(pk.pltpu, "make_async_copy",
+                                  lambda *a: _NoCopy())]}
     forms.update(("q%d_k%d_run%d" % blk,
-                  (pk, "_wide_bwd_blocks", lambda s, blk=blk: blk))
+                  [(pk, "_wide_bwd_blocks", lambda s, blk=blk: blk)])
                  for blk in BWD_BLOCKS
                  if seq % blk[0] == 0 and seq % (blk[1] * blk[2]) == 0)
     return forms
 
 
 def traced_holding(held, fn):
-    """``fn``, traced with ``held`` (object, attribute, value) in place;
-    as it is where ``held`` is None."""
-    if held is None:
+    """``fn``, traced with every (object, attribute, value) of ``held``
+    in place; as it is where ``held`` is empty."""
+    if not held:
         return fn
 
     def run(*a):
-        with mock.patch.object(*held):
+        with contextlib.ExitStack() as stack:
+            for one in held:
+                stack.enter_context(mock.patch.object(*one))
             return fn(*a)
 
     return run
@@ -300,7 +340,7 @@ def pieces(seq, hk, hv, d, heads256, dtype, chunk):
         fn.__name__ = name.replace(".", "_")
         return jax.jit(fn)
 
-    def grad_of(name, fn, args, wgt, held=None):
+    def grad_of(name, fn, args, wgt, held=()):
         """The value and gradients of a weighted sum of ``fn`` (the
         weight an argument: no constant of the program), traced with
         ``held`` in place."""
@@ -312,12 +352,14 @@ def pieces(seq, hk, hv, d, heads256, dtype, chunk):
         out[name + ".grad"] = (named(name + ".grad", traced_holding(
             held, grads)), (wgt,) + args)
 
-    def both(name, fn, args, wgt):
-        out[name + ".fwd"] = (named(name + ".fwd", fn), args)
-        grad_of(name, fn, args, wgt)
+    def both(name, fn, args, wgt, held=()):
+        out[name + ".fwd"] = (named(name + ".fwd", traced_holding(held, fn)),
+                              args)
+        grad_of(name, fn, args, wgt, held)
 
-    both("rule.kernel", lambda *a: dr.delta_rule_core(
-        *a, hk, chunk, 1e-6, dtype, True), ins, wgt)
+    for form, held in rule_forms().items():
+        both("rule." + form, lambda *a: dr.delta_rule_core(
+            *a, hk, chunk, 1e-6, dtype, True), ins, wgt, held)
     both("rule.scan", lambda *a: dr.delta_rule_core(
         *a, hk, chunk, 1e-6, dtype, False), ins, wgt)
     n = seq // chunk
@@ -392,6 +434,31 @@ def check(seq, hk, hv, d, dtype, chunk):
             for name, o in got.items()}
 
 
+def rule_forms_check(seq, hk, hv, d, dtype, chunk):
+    """The output and every gradient of `rule_forms`' other forms against
+    the shipped pair's: largest difference over the largest value."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops import delta_rule as dr
+    ins, wgt = rule_inputs(seq, hk, hv, d, dtype, seed=5)
+
+    def of(held):
+        fn = jax.value_and_grad(lambda *a: jnp.sum(dr.delta_rule_core(
+            *a, hk, chunk, 1e-6, dtype, True).astype(jnp.float32) * wgt),
+            argnums=(0, 1, 2, 3, 4))
+        out, grads = jax.jit(traced_holding(held, fn))(*ins)
+        return [t.astype(jnp.float32) for t in (out, *grads)]
+
+    forms = rule_forms()
+    want = of(forms.pop("kernel"))
+    return {form: {name: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+                   for name, a, b in zip(
+                       ("out", "dqkv", "dz", "dg", "dbeta", "dscale"),
+                       of(held), want)}
+            for form, held in forms.items()}
+
+
 def flash_check(seq, heads256, dtype):
     """The shipped one-kernel backward and the lab's controls against the
     two-kernel form at the lab's shape: largest difference of each
@@ -450,12 +517,16 @@ def main():
     if opts.tiny:   # the CPU's profile has no device lines
         line["ran"] = sorted(jitted)
     else:
-        for name, (ms, ops) in device_ms(jitted).items():
+        for name, (ms, ops) in device_ms(jitted, stems=10).items():
             line[name + "_device_ms"] = round(ms, 4)
             line[name + "_device_ops"] = ops
     if opts.only in "check":
         line["check"] = check(short, shape["hk"], shape["hv"], shape["d"],
                               shape["dtype"], shape["chunk"])
+    if opts.only in "rule.check":
+        line["rule_forms_vs_kernel"] = rule_forms_check(
+            short, shape["hk"], shape["hv"], shape["d"], shape["dtype"],
+            shape["chunk"])
     if opts.only in "flash256.check":
         line["flash256_vs_two_kernels"] = flash_check(
             2048 if opts.tiny else shape["seq"], shape["heads256"],

@@ -4,9 +4,11 @@ divides, at C and 2 C, with a head that never forgets and one that
 always does; the reference's own recurrence and its control; the
 triangular inverse and its backward. And the kernels of PR 58 in
 `interpret` mode against their `jax.numpy` forms: the delta rule's kernel pair (`pallas_kernels.delta_rule_fused`,
-forward and backward, value heads twice the key heads, over one block of
-rows and over two) against the `jax.numpy` chunked form and the
-recurrence a position; the flash kernels at a head of 256 lanes, 16 : 2
+forward and backward, a grid step a KEY head since PR 60: value heads
+once, twice and four times the key heads over one block of rows, twice
+over two, and four times in steps of two) against the `jax.numpy`
+chunked form and the recurrence a position; that dq and dk leave the
+backward's call at key-head width; the flash kernels at a head of 256 lanes, 16 : 2
 style groups, causal with and without a window and plain, against the
 einsum path; the attention op with 64 rotated lanes of 256 and the gate a
 lane through them; the rules that say where they run."""
@@ -118,36 +120,48 @@ def core_inputs(length, hk=1, hv=2, d=128, seed=0):
     return qkv, z, g, beta, scale
 
 
-@pytest.mark.parametrize("length", [384, 2048])
-def test_rule_kernels_match_the_scan_forward_and_backward(interpret, length):
-    """One block of three chunks (the whole sequence), and two blocks of
-    eight with the state and its gradient carried between them: the
-    SiLU, the heads' L2 norms, the rule and the gated head norm as ONE kernel each
-    way against the `jax.numpy` form, and that against the recurrence a
+def rule_both_ways(kernel, key_heads, lanes):
+    """The value and the five gradients of a weighted sum of
+    `delta_rule_core`'s output, jitted."""
+    weight = jnp.cos(jnp.arange(float(lanes)))
+    return jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(dr.delta_rule_core(
+            *a, key_heads, 128, 1e-6, jnp.float32, kernel) * weight),
+        argnums=(0, 1, 2, 3, 4)))
+
+
+@pytest.mark.parametrize("length,hk,rep", [
+    (384, 1, 1),        # every value head its own keys
+    (384, 1, 2),
+    (384, 2, 4),        # the second key head's lanes and rows
+    (2048, 1, 2)])
+def test_rule_kernels_match_the_scan_forward_and_backward(interpret, length,
+                                                          hk, rep):
+    """One block of three chunks (the whole sequence) with one, two and
+    four value heads a key head walked together in a grid step (the four
+    under two key heads), and two blocks of eight chunks with the heads' states and
+    their gradients carried between grid steps: the SiLU, the heads' L2
+    norms, the rule and the gated head norm as ONE kernel each way
+    against the `jax.numpy` form, and that against the recurrence a
     position."""
-    ins = core_inputs(length)
-    weight = jnp.cos(jnp.arange(256.0))
-
-    def both(kernel):
-        return jax.jit(jax.value_and_grad(
-            lambda *a: jnp.sum(dr.delta_rule_core(
-                *a, 1, 128, 1e-6, jnp.float32, kernel) * weight),
-            argnums=(0, 1, 2, 3, 4)))
-
+    hv = hk * rep
+    assert pk.delta_heads_a_step(rep) == rep
+    ins = core_inputs(length, hk, hv)
     with HIGHEST:
-        (want, dwant), (got, dgot) = both(False)(*ins), both(True)(*ins)
-        if length == 384:
+        want, dwant = rule_both_ways(False, hk, hv * 128)(*ins)
+        got, dgot = rule_both_ways(True, hk, hv * 128)(*ins)
+        if (length, rep) == (384, 2):
             qkv, z, g, beta, scale = ins
-            f = jax.nn.silu(qkv).reshape(1, length, 4, 128)
-            q, k, v = f[:, :, :1], f[:, :, 1:2], f[:, :, 2:]
+            f = jax.nn.silu(qkv).reshape(1, length, 2 * hk + hv, 128)
+            q, k, v = f[:, :, :hk], f[:, :, hk:2 * hk], f[:, :, 2 * hk:]
             q = q / jnp.sqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
                 * 128 ** -0.5
             k = k / jnp.sqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
             step = dr.heads_rms_norm_gated(
                 jax.jit(dr.delta_rule_stepwise)(q, k, v, g, beta),
-                z.reshape(1, length, 2, 128), scale, 1e-6)
+                z.reshape(1, length, hv, 128), scale, 1e-6)
             out = jax.jit(lambda *a: dr.delta_rule_core(
-                *a, 1, 128, 1e-6, jnp.float32, True))(*ins)
+                *a, hk, 128, 1e-6, jnp.float32, True))(*ins)
             np.testing.assert_allclose(out.reshape(step.shape), step,
                                        rtol=1e-3, atol=5e-5)
     np.testing.assert_allclose(got, want, rtol=1e-5)
@@ -156,6 +170,94 @@ def test_rule_kernels_match_the_scan_forward_and_backward(interpret, length):
         np.testing.assert_allclose(np.asarray(a) / scale_,
                                    np.asarray(b) / scale_, atol=2e-5,
                                    err_msg=name)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (the kernels' own bodies apart: what XLA runs)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("rep,most,width,sums", [
+    (2, 4, 1, 0),       # the cell's: a key head a step, nothing to add
+    (4, 2, 2, 2)])      # four heads in steps of two: XLA adds the steps'
+def test_dq_and_dk_leave_the_backward_at_key_head_width(
+        interpret, monkeypatch, rep, most, width, sums):
+    """The backward's `pallas_call` returns dq and dk as [B, S, Hk * 128]
+    where a grid step walks all of a key head's value heads, and the
+    gradient of `delta_rule_fused` then holds no sum over them outside
+    the kernels; where a key head takes several steps
+    (`delta_heads_a_step`) XLA adds the steps' and the result is the
+    same."""
+    hk, length = 1, 256
+    qkv, z, g, beta, scale = core_inputs(length, hk, hk * rep)
+
+    def rows(t):
+        return jnp.moveaxis(t, 1, 2)[:, :, None]
+
+    args = (qkv, z, rows(jnp.cumsum(g.reshape(1, 2, 128, -1), 2).reshape(
+        g.shape)), rows(beta), scale[None])
+
+    def grad():     # a function of its own a trace: no cached one is reused
+        return jax.grad(lambda *a: jnp.sum(pk.delta_rule_fused(
+            *a, hk, 1e-6) ** 2), argnums=(0, 1, 2, 3, 4))
+
+    with HIGHEST:
+        want = jax.jit(grad())(*args)
+        monkeypatch.setattr(pk, "MAX_DELTA_HEADS_A_STEP", most)
+        assert pk.delta_heads_a_step(rep) == rep // width
+        eqns = list(_equations(jax.make_jaxpr(grad())(*args).jaxpr))
+        got = jax.jit(grad())(*args)
+    (bwd,) = [e for e in eqns if e.primitive.name == "pallas_call"
+              and e.params["name"] == "delta_rule_bwd"]
+    dq, dk, dv = (v.aval.shape for v in bwd.outvars[:3])
+    assert dq == dk == (1, length, hk * width * 128)
+    assert dv == (1, length, hk * rep * 128)
+    assert bwd.params["grid_mapping"].grid == (1, hk * width, 1)
+    # a sum over the value heads of a key head is a reduction of a
+    # [B, S, Hk, steps, 128] view
+    assert sum(e.primitive.name == "reduce_sum"
+               and len(e.invars[0].aval.shape) == 5 for e in eqns) == sums
+    for name, a, b in zip("qkv z g beta scale".split(), got, want):
+        top = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a) / top, np.asarray(b) / top,
+                                   atol=0 if width == 1 else 1e-6,
+                                   err_msg=name)
+
+
+def test_the_heads_a_grid_step_walks(interpret, monkeypatch):
+    """`delta_heads_a_step`, and `executor.delta_rule_heads_a_step` of
+    an op whose forward was traced: the value heads a key head where the
+    kernels run, 0 on the `lax.scan`."""
+    assert [pk.delta_heads_a_step(rep) for rep in (1, 2, 3, 4, 6, 7, 8)] == [
+        1, 2, 3, 4, 3, 1, 4]
+
+    def traced(value_heads):
+        layer = Layer(OperatorType.DELTA_MIXER, "op", [])
+        layer.properties.update(num_key_heads=1, num_value_heads=value_heads,
+                                key_head_dim=128, value_head_dim=128)
+        op = OpRegistry.create(layer, [(1, 256, 64)])
+        assert op.traced_gauges()["executor.delta_rule_heads_a_step"] == 0
+        jax.eval_shape(
+            lambda p, x: op.forward(p, [x], OpContext(
+                training=True, compute_dtype=jnp.float32)),
+            jax.eval_shape(op.init_params, jax.random.PRNGKey(0)),
+            jax.ShapeDtypeStruct((1, 256, 64), jnp.float32))
+        return op.traced_gauges()
+
+    assert traced(2) == {"executor.delta_mixer_ops": 1,
+                         "executor.delta_rule_kernel_ops": 1,
+                         "executor.delta_rule_heads_a_step": 2}
+    assert traced(1)["executor.delta_rule_heads_a_step"] == 1
+    monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "off")
+    assert traced(2) == {"executor.delta_mixer_ops": 1,
+                         "executor.delta_rule_kernel_ops": 0,
+                         "executor.delta_rule_heads_a_step": 0}
 
 
 def test_where_the_walk_runs_as_kernels(interpret, monkeypatch):

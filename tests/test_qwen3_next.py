@@ -92,7 +92,8 @@ def test_delta_mixer_matches_the_references_mixer():
                    p, [x[1:]])
     np.testing.assert_allclose(alone, got[1:], rtol=2e-4, atol=2e-5)
     assert op.traced_gauges() == {"executor.delta_mixer_ops": 1,
-                                  "executor.delta_rule_kernel_ops": 0}
+                                  "executor.delta_rule_kernel_ops": 0,
+                                  "executor.delta_rule_heads_a_step": 0}
     kind, counted = op._counters["delta/chunks"]
     assert kind == "sum" and float(counted) == 2 * 4 * 3   # ceil(19 / 8)
 
@@ -234,6 +235,22 @@ def test_model_against_the_reference_logits_and_three_losses(tiny):
     assert 0 < counters["delta/decay_min"] < counters["delta/decay_mean"] < 1
     assert counters["executor.delta_mixer_ops"] == 1
     assert counters["executor.delta_rule_kernel_ops"] == 0
+    assert counters["executor.delta_rule_heads_a_step"] == 0
+
+
+def test_the_heads_a_step_is_the_largest_ops_not_the_sum(tiny, monkeypatch):
+    """Two mixers that each walk two heads a step publish 2, while the
+    counts beside it add up (`executor.GAUGES_OF_THE_LARGEST_OP`)."""
+    ff = tiny[-1]
+    nodes = ff.executor.nodes
+    mixer = next(n for n in nodes if n.op.op_type == OperatorType.DELTA_MIXER)
+    monkeypatch.setattr(type(mixer.op), "traced_gauges", lambda self: {
+        "executor.delta_rule_kernel_ops": 1,
+        "executor.delta_rule_heads_a_step": 2})
+    monkeypatch.setattr(ff.executor, "nodes", list(nodes) + [mixer])
+    gauges = ff.executor.traced_gauges()
+    assert gauges["executor.delta_rule_kernel_ops"] == 2
+    assert gauges["executor.delta_rule_heads_a_step"] == 2
 
 
 @pytest.fixture(scope="module")

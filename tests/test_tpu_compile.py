@@ -710,7 +710,8 @@ class TestHybridDecoderKernels:
                 assert "delta_rule_fwd" in hlo and "delta_rule_bwd" in hlo
                 assert op.traced_gauges() == {
                     "executor.delta_mixer_ops": 1,
-                    "executor.delta_rule_kernel_ops": 1}
+                    "executor.delta_rule_kernel_ops": 1,
+                    "executor.delta_rule_heads_a_step": 2}
                 continue
             route = op._route
             assert (route.core, route.grouped_kv, route.wide_head,
