@@ -6,7 +6,9 @@ mirror the public ``config.json`` files (``nemotron_h``'s names where two
 families differ). New scope vs the reference, whose zoo has one file a
 family.
 
-    M   x + ssm_mixer(rms_norm(x))        Mamba-2 mixer (ops/ssm.py)
+    M   x + ssm_mixer(rms_norm(x))        Mamba-2 mixer (ops/ssm.py; its
+                                          scan one Pallas kernel each
+                                          way where the shape allows)
     E   x + moe_layer(rms_norm(x))        sigmoid top-k experts over the
                                           experts held, a shared expert
     *   x + attention(rms_norm(x))        causal GQA, no rotary embedding

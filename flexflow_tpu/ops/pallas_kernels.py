@@ -85,7 +85,10 @@ layer's sum of rows into their tokens, with its transpose
 47), between the product and a flash kernel; `gated_conv_lanes` (PR 45),
 the gated short convolution's pass between its two products; and
 `selective_scan` (PR 52), the Mamba-1 recurrence with a decay a channel
-and state, forward and backward, time walked inside the kernel.
+and state, forward and backward, time walked inside the kernel;
+`delta_rule_fused` (PR 58), the gated delta rule in chunks, and `ssd_scan`
+(PR 62), the Mamba-2 recurrence in chunks: one kernel each way, the state
+in VMEM, the backward the chunk function's `jax.vjp` inside the kernel.
 
 CPU fallback: the same kernels run under ``interpret=True`` when
 FLEXFLOW_TPU_PALLAS=interpret (used by the deviceless tests); otherwise
@@ -95,6 +98,7 @@ non-TPU backends take the XLA path.
 from __future__ import annotations
 
 import functools
+import operator
 import os
 
 import jax
@@ -3749,9 +3753,11 @@ def gated_conv_lanes(proj, w, gate: bool = True):
 #     h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
 #     y_t[c]    = sum_n h_t[n, c] C_t[n] + D[c] x_t[c]
 # The decay differs by channel AND state, so there is no chunked-product
-# form (`ops/ssm.py` `ssd_chunked`), and the state [N, C] a position is
-# never written to HBM: time is walked inside the kernel, the state stays
-# in VMEM. A vreg holds 8 x 128 = 1024 CHANNELS of one position
+# form (Mamba-2's, whose decay is one scalar a head, has one: `ssd_scan`
+# below is its home, `ops/ssm.py` `ssd_chunked` the same in `jax.numpy`),
+# and the state [N, C] a position is never written to HBM: time is walked
+# inside the kernel, the state stays in VMEM. A vreg holds 8 x 128 =
+# 1024 CHANNELS of one position
 # (operands [B, S, C / 128, 128], whose (8, 128) tiles XLA lays out in
 # one pass over [B, S, C]), the N states are N such arrays, and B_t[n],
 # C_t[n] are scalars out of SMEM: every sum over the states is a
@@ -4408,6 +4414,375 @@ def delta_rule_fused(qkv, z, g, beta, w_n, key_heads: int, eps: float):
     `jax.numpy`."""
     return _delta_rule_fused(qkv, z, g, beta, w_n, key_heads, eps,
                              pallas_mode() == "interpret")
+
+
+# ---------------------------------------------------------------------------
+# The chunked scan of a Mamba-2 mixer (PR 62): a head's decay is ONE
+# scalar a position, so a chunk of Q positions is four matrix products
+# (`ops/ssm.py` `ssd_chunked`, the same rule in `jax.numpy`): with cs the
+# running sum of dt A inside the chunk and S the state that enters it,
+#     Y = ((C B^T) * exp(cs_l - cs_s) [s <= l]) (dt x) + exp(cs) * (C S) + D x
+#     S <- exp(cs_Q) S + (B * exp(cs_Q - cs))^T (dt x)
+# EVERYTHING of a chunk stays in VMEM: the running sums (a product with a
+# triangle of ones, exact in float32: `_by_pieces`), a head's [Q, Q]
+# decay tile, the scores, dt x
+# and the state, a [N, H*P] float32 scratch carried from chunk to chunk
+# and from grid step to grid step: the state's lanes are the heads' lanes
+# of x, so C S and the state's update are ONE product each for the heads
+# of a 128-lane tile (one head of 128 or two of 64, whose score tiles
+# meet the tile's dt x with the other head's lanes zeroed). C B^T is
+# formed once a group. Nothing of a chunk but its output and the state
+# that entered it (kept for the backward) goes to HBM. The backward is the
+# same function's `jax.vjp`, taken inside the kernel a chunk at a time in
+# reverse with dS carried (PR 58's form); a product's backward rounds the
+# cotangent to the operands' dtype, as the flash kernels round dS.
+SSD_ROWS = 1024       # rows a grid step, where S allows
+MAX_SSD_LANES = 1024  # H * P: a block holds its rows of every head
+_SSD_COMPILER_PARAMS = dict(vmem_limit_bytes=64 << 20)
+
+
+def ssd_shape_legal(seq_len: int, heads: int, head_dim: int, groups: int,
+                    state: int, chunk: int) -> bool:
+    """The shapes `ssd_scan` takes: heads of 64 or 128 lanes that fill
+    whole 128-lane tiles, up to MAX_SSD_LANES lanes of them, on groups
+    that divide them, a state of 128, chunks of 128 or 256 positions;
+    any length (padded to whole blocks of rows with dt = 0)."""
+    return (seq_len >= 1 and head_dim in (64, LANES)
+            and (heads * head_dim) % LANES == 0
+            and heads * head_dim <= MAX_SSD_LANES
+            and groups >= 1 and heads % groups == 0
+            and state == LANES and chunk in (128, 256))
+
+
+def _ssd_rows(s: int, chunk: int) -> int:
+    """Rows a grid step: SSD_ROWS, or the whole (padded) sequence where
+    that is shorter."""
+    return min(SSD_ROWS, -(-s // chunk) * chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dot_rounding_back(a, b, dims):
+    """`_dot` whose backward rounds the cotangent to the operands' dtype
+    before the two products it enters (one MXU pass each in bfloat16, as
+    XLA's default precision multiplies a float32 cotangent on the chip)."""
+    return _dot(a, b, dims)
+
+
+def _dot_rounding_back_fwd(a, b, dims):
+    return _dot(a, b, dims), (a, b)
+
+
+# dims of out -> (dims of d a from (g, b) or (b, g), ... of d b)
+_DOT_PULLBACKS = {_NN: (lambda g, a, b: (_dot(g, b, _NT), _dot(a, g, _TN))),
+                  _NT: (lambda g, a, b: (_dot(g, b, _NN), _dot(g, a, _TN))),
+                  _TN: (lambda g, a, b: (_dot(b, g, _NT), _dot(a, g, _NN)))}
+
+
+def _dot_rounding_back_bwd(dims, kept, g):
+    a, b = kept
+    da, db = _DOT_PULLBACKS[dims](g.astype(a.dtype), a, b)
+    return da.astype(a.dtype), db.astype(b.dtype)
+
+
+_dot_rounding_back.defvjp(_dot_rounding_back_fwd, _dot_rounding_back_bwd)
+
+
+def _by_pieces(x, axis: int, product, out_axis: int):
+    """``product`` of a float32 x with a matrix of zeros and ones, exact:
+    x goes in as its three bfloat16 pieces side by side along ``axis``
+    (they hold all 24 bits, and a product with 0 or 1 rounds nothing) and
+    the three results, side by side along ``out_axis``, are added: ONE
+    MXU pass where a float32 product takes six."""
+    n = x.shape[axis]
+    out = product(jnp.concatenate(_bf16_pieces(x, 3), axis=axis))
+    return sum(jax.lax.slice_in_dim(out, i * n, (i + 1) * n, axis=out_axis)
+               for i in range(3))
+
+
+def _ones(q: int, relation):
+    """[Q, Q] bfloat16: 1 where ``relation(row, column)``, else 0."""
+    return jnp.where(relation(
+        jax.lax.broadcasted_iota(jnp.int32, (q, q), 0),
+        jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)), 1.0, 0.0).astype(
+            jnp.bfloat16)
+
+
+@jax.custom_vjp
+def _running_sums(x):
+    """x [Q, H] float32 -> its running sums down the rows, float32."""
+    return _by_pieces(x, 1, lambda p: _dot(
+        _ones(x.shape[0], operator.ge), p, _NN), 1)
+
+
+def _sums_from_the_end(g):
+    return _by_pieces(g, 1, lambda p: _dot(
+        _ones(g.shape[0], operator.le), p, _NN), 1)
+
+
+_running_sums.defvjp(lambda x: (_running_sums(x), None),
+                     lambda _, g: (_sums_from_the_end(g),))
+
+
+@jax.custom_vjp
+def _rows_along_lanes(x):
+    """x [Q, H] float32 -> [H, Q], the same numbers."""
+    return _by_pieces(x, 1, lambda p: _dot(
+        p, _ones(x.shape[0], operator.eq), _TN), 0)
+
+
+def _lanes_down_rows(g):    # [H, Q] -> [Q, H]
+    return _by_pieces(g, 0, lambda p: _dot(
+        _ones(g.shape[1], operator.eq), p, _NT), 1)
+
+
+_rows_along_lanes.defvjp(lambda x: (_rows_along_lanes(x), None),
+                         lambda _, g: (_lanes_down_rows(g),))
+
+
+def _ssd_chunk(st, x, dt, a, d, bm, cm, *, heads: int, groups: int):
+    """One chunk of every head: (y [Q, H*P] float32 with D x, the state
+    after it). st [N, H*P] float32 the state before (a head's P lanes
+    where x has them); x [Q, H*P], bm, cm [Q, G*N] in the products' dtype;
+    dt [Q, H], a [1, H] (negative), d [1, H*P] float32."""
+    f32, cd = jnp.float32, x.dtype
+    q, width = x.shape
+    p, n, rep = width // heads, st.shape[0], heads // groups
+    a_tile = LANES // p     # heads a 128-lane tile
+    rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    lower = rows >= cols
+    da = dt * a
+    # the running sum down the rows [Q, H], and the same numbers along
+    # the lanes [H, Q] (what a decay tile's diagonal sends back to them
+    # cancels before the sum's pullback)
+    cs = _running_sums(da)
+    cs_row = _rows_along_lanes(cs)
+    end = cs[q - 1:q]
+    since_start, to_end, whole = jnp.exp(cs), jnp.exp(end - cs), jnp.exp(end)
+    xf, bf, cf = x.astype(f32), bm.astype(f32), cm.astype(f32)
+    skip = d * xf
+
+    def of_group(t, g):
+        return t[:, g * n:(g + 1) * n].astype(cd)
+
+    cb = [_dot_rounding_back(of_group(cf, g), of_group(bf, g), _NT)
+          for g in range(groups)]
+
+    def first(t):   # the lanes of a tile's first head
+        return jax.lax.broadcasted_iota(jnp.int32, t.shape, 1) < p
+
+    def head_lanes(v, tile):    # [R, H] -> [R, 128]: a head's value its lanes
+        h = tile * a_tile
+        shape = (v.shape[0], LANES)
+        own = jnp.broadcast_to(v[:, h:h + 1], shape)
+        if a_tile == 1:
+            return own
+        return jnp.where(first(own), own,
+                         jnp.broadcast_to(v[:, h + 1:h + 2], shape))
+
+    ys, states = [], []
+    for tile in range(width // LANES):
+        at = slice(tile * LANES, (tile + 1) * LANES)
+        tile_heads = range(tile * a_tile, (tile + 1) * a_tile)
+        xd = xf[:, at] * head_lanes(dt, tile)
+        xe = xd * head_lanes(to_end, tile)
+        s_in = st[:, at]
+
+        def only(t, members):
+            """t with the lanes of the tile's other heads zeroed."""
+            if len(members) == a_tile:
+                return t
+            if members[0] == tile_heads[0]:
+                return jnp.where(first(t), t, 0.0)
+            return jnp.where(first(t), 0.0, t)
+
+        y = skip[:, at]
+        for h in tile_heads:
+            decay = jnp.exp(jnp.where(
+                lower, cs[:, h:h + 1] - cs_row[h:h + 1], _MASKED))
+            y = y + _dot_rounding_back(
+                (cb[h // rep] * decay).astype(cd),
+                only(xd, [h]).astype(cd), _NN)
+        entered, own = 0.0, 0.0
+        for g in sorted({h // rep for h in tile_heads}):
+            members = [h for h in tile_heads if h // rep == g]
+            entered = entered + _dot_rounding_back(
+                of_group(cf, g), only(s_in, members).astype(cd), _NN)
+            own = own + _dot_rounding_back(
+                of_group(bf, g), only(xe, members).astype(cd), _TN)
+        ys.append(y + entered * head_lanes(since_start, tile))
+        states.append(s_in * head_lanes(whole, tile) + own)
+    return jnp.concatenate(ys, axis=1), jnp.concatenate(states, axis=1)
+
+
+def _ssd_chunk_rows(c, chunk: int):
+    return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+
+
+def _ssd_operands(xbc_ref, rows, width: int):
+    """x, B, C of a chunk: lane ranges of the convolution's one array."""
+    gn = (xbc_ref.shape[2] - width) // 2
+    return (xbc_ref[0, rows, :width], xbc_ref[0, rows, width:width + gn],
+            xbc_ref[0, rows, width + gn:])
+
+
+def _ssd_fwd_kernel(xbc_ref, dt_ref, a_ref, d_ref, y_ref, kept_ref, state, *,
+                    heads: int, groups: int, chunk: int):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    def walk(c, carry):
+        rows = _ssd_chunk_rows(c, chunk)
+        x, bm, cm = _ssd_operands(xbc_ref, rows, state.shape[1])
+        kept_ref[0, c] = state[...]
+        y_ref[0, rows, :], state[...] = _ssd_chunk(
+            state[...], x, dt_ref[0, rows, :], a_ref[...], d_ref[...], bm, cm,
+            heads=heads, groups=groups)
+        return carry
+
+    jax.lax.fori_loop(0, xbc_ref.shape[1] // chunk, walk, None)
+
+
+def _ssd_bwd_kernel(xbc_ref, dt_ref, a_ref, d_ref, kept_ref, dy_ref,
+                    dxbc_ref, ddt_ref, da_ref, dd_ref, dstate, *, heads: int,
+                    groups: int, chunk: int):
+    """A chunk's function differentiated where it stands, the chunks in
+    reverse with dS carried; dx, dB and dC leave as the lane ranges of ONE
+    array, as x, B and C came; dA and dD add up over a sample's rows."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+        da_ref[...] = jnp.zeros_like(da_ref)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+
+    chunks = xbc_ref.shape[1] // chunk
+    width = dstate.shape[1]
+
+    def walk(j, carry):
+        c = chunks - 1 - j
+        rows = _ssd_chunk_rows(c, chunk)
+        x, bm, cm = _ssd_operands(xbc_ref, rows, width)
+        _, pull = jax.vjp(
+            functools.partial(_ssd_chunk, heads=heads, groups=groups),
+            kept_ref[0, c], x, dt_ref[0, rows, :], a_ref[...], d_ref[...],
+            bm, cm)
+        dstate[...], dx, ddt_ref[0, rows, :], da, dd, db, dc = pull(
+            (dy_ref[0, rows, :], dstate[...]))
+        gn = db.shape[1]
+        dxbc_ref[0, rows, :width] = dx
+        dxbc_ref[0, rows, width:width + gn] = db
+        dxbc_ref[0, rows, width + gn:] = dc
+        da_ref[0] += da
+        dd_ref[0] += dd
+        return carry
+
+    jax.lax.fori_loop(0, chunks, walk, None)
+
+
+def _ssd_call(xbc, dt, a, d, groups, state, chunk, interpret, kept=None,
+              dy=None):
+    """The forward kernel, or with ``kept`` and ``dy`` the backward: the
+    operands padded to whole blocks of rows (dt = 0: decay 1, no input)
+    and the per-head rates laid as rows."""
+    f32 = jnp.float32
+    batch, s, lanes_in = xbc.shape
+    heads, width = dt.shape[-1], lanes_in - 2 * groups * state
+    rows = _ssd_rows(s, chunk)
+    pad = (-s) % rows
+    if pad:
+        xbc, dt, dy = (t if t is None else jnp.pad(
+            t, ((0, 0), (0, pad), (0, 0))) for t in (xbc, dt, dy))
+    blocks, a_block = (s + pad) // rows, rows // chunk
+    reverse = kept is not None
+
+    def at(i):
+        return blocks - 1 - i if reverse else i
+
+    def lanes(w):
+        return pl.BlockSpec((1, rows, w), lambda b, i: (b, at(i), 0))
+
+    def whole(w):
+        return pl.BlockSpec((1, w), lambda b, i: (0, 0))
+
+    def summed(w):
+        return pl.BlockSpec((1, 1, w), lambda b, i: (b, 0, 0))
+
+    states = pl.BlockSpec((1, a_block, state, width),
+                          lambda b, i: (b, at(i), 0, 0))
+    operands = (xbc, dt.astype(f32), a.astype(f32)[None],
+                jnp.repeat(d.astype(f32), width // heads)[None])
+    in_specs = [lanes(lanes_in), lanes(heads), whole(heads), whole(width)]
+    params = dict(heads=heads, groups=groups, chunk=chunk)
+    shaped = jax.ShapeDtypeStruct
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"), **_SSD_COMPILER_PARAMS)
+    scratch = [pltpu.VMEM((state, width), f32)]
+    if not reverse:
+        y, kept = pl.pallas_call(
+            functools.partial(_ssd_fwd_kernel, **params),
+            name="ssd_scan_fwd",
+            out_shape=(shaped((batch, s + pad, width), f32),
+                       shaped((batch, blocks * a_block, state, width), f32)),
+            grid=(batch, blocks), in_specs=in_specs,
+            out_specs=(lanes(width), states), scratch_shapes=scratch,
+            compiler_params=compiler_params, interpret=interpret)(*operands)
+        return y[:, :s], kept
+    dxbc, ddt, da, dd = pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, **params),
+        name="ssd_scan_bwd",
+        out_shape=(shaped(xbc.shape, xbc.dtype), shaped(dt.shape, f32),
+                   shaped((batch, 1, heads), f32),
+                   shaped((batch, 1, width), f32)),
+        grid=(batch, blocks),
+        in_specs=in_specs + [states, lanes(width)],
+        out_specs=(lanes(lanes_in), lanes(heads), summed(heads),
+                   summed(width)),
+        scratch_shapes=scratch, compiler_params=compiler_params,
+        interpret=interpret)(*operands, kept, dy.astype(f32))
+    return (dxbc[:, :s], ddt[:, :s], jnp.sum(da, axis=(0, 1)),
+            jnp.sum(dd.reshape(batch, heads, -1), axis=(0, 2)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _ssd_scan(xbc, dt, a, d, groups, state, chunk, interpret):
+    return _ssd_call(xbc, dt, a, d, groups, state, chunk, interpret)[0]
+
+
+def _ssd_scan_fwd(xbc, dt, a, d, groups, state, chunk, interpret):
+    y, kept = _ssd_call(xbc, dt, a, d, groups, state, chunk, interpret)
+    return y, (xbc, dt, a, d, kept)
+
+
+def _ssd_scan_bwd(groups, state, chunk, interpret, res, dy):
+    *operands, kept = res
+    # a cotangent in its operand's dtype
+    return tuple(g.astype(t.dtype) for g, t in zip(
+        _ssd_call(*operands, groups, state, chunk, interpret, kept, dy),
+        operands))
+
+
+_ssd_scan.defvjp(_ssd_scan_fwd, _ssd_scan_bwd)
+
+
+def ssd_scan(xbc, dt, a, d, groups: int, state: int, chunk: int):
+    """y [B, S, H*P] float32 of the Mamba-2 recurrence above, D x
+    included. xbc [B, S, H*P + 2 G*N]: x, B and C side by side along the
+    lanes in the products' dtype, the ONE array the convolution left (the
+    kernels take their lane ranges of a block: nothing is sliced or laid
+    out again in HBM, no [B, S, H, P] view, and the backward writes
+    their gradients as one array of the same form); dt [B, S, H] float32
+    (after its softplus), a [H] (negative) and d [H]. The state is zero
+    at a sample's start and the recurrence runs in chunks of ``chunk``
+    positions. Decays, their running sums, every `exp` and the carried
+    state are float32; the products take their operands in xbc's dtype
+    and accumulate in float32. ONE kernel forward (`ssd_scan_fwd`, which
+    keeps the state that entered every chunk, [B, S / chunk, N, H*P]
+    float32) and ONE backward (`ssd_scan_bwd`). Caller checks
+    `pallas_mode` and `ssd_shape_legal`; `ops.ssm.ssd_chunked` is the
+    same in `jax.numpy`."""
+    return _ssd_scan(xbc, dt, a, d, groups, state, chunk,
+                     pallas_mode() == "interpret")
 
 
 def pallas_mode() -> str:

@@ -17,14 +17,19 @@ scope (`ssm_mixer`, inside it `ssd_scan`).
     y = GroupRMSNorm(y * silu(z)) * scale                  (G groups)
     out = y W_out
 
-The recurrence is computed in chunks of `chunk_size` positions
-(`ssd_chunked`): inside a chunk by the masked decay matrix (matrix
-products on the MXU), between chunks by a recurrence over the chunks'
-end states (`lax.scan`, linear in the sequence). Decays and cumulative
-sums are float32 whatever the compute dtype; the state is zero at the
-start of every sequence. Its backward pass is the autodiff of that
-program: the decay exponent is masked BEFORE the exponential, so the
-upper triangle contributes exact zeros to value and gradient alike.
+The recurrence is computed in chunks of `chunk_size` positions: inside a
+chunk by the masked decay matrix (matrix products on the MXU), between
+chunks by the state that leaves one and enters the next. Decays and
+cumulative sums are float32 whatever the compute dtype; the state is
+zero at the start of every sequence; the decay exponent is masked BEFORE
+the exponential, so the upper triangle contributes exact zeros to value
+and gradient alike. Where Pallas is on and `ssd_shape_legal` admits the
+shape (`SSMMixer.scans_by_kernel`) that is ONE kernel forward and one
+backward (`pallas_kernels.ssd_scan`, PR 62: a chunk's tiles and the
+state stay in VMEM, the backward is the chunk function's `jax.vjp`
+inside the kernel) under the scope `ssd_scan`; everywhere else it is
+`ssd_chunked`, the same in `jax.numpy` (every chunk's own end state as
+an array, a `lax.scan` over them, the backward by autodiff).
 `ssd_stepwise` is the same recurrence one position at a time, for tests.
 
 Beside it the Mamba-1 mixer (`MambaMixer`, PR 52), whose decay differs by
@@ -207,10 +212,25 @@ class SSMMixer(Op):
         self.conv_dim = self.d_inner + 2 * self.n_groups * self.state_size
         self.kernel_init = (p.get("kernel_initializer")
                             or DefaultWeightInitializer())
+        # whether the last traced forward ran the kernels (`traced_gauges`)
+        self._in_kernel = None
         super().__init__(layer, input_shapes)
 
     def compute_output_shapes(self):
         return [tuple(self.input_shapes[0])]
+
+    def scans_by_kernel(self, mesh, seq=None) -> bool:
+        """Whether the scan over ``seq`` positions (the op's own by
+        default) runs as the kernel pair `pallas_kernels.ssd_scan`:
+        Pallas on, a shape `ssd_shape_legal` admits, and one device (a
+        bare kernel call has no partitioning); else `ssd_chunked`."""
+        from flexflow_tpu.ops import pallas_kernels as pk
+        seq = self.input_shapes[0][1] if seq is None else seq
+        return bool(pk.pallas_mode() != "off"
+                    and pk.ssd_shape_legal(
+                        seq, self.num_heads, self.head_dim, self.n_groups,
+                        self.state_size, self.chunk_size)
+                    and (mesh is None or mesh.devices.size == 1))
 
     def init_params(self, rng):
         e = self.input_shapes[0][-1]
@@ -234,11 +254,27 @@ class SSMMixer(Op):
         }
 
     def forward(self, params, inputs, ctx: OpContext):
+        from flexflow_tpu.ops import pallas_kernels as pk
+
         (x,) = inputs
         cd = ctx.compute_dtype
         b, s, _ = x.shape
         h, p, g, n = (self.num_heads, self.head_dim, self.n_groups,
                       self.state_size)
+        self._in_kernel = self.scans_by_kernel(ctx.mesh, s)
+
+        def scan(xbc, dt, a, d):
+            """y [B, S, d_inner] float32 with D x, from the convolved
+            [x ; B ; C] at the lanes they have."""
+            if self._in_kernel:
+                return pk.ssd_scan(xbc, dt, a, d, g, n, self.chunk_size)
+            xs = xbc[..., :self.d_inner].reshape(b, s, h, p)
+            bm = xbc[..., self.d_inner:self.d_inner + g * n]
+            cm = xbc[..., self.d_inner + g * n:]
+            y = ssd_chunked(xs, dt, a, bm.reshape(b, s, g, n),
+                            cm.reshape(b, s, g, n), self.chunk_size, cd)
+            y = y + d.astype(jnp.float32)[:, None] * xs.astype(jnp.float32)
+            return y.reshape(b, s, self.d_inner)
 
         def mixer(params, x):
             proj = jnp.einsum("bse,ef->bsf", x.astype(cd),
@@ -251,22 +287,20 @@ class SSMMixer(Op):
             xbc = jax.nn.silu(causal_depthwise_conv1d(
                 xbc.astype(cd), params["conv_w"], params["conv_b"])
             ).astype(cd)
-            xs = xbc[..., :self.d_inner].reshape(b, s, h, p)
-            bm = xbc[..., self.d_inner:self.d_inner + g * n].reshape(
-                b, s, g, n)
-            cm = xbc[..., self.d_inner + g * n:].reshape(b, s, g, n)
             a = -jnp.exp(params["a_log"].astype(jnp.float32))
-            y = scoped("ssd_scan", lambda *t: ssd_chunked(
-                *t, self.chunk_size, cd))(xs, dt, a, bm, cm)
-            y = y + params["d"].astype(jnp.float32)[:, None] \
-                * xs.astype(jnp.float32)
-            y = gated_group_rms_norm(y.reshape(b, s, self.d_inner), z,
-                                     params["norm_scale"], g, self.eps)
+            y = scoped("ssd_scan", scan)(xbc, dt, a, params["d"])
+            y = gated_group_rms_norm(y, z, params["norm_scale"], g,
+                                     self.eps)
             return jnp.einsum("bsf,fe->bse", y.astype(cd),
                               params["w_out"].astype(cd),
                               preferred_element_type=jnp.float32)
 
         return [scoped(self.scopes_itself, mixer)(params, x).astype(x.dtype)]
+
+    def traced_gauges(self):
+        """`ssm/ssd_kernel_ops`: 1 where the op's scan ran as the Pallas
+        kernel pair when it was last traced, 0 where as `ssd_chunked`."""
+        return {"ssm/ssd_kernel_ops": int(bool(self._in_kernel))}
 
     def output_dim_roles(self):
         # the sequence dim recurs: not position-independent, so no SEQ role
@@ -290,14 +324,20 @@ class SSMMixer(Op):
     def interior_bytes(self):
         """Bytes the op keeps for its backward pass besides its output:
         the projection (z, xBC, dt), the convolved xBC and the normalised
-        y, at the op's element size; the per-chunk decay matrices and
-        states in float32."""
+        y, at the op's element size; in float32 the state that enters
+        every chunk and, at a shape the kernel pair does not take
+        (`ssd_chunked`'s arrays), a chunk's decay matrix a head."""
+        from flexflow_tpu.ops.pallas_kernels import ssd_shape_legal
+
         b, s, _ = self.input_shapes[0]
         q = self.chunk_size
         width = 2 * (self.d_inner + self.conv_dim) + self.num_heads
         chunks = -(-s // q)
+        tiles = 0 if ssd_shape_legal(
+            s, self.num_heads, self.head_dim, self.n_groups,
+            self.state_size, q) else q * q
         f32 = 4 * b * chunks * self.num_heads * (
-            q * q + self.head_dim * self.state_size)
+            tiles + self.head_dim * self.state_size)
         return b * s * width * self.dtype.size + f32
 
     def params_elems(self):

@@ -547,6 +547,56 @@ class TestHybridDecoderKernels:
         # 128 x 128, a few copies live at once
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
+    def test_scan_kernels_of_the_mixer_at_the_cells_widths(self, topo,
+                                                          on_tpu):
+        """The Mamba-2 mixer of the nemotron cell (8,192 positions, hidden
+        2688, 8 heads of 64 on one group, a state of 128, chunks of 128,
+        bfloat16), forward and backward (PR 62): the scan is two kernels,
+        `ssd_scan_fwd` and `ssd_scan_bwd`, that compile inside the VMEM
+        they ask for, both under `ssm_mixer` / `ssd_scan` (part `ssm`);
+        what the pair keeps is the state that enters every chunk, 16.8 MB
+        of float32, and no [.., 128, 128] float32 decay tile a chunk and
+        head is left in HBM (`ssd_chunked` above keeps several: eight
+        heads x 64 chunks of them are 33.5 MB each)."""
+        from flexflow_tpu.ffconst import OperatorType
+        from flexflow_tpu.layer import Layer
+        from flexflow_tpu.ops.base import OpContext, OpRegistry
+        seq, hidden = 8192, 2688
+        one = SingleDeviceSharding(topo.devices[0])
+        layer = Layer(OperatorType.SSM_MIXER, "mixer", [])
+        layer.properties.update(num_heads=8, head_dim=64, n_groups=1,
+                                state_size=128, chunk_size=128)
+        op = OpRegistry.create(layer, [(1, seq, hidden)])
+        assert op.scans_by_kernel(None)
+        params = {
+            leaf: jax.ShapeDtypeStruct(
+                a.shape, jnp.float32 if leaf in op.full_precision_params
+                else jnp.bfloat16, sharding=one)
+            for leaf, a in jax.eval_shape(
+                op.init_params, jax.random.PRNGKey(0)).items()}
+        x = jax.ShapeDtypeStruct((1, seq, hidden), jnp.bfloat16, sharding=one)
+        ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
+        compiled = jax.jit(jax.grad(lambda p, x: op.forward(
+            p, [x], ctx)[0].astype(jnp.float32).sum(), argnums=(0, 1))).lower(
+                params, x).compile()
+        hlo = compiled.as_text()
+        assert op.traced_gauges() == {"ssm/ssd_kernel_ops": 1}
+        assert pallas_kernel_count(hlo) == 2
+        table = table_of(hlo)
+        kernels = [table[re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)[1]]
+                   for line in hlo.splitlines()
+                   if "custom_call_target=\"tpu_custom_call\"" in line]
+        assert sorted((r["part"], r["direction"]) for r in kernels) == [
+            ("ssm", "backward"), ("ssm", "forward")], kernels
+        assert all("jit(ssm_mixer)" in r["op_name"]
+                   and "jit(ssd_scan)" in r["op_name"] for r in kernels)
+        assert "ssd_scan_fwd" in hlo and "ssd_scan_bwd" in hlo
+        assert not re.search(r"f32\[(?:\d+,)*128,128\]", re.sub(
+            r"f32\[1,64,128,512\]", "", hlo))
+        assert re.search(r"f32\[1,64,128,512\]", hlo)   # the states kept
+        # the projection, the convolved [x ; B ; C], y and their
+        # gradients, the states: no more than the `jax.numpy` form's tiles
+        assert compiled.memory_analysis().temp_size_in_bytes < 400 << 20
 
     def test_gated_conv_op_at_the_cells_widths(self, topo, on_tpu):
         """The short convolution op of the lfm2 cell (16,384 positions,
