@@ -14,6 +14,12 @@ os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
 )
 
+# `transformers` (the two tests against Hugging Face's Llama) imports
+# TensorFlow and Flax where it finds them, for nobody here: 15 s an import
+# with them, 9 without (ROADMAP D10)
+os.environ.setdefault("USE_TF", "0")
+os.environ.setdefault("USE_FLAX", "0")
+
 import jax
 
 jax.config.update("jax_platforms", "cpu")

@@ -604,7 +604,7 @@ class TestInspectCli:
         script = os.path.join(repo, "scripts", "ckpt_inspect.py")
         # one real subprocess run proves the CLI entry point end to end
         r = subprocess.run([sys.executable, script, str(tmp_path / "good")],
-                           capture_output=True, text=True)
+                           capture_output=True, text=True, timeout=300)
         assert r.returncode == 0, r.stdout + r.stderr
         assert "integrity: verified" in r.stdout
         # remaining exit-code matrix via main() in-process (each

@@ -13,6 +13,7 @@ style groups, causal with and without a window and plain, against the
 einsum path; the attention op with 64 rotated lanes of 256 and the gate a
 lane through them; the rules that say where they run."""
 
+import functools
 import os
 import sys
 
@@ -40,6 +41,9 @@ def interpret(monkeypatch):
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
 
 
+# (operands are made by ONE program a shape: eagerly every `jax.random`
+# and `at[].set` call is a program of its own, ROADMAP D10)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def small_rule_inputs(length, hk=2, hv=4, d=8, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q = jax.random.normal(ks[0], (2, length, hk, d))
@@ -82,11 +86,12 @@ def test_chunked_rule_matches_the_recurrence_a_position(chunk):
 def test_the_reference_runs_the_same_recurrence_and_its_two_controls():
     q, k, v, g, beta = small_rule_inputs(21)
     rep = lambda t: jnp.repeat(t, 2, axis=2)    # noqa: E731
-    with HIGHEST:
-        want = dr.delta_rule_stepwise(q, k, v, g, beta)
-        got = jax.jit(ref.delta_rule)(rep(q), rep(k), v, g, beta)
-        plain = jax.jit(lambda *a: ref.delta_rule(*a, correction=False))(
-            rep(q), rep(k), v, g, beta)
+    with HIGHEST:       # one program
+        want, got, plain = jax.jit(lambda q, k, v, g, beta: (
+            dr.delta_rule_stepwise(q, k, v, g, beta),
+            ref.delta_rule(rep(q), rep(k), v, g, beta),
+            ref.delta_rule(rep(q), rep(k), v, g, beta, correction=False)))(
+                q, k, v, g, beta)
     np.testing.assert_allclose(got, want, atol=2e-6)
     # without the correction the state only accumulates: another output
     assert float(jnp.max(jnp.abs(plain - want))) > 1e-2
@@ -95,18 +100,20 @@ def test_the_reference_runs_the_same_recurrence_and_its_two_controls():
 def test_unit_lower_inverse_and_its_own_backward():
     a = jnp.tril(0.3 * jax.random.normal(jax.random.PRNGKey(2), (3, 16, 16)),
                  -1)
+    w = jax.random.normal(jax.random.PRNGKey(3), a.shape)
     with HIGHEST:
-        t = dr.unit_lower_inverse(a)
-        np.testing.assert_allclose(t @ (jnp.eye(16) + a),
-                                   jnp.broadcast_to(jnp.eye(16), a.shape),
-                                   atol=1e-5)
-        w = jax.random.normal(jax.random.PRNGKey(3), a.shape)
-        got = jax.grad(lambda a: jnp.sum(dr.unit_lower_inverse(a) * w))(a)
-        want = jax.grad(lambda a: jnp.sum(
-            jnp.linalg.inv(jnp.eye(16) + a) * w))(a)
+        t, got, want = jax.jit(lambda a: (
+            dr.unit_lower_inverse(a),
+            jax.grad(lambda a: jnp.sum(dr.unit_lower_inverse(a) * w))(a),
+            jax.grad(lambda a: jnp.sum(
+                jnp.linalg.inv(jnp.eye(16) + a) * w))(a)))(a)
+    np.testing.assert_allclose(
+        np.asarray(t) @ (np.eye(16, dtype=np.float32) + np.asarray(a)),
+        np.broadcast_to(np.eye(16, dtype=np.float32), a.shape), atol=1e-5)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def core_inputs(length, hk=1, hv=2, d=128, seed=0):
     """(qkv, z, g, beta, the norm's scale) as the op hands them over."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
@@ -151,15 +158,17 @@ def test_rule_kernels_match_the_scan_forward_and_backward(interpret, length,
         want, dwant = rule_both_ways(False, hk, hv * 128)(*ins)
         got, dgot = rule_both_ways(True, hk, hv * 128)(*ins)
         if (length, rep) == (384, 2):
-            qkv, z, g, beta, scale = ins
-            f = jax.nn.silu(qkv).reshape(1, length, 2 * hk + hv, 128)
-            q, k, v = f[:, :, :hk], f[:, :, hk:2 * hk], f[:, :, 2 * hk:]
-            q = q / jnp.sqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
-                * 128 ** -0.5
-            k = k / jnp.sqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-            step = dr.heads_rms_norm_gated(
-                jax.jit(dr.delta_rule_stepwise)(q, k, v, g, beta),
-                z.reshape(1, length, hv, 128), scale, 1e-6)
+            def a_position(qkv, z, g, beta, scale):
+                f = jax.nn.silu(qkv).reshape(1, length, 2 * hk + hv, 128)
+                q, k, v = f[:, :, :hk], f[:, :, hk:2 * hk], f[:, :, 2 * hk:]
+                q = q / jnp.sqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+                    * 128 ** -0.5
+                k = k / jnp.sqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+                return dr.heads_rms_norm_gated(
+                    dr.delta_rule_stepwise(q, k, v, g, beta),
+                    z.reshape(1, length, hv, 128), scale, 1e-6)
+
+            step = jax.jit(a_position)(*ins)
             out = jax.jit(lambda *a: dr.delta_rule_core(
                 *a, hk, 128, 1e-6, jnp.float32, True))(*ins)
             np.testing.assert_allclose(out.reshape(step.shape), step,

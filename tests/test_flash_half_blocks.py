@@ -15,8 +15,11 @@ from flexflow_tpu.ops import pallas_kernels as pk
 # heads of 64 (PR 47): (kernel family) -> (positions, mask)
 HALF_BLOCK_KINDS = {
     "whole_tile": (256, dict()),                 # flash_fwd_whole, flash_bwd
-    "chunk_loop": (2 * pk.MAX_BWD_SEQ, dict()),  # flash_fwd, flash_bwd_blocked
-    "one_span": (1536, dict(window=128)),        # the span kernels
+    # flash_fwd, flash_bwd_blocked: the shortest length past MAX_BWD_SEQ
+    # at which the super-blocks engage (two Q blocks a K chunk of 512)
+    "chunk_loop": (1536, dict()),
+    # the span kernels: past MAX_BWD_SEQ, the same [256, 384] tile a block
+    "one_span": (1280, dict(window=128)),
 }
 
 
@@ -39,6 +42,8 @@ def test_grouped_keys_at_two_heads_a_lane_block(heads, kind, monkeypatch):
     seq, kw = HALF_BLOCK_KINDS[kind]
     assert pk.grouped_kv_shape_legal(h, hk, d)
     assert (pk.one_span(seq, True, **kw) is not None) == (kind == "one_span")
+    assert pk.super_block_engaged(seq, True, 0, None, 0) == (
+        kind == "chunk_loop")
     q, _, _, do = _qkv(seq, d, jnp.bfloat16, seed=seq + h, h=h)
     k, v, _, _ = _qkv(seq, d, jnp.bfloat16, seed=seq + h + 1, h=hk)
     _assert_grouped_is_the_repeated_form(q, k, v, do, h, hk, True, kw)

@@ -511,21 +511,29 @@ def _assert_grouped_is_the_repeated_form(q, k, v, do, h, hk, causal, kw):
     sums within a bf16 rounding of each head's partial; the public call
     the same values."""
     rep, seq, d = h // hk, q.shape[1], k.shape[-1] // hk
-    # one program a kernel call, as the calls were when they ran eagerly
-    o, lse = jax.jit(lambda q, k, v: pk._flash_fwd(
-        q, k, v, h, causal, True, num_kv_heads=hk, **kw))(q, k, v)
-    kr, vr = _repeated(k, hk, rep), _repeated(v, hk, rep)
-    want_o, want_lse = jax.jit(lambda q, k, v: pk._flash_fwd(
-        q, k, v, h, causal, True, **kw))(q, kr, vr)
+
+    def both_ways(**grouped):   # a form is ONE program: forward, backward
+        def run(q, k, v, do):
+            o, lse = pk._flash_fwd(q, k, v, h, causal, True, **grouped, **kw)
+            direct = (o, lse) + tuple(pk._flash_bwd(
+                q, k, v, o, lse, do, h, causal, True, **grouped, **kw))
+            if not grouped:
+                return direct
+            # and through the public call, in the same program (where the
+            # two are one computation the compiler makes them one): float32
+            # keys in, as the op hands them, float32 group sums out
+            out, vjp = jax.vjp(lambda q, k, v: pk.flash_attention(
+                q, k, v, h, causal, num_kv_heads=hk, **kw),
+                q, k.astype(jnp.float32), v.astype(jnp.float32))
+            return direct + ((out,) + vjp(do),)
+        return jax.jit(run)
+
+    o, lse, dq, dk, dv, public = both_ways(num_kv_heads=hk)(q, k, v, do)
+    want_o, want_lse, want_dq, dkr, dvr = both_ways()(
+        q, _repeated(k, hk, rep), _repeated(v, hk, rep), do)
     assert np.array_equal(np.asarray(o, np.float32),
                           np.asarray(want_o, np.float32))
     assert np.array_equal(np.asarray(lse), np.asarray(want_lse))
-
-    dq, dk, dv = jax.jit(lambda q, k, v, o, lse, do: pk._flash_bwd(
-        q, k, v, o, lse, do, h, causal, True, num_kv_heads=hk, **kw))(
-            q, k, v, o, lse, do)
-    want_dq, dkr, dvr = jax.jit(lambda q, k, v, o, lse, do: pk._flash_bwd(
-        q, k, v, o, lse, do, h, causal, True, **kw))(q, kr, vr, o, lse, do)
     assert np.array_equal(np.asarray(dq, np.float32),
                           np.asarray(want_dq, np.float32))
     for name, got, parts in (("dk", dk, dkr), ("dv", dv, dvr)):
@@ -539,12 +547,10 @@ def _assert_grouped_is_the_repeated_form(q, k, v, do, h, hk, causal, kw):
         assert np.abs(want).max() > 0 and not np.allclose(want,
                                                           parts[..., 0, :])
 
-    # through the public call: float32 keys in (as the op hands them),
-    # float32 group sums out, the same values
-    g = _grads(lambda q, k, v: pk.flash_attention(
-        q, k, v, h, causal, num_kv_heads=hk, **kw), q, *_f32(k, v), do)
-    assert [a.dtype for a in g] == [jnp.bfloat16, jnp.float32, jnp.float32]
-    for a, b in zip(g, (dq, dk, dv)):
+    # the public call: the same values
+    assert [a.dtype for a in public] == [jnp.bfloat16, jnp.bfloat16,
+                                         jnp.float32, jnp.float32]
+    for a, b in zip(public, (o, dq, dk, dv)):
         assert np.array_equal(np.asarray(a, np.float32),
                               np.asarray(b, np.float32))
 
@@ -665,14 +671,23 @@ def test_the_op_hands_over_grouped_keys_where_the_shapes_allow(
     x, g = (jnp.asarray(rs.randn(b, s, e).astype(np.float32))
             for _ in range(2))
 
-    def run(op):
+    def both_ways(op):
         def loss(p, x):
             ctx = OpContext(training=True, mesh=mesh,
                             compute_dtype=jnp.bfloat16)
             return jnp.sum(op.forward(p, [x], ctx)[0] * g)
-        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+        return jax.value_and_grad(loss, argnums=(0, 1))
 
-    got, want = run(op), run(steered)
+    got = jax.jit(both_ways(op))(params, x)
+    if grouped:
+        want = jax.jit(both_ways(steered))(params, x)
+    else:
+        # the rule left the repeat in place: steering changes nothing,
+        # the two trace to ONE program, character for character, and it
+        # has run
+        assert str(jax.make_jaxpr(both_ways(steered))(params, x)) == str(
+            jax.make_jaxpr(both_ways(op))(params, x))
+        want = got
     assert op._route.grouped_kv == grouped and op._route.core == "flash"
     assert op.traced_gauges()["executor.flash_grouped_kv_ops"] == grouped
     assert not steered._route.grouped_kv

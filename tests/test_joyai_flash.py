@@ -9,29 +9,23 @@ eight experts to the uncut layer, and what stays as it was."""
 
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import family_model as fm
+from benchmarks import harness as hs
+from benchmarks.references import common
+from benchmarks.references import joyai_flash as ref
+from family_model import ROOT, OpContext, make_op, run_op
+from flexflow_tpu import losses
+from flexflow_tpu.ffconst import OperatorType
+from flexflow_tpu.ops import pallas_kernels as pk
+from flexflow_tpu.ops.attention import rotary_interleaved
+from one_program import output_and_gradients
 
-from benchmarks import harness as hs  # noqa: E402
-from benchmarks.references import common  # noqa: E402
-from benchmarks.references import joyai_flash as ref  # noqa: E402
-from flexflow_tpu import losses  # noqa: E402
-from flexflow_tpu.ffconst import OperatorType  # noqa: E402
-from flexflow_tpu.layer import Layer  # noqa: E402
-from flexflow_tpu.ops import pallas_kernels as pk  # noqa: E402
-from flexflow_tpu.ops.attention import rotary_interleaved  # noqa: E402
-from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
-from one_program import output_and_gradients  # noqa: E402
-
-HIGHEST = jax.default_matmul_precision("highest")
 CELL = "joyai_llm_flash.s4096_b1.1chip"
 # tiny widths that keep the query/key head (16 + 8) wider than the value
 # head (16)
@@ -42,18 +36,6 @@ TINY = dict(num_hidden_layers=2, vocab_size=64, hidden_size=32,
             n_routed_experts_published=16, num_experts_per_tok=3,
             moe_intermediate_size=24, slot_slack=3.0, initializer_range=0.2,
             seq=32, batch=2, steps_per_epoch=1)
-
-
-def make_op(kind, props, shapes):
-    layer = Layer(kind, "op", [])
-    layer.properties.update(props)
-    return OpRegistry.create(layer, shapes)
-
-
-def run_op(op, params, inputs):
-    ctx = OpContext(training=False, compute_dtype=jnp.float32)
-    with HIGHEST:
-        return np.asarray(op.forward(params, inputs, ctx)[0])
 
 
 # ---------------------------------------------------------------------------
@@ -68,7 +50,7 @@ def assembled(q, k, v, qr, kr, heads):
     qh, kh, vh, qrh = (pk.split_heads(t, heads) for t in (q, k, v, qr))
     kk = jnp.concatenate([kh, jnp.broadcast_to(
         kr[:, None], (b, heads, s, kr.shape[-1]))], -1)
-    with HIGHEST:
+    with fm.highest():
         return pk.merge_heads(scaled_dot_product_attention(
             jnp.concatenate([qh, qrh], -1), kk, vh, causal=True))
 
@@ -180,9 +162,9 @@ def test_latent_attention_matches_the_reference_and_counts_itself():
     params["q_a_norm"] = jnp.asarray(rs.rand(24) + 0.5, jnp.float32)
     params["kv_a_norm"] = jnp.asarray(rs.rand(16) + 0.5, jnp.float32)
     x = jnp.asarray(rs.randn(2, 24, 32), jnp.float32)
-    with HIGHEST:
-        want = ref.latent_attention(x, params, theta=3.2e7, eps=1e-6,
-                                    operand="f32")
+    with fm.highest():
+        want = jax.jit(lambda x, p: ref.latent_attention(
+            x, p, theta=3.2e7, eps=1e-6, operand="f32"))(x, params)
     np.testing.assert_allclose(run_op(op, params, [x] * 3), want, rtol=1e-4,
                                atol=1e-5)
     # the control's program turns every lane of a head and is another model
@@ -225,9 +207,9 @@ def test_the_shared_expert_takes_the_experts_own_form():
     assert op.params_elems() == sum(int(np.prod(p.shape))
                                     for p in params.values())
     params["e_bias"] = jnp.asarray(rs.randn(16) * 0.1, jnp.float32)
-    with HIGHEST:
-        want = ref.experts(g, params, k=3, scaling=2.5, offset=0,
-                           operand="f32")
+    with fm.highest():
+        want = jax.jit(lambda g, p: ref.experts(
+            g, p, k=3, scaling=2.5, offset=0, operand="f32"))(g, params)
     np.testing.assert_allclose(run_op(op, params, [g]), want, rtol=1e-4,
                                atol=1e-5)
     # the ungated layer's shared expert is the squared ReLU it was, with
@@ -240,7 +222,7 @@ def test_the_shared_expert_takes_the_experts_own_form():
     assert plain.flops() == int(2 * 48 * 32 * 16 + 4 * 48 * 3 * 32 * 24
                                 + 4 * 48 * 32 * 40)
     zero = dict(pp, w_down=jnp.zeros_like(pp["w_down"]))
-    with HIGHEST:
+    with fm.highest():
         by_hand = jnp.square(jax.nn.relu(g @ pp["ws_up"])) @ pp["ws_down"]
     np.testing.assert_allclose(run_op(plain, zero, [g]), by_hand, rtol=1e-4,
                                atol=1e-5)
@@ -264,26 +246,21 @@ def test_thirty_two_shares_add_up_to_the_uncut_layer():
     w["b1_mixer"]["e_bias"] = jnp.asarray(rs.randn(64) * 0.1, jnp.float32)
     ref_kw = dict(eps=1e-6, rope_theta=3.2e7, num_experts_per_tok=8,
                   routed_scaling_factor=2.5, expert_offset=0)
-    with HIGHEST:
-        want = np.asarray(ref.layer(x, w, "b1", ref_kw, "f32"))
-        h = ref.rms_norm(x, w["b1_norm"]["scale"], 1e-6)
+    with fm.highest():
+        want, h = jax.jit(lambda x, w: (
+            ref.layer(x, w, "b1", ref_kw, "f32"),
+            ref.rms_norm(x, w["b1_norm"]["scale"], 1e-6)))(x, w)
     attended = np.asarray(x) + run_op(attn, w["b1_attn"], [h] * 3)
-    with HIGHEST:
-        g = ref.rms_norm(jnp.asarray(attended), w["b1_post_norm"]["scale"],
-                         1e-6)
-        p = w["b1_mixer"]
-        shared = np.asarray(ref.swiglu(g, p["ws_gate"], p["ws_up"],
-                                       p["ws_down"], "f32"))
-    total = attended + shared
-    for chip in range(32):
-        held = slice(2 * chip, 2 * chip + 2)
-        op = make_op(OperatorType.MOE_LAYER,
-                     dict(kw, experts_held=2, expert_offset=2 * chip),
-                     [x.shape])
-        share = dict(p, **{n: p[n][held]
-                           for n in ("w_gate", "w_up", "w_down")})
-        total = total + (run_op(op, share, [g]) - shared)
-        assert float(op._counters["moe/overflow_slots"][1]) == 0
+    p = w["b1_mixer"]
+    with fm.highest():
+        g, shared = jax.jit(lambda a, scale, p: (
+            ref.rms_norm(a, scale, 1e-6),
+            ref.swiglu(ref.rms_norm(a, scale, 1e-6), p["ws_gate"],
+                       p["ws_up"], p["ws_down"], "f32")))(
+                attended, w["b1_post_norm"]["scale"], p)
+    shared = np.asarray(shared)
+    parts = fm.expert_shares(kw, p, [g], 2, 32)
+    total = attended + shared + sum(part - shared for part in parts)
     np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
 
 
@@ -316,19 +293,7 @@ def test_part_sums_of_the_weighted_loss():
 
 @pytest.fixture(scope="module")
 def tiny():
-    from benchmarks import manifest as mf
-    manifest = mf.load_manifest(ROOT)
-    _, config, traffic = mf.find_cell(manifest, CELL, ROOT)
-    family = hs.load_by_path("families", config["family"], ROOT)
-    s = family.sizes(config, traffic, TINY)
-    # a rate at which two Adam steps move the loss
-    config = dict(config, adam=dict(config["adam"], alpha=1e-3,
-                                    state_dtype="float32"))
-    xs, y = family.make_data(s, 11)
-    weights = jax.device_get(family.make_weights(s, 11))
-    ff = family.build(config, s, 1, 11)
-    family.install_weights(ff, weights)
-    return family, config, s, traffic, xs, y, weights, ff
+    return fm.build_tiny(CELL, TINY)
 
 
 def test_model_against_the_reference_both_halves_loss_and_two_adam_steps(
@@ -366,27 +331,23 @@ def test_model_against_the_reference_both_halves_loss_and_two_adam_steps(
 def test_a_program_built_otherwise_is_not_correct(tiny, control, failing):
     """The mechanisms' controls: rotary over the whole head, the module
     reading the unshifted embedding, lambda 0; the reference as stated."""
-    family, config, _, traffic, _, _, weights, _ = tiny
-    s = family.sizes(config, traffic, dict(TINY, **control))
-    xs, y = family.make_data(s, 11)
-    ff = family.build(config, s, 1, 11)
-    family.install_weights(ff, weights)
+    ff, s = fm.control_model(tiny, dict(TINY, **control))
+    want = fm.reference_predictions(tiny, s)
+    if failing == "pred_nrmse":     # judged by its logits: no step taken
+        nrmse = hs.prediction_errors(fm.predictions(ff, tiny),
+                                     want["preds"], False)["nrmse"]
+        assert nrmse > tiny.family.TOLERANCES["pred_nrmse"]
+        return
+    xs, y = tiny.family.make_data(s, 11)    # the labels carry the weight
     system, _ = hs.system_side(ff, xs, y, s["batch"])
-    want = hs.reference_side(family, weights, s, traffic, config, xs, y,
-                             s["batch"], steps=1)
     rows = {r["name"]: r for r in hs.compare(system, want,
-                                             family.TOLERANCES)}
+                                             tiny.family.TOLERANCES)}
     assert rows[failing]["ok"] is False
 
 
 def test_the_step_names_the_new_scopes(tiny):
-    family, _, s, _, xs, y, _, ff = tiny
     from flexflow_tpu.obs import step_scopes
-    step = ff.executor.make_train_step()
-    text = step.lower(ff.params, ff.opt_state, ff.state,
-                      ff._stage_inputs([xs[0][:s["batch"]]]),
-                      ff._shard_batch(y[:s["batch"]]),
-                      jax.random.PRNGKey(0)).compile().as_text()
+    text = fm.compiled_step_text(tiny)
     for scope in ("jvp(jit(attention_latent))",
                   "transpose(jvp(jit(attention_latent)))",
                   "jvp(jit(mtp))/jit(attention_latent)",

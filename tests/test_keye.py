@@ -15,25 +15,19 @@ program and the reference order their sums differently, so a logit
 agrees to a few float32 units of its size, a loss to 2e-5 and a gradient
 leaf to 2e-4 of its largest entry."""
 
-import os
-import sys
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import family_model as fm
+from benchmarks import harness as hs
+from benchmarks.references import keye as ref
+from family_model import OpContext, as_arrays
+from flexflow_tpu.ops.attention import INDEXER_LEAVES
 
-from benchmarks import harness as hs  # noqa: E402
-from benchmarks import manifest as mf  # noqa: E402
-from benchmarks.references import keye as ref  # noqa: E402
-from flexflow_tpu.ops.attention import INDEXER_LEAVES  # noqa: E402
-from flexflow_tpu.ops.base import OpContext  # noqa: E402
-
-HIGHEST = jax.default_matmul_precision("highest")
 CELL = "keye_vl2_30b_a3b.s16384_b1.1chip"
 SEQ = 48
 TINY = dict(num_hidden_layers=2, vocab_size=64, hidden_size=32,
@@ -52,32 +46,28 @@ TINY = dict(num_hidden_layers=2, vocab_size=64, hidden_size=32,
 _GRID = [(8, 8 + i // 6, 8 + i % 6) for i in range(30)]
 _TAIL = [(14 + i,) * 3 for i in range(SEQ - 38)]
 GRID = tuple(zip(*([(i,) * 3 for i in range(8)] + _GRID + _TAIL)))
-SIZES = {"text": TINY, "grid": dict(TINY, mrope_positions=GRID)}
+AFTER_ANOTHER = "text_built_after_another_family"
+SIZES = {"text": TINY, "grid": dict(TINY, mrope_positions=GRID),
+         AFTER_ANOTHER: TINY}
 
 
 @pytest.fixture(scope="module")
 def cell():
-    manifest = mf.load_manifest()
-    _, config, traffic = mf.find_cell(manifest, CELL)
-    return hs.load_by_path("families", config["family"]), config, traffic
+    return fm.load_cell(CELL, adam=None)
 
 
 @pytest.fixture(scope="module")
-def built(cell):
-    """name -> (s, xs, y, weights, ff), each built once."""
-    family, config, traffic = cell
-    cache = {}
+def tinies(cell):
+    """name -> the `fm.Tiny` of `SIZES[name]`, each built once."""
+    return fm.built_by_name(cell, SIZES)
 
+
+@pytest.fixture(scope="module")
+def built(tinies):
+    """name -> (s, xs, y, weights, ff) of `tinies(name)`."""
     def get(name):
-        if name not in cache:
-            s = family.sizes(config, traffic, SIZES[name])
-            xs, y = family.make_data(s, 11)
-            weights = jax.device_get(family.make_weights(s, 11))
-            ff = family.build(config, s, 1, 11)
-            family.install_weights(ff, weights)
-            cache[name] = (s, xs, y, weights, ff)
-        return cache[name]
-
+        tiny = tinies(name)
+        return tiny.s, tiny.xs, tiny.y, tiny.weights, tiny.ff
     return get
 
 
@@ -110,10 +100,6 @@ def reference_losses_of(family, s):
     return losses
 
 
-def as_arrays(weights):
-    return jax.tree.map(jnp.asarray, weights)
-
-
 def assert_leaves_close(got, want, atol=2e-4):
     assert set(got) == set(want)
     for name in want:
@@ -132,23 +118,33 @@ def compared(built, cell):
     family = cell[0]
     cache = {}
 
+    def total(fn):
+        def step_loss(*args):
+            lm, index, logits = fn(*args)
+            return lm + sum(index), (lm, index, logits)
+        return jax.jit(jax.value_and_grad(step_loss, has_aux=True))
+
     def get(name):
         if name not in cache:
+            if name == AFTER_ANOTHER:
+                # the SAME cut, built right after another family's module
+                # has built its own through the helper
+                import test_qwen3_next as another
+                guid = fm.Layer._next_guid[0]
+                fm.build_tiny(another.CELL, another.TINY)
+                assert fm.Layer._next_guid[0] > guid
             s, xs, y, weights, ff = built(name)
-            program = program_losses_of(ff, xs, y)
-            reference = reference_losses_of(family, s)
-
-            def total(fn):
-                def step_loss(*args):
-                    lm, index, logits = fn(*args)
-                    return lm + sum(index), (lm, index, logits)
-                return jax.value_and_grad(step_loss, has_aux=True)
-
-            with HIGHEST:
-                (_, got), g_got = jax.jit(total(program))(ff.params)
-                (_, want), g_want = jax.jit(total(reference))(
-                    as_arrays(weights), jnp.asarray(xs[0]), jnp.asarray(y))
-            cache[name] = (got + (g_got,), want + (g_want,))
+            with fm.highest():
+                (_, got), g_got = total(program_losses_of(ff, xs, y))(
+                    ff.params)
+                if name == AFTER_ANOTHER:   # the first build's reference
+                    want = get("text")[1]
+                else:
+                    (_, want), g_want = total(reference_losses_of(family, s))(
+                        as_arrays(weights), jnp.asarray(xs[0]),
+                        jnp.asarray(y))
+                    want += (g_want,)
+            cache[name] = (got + (g_got,), want)
         return cache[name]
 
     return get
@@ -165,10 +161,21 @@ def test_model_against_the_reference_logits_and_every_loss(name, compared):
     assert min(float(v) for v in want[1]) > 1e-3     # the loss is there
 
 
-@pytest.mark.parametrize("name", ["text", "grid"])
+@pytest.mark.parametrize("name", ["text", "grid", AFTER_ANOTHER])
 def test_every_gradient_leaf_matches_the_reference(name, compared):
+    """The third case (ROADMAP D0's order-dependent faults): two family
+    modules built one after the other through `family_model` do not see
+    each other's state. The process-wide `Layer._next_guid` has moved on
+    and the leaves still pair by name; no "highest" is left behind by a
+    context entered inside itself; the second build's step is the first
+    build's to the bit."""
     got, want = compared(name)
     assert_leaves_close(got[3], jax.device_get(want[3]))
+    assert jax.config.jax_default_matmul_precision is None
+    if name == AFTER_ANOTHER:
+        first = compared("text")[0]
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(first)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_three_streams_that_differ_change_the_result(compared):
@@ -181,7 +188,7 @@ def test_which_leaves_learn_from_which_loss(built):
     alone, and no other leaf gets any from it."""
     _, xs, y, _, ff = built("text")
     program = program_losses_of(ff, xs, y)
-    with HIGHEST:
+    with fm.highest():
         from_lm = jax.jit(jax.grad(lambda p: program(p)[0]))(ff.params)
         from_index = jax.jit(jax.grad(lambda p: sum(program(p)[1])))(
             ff.params)
@@ -198,15 +205,32 @@ def test_which_leaves_learn_from_which_loss(built):
     assert seen == 2 * len(INDEXER_LEAVES)
 
 
+_KEPT = {}
+
+
 def _op_mask(ff, weights, x, layer=0):
     """The pairs the PROGRAM's op of `layer` keeps for the layer's
     input x [b, s, e] (the op's own indexer and selection)."""
-    op = next(n.op for n in ff.executor.nodes
-              if n.op.name == f"b{layer}_attn")
-    ctx = OpContext(training=False, compute_dtype=jnp.float32)
+    if (id(ff), layer) not in _KEPT:     # one program a model and layer
+        op = next(n.op for n in ff.executor.nodes
+                  if n.op.name == f"b{layer}_attn")
+        ctx = OpContext(training=False, compute_dtype=jnp.float32)
+        _KEPT[id(ff), layer] = jax.jit(
+            lambda p, x: op._kept_keys(p, x, ctx, False)[3])
     params = {k: jnp.asarray(v) for k, v in
               weights[f"b{layer}_attn"].items()}
-    return np.asarray(op._kept_keys(params, x, ctx, False)[3]) != 0
+    return np.asarray(_KEPT[id(ff), layer](params, x)) != 0
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_kept(kw_items, eps):
+    """(w, ids) -> (the reference's kept pairs of layer 0, the layer's
+    normed input), one program for both cases below."""
+    kw = dict(kw_items)
+    return jax.jit(lambda w, ids: (
+        ref.kept_pairs(w, ids, 0, **kw)[0],
+        ref.rms_norm(w["embed_tokens"]["kernel"][ids],
+                     w["b0_norm"]["scale"], eps)))
 
 
 @pytest.mark.parametrize("ties", [False, True], ids=["seeded", "ties"])
@@ -220,11 +244,10 @@ def test_the_kept_sets_are_the_references(built, cell, ties):
         weights = jax.tree.map(np.array, weights)
         weights["b0_attn"]["w_iw"][:] = 0.0
     w = as_arrays(weights)
-    with HIGHEST:
-        want, _ = ref.kept_pairs(w, jnp.asarray(xs[0]), 0,
-                                 **family.reference_kw(s))
-        x = ref.rms_norm(w["embed_tokens"]["kernel"][xs[0]],
-                         w["b0_norm"]["scale"], s["rms_norm_eps"])
+    with fm.highest():
+        want, x = _reference_kept(
+            tuple(sorted(family.reference_kw(s).items())),
+            s["rms_norm_eps"])(w, jnp.asarray(xs[0]))
         got = _op_mask(ff, weights, x)
     want = np.asarray(want)
     np.testing.assert_array_equal(got, want)
@@ -250,8 +273,8 @@ def test_the_share_test(built, cell):
     w = as_arrays(weights)
     kw = family.reference_kw(s)
     akw = dict(ref.attention_kw(kw, SEQ), operand="f32", head_sums=True)
-    with HIGHEST:
-        h = ref.rms_norm(w["embed_tokens"]["kernel"][xs[0]],
+    def shares(w, ids):
+        h = ref.rms_norm(w["embed_tokens"]["kernel"][ids],
                          w["b0_norm"]["scale"], s["rms_norm_eps"])
         p = w["b0_attn"]
         whole = ref.attention(h, p, **akw)
@@ -261,12 +284,6 @@ def test_the_share_test(built, cell):
             ranks.append(ref.attention(h, dict(
                 p, wq=p["wq"][heads], wo=p["wo"][heads], wk=p["wk"][kv],
                 wv=p["wv"][kv]), **akw))
-        np.testing.assert_allclose(sum(r[0] for r in ranks), whole[0],
-                                   rtol=1e-4, atol=1e-5)
-        for r in ranks:
-            np.testing.assert_array_equal(r[4], whole[4])
-        np.testing.assert_allclose(sum(r[3] for r in ranks), whole[3],
-                                   rtol=1e-4, atol=1e-6)
         # the indexer's target is the normalised sum: a rank alone reads
         # its own heads' (what one chip does), the deployment the sum's
         g = ref.rms_norm(h, w["b0_post_norm"]["scale"], s["rms_norm_eps"])
@@ -278,7 +295,17 @@ def test_the_share_test(built, cell):
         parts = [ref.experts(g, dict(m, **{
             k: stacked[k][4 * e:4 * e + 4] for k in stacked}), k=3,
             offset=4 * e, operand="f32") for e in range(2)]
-        np.testing.assert_allclose(sum(parts), uncut, rtol=1e-4, atol=1e-5)
+        return whole, ranks, uncut, parts
+
+    with fm.highest():      # one program
+        whole, ranks, uncut, parts = jax.jit(shares)(w, jnp.asarray(xs[0]))
+    np.testing.assert_allclose(sum(r[0] for r in ranks), whole[0],
+                               rtol=1e-4, atol=1e-5)
+    for r in ranks:
+        np.testing.assert_array_equal(r[4], whole[4])
+    np.testing.assert_allclose(sum(r[3] for r in ranks), whole[3],
+                               rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(sum(parts), uncut, rtol=1e-4, atol=1e-5)
 
 
 def test_the_attention_op_refuses_what_it_has_not():
@@ -350,25 +377,22 @@ def test_a_checkpoint_round_trip_keeps_the_families_leaves(built, tmp_path):
 # ---------------------------------------------------------------------------
 # the controls, each built through a `program_*` override: another result
 
-def _control(cell, built, **control):
-    family, config, traffic = cell
-    _, xs, y, weights, _ = built("text")
-    s = family.sizes(config, traffic, dict(TINY, **control))
-    ff = family.build(config, s, 1, 11)
-    family.install_weights(ff, weights)
-    return ff, xs, y
+def _control(tinies, **control):
+    tiny = tinies("text")
+    return (fm.control_model(tiny, dict(TINY, **control))[0], tiny.xs,
+            tiny.y)
 
 
-def test_every_key_kept_is_another_model(cell, built, compared):
-    ff, xs, _ = _control(cell, built, program_topk=SEQ)
+def test_every_key_kept_is_another_model(tinies, compared):
+    ff, xs, _ = _control(tinies, program_topk=SEQ)
     got = np.asarray(ff.predict([xs[0]]), np.float32)
     want = np.asarray(compared("text")[1][2])
     assert hs.prediction_errors(got, want, False)["nrmse"] > 0.05
 
 
-def test_without_the_indexers_loss_the_step_loss_is_another(cell, built,
+def test_without_the_indexers_loss_the_step_loss_is_another(tinies,
                                                             compared):
-    ff, xs, y = _control(cell, built, program_index_loss=False)
+    ff, xs, y = _control(tinies, program_index_loss=False)
     lm, index, _ = jax.jit(program_losses_of(ff, xs, y))(ff.params)
     assert index == []
     want = compared("text")[1]
@@ -376,7 +400,8 @@ def test_without_the_indexers_loss_the_step_loss_is_another(cell, built,
     assert float(sum(want[1])) > 1e-3 * float(lm)
 
 
-def test_the_family_holds_the_indexer_to_the_references_keys(cell, built):
+def test_the_family_holds_the_indexer_to_the_references_keys(cell, built,
+                                                             tinies):
     """`kept_pairs_that_differ`, the number behind the family's
     `extra_checks` row: none as the cell states the program; an indexer
     whose products read bfloat16 operands keeps other keys."""
@@ -386,7 +411,7 @@ def test_the_family_holds_the_indexer_to_the_references_keys(cell, built):
     rows = dict((name, ok) for name, ok, _ in family.extra_checks(
         ff, s, 1, False))
     assert rows["indexer_keeps_the_references_keys"]
-    low, _, _ = _control(cell, built, program_indexer_dtype="bfloat16")
+    low, _, _ = _control(tinies, program_indexer_dtype="bfloat16")
     assert family.kept_pairs_that_differ(low, s) > 0
 
 

@@ -11,30 +11,23 @@ layer, and what the new properties refuse."""
 import json
 import math
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmarks import harness as hs  # noqa: E402
-from benchmarks import manifest as mf  # noqa: E402
-from benchmarks.references import laguna as ref  # noqa: E402
-from flexflow_tpu.ffconst import OperatorType  # noqa: E402
-from flexflow_tpu.layer import Layer  # noqa: E402
-from flexflow_tpu.ops import pallas_kernels as pk  # noqa: E402
-from flexflow_tpu.ops.attention import (rotary_embedding,  # noqa: E402
+import family_model as fm
+from benchmarks import harness as hs
+from benchmarks.references import laguna as ref
+from family_model import ROOT, OpContext, make_op, run_op
+from flexflow_tpu.ffconst import OperatorType
+from flexflow_tpu.ops import pallas_kernels as pk
+from flexflow_tpu.ops.attention import (rotary_embedding,
                                         rotary_frequencies, rotary_partial,
                                         scaled_dot_product_attention)
-from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
-from one_program import output_and_gradients  # noqa: E402
+from one_program import output_and_gradients
 
-HIGHEST = jax.default_matmul_precision("highest")
 CELL = "laguna_xs2.s8192_b1.1chip"
 # every width small, the structure whole: both head counts (3 and 4 query
 # heads a key/value head), both rotary forms, the gate, a window, the
@@ -50,19 +43,6 @@ TINY = dict(num_hidden_layers=5, vocab_size=64, hidden_size=32,
 YARN = dict(rope_type="yarn", factor=64, beta_fast=64, beta_slow=1,
             original_max_position_embeddings=4096,
             attention_factor=1.4158883083359672)
-
-
-def make_op(kind, props, shapes):
-    layer = Layer(kind, "op", [])
-    layer.properties.update(props)
-    return OpRegistry.create(layer, shapes)
-
-
-def run_op(op, params, inputs):
-    ctx = OpContext(training=False, compute_dtype=jnp.float32)
-    with HIGHEST:
-        return np.asarray(jax.jit(lambda p, x: op.forward(p, x, ctx)[0])(
-            params, inputs))
 
 
 # ---------------------------------------------------------------------------
@@ -128,7 +108,7 @@ def test_narrow_window_flash_matches_the_einsum_core(seq, window, group):
             split(q), split(repeat_kv(k, kv, heads)),
             split(repeat_kv(v, kv, heads)), causal=True, window=window))
 
-    with HIGHEST:
+    with fm.highest():
         o, got = output_and_gradients(flash, weight, q, k, v)
         o_want, want = output_and_gradients(einsum_core, weight, q, k, v)
     np.testing.assert_allclose(o, o_want, rtol=2e-4, atol=2e-5)
@@ -201,7 +181,7 @@ def test_one_span_reads_grouped_keys_at_the_kv_head(group, monkeypatch):
             split(q), split(repeat_kv(k, 1, group)),
             split(repeat_kv(v, 1, group)), causal=True, window=window))
 
-    with HIGHEST:
+    with fm.highest():
         o, got = output_and_gradients(flash, weight, q, k, v)
         o_want, want = output_and_gradients(einsum_core, weight, q, k, v)
     np.testing.assert_allclose(o, o_want, rtol=2e-4, atol=2e-5)
@@ -247,7 +227,7 @@ def test_the_rule_leaves_every_other_kind_alone(monkeypatch):
     asked, rule = [], pk.one_span
     monkeypatch.setattr(pk, "one_span", lambda *a, **k: asked.append(
         rule(*a, **k)) or asked[-1])
-    with HIGHEST:
+    with fm.highest():
         got = jax.jit(jax.grad(by_kernel, argnums=(0, 1, 2)))(q, k, v)
         want = jax.jit(jax.grad(by_einsum, argnums=(0, 1, 2)))(q, k, v)
     # forward and backward both asked, and both took the chunk loop
@@ -445,8 +425,9 @@ def test_gated_attention_matches_the_reference_and_counts_itself():
     rs = np.random.RandomState(3)
     x = jnp.asarray(rs.randn(2, 24, 32), jnp.float32)
     rope = dict(YARN, rope_theta=500000.0, partial_rotary_factor=0.5)
-    with HIGHEST:
-        want = ref.attention(x, params, rope=rope, window=0, operand="f32")
+    with fm.highest():
+        want = jax.jit(lambda x, p: ref.attention(
+            x, p, rope=rope, window=0, operand="f32"))(x, params)
     np.testing.assert_allclose(run_op(op, params, [x] * 3), want, rtol=1e-4,
                                atol=1e-5)
     # each control's program is another model
@@ -475,8 +456,9 @@ def test_the_gates_gradient_by_finite_differences():
         out = op.forward(dict(params, w_gate=w_gate), [x] * 3, ctx)[0]
         return jnp.sum(out * weight)
 
-    with HIGHEST:
-        grad = np.asarray(jax.grad(loss)(params["w_gate"]))
+    with fm.highest():
+        grad = np.asarray(jax.jit(jax.grad(loss))(params["w_gate"]))
+        loss = jax.jit(loss)
         for at in ((0, 0), (7, 3), (31, 5), (16, 2)):
             step = np.zeros((32, 6), np.float32)
             step[at] = 1e-2
@@ -539,18 +521,7 @@ def test_the_search_prices_each_op_with_its_own_heads_window_and_gate():
 
 @pytest.fixture(scope="module")
 def tiny():
-    manifest = mf.load_manifest(ROOT)
-    _, config, traffic = mf.find_cell(manifest, CELL, ROOT)
-    family = hs.load_by_path("families", config["family"], ROOT)
-    s = family.sizes(config, traffic, TINY)
-    # a rate at which two Adam steps move the loss
-    config = dict(config, adam=dict(config["adam"], alpha=1e-3,
-                                    state_dtype="float32"))
-    xs, y = family.make_data(s, 11)
-    weights = jax.device_get(family.make_weights(s, 11))
-    ff = family.build(config, s, 1, 11)
-    family.install_weights(ff, weights)
-    return family, config, s, traffic, xs, y, weights, ff
+    return fm.build_tiny(CELL, TINY)
 
 
 def test_create_decoder_builds_the_cut_from_the_per_layer_lists(tiny):
@@ -607,61 +578,27 @@ def test_model_against_the_reference_logits_and_three_losses(tiny):
 
 
 def test_every_gradient_leaf_matches_the_reference(tiny):
-    family, _, s, _, xs, y, weights, ff = tiny
-    ex = ff.executor
-    inputs = ff._stage_inputs([xs[0]])
-    labels = ff._shard_batch(y)
-
-    def program_loss(p):
-        ctx = OpContext(training=True, rng=jax.random.PRNGKey(0),
-                        compute_dtype=ex.compute_dtype, mesh=ex.mesh)
-        values, _, _ = ex.run_graph(p, {}, inputs, ctx)
-        return ex._loss_value(values[ex.final_ref], labels)
-
-    def reference_loss(w, ids, labels):
-        logits = ref.forward(w, ids, **family.reference_kw(s))
-        return jnp.sum(ref.sample_losses(logits, labels)) / labels.size
-
-    params = {k: {p: jnp.asarray(v) for p, v in leaves.items()}
-              for k, leaves in weights.items()}
-    with HIGHEST:
-        got = jax.jit(jax.grad(program_loss))(params)
-        want = jax.jit(jax.grad(reference_loss))(
-            params, jnp.asarray(xs[0]), jnp.asarray(y))
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    leaves = 0
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
-                            jax.tree.leaves(want)):
-        name = jax.tree_util.keystr(path)
-        if "e_bias" in name:
-            assert not np.any(np.asarray(g)), name     # moves no gradient
-            continue
-        scale = float(jnp.max(jnp.abs(w)))
-        assert scale > 0, name
-        np.testing.assert_allclose(np.asarray(g) / scale,
-                                   np.asarray(w) / scale, atol=2e-4,
-                                   err_msg=name)
-        leaves += 1
-    assert leaves == 2 + 5 * 7 + 2 + 4 * 7 + 1    # the gates' among them
+    _, got, want = fm.gradients_of(tiny)
+    # the routers' bias moves no gradient; the gates' are among the rest
+    assert fm.assert_leaves_close(got, want, still=("e_bias",)) == (
+        2 + 5 * 7 + 2 + 4 * 7 + 1)
 
 
 _CONTROLS_REFERENCE = {}
 
 
 def controls_reference(tiny, layers):
-    """The weights and the reference's predictions of the model cut to
-    ``layers`` layers, made once a cut: the `program_*` keys reach
-    `family.build` alone, so every control of a cut is compared with the
-    same reference on the same weights."""
-    family, config, _, traffic, _, _, _, _ = tiny
+    """The model cut to ``layers`` layers as the cell states it, its
+    weights and the reference's predictions, made once a cut: the
+    `program_*` keys reach `family.build` alone, so every control of a
+    cut is compared with the same reference on the same weights."""
     if layers not in _CONTROLS_REFERENCE:
-        s = family.sizes(config, traffic,
-                         dict(TINY, num_hidden_layers=layers))
-        xs, y = family.make_data(s, 11)
-        weights = jax.device_get(family.make_weights(s, 11))
-        want = hs.reference_side(family, weights, s, traffic, config, xs, y,
-                                 s["batch"], steps=1)
-        _CONTROLS_REFERENCE[layers] = weights, xs, want["preds"]
+        s = tiny.family.sizes(tiny.config, tiny.traffic,
+                              dict(TINY, num_hidden_layers=layers))
+        cut = tiny._replace(s=s, weights=jax.device_get(
+            tiny.family.make_weights(s, 11)))
+        _CONTROLS_REFERENCE[layers] = (
+            cut, fm.reference_predictions(cut)["preds"])
     return _CONTROLS_REFERENCE[layers]
 
 
@@ -677,25 +614,17 @@ def test_a_program_built_otherwise_is_not_correct(tiny, layers, control):
     a wider window; the reference as the cell states it. On the model's
     first layer (full attention, dense), and its first two for the
     window's."""
-    family, config, _, traffic, _, _, _, _ = tiny
-    s = family.sizes(config, traffic,
-                     dict(TINY, num_hidden_layers=layers, **control))
-    weights, xs, want = controls_reference(tiny, layers)
-    ff = family.build(config, s, 1, 11)
-    family.install_weights(ff, weights)
-    got = np.asarray(ff.predict([xs[0][:s["batch"]]])).astype(np.float32)
-    nrmse = hs.prediction_errors(got, want, False)["nrmse"]
-    assert nrmse > family.TOLERANCES["pred_nrmse"]
+    cut, want = controls_reference(tiny, layers)
+    ff, _ = fm.control_model(
+        cut, dict(TINY, num_hidden_layers=layers, **control))
+    nrmse = hs.prediction_errors(fm.predictions(ff, cut), want,
+                                 False)["nrmse"]
+    assert nrmse > tiny.family.TOLERANCES["pred_nrmse"]
 
 
 def test_the_step_names_the_new_scopes(tiny):
-    family, _, s, _, xs, y, _, ff = tiny
     from flexflow_tpu.obs import step_scopes
-    step = ff.executor.make_train_step()
-    text = step.lower(ff.params, ff.opt_state, ff.state,
-                      ff._stage_inputs([xs[0][:s["batch"]]]),
-                      ff._shard_batch(y[:s["batch"]]),
-                      jax.random.PRNGKey(0)).compile().as_text()
+    text = fm.compiled_step_text(tiny)
     for scope in ("jvp(jit(attention_full))/jit(attention_gate)",
                   "transpose(jvp(jit(attention_full)))/jit(attention_gate)",
                   "jvp(jit(attention_window))/jit(attention_gate)",
@@ -735,31 +664,25 @@ def test_four_shares_add_up_to_the_uncut_layer():
         rope_full=(), rope_sliding=(("rope_theta", 10000.0),),
         sliding_window=8, num_experts_per_tok=3, routed_scaling_factor=2.5,
         expert_offset=0)
-    with HIGHEST:
-        want = np.asarray(ref.layer(x, w, 1, ref_kw, "f32"))
-        h = ref.rms_norm(x, w["b1_norm"]["scale"], 1e-6)
+    with fm.highest():
+        want, h = jax.jit(lambda x, w: (
+            ref.layer(x, w, 1, ref_kw, "f32"),
+            ref.rms_norm(x, w["b1_norm"]["scale"], 1e-6)))(x, w)
     attended = np.asarray(x) + run_op(attn, w["b1_attn"], [h] * 3)
-    with HIGHEST:
-        g = ref.rms_norm(jnp.asarray(attended), w["b1_post_norm"]["scale"],
-                         1e-6)
-        p = w["b1_mixer"]
-        shared = np.asarray(ref.shared_expert(g, p, "f32"))
-    total = attended + shared
-    for chip in range(4):
-        held = slice(4 * chip, 4 * chip + 4)
-        op = make_op(OperatorType.MOE_LAYER,
-                     dict(kw, experts_held=4, expert_offset=4 * chip),
-                     [x.shape])
-        share = dict(p, **{n: p[n][held]
-                           for n in ("w_gate", "w_up", "w_down")})
-        total = total + (run_op(op, share, [g]) - shared)
-        # the reference's own share is the same part (a pair the buffer
-        # could not hold would show here)
-        with HIGHEST:
-            part = ref.routed_experts_part(
-                g, share, k=3, scaling=2.5, offset=4 * chip, operand="f32")
-        np.testing.assert_allclose(run_op(op, share, [g]) - shared, part,
-                                   rtol=2e-4, atol=2e-5)
+    p = w["b1_mixer"]
+    with fm.highest():
+        g, shared = jax.jit(lambda a, scale, p: (
+            ref.rms_norm(a, scale, 1e-6),
+            ref.shared_expert(ref.rms_norm(a, scale, 1e-6), p, "f32")))(
+                attended, w["b1_post_norm"]["scale"], p)
+    shared = np.asarray(shared)
+    # the reference's own share is the same part less the shared expert
+    # (a pair the buffer could not hold would show here)
+    parts = fm.expert_shares(
+        kw, p, [g], 4, 4,
+        reference=lambda share, offset: shared + ref.routed_experts_part(
+            g, share, k=3, scaling=2.5, offset=offset, operand="f32"))
+    total = attended + shared + sum(part - shared for part in parts)
     np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
 
 

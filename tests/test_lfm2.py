@@ -11,26 +11,19 @@ uncut layer; the search's price of the new op; the four controls."""
 
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import family_model as fm
+from benchmarks import harness as hs
+from benchmarks.references import lfm2 as ref
+from family_model import ROOT, OpContext, make_op, run_op
+from flexflow_tpu.ffconst import OperatorType
+from one_program import output_and_gradients
 
-from benchmarks import harness as hs  # noqa: E402
-from benchmarks import manifest as mf  # noqa: E402
-from benchmarks.references import lfm2 as ref  # noqa: E402
-from flexflow_tpu.ffconst import OperatorType  # noqa: E402
-from flexflow_tpu.layer import Layer  # noqa: E402
-from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
-from one_program import output_and_gradients  # noqa: E402
-
-HIGHEST = jax.default_matmul_precision("highest")
 CELL = "lfm2_8b_a1b.s16384_b1.1chip"
 # every width small, the structure whole: conv + MLP, attention + experts,
 # conv + experts three times; 4 query heads a key/value head; 4 held
@@ -41,19 +34,6 @@ TINY = dict(num_hidden_layers=5, vocab_size=64, hidden_size=32,
             num_experts_per_tok=3, moe_intermediate_size=24, slot_slack=3.0,
             initializer_range=0.2, embedding_std=0.2, seq=32, batch=2,
             steps_per_epoch=1)
-
-
-def make_op(kind, props, shapes):
-    layer = Layer(kind, "op", [])
-    layer.properties.update(props)
-    return OpRegistry.create(layer, shapes)
-
-
-def run_op(op, params, inputs):
-    ctx = OpContext(training=False, compute_dtype=jnp.float32)
-    with HIGHEST:
-        return np.asarray(jax.jit(lambda p, x: op.forward(p, x, ctx)[0])(
-            params, inputs))
 
 
 # ---------------------------------------------------------------------------
@@ -88,20 +68,20 @@ def test_short_conv_matches_the_three_term_sum(taps, gate):
     # the sum as written and its gradients, one program
     ctx = OpContext(training=True, compute_dtype=jnp.float32)
     weight = rs.randn(*h.shape).astype(np.float32)
-    with HIGHEST:
+    with fm.highest():
         want, want_grads = output_and_gradients(
             lambda p, h: three_term_sum(h, p, taps, gate), weight, p, h)
     np.testing.assert_allclose(run_op(op, p, [h]), want, rtol=1e-5,
                                atol=1e-6)
     # the reference writes the same sum as shifted products
     if gate:
-        with HIGHEST:
+        with fm.highest():
             np.testing.assert_allclose(
                 jax.jit(lambda h, p: ref.short_conv(h, p, "f32"))(h, p),
                 want, rtol=1e-5, atol=1e-6)
     # backward: the op's own (it keeps the projection alone) against
     # autodiff of the sum as written, for the input and every leaf
-    with HIGHEST:
+    with fm.highest():
         got = jax.jit(jax.grad(lambda p, h: jnp.sum(
             op.forward(p, [h], ctx)[0] * weight), argnums=(0, 1)))(p, h)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want_grads)):
@@ -126,7 +106,7 @@ def test_the_second_sample_reads_nothing_of_the_first():
         assert np.array_equal(a[1], b[1])
         assert not np.array_equal(a[0], b[0])
     # position 0 sees its own lane alone: y_0 = (C_0 * w_2 * B_0 * x_0) W_out
-    with HIGHEST:
+    with fm.highest():
         b_, c_, x_ = jnp.split(h[1, 0] @ p["w_in"], 3)
         want = (c_ * p["conv_w"][2] * b_ * x_) @ p["w_out"]
     np.testing.assert_allclose(run_op(op, p, [h])[1, 0], want, rtol=1e-5,
@@ -215,18 +195,7 @@ def test_the_search_prices_the_convolution_op():
 
 @pytest.fixture(scope="module")
 def tiny():
-    manifest = mf.load_manifest(ROOT)
-    _, config, traffic = mf.find_cell(manifest, CELL, ROOT)
-    family = hs.load_by_path("families", config["family"], ROOT)
-    s = family.sizes(config, traffic, TINY)
-    # a rate at which two Adam steps move the loss
-    config = dict(config, adam=dict(config["adam"], alpha=1e-3,
-                                    state_dtype="float32"))
-    xs, y = family.make_data(s, 11)
-    weights = jax.device_get(family.make_weights(s, 11))
-    ff = family.build(config, s, 1, 11)
-    family.install_weights(ff, weights)
-    return family, config, s, traffic, xs, y, weights, ff
+    return fm.build_tiny(CELL, TINY)
 
 
 def test_create_decoder_builds_the_cut_from_the_public_keys(tiny):
@@ -288,51 +257,15 @@ def test_model_against_the_reference_logits_and_three_losses(tiny):
 
 @pytest.fixture(scope="module")
 def gradients(tiny):
-    """(the weights as arrays, the program's gradient of its loss, the
-    reference's of its own) on the whole epoch's batch."""
-    family, _, s, _, xs, y, weights, ff = tiny
-    ex = ff.executor
-    inputs = ff._stage_inputs([xs[0]])
-    labels = ff._shard_batch(y)
-
-    def program_loss(p):
-        ctx = OpContext(training=True, rng=jax.random.PRNGKey(0),
-                        compute_dtype=ex.compute_dtype, mesh=ex.mesh)
-        values, _, _ = ex.run_graph(p, {}, inputs, ctx)
-        return ex._loss_value(values[ex.final_ref], labels)
-
-    def reference_loss(w, ids, labels):
-        logits = ref.forward(w, ids, **family.reference_kw(s))
-        return jnp.sum(ref.sample_losses(logits, labels)) / labels.size
-
-    params = {k: {p: jnp.asarray(v) for p, v in leaves.items()}
-              for k, leaves in weights.items()}
-    with HIGHEST:
-        got = jax.jit(jax.grad(program_loss))(params)
-        want = jax.jit(jax.grad(reference_loss))(
-            params, jnp.asarray(xs[0]), jnp.asarray(y))
-    return params, got, want
+    return fm.gradients_of(tiny)
 
 
 def test_every_gradient_leaf_matches_the_reference(gradients):
     _, got, want = gradients
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    leaves = 0
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
-                            jax.tree.leaves(want)):
-        name = jax.tree_util.keystr(path)
-        if "e_bias" in name:
-            assert not np.any(np.asarray(g)), name     # moves no gradient
-            continue
-        scale = float(jnp.max(jnp.abs(w)))
-        assert scale > 0, name
-        np.testing.assert_allclose(np.asarray(g) / scale,
-                                   np.asarray(w) / scale, atol=2e-4,
-                                   err_msg=name)
-        leaves += 1
     # the table; 4 conv layers of 2 norms + 3 leaves; the attention
     # layer's 2 + 6; the MLP's 2; 4 expert layers' 4; the final norm
-    assert leaves == 1 + 4 * 5 + 8 + 2 + 4 * 4 + 1
+    assert fm.assert_leaves_close(got, want, still=("e_bias",)) == (
+        1 + 4 * 5 + 8 + 2 + 4 * 4 + 1)
 
 
 def test_one_table_with_both_uses_gradients_and_one_adam_state(
@@ -371,7 +304,7 @@ def test_one_table_with_both_uses_gradients_and_one_adam_state(
         return jnp.sum(ref.sample_losses(logits, jnp.asarray(y))) / y.size
 
     e = params["embed_tokens"]["kernel"]
-    with HIGHEST:
+    with fm.highest():
         gather, head = jax.jit(jax.grad(two_tables, argnums=(0, 1)))(e, e)
     assert float(jnp.max(jnp.abs(gather))) > 0 < float(jnp.max(jnp.abs(head)))
     scale = float(jnp.max(jnp.abs(gather + head)))
@@ -403,30 +336,27 @@ def test_a_program_built_otherwise_is_not_correct(tiny, control):
     table of its own for the head, the heads' norm left out; the
     reference as the cell states it. On the model's first two layers
     (conv + MLP, attention + experts)."""
-    family, config, _, traffic, _, _, _, _ = tiny
-    cut = dict(TINY, num_hidden_layers=2)
-    s = family.sizes(config, traffic, dict(cut, **control))
+    family = tiny.family
+    sizes = dict(TINY, num_hidden_layers=2)
     if not _CONTROLS_REFERENCE:
-        # the cut's weights, batch and reference, made once: the
-        # `program_*` keys reach `family.build` alone
-        stated = family.sizes(config, traffic, cut)
-        xs, y = family.make_data(stated, 11)
-        weights = jax.device_get(family.make_weights(stated, 11))
-        want = hs.reference_side(family, weights, stated, traffic, config,
-                                 xs, y, stated["batch"], steps=1)["preds"]
-        _CONTROLS_REFERENCE.extend([weights, xs, y, want])
-    weights, xs, y, want = _CONTROLS_REFERENCE
+        # the cut's weights and reference, made once: the `program_*`
+        # keys reach `family.build` alone
+        s = family.sizes(tiny.config, tiny.traffic, sizes)
+        cut = tiny._replace(s=s, weights=jax.device_get(
+            family.make_weights(s, 11)))
+        _CONTROLS_REFERENCE.extend(
+            [cut, fm.reference_predictions(cut)["preds"]])
+    cut, want = _CONTROLS_REFERENCE
+    weights = cut.weights
     if "program_qk_layernorm" in control:
         # scales of one and heads of unit variance would hide the norm
         weights = dict(weights, b1_attn=dict(weights["b1_attn"], **{
             name: weights["b1_attn"][name] * 3.0
             for name in ("q_norm", "k_norm")}))
-        want = hs.reference_side(family, weights, s, traffic, config, xs, y,
-                                 s["batch"], steps=1)["preds"]
-    ff = family.build(config, s, 1, 11)
-    family.install_weights(ff, weights)
-    got = np.asarray(ff.predict([xs[0][:s["batch"]]])).astype(np.float32)
-    nrmse = hs.prediction_errors(got, want, False)["nrmse"]
+        want = fm.reference_predictions(cut, weights=weights)["preds"]
+    ff, s = fm.control_model(cut, dict(sizes, **control), weights)
+    nrmse = hs.prediction_errors(fm.predictions(ff, cut), want,
+                                 False)["nrmse"]
     assert nrmse > family.TOLERANCES["pred_nrmse"]
     checks = dict((n, ok) for n, ok, _ in family.extra_checks(ff, s, 1,
                                                               False))
@@ -434,13 +364,8 @@ def test_a_program_built_otherwise_is_not_correct(tiny, control):
 
 
 def test_the_step_names_the_new_scopes(tiny):
-    family, _, s, _, xs, y, _, ff = tiny
     from flexflow_tpu.obs import step_scopes
-    step = ff.executor.make_train_step()
-    text = step.lower(ff.params, ff.opt_state, ff.state,
-                      ff._stage_inputs([xs[0][:s["batch"]]]),
-                      ff._shard_batch(y[:s["batch"]]),
-                      jax.random.PRNGKey(0)).compile().as_text()
+    text = fm.compiled_step_text(tiny)
     for scope in ("jvp(jit(op_short_conv))/jit(gated_conv)",
                   "transpose(jvp(jit(op_short_conv)))",
                   "jit(attention_full))/jit(rotary_whole)",
@@ -478,32 +403,22 @@ def test_four_shares_add_up_to_the_uncut_layer():
     ref_kw = dict(eps=1e-5, layer_types=("conv",), rope_theta=1e6,
                   num_experts_per_tok=3, routed_scaling_factor=1.0,
                   expert_offset=0)
-    with HIGHEST:
-        want = np.asarray(ref.layer(x, w, 0, ref_kw, "f32"))
-        h = ref.rms_norm(x, w["b0_norm"]["scale"], 1e-5)
+    with fm.highest():
+        want, h = jax.jit(lambda x, w: (
+            ref.layer(x, w, 0, ref_kw, "f32"),
+            ref.rms_norm(x, w["b0_norm"]["scale"], 1e-5)))(x, w)
     mixed = np.asarray(x) + run_op(conv, w["b0_conv"], [h])
-    with HIGHEST:
-        g = ref.rms_norm(jnp.asarray(mixed), w["b0_post_norm"]["scale"],
-                         1e-5)
-    p = w["b0_mixer"]
-    total = mixed
-    for chip in range(4):
-        held = slice(4 * chip, 4 * chip + 4)
-        op = make_op(OperatorType.MOE_LAYER,
-                     dict(kw, experts_held=4, expert_offset=4 * chip),
-                     [x.shape])
-        share = dict(p, **{n: p[n][held]
-                           for n in ("w_gate", "w_up", "w_down")})
-        part = run_op(op, share, [g])
-        total = total + part
-        # the reference's own share is the same part (a pair the buffer
-        # could not hold would show here)
-        with HIGHEST:
-            np.testing.assert_allclose(
-                part, ref.experts(g, share, k=3, scaling=1.0,
-                                  offset=4 * chip, operand="f32"),
-                rtol=2e-4, atol=2e-5)
-    np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
+    with fm.highest():
+        g = jax.jit(ref.rms_norm, static_argnums=2)(
+            mixed, w["b0_post_norm"]["scale"], 1e-5)
+    # the reference's own share is the same part (a pair the buffer
+    # could not hold would show here)
+    parts = fm.expert_shares(
+        kw, w["b0_mixer"], [g], 4, 4,
+        reference=lambda share, offset: ref.experts(
+            g, share, k=3, scaling=1.0, offset=offset, operand="f32"))
+    np.testing.assert_allclose(mixed + sum(parts), want, rtol=2e-4,
+                               atol=2e-5)
 
 
 def test_search_prices_and_places_the_new_op_and_the_one_table(tiny):

@@ -4,25 +4,18 @@ logits, loss and every gradient leaf; the chunked scan against the
 stepwise recurrence; the grouped product against a loop; and the share
 tests that tie a chip's share of a layer to the uncut layer."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmarks import harness as hs  # noqa: E402
-from benchmarks.references import nemotron_h as ref  # noqa: E402
-from flexflow_tpu.ffconst import OperatorType  # noqa: E402
-from flexflow_tpu.layer import Layer  # noqa: E402
-from flexflow_tpu.ops import moe, pallas_kernels, ssm  # noqa: E402
-from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
-from one_program import output_and_gradients  # noqa: E402
+import family_model as fm
+from benchmarks import harness as hs
+from benchmarks.references import nemotron_h as ref
+from family_model import OpContext, make_op, run_op
+from flexflow_tpu.ffconst import OperatorType
+from flexflow_tpu.ops import moe, pallas_kernels, ssm
+from one_program import output_and_gradients
 
 family = hs.load_by_path("families", "nemotron_h")
 
@@ -38,10 +31,7 @@ TINY = dict(
     norm_topk_prob=True, slot_slack=3.0, initializer_range=0.2,
     embedding_std=1.0, seq=29,
     batch=2, steps_per_epoch=1)
-CONFIG = dict(search_budget=2, adam=dict(
-    alpha=1e-3, beta1=0.9, beta2=0.999, epsilon=1e-8, weight_decay=0.0,
-    state_dtype="float32"))
-HIGHEST = jax.default_matmul_precision("highest")
+CONFIG = dict(search_budget=2, adam=fm.ADAM)
 
 # What a case costs is the programs it compiles (ROADMAP D10), and a
 # `jnp` call outside `jax.jit` compiles one an operation: the tests below
@@ -53,16 +43,18 @@ route_held_experts = jax.jit(moe.route_held_experts, static_argnums=(1, 2, 3))
 
 @pytest.fixture(scope="module")
 def model():
-    ff = family.build(CONFIG, TINY, 1, 3)
-    weights = jax.device_get(family.make_weights(TINY, 3))
-    family.install_weights(ff, weights)
-    (ids,), labels = family.make_data(TINY, 3)
+    ff, weights, (ids,), labels = fm.build_model(family, CONFIG, TINY, 3)
     return ff, weights, ids, labels
 
 
-def reference_loss(w, ids, labels):
-    logits = ref.forward(w, ids, **family.reference_kw(TINY))
-    return jnp.sum(ref.sample_losses(logits, labels)) / labels.size
+@pytest.fixture(scope="module")
+def reference(model):
+    """(the reference's loss on the module's batch, its gradient): ONE
+    program, a sample a call, by the harness's own driver."""
+    from benchmarks.references import common
+    _, weights, ids, labels = model
+    return common.loss_and_grads(ref, fm.as_arrays(weights), ids, labels, 1,
+                                 **family.reference_kw(TINY))
 
 
 def test_searched_like_any_other_graph(model):
@@ -73,13 +65,13 @@ def test_searched_like_any_other_graph(model):
             OperatorType.MULTIHEAD_ATTENTION} <= types
 
 
-def test_forward_logits_and_loss_match_the_reference(model):
+def test_forward_logits_and_loss_match_the_reference(model, reference):
     ff, weights, ids, labels = model
     got = np.asarray(ff.predict([ids]))
-    with HIGHEST:
+    with fm.highest():
         want = np.asarray(jax.jit(lambda w, ids: ref.forward(
             w, ids, **family.reference_kw(TINY)))(weights, ids))
-        want_loss = float(jax.jit(reference_loss)(weights, ids, labels))
+    want_loss = reference[0]
     assert got.shape == (TINY["batch"], TINY["seq"], TINY["vocab_size"])
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     ff.fit([ids], labels, epochs=1, verbose=False)
@@ -90,31 +82,15 @@ def test_forward_logits_and_loss_match_the_reference(model):
     assert ff.op_counters["moe/load_max_over_mean"] >= 1.0
 
 
-def test_every_gradient_leaf_matches_the_reference(model):
+def test_every_gradient_leaf_matches_the_reference(model, reference):
     ff, weights, ids, labels = model
-    ex = ff.executor
-    inputs = ff._stage_inputs([ids])
-    lab = ff._shard_batch(labels)
-
-    def program_loss(p):
-        ctx = OpContext(training=True, rng=jax.random.PRNGKey(0),
-                        compute_dtype=ex.compute_dtype, mesh=ex.mesh)
-        values, _, _ = ex.run_graph(p, {}, inputs, ctx)
-        return ex._loss_value(values[ex.final_ref], lab)
-
-    params = {k: {p: jnp.asarray(v) for p, v in leaves.items()}
-              for k, leaves in weights.items()}
-    with HIGHEST:
-        got = jax.jit(jax.grad(program_loss))(params)
-        want = jax.jit(jax.grad(reference_loss))(params, jnp.asarray(ids),
-                                                 jnp.asarray(labels))
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
-                            jax.tree.leaves(want)):
-        scale = float(jnp.max(jnp.abs(w))) or 1.0
-        np.testing.assert_allclose(np.asarray(g) / scale,
-                                   np.asarray(w) / scale, atol=2e-4,
-                                   err_msg=jax.tree_util.keystr(path))
+    with fm.highest():
+        got = jax.jit(jax.grad(fm.program_loss_of(ff, [ids], labels)))(
+            fm.as_arrays(weights))
+    want = reference[1]
+    # the routers' bias moves no gradient on either side
+    compared = fm.assert_leaves_close(got, want, still=("e_bias",))
+    assert compared == len(jax.tree.leaves(want)) - 4
 
 
 # ---------------------------------------------------------------------------
@@ -140,7 +116,7 @@ def test_chunked_scan_matches_the_stepwise_recurrence(length):
     weight = np.random.RandomState(1).randn(*args[0].shape).astype(
         np.float32)
 
-    with HIGHEST:
+    with fm.highest():
         y, got = output_and_gradients(
             lambda *a: ssm.ssd_chunked(*a, chunk=8), weight, *args)
         y_want, want = output_and_gradients(ssm.ssd_stepwise, weight, *args)
@@ -181,7 +157,7 @@ def test_grouped_matmul_matches_a_loop(mode, monkeypatch):
         return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
                                           has_aux=True))(lhs, rhs)
 
-    with HIGHEST:
+    with fm.highest():
         (_, y), got = value_and_gradients(grouped)
         (_, y_want), want = value_and_gradients(loop)
     np.testing.assert_allclose(y, y_want, rtol=1e-4, atol=1e-4)
@@ -535,6 +511,9 @@ LAYERS = {
 }
 
 
+_SCATTER_ADD = {}
+
+
 @pytest.mark.parametrize("mode", ["off", "interpret"])
 @pytest.mark.parametrize("name", list(LAYERS))
 def test_layer_gradients_match_the_scatter_add_form(name, mode, monkeypatch):
@@ -550,9 +529,9 @@ def test_layer_gradients_match_the_scatter_add_form(name, mode, monkeypatch):
     inputs = [rs.randn(2, seq, width).astype(np.float32)
               for _ in range(n_inputs)]
     probe = rs.randn(2, seq, width).astype(np.float32)
-    layer = Layer(OperatorType.MOE_LAYER, "op", [])
+    layer = fm.Layer(OperatorType.MOE_LAYER, "op", [])
     layer.properties.update(props)
-    op = OpRegistry.create(layer, [x.shape for x in inputs])
+    op = fm.OpRegistry.create(layer, [x.shape for x in inputs])
     params = jax.jit(op.init_params)(jax.random.PRNGKey(4))
     if "e_bias" in params:
         params["e_bias"] = (0.1 * rs.randn(props["n_experts"])).astype(
@@ -565,12 +544,18 @@ def test_layer_gradients_match_the_scatter_add_form(name, mode, monkeypatch):
         op._counters = None
         return y, overflow
 
-    with HIGHEST:
+    with fm.highest():
         (y, overflow), got = output_and_gradients(program, probe, params,
                                                   inputs)
-        y_want, want = output_and_gradients(
-            lambda p, xs: scatter_add_layer(op, p, xs), probe, params,
-            inputs)
+        if name not in _SCATTER_ADD:
+            # once a layer, for both modes: the form's grouped products
+            # by `ragged_dot` (the seeds give both cases the same
+            # operands and leaves)
+            with fm.pallas("off"):
+                _SCATTER_ADD[name] = output_and_gradients(
+                    lambda p, xs: scatter_add_layer(op, p, xs), probe,
+                    params, inputs)
+        y_want, want = _SCATTER_ADD[name]
     np.testing.assert_allclose(y, y_want, rtol=1e-5, atol=1e-5)
     overflow = float(overflow)
     assert (overflow > 0) == ("buffer_too_small" in name)
@@ -599,18 +584,6 @@ def test_layer_gradients_match_the_scatter_add_form(name, mode, monkeypatch):
 # is the uncut layer
 
 
-def make_op(kind, props, shape):
-    layer = Layer(kind, "op", [])
-    layer.properties.update(props)
-    return OpRegistry.create(layer, [shape])
-
-
-def run_op(op, params, x):
-    ctx = OpContext(training=False, compute_dtype=jnp.float32)
-    with HIGHEST:
-        return np.asarray(op.forward(params, [x], ctx)[0])
-
-
 @pytest.fixture(scope="module")
 def hidden():
     return jnp.asarray(np.random.RandomState(5).randn(2, 24, 32), jnp.float32)
@@ -621,24 +594,18 @@ def test_sixteen_expert_shares_add_up_to_the_uncut_layer(hidden):
     against the reference's uncut layer (all 16 experts held)."""
     kw = dict(n_experts=16, k=3, hidden_size=24, shared_width=48,
               routed_scaling=2.5, slot_slack=15.0)
-    full = make_op(OperatorType.MOE_LAYER, kw, hidden.shape)
+    full = make_op(OperatorType.MOE_LAYER, kw, [hidden.shape])
     params = full.init_params(jax.random.PRNGKey(1))
-    with HIGHEST:
-        want = np.asarray(ref.experts(hidden, params, k=3, scaling=2.5,
-                                      offset=0, operand="f32"))
-        shared = np.asarray(ref.relu2_mlp(hidden, params["ws_up"],
-                                          params["ws_down"], "f32"))
-    np.testing.assert_allclose(run_op(full, params, hidden), want,
+    with fm.highest():
+        want, shared = jax.jit(lambda x, p: (
+            ref.experts(x, p, k=3, scaling=2.5, offset=0, operand="f32"),
+            ref.relu2_mlp(x, p["ws_up"], p["ws_down"], "f32")))(
+                hidden, params)
+    np.testing.assert_allclose(run_op(full, params, [hidden]), want,
                                rtol=1e-4, atol=1e-4)
-    total = np.zeros_like(want)
-    for chip in range(16):
-        op = make_op(OperatorType.MOE_LAYER,
-                     dict(kw, experts_held=1, expert_offset=chip),
-                     hidden.shape)
-        share = dict(params, w_up=params["w_up"][chip:chip + 1],
-                     w_down=params["w_down"][chip:chip + 1])
-        total += run_op(op, share, hidden) - shared
-        assert float(op._counters["moe/overflow_slots"][1]) == 0
+    parts = fm.expert_shares(kw, params, [hidden], 1, 16,
+                             leaves=("w_up", "w_down"))
+    total = sum(part - np.asarray(shared) for part in parts)
     np.testing.assert_allclose(total + shared, want, rtol=1e-4, atol=1e-4)
 
 
@@ -649,19 +616,22 @@ def test_eight_head_shares_of_a_mamba_mixer_add_up(hidden):
     h, p, g, n = 8, 4, 8, 8
     kw = dict(num_heads=h, head_dim=p, n_groups=g, state_size=n,
               chunk_size=8)
-    full = make_op(OperatorType.SSM_MIXER, kw, hidden.shape)
+    full = make_op(OperatorType.SSM_MIXER, kw, [hidden.shape])
     params = full.init_params(jax.random.PRNGKey(2))
-    with HIGHEST:
-        want = np.asarray(ref.mamba2(
-            hidden, params, heads=h, head_dim=p, groups=g, state=n,
-            eps=1e-5, operand="f32"))
-    np.testing.assert_allclose(run_op(full, params, hidden), want,
+    with fm.highest():
+        want = np.asarray(jax.jit(lambda x, w: ref.mamba2(
+            x, w, heads=h, head_dim=p, groups=g, state=n, eps=1e-5,
+            operand="f32"))(hidden, params))
+    np.testing.assert_allclose(run_op(full, params, [hidden]), want,
                                rtol=1e-4, atol=1e-4)
     d_inner, gn = h * p, g * n
     cols = np.arange(2 * d_inner + 2 * gn + h)
     z, xs, bs, cs, dts = np.split(cols, np.cumsum(
         [d_inner, d_inner, gn, gn]))
     total = np.zeros_like(want)
+    a_share = fm.op_program(make_op(
+        OperatorType.SSM_MIXER, dict(kw, num_heads=1, n_groups=1),
+        [hidden.shape]))
     for chip in range(8):
         head = slice(chip * p, (chip + 1) * p)
         grp = slice(chip * n, (chip + 1) * n)
@@ -675,9 +645,7 @@ def test_eight_head_shares_of_a_mamba_mixer_add_up(hidden):
             a_log=params["a_log"][chip:chip + 1], d=params["d"][chip:chip + 1],
             norm_scale=params["norm_scale"][head],
             w_out=params["w_out"][head])
-        op = make_op(OperatorType.SSM_MIXER,
-                     dict(kw, num_heads=1, n_groups=1), hidden.shape)
-        total += run_op(op, share, hidden)
+        total += a_share(share, [hidden])
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-4)
 
 
@@ -686,22 +654,24 @@ def test_eight_head_shares_of_attention_add_up(hidden):
     heads of 8 on a model width of 32 that they do not divide."""
     kw = dict(embed_dim=32, num_heads=16, num_kv_heads=8, head_dim=8,
               bias=False, causal=True)
-    full = make_op(OperatorType.MULTIHEAD_ATTENTION, kw, hidden.shape)
+    full = make_op(OperatorType.MULTIHEAD_ATTENTION, kw, [hidden.shape])
     assert full.head_dim == 8
     params = full.init_params(jax.random.PRNGKey(3))
-    with HIGHEST:
-        want = np.asarray(ref.attention(hidden, params, "f32"))
-    np.testing.assert_allclose(run_op(full, params, hidden), want,
+    with fm.highest():
+        want = np.asarray(jax.jit(lambda x, w: ref.attention(x, w, "f32"))(
+            hidden, params))
+    np.testing.assert_allclose(run_op(full, params, [hidden]), want,
                                rtol=1e-4, atol=1e-4)
     total = np.zeros_like(want)
+    a_share = fm.op_program(make_op(
+        OperatorType.MULTIHEAD_ATTENTION,
+        dict(kw, num_heads=2, num_kv_heads=1), [hidden.shape]))
     for chip in range(8):
         q = slice(2 * chip, 2 * chip + 2)
         share = dict(wq=params["wq"][q], wo=params["wo"][q],
                      wk=params["wk"][chip:chip + 1],
                      wv=params["wv"][chip:chip + 1])
-        op = make_op(OperatorType.MULTIHEAD_ATTENTION,
-                     dict(kw, num_heads=2, num_kv_heads=1), hidden.shape)
-        total += run_op(op, share, hidden)
+        total += a_share(share, [hidden])
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-4)
 
 
@@ -714,7 +684,7 @@ def test_a_sliced_vocabulary_gives_the_slice_of_the_logits(model):
     sliced = dict(weights,
                   embed_tokens={"kernel": weights["embed_tokens"]["kernel"][:16]},
                   lm_head={"kernel": weights["lm_head"]["kernel"][:, :16]})
-    with HIGHEST:
+    with fm.highest():
         forward = jax.jit(lambda w, ids: ref.forward(w, ids, **kw))
         full = forward(weights, ids)
         part = forward(sliced, ids)
@@ -723,7 +693,7 @@ def test_a_sliced_vocabulary_gives_the_slice_of_the_logits(model):
 
 def test_attention_head_dim_defaults_to_the_split_of_the_width(hidden):
     op = make_op(OperatorType.MULTIHEAD_ATTENTION,
-                 dict(embed_dim=32, num_heads=4), hidden.shape)
+                 dict(embed_dim=32, num_heads=4), [hidden.shape])
     assert op.head_dim == 8 and "head_dim" not in op.layer.properties
 
 

@@ -19,20 +19,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import family_model as fm
+from benchmarks import harness as hs
+from benchmarks.references import common
+from benchmarks.references import ouro as ref
+from family_model import ROOT, program_loss_of
+from flexflow_tpu import AdamOptimizer, FFConfig, FFModel, LossType
+from flexflow_tpu.models import DecoderConfig, create_decoder
 
-from benchmarks import harness as hs  # noqa: E402
-from benchmarks import manifest as mf  # noqa: E402
-from benchmarks.references import common  # noqa: E402
-from benchmarks.references import ouro as ref  # noqa: E402
-from flexflow_tpu import (AdamOptimizer, FFConfig, FFModel,  # noqa: E402
-                          LossType)
-from flexflow_tpu.models import DecoderConfig, create_decoder  # noqa: E402
-from flexflow_tpu.ops.base import OpContext  # noqa: E402
-
-HIGHEST = jax.default_matmul_precision("highest")
 CELL = "ouro_2_6b.s4096_b1.1chip"
 # every width small, the structure whole: two blocks with sandwich norms,
 # three passes, as many key/value heads as query heads, an untied head
@@ -46,26 +40,14 @@ LAYER_OPS = ("norm", "attn", "attn_out_norm", "post_norm", "gate_up_proj",
 
 @pytest.fixture(scope="module")
 def cell():
-    manifest = mf.load_manifest(ROOT)
-    _, config, traffic = mf.find_cell(manifest, CELL, ROOT)
-    family = hs.load_by_path("families", config["family"], ROOT)
-    # a rate at which two Adam steps move the loss; ten rounds of the
-    # search (at two layers and three passes thirty take half a minute)
-    config = dict(config, search_budget=10,
-                  adam=dict(config["adam"], alpha=1e-3,
-                            state_dtype="float32"))
-    return family, config, traffic
+    # ten rounds of the search (at two layers and three passes thirty
+    # take half a minute)
+    return fm.load_cell(CELL, search_budget=10)
 
 
 @pytest.fixture(scope="module")
 def tiny(cell):
-    family, config, traffic = cell
-    s = family.sizes(config, traffic, TINY)
-    xs, y = family.make_data(s, 11)
-    weights = jax.device_get(family.make_weights(s, 11))
-    ff = family.build(config, s, 1, 11)
-    family.install_weights(ff, weights)
-    return family, config, s, traffic, xs, y, weights, ff
+    return fm.build_tiny(cell, TINY)
 
 
 def test_create_decoder_builds_owners_and_readers(tiny):
@@ -140,88 +122,57 @@ def test_the_loss_and_its_counters_against_the_reference(tiny):
     """`losses.expected_exit_loss` on a made-up output: the loss, the
     passes' cross-entropies, the masses and the entropy are the
     reference's; at gates of zero the masses are 1/2, 1/4, 1/4."""
-    from flexflow_tpu.losses import expected_exit_loss
+    from flexflow_tpu import losses
+    # (one program a call: eagerly every `jnp` operation of the loss and
+    # of the reference is a program of its own, ROADMAP D10)
+    expected_exit_loss = jax.jit(losses.expected_exit_loss,
+                                 static_argnums=2,
+                                 static_argnames=("beta", "uniform"))
     rs = np.random.RandomState(3)
-    out = jnp.asarray(rs.randn(2, 3 * 8, 13), jnp.float32)
-    y = jnp.asarray(rs.randint(0, 12, (2, 8)), jnp.int32)
+    out = rs.randn(2, 3 * 8, 13).astype(np.float32)
+    y = rs.randint(0, 12, (2, 8)).astype(np.int32)
     loss, counted = expected_exit_loss(out, y, 3, beta=0.1)
-    per_position, p, ce = ref.position_losses(out, y)
-    np.testing.assert_allclose(loss, jnp.mean(per_position), rtol=1e-6)
+    per_position, p, ce = map(np.asarray, jax.jit(ref.position_losses)(
+        out, y))
+    np.testing.assert_allclose(loss, np.mean(per_position), rtol=1e-6)
     np.testing.assert_allclose(counted["loss/exit_nll"],
-                               jnp.sum(ce, axis=(0, 2)), rtol=1e-6)
+                               np.sum(ce, axis=(0, 2)), rtol=1e-6)
     np.testing.assert_allclose(counted["loss/exit_mass"],
-                               jnp.sum(p, axis=(0, 2)), rtol=1e-6)
-    entropy = -jnp.sum(p * jnp.log(p), axis=1)
+                               np.sum(p, axis=(0, 2)), rtol=1e-6)
+    entropy = -np.sum(p * np.log(p), axis=1)
     np.testing.assert_allclose(counted["loss/exit_entropy"],
-                               jnp.sum(entropy), rtol=1e-6)
-    even = out.at[..., -1].set(0.0)
+                               np.sum(entropy), rtol=1e-6)
+    even = out.copy()
+    even[..., -1] = 0.0
     _, counted = expected_exit_loss(even, y, 3)
     np.testing.assert_allclose(counted["loss/exit_mass"],
                                [8.0, 4.0, 4.0], rtol=1e-6)
     # the last pass's gate is not read: it gets no gradient
-    gate_grad = jax.grad(lambda o: expected_exit_loss(o, y, 3, 0.1)[0])(
-        out)[..., -1].reshape(2, 3, 8)
-    assert not np.any(np.asarray(gate_grad[:, 2]))
-    assert np.all(np.asarray(gate_grad[:, :2]) != 0)
+    gate_grad = np.asarray(jax.jit(jax.grad(
+        lambda o: losses.expected_exit_loss(o, y, 3, 0.1)[0]))(out))[
+            ..., -1].reshape(2, 3, 8)
+    assert not np.any(gate_grad[:, 2])
+    assert np.all(gate_grad[:, :2] != 0)
     # one pass: the plain cross-entropy, no entropy
     one, counted = expected_exit_loss(out[:, :8], y, 1, beta=0.1)
-    np.testing.assert_allclose(one, jnp.mean(ce[:, 0]), rtol=1e-6)
+    np.testing.assert_allclose(one, np.mean(ce[:, 0]), rtol=1e-6)
     assert float(counted["loss/exit_entropy"]) == 0.0
     uniform, _ = expected_exit_loss(out, y, 3, beta=0.1, uniform=True)
     np.testing.assert_allclose(
-        uniform, jnp.mean(jnp.mean(ce, axis=1)) - 0.1 * np.log(3.0),
+        uniform, np.mean(np.mean(ce, axis=1)) - 0.1 * np.log(3.0),
         rtol=1e-6)
-
-
-def program_loss_of(ff, xs, y):
-    ex = ff.executor
-    inputs = ff._stage_inputs([xs[0]])
-    labels = ff._shard_batch(y)
-
-    def loss(p):
-        ctx = OpContext(training=True, rng=jax.random.PRNGKey(0),
-                        compute_dtype=ex.compute_dtype, mesh=ex.mesh)
-        values, _, _ = ex.run_graph(p, {}, inputs, ctx)
-        return ex._loss_value(values[ex.final_ref], labels)
-
-    return loss
 
 
 @pytest.fixture(scope="module")
 def gradients(tiny):
-    """(the weights as arrays, the program's gradient of its loss, the
-    reference's of its own) on the whole epoch's batch."""
-    family, _, s, _, xs, y, weights, ff = tiny
-
-    def reference_loss(w, ids, labels):
-        out = ref.forward(w, ids, **family.reference_kw(s))
-        return jnp.sum(ref.sample_losses(out, labels)) / labels.size
-
-    params = {k: {p: jnp.asarray(v) for p, v in leaves.items()}
-              for k, leaves in weights.items()}
-    with HIGHEST:
-        got = jax.jit(jax.grad(program_loss_of(ff, xs, y)))(params)
-        want = jax.jit(jax.grad(reference_loss))(
-            params, jnp.asarray(xs[0]), jnp.asarray(y))
-    return params, got, want
+    return fm.gradients_of(tiny)
 
 
 def test_every_gradient_leaf_matches_the_reference(gradients):
     _, got, want = gradients
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    leaves = 0
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
-                            jax.tree.leaves(want)):
-        name = jax.tree_util.keystr(path)
-        scale = float(jnp.max(jnp.abs(w)))
-        assert scale > 0, name
-        np.testing.assert_allclose(np.asarray(g) / scale,
-                                   np.asarray(w) / scale, atol=2e-4,
-                                   err_msg=name)
-        leaves += 1
     # the table; two layers of 4 norms, 4 attention leaves and the MLP's
     # 2; the final norm; the head; the gate's weight and bias
-    assert leaves == 1 + 2 * 10 + 1 + 1 + 2
+    assert fm.assert_leaves_close(got, want) == 1 + 2 * 10 + 1 + 1 + 2
 
 
 def test_a_shared_leafs_gradient_is_the_sum_over_unshared_copies(
@@ -242,7 +193,7 @@ def test_a_shared_leafs_gradient_is_the_sum_over_unshared_copies(
             if f"ut{ut}_{name}" in apart.params:
                 copies[f"ut{ut}_{name}"] = params[name]
     assert jax.tree.structure(copies) == jax.tree.structure(apart.params)
-    with HIGHEST:
+    with fm.highest():
         got = jax.jit(jax.grad(program_loss_of(apart, xs, y)))(copies)
     summed = 0
     for name, leaves in shared.items():
@@ -325,7 +276,7 @@ def test_one_pass_without_output_norms_is_the_llama_block_model(tiny):
     install(normed, lambda name, leaf, value: [(name, value)])
     kw = dict(num_hidden_layers=2, eps=s["rms_norm_eps"], rope_theta=1e6,
               total_ut_steps=1)
-    with HIGHEST:
+    with fm.highest():
         out = jax.jit(lambda w, ids: ref.forward(w, ids, **kw))(
             weights, x0[0])
     np.testing.assert_allclose(np.asarray(normed.predict(x0)),
@@ -605,20 +556,17 @@ def test_a_program_built_otherwise_is_not_correct(cell, tiny, control):
     in every pass, is the shared-gradient test's): fewer passes, no
     output norms, no norm between passes, uniform exit weights; the
     reference as the cell states it."""
-    family, config, traffic = cell
-    _, _, stated, _, xs, y, weights, _ = tiny
-    s = family.sizes(config, traffic, dict(TINY, **control))
-    ff = family.build(config, s, 1, 11)
-    family.install_weights(ff, weights)
-    system, _ = hs.system_side(ff, xs, y, s["batch"])
+    ff, s = fm.control_model(tiny, dict(TINY, **control))
     if not _STATED:     # the reference as the cell states it: one a module
-        _STATED.append(hs.reference_side(family, weights, stated, traffic,
-                                         config, xs, y, s["batch"], steps=1))
+        _STATED.append(fm.reference_predictions(tiny))
+    if "program_exit_weights" not in control:
+        # judged by its output: no step taken
+        nrmse = hs.prediction_errors(fm.predictions(ff, tiny),
+                                     _STATED[0]["preds"], False)["nrmse"]
+        assert nrmse > tiny.family.TOLERANCES["pred_nrmse"], nrmse
+        return
+    system, _ = hs.system_side(ff, tiny.xs, tiny.y, s["batch"])
     rows = {r["name"]: r for r in hs.compare(system, _STATED[0],
-                                             family.TOLERANCES)}
-    failed = {n for n, r in rows.items() if not r["ok"]}
-    if "program_exit_weights" in control:
-        # the output is the stated model's; the loss is not
-        assert failed == {"loss0_rel"}, rows
-    else:
-        assert "pred_nrmse" in failed, rows
+                                             tiny.family.TOLERANCES)}
+    # the output is the stated model's; the loss is not
+    assert {n for n, r in rows.items() if not r["ok"]} == {"loss0_rel"}, rows

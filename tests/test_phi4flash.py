@@ -20,30 +20,24 @@ bfloat16 (7e-3 a rounding) miss the first by ten times and more
 
 import dataclasses
 import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import family_model as fm
+from benchmarks import harness as hs
+from benchmarks.references import phi4flash as ref
+from family_model import OpContext, as_arrays
+from flexflow_tpu import FFConfig
+from flexflow_tpu.models import DecoderConfig, create_decoder
+from flexflow_tpu.models.decoder import sambay_pattern
+from flexflow_tpu.ops import pallas_kernels as pk
+from flexflow_tpu.ops import ssm
+from flexflow_tpu.ops.base import exported_reads
+from one_program import output_and_gradients
 
-from benchmarks import harness as hs  # noqa: E402
-from benchmarks import manifest as mf  # noqa: E402
-from benchmarks.references import phi4flash as ref  # noqa: E402
-from flexflow_tpu import AdamOptimizer, FFConfig, LossType  # noqa: E402
-from flexflow_tpu.models import DecoderConfig, create_decoder  # noqa: E402
-from flexflow_tpu.models.decoder import sambay_pattern  # noqa: E402
-from flexflow_tpu.ops import pallas_kernels as pk  # noqa: E402
-from flexflow_tpu.ops import ssm  # noqa: E402
-from flexflow_tpu.ops.base import OpContext, exported_reads  # noqa: E402
-from one_program import output_and_gradients  # noqa: E402
-
-HIGHEST = jax.default_matmul_precision("highest")
 CELL = "phi4_mini_flash.s8192_b1.1chip"
 # every width small, the structure whole
 WIDTHS = dict(vocab_size=64, hidden_size=32, num_attention_heads=8,
@@ -65,27 +59,22 @@ SIZES = {"whole": WHOLE, "stage": STAGE, "deep": DEEP}
 
 @pytest.fixture(scope="module")
 def cell():
-    manifest = mf.load_manifest()
-    _, config, traffic = mf.find_cell(manifest, CELL)
-    return hs.load_by_path("families", config["family"]), config, traffic
+    # the cell's own rate (1e-7: the three steps barely move the leaves)
+    return fm.load_cell(CELL, adam=None)
 
 
 @pytest.fixture(scope="module")
-def built(cell):
-    """name -> (s, xs, y, weights, ff), each built once."""
-    family, config, traffic = cell
-    cache = {}
+def tinies(cell):
+    """name -> the `fm.Tiny` of `SIZES[name]`, each built once."""
+    return fm.built_by_name(cell, SIZES)
 
+
+@pytest.fixture(scope="module")
+def built(tinies):
+    """name -> (s, xs, y, weights, ff) of `tinies(name)`."""
     def get(name):
-        if name not in cache:
-            s = family.sizes(config, traffic, SIZES[name])
-            xs, y = family.make_data(s, 11)
-            weights = jax.device_get(family.make_weights(s, 11))
-            ff = family.build(config, s, 1, 11)
-            family.install_weights(ff, weights)
-            cache[name] = (s, xs, y, weights, ff)
-        return cache[name]
-
+        tiny = tinies(name)
+        return tiny.s, tiny.xs, tiny.y, tiny.weights, tiny.ff
     return get
 
 
@@ -119,18 +108,6 @@ def program_loss_of(ff, xs, y, gated=()):
         return ex._loss_value(values[ex.final_ref], labels)
 
     return loss
-
-
-def reference_loss_of(family, s):
-    def loss(w, ids, labels):
-        out = ref.forward(w, ids, **family.reference_kw(s))
-        return jnp.sum(ref.sample_losses(out, labels)) / labels.size
-    return loss
-
-
-def as_arrays(weights):
-    return {k: {p: jnp.asarray(v) for p, v in leaves.items()}
-            for k, leaves in weights.items()}
 
 
 def assert_leaves_close(got, want, atol=2e-4):
@@ -294,21 +271,19 @@ def test_model_against_the_reference_output_and_three_losses(
 
 
 @pytest.fixture(scope="module")
-def gradients(built, cell):
+def gradients(tinies):
     """name -> (the weights as arrays, the program's gradient of its
     loss, the reference's of its own) on the whole epoch's batch."""
-    family, _, _ = cell
     cache = {}
 
     def get(name):
         if name not in cache:
-            s, xs, y, weights, ff = built(name)
-            params = as_arrays(weights)
-            with HIGHEST:
-                got = jax.jit(jax.grad(program_loss_of(ff, xs, y)))(params)
-                want = jax.jit(jax.grad(reference_loss_of(family, s)))(
-                    params, jnp.asarray(xs[0]), jnp.asarray(y))
-            cache[name] = (params, got, want)
+            tiny = tinies(name)
+            params = as_arrays(tiny.weights)
+            with fm.highest():
+                got = jax.jit(jax.grad(program_loss_of(
+                    tiny.ff, tiny.xs, tiny.y)))(params)
+            cache[name] = (params, got, fm.reference_gradient(tiny))
         return cache[name]
 
     return get
@@ -326,7 +301,7 @@ def test_every_gradient_leaf_matches_the_reference(name, gradients):
         "stage": 3 + 4 * 6 + 9 + 13 + 2 + 9}[name]
 
 
-def test_a_producers_gradient_is_the_sum_over_its_readers(built, cell):
+def test_a_producers_gradient_is_the_sum_over_its_readers(built, tinies):
     """Depth 12: layer 6's scan output is read by the units of layers 8
     and 10, layer 7's keys and values by the cross layers 9 and 11. With
     the cotangent of an exported tensor let through ONE reader at a time
@@ -334,8 +309,7 @@ def test_a_producers_gradient_is_the_sum_over_its_readers(built, cell):
     the producers' leaves receive through each reader, beside what they
     receive with none, adds up to their gradient, which is the
     reference's; no part alone is."""
-    family, _, _ = cell
-    s, xs, y, weights, ff = built("deep")
+    _, xs, y, weights, ff = built("deep")
     params = as_arrays(weights)
     gauges = ff.executor.traced_gauges()
     assert gauges["executor.shared_tensors"] == 3
@@ -343,14 +317,13 @@ def test_a_producers_gradient_is_the_sum_over_its_readers(built, cell):
     readers = {"b6_mixer": ["b8_memory_gated", "b10_memory_gated"],
                "b7_attn": ["b9_attn", "b11_attn"]}
     names = tuple(n for group in readers.values() for n in group)
-    with HIGHEST:
+    with fm.highest():
         grad = jax.jit(jax.grad(program_loss_of(ff, xs, y, names)))
         whole = grad(params, jnp.ones(4))
         none = grad(params, jnp.zeros(4))
         parts = {name: grad(params, jnp.zeros(4).at[k].set(1.0))
                  for k, name in enumerate(names)}
-        want = jax.jit(jax.grad(reference_loss_of(family, s)))(
-            params, jnp.asarray(xs[0]), jnp.asarray(y))
+    want = fm.reference_gradient(tinies("deep"))
     for producer, leaves in (
             ("b6_mixer", ("a_log", "w_x", "w_dt", "dt_bias", "conv_w",
                           "w_in")),
@@ -453,7 +426,7 @@ def test_the_scan_kernel_matches_the_stepwise_form(batch, seq, channels,
     d = f32(channels)
     weight = f32(batch, seq, channels)
     args = (x, dt, bm, cm, a, d)
-    with HIGHEST:
+    with fm.highest():
         (got, want), grads = zip(*(
             output_and_gradients(f, weight, *args)
             for f in (pk.selective_scan, ssm.selective_scan_stepwise)))
@@ -501,7 +474,7 @@ def test_the_mixer_op_runs_the_kernel_where_pallas_is_on(monkeypatch):
         def loss(p, h):
             outs = op.forward(p, [h], ctx)
             return sum(jnp.sum(o * o) for o in outs), outs
-        with HIGHEST:
+        with fm.highest():
             (_, outs), grads = jax.jit(jax.value_and_grad(
                 loss, has_aux=True))(params, h)
         return outs, grads
@@ -519,7 +492,7 @@ def test_the_mixer_op_runs_the_kernel_where_pallas_is_on(monkeypatch):
     out, memory = off[0]
     assert out.shape == (2, 70, 16) and memory.shape == (2, 70, 32)
     # the reference's mixer gives the same pair
-    with HIGHEST:
+    with fm.highest():
         y, want = jax.jit(lambda h, p: ref.mamba(h, p, "f32"))(h, params)
     np.testing.assert_allclose(memory, y, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
@@ -558,7 +531,7 @@ def test_differential_attention_takes_the_flash_route(monkeypatch):
         inputs = [h] + (list(given) if name == "cross" else [h, h])
 
         def run():
-            with HIGHEST:   # a new function a call: the mode in force
+            with fm.highest():   # a new function a call: the mode in force
                 return jax.jit(lambda p, xs: op.forward(p, xs, ctx))(
                     params, inputs)
 
@@ -577,7 +550,7 @@ def test_differential_attention_takes_the_flash_route(monkeypatch):
             np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
         # the reference: heads first, the maps apart, blocks of queries
         p = {k_: np.asarray(v_) for k_, v_ in params.items()}
-        with HIGHEST:
+        with fm.highest():
             kv = (tuple(jnp.moveaxis(t.reshape(1, 256, 2, 64), 2, 1)
                         for t in given) if name == "cross"
                   else ref.projected_kv(h, p, "f32"))
@@ -659,7 +632,7 @@ def test_a_rematted_reader_does_not_recompute_its_producer(built):
                "b3_attn"}
     assert scans(readers) == plain
     assert scans({"b0_mixer"}) > plain
-    with HIGHEST:
+    with fm.highest():
         want = jax.jit(jax.grad(program_loss_of(ff, xs, y)))(params)
         kept, ex.remat_ops = ex.remat_ops, readers - {"b3_attn"}
         try:
@@ -786,7 +759,7 @@ def test_a_program_built_otherwise_is_not_correct(cell, built, control):
     x0 = xs[0][:s["batch"]]
     got = np.asarray(ff.predict([x0]), np.float32)
     if not _STATED:     # the reference as the cell states it: one a module
-        with HIGHEST:
+        with fm.highest():
             _STATED.append(np.asarray(jax.jit(lambda w, x: ref.forward(
                 w, x, **family.reference_kw(stated)))(as_arrays(weights),
                                                       jnp.asarray(x0))))

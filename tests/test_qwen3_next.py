@@ -12,26 +12,17 @@ refusals. The rule's forms alone (chunked against stepwise, the
 triangular inverse) and the kernels are in
 tests/test_delta_rule_kernels.py."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import family_model as fm
+from benchmarks import harness as hs
+from benchmarks.references import qwen3_next as ref
+from family_model import make_op, run_op
+from flexflow_tpu.ffconst import OperatorType
 
-from benchmarks import harness as hs  # noqa: E402
-from benchmarks import manifest as mf  # noqa: E402
-from benchmarks.references import qwen3_next as ref  # noqa: E402
-from flexflow_tpu.ffconst import OperatorType  # noqa: E402
-from flexflow_tpu.layer import Layer  # noqa: E402
-from flexflow_tpu.ops.base import OpContext, OpRegistry  # noqa: E402
-
-HIGHEST = jax.default_matmul_precision("highest")
 CELL = "qwen3_next_80b_a3b.s16384_b1.1chip"
 # every width small, both kinds of layer (a period of two: a delta layer,
 # then the attention layer); 2 key and 4 value heads of 8, chunks of 8;
@@ -47,19 +38,6 @@ TINY = dict(num_hidden_layers=2, full_attention_interval=2, vocab_size=64,
             shared_expert_intermediate_size=24, slot_slack=3.0,
             initializer_range=0.2, qk_norm_scale=4.0, seq=28, batch=2,
             steps_per_epoch=1)
-
-
-def make_op(kind, props, shapes):
-    layer = Layer(kind, "op", [])
-    layer.properties.update(props)
-    return OpRegistry.create(layer, shapes)
-
-
-def run_op(op, params, inputs):
-    ctx = OpContext(training=False, compute_dtype=jnp.float32)
-    with HIGHEST:
-        return np.asarray(jax.jit(lambda p, x: op.forward(p, x, ctx)[0])(
-            params, inputs))
 
 
 # ---------------------------------------------------------------------------
@@ -80,7 +58,7 @@ def test_delta_mixer_matches_the_references_mixer():
         "w_out": (32, 32)}
     assert op.params_elems() == sum(v.size for v in p.values())
     got = run_op(op, p, [x])
-    with HIGHEST:
+    with fm.highest():
         want = jax.jit(lambda x, p: ref.delta_mixer(
             x, p, key_heads=2, eps=1e-6, operand="f32"))(x, p)
         no_decay = jax.jit(lambda x, p: ref.delta_mixer(
@@ -116,7 +94,7 @@ def test_gate_a_lane_and_zero_centred_head_norms_match_the_reference():
     assert op.params_elems() == sum(v.size for v in p.values())
     got = run_op(op, p, [x, x, x])
     kw = dict(theta=1e7, rotary_dim=4, eps=1e-6, operand="f32")
-    with HIGHEST:
+    with fm.highest():
         want = jax.jit(lambda x, p: ref.attention(x, p, **kw))(x, p)
         bare = jax.jit(lambda x, p: ref.attention(x, p, gate=False, **kw))(
             x, p)
@@ -126,7 +104,7 @@ def test_gate_a_lane_and_zero_centred_head_norms_match_the_reference():
         make_op(OperatorType.MULTIHEAD_ATTENTION,
                 dict(ATTENTION, gate=True), [x.shape] * 3)
     with pytest.raises(NotImplementedError, match="gate a lane"):
-        op.decode_forward(p, [x, x, x], OpContext(), None, None, 0)
+        op.decode_forward(p, [x, x, x], fm.OpContext(), None, None, 0)
 
 
 def test_zero_centred_norm_op():
@@ -147,18 +125,7 @@ def test_zero_centred_norm_op():
 
 @pytest.fixture(scope="module")
 def tiny():
-    manifest = mf.load_manifest(ROOT)
-    _, config, traffic = mf.find_cell(manifest, CELL, ROOT)
-    family = hs.load_by_path("families", config["family"], ROOT)
-    s = family.sizes(config, traffic, TINY)
-    # a rate at which two Adam steps move the loss
-    config = dict(config, adam=dict(config["adam"], alpha=1e-3,
-                                    state_dtype="float32"))
-    xs, y = family.make_data(s, 11)
-    weights = jax.device_get(family.make_weights(s, 11))
-    ff = family.build(config, s, 1, 11)
-    family.install_weights(ff, weights)
-    return family, config, s, traffic, xs, y, weights, ff
+    return fm.build_tiny(CELL, TINY)
 
 
 def test_create_decoder_builds_the_cut_from_the_public_keys(tiny):
@@ -198,9 +165,7 @@ def test_create_decoder_builds_the_cut_from_the_public_keys(tiny):
 
 def test_the_published_count_of_parameters():
     """424,340,544 at the cell's sizes, by the issue's table."""
-    manifest = mf.load_manifest(ROOT)
-    _, config, traffic = mf.find_cell(manifest, CELL, ROOT)
-    family = hs.load_by_path("families", config["family"], ROOT)
+    family, config, traffic = fm.load_cell(CELL)
     s = family.sizes(config, traffic)
     shapes = family.weight_shapes(s)
 
@@ -253,50 +218,12 @@ def test_the_heads_a_step_is_the_largest_ops_not_the_sum(tiny, monkeypatch):
     assert gauges["executor.delta_rule_heads_a_step"] == 2
 
 
-@pytest.fixture(scope="module")
-def gradients(tiny):
-    """(the weights as arrays, the program's gradient of its loss, the
-    reference's of its own) on the whole epoch's batch."""
-    family, _, s, _, xs, y, weights, ff = tiny
-    ex = ff.executor
-    inputs = ff._stage_inputs([xs[0]])
-    labels = ff._shard_batch(y)
-
-    def program_loss(p):
-        ctx = OpContext(training=True, rng=jax.random.PRNGKey(0),
-                        compute_dtype=ex.compute_dtype, mesh=ex.mesh)
-        values, _, _ = ex.run_graph(p, {}, inputs, ctx)
-        return ex._loss_value(values[ex.final_ref], labels)
-
-    def reference_loss(w, ids, labels):
-        logits = ref.forward(w, ids, **family.reference_kw(s))
-        return jnp.sum(ref.sample_losses(logits, labels)) / labels.size
-
-    params = {k: {p: jnp.asarray(v) for p, v in leaves.items()}
-              for k, leaves in weights.items()}
-    with HIGHEST:
-        got = jax.jit(jax.grad(program_loss))(params)
-        want = jax.jit(jax.grad(reference_loss))(
-            params, jnp.asarray(xs[0]), jnp.asarray(y))
-    return params, got, want
-
-
-def test_every_gradient_leaf_matches_the_reference(gradients):
-    _, got, want = gradients
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    leaves = 0
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
-                            jax.tree.leaves(want)):
-        name = jax.tree_util.keystr(path)
-        scale = float(jnp.max(jnp.abs(w)))
-        assert scale > 0, name
-        np.testing.assert_allclose(np.asarray(g) / scale,
-                                   np.asarray(w) / scale, atol=3e-4,
-                                   err_msg=name)
-        leaves += 1
+def test_every_gradient_leaf_matches_the_reference(tiny):
+    _, got, want = fm.gradients_of(tiny)
     # the table and the head; the delta layer's 2 norms + 7 leaves; the
     # attention layer's 2 + 6; 2 expert layers' 8; the final norm
-    assert leaves == 2 + 9 + 8 + 2 * 8 + 1
+    assert fm.assert_leaves_close(got, want, atol=3e-4) == (
+        2 + 9 + 8 + 2 * 8 + 1)
 
 
 def test_checkpoint_round_trip(tiny, tmp_path):
@@ -333,38 +260,27 @@ def test_a_control_of_either_kind_is_told(tiny, control):
     the logits' spread; every control reads fifty times that or more
     (the cell's own limit is for bfloat16 on the chip, where
     `scripts/program_controls.py` runs these and the other two)."""
-    family, config, stated, traffic, xs, y, weights, ff = tiny
-    s = family.sizes(config, traffic, dict(TINY, **control))
     if not _CONTROLS:
-        family.install_weights(ff, weights)     # the steps above moved them
-        _CONTROLS.update(
-            got=np.asarray(ff.predict([xs[0][:s["batch"]]])).astype(
-                np.float32),
-            want=hs.reference_side(family, weights, stated, traffic, config,
-                                   xs, y, s["batch"], steps=1)["preds"])
+        # the steps above moved them
+        tiny.family.install_weights(tiny.ff, tiny.weights)
+        _CONTROLS.update(got=fm.predictions(tiny.ff, tiny),
+                         want=fm.reference_predictions(tiny)["preds"])
         assert hs.prediction_errors(_CONTROLS["got"], _CONTROLS["want"],
                                     False)["nrmse"] < 1e-4
     got, want = _CONTROLS["got"], _CONTROLS["want"]
+    sizes = dict(TINY, **control)
     if next(iter(control)).startswith("program_"):
-        other = family.build(config, s, 1, 11)
-        family.install_weights(other, weights)
-        got = np.asarray(other.predict([xs[0][:s["batch"]]])).astype(
-            np.float32)
+        got = fm.predictions(fm.control_model(tiny, sizes)[0], tiny)
     else:
-        want = hs.reference_side(family, weights, s, traffic, config, xs, y,
-                                 s["batch"], steps=1)["preds"]
+        want = fm.reference_predictions(tiny, tiny.family.sizes(
+            tiny.config, tiny.traffic, sizes))["preds"]
     nrmse = hs.prediction_errors(got, want, False)["nrmse"]
     assert nrmse > 5e-3, nrmse
 
 
 def test_the_step_names_the_new_scopes(tiny):
-    _, _, s, _, xs, y, _, ff = tiny
     from flexflow_tpu.obs import step_scopes
-    step = ff.executor.make_train_step()
-    text = step.lower(ff.params, ff.opt_state, ff.state,
-                      ff._stage_inputs([xs[0][:s["batch"]]]),
-                      ff._shard_batch(y[:s["batch"]]),
-                      jax.random.PRNGKey(0)).compile().as_text()
+    text = fm.compiled_step_text(tiny)
     for scope in ("jvp(jit(delta_mixer))/jit(delta_rule)",
                   "transpose(jvp(jit(delta_mixer)))",
                   "jit(attention_full))/jit(attention_gate)",
@@ -404,40 +320,32 @@ def test_thirty_two_style_shares_add_up_to_the_uncut_layer():
     ref_kw = dict(eps=1e-6, layer_types=("linear_attention",),
                   linear_num_key_heads=2, num_experts_per_tok=3,
                   norm_topk_prob=True, expert_offset=0)
-    with HIGHEST:
-        want = np.asarray(ref.layer(x, w, 0, ref_kw, "f32"))
-        h = ref.rms_norm(x, w["b0_norm"]["scale"], 1e-6)
+    with fm.highest():
+        want, h = jax.jit(lambda x, w: (
+            ref.layer(x, w, 0, ref_kw, "f32"),
+            ref.rms_norm(x, w["b0_norm"]["scale"], 1e-6)))(x, w)
     mixed = np.asarray(x) + run_op(delta, w["b0_delta"], [h])
-    with HIGHEST:
-        g = ref.rms_norm(jnp.asarray(mixed), w["b0_post_norm"]["scale"],
-                         1e-6)
+    with fm.highest():
+        g = jax.jit(ref.rms_norm, static_argnums=2)(
+            mixed, w["b0_post_norm"]["scale"], 1e-6)
     p = w["b0_mixer"]
     routed_only = dict(kw, shared_width=0, shared_gate=False)
     shared_leaves = ("ws_gate", "ws_up", "ws_down", "w_shared_gate")
-    total = mixed
-    for chip in range(4):
-        held = slice(2 * chip, 2 * chip + 2)
-        op = make_op(OperatorType.MOE_LAYER,
-                     dict(routed_only, experts_held=2, expert_offset=2 * chip),
-                     [x.shape])
-        share = {n: (v[held] if n in ("w_gate", "w_up", "w_down") else v)
-                 for n, v in p.items() if n not in shared_leaves}
-        total = total + run_op(op, share, [g])
+    routed_leaves = {n: v for n, v in p.items() if n not in shared_leaves}
+    parts = fm.expert_shares(routed_only, routed_leaves, [g], 2, 4)
     # the shared expert with its gate, once: a chip's layer less its
     # routed part
-    chip0 = dict(p, **{n: p[n][:2] for n in ("w_gate", "w_up", "w_down")})
+    chip0 = dict(p, **{n: p[n][:2] for n in fm.EXPERT_LEAVES})
     with_shared = run_op(make_op(OperatorType.MOE_LAYER, dict(
         kw, experts_held=2, expert_offset=0), [x.shape]), chip0, [g])
-    routed = run_op(make_op(OperatorType.MOE_LAYER, dict(
-        routed_only, experts_held=2, expert_offset=0), [x.shape]),
-        {n: v for n, v in chip0.items() if n not in shared_leaves}, [g])
-    total = total + (with_shared - routed)
+    total = mixed + sum(parts) + (with_shared - parts[0])
     np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
     # and a chip's layer is the reference's own share
-    with HIGHEST:
+    with fm.highest():
         np.testing.assert_allclose(
-            with_shared, ref.experts(g, chip0, k=3, norm_topk=True, offset=0,
-                                     operand="f32"), rtol=2e-4, atol=2e-5)
+            with_shared, jax.jit(lambda g, p: ref.experts(
+                g, p, k=3, norm_topk=True, offset=0, operand="f32"))(
+                    g, chip0), rtol=2e-4, atol=2e-5)
 
 
 def test_search_prices_the_new_op_and_refuses_its_remat_twin(tiny):

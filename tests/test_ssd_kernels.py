@@ -9,6 +9,7 @@ head whose decay is near 0 and one near 1 across the whole sample; batch
 2 (the state starts from zero at each sample); the rule that says which
 shapes the kernels take, and the mixer op under both routes."""
 
+import functools
 import os
 import sys
 
@@ -36,6 +37,7 @@ def interpret(monkeypatch):
     monkeypatch.setenv("FLEXFLOW_TPU_PALLAS", "interpret")
 
 
+@functools.partial(jax.jit, static_argnums=tuple(range(8)))
 def scan_inputs(batch, length, heads, p, groups, n=128, dtype=jnp.float32,
                 seed=0):
     """(xbc, dt, a, d) as the mixer hands them to its scan, and a weight
