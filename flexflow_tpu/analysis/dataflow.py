@@ -328,6 +328,13 @@ def required_input_specs(node, getspec, getparam) -> List[Any]:
             r[0] = out0[0]
         return [tuple(r)]
 
+    if t in (OperatorType.HC_PRE, OperatorType.HC_POST):
+        # the stream, the branch's output and the maps, [B, S, lanes]
+        # each: the batch follows the output; the products contract over
+        # all n*C lanes and the kernels take whole rows
+        return [tuple([out0[0] if s[0] == out_shape[0] else None]
+                      + [None] * (len(s) - 1)) for s in in_shapes]
+
     if t == OperatorType.BATCHMATMUL:
         reqs = []
         for s in in_shapes:

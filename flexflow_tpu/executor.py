@@ -43,7 +43,8 @@ COUNT_SUFFIX = "#n"
 SIDE_CHANNELS = ("_aux_loss", "_counters", "_new_state", "_new_states")
 # `Op.traced_gauges` keys that say a size, not a count: the model's is the
 # largest of its ops', where every other key is added up
-GAUGES_OF_THE_LARGEST_OP = frozenset({"executor.delta_rule_heads_a_step"})
+GAUGES_OF_THE_LARGEST_OP = frozenset({"executor.delta_rule_heads_a_step",
+                                      "hc/streams", "hc/sinkhorn_iters"})
 # the op kinds of which a decoder layer holds one
 SEQUENCE_MIXERS = (OperatorType.MULTIHEAD_ATTENTION, OperatorType.SSM_MIXER,
                    OperatorType.SHORT_CONV, OperatorType.MAMBA_MIXER,
@@ -127,6 +128,10 @@ class GraphExecutor:
         # of a looped model's exit distribution; `exit_uniform` puts
         # 1 / T in the distribution's place (a control)
         self.loss_parts = None
+        # names of the op counters of kind "max", noted while the forward
+        # is traced: `FFModel.fit` carries their largest over an epoch's
+        # steps where it adds every other counter up
+        self.max_counters = set()
         self.exit_entropy_beta = 0.0
         self.exit_uniform = False
         # mixed-precision master-weight regime (bf16 compute): forward and
@@ -520,9 +525,10 @@ class GraphExecutor:
         """Evaluate ops in topo order; returns (values, new_state, aux_losses).
 
         ``counters`` (a dict, filled in place) collects what ops count
-        during forward (``op._counters``: name -> ("sum" | "mean", value),
-        e.g. the expert layers' routing counts): sums add up over ops,
-        means keep a count beside them under ``name + COUNT_SUFFIX``; an
+        during forward (``op._counters``: name -> ("sum" | "mean" | "max",
+        value), e.g. the expert layers' routing counts): sums add up over
+        ops, means keep a count beside them under ``name + COUNT_SUFFIX``,
+        of a "max" the largest stays (``self.max_counters``); an
         INTEGER sum stays a vector of the ops' counts, exact, for the
         host to add. The train step returns them with the metrics.
 
@@ -742,6 +748,13 @@ class GraphExecutor:
                             counters[cname] = jnp.concatenate(
                                 [counters.get(cname, jnp.zeros(0, v.dtype)),
                                  jnp.reshape(v, 1)])
+                            continue
+                        if kind == "max":
+                            # the largest over the ops of a step, and
+                            # (`FFModel.fit`) over the steps of an epoch
+                            counters[cname] = jnp.maximum(
+                                counters.get(cname, -jnp.inf), v)
+                            self.max_counters.add(cname)
                             continue
                         counters[cname] = counters.get(cname, 0.0) + v
                         if kind == "mean":

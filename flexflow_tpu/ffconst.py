@@ -191,6 +191,12 @@ class OperatorType(enum.Enum):
     MAMBA_MIXER = enum.auto()
     # appended (PR 58): the gated delta-rule mixer (ops/delta_rule.py)
     DELTA_MIXER = enum.auto()
+    # appended (PR 64): the two halves of a hyper-connection around a
+    # sublayer (ops/hyper_connection.py): the read of the branch's input
+    # out of the residual streams with the three mixing maps, and the
+    # write of the branch's output back into them
+    HC_PRE = enum.auto()
+    HC_POST = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

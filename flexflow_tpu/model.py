@@ -487,6 +487,28 @@ class FFModel:
             **({} if output_gate else {"output_gate": False})), name)
         return self._finish(layer)
 
+    def hc_pre(self, stream: Tensor, streams: int,
+               sinkhorn_iters: int = 20, eps: float = 1e-6,
+               clamp=(-30.0, 30.0), name: Optional[str] = None):
+        """The read half of a hyper-connection (ops/hyper_connection.py)
+        over a residual stream [B, S, n*C] of ``streams`` = n copies:
+        returns (h [B, S, C], the branch's input; maps [B, S, 128], the
+        three mixing maps a position; the stream, handed through), for
+        ``hc_post`` to read the last two."""
+        layer = self._add_layer(OperatorType.HC_PRE, [stream], dict(
+            streams=streams, sinkhorn_iters=sinkhorn_iters, eps=eps,
+            clamp_min=clamp[0], clamp_max=clamp[1]), name)
+        return self._finish(layer)
+
+    def hc_post(self, stream: Tensor, output: Tensor, maps: Tensor,
+                streams: int, name: Optional[str] = None) -> Tensor:
+        """The write half: the new stream X'[i] = sum_j H_res[i, j] X[j]
+        + H_post[i] y from ``hc_pre``'s stream and maps and the branch's
+        output y [B, S, C]."""
+        layer = self._add_layer(OperatorType.HC_POST, [stream, output, maps],
+                                dict(streams=streams), name)
+        return self._finish(layer)
+
     def moe_layer(self, input: Tensor, n_experts: int, k: int,
                   hidden_size: int, shared_width: int = 0,
                   experts_held: int = 0, expert_offset: int = 0,
@@ -1598,9 +1620,10 @@ class FFModel:
                             and jnp.issubdtype(v.dtype, jnp.integer)]}
                         if counts:
                             step_counts.append(counts)
-                        mtotals = (mvals if mtotals is None
-                                   else jax.tree.map(jnp.add, mtotals,
-                                                     mvals))
+                        largest = self.executor.max_counters
+                        mtotals = (mvals if mtotals is None else {
+                            k: (jnp.maximum if k in largest else jnp.add)(
+                                mtotals[k], v) for k, v in mvals.items()})
                     if tracer.fence:
                         with tracer.phase("device_wait"):
                             jax.block_until_ready(loss)

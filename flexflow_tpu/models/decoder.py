@@ -56,7 +56,9 @@ family.
         score-correction bias, the chosen renormalised and scaled
         (``routed_scaling_factor``), experts down(act(gate(x)) * up(x))
         of ``moe_intermediate_size``, and a shared expert of the same
-        gated form, ``n_shared_experts * moe_intermediate_size`` wide
+        gated form, ``n_shared_experts * moe_intermediate_size`` wide.
+        ``rope_scaling`` (DeepSeek-V2's YaRN keys) scales the rotated
+        lanes' frequencies and the softmax scale (ops/attention.py)
 
     F   gated full attention, then a feed-forward, each behind its own
         norm and residual (PR 41). Attention: causal GQA with layer i's
@@ -154,6 +156,21 @@ feed-forward are the short form. The three attention properties are the
 op's (``FFModel.multihead_attention(..., gate, partial_rotary_factor,
 rope_scaling)``), off by default.
 
+``hc_mult`` n > 0 (PR 64) replaces the residual path of every block
+that is built on `_attention_ffn_block` (`A X F S C R U`; any other
+letter is refused) by manifold-constrained hyper-connections
+(ops/hyper_connection.py): the embedding's output is laid n times side
+by side, X_0 = [e; e; ...] [B, S, n*E]; each sublayer reads its branch's
+input h = sum_i H_pre[i] X[i] through ``<prefix>_hc_attn`` /
+``<prefix>_hc_ffn`` (`FFModel.hc_pre`; the norm, the mixer or the
+feed-forward are what they were, on h) and writes X'[i] = sum_j
+H_res[i, j] X[j] + H_post[i] y through ``<prefix>_res1`` / ``_res2``
+(`FFModel.hc_post`, where the plain path has its `add`); the n streams
+are summed ahead of the final norm (``hc_merge``). ``hc_sinkhorn_iters``,
+``hc_eps``, ``mhc_h_res_clamp_min`` / ``_max`` are the public config's
+keys. With 0 (the default) every
+block adds its branch to ONE stream, node for node as before.
+
 ``num_nextn_predict_layers`` 1 adds the multi-token-prediction module: a
 second branch off the last block's output x_L (before the final norm)
 that reads the NEXT token's embedding, u_i = [rms_norm(e(t_{i+1})) ;
@@ -167,7 +184,9 @@ with ``WEIGHTED_SPARSE_CATEGORICAL_CROSSENTROPY`` on labels [B, 2S, 2]
 half scaled by the weight between the two losses); ``ff.loss_parts``
 then names the halves, and an epoch's ``ff.op_counters`` hold
 ``loss/main_nll`` and ``loss/mtp_nll``, the sums of the two unweighted
-cross-entropies over their targets.
+cross-entropies over their targets. Under ``hc_mult`` the module reads
+the SUMMED x_L, lays its u n times as streams of its own and sums them
+ahead of its final norm.
 
 ``total_ut_steps`` T > 1 applies the whole stack T times with ONE set
 of leaves (a looped model): pass 1 builds the layers under the trace
@@ -267,7 +286,16 @@ class DecoderConfig:
     qk_rope_head_dim: int = 8
     v_head_dim: int = 16
     rope_whole_head: bool = False
+    rope_scaling: Optional[dict] = None     # YaRN on the rotated lanes
     n_shared_experts: int = 1               # `X`
+    # hyper-connections (module docstring): streams (0: the plain
+    # residual), Sinkhorn steps, eps of the norm and of the steps'
+    # divisions, the clamp ahead of exp
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
     # `F`, `S`: a layer's own query heads, attention kind ("full_attention"
     # / "sliding_attention": the pattern, where given) and feed-forward
     # kind ("dense" / "sparse"), rotary parameters by attention kind, the
@@ -449,26 +477,72 @@ def _llama_block(ff, t, i, cfg):
     return ff.add(t, _swiglu_mlp(ff, h, cfg, f"l{i}"), name=f"l{i}_res2")
 
 
+def _residual(ff, cfg):
+    """The residual rule of a block, (read, write): ``read(t, name)``
+    gives (what the branch's norm reads, what ``write`` needs of the
+    stream) and ``write(kept, y, name)`` the stream after the branch's
+    output y. Plain: the stream itself and `add`. Under ``hc_mult`` the
+    two halves of a hyper-connection."""
+    n = cfg.hc_mult
+    if not n:
+        return (lambda t, name: (t, t),
+                lambda kept, y, name: ff.add(kept, y, name=name))
+
+    def read(t, name):
+        h, maps, stream = ff.hc_pre(
+            t, n, sinkhorn_iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+            clamp=(cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max),
+            name=name)
+        return h, (stream, maps)
+
+    def write(kept, y, name):
+        return ff.hc_post(kept[0], y, kept[1], n, name=name)
+
+    return read, write
+
+
+def _as_streams(ff, t, cfg, name):
+    """[B, S, E] laid ``hc_mult`` times side by side (as it is at 0)."""
+    return (ff.concat([t] * cfg.hc_mult, axis=2, name=name)
+            if cfg.hc_mult else t)
+
+
+def _streams_summed(ff, t, cfg, name):
+    """The sum of the ``hc_mult`` streams of [B, S, n*E]."""
+    if cfg.hc_mult < 2:
+        return t
+    parts = ff.split(t, [cfg.hidden_size] * cfg.hc_mult, axis=2, name=name)
+    t = parts[0]
+    for i, part in enumerate(parts[1:], 1):
+        t = ff.add(t, part, name=f"{name}_sum{i}")
+    return t
+
+
 def _attention_ffn_block(ff, t, prefix, cfg, mixer, experts,
                          shared_width, sandwich=False):
     """x' = x + mixer(norm(x)), x'' = x' + f(norm(x')) with f the
     SwiGLU MLP or the sigmoid-scored experts with their gated shared
     expert (none at ``shared_width`` 0): the block of `A` / `X`, of
     `F` / `S` / `C` and of `U`, which differ in ``mixer(h)``; with
-    ``sandwich`` each branch's output is normed too."""
+    ``sandwich`` each branch's output is normed too. Where `x +` stands
+    is the model's residual rule (`_residual`: `add`, or under
+    ``hc_mult`` the two halves of a hyper-connection)."""
     eps, zero = cfg.layer_norm_epsilon, cfg.zero_centered_norms
-    h = ff.rms_norm(t, eps=eps, zero_centered=zero, name=f"{prefix}_norm")
+    read, write = _residual(ff, cfg)
+    h, kept = read(t, f"{prefix}_hc_attn")
+    h = ff.rms_norm(h, eps=eps, zero_centered=zero, name=f"{prefix}_norm")
     a = mixer(h)
     if sandwich:
         a = ff.rms_norm(a, eps=eps, name=f"{prefix}_attn_out_norm")
-    t = ff.add(t, a, name=f"{prefix}_res1")
-    g = ff.rms_norm(t, eps=eps, zero_centered=zero,
+    t = write(kept, a, f"{prefix}_res1")
+    g, kept = read(t, f"{prefix}_hc_ffn")
+    g = ff.rms_norm(g, eps=eps, zero_centered=zero,
                     name=f"{prefix}_post_norm")
     if not experts:
         m = _swiglu_mlp(ff, g, cfg, prefix, one_product=True)
         if sandwich:
             m = ff.rms_norm(m, eps=eps, name=f"{prefix}_mlp_out_norm")
-        return ff.add(t, m, name=f"{prefix}_res2")
+        return write(kept, m, f"{prefix}_res2")
     m = ff.moe_layer(
         g, cfg.n_routed_experts, cfg.num_experts_per_tok,
         cfg.moe_intermediate_size, shared_width=shared_width,
@@ -477,7 +551,7 @@ def _attention_ffn_block(ff, t, prefix, cfg, mixer, experts,
         norm_topk=cfg.norm_topk_prob, slot_slack=cfg.slot_slack,
         scoring=cfg.router_scoring, gated=True, activation=cfg.hidden_act,
         shared_gate=cfg.shared_expert_gate, name=f"{prefix}_mixer")
-    return ff.add(t, m, name=f"{prefix}_res2")
+    return write(kept, m, f"{prefix}_res2")
 
 
 def _latent_block(ff, t, prefix, cfg, experts):
@@ -494,7 +568,8 @@ def _latent_block(ff, t, prefix, cfg, experts):
             kv_lora_rank=cfg.kv_lora_rank,
             qk_rope_head_dim=cfg.qk_rope_head_dim,
             latent_norm_eps=cfg.layer_norm_epsilon,
-            rope_whole_head=cfg.rope_whole_head, name=f"{prefix}_attn")
+            rope_whole_head=cfg.rope_whole_head,
+            rope_scaling=cfg.rope_scaling, name=f"{prefix}_attn")
 
     return _attention_ffn_block(
         ff, t, prefix, cfg, attention, experts,
@@ -677,7 +752,9 @@ def _mtp_module(ff, embedded, x_last, cfg):
                        ff.rms_norm(x_last, eps=eps, name="mtp_hnorm")],
                       axis=2, name="mtp_eh")
         u = ff.dense(u, cfg.hidden_size, use_bias=False, name="mtp_eh_proj")
-        u = _latent_block(ff, u, "mtp", cfg, experts=True)
+        u = _latent_block(ff, _as_streams(ff, u, cfg, "mtp_hc_streams"),
+                          "mtp", cfg, experts=True)
+        u = _streams_summed(ff, u, cfg, "mtp_hc_merge")
         return ff.rms_norm(u, eps=eps, name="mtp_final_ln")
 
 
@@ -784,15 +861,23 @@ def create_decoder(cfg: DecoderConfig, ff_config: FFConfig = None) -> FFModel:
             raise ValueError(f"decoder: layer_types holds {sorted(unknown)} "
                              f"(known: {sorted(LAYER_TYPE_LETTERS)})")
         pattern = "".join(LAYER_TYPE_LETTERS[k] for k in cfg.layer_types)
+    if cfg.hc_mult:
+        others = sorted(set(pattern) - set("AXFSCRU"))
+        if others or cfg.hc_mult < 0:
+            raise ValueError(
+                f"decoder: hc_mult {cfg.hc_mult} with the blocks {others}: "
+                f"hyper-connections stand in the residual path of the "
+                f"blocks `A X F S C R U` (`_attention_ffn_block`)")
+        t = _as_streams(ff, t, cfg, "hc_streams")
     if cfg.total_ut_steps > 1:
         if ("D" in pattern or cfg.num_nextn_predict_layers
-                or cfg.tie_word_embeddings):
+                or cfg.tie_word_embeddings or cfg.hc_mult):
             raise NotImplementedError(
                 "decoder: a looped model (total_ut_steps > 1) takes an "
-                "untied head, no multi-token-prediction module and no "
-                "block-diffusion block")
+                "untied head, no multi-token-prediction module, no "
+                "block-diffusion block and no hyper-connections")
         return _looped(ff, t, pattern, cfg)
-    t = _stack(ff, t, pattern, cfg)
+    t = _streams_summed(ff, _stack(ff, t, pattern, cfg), cfg, "hc_merge")
     if "D" in pattern:
         # the head and the loss read the noised half alone
         half = cfg.seq_length // 2

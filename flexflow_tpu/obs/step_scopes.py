@@ -38,6 +38,9 @@ SCOPE_PARTS = {"optimizer_update": "optimizer_update", "loss": "loss",
                # the gated delta-rule mixer (its chunked rule lies in the
                # nested call `delta_rule` inside it)
                "delta_mixer": "delta_mixer",
+               # the two halves of a hyper-connection around a sublayer
+               # (`hc_read`, `hc_maps`, `hc_write` lie inside it)
+               "hyper_connection": "hyper_connection",
                # a multi-token-prediction module's own ops, whatever their
                # kind: the scope lies around theirs (`FFModel.scope`)
                "mtp": "mtp",

@@ -20,6 +20,7 @@ import flexflow_tpu.ops.moe  # noqa: F401
 import flexflow_tpu.ops.experts  # noqa: F401
 import flexflow_tpu.ops.ssm  # noqa: F401
 import flexflow_tpu.ops.short_conv  # noqa: F401
+import flexflow_tpu.ops.hyper_connection  # noqa: F401
 import flexflow_tpu.ops.delta_rule  # noqa: F401
 import flexflow_tpu.ops.parallel_ops  # noqa: F401
 

@@ -102,24 +102,30 @@ def rotary_embedding(x, *, theta: float = 10000.0, position_offset=0,
             ).astype(x.dtype)
 
 
-def rotary_interleaved(x, *, theta: float, head_dim: int, lanes_of=None):
+def rotary_interleaved(x, *, theta: float, head_dim: int, lanes_of=None,
+                       inv_freq=None, factor: float = 1.0):
     """RoPE over the ADJACENT pairs (2i, 2i+1) of x [B, S, n*D], n heads of
     ``head_dim`` D side by side along the minor axis, positions 0..S-1. A
     lane's partner is its neighbour, fetched by a roll of the minor axis:
     no strided slice, and no head is taken apart. ``lanes_of`` (first,
     total): the D lanes are those from ``first`` of a rotated head
-    ``total`` wide, whose frequencies they take (a control's)."""
+    ``total`` wide, whose frequencies they take (a control's).
+    ``inv_freq`` [D / 2]: a scaled table (`rotary_frequencies`) in place
+    of theta's, and ``factor`` on its cos and sin."""
     seq_axis, d = 1, head_dim
     s, width = x.shape[seq_axis], x.shape[-1]
     first, total = lanes_of or (0, d)
-    inv_freq = 1.0 / (theta ** ((first + jnp.arange(
-        0, d, 2, dtype=jnp.float32)) / total))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** ((first + jnp.arange(
+            0, d, 2, dtype=jnp.float32)) / total))
     pos = jnp.arange(s, dtype=jnp.float32)
     angles = jnp.repeat(pos[:, None] * inv_freq[None, :], 2, axis=-1)  # [S, D]
     angles = jnp.tile(angles, (1, width // d))
     shape = [1] * x.ndim
     shape[seq_axis], shape[-1] = s, width
     cos, sin = jnp.cos(angles).reshape(shape), jnp.sin(angles).reshape(shape)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
     even = jax.lax.broadcasted_iota(jnp.int32, shape, x.ndim - 1) % 2 == 0
     # lane 2i gets -x[2i+1], lane 2i+1 gets x[2i]
@@ -142,7 +148,9 @@ def rotary_frequencies(dim: int, theta: float, scaling=None):
     and cos and sin scaled by ``attention_factor`` (0.1 ln factor + 1
     where the config gives none)."""
     f = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
-    kind = (scaling or {}).get("rope_type", "default")
+    # `type` is the key's older name (DeepSeek-V2's configs and their kin)
+    kind = ((scaling or {}).get("rope_type") or (scaling or {}).get("type")
+            or "default")
     if kind == "default":
         return f, 1.0
     if kind != "yarn":
@@ -165,6 +173,22 @@ def rotary_frequencies(dim: int, theta: float, scaling=None):
     factor_of_attention = scaling.get("attention_factor") or (
         0.1 * math.log(factor) + 1.0)
     return (f / factor) * (1.0 - m) + f * m, float(factor_of_attention)
+
+
+def latent_yarn_factors(scaling):
+    """(factor of cos and sin, factor of the softmax scale) of latent
+    attention under YaRN as DeepSeek-V2 states it: with m(s) = 0.1 s ln
+    factor + 1 (1 where factor <= 1), cos and sin times m(mscale) /
+    m(mscale_all_dim), and the softmax scale times m(mscale_all_dim)^2
+    where ``mscale_all_dim`` is given and not 0."""
+    factor = float(scaling["factor"])
+
+    def m(s):
+        return 0.1 * s * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    all_dim = scaling.get("mscale_all_dim", 0) or 0
+    return (m(scaling.get("mscale", 1)) / m(all_dim),
+            m(all_dim) ** 2 if all_dim else 1.0)
 
 
 def rotary_partial(x, inv_freq, *, rotary_dim: int,
@@ -377,7 +401,12 @@ class MultiHeadAttention(Op):
         [c_kv ; k_r] = x wkv_a;  c_kv = rms_norm(c_kv)
         k_nope, v = c_kv wkv_b_k, c_kv wkv_b_v
         scores = (q_nope k_nope^T + rope(q_rope) rope(k_r)^T) * scale
-    with rotary over the ADJACENT pairs (2i, 2i+1) of the rotated lanes.
+    with rotary over the ADJACENT pairs (2i, 2i+1) of the rotated lanes;
+    under ``rope_scaling`` (PR 64; DeepSeek-V2's keys: ``type`` "yarn",
+    ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    ``beta_slow``, ``mscale``, ``mscale_all_dim``) YaRN's table
+    (`rotary_frequencies`), cos and sin and the softmax scale times
+    `latent_yarn_factors`.
     Leaves: wq_a [E, Rq], q_a_norm [Rq], wq_b_nope [H, Rq, D], wq_b_rope
     [H, Rq, R], wkv_a [E, Rkv + R], kv_a_norm [Rkv], wkv_b_k, wkv_b_v
     [H, Rkv, D], wo [H, D, E]. What differs from the plain op is the
@@ -535,14 +564,17 @@ class MultiHeadAttention(Op):
                         self.window or self.block_diffusion or self.qk_norm
                         or self.use_bias or self.rope_wrap or self.gate
                         or self.lane_gate
-                        or self.rope_scaling
                         or self.rotary_dim != self.head_dim
                         or p.get("seq_parallel")):
                 raise ValueError(
                     f"attention '{layer.name}': latent attention is causal "
                     f"self-attention with as many key/value heads as query "
                     f"heads, no bias, window, mask, head norm, gate, "
-                    f"partial or scaled rotary, or ring")
+                    f"partial rotary, or ring")
+            if self.rope_scaling and self.rope_whole_head:
+                raise ValueError(
+                    f"attention '{layer.name}': rope_whole_head (a "
+                    f"control) takes plain frequencies")
         # separate q/k/v projection biases (torch nn.MultiheadAttention
         # parity — in_proj_bias). Off by default: they cost an extra
         # elementwise pass over q/k/v every step and native models
@@ -1384,15 +1416,26 @@ class MultiHeadAttention(Op):
             return jnp.dot(a.astype(cd), w.astype(cd),
                            preferred_element_type=jnp.float32)
 
+        # YaRN on the rotated lanes (PR 64): the scaled table, cos and
+        # sin times one factor, the softmax scale times another, which
+        # the float32 queries take on ahead of their rounding
+        inv_freq, of_tables, of_scores = None, 1.0, 1.0
+        if self.rope_scaling:
+            inv_freq = rotary_frequencies(r, theta, self.rope_scaling)[0]
+            of_tables, of_scores = latent_yarn_factors(self.rope_scaling)
+
         def rotated(t):     # [B, S, n*R], n heads side by side
             return rotary_interleaved(
                 t, theta=theta, head_dim=r,
                 lanes_of=(self.head_dim, self.head_dim + r)
-                if self.rope_whole_head else None)
+                if self.rope_whole_head else None, inv_freq=inv_freq,
+                factor=of_tables)
 
         c_q = rms_normed(dot(x, params["wq_a"]), params["q_a_norm"], eps)
         q_nope = self._project(c_q, params["wq_b_nope"], None, cd)
         q_rope = rotated(self._project(c_q, params["wq_b_rope"], None, cd))
+        if of_scores != 1.0:
+            q_nope, q_rope = q_nope * of_scores, q_rope * of_scores
         kv = dot(x, params["wkv_a"])
         c_kv = rms_normed(kv[..., :rkv], params["kv_a_norm"], eps)
         k_nope = self._project(c_kv, params["wkv_b_k"], None, cd)

@@ -88,7 +88,9 @@ the gated short convolution's pass between its two products; and
 and state, forward and backward, time walked inside the kernel;
 `delta_rule_fused` (PR 58), the gated delta rule in chunks, and `ssd_scan`
 (PR 62), the Mamba-2 recurrence in chunks: one kernel each way, the state
-in VMEM, the backward the chunk function's `jax.vjp` inside the kernel.
+in VMEM, the backward the chunk function's `jax.vjp` inside the kernel;
+`hc_read_lanes` / `hc_write_lanes` (PR 64), the two passes of a
+hyper-connection over the residual streams, one kernel each way.
 
 CPU fallback: the same kernels run under ``interpret=True`` when
 FLEXFLOW_TPU_PALLAS=interpret (used by the deviceless tests); otherwise
@@ -4782,6 +4784,463 @@ def ssd_scan(xbc, dt, a, d, groups: int, state: int, chunk: int):
     `pallas_mode` and `ssd_shape_legal`; `ops.ssm.ssd_chunked` is the
     same in `jax.numpy`."""
     return _ssd_scan(xbc, dt, a, d, groups, state, chunk,
+                     pallas_mode() == "interpret")
+
+
+# ---------------------------------------------------------------------------
+# The two passes of a hyper-connection around a sublayer (PR 64): the
+# residual stream is n copies of the hidden width side by side along the
+# lanes, x [T, n*C], and a sublayer reads its branch's input out of them
+# and writes the branch's output back through three maps a position
+# (`ops/hyper_connection.py` has the mathematics and the same passes in
+# `jax.numpy`). Both are bound by bytes: 24 multiply-adds a lane of C
+# against a stream that is read and written whole. `hc_read`: ONE pass
+# over a block of rows gives the sum of squares, the n (n + 2) products
+# with phi (one MXU pass: phi's float32 columns come as three bfloat16
+# terms in three groups of 32 lanes, so the product is float32-exact for
+# an x stored in bfloat16), the read map sigmoid(a r z + b) and
+# h = sum_i H_pre[i] x_i; the RMS division is applied to the products,
+# not to the stream. `hc_write`: x'_i = sum_j H_res[i, j] x_j + H_post[i] y
+# in one pass, the maps a [rows, 128] float32 block whose columns are
+# broadcast along the lanes. Each has ONE backward kernel; the read's
+# takes the cotangent of the stream that the write's backward made
+# (`hc_read_lanes` hands x through as an output, so that the two uses of
+# one stream meet inside this kernel and not in a pass of XLA's), the
+# branch's, and the products' (from the maps' `jax.vjp` outside).
+
+HC_ROWS = 128         # positions a grid step
+HC_GROUP = 32         # lanes a term of phi's three takes in the operand
+MAX_HC_LANES = 32768  # n * C: a block holds its rows of every stream
+_HC_COMPILER_PARAMS = dict(dimension_semantics=("parallel",),
+                           vmem_limit_bytes=100 << 20)
+
+
+def hc_shape_legal(rows: int, streams: int, width: int) -> bool:
+    """What `hc_read_lanes` / `hc_write_lanes` take: whole blocks of
+    rows, streams of whole 128-lane columns, and maps that with their
+    witness fit a group of 32 lanes (n <= 4)."""
+    return (rows > 0 and rows % HC_ROWS == 0 and width % LANES == 0
+            and 1 <= streams and streams * (streams + 4) <= HC_GROUP
+            and streams * width <= MAX_HC_LANES)
+
+
+def _hc_chunk(width: int) -> int:
+    return next(n for n in (512, 256, LANES) if width % n == 0)
+
+
+def _fold(x):
+    """[rows, k * 128] -> [rows, 128]: the 128-lane columns added up."""
+    return functools.reduce(operator.add, (
+        x[:, at:at + LANES] for at in range(0, x.shape[1], LANES)))
+
+
+def _lane_sum(x):
+    return jnp.sum(x, axis=-1, keepdims=True)
+
+
+def _lane_iota(rows: int):
+    return jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+
+
+def _sigmoid(x):
+    return 1.0 / (1.0 + jnp.exp(-x))
+
+
+def hc_phi_operand(phi, dtype, transposed: bool = False):
+    """phi [n*C, K] float32 as the kernels' MXU operand [n*C, 128] in
+    ``dtype``: float32 as it is, its K columns first; bfloat16 as three
+    terms (phi = hi + mid + lo to 2^-24) in the lane groups 0, 32, 64.
+    ``transposed`` [128, n*C], for the backward's dz phi^T: the groups
+    hold hi, hi and mid, against a dz laid as hi, lo, hi."""
+    k = phi.shape[1]
+    phi = phi.astype(jnp.float32)
+
+    def group(term):
+        return jnp.pad(term.astype(dtype), ((0, 0), (0, HC_GROUP - k)))
+
+    if dtype == jnp.float32:
+        terms = [phi]
+    else:
+        hi = phi.astype(dtype)
+        rest = phi - hi.astype(jnp.float32)
+        mid = rest.astype(dtype)
+        lo = rest - mid.astype(jnp.float32)
+        terms = [hi, hi, mid] if transposed else [hi, mid, lo]
+    operand = jnp.concatenate([group(t) for t in terms], axis=1)
+    operand = jnp.pad(operand, ((0, 0), (0, LANES - operand.shape[1])))
+    return operand.T if transposed else operand
+
+
+def _hc_coefficients(a, b):
+    """[8, 128] float32: row 0 the products' scales a, row 1 the biases."""
+    k = a.shape[0]
+    return jnp.pad(jnp.stack([a, b]).astype(jnp.float32),
+                   ((0, 6), (0, LANES - k)))
+
+
+def _hc_read_map(zr, ab_ref, k: int):
+    """(z, r, H_pre over the first lanes) of a block's products."""
+    lane = _lane_iota(zr.shape[0])
+    r = zr[:, LANES - 1:]
+    z = jnp.where(lane < k, zr, 0.0)
+    return z, r, _sigmoid(ab_ref[0:1, :] * r * z + ab_ref[1:2, :])
+
+
+def _hc_read_kernel(x_ref, phi_ref, ab_ref, h_ref, zr_ref, *, n: int,
+                    c: int, k: int, eps: float):
+    """A block of rows of the stream, all n*C lanes: the sum of squares
+    and the products with phi CHUNK lanes at a time, then h."""
+    f32 = jnp.float32
+    rows, chunk = x_ref.shape[0], _hc_chunk(c)
+    squares = jnp.zeros((rows, LANES), f32)
+    products = jnp.zeros((rows, LANES), f32)
+    for at in range(0, n * c, chunk):
+        xc = x_ref[:, at:at + chunk]
+        products += _dot(xc, phi_ref[at:at + chunk, :], _NN)
+        xf = xc.astype(f32)
+        squares += _fold(xf * xf)
+    r = jax.lax.rsqrt(_lane_sum(squares) / (n * c) + eps)
+    lane = _lane_iota(rows)
+    z = (products + pltpu.roll(products, LANES - HC_GROUP, 1)
+         + pltpu.roll(products, LANES - 2 * HC_GROUP, 1))
+    zr = jnp.where(lane == LANES - 1, r, jnp.where(lane < k, z, 0.0))
+    zr_ref[...] = zr
+    pre = _hc_read_map(zr, ab_ref, k)[2]
+    for at in range(0, c, chunk):
+        h_ref[:, at:at + chunk] = sum(
+            pre[:, i:i + 1] * x_ref[:, i * c + at:i * c + at + chunk
+                                    ].astype(f32)
+            for i in range(n)).astype(h_ref.dtype)
+
+
+def _hc_read_bwd_kernel(x_ref, dh_ref, dxo_ref, zr_ref, dzr_ref, ab_ref,
+                        phit_ref, dx_ref, dl_ref, *, n: int, c: int,
+                        k: int):
+    """dx = dx_out + H_pre[i] dh + dz phi^T + (dr dr/dx) x and the read
+    map's logits' gradient dl (its first n lanes), where dz, dr are the
+    products' and the statistic's cotangents: what came in (dzr: the
+    other two maps') plus the read map's own, dl a r and sum dl a z."""
+    f32 = jnp.float32
+    rows, chunk = x_ref.shape[0], _hc_chunk(c)
+    lane = _lane_iota(rows)
+    z, r, pre = _hc_read_map(zr_ref[...], ab_ref, k)
+    dots = [jnp.zeros((rows, LANES), f32) for _ in range(n)]
+    for at in range(0, c, chunk):
+        dh = dh_ref[:, at:at + chunk].astype(f32)
+        for i in range(n):
+            dots[i] += _fold(dh * x_ref[:, i * c + at:i * c + at + chunk
+                                        ].astype(f32))
+    d_pre = sum(jnp.where(lane == i, _lane_sum(dots[i]), 0.0)
+                for i in range(n))
+    dl = d_pre * pre * (1.0 - pre)
+    dl_ref[...] = dl
+    a = ab_ref[0:1, :]
+    dzr = dzr_ref[...]
+    dz = jnp.where(lane < k, dzr, 0.0) + dl * a * r
+    dr = dzr[:, LANES - 1:] + _lane_sum(dl * a * z)
+    of_x = -dr * r * r * r / (n * c)
+    if x_ref.dtype == f32:
+        packed = dz
+    else:
+        hi = dz.astype(x_ref.dtype).astype(f32)
+        packed = (hi + pltpu.roll(dz - hi, HC_GROUP, 1)
+                  + pltpu.roll(hi, 2 * HC_GROUP, 1))
+    packed = packed.astype(x_ref.dtype)
+    for i in range(n):
+        for at in range(0, c, chunk):
+            lanes = slice(i * c + at, i * c + at + chunk)
+            dx_ref[:, lanes] = (
+                dxo_ref[:, lanes].astype(f32)
+                + pre[:, i:i + 1] * dh_ref[:, at:at + chunk].astype(f32)
+                + of_x * x_ref[:, lanes].astype(f32)
+                + _dot(packed, phit_ref[:, lanes], _NN)
+            ).astype(dx_ref.dtype)
+
+
+def _hc_maps_columns(m_ref, n: int):
+    """(H_post[i], H_res[i][j]) as [rows, 1] columns of the maps' block."""
+    m = m_ref[...]
+    post = [m[:, n + i:n + i + 1] for i in range(n)]
+    res = [[m[:, 2 * n + i * n + j:2 * n + i * n + j + 1] for j in range(n)]
+           for i in range(n)]
+    return post, res
+
+
+def _hc_write_kernel(x_ref, y_ref, m_ref, o_ref, *, n: int, c: int):
+    f32 = jnp.float32
+    chunk = _hc_chunk(c)
+    post, res = _hc_maps_columns(m_ref, n)
+    for at in range(0, c, chunk):
+        y = y_ref[:, at:at + chunk].astype(f32)
+        xs = [x_ref[:, j * c + at:j * c + at + chunk].astype(f32)
+              for j in range(n)]
+        for i in range(n):
+            o_ref[:, i * c + at:i * c + at + chunk] = (
+                post[i] * y + sum(res[i][j] * xs[j] for j in range(n))
+            ).astype(o_ref.dtype)
+
+
+def _hc_write_bwd_kernel(x_ref, y_ref, m_ref, g_ref, dx_ref, dy_ref, dm_ref,
+                         *, n: int, c: int):
+    """With g the cotangent of the new stream: dx_j = sum_i H_res[i, j]
+    g_i, dy = sum_i H_post[i] g_i, and a position's d H_post[i] = <g_i,
+    y>, d H_res[i, j] = <g_i, x_j>, summed over the lanes here."""
+    f32 = jnp.float32
+    rows, chunk = x_ref.shape[0], _hc_chunk(c)
+    post, res = _hc_maps_columns(m_ref, n)
+    d_post = [jnp.zeros((rows, LANES), f32) for _ in range(n)]
+    d_res = [[jnp.zeros((rows, LANES), f32) for _ in range(n)]
+             for _ in range(n)]
+    for at in range(0, c, chunk):
+        y = y_ref[:, at:at + chunk].astype(f32)
+        xs = [x_ref[:, j * c + at:j * c + at + chunk].astype(f32)
+              for j in range(n)]
+        gs = [g_ref[:, i * c + at:i * c + at + chunk].astype(f32)
+              for i in range(n)]
+        for j in range(n):
+            dx_ref[:, j * c + at:j * c + at + chunk] = sum(
+                res[i][j] * gs[i] for i in range(n)).astype(dx_ref.dtype)
+        dy_ref[:, at:at + chunk] = sum(
+            post[i] * gs[i] for i in range(n)).astype(dy_ref.dtype)
+        for i in range(n):
+            d_post[i] += _fold(gs[i] * y)
+            for j in range(n):
+                d_res[i][j] += _fold(gs[i] * xs[j])
+    lane = _lane_iota(rows)
+    dm = jnp.zeros((rows, LANES), f32)
+    for i in range(n):
+        dm = jnp.where(lane == n + i, _lane_sum(d_post[i]), dm)
+        for j in range(n):
+            dm = jnp.where(lane == 2 * n + i * n + j,
+                           _lane_sum(d_res[i][j]), dm)
+    dm_ref[...] = dm
+
+
+def _hc_maps_steps(lt, n: int, iters: int, eps: float, clamp):
+    """The three maps of a block of positions laid along the LANES: lt
+    [K.., R] float32 logits (row k the k-th product's) -> [K, R], rows
+    H_pre, H_post and H_res row-major. A Sinkhorn step is elementwise
+    work on n arrays [n, R] (row i of the matrix: its columns on the
+    sublanes) and sums over them (a column's) or over the sublanes (a
+    row's)."""
+    pre = _sigmoid(lt[0:n])
+    post = 2.0 * _sigmoid(lt[n:2 * n])
+    ms = [jnp.exp(jnp.clip(lt[(2 + i) * n:(3 + i) * n], *clamp))
+          for i in range(n)]
+    for _ in range(iters):
+        columns = functools.reduce(operator.add, ms) + eps
+        ms = [m / columns for m in ms]
+        ms = [m / (jnp.sum(m, axis=0, keepdims=True) + eps) for m in ms]
+    return jnp.concatenate([pre, post] + ms, axis=0)
+
+
+def _hc_maps_kernel(l_ref, *refs, n: int, iters: int, eps: float, clamp,
+                    transposed: bool):
+    """A block [R, 128] of logits -> the maps [R, 128], through the
+    transposed form [128, R] (positions along the lanes). ``transposed``:
+    the backward, the steps' own `jax.vjp` formed again from the logits."""
+    k = n * (n + 2)
+    rows = l_ref.shape[0]
+    lt = l_ref[...].T[:HC_GROUP]
+
+    def steps(t):
+        return _hc_maps_steps(t, n, iters, eps, clamp)
+
+    if transposed:
+        g_ref, o_ref = refs
+        (out,) = jax.vjp(steps, lt)[1](g_ref[...].T[:k])
+    else:
+        (o_ref,) = refs
+        out = steps(lt)
+        # the witness, in the 2 n lanes after the maps: how far a
+        # position's rows and columns of H_res sum from one
+        ms = [out[(2 + i) * n:(3 + i) * n] for i in range(n)]
+        out = jnp.concatenate(
+            [out] + [jnp.abs(jnp.sum(m, axis=0, keepdims=True) - 1.0)
+                     for m in ms]
+            + [jnp.abs(functools.reduce(operator.add, ms) - 1.0)], axis=0)
+    o_ref[...] = jnp.concatenate(
+        [out, jnp.zeros((LANES - out.shape[0], rows), jnp.float32)],
+        axis=0).T
+
+
+def _hc_maps_call(logits, g, n, iters, eps, clamp, interpret):
+    rows = logits.shape[0]
+    block = next(r for r in (512, 256, HC_ROWS) if rows % r == 0)
+    spec = pl.BlockSpec((block, LANES), lambda t: (t, 0))
+    return pl.pallas_call(
+        functools.partial(_hc_maps_kernel, n=n, iters=iters, eps=eps,
+                          clamp=clamp, transposed=g is not None),
+        name="hc_maps" if g is None else "hc_maps_bwd",
+        out_shape=jax.ShapeDtypeStruct(logits.shape, jnp.float32),
+        grid=(rows // block,),
+        in_specs=[spec] * (1 if g is None else 2), out_specs=spec,
+        compiler_params=pltpu.CompilerParams(**_HC_COMPILER_PARAMS),
+        interpret=interpret)(*((logits,) if g is None else (logits, g)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+def _hc_maps(logits, n, iters, eps, clamp, interpret):
+    return _hc_maps_call(logits, None, n, iters, eps, clamp, interpret)
+
+
+def _hc_maps_fwd(logits, n, iters, eps, clamp, interpret):
+    return _hc_maps_call(logits, None, n, iters, eps, clamp,
+                         interpret), logits
+
+
+def _hc_maps_bwd(n, iters, eps, clamp, interpret, logits, g):
+    return (_hc_maps_call(logits, g.astype(jnp.float32), n, iters, eps,
+                          clamp, interpret),)
+
+
+_hc_maps.defvjp(_hc_maps_fwd, _hc_maps_bwd)
+
+
+def hc_maps_lanes(logits, n: int, iters: int, eps: float, clamp):
+    """maps [T, 128] float32 for logits [T, 128] float32 whose first K =
+    n (n + 2) lanes hold a position's products scaled and biased: H_pre
+    = sigmoid, H_post = 2 sigmoid, H_res = ``iters`` Sinkhorn steps
+    (columns, then rows; ``eps`` in every division) on exp(clip(.,
+    *clamp)); the next 2 n lanes hold |a row's sum - 1| and |a column's
+    sum - 1| of H_res (a witness, no gradient), the rest zero. One kernel
+    each way: XLA
+    makes a fusion of every half step and of its gradient, a hundred and
+    more launches a sublayer over arrays of a few hundred kilobytes.
+    Caller checks `pallas_mode` and `hc_shape_legal`."""
+    return _hc_maps(logits, n, iters, eps, tuple(clamp),
+                    pallas_mode() == "interpret")
+
+
+def _hc_rows(lanes: int):
+    return pl.BlockSpec((HC_ROWS, lanes), lambda t: (t, 0))
+
+
+def _hc_whole(rows: int, lanes: int):
+    return pl.BlockSpec((rows, lanes), lambda t: (0, 0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _hc_read(x, phi, a, b, n, eps, interpret):
+    return _hc_read_fwd(x, phi, a, b, n, eps, interpret)[0]
+
+
+def _hc_read_fwd(x, phi, a, b, n, eps, interpret):
+    rows, width = x.shape
+    c, k = width // n, phi.shape[1]
+    h, zr = pl.pallas_call(
+        functools.partial(_hc_read_kernel, n=n, c=c, k=k, eps=eps),
+        name="hc_read",
+        out_shape=(jax.ShapeDtypeStruct((rows, c), x.dtype),
+                   jax.ShapeDtypeStruct((rows, LANES), jnp.float32)),
+        grid=(rows // HC_ROWS,),
+        in_specs=[_hc_rows(width), _hc_whole(width, LANES),
+                  _hc_whole(8, LANES)],
+        out_specs=(_hc_rows(c), _hc_rows(LANES)),
+        compiler_params=pltpu.CompilerParams(**_HC_COMPILER_PARAMS),
+        interpret=interpret)(x, hc_phi_operand(phi, x.dtype),
+                             _hc_coefficients(a, b))
+    return (h, zr, x), (x, zr, phi, a, b)
+
+
+def _hc_read_bwd(n, eps, interpret, kept, cotangents):
+    x, zr, phi, a, b = kept
+    dh, dzr, dxo = cotangents
+    rows, width = x.shape
+    c, k = width // n, phi.shape[1]
+    dx, dl = pl.pallas_call(
+        functools.partial(_hc_read_bwd_kernel, n=n, c=c, k=k),
+        name="hc_read_bwd",
+        out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((rows, LANES), jnp.float32)),
+        grid=(rows // HC_ROWS,),
+        in_specs=[_hc_rows(width), _hc_rows(c), _hc_rows(width),
+                  _hc_rows(LANES), _hc_rows(LANES), _hc_whole(8, LANES),
+                  _hc_whole(LANES, width)],
+        out_specs=(_hc_rows(width), _hc_rows(LANES)),
+        compiler_params=pltpu.CompilerParams(**_HC_COMPILER_PARAMS),
+        interpret=interpret)(
+            x, dh, dxo.astype(x.dtype), zr, dzr.astype(jnp.float32),
+            _hc_coefficients(a, b),
+            hc_phi_operand(phi, x.dtype, transposed=True))
+    # the leaves' gradients, over the rows: phi's is a product of the
+    # stream with the products' whole cotangent (its float32 as two terms
+    # of the stream's dtype, side by side), the read map's scale and bias
+    # take the sums of its logits' gradient
+    r, z, dl = zr[:, LANES - 1:], zr[:, :k], dl[:, :k]
+    dz = dzr[:, :k].astype(jnp.float32) + dl * a * r
+    hi = dz.astype(x.dtype)
+    terms = jnp.concatenate(
+        [hi, (dz - hi.astype(jnp.float32)).astype(x.dtype)], axis=1)
+    both = jax.lax.dot_general(x, terms, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+    return (dx, (both[:, :k] + both[:, k:]).astype(phi.dtype),
+            jnp.sum(dl * r * z, axis=0).astype(a.dtype),
+            jnp.sum(dl, axis=0).astype(b.dtype))
+
+
+_hc_read.defvjp(_hc_read_fwd, _hc_read_bwd)
+
+
+def hc_read_lanes(x, phi, a, b, n: int, eps: float):
+    """(h [T, C], zr [T, 128] float32, x) for the stream x [T, n*C]: with
+    r = (mean(x^2) + eps)^-1/2 and z = x phi [T, K] (phi float32; K = n (n
+    + 2) columns: the read map's n, the write map's n, the mixing map's
+    n * n), h = sum_i sigmoid(a_i r z_i + b_i) x_i. zr holds z in its
+    first K lanes and r in its last; the caller forms the other maps
+    from them. x comes back as it went in: who reads THAT output hands
+    its cotangent to this function's backward kernel, which adds it
+    inside. Caller checks `pallas_mode` and `hc_shape_legal`."""
+    return _hc_read(x, phi, a, b, n, eps, pallas_mode() == "interpret")
+
+
+def _hc_write_call(x, y, maps, g, n, interpret):
+    rows, width = x.shape
+    c = width // n
+    specs = [_hc_rows(width), _hc_rows(c), _hc_rows(LANES)]
+    if g is None:
+        return pl.pallas_call(
+            functools.partial(_hc_write_kernel, n=n, c=c), name="hc_write",
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            grid=(rows // HC_ROWS,), in_specs=specs,
+            out_specs=_hc_rows(width),
+            compiler_params=pltpu.CompilerParams(**_HC_COMPILER_PARAMS),
+            interpret=interpret)(x, y, maps)
+    return pl.pallas_call(
+        functools.partial(_hc_write_bwd_kernel, n=n, c=c),
+        name="hc_write_bwd",
+        out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(y.shape, y.dtype),
+                   jax.ShapeDtypeStruct(maps.shape, jnp.float32)),
+        grid=(rows // HC_ROWS,), in_specs=specs + [_hc_rows(width)],
+        out_specs=(_hc_rows(width), _hc_rows(c), _hc_rows(LANES)),
+        compiler_params=pltpu.CompilerParams(**_HC_COMPILER_PARAMS),
+        interpret=interpret)(x, y, maps, g.astype(x.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _hc_write(x, y, maps, n, interpret):
+    return _hc_write_call(x, y, maps, None, n, interpret)
+
+
+def _hc_write_fwd(x, y, maps, n, interpret):
+    return _hc_write_call(x, y, maps, None, n, interpret), (x, y, maps)
+
+
+def _hc_write_bwd(n, interpret, kept, g):
+    return _hc_write_call(*kept, g, n, interpret)
+
+
+_hc_write.defvjp(_hc_write_fwd, _hc_write_bwd)
+
+
+def hc_write_lanes(x, y, maps, n: int):
+    """x' [T, n*C], x'_i = sum_j H_res[i, j] x_j + H_post[i] y, for the
+    stream x, the branch's output y [T, C] and maps [T, 128] float32
+    (lanes n..2n-1 H_post, lanes 2n + i n + j H_res[i, j]; the first n,
+    H_pre, are not read). Its backward is one kernel too. Caller checks
+    `pallas_mode` and `hc_shape_legal`."""
+    return _hc_write(x, y, maps.astype(jnp.float32), n,
                      pallas_mode() == "interpret")
 
 

@@ -186,9 +186,13 @@ def serialize_graph(nodes, final_guid: Optional[int] = None,
             attrs["pinned"] = 1
         if op.exports:
             attrs["exports"] = int(op.exports)
+        if getattr(op, "aliased_outputs", 0):
+            # its last outputs are inputs handed through: no bytes
+            attrs["aliased_outputs"] = int(op.aliased_outputs)
         if (getattr(op, "differential", False)
                 or getattr(op, "sparse_index", None)
-                or op.op_type == OperatorType.DELTA_MIXER):
+                or op.op_type in (OperatorType.DELTA_MIXER,
+                                  OperatorType.HC_PRE)):
             # its lambda (the indexer's loss and the counts of pairs)
             # leaves the step beside its output
             attrs["side_counters"] = 1

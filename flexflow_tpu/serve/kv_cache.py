@@ -96,6 +96,14 @@ def init_kv_cache(ff, batch: Optional[int] = None,
         raise ValueError("model has no sequence dim to cache")
     dtype = dtype or ff.executor.compute_dtype
     for node in ff.executor.nodes:
+        if node.op.op_type in (OperatorType.HC_PRE, OperatorType.HC_POST):
+            raise NotImplementedError(
+                f"'{node.op.name}' is a hyper-connection: a decode step "
+                f"would carry the position's n residual streams from "
+                f"layer to layer and mix them by maps formed from all of "
+                f"them, where `decode_forward` carries one hidden row; "
+                f"serving several residual streams a position is not "
+                f"built")
         if node.op.op_type == OperatorType.MAMBA_MIXER or getattr(
                 node.op, "exports", 0) or getattr(node.op, "kv_given",
                                                   False):

@@ -8,6 +8,7 @@
 // materializes constraint boundaries from them.
 #pragma once
 
+#include <algorithm>
 #include <cstdint>
 #include <map>
 #include <string>
@@ -78,10 +79,18 @@ struct Node {
   int64_t input_bytes(int i) const {
     return shape_elems(input_shapes[i]) * dtype_size;
   }
+  // outputs that occupy and move bytes: all but the last
+  // `aliased_outputs`, which are inputs handed through (a hyper-
+  // connection's stream: the reader of THAT output hands its cotangent
+  // to the producer's backward)
+  size_t own_outputs() const {
+    size_t aliased = (size_t)attrs.get("aliased_outputs").as_double(0.0);
+    return output_shapes.size() - std::min(aliased, output_shapes.size());
+  }
   int64_t total_io_bytes() const {
     int64_t b = param_bytes();
     for (size_t i = 0; i < input_shapes.size(); ++i) b += input_bytes(i);
-    for (size_t i = 0; i < output_shapes.size(); ++i) b += output_bytes(i);
+    for (size_t i = 0; i < own_outputs(); ++i) b += output_bytes(i);
     // intermediates an op with a wide interior writes between its own
     // stages (the op states them; 0 for every other op)
     b += static_cast<int64_t>(attrs.get("interior_bytes").as_double(0.0));

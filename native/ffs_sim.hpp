@@ -453,7 +453,7 @@ inline SimResult simulate_pipeline(const Graph& g, const MachineModel& m,
     NodeCost nc = node_cost(n, c, inner, m, training, measured);
     double pmem = node_param_memory(n, c, inner, mem_f);
     double act = 0;
-    for (size_t oi = 0; oi < n.output_shapes.size(); ++oi)
+    for (size_t oi = 0; oi < n.own_outputs(); ++oi)
       act += n.act_bytes(oi) /
              (oi < c.out.size() ? shards_of(c.out[oi], inner) : 1);
     const bool body = meta.body.count(n.guid) > 0;

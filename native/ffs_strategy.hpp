@@ -1368,7 +1368,7 @@ inline double node_act_bytes(const Node& n, const Choice& c,
                             // backward rebuilds it from the checkpointed
                             // inputs (counted at their producers)
   double mem = 0;
-  for (size_t i = 0; i < n.output_shapes.size(); ++i) {
+  for (size_t i = 0; i < n.own_outputs(); ++i) {
     int k = i < c.out.size() ? shards_of(c.out[i], mesh) : 1;
     mem += n.act_bytes(i) / k;
   }

@@ -45,7 +45,7 @@ if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu") \
 
 ZOO = ["mlp", "alexnet", "resnet", "resnext", "inception", "dlrm", "xdl",
        "candle_uno", "moe", "moe_encoder", "transformer", "llama", "lfm2",
-       "ouro", "phi4flash", "keye", "qwen3_next"]
+       "ouro", "phi4flash", "keye", "qwen3_next", "xing4"]
 
 
 def build_model(name: str, ff_config):
@@ -178,6 +178,20 @@ def build_model(name: str, ff_config):
             router_scoring="softmax", shared_expert_gate=True,
             experts_held=4, delta_chunk_size=8, batch_size=8,
             seq_length=16), ff_config), "cat"
+    if name == "xing4":
+        # every residual connection a hyper-connection over 4 streams
+        # (two exported tensors a sublayer: the maps and the stream
+        # handed through); latent attention under YaRN, a dense layer,
+        # then experts, the multi-token-prediction module
+        from flexflow_tpu.models import DecoderConfig, create_decoder
+        return create_decoder(DecoderConfig(
+            hybrid_override_pattern="AX", hc_mult=4,
+            num_attention_heads=4, experts_held=4,
+            num_nextn_predict_layers=1,
+            rope_scaling=dict(type="yarn", factor=64, beta_fast=32,
+                              beta_slow=1, mscale=1, mscale_all_dim=1,
+                              original_max_position_embeddings=8),
+            batch_size=8, seq_length=16), ff_config), "cat"
     raise SystemExit(f"unknown --model {name!r} (zoo: {', '.join(ZOO)})")
 
 

@@ -243,6 +243,47 @@ class TestHybridDecoderKernels:
                                           seq * width)) >= 3
 
 
+    def test_hyper_connection_ops_at_the_xing4_cells_widths(self, topo,
+                                                            on_tpu):
+        """A sublayer's two ops of the xing4 cell (4,096 positions, 4
+        streams of 3584 lanes, bfloat16 stream, float32 leaves), forward
+        and backward: six kernels (read, maps, write, each way), no
+        [S, 4, 3584] view and no float32 copy of the stream outside
+        them, and the stream's two cotangents met inside the read's
+        backward (no add of two [S, 4 * 3584] arrays in XLA)."""
+        from flexflow_tpu.ffconst import OperatorType
+        from flexflow_tpu.layer import Layer
+        from flexflow_tpu.obs.inspect import arrays_between_fusions
+        from flexflow_tpu.ops.base import OpContext, OpRegistry
+        seq, n, c = 4096, 4, 3584
+        assert pk.hc_shape_legal(seq, n, c)
+        pre_layer = Layer(OperatorType.HC_PRE, "pre", [])
+        pre_layer.properties.update(streams=n)
+        pre = OpRegistry.create(pre_layer, [(1, seq, n * c)])
+        post_layer = Layer(OperatorType.HC_POST, "post", [])
+        post_layer.properties.update(streams=n)
+        post = OpRegistry.create(post_layer, [
+            (1, seq, n * c), (1, seq, c), (1, seq, 128)])
+        params, (x,) = abstract_op(topo, pre)
+        assert all(p.dtype == jnp.float32 for p in params.values())
+        ctx = OpContext(training=True, compute_dtype=jnp.bfloat16)
+
+        def sublayer(p, x):
+            h, maps, stream = pre.forward(p, [x], ctx)
+            pre._counters = None
+            return post.forward({}, [stream, h * 2, maps], ctx)[0].astype(
+                jnp.float32).sum()
+
+        hlo = _compile(jax.value_and_grad(sublayer, argnums=(0, 1)), params, x)
+        assert pre.traced_gauges()["hc/kernel_fallbacks"] == 0
+        assert post.traced_gauges()["hc/kernel_fallbacks"] == 0
+        assert pallas_kernel_count(hlo) == 6
+        assert not arrays_between_fusions(hlo, "f32", seq * n * c)
+        assert not re.search(r"\[1,4096,4,3584\]|\[4096,4,3584\]", hlo)
+        parts = {(r["part"], r["direction"]) for r in table_of(hlo).values()}
+        assert {("hyper_connection", "forward"),
+                ("hyper_connection", "backward")} <= parts
+
     def test_the_new_ops_of_the_phi4_mini_flash_cell_at_its_widths(
             self, topo, on_tpu):
         """PR 52's op kinds at the cell's widths (8,192 positions, hidden
